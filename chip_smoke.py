@@ -1,0 +1,520 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process, one TPU v5e chip, no arguments: drives the trainer's main path
+(rollout → reward → shaping → learner update → weight push → next rollout under
+the new adapter version) at the full width of Qwen2.5-0.5B with seeded random
+weights, through ``train_distributed.run_smoke`` — the same assembly
+``python train_distributed.py --smoke`` runs on the CPU at tiny size.
+
+Each phase prints one JSON object with its seconds; any phase that fails ends
+the script with a non-zero code. With no accelerator (or ``JAX_PLATFORMS=cpu``)
+it fails at the ``device`` phase and prints no result. The last line of a
+passing run is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
+
+``--chips 4`` (never given by the driver) runs one other thing and nothing
+else: the same seeded 2-step paged run timeshared on one of four devices and
+role-split 2 actors + 2 learners, compared with each other.
+
+The cuts from the reference volume, stated here and in the output: prompts
+≤ 256 and answers ≤ 256 tokens (reference 350 / 1,200), 8 prompts × 8
+candidates (reference 30 × 16), LoRA rank 16, 3 train steps. Depth and width
+are Qwen2.5-0.5B's own.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+#: bf16 has 8 bits of mantissa (one ulp is 2^-8 ≈ 0.4% of a value); the two
+#: four-chip runs reduce in different orders, so per-step losses may sit a few
+#: ulps apart, and Adam's sign-like first steps turn a last-bit difference in
+#: a near-zero gradient into a full ±lr step of that one element
+LOSS_RTOL = 0.05
+ADAPTER_REL_L2_TOL = 0.25
+
+
+def emit(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+class CompileLog:
+    """Every program JAX compiles (or loads from the persistent cache) from
+    now on: (function name, seconds, when it finished), off JAX's own
+    monitoring events."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.events: list[tuple[str, float, float]] = []
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **kw) -> None:
+        if event == self.EVENT:
+            self.events.append((
+                str(kw.get("fun_name", "?")), float(duration),
+                time.perf_counter(),
+            ))
+
+    def mark(self) -> int:
+        return len(self.events)
+
+    def since(self, mark: int) -> dict:
+        new = self.events[mark:]
+        return {
+            "programs": len(new),
+            "seconds": round(sum(s for _, s, _ in new), 3),
+        }
+
+    def recompiled_after(self, mark: int, t: float) -> tuple[list, list]:
+        """(names compiled again, names compiled for the first time) among
+        the programs since ``mark`` that finished after time ``t``."""
+        before = {n for n, _, done in self.events[mark:] if done < t}
+        late = sorted({n for n, _, done in self.events[mark:] if done >= t})
+        return ([n for n in late if n in before],
+                [n for n in late if n not in before])
+
+
+# ------------------------------------------------------------------ kernels
+
+
+def _paged_case(key, *, rows, heads, kv_heads, head_dim, page_size, pps,
+                quantized):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops.paged import (
+        dispatch_choice_key, dispatch_choices, make_page_table,
+        paged_attention_op, paged_attention_reference, quantize_pages,
+        resolve_paged_impl,
+    )
+
+    kq, kk, kv, kl = jax.random.split(key, 4)
+    shape = (kv_heads, rows * pps, page_size, head_dim)
+    q = jax.random.normal(kq, (rows, heads, head_dim), jnp.bfloat16)
+    k_pages = jax.random.normal(kk, shape, jnp.bfloat16)
+    v_pages = jax.random.normal(kv, shape, jnp.bfloat16)
+    if quantized:
+        k_pages, v_pages = quantize_pages(k_pages), quantize_pages(v_pages)
+    lengths = jax.random.randint(kl, (rows,), 1, pps * page_size + 1)
+    table = jnp.asarray(make_page_table(rows, pps * page_size, page_size))
+    got = jax.jit(paged_attention_op)(q, k_pages, v_pages, lengths, table)
+    want = paged_attention_reference(q, k_pages, v_pages, lengths, table)
+    err = float(jnp.max(jnp.abs(
+        got.astype(jnp.float32) - want.astype(jnp.float32)
+    )))
+    ran = dispatch_choices[dispatch_choice_key(
+        quantized=quantized, num_kv_heads=kv_heads,
+        num_groups=heads // kv_heads, head_dim=head_dim,
+        page_size=page_size, pps=pps,
+    )]
+    assert ran == resolve_paged_impl("auto"), ran
+    assert np.isfinite(err) and err < 5e-2, f"paged {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": round(err, 5)}
+
+
+def _sampler_case(key, logits_dtype, *, rows, vocab):
+    """The fused sampler, compiled, against the multi-pass reference: greedy
+    tokens bit for bit, every logprob against ``token_logprob``, sampled
+    tokens inside the reference nucleus, and the empirical distribution of
+    many draws from one row against the reference probabilities."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops.sampling import (
+        sample_with_logprob, token_logprob, top_p_filter_bisect,
+    )
+
+    k_logits, k_row, k1, k2 = jax.random.split(key, 4)
+    logits = (3.0 * jax.random.normal(k_logits, (rows, vocab))).astype(
+        logits_dtype
+    )
+    fused = jax.jit(
+        lambda rng, lg, t, p: sample_with_logprob(
+            rng, lg, t, p, capture_logprob=True, impl="fused"
+        )
+    )
+    # greedy: the token is the argmax, bit for bit
+    tok, logp = fused(k1, logits, 0.0, 0.95)
+    np.testing.assert_array_equal(
+        np.asarray(tok), np.asarray(jnp.argmax(logits, axis=-1))
+    )
+    np.testing.assert_allclose(
+        np.asarray(logp), np.asarray(token_logprob(logits, tok)),
+        rtol=1e-4, atol=1e-4,
+    )
+    # sampled: inside the nucleus, with the raw-basis logprob of what it drew
+    t, p = 1.2, 0.95
+    tok, logp = fused(k2, logits, t, p)
+    tok_h = np.asarray(tok)
+    assert ((0 <= tok_h) & (tok_h < vocab)).all()
+    np.testing.assert_allclose(
+        np.asarray(logp), np.asarray(token_logprob(logits, tok)),
+        rtol=1e-4, atol=1e-4,
+    )
+    kept = np.asarray(
+        top_p_filter_bisect(logits.astype(jnp.float32) / t, p)
+    ) > -1e29
+    outside = int((~kept[np.arange(rows), tok_h]).sum())
+    # a token tied with the bisected threshold may sit on either side of it
+    assert outside <= max(1, rows // 32), f"{outside}/{rows} outside nucleus"
+    # distribution: many draws of ONE peaked row
+    draws = 1024
+    row = jnp.full((vocab,), -30.0, jnp.float32).at[:16].set(
+        2.0 * jax.random.normal(k_row, (16,))
+    )
+    tiled = jnp.tile(row[None, :].astype(logits_dtype), (draws, 1))
+    tok, _ = fused(k1, tiled, t, p)
+    ref = jax.nn.softmax(
+        top_p_filter_bisect(tiled[:1].astype(jnp.float32) / t, p)[0]
+    )
+    emp = np.bincount(np.asarray(tok), minlength=vocab) / draws
+    tv = 0.5 * float(np.abs(emp - np.asarray(ref)).sum())
+    support = int((np.asarray(ref) > 0).sum())
+    assert tv < 3.0 * (support / draws) ** 0.5, f"TV {tv} over {support}"
+    return {"outside_nucleus": outside, "tv_distance": round(tv, 4),
+            "nucleus_support": support}
+
+
+def phase_kernels(seed: int, compiles: CompileLog) -> None:
+    """Each Pallas kernel the trainer phases use, compiled (never
+    interpreted) at the 0.5B geometry, against its reference on the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from distrl_llm_tpu.models import QWEN2_0_5B as cfg
+    from distrl_llm_tpu.ops.paged import DEFAULT_PAGE_SIZE, pages_per_seq
+    from distrl_llm_tpu.ops.sampling import sample_dispatch
+
+    t0, mark = time.perf_counter(), compiles.mark()
+    key = jax.random.PRNGKey(seed)
+    # the paged trainer phase's own geometry: 256-token prompts and answers
+    pps = pages_per_seq(256, DEFAULT_PAGE_SIZE) + 1 + pages_per_seq(
+        256, DEFAULT_PAGE_SIZE
+    )
+    geom = dict(rows=64, heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim, page_size=DEFAULT_PAGE_SIZE, pps=pps)
+    out: dict = {
+        "paged_bf16": _paged_case(key, quantized=False, **geom),
+        "paged_int8_kv": _paged_case(key, quantized=True, **geom),
+        # "auto" is the same kernel at head_dim 128 (every 7B/8B config)
+        "paged_bf16_hd128": _paged_case(
+            key, quantized=False, **{**geom, "heads": 32, "kv_heads": 8,
+                                     "head_dim": 128},
+        ),
+    }
+    use_fused, interpret = sample_dispatch("bisect")
+    assert use_fused and not interpret, "auto sampler is not the fused kernel"
+    for name, dt in (("sampler_f32", jnp.float32), ("sampler_bf16", jnp.bfloat16)):
+        out[name] = _sampler_case(key, dt, rows=64, vocab=cfg.vocab_size)
+    # the learner's attention: the trainer phases run the CLI's default
+    # attn_impl="reference" (XLA), so no attention kernel is on their path
+    out["learner_attention"] = "reference (XLA): no kernel selected"
+    emit("kernels", seconds=round(time.perf_counter() - t0, 2),
+         compile=compiles.since(mark), **out)
+
+
+# ------------------------------------------------------------------ trainer
+
+
+def chip_sizes(steps: int):
+    from train_distributed import SmokeSizes
+
+    from distrl_llm_tpu.ops.paged import DEFAULT_PAGE_SIZE
+
+    return SmokeSizes(
+        prompts=8, candidates=8, steps=steps,
+        max_prompt_tokens=256, max_new_tokens=256,
+        micro_batch=8, lora_rank=16, dtype="bfloat16",
+        page_size=DEFAULT_PAGE_SIZE, max_concurrent_rows=32, decode_chunk=128,
+    )
+
+
+def cuts(sizes, model_cfg) -> dict:
+    """The cuts from the reference volume, as the trainer phases print them."""
+    return {
+        "max_prompt_tokens": f"{sizes.max_prompt_tokens} (reference 350)",
+        "max_new_tokens": f"{sizes.max_new_tokens} (reference 1200)",
+        "prompts_x_candidates":
+            f"{sizes.prompts} x {sizes.candidates} (reference 30 x 16)",
+        "weights": f"random from --seed, {sizes.dtype}",
+        "depth": f"{model_cfg.num_layers} layers (uncut)",
+    }
+
+
+def trainer_config(engine_impl: str, seed: int, sizes, actors: int = 1,
+                   learners: int = 1):
+    from distrl_llm_tpu.config import TrainConfig
+
+    paged = engine_impl == "paged"
+    return TrainConfig(
+        model="qwen2.5-0.5b (random weights)", engine_impl=engine_impl,
+        # the refill scheduler, engaged: half as many decode slots as rows
+        continuous_batching=paged,
+        max_concurrent_sequences=sizes.max_concurrent_rows if paged else 0,
+        # no plan database outside the checkout is read: the engines resolve
+        # the static defaults, printed below
+        autotune=False, seed=seed,
+        # (TrainConfig carries these into its MeshConfig itself)
+        number_of_actors=actors, number_of_learners=learners,
+        # one engine call per round (no learner-side share of the rollout):
+        # a role-split run then draws the same samples from the same keys
+        # as a one-device run
+        learner_chunk_size=0,
+    )
+
+
+def run_trainer(model_cfg, config, sizes, seed: int, compiles: CompileLog,
+                devices=None) -> dict:
+    """``run_smoke`` at these sizes with the dense smoke reward, plus the
+    asserts every trainer phase shares. Returns the report with a
+    ``summary`` of what to print."""
+    import jax
+    from train_distributed import dense_smoke_reward, run_smoke
+
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine.engine import ENGINE_CHUNK_FALLBACK
+    from distrl_llm_tpu.ops import paged as paged_ops
+    from distrl_llm_tpu.ops.sampling import (
+        sample_dispatch_choices, sample_impl_mode,
+    )
+
+    paged_ops.dispatch_choices.clear()
+    sample_dispatch_choices.clear()
+    fallback0 = telemetry.observe_snapshot()["counters"].get(
+        ENGINE_CHUNK_FALLBACK, 0
+    )
+    on_tpu = jax.default_backend() == "tpu"
+
+    t0, mark0 = time.perf_counter(), compiles.mark()
+    report = run_smoke(
+        config, model_cfg, sizes, seed=seed, reward_fn=dense_smoke_reward,
+        devices=devices,
+    )
+    seconds = time.perf_counter() - t0
+    steps, rounds = report["steps"], report["rounds"]
+    trainer = report["trainer"]
+    engine = trainer.engine
+
+    assert len(steps) == sizes.steps, (len(steps), sizes.steps)
+    # rounds[0] is the initial evaluation; then one rollout per train step
+    train_rounds = rounds[-sizes.steps:]
+    assert all(r["gen_tokens"] > 0 for r in rounds), rounds
+    versions = [r["policy_version"] for r in train_rounds]
+    assert versions == list(range(sizes.steps)), (
+        f"rollouts sampled under policy versions {versions}: step k must "
+        "sample under the adapter that k-1 updates produced"
+    )
+    checksums = [r["adapter_checksum"] for r in train_rounds] + [
+        report["final_adapter_checksum"]
+    ]
+    assert all(a != b for a, b in zip(checksums, checksums[1:])), (
+        f"adapter unchanged by an update: {checksums}"
+    )
+    fallbacks = telemetry.observe_snapshot()["counters"].get(
+        ENGINE_CHUNK_FALLBACK, 0
+    ) - fallback0
+    assert fallbacks == 0, f"{fallbacks} engine/chunk_fallback"
+    # what the configuration asked for: DISTRL_SAMPLE_KERNEL, by default
+    # "auto" = the fused kernel on a TPU backend, the multi-pass path elsewhere
+    mode = sample_impl_mode()
+    asked = "fused" if mode in ("fused", "interpret") or (
+        mode == "auto" and on_tpu
+    ) else "xla"
+    sampler = sorted(set(sample_dispatch_choices.values()))
+    assert sampler == [asked], (sampler, asked)
+    paged_record = None
+    if config.engine_impl == "paged":
+        paged_record = paged_ops.dispatch_choices.get(engine._dispatch_key())
+        assert paged_record is not None, "no paged dispatch was recorded"
+        if on_tpu:
+            assert paged_record.startswith("native"), paged_record
+    # no compile after step 1 for a program already compiled: the second
+    # train step's rollout starts the window
+    again, fresh = [], []
+    if sizes.steps > 1:
+        again, fresh = compiles.recompiled_after(
+            mark0, train_rounds[1]["started"]
+        )
+    assert not again, f"compiled again after step 1: {again}"
+    plan = getattr(engine, "resolved_plan", None)
+    peak = None
+    stats = jax.local_devices()[0].memory_stats()
+    if stats:
+        peak = stats.get("peak_bytes_in_use")
+    report["summary"] = {
+        "seconds": round(seconds, 2),
+        "compile": compiles.since(mark0),
+        "losses": [m["loss"] for m in steps],
+        "policy_versions": versions,
+        "adapter_checksums": [round(c, 6) for c in checksums],
+        "round_seconds": [round(r["seconds"], 2) for r in rounds],
+        "update_seconds": [
+            round(m["timing/update_duration"], 2) for m in steps
+        ],
+        "gen_tokens": [r["gen_tokens"] for r in rounds],
+        "paged_dispatch": paged_record,
+        "sampler": sampler[0],
+        "chunk_fallbacks": fallbacks,
+        "plan": plan.plan.to_dict() if plan is not None else None,
+        "plan_source": plan.source if plan is not None else None,
+        "peak_bytes_in_use": peak,
+        "compiled_after_step_1": fresh,
+    }
+    return report
+
+
+def phase_trainer(name: str, engine_impl: str, steps: int, seed: int,
+                  compiles: CompileLog) -> None:
+    from distrl_llm_tpu.models import QWEN2_0_5B
+
+    sizes = chip_sizes(steps)
+    report = run_trainer(
+        QWEN2_0_5B, trainer_config(engine_impl, seed, sizes), sizes, seed,
+        compiles,
+    )
+    emit(name, model="QWEN2_0_5B", engine_impl=engine_impl, steps=steps,
+         cuts=cuts(sizes, QWEN2_0_5B), **report["summary"])
+
+
+# --------------------------------------------------------------- four chips
+
+
+def _device_ids(tree) -> set[int]:
+    import jax
+
+    return {
+        d.id for leaf in jax.tree_util.tree_leaves(tree)
+        for d in leaf.devices()
+    }
+
+
+def phase_four_chips(seed: int, compiles: CompileLog, model_cfg=None,
+                     sizes=None) -> None:
+    """The same seeded 2-step paged run (i) timeshared on one of the four
+    devices and (ii) role-split, 2 actors + 2 data-parallel learners —
+    losses and final adapter compared, placement read off the arrays."""
+    import jax
+    import numpy as np
+
+    from distrl_llm_tpu.models import QWEN2_0_5B
+
+    model_cfg = model_cfg if model_cfg is not None else QWEN2_0_5B
+    sizes = sizes if sizes is not None else chip_sizes(steps=2)
+    devices = jax.devices()
+    assert len(devices) == 4, f"--chips 4 needs four devices, found {devices}"
+    t0 = time.perf_counter()
+
+    def run(actors: int, learners: int, devs):
+        cfg = trainer_config("paged", seed, sizes, actors, learners)
+        return run_trainer(model_cfg, cfg, sizes, seed, compiles, devs)
+
+    one = run(1, 1, [devices[0]])
+    lora_one = jax.device_get(one["trainer"].lora)
+    assert one["trainer"].meshes.timeshared
+    one["trainer"] = None  # drop the first run's device memory
+    split = run(2, 2, devices)
+    tr = split["trainer"]
+    assert not tr.meshes.timeshared
+
+    actor_ids = {d.id for d in tr.meshes.rollout.devices.flat}
+    learner_ids = {d.id for d in tr.meshes.learner.devices.flat}
+    assert len(actor_ids) == 2 and len(learner_ids) == 2
+    assert not actor_ids & learner_ids
+    placement = {
+        "actor_devices": sorted(actor_ids),
+        "learner_devices": sorted(learner_ids),
+        "rollout_params": sorted(_device_ids(tr.base_params)),
+        "rollout_adapter": sorted(_device_ids(tr._lora_rollout)),
+        "kv_pages": sorted(tr.engine.last_pool_stats["kv_devices"]),
+        "learner_params": sorted(_device_ids(tr.base_params_learner)),
+        "learner_adapter": sorted(_device_ids(tr.lora)),
+        "optimizer_state": sorted(_device_ids(tr.opt_state)),
+    }
+    for what in ("rollout_params", "rollout_adapter", "kv_pages"):
+        assert set(placement[what]) == actor_ids, (what, placement)
+    for what in ("learner_params", "learner_adapter", "optimizer_state"):
+        assert set(placement[what]) == learner_ids, (what, placement)
+    # _push_weights moved the adapter across submeshes: the rollout copy is
+    # the learner's adapter, value for value, on the other devices
+    lora_split = jax.device_get(tr.lora)
+    pushed = jax.device_get(tr._lora_rollout)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, lora_split, pushed)
+    assert tr._rollout_weight_version == tr.weight_version == sizes.steps
+
+    loss_one, loss_split = one["summary"]["losses"], split["summary"]["losses"]
+    scale = max(abs(x) for x in loss_one)
+    loss_err = [abs(a - b) / scale for a, b in zip(loss_one, loss_split)]
+    num = sum(
+        float(np.sum((np.asarray(a, np.float64) - np.asarray(b, np.float64)) ** 2))
+        for a, b in zip(jax.tree_util.tree_leaves(lora_one),
+                        jax.tree_util.tree_leaves(lora_split))
+    )
+    # relative to how far training moved the adapter, not to its init
+    moved = sum(
+        float(np.sum(np.asarray(a["b"], np.float64) ** 2))
+        for a in jax.tree_util.tree_leaves(
+            lora_one, is_leaf=lambda x: isinstance(x, dict) and "b" in x
+        )
+    )
+    rel_l2 = (num / max(moved, 1e-30)) ** 0.5
+    emit(
+        "four_chips", seconds=round(time.perf_counter() - t0, 2),
+        tolerance={"loss_rel": LOSS_RTOL, "adapter_rel_l2": ADAPTER_REL_L2_TOL},
+        losses_one_device=loss_one, losses_role_split=loss_split,
+        loss_rel_err=[round(e, 6) for e in loss_err],
+        adapter_rel_l2=round(rel_l2, 6), placement=placement,
+        one_device=one["summary"], role_split=split["summary"],
+    )
+    assert all(e <= LOSS_RTOL for e in loss_err), (loss_err, LOSS_RTOL)
+    assert rel_l2 <= ADAPTER_REL_L2_TOL, (rel_l2, ADAPTER_REL_L2_TOL)
+
+
+# --------------------------------------------------------------------- main
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    t0 = time.perf_counter()
+    import jax
+
+    from distrl_llm_tpu.utils.devices import enable_compile_cache, require_tpu
+
+    cache_dir = enable_compile_cache()
+    # no cpu_requested: this script has no CPU mode, whatever the variable says
+    devices = require_tpu(jax.devices())
+    device = {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    assert device["count"] == args.chips, (
+        f"--chips {args.chips} but JAX sees {device['count']} device(s)"
+    )
+    emit("device", seconds=round(time.perf_counter() - t0, 2),
+         compile_cache_dir=cache_dir, jax=jax.__version__, **device)
+
+    compiles = CompileLog()
+    if args.chips == 4:
+        phase_four_chips(args.seed, compiles)
+    else:
+        phase_kernels(args.seed, compiles)
+        phase_trainer("trainer_paged", "paged", 3, args.seed, compiles)
+        phase_trainer("trainer_dense", "dense", 1, args.seed, compiles)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

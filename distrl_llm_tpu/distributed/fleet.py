@@ -53,6 +53,7 @@ from dataclasses import dataclass, field
 
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.obs import FLEET_SCALE_EVENTS, FLEET_TARGET_WORKERS
+from distrl_llm_tpu.utils.devices import holds_tpu, worker_env
 
 log = logging.getLogger(__name__)
 
@@ -69,7 +70,8 @@ class WorkerSpec:
     ``extra_args`` verbatim — e.g. ``("--metrics-port", "0")`` or a
     ``--fault-schedule`` for chaos runs. ``env`` overlays the inherited
     environment (``DISTRL_OBS=1`` for fleet-aggregation runs, forced
-    ``JAX_PLATFORMS=cpu`` in tests).
+    ``JAX_PLATFORMS=cpu`` in tests); a worker that is not a CPU worker is
+    also given its own TPU chip there (``utils.devices.worker_env``).
     """
 
     serve_model: str | None = None
@@ -135,6 +137,7 @@ class _Proc:
     # plane's drain but cannot observe its exit status or respawn it
     proc: subprocess.Popen | None
     address: tuple[str, int]
+    chip: int | None = None  # the TPU chip its environment names (None: CPU)
     retiring: bool = False   # supervisor-initiated drain in progress
     drained: bool = False    # exit 0 after a retire (the SIGTERM contract)
 
@@ -197,7 +200,19 @@ class FleetSupervisor:
     # ------------------------------------------------------------ spawn
 
     def _spawn(self) -> _Proc:
-        env = {**os.environ, **self.spec.env}
+        # one process per chip: the worker's chip is named in its
+        # environment, and a driver that holds every chip itself is told so
+        # here instead of starting a child that hangs
+        with self._mu:
+            in_use = [
+                r.chip for r in self._procs.values()
+                if r.chip is not None and r.proc is not None
+                and r.proc.poll() is None
+            ]
+        env, chip = worker_env(
+            os.environ, self.spec.env, chips_in_use=in_use,
+            parent_holds_tpu=holds_tpu(),
+        )
         proc = subprocess.Popen(
             self.spec.argv(), stdout=subprocess.PIPE,
             stderr=subprocess.DEVNULL, text=True, env=env,
@@ -222,7 +237,7 @@ class FleetSupervisor:
                 f"worker failed to report PORT within {self.spawn_timeout_s}s "
                 f"(exit {proc.returncode})"
             )
-        return _Proc(proc=proc, address=(_HOST, port))
+        return _Proc(proc=proc, address=(_HOST, port), chip=chip)
 
     def start(self, n: int) -> list[tuple[str, int]]:
         """Spawn the initial pool (pre-connect: no admission — the caller

@@ -5,8 +5,8 @@ The reference passes ``--actor_gpu_usage`` straight to vLLM's
 block pool as: usable = usage × device_memory − weights − activation
 workspace, pool_blocks = usable / block_bytes. This module is the TPU-native
 equivalent: it converts the same fraction into ``max_kv_pages`` for the paged
-engine's refill pool (engine/page_pool.py), measured against real HBM when a
-TPU is attached and a v5e-sized fallback otherwise.
+engine's refill pool (engine/page_pool.py), measured against the HBM the
+TPU runtime reports (and against one v5e chip's 16 GiB on the CPU).
 """
 
 from __future__ import annotations
@@ -18,8 +18,8 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
-# v5e/v5p chips carry 16 GiB; used only when memory_stats() is unavailable
-# (CPU test runs, older runtimes)
+# what a backend with no accelerator memory to report (the CPU of tests and
+# rehearsals) is sized against: one v5e chip's 16 GiB
 DEFAULT_HBM_BYTES = 16 * 1024**3
 
 # slice of the budget held back for XLA workspace, decode activations, and
@@ -29,15 +29,20 @@ ACTIVATION_RESERVE = 0.08
 
 
 def device_hbm_bytes(device=None) -> int:
-    """Accelerator memory capacity, from the runtime when it reports one."""
-    try:
-        dev = device or jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 — CPU/interpreted backends
-        pass
-    return DEFAULT_HBM_BYTES
+    """Accelerator memory capacity as the runtime reports it. A TPU that
+    reports no ``bytes_limit`` is an error (a pool sized against a guessed
+    chip either wastes the real one or overruns it); only a backend that is
+    not a TPU gets ``DEFAULT_HBM_BYTES``."""
+    dev = device or jax.local_devices()[0]
+    if dev.platform != "tpu":
+        return DEFAULT_HBM_BYTES
+    stats = dev.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit'] to size the KV "
+            "pool against — pass hbm_bytes explicitly"
+        )
+    return int(stats["bytes_limit"])
 
 
 def tree_bytes(params) -> int:

@@ -70,27 +70,13 @@ from distrl_llm_tpu.engine.paged_engine import (
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.ops.paged import pages_per_seq
 
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map as _raw_shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import (  # type: ignore[no-redef]
-        shard_map as _raw_shard_map,
-    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Replication checks off across both shard_map generations (the new API
-    renamed check_rep → check_vma)."""
-    try:
-        return _raw_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return _raw_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """``jax.shard_map`` with the replication checks off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
+    )
 
 Params = dict[str, Any]
 

@@ -89,9 +89,7 @@ def _proj(h, p, lora, key, bias_key, lora_scale,
 
         a, b = lora[key]["a"], lora[key]["b"]
         bits = 4 if w["q"].dtype == jnp.int4 else 8
-        use, interp = quant_matmul_dispatch(
-            w["q"].shape, bits, a.shape[-1], h.shape[-1], h.dtype
-        )
+        use, interp = quant_matmul_dispatch()
         dispatch_choices[
             (bits, h.shape[-1], w["q"].shape[-1], a.shape[-1])
         ] = "kernel" if use else "xla"

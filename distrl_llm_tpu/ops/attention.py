@@ -12,8 +12,12 @@ dims map onto (sublane, lane) tiles.
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30  # large-negative for masked logits; avoids NaNs from true -inf
 
@@ -122,9 +126,9 @@ def attention_cached_quant(
     * v: out[..., d] = Σ_s probs·v8·v_scale[s] — the scale rides the probs
       ([B, K, G, Sq, Sk] f32, already materialized by the softmax).
 
-    XLA fuses the int8→f32 convert into the dot-operand read; the
-    decode-step HBM audit in tools/tpu_kernel_check.py is the on-chip
-    check that no f32 cache-sized temp materializes.
+    XLA is expected to fuse the int8→f32 convert into the dot-operand read
+    (no f32 cache-sized temp); a compiled step's ``memory_analysis`` shows
+    whether it did.
 
     ``formulation="mulred"`` — see attention_cached: mandatory for the
     scan-chunk programs, where a dot over the carried int8 cache costs a
@@ -192,9 +196,8 @@ def mulred_broadcast_bytes(batch_rows: int, kv_heads: int, groups: int,
                            head_dim: int, kv_len: int) -> int:
     """Bytes of ONE layer's unfused ``_gqa_mulred`` broadcast product — the
     [B, KH, G, D, S] f32 temp a backend would materialize if it failed to
-    fuse reduce-of-product into the cache read. The HBM audits
-    (tools/tpu_kernel_check.py and ``compile_chunk_guarded``'s
-    ``fusion_bytes`` threshold) price temp bytes against this: a fused
+    fuse reduce-of-product into the cache read. ``compile_chunk_guarded``'s
+    ``fusion_bytes`` threshold prices temp bytes against this: a fused
     program's scratch sits far below it, an unfused one lands on it and
     OOMs real geometries (ADVICE r5)."""
     return batch_rows * kv_heads * groups * head_dim * kv_len * 4
@@ -237,62 +240,20 @@ def _gqa_mulred(q, k, v, mask, scale, *, k_scale=None, v_scale=None):
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
-import logging
-
-logger = logging.getLogger(__name__)
-
+#: set once a "flash"/"splash" request ran the XLA reference instead (a
+#: backend other than the TPU, or a call outside the kernels'
+#: self-attention contract) — bench rows read it as ``attn_fallback``
 _flash_fallback_warned = False
-_kernel_probe_state: dict = {}
-
-# substrings marking transient device/runtime failures that say nothing
-# about lowering legality — never negative-cache these
-_TRANSIENT_ERR_MARKS = ("RESOURCE_EXHAUSTED", "DEADLINE", "UNAVAILABLE",
-                        "CANCELLED", "ABORTED")
 
 
-def _kernel_lowers(kind: str, h: int, kh: int, d: int, sq: int, dtype) -> bool:
-    """Probe-compile the flash/splash kernel — forward AND backward — at
-    this (head geometry, seq) config, once per config. Mosaic block-rule
-    rejections fire at COMPILE time — past any try/except around the
-    traced call inside a larger jit, which is exactly how the paged launch
-    failed on first silicon (round 3; see ops/paged_int8.py). An eager
-    probe catches them while the reference-path fallback is still
-    possible. The seq is part of the key because block shapes derive from
-    it (splash: block = min(512, padded seq)); the grad pass covers the
-    custom-VJP dkv/dq kernels the training path differentiates through."""
-    key = (kind, h, kh, d, sq, jnp.dtype(dtype).name)
-    if key not in _kernel_probe_state:
-        try:
-            b = 1
-            q = jnp.zeros((b, sq, h, d), dtype)
-            k = jnp.zeros((b, sq, kh, d), dtype)
-            if kind == "flash":
-                from distrl_llm_tpu.ops.flash_attention import flash_attention
-
-                fwd = lambda q_, k_: flash_attention(q_, k_, k_, None)  # noqa: E731
-            else:
-                from distrl_llm_tpu.ops.splash import splash_attention
-
-                valid = jnp.ones((b, sq), jnp.int32)
-                fwd = lambda q_, k_: splash_attention(q_, k_, k_, valid)  # noqa: E731
-            jax.block_until_ready(fwd(q, k))
-            # backward kernels (dq/dkv block specs) lower independently
-            g = jax.grad(lambda q_, k_: fwd(q_, k_).astype(jnp.float32).sum(),
-                         argnums=(0, 1))(q, k)
-            jax.block_until_ready(g)
-            _kernel_probe_state[key] = True
-        except Exception as e:  # noqa: BLE001 — classify before caching
-            msg = str(e).upper()
-            transient = any(m in msg for m in _TRANSIENT_ERR_MARKS)
-            if not transient:
-                _kernel_probe_state[key] = False
-            logger.warning(
-                "%s attention kernel failed its lowering probe for %s (%s); "
-                "using the XLA reference path%s", kind, key, e,
-                " (transient error — will re-probe)" if transient else "",
-            )
-            return False
-    return _kernel_probe_state[key]
+def _note_reference(impl: str, why: str) -> None:
+    global _flash_fallback_warned
+    if not _flash_fallback_warned:
+        _flash_fallback_warned = True
+        logger.warning(
+            "%s attention runs the XLA reference path here (%s) — "
+            "O(Sq*Sk) memory", impl, why,
+        )
 
 
 def attention(
@@ -304,50 +265,36 @@ def attention(
     impl: str = "reference",
     key_valid: jax.Array | None = None,
 ) -> jax.Array:
-    """Dispatching front door. ``impl``: "reference" (XLA) or "flash" (Pallas,
-    TPU only; warns once and falls back to reference where unsupported).
+    """Dispatching front door. ``impl``: "reference" (XLA), "flash" or
+    "splash" (Pallas kernels).
+
+    On a TPU backend a named kernel is what runs: a kernel that fails to
+    lower or to run fails the step, it never gives way to the reference
+    (tests/test_tpu_compile.py holds the lowerings at training shapes). The
+    reference serves a kernel request only where the kernel cannot apply by
+    construction: on a backend other than the TPU, and outside the kernels'
+    contract (self-attention under a head-agnostic mask — cached decode
+    steps and warm-prefix prefill are not).
 
     ``key_valid`` is the [B, Sk] validity vector; the flash/splash paths
     consume it directly (no [B, 1, Sq, Sk] mask needs to exist). When only
-    ``key_valid`` is given and the fallback runs, the dense causal mask is
+    ``key_valid`` is given and the reference runs, the dense causal mask is
     built here."""
-    global _flash_fallback_warned
-    h, kh, d = q.shape[2], k.shape[2], q.shape[3]
-    if impl == "splash":
-        try:
-            if jax.default_backend() != "tpu":
-                raise NotImplementedError(
-                    "splash kernel requires the TPU backend (interpret mode "
-                    "is test-only)"
-                )
-            if not _kernel_lowers("splash", h, kh, d, q.shape[1], q.dtype):
-                raise NotImplementedError("splash failed its lowering probe")
+    if impl in ("flash", "splash"):
+        if jax.default_backend() != "tpu":
+            _note_reference(impl, "no TPU backend")
+        elif q.shape[1] != k.shape[1] or (
+            mask is not None and mask.shape[1] != 1
+        ):
+            _note_reference(impl, "not a self-attention call")
+        elif impl == "splash":
             from distrl_llm_tpu.ops.splash import splash_attention
 
             return splash_attention(q, k, v, key_valid, scale=scale)
-        except Exception as e:  # noqa: BLE001 — fall back with one warning
-            if not _flash_fallback_warned:
-                _flash_fallback_warned = True
-                logger.warning(
-                    "splash attention unavailable (%s); falling back to the "
-                    "XLA reference path", e,
-                )
-    if impl == "flash":
-        try:
-            if jax.default_backend() == "tpu" and not _kernel_lowers(
-                "flash", h, kh, d, q.shape[1], q.dtype
-            ):
-                raise NotImplementedError("flash failed its lowering probe")
+        else:
             from distrl_llm_tpu.ops.flash_attention import flash_attention
 
             return flash_attention(q, k, v, mask, scale=scale, key_valid=key_valid)
-        except (ImportError, NotImplementedError) as e:
-            if not _flash_fallback_warned:
-                _flash_fallback_warned = True
-                logger.warning(
-                    "flash attention unavailable (%s); falling back to the XLA "
-                    "reference path — O(Sq*Sk) memory", e,
-                )
     if mask is None and key_valid is not None:
         mask = causal_padding_mask(key_valid, q_len=q.shape[1])
     return attention_reference(q, k, v, mask, scale=scale)

@@ -19,7 +19,7 @@ def linear(x: jax.Array, w, b: jax.Array | None = None) -> jax.Array:
 
     Quantized containers dispatch to the fused Pallas dequant-matmul
     (ops/quant_matmul.py) when it is enabled for this backend
-    (DISTRL_QUANT_MATMUL; probe-gated "auto" = TPU only), else to the XLA
+    (DISTRL_QUANT_MATMUL; "auto" = TPU only), else to the XLA
     container path below — same math, same order, greedy-bit-identical."""
     if isinstance(w, dict):
         if w["q"].ndim == 3:
@@ -28,9 +28,7 @@ def linear(x: jax.Array, w, b: jax.Array | None = None) -> jax.Array:
             )
 
             bits = 4 if w["q"].dtype == jnp.int4 else 8
-            use, interp = quant_matmul_dispatch(
-                w["q"].shape, bits, 0, x.shape[-1], x.dtype
-            )
+            use, interp = quant_matmul_dispatch()
             dispatch_choices[(bits, x.shape[-1], w["q"].shape[-1], 0)] = (
                 "kernel" if use else "xla"
             )

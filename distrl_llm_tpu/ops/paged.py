@@ -19,20 +19,19 @@ distributed_actor.py:148–150). TPU-native design:
   sequences grow and rewrites rows on admission/preemption; wave mode uses
   a per-round constant layout). The indirection layer is also what lets
   prompt-prefix sharing land without touching the kernel.
-* **Kernel**: jaxlib's Pallas TPU ``paged_attention`` (Mosaic) on TPU — via
-  the compact-scales launch (ops/paged_int8.py) for int8 pages; a jnp
-  reference with identical semantics elsewhere and for parity tests.
+* **Kernel**: our Pallas TPU kernels (ops/paged_native.py, compact int8
+  scales) on a TPU backend; a jnp reference with identical semantics
+  elsewhere and for parity tests.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from distrl_llm_tpu.ops.attention import NEG_INF
+from distrl_llm_tpu.ops.per_device import per_device
 
 DEFAULT_PAGE_SIZE = 128
 
@@ -57,10 +56,9 @@ def quantize_pages(pages: jax.Array):
 
     Decode-speed note: jaxlib's public ``paged_attention`` wrapper broadcasts
     these scales to head_dim before its pallas_call (a full-cache f32 temp
-    per step, which would negate the bandwidth win); the TPU kernel path
-    here uses the COMPACT-scales launch instead (ops/paged_int8.py — same
-    jaxlib kernel, scales shipped [ps, 1], ~1 + 4/head_dim bytes/element),
-    so int8 KV buys both capacity AND read bandwidth."""
+    per step, which would negate the bandwidth win); the native kernels
+    (ops/paged_native.py) read the scales COMPACT ([ps, 1], ~1 + 4/head_dim
+    bytes/element), so int8 KV buys both capacity AND read bandwidth."""
     return _quant_utils().quantize_to_int8(pages)
 
 
@@ -296,9 +294,6 @@ def paged_attention_reference(
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
-_kernel_fail_warned = False
-_fixed_launch_state: dict = {}
-
 #: kernel default for the blocked launch's page-axis collapse — callers
 #: passing 0 get this (kept here so plan resolution, bench records, and the
 #: analytic grid-step model all agree on what "default" means)
@@ -322,18 +317,17 @@ def paged_grid_steps(
     "native_verify" is the FUSED draft-block verify: the whole (d+1)-query
     speculative verify step in ONE blocked sweep — same (B,
     ceil(pps / pages_per_block)) count as "native_blocked", where the
-    unrolled verify paid that count (d+1) TIMES per step; the jaxlib
-    kernels ("fixed"/"jaxlib"/"kernel") walk pages with manual DMA inside a
-    (1, B, K) grid; the jnp reference has no Pallas grid (0)."""
-    base = impl.split("!")[0]  # strip the "!transient-probe" honesty marker
-    if base == "native":
+    unrolled verify paid that count (d+1) TIMES per step; jaxlib's kernel
+    ("kernel") walks pages with manual DMA inside a (1, B, K) grid; the jnp
+    reference has no Pallas grid (0)."""
+    if impl == "native":
         return batch * num_kv_heads * pps
-    if base == "native_folded":
+    if impl == "native_folded":
         return batch * pps
-    if base in ("native_blocked", "native_verify"):
+    if impl in ("native_blocked", "native_verify"):
         ppb = max(1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps))
         return batch * -(-pps // ppb)
-    if base in ("fixed", "jaxlib", "kernel"):
+    if impl == "kernel":
         return batch * num_kv_heads
     return 0
 
@@ -361,8 +355,8 @@ def dispatch_choice_key(
 def divisor_blocks(pages_per_compute_block: int, pps: int) -> int:
     """Largest divisor of ``pps`` that fits ``pages_per_compute_block`` —
     the per-call block count the one-page kernels launch with. Shared so
-    consumers (the fused-verify probe) derive it from the geometry instead
-    of indexing the dispatch key tuple positionally."""
+    consumers derive it from the geometry instead of indexing the dispatch
+    key tuple positionally."""
     return max(
         (d for d in range(1, min(pages_per_compute_block, pps) + 1)
          if pps % d == 0),
@@ -378,30 +372,46 @@ def dispatch_key_is_verify(key) -> bool:
     summary, trace filters) must call this instead of indexing, so the
     next field appended to the key cannot silently break their filters."""
     return isinstance(key, tuple) and len(key) >= 10 and bool(key[9])
-# per-config record of what the auto-dispatch chain actually chose
-# ("native" | "native_folded" | "fixed" | "jaxlib" | "reference") —
-# bench records surface
-# this so a reference-fallback run cannot masquerade as a kernel
-# measurement (same honesty contract as attn_fallback / scan_chunk_active)
+
+
+# per-config record of what each paged dispatch resolved to ("native" |
+# "native_folded" | "native_blocked" | "kernel" | "reference") — engines,
+# bench records and chip_smoke.py read it, so a run on the reference can
+# never pass for a kernel measurement
 dispatch_choices: dict = {}
 # NOTE on grid-step accounting: the analytic count is batch-dependent, so
 # it is never cached here — consumers read WHICH impl ran from
 # dispatch_choices (keyed per requested impl + geometry) and compute
 # paged_grid_steps() against their own live batch/ppb.
-# probe keys whose latest failure was transient (RESOURCE_EXHAUSTED etc.):
-# transient failures are never negative-cached, but the dispatch decision is
-# made at TRACE time and baked into the compiled program — a transient probe
-# error during the first trace silently downgrades that shape until retrace.
-# The chain marks affected dispatch_choices with "!transient-probe" so bench
-# records can flag the downgrade instead of presenting it as a settled pick.
-transient_probe_keys: set = set()
+
+#: every spelling ``paged_attention_op(impl=...)`` takes
+PAGED_IMPLS = ("auto", "reference", "kernel", "native", "native_folded",
+               "native_blocked")
+#: what "auto" is on a TPU backend, at every geometry: the one-page native
+#: kernel — the only family that lowers for head_dim 64 AND 128
+#: (tests/test_tpu_compile.py) and the one chip_smoke.py holds to the
+#: reference on the chip. The folded/blocked variants and jaxlib's kernel
+#: run where a caller or a stored plan names them.
+AUTO_TPU_IMPL = "native"
+
+
+def resolve_paged_impl(impl: str) -> str:
+    """The concrete impl a request runs as. "auto" is ``AUTO_TPU_IMPL`` on a
+    TPU backend and the jnp reference on any other; every other spelling is
+    itself. Nothing here probes and nothing downstream catches: on the TPU a
+    kernel that fails to lower or to run fails the step that called it."""
+    if impl not in PAGED_IMPLS:
+        raise ValueError(f"impl must be one of {PAGED_IMPLS}, got {impl!r}")
+    if impl == "auto":
+        return AUTO_TPU_IMPL if jax.default_backend() == "tpu" else "reference"
+    return impl
 
 
 def _native_call(q, k_pages, v_pages, lengths, page_indices,
-                 *, quantized: bool, pages_per_compute_block: int = 0,
+                 *, quantized: bool,
                  folded: bool = False, blocked: bool = False,
                  pages_per_block: int = 0, interpret: bool = False):
-    """Adapter: the probe/dispatch launch signature → our native kernels
+    """Adapter: the dispatch's launch signature → our native kernels
     (ops/paged_native.py), which take int8 weights and compact scales as
     separate arrays. ``folded`` selects the kv-heads-in-block variant with
     a (B, pps) grid (half the grid steps, BASELINE.md r5 grid-overhead
@@ -453,130 +463,6 @@ def _native_verify_call(q, k_pages, v_pages, lengths, page_indices,
     )
 
 
-def _probe_launch(
-    fn_name: str,
-    quantized: bool,
-    num_kv_heads: int,
-    num_groups: int,
-    head_dim: int,
-    page_size: int,
-    q_dtype,
-    kv_dtype,
-    blocks: int,
-    pps: int,
-    pages_per_block: int = 0,
-    verify_len: int = 0,
-) -> bool:
-    """Per-config probe: compile + run a paged-attention launch at tiny
-    shapes on the REAL backend. Launches are validated under the Pallas
-    interpreter in CI, but a Mosaic lowering rejection (or jaxlib internal
-    kernel drift) would otherwise surface as a compile error inside the
-    engine's jitted step — past the point where ``impl="auto"`` could fall
-    back. Probing in an isolated computation keeps auto mode graceful.
-
-    Keyed on the quantities that select Mosaic code paths: the launch, the
-    quantization flag (scale scratch layout), num_kv_heads (the kernel's
-    per-head HBM DMA slice — probing K=1 hid a real Mosaic rejection of
-    ``pages.at[head]`` for head_dim 64, first seen on silicon round 3),
-    num_groups (3-d vs 4-d block specs via ``num_groups % 8``), head_dim,
-    page_size and the compute-block count (VMEM scratch tiling), the
-    q/KV dtypes (Mosaic tiles bf16 (16,128) vs f32 (8,128)), and the REAL
-    pages_per_sequence — a pps=1 probe compiled a single-page program whose
-    DMA pattern differed from the real call's, passing where the real shape
-    failed (second silicon lesson of round 3)."""
-    key = (fn_name, quantized, num_kv_heads, num_groups, head_dim, page_size,
-           q_dtype, kv_dtype, blocks, pps,
-           pages_per_block if fn_name in ("native_blocked", "native_verify")
-           else 0,
-           verify_len if fn_name == "native_verify" else 0)
-    if key not in _fixed_launch_state:
-        try:
-            from distrl_llm_tpu.ops.paged_int8 import (
-                paged_attention_gqa,
-                paged_attention_int8,
-            )
-
-            if fn_name == "native":
-                fn = functools.partial(_native_call, quantized=quantized)
-            elif fn_name == "native_folded":
-                fn = functools.partial(
-                    _native_call, quantized=quantized, folded=True)
-            elif fn_name == "native_blocked":
-                fn = functools.partial(
-                    _native_call, quantized=quantized, blocked=True,
-                    pages_per_block=pages_per_block)
-            elif fn_name == "native_verify":
-                fn = None  # verify-shaped probe built below
-            elif fn_name == "fixed":
-                fn = paged_attention_int8 if quantized else paged_attention_gqa
-            else:
-                from jax.experimental.pallas.ops.tpu.paged_attention import (
-                    paged_attention as fn,
-                )
-
-            b = 1  # one sequence at the REAL pages-per-sequence count
-            shape = (num_kv_heads, b * pps, page_size, head_dim)
-            if quantized:
-                kp = vp = init_quantized_pages(shape)
-            else:
-                kp = vp = jnp.zeros(shape, kv_dtype)
-            if fn_name == "native_verify":
-                # the fused verify launch takes an S-query block per row and
-                # its own Mosaic code path (S·G query rows in the block) —
-                # probe it at the REAL draft-block length
-                out = _native_verify_call(
-                    jnp.zeros(
-                        (b, verify_len, num_kv_heads * num_groups, head_dim),
-                        q_dtype,
-                    ),
-                    kp, vp,
-                    jnp.ones((b,), jnp.int32),
-                    jnp.asarray(
-                        make_page_table(b, pps * page_size, page_size)
-                    ),
-                    quantized=quantized, pages_per_block=pages_per_block,
-                )
-            else:
-                out = fn(
-                    jnp.zeros(
-                        (b, num_kv_heads * num_groups, head_dim), q_dtype
-                    ),
-                    kp, vp,
-                    jnp.ones((b,), jnp.int32),
-                    jnp.asarray(
-                        make_page_table(b, pps * page_size, page_size)
-                    ),
-                    pages_per_compute_block=blocks,
-                )
-            jax.block_until_ready(out)
-            _fixed_launch_state[key] = True
-            transient_probe_keys.discard(key)
-        except Exception as e:  # noqa: BLE001 — classify before caching
-            from distrl_llm_tpu.ops.attention import _TRANSIENT_ERR_MARKS
-
-            transient = any(m in str(e).upper() for m in _TRANSIENT_ERR_MARKS)
-            if transient:
-                transient_probe_keys.add(key)
-            else:
-                _fixed_launch_state[key] = False
-                transient_probe_keys.discard(key)
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "paged-attention %s launch unavailable on this backend for "
-                "%s (%s)%s",
-                fn_name,
-                key,
-                e,
-                " (transient error — not cached, but a trace consuming this"
-                " result bakes the downgrade into its compiled program until"
-                " retrace; dispatch_choices marks it '!transient-probe')"
-                if transient else "",
-            )
-            return False
-    return _fixed_launch_state[key]
-
-
 def paged_attention_op(
     q: jax.Array,  # [B, H, hd]
     k_pages: jax.Array,
@@ -588,164 +474,56 @@ def paged_attention_op(
     pages_per_compute_block: int = 4,
     pages_per_block: int = 0,
 ) -> jax.Array:
-    """Dispatch: Pallas TPU kernel when available, jnp reference otherwise.
+    """Dispatch one decode query per row over the paged cache.
 
-    ``impl``: "auto" (probe-gated kernel chain on TPU backends, reference
-    elsewhere), "kernel" (force the corrected jaxlib launch), "native"
-    (force our pipeline-gather kernel, ops/paged_native.py),
-    "native_folded" / "native_blocked" (its kv-folded and grid-collapsed
-    variants — ``pages_per_block`` sizes the blocked kernel's page
-    collapse; 0 = DEFAULT_PAGES_PER_BLOCK), or "reference"."""
-    use_kernel = impl in (
-        "kernel", "native", "native_folded", "native_blocked"
-    ) or (impl == "auto" and jax.default_backend() == "tpu")
-    choice_key = None
-    if use_kernel:
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention,
-            )
+    ``impl``: "auto" (``resolve_paged_impl``: the native kernel on a TPU
+    backend, the reference elsewhere), "native" (our pipeline-gather
+    kernel, ops/paged_native.py), "native_folded" / "native_blocked" (its
+    kv-folded and grid-collapsed variants — ``pages_per_block`` sizes the
+    blocked kernel's page collapse; 0 = DEFAULT_PAGES_PER_BLOCK), "kernel"
+    (jaxlib's own launch) or "reference". What ran is recorded in
+    ``dispatch_choices``."""
+    resolved = resolve_paged_impl(impl)
+    pps = page_indices.shape[1]
+    quantized = is_quantized_pages(k_pages)
+    kw = k_pages.weight if quantized else k_pages
+    num_kv_heads = kw.shape[0]
+    choice_key = dispatch_choice_key(
+        quantized=quantized, num_kv_heads=num_kv_heads,
+        num_groups=q.shape[1] // num_kv_heads, head_dim=kw.shape[-1],
+        page_size=kw.shape[-2], pps=pps,
+        pages_per_compute_block=pages_per_compute_block,
+        impl=impl, pages_per_block=pages_per_block,
+    )
+    dispatch_choices[choice_key] = resolved
+    if resolved == "reference":
+        return paged_attention_reference(
+            q, k_pages, v_pages, lengths, page_indices
+        )
+    # the kernels compute raw q·k (no internal scaling)
+    scaled_q = q * (q.shape[-1] ** -0.5)
+    if resolved == "kernel":
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention,
+        )
 
-            # the kernel computes raw q·k (no internal scaling) and requires
-            # pages_per_sequence % pages_per_compute_block == 0
-            pps = page_indices.shape[1]
-            scaled_q = q * (q.shape[-1] ** -0.5)
-            quantized = is_quantized_pages(k_pages)
-            kw = k_pages.weight if quantized else k_pages
-            num_kv_heads = kw.shape[0]
-            num_groups = q.shape[1] // num_kv_heads
-            head_dim, page_size = kw.shape[-1], kw.shape[-2]
-            choice_key = dispatch_choice_key(
-                quantized=quantized, num_kv_heads=num_kv_heads,
-                num_groups=num_groups, head_dim=head_dim,
-                page_size=page_size, pps=pps,
-                pages_per_compute_block=pages_per_compute_block,
-                impl=impl, pages_per_block=pages_per_block,
-            )
-            blocks = choice_key[-2]
-            # auto mode walks a probe-gated chain (probes run once per
-            # config at the REAL kv-head count and pages-per-sequence):
-            # - hd % 128 == 0: corrected jaxlib launch (proven, multi-page
-            #   DMA blocks) → our native kernel → jaxlib wrapper → jnp
-            #   reference;
-            # - hd % 128 != 0: our native kernel FIRST — both jaxlib
-            #   kernels' manual per-head HBM DMA slice is rejected by
-            #   Mosaic for unaligned head_dim (round-3 silicon finding;
-            #   ops/paged_native.py), which two rounds of interpreter
-            #   parity could not see.
-            ppb_eff = max(
-                1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps)
-            )
-            probe = functools.partial(
-                _probe_launch, quantized=quantized,
-                num_kv_heads=num_kv_heads, num_groups=num_groups,
-                head_dim=head_dim, page_size=page_size,
-                q_dtype=scaled_q.dtype, kv_dtype=kw.dtype, blocks=blocks,
-                pps=pps, pages_per_block=ppb_eff,
-            )
-            # native_folded/native_blocked sit BEHIND the silicon-proven
-            # native until their kernel-check stanzas PASS on chip (probes
-            # run all-zero inputs, so they catch lowering rejections but
-            # not a silent miscompile — round-3 lesson); the bench A/B
-            # forces them via BENCH_PAGED_IMPL, and the chain order flips
-            # in a follow-up once the stanzas land
-            chain = (
-                ("native", "native_folded", "native_blocked", "fixed",
-                 "jaxlib")
-                if head_dim % 128
-                else ("fixed", "native", "native_folded", "native_blocked",
-                      "jaxlib")
-            )
-            if impl == "kernel":  # forced: corrected launch, no probe
-                chain = ("fixed",)
-            elif impl == "native":  # forced: our kernel, no probe
-                chain = ("native",)
-            elif impl == "native_folded":  # forced: kv-folded variant
-                chain = ("native_folded",)
-            elif impl == "native_blocked":  # forced: grid-collapsed variant
-                chain = ("native_blocked",)
-            # sticky across calls sharing this choice_key (one trace calls
-            # this op once PER LAYER): if any earlier layer's chain was
-            # transiently downgraded, the compiled program mixes reference-
-            # path layers with kernel layers — a later layer's clean probe
-            # must not erase the flag
-            transient_seen = dispatch_choices.get(choice_key, "").endswith(
-                "!transient-probe"
-            )
-            dispatch_choices[choice_key] = "reference" + (
-                "!transient-probe" if transient_seen else ""
-            )
-            for fn_name in chain:
-                if len(chain) > 1 and not probe(fn_name):
-                    pkey = (fn_name, quantized, num_kv_heads, num_groups,
-                            head_dim, page_size, scaled_q.dtype, kw.dtype,
-                            blocks, pps,
-                            ppb_eff if fn_name == "native_blocked" else 0)
-                    transient_seen = transient_seen or (
-                        pkey in transient_probe_keys
-                    )
-                    continue
-                dispatch_choices[choice_key] = fn_name + (
-                    "!transient-probe" if transient_seen else ""
-                )
-                if fn_name in ("native", "native_folded", "native_blocked"):
-                    return _native_call(
-                        scaled_q, k_pages, v_pages,
-                        lengths.astype(jnp.int32), page_indices,
-                        quantized=quantized,
-                        folded=fn_name == "native_folded",
-                        blocked=fn_name == "native_blocked",
-                        pages_per_block=ppb_eff,
-                    ).astype(q.dtype)
-                if fn_name == "fixed":
-                    from distrl_llm_tpu.ops.paged_int8 import (
-                        paged_attention_gqa,
-                        paged_attention_int8,
-                    )
-
-                    fn = (
-                        paged_attention_int8
-                        if quantized
-                        else paged_attention_gqa
-                    )
-                    return fn(
-                        scaled_q, k_pages, v_pages,
-                        lengths.astype(jnp.int32), page_indices,
-                        pages_per_compute_block=blocks,
-                    ).astype(q.dtype)
-                return paged_attention(
-                    scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
-                    page_indices, pages_per_compute_block=blocks,
-                ).astype(q.dtype)
-            if transient_seen:
-                # every chain member's probe failed and at least one failure
-                # was transient: this trace runs the reference path until a
-                # retrace re-probes — flag it
-                dispatch_choices[choice_key] = "reference!transient-probe"
-        except Exception as e:  # noqa: BLE001 — fall back with one warning
-            if impl in ("kernel", "native", "native_folded", "native_blocked"):
-                raise
-            # the chain recorded its pick before launching; the launch
-            # failed, so what actually runs below is the reference (keep the
-            # transient marker sticky — see above)
-            if choice_key is not None:
-                dispatch_choices[choice_key] = "reference" + (
-                    "!transient-probe" if transient_seen else ""
-                )
-            global _kernel_fail_warned
-            if not _kernel_fail_warned:
-                _kernel_fail_warned = True
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "paged_attention kernel unavailable (%s); using reference",
-                    e,
-                )
-    if choice_key is None:
-        # non-kernel path (CPU/GPU backend or impl="reference") — still a
-        # paged dispatch, and the honesty field must say so
-        dispatch_choices[("no-kernel-path",)] = "reference"
-    return paged_attention_reference(q, k_pages, v_pages, lengths, page_indices)
+        # requires pages_per_sequence % pages_per_compute_block == 0
+        return per_device(paged_attention)(
+            scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
+            page_indices,
+            pages_per_compute_block=divisor_blocks(
+                pages_per_compute_block, pps
+            ),
+        ).astype(q.dtype)
+    return per_device(_native_call)(
+        scaled_q, k_pages, v_pages, lengths.astype(jnp.int32), page_indices,
+        quantized=quantized,
+        folded=resolved == "native_folded",
+        blocked=resolved == "native_blocked",
+        pages_per_block=max(
+            1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps)
+        ),
+    ).astype(q.dtype)
 
 
 def paged_verify_reference(
@@ -788,8 +566,8 @@ def paged_verify_op(
     per-position ``paged_attention_op`` dispatches (the pre-fusion
     behavior, exact to the dispatch).
 
-    ``verify_impl``: "fused" (probe-gated fused kernel on TPU for the
-    native impl family, unrolled fallback elsewhere) or "unrolled" (force
+    ``verify_impl``: "fused" (the fused kernel on a TPU backend for the
+    native impl family, unrolled elsewhere) or "unrolled" (force
     per-position dispatch — the A/B control and the interpreter-parity
     anchor). The decision is recorded in ``dispatch_choices`` under the
     verify-marked key (``dispatch_choice_key(..., verify_len=S)``):
@@ -817,24 +595,16 @@ def paged_verify_op(
     )
     # the fused kernel is a native-family launch; "kernel"/"reference"
     # pins have no fused spelling and always unroll onto their own impl
-    fused_eligible = (
+    if (
         verify_impl == "fused"
-        and impl in ("auto", "native", "native_folded", "native_blocked")
+        and resolve_paged_impl(impl).startswith("native")
         and jax.default_backend() == "tpu"
-    )
-    if fused_eligible:
-        scaled_q = q * (hd ** -0.5)
-        if _probe_launch(
-            "native_verify", quantized, num_kv_heads, num_groups, head_dim,
-            page_size, scaled_q.dtype, kw.dtype,
-            divisor_blocks(pages_per_compute_block, pps), pps,
-            pages_per_block=ppb_eff, verify_len=s,
-        ):
-            dispatch_choices[choice_key] = "native_verify"
-            return _native_verify_call(
-                scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
-                page_indices, quantized=quantized, pages_per_block=ppb_eff,
-            ).astype(q.dtype)
+    ):
+        dispatch_choices[choice_key] = "native_verify"
+        return per_device(_native_verify_call)(
+            q * (hd ** -0.5), k_pages, v_pages, lengths.astype(jnp.int32),
+            page_indices, quantized=quantized, pages_per_block=ppb_eff,
+        ).astype(q.dtype)
     # unrolled: S per-position dispatches (each records its own decode
     # dispatch choice; the verify key records that the step ran unrolled)
     dispatch_choices[choice_key] = "unrolled"

@@ -14,8 +14,7 @@ This kernel takes the other road: **no manual DMA at all**. The grid is
 ``index_map``, which reads the scalar-prefetched page table —
 ``(b, kv, j) -> (kv, table[b, j], 0, 0)``. The pipeline emitter then moves
 whole ``[1, page_size, head_dim]`` blocks, never slicing inside the minor
-dims — the exact pattern our flash/splash launches already proved on this
-Mosaic version at d=64 (tools/tpu_kernel_check.py, S=4096 PASS).
+dims — the pattern the flash/splash launches use at d=64.
 
 Per (b, kv) series the kernel runs classic online softmax over the pages:
 m/l/acc VMEM scratch carried across the innermost grid dimension, page
@@ -25,13 +24,14 @@ DMAs still run — the admission/capacity win of paging is unchanged, and
 bounding the DMA walk per row is a follow-up (bucketed pps compiles).
 
 The int8 path consumes the engine's COMPACT per-token scales ([K, P, ps,
-1] f32, see ops/paged_int8.py) directly: dequantization is one broadcast
+1] f32, ops/paged.py::quantize_pages) directly: dequantization is one broadcast
 multiply in VMEM, so int8 stays a bandwidth win (~1.03 bytes/element
 moved) rather than the 5 bytes/element of jaxlib's pre-broadcast wrapper.
 
 Parity: CI pins numerics against ``paged_attention_reference`` under the
-Pallas interpreter; tools/tpu_kernel_check.py revalidates the Mosaic
-lowering + numerics on silicon (SURVEY §2b N1/N10).
+Pallas interpreter and compiles the kernels for a described v5e
+(tests/test_tpu_compile.py); ``chip_smoke.py`` compares them with the
+reference on the chip (SURVEY §2b N1/N10).
 """
 
 from __future__ import annotations
@@ -47,14 +47,6 @@ from jax.experimental.pallas.ops.tpu.paged_attention.quantization_utils import (
 )
 
 NEG_INF = -1e30
-
-# jax 0.7 renamed TPUCompilerParams → CompilerParams; support both so the
-# interpret-mode parity suite runs on either generation (the old name was
-# one of the pre-existing "Pallas interpret" CI failures — it was an API
-# drift, not an interpreter limitation)
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 
 def _paged_kernel(
@@ -204,7 +196,7 @@ def paged_attention_native(
                 pltpu.VMEM((groups, head_dim), jnp.float32),
             ],
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(
@@ -361,7 +353,7 @@ def paged_attention_native_folded(
                 pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
             ],
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(
@@ -673,7 +665,7 @@ def paged_attention_native_verify(
                 ),
             ],
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(
@@ -791,7 +783,7 @@ def paged_attention_native_blocked(
                 pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
             ],
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(

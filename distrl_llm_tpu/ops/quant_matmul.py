@@ -1,7 +1,8 @@
 """Fused quantized-matmul Pallas kernel: dequant on the operand read, LoRA in
 the epilogue.
 
-Decode is memory-bound (~2% MFU, BENCH_r05 ≈ 4% of the HBM roofline), so
+Decode is memory-bound (~2% MFU, ≈ 4% of the HBM roofline in the round-5
+chip rows), so
 tok/s/chip tracks resident bytes per token almost linearly.  The container
 path in ``ops/linear.py`` *hopes* XLA fuses ``(q·scale).astype → einsum`` into
 the MXU operand read; this module replaces the hope with a measured kernel for
@@ -20,10 +21,9 @@ decode shapes:
   XLA-container path (pinned by tools/quant_smoke.py and
   tests/test_quant_matmul.py).
 
-Dispatch is probe-gated with the exact XLA container path as fallback
-(``ops.attention._kernel_lowers`` discipline): ``DISTRL_QUANT_MATMUL`` =
-``auto`` (kernel on TPU when the lowering probe passes; container path
-elsewhere — the CPU tier-1 default, byte-identical to before this module),
+Dispatch: ``DISTRL_QUANT_MATMUL`` = ``auto`` (kernel on a TPU backend;
+container path elsewhere — the CPU tier-1 default, byte-identical to before
+this module),
 ``kernel`` (force; implies interpret off-TPU), ``interpret`` (Pallas
 interpreter — CPU parity tests), ``xla`` (pin the container path).
 
@@ -36,7 +36,6 @@ through dequant into LoRA only — tests/test_quant.py) differentiates through
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
@@ -44,14 +43,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-logger = logging.getLogger(__name__)
+from distrl_llm_tpu.ops.per_device import per_device
 
 #: trace-time dispatch record (the ops.paged.dispatch_choices idiom): keyed by
 #: (bits, K, N, rank, dtype) → "kernel" | "xla"; bench reads it so a row
 #: claiming the fused path can never have silently measured the container path
 dispatch_choices: dict = {}
-
-_probe_state: dict = {}
 
 MODES = ("auto", "kernel", "interpret", "xla")
 
@@ -204,42 +201,16 @@ def _qmm_bwd(lora_scale, interpret, res, g_out):
 _quant_matmul_p.defvjp(_qmm_fwd, _qmm_bwd)
 
 
-def _kernel_lowers(k: int, n: int, gdim: int, g: int, bits: int, rank: int,
-                   dtype) -> bool:
-    """Probe-compile the kernel at this (K, N, groups, bits, rank) config —
-    Mosaic block-rule/int-width rejections fire at COMPILE time, past any
-    try/except around a traced call inside a larger jit (the round-3 paged
-    lesson, ops/paged_int8.py)."""
-    key = (k, n, gdim, g, bits, rank, jnp.dtype(dtype).name)
-    if key not in _probe_state:
-        try:
-            qdt = jnp.int4 if bits == 4 else jnp.int8
-            x = jnp.zeros((8, k), dtype)
-            q = jnp.zeros((gdim, g, n), qdt)
-            s = jnp.zeros((gdim, 1, n), jnp.float32)
-            a = jnp.zeros((k, rank), dtype) if rank else None
-            b = jnp.zeros((rank, n), dtype) if rank else None
-            jax.block_until_ready(
-                _kernel_call(x, q, s, None, a, b, 1.0, interpret=False)
-            )
-            _probe_state[key] = True
-        except Exception as e:  # noqa: BLE001 — fall back, loudly, once
-            _probe_state[key] = False
-            logger.warning(
-                "quant_matmul kernel failed its lowering probe for %s (%s); "
-                "using the XLA container path", key, e,
-            )
-    return _probe_state[key]
-
-
-def quant_matmul_dispatch(q_shape, bits: int, rank: int, k: int,
-                          dtype) -> tuple[bool, bool]:
+def quant_matmul_dispatch() -> tuple[bool, bool]:
     """(use_kernel, interpret) for this call, per DISTRL_QUANT_MATMUL.
 
-    "auto" engages the kernel only on TPU and only when the probe compiles
-    (CPU/tier-1 keeps the container path byte-identically); "kernel" forces
-    it (interpreted off-TPU — the CI/e2e drill); "interpret" forces the
-    Pallas interpreter everywhere; "xla" pins the container path."""
+    "auto" is the kernel on a TPU backend and the container path on any
+    other (CPU/tier-1 keeps the container path byte-identically); a kernel
+    that fails to compile on the TPU fails the step — nothing gives way to
+    the container path (tests/test_tpu_compile.py holds the lowering at
+    model widths). "kernel" forces it (interpreted off-TPU — the CI/e2e
+    drill); "interpret" forces the Pallas interpreter everywhere; "xla"
+    pins the container path."""
     mode = quant_matmul_mode()
     if mode == "xla":
         return False, False
@@ -248,8 +219,7 @@ def quant_matmul_dispatch(q_shape, bits: int, rank: int, k: int,
         return True, True
     if mode == "kernel":
         return True, not on_tpu
-    gdim, g, n = q_shape
-    return (on_tpu and _kernel_lowers(k, n, gdim, g, bits, rank, dtype)), False
+    return on_tpu, False
 
 
 def quant_matmul(
@@ -280,8 +250,7 @@ def quant_matmul(
             f"container input dim {q.shape[0]}x{q.shape[1]} != x's {k}"
         )
     x2 = x.reshape(-1, k)
-    out = _quant_matmul_p(
-        x2, q, scale, bias, lora_a, lora_b,
-        float(lora_scale), interpret,
-    )
+    out = per_device(
+        lambda *arrays: _quant_matmul_p(*arrays, float(lora_scale), interpret)
+    )(x2, q, scale, bias, lora_a, lora_b)
     return out.reshape(*lead, q.shape[-1])

@@ -31,12 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from distrl_llm_tpu.ops.attention import NEG_INF
 
-# jax.shard_map is the promoted (>= 0.6) spelling; older jax ships it in
-# experimental only — same drift class as pltpu.CompilerParams (CI triage)
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def _chunk_logits(q, k, scale):
@@ -61,10 +56,7 @@ def _ring_local(q, k, v, kv_valid, *, axis_name: str, sp: int, scale: float,
     m = jnp.full((b, kh, g, c), NEG_INF, jnp.float32)
     l = jnp.zeros((b, kh, g, c), jnp.float32)
     o = jnp.zeros((b, kh, g, c, d), jnp.float32)
-    # (older jax has no pcast — and no varying-axis typing to satisfy, so
-    # skipping the cast there is exactly equivalent)
-    if hasattr(jax.lax, "pcast"):
-        m, l, o = jax.lax.pcast((m, l, o), varying_axes, to="varying")
+    m, l, o = jax.lax.pcast((m, l, o), varying_axes, to="varying")
 
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 

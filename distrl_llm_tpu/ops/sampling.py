@@ -10,15 +10,13 @@ the sorted distribution whose mass reaches top_p).
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
 import jax.numpy as jnp
 
 from distrl_llm_tpu.ops.attention import NEG_INF
-
-logger = logging.getLogger(__name__)
+from distrl_llm_tpu.ops.per_device import per_device
 
 
 def top_p_filter(logits: jax.Array, top_p: jax.Array | float) -> jax.Array:
@@ -173,8 +171,6 @@ sample_dispatch_choices: dict = {}
 
 SAMPLE_IMPLS = ("auto", "fused", "interpret", "xla")
 
-_sampler_probe_state: dict = {}
-
 
 def sample_impl_mode() -> str:
     """Resolved DISTRL_SAMPLE_KERNEL mode (validated; default "auto")."""
@@ -187,45 +183,64 @@ def sample_impl_mode() -> str:
     return mode
 
 
+#: the kernel views one logits row as a [V/128, 128] tile (every vreg full;
+#: a [1, V] row would occupy one sublane in eight) — rows are padded to a
+#: whole number of (8, 128) tiles
+_LANES = 128
+_ROW_TILE = 8 * _LANES
+
+
 def _fused_sample_kernel(temp_ref, topp_ref, seed_ref, logits_ref,
                          tok_ref, logp_ref, *, iters: int):
-    """One row: (token, raw-basis logprob) in a single pass over the
-    logits. Padded columns carry NEG_INF and can never win an argmax or
-    contribute mass."""
-    raw = logits_ref[...]  # [1, Vp] f32
+    """One logits row, viewed as an [R, 128] tile: (token, raw-basis
+    logprob) in a single pass. Padded columns carry NEG_INF and can never
+    win an argmax or contribute mass. All row reductions are full-tile
+    reductions to a scalar; argmax is spelled max + min-index-at-max (first
+    occurrence wins, jnp.argmax's tie rule)."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    raw = logits_ref[0]  # [R, 128] f32
     t0 = temp_ref[0, 0]
     top_p = topp_ref[0, 0]
 
-    greedy = jnp.argmax(raw, axis=-1)  # [1]
+    # flat vocab index of each element; f32 holds it exactly (V < 2^24)
+    idx = (
+        jax.lax.broadcasted_iota(jnp.int32, raw.shape, 0) * _LANES
+        + jax.lax.broadcasted_iota(jnp.int32, raw.shape, 1)
+    )
+    idx_f = idx.astype(jnp.float32)
+
+    def argmax(x, x_max):
+        return jnp.min(jnp.where(x == x_max, idx_f, jnp.float32(2.0 ** 24)))
+
+    m_raw = jnp.max(raw)
+    greedy = argmax(raw, m_raw)
 
     # tempered softmax (sample()'s exact order: scale, then filter)
     t = jnp.maximum(t0, 1e-6)
     scaled = raw / t
-    m = jnp.max(scaled, axis=-1, keepdims=True)
-    e = jnp.exp(scaled - m)
-    z = jnp.sum(e, axis=-1, keepdims=True)
-    probs = e / z
+    e = jnp.exp(scaled - jnp.max(scaled))
+    probs = e / jnp.sum(e)
 
     # bisect the keep threshold (top_p_filter_bisect's math: kept mass is
     # always >= top_p; the LOW end of the interval is the threshold)
     def body(_, interval):
         lo, hi = interval
         mid = 0.5 * (lo + hi)
-        mass = jnp.sum(jnp.where(probs >= mid, probs, 0.0), axis=-1,
-                       keepdims=True)
-        ok = mass >= top_p
+        ok = jnp.sum(jnp.where(probs >= mid, probs, 0.0)) >= top_p
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
-    lo = jnp.zeros_like(m)
-    hi = jnp.max(probs, axis=-1, keepdims=True)
-    lo, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    lo, _ = jax.lax.fori_loop(
+        0, iters, body, (jnp.float32(0.0), jnp.max(probs))
+    )
     filtered = jnp.where(probs >= lo, scaled, NEG_INF)
 
     # Gumbel-max draw with counter-hash uniforms: murmur3 fmix32 over
     # (seed, column) — identical bits compiled and interpreted
-    vp = raw.shape[-1]
-    col = jax.lax.broadcasted_iota(jnp.uint32, (1, vp), 1)
-    h = col * jnp.uint32(0x9E3779B9) + seed_ref[0, 0].astype(jnp.uint32)
+    h = idx.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) + seed_ref[i].astype(
+        jnp.uint32
+    )
     h = h ^ (h >> 16)
     h = h * jnp.uint32(0x85EBCA6B)
     h = h ^ (h >> 13)
@@ -235,26 +250,19 @@ def _fused_sample_kernel(temp_ref, topp_ref, seed_ref, logits_ref,
     # representable in f32: a 24-bit mapping can round to 1.0f (prob 2^-24
     # per element), where -log(-log(1)) = +inf hands the argmax to an
     # arbitrary — possibly padded — column
-    u = (h >> 9).astype(jnp.float32) * jnp.float32(2.0 ** -23) + jnp.float32(
-        2.0 ** -24
-    )
-    gumbel = -jnp.log(-jnp.log(u))
-    sampled = jnp.argmax(filtered + gumbel, axis=-1)
+    u = (h >> 9).astype(jnp.int32).astype(jnp.float32) * jnp.float32(
+        2.0 ** -23
+    ) + jnp.float32(2.0 ** -24)
+    noisy = filtered - jnp.log(-jnp.log(u))
+    sampled = argmax(noisy, jnp.max(noisy))
 
-    tok = jnp.where(t0 == 0.0, greedy, sampled).astype(jnp.int32)  # [1]
+    tok = jnp.where(t0 == 0.0, greedy, sampled)
 
     # raw-basis logprob of the chosen token (token_logprob's math)
-    m_raw = jnp.max(raw, axis=-1)
-    logz = jnp.log(jnp.sum(jnp.exp(raw - m_raw[..., None]), axis=-1)) + m_raw
-    picked = jnp.max(
-        jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, (1, vp), 1) == tok[..., None],
-            raw, NEG_INF,
-        ),
-        axis=-1,
-    )
-    tok_ref[0, 0] = tok[0]
-    logp_ref[0, 0] = (picked - logz)[0]
+    logz = jnp.log(jnp.sum(jnp.exp(raw - m_raw))) + m_raw
+    picked = jnp.max(jnp.where(idx_f == tok, raw, NEG_INF))
+    tok_ref[i] = tok.astype(jnp.int32)
+    logp_ref[i] = picked - logz
 
 
 def fused_sample(
@@ -271,69 +279,48 @@ def fused_sample(
     from jax.experimental.pallas import tpu as pltpu
 
     b, v = logits.shape
-    vp = -(-v // 128) * 128
+    vp = -(-v // _ROW_TILE) * _ROW_TILE
     lg = logits.astype(jnp.float32)
     if vp != v:
         lg = jnp.pad(lg, ((0, 0), (0, vp - v)), constant_values=NEG_INF)
+    lg = lg.reshape(b, vp // _LANES, _LANES)
     # one independent 32-bit seed per row off the caller's key — the same
     # key the multi-pass path would hand jax.random.categorical
-    seeds = jax.random.bits(rng, (b, 1), jnp.uint32).astype(jnp.int32)
+    seeds = jax.random.bits(rng, (b,), jnp.uint32).astype(jnp.int32)
     t = jnp.full((1, 1), 0.0, jnp.float32) + jnp.asarray(
         temperature, jnp.float32
     )
     p = jnp.full((1, 1), 0.0, jnp.float32) + jnp.asarray(top_p, jnp.float32)
+    # scalars and per-row seeds/results live whole in SMEM (Mosaic has no
+    # legal (1, 1) block over a [B, 1] array); each grid step reads and
+    # writes its own row's slot
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     tok, logp = pl.pallas_call(
         functools.partial(_fused_sample_kernel, iters=iters),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, vp), lambda i: (i, 0)),
+            smem, smem, smem,
+            pl.BlockSpec((1, vp // _LANES, _LANES), lambda i: (i, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
-        ],
+        out_specs=[smem, smem],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
         ],
         interpret=interpret,
     )(t, p, seeds, lg)
-    return tok[:, 0], logp[:, 0]
+    return tok, logp
 
 
-def _sampler_lowers(vocab: int) -> bool:
-    """Probe-compile the fused sampler at this vocab — Mosaic rejections
-    fire at COMPILE time, past any try/except around a traced call inside
-    the engines' jitted steps (the ops/attention._kernel_lowers
-    discipline)."""
-    key = ("fused_sample", vocab)
-    if key not in _sampler_probe_state:
-        try:
-            jax.block_until_ready(fused_sample(
-                jax.random.PRNGKey(0), jnp.zeros((2, vocab), jnp.float32),
-                1.0, 0.9,
-            ))
-            _sampler_probe_state[key] = True
-        except Exception as e:  # noqa: BLE001 — fall back, loudly, once
-            _sampler_probe_state[key] = False
-            logger.warning(
-                "fused sampler failed its lowering probe at vocab=%d (%s); "
-                "using the multi-pass sampler", vocab, e,
-            )
-    return _sampler_probe_state[key]
-
-
-def sample_dispatch(vocab: int, top_p_impl: str) -> tuple[bool, bool]:
+def sample_dispatch(top_p_impl: str) -> tuple[bool, bool]:
     """(use_fused, interpret) per DISTRL_SAMPLE_KERNEL.
 
-    "auto" engages the kernel on TPU when the probe compiles — except under
-    an EXPLICIT exact-nucleus pin (top_p_impl="exact" is a reproducibility
-    ask the bisect-filter kernel must not silently override). Off-TPU,
-    "auto" keeps the multi-pass path (the CPU tier-1 default,
-    byte-identical to before the kernel existed)."""
+    "auto" is the fused kernel on a TPU backend — except under an EXPLICIT
+    exact-nucleus pin (top_p_impl="exact" is a reproducibility ask the
+    bisect-filter kernel must not silently override) — and the multi-pass
+    path on any other backend. A kernel that ``auto`` selected and that
+    fails to compile fails the step: nothing here gives way to the
+    multi-pass path (tests/test_tpu_compile.py holds the lowering)."""
     mode = sample_impl_mode()
     if mode == "xla":
         return False, False
@@ -342,9 +329,7 @@ def sample_dispatch(vocab: int, top_p_impl: str) -> tuple[bool, bool]:
     on_tpu = jax.default_backend() == "tpu"
     if mode == "fused":
         return True, not on_tpu
-    if top_p_impl == "exact":
-        return False, False
-    return (on_tpu and _sampler_lowers(vocab)), False
+    return (on_tpu and top_p_impl != "exact"), False
 
 
 def sample_with_logprob(
@@ -362,7 +347,7 @@ def sample_with_logprob(
     (DISTRL_SAMPLE_KERNEL / probe), else to the multi-pass ``sample`` +
     ``token_logprob`` reference — greedy outputs bit-identical either way."""
     use, interp = (
-        sample_dispatch(logits.shape[-1], top_p_impl)
+        sample_dispatch(top_p_impl)
         if impl is None
         else ({"fused": (True, False), "interpret": (True, True),
                "xla": (False, False)}[impl])
@@ -371,8 +356,10 @@ def sample_with_logprob(
         "fused" if use else "xla"
     )
     if use:
-        tok, logp = fused_sample(rng, logits, temperature, top_p,
-                                 interpret=interp)
+        tok, logp = per_device(fused_sample)(
+            rng, logits, jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_p, jnp.float32), interpret=interp,
+        )
         return tok, (logp if capture_logprob else None)
     tok = sample(rng, logits, temperature, top_p, top_p_impl=top_p_impl)
     return tok, (token_logprob(logits, tok) if capture_logprob else None)

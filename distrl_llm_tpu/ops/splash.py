@@ -50,9 +50,13 @@ def _make_kernel(groups: int, seq: int, block: int, interpret: bool):
         block_q_dq=min(block, seq),
         block_kv_dq=min(block, seq),
     )
-    return kernel.make_splash_mqa_single_device(
-        mask, block_sizes=block_sizes, interpret=interpret
-    )
+    # the kernel object carries its mask-info arrays; built under a trace
+    # they would be that trace's tracers, and this cache would hand them to
+    # the next one (UnexpectedTracerError on the second jit of one geometry)
+    with jax.ensure_compile_time_eval():
+        return kernel.make_splash_mqa_single_device(
+            mask, block_sizes=block_sizes, interpret=interpret
+        )
 
 
 def splash_attention(

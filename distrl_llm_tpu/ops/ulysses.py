@@ -27,12 +27,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from distrl_llm_tpu.ops.attention import attention
 
-# jax.shard_map is the promoted (>= 0.6) spelling; older jax ships it in
-# experimental only — same drift class as pltpu.CompilerParams (CI triage)
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def _ulysses_local(q, k, v, kv_valid, *, axis_name: str, sp: int, scale: float,

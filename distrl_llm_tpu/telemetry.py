@@ -650,22 +650,36 @@ _PEAK_TFLOPS_BY_KIND = (
 )
 
 
+def peak_flops_for_kind(device_kind: str) -> float:
+    """Peak dense bf16 FLOP/s of one chip of ``device_kind``. A kind the
+    table does not hold is an error, never a default peak."""
+    low = device_kind.lower()
+    for sub, tflops in _PEAK_TFLOPS_BY_KIND:
+        if sub in low:
+            return tflops * 1e12
+    raise ValueError(
+        f"device_kind {device_kind!r} is not in telemetry._PEAK_TFLOPS_BY_KIND"
+        " — add its published peak there (or set DISTRL_PEAK_FLOPS)"
+    )
+
+
 def device_peak_flops() -> float | None:
-    """Peak FLOP/s of one local accelerator chip, or None when unknown (CPU
-    hosts): the MFU denominator. ``DISTRL_PEAK_FLOPS`` (FLOP/s) overrides."""
+    """Peak FLOP/s of one local accelerator chip: the MFU denominator.
+    None on a host with no TPU (no utilisation is published there);
+    ``DISTRL_PEAK_FLOPS`` (FLOP/s) overrides; a TPU the table cannot name
+    raises (``peak_flops_for_kind``)."""
     env = os.environ.get("DISTRL_PEAK_FLOPS")
     if env:
         return float(env)
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend at all
+    try:
+        dev = jax.devices()[0]
+    except RuntimeError:  # no backend at all
         return None
-    for sub, tflops in _PEAK_TFLOPS_BY_KIND:
-        if sub in kind:
-            return tflops * 1e12
-    return None
+    if dev.platform != "tpu":
+        return None
+    return peak_flops_for_kind(dev.device_kind)
 
 
 def mfu(tok_per_s: float, flops_per_token: float, peak_flops: float) -> float:

@@ -131,7 +131,9 @@ class CharTokenizer:
     def decode(self, ids: Sequence[int], skip_special_tokens: bool = True) -> str:
         specials = {self.pad_token_id, self.eos_token_id}
         kept = [i for i in ids if not (skip_special_tokens and i in specials)]
-        return bytes(kept).decode("utf-8", errors="ignore")
+        # over a real model's vocabulary a random policy samples ids past the
+        # byte range; they fold onto bytes (encode never produces them)
+        return bytes(i & 0xFF for i in kept).decode("utf-8", errors="ignore")
 
     def apply_chat_template(
         self, messages, add_generation_prompt=False, tokenize=False, chat_template=None

@@ -77,37 +77,24 @@ class TestFlashNumerics:
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), atol=5e-2, rtol=5e-2)
 
 
-class TestLoweringProbe:
-    """_kernel_lowers must negative-cache lowering rejections (one warning,
-    no retries) but RE-probe after transient device errors."""
+class TestKernelFailuresSurface:
+    """On a TPU backend ``attention(impl="flash"|"splash")`` runs the kernel
+    or fails: a lowering rejection or a device error reaches the caller, and
+    nothing gives way to the XLA reference. (The backend is steered in the
+    test; the kernels themselves are stubbed, so no TPU is needed.)"""
 
-    @pytest.fixture(autouse=True)
-    def _isolated_probe_cache(self):
-        """Snapshot/restore the process-wide probe cache: verdicts produced
-        by this class's FAKE kernels must never leak into later tests."""
-        import importlib
-
-        attn_mod = importlib.import_module("distrl_llm_tpu.ops.attention")
-        saved = dict(attn_mod._kernel_probe_state)
-        attn_mod._kernel_probe_state.clear()
-        yield
-        attn_mod._kernel_probe_state.clear()
-        attn_mod._kernel_probe_state.update(saved)
-
-    def _clean(self):
+    @pytest.fixture()
+    def attn_mod(self, monkeypatch):
         import importlib
 
         # ops/__init__ re-exports the attention FUNCTION under the name
-        attn_mod = importlib.import_module("distrl_llm_tpu.ops.attention")
-        attn_mod._kernel_probe_state.clear()
-        return attn_mod
+        mod = importlib.import_module("distrl_llm_tpu.ops.attention")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(mod, "_flash_fallback_warned", False)
+        return mod
 
-    def test_lowering_rejection_cached(self, monkeypatch):
-        attn_mod = self._clean()
-        calls = []
-
+    def test_lowering_rejection_raises(self, attn_mod, monkeypatch):
         def boom(*a, **k):
-            calls.append(1)
             raise ValueError(
                 "The Pallas TPU lowering currently requires that the last two "
                 "dimensions of your block shape are divisible by 8 and 128"
@@ -115,34 +102,38 @@ class TestLoweringProbe:
 
         import distrl_llm_tpu.ops.flash_attention as fa_mod
         monkeypatch.setattr(fa_mod, "flash_attention", boom)
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 256, jnp.float32) is False
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 256, jnp.float32) is False
-        assert len(calls) == 1  # second call served from the negative cache
+        q, k, v = make_qkv(s=128)
+        with pytest.raises(ValueError, match="block shape"):
+            attn_mod.attention(q, k, v, None, impl="flash")
+        assert attn_mod._flash_fallback_warned is False
 
-    def test_transient_error_reprobes(self, monkeypatch):
-        attn_mod = self._clean()
-        calls = []
-
+    def test_device_error_raises(self, attn_mod, monkeypatch):
         def flaky(*a, **k):
-            calls.append(1)
-            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory allocating probe")
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
 
-        import distrl_llm_tpu.ops.flash_attention as fa_mod
-        monkeypatch.setattr(fa_mod, "flash_attention", flaky)
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 256, jnp.float32) is False
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 256, jnp.float32) is False
-        assert len(calls) == 2  # transient failures are not cached
+        import distrl_llm_tpu.ops.splash as splash_mod
+        monkeypatch.setattr(splash_mod, "splash_attention", flaky)
+        q, k, v = make_qkv(s=128)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            attn_mod.attention(
+                q, k, v, None, impl="splash",
+                key_valid=jnp.ones((2, 128), jnp.int32),
+            )
 
-    def test_success_cached(self, monkeypatch):
-        attn_mod = self._clean()
+    def test_kernel_result_is_returned(self, attn_mod, monkeypatch):
         calls = []
 
         def ok(q, k, v, mask, **kw):
-            calls.append(1)
-            return q
+            calls.append(kw)
+            return q + 1.0
 
         import distrl_llm_tpu.ops.flash_attention as fa_mod
         monkeypatch.setattr(fa_mod, "flash_attention", ok)
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 128, jnp.float32) is True
-        assert attn_mod._kernel_lowers("flash", 4, 2, 64, 128, jnp.float32) is True
-        assert len(calls) == 2  # fwd + grad on first call only
+        q, k, v = make_qkv(s=128)
+        out = attn_mod.attention(q, k, v, None, impl="flash")
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(q + 1.0))
+        assert len(calls) == 1
+        # outside the kernel's self-attention contract (a cached decode
+        # step: one query against Sk keys) the reference is the path
+        out1 = attn_mod.attention(q[:, :1], k, v, None, impl="flash")
+        assert len(calls) == 1 and out1.shape == q[:, :1].shape

@@ -3,8 +3,8 @@
 The kernel exists because both jaxlib paged kernels reject head_dim % 128
 != 0 on real Mosaic (round-3 silicon finding — ops/paged_native.py). CI
 pins its numerics here at exactly the shapes that broke: GQA 14q/2kv,
-hd=64, ragged lengths, dead rows; tools/tpu_kernel_check.py revalidates
-the lowering on-chip.
+hd=64, ragged lengths, dead rows; tests/test_tpu_compile.py holds the
+lowering for a v5e and chip_smoke.py the numbers on the chip.
 """
 
 import numpy as np
@@ -261,11 +261,11 @@ class TestBlockedKernel:
         assert paged_grid_steps("native_blocked", **g) == 8 * -(
             -12 // DEFAULT_PAGES_PER_BLOCK
         )
-        # the honesty-marker suffix is stripped, the reference has no grid
-        assert paged_grid_steps("native!transient-probe", **g) == 8 * 2 * 12
+        # one-page native: a (B, K, pps) grid; the reference has no grid
+        assert paged_grid_steps("native", **g) == 8 * 2 * 12
         assert paged_grid_steps("reference", **g) == 0
-        # jaxlib kernels walk pages inside a (1, B, K) grid
-        assert paged_grid_steps("fixed", **g) == 8 * 2
+        # jaxlib's kernel walks pages inside a (1, B, K) grid
+        assert paged_grid_steps("kernel", **g) == 8 * 2
 
     def test_validation(self):
         q, kp, vp, lengths, table = _setup(b=2, h=4, kh=2, hd=64, ps=8, pps=2)

@@ -1,7 +1,7 @@
 """Fused quantized-matmul Pallas kernel (ops/quant_matmul.py).
 
-Pins the kernel's contract under the Pallas interpreter (the on-chip
-Mosaic lowering revalidates via tools/tpu_kernel_check.py): bit-identity
+Pins the kernel's contract under the Pallas interpreter (the Mosaic
+lowering for a v5e is held by tests/test_tpu_compile.py): bit-identity
 with the XLA container path at decode-tile sizes, the LoRA epilogue's
 exact math order, padding edges, gradients through the custom VJP, the
 DISTRL_QUANT_MATMUL dispatch modes, and end-to-end engine greedy
@@ -139,16 +139,14 @@ class TestDispatch:
     def test_auto_is_xla_off_tpu(self):
         # CPU tier-1 default: the container path, byte-identical to the
         # pre-kernel behavior
-        use, _ = quant_matmul_dispatch((1, 64, 32), 8, 0, 64, jnp.float32)
+        use, _ = quant_matmul_dispatch()
         assert use is (jax.default_backend() == "tpu") or use is False
 
     def test_explicit_modes(self):
         for mode, want_use in (("xla", False), ("interpret", True)):
             os.environ["DISTRL_QUANT_MATMUL"] = mode
             try:
-                use, interp = quant_matmul_dispatch(
-                    (1, 64, 32), 8, 0, 64, jnp.float32
-                )
+                use, interp = quant_matmul_dispatch()
             finally:
                 del os.environ["DISTRL_QUANT_MATMUL"]
             assert use is want_use

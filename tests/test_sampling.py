@@ -1,6 +1,6 @@
 """Sampler unit tests: top-p nucleus semantics, greedy, temperature, and
 the fused sample-from-logits Pallas kernel (ISSUE 15 — interpreter-mode
-pins; tools/tpu_kernel_check.py revalidates the Mosaic lowering)."""
+pins; tests/test_tpu_compile.py holds the Mosaic lowering)."""
 
 import os
 
@@ -210,8 +210,10 @@ class TestFusedSampler:
         )
         ref = sample(jax.random.PRNGKey(0), lg, 0.0, 0.95)
         np.testing.assert_array_equal(np.asarray(tok), np.asarray(ref))
-        np.testing.assert_array_equal(
-            np.asarray(logp), np.asarray(token_logprob(lg, tok))
+        # the kernel sums a row as an [R, 128] tile, token_logprob as one
+        # vector: same math, another reduction order — a last-bit difference
+        np.testing.assert_allclose(
+            np.asarray(logp), np.asarray(token_logprob(lg, tok)), rtol=2e-6
         )
 
     def test_sampled_tokens_within_nucleus(self):
@@ -305,5 +307,5 @@ class TestFusedSampler:
                 sample_impl_mode()
         finally:
             del os.environ["DISTRL_SAMPLE_KERNEL"]
-        use, _ = sample_dispatch(300, "exact")
+        use, _ = sample_dispatch("exact")
         assert use is False  # an explicit exact-nucleus ask never fuses

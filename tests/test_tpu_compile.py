@@ -1,0 +1,267 @@
+"""The main path's Pallas kernels compile for a TPU v5e, at model widths.
+
+Interpret mode proves a kernel's arithmetic; it never meets Mosaic's block
+rules, its memory spaces or the chip's VMEM. The TPU compiler is installed
+wherever JAX's TPU support is, and compiles for a chip that is DESCRIBED and
+not attached — so these tests hold, on the CPU and in seconds, what would
+otherwise surface only when a whole engine step compiles on the machine with
+the chip.
+
+Nothing runs: the tests pass shapes, not arrays, and assert that the compiled
+program holds a Mosaic kernel (``tpu_custom_call``) — a dispatcher that
+quietly took a reference path would compile just as well.
+
+The topology is described inside a module-scoped fixture, after a test of this
+file has started, and in this process: only one process at a time may load the
+TPU's library, and every xdist worker imports every test file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+QWEN_0_5B = dict(heads=14, kv_heads=2, head_dim=64)  # QWEN2_0_5B attention
+HD128 = dict(heads=32, kv_heads=8, head_dim=128)  # Llama-3-8B attention
+VOCAB = 151936
+ROWS = 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` → a ShapeDtypeStruct placed on one v5e chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+
+def assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def kv_pages(chip, shape, quantized: bool):
+    """bf16 pages, or the int8 container with its compact per-token scales."""
+    from distrl_llm_tpu.ops.paged import _quant_utils
+
+    if not quantized:
+        return chip(shape, jnp.bfloat16)
+    return _quant_utils().QuantizedTensor(
+        weight=chip(shape, jnp.int8),
+        scales=chip(shape[:3] + (1,), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize(
+    "impl,geom",
+    [
+        ("native", QWEN_0_5B),  # what "auto" is on a TPU backend
+        ("native", HD128),
+        ("native_folded", QWEN_0_5B),  # what a stored plan may name
+        ("native_blocked", QWEN_0_5B),
+    ],
+    ids=["native-hd64", "native-hd128", "folded-hd64", "blocked-hd64"],
+)
+def test_paged_decode(chip, impl, geom, quantized):
+    from distrl_llm_tpu.ops.paged import paged_attention_op
+
+    page_size, pps = 16, 64
+    h, kh, hd = geom["heads"], geom["kv_heads"], geom["head_dim"]
+    pages = kv_pages(chip, (kh, ROWS * pps, page_size, hd), quantized)
+    assert_kernel(
+        functools.partial(paged_attention_op, impl=impl),
+        chip((ROWS, h, hd), jnp.bfloat16), pages, pages,
+        chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32),
+    )
+
+
+def test_jaxlib_launch_where_it_applies(chip):
+    """``impl="kernel"`` (jaxlib's own launch, only ever named explicitly)
+    compiles at head_dim 128; at head_dim 64 Mosaic refuses its block specs,
+    and the refusal reaches the caller."""
+    from distrl_llm_tpu.ops.paged import paged_attention_op
+
+    page_size, pps = 16, 64
+
+    def args(geom):
+        h, kh, hd = geom["heads"], geom["kv_heads"], geom["head_dim"]
+        pages = kv_pages(chip, (kh, ROWS * pps, page_size, hd), False)
+        return (chip((ROWS, h, hd), jnp.bfloat16), pages, pages,
+                chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32))
+
+    kernel = functools.partial(paged_attention_op, impl="kernel")
+    assert_kernel(kernel, *args(HD128))
+    with pytest.raises(ValueError, match="block shape"):
+        jax.jit(kernel).lower(*args(QWEN_0_5B))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+def test_paged_verify(chip, quantized):
+    """The fused draft-block verify sweep (speculative decoding)."""
+    from distrl_llm_tpu.ops.paged import _native_verify_call
+
+    page_size, pps, draft = 16, 64, 4
+    h, kh, hd = (QWEN_0_5B[k] for k in ("heads", "kv_heads", "head_dim"))
+    pages = kv_pages(chip, (kh, ROWS * pps, page_size, hd), quantized)
+    assert_kernel(
+        functools.partial(_native_verify_call, quantized=quantized),
+        chip((ROWS, draft + 1, h, hd), jnp.bfloat16), pages, pages,
+        chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_fused_sampler(chip, dtype):
+    from distrl_llm_tpu.ops.sampling import fused_sample
+
+    assert_kernel(
+        fused_sample,
+        chip((2,), jnp.uint32), chip((ROWS, VOCAB), dtype),
+        chip((), jnp.float32), chip((), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("impl", ["flash", "splash"])
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_learner_attention(chip, monkeypatch, impl, grad):
+    """Through the ``attention`` front door, which routes by backend — the
+    described chip is not a backend, so the test says "tpu" for it."""
+    from distrl_llm_tpu.ops.attention import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s = 2, 1024
+    h, kh, hd = (QWEN_0_5B[k] for k in ("heads", "kv_heads", "head_dim"))
+
+    def fwd(q, k, v, valid):
+        return attention(q, k, v, None, impl=impl, key_valid=valid)
+
+    def loss(q, k, v, valid):
+        return fwd(q, k, v, valid).astype(jnp.float32).sum()
+
+    assert_kernel(
+        jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd,
+        chip((b, s, h, hd), jnp.bfloat16), chip((b, s, kh, hd), jnp.bfloat16),
+        chip((b, s, kh, hd), jnp.bfloat16), chip((b, s), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+@pytest.mark.parametrize(
+    "k,n", [(896, 4864), (4864, 896), (896, VOCAB)],
+    ids=["up", "down", "lm_head"],
+)
+def test_dequant_matmul(chip, k, n, bits):
+    """Dequant-matmul with the LoRA epilogue, at Qwen2.5-0.5B's widths."""
+    from distrl_llm_tpu.ops.quant_matmul import quant_matmul
+
+    group, rank = 64, 16
+    w = {
+        "q": chip((k // group, group, n), jnp.int4 if bits == 4 else jnp.int8),
+        "scale": chip((k // group, 1, n), jnp.float32),
+    }
+    assert_kernel(
+        lambda x, w, a, b: quant_matmul(x, w, None, a, b, 2.0),
+        chip((ROWS, k), jnp.bfloat16), w,
+        chip((k, rank), jnp.bfloat16), chip((rank, n), jnp.bfloat16),
+    )
+
+
+@pytest.fixture(scope="module")
+def two_chips(topo):
+    """(mesh of two of the chips, ``on(shape, dtype)`` → a ShapeDtypeStruct
+    replicated over it): a role submesh of ``number_of_actors=2``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distrl_llm_tpu.parallel.mesh import AXES
+
+    mesh = Mesh(np.asarray(topo.devices[:2]).reshape(2, 1, 1, 1), AXES)
+    everywhere = NamedSharding(mesh, P())
+    return mesh, lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=everywhere
+    )
+
+
+class TestProgramOverTwoChips:
+    """GSPMD refuses a bare Mosaic kernel inside a jit that spans devices.
+    Under the context mesh the engines set (``ops/per_device.py``) the
+    dispatchers wrap their kernels in a replicated ``shard_map``, so a
+    role submesh of several chips compiles the same kernels."""
+
+    def test_bare_kernel_is_refused(self, two_chips):
+        from distrl_llm_tpu.ops.sampling import fused_sample
+
+        _, on = two_chips
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            jax.jit(fused_sample).lower(
+                on((2,), jnp.uint32), on((ROWS, VOCAB), jnp.float32),
+                on((), jnp.float32), on((), jnp.float32),
+            )
+
+    def test_sampler_and_paged_decode(self, two_chips):
+        from distrl_llm_tpu.ops.paged import paged_attention_op
+        from distrl_llm_tpu.ops.sampling import sample_with_logprob
+
+        mesh, on = two_chips
+        page_size, pps = 16, 64
+        h, kh, hd = (QWEN_0_5B[k] for k in ("heads", "kv_heads", "head_dim"))
+        pages = on((kh, ROWS * pps, page_size, hd), jnp.bfloat16)
+
+        def step(rng, logits, q, k_pages, v_pages, lengths, table):
+            tok, logp = sample_with_logprob(
+                rng, logits, 1.2, 0.95, capture_logprob=True, impl="fused"
+            )
+            att = paged_attention_op(
+                q, k_pages, v_pages, lengths, table, impl="native"
+            )
+            return tok, logp, att
+
+        with jax.set_mesh(mesh):
+            text = jax.jit(step).lower(
+                on((2,), jnp.uint32), on((ROWS, VOCAB), jnp.float32),
+                on((ROWS, h, hd), jnp.bfloat16), pages, pages,
+                on((ROWS,), jnp.int32), on((ROWS, pps), jnp.int32),
+            ).compile().as_text()
+        assert text.count("tpu_custom_call") >= 2
+
+    def test_dequant_matmul_forward_and_backward(self, two_chips):
+        from distrl_llm_tpu.ops.quant_matmul import quant_matmul
+
+        mesh, on = two_chips
+        k, n, group, rank = 896, 4864, 64, 16
+        w = {"q": on((k // group, group, n), jnp.int8),
+             "scale": on((k // group, 1, n), jnp.float32)}
+
+        def loss(a, b, x, w):
+            return quant_matmul(x, w, None, a, b, 2.0).astype(jnp.float32).sum()
+
+        with jax.set_mesh(mesh):
+            text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+                on((k, rank), jnp.bfloat16), on((rank, n), jnp.bfloat16),
+                on((ROWS, k), jnp.bfloat16), w,
+            ).compile().as_text()
+        assert "tpu_custom_call" in text

@@ -420,9 +420,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     args = build_parser().parse_args(argv)
     return args.fn(args)
 

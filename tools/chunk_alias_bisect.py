@@ -24,9 +24,6 @@ sys.path.insert(0, ".")
 
 import jax
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-honor_jax_platforms()
 
 import jax.numpy as jnp
 

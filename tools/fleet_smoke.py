@@ -415,9 +415,6 @@ def gate_quiescent() -> None:
 
 
 def main() -> int:
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     t0 = time.time()
     print("== gate 1: elastic 2→4→2 with seeded chaos")
     gate_elastic()

@@ -32,9 +32,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
 
-honor_jax_platforms()
 os.environ["DISTRL_POOL_CHECK"] = "1"
 
 

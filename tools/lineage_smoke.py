@@ -72,10 +72,6 @@ def spawn_worker(port: int = 0):
 
 
 def main() -> int:
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
     import jax
     import numpy as np
 

@@ -72,10 +72,6 @@ def scrape(url: str):
 
 
 def main() -> int:
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
     import jax
     import numpy as np
 

@@ -23,10 +23,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 
 def main() -> int:
     import jax.numpy as jnp

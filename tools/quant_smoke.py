@@ -29,10 +29,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 
 def _greedy_tokens(params, lora, env_mode: str) -> "object":
     """One greedy TINY decode round under DISTRL_QUANT_MATMUL=env_mode

@@ -30,9 +30,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
 
-honor_jax_platforms()
 os.environ["DISTRL_POOL_CHECK"] = "1"
 
 PAGE = 8

@@ -28,10 +28,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
-
 
 def run_mode(mode: str, trace_dir: str | None = None):
     import jax

@@ -26,7 +26,7 @@ stage "dryrun_multichip" timeout 300 python __graft_entry__.py
 stage "cli_smoke" env JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   timeout 600 python train_distributed.py --smoke
-stage "bench_fallback" env JAX_PLATFORMS=cpu BENCH_MODEL=tiny BENCH_PROMPTS=4 \
+stage "bench_cpu_row" env JAX_PLATFORMS=cpu BENCH_MODEL=tiny BENCH_PROMPTS=4 \
   BENCH_CANDIDATES=2 BENCH_MAX_PROMPT=32 BENCH_MAX_NEW=32 \
   timeout 600 python bench.py
 # telemetry acceptance gate: 2-step traced train + worker round → one
@@ -180,7 +180,7 @@ stage "suite_engines_2" timeout 600 python -m pytest -q \
   tests/test_speculative.py tests/test_sharded_paged.py
 stage "suite_engines_3" timeout 600 python -m pytest -q \
   tests/test_paged_budget.py tests/test_inflight_updates.py \
-  tests/test_paged_int8_kernel.py tests/test_prefix_sharing.py
+  tests/test_prefix_sharing.py tests/test_tpu_compile.py
 stage "suite_learner" timeout 600 python -m pytest -q \
   tests/test_train_step.py tests/test_losses.py tests/test_model_golden.py \
   tests/test_lora.py tests/test_optim.py tests/test_quant.py tests/test_sharding.py
@@ -213,7 +213,7 @@ stage "suite_slow_learner" timeout 1200 python -m pytest -q -m slow \
   tests/test_rollout_buffer.py tests/test_rollout_modes.py
 stage "suite_slow_ops" timeout 1200 python -m pytest -q -m slow \
   tests/test_ring_attention.py tests/test_ulysses.py tests/test_sampling.py \
-  tests/test_long_context.py tests/test_paged_int8_kernel.py \
+  tests/test_long_context.py \
   tests/test_sharding.py tests/test_role_separation.py
 stage "suite_slow_io" timeout 1200 python -m pytest -q -m slow \
   tests/test_from_pretrained.py tests/test_real_checkpoint.py \

@@ -29,9 +29,6 @@ from functools import partial
 
 import jax
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-honor_jax_platforms()
 
 import jax.numpy as jnp
 

@@ -38,9 +38,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
 
-honor_jax_platforms()
 os.environ["DISTRL_POOL_CHECK"] = "1"
 # seeded SLO breach: the sentinel must see an injected TTFT blowup at
 # step 2 and produce exactly one incident bundle (set before it builds)

@@ -9,7 +9,7 @@ The acceptance contract for the system-integrated speculative path
   the fused verify dispatch threaded (on CPU it resolves to the exact
   unrolled reference — the dispatch layer, not the kernel, is what this
   gate exercises; interpreter kernel parity lives in
-  tests/test_paged_native.py and silicon parity in tpu_kernel_check.py);
+  tests/test_paged_native.py, the v5e lowering in tests/test_tpu_compile.py);
 * chunked dispatch (scan_chunk over the spec scheduler) stays
   bit-identical AND actually runs (scan_chunk_active);
 * per-round spec stats populate (accept rate, tokens/verify-step, emit
@@ -31,10 +31,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from distrl_llm_tpu.utils.platform import honor_jax_platforms  # noqa: E402
-
-honor_jax_platforms()
 
 
 def engine_checks() -> None:

@@ -23,7 +23,7 @@ deterministic TINY model — the chaos_smoke twin-worker topology):
 
 Exit 0 = the bus held; nonzero otherwise. ``tools/run_all_checks.sh`` runs
 this as the weight-bus stage; ``--report-json PATH`` additionally writes the
-dispatch-vs-broadcast byte/latency A/B record tools/tpu_bench_loop.sh stages.
+dispatch-vs-broadcast byte/latency A/B record.
 """
 
 from __future__ import annotations
@@ -199,12 +199,9 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--report-json", type=str, default=None,
                     help="write the dispatch-vs-broadcast A/B record here "
-                         "(one JSON object; tpu_bench_loop.sh stages it)")
+                         "(one JSON object)")
     args = ap.parse_args()
 
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import numpy as np
 
     from distrl_llm_tpu.distributed import weight_bus as wb

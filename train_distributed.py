@@ -12,12 +12,17 @@ Usage (reference README.md:48–61 contract):
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import time
+
+import numpy as np
 
 from distrl_llm_tpu.config import MeshConfig, TrainConfig
 from distrl_llm_tpu.data import prepare_dataset
 from distrl_llm_tpu.rewards import reward_function
 from distrl_llm_tpu.tokenizer import load_tokenizer
 from distrl_llm_tpu.trainer import Trainer
+from distrl_llm_tpu.utils.devices import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -83,9 +88,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode steps fused per dispatch via lax.scan "
                         "(all engines: dense, paged wave/refill, sharded, "
                         "and speculative) — "
-                        "amortizes per-dispatch overhead on network-"
-                        "tunneled PJRT clients (tools/dispatch_probe.py "
-                        "measures it); auto-falls back if the compiler "
+                        "amortizes per-dispatch host overhead "
+                        "(tools/dispatch_probe.py measures it); "
+                        "auto-falls back if the compiler "
                         "double-buffers the KV cache. 0 = off; unset = "
                         "let the autotune plan DB decide (static "
                         "default: off). An explicit value, including 0, "
@@ -561,123 +566,238 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     return TrainConfig(mesh=mesh, **fields)
 
 
-def run_smoke(config: TrainConfig) -> None:
-    """BASELINE config-1-shaped integration smoke without downloads: random
-    tiny model through the REAL engine + learner + trainer on whatever devices
-    exist (CPU mesh or the one TPU chip). Asserts loss is finite and prints
-    the final metrics record."""
-    import dataclasses
+@dataclasses.dataclass(frozen=True)
+class SmokeSizes:
+    """What the offline assembly (``run_smoke``) is sized by. The defaults are
+    the CPU smoke's; ``chip_smoke.py`` passes the chip's."""
 
+    prompts: int = 4  # prompts per step (batch_size)
+    candidates: int = 4  # samples per prompt
+    steps: int = 2  # train steps: the one episode holds steps·prompts problems
+    max_prompt_tokens: int = 64
+    max_new_tokens: int = 32
+    micro_batch: int = 4  # learner micro-batch rows (train_batch_size)
+    lora_rank: int = 4
+    dtype: str = "float32"  # base weights; the chip runs "bfloat16"
+    # paged engine only
+    page_size: int = 8
+    max_concurrent_rows: int = 4
+    decode_chunk: int = 4
+
+
+def smoke_problems(n: int, seed: int, max_chars: int) -> dict[str, list[str]]:
+    """``n`` seeded arithmetic problems of varied length (so prompts differ in
+    length under a byte tokenizer), each at most ``max_chars`` characters."""
+    rng = np.random.default_rng(seed)
+    problems, solutions = [], []
+    for _ in range(n):
+        terms = rng.integers(1, 1000, size=int(rng.integers(2, 12)))
+        text = "What is " + " + ".join(str(t) for t in terms) + "?"
+        problems.append(text[:max_chars])
+        solutions.append(str(int(terms.sum())))
+    return {"problem": problems, "solution": solutions}
+
+
+def dense_smoke_reward(completions, solutions) -> np.ndarray:
+    """(N, 2) reward contract with a dense, deterministic accuracy column: a
+    hash of the completion's text. A smoke's policy is random weights, whose
+    completions the math reward scores 0 throughout — every microbatch is
+    then skipped and no update ever happens. This one gives each group
+    unequal rewards, so the learner really steps."""
+    import zlib
+
+    acc = [(zlib.crc32(c.encode("utf-8")) % 8) / 8.0 for c in completions]
+    return np.column_stack((np.zeros(len(acc)), np.asarray(acc)))
+
+
+def tree_checksum(tree) -> float:
+    """Sum of absolute values over a pytree's leaves, on the host."""
     import jax
-    import numpy as np
 
+    return float(sum(
+        np.abs(np.asarray(leaf, np.float64)).sum()
+        for leaf in jax.tree_util.tree_leaves(tree)
+    ))
+
+
+def run_smoke(
+    config: TrainConfig,
+    model_cfg=None,
+    sizes: SmokeSizes = SmokeSizes(),
+    *,
+    seed: int = 0,
+    reward_fn=reward_function,
+    devices: list | None = None,
+) -> dict:
+    """BASELINE config-1-shaped integration smoke without downloads: a
+    random-init model (``model_cfg``, default TINY) through the REAL engine
+    + learner + ``Trainer.train()`` on whatever devices exist (CPU mesh, one
+    TPU chip, or a role-split of several). ``--smoke`` on the CPU and
+    ``chip_smoke.py`` on the chip are this one function at different
+    ``sizes``. Asserts every loss is finite and returns what happened: one
+    record per rollout round (the policy version it sampled under, the
+    checksum of the adapter it was given), one per train step, the final
+    adapter checksum, and the trainer itself for placement checks."""
+    import jax
+    import jax.numpy as jnp
+
+    from distrl_llm_tpu.data import process_dataset
     from distrl_llm_tpu.engine.engine import GenerationEngine
     from distrl_llm_tpu.metrics import MemorySink
     from distrl_llm_tpu.models import TINY, init_params
+    from distrl_llm_tpu.models.lora import lora_scale
+    from distrl_llm_tpu.parallel.mesh import build_role_meshes
+    from distrl_llm_tpu.parallel.partition import param_specs, shard_tree
     from distrl_llm_tpu.tokenizer import CharTokenizer
 
     config = dataclasses.replace(
         config,
-        model="tiny", episodes=1, batch_size=4, num_candidates=4, topk=4,
-        train_batch_size=4, max_prompt_tokens=64,
-        # multi-turn envs need the answer window to seat a policy turn PLUS
-        # the injected observation (CharTokenizer: 1 char ≈ 1 token) or every
-        # turn resume is declined for lack of room
-        max_new_tokens=32 if config.env == "math" else 96,
-        number_of_actors=1, number_of_learners=1, learner_chunk_size=1,
+        model=config.model if model_cfg is not None else "tiny",
+        episodes=1, batch_size=sizes.prompts,
+        num_candidates=sizes.candidates, topk=sizes.candidates,
+        eval_n=sizes.candidates, train_batch_size=sizes.micro_batch,
+        max_prompt_tokens=sizes.max_prompt_tokens,
+        max_new_tokens=sizes.max_new_tokens,
         eval_every=0, save_every=0, metrics_backend="null",
-        max_lora_rank=4, lora_alpha=8, lr=1e-3,
-        mesh=MeshConfig(
-            number_of_actors=1, number_of_learners=1,
-            tp=config.mesh.tp, sp=config.mesh.sp, fsdp=config.mesh.fsdp,
-        ),
+        max_lora_rank=sizes.lora_rank, lora_alpha=2 * sizes.lora_rank,
+        lr=1e-3,
     )
-    tokenizer = CharTokenizer(TINY.vocab_size)
-    problems = [f"What is {i}+{i}?" for i in range(8)]
-    from distrl_llm_tpu.data import process_dataset
-
-    train = process_dataset(
-        tokenizer, {"problem": problems, "solution": [str(2 * i) for i in range(8)]}
+    model_cfg = model_cfg if model_cfg is not None else TINY
+    tokenizer = CharTokenizer(model_cfg.vocab_size)
+    # the chat template costs ~75 characters of a byte-tokenized prompt
+    train = process_dataset(tokenizer, smoke_problems(
+        sizes.steps * sizes.prompts, seed,
+        max_chars=max(8, sizes.max_prompt_tokens - 80),
+    ))
+    test = {k: v[:sizes.prompts] for k, v in train.items()}
+    base = init_params(
+        jax.random.PRNGKey(seed), model_cfg, dtype=jnp.dtype(sizes.dtype)
     )
-    test = {k: v[:4] for k, v in train.items()}
-    base = init_params(jax.random.PRNGKey(0), TINY)
     if config.base_quant != "none":
-        from distrl_llm_tpu.ops.quant import (
-            default_group_size, quant_bits_for, quantize_params,
-        )
+        from distrl_llm_tpu.ops.quant import quant_bits_for, quantize_params
 
-        bits = quant_bits_for(config.base_quant)
         base = quantize_params(
-            base, bits=bits, group_size=config.quant_group_size or 16
+            base, bits=quant_bits_for(config.base_quant),
+            group_size=config.quant_group_size or 16,
         )
+    # each role holds the frozen base on its own submesh, as
+    # Trainer.from_pretrained places a loaded checkpoint; timeshared roles
+    # alias one copy
+    meshes = build_role_meshes(config.mesh, devices)
+    specs = param_specs(base)
+    base_rollout = shard_tree(base, meshes.rollout, specs)
+    base_learner = (
+        base_rollout if meshes.timeshared
+        else shard_tree(base, meshes.learner, specs)
+    )
+    engine_common = dict(
+        max_prompt_tokens=config.max_prompt_tokens,
+        max_new_tokens=config.max_new_tokens,
+        pad_token_id=tokenizer.pad_token_id,
+        lora_scale=lora_scale(config.max_lora_rank, config.lora_alpha),
+        # behavior-logprob capture whenever the objective needs it, so
+        # --smoke composes with --clip_ratio / --rollout_mode async
+        capture_logprobs=config.clip_ratio > 0.0,
+        # honor --autotune/--plan-db in the smoke path too: "--autotune
+        # off skips the DB read entirely" must hold for every engine the
+        # CLI builds
+        autotune=config.autotune,
+        plan_db=config.plan_db,
+    )
     if config.engine_impl == "paged":
         from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
-        from distrl_llm_tpu.models.lora import lora_scale
 
         engine = PagedGenerationEngine(
-            TINY,
-            max_prompt_tokens=config.max_prompt_tokens,
-            max_new_tokens=config.max_new_tokens,
-            # multi-turn smoke: half-vocab EOS so the random tiny policy
+            model_cfg,
+            # multi-turn smoke: half-vocab EOS so the random policy
             # actually ends turns inside the window and the env gets to
             # inject observations; math keeps the real EOS contract
             eos_token_ids=(
                 [tokenizer.eos_token_id] if config.env == "math"
-                else list(range(2, TINY.vocab_size, 2))
+                else list(range(2, model_cfg.vocab_size, 2))
             ),
-            pad_token_id=tokenizer.pad_token_id,
-            page_size=8, max_concurrent_rows=4,
+            page_size=sizes.page_size,
+            max_concurrent_rows=sizes.max_concurrent_rows,
             scheduler="refill" if config.continuous_batching else "static",
             continuous_admission=config.continuous_admission,
-            decode_chunk=4,
-            lora_scale=lora_scale(config.max_lora_rank, config.lora_alpha),
-            capture_logprobs=config.clip_ratio > 0.0,
-            autotune=config.autotune,
-            plan_db=config.plan_db,
+            decode_chunk=sizes.decode_chunk,
+            kv_quant=config.kv_cache_quant,
+            **engine_common,
         )
     else:
         engine = GenerationEngine(
-            TINY,
-            max_prompt_tokens=config.max_prompt_tokens,
-            max_new_tokens=config.max_new_tokens,
-            eos_token_ids=[tokenizer.eos_token_id],
-            pad_token_id=tokenizer.pad_token_id,
-            # behavior-logprob capture whenever the objective needs it, so
-            # --smoke composes with --clip_ratio / --rollout_mode async
-            capture_logprobs=config.clip_ratio > 0.0,
-            # honor --autotune/--plan-db in the smoke path too: "--autotune
-            # off skips the DB read entirely" must hold for every engine the
-            # CLI builds
-            autotune=config.autotune,
-            plan_db=config.plan_db,
+            model_cfg, eos_token_ids=[tokenizer.eos_token_id],
+            **engine_common,
         )
     sink = MemorySink()
-    from distrl_llm_tpu.parallel.mesh import build_role_meshes
-
     trainer = Trainer(
-        train, test, reward_function, config,
-        tokenizer=tokenizer, engine=engine, base_params=base, model_cfg=TINY,
-        meshes=build_role_meshes(config.mesh), sink=sink,
+        train, test, reward_fn, config,
+        tokenizer=tokenizer, engine=engine, base_params=base_rollout,
+        base_params_learner=base_learner, model_cfg=model_cfg,
+        meshes=meshes, sink=sink,
     )
+    # observe each rollout round from outside: what version it sampled
+    # under and which adapter it was handed (the first round is the initial
+    # evaluation, the rest one per train step)
+    rounds: list[dict] = []
+    generate_round = trainer._generate_round
+
+    def observed_round(batch, sampling):
+        entry = {
+            "started": time.perf_counter(),
+            "policy_version": trainer._rollout_weight_version,
+            "adapter_checksum": tree_checksum(trainer._lora_rollout),
+        }
+        out = generate_round(batch, sampling)
+        entry["seconds"] = time.perf_counter() - entry["started"]
+        entry["gen_tokens"] = int(sum(
+            sum(lens) for c in out for lens in c["token_lengths"]
+        ))
+        rounds.append(entry)
+        return out
+
+    trainer._generate_round = observed_round
     trainer.train()
-    train_recs = [m for _, m in sink.records if "loss" in m]
-    assert train_recs, "no train steps ran"
-    assert all(np.isfinite(m["loss"]) for m in train_recs), "non-finite loss"
-    print(f"SMOKE OK — {len(train_recs)} train steps on "
-          f"{jax.device_count()} {jax.devices()[0].platform} device(s)")
-    print(train_recs[-1])
+    steps = [m for _, m in sink.records if "loss" in m]
+    assert steps, "no train steps ran"
+    assert all(np.isfinite(m["loss"]) for m in steps), "non-finite loss"
+    return {
+        "steps": steps,
+        "rounds": rounds,
+        "final_adapter_checksum": tree_checksum(trainer.lora),
+        "trainer": trainer,
+    }
 
 
 def main(argv: list[str] | None = None) -> None:
     args = build_parser().parse_args(argv)
     config = config_from_args(args)
 
-    from distrl_llm_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
+    enable_compile_cache()
 
     if args.smoke:
-        run_smoke(config)
+        import jax
+
+        report = run_smoke(
+            # one actor group and one learner group, however many roles the
+            # CLI's defaults name; one learner row per hybrid round
+            dataclasses.replace(
+                config, learner_chunk_size=1,
+                number_of_actors=1, number_of_learners=1,
+                mesh=dataclasses.replace(
+                    config.mesh, number_of_actors=1, number_of_learners=1
+                ),
+            ),
+            sizes=SmokeSizes(
+                # multi-turn envs need the answer window to seat a policy
+                # turn PLUS the injected observation (CharTokenizer: 1 char
+                # ≈ 1 token) or every turn resume is declined for lack of room
+                max_new_tokens=32 if config.env == "math" else 96,
+            ),
+        )
+        print(f"SMOKE OK — {len(report['steps'])} train steps on "
+              f"{jax.device_count()} {jax.devices()[0].platform} device(s)")
+        print(report["steps"][-1])
         return
 
     tokenizer = load_tokenizer(args.checkpoint_path or config.model)
