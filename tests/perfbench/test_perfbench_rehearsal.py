@@ -1,0 +1,75 @@
+"""The ``rollout`` and ``learner`` drivers end to end on the CPU, through the
+tiny cells the tests bring (``tiny_spec.py``): the last line parses, has exactly
+the contract's keys, and reports no time, rate, utilization or share, since a
+CPU run may report counts only. (``test_perfbench_rehearsal_rl.py`` holds the
+``rl_step`` driver and the refusals.)
+
+The chip runs are the builder's and the driver's; these hold what a CPU can:
+control flow, the correctness check's own logic, the same traffic from the
+same seed.
+"""
+
+import pytest
+
+from rehearsal_helpers import SWITCH, assert_cell_ran, run_cell, shared_cell
+from tiny_spec import write_tiny_benchmark
+
+
+@pytest.fixture(scope="module")
+def tiny_benchmark(tmp_path_factory):
+    return write_tiny_benchmark(tmp_path_factory.mktemp("tiny"))
+
+
+@pytest.mark.parametrize("cell,trace", [
+    ("tiny.rollout", 0), ("tiny.rollout", 1), ("tiny.learner", 0), ("tiny.learner", 1),
+])
+def test_engine_only_and_learner_only_cells_run_end_to_end(tiny_benchmark, cell, trace):
+    line, notes = shared_cell(tiny_benchmark, cell, trace)
+    assert_cell_ran(line, notes, trace)
+
+
+def test_the_environment_is_put_back(tiny_benchmark):
+    """The harness unsets every DISTRL_* switch for the run and only for it
+    (``run_cell`` sets one round the run and finds it again after)."""
+    _, notes = shared_cell(tiny_benchmark, "tiny.learner", 0)
+    assert SWITCH in notes["run"]["distrl_switches_unset"]
+
+
+def test_same_seed_same_traffic(tiny_benchmark):
+    """Two runs of one seed (one of them traced) check the same rows and read
+    the same numbers; another seed does not."""
+    _, notes_a = shared_cell(tiny_benchmark, "tiny.rollout", 0)
+    _, notes_b = shared_cell(tiny_benchmark, "tiny.rollout", 1)
+    _, notes_c = shared_cell(tiny_benchmark, "tiny.rollout", 0, seed=10)
+    for key in ("rows", "decoded", "mean_abs"):
+        assert notes_a["check"][key] == notes_b["check"][key]
+    assert notes_a["check"]["mean_abs"] != notes_c["check"]["mean_abs"]
+
+
+def test_the_generators_three_ways_to_end_an_answer():
+    from perfbench import assembly
+
+    assert assembly.eos_ids({"eos": "never"}, 256, seed=1, real_eos=3) == [-1]
+    assert assembly.eos_ids({}, 256, seed=1, real_eos=3) == [3]
+    drawn = assembly.eos_ids({"eos_rate": 0.25}, 256, seed=1, real_eos=3)
+    assert len(set(drawn)) == 64 and all(0 <= i < 256 for i in drawn)
+    assert drawn == assembly.eos_ids({"eos_rate": 0.25}, 256, seed=1, real_eos=3)
+    assert drawn != assembly.eos_ids({"eos_rate": 0.25}, 256, seed=2, real_eos=3)
+
+
+def test_a_wrong_answer_is_not_correct(tiny_benchmark, monkeypatch):
+    """The check decides ``correct``: with a tolerance no run can meet, the same
+    run reports ``correct: false`` (and still prints its line)."""
+    from perfbench import correct
+
+    monkeypatch.setattr(correct, "LOGPROB_MEAN_ABS_TOL", 0.0)
+    line, notes = run_cell(tiny_benchmark, "tiny.rollout", 0)
+    assert line["correct"] is False and notes["check"]["ok"] is False
+
+
+def test_a_wrong_gradient_is_not_correct(tiny_benchmark, monkeypatch):
+    from perfbench import correct
+
+    monkeypatch.setattr(correct, "GRAD_SIGN_MASS_TOL", 1.5)
+    line, notes = run_cell(tiny_benchmark, "tiny.learner", 0)
+    assert line["correct"] is False and notes["check"]["ok"] is False

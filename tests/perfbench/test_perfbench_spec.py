@@ -1,0 +1,145 @@
+"""``BENCHMARK.json`` against the contract it is written to, and every file it
+names: what the driver would refuse before a single run, held here for free."""
+
+import glob
+import json
+import os
+import re
+
+import pytest
+
+from tiny_spec import REPO, real_benchmark
+
+BENCH = real_benchmark()
+NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}$")
+PLAIN_PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def reported(metrics, cell):
+    return [m for m in metrics if cell in m.get("workloads", CELLS)]
+
+
+def under_paths(rel: str) -> bool:
+    return any(rel == p or rel.startswith(p + "/") for p in BENCH["paths"])
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= len(BENCH["command"]) <= 32
+    assert 1 <= len(BENCH["paths"]) <= 16
+    for p in BENCH["paths"]:
+        assert PLAIN_PATH.match(p) and not p.startswith("/") and ".." not in p
+        assert os.path.isdir(os.path.join(REPO, p))
+    # the command names no file of the repo outside the paths
+    for arg in BENCH["command"]:
+        if os.path.exists(os.path.join(REPO, arg)):
+            assert under_paths(arg), arg
+    assert 1 <= len(BENCH["configs"]) <= 24 and 2 <= len(CELLS) <= 24
+    assert 1 <= len(BENCH["end_to_end"]) <= 16 and 1 <= len(BENCH["per_layer"]) <= 128
+
+
+def test_names_are_plain_and_used_once():
+    names = [x["name"] for key in ("configs", "workloads", "end_to_end", "per_layer")
+             for x in BENCH[key]]
+    assert all(NAME.match(n) for n in names), names
+    assert len(names) == len(set(names))
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+def test_every_file_under_the_paths_has_a_plain_name():
+    for p in BENCH["paths"]:
+        for path in glob.glob(os.path.join(REPO, p, "**"), recursive=True):
+            rel = os.path.relpath(path, REPO)
+            if "__pycache__" in rel or rel.endswith(".pyc"):
+                continue
+            assert PLAIN_PATH.match(rel), rel
+
+
+def test_four_chip_cells_are_at_most_a_quarter_and_at_least_allowed_one():
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
+    assert len(four) <= max(1, len(CELLS) // 4)
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_file(config):
+    assert under_paths(config["file"]) and len(config["why"]) <= 200
+    assert config["source"].startswith("https://")
+    with open(os.path.join(REPO, config["file"]), encoding="utf-8") as f:
+        held = json.load(f)
+    assert held["source"] == config["source"]
+    assert held["reduced"] == config["reduced"]
+    # a width is never cut: only depth may be named
+    assert set(config["reduced"]) <= {"num_hidden_layers"}
+    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    # the plain reference beside it
+    assert os.path.isfile(os.path.join(REPO, "perfbench", held["reference"] + ".py"))
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_cell_names_files_that_exist_and_reports_what_it_must(cell):
+    from perfbench import spec
+
+    assert len(cell["why"]) <= 200
+    loaded = spec.load_cell(BENCH, cell["name"])
+    kind = loaded.traffic["kind"]
+    assert os.path.isfile(os.path.join(REPO, "perfbench", "drivers", f"{kind}.py"))
+    e2e = [m["name"] for m in reported(BENCH["end_to_end"], cell["name"])]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layer = reported(BENCH["per_layer"], cell["name"])
+    assert layer
+    # a per-layer metric is reported only where the metric it moves is
+    assert all(m["moves"] in e2e for m in layer), [
+        (m["name"], m["moves"]) for m in layer if m["moves"] not in e2e]
+
+
+@pytest.mark.parametrize("metric", BENCH["end_to_end"], ids=lambda m: m["name"])
+def test_end_to_end_metric(metric):
+    assert metric["source"] in ("host_clock", "device_trace")
+    assert metric["better"] in ("lower", "higher")
+    assert 0.01 <= metric["bound"] <= 0.1
+    if metric["name"] == "setup_s":
+        assert metric["bound"] == 0.1 and "workloads" not in metric
+    assert set(metric.get("workloads", CELLS)) <= set(CELLS)
+
+
+@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_has_its_file_and_its_reader(metric):
+    from perfbench import spec
+
+    held = spec.load_layer_metric(BENCH["paths"], metric["name"])
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert held[key] == metric[key], (metric["name"], key)
+    assert metric["source"] in SOURCES
+    assert metric["moves"] in [m["name"] for m in BENCH["end_to_end"]]
+    assert "bound" not in metric
+    reader = spec.load_module(BENCH["paths"], "readers", held["reader"])
+    assert callable(reader.read)
+    # a reader that finds nothing to read returns nothing
+    assert reader.read({}, held.get("args", {}), None) is None
+    if metric["name"].endswith("_roofline"):
+        assert metric["unit"] == "%"
+    if "regex" in held.get("args", {}):
+        re.compile(held["args"]["regex"])
+
+
+def test_traffic_is_data():
+    for path in glob.glob(os.path.join(REPO, "perfbench", "traffic", "*")):
+        assert path.endswith((".json", ".jsonl", ".toml", ".txt", ".csv")), path
+
+
+def test_no_file_waits_for_a_cell():
+    """Every traffic mix and per-layer metric under ``perfbench/`` is named by
+    ``BENCHMARK.json``: a file for a cell that is not there comes with the cell."""
+    def held(sub):
+        return {os.path.basename(p)[:-5]
+                for p in glob.glob(os.path.join(REPO, "perfbench", sub, "*.json"))}
+
+    assert held("traffic") == {w["traffic"] for w in BENCH["workloads"]}
+    assert held("layer_metrics") == {m["name"] for m in BENCH["per_layer"]}
