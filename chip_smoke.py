@@ -30,6 +30,8 @@ import json
 import sys
 import time
 
+from distrl_llm_tpu.telemetry import CompileLog  # the program's one compile listener
+
 #: bf16 has 8 bits of mantissa (one ulp is 2^-8 ≈ 0.4% of a value); the two
 #: four-chip runs reduce in different orders, so per-step losses may sit a few
 #: ulps apart, and Adam's sign-like first steps turn a last-bit difference in
@@ -40,45 +42,6 @@ ADAPTER_REL_L2_TOL = 0.25
 
 def emit(phase: str, **fields) -> None:
     print(json.dumps({"phase": phase, **fields}), flush=True)
-
-
-class CompileLog:
-    """Every program JAX compiles (or loads from the persistent cache) from
-    now on: (function name, seconds, when it finished), off JAX's own
-    monitoring events."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.events: list[tuple[str, float, float]] = []
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **kw) -> None:
-        if event == self.EVENT:
-            self.events.append((
-                str(kw.get("fun_name", "?")), float(duration),
-                time.perf_counter(),
-            ))
-
-    def mark(self) -> int:
-        return len(self.events)
-
-    def since(self, mark: int) -> dict:
-        new = self.events[mark:]
-        return {
-            "programs": len(new),
-            "seconds": round(sum(s for _, s, _ in new), 3),
-        }
-
-    def recompiled_after(self, mark: int, t: float) -> tuple[list, list]:
-        """(names compiled again, names compiled for the first time) among
-        the programs since ``mark`` that finished after time ``t``."""
-        before = {n for n, _, done in self.events[mark:] if done < t}
-        late = sorted({n for n, _, done in self.events[mark:] if done >= t})
-        return ([n for n in late if n in before],
-                [n for n in late if n not in before])
 
 
 # ------------------------------------------------------------------ kernels

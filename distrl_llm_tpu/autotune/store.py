@@ -3,8 +3,8 @@
 One JSON file maps ``plan_key`` → {plan, measurements, note}. Design rules:
 
 * **Never crash a run.** A missing, corrupt, truncated, or
-  schema-incompatible file loads as EMPTY (with a warning and an
-  ``autotune/db_reset`` counter) — the caller falls back to the static
+  schema-incompatible file loads as EMPTY (with a warning) — the caller
+  falls back to the static
   defaults exactly as if nothing had ever been tuned, and the next
   ``tools/autotune.py`` run rewrites the file. Pinned by
   tests/test_autotune.py.
@@ -25,16 +25,11 @@ import logging
 import os
 import tempfile
 
-from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.autotune.plan import ExecutionPlan
 
 log = logging.getLogger(__name__)
 
 SCHEMA_VERSION = 1
-
-# corrupt/missing-DB fallback counter (one owner; pinned by the autotune
-# smoke as the "never crash a run" evidence)
-AUTOTUNE_DB_RESET = "autotune/db_reset"
 
 DB_ENV = "DISTRL_PLAN_DB"
 ENABLE_ENV = "DISTRL_AUTOTUNE"
@@ -77,7 +72,6 @@ class PlanStore:
                 "re-run tools/autotune.py to repopulate",
                 self.path, type(e).__name__, e,
             )
-            telemetry.counter_add(AUTOTUNE_DB_RESET)
             return self
         if not isinstance(doc, dict) or doc.get("schema_version") != SCHEMA_VERSION:
             log.warning(
@@ -87,7 +81,6 @@ class PlanStore:
                 doc.get("schema_version") if isinstance(doc, dict) else None,
                 SCHEMA_VERSION,
             )
-            telemetry.counter_add(AUTOTUNE_DB_RESET)
             return self
         entries = doc.get("entries")
         if isinstance(entries, dict):
@@ -111,7 +104,6 @@ class PlanStore:
                 "plan DB entry %s is invalid (%s) — ignoring it; re-run "
                 "tools/autotune.py to repopulate", key, e,
             )
-            telemetry.counter_add(AUTOTUNE_DB_RESET)
             return None
 
     def put(self, key: str, plan: ExecutionPlan,

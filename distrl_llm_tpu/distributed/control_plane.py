@@ -745,7 +745,6 @@ class DriverClient:
             ok = False
             rid = self._next_id()
             try:
-                t0 = time.perf_counter()
                 conn.send(MSG_PING, rid)
                 frame = conn.recv(timeout_ms)
                 ok = (
@@ -753,11 +752,6 @@ class DriverClient:
                     and frame[0] == MSG_PONG
                     and frame[1] == rid
                 )
-                if ok:
-                    telemetry.hist_observe(
-                        resilience.CP_RPC_PING_MS,
-                        (time.perf_counter() - t0) * 1e3,
-                    )
             except WorkerDeadError:
                 ok = False
             if ok:

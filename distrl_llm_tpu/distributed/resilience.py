@@ -69,9 +69,8 @@ CP_WEIGHT_PUSHES = "cp/weight_pushes"          # counter: per-worker weight push
 CP_WEIGHT_FULL_SYNCS = "cp/weight_full_syncs"  # counter: full-tensor (non-delta) sends
 CP_WEIGHT_REREQUESTS = "cp/weight_rerequests"  # counter: unknown-version re-pushes
 CP_WEIGHT_BROADCAST_MS = "cp/weight_broadcast_ms"  # hist: push → last worker ack
-# ---- RPC latency histograms (control_plane.py) ----
+# ---- RPC latency histogram (control_plane.py) ----
 CP_RPC_DISPATCH_MS = "cp/rpc_dispatch_ms"  # hist: dispatch → result frame
-CP_RPC_PING_MS = "cp/rpc_ping_ms"          # hist: health-check round trip
 
 FAULT_SCHEDULE_ENV = "DISTRL_FAULT_SCHEDULE"
 

@@ -89,12 +89,14 @@ class _DecodeState(NamedTuple):
 def _prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
              max_total: int, lora_scale: float, cache_dtype, attn_impl: str):
     b, p = prompt_ids.shape
-    cache = (
-        init_kv_cache_int8(cfg, b, max_total)
-        if cache_dtype == "int8"
-        else init_kv_cache(cfg, b, max_total, dtype=cache_dtype)
-    )
-    key_mask = jnp.pad(prompt_mask, ((0, 0), (0, max_total - p)))
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        cache = (
+            init_kv_cache_int8(cfg, b, max_total)
+            if cache_dtype == "int8"
+            else init_kv_cache(cfg, b, max_total, dtype=cache_dtype)
+        )
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        key_mask = jnp.pad(prompt_mask, ((0, 0), (0, max_total - p)))
     last_logits, cache = forward(
         params, cfg, prompt_ids,
         attention_mask=key_mask, lora=lora, lora_scale=lora_scale,
@@ -108,22 +110,24 @@ def _decode_init(cache, key_mask, first_logits, row_alive,
                  *, n: int, max_steps: int, pad_id: int):
     """Expand prefill state to candidate rows: row b*n + j is candidate j of
     prompt b."""
-    cache = jax.tree_util.tree_map(lambda c: jnp.repeat(c, n, axis=0), cache)
-    key_mask = jnp.repeat(key_mask, n, axis=0)
-    logits = jnp.repeat(first_logits, n, axis=0)
-    bn = logits.shape[0]
-    return _DecodeState(
-        step=jnp.zeros((), jnp.int32),
-        out=jnp.full((bn, max_steps), pad_id, jnp.int32),
-        logps=jnp.zeros((bn, max_steps), jnp.float32),
-        lengths=jnp.zeros((bn,), jnp.int32),
-        # rows with an empty prompt are batch padding — born done, so they
-        # never gate the early-exit or sample from their NaN logits
-        done=jnp.repeat(~row_alive, n, axis=0),
-        key_mask=key_mask,
-        logits=logits,
-        cache=cache,
-    )
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        cache = jax.tree_util.tree_map(lambda c: jnp.repeat(c, n, axis=0), cache)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        key_mask = jnp.repeat(key_mask, n, axis=0)
+        logits = jnp.repeat(first_logits, n, axis=0)
+        bn = logits.shape[0]
+        return _DecodeState(
+            step=jnp.zeros((), jnp.int32),
+            out=jnp.full((bn, max_steps), pad_id, jnp.int32),
+            logps=jnp.zeros((bn, max_steps), jnp.float32),
+            lengths=jnp.zeros((bn,), jnp.int32),
+            # rows with an empty prompt are batch padding — born done, so they
+            # never gate the early-exit or sample from their NaN logits
+            done=jnp.repeat(~row_alive, n, axis=0),
+            key_mask=key_mask,
+            logits=logits,
+            cache=cache,
+        )
 
 
 def _decode_step(params, lora, state: _DecodeState, rng,
@@ -149,28 +153,33 @@ def _decode_step(params, lora, state: _DecodeState, rng,
     # outputs bit-identical either way. Done rows' logprobs are zeroed
     # below, so the pre-pad-substitution logprob is observably identical
     # to the old post-substitution token_logprob.
+    with jax.named_scope(telemetry.ENGINE_SAMPLE):
+        step_rng = jax.random.fold_in(rng, s.step)
+    # outside every scope: the fused sampler's call must keep its name
+    # (ops/sampling.py); the multi-pass path names its own work
     tok, logp_s = sample_with_logprob(
-        jax.random.fold_in(rng, s.step), s.logits, temperature, top_p,
+        step_rng, s.logits, temperature, top_p,
         top_p_impl=top_p_impl, capture_logprob=capture_logprobs,
     )
-    tok = jnp.where(s.done, pad_id, tok)
-    out = jax.lax.dynamic_update_slice(s.out, tok[:, None], (0, s.step))
-    if capture_logprobs:  # per-step vocab logsumexp — only when requested
-        logp = jnp.where(s.done, 0.0, logp_s)
-        logps = jax.lax.dynamic_update_slice(
-            s.logps, logp[:, None], (0, s.step)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        tok = jnp.where(s.done, pad_id, tok)
+        out = jax.lax.dynamic_update_slice(s.out, tok[:, None], (0, s.step))
+        if capture_logprobs:  # per-step vocab logsumexp — only when requested
+            logp = jnp.where(s.done, 0.0, logp_s)
+            logps = jax.lax.dynamic_update_slice(
+                s.logps, logp[:, None], (0, s.step)
+            )
+        else:
+            logps = s.logps
+        lengths = s.lengths + (~s.done).astype(jnp.int32)
+        hit_eos = jnp.isin(tok, eos_ids)
+        # the just-sampled token occupies position prompt_len + step for rows
+        # that were still alive; they attend to it on the next forward
+        key_mask = jax.lax.dynamic_update_slice(
+            s.key_mask, (~s.done).astype(s.key_mask.dtype)[:, None],
+            (0, prompt_len + s.step),
         )
-    else:
-        logps = s.logps
-    lengths = s.lengths + (~s.done).astype(jnp.int32)
-    hit_eos = jnp.isin(tok, eos_ids)
-    # the just-sampled token occupies position prompt_len + step for rows
-    # that were still alive; they attend to it on the next forward
-    key_mask = jax.lax.dynamic_update_slice(
-        s.key_mask, (~s.done).astype(s.key_mask.dtype)[:, None],
-        (0, prompt_len + s.step),
-    )
-    done = s.done | hit_eos
+        done = s.done | hit_eos
     next_logits, cache = forward(
         params, cfg, tok[:, None],
         attention_mask=key_mask, lora=lora, lora_scale=lora_scale,
@@ -178,10 +187,11 @@ def _decode_step(params, lora, state: _DecodeState, rng,
         attn_impl=attn_impl,
         cache_read_formulation=cache_read_formulation,
     )
-    return _DecodeState(
-        step=s.step + 1, out=out, logps=logps, lengths=lengths, done=done,
-        key_mask=key_mask, logits=next_logits[:, 0], cache=cache,
-    )
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        return _DecodeState(
+            step=s.step + 1, out=out, logps=logps, lengths=lengths, done=done,
+            key_mask=key_mask, logits=next_logits[:, 0], cache=cache,
+        )
 
 
 def _decode_chunk(params, lora, state: _DecodeState, rng,
@@ -591,8 +601,10 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int):
                 # delayed read of an ASYNC-copied snapshot: a newer copy is
                 # already in flight, so this waits on a transfer that
                 # finished ~check steps ago, never on the current step
-                # graftcheck: disable=GC301 -- reads a finished async copy >=1 check-intervals old
-                if bool(np.asarray(snapshots.popleft()).all()):
+                with telemetry.span(telemetry.ENGINE_SNAPSHOT_WAIT):
+                    # graftcheck: disable=GC301 -- reads a finished async copy >=1 check-intervals old
+                    all_done = bool(np.asarray(snapshots.popleft()).all())
+                if all_done:
                     stop = True
                     break
             if stop:
@@ -1019,21 +1031,22 @@ class GenerationEngine(LoraMailbox):
         if p != self.max_prompt_tokens:
             raise ValueError(f"prompts must be padded to {self.max_prompt_tokens}, got {p}")
         max_steps = min(sampling.max_tokens, self.max_new_tokens)
-        # an in-flight swap from an earlier wave of THIS round also covers
-        # this wave's prefill (its rows haven't sampled yet)
-        lora = self._round_entry_lora(lora)
+        with telemetry.span(telemetry.ENGINE_SETUP):
+            # an in-flight swap from an earlier wave of THIS round also covers
+            # this wave's prefill (its rows haven't sampled yet)
+            lora = self._round_entry_lora(lora)
 
-        # bucket selection: smallest bucket holding the longest real prompt;
-        # prompts are left-padded, so the bucket keeps the trailing columns
-        bucket = self.bucket_for(prompt_mask)
-        if bucket < p:
-            prompt_ids = prompt_ids[:, p - bucket:]
-            prompt_mask = prompt_mask[:, p - bucket:]
-        prefill_fn, decode_step_fn = self._fns_for_bucket(bucket)
+            # bucket selection: smallest bucket holding the longest real prompt;
+            # prompts are left-padded, so the bucket keeps the trailing columns
+            bucket = self.bucket_for(prompt_mask)
+            if bucket < p:
+                prompt_ids = prompt_ids[:, p - bucket:]
+                prompt_mask = prompt_mask[:, p - bucket:]
+            prefill_fn, decode_step_fn = self._fns_for_bucket(bucket)
 
-        prefill_tokens = int(np.asarray(prompt_mask).sum())
+            prefill_tokens = int(np.asarray(prompt_mask).sum())
         t0 = time.perf_counter()
-        with telemetry.span("engine/prefill", rows=b, bucket=bucket,
+        with telemetry.span(telemetry.ENGINE_PREFILL, rows=b, bucket=bucket,
                             tokens=prefill_tokens):
             cache, key_mask, last_logits = prefill_fn(
                 params, lora, jnp.asarray(prompt_ids), jnp.asarray(prompt_mask)
@@ -1063,7 +1076,7 @@ class GenerationEngine(LoraMailbox):
         # explicit enter/exit: the span must cover BOTH dispatch branches
         # and the final device→host readback that syncs the decode
         t1 = time.perf_counter()
-        dec_span = telemetry.span("engine/decode", rows=b * sampling.n,
+        dec_span = telemetry.span(telemetry.ENGINE_DECODE, rows=b * sampling.n,
                                   bucket=bucket)
         dec_span.__enter__()
 
@@ -1120,13 +1133,14 @@ class GenerationEngine(LoraMailbox):
                 )
 
             state = run_decode_loop(step, state, max_steps, self.decode_chunk)
-        out = np.asarray(state.out).reshape(b, sampling.n, max_steps)
-        lengths = np.asarray(state.lengths).reshape(b, sampling.n)
-        logps = (
-            np.asarray(state.logps).reshape(b, sampling.n, max_steps)
-            if self.capture_logprobs else None
-        )
-        gen_tokens = int(lengths.sum())
+        with telemetry.span(telemetry.ENGINE_READBACK):
+            out = np.asarray(state.out).reshape(b, sampling.n, max_steps)
+            lengths = np.asarray(state.lengths).reshape(b, sampling.n)
+            logps = (
+                np.asarray(state.logps).reshape(b, sampling.n, max_steps)
+                if self.capture_logprobs else None
+            )
+            gen_tokens = int(lengths.sum())
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
         self.last_round_stats = accumulate_round_stats(

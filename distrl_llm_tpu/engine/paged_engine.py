@@ -167,10 +167,11 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
     """Pack prompts, run one forward over B rows, return per-prompt page
     tiles [K, B, prompt_pages, ps, hd] per layer + sampling logits."""
     b, p = prompt_ids.shape
-    packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
-    pad_to = prompt_pages * page_size
-    packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
-    packed_mask = jnp.pad(packed_mask, ((0, 0), (0, pad_to - p)))
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
+        pad_to = prompt_pages * page_size
+        packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
+        packed_mask = jnp.pad(packed_mask, ((0, 0), (0, pad_to - p)))
 
     shape = (cfg.num_kv_heads, b * prompt_pages, page_size, cfg.head_dim)
 
@@ -183,14 +184,15 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
             return init_quantized_pages(shape)
         return jnp.zeros(shape, cache_dtype)
 
-    cache = {
-        "k": tuple(make_pages() for _ in range(cfg.num_layers)),
-        "v": tuple(make_pages() for _ in range(cfg.num_layers)),
-        "lengths": real_len,
-        "page_indices": jnp.asarray(
-            make_page_table(b, pad_to, page_size)
-        ),
-    }
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        cache = {
+            "k": tuple(make_pages() for _ in range(cfg.num_layers)),
+            "v": tuple(make_pages() for _ in range(cfg.num_layers)),
+            "lengths": real_len,
+            "page_indices": jnp.asarray(
+                make_page_table(b, pad_to, page_size)
+            ),
+        }
     positions = jnp.broadcast_to(
         jnp.arange(pad_to, dtype=jnp.int32)[None, :], (b, pad_to)
     )
@@ -350,20 +352,26 @@ def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices
     # fused sample+logprob when enabled (ops/sampling.py); done rows'
     # logprobs are zeroed below, so pre-substitution logprobs are
     # observably identical to the old post-substitution token_logprob
+    with jax.named_scope(telemetry.ENGINE_SAMPLE):
+        step_rng = jax.random.fold_in(rng, s.step)
+    # outside every scope: the fused sampler's call must keep its name
+    # (ops/sampling.py); the multi-pass path names its own work
     tok, logp_s = sample_with_logprob(
-        jax.random.fold_in(rng, s.step), s.logits, temperature, top_p,
+        step_rng, s.logits, temperature, top_p,
         top_p_impl=top_p_impl, capture_logprob=capture_logprobs,
     )
-    tok = jnp.where(s.done, pad_id, tok)
-    out = jax.lax.dynamic_update_slice(s.out, tok[:, None], (0, s.step))
-    if capture_logprobs:
-        logp = jnp.where(s.done, 0.0, logp_s)
-        logps = jax.lax.dynamic_update_slice(s.logps, logp[:, None], (0, s.step))
-    else:
-        logps = s.logps
-    gen_lengths = s.gen_lengths + (~s.done).astype(jnp.int32)
-    hit_eos = jnp.isin(tok, eos_ids)
-    done = s.done | hit_eos
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        tok = jnp.where(s.done, pad_id, tok)
+        out = jax.lax.dynamic_update_slice(s.out, tok[:, None], (0, s.step))
+        if capture_logprobs:
+            logp = jnp.where(s.done, 0.0, logp_s)
+            logps = jax.lax.dynamic_update_slice(
+                s.logps, logp[:, None], (0, s.step))
+        else:
+            logps = s.logps
+        gen_lengths = s.gen_lengths + (~s.done).astype(jnp.int32)
+        hit_eos = jnp.isin(tok, eos_ids)
+        done = s.done | hit_eos
 
     cache = {
         "k": s.k_pages, "v": s.v_pages,
@@ -377,9 +385,11 @@ def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices
         kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
         pages_per_block=pages_per_block,
     )
-    seq_lengths = s.seq_lengths + (~s.done).astype(jnp.int32)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        seq_lengths = s.seq_lengths + (~s.done).astype(jnp.int32)
+        step = s.step + 1
     return _PagedDecodeState(
-        step=s.step + 1, out=out, logps=logps, gen_lengths=gen_lengths,
+        step=step, out=out, logps=logps, gen_lengths=gen_lengths,
         done=done, logits=next_logits[:, 0], seq_lengths=seq_lengths,
         k_pages=cache["k"], v_pages=cache["v"],
     )
@@ -486,6 +496,7 @@ def _admit_tables(state, new_cand, admit_mask, real_len, dst_partial,
     return cand, live_new, prompt_of, recopy
 
 
+@jax.named_scope(telemetry.ENGINE_ADMIT)
 def _refill_admit(state: _RefillState, new_cand, admit_mask, last_logits,
                   real_len, dst_partial, src_partial=None, copy_mask=None,
                   *, n: int, b: int, prompt_pages: int, page_size: int):
@@ -796,21 +807,26 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
     # fused sample+logprob when enabled (ops/sampling.py); dead slots'
     # writes are dropped via the out-of-range sentinel either way, so the
     # pre-substitution logprob is observably identical
+    with jax.named_scope(telemetry.ENGINE_SAMPLE):
+        step_rng = jax.random.fold_in(rng, s.step)
+    # outside every scope: the fused sampler's call must keep its name
+    # (ops/sampling.py); the multi-pass path names its own work
     tok, logp = sample_with_logprob(
-        jax.random.fold_in(rng, s.step), s.logits, temperature, top_p,
+        step_rng, s.logits, temperature, top_p,
         top_p_impl=top_p_impl, capture_logprob=capture_logprobs,
     )
-    tok = jnp.where(s.done, pad_id, tok)
-    row = jnp.where(alive, s.cand, total)  # `total` is out of range → dropped
-    out = s.out.at[row, s.gen_lengths].set(tok, mode="drop")
-    if capture_logprobs:
-        logps_buf = s.logps_buf.at[row, s.gen_lengths].set(logp, mode="drop")
-    else:
-        logps_buf = s.logps_buf
-    gen_lengths = s.gen_lengths + alive.astype(jnp.int32)
-    lengths_buf = s.lengths_buf.at[row].set(gen_lengths, mode="drop")
-    hit_eos = jnp.isin(tok, eos_ids) & alive
-    done = s.done | hit_eos | (gen_lengths >= max_steps)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        tok = jnp.where(s.done, pad_id, tok)
+        row = jnp.where(alive, s.cand, total)  # `total` is out of range → dropped
+        out = s.out.at[row, s.gen_lengths].set(tok, mode="drop")
+        if capture_logprobs:
+            logps_buf = s.logps_buf.at[row, s.gen_lengths].set(logp, mode="drop")
+        else:
+            logps_buf = s.logps_buf
+        gen_lengths = s.gen_lengths + alive.astype(jnp.int32)
+        lengths_buf = s.lengths_buf.at[row].set(gen_lengths, mode="drop")
+        hit_eos = jnp.isin(tok, eos_ids) & alive
+        done = s.done | hit_eos | (gen_lengths >= max_steps)
 
     cache = {
         "k": s.k_pages, "v": s.v_pages,
@@ -824,10 +840,13 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
         kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
         pages_per_block=pages_per_block,
     )
-    seq_lengths = s.seq_lengths + alive.astype(jnp.int32)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        seq_lengths = s.seq_lengths + alive.astype(jnp.int32)
+        step = s.step + 1
+        alive_steps = s.alive_steps + alive.sum().astype(jnp.int32)
     return _RefillState(
-        step=s.step + 1,
-        alive_steps=s.alive_steps + alive.sum().astype(jnp.int32),
+        step=step,
+        alive_steps=alive_steps,
         out=out, logps_buf=logps_buf,
         lengths_buf=lengths_buf, cand=s.cand,
         done=done, logits=next_logits[:, 0], seq_lengths=seq_lengths,
@@ -877,7 +896,8 @@ def _refill_decode_chunk(params, lora, state: _RefillState, rng,
         )
 
     state = scan_steps_guarded(run, state, chunk)
-    return state, jnp.copy(state.done), jnp.copy(state.seq_lengths)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        return state, jnp.copy(state.done), jnp.copy(state.seq_lengths)
 
 
 def _spec_decode_chunk(params, lora, state, rng, drafter_lora=None,
@@ -966,60 +986,64 @@ def _spec_admit(state, new_cand, admit_mask, last_logits, real_len,
 
     s = state
     total = b * n
-    cand, live_new, prompt_of, recopy = _admit_tables(
-        s, new_cand, admit_mask, real_len, dst_partial, n=n, b=b,
-        prompt_pages=prompt_pages, page_size=page_size,
-        src_partial=src_partial, copy_mask=copy_mask,
-    )
+    with jax.named_scope(telemetry.ENGINE_ADMIT):
+        cand, live_new, prompt_of, recopy = _admit_tables(
+            s, new_cand, admit_mask, real_len, dst_partial, n=n, b=b,
+            prompt_pages=prompt_pages, page_size=page_size,
+            src_partial=src_partial, copy_mask=copy_mask,
+        )
+        first_logits = last_logits[prompt_of]
 
     # first token per admitted slot, from the prompt's last-position logits
     # (fused sample+logprob when enabled — ops/sampling.py; the rejection-
-    # sampling accept path in _spec_step is untouched)
+    # sampling accept path in _spec_step is untouched). Outside the scope:
+    # the fused sampler's call must keep its name
     tok0, logp0 = sample_with_logprob(
-        rng, last_logits[prompt_of], temperature, top_p,
+        rng, first_logits, temperature, top_p,
         top_p_impl=top_p_impl, capture_logprob=capture_logprobs,
     )
-    hit_eos = jnp.isin(tok0, eos_ids)
-    done = jnp.where(admit_mask, ~live_new | hit_eos, s.done)
+    with jax.named_scope(telemetry.ENGINE_ADMIT):
+        hit_eos = jnp.isin(tok0, eos_ids)
+        done = jnp.where(admit_mask, ~live_new | hit_eos, s.done)
 
-    # n-gram buffer: packed prompt then tok0 at position real_len
-    w = s.seq_buf.shape[1]
-    p_len = packed_ids.shape[1]
-    seq_rows = jnp.pad(packed_ids[prompt_of], ((0, 0), (0, w - p_len)))
-    rl = real_len[prompt_of]
-    seq_rows = jnp.where(
-        jnp.arange(w)[None, :] == rl[:, None], tok0[:, None], seq_rows
-    )
-    seq_buf = jnp.where(admit_mask[:, None], seq_rows, s.seq_buf)
+        # n-gram buffer: packed prompt then tok0 at position real_len
+        w = s.seq_buf.shape[1]
+        p_len = packed_ids.shape[1]
+        seq_rows = jnp.pad(packed_ids[prompt_of], ((0, 0), (0, w - p_len)))
+        rl = real_len[prompt_of]
+        seq_rows = jnp.where(
+            jnp.arange(w)[None, :] == rl[:, None], tok0[:, None], seq_rows
+        )
+        seq_buf = jnp.where(admit_mask[:, None], seq_rows, s.seq_buf)
 
-    # tok0 is generated output: out[cand, 0] and per-candidate length 1
-    row = jnp.where(admit_mask & live_new, cand, total)
-    out = s.out.at[row, 0].set(tok0, mode="drop")
-    if capture_logprobs:
-        logps_buf = s.logps_buf.at[row, 0].set(logp0, mode="drop")
-    else:
-        logps_buf = s.logps_buf
-    lengths_buf = s.lengths_buf.at[row].set(1, mode="drop")
+        # tok0 is generated output: out[cand, 0] and per-candidate length 1
+        row = jnp.where(admit_mask & live_new, cand, total)
+        out = s.out.at[row, 0].set(tok0, mode="drop")
+        if capture_logprobs:
+            logps_buf = s.logps_buf.at[row, 0].set(logp0, mode="drop")
+        else:
+            logps_buf = s.logps_buf
+        lengths_buf = s.lengths_buf.at[row].set(1, mode="drop")
 
-    return SpecRefillState(
-        step=s.step,
-        alive_steps=s.alive_steps,
-        out=out,
-        logps_buf=logps_buf,
-        lengths_buf=lengths_buf,
-        cand=cand,
-        done=done,
-        last_tok=jnp.where(admit_mask, tok0, s.last_tok),
-        seq_buf=seq_buf,
-        seq_lengths=jnp.where(admit_mask, real_len[prompt_of], s.seq_lengths),
-        gen_lengths=jnp.where(admit_mask, 1, s.gen_lengths),
-        page_indices=s.page_indices,
-        k_pages=tuple(recopy(x) for x in s.k_pages),
-        v_pages=tuple(recopy(x) for x in s.v_pages),
-        emit_hist=s.emit_hist,
-        draft_total=s.draft_total,
-        accept_total=s.accept_total,
-    )
+        return SpecRefillState(
+            step=s.step,
+            alive_steps=s.alive_steps,
+            out=out,
+            logps_buf=logps_buf,
+            lengths_buf=lengths_buf,
+            cand=cand,
+            done=done,
+            last_tok=jnp.where(admit_mask, tok0, s.last_tok),
+            seq_buf=seq_buf,
+            seq_lengths=jnp.where(admit_mask, real_len[prompt_of], s.seq_lengths),
+            gen_lengths=jnp.where(admit_mask, 1, s.gen_lengths),
+            page_indices=s.page_indices,
+            k_pages=tuple(recopy(x) for x in s.k_pages),
+            v_pages=tuple(recopy(x) for x in s.v_pages),
+            emit_hist=s.emit_hist,
+            draft_total=s.draft_total,
+            accept_total=s.accept_total,
+        )
 
 
 def _self_draft(params, drafter_lora, state, step_rng, *, cfg: ModelConfig,
@@ -1124,76 +1148,78 @@ def _spec_step(params, lora, state, rng, drafter_lora=None, *,
         pages_per_block=pages_per_block, paged_verify=True,
         paged_verify_impl=spec_verify,
     )  # [R, d+1, V]
-    probs = sampling_probs(logits, temperature, top_p, top_p_impl=top_p_impl)
-    emit, n_emit, n_accept = spec_accept(step_rng, probs, draft, draft_probs)
+    with jax.named_scope(telemetry.ENGINE_SAMPLE):
+        probs = sampling_probs(logits, temperature, top_p, top_p_impl=top_p_impl)
+        emit, n_emit, n_accept = spec_accept(step_rng, probs, draft, draft_probs)
 
-    # EOS truncation: emission stops AT the first EOS among emitted tokens
-    pos = jnp.arange(d + 1)[None, :]
-    is_eos = jnp.isin(emit, eos_ids) & (pos < n_emit[:, None])
-    any_eos = is_eos.any(axis=1)
-    first_eos = jnp.argmax(is_eos, axis=1)
-    n_emit = jnp.where(any_eos, first_eos + 1, n_emit)
-    # cap at the per-candidate token budget
-    room = jnp.maximum(max_steps - s.gen_lengths, 0)
-    n_emit = jnp.minimum(n_emit, room)
-    n_emit = jnp.where(alive, n_emit, 0)
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        # EOS truncation: emission stops AT the first EOS among emitted tokens
+        pos = jnp.arange(d + 1)[None, :]
+        is_eos = jnp.isin(emit, eos_ids) & (pos < n_emit[:, None])
+        any_eos = is_eos.any(axis=1)
+        first_eos = jnp.argmax(is_eos, axis=1)
+        n_emit = jnp.where(any_eos, first_eos + 1, n_emit)
+        # cap at the per-candidate token budget
+        room = jnp.maximum(max_steps - s.gen_lengths, 0)
+        n_emit = jnp.minimum(n_emit, room)
+        n_emit = jnp.where(alive, n_emit, 0)
 
-    gen_lengths = s.gen_lengths + n_emit
-    done = s.done | (alive & (any_eos | (gen_lengths >= max_steps)))
+        gen_lengths = s.gen_lengths + n_emit
+        done = s.done | (alive & (any_eos | (gen_lengths >= max_steps)))
 
-    # scatter the emitted tokens into out / seq_buf (static d+1 writes)
-    out = s.out
-    logps_buf = s.logps_buf
-    seq_buf = s.seq_buf
-    row = jnp.where(alive, s.cand, total)  # `total` → dropped
-    for i in range(d + 1):
-        live_i = i < n_emit
-        row_i = jnp.where(live_i, row, total)
-        out = out.at[row_i, s.gen_lengths + i].set(emit[:, i], mode="drop")
-        if capture_logprobs:
-            # behavior logprob on the RAW basis (same convention as every
-            # other decode path): the verify logits at slot i judge/sample
-            # emit[:, i]
-            logp_i = token_logprob(logits[:, i], emit[:, i])
-            logps_buf = logps_buf.at[row_i, s.gen_lengths + i].set(
-                logp_i, mode="drop"
-            )
-        slot_i = jnp.where(live_i, jnp.arange(row.shape[0]), row.shape[0])
-        seq_buf = seq_buf.at[slot_i, buf_len + i].set(emit[:, i], mode="drop")
-    lengths_buf = s.lengths_buf.at[row].set(gen_lengths, mode="drop")
+        # scatter the emitted tokens into out / seq_buf (static d+1 writes)
+        out = s.out
+        logps_buf = s.logps_buf
+        seq_buf = s.seq_buf
+        row = jnp.where(alive, s.cand, total)  # `total` → dropped
+        for i in range(d + 1):
+            live_i = i < n_emit
+            row_i = jnp.where(live_i, row, total)
+            out = out.at[row_i, s.gen_lengths + i].set(emit[:, i], mode="drop")
+            if capture_logprobs:
+                # behavior logprob on the RAW basis (same convention as every
+                # other decode path): the verify logits at slot i judge/sample
+                # emit[:, i]
+                logp_i = token_logprob(logits[:, i], emit[:, i])
+                logps_buf = logps_buf.at[row_i, s.gen_lengths + i].set(
+                    logp_i, mode="drop"
+                )
+            slot_i = jnp.where(live_i, jnp.arange(row.shape[0]), row.shape[0])
+            seq_buf = seq_buf.at[slot_i, buf_len + i].set(emit[:, i], mode="drop")
+        lengths_buf = s.lengths_buf.at[row].set(gen_lengths, mode="drop")
 
-    last_tok = jnp.where(
-        alive,
-        jnp.take_along_axis(
-            emit, jnp.clip(n_emit - 1, 0, d)[:, None], axis=1
-        )[:, 0],
-        s.last_tok,
-    )
-    seq_lengths = s.seq_lengths + n_emit
-    # acceptance accounting: one [d_max+2]-bucket histogram increment per
-    # step (device-side — the host reads it at snapshot boundaries / round
-    # end, never per step). hist_width is the CONFIGURED max draft length's
-    # width, so adaptive shrink (draft_len < max) changes no shapes.
-    hw = hist_width or (d + 2)
-    hist_inc = (
-        (n_emit[:, None] == jnp.arange(hw)[None, :]) & alive[:, None]
-    ).astype(jnp.int32).sum(axis=0)
-    return SpecRefillState(
-        step=s.step + 1,
-        alive_steps=s.alive_steps + alive.sum().astype(jnp.int32),
-        out=out, logps_buf=logps_buf,
-        lengths_buf=lengths_buf, cand=s.cand,
-        done=done, last_tok=last_tok, seq_buf=seq_buf,
-        seq_lengths=seq_lengths, gen_lengths=gen_lengths,
-        page_indices=s.page_indices,
-        k_pages=cache["k"], v_pages=cache["v"],
-        emit_hist=s.emit_hist + hist_inc,
-        draft_total=s.draft_total + d * alive.sum().astype(jnp.int32),
-        accept_total=(
-            s.accept_total
-            + jnp.where(alive, n_accept, 0).sum().astype(jnp.int32)
-        ),
-    )
+        last_tok = jnp.where(
+            alive,
+            jnp.take_along_axis(
+                emit, jnp.clip(n_emit - 1, 0, d)[:, None], axis=1
+            )[:, 0],
+            s.last_tok,
+        )
+        seq_lengths = s.seq_lengths + n_emit
+        # acceptance accounting: one [d_max+2]-bucket histogram increment per
+        # step (device-side — the host reads it at snapshot boundaries / round
+        # end, never per step). hist_width is the CONFIGURED max draft length's
+        # width, so adaptive shrink (draft_len < max) changes no shapes.
+        hw = hist_width or (d + 2)
+        hist_inc = (
+            (n_emit[:, None] == jnp.arange(hw)[None, :]) & alive[:, None]
+        ).astype(jnp.int32).sum(axis=0)
+        return SpecRefillState(
+            step=s.step + 1,
+            alive_steps=s.alive_steps + alive.sum().astype(jnp.int32),
+            out=out, logps_buf=logps_buf,
+            lengths_buf=lengths_buf, cand=s.cand,
+            done=done, last_tok=last_tok, seq_buf=seq_buf,
+            seq_lengths=seq_lengths, gen_lengths=gen_lengths,
+            page_indices=s.page_indices,
+            k_pages=cache["k"], v_pages=cache["v"],
+            emit_hist=s.emit_hist + hist_inc,
+            draft_total=s.draft_total + d * alive.sum().astype(jnp.int32),
+            accept_total=(
+                s.accept_total
+                + jnp.where(alive, n_accept, 0).sum().astype(jnp.int32)
+            ),
+        )
 
 
 class PagedGenerationEngine(LoraMailbox):
@@ -2161,7 +2187,7 @@ class PagedGenerationEngine(LoraMailbox):
             real_len = jnp.asarray(real_len_h.astype(np.int32))
         else:
             t0 = time.perf_counter()
-            with telemetry.span("engine/prefill", rows=b,
+            with telemetry.span(telemetry.ENGINE_PREFILL, rows=b,
                                 tokens=prefill_tokens):
                 prompt_k, prompt_v, last_logits, real_len = self._prefill(
                     params, lora, jnp.asarray(prompt_ids),
@@ -2170,9 +2196,13 @@ class PagedGenerationEngine(LoraMailbox):
                 jax.block_until_ready(last_logits)
             t_prefill = time.perf_counter() - t0
         t_decode0 = time.perf_counter()
-        dec_span = telemetry.span("engine/refill_decode", slots=r_slots,
+        dec_span = telemetry.span(telemetry.ENGINE_REFILL_DECODE, slots=r_slots,
                                   candidates=total)
         dec_span.__enter__()
+        # entry to the first dispatch: pool construction, prefix
+        # registration, the chunk program, the round's queues
+        setup_span = telemetry.span(telemetry.ENGINE_SETUP)
+        setup_span.__enter__()
 
         temperature = jnp.asarray(sampling.temperature, jnp.float32)
         top_p = jnp.asarray(sampling.top_p, jnp.float32)
@@ -2625,7 +2655,7 @@ class PagedGenerationEngine(LoraMailbox):
             if cache_on:
                 group_hit_tok[g] = 0
             t0 = time.perf_counter()
-            with telemetry.span("engine/prefill", rows=1, tokens=rl):
+            with telemetry.span(telemetry.ENGINE_PREFILL, rows=1, tokens=rl):
                 k_t, v_t, logits_g, _rl = self._prefill(
                     params, lora_cell[0], prompt_ids_j[g:g + 1],
                     prompt_mask_j[g:g + 1],
@@ -2670,7 +2700,7 @@ class PagedGenerationEngine(LoraMailbox):
             group_hit_tok[g] = hit
             suffix = real_toks[g][hit:rl]
             t0 = time.perf_counter()
-            with telemetry.span("engine/prefill", rows=1,
+            with telemetry.span(telemetry.ENGINE_PREFILL, rows=1,
                                 tokens=rl - hit):
                 suf = np.full(self.prompt_pages * ps, self.pad_id,
                               np.int32)
@@ -3145,8 +3175,11 @@ class PagedGenerationEngine(LoraMailbox):
             boundary_admits = 0
             fill_declined = None
 
-        group_decline = admit_groups() if continuous else None
-        state = fill_idle(state, range(r_slots))
+        setup_span.__exit__(None, None, None)
+        with telemetry.span(telemetry.ENGINE_ADMIT) as admit_span:
+            group_decline = admit_groups() if continuous else None
+            state = fill_idle(state, range(r_slots))
+            admit_span.set(groups=groups_prefilled, slots=pool.total_admissions)
         if sl is not None:
             serving_boundary(group_decline, had_idle=True)
 
@@ -3350,10 +3383,13 @@ class PagedGenerationEngine(LoraMailbox):
             # delayed reads of ASYNC-copied snapshots dispatched one host
             # boundary ago — the copy already completed while the last
             # `check` decode steps ran
-            # graftcheck: disable=GC301 -- reads a finished async copy one boundary old
-            done_h = np.asarray(done_snap)
-            # graftcheck: disable=GC301 -- same delayed snapshot as the line above
-            seq_h = np.asarray(seq_snap)
+            # (the one place the host waits on the device: a long span here
+            # is a device-side stall, not host work)
+            with telemetry.span(telemetry.ENGINE_SNAPSHOT_WAIT):
+                # graftcheck: disable=GC301 -- reads a finished async copy one boundary old
+                done_h = np.asarray(done_snap)
+                # graftcheck: disable=GC301 -- same delayed snapshot as the line above
+                seq_h = np.asarray(seq_snap)
             if sl is not None:
                 # first-token detection off the same boundary snapshot: a
                 # slot whose resident length moved past its occupant's
@@ -3412,64 +3448,82 @@ class PagedGenerationEngine(LoraMailbox):
                 host_cand[s_i] = total
             table_dirty = bool(idle)
             if budgeted:
-                # grant pass: extend every occupied slot's pages to cover its
-                # write frontier through the next grant window (spec: the
-                # verify overhang rides in lag_tokens/write_ceiling_extra);
-                # preempt the least-advanced occupant when the pool runs dry
-                idle_set = set(idle)
-                for s_i in range(r_slots):
-                    if host_cand[s_i] >= total or s_i in idle_set:
-                        continue
-                    if snap_epoch[s_i] != epoch[s_i]:
-                        continue  # admitted post-snapshot; admit grant covers
-                    rl = int(real_len_h[int(host_cand[s_i]) // n])
-                    target = min(
-                        int(seq_h[s_i]) + lag_tokens,
-                        rl + max_steps + write_ceiling_extra,
-                    )
-                    while pool.ensure(s_i, target):
-                        occupied = [
-                            v for v in range(r_slots)
-                            if host_cand[v] < total and v != s_i
-                            and snap_epoch[v] == epoch[v]
-                        ]
-                        if occupied and meta is not None:
-                            # class-aware preemption (ISSUE 19): evict the
-                            # highest-rank (lowest-priority) occupant first
-                            # — scavenger before batch before interactive —
-                            # least progress within a class. Non-gateway
-                            # rounds keep the pure least-progress victim
-                            victim = min(
-                                occupied,
-                                key=lambda v: (
-                                    -rank_of(int(host_cand[v]) // n),
-                                    int(seq_h[v])
+                with telemetry.span(telemetry.ENGINE_GRANT):
+                    # grant pass: extend every occupied slot's pages to cover its
+                    # write frontier through the next grant window (spec: the
+                    # verify overhang rides in lag_tokens/write_ceiling_extra);
+                    # preempt the least-advanced occupant when the pool runs dry
+                    idle_set = set(idle)
+                    for s_i in range(r_slots):
+                        if host_cand[s_i] >= total or s_i in idle_set:
+                            continue
+                        if snap_epoch[s_i] != epoch[s_i]:
+                            continue  # admitted post-snapshot; admit grant covers
+                        rl = int(real_len_h[int(host_cand[s_i]) // n])
+                        target = min(
+                            int(seq_h[s_i]) + lag_tokens,
+                            rl + max_steps + write_ceiling_extra,
+                        )
+                        while pool.ensure(s_i, target):
+                            occupied = [
+                                v for v in range(r_slots)
+                                if host_cand[v] < total and v != s_i
+                                and snap_epoch[v] == epoch[v]
+                            ]
+                            if occupied and meta is not None:
+                                # class-aware preemption (ISSUE 19): evict the
+                                # highest-rank (lowest-priority) occupant first
+                                # — scavenger before batch before interactive —
+                                # least progress within a class. Non-gateway
+                                # rounds keep the pure least-progress victim
+                                victim = min(
+                                    occupied,
+                                    key=lambda v: (
+                                        -rank_of(int(host_cand[v]) // n),
+                                        int(seq_h[v])
+                                        - int(real_len_h[int(host_cand[v]) // n]),
+                                    ),
+                                )
+                            elif occupied:
+                                victim = min(
+                                    occupied,
+                                    key=lambda v: int(seq_h[v])
                                     - int(real_len_h[int(host_cand[v]) // n]),
-                                ),
-                            )
-                        elif occupied:
-                            victim = min(
-                                occupied,
-                                key=lambda v: int(seq_h[v])
-                                - int(real_len_h[int(host_cand[v]) // n]),
-                            )
-                        else:
-                            victim = s_i  # nothing else to evict: self-evict
-                        preempt(victim)
-                        if victim == s_i:
-                            break
-                    table_dirty = True
+                                )
+                            else:
+                                victim = s_i  # nothing else to evict: self-evict
+                            with telemetry.span(telemetry.ENGINE_PREEMPT):
+                                preempt(victim)
+                            if victim == s_i:
+                                break
+                        table_dirty = True
             boundary_marks = pool.total_admissions + groups_prefilled
             group_decline = None
+            idle_free = [s for s in idle if host_cand[s] >= total]
+            # one span per admission pass that can admit: a queued group to
+            # prefill, or a pending candidate and a free slot to put it in
+            admit_span = (
+                telemetry.span(telemetry.ENGINE_ADMIT)
+                if (continuous and group_queue) or (pending and idle_free)
+                else None
+            )
+            if admit_span is not None:
+                admit_span.__enter__()
+                groups0, slots0 = groups_prefilled, pool.total_admissions
             if continuous and group_queue:
                 # freed pages (released slots, dropped chains) may now fit
                 # the next queued group's prefill — the backfill that
                 # replaces the fixed episode batch
                 group_decline = admit_groups()
-            idle_free = [s for s in idle if host_cand[s] >= total]
             if pending:
                 state = fill_idle(state, idle_free)
                 table_dirty = True
+            if admit_span is not None:
+                admit_span.set(
+                    groups=groups_prefilled - groups0,
+                    slots=pool.total_admissions - slots0,
+                )
+                admit_span.__exit__(None, None, None)
             if table_dirty:
                 state = state._replace(page_indices=jnp.asarray(pool.table))
             if pool.self_check:
@@ -3530,6 +3584,8 @@ class PagedGenerationEngine(LoraMailbox):
         # final blocking read closes the snapshot lag on the last occupants
         # (mark_finished, not a bare flag write: the serving ledger's
         # finish events and the sharing chain drops stay exactly-once)
+        readback_span = telemetry.span(telemetry.ENGINE_READBACK)
+        readback_span.__enter__()
         done_h = np.asarray(state.done)
         for s_i in np.nonzero(done_h)[0]:
             c = host_cand[s_i]
@@ -3691,6 +3747,7 @@ class PagedGenerationEngine(LoraMailbox):
             if self.capture_logprobs else None
         )
         gen_tokens = int(lengths.sum())
+        readback_span.__exit__(None, None, None)
         if self.spec_draft:
             # acceptance accounting off the device-carried histogram: one
             # read at round end, zero per-step host traffic
@@ -3804,7 +3861,7 @@ class PagedGenerationEngine(LoraMailbox):
 
         prefill_tokens = int(np.asarray(prompt_mask).sum())
         t0 = time.perf_counter()
-        with telemetry.span("engine/prefill", rows=b, tokens=prefill_tokens):
+        with telemetry.span(telemetry.ENGINE_PREFILL, rows=b, tokens=prefill_tokens):
             prompt_k, prompt_v, last_logits, real_len = self._prefill(
                 params, lora, jnp.asarray(prompt_ids), jnp.asarray(prompt_mask)
             )
@@ -3812,7 +3869,7 @@ class PagedGenerationEngine(LoraMailbox):
         t_prefill = time.perf_counter() - t0
         row_alive = jnp.asarray(prompt_mask).sum(axis=-1) > 0
         t1 = time.perf_counter()
-        dec_span = telemetry.span("engine/decode", rows=b * n)
+        dec_span = telemetry.span(telemetry.ENGINE_DECODE, rows=b * n)
         dec_span.__enter__()
         state, page_indices = self._fanout(
             prompt_k, prompt_v, last_logits, real_len, row_alive,
@@ -3881,13 +3938,14 @@ class PagedGenerationEngine(LoraMailbox):
                 )
 
             state = run_decode_loop(step, state, max_steps, self.decode_chunk)
-        out = np.asarray(state.out).reshape(b, n, max_steps)
-        lengths = np.asarray(state.gen_lengths).reshape(b, n)
-        logps = (
-            np.asarray(state.logps).reshape(b, n, max_steps)
-            if self.capture_logprobs else None
-        )
-        gen_tokens = int(lengths.sum())
+        with telemetry.span(telemetry.ENGINE_READBACK):
+            out = np.asarray(state.out).reshape(b, n, max_steps)
+            lengths = np.asarray(state.gen_lengths).reshape(b, n)
+            logps = (
+                np.asarray(state.logps).reshape(b, n, max_steps)
+                if self.capture_logprobs else None
+            )
+            gen_tokens = int(lengths.sum())
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
         decode_s = time.perf_counter() - t1

@@ -35,6 +35,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.models.transformer import forward
 from distrl_llm_tpu.ops.linear import linear
@@ -86,16 +87,27 @@ def answer_logprobs(
     )
     if logit_chunk <= 0 or logit_chunk >= t:
         pred, _ = forward(params, cfg, full_ids, **fwd_kwargs)  # [B, T, V]
-        gathered = jnp.take_along_axis(pred, answer_ids[..., None], axis=-1)[..., 0]
-        lse = jax.nn.logsumexp(pred, axis=-1)
-        if not return_entropy:
-            return gathered - lse
-        entropy = lse - (jax.nn.softmax(pred, axis=-1) * pred).sum(-1)
-        return gathered - lse, entropy
+        with jax.named_scope(telemetry.LEARNER_LOSS_LOGPROB):
+            gathered = jnp.take_along_axis(pred, answer_ids[..., None], axis=-1)[..., 0]
+            lse = jax.nn.logsumexp(pred, axis=-1)
+            if not return_entropy:
+                return gathered - lse
+            entropy = lse - (jax.nn.softmax(pred, axis=-1) * pred).sum(-1)
+            return gathered - lse, entropy
 
     x, _ = forward(params, cfg, full_ids, skip_lm_head=True, **fwd_kwargs)
+    with jax.named_scope(telemetry.LEARNER_LOSS_LOGPROB):
+        return _chunked_logprobs(
+            x, params, cfg, answer_ids, logit_chunk, return_entropy
+        )
+
+
+def _chunked_logprobs(x, params, cfg: ModelConfig, answer_ids, chunk: int,
+                      return_entropy: bool):
+    """The output head and the log-softmax gather per time chunk (module
+    docstring): ``x`` is the final-norm hidden states of the answer region."""
+    t = answer_ids.shape[1]
     b, _, d = x.shape
-    chunk = logit_chunk
     # pad T up to a chunk multiple (padded positions are sliced off below) —
     # falling back to a DIVISOR of T would silently collapse to tiny chunks
     # for awkward lengths (prime T → chunk 1 → T sequential [B,1,V] matmuls)

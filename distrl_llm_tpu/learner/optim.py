@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from distrl_llm_tpu import telemetry
+
 BLOCK = 256
 
 
@@ -78,6 +80,7 @@ jax.tree_util.register_pytree_node(
 )
 
 
+@jax.named_scope(telemetry.LEARNER_OPTIMIZER_CODEC)
 def _quantize(x: jax.Array) -> _Quantized:
     """Signed dynamic code: q = sign·m, m ∈ {0..127} indexing ``_LUT``."""
     flat = x.reshape(-1)
@@ -93,6 +96,7 @@ def _quantize(x: jax.Array) -> _Quantized:
     return _Quantized(q.reshape(-1), scale, size, tuple(x.shape))
 
 
+@jax.named_scope(telemetry.LEARNER_OPTIMIZER_CODEC)
 def _dequantize(z: _Quantized, dtype=jnp.float32) -> jax.Array:
     q = z.q.reshape(-1, BLOCK).astype(jnp.int32)
     mag = jnp.asarray(_LUT)[jnp.abs(q)]

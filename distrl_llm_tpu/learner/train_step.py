@@ -364,10 +364,14 @@ def make_train_step(
             lambda x: x.reshape((num_micro, micro_size) + x.shape[1:]), batch
         )
 
-        grad_fn = jax.value_and_grad(
-            lambda lo, mb, key: loss_fn(lo, base_params, mb=mb, dropout_rng=key),
-            has_aux=True,
-        )
+        def scoped_loss(lo, mb, key):
+            # forward under this name; JAX writes the rest of the path itself:
+            # transpose(jvp(learner/loss)) is the backward pass, and
+            # rematted_computation under it the recomputed forward
+            with jax.named_scope(telemetry.LEARNER_LOSS):
+                return loss_fn(lo, base_params, mb=mb, dropout_rng=key)
+
+        grad_fn = jax.value_and_grad(scoped_loss, has_aux=True)
         # independent dropout masks per microbatch (None → dropout disabled)
         micro_keys = (
             jax.random.split(dropout_rng, num_micro)
@@ -379,14 +383,16 @@ def make_train_step(
             grads_acc, loss_acc, nb_acc = carry
             (loss, aux), grads = grad_fn(lora, mb, key)
             weight, has_real = aux[0], aux[1]
-            grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
-            # dynamics sums ride the scan's ys output (stacked then summed
-            # below) so the carry shape is untouched; None when off — the
-            # exact pre-ISSUE-16 scan
-            ys = aux[2] if emit_dynamics else None
-            return (grads_acc, loss_acc + loss, nb_acc + has_real), ys
+            with jax.named_scope(telemetry.LEARNER_GRAD_ACCUM):
+                grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
+                # dynamics sums ride the scan's ys output (stacked then summed
+                # below) so the carry shape is untouched; None when off — the
+                # exact pre-ISSUE-16 scan
+                ys = aux[2] if emit_dynamics else None
+                return (grads_acc, loss_acc + loss, nb_acc + has_real), ys
 
-        zero_grads = jax.tree_util.tree_map(jnp.zeros_like, lora)
+        with jax.named_scope(telemetry.LEARNER_GRAD_ACCUM):
+            zero_grads = jax.tree_util.tree_map(jnp.zeros_like, lora)
         (grads, loss_sum, num_real_micro), dyn_stacked = jax.lax.scan(
             accumulate, (zero_grads, jnp.zeros([]), jnp.zeros([])),
             (micro, micro_keys),
@@ -394,8 +400,9 @@ def make_train_step(
         # reference scaling: each microbatch contributes grad/num_batches
         # (distributed_actor.py:382); num_batches counts microbatches with real
         # rows, skipped-or-not — padding-only microbatches are excluded.
-        denom = jnp.maximum(num_real_micro, 1.0)
-        grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
+        with jax.named_scope(telemetry.LEARNER_GRAD_ACCUM):
+            denom = jnp.maximum(num_real_micro, 1.0)
+            grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
 
         dynamics = None
         if emit_dynamics:
@@ -405,8 +412,9 @@ def make_train_step(
             # grad norms read the averaged grads the optimizer consumes —
             # the same tree, pure reads, no effect on the update
             dynamics = _derive_dynamics(sums, grads, train_mode=train_mode)
-        updates, opt_state = optimizer.update(grads, opt_state, lora)
-        lora = optax.apply_updates(lora, updates)
+        with jax.named_scope(telemetry.LEARNER_OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, lora)
+            lora = optax.apply_updates(lora, updates)
         if emit_dynamics:
             return lora, opt_state, loss_sum, dynamics
         return lora, opt_state, loss_sum

@@ -26,6 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.ops.attention import attention, attention_cached, causal_padding_mask
 from distrl_llm_tpu.ops.linear import linear, lora_delta
@@ -143,13 +144,55 @@ def _layer(
 ):
     b, s, _ = x.shape
     proj = partial(_proj, lora_dropout=lora_dropout, dropout_rng=dropout_rng)
-    h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
-    q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = proj(h, p, lora, "wk", "bk", lora_scale).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
+        q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = proj(h, p, lora, "wk", "bk", lora_scale).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        att, cache_k, cache_v, cache_k_scale, cache_v_scale = _attend(
+            q, k, v, cache_k, cache_v, cache_k_scale, cache_v_scale,
+            mask=mask, cache_offset=cache_offset, attn_impl=attn_impl,
+            attn_mesh=attn_mesh, key_valid=key_valid,
+            paged_lengths=paged_lengths, page_indices=page_indices,
+            page_size=page_size, paged_impl=paged_impl,
+            pages_per_block=pages_per_block, paged_verify=paged_verify,
+            paged_verify_impl=paged_verify_impl, paged_chunked=paged_chunked,
+            paged_prefix=paged_prefix,
+            cache_read_formulation=cache_read_formulation,
+        )
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        att = att.reshape(b, s, cfg.q_dim)
+        x = x + proj(att, p, lora, "wo", "bo", lora_scale)
 
+    with jax.named_scope(telemetry.MODEL_MLP):
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
+        act = (
+            jax.nn.silu if cfg.hidden_act == "silu"
+            else partial(jax.nn.gelu, approximate=True)  # Gemma gelu_pytorch_tanh
+        )
+        gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
+        up = proj(h, p, lora, "w_up", "b_up", lora_scale)
+        x = x + proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
+    return x, cache_k, cache_v, cache_k_scale, cache_v_scale
+
+
+def _attend(
+    q: jax.Array,  # [B, S, H, hd], rotated
+    k: jax.Array,  # [B, S, K, hd], rotated
+    v: jax.Array,
+    cache_k, cache_v, cache_k_scale, cache_v_scale,
+    *, mask, cache_offset, attn_impl: str, attn_mesh, key_valid,
+    paged_lengths, page_indices, page_size: int, paged_impl: str,
+    pages_per_block: int, paged_verify: bool, paged_verify_impl: str,
+    paged_chunked: bool, paged_prefix: bool, cache_read_formulation: str,
+):
+    """The layer's KV write (under ``engine/kv_write``) and its attention
+    call, whichever implementation: paged, dense cache, ring, ulysses or plain.
+    Returns ``(att, cache_k, cache_v, cache_k_scale, cache_v_scale)``."""
+    b, s = q.shape[:2]
     if cache_k is not None and page_indices is not None:
         # paged cache (ops/paged.py — the N1 ragged decode path): cache_k/v
         # are page arrays [K, total_pages, ps, hd]; sequences are PACKED, so
@@ -160,10 +203,11 @@ def _layer(
         )
 
         if s == 1:
-            cache_k = write_token_to_pages(
-                cache_k, k[:, 0], paged_lengths, page_indices, page_size)
-            cache_v = write_token_to_pages(
-                cache_v, v[:, 0], paged_lengths, page_indices, page_size)
+            with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+                cache_k = write_token_to_pages(
+                    cache_k, k[:, 0], paged_lengths, page_indices, page_size)
+                cache_v = write_token_to_pages(
+                    cache_v, v[:, 0], paged_lengths, page_indices, page_size)
             att = paged_attention_op(
                 q[:, 0], cache_k, cache_v, paged_lengths + 1, page_indices,
                 impl=paged_impl, pages_per_block=pages_per_block,
@@ -181,12 +225,13 @@ def _layer(
             q_valid = key_valid[:, :s] if key_valid is not None else (
                 jnp.ones((b, s), jnp.int32)
             )
-            cache_k = write_tokens_to_pages(
-                cache_k, k, paged_lengths, page_indices, page_size,
-                valid=q_valid > 0)
-            cache_v = write_tokens_to_pages(
-                cache_v, v, paged_lengths, page_indices, page_size,
-                valid=q_valid > 0)
+            with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+                cache_k = write_tokens_to_pages(
+                    cache_k, k, paged_lengths, page_indices, page_size,
+                    valid=q_valid > 0)
+                cache_v = write_tokens_to_pages(
+                    cache_v, v, paged_lengths, page_indices, page_size,
+                    valid=q_valid > 0)
             att = chunked_context_attention(
                 q, gather_pages_dense(cache_k, page_indices),
                 gather_pages_dense(cache_v, page_indices),
@@ -211,12 +256,13 @@ def _layer(
             q_valid = key_valid[:, :s] if key_valid is not None else (
                 jnp.ones((b, s), jnp.int32)
             )
-            cache_k = write_tokens_to_pages(
-                cache_k, k, paged_lengths, page_indices, page_size,
-                valid=q_valid > 0)
-            cache_v = write_tokens_to_pages(
-                cache_v, v, paged_lengths, page_indices, page_size,
-                valid=q_valid > 0)
+            with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+                cache_k = write_tokens_to_pages(
+                    cache_k, k, paged_lengths, page_indices, page_size,
+                    valid=q_valid > 0)
+                cache_v = write_tokens_to_pages(
+                    cache_v, v, paged_lengths, page_indices, page_size,
+                    valid=q_valid > 0)
             ctx_k = gather_pages_dense(
                 cache_k, page_indices[:, :-1], dtype=q.dtype)
             ctx_v = gather_pages_dense(
@@ -241,10 +287,11 @@ def _layer(
             # (paged_verify_impl="unrolled" / non-TPU backends)
             from distrl_llm_tpu.ops.paged import paged_verify_op
 
-            cache_k = write_tokens_to_pages(
-                cache_k, k, paged_lengths, page_indices, page_size)
-            cache_v = write_tokens_to_pages(
-                cache_v, v, paged_lengths, page_indices, page_size)
+            with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+                cache_k = write_tokens_to_pages(
+                    cache_k, k, paged_lengths, page_indices, page_size)
+                cache_v = write_tokens_to_pages(
+                    cache_v, v, paged_lengths, page_indices, page_size)
             att = paged_verify_op(
                 q, cache_k, cache_v, paged_lengths, page_indices,
                 impl=paged_impl, pages_per_block=pages_per_block,
@@ -252,28 +299,30 @@ def _layer(
             )
         else:
             # packed prefill: write the prompt pages, attend over the input
-            cache_k = write_prompt_to_pages(cache_k, k, page_indices, page_size)
-            cache_v = write_prompt_to_pages(cache_v, v, page_indices, page_size)
+            with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+                cache_k = write_prompt_to_pages(cache_k, k, page_indices, page_size)
+                cache_v = write_prompt_to_pages(cache_v, v, page_indices, page_size)
             att = attention(q, k, v, mask, impl=attn_impl, key_valid=key_valid)
     elif cache_k is not None:
         quant = cache_k_scale is not None
-        if quant:
-            # int8 KV cache: quantize the new positions per (B, K, position)
-            # over head_dim and write values + scales; attention reads the
-            # cache at 1 byte/element with dequant folded into the einsums
-            from distrl_llm_tpu.ops.attention import quantize_kv_position
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            if quant:
+                # int8 KV cache: quantize the new positions per (B, K, position)
+                # over head_dim and write values + scales; attention reads the
+                # cache at 1 byte/element with dequant folded into the einsums
+                from distrl_llm_tpu.ops.attention import quantize_kv_position
 
-            k_t, ks = quantize_kv_position(k.transpose(0, 2, 3, 1))
-            v_t, vs = quantize_kv_position(v.transpose(0, 2, 3, 1))
-            cache_k_scale = jax.lax.dynamic_update_slice(
-                cache_k_scale, ks, (0, 0, 0, cache_offset))
-            cache_v_scale = jax.lax.dynamic_update_slice(
-                cache_v_scale, vs, (0, 0, 0, cache_offset))
-        else:
-            k_t = k.astype(cache_k.dtype).transpose(0, 2, 3, 1)  # [B, K, hd, S]
-            v_t = v.astype(cache_v.dtype).transpose(0, 2, 3, 1)
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k_t, (0, 0, 0, cache_offset))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v_t, (0, 0, 0, cache_offset))
+                k_t, ks = quantize_kv_position(k.transpose(0, 2, 3, 1))
+                v_t, vs = quantize_kv_position(v.transpose(0, 2, 3, 1))
+                cache_k_scale = jax.lax.dynamic_update_slice(
+                    cache_k_scale, ks, (0, 0, 0, cache_offset))
+                cache_v_scale = jax.lax.dynamic_update_slice(
+                    cache_v_scale, vs, (0, 0, 0, cache_offset))
+            else:
+                k_t = k.astype(cache_k.dtype).transpose(0, 2, 3, 1)  # [B, K, hd, S]
+                v_t = v.astype(cache_v.dtype).transpose(0, 2, 3, 1)
+            cache_k = jax.lax.dynamic_update_slice(cache_k, k_t, (0, 0, 0, cache_offset))
+            cache_v = jax.lax.dynamic_update_slice(cache_v, v_t, (0, 0, 0, cache_offset))
         if attn_impl == "flash" and isinstance(cache_offset, int) and cache_offset == 0 and s > 1:
             # prefill: the cache holds nothing beyond the prompt being
             # written, so attention is plain self-attention over the input —
@@ -308,18 +357,27 @@ def _layer(
         att = ulysses_attention(q, k, v, key_valid, mesh=attn_mesh)
     else:
         att = attention(q, k, v, mask, impl=attn_impl, key_valid=key_valid)
-    att = att.reshape(b, s, cfg.q_dim)
-    x = x + proj(att, p, lora, "wo", "bo", lora_scale)
+    return att, cache_k, cache_v, cache_k_scale, cache_v_scale
 
-    h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
-    act = (
-        jax.nn.silu if cfg.hidden_act == "silu"
-        else partial(jax.nn.gelu, approximate=True)  # Gemma gelu_pytorch_tanh
-    )
-    gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
-    up = proj(h, p, lora, "w_up", "b_up", lora_scale)
-    x = x + proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
-    return x, cache_k, cache_v, cache_k_scale, cache_v_scale
+
+_MLP_KEYS = frozenset(
+    ("mlp_norm", "w_gate", "w_up", "w_down", "b_gate", "b_up", "b_down")
+)
+
+
+def _slice_layer(stacked: Params, i: int) -> Params:
+    """Layer ``i`` of a stacked tree (base weights or LoRA factors), each
+    weight sliced under the scope of the block that reads it, so a slice the
+    compiler does not fuse into its matmul is still that block's time. Same
+    leaves in the same order as ``tree_map(lambda w: w[i], stacked)``."""
+    if not isinstance(stacked, dict):
+        return jax.tree_util.tree_map(lambda w: w[i], stacked)
+    out = {}
+    for key in sorted(stacked):
+        scope = telemetry.MODEL_MLP if key in _MLP_KEYS else telemetry.MODEL_ATTN_PROJ
+        with jax.named_scope(scope):
+            out[key] = jax.tree_util.tree_map(lambda w: w[i], stacked[key])
+    return out
 
 
 def forward(
@@ -379,11 +437,13 @@ def forward(
     if positions is None:
         positions = cache_offset + jnp.arange(s, dtype=jnp.int32)[None, :]
         positions = jnp.broadcast_to(positions, (b, s))
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
-    x = jnp.take(params["embed"], input_ids, axis=0)
-    if cfg.scale_embeddings:  # Gemma: hidden states enter at sqrt(D) scale
-        x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
+    with jax.named_scope(telemetry.MODEL_EMBED):
+        x = jnp.take(params["embed"], input_ids, axis=0)
+        if cfg.scale_embeddings:  # Gemma: hidden states enter at sqrt(D) scale
+            x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
 
     # paged caches attend raggedly by per-row length (decode) or over the
     # packed input only (prefill) — the dense key window is the input itself
@@ -401,12 +461,13 @@ def forward(
             and attn_impl not in ("ring", "ulysses", "flash", "splash"))
         or (kv_cache is None and attn_impl not in ("ring", "ulysses", "flash", "splash"))
     )
-    mask = (
-        causal_padding_mask(
-            attention_mask, q_len=s, q_offset=0 if paged else cache_offset
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        mask = (
+            causal_padding_mask(
+                attention_mask, q_len=s, q_offset=0 if paged else cache_offset
+            )
+            if needs_dense_mask else None
         )
-        if needs_dense_mask else None
-    )
 
     layer_fn = partial(
         _layer,
@@ -465,11 +526,8 @@ def forward(
         kv_quant = "k_scale" in kv_cache  # int8 dense cache carries scales
         new_k, new_v, new_ks, new_vs = [], [], [], []
         for i in range(cfg.num_layers):
-            p_i = jax.tree_util.tree_map(lambda w: w[i], params["layers"])
-            lora_i = (
-                jax.tree_util.tree_map(lambda w: w[i], lora["layers"])
-                if lora is not None else None
-            )
+            p_i = _slice_layer(params["layers"], i)
+            lora_i = _slice_layer(lora["layers"], i) if lora is not None else None
             key_i = layer_keys[i] if layer_keys is not None else None
             x, ck, cv, cks, cvs = layer_fn(
                 x, p_i, lora_i, kv_cache["k"][i], kv_cache["v"][i],
@@ -487,6 +545,19 @@ def forward(
             if kv_quant else {}
         )
 
+    with jax.named_scope(telemetry.MODEL_HEAD):
+        logits = _head(x, params, cfg, logits_slice, logits_positions, skip_lm_head)
+
+    if kv_cache is None:
+        new_cache = None
+    else:
+        new_cache = {**kv_cache, "k": new_k, "v": new_v, **new_scales}
+    return logits, new_cache
+
+
+def _head(x, params: Params, cfg: ModelConfig, logits_slice, logits_positions,
+          skip_lm_head: bool) -> jax.Array:
+    """Final norm, the positions the caller wants, and the output head."""
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                  offset=cfg.rmsnorm_offset)
     if logits_slice is not None:
@@ -508,12 +579,7 @@ def forward(
     else:
         lm_head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
         logits = linear(x, lm_head).astype(jnp.float32)
-
-    if kv_cache is None:
-        new_cache = None
-    else:
-        new_cache = {**kv_cache, "k": new_k, "v": new_v, **new_scales}
-    return logits, new_cache
+    return logits
 
 
 def init_params(

@@ -28,6 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from distrl_llm_tpu import telemetry
+
 _BLOCK = 128  # kernel block granularity; seq is padded up to a multiple
 
 
@@ -96,10 +98,11 @@ def flash_attention(
         block_k_dkv=block, block_q_dkv=block,
         block_k_major_dq=block, block_k_dq=block, block_q_dq=block,
     )
-    out = fa.flash_attention(
-        qt, kt, vt, segment_ids=seg, causal=True, sm_scale=scale,
-        block_sizes=sizes,
-    )
+    with jax.named_scope(telemetry.KERNEL_FLASH):
+        out = fa.flash_attention(
+            qt, kt, vt, segment_ids=seg, causal=True, sm_scale=scale,
+            block_sizes=sizes,
+        )
     out = out.transpose(0, 2, 1, 3)  # [B, S, H, D]
     if pad:
         out = out[:, :sq]

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.ops.attention import NEG_INF
 from distrl_llm_tpu.ops.per_device import per_device
 
@@ -407,6 +408,9 @@ def resolve_paged_impl(impl: str) -> str:
     return impl
 
 
+# the scope sits OUTSIDE the kernels' own jit: round an inline pallas_call it
+# would rename the custom call and re-key the program (ops/sampling.py)
+@jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION)
 def _native_call(q, k_pages, v_pages, lengths, page_indices,
                  *, quantized: bool,
                  folded: bool = False, blocked: bool = False,
@@ -439,6 +443,7 @@ def _native_call(q, k_pages, v_pages, lengths, page_indices,
     return kernel(q, k_pages, v_pages, lengths, page_indices, **kw)
 
 
+@jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION)
 def _native_verify_call(q, k_pages, v_pages, lengths, page_indices,
                         *, quantized: bool, pages_per_block: int = 0,
                         interpret: bool = False):
@@ -508,13 +513,14 @@ def paged_attention_op(
         )
 
         # requires pages_per_sequence % pages_per_compute_block == 0
-        return per_device(paged_attention)(
-            scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
-            page_indices,
-            pages_per_compute_block=divisor_blocks(
-                pages_per_compute_block, pps
-            ),
-        ).astype(q.dtype)
+        with jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION):
+            return per_device(paged_attention)(
+                scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
+                page_indices,
+                pages_per_compute_block=divisor_blocks(
+                    pages_per_compute_block, pps
+                ),
+            ).astype(q.dtype)
     return per_device(_native_call)(
         scaled_q, k_pages, v_pages, lengths.astype(jnp.int32), page_indices,
         quantized=quantized,

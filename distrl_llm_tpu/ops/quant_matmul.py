@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.ops.per_device import per_device
 
 #: trace-time dispatch record (the ops.paged.dispatch_choices idiom): keyed by
@@ -144,17 +145,21 @@ def _kernel_call(x2, q, scale, bias, a, b, lora_scale: float,
         in_specs.append(pl.BlockSpec((r, bn), lambda i, j: (0, j)))
         operands.extend([a, b])
 
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel_body, out_dtype=out_dtype, has_bias=has_bias,
-            has_lora=has_lora, lora_scale=lora_scale,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        interpret=interpret,
-    )(*operands)
+    # this call is traced inline under the decoder's ``model/*`` scopes, and
+    # the TPU compiler names a custom call after its innermost scope: with
+    # this one the kernel reads ``%quant_matmul`` in a trace, not ``%mlp``
+    with jax.named_scope(telemetry.KERNEL_QUANT_MATMUL):
+        out = pl.pallas_call(
+            functools.partial(
+                _kernel_body, out_dtype=out_dtype, has_bias=has_bias,
+                has_lora=has_lora, lora_scale=lora_scale,
+            ),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+            interpret=interpret,
+        )(*operands)
     return out[:m, :n]
 
 

@@ -15,6 +15,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.ops.attention import NEG_INF
 from distrl_llm_tpu.ops.per_device import per_device
 
@@ -356,13 +357,21 @@ def sample_with_logprob(
         "fused" if use else "xla"
     )
     if use:
+        # NO scope round the fused kernel, here or in a caller: a Pallas call
+        # traced inline is not metadata-only under a scope. The TPU compiler
+        # names the custom call after the innermost scope (the benchmark finds
+        # this kernel as ``%_unknown_``), and the kernel body's serialized
+        # MLIR carries the name stack, which re-keys the program in the
+        # persistent cache. It gets a name and a scope together, in the
+        # ``benchmark`` PR that re-points ``kernel.sampler_share`` (ROADMAP S0b)
         tok, logp = per_device(fused_sample)(
             rng, logits, jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32), interpret=interp,
         )
         return tok, (logp if capture_logprob else None)
-    tok = sample(rng, logits, temperature, top_p, top_p_impl=top_p_impl)
-    return tok, (token_logprob(logits, tok) if capture_logprob else None)
+    with jax.named_scope(telemetry.ENGINE_SAMPLE):
+        tok = sample(rng, logits, temperature, top_p, top_p_impl=top_p_impl)
+        return tok, (token_logprob(logits, tok) if capture_logprob else None)
 
 
 def token_logprob(logits: jax.Array, tokens: jax.Array) -> jax.Array:

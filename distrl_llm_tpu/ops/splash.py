@@ -23,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from distrl_llm_tpu import telemetry
+
 
 @functools.cache
 def _mods():
@@ -108,7 +110,8 @@ def splash_attention(
     # vmap over KV heads (shared segment ids), then over batch
     per_head = jax.vmap(splash, in_axes=(0, 0, 0, None))
     per_batch = jax.vmap(per_head, in_axes=(0, 0, 0, 0))
-    out = per_batch(qg, kt, vt, seg)  # [B, K, G, S, D]
+    with jax.named_scope(telemetry.KERNEL_SPLASH):
+        out = per_batch(qg, kt, vt, seg)  # [B, K, G, S, D]
     out = out.reshape(b, h, sp, d).transpose(0, 2, 1, 3)
     if pad:
         out = out[:, :s]

@@ -56,6 +56,75 @@ HIST_BUCKET_BOUNDS = (
     1000.0, 2500.0, 5000.0, 10000.0, 30000.0, 60000.0,
 )
 
+# ------------------------------------------------------ the timeline's names
+#
+# One fixed vocabulary for the step timeline, defined here so that one file
+# lists every name an operator can meet in a profile or an exported trace.
+# Host spans (``span(NAME)``) land in the Chrome JSON and, while a
+# ``jax.profiler`` trace runs, on its host plane; device scopes
+# (``jax.named_scope(NAME)``) are trace-time metadata on the HLO the jitted
+# programs lower to (``op_name``), which the profiler's device lines and
+# ``perfbench/trace_scopes.py`` read back. A scope changes no program (JAX
+# strips locations from the persistent cache's key), with the one exception
+# noted at the kernel names below. README "The timeline's names" has the
+# table; PERF.md §3 says which metric reads which.
+
+# engine rounds, host side (engine/engine.py, engine/paged_engine.py)
+ENGINE_SETUP = "engine/setup"  # entry to the first dispatch
+ENGINE_PREFILL = "engine/prefill"
+ENGINE_DECODE = "engine/decode"
+ENGINE_REFILL_DECODE = "engine/refill_decode"
+ENGINE_ADMIT = "engine/admit"  # host: one admission pass; device: the admit program
+ENGINE_GRANT = "engine/grant"  # a budgeted pool's page-grant pass
+ENGINE_SNAPSHOT_WAIT = "engine/snapshot_wait"  # the host waits on the device here
+ENGINE_PREEMPT = "engine/preempt"
+ENGINE_READBACK = "engine/readback"  # the round's final blocking reads
+# trainer, host side, nested in the PhaseSpans phases (driver/<phase>)
+DRIVER_SHAPING = "driver/shaping"
+DRIVER_UPDATE_BATCH = "driver/update/batch"
+DRIVER_UPDATE_STEP = "driver/update/step"
+DRIVER_PUSH = "driver/push"
+DRIVER_LOG = "driver/log"
+# one span per program JAX builds while tracing is on: compile/<fun_name>
+COMPILE_PREFIX = "compile"
+# device scopes: the decoder (models/transformer.py)
+MODEL_EMBED = "model/embed"
+MODEL_ATTN_PROJ = "model/attn_proj"
+MODEL_ATTN_CORE = "model/attn_core"
+MODEL_MLP = "model/mlp"
+MODEL_HEAD = "model/head"
+# device scopes: the engines' step programs
+ENGINE_KV_WRITE = "engine/kv_write"
+ENGINE_SAMPLE = "engine/sample"
+ENGINE_BOOKKEEPING = "engine/bookkeeping"
+# device scopes: the Pallas call sites (ops/), each OUTSIDE the kernel's own
+# jit. Round an inline pallas_call a scope is not metadata-only: the TPU
+# compiler names the custom call after the innermost scope and the kernel
+# body's serialized MLIR carries the name stack, so the fused sampler
+# (ops/sampling.py, traced inline) has no scope until the benchmark stops
+# finding it by the name ``%_unknown_`` (ROADMAP S0b)
+KERNEL_PAGED_ATTENTION = "kernel/paged_attention"
+KERNEL_QUANT_MATMUL = "kernel/quant_matmul"
+KERNEL_FLASH = "kernel/flash"
+KERNEL_SPLASH = "kernel/splash"
+# device scopes: the train step (learner/). JAX writes the rest of the path:
+# ``transpose(jvp(learner/loss))`` is the backward pass and
+# ``rematted_computation`` under it the recomputed forward
+LEARNER_LOSS = "learner/loss"
+LEARNER_LOSS_LOGPROB = "learner/loss/logprob"
+LEARNER_GRAD_ACCUM = "learner/grad_accum"
+LEARNER_OPTIMIZER = "learner/optimizer"
+LEARNER_OPTIMIZER_CODEC = "learner/optimizer/codec"
+
+#: every ``jax.named_scope`` name the jitted programs carry
+SCOPE_NAMES = (
+    MODEL_EMBED, MODEL_ATTN_PROJ, MODEL_ATTN_CORE, MODEL_MLP, MODEL_HEAD,
+    ENGINE_KV_WRITE, ENGINE_SAMPLE, ENGINE_BOOKKEEPING, ENGINE_ADMIT,
+    KERNEL_PAGED_ATTENTION, KERNEL_QUANT_MATMUL, KERNEL_FLASH, KERNEL_SPLASH,
+    LEARNER_LOSS, LEARNER_LOSS_LOGPROB, LEARNER_GRAD_ACCUM, LEARNER_OPTIMIZER,
+    LEARNER_OPTIMIZER_CODEC,
+)
+
 
 class _State:
     """Process-global telemetry state. A plain class (not a dataclass) so
@@ -110,8 +179,12 @@ _STATE = _State()
 
 def configure(enabled: bool) -> None:
     """Turn span recording on/off (counters/gauges always record — they are
-    the MetricsSink feed and cost a dict write)."""
+    the MetricsSink feed and cost a dict write). Turning it on also starts
+    the process's compile listener, so every program JAX builds while
+    tracing is on lands on the timeline as a ``compile/<fun_name>`` span."""
     _STATE.enabled = enabled
+    if enabled:
+        _ensure_compile_spans()
 
 
 def enabled() -> bool:
@@ -233,20 +306,43 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# jax.profiler.TraceAnnotation, bound at the first recorded span (this module
+# imports without JAX); False where JAX is not installed
+_TRACE_ANNOTATION: Any = None
+
+
+def _trace_annotation(name: str):
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name) if _TRACE_ANNOTATION else None
+
 
 class _Span:
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_annotation")
 
     def __init__(self, name: str, args: dict):
         self.name = name
         self.args = args
 
     def __enter__(self) -> "_Span":
+        # the same span on the profiler's own clock: any jax.profiler trace
+        # that is running (the harness's, --profile_dir's, the sentinel's
+        # capture) shows it on its host plane beside the device lines
+        self._annotation = _trace_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.time_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         ident = threading.get_ident()
         st = _STATE
         if ident not in st.thread_names:
@@ -631,6 +727,82 @@ def export_chrome_trace(path: str, metadata: Mapping[str, Any] | None = None,
     with open(path, "w") as f:
         json.dump(doc, f)
     return path
+
+
+# ------------------------------------------------------------ compile events
+
+
+class CompileLog:
+    """Every program JAX builds (compiles, or loads from the persistent
+    cache) from now on: (function name, seconds, when it finished on
+    ``perf_counter``), off JAX's own monitoring events. ``spans=True`` also
+    records each build, while tracing is on, as a span ``compile/<fun_name>``
+    that ends at the event, so a gap or a slow step in an exported trace is
+    named by the program that was built in it."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, spans: bool = False):
+        import jax.monitoring
+
+        self.events: list[tuple[str, float, float]] = []
+        self._spans = spans
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **kw) -> None:
+        if event != self.EVENT:
+            return
+        name = str(kw.get("fun_name", "?"))
+        self.events.append((name, float(duration), time.perf_counter()))
+        st = _STATE
+        if self._spans and st.enabled:
+            t1 = time.time_ns()
+            dur_us = max(int(duration * 1e6), 1)
+            st.events.append({
+                "ph": "X",
+                "name": f"{COMPILE_PREFIX}/{name}",
+                "ts": t1 // 1000 - dur_us,
+                "dur": dur_us,
+                "tid": threading.get_ident(),
+                "args": {},
+            })
+
+    def close(self) -> None:
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
+
+    def mark(self) -> int:
+        return len(self.events)
+
+    def since(self, mark: int) -> dict:
+        new = self.events[mark:]
+        return {
+            "programs": len(new),
+            "seconds": round(sum(s for _, s, _ in new), 3),
+        }
+
+    def recompiled_after(self, mark: int, t: float) -> tuple[list, list]:
+        """(names compiled again, names compiled for the first time) among
+        the programs since ``mark`` that finished after time ``t``."""
+        before = {n for n, _, done in self.events[mark:] if done < t}
+        late = sorted({n for n, _, done in self.events[mark:] if done >= t})
+        return ([n for n in late if n in before],
+                [n for n in late if n not in before])
+
+
+# the process's one span-recording listener, started by the first
+# ``configure(True)``; False where JAX is not installed
+_COMPILE_SPANS: Any = None
+
+
+def _ensure_compile_spans() -> None:
+    global _COMPILE_SPANS
+    if _COMPILE_SPANS is None:
+        try:
+            _COMPILE_SPANS = CompileLog(spans=True)
+        except ImportError:
+            _COMPILE_SPANS = False
 
 
 # ----------------------------------------------------------- MFU / hardware

@@ -935,49 +935,55 @@ class Trainer:
         bus (save_lora distributed_actor.py:85 / load_lora :150). Records the
         version now resident on the rollout mesh; ``_generate_round`` asserts
         it before sampling."""
-        pushed = self.lora
-        if self._full:
-            # master weights train in f32; rollout samples at the base dtype
-            pushed = jax.tree_util.tree_map(
-                lambda x: x.astype(self._rollout_dtype), pushed
-            )
-        if self.config.async_rollout:
-            # the train step DONATES self.lora's buffers; in the overlap
-            # window the next batch's generation still reads the pushed tree,
-            # so it must own its buffers (same-device/same-dtype paths would
-            # otherwise alias the donated arrays → "buffer deleted" crashes)
-            pushed = jax.tree_util.tree_map(jnp.copy, pushed)
-        if getattr(self.engine, "is_remote", False):
-            # remote rollout: the adapter ships over the wire — either once
-            # per version on the broadcast bus (below) or inside each
-            # round's dispatch payloads — no local rollout-mesh copy
-            self._lora_rollout = pushed
-            if getattr(self.engine, "bus", None) is not None:
-                # versioned weight bus (ISSUE 9): ONE asynchronous push per
-                # optimizer step; subsequent dispatches reference it as
-                # {weight_version} and mid-round swaps ride the same push
-                # when inflight_weight_updates is on
-                self.engine.push_lora(pushed, version=self.weight_version)
-        elif self.meshes is not None and not self.meshes.timeshared:
-            from distrl_llm_tpu.parallel.partition import shard_tree
+        with telemetry.span(
+            telemetry.DRIVER_PUSH, version=self.weight_version
+        ) as push_span:
+            pushed = self.lora
+            if self._full:
+                # master weights train in f32; rollout samples at the base dtype
+                pushed = jax.tree_util.tree_map(
+                    lambda x: x.astype(self._rollout_dtype), pushed
+                )
+            if self.config.async_rollout:
+                # the train step DONATES self.lora's buffers; in the overlap
+                # window the next batch's generation still reads the pushed tree,
+                # so it must own its buffers (same-device/same-dtype paths would
+                # otherwise alias the donated arrays → "buffer deleted" crashes)
+                pushed = jax.tree_util.tree_map(jnp.copy, pushed)
+            if getattr(self.engine, "is_remote", False):
+                # remote rollout: the adapter ships over the wire — either once
+                # per version on the broadcast bus (below) or inside each
+                # round's dispatch payloads — no local rollout-mesh copy
+                self._lora_rollout = pushed
+                push_span.set(mode="remote")
+                if getattr(self.engine, "bus", None) is not None:
+                    # versioned weight bus (ISSUE 9): ONE asynchronous push per
+                    # optimizer step; subsequent dispatches reference it as
+                    # {weight_version} and mid-round swaps ride the same push
+                    # when inflight_weight_updates is on
+                    self.engine.push_lora(pushed, version=self.weight_version)
+            elif self.meshes is not None and not self.meshes.timeshared:
+                from distrl_llm_tpu.parallel.partition import shard_tree
 
-            self._lora_rollout = shard_tree(pushed, self.meshes.rollout)
-        else:
-            self._lora_rollout = pushed
-        self._rollout_weight_version = self.weight_version
-        if self._gateway_service is not None:
-            # the gateway serves the freshest pushed policy: attribute
-            # swap only — a round already being formed finishes on the
-            # previous tree (one-round staleness, same as rollout)
-            gw_params, gw_lora = self._engine_params("rollout")
-            self._gateway_service.params = gw_params
-            self._gateway_service.lora = gw_lora
-        if self.lineage is not None:
-            # weight-version lineage: push time opens the learn-to-act
-            # window; with a broadcast bus the policy-lag loop stays open
-            # until on_broadcast_complete (the bus hook), locally it closes
-            # here — the pushed tree IS resident when this returns
-            self.lineage.on_push(self.weight_version)
+                self._lora_rollout = shard_tree(pushed, self.meshes.rollout)
+                push_span.set(mode="split")
+            else:
+                self._lora_rollout = pushed
+                push_span.set(mode="timeshared")
+            self._rollout_weight_version = self.weight_version
+            if self._gateway_service is not None:
+                # the gateway serves the freshest pushed policy: attribute
+                # swap only — a round already being formed finishes on the
+                # previous tree (one-round staleness, same as rollout)
+                gw_params, gw_lora = self._engine_params("rollout")
+                self._gateway_service.params = gw_params
+                self._gateway_service.lora = gw_lora
+            if self.lineage is not None:
+                # weight-version lineage: push time opens the learn-to-act
+                # window; with a broadcast bus the policy-lag loop stays open
+                # until on_broadcast_complete (the bus hook), locally it closes
+                # here — the pushed tree IS resident when this returns
+                self.lineage.on_push(self.weight_version)
 
     # ---------------------------------------------------------------- gateway
 
@@ -1831,11 +1837,14 @@ class Trainer:
 
         # shaping: baselines / GRPO group-norm advantages + metric collection
         # (distributed_trainer.py:262–279), then top-k (:281–294)
-        stats = shape_rewards(candidates, cfg.learner)
-        if cfg.topk < cfg.num_candidates:
-            topk_filter(candidates, cfg.topk)
+        with telemetry.span(telemetry.DRIVER_SHAPING):
+            stats = shape_rewards(candidates, cfg.learner)
+            if cfg.topk < cfg.num_candidates:
+                topk_filter(candidates, cfg.topk)
 
         with timer("update"):
+            batch_span = telemetry.span(telemetry.DRIVER_UPDATE_BATCH)
+            batch_span.__enter__()
             problems, answers, coeffs, raw = flatten_for_update(
                 candidates, cfg.learner
             )
@@ -1872,6 +1881,9 @@ class Trainer:
                 # update; disabled (None) when the rate is 0
                 self._next_rng() if cfg.lora_dropout > 0.0 else None,
             )
+            batch_span.__exit__(None, None, None)
+            step_span = telemetry.span(telemetry.DRIVER_UPDATE_STEP)
+            step_span.__enter__()  # dispatch to the host's fetch of the loss
             if self.learn is not None:
                 # training-dynamics bundle (ISSUE 16): the armed step
                 # returns it through the aux pytree, and the loss fetch the
@@ -1887,6 +1899,7 @@ class Trainer:
             else:
                 self.lora, self.opt_state, loss = self.train_step(*step_args)
                 loss = float(loss)
+            step_span.__exit__(None, None, None)
         if (
             self._inject_nan_step is not None
             and self.total_batch_steps + 1 == self._inject_nan_step
@@ -1975,6 +1988,8 @@ class Trainer:
 
         self.total_batch_steps += 1
         self.total_samples_processed += n_samples
+        log_span = telemetry.span(telemetry.DRIVER_LOG)
+        log_span.__enter__()  # metrics assembly and the sink's write
         metrics = {
             "loss": loss,
             "mean_accuracy_reward": float(np.mean(stats.mean_acc)),
@@ -2070,6 +2085,7 @@ class Trainer:
         # ride the same sink record
         metrics.update(telemetry.metrics_snapshot())
         self.sink.log(metrics, step=self.total_batch_steps)
+        log_span.__exit__(None, None, None)
         if self.obs is not None:
             # ring record + sentinel pass + fleet refresh — the per-step
             # entry point of the observability plane
