@@ -13,7 +13,7 @@ reference's loss and gradient on those rows.
 
 Traffic parameters: ``train_config``, ``rows``, ``prompt_tokens``,
 ``answer_tokens``, ``check_rows`` [rows, prompt tokens, answer tokens],
-``distinct_batches``, ``trace_units``.
+``distinct_batches``, ``trace_units``, ``check`` (the cell's own tolerances).
 """
 
 from __future__ import annotations
@@ -113,7 +113,7 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
     check = correct.learner_update_check(
         reference, model_cfg, params, before_host, jax.device_get(after),
         trainer.scale, loss, ids, np.ones_like(ids), answer_cols,
-        host["coeffs"][r],
+        host["coeffs"][r], check=traffic.get("check"),
     )
     harness.emit("check", **check)
     del after, before, before_host

@@ -1,5 +1,9 @@
 """Reader ``required_work``: measured time against the operations or bytes the
-algorithm needs (``roofline.py``) at the chip's published peak (``peaks.json``).
+algorithm needs at the chip's published peak (``peaks.json``). The counts are
+those of the module the cell's configuration names under ``counts``, found
+over the benchmark's ``paths`` as its ``reference`` is (default ``roofline``,
+the dense GQA decoder's): ``train_flops_per_token``, ``decode_weight_bytes``
+and ``kv_read_bytes``, called as ``roofline.py`` defines them.
 
 ``args["what"]``:
 
@@ -19,21 +23,25 @@ algorithm needs (``roofline.py``) at the chip's published peak (``peaks.json``).
 
 from __future__ import annotations
 
-from perfbench import roofline
+from perfbench import spec
 from perfbench.readers.trace_ops import matching_seconds
+
+DEFAULT_COUNTS = "roofline"
 
 
 def read(observed, args, ctx):
     peaks, model = observed.get("peaks"), observed.get("model")
-    if peaks is None or model is None:
+    if peaks is None or model is None or ctx is None:
         return None
+    counts = spec.load_module(
+        ctx.cell.paths, "", ctx.cell.config.get("counts", DEFAULT_COUNTS))
     what = args["what"]
     if what == "learner_mfu":
         units, shape = observed.get("units"), observed.get("learner")
         if not units or not shape:
             return None
         tok_s = sum(u["tokens"] for u in units) / (units[-1]["t1"] - units[0]["t0"])
-        flops = roofline.train_flops_per_token(
+        flops = counts.train_flops_per_token(
             model, seq_len=shape["seq_len"], answer_len=shape["answer_len"],
             lora_rank=shape["lora_rank"],
         )
@@ -45,12 +53,12 @@ def read(observed, args, ctx):
         units = [u for u in observed.get("units", []) if u.get("steps_dispatched")]
         if not units:
             return None
-        weights = roofline.decode_weight_bytes(
+        weights = counts.decode_weight_bytes(
             model, weight_bytes=layout["weight_bytes"],
             lora_rank=layout["lora_rank"],
         )
         needed = sum(
-            u["steps_dispatched"] * weights + roofline.kv_read_bytes(
+            u["steps_dispatched"] * weights + counts.kv_read_bytes(
                 model, u["prompt_lens"], u["gen_lens"], kv_bytes=layout["kv_bytes"])
             for u in units
         )
@@ -64,7 +72,7 @@ def read(observed, args, ctx):
         if kernel_s <= 0:
             return None
         needed = sum(
-            roofline.kv_read_bytes(model, u["prompt_lens"], u["gen_lens"],
+            counts.kv_read_bytes(model, u["prompt_lens"], u["gen_lens"],
                                    kv_bytes=layout["kv_bytes"])
             for u in units
         )

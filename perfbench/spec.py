@@ -9,15 +9,22 @@ a file under one of them and edits none that is there.
 from __future__ import annotations
 
 import dataclasses
+import glob
 import importlib.util
 import json
 import os
+import re
 import sys
 from types import ModuleType
 from typing import Any
 
 #: the checkout: the directory that holds ``BENCHMARK.json`` and ``perfbench/``
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+#: a scope name: lower-case components joined by single slashes, as
+#: ``jax.named_scope`` writes them into an operation's path
+SCOPE_NAME = re.compile(r"^[a-z0-9_]+(/[a-z0-9_]+)*$")
 
 
 class SpecError(ValueError):
@@ -101,6 +108,11 @@ def load_cell(bench: dict[str, Any], workload: str) -> Cell:
     traffic = load_json(find_file(paths, "traffic", f"{entry['traffic']}.json"))
     if "kind" not in traffic:
         raise SpecError(f"traffic {entry['traffic']!r} has no 'kind'")
+    if "check" in traffic and not traffic["check"].get("basis"):
+        raise SpecError(
+            f"traffic {entry['traffic']!r}: its 'check' sets tolerances and gives "
+            "no 'basis' (the runs they were measured from)"
+        )
     return Cell(
         name=workload, chips=int(entry["chips"]),
         config_name=entry["config"], config=config,
@@ -124,3 +136,24 @@ def load_layer_metric(paths, name: str) -> dict[str, Any]:
         if key not in metric:
             raise SpecError(f"layer_metrics/{name}.json has no {key!r}")
     return metric
+
+
+def load_scope_names(paths) -> tuple[str, ...]:
+    """The scope names a run's device time is summed under: every
+    ``<path>/scopes/*.json`` (``{"names": [...], "for": "..."}``) over
+    ``paths`` in order, the files of one directory by name. The union of the
+    files, and a name held twice is refused: a PR whose program carries a new
+    ``jax.named_scope`` adds a file and edits none."""
+    names: dict[str, str] = {}
+    for p in paths:
+        for path in sorted(glob.glob(os.path.join(ROOT, p, "scopes", "*.json"))):
+            held = load_json(path)
+            if not isinstance(held, dict) or "names" not in held or "for" not in held:
+                raise SpecError(f"{path} must hold 'names' and what they are 'for'")
+            for name in held["names"]:
+                if not isinstance(name, str) or SCOPE_NAME.match(name) is None:
+                    raise SpecError(f"{path}: {name!r} is no plain scope name")
+                if name in names:
+                    raise SpecError(f"scope {name!r} is in {names[name]} and in {path}")
+                names[name] = path
+    return tuple(names)

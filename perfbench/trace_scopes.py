@@ -1,10 +1,13 @@
 """Device time by the program's own scope names, from the traced ``.xplane.pb``.
 
 ``trace_reduce`` ranks operations by HLO name and returned shape, which a change
-to a layer renames. The program carries a fixed vocabulary of
-``jax.named_scope`` names instead (``distrl_llm_tpu/telemetry.py``,
-``SCOPE_NAMES``; the copy here is the yardstick's, so that it also runs over a
-program that has none), and this module sums device time under them.
+to a layer renames. The program carries ``jax.named_scope`` names instead
+(``distrl_llm_tpu/telemetry.py``, ``SCOPE_NAMES``), and this module sums device
+time under them. Which names count is data: the VOCABULARY of a run is what
+``spec.load_scope_names`` finds in the ``scopes/*.json`` files under the
+benchmark's ``paths`` (the yardstick's own copy, so that it also runs over a
+program that has none; a PR whose program carries a new name adds a file), and
+every function here that needs it takes it as a tuple of names.
 
 Where the scope is. On a v5e trace (found with one ``--trace 1`` run, PR 24) an
 ``XLA Ops`` event carries only ``device_offset_ps`` / ``device_duration_ps`` of
@@ -36,6 +39,7 @@ without scopes filled) has no table: ``table`` says so and returns None.
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 import sys
@@ -47,37 +51,34 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _ROOT not in sys.path:  # run as a script, as cut_testdata.py is
     sys.path.insert(0, _ROOT)
 
-from perfbench import trace_reduce  # noqa: E402
+from perfbench import spec, trace_reduce  # noqa: E402
 
-#: the scope names the program may carry: a copy of ``telemetry.SCOPE_NAMES``
-#: (``tests/perfbench/test_perfbench_trace_scopes.py`` holds the two together)
-VOCABULARY = (
-    "model/embed", "model/attn_proj", "model/attn_core", "model/mlp", "model/head",
-    "engine/kv_write", "engine/sample", "engine/bookkeeping", "engine/admit",
-    "kernel/paged_attention", "kernel/quant_matmul", "kernel/flash", "kernel/splash",
-    "learner/loss", "learner/loss/logprob", "learner/grad_accum",
-    "learner/optimizer", "learner/optimizer/codec",
-)
 UNSCOPED = "unscoped"
 LOSS = "learner/loss"
 SCOPE_STAT = "tf_op"
 
-# a name counts only as a whole path component or run of components:
-# ``jvp(learner/loss)`` and ``.../model/mlp/dot_general`` match, ``my_model/mlp`` does not
-_NAMES = re.compile(
-    r"(?<![A-Za-z0-9_])("
-    + "|".join(re.escape(n) for n in sorted(VOCABULARY, key=len, reverse=True))
-    + r")(?![A-Za-z0-9_])"
-)
+
+@functools.lru_cache(maxsize=None)
+def _names(vocabulary: tuple[str, ...]) -> re.Pattern:
+    """The regex that finds ``vocabulary``'s names on a path, built once per
+    vocabulary. A name counts only as a whole path component or run of
+    components: ``jvp(learner/loss)`` and ``.../model/mlp/dot_general`` match,
+    ``my_model/mlp`` does not."""
+    return re.compile(
+        r"(?<![A-Za-z0-9_])("
+        + "|".join(re.escape(n) for n in sorted(vocabulary, key=len, reverse=True))
+        + r")(?![A-Za-z0-9_])"
+    )
 
 
-def classify(path: str | None) -> str:
-    """The row an operation's time goes to: the innermost vocabulary name on
-    ``path`` (the last to start; the regex prefers the longer of two that start
-    together), prefixed by the learner's phase where ``learner/loss`` is on it."""
-    if not path:
+def classify(path: str | None, vocabulary: tuple[str, ...]) -> str:
+    """The row an operation's time goes to: the innermost name of
+    ``vocabulary`` on ``path`` (the last to start; the regex prefers the longer
+    of two that start together), prefixed by the learner's phase where
+    ``learner/loss`` is on it."""
+    if not path or not vocabulary:
         return UNSCOPED
-    found = [m.group(1) for m in _NAMES.finditer(path)]
+    found = [m.group(1) for m in _names(vocabulary).finditer(path)]
     if not found:
         return UNSCOPED
     inner = found[-1]
@@ -249,11 +250,13 @@ def cut(trace: dict[str, Any], lo_ns: float, hi_ns: float) -> dict[str, Any]:
 # ----------------------------------------------------------------- the table
 
 
-def table(trace: dict[str, Any], window_ns: tuple[float, float] | None = None,
+def table(trace: dict[str, Any], vocabulary: tuple[str, ...],
+          window_ns: tuple[float, float] | None = None,
           top: int = 8) -> dict[str, Any] | None:
     """Busy seconds of the average device under each row (see the module's
     docstring), over ``window_ns`` on the trace's clock (None: everything).
-    None where no event carries a vocabulary name or no device was traced."""
+    None where no event carries a name of ``vocabulary`` or no device was
+    traced."""
     per_device = []
     for plane in trace["planes"]:
         if trace_reduce.DEVICE_PLANE.match(plane["name"]) is None:
@@ -275,7 +278,7 @@ def table(trace: dict[str, Any], window_ns: tuple[float, float] | None = None,
             if not is_leaf:
                 continue
             if path not in labels:
-                labels[path] = classify(path)
+                labels[path] = classify(path, vocabulary)
             label = labels[path]
             seconds[label] = seconds.get(label, 0.0) + (e - s) / 1e9
             if label == UNSCOPED:
@@ -347,7 +350,7 @@ def table_for(ctx) -> dict[str, Any] | None:
         window = None if offset is None else (
             tracer.window_wall_ns[0] - offset, tracer.window_wall_ns[1] - offset
         )
-        tab = table(trace, window)
+        tab = table(trace, spec.load_scope_names(ctx.cell.paths), window)
         if tab is None:
             harness.emit(
                 "trace_scopes", problem=(
@@ -399,13 +402,16 @@ def span_clock(host: dict[str, Any], host_spans,
 
 def main(argv: list[str]) -> int:
     """``python3 perfbench/trace_scopes.py <file.xplane.pb> [out.json start_ms
-    length_ms ...]``: prints the table of the whole trace, and writes the
-    events that start in each [start_ms, start_ms + length_ms) after the first
-    device event, scope kept, as ``testdata/`` holds them."""
+    length_ms ...]``: prints the table of the whole trace under the vocabulary
+    of the checkout's ``BENCHMARK.json``, and writes the events that start in
+    each [start_ms, start_ms + length_ms) after the first device event, scope
+    kept, as ``testdata/`` holds them."""
     import json
 
     trace = load(argv[0])
-    print(json.dumps(table(trace) or {"problem": "no operation carries a scope"}))
+    vocabulary = spec.load_scope_names(spec.load_benchmark()["paths"])
+    print(json.dumps(table(trace, vocabulary)
+                     or {"problem": "no operation carries a scope"}))
     if len(argv) > 1:
         first = min(e[1] for p in trace["planes"] for e in p["events"])
         planes = [{"name": p["name"], "events": []} for p in trace["planes"]]

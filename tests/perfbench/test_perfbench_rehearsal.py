@@ -35,6 +35,19 @@ def test_the_environment_is_put_back(tiny_benchmark):
     assert SWITCH in notes["run"]["distrl_switches_unset"]
 
 
+def test_a_learner_cell_without_a_check_reads_the_two_constants(tiny_benchmark):
+    """``learner-1k`` states no tolerances of its own, nor does the tiny learner
+    cell: their check lines print ``correct.py``'s defaults (a cell that states
+    its own: ``test_perfbench_second_family.py``)."""
+    from perfbench import correct, spec
+    from tiny_spec import real_benchmark
+
+    _, notes = shared_cell(tiny_benchmark, "tiny.learner", 0)
+    assert notes["check"]["tol_loss_scaled"] == correct.LOSS_SCALED_TOL == 2e-3
+    assert notes["check"]["tol_grad_sign_mass"] == correct.GRAD_SIGN_MASS_TOL == 0.995
+    assert "check" not in spec.load_cell(real_benchmark(), "qwen2.5-7b-L14.learner-1k").traffic
+
+
 def test_same_seed_same_traffic(tiny_benchmark):
     """Two runs of one seed (one of them traced) check the same rows and read
     the same numbers; another seed does not."""

@@ -129,6 +129,73 @@ def test_per_layer_metric_has_its_file_and_its_reader(metric):
         re.compile(held["args"]["regex"])
 
 
+def traffic_files():
+    """Every traffic file under the real benchmark's paths (the tiny ones too)."""
+    return sorted(
+        path for p in BENCH["paths"]
+        for path in glob.glob(os.path.join(REPO, p, "**", "traffic", "*.json"), recursive=True)
+    )
+
+
+def held_check(path):
+    with open(path, encoding="utf-8") as f:
+        return json.load(f).get("check")
+
+
+@pytest.mark.parametrize("path", traffic_files(), ids=lambda p: os.path.basename(p)[:-5])
+def test_a_check_block_carries_the_runs_it_came_from(path):
+    """A traffic file's ``check`` sets the tolerances ``correct`` is decided
+    by; each is measured, and says from which runs (``basis``)."""
+    check = held_check(path)
+    if check is None:
+        return
+    assert isinstance(check.get("basis"), str) and len(check["basis"]) >= 40, path
+    known = {"logprob_mean_abs_tol", "logprob_max_abs_tol", "loss_scaled_tol",
+             "grad_sign_mass_tol", "basis"}
+    assert set(check) <= known, set(check) - known
+    assert all(isinstance(check[k], float) for k in set(check) - {"basis"})
+
+
+def test_checks_are_where_they_are_expected():
+    held = {os.path.basename(p)[:-5] for p in traffic_files() if held_check(p)}
+    assert {"rollout-lockstep", "tiny-learner-checked"} <= held
+    assert "learner-1k" not in held  # it reads correct.py's two constants
+
+
+@pytest.mark.parametrize("check, refused", [
+    ({"loss_scaled_tol": 1e-3}, True), ({"loss_scaled_tol": 1e-3, "basis": ""}, True),
+    ({"loss_scaled_tol": 1e-3, "basis": "nine runs, PR n"}, False), (None, False),
+])
+def test_a_check_without_basis_is_refused_with_the_cell(tmp_path, check, refused):
+    from perfbench import spec
+    from tiny_spec import tiny_benchmark
+
+    bench = tiny_benchmark()
+    traffic = spec.load_cell(bench, "tiny.learner").traffic
+    os.makedirs(tmp_path / "traffic")
+    with open(tmp_path / "traffic" / "tiny-learner.json", "w", encoding="utf-8") as f:
+        json.dump({**traffic, **({} if check is None else {"check": check})}, f)
+    bench["paths"] = [str(tmp_path), *bench["paths"]]  # found first
+    if refused:
+        with pytest.raises(spec.SpecError, match="basis"):
+            spec.load_cell(bench, "tiny.learner")
+    else:
+        assert spec.load_cell(bench, "tiny.learner").traffic.get("check") == check
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_a_configurations_counts_module_is_beside_its_reference(config):
+    """``counts`` is optional and names a module under the paths, found as the
+    reference is; with none, ``roofline`` (the dense GQA decoder's)."""
+    from perfbench import spec
+
+    with open(os.path.join(REPO, config["file"]), encoding="utf-8") as f:
+        held = json.load(f)
+    counts = spec.load_module(BENCH["paths"], "", held.get("counts", "roofline"))
+    for name in ("train_flops_per_token", "decode_weight_bytes", "kv_read_bytes"):
+        assert callable(getattr(counts, name)), name
+
+
 def test_traffic_is_data():
     for path in glob.glob(os.path.join(REPO, "perfbench", "traffic", "*")):
         assert path.endswith((".json", ".jsonl", ".toml", ".txt", ".csv")), path

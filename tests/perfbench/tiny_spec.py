@@ -2,6 +2,12 @@
 per-layer metric and its reader, all NEW files under ``tests/perfbench/tiny/``,
 and one new ``BENCHMARK.json`` that names them beside every metric of the real
 one. No file of ``perfbench/`` is edited to add them: that is the point.
+
+What a second model family brings is rehearsed the same way (PR 27): a scope
+name of its own (``scopes/tiny.json``), its own counts (``tiny_counts.py``), a
+second configuration that names them (``configs/tiny-counted.json``) and a
+learner traffic file that states its own tolerances
+(``traffic/tiny-learner-checked.json``), in one more cell.
 """
 
 from __future__ import annotations
@@ -12,12 +18,14 @@ import os
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 TINY_DIR = "tests/perfbench/tiny"
 
-#: cell -> (traffic file, chips, the end-to-end metric the cell's kind reports)
+#: cell -> (traffic file, chips, the end-to-end metric the cell's kind reports);
+#: a cell's configuration is the part of its name before the dot
 CELLS = {
     "tiny.rollout": ("tiny-rollout", 1, "rollout_tok_s"),
     "tiny.learner": ("tiny-learner", 1, "learner_tok_s"),
     "tiny.rl-dense": ("tiny-rl-dense", 1, "step_s"),
     "tiny.rl-split4": ("tiny-rl-split4", 4, "step_s"),
+    "tiny-counted.learner-checked": ("tiny-learner-checked", 1, "learner_tok_s"),
 }
 
 
@@ -42,13 +50,13 @@ def tiny_benchmark() -> dict:
         "paths": [TINY_DIR, "perfbench"],
         "run_seconds": 1,
         "configs": [{
-            "name": "tiny", "source": "distrl_llm_tpu/models/configs.py::TINY",
-            "file": f"{TINY_DIR}/configs/tiny.json", "reduced": [],
+            "name": config, "source": "distrl_llm_tpu/models/configs.py::TINY",
+            "file": f"{TINY_DIR}/configs/{config}.json", "reduced": [],
             "why": "every driver's control flow on the CPU; counts only",
-        }],
+        } for config in sorted({cell.split(".")[0] for cell in CELLS})],
         "workloads": [
-            {"name": cell, "config": "tiny", "traffic": traffic, "chips": chips,
-             "why": "rehearsal"}
+            {"name": cell, "config": cell.split(".")[0], "traffic": traffic,
+             "chips": chips, "why": "rehearsal"}
             for cell, (traffic, chips, _) in CELLS.items()
         ],
         "end_to_end": [over_tiny(m, "name") for m in real["end_to_end"]],
