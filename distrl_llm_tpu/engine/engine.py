@@ -65,8 +65,8 @@ class GenerationResult(NamedTuple):
     # against on real hardware.
     steps_dispatched: int | None = None
     # sum over dispatched steps of the number of ALIVE slots at that step
-    # (refill scheduler only). tokens/alive_slot_steps is the realized
-    # per-slot emission rate with the drain-tail idle slots excluded —
+    # (the paged engine, both schedulers). tokens/alive_slot_steps is the
+    # realized per-slot emission rate with the drain-tail idle slots excluded —
     # steps_dispatched*slots systematically understates spec acceptance.
     alive_slot_steps: int | None = None
     # RAW-model log-probabilities of the sampled tokens [B, n, T] f32 (the
@@ -255,8 +255,8 @@ def generate_in_waves(
         return inner_generate(params, lora, prompt_ids, prompt_mask, sampling, rng)
     per_wave = max(max_rows // n, 1)
     tokens, lengths, logps = [], [], []
-    steps = 0
-    have_steps = have_logps = True
+    steps = alive = 0
+    have_steps = have_alive = have_logps = True
     for w in range(-(-b // per_wave)):
         lo = w * per_wave
         ids = prompt_ids[lo : lo + per_wave]
@@ -283,10 +283,16 @@ def generate_in_waves(
             have_steps = False
         else:
             steps += res.steps_dispatched
+        if res.alive_slot_steps is None:
+            have_alive = False
+        else:
+            # a tail wave's pad rows are dead from the start: never alive
+            alive += res.alive_slot_steps
     return GenerationResult(
         tokens=np.concatenate(tokens, axis=0),
         lengths=np.concatenate(lengths, axis=0),
         steps_dispatched=steps if have_steps else None,
+        alive_slot_steps=alive if have_alive else None,
         logprobs=np.concatenate(logps, axis=0) if have_logps else None,
     )
 
@@ -838,6 +844,7 @@ class GenerationEngine(LoraMailbox):
         # the KV cache (memory_analysis guard) hold their compiled fn here;
         # buckets where it did are marked None and use the host loop
         self._chunk_compiled: dict[int, Any] = {}
+        cfg.refuse_hybrid("the dense engine (engine_impl='dense')")
         self.cfg = cfg
         self.max_prompt_tokens = max_prompt_tokens
         self.max_new_tokens = max_new_tokens

@@ -60,6 +60,7 @@ from distrl_llm_tpu.engine.engine import (
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.models.transformer import forward
 from distrl_llm_tpu.ops.paged import (
+    DEFAULT_PAGE_SIZE,
     make_page_table,
     pages_per_seq,
 )
@@ -87,6 +88,11 @@ ENGINE_CONT_PREFILLS = "engine/cont_prefills"              # counter
 # whose re-prefill that in-place resume avoided (KV stayed resident)
 ENGINE_TURN_RESUMES = "engine/turn_resumes"                # counter
 ENGINE_TURN_PREFILL_SAVED = "engine/turn_prefill_saved_tokens"  # counter
+# block-sparse layers (MiniCPM-SALA): blocks a round's decode steps attended
+# and blocks they could see, summed over sparse layers, KV heads, live slots
+# and steps. Carried in the decode state, fetched with the round's result.
+ENGINE_SPARSE_BLOCKS_ATTENDED = "engine/sparse_blocks_attended"  # counter
+ENGINE_SPARSE_BLOCKS_VISIBLE = "engine/sparse_blocks_visible"    # counter
 
 Params = dict[str, Any]
 
@@ -101,6 +107,7 @@ class _PagedDecodeState(NamedTuple):
     seq_lengths: jax.Array  # [Bn] tokens resident in the cache per row
     k_pages: tuple  # L × [K, total_pages, ps, hd]
     v_pages: tuple
+    mixer: Any = None  # see _RefillState.mixer
 
 
 class _RefillState(NamedTuple):
@@ -124,6 +131,11 @@ class _RefillState(NamedTuple):
     page_indices: jax.Array  # [R, width] — rewritten per admit
     k_pages: tuple
     v_pages: tuple
+    # what a slot holds beside K/V pages when the model's layers differ in
+    # kind (models/hybrid.py::init_mixer_state: a float32 state per lightning
+    # layer, the selector's pooled keys per sparse layer, the round's
+    # block counter). None for a dense GQA model: no leaf, the same programs.
+    mixer: Any = None
 
 
 def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -136,6 +148,15 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
     packed_mask = (jnp.arange(p)[None, :] < real_len[:, None]).astype(mask.dtype)
     return packed * packed_mask, packed_mask, real_len
 
+
+
+def _count_sparse_blocks(mixer) -> None:
+    """File a round's block counter (``mixer["sel_stats"]``) with telemetry."""
+    if mixer is None:
+        return
+    attended, visible = (int(x) for x in np.asarray(mixer["sel_stats"]))
+    telemetry.counter_add(ENGINE_SPARSE_BLOCKS_ATTENDED, attended)
+    telemetry.counter_add(ENGINE_SPARSE_BLOCKS_VISIBLE, visible)
 
 
 def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
@@ -205,6 +226,108 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
         logits_positions=jnp.maximum(real_len - 1, 0),
     )
     return cache["k"], cache["v"], logits[:, 0], real_len
+
+
+#: tokens of one prefill segment of a model whose layers differ in kind
+HYBRID_PREFILL_SEGMENT = 1024
+
+
+def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
+                          cfg: ModelConfig, prompt_pages: int, page_size: int,
+                          lora_scale: float, cache_dtype, attn_impl: str,
+                          total_tokens: int):
+    """``_paged_prefill`` for a model with sparse and lightning layers: the
+    packed prompts run SEGMENT after segment (every row at the same
+    page-aligned offset), each through all layers, the cache carried between
+    them: K/V pages and pooled selector keys for the sparse layers, a state
+    for each lightning layer. A 20k-token prompt run whole would hold the
+    MLP's activations and a sparse layer's scores for all of it at once.
+
+    Returns ``(k tiles, v tiles, logits, real_len, mixer)``: ``mixer`` is each
+    PROMPT's state after its last real token and its pooled keys, which a
+    candidate that aliases the prompt's pages is also handed at admission."""
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.ops.linear import linear
+
+    b, p = prompt_ids.shape
+    pad_to = prompt_pages * page_size
+    seg_pages = max(
+        d for d in range(1, prompt_pages + 1)
+        if prompt_pages % d == 0 and d * page_size <= max(
+            HYBRID_PREFILL_SEGMENT, page_size)
+    )
+    seg, n_seg = seg_pages * page_size, prompt_pages // seg_pages
+    with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+        packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
+        packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
+        packed_mask = jnp.pad(packed_mask, ((0, 0), (0, pad_to - p)))
+    shape = (cfg.num_kv_heads, b * prompt_pages, page_size, cfg.head_dim)
+    n_sparse = cfg.kind_count("sparse")
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        mixer = init_mixer_state(cfg, b, total_tokens, cache_dtype)
+        cache = {
+            "k": tuple(jnp.zeros(shape, cache_dtype) for _ in range(n_sparse)),
+            "v": tuple(jnp.zeros(shape, cache_dtype) for _ in range(n_sparse)),
+            "lin": mixer["lin"], "pooled": mixer["pooled"],
+        }
+    table = jnp.asarray(make_page_table(b, pad_to, page_size))
+    last = jnp.maximum(real_len - 1, 0)
+
+    def one_segment(carry, xs):
+        cache, hidden = carry
+        ids, mask, start = xs
+        x, out = forward(
+            params, cfg, ids, attention_mask=mask, lora=lora,
+            lora_scale=lora_scale, attn_impl=attn_impl, page_size=page_size,
+            kv_cache={**cache, "lengths": real_len, "page_indices": table,
+                      "segment_start": start},
+            # the row's last real token, if it lies in this segment
+            logits_positions=jnp.clip(last - start, 0, seg - 1),
+            skip_lm_head=True,
+        )
+        here = (last >= start) & (last < start + seg)
+        hidden = jnp.where(here[:, None], x[:, 0], hidden)
+        return ({name: out[name] for name in cache}, hidden), None
+
+    segments = (
+        packed_ids.reshape(b, n_seg, seg).swapaxes(0, 1),
+        packed_mask.reshape(b, n_seg, seg).swapaxes(0, 1),
+        jnp.arange(n_seg, dtype=jnp.int32) * seg,
+    )
+    hidden0 = jnp.zeros((b, cfg.hidden_size), params["final_norm"].dtype)
+    (cache, hidden), _ = jax.lax.scan(one_segment, (cache, hidden0), segments)
+    with jax.named_scope(telemetry.MODEL_HEAD):
+        head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
+        logits = linear(hidden, head).astype(jnp.float32)
+    mixer = {**mixer, "lin": cache["lin"], "pooled": cache["pooled"]}
+    return cache["k"], cache["v"], logits, real_len, mixer
+
+
+def _hand_mixer(mixer, prompt_mixer, prompt_of, admit_mask):
+    """Admitted slots take their prompt's lightning states and pooled keys
+    (a candidate aliases its prompt's K/V pages; what is not in pages is
+    copied). Other slots and the round's counter are kept."""
+    if mixer is None:
+        return None
+
+    def hand(slot, prompt):
+        keep = admit_mask.reshape((-1,) + (1,) * (slot.ndim - 1))
+        return jnp.where(keep, prompt[prompt_of], slot)
+
+    return {
+        **mixer,
+        "lin": tuple(map(hand, mixer["lin"], prompt_mixer["lin"])),
+        "pooled": tuple(map(hand, mixer["pooled"], prompt_mixer["pooled"])),
+    }
+
+
+def _mixer_cache(mixer, alive):
+    """The entries a hybrid model's ``forward`` reads beside k/v pages."""
+    return {} if mixer is None else {**mixer, "alive": alive}
+
+
+def _mixer_from_cache(mixer, cache):
+    return None if mixer is None else {name: cache[name] for name in mixer}
 
 
 def _grow_pool(pages, extra_pages: int):
@@ -288,6 +411,7 @@ def _cont_adopt(state, k_tiles, v_tiles, dst_idx, logits_buf, logits_row, g):
 
 
 def _paged_fanout(prompt_k, prompt_v, last_logits, real_len, row_alive,
+                  prompt_mixer=None,
                   *, n: int, b: int, prompt_pages: int, private_pages: int,
                   page_size: int, max_steps: int):
     """Expand B prompts to B·n candidate rows with SHARED prompt prefixes.
@@ -337,6 +461,13 @@ def _paged_fanout(prompt_k, prompt_v, last_logits, real_len, row_alive,
         seq_lengths=jnp.repeat(real_len, n, axis=0),
         k_pages=k_pages,
         v_pages=v_pages,
+        # each candidate starts from its prompt's states and pooled keys
+        mixer=None if prompt_mixer is None else {
+            **prompt_mixer,
+            "lin": tuple(jnp.repeat(x, n, axis=0) for x in prompt_mixer["lin"]),
+            "pooled": tuple(
+                jnp.repeat(x, n, axis=0) for x in prompt_mixer["pooled"]),
+        },
     )
     return state, page_indices
 
@@ -377,6 +508,7 @@ def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices
         "k": s.k_pages, "v": s.v_pages,
         "lengths": s.seq_lengths,
         "page_indices": page_indices,
+        **_mixer_cache(s.mixer, ~s.done),
     }
     next_logits, cache = forward(
         params, cfg, tok[:, None],
@@ -392,6 +524,7 @@ def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices
         step=step, out=out, logps=logps, gen_lengths=gen_lengths,
         done=done, logits=next_logits[:, 0], seq_lengths=seq_lengths,
         k_pages=cache["k"], v_pages=cache["v"],
+        mixer=_mixer_from_cache(s.mixer, cache),
     )
 
 
@@ -424,7 +557,8 @@ def _paged_decode_chunk(params, lora, state: _PagedDecodeState, rng,
 def _refill_init(prompt_k, prompt_v, *, b: int, r_slots: int, total: int,
                  max_steps: int, vocab: int, pool_pages: int,
                  prompt_pages: int, private_pages: int, pad_id: int,
-                 shared_pages: int | None = None):
+                 shared_pages: int | None = None, cfg: ModelConfig | None = None,
+                 page_size: int = 0, cache_dtype=jnp.bfloat16):
     """Empty R-slot decode state over the shared prompt pool: every slot is
     born dead; ``_refill_admit`` assigns occupants (including the first R).
 
@@ -443,6 +577,11 @@ def _refill_init(prompt_k, prompt_v, *, b: int, r_slots: int, total: int,
     tiles, and the scratch page is physical page 0."""
     total_shared = b * prompt_pages if shared_pages is None else shared_pages
     width = prompt_pages + private_pages
+    mixer = None
+    if cfg is not None and cfg.hybrid:  # what a slot holds beside K/V pages
+        from distrl_llm_tpu.models.hybrid import init_mixer_state
+
+        mixer = init_mixer_state(cfg, r_slots, width * page_size, cache_dtype)
 
     return _RefillState(
         step=jnp.zeros((), jnp.int32),
@@ -458,6 +597,7 @@ def _refill_init(prompt_k, prompt_v, *, b: int, r_slots: int, total: int,
         page_indices=jnp.full((r_slots, width), total_shared, jnp.int32),
         k_pages=tuple(_grow_pool(x, pool_pages) for x in prompt_k),
         v_pages=tuple(_grow_pool(x, pool_pages) for x in prompt_v),
+        mixer=mixer,
     )
 
 
@@ -499,6 +639,7 @@ def _admit_tables(state, new_cand, admit_mask, real_len, dst_partial,
 @jax.named_scope(telemetry.ENGINE_ADMIT)
 def _refill_admit(state: _RefillState, new_cand, admit_mask, last_logits,
                   real_len, dst_partial, src_partial=None, copy_mask=None,
+                  prompt_mixer=None,
                   *, n: int, b: int, prompt_pages: int, page_size: int):
     """Assign candidates to slots (vLLM's scheduler admitting waiting
     sequences into freed slots, static-shape edition). All shapes are
@@ -524,6 +665,7 @@ def _refill_admit(state: _RefillState, new_cand, admit_mask, last_logits,
         page_indices=s.page_indices,
         k_pages=tuple(recopy(x) for x in s.k_pages),
         v_pages=tuple(recopy(x) for x in s.v_pages),
+        mixer=_hand_mixer(s.mixer, prompt_mixer, prompt_of, admit_mask & live_new),
     )
 
 
@@ -832,6 +974,7 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
         "k": s.k_pages, "v": s.v_pages,
         "lengths": s.seq_lengths,
         "page_indices": s.page_indices,
+        **_mixer_cache(s.mixer, alive),
     }
     next_logits, cache = forward(
         params, cfg, tok[:, None],
@@ -852,6 +995,7 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
         done=done, logits=next_logits[:, 0], seq_lengths=seq_lengths,
         gen_lengths=gen_lengths, page_indices=s.page_indices,
         k_pages=cache["k"], v_pages=cache["v"],
+        mixer=_mixer_from_cache(s.mixer, cache),
     )
 
 
@@ -1237,7 +1381,10 @@ class PagedGenerationEngine(LoraMailbox):
         cache_dtype=jnp.bfloat16,
         attn_impl: str = "reference",
         paged_impl: str = "auto",
-        page_size: int = 128,
+        # None = DEFAULT_PAGE_SIZE (128), or one block where the model has
+        # block-sparse layers (they attend by page); an explicit value that
+        # such a model cannot run under is refused, never replaced
+        page_size: int | None = None,
         decode_chunk: int = 128,
         # "none" | "int8" (per-token absmax KV cache, compact-scales Pallas
         # variants). None = consult the autotune plan DB
@@ -1533,6 +1680,32 @@ class PagedGenerationEngine(LoraMailbox):
         if kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be none/int8, got {kv_quant!r}")
         self.kv_quant = kv_quant
+        if cfg.hybrid:
+            # a model whose layers differ in kind (sparse + lightning): each
+            # slot also holds a recurrent state and a selector cache, handed
+            # over at admission. What re-derives or moves K/V alone refuses.
+            if kv_quant != "none":
+                cfg.refuse_hybrid(f"kv_quant={kv_quant!r} (an int8 KV pool)")
+            if spec_draft:
+                cfg.refuse_hybrid("spec_draft (speculative decoding)")
+            if prefix_sharing:
+                cfg.refuse_hybrid(
+                    "prefix_sharing / continuous_admission (pool-allocated "
+                    "prompt chains)")
+            if max_kv_pages:
+                cfg.refuse_hybrid(
+                    "max_kv_pages (a budgeted pool preempts by re-prefill)")
+            block = cfg.sparse_block_size if cfg.kind_count("sparse") else None
+            if block and page_size not in (None, block):
+                raise ValueError(
+                    f"page_size={page_size} cannot hold a model with "
+                    f"{cfg.mixer_names} layers: its block-sparse layers attend "
+                    f"by page, so a page is one block of {block} tokens "
+                    f"(sparse_block_size). Pass page_size={block} or leave it out."
+                )
+            page_size = page_size or block
+        if page_size is None:
+            page_size = DEFAULT_PAGE_SIZE
         # ---- tiered KV cache (ISSUE 18) resolution: tier 1 aliases cached
         # chains out of the continuous-admission pool, so it inherits the
         # cb_mode policy verbatim — explicit wins (including False), a
@@ -1578,6 +1751,10 @@ class PagedGenerationEngine(LoraMailbox):
             )
             pcache = False
         self.prefix_cache = bool(pcache)
+        if self.prefix_cache:
+            cfg.refuse_hybrid("prefix_cache (the radix cache over K/V pages)")
+        if kv_spill:
+            cfg.refuse_hybrid("kv_spill (K/V pages parked in host memory)")
         if kv_spill and not self.prefix_cache:
             raise ValueError(
                 "kv_spill parks KV pages through the tiered cache's host "
@@ -1763,6 +1940,11 @@ class PagedGenerationEngine(LoraMailbox):
 
         self._prefill = jax.jit(
             partial(
+                _paged_prefill_hybrid, cfg=cfg, prompt_pages=self.prompt_pages,
+                page_size=page_size, lora_scale=lora_scale,
+                cache_dtype=cache_dtype, attn_impl=attn_impl,
+                total_tokens=(self.prompt_pages + self.private_pages) * page_size,
+            ) if cfg.hybrid else partial(
                 _paged_prefill, cfg=cfg, prompt_pages=self.prompt_pages,
                 page_size=page_size, lora_scale=lora_scale,
                 cache_dtype=cache_dtype, attn_impl=attn_impl, kv_quant=kv_quant,
@@ -1790,6 +1972,7 @@ class PagedGenerationEngine(LoraMailbox):
             partial(
                 _refill_init, prompt_pages=self.prompt_pages,
                 private_pages=self.private_pages, pad_id=self.pad_id,
+                cfg=cfg, page_size=page_size, cache_dtype=cache_dtype,
             ),
             static_argnames=(
                 "b", "r_slots", "total", "max_steps", "vocab", "pool_pages",
@@ -2075,6 +2258,8 @@ class PagedGenerationEngine(LoraMailbox):
         self.last_pool_stats = None
         self.last_spec_stats = None
         self.last_round_stats = None  # waves/refill of THIS round accumulate
+        if self.turn_hook is not None:
+            self.cfg.refuse_hybrid("turn_hook (in-place multi-turn resume)")
         if self.turn_hook is not None and (
             self.scheduler != "refill" or not self.max_concurrent_rows
             or self.spec_draft
@@ -2169,6 +2354,7 @@ class PagedGenerationEngine(LoraMailbox):
             # is prefilled at [1, P] and adopted into pool-allocated chain
             # pages when the request queue admits it mid-round
             t_prefill = 0.0
+            prompt_mixer = ()
             shape0 = (self.cfg.num_kv_heads, 0, ps, self.cfg.head_dim)
             if self.kv_quant == "int8":
                 from distrl_llm_tpu.ops.paged import init_quantized_pages
@@ -2189,10 +2375,13 @@ class PagedGenerationEngine(LoraMailbox):
             t0 = time.perf_counter()
             with telemetry.span(telemetry.ENGINE_PREFILL, rows=b,
                                 tokens=prefill_tokens):
-                prompt_k, prompt_v, last_logits, real_len = self._prefill(
+                prompt_k, prompt_v, last_logits, real_len, *held = self._prefill(
                     params, lora, jnp.asarray(prompt_ids),
                     jnp.asarray(prompt_mask)
                 )
+                # a hybrid model's prefill also returns each prompt's
+                # lightning states and pooled keys, for its candidates
+                prompt_mixer = tuple(held)
                 jax.block_until_ready(last_logits)
             t_prefill = time.perf_counter() - t0
         t_decode0 = time.perf_counter()
@@ -2402,7 +2591,9 @@ class PagedGenerationEngine(LoraMailbox):
                 return self._refill_admit(
                     s, jnp.asarray(new_cand), jnp.asarray(admit_mask),
                     logits_cell[0], real_len, jnp.asarray(dst_partial),
-                    *_admit_extras(src_partial, copy_mask), n=n, b=b,
+                    *_admit_extras(src_partial, copy_mask),
+                    prompt_mixer=prompt_mixer[0] if prompt_mixer else None,
+                    n=n, b=b,
                 )
 
             def admit_last_pos(rl: int, plen: int) -> int:
@@ -3592,6 +3783,7 @@ class PagedGenerationEngine(LoraMailbox):
             if c < total:
                 mark_finished(int(c))
         alive_h = int(np.asarray(state.alive_steps))
+        _count_sparse_blocks(getattr(state, "mixer", None))
         if cache_on:
             # park every resident cached page host-side: device page ids
             # are round-scoped, so the tree survives between rounds as a
@@ -3609,7 +3801,9 @@ class PagedGenerationEngine(LoraMailbox):
             # which devices held this round's KV pages (role placement)
             "kv_devices": sorted(
                 d.id
-                for d in jax.tree_util.tree_leaves(state.k_pages)[0].devices()
+                # (any leaf stands in where no layer has K/V: all-lightning)
+                for d in jax.tree_util.tree_leaves(
+                    (state.k_pages, state))[0].devices()
             ),
             "pool_pages": pool_pages,
             "worst_case_pages": worst_pool,
@@ -3862,7 +4056,7 @@ class PagedGenerationEngine(LoraMailbox):
         prefill_tokens = int(np.asarray(prompt_mask).sum())
         t0 = time.perf_counter()
         with telemetry.span(telemetry.ENGINE_PREFILL, rows=b, tokens=prefill_tokens):
-            prompt_k, prompt_v, last_logits, real_len = self._prefill(
+            prompt_k, prompt_v, last_logits, real_len, *prompt_mixer = self._prefill(
                 params, lora, jnp.asarray(prompt_ids), jnp.asarray(prompt_mask)
             )
             jax.block_until_ready(last_logits)
@@ -3873,6 +4067,7 @@ class PagedGenerationEngine(LoraMailbox):
         dec_span.__enter__()
         state, page_indices = self._fanout(
             prompt_k, prompt_v, last_logits, real_len, row_alive,
+            prompt_mixer=prompt_mixer[0] if prompt_mixer else None,
             n=n, b=b, max_steps=max_steps,
         )
 
@@ -3946,6 +4141,7 @@ class PagedGenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
+            _count_sparse_blocks(state.mixer)
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
         decode_s = time.perf_counter() - t1
@@ -3959,4 +4155,9 @@ class PagedGenerationEngine(LoraMailbox):
             decode_s=decode_s, gen_tokens=gen_tokens,
             gen_rows=b * n,
         )
-        return GenerationResult(tokens=out, lengths=lengths, logprobs=logps)
+        # a wave steps in lockstep with no speculation: a row is alive at a
+        # step exactly when it emits a token there
+        return GenerationResult(
+            tokens=out, lengths=lengths, logprobs=logps,
+            steps_dispatched=steps_seen[0], alive_slot_steps=gen_tokens,
+        )

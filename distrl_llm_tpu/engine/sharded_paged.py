@@ -117,6 +117,7 @@ class ShardedPagedEngine(LoraMailbox):
         # error instead of a TypeError deep in trainer wiring
         spec_draft: int | None = None,
     ):
+        cfg.refuse_hybrid("the dp-sharded paged engine (engine_impl='paged_sharded')")
         if spec_draft:
             raise NotImplementedError(
                 "speculative decoding is a per-replica refill-scheduler "

@@ -9,11 +9,24 @@ with optional QKV bias (Qwen2 yes), gated MLP with SiLU or tanh-GELU
 sqrt(hidden) embedding scaling (Gemma), optional tied embeddings, and a
 recorded sliding window (Mistral v0.1 — full attention is exact for
 sequences within the window; the engines enforce that).
+
+A model whose layers are not all alike (MiniCPM-SALA: block-sparse attention
+layers beside lightning linear-attention layers) carries ``mixer_types``, the
+published per-layer list. ``None`` means every layer is the dense GQA layer
+above, and nothing else in this file applies. The list is kept WHOLE when the
+depth is cut: ``num_layers`` runs its first entries, and a lightning layer's
+decay reads its published index.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
+
+#: published ``mixer_types`` entry -> the kind the program names its stacks by
+MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+#: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
+KNOWN_MODEL_TYPES = ("", "qwen2", "llama", "mistral", "gemma", "minicpm_sala")
 
 
 @dataclass(frozen=True)
@@ -37,12 +50,136 @@ class ModelConfig:
     # REFUSE sequences longer than the window (full attention ≡ SWA within
     # it) rather than silently change the model's semantics
     sliding_window: int | None = None
+    # ---- per-layer mixers (MiniCPM-SALA). ``mixer_types`` is the PUBLISHED
+    # list, whole; None = every layer is dense GQA and nothing below is read
+    mixer_types: tuple[str, ...] | None = None
+    qk_norm: bool = False  # RMSNorm over head_dim on q and k, one weight each
+    attn_use_rope: bool = True  # sparse layers: MiniCPM-SALA rotates nothing
+    attn_output_gate: bool = False  # sparse layers: y = Wo(o * sigmoid(Wz h))
+    lightning_heads: int = 0  # q, k and v heads alike (lightning_nh == nkv)
+    lightning_head_dim: int = 0
+    lightning_use_rope: bool = True
+    lightning_output_gate: bool = False
+    lightning_output_norm: bool = False  # RMSNorm over the joined head dims
+    # InfLLM-V2 selector (MiniCPM4.1's published sparse_config)
+    sparse_kernel_size: int = 32  # keys mean-pooled into one selector key
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64  # the unit of choice (and the engine's page)
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192  # a context at most this long attends densely
+    # muP: x0 = scale_emb*E, x += scale_depth/sqrt(L_published)*f(x),
+    # logits = head(norm(x) / (hidden/dim_model_base)); 0 = off
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
                 f"hidden_act must be silu/gelu_tanh, got {self.hidden_act!r}"
             )
+        if self.mixer_types is not None:
+            unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
+            if unknown:
+                raise ValueError(
+                    f"mixer_types holds {unknown}: the layer kinds this program "
+                    f"runs are {sorted(MIXER_KINDS)}"
+                )
+            if self.num_layers > len(self.mixer_types):
+                raise ValueError(
+                    f"num_layers {self.num_layers} exceeds the {len(self.mixer_types)} "
+                    "published mixer_types"
+                )
+            if (self.sparse_block_size % self.sparse_kernel_stride
+                    or self.sparse_kernel_size % self.sparse_kernel_stride):
+                raise ValueError(
+                    "the selector needs kernel_size and block_size to be multiples "
+                    "of kernel_stride"
+                )
+
+    # ------------------------------------------------------ per-layer pattern
+
+    @property
+    def hybrid(self) -> bool:
+        """True where layers differ in kind (``mixer_types`` is set)."""
+        return self.mixer_types is not None
+
+    @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        """Kind of each layer that is RUN ("dense" | "sparse" | "lightning")."""
+        if self.mixer_types is None:
+            return ("dense",) * self.num_layers
+        return tuple(MIXER_KINDS[m] for m in self.mixer_types[: self.num_layers])
+
+    def kind_count(self, kind: str) -> int:
+        return sum(1 for k in self.layer_kinds if k == kind)
+
+    @property
+    def layer_runs(self) -> tuple[tuple[str, int, int, int], ...]:
+        """Runs of like layers in published order: (kind, first layer's index
+        in the model, first layer's index in its kind's stack, count)."""
+        runs, seen = [], {}
+        for i, kind in enumerate(self.layer_kinds):
+            at = seen.get(kind, 0)
+            if runs and runs[-1][0] == kind:
+                runs[-1][3] += 1
+            else:
+                runs.append([kind, i, at, 1])
+            seen[kind] = at + 1
+        return tuple(tuple(r) for r in runs)
+
+    @property
+    def mixer_names(self) -> str:
+        """The published names of the non-dense layer kinds, for a refusal."""
+        return ", ".join(sorted(set(self.mixer_types or ())))
+
+    def refuse_hybrid(self, what: str) -> None:
+        """Raise, naming the mixer kinds, where ``what`` holds K/V of one
+        kind for every layer and so cannot hold this model. The single owner
+        of that sentence for every engine and feature."""
+        if self.hybrid:
+            raise ValueError(
+                f"{what} cannot hold a model with {self.mixer_names} layers: it "
+                "keeps one kind of K/V for every layer, and these layers keep a "
+                "recurrent state or a selector cache. Use engine_impl='paged' "
+                "without it."
+            )
+
+    @property
+    def residual_scale(self) -> float:
+        """What each block's output is multiplied by before it joins the
+        stream: scale_depth / sqrt(published depth), or 1."""
+        if not self.scale_depth:
+            return 1.0
+        depth = len(self.mixer_types) if self.mixer_types else self.num_layers
+        return self.scale_depth / math.sqrt(depth)
+
+    @property
+    def logit_scale(self) -> float:
+        """What the final hidden state is multiplied by before the head."""
+        return self.dim_model_base / self.hidden_size if self.dim_model_base else 1.0
+
+    @property
+    def lightning_dim(self) -> int:
+        return self.lightning_heads * self.lightning_head_dim
+
+    def lightning_decay_rates(self):
+        """[n_lightning, heads] float32 numpy: the per-token log-decay
+        ``s_h * (1 - l/(L-1) + 1e-5)`` of each lightning layer that is run,
+        with ``s_h = 2^(-8h/H)``, h = 1..H, and ``l`` the layer's PUBLISHED
+        index (Lightning Attention's ALiBi-style slopes)."""
+        import numpy as np
+
+        heads = self.lightning_heads
+        slopes = 2.0 ** (-8.0 * np.arange(1, heads + 1) / heads)
+        last = max(len(self.mixer_types) - 1, 1)
+        rows = [
+            slopes * (1.0 - i / last + 1e-5)
+            for i, k in enumerate(self.layer_kinds) if k == "lightning"
+        ]
+        return np.asarray(rows, np.float32).reshape(len(rows), heads)
 
     @property
     def q_dim(self) -> int:
@@ -71,13 +208,22 @@ class ModelConfig:
         biases/norms excluded as FLOP-negligible, embedding lookups are not
         matmuls). The 2·N term of every FLOPs-per-token estimate — the
         single owner for bench.py and the telemetry MFU series."""
-        per_layer = (
-            self.hidden_size * self.q_dim          # q proj
-            + 2 * self.hidden_size * self.kv_dim   # k, v proj
-            + self.q_dim * self.hidden_size        # o proj
-            + 3 * self.hidden_size * self.intermediate_size  # gate, up, down
+        mlp = 3 * self.hidden_size * self.intermediate_size  # gate, up, down
+        attn = (
+            2 * self.hidden_size * self.q_dim       # q, o proj
+            + 2 * self.hidden_size * self.kv_dim    # k, v proj
         )
-        return self.num_layers * per_layer + self.hidden_size * self.vocab_size
+        if not self.hybrid:
+            return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
+        sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
+        lightning = self.hidden_size * self.lightning_dim * (
+            4 + self.lightning_output_gate
+        )
+        return (
+            self.kind_count("sparse") * (sparse + mlp)
+            + self.kind_count("lightning") * (lightning + mlp)
+            + self.hidden_size * self.vocab_size
+        )
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
         """Model FLOPs per decoded token: 2·(matmul params) for the dense
@@ -96,6 +242,8 @@ class ModelConfig:
     def model_type(self) -> str:
         """The HF model_type this config round-trips through
         ``from_hf_config`` as (used by HF-format snapshot export)."""
+        if self.hybrid:
+            return "minicpm_sala"
         if self.rmsnorm_offset:
             return "gemma"
         if self.sliding_window is not None:
@@ -118,7 +266,54 @@ class ModelConfig:
                 f"model_type {mt!r} is not supported (Gemma-1 only); "
                 "its extra norms/softcapping would be silently dropped"
             )
+        if mt not in KNOWN_MODEL_TYPES:
+            # every key this function does not know is ignored, so an unknown
+            # architecture would load, silently, as a dense GQA decoder
+            raise ValueError(
+                f"model_type {mt!r} is not supported (known: "
+                f"{', '.join(t for t in KNOWN_MODEL_TYPES if t)}); loading it as "
+                "a dense GQA decoder would silently drop what makes it differ"
+            )
         gemma = mt == "gemma"
+        hybrid: dict = {}
+        mixers = get("mixer_types")
+        if mixers is not None or mt == "minicpm_sala":
+            if not mixers:
+                raise ValueError(f"model_type {mt!r} needs its mixer_types list")
+            if get("lightning_nkv", get("lightning_nh")) != get("lightning_nh"):
+                raise ValueError(
+                    "lightning layers with fewer k/v heads than q heads "
+                    f"(lightning_nkv {get('lightning_nkv')} != lightning_nh "
+                    f"{get('lightning_nh')}) are not supported"
+                )
+            if str(get("lightning_scale", "1/sqrt(d)")) != "1/sqrt(d)":
+                raise ValueError(
+                    f"lightning_scale {get('lightning_scale')!r} is not supported"
+                )
+            sparse = dict(get("sparse_config") or {})
+            hybrid = dict(
+                mixer_types=tuple(mixers),
+                qk_norm=bool(get("qk_norm", False)),
+                attn_use_rope=bool(get("attn_use_rope", True)),
+                attn_output_gate=bool(get("attn_use_output_gate", False)),
+                lightning_heads=int(get("lightning_nh", num_heads)),
+                lightning_head_dim=int(
+                    get("lightning_head_dim", None) or get("head_dim", None)
+                    or hf.hidden_size // num_heads
+                ),
+                lightning_use_rope=bool(get("lightning_use_rope", True)),
+                lightning_output_gate=bool(get("use_output_gate", False)),
+                lightning_output_norm=bool(get("use_output_norm", False)),
+                scale_emb=float(get("scale_emb", 1.0)),
+                scale_depth=float(get("scale_depth", 0.0)),
+                dim_model_base=int(get("dim_model_base", 0)),
+                **{
+                    f"sparse_{key}": int(sparse[key])
+                    for key in ("kernel_size", "kernel_stride", "block_size", "topk",
+                                "init_blocks", "window_size", "dense_len")
+                    if key in sparse
+                },
+            )
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -132,13 +327,14 @@ class ModelConfig:
             head_dim=get("head_dim", None) or hf.hidden_size // num_heads,
             rope_theta=get("rope_theta", 10000.0),
             rms_norm_eps=get("rms_norm_eps", 1e-6),
-            attention_bias=hf.model_type == "qwen2" or bool(get("attention_bias", False)),
+            attention_bias=mt == "qwen2" or bool(get("attention_bias", False)),
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
             max_position_embeddings=get("max_position_embeddings", 32768),
             hidden_act="gelu_tanh" if "gelu" in act else "silu",
             rmsnorm_offset=gemma,
             scale_embeddings=gemma,
             sliding_window=int(window) if window else None,
+            **hybrid,
         )
 
 

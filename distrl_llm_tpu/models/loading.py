@@ -35,6 +35,20 @@ _HF_LAYER_MAP = {
     "mlp_norm": "post_attention_layernorm.weight",
 }
 
+# what a layer of a model with per-layer mixers (MiniCPM-SALA, models/hybrid.py)
+# holds beside the names above, in both its kinds: the per-head q/k norms, the
+# output gate, and a lightning layer's norm over its joined heads. The two
+# kinds share every tensor name; which stack a layer's tensors join is read
+# off ``cfg.layer_kinds``. (No checkpoint was at hand when this was written:
+# the names follow the family's modeling file as MiniCPM4 names them.)
+_HF_HYBRID_MAP = {
+    **{k: v for k, v in _HF_LAYER_MAP.items() if not k.startswith("b")},
+    "q_norm": "self_attn.q_norm.weight",
+    "k_norm": "self_attn.k_norm.weight",
+    "wz": "self_attn.o_gate.weight",
+    "o_norm": "self_attn.o_norm.weight",
+}
+
 
 def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
     if name in sd:
@@ -50,6 +64,8 @@ def params_from_state_dict(
     sd: Mapping[str, np.ndarray], cfg: ModelConfig, dtype=np.float32
 ) -> Params:
     """Numpy state dict (HF names) → our stacked param pytree."""
+    if cfg.hybrid:
+        return _hybrid_params_from_state_dict(sd, cfg, dtype)
 
     def stack(key: str, hf_name: str) -> np.ndarray:
         per_layer = [
@@ -65,6 +81,38 @@ def params_from_state_dict(
         for key, hf_name in _HF_LAYER_MAP.items()
         if cfg.attention_bias or not key.startswith("b")
     }
+    params: Params = {
+        "embed": _get(sd, "model.embed_tokens.weight").astype(dtype),
+        "final_norm": _get(sd, "model.norm.weight").astype(dtype),
+        "layers": layers,
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = _get(sd, "lm_head.weight").astype(dtype).T
+    return params
+
+
+def _hybrid_layer_keys(cfg: ModelConfig, kind: str) -> list[str]:
+    """The leaves of one layer of ``kind``, as ``init_hybrid_params`` lays
+    them out."""
+    gate = cfg.attn_output_gate if kind == "sparse" else cfg.lightning_output_gate
+    skip = set() if cfg.qk_norm else {"q_norm", "k_norm"}
+    skip |= set() if gate else {"wz"}
+    skip |= set() if kind == "lightning" and cfg.lightning_output_norm else {"o_norm"}
+    return [k for k in _HF_HYBRID_MAP if k not in skip]
+
+
+def _hybrid_params_from_state_dict(sd, cfg: ModelConfig, dtype) -> Params:
+    """One stack per layer kind, each in the order its layers appear in the
+    model: layer i's tensors join the stack of ``cfg.layer_kinds[i]``."""
+    layers: Params = {}
+    for kind in dict.fromkeys(cfg.layer_kinds):
+        at = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+        layers[kind] = {}
+        for key in _hybrid_layer_keys(cfg, kind):
+            out = np.stack([
+                _get(sd, f"model.layers.{i}.{_HF_HYBRID_MAP[key]}") for i in at
+            ]).astype(dtype)
+            layers[kind][key] = out.transpose(0, 2, 1) if key.startswith("w") else out
     params: Params = {
         "embed": _get(sd, "model.embed_tokens.weight").astype(dtype),
         "final_norm": _get(sd, "model.norm.weight").astype(dtype),
@@ -97,6 +145,17 @@ def state_dict_from_params(params: Params, cfg: ModelConfig) -> dict[str, np.nda
     inverse of ``params_from_state_dict``)."""
     sd: dict[str, np.ndarray] = {}
     layers = params["layers"]
+    if cfg.hybrid:
+        for kind, stack in layers.items():
+            at = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+            for key, stacked in stack.items():
+                stacked = np.asarray(stacked)
+                if key.startswith("w"):
+                    stacked = stacked.transpose(0, 2, 1)
+                for j, i in enumerate(at):
+                    sd[f"model.layers.{i}.{_HF_HYBRID_MAP[key]}"] = (
+                        np.ascontiguousarray(stacked[j]))
+        layers = {}
     for key, hf_name in _HF_LAYER_MAP.items():
         if key not in layers:
             continue

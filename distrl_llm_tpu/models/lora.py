@@ -45,18 +45,38 @@ def init_lora_params(
     dtype=jnp.float32,
 ) -> Params:
     """A ~ N(0, 1/r) (std r^-1/2), B = 0 — output delta starts at 0 and the
-    initial A@B gradient scale is rank-independent."""
-    layers: Params = {}
-    keys = jax.random.split(rng, len(targets))
-    for key, target in zip(keys, targets):
-        d_in = getattr(cfg, _TARGET_DIMS[target][0])
-        d_out = getattr(cfg, _TARGET_DIMS[target][1])
-        a = jax.random.normal(key, (cfg.num_layers, d_in, rank)) * (rank**-0.5)
-        layers[target] = {
-            "a": a.astype(dtype),
-            "b": jnp.zeros((cfg.num_layers, rank, d_out), dtype),
-        }
-    return {"layers": layers}
+    initial A@B gradient scale is rank-independent.
+
+    A model whose layers differ in kind (``cfg.hybrid``) gets one set of
+    factors per kind, ``{"layers": {kind: {target: {"a", "b"}}}}``, stacked
+    like that kind's base weights: a lightning layer's k and v project to all
+    its heads, a sparse layer's to its few KV heads."""
+    def factors(rng, n_layers: int, dims: dict[str, int]) -> Params:
+        layers: Params = {}
+        for key, target in zip(jax.random.split(rng, len(targets)), targets):
+            d_in, d_out = (dims[attr] for attr in _TARGET_DIMS[target])
+            a = jax.random.normal(key, (n_layers, d_in, rank)) * (rank**-0.5)
+            layers[target] = {
+                "a": a.astype(dtype),
+                "b": jnp.zeros((n_layers, rank, d_out), dtype),
+            }
+        return layers
+
+    dims = {
+        attr: getattr(cfg, attr)
+        for attr in ("hidden_size", "intermediate_size", "q_dim", "kv_dim")
+    }
+    if not cfg.hybrid:
+        return {"layers": factors(rng, cfg.num_layers, dims)}
+    per_kind = {
+        "sparse": dims,
+        "lightning": {**dims, "q_dim": cfg.lightning_dim, "kv_dim": cfg.lightning_dim},
+    }
+    kinds = [k for k in per_kind if cfg.kind_count(k)]
+    return {"layers": {
+        kind: factors(key, cfg.kind_count(kind), per_kind[kind])
+        for key, kind in zip(jax.random.split(rng, len(kinds)), kinds)
+    }}
 
 
 def merge_lora(base: Params, lora: Params, alpha: float) -> Params:
@@ -64,19 +84,26 @@ def merge_lora(base: Params, lora: Params, alpha: float) -> Params:
     for checkpoint export, mirroring the reference's save_pretrained artifact
     (distributed_actor.py:263–264). Rank is derived from the adapter shapes so
     the scale can't silently mismatch."""
-    rank = next(iter(lora["layers"].values()))["a"].shape[-1]
-    scale = lora_scale(rank, alpha)
-    merged_layers = dict(base["layers"])
-    for target, ab in lora["layers"].items():
-        w = base["layers"][target]
-        from distrl_llm_tpu.ops.quant import is_quantized
+    from distrl_llm_tpu.ops.quant import is_quantized
 
-        if is_quantized(w):
-            raise NotImplementedError("cannot merge LoRA into quantized base weights")
-        delta = jnp.einsum("lir,lro->lio", ab["a"].astype(w.dtype), ab["b"].astype(w.dtype))
-        merged_layers[target] = w + delta * scale
+    def merge(weights: Params, factors: Params) -> Params:
+        merged = dict(weights)
+        for target, ab in factors.items():
+            if "a" not in ab:  # one more level: a layer kind's own stacks
+                merged[target] = merge(weights[target], ab)
+                continue
+            w = weights[target]
+            if is_quantized(w):
+                raise NotImplementedError(
+                    "cannot merge LoRA into quantized base weights")
+            scale = lora_scale(ab["a"].shape[-1], alpha)
+            delta = jnp.einsum(
+                "lir,lro->lio", ab["a"].astype(w.dtype), ab["b"].astype(w.dtype))
+            merged[target] = w + delta * scale
+        return merged
+
     out = dict(base)
-    out["layers"] = merged_layers
+    out["layers"] = merge(base["layers"], lora["layers"])
     return out
 
 

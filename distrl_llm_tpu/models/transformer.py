@@ -167,6 +167,14 @@ def _layer(
         att = att.reshape(b, s, cfg.q_dim)
         x = x + proj(att, p, lora, "wo", "bo", lora_scale)
 
+    x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    return x, cache_k, cache_v, cache_k_scale, cache_v_scale
+
+
+def _mlp_half(x, p: Params, lora, *, cfg: ModelConfig, proj, lora_scale,
+              residual_scale=None):
+    """The second half of a layer of any kind: norm, gated MLP, residual
+    (``residual_scale``: muP's factor on what joins the stream, or None)."""
     with jax.named_scope(telemetry.MODEL_MLP):
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
         act = (
@@ -175,8 +183,8 @@ def _layer(
         )
         gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
         up = proj(h, p, lora, "w_up", "b_up", lora_scale)
-        x = x + proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
-    return x, cache_k, cache_v, cache_k_scale, cache_v_scale
+        y = proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
+        return x + (y if residual_scale is None else residual_scale * y)
 
 
 def _attend(
@@ -426,6 +434,21 @@ def forward(
     prompt pages, and decode runs paged attention over each row's true
     [0, length+1) prefix.
     """
+    if cfg.hybrid:
+        # layers of several kinds (MiniCPM-SALA): models/hybrid.py has the
+        # layer bodies, the per-kind stacks and the cache of two kinds of state
+        from distrl_llm_tpu.models.hybrid import forward_hybrid
+
+        return forward_hybrid(
+            params, cfg, input_ids, attention_mask=attention_mask,
+            positions=positions, lora=lora, lora_scale=lora_scale,
+            kv_cache=kv_cache, remat=remat, attn_impl=attn_impl,
+            logits_slice=logits_slice, logits_positions=logits_positions,
+            page_size=page_size, lora_dropout=lora_dropout,
+            dropout_rng=dropout_rng, skip_lm_head=skip_lm_head,
+            attn_mesh=attn_mesh, paged_verify=paged_verify,
+            paged_chunked=paged_chunked, paged_prefix=paged_prefix,
+        )
     b, s = input_ids.shape
     paged = kv_cache is not None and "page_indices" in kv_cache
     if kv_cache is not None and not paged and isinstance(cache_offset, int):
@@ -560,6 +583,8 @@ def _head(x, params: Params, cfg: ModelConfig, logits_slice, logits_positions,
     """Final norm, the positions the caller wants, and the output head."""
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                  offset=cfg.rmsnorm_offset)
+    if cfg.logit_scale != 1.0:  # muP: the head reads x / (hidden / dim_model_base)
+        x = x * jnp.asarray(cfg.logit_scale, x.dtype)
     if logits_slice is not None:
         # project only the needed positions — the learner's logprob recompute
         # discards all prompt logits, so slicing the hidden states first skips
@@ -586,31 +611,54 @@ def init_params(
     rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32
 ) -> Params:
     """Random init with HF-comparable scales (normal 0.02 for projections)."""
-    keys = iter(jax.random.split(rng, 16))
-    init = lambda k, shape: (0.02 * jax.random.normal(k, shape)).astype(dtype)
-    L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
-    layers: Params = {
-        "attn_norm": jnp.ones((L, D), dtype),
-        "mlp_norm": jnp.ones((L, D), dtype),
-        "wq": init(next(keys), (L, D, cfg.q_dim)),
-        "wk": init(next(keys), (L, D, cfg.kv_dim)),
-        "wv": init(next(keys), (L, D, cfg.kv_dim)),
-        "wo": init(next(keys), (L, cfg.q_dim, D)),
-        "w_gate": init(next(keys), (L, D, F)),
-        "w_up": init(next(keys), (L, D, F)),
-        "w_down": init(next(keys), (L, F, D)),
-    }
+    if cfg.hybrid:
+        from distrl_llm_tpu.models.hybrid import init_hybrid_params
+
+        return init_hybrid_params(rng, cfg, dtype)
+    init = _normal_init(rng, 16, dtype)
+    L = cfg.num_layers
+    layers = _init_layer_stack(init, cfg, L, cfg.q_dim, cfg.kv_dim, dtype)
     if cfg.attention_bias:
         layers["bq"] = jnp.zeros((L, cfg.q_dim), dtype)
         layers["bk"] = jnp.zeros((L, cfg.kv_dim), dtype)
         layers["bv"] = jnp.zeros((L, cfg.kv_dim), dtype)
+    return _init_around_layers(init, cfg, layers, dtype)
+
+
+def _normal_init(rng: jax.Array, draws: int, dtype):
+    """``init(shape)``: Normal(0, 0.02) from the next of ``draws`` keys."""
+    keys = iter(jax.random.split(rng, draws))
+    return lambda shape: (0.02 * jax.random.normal(next(keys), shape)).astype(dtype)
+
+
+def _init_layer_stack(init, cfg: ModelConfig, n: int, q_dim: int, kv_dim: int,
+                      dtype) -> Params:
+    """``n`` stacked layers' norms, the four mixer projections and the gated
+    MLP: what a layer of any kind holds (a kind adds its own leaves)."""
+    D, F = cfg.hidden_size, cfg.intermediate_size
+    return {
+        "attn_norm": jnp.ones((n, D), dtype),
+        "mlp_norm": jnp.ones((n, D), dtype),
+        "wq": init((n, D, q_dim)),
+        "wk": init((n, D, kv_dim)),
+        "wv": init((n, D, kv_dim)),
+        "wo": init((n, q_dim, D)),
+        "w_gate": init((n, D, F)),
+        "w_up": init((n, D, F)),
+        "w_down": init((n, F, D)),
+    }
+
+
+def _init_around_layers(init, cfg: ModelConfig, layers: Params, dtype) -> Params:
+    """The embedding, the final norm and the head round ``layers``."""
+    D = cfg.hidden_size
     params: Params = {
-        "embed": init(next(keys), (cfg.vocab_size, D)),
+        "embed": init((cfg.vocab_size, D)),
         "final_norm": jnp.ones((D,), dtype),
         "layers": layers,
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = init(next(keys), (D, cfg.vocab_size))
+        params["lm_head"] = init((D, cfg.vocab_size))
     return params
 
 

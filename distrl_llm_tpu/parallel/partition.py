@@ -16,6 +16,9 @@ Layout conventions (models/transformer.py):
                is always replicated.
   kv cache:    per-layer tuples of [B, K, hd, S]; batch over "dp", kv heads
                over "tp" (build it with models.init_kv_cache).
+  hybrid:      a model whose layers differ in kind keeps one stack per kind,
+               layers/<kind>/w* (models/hybrid.py); the rules read a leaf's
+               own name and its target's, so the extra level changes nothing.
 """
 
 from __future__ import annotations
@@ -28,7 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Params = dict[str, Any]
 
 # layer weights whose OUT dim is tp-sharded (column parallel)
-_COL = {"wq", "wk", "wv", "w_gate", "w_up"}
+# (wz: the output gate of a sparse or lightning layer, models/hybrid.py)
+_COL = {"wq", "wk", "wv", "wz", "w_gate", "w_up"}
 # layer weights whose IN dim is tp-sharded (row parallel)
 _ROW = {"wo", "w_down"}
 
@@ -45,7 +49,7 @@ def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:
         return P("tp", "fsdp")
     if name == "lm_head":
         return P("fsdp", "tp")
-    if name in ("final_norm", "attn_norm", "mlp_norm"):
+    if name.endswith("norm"):  # final/attn/mlp, and a hybrid layer's q/k/o norms
         return P(*([None] * ndim))
     if name in _COL:
         return P(None, "fsdp", "tp")

@@ -93,6 +93,12 @@ MODEL_ATTN_PROJ = "model/attn_proj"
 MODEL_ATTN_CORE = "model/attn_core"
 MODEL_MLP = "model/mlp"
 MODEL_HEAD = "model/head"
+# the mixers of a model whose layers differ in kind (MiniCPM-SALA): the linear
+# attention (chunked or one step), the block-sparse layer's choice of blocks,
+# and its attention over the chosen blocks
+MODEL_LINEAR_ATTN = "model/linear_attn"
+MODEL_SPARSE_SELECT = "model/sparse_select"
+MODEL_SPARSE_ATTN = "model/sparse_attn"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -123,6 +129,7 @@ SCOPE_NAMES = (
     KERNEL_PAGED_ATTENTION, KERNEL_QUANT_MATMUL, KERNEL_FLASH, KERNEL_SPLASH,
     LEARNER_LOSS, LEARNER_LOSS_LOGPROB, LEARNER_GRAD_ACCUM, LEARNER_OPTIMIZER,
     LEARNER_OPTIMIZER_CODEC,
+    MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
 )
 
 

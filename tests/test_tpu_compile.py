@@ -133,16 +133,80 @@ def test_paged_verify(chip, quantized):
     )
 
 
+@pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_fused_sampler(chip, dtype):
+def test_fused_sampler(chip, dtype, vocab):
+    """73,448 (MiniCPM-SALA) is no multiple of 128: the row is padded to whole
+    (8, 128) tiles with columns that can neither win nor carry mass."""
     from distrl_llm_tpu.ops.sampling import fused_sample
 
     assert_kernel(
         fused_sample,
-        chip((2,), jnp.uint32), chip((ROWS, VOCAB), dtype),
+        chip((2,), jnp.uint32), chip((ROWS, vocab), dtype),
         chip((), jnp.float32), chip((), jnp.float32),
     )
+
+
+def sala_config():
+    """MiniCPM-SALA's widths (perfbench/configs/minicpm-sala-L10.json), two
+    layers of each kind."""
+    from distrl_llm_tpu.models import ModelConfig
+
+    return ModelConfig(
+        vocab_size=73448, hidden_size=4096, intermediate_size=16384, num_layers=4,
+        num_heads=32, num_kv_heads=2, head_dim=128,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn", "minicpm4"),
+        lightning_heads=32, lightning_head_dim=128, qk_norm=True, attn_use_rope=False,
+        attn_output_gate=True, lightning_output_gate=True, lightning_output_norm=True,
+        scale_emb=12.0, scale_depth=1.4, dim_model_base=256,
+    )
+
+
+@pytest.mark.parametrize("mode", ["decode", "segment"])
+def test_sala_mixers_at_published_widths(chip, mode):
+    """The two mixers of MiniCPM-SALA are plain XLA (no Mosaic kernel yet):
+    what is held here is that the chip's compiler takes them at the published
+    widths, 21k tokens of context a slot, in both cache modes: the decode
+    step's sort, top-k and page gather, and a prefill segment's masked scores
+    and float32 HIGHEST chunked scan."""
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
+    from distrl_llm_tpu.ops.sparse_attention import (
+        pool_keys, sparse_attend, sparse_decode, update_pooled,
+    )
+
+    cfg = sala_config()
+    rows, width = 8, 329  # page-table columns of 20,480 + 512 tokens in pages of 64
+    state = jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * 64))
+    pages = chip((2, rows * width, 64, 128), jnp.bfloat16)
+    pooled = chip(state["pooled"][0].shape, jnp.bfloat16)
+    lin = chip(state["lin"][0].shape, jnp.float32)
+    rates = chip((32,), jnp.float32)
+    if mode == "decode":
+        def step(q, k_pages, v_pages, pooled, lengths, table, ql, kl, vl, lin, rates):
+            pooled = update_pooled(pooled, k_pages, lengths + 1, table, cfg)
+            out, stats = sparse_decode(q, k_pages, v_pages, pooled, lengths, table, cfg)
+            return out, stats, pooled, lightning_step(ql, kl, vl, rates, lin)
+
+        head = chip((rows, 32, 128), jnp.bfloat16)
+        compiled = jax.jit(step).lower(
+            head, pages, pages, pooled, chip((rows,), jnp.int32),
+            chip((rows, width), jnp.int32), head, head, head, lin, rates,
+        ).compile()
+    else:
+        def segment(q, k, v, pos, ql, kl, vl, valid, lin, rates):
+            out = sparse_attend(q, k, v, pool_keys(k, cfg), pos, cfg)
+            return out, lightning_chunked(ql, kl, vl, rates, valid, state=lin)
+
+        seg = chip((rows, 1024, 32, 128), jnp.bfloat16)
+        ctx = chip((rows, 20480, 2, 128), jnp.bfloat16)
+        compiled = jax.jit(segment).lower(
+            seg, ctx, ctx, chip((rows, 1024), jnp.int32), seg, seg, seg,
+            chip((rows, 1024), jnp.int32), lin, rates,
+        ).compile()
+    # one query block's scores and one slot's gathered pages, not a whole prompt's
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
 @pytest.mark.parametrize("impl", ["flash", "splash"])
