@@ -154,30 +154,34 @@ def write_token_to_pages(
 ):
     """Scatter one decoded token's KV into each row's current page slot.
 
+    The KV head is an INDEX of the scatter, like page and slot, and not a
+    window (``pages.at[:, page, slot]``): a window over the head makes XLA's
+    TPU layout assignment relayout the whole pool into a head-minor tiling
+    before the scatter and back after it, two pool-sized copies an array a
+    step (tests/test_tpu_compile.py::test_page_write_keeps_the_pool_layout).
+
     With ``valid``, rows marked False are DROPPED (out-of-range page index +
     ``mode="drop"``) instead of written — the continuation-prefill path uses
     this so padding positions never touch pages the row doesn't own."""
-    b = new_kv.shape[0]
+    b, kh, _ = new_kv.shape
     rows = jnp.arange(b)
     page = page_indices[rows, lengths // page_size]  # [B]
     slot = lengths % page_size  # [B]
     raw = pages.weight if is_quantized_pages(pages) else pages
     if valid is not None:
         page = jnp.where(valid, page, raw.shape[1])  # OOB → dropped
+    at = (jnp.arange(kh)[:, None], page[None, :], slot[None, :])  # → [K, B]
     tok = new_kv.transpose(1, 0, 2)  # [K, B, hd]
     if is_quantized_pages(pages):
         qu = _quant_utils()
         scales = qu.get_quantization_scales(tok)  # [K, B, 1]
-        weight = pages.weight.at[:, page, slot].set(
-            qu.to_int8(tok, scales), mode="drop"
-        )
         return type(pages)(
-            weight=weight,
-            scales=pages.scales.at[:, page, slot].set(
+            weight=pages.weight.at[at].set(qu.to_int8(tok, scales), mode="drop"),
+            scales=pages.scales.at[at].set(
                 scales.astype(pages.scales.dtype), mode="drop"
             ),
         )
-    return pages.at[:, page, slot].set(tok.astype(pages.dtype), mode="drop")
+    return pages.at[at].set(tok.astype(pages.dtype), mode="drop")
 
 
 def write_tokens_to_pages(

@@ -85,7 +85,10 @@ def update_pooled(pooled, k_pages, lengths, page_indices, cfg):
     j = jnp.where(complete, (lengths - kernel) // stride, pooled.shape[1])  # OOB: dropped
     pos = jnp.maximum(lengths[:, None] - kernel + jnp.arange(kernel)[None, :], 0)
     pages = jnp.take_along_axis(page_indices, pos // ps, axis=1)  # [R, kernel]
-    keys = k_pages[:, pages, pos % ps]  # [K, R, kernel, hd]
+    # the KV head is an index, not a window: a window over it relayouts the
+    # whole pool for this read (ops/paged.py::write_token_to_pages)
+    head = jnp.arange(k_pages.shape[0])[:, None, None]
+    keys = k_pages[head, pages[None], (pos % ps)[None]]  # [K, R, kernel, hd]
     mean = keys.astype(_F32).mean(axis=2).transpose(1, 0, 2)  # [R, K, hd]
     return pooled.at[jnp.arange(r), j].set(mean.astype(pooled.dtype), mode="drop")
 

@@ -170,6 +170,35 @@ def test_the_chosen_blocks_are_the_references_and_the_choice_is_live():
     assert (got[late][:, 0] != got[late][:, 1]).any()  # the KV heads choose apart
 
 
+@pytest.mark.parametrize("kh", [2, 4])
+def test_the_decode_steps_pooled_key_is_a_plain_loops(kh):
+    """``update_pooled`` reads the last ``kernel`` keys of each (row, KV head)
+    back from the pages, the head as an index (ops/paged.py says why): the
+    pooled keys it leaves are those of a loop over rows and heads, bit for
+    bit, and a row whose count completes no pooled key is left alone."""
+    from distrl_llm_tpu.ops.sparse_attention import update_pooled
+
+    rng = np.random.default_rng(kh)
+    rows, width, ps, hd, n_pooled = 5, 4, 4, 16, 8
+    k_pages = rng.normal(size=(kh, rows * width + 1, ps, hd)).astype(np.float32)
+    table = rng.permutation(rows * width + 1)[: rows * width].reshape(rows, width)
+    pooled = rng.normal(size=(rows, n_pooled, kh, hd)).astype(np.float32)
+    # kernel 4, stride 2: counts 4, 6, 10 complete keys 0, 1, 3 (the last
+    # across a page boundary); 7 and 3 complete none
+    lengths = np.array([4, 6, 10, 7, 3], np.int32)
+    got = update_pooled(jnp.asarray(pooled), jnp.asarray(k_pages), jnp.asarray(lengths),
+                        jnp.asarray(table.astype(np.int32)), CFG)
+    want = pooled.copy()
+    for r, (n, j) in enumerate(zip(lengths, [0, 1, 3, None, None])):
+        if j is None:
+            continue
+        for h in range(kh):
+            keys = np.stack([k_pages[h, table[r, t // ps], t % ps] for t in range(n - 4, n)])
+            want[r, j, h] = np.asarray(jnp.asarray(keys).mean(axis=0))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert (want != pooled).any()
+
+
 # -------------------------------------------------------------- the engine
 
 

@@ -67,6 +67,92 @@ class TestPageWrites:
             np.testing.assert_allclose(np.asarray(got), np.asarray(new[r]))
 
 
+def _pool(rng, shape, quantized):
+    """A pool with something in every slot (so an untouched slot can be told
+    from a written one) and the same pool as numpy arrays, one per leaf."""
+    from distrl_llm_tpu.ops.paged import quantize_pages
+
+    pages = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pages = quantize_pages(pages) if quantized else pages.astype(jnp.bfloat16)
+    return pages, [np.array(leaf) for leaf in jax.tree_util.tree_leaves(pages)]
+
+
+def _loop_write(want, tok, lengths, table, ps, valid, quantized):
+    """The plain write: row by row, head by head, one slot at a time."""
+    from distrl_llm_tpu.ops.paged import quantize_pages
+
+    tok = jnp.asarray(tok)  # the int8 codes and scales are per (row, head) vector
+    vals = [np.asarray(leaf) for leaf in jax.tree_util.tree_leaves(
+        quantize_pages(tok) if quantized else tok.astype(jnp.bfloat16))]
+    for r in range(tok.shape[0]):
+        if not valid[r]:
+            continue
+        page, slot = table[r, lengths[r] // ps], lengths[r] % ps
+        for h in range(tok.shape[1]):
+            for leaf, val in zip(want, vals):
+                leaf[h, page, slot] = val[r, h]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("ps", [16, 64, 128])
+@pytest.mark.parametrize("kh", [2, 4, 8])
+class TestTokenWriteAgainstLoop:
+    """``write_token_to_pages`` addresses (KV head, page, slot) point by point
+    (ops/paged.py says why); the pools it leaves are those of a numpy loop,
+    bit for bit, in both containers."""
+
+    B, HD, PPS = 6, 8, 3
+
+    def table(self, rng):
+        # rows own scattered pages, and two pages of the pool belong to no row
+        perm = rng.permutation(self.B * self.PPS + 2)[: self.B * self.PPS]
+        return perm.reshape(self.B, self.PPS).astype(np.int32)
+
+    def check(self, got, want):
+        for g, w in zip(jax.tree_util.tree_leaves(got), want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g), w)
+
+    def test_one_token(self, kh, ps, quantized):
+        rng = np.random.default_rng(kh * 1000 + ps)
+        shape = (kh, self.B * self.PPS + 2, ps, self.HD)
+        pages, want = _pool(rng, shape, quantized)
+        table = self.table(rng)
+        # a page's last slot, the next page's first, the pool's first and last
+        lengths = np.array([ps - 1, ps, 0, 3 * ps - 1, ps + 5, 2 * ps - 1], np.int32)
+        tok = rng.normal(size=(self.B, kh, self.HD)).astype(np.float32)
+        for valid in (None, np.array([True, False, True, True, False, True])):
+            got = write_token_to_pages(
+                pages, jnp.asarray(tok), jnp.asarray(lengths), jnp.asarray(table),
+                ps, valid=None if valid is None else jnp.asarray(valid),
+            )
+            ref = [w.copy() for w in want]
+            _loop_write(ref, tok, lengths, table, ps,
+                        np.ones(self.B, bool) if valid is None else valid, quantized)
+            self.check(got, ref)
+            assert any((r != w).any() for r, w in zip(ref, want))
+
+    def test_three_tokens_across_a_page_boundary(self, kh, ps, quantized):
+        from distrl_llm_tpu.ops.paged import write_tokens_to_pages
+
+        rng = np.random.default_rng(kh * 1000 + ps + 1)
+        shape = (kh, self.B * self.PPS + 2, ps, self.HD)
+        pages, want = _pool(rng, shape, quantized)
+        table = self.table(rng)
+        # slots ps-2, ps-1 | 0 for the first row; ps-1 | 0, 1 for the second
+        lengths = np.array([ps - 2, ps - 1, 0, 2 * ps - 1, ps, 5], np.int32)
+        toks = rng.normal(size=(self.B, 3, kh, self.HD)).astype(np.float32)
+        valid = rng.random((self.B, 3)) < 0.7
+        valid[0] = valid[1] = True
+        got = write_tokens_to_pages(
+            pages, jnp.asarray(toks), jnp.asarray(lengths), jnp.asarray(table), ps,
+            valid=jnp.asarray(valid),
+        )
+        for i in range(3):
+            _loop_write(want, toks[:, i], lengths + i, table, ps, valid[:, i], quantized)
+        self.check(got, want)
+
+
 class TestPagedAttentionReference:
     def test_matches_dense_masked_attention(self):
         """Reference paged attention over packed pages == dense attention over

@@ -133,6 +133,67 @@ def test_paged_verify(chip, quantized):
     )
 
 
+@pytest.mark.parametrize(
+    "pool,page_size,width,consumer",
+    [((4, 241, 128, 128), 128, 4, "kernel"), ((2, 1856, 64, 128), 64, 29, "gathers")],
+    ids=["qwen-4x241x128-kernel", "sala-2x1856x64-gathers"],
+)
+def test_page_write_keeps_the_pool_layout(chip, pool, page_size, width, consumer):
+    """One layer's fragment of the decode step (write K, write V, attend,
+    return the state; the state donated) holds no copy of the pool, at the two
+    benchmark geometries: Qwen2.5-7B's pool before the Mosaic paged kernel,
+    and MiniCPM-SALA's before the sparse layers' selector read
+    (``update_pooled``) and their page gather.
+
+    The pool lives in ``{3,2,1,0:T(8,128)(2,1)}`` (parameters, outputs, the
+    kernel's operands). A write whose scatter window spans the KV head
+    (``pages.at[:, page, slot].set``, ``update_window_dims={0,2}``) is given
+    ``{3,0,2,1:T(4,128)(2,1)}`` (``T(2,128)`` at 2 heads) by XLA's layout
+    assignment, so the pool was copied into that layout before the scatter
+    and back after it: 2 copies an array a step, 20% of the Qwen rollout
+    (PERF.md, PR 30). A read of the same form (``k_pages[:, pages, slots]``)
+    asks for the same copy. With that write both cases fail; with the KV
+    head an index (``write_token_to_pages``, ``update_pooled``) the scatter
+    and the gather stay in the pool's layout."""
+    from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
+    from distrl_llm_tpu.ops.sparse_attention import update_pooled
+
+    kh, hd = pool[0], pool[3]
+    heads, chosen_pages = 28, 16  # query heads; pages a (row, KV head) gathers
+
+    def fragment(state, q, k, v, lengths, table, chosen, pooled):
+        k_pages = write_token_to_pages(state["k"], k, lengths, table, page_size)
+        v_pages = write_token_to_pages(state["v"], v, lengths, table, page_size)
+        if consumer == "kernel":
+            out = paged_attention_op(
+                q, k_pages, v_pages, lengths + 1, table, impl="native"
+            )
+        else:
+            head = jnp.arange(kh)[None, :, None]
+            out = (update_pooled(pooled, k_pages, lengths + 1, table, sala_config()),
+                   k_pages[head, chosen], v_pages[head, chosen])
+        return {"k": k_pages, "v": v_pages}, out
+
+    pages = chip(pool, jnp.bfloat16)
+    tok = chip((ROWS, kh, hd), jnp.bfloat16)
+    compiled = jax.jit(fragment, donate_argnums=0).lower(
+        {"k": pages, "v": pages}, chip((ROWS, heads, hd), jnp.bfloat16), tok, tok,
+        chip((ROWS,), jnp.int32), chip((ROWS, width), jnp.int32),
+        chip((ROWS, kh, chosen_pages), jnp.int32),
+        chip((ROWS, 1311, kh, hd), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    shape = "bf16[" + ",".join(map(str, pool)) + "]"
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if " copy(" in line and shape in line.split(" copy(")[0]
+    ]
+    assert not copies, copies
+    if consumer == "kernel":
+        assert "tpu_custom_call" in text
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
