@@ -1,6 +1,6 @@
 """In-process micro-bench harness: score each candidate plan on THIS device.
 
-Three rules, all learned from round 5's contaminated rows (VERDICT.md):
+Three rules:
 
 * **Warmup/steady-state separation.** The first generate() pays XLA
   compilation (minutes, cold); a
@@ -15,9 +15,8 @@ Three rules, all learned from round 5's contaminated rows (VERDICT.md):
   (``compile_chunk_guarded``) stay active underneath, so a chunk candidate
   whose program double-buffers is measured as what it actually ran
   (host-dispatched fallback) and flagged via ``scan_chunk_active``.
-* **Deterministic volume.** EOS is unreachable (the pinned-fallback trick
-  bench.py uses), so every candidate decodes exactly the same token count
-  and tok/s is comparable across candidates.
+* **Deterministic volume.** EOS is unreachable, so every candidate decodes
+  exactly the same token count and tok/s is comparable across candidates.
 """
 
 from __future__ import annotations

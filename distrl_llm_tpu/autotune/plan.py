@@ -1,11 +1,10 @@
 """Execution-plan space: the discrete dispatch choices the engines used to
 hard-code, as one typed record.
 
-Round 5's headline regression (VERDICT.md) was a PLAN bug, not a kernel bug:
-the bench's "production default" engaged the scan-chunk lever silicon had
-measured 2.5× slower, and the paged path ran 5–6× behind dense at the benched
-geometry. Every knob in :class:`ExecutionPlan` is one of those choices — the
-things a measurement on the device, not a guess in the source, should pick
+A hard-coded default can engage a lever (scan chunking, the paged path) at
+a geometry where the device runs it slower than leaving it off. Every knob
+in :class:`ExecutionPlan` is one of those choices — the things a
+measurement on the device, not a guess in the source, should pick
 (the system-level tuning discipline LlamaRL/RLAX apply to keep RL pipelines
 at hardware speed across geometries; PAPERS.md).
 
@@ -49,8 +48,8 @@ KV_FORMATS = (None, "none", "int8")
 #: frozen-base weight formats (ISSUE 15): int8/int4 weight-only containers
 #: (ops/quant.py) consumed by the fused dequant-matmul kernel
 #: (ops/quant_matmul.py). The ENGINE never loads weights, so this field is
-#: consumed by the callers that build the base tree (bench production
-#: defaults, tools/autotune.py measure, microbench) — stored so a tuned
+#: consumed by the callers that build the base tree (tools/autotune.py
+#: measure, microbench) — stored so a tuned
 #: "int4 base + int8 KV" serving stack is one DB entry, not a flag recipe.
 BASE_QUANTS = (None, "none", "int8", "int4")
 #: tiered KV prefix cache (ISSUE 18): "on" = cross-request radix prefix
@@ -87,8 +86,8 @@ class ExecutionPlan:
 
     # which engine class/scheduler serves decode. Engines can't change their
     # own class, so this field is consulted by the CALLERS that pick one
-    # (bench.py; tools/autotune.py reports it) and pinned to the actual
-    # class by the engine's own resolution (honest bench records).
+    # (tools/autotune.py reports it) and pinned to the actual class by the
+    # engine's own resolution.
     decode_path: str = "dense"
     # K decode steps fused per dispatch via lax.scan; 0 = host loop
     scan_chunk: int = 0
@@ -138,7 +137,7 @@ class ExecutionPlan:
     kv_format: str | None = None
     # frozen-base weight format (ISSUE 15): "int8"/"int4" weight-only
     # containers / "none" full-width; None = caller default. Consumed by
-    # the weight-loading callers (bench/autotune), not the engines.
+    # the weight-loading callers (tools/autotune.py), not the engines.
     base_quant: str | None = None
     # tiered KV prefix cache (ISSUE 18): "on" arms the cross-request radix
     # prefix index + host spill store on the refill pool (requires

@@ -1,4 +1,4 @@
-"""resolve_plan(): the one lookup every engine and bench makes at build time.
+"""resolve_plan(): the one lookup every engine makes at build time.
 
 Resolution order, PER FIELD: explicit user kwarg > stored plan (exact
 rows-bucket key, then the any-rows key) > static default. An empty DB is a
@@ -10,8 +10,7 @@ Every resolution is recorded through the PR-1 telemetry layer: an
 ``autotune/plan_resolved`` counter plus ``autotune/plan_db_hit`` /
 ``autotune/plan_default``, and (when tracing is on) an ``autotune/resolve``
 span carrying the key, source, and resolved choices — so a trace shows
-which plan a round ran under without cross-reading bench JSONs after the
-fact (the round-5 failure mode).
+which plan a round ran under.
 """
 
 from __future__ import annotations
@@ -91,9 +90,8 @@ def resolve_plan(
     """Resolve the execution plan for one (device, model, geometry).
 
     ``requested`` holds ONLY the fields the caller pinned explicitly (an
-    engine kwarg the user actually passed, a BENCH_* env var that was set);
-    those always win. Invalid requested values raise — a typo'd explicit
-    kwarg must fail loudly, while an invalid STORED plan only logs and falls
+    engine kwarg the user actually passed); those always win. Invalid
+    requested values raise — a typo'd explicit kwarg must fail loudly, while an invalid STORED plan only logs and falls
     back (PlanStore.get)."""
     requested = dict(requested or {})
     unknown = set(requested) - set(TUNABLE_FIELDS)

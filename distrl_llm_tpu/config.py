@@ -34,7 +34,7 @@ class SamplingConfig:
     # top-p filter implementation: False = sort-free bisection (fast path;
     # kept set is a superset of the exact nucleus by at most the boundary
     # tie mass), True = exact rank-based sort filter matching the reference's
-    # vLLM semantics — for eval/reproducibility runs (ADVICE r1).
+    # vLLM semantics — for eval/reproducibility runs.
     top_p_exact: bool = False
     # explicit impl override (a key of ops.sampling.TOP_P_IMPLS, e.g.
     # "bisect_mw"); None derives from top_p_exact. Engines resolve via
@@ -72,7 +72,7 @@ def parse_buckets(
     spec: str | None, field: str = "prompt_buckets"
 ) -> tuple[int, ...]:
     """Parse a comma-separated bucket list ("128,256") into a tuple; shared
-    by the CLI and bench so the format cannot drift. ``field`` names the
+    by every CLI entry so the format cannot drift. ``field`` names the
     flag in the error message."""
     if not spec:
         return ()
@@ -183,7 +183,7 @@ class TrainConfig:
     # 0 = dense. At the default learner shapes (8×1200×152k vocab, f32)
     # chunk=128 is ~5.8 GB → ~0.6 GB of logits memory.
     logprob_chunk: int = 128
-    # bf16 full-rank fine-tuning (BASELINE config 3: "bf16 full-rank, no
+    # bf16 full-rank fine-tuning (reference recipe 3: "bf16 full-rank, no
     # 4-bit"): the WHOLE param tree trains instead of a LoRA adapter; weight
     # sync pushes the full tree to the rollout mesh each step. Requires an
     # unquantized base; LoRA rank/alpha/dropout and the adapter-file writer

@@ -93,7 +93,7 @@ def extract_gsm8k_solution(answer: str) -> str:
 
 def prepare_gsm8k(dataset_name: str, tokenizer, test_size: float = 0.1,
                   seed: int | None = None):
-    """Load + template GSM8K (BASELINE config 3's dataset). Unlike MATH-500
+    """Load + template GSM8K (reference recipe 3's dataset). Unlike MATH-500
     (a single 'test' split the reference carves 90/10,
     train_distributed.py:44), GSM8K ships dedicated splits — training on its
     official 1,319-row test set would contaminate every published-accuracy

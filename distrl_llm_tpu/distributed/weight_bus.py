@@ -398,7 +398,7 @@ class WeightBus:
         self.last_pushed_version: int | None = None
         self.last_acked_version: int | None = None
         # bytes shipped for the most recent completed broadcast (all
-        # workers), for the bench/smoke artifacts
+        # workers), for the smoke's artifacts
         self.last_broadcast_bytes = 0
         self.last_broadcast_ms: float | None = None
         # per-worker ack latency of the most recent broadcast ("host:port"

@@ -306,10 +306,10 @@ def scan_steps_guarded(run, state, chunk: int):
     spare flops once every row was done — and that cond was exactly what
     double-buffered the carry: the select between the skipped and stepped
     KV caches keeps both alive, so the TPU compiler materialized a full
-    cache-sized temp (r5 silicon finding, tools/scan_alias_probe.py: the
+    cache-sized temp (found on the chip, tools/scan_alias_probe.py: the
     same body compiles with temp == cache bytes with the cond and ~0
-    without, scan and fori_loop alike). Every scan_chunk bench row had
-    silently fallen back to host dispatch because of it.
+    without, scan and fori_loop alike), and every chunked program then
+    fell back to host dispatch.
 
     Running the body unconditionally is semantically safe because the
     step functions are ALREADY per-row no-ops for done rows — partial
@@ -347,7 +347,7 @@ def compile_chunk_guarded(fn_jit, alias_bytes: int, what: str,
     the failure mode this guards against is a CACHE-sized temp, which at
     any scale that matters is hundreds of MBs.
 
-    ``fusion_bytes`` (ADVICE r5) is the second, smaller envelope for
+    ``fusion_bytes`` is the second, smaller envelope for
     mulred-formulation programs: the per-layer ``_gqa_mulred``
     broadcast-product temp ([B, KH, G, D, S] f32) that a backend failing
     to fuse reduce-of-product into the cache read would materialize. That
@@ -358,8 +358,8 @@ def compile_chunk_guarded(fn_jit, alias_bytes: int, what: str,
 
     EVERY fallback here is loud: a ``log.warning`` naming the cause plus
     an ``engine/chunk_fallback`` telemetry counter — silently flipping
-    ``scan_chunk_active`` is exactly the trap that contaminated the
-    round-5 bench rows (VERDICT.md)."""
+    ``scan_chunk_active`` would let a run measure another program than
+    the one its configuration names."""
     try:
         compiled = fn_jit.lower(*args, **kwargs).compile()
         # compile tracker (ISSUE 8): keyed by program name × the arg
@@ -396,10 +396,6 @@ def compile_chunk_guarded(fn_jit, alias_bytes: int, what: str,
             )
             telemetry.counter_add(ENGINE_CHUNK_FALLBACK)
             return None
-        # measured roofline input (ISSUE 8): the XLA-reported FLOPs/bytes
-        # of the accepted program, surfaced on the obs endpoint and in the
-        # trace metadata for trace_report's roofline section
-        obs.record_cost(what, compiled)
         return compiled
     except Exception as e:  # pragma: no cover - backend-specific
         if jax.default_backend() == "tpu":
@@ -499,7 +495,7 @@ def make_swap_aware_chunk_step(mailbox, lora_cell: list, steps_seen: list,
     and refetches the chunk program from its signature-keyed cache when a
     swap changes the adapter's STRUCTURE (e.g. a None-adapter round
     receiving its first adapter) — compiled executables raise on a
-    structurally different pytree instead of retracing (ADVICE r3).
+    structurally different pytree instead of retracing.
 
     When the new signature's program fell back (memory guard / compile
     failure), the round finishes per-step at the same k-step cadence,
@@ -767,7 +763,7 @@ class GenerationEngine(LoraMailbox):
         plan_db: str | None = None,  # plan-DB path; None = env/default path
         # expected concurrent candidate rows, for plan-key selection ONLY
         # (batch size arrives at generate()): callers that know the round
-        # volume (bench) pass it so their own resolve and the engine's hit
+        # volume pass it so their own resolve and the engine's hit
         # the SAME DB entry; 0 = the any-rows entry
         plan_rows: int = 0,
     ):
@@ -786,7 +782,7 @@ class GenerationEngine(LoraMailbox):
         # Execution-plan resolution (distrl_llm_tpu/autotune): explicit
         # kwargs always win; a stored measured plan fills the rest; with no
         # DB entry the static defaults apply byte-identically. decode_path
-        # is pinned to this class so bench/trace records stay honest.
+        # is pinned to this class so trace records stay honest.
         from distrl_llm_tpu.autotune import resolve_plan
 
         requested: dict[str, Any] = {"decode_path": "dense"}
@@ -903,8 +899,8 @@ class GenerationEngine(LoraMailbox):
         """Whether chunked decode actually ran: True once a chunked program
         compiled AND passed the memory guard, False if every attempt fell
         back to the host loop, None before the first decode (or scan_chunk=0).
-        Bench records report this so a fallback can't masquerade as a
-        chunked measurement."""
+        A measurement reports this so a fallback can't masquerade as a
+        chunked run."""
         if not self.scan_chunk or not self._chunk_compiled:
             return None
         return any(v is not None for v in self._chunk_compiled.values())
@@ -992,7 +988,7 @@ class GenerationEngine(LoraMailbox):
             fusion_bytes = 0
             if self.cache_read_formulation == "mulred":
                 # per-layer _gqa_mulred broadcast product at this bucket's
-                # full window — the unfused-temp envelope (ADVICE r5)
+                # full window — the unfused-temp envelope
                 from distrl_llm_tpu.ops.attention import mulred_broadcast_bytes
 
                 fusion_bytes = mulred_broadcast_bytes(
@@ -1071,13 +1067,6 @@ class GenerationEngine(LoraMailbox):
         temperature = jnp.asarray(sampling.temperature, jnp.float32)
         top_p = jnp.asarray(sampling.top_p, jnp.float32)
         top_p_impl = sampling.resolved_top_p_impl(self.plan_top_p_impl)
-        # measured bytes/token source (ISSUE 15; DISTRL_MEASURE_COST=1
-        # only): file the step program's XLA cost_analysis once
-        obs.maybe_record_step_cost(
-            "decode_step/dense", decode_step_fn, params, lora, state, rng,
-            eos_ids=self.eos_ids, temperature=temperature, top_p=top_p,
-            top_p_impl=top_p_impl,
-        )
         lora_cell = [lora]
         steps_seen = [0]
         # explicit enter/exit: the span must cover BOTH dispatch branches

@@ -240,7 +240,7 @@ class RadixCache:
         self._resident: dict[int, _RadixNode] = {}  # nid -> node
         self._tick = 0
         self._next_nid = 0
-        # cumulative counters (engine snapshots per-round deltas for bench)
+        # cumulative counters (the engine snapshots per-round deltas)
         self.lookup_tok = 0
         self.hit_tok = 0
         self.prefill_tok_saved = 0
@@ -344,7 +344,7 @@ class PagePool:
         # pages outside [first_page, first_page + n_pages) the pool has
         # adopted (a static prompt region registered/reclaimed into it)
         self.adopted: set[int] = set()
-        # stats the bench/telemetry satellites read
+        # stats the telemetry satellites read
         self.cow_splits = 0
         self.peak_shared_pages = 0
         self.prefix_admissions = 0

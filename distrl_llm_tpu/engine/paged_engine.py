@@ -161,8 +161,8 @@ def _count_sparse_blocks(mixer) -> None:
 
 def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
                            *, per_call: int, calls_per_step: int = 1):
-    """Paged grid-overhead telemetry (the BASELINE r5 model: decode's cost
-    floor is Pallas grid steps × ~1 µs/grid-step). ``per_call`` is the
+    """Paged grid-overhead telemetry (decode's cost floor is Pallas grid
+    steps × about 1 µs a grid step; PERF.md §7). ``per_call`` is the
     dispatch chain's trace-time analytic count for the CALLER's own
     geometry and LIVE row count (engines derive it from their exact
     dispatch-choice record — see ``_grid_steps_per_call`` — never from a
@@ -173,7 +173,7 @@ def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
     Total grid steps this round = per-call × calls/step × layers × steps.
     The realized µs/grid-step gauge is an upper bound (decode seconds also
     carry sampling and non-attention layers), but it makes the
-    launch-overhead regime visible in every trace without a bench run."""
+    launch-overhead regime visible in every trace."""
     if not per_call or not steps:
         return
     total = per_call * calls_per_step * num_layers * steps
@@ -1469,7 +1469,7 @@ class PagedGenerationEngine(LoraMailbox):
         # Execution-plan resolution (distrl_llm_tpu/autotune): explicit
         # kwargs win, a stored measured plan fills the rest, no DB entry =
         # the static defaults byte-identically. decode_path is pinned to
-        # what this construction actually is (honest bench/trace records).
+        # what this construction actually is (honest trace records).
         from distrl_llm_tpu.autotune import (
             IMPL_TO_PAGED_KERNEL, PAGED_KERNEL_TO_IMPL, resolve_plan,
         )
@@ -1661,7 +1661,7 @@ class PagedGenerationEngine(LoraMailbox):
             )
         self.prefix_sharing = bool(prefix_sharing)
         self.continuous_admission = bool(cont)
-        # the scheduler self-description bench/telemetry record (the wave
+        # the scheduler self-description telemetry record (the wave
         # path reports "waves" regardless; generate() stamps last_cb_mode
         # with what each round actually ran)
         self.cb_mode = (
@@ -1838,7 +1838,7 @@ class PagedGenerationEngine(LoraMailbox):
             and max_kv_pages < pool_floor + self.private_pages
         ):
             # above the hard floor but wedge-prone (ISSUE 19 satellite, the
-            # BENCH_KV_PAGES<=16 gotcha): a budget that cannot hold the
+            # max_kv_pages<=16 gotcha): a budget that cannot hold the
             # head group's chain plus TWO private regions serializes every
             # admission behind a full drain, and a mid-round decline with
             # no live slot trips the wedge detector. Warn at build time
@@ -1857,7 +1857,7 @@ class PagedGenerationEngine(LoraMailbox):
         self.max_kv_pages = max_kv_pages
         self.last_pool_stats: dict | None = None
         # request-level serving observability (ISSUE 13): when an owner
-        # (trainer --serving_obs, worker --serving-obs, bench cb rows)
+        # (trainer --serving_obs, worker --serving-obs)
         # attaches a serving_obs.ServingLedger here, the refill/spec/
         # continuous loops emit per-group lifecycle events and the
         # admission audit at host chunk boundaries. None = every hook site
@@ -1866,7 +1866,7 @@ class PagedGenerationEngine(LoraMailbox):
         # schedules)
         self.serving_ledger: Any = None
         # closed-loop admission limits (ISSUE 14): when an owner (trainer
-        # --control, worker --control, bench control A/B rows) attaches a
+        # --control, worker --control) attaches a
         # control.ControlLimits here, the continuous-admission loop
         # consults it — the HBM governor's chain-cap scale and the SLO
         # shedder's shed gate. None = one attribute check per admission
@@ -1874,7 +1874,7 @@ class PagedGenerationEngine(LoraMailbox):
         # (pinned in tests/test_control.py)
         self.control_limits: Any = None
         # multi-turn episode continuation (ISSUE 17): when an owner (trainer
-        # env driver, bench env arm) attaches a turn hook here, the refill
+        # env driver) attaches a turn hook here, the refill
         # idle pass consults it before retiring a finished candidate —
         # ``hook(cand_id, gen_tokens) -> np.ndarray | None`` returns
         # observation tokens to append in place (KV chain stays resident) or
@@ -2136,8 +2136,8 @@ class PagedGenerationEngine(LoraMailbox):
     def scan_chunk_active(self) -> bool | None:
         """Whether chunked decode (wave or refill) actually ran — None
         before the first round (or scan_chunk off), False if every attempt
-        fell back to per-step dispatch (bench honesty flag, same contract
-        as the dense engine's)."""
+        fell back to per-step dispatch (same contract as the dense
+        engine's)."""
         if self.scan_chunk <= 1 or not self._chunk_compiled:
             return None
         return any(v is not None for v in self._chunk_compiled.values())
@@ -2254,7 +2254,7 @@ class PagedGenerationEngine(LoraMailbox):
         self._reset_lora_mailbox_round()
         # pool telemetry is per-round (only the refill path produces it):
         # without this reset a wave-path round would leave a previous
-        # refill/eval round's stats for trainer/bench snapshots to misread
+        # refill/eval round's stats for the trainer's snapshots to misread
         self.last_pool_stats = None
         self.last_spec_stats = None
         self.last_round_stats = None  # waves/refill of THIS round accumulate
@@ -2619,25 +2619,6 @@ class PagedGenerationEngine(LoraMailbox):
                 )
 
             pool.spill_fn = _spill_payload
-        # measured bytes/token source (ISSUE 15; DISTRL_MEASURE_COST=1
-        # only): file the slot-step program's XLA cost_analysis once
-        from distrl_llm_tpu import obs as _obs
-
-        if self.spec_draft:
-            _obs.maybe_record_step_cost(
-                "decode_step/spec", self._spec_step, params, lora_cell[0],
-                state, rng, drafter_cell[0], eos_ids=self.eos_ids,
-                temperature=temperature, top_p=top_p, max_steps=max_steps,
-                draft_len=d_cell[0], ngram_k=self.spec_ngram,
-                top_p_impl=top_p_impl,
-            )
-        else:
-            _obs.maybe_record_step_cost(
-                "decode_step/refill", self._refill_step, params,
-                lora_cell[0], state, rng, eos_ids=self.eos_ids,
-                temperature=temperature, top_p=top_p, max_steps=max_steps,
-                top_p_impl=top_p_impl,
-            )
         # K-steps-per-dispatch (dispatch-overhead lever). K must
         # DIVIDE `check`: the host acts when since_host >= check, so a
         # non-divisor K stretches the effective cadence to ceil(check/K)·K
@@ -2678,7 +2659,7 @@ class PagedGenerationEngine(LoraMailbox):
                 k_conf = 0
         # signature the chunk program was built for: an in-flight swap to a
         # structurally different adapter must refetch (compiled executables
-        # raise on structure change instead of retracing — ADVICE r3). The
+        # raise on structure change instead of retracing). The
         # drafter operand is part of the signature: the first consumed swap
         # on a lora=None round rotates the drafter None→adapter with the
         # TARGET signature unchanged, so keying on the target alone would
@@ -2742,7 +2723,7 @@ class PagedGenerationEngine(LoraMailbox):
         # None): per-group quota reservations, deterministic aging counters,
         # per-candidate streamed-token cursors, the declined head group's
         # class for the per-class stall attribution, and the per-class
-        # shed/preempt action tally the bench artifact scores
+        # shed/preempt action tally
         quota_charged: dict[int, int] = {}
         group_waited: dict[int, int] = {}
         stream_sent: dict[int, int] = {}
@@ -2934,7 +2915,7 @@ class PagedGenerationEngine(LoraMailbox):
                 # floor is pinned 0: the ISSUE 14 behavior, bit for bit
                 if g not in shed_groups_seen:
                     # counted once per deferred group, however many
-                    # passes decline it (the bench row's shed_groups)
+                    # passes decline it
                     shed_groups_seen.add(g)
                     telemetry.counter_add(CONTROL_SHED_GROUPS)
                     c_g = cls_of(g)
@@ -3760,7 +3741,7 @@ class PagedGenerationEngine(LoraMailbox):
                             f"Minimum viable budget for the head request: "
                             f"{need} pages (ceil(prompt {rl_h} / page_size "
                             f"{ps}) chain + {self.private_pages} private) — "
-                            f"raise max_kv_pages / BENCH_KV_PAGES to at "
+                            f"raise max_kv_pages to at "
                             f"least {need}"
                         )
                 else:
@@ -3810,8 +3791,8 @@ class PagedGenerationEngine(LoraMailbox):
             "peak_pages_used": pool.peak_pages_used,
             "preemptions": pool.preemptions,
             "budgeted": budgeted,
-            # continuous-batching self-description (ISSUE 12, read by bench
-            # rollout rows + tools/cb_smoke.py): which admission regime ran,
+            # continuous-batching self-description (ISSUE 12, read by
+            # tools/cb_smoke.py): which admission regime ran,
             # how much of the prompt segment was physically shared, and how
             # much mid-round backfill the fixed batch would have idled away
             "cb_mode": self.cb_mode,
@@ -3835,8 +3816,8 @@ class PagedGenerationEngine(LoraMailbox):
                 len(shed_groups_seen) if limits is not None else None
             ),
             # multi-tenant gateway rounds (ISSUE 19): per-class shed/preempt
-            # action tally — the bench artifact's "actions land on low
-            # classes" contract reads this (None = no gateway identity)
+            # action tally — the "actions land on low classes" contract
+            # reads this (None = no gateway identity)
             "class_actions": (
                 {k: dict(v) for k, v in class_actions.items()}
                 if meta is not None else None
@@ -3854,7 +3835,7 @@ class PagedGenerationEngine(LoraMailbox):
             ),
             # tiered KV cache (ISSUE 18): per-round radix-counter deltas +
             # this round's spill/restore latency (None on the cache-off
-            # control — the bench contract's honest-null discipline)
+            # control: a null, not a made-up zero)
             "prefix_cache": bool(cache_on),
             "radix_hit_rate": (
                 round(
@@ -4074,14 +4055,6 @@ class PagedGenerationEngine(LoraMailbox):
         temperature = jnp.asarray(sampling.temperature, jnp.float32)
         top_p = jnp.asarray(sampling.top_p, jnp.float32)
         top_p_impl = sampling.resolved_top_p_impl(self.plan_top_p_impl)
-        # measured bytes/token source (ISSUE 15; DISTRL_MEASURE_COST=1 only)
-        from distrl_llm_tpu import obs as _obs
-
-        _obs.maybe_record_step_cost(
-            "decode_step/paged", self._decode_step, params, lora, state,
-            rng, page_indices, eos_ids=self.eos_ids, temperature=temperature,
-            top_p=top_p, top_p_impl=top_p_impl,
-        )
         lora_cell = [lora]
         steps_seen = [0]
 

@@ -209,7 +209,7 @@ class SpecRefillState(NamedTuple):
     # under-count rows whose final emitted token was an accepted draft,
     # e.g. an accepted EOS) — together they give the accept rate,
     # tokens/verify-step, and the emit distribution (engine/spec_*
-    # telemetry + the bench row's spec fields)
+    # telemetry)
     emit_hist: jax.Array  # [d_max+2] i32
     draft_total: jax.Array  # [] i32
     accept_total: jax.Array  # [] i32

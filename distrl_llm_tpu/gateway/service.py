@@ -2,7 +2,7 @@
 (ISSUE 19).
 
 The service owns an open request queue fed by :mod:`.server` (or any
-in-process producer — the smoke and bench drive it directly) and a
+in-process producer — the smoke drives it directly) and a
 round-forming loop: drain up to ``max_groups_per_round`` requests in
 class-then-FIFO-with-aging order, attach the round's tenancy to the
 engine (``round_meta`` / ``quota_book`` / ``stream_hook``), run ONE
@@ -101,8 +101,8 @@ class GatewayService:
         self.completed = 0
         self.failed = 0
         # run-cumulative per-class shed/preempt group tallies (the engine's
-        # last_pool_stats only covers one round) — the bench's
-        # shed_frac_by_class reads this
+        # last_pool_stats only covers one round); tools/gateway_smoke.py
+        # reads this
         self.class_actions: dict[str, dict[str, int]] = {
             "shed": {}, "preempt": {},
         }

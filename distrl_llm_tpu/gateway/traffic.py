@@ -5,7 +5,7 @@ Production-shaped load is heterogeneous in BOTH dimensions Laminar
 measures (PAPERS.md): arrival times (Poisson steady state punctuated by
 bursts) and lengths (long-tail — a few huge prompts/outputs dominate
 the page pool). This module synthesizes such traces deterministically
-from a seed, persists them as JSONL so a bench round and a regression
+from a seed, persists them as JSONL so a measured round and a regression
 bisect replay the SAME arrivals, and drives them at the gateway
 OPEN-LOOP: each request fires at its scheduled offset whether or not
 earlier requests completed — under overload the queue grows, which is

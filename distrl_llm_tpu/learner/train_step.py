@@ -204,7 +204,7 @@ def _microbatch_loss(
 
     ``train_mode="lora"``: ``lora`` is the trainable adapter over the frozen
     ``base_params``. ``train_mode="full"``: ``lora`` IS the full trainable
-    param tree (bf16 full-rank — BASELINE config 3's no-LoRA mode) and
+    param tree (bf16 full-rank — reference recipe 3's no-LoRA mode) and
     ``base_params`` is ignored.
 
     ``emit_dynamics`` (static) appends the per-microbatch dynamics sums to

@@ -1,6 +1,6 @@
 """Model architecture configs for the dense decoder families the reference
 trains through unsloth (train_distributed.py:11 — any FastLanguageModel
-checkpoint; BASELINE.json configs name Qwen2.5 and Llama-3).
+checkpoint; the reference's recipes name Qwen2.5 and Llama-3).
 
 One ``ModelConfig`` covers the supported families — Qwen2.5, Llama-3,
 Mistral, Gemma — via the knobs where they actually differ: GQA attention
@@ -207,7 +207,7 @@ class ModelConfig:
         """Parameters participating in matmuls (projections + MLP + lm_head;
         biases/norms excluded as FLOP-negligible, embedding lookups are not
         matmuls). The 2·N term of every FLOPs-per-token estimate — the
-        single owner for bench.py and the telemetry MFU series."""
+        single owner for the telemetry MFU series."""
         mlp = 3 * self.hidden_size * self.intermediate_size  # gate, up, down
         attn = (
             2 * self.hidden_size * self.q_dim       # q, o proj
@@ -418,7 +418,7 @@ def preset_for_model_name(name: str) -> ModelConfig | None:
     if low == "tiny":  # exact only — "tiny" substrings occur in real model ids
         return TINY
     if "r1-distill" in low:
-        # BASELINE config 4's model family: tensor dims match the Qwen2/Llama
+        # reference recipe 4's model family: tensor dims match the Qwen2/Llama
         # presets but NOT the RoPE config (R1-Distill-Qwen-7B derives from
         # Qwen2.5-MATH-7B: rope_theta 1e4 vs the preset's 1e6, 131k context).
         # A preset would silently rotate positions at the wrong frequencies —

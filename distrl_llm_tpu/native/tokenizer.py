@@ -189,7 +189,7 @@ class NativeBPETokenizer:
         if "eos_token_id" not in kw:
             # conventional names only; a silently-wrong eos breaks generation
             # termination (rollouts would always run to max_tokens), so an
-            # unrecognized vocabulary must fail loudly (ADVICE r1)
+            # unrecognized vocabulary must fail loudly
             specials = {t["content"]: t["id"] for t in tj.get("added_tokens", [])}
             for name in ("<|im_end|>", "</s>", "<|eot_id|>", "<|endoftext|>"):
                 if name in specials:

@@ -3,10 +3,9 @@ aggregation, measured resource attribution, and an anomaly sentinel with a
 flight recorder.
 
 PR 1's telemetry is post-hoc — spans, counters, and Perfetto traces you read
-after the run. The north star is a production service under heavy traffic,
-and its two loudest facts (the ~2% MFU / ~25× roofline headroom, and the
-multi-host elastic fleet of ROADMAP item 4) both demand *live*, *attributed*
-telemetry. This module adds, on top of ``telemetry.py``'s registry:
+after the run. A long-running service and a multi-host elastic fleet need
+*live*, *attributed* telemetry. This module adds, on top of
+``telemetry.py``'s registry:
 
 * **Live export** — :class:`MetricsServer` serves the process's cumulative
   registry (``telemetry.observe_snapshot``) over HTTP as Prometheus text
@@ -21,10 +20,7 @@ telemetry. This module adds, on top of ``telemetry.py``'s registry:
 * **Measured attribution** — per-phase HBM watermarks sampled from
   ``jax.Device.memory_stats()`` at span boundaries (the PhaseSpans hook), a
   compile/retrace tracker keyed by jitted-fn × shape signature (silent
-  retrace storms become a counter), and XLA ``cost_analysis()``-derived
-  FLOPs/bytes per explicitly-compiled step program — all surfaced on the
-  endpoint, in bench rows, and in ``tools/trace_report.py``'s roofline
-  section.
+  retrace storms become a counter) — both surfaced on the endpoint.
 * **Anomaly sentinel + flight recorder** — a bounded in-memory ring of
   recent step records; deterministic triggers (NaN/Inf loss, reward
   collapse, staleness blowup, tok/s regression vs a running EMA, HBM
@@ -167,11 +163,10 @@ def phase_hbm() -> dict[str, dict[str, float]]:
         return {k: dict(v) for k, v in _phase_hbm.items()}
 
 
-# ------------------------------------------- compile / retrace / cost table
+# -------------------------------------------------- compile / retrace table
 
 _compile_mu = threading.Lock()
 _compile_counts: dict[tuple, int] = {}
-_costs: dict[str, dict[str, float]] = {}
 
 
 def note_compile(fn: str, signature: Any = ()) -> None:
@@ -213,74 +208,12 @@ def retrace_total() -> int:
 
 
 def reset_compile_tracker() -> None:
-    """Scope the tracker to a run (bench clears it before warmup, tests
-    between cases). Registry counters are NOT rewound — they are monotonic
-    by contract."""
+    """Scope the tracker to a run (tests clear it between cases). Registry
+    counters are NOT rewound — they are monotonic by contract."""
     with _compile_mu:
         _compile_counts.clear()
-        _costs.clear()
     with _phase_mu:
         _phase_hbm.clear()
-
-
-def record_cost(what: str, compiled) -> dict[str, float] | None:
-    """Extract XLA ``cost_analysis()`` FLOPs/bytes from an explicitly
-    compiled program (the AOT paths — ``compile_chunk_guarded`` — already
-    hold one) and file it under ``what`` for the endpoint, bench rows, and
-    the trace_report roofline section. Returns the entry, or None when the
-    backend reports no analysis."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend without cost analysis
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, Mapping):
-        return None
-    flops = float(ca.get("flops", 0.0) or 0.0)
-    byts = float(ca.get("bytes accessed", 0.0) or 0.0)
-    if flops <= 0.0 and byts <= 0.0:
-        return None
-    entry = {"flops": flops, "bytes_accessed": byts}
-    with _compile_mu:
-        _costs[what] = entry
-    return dict(entry)
-
-
-def costs() -> dict[str, dict[str, float]]:
-    """Measured (cost_analysis) FLOPs/bytes per compiled step program."""
-    with _compile_mu:
-        return {k: dict(v) for k, v in _costs.items()}
-
-
-def cost_measurement_enabled() -> bool:
-    """DISTRL_MEASURE_COST=1 (bench sets it): engines AOT-lower their
-    decode-step programs once and file the XLA cost_analysis — the
-    measured-bytes/token source for bench rows and the trace_report
-    roofline section (ISSUE 15). Off by default: the AOT compile is
-    measurement-only work (deduped by the persistent XLA compile cache,
-    but not free)."""
-    return os.environ.get("DISTRL_MEASURE_COST") == "1"
-
-
-def maybe_record_step_cost(what: str, fn_jit, *args, **kwargs) -> None:
-    """AOT-lower+compile ``fn_jit`` at these concrete args and record its
-    cost_analysis under ``what`` — once per name, only under
-    DISTRL_MEASURE_COST=1. Never raises: backends without AOT/cost
-    analysis leave the entry absent (bench reports null, not a fabricated
-    number). ``lower`` only traces — donated args are not consumed."""
-    if not cost_measurement_enabled():
-        return
-    with _compile_mu:
-        if what in _costs:
-            return
-    try:
-        record_cost(what, fn_jit.lower(*args, **kwargs).compile())
-    except Exception as e:  # noqa: BLE001 — measurement must not kill a run
-        logging.getLogger(__name__).warning(
-            "step-cost measurement for %s failed (%s: %s)",
-            what, type(e).__name__, e,
-        )
 
 
 # --------------------------------------------------------------- exposition
@@ -361,7 +294,7 @@ def prometheus_text(snapshot: Mapping[str, Any] | None = None,
 
 
 def json_snapshot(fleet: Mapping[str, Any] | None = None) -> dict[str, Any]:
-    """The JSON form of one scrape: cumulative registry + compile/cost/HBM
+    """The JSON form of one scrape: cumulative registry + compile/HBM
     tables + (driver-side) the fleet view."""
     snap = telemetry.observe_snapshot()
     return {
@@ -374,7 +307,6 @@ def json_snapshot(fleet: Mapping[str, Any] | None = None) -> dict[str, Any]:
             "retraces": retrace_total(),
             "keys": len(compile_counts()),
         },
-        "costs": costs(),
         "hbm": hbm_stats(),
         "phase_hbm": phase_hbm(),
         "fleet": dict(fleet) if fleet else None,

@@ -152,7 +152,7 @@ def _gqa_attention(q, k, v, mask, scale, *, kv_subscript: str,
     if formulation not in ("dot", "mulred"):
         # a typo ('mul_red', 'dot_general', …) must not silently take the
         # dot path — inside a scan program that reintroduces the per-leaf
-        # relayout copy / OOM the flag exists to avoid (ADVICE r5)
+        # relayout copy / OOM the flag exists to avoid
         raise ValueError(
             f"formulation must be 'dot' or 'mulred', got {formulation!r}"
         )
@@ -199,7 +199,7 @@ def mulred_broadcast_bytes(batch_rows: int, kv_heads: int, groups: int,
     fuse reduce-of-product into the cache read. ``compile_chunk_guarded``'s
     ``fusion_bytes`` threshold prices temp bytes against this: a fused
     program's scratch sits far below it, an unfused one lands on it and
-    OOMs real geometries (ADVICE r5)."""
+    OOMs real geometries."""
     return batch_rows * kv_heads * groups * head_dim * kv_len * 4
 
 
@@ -242,7 +242,7 @@ def _gqa_mulred(q, k, v, mask, scale, *, k_scale=None, v_scale=None):
 
 #: set once a "flash"/"splash" request ran the XLA reference instead (a
 #: backend other than the TPU, or a call outside the kernels'
-#: self-attention contract) — bench rows read it as ``attn_fallback``
+#: self-attention contract)
 _flash_fallback_warned = False
 
 

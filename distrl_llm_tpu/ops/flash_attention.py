@@ -4,7 +4,7 @@ Wraps jaxlib's Pallas TPU flash-attention kernel (differentiable: custom-VJP
 fwd+bwd kernels) behind the same ``(q, k, v, mask, scale)`` interface as
 ``attention_reference``, so ``attention(..., impl="flash")`` swaps the O(S²)
 XLA softmax for the O(S)-memory blockwise kernel. This is what makes 4k+
-long-CoT learner forwards (BASELINE config 4) fit: at S=4k the reference path
+long-CoT learner forwards (reference recipe 4) fit: at S=4k the reference path
 materializes [B, H, S, S] f32 logits (~1 GB per layer at B=8), flash keeps
 only block-sized tiles in VMEM.
 

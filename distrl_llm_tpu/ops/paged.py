@@ -300,8 +300,8 @@ def paged_attention_reference(
 
 
 #: kernel default for the blocked launch's page-axis collapse — callers
-#: passing 0 get this (kept here so plan resolution, bench records, and the
-#: analytic grid-step model all agree on what "default" means)
+#: passing 0 get this (kept here so plan resolution and the analytic
+#: grid-step model agree on what "default" means)
 DEFAULT_PAGES_PER_BLOCK = 8
 
 
@@ -310,11 +310,11 @@ def paged_grid_steps(
     pages_per_block: int = 0,
 ) -> int:
     """Analytic Pallas grid-step count of ONE paged-attention call (one
-    layer, one decode step) for ``impl``. This is the denominator of the
-    round-5 overhead model (BASELINE.md): decode at the benched geometry is
-    bound by grid steps × Mosaic's ~1 µs/grid-step floor, not by bandwidth,
-    so every engine/bench artifact records this count (ops/paged_grid_steps
-    counter, bench ``grid_steps_estimate``) to make the regime visible.
+    layer, one decode step) for ``impl``. At small head dims and many short
+    pages decode is bound by grid steps × Mosaic's per-grid-step floor
+    (about 1 µs; PERF.md §7, a pre-chip record), not by bandwidth, so the
+    engines record this count (``ops/paged_grid_steps`` counter) to make
+    the regime visible.
 
     Counts per impl: "native" runs a (B, K, pps) grid; "native_folded"
     folds kv heads into the block — (B, pps); "native_blocked" additionally
@@ -369,20 +369,10 @@ def divisor_blocks(pages_per_compute_block: int, pps: int) -> int:
     )
 
 
-def dispatch_key_is_verify(key) -> bool:
-    """True when a ``dispatch_choices`` key records a speculative
-    draft-block verify dispatch (``paged_verify_op``) rather than a
-    single-query decode. The ONLY place outside ``dispatch_choice_key``
-    allowed to know the tuple layout — consumers (bench's decode-impl
-    summary, trace filters) must call this instead of indexing, so the
-    next field appended to the key cannot silently break their filters."""
-    return isinstance(key, tuple) and len(key) >= 10 and bool(key[9])
-
-
 # per-config record of what each paged dispatch resolved to ("native" |
-# "native_folded" | "native_blocked" | "kernel" | "reference") — engines,
-# bench records and chip_smoke.py read it, so a run on the reference can
-# never pass for a kernel measurement
+# "native_folded" | "native_blocked" | "kernel" | "reference") — engines
+# and chip_smoke.py read it, so a run on the reference can never pass for a
+# kernel measurement
 dispatch_choices: dict = {}
 # NOTE on grid-step accounting: the analytic count is batch-dependent, so
 # it is never cached here — consumers read WHICH impl ran from
@@ -422,8 +412,8 @@ def _native_call(q, k_pages, v_pages, lengths, page_indices,
     """Adapter: the dispatch's launch signature → our native kernels
     (ops/paged_native.py), which take int8 weights and compact scales as
     separate arrays. ``folded`` selects the kv-heads-in-block variant with
-    a (B, pps) grid (half the grid steps, BASELINE.md r5 grid-overhead
-    analysis); ``blocked`` the multi-page grid-collapsed variant with a
+    a (B, pps) grid (1/K of the grid steps; ``paged_grid_steps``);
+    ``blocked`` the multi-page grid-collapsed variant with a
     (B, ceil(pps / pages_per_block)) grid on top of the folding."""
     from distrl_llm_tpu.ops.paged_native import (
         paged_attention_native,
@@ -582,7 +572,7 @@ def paged_verify_op(
     anchor). The decision is recorded in ``dispatch_choices`` under the
     verify-marked key (``dispatch_choice_key(..., verify_len=S)``):
     "native_verify" when the fused sweep ran, "unrolled" otherwise — so
-    engines/bench can compute the verify step's TRUE grid cost
+    engines can compute the verify step's TRUE grid cost
     (``paged_grid_steps("native_verify", ...)`` × 1 call vs the per-impl
     count × (d+1) calls) instead of guessing."""
     b, s, h, hd = q.shape

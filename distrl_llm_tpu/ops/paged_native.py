@@ -224,10 +224,10 @@ def _paged_kernel_folded(
     pps: int,
 ):
     """kv-heads-folded variant of ``_paged_kernel``: the kv-head axis rides
-    INSIDE the block instead of the grid, halving the grid-step count (the
-    0.5B paged rows measured Mosaic's ~1 µs/grid-step floor dominating at
-    (B × K × pps) granularity — BASELINE.md r5 analysis) and doubling each
-    DMA. Compute is the same online softmax, batched over K via
+    INSIDE the block instead of the grid, dividing the grid-step count by
+    K (at (B × K × pps) granularity Mosaic's per-grid-step floor of about
+    1 µs dominates a 0.5B-width step; PERF.md §7) and multiplying each DMA
+    by K. Compute is the same online softmax, batched over K via
     dot_general batch dims — no in-kernel head slicing, so the hd%128
     Mosaic constraint this file exists for is still never violated."""
     b = pl.program_id(0)
@@ -369,10 +369,10 @@ def _make_blocked_kernel(*, page_size: int, ppb: int, nblk: int,
     """Kernel body for ``paged_attention_native_blocked``: ``ppb`` pages of
     ALL kv heads folded into one grid step (grid (B, ceil(pps/ppb)) — the
     kv-heads folding of ``_paged_kernel_folded`` composed with a page-axis
-    collapse). The round-5 silicon numbers put the one-page kernel at
-    Mosaic's ~1 µs/grid-step floor with (B × K × pps) steps per layer
-    (BASELINE.md): the kernel is LAUNCH-bound, not bandwidth-bound, so the
-    lever is fewer grid steps moving the same bytes.
+    collapse). The one-page kernel sits at Mosaic's per-grid-step floor of
+    about 1 µs with (B × K × pps) steps per layer (PERF.md §7): it is
+    LAUNCH-bound, not bandwidth-bound, so the lever is fewer grid steps
+    moving the same bytes.
 
     The per-page gather stays in BlockSpec ``index_map``s — one per
     in-block page, each reading its own scalar-prefetched table slot

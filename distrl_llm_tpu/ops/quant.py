@@ -93,69 +93,6 @@ def quantize_params(
     return out
 
 
-def pack_int4(q: jax.Array) -> jax.Array:
-    """Pack int4 values (range [-8, 7], any int dtype) two-per-byte along
-    the group axis (-2) → int8 [..., G, g/2, out].
-
-    The TRANSPORT/STORAGE form of an int4 payload: serialization layers
-    that widen jnp.int4 to a full byte store nibbles at their true
-    0.5 byte/value width instead (``pack_params_int4`` applies it to a
-    whole quantized tree — the bench/prep params disk cache goes through
-    it). ``unpack_int4`` is the bit-exact inverse (pinned by
-    tests/test_quant.py). Requires an even group dim."""
-    q8 = q.astype(jnp.int8)
-    g = q8.shape[-2]
-    if g % 2 != 0:
-        raise ValueError(f"pack_int4 needs an even group dim, got {g}")
-    lo = q8[..., 0::2, :] & jnp.int8(0x0F)
-    hi = jnp.left_shift(q8[..., 1::2, :] & jnp.int8(0x0F), 4)
-    return lo | hi
-
-
-def unpack_int4(packed: jax.Array, dtype=jnp.int4) -> jax.Array:
-    """int8 nibble-packed [..., G, g/2, out] → int4 values [..., G, g, out]
-    (sign-extended via arithmetic shifts — bit-exact pack/unpack
-    roundtrip)."""
-    p8 = packed.astype(jnp.int8)
-    lo = jnp.right_shift(jnp.left_shift(p8, 4), 4)  # sign-extend low nibble
-    hi = jnp.right_shift(p8, 4)  # arithmetic shift sign-extends high nibble
-    *lead, gh, out = p8.shape
-    stacked = jnp.stack([lo, hi], axis=-2)  # [..., g/2, 2, out]
-    return stacked.reshape(*lead, gh * 2, out).astype(dtype)
-
-
-def pack_params_int4(params: Params) -> Params:
-    """Transport form of a quantized param tree: every int4 container's
-    payload is nibble-packed (``{"q4": int8 [..., G, g/2, out], "scale"}``
-    replaces ``{"q", "scale"}``), halving its serialized bytes. int8
-    containers and dense leaves pass through untouched; containers with an
-    odd group dim stay unpacked. ``unpack_params_int4`` is the bit-exact
-    inverse."""
-    layers = dict(params.get("layers", {}))
-    for name, w in layers.items():
-        if (
-            is_quantized(w) and w["q"].dtype == jnp.int4
-            and w["q"].shape[-2] % 2 == 0
-        ):
-            layers[name] = {"q4": pack_int4(w["q"]), "scale": w["scale"]}
-    out = dict(params)
-    out["layers"] = layers
-    return out
-
-
-def unpack_params_int4(params: Params) -> Params:
-    """Inverse of ``pack_params_int4``: nibble-packed containers return to
-    their live ``{"q": int4, "scale"}`` form; everything else passes
-    through."""
-    layers = dict(params.get("layers", {}))
-    for name, w in layers.items():
-        if isinstance(w, dict) and "q4" in w:
-            layers[name] = {"q": unpack_int4(w["q4"]), "scale": w["scale"]}
-    out = dict(params)
-    out["layers"] = layers
-    return out
-
-
 def quant_bits_for(config_value: str) -> int | None:
     """Map the ``base_quant`` config field ({"none","int8","int4"}) to bits."""
     return {"none": None, "int8": 8, "int4": 4}[config_value]

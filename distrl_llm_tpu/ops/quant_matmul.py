@@ -47,8 +47,8 @@ from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.ops.per_device import per_device
 
 #: trace-time dispatch record (the ops.paged.dispatch_choices idiom): keyed by
-#: (bits, K, N, rank, dtype) → "kernel" | "xla"; bench reads it so a row
-#: claiming the fused path can never have silently measured the container path
+#: (bits, K, N, rank, dtype) → "kernel" | "xla", so that a run claiming the
+#: fused path can be shown not to have measured the container path
 dispatch_choices: dict = {}
 
 MODES = ("auto", "kernel", "interpret", "xla")

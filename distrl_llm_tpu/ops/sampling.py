@@ -167,7 +167,7 @@ def sample(
 # precedent), not bit-exact.
 
 #: trace-time dispatch record (ops.paged.dispatch_choices idiom): keyed by
-#: (rows, vocab) → "fused" | "xla"; bench reads it for the sample_kernel row
+#: (rows, vocab) → "fused" | "xla"; chip_smoke.py reads it
 sample_dispatch_choices: dict = {}
 
 SAMPLE_IMPLS = ("auto", "fused", "interpret", "xla")

@@ -1,6 +1,6 @@
 """Splash attention (Pallas) for GQA training forwards — no repeat_kv.
 
-VERDICT r1 flagged the flash path's GQA handling: jaxlib's flash kernel
+The flash path's GQA handling is wasteful: jaxlib's flash kernel
 demands equal head counts, so K/V are ``jnp.repeat``-ed to full heads — the
 exact KV traffic multiplication (7× for Qwen2.5-0.5B) the decode path avoids.
 The splash kernel is natively multi-query: built per KV head group

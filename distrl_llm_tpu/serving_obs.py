@@ -29,7 +29,7 @@ engine:
   admission), ``chain_cap`` (the live prefix-chain cap), or
   ``budget_wedge`` (the PR 12 wedge detector: all slots dead and the page
   budget cannot make progress). ``serving/admission_stalls/<reason>``
-  counters explain the ``slot_idle_frac`` bench field instead of just
+  counters explain the ``slot_idle_frac`` round statistic instead of just
   measuring it; ``tools/serving_smoke.py`` asserts the reason counts sum
   to the declined passes — an unattributed decline is a bug, not a gap.
 * **Live occupancy tracks** — per-boundary gauges (``serving/live_slots``,
@@ -46,7 +46,7 @@ counter — so ``tools/lineage_report.py --serving`` joins serving latency
 onto policy-lag rows.
 
 Cost contract: the ledger exists only when armed (``--serving_obs`` /
-worker ``--serving-obs`` / an attached bench ledger); every hook site in
+worker ``--serving-obs`` / a ledger an owner attaches); every hook site in
 the engine is one ``is not None`` attribute check when off, so the
 telemetry-off fast path and the sync byte-identity pins are untouched.
 The ledger never changes scheduling decisions — byte-identical outputs
@@ -110,8 +110,8 @@ STALL_REASONS = (
     "no_slots", "no_pages", "chain_cap", "budget_wedge", "shed", "quota",
 )
 
-# closed-value window per metric for percentile queries (bench rows, the
-# smoke): bounds host memory on a long-running server; counts/sums in the
+# closed-value window per metric for percentile queries (the smoke):
+# bounds host memory on a long-running server; counts/sums in the
 # registry histograms stay exact regardless
 _SAMPLE_WINDOW = 8192
 

@@ -1,10 +1,7 @@
 """Unified telemetry: span tracing, perf counters, and a Perfetto-exportable
 step timeline across the driver, engines, and workers.
 
-Round 5's verdict was the motivating failure: a 2.5×-slower scan-chunk lever
-was silently engaged and the paged engine ran 5–6× behind the dense fallback,
-both discovered only by cross-reading bench JSONs after the fact. The
-reference's only observability is inline ``time.time()`` pairs (SURVEY §5);
+The reference's only observability is inline ``time.time()`` pairs (SURVEY §5);
 this module gives every layer the same three instruments:
 
 * **Spans** — ``with span("engine/prefill", rows=b): ...`` appends one dict

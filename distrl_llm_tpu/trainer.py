@@ -296,7 +296,7 @@ class Trainer:
         self._rng_mu = _threading.Lock()
         self._rng, lora_key = jax.random.split(self._rng)
         if config.full_finetune:
-            # BASELINE config 3 (bf16 full-rank, no 4-bit): the WHOLE param
+            # reference recipe 3 (bf16 full-rank, no 4-bit): the WHOLE param
             # tree is the trainable state; there is no adapter. self.lora
             # holds whichever tree trains — the engine call sites and weight
             # push branch on _full below. The trainable copy is kept in f32
@@ -411,7 +411,7 @@ class Trainer:
         # known (telemetry table / DISTRL_PEAK_FLOPS); None suppresses the
         # engine/mfu series rather than publishing a made-up number.
         # decode_tok_s is WHOLE-ENGINE throughput, so MFU divides it by the
-        # rollout chip count first (bench.py:learner does the same) —
+        # rollout chip count first —
         # otherwise an 8-chip mesh reports ~8× the true utilisation
         self._peak_flops = telemetry.device_peak_flops()
         self._rollout_chips = (
@@ -2000,11 +2000,11 @@ class Trainer:
             "episode": episode,
             "total_batch_steps": self.total_batch_steps,
             "total_samples_processed": self.total_samples_processed,
-            # rollout-regime provenance on every train-curve record (the
-            # bench rows carry the same three fields — artifacts from
-            # different regimes must be distinguishable from the JSONL
-            # alone): the mode, the EFFECTIVE staleness bound (0 sync /
-            # 1 pipelined / K async), and cumulative stale drops
+            # rollout-regime provenance on every train-curve record
+            # (artifacts from different regimes must be distinguishable
+            # from the JSONL alone): the mode, the EFFECTIVE staleness
+            # bound (0 sync / 1 pipelined / K async), and cumulative stale
+            # drops
             "rollout_mode": cfg.rollout_mode,
             "max_staleness": cfg.allowed_weight_lag,
             "rollout_dropped_stale": getattr(
@@ -2024,7 +2024,7 @@ class Trainer:
         # _generate_round on the thread that ran THIS round (reading the
         # engine attribute here would race async rollout / eval rounds).
         # A stat the engine didn't produce is SKIPPED, not logged as None
-        # (a null metric poisons sink aggregations — ADVICE r5).
+        # (a null metric poisons sink aggregations).
         pool = next(
             (c["pool_stats"] for c in candidates if "pool_stats" in c), None
         )
@@ -2161,12 +2161,6 @@ class Trainer:
                 # trace_report divides whole-engine tok/s by this before
                 # comparing against the single-chip peak
                 "chips": self._rollout_chips,
-                # measured attribution (ISSUE 8): XLA cost_analysis of the
-                # explicitly-compiled step programs + per-phase HBM
-                # watermarks — the roofline section's inputs (both empty
-                # on runs that recorded neither)
-                "costs": obs_mod.costs(),
-                "phase_hbm": obs_mod.phase_hbm(),
             },
         )
         log.info("telemetry trace written to %s", path)

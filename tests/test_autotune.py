@@ -7,7 +7,7 @@ The two contracts the subsystem exists for, both pinned here:
   byte-identically to the pre-autotuner hard-coded defaults;
 * with a DB populated from the round-5 silicon measurements, the resolved
   plan for the benched dense-bf16 geometry selects scan-chunk OFF — the
-  2.5× regression (VERDICT.md) becomes unrepresentable without deleting
+  2.5× regression becomes unrepresentable without deleting
   the DB.
 """
 
@@ -561,8 +561,7 @@ class TestBenchIngest:
         assert r.source == "db"
         assert r.plan.decode_path == "dense"
         # the winner ran with scan-chunk FALLEN BACK → the stored plan turns
-        # chunking OFF: bench.py's production default can no longer engage
-        # the 2.5×-slower lever while this DB exists
+        # chunking OFF
         assert r.plan.scan_chunk == 0
         assert r.plan.top_p_impl == "bisect_mw"
 

@@ -114,7 +114,7 @@ def test_workers_capture_logprobs_gate():
 
 
 class TestReadmeBaselineCommands:
-    """The README's five BASELINE-config commands must parse into valid
+    """The README's five reference-recipe commands must parse into valid
     TrainConfigs — documentation that cannot rot."""
 
     CMDS = [

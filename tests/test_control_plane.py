@@ -1,6 +1,6 @@
 """Multi-process control-plane tests (N5): real worker subprocesses on CPU.
 
-The VERDICT r1 minimum bar: a 2-process test that dispatches a rollout shard
+The minimum bar: a 2-process test that dispatches a rollout shard
 and collects rewards over the control plane — plus health checks and the
 shard-resubmission failure path the reference lacks (its worker death kills
 the run, SURVEY §5).
@@ -287,8 +287,8 @@ class TestJaxDistributed:
 
     @pytest.mark.slow
     def test_two_process_rollout_train_round(self):
-        """Full round across 2 REAL jax.distributed processes (VERDICT r3
-        item 8): per-process local rollouts through the generation engine,
+        """Full round across 2 REAL jax.distributed processes:
+        per-process local rollouts through the generation engine,
         then one jitted GRPO train step over the global dp mesh — the
         gradient psum crosses the process boundary (gloo CPU collectives,
         the DCN stand-in). Each rank feeds different batch rows, so the

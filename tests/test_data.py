@@ -69,7 +69,7 @@ class TestDictDataset:
 
 
 class TestGsm8k:
-    """GSM8K prep (BASELINE config 3's dataset): '#### N' gold-answer
+    """GSM8K prep (reference recipe 3's dataset): '#### N' gold-answer
     extraction feeding the same exact-match reward contract."""
 
     @pytest.mark.parametrize("raw,want", [

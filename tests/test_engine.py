@@ -359,7 +359,7 @@ class TestScanChunk:
         np.testing.assert_array_equal(a.lengths, b.lengths)
 
     def test_chunk_matches_default_dot_host_decode(self, setup):
-        """ADVICE r5: TestScanChunk pins its host reference to mulred for
+        """TestScanChunk pins its host reference to mulred for
         bit-exact dispatch comparison, which left the DEFAULT dot-formulation
         host path untested against the chunk path at engine level. This is
         the tolerance-based cross-formulation anchor: a default engine (dot
@@ -382,7 +382,7 @@ class TestScanChunk:
         np.testing.assert_allclose(a.logprobs, b.logprobs, rtol=1e-4, atol=1e-5)
 
     def test_structural_swap_rebuilds_chunk_program(self, setup):
-        """ADVICE r3 regression: an in-flight swap to a STRUCTURALLY
+        """Regression: an in-flight swap to a STRUCTURALLY
         different adapter (None-adapter round receiving its first adapter)
         lands at a chunk boundary; the chunk program is a compiled
         executable that raises on structure change instead of retracing —

@@ -111,7 +111,7 @@ class TestProfiler:
     @pytest.mark.slow
     def test_trace_dir_produced(self, tmp_path):
         """profile_dir is no longer a dead flag: a smoke run produces a
-        TensorBoard trace directory (VERDICT r1 item 6)."""
+        TensorBoard trace directory."""
         prof = str(tmp_path / "traces")
         cfg = make_config(profile_dir=prof, profile_start_step=1, profile_num_steps=1)
         trainer = make_trainer(config=cfg)

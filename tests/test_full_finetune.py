@@ -1,4 +1,4 @@
-"""bf16 full-rank fine-tuning (BASELINE config 3: "bf16 full-rank, no
+"""bf16 full-rank fine-tuning (reference recipe 3: "bf16 full-rank, no
 4-bit") — the whole param tree trains instead of a LoRA adapter."""
 
 import numpy as np

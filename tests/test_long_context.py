@@ -1,4 +1,4 @@
-"""Long-CoT shapes (BASELINE config 4: 4k-token rollouts) on the CPU mesh.
+"""Long-CoT shapes (reference recipe 4: 4k-token rollouts) on the CPU mesh.
 
 The reference cannot express these at all (sequence hard-fixed at 1,550
 tokens, SURVEY §5 long-context); here the learner's 4k-token step runs

@@ -489,7 +489,7 @@ class TestFamilyReviewRegressions:
 
 class TestR1DistillPreset:
     def test_r1_distill_models_refuse_presets(self):
-        """BASELINE config 4's models match preset tensor dims but NOT RoPE
+        """Reference recipe 4's models match preset tensor dims but NOT RoPE
         (R1-Distill-Qwen-7B derives from Qwen2.5-Math-7B: rope_theta 1e4 vs
         the preset's 1e6) — a preset would silently produce garbage logits,
         so every distill id must force config.json-driven loading (review)."""

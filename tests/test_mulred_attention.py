@@ -93,7 +93,7 @@ class TestMulredOp:
 
 
 class TestFormulationValidation:
-    """ADVICE r5: an unrecognized formulation string must raise, not
+    """An unrecognized formulation string must raise, not
     silently fall back to the dot path (a typo like 'mul_red' inside a scan
     program would reintroduce the relayout/OOM the flag avoids)."""
 

@@ -178,7 +178,7 @@ class TestDifferential:
 class TestLoadTokenizerDispatch:
     def test_unigram_checkpoint_loads_native_spm(self, tmp_path):
         """load_tokenizer must route Unigram tokenizer.json to the C++ SPM
-        core (the VERDICT r2 gap: Gemma silently fell back to HF)."""
+        core (once a gap: Gemma silently fell back to HF)."""
         _, native = _build_pair()  # builds the serialized fixture pieces
         rng = np.random.default_rng(0)
         vocab = [["<unk>", 0.0], ["▁hi", -1.0], ["hi", -1.5]]

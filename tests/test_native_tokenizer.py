@@ -6,7 +6,7 @@ byte-level BPE is TRAINED at test time with the `tokenizers` library using the
 exact Qwen2 tokenizer.json configuration (NFC normalizer + cl100k-style Split
 regex + ByteLevel), saved as tokenizer.json, and the C++ core must reproduce
 the Rust encode/decode exactly — including the \\p{N}{1,3} digit chunking and
-newline alternatives the round-1 GPT-2 approximation got wrong (ADVICE r1).
+newline alternatives the round-1 GPT-2 approximation got wrong.
 """
 
 import json
@@ -44,7 +44,7 @@ CORPUS = [
 TRICKY = [
     "12345678901234567890",          # digit chunking \p{N}{1,3}
     "1,234,567.89 and -42",
-    "a\n\nb",                        # newline alternatives (ADVICE example)
+    "a\n\nb",                        # newline alternatives
     "x \n \n y",                     # mixed space/newline runs
     "   leading and trailing   ",
     "tabs\tand nbsp　ideographic",

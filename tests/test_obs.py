@@ -2,7 +2,7 @@
 JSON), the live endpoint, fleet aggregation math from synthetic worker
 snapshots, the flight-recorder ring bound/eviction, sentinel trigger
 determinism (seeded NaN → exactly one incident bundle), the TraceProfiler
-capture guards, and the trace_report roofline section."""
+capture guards, and trace_report's MFU line."""
 
 import json
 import os
@@ -503,14 +503,77 @@ class TestCompileTracker:
         obs.note_compile("fn_b", [[1, 2], [3]])
         assert obs.retrace_total() == 1
 
-    def test_record_cost_from_compiled(self):
+
+class TestNoCostRecorder:
+    def test_no_costs_key_and_no_extra_programs(self, tmp_path, monkeypatch):
+        """The step-cost recorder is gone, not merely unread: neither the
+        endpoint's snapshot nor an exported trace's metadata has a
+        ``costs`` key, and the switch that once made the engines lower
+        their step programs a second time builds no program more."""
+        import types
+
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
-        compiled = jax.jit(lambda x: x * 2).lower(jnp.ones((4,))).compile()
-        entry = obs.record_cost("toy", compiled)
-        assert entry is not None and entry["flops"] > 0
-        assert obs.costs()["toy"]["flops"] == entry["flops"]
+        from distrl_llm_tpu.config import SamplingConfig
+        from distrl_llm_tpu.engine import GenerationEngine
+        from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
+        from distrl_llm_tpu.models import TINY, init_params
+        from distrl_llm_tpu.trainer import Trainer
+
+        assert "costs" not in obs.json_snapshot()
+
+        telemetry.configure(enabled=True)
+        Trainer._export_trace(types.SimpleNamespace(
+            config=types.SimpleNamespace(
+                trace_dir=str(tmp_path), model="tiny",
+                max_prompt_tokens=8, max_new_tokens=4,
+            ),
+            model_cfg=TINY, _peak_flops=None, _rollout_chips=1,
+        ))
+        telemetry.configure(enabled=False)
+        doc = json.loads((tmp_path / "trace.json").read_text())
+        assert doc["metadata"]["decode_flops_per_token"] > 0
+        assert "costs" not in doc["metadata"]
+
+        params = init_params(jax.random.PRNGKey(7), TINY)
+        ids = np.arange(1, 17, dtype=np.int32).reshape(2, 8)
+        mask = np.ones((2, 8), np.int32)
+        sampling = SamplingConfig(max_tokens=4, temperature=1.0, n=2)
+        kw = dict(
+            max_prompt_tokens=8, max_new_tokens=4, pad_token_id=0,
+            eos_token_ids=[TINY.vocab_size - 1], cache_dtype=jnp.float32,
+        )
+
+        def round_of_each():
+            for engine in (
+                GenerationEngine(TINY, **kw),
+                PagedGenerationEngine(TINY, page_size=8, **kw),
+                PagedGenerationEngine(
+                    TINY, page_size=8, scheduler="refill",
+                    max_concurrent_rows=2, **kw
+                ),
+            ):
+                engine.generate(
+                    params, None, ids, mask, sampling, jax.random.PRNGKey(0)
+                )
+
+        log = telemetry.CompileLog()
+        try:
+            round_of_each()  # module-level programs are built once, here
+            mark = log.mark()
+            round_of_each()
+            without = log.since(mark)["programs"]
+            assert without > 0  # each engine builds its own programs
+            # the name is split so that a search of the tree for the switch
+            # finds no reader and no writer, this test included
+            monkeypatch.setenv("DISTRL_MEASURE" + "_COST", "1")
+            mark = log.mark()
+            round_of_each()
+            assert log.since(mark)["programs"] == without
+        finally:
+            log.close()
 
 
 class TestTraceProfilerGuards:
@@ -568,7 +631,7 @@ class TestTraceProfilerGuards:
         assert calls["stop"] == 2  # nothing left to stop
 
 
-class TestRooflineReport:
+class TestTraceReport:
     def _events(self):
         return [
             {"ph": "M", "name": "process_name", "pid": 1, "tid": 0,
@@ -581,37 +644,26 @@ class TestRooflineReport:
              "pid": 1, "tid": 2, "args": {"tokens": 4000}},
         ]
 
-    def test_section_rendered_with_obs_metadata(self):
+    def test_mfu_line_from_trace_metadata(self):
         import importlib
 
         tr = importlib.import_module("tools.trace_report")
-        metadata = {
-            "decode_flops_per_token": 1e9,
-            "peak_flops": 1e13,
-            "chips": 1,
-            "costs": {"scan_chunk=8 bucket=64": {
-                "flops": 2e9, "bytes_accessed": 1e9,
-            }},
-            "phase_hbm": {"generation": {
-                "live_max": 1.0, "peak_max": 2.0 * 2**30, "samples": 3,
-            }},
-        }
-        report = tr.build_report(self._events(), metadata)
-        assert "roofline (measured):" in report
-        assert "generation" in report and "2.00 GiB" in report
-        assert "scan_chunk=8 bucket=64" in report
-        assert "intensity 2.00 FLOP/B" in report
+        report = tr.build_report(self._events(), {
+            "decode_flops_per_token": 1e9, "peak_flops": 1e13, "chips": 1,
+        })
         # 4000 tok / 2 s = 2000 tok/s × 1 GF/tok = 2 TF/s of 10 TF peak
-        assert "20.00% of peak" in report
-
-    def test_section_absent_without_obs_metadata(self):
-        import importlib
-
-        tr = importlib.import_module("tools.trace_report")
+        assert "decode  tok/s: 2,000" in report
+        assert "decode MFU:    20.00%" in report
+        # two chips: the engine's tok/s is divided before the peak
+        report = tr.build_report(self._events(), {
+            "decode_flops_per_token": 1e9, "peak_flops": 1e13, "chips": 2,
+        })
+        assert "decode MFU:    10.00%" in report and "2 chips" in report
+        # no peak known: the line says what is missing, never a number
         report = tr.build_report(
             self._events(), {"decode_flops_per_token": 1e9}
         )
-        assert "roofline (measured)" not in report
+        assert "decode MFU:    n/a" in report
 
     def test_truncated_trace_one_line_failure(self, tmp_path, capsys):
         """A still-being-written/truncated trace file must exit 1 with one

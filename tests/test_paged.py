@@ -630,7 +630,7 @@ class TestRefillScanChunk:
 
     @pytest.mark.slow
     def test_structural_swap_rebuilds_chunk_program(self, setup4):
-        """ADVICE r3 regression (refill flavor): the None->first-adapter
+        """Regression (refill flavor): the None->first-adapter
         in-flight swap lands at a k-aligned dispatch; the compiled chunk
         program must be refetched for the new signature, not crash."""
         from distrl_llm_tpu.models import init_lora_params

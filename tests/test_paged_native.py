@@ -232,7 +232,7 @@ class TestBlockedKernel:
     def test_grid_step_budget_r5_geometry(self):
         """The acceptance criterion: ≥ 8× fewer grid steps than the
         one-page kernel at the benched r5 paged geometry (480 rows × 2 kv
-        × 13 pages; BASELINE.md's ~300k-steps-per-decode-step analysis)."""
+        × 13 pages: about 300k grid steps a decode step; PERF.md §7)."""
         from distrl_llm_tpu.ops.paged import paged_grid_steps
 
         r5 = dict(batch=480, num_kv_heads=2, pps=13)

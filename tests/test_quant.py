@@ -6,7 +6,6 @@ quantized base, a train step (grads flow only through LoRA), and partition
 specs for the container leaves.
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -239,36 +238,6 @@ class TestEdgeCases:
         deq = dequantize(quantize(w, bits=8), dtype=jnp.float32)
         assert deq.shape == w.shape
 
-    def test_int4_pack_unpack_roundtrip_bit_exact(self):
-        from distrl_llm_tpu.ops.quant import pack_int4, unpack_int4
-
-        q = quantize(rand_w((128, 48), seed=6), bits=4, group_size=32)["q"]
-        packed = pack_int4(q)
-        assert packed.dtype == jnp.int8
-        assert packed.shape == (4, 16, 48)  # group axis halved
-        assert packed.nbytes * 2 == q.astype(jnp.int8).nbytes
-        restored = unpack_int4(packed)
-        assert restored.dtype == q.dtype
-        assert (np.asarray(restored.astype(jnp.int8))
-                == np.asarray(q.astype(jnp.int8))).all()
-
-    def test_int4_pack_full_value_range(self):
-        # every representable nibble (-8..7) survives the roundtrip,
-        # including the -8 jnp.int4 can hold but absmax never emits
-        from distrl_llm_tpu.ops.quant import pack_int4, unpack_int4
-
-        vals = jnp.asarray(
-            np.arange(-8, 8, dtype=np.int8).reshape(1, 16, 1), jnp.int8
-        )
-        out = unpack_int4(pack_int4(vals), dtype=jnp.int8)
-        assert (np.asarray(out) == np.asarray(vals)).all()
-
-    def test_pack_int4_odd_group_raises(self):
-        from distrl_llm_tpu.ops.quant import pack_int4
-
-        with pytest.raises(ValueError, match="even"):
-            pack_int4(jnp.zeros((1, 3, 4), jnp.int8))
-
     def test_scales_pinned_f32(self):
         # bf16-rounding the scales stacks ~0.4% error on the quantization
         # error (ops/linear.py) — the container contract stores them f32
@@ -300,59 +269,3 @@ class TestEdgeCases:
         assert not isinstance(qp["final_norm"], dict)
         assert not isinstance(qp["layers"]["attn_norm"], dict)
         assert not isinstance(qp["layers"]["mlp_norm"], dict)
-
-    def test_pack_params_int4_roundtrip_and_passthrough(self):
-        # the transport form the bench/prep params disk cache serializes:
-        # int4 payloads nibble-packed (half the bytes), int8 and dense
-        # leaves untouched, bit-exact roundtrip
-        from distrl_llm_tpu.ops.quant import (
-            pack_params_int4, unpack_params_int4,
-        )
-
-        params = init_params(jax.random.PRNGKey(0), TINY)
-        q4 = quantize_params(params, bits=4, group_size=16)
-        packed = pack_params_int4(q4)
-        for name in QUANT_TARGETS:
-            assert "q4" in packed["layers"][name]
-            assert packed["layers"][name]["q4"].dtype == jnp.int8
-            assert (packed["layers"][name]["q4"].nbytes * 2
-                    == q4["layers"][name]["q"].astype(jnp.int8).nbytes)
-        restored = unpack_params_int4(packed)
-        for name in QUANT_TARGETS:
-            a = restored["layers"][name]["q"].astype(jnp.int8)
-            b = q4["layers"][name]["q"].astype(jnp.int8)
-            assert (np.asarray(a) == np.asarray(b)).all()
-        # int8 trees pass through both directions untouched
-        q8 = quantize_params(params, bits=8, group_size=16)
-        assert pack_params_int4(q8)["layers"]["wq"] is q8["layers"]["wq"]
-        assert unpack_params_int4(q8)["layers"]["wq"] is q8["layers"]["wq"]
-
-    def test_bench_params_cache_packs_int4(self, tmp_path):
-        # the production caller: host_quantized_params round-trips the
-        # packed form through orbax and hands back live int4 containers
-        import sys as _sys
-
-        _sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-        from distrl_llm_tpu.models import TINY as _TINY
-        from distrl_llm_tpu.ops.quant import is_quantized_tree
-
-        os.environ["BENCH_PARAMS_CACHE"] = str(tmp_path)
-        try:
-            host = jax.devices("cpu")[0]
-            saved = bench.host_quantized_params(
-                "tiny", _TINY, jnp.float32, "int4", host
-            )
-            restored = bench.host_quantized_params(
-                "tiny", _TINY, jnp.float32, "int4", host
-            )
-        finally:
-            del os.environ["BENCH_PARAMS_CACHE"]
-        assert is_quantized_tree(restored)
-        for name in ("wq", "w_down"):
-            assert restored["layers"][name]["q"].dtype == jnp.int4
-            assert (
-                np.asarray(restored["layers"][name]["q"].astype(jnp.int8))
-                == np.asarray(saved["layers"][name]["q"].astype(jnp.int8))
-            ).all()

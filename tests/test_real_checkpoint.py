@@ -1,4 +1,4 @@
-"""Real-format checkpoint realism (VERDICT r3 item 10 / missing #5).
+"""Real-format checkpoint realism.
 
 The loader was previously exercised against state dicts synthesized by THIS
 repo's own code paths; these tests make ``transformers`` itself write the

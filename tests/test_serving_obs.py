@@ -2,7 +2,7 @@
 state machine, the paged engine's refill/continuous instrumentation
 (byte-identity with the ledger armed, complete monotone lifecycles,
 admission-stall conservation), the fleet fold, the sentinel SLO triggers,
-config/CLI validation, and the serving_report / bench_history satellites."""
+config/CLI validation, and the serving_report satellite."""
 
 import json
 import os
@@ -572,69 +572,6 @@ class TestServingReportTool:
         ])
         assert serving_report.main([path]) == 0
         assert "carry no reason" in capsys.readouterr().out
-
-
-class TestBenchHistoryLatency:
-    def test_latency_metrics_lower_is_better(self):
-        from tools import bench_history as bh
-
-        assert bh.lower_is_better("ttft_p99_ms")
-        assert bh.lower_is_better("serving_queue_wait_ms")
-        assert not bh.lower_is_better("rollout_tokens_per_sec_per_chip")
-        # throughput: a drop flags, an improvement doesn't
-        assert bh.regressed("tok_s", 100.0, 80.0, 0.10)
-        assert not bh.regressed("tok_s", 100.0, 120.0, 0.10)
-        # latency: an INCREASE flags, an improvement doesn't (the bug the
-        # satellite fixes: a >10% TTFT improvement used to read as a drop)
-        assert bh.regressed("ttft_p50_ms", 100.0, 120.0, 0.10)
-        assert not bh.regressed("ttft_p50_ms", 100.0, 80.0, 0.10)
-
-    def test_row_latency_fields_scanned(self, tmp_path, monkeypatch, capsys):
-        from tools import bench_history as bh
-
-        def art(n, value, ttft):
-            rec = {"metric": "rollout_tokens_per_sec_per_chip",
-                   "value": value, "backend": "cpu",
-                   "ttft_p50_ms": ttft}
-            return {"n": n, "rc": 0, "tail": json.dumps(rec)}
-
-        for n, value, ttft in ((1, 100.0, 50.0), (2, 101.0, 80.0)):
-            with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-                json.dump(art(n, value, ttft), f)
-        monkeypatch.setattr(bh, "REPO", str(tmp_path))
-        rc = bh.main(["--glob", "BENCH_r*.json"])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "ttft_p50_ms 50.0 → 80.0" in out.replace(",", "")
-
-    def test_rate_fields_scanned_higher_is_better(
-        self, tmp_path, monkeypatch, capsys
-    ):
-        """ISSUE 18: radix_hit_rate is scanned HIGHER-is-better — a hit-
-        rate collapse between comparable cache-on rounds flags (warm
-        admissions stopped landing) while an improvement never does; the
-        restore latency scans with the *_ms fields (lower-is-better)."""
-        from tools import bench_history as bh
-
-        assert "radix_hit_rate" in bh.RATE_FIELDS
-        assert "spill_restore_ms_p50" in bh.LATENCY_FIELDS
-        assert bh.lower_is_better("spill_restore_ms_p50")
-        assert not bh.lower_is_better("radix_hit_rate")
-
-        def art(n, hit):
-            rec = {"metric": "rollout_tokens_per_sec_per_chip",
-                   "value": 100.0, "backend": "cpu",
-                   "radix_hit_rate": hit}
-            return {"n": n, "rc": 0, "tail": json.dumps(rec)}
-
-        for n, hit in ((1, 0.8), (2, 0.4)):
-            with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-                json.dump(art(n, hit), f)
-        monkeypatch.setattr(bh, "REPO", str(tmp_path))
-        rc = bh.main(["--glob", "BENCH_r*.json"])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "radix_hit_rate 0.800 → 0.400" in out.replace(",", "")
 
 
 class TestLineageServingJoin:

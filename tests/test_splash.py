@@ -3,7 +3,7 @@
 Runs the REAL kernel under the Pallas interpreter on CPU (same code path
 Mosaic compiles on TPU) against the XLA reference — forward and gradients
 (the kernel carries custom-VJP backward kernels, needed by the learner).
-VERDICT r1 weak #6: this path replaces flash's GQA repeat_kv (G× KV traffic).
+This path replaces flash's GQA repeat_kv (G× KV traffic).
 """
 
 import numpy as np

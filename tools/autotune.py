@@ -9,14 +9,13 @@ Three subcommands:
   Warmup/steady-state separated; OOM/compile-failing candidates score
   infeasible instead of killing the sweep.
 
-* ``ingest`` — derive plans from EXISTING bench.py JSON rows (e.g. the
-  round-5 silicon artifacts under benchmarks/r5/): group rows by
-  (device, model, geometry), pick the fastest error-free row, and store the
-  plan it actually ran — ``scan_chunk_active: false`` rows store chunk 0,
-  which is how the r5 "2.5×-slower production default" becomes
-  unrepresentable once the DB exists. Geometry is not recorded in bench
-  rows, so ``--max-prompt/--max-new`` name it (defaults: the reference
-  350/1200).
+* ``ingest`` — derive plans from the JSON rows the retired benchmark
+  script printed (the last of them are under benchmarks/r5/; nothing
+  produces such rows now, and the subcommand goes with the plan database):
+  group rows by (device, model, geometry), pick the fastest error-free row,
+  and store the plan it actually ran — ``scan_chunk_active: false`` rows
+  store chunk 0. Geometry is not recorded in those rows, so
+  ``--max-prompt/--max-new`` name it (defaults: the reference 350/1200).
 
 * ``report`` — print every stored plan with its best measurement.
 
@@ -95,8 +94,7 @@ def plan_from_bench_row(row: dict):
     if path == "speculative":
         # spec rows carry their whole configuration (ISSUE 6): the draft
         # length, the drafter, and the verify kernel that actually ran —
-        # storing them makes the tuned plan reproducible without
-        # BENCH_SPEC_* scaffolding
+        # storing them makes the tuned plan reproducible
         spec_kw = {
             "spec_draft_len": int(row.get("spec_draft") or 0),
             "spec_drafter": row.get("spec_drafter"),
@@ -403,7 +401,11 @@ def build_parser() -> argparse.ArgumentParser:
     m.add_argument("--repeats", type=int, default=2)
     m.set_defaults(fn=cmd_measure)
 
-    i = sub.add_parser("ingest", help="derive plans from bench.py JSON rows")
+    i = sub.add_parser(
+        "ingest",
+        help="derive plans from the retired benchmark script's JSON rows "
+             "(benchmarks/r5)",
+    )
     common(i)
     i.add_argument("bench", nargs="+", help="bench JSON files (one row/line)")
     i.add_argument("--max-prompt", dest="max_prompt", type=int, default=350)

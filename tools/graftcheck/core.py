@@ -162,10 +162,9 @@ def load_project(root: str, extra_rel: Iterable[str] = ()) -> Project:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     wanted.append(os.path.join(dirpath, fn))
-    for fn in ("train_distributed.py", "bench.py"):
-        p = os.path.join(root, fn)
-        if os.path.exists(p):
-            wanted.append(p)
+    entry = os.path.join(root, "train_distributed.py")
+    if os.path.exists(entry):
+        wanted.append(entry)
     for rel in extra_rel:
         p = os.path.join(root, rel)
         if os.path.exists(p):

@@ -1,8 +1,7 @@
-"""On-chip HBM envelope for BASELINE config 2 (Qwen2.5-7B, one chip).
+"""On-chip HBM envelope for reference recipe 2 (Qwen2.5-7B, one chip).
 
-The round-2 verdict asked for the 7B-on-one-chip capacity math to come from
-measurement-grade accounting instead of folklore: this tool computes the
-envelope with ``jax.eval_shape`` (exact per-leaf bytes, nothing allocated)
+The 7B-on-one-chip capacity math from exact accounting: this tool computes
+the envelope with ``jax.eval_shape`` (exact per-leaf bytes, nothing allocated)
 for the int4-quantized base + LoRA + the paged engine's page pools at the
 reference rollout volume (480 candidates, 350+1,200 token budget,
 train_distributed.py:17-28), across slot counts and KV-quant modes, and
@@ -33,7 +32,7 @@ def main() -> None:
     ap.add_argument("--usage", type=float, default=0.91,
                     help="--actor_gpu_usage (reference default)")
     ap.add_argument("--markdown", action="store_true",
-                    help="emit the BASELINE.md table body")
+                    help="emit the table as markdown rows")
     args = ap.parse_args()
 
     import jax
@@ -75,7 +74,7 @@ def main() -> None:
     w_bytes = tree_bytes_abstract(base_q)
     lora_bytes = tree_bytes_abstract(lora)
 
-    # config-2 volume (BASELINE.md; reference train_distributed.py:17-28)
+    # recipe-2 volume (reference train_distributed.py:17-28)
     B, n = 30, 16
     total = B * n  # 480 candidates
     P_TOK, NEW = 350, 1200

@@ -38,9 +38,8 @@ PAGE = 8
 
 class _FixedObsHook:
     """Minimal deterministic engine turn hook: every candidate re-enters
-    once with the same observation block (cf. bench.py's _BenchTurnHook —
-    this one exists so the smoke's transcripts are reproducible inputs for
-    the history re-admission round, not to measure scheduling)."""
+    once with the same observation block, so that the smoke's transcripts
+    are reproducible inputs for the history re-admission round."""
 
     def __init__(self, obs):
         self.obs = obs

@@ -26,9 +26,6 @@ stage "dryrun_multichip" timeout 300 python __graft_entry__.py
 stage "cli_smoke" env JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   timeout 600 python train_distributed.py --smoke
-stage "bench_cpu_row" env JAX_PLATFORMS=cpu BENCH_MODEL=tiny BENCH_PROMPTS=4 \
-  BENCH_CANDIDATES=2 BENCH_MAX_PROMPT=32 BENCH_MAX_NEW=32 \
-  timeout 600 python bench.py
 # telemetry acceptance gate: 2-step traced train + worker round → one
 # Chrome-trace JSON that parses and trace_report.py exits 0 on
 stage "telemetry_smoke" env JAX_PLATFORMS=cpu \
@@ -149,17 +146,6 @@ stage "gateway_smoke" env JAX_PLATFORMS=cpu \
 # autoscaler is byte-identical to controllers-off
 stage "fleet_smoke" env JAX_PLATFORMS=cpu \
   timeout 600 python tools/fleet_smoke.py
-# bench-trajectory stage (WARN-ONLY): fold the BENCH_r*.json artifacts into
-# one table and flag >10% per-metric tok/s regressions — machine-readable
-# bench history, but cross-round rows come from different silicon windows,
-# so a flag warns instead of failing the battery
-echo "=== bench_history (warn-only)"
-if timeout 120 python tools/bench_history.py; then
-  echo "PASS bench_history"
-else
-  echo "WARN bench_history (regression flagged or artifacts unreadable; non-gating)"
-fi
-
 if [ "${1:-}" = "--quick" ]; then
   # representative post-tiering mix: budget accounting + config + one
   # engine-parity and one learner-parity anchor from the default tier
@@ -196,7 +182,8 @@ stage "suite_misc" timeout 600 python -m pytest -q \
 stage "suite_io" timeout 600 python -m pytest -q \
   tests/test_from_pretrained.py tests/test_remote_engine.py \
   tests/test_native_tokenizer.py tests/test_native_spm.py \
-  tests/test_config.py tests/test_cli.py tests/test_real_checkpoint.py
+  tests/test_config.py tests/test_cli.py tests/test_real_checkpoint.py \
+  tests/test_devices.py tests/test_docs.py
 # the slow tier (excluded from the default run by pytest.ini addopts):
 # heavyweight fuzz/parity/scale cases. Chunked like the fast stages so one
 # stage timeout can't silently drop the back half of the tier.

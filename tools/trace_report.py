@@ -2,10 +2,7 @@
 """One-command diagnosis of a telemetry trace: per-phase / per-worker time
 breakdown with tok/s and MFU.
 
-Round 5's regressions (a 2.5×-slower scan-chunk silently engaged; the paged
-engine 5–6× behind dense) were only found by cross-reading bench JSONs after
-the fact. This report answers the same questions from one run's trace file
-(written by ``--trace-dir`` — see telemetry.py):
+Reads one run's trace file (written by ``--trace-dir`` — see telemetry.py):
 
     python tools/trace_report.py run_myrun/trace/trace.json
 
@@ -506,7 +503,7 @@ def spec_section(spans: dict[tuple[int, str], list[dict]]) -> list[str]:
     """Speculative-decoding diagnosis from one trace: every spec-mode
     refill round stamps its decode span with ``spec_drafter`` /
     ``spec_accept_rate`` / ``tokens_per_verify_step``, so the report can
-    show the realized speculation without a bench run — the mean accepted
+    show the realized speculation — the mean accepted
     draft prefix per verify step, tokens emitted per step (the speculation
     multiplier on step rate), and the drafter mix across rounds (a run
     that swaps --spec_drafter mid-experiment shows both). Empty when no
@@ -542,86 +539,6 @@ def spec_section(spans: dict[tuple[int, str], list[dict]]) -> list[str]:
         "  drafter mix:        "
         + ", ".join(f"{k} ×{v}" for k, v in sorted(mix.items()))
     )
-    lines.append("")
-    return lines
-
-
-def roofline_section(spans: dict[tuple[int, str], list[dict]],
-                     metadata: dict, decode_tok_s: float | None,
-                     peak_flops: float | None) -> list[str]:
-    """Measured roofline/MFU attribution (ISSUE 8), from the obs plane's
-    signals in the trace metadata: per-phase wall time + HBM high-watermark
-    (``phase_hbm``, sampled from jax.Device.memory_stats at span
-    boundaries) and the XLA ``cost_analysis`` FLOPs/bytes of every
-    explicitly-compiled step program (``costs``) with the arithmetic
-    intensity that says which side of the roofline it sits on. Empty when
-    the run recorded neither (obs unarmed) — old traces are unchanged."""
-    costs = metadata.get("costs") or {}
-    phase_hbm = metadata.get("phase_hbm") or {}
-    if not costs and not phase_hbm:
-        return []
-    lines = ["roofline (measured):"]
-    phase_us: dict[str, int] = {}
-    for (_pid, name), evs in spans.items():
-        if name.startswith("driver/"):
-            phase_us[name[7:]] = phase_us.get(name[7:], 0) + sum(
-                e.get("dur", 0) for e in evs
-            )
-    if phase_us:
-        total_us = max(sum(phase_us.values()), 1)
-        lines.append(
-            f"  {'phase':<14} {'time s':>8} {'share':>7} {'hbm peak':>10}"
-        )
-        for phase, us in sorted(phase_us.items(), key=lambda kv: -kv[1]):
-            hbm = phase_hbm.get(phase, {}).get("peak_max")
-            hbm_s = f"{hbm / 2**30:.2f} GiB" if hbm else "n/a"
-            lines.append(
-                f"  {phase:<14} {us / 1e6:>8.3f} "
-                f"{100 * us / total_us:>6.1f}% {hbm_s:>10}"
-            )
-    fpt = metadata.get("decode_flops_per_token")
-    if decode_tok_s and fpt and peak_flops:
-        chips = metadata.get("chips", 1) or 1
-        achieved = decode_tok_s / chips * fpt
-        lines.append(
-            f"  decode: {decode_tok_s:,.0f} tok/s × {fpt / 1e9:.3f} GF/tok "
-            f"= {achieved / 1e12:.4f} TF/s/chip achieved "
-            f"({100 * achieved / peak_flops:.2f}% of peak)"
-        )
-    if costs:
-        # measured bytes/token (ISSUE 15): decode emits one token per
-        # alive slot per step, so a decode-step program's cost_analysis
-        # bytes x dispatched steps / generated tokens is the HBM traffic
-        # each token actually paid — the quantized-serving scoreboard.
-        # Steps and tokens come from the engine/decode span args.
-        # dense/wave engines span "engine/decode"; the refill scheduler
-        # (continuous batching + speculative — the serving path ISSUE 15
-        # targets) spans "engine/refill_decode"
-        dec_tokens = dec_steps = 0
-        for (_pid, name), evs in spans.items():
-            if name in ("engine/decode", "engine/refill_decode"):
-                for e in evs:
-                    a = e.get("args", {}) or {}
-                    dec_tokens += int(a.get("tokens") or 0)
-                    dec_steps += int(a.get("steps") or 0)
-        lines.append("  compiled step programs (XLA cost_analysis):")
-        for what, c in sorted(costs.items()):
-            flops = c.get("flops", 0.0)
-            byts = c.get("bytes_accessed", 0.0)
-            ai = f"{flops / byts:.2f} FLOP/B" if byts else "n/a"
-            bpt = ""
-            if (
-                what.startswith("decode_step/") and byts
-                and dec_tokens and dec_steps
-            ):
-                bpt = (
-                    f", {byts * dec_steps / dec_tokens / 1e6:.3f} "
-                    "MB/token measured"
-                )
-            lines.append(
-                f"    {what}: {flops / 1e9:.3f} GFLOP, "
-                f"{byts / 2**30:.3f} GiB accessed, intensity {ai}{bpt}"
-            )
     lines.append("")
     return lines
 
@@ -694,10 +611,6 @@ def build_report(events: list[dict], metadata: dict,
     # same blob), so counting them would double the tokens and mix
     # prefill-inclusive durations into the decode rate
     decode = tok_s(("engine/decode", "engine/refill_decode"))
-    lines.extend(roofline_section(
-        spans, metadata, decode,
-        peak_flops or metadata.get("peak_flops"),
-    ))
     lines.append("throughput:")
     lines.append(f"  prefill tok/s: "
                  f"{f'{prefill:,.0f}' if prefill else 'n/a (no token counts)'}")

@@ -8,8 +8,8 @@ Two scales:
   engine sampling → reward → GRPO shaping → 8-bit-Adam LoRA updates →
   weight sync. The curve climbing is the same "de-facto integration test"
   the reference's screenshots document, at toy scale.
-* ``--model <local checkpoint dir>`` (TPU): the real thing — BASELINE
-  config-1 shape via ``Trainer.from_pretrained`` with the native tokenizer
+* ``--model <local checkpoint dir>`` (TPU): the real thing — reference
+  recipe 1's shape via ``Trainer.from_pretrained`` with the native tokenizer
   and MATH-style data; logs the exact reference metric names.
 
 Artifacts: ``media/reward_curve_<tag>.jsonl`` (one record per train step,
@@ -109,7 +109,7 @@ def run_synth(episodes: int, learner: str, model_name: str = "qwen2.5-0.5b"):
     """Real-scale learning without downloadable weights: a RANDOM-INIT
     QWEN2_0_5B policy + the dense digit-fraction reward. The policy can't
     solve MATH from random init, but it CAN learn to emit digits — the same
-    full-loop learning signal as the tiny run at BASELINE config-1 model
+    full-loop learning signal as the tiny run at reference recipe 1's model
     scale, runnable the moment a chip answers (no egress required)."""
     import jax
     import jax.numpy as jnp
@@ -298,7 +298,7 @@ def main() -> int:
 
     steps = [m.get("_step", i + 1) for i, m in enumerate(train_recs)]
     rewards = [m["mean_accuracy_reward"] for m in train_recs]
-    # eval series (VERDICT r4 item 6): the reference's pass@1/BoN overlay
+    # eval series: the reference's pass@1/BoN overlay
     # (distributed_trainer.py:412–415). Key names embed eval_n, so match
     # by prefix.
     def _eval_series(prefix: str):

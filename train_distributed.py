@@ -629,7 +629,7 @@ def run_smoke(
     reward_fn=reward_function,
     devices: list | None = None,
 ) -> dict:
-    """BASELINE config-1-shaped integration smoke without downloads: a
+    """Reference-recipe-1-shaped integration smoke without downloads: a
     random-init model (``model_cfg``, default TINY) through the REAL engine
     + learner + ``Trainer.train()`` on whatever devices exist (CPU mesh, one
     TPU chip, or a role-split of several). ``--smoke`` on the CPU and
