@@ -168,9 +168,16 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     out: dict = {
         "paged_bf16": _paged_case(key, quantized=False, **geom),
         "paged_int8_kv": _paged_case(key, quantized=True, **geom),
-        # "auto" is the same kernel at head_dim 128 (every 7B/8B config)
+        # "auto" is the same launch at head_dim 128 (every 7B/8B config); it
+        # sizes its block of pages from the shapes
         "paged_bf16_hd128": _paged_case(
             key, quantized=False, **{**geom, "heads": 32, "kv_heads": 8,
+                                     "head_dim": 128},
+        ),
+        # the benchmark's rollout cell: Qwen2.5-7B's 28 / 4 heads of 128,
+        # page 128, five pages a row, ragged lengths
+        "paged_bf16_7b": _paged_case(
+            key, quantized=False, **{**geom, "heads": 28, "kv_heads": 4,
                                      "head_dim": 128},
         ),
     }

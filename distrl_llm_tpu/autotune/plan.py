@@ -102,9 +102,10 @@ class ExecutionPlan:
     # max_prompt_tokens bucket (engine-compiled per bucket used)
     prompt_buckets: tuple[int, ...] = ()
     # paged-attention kernel variant (paged/speculative paths); None derives
-    # exactly as the engine always has (paged_impl="auto": the probe-gated
-    # chain). "one_page"/"folded"/"blocked" pin the native kernel variants —
-    # the grid-step ladder of the r5 overhead analysis (ops/paged_native.py)
+    # exactly as the engine always has (paged_impl="auto"). "one_page" /
+    # "folded" / "blocked" pin paged_impl "native" / "native_folded" /
+    # "native_blocked" (ops/paged_native.py); "one_page" keeps its spelling
+    # though "native" moves a row's pages a grid step since PR 32
     paged_kernel: str | None = None
     # blocked-kernel page collapse (pages folded per grid step); 0 = the
     # kernel default (ops.paged.DEFAULT_PAGES_PER_BLOCK). Only consumed by

@@ -8,8 +8,8 @@ distributed_actor.py:148–150), built TPU-native:
 
 * prompts are packed (left padding removed) during a jitted prefill, so a
   short prompt costs its own length, not ``max_prompt_tokens``;
-* decode attention is jaxlib's Pallas ``paged_attention`` kernel on TPU (jnp
-  reference elsewhere — ops/paged.py);
+* decode attention is our Pallas kernel on a TPU (ops/paged_native.py; the
+  jnp reference elsewhere — ops/paged.py);
 * candidates SHARE their prompt's full prompt pages (vLLM prefix sharing):
   the page table points each candidate's leading columns at a shared pool
   written once by prefill; only the partial last prompt page — extended in
@@ -161,8 +161,9 @@ def _count_sparse_blocks(mixer) -> None:
 
 def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
                            *, per_call: int, calls_per_step: int = 1):
-    """Paged grid-overhead telemetry (decode's cost floor is Pallas grid
-    steps × about 1 µs a grid step; PERF.md §7). ``per_call`` is the
+    """Paged grid telemetry: which launch geometry ran, and what a grid
+    step of it cost (0.37-1.5 µs on a v5e, by what the step moves:
+    ``ops.paged.paged_grid_steps``). ``per_call`` is the
     dispatch chain's trace-time analytic count for the CALLER's own
     geometry and LIVE row count (engines derive it from their exact
     dispatch-choice record — see ``_grid_steps_per_call`` — never from a
@@ -2105,10 +2106,14 @@ class PagedGenerationEngine(LoraMailbox):
         choice = dispatch_choices.get(self._dispatch_key())
         if not choice:
             return 0
+        quantized = self.kv_quant == "int8"
         return paged_grid_steps(
             choice, batch=rows, num_kv_heads=self.cfg.num_kv_heads,
             pps=self.prompt_pages + self.private_pages,
             pages_per_block=self.pages_per_block,
+            head_dim=self.cfg.head_dim, page_size=self.page_size,
+            kv_itemsize=1 if quantized else jnp.dtype(self.cache_dtype).itemsize,
+            quantized=quantized,
         )
 
     def _verify_dispatch_choice(self, draft_len: int | None = None):

@@ -299,34 +299,54 @@ def paged_attention_reference(
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
-#: kernel default for the blocked launch's page-axis collapse — callers
-#: passing 0 get this (kept here so plan resolution and the analytic
-#: grid-step model agree on what "default" means)
+#: kernel default for the blocked and verify launches' page-axis collapse —
+#: callers passing 0 get this (kept here so plan resolution and the analytic
+#: grid-step model agree on what "default" means). "native" chooses its own
+#: from the shapes: ``paged_native.native_pages_per_step``.
 DEFAULT_PAGES_PER_BLOCK = 8
 
 
 def paged_grid_steps(
     impl: str, *, batch: int, num_kv_heads: int, pps: int,
-    pages_per_block: int = 0,
+    pages_per_block: int = 0, head_dim: int = 0, page_size: int = 0,
+    kv_itemsize: int = 2, quantized: bool = False,
 ) -> int:
     """Analytic Pallas grid-step count of ONE paged-attention call (one
-    layer, one decode step) for ``impl``. At small head dims and many short
-    pages decode is bound by grid steps × Mosaic's per-grid-step floor
-    (about 1 µs; PERF.md §7, a pre-chip record), not by bandwidth, so the
-    engines record this count (``ops/paged_grid_steps`` counter) to make
-    the regime visible.
+    layer, one decode step) for ``impl``. The engines record it
+    (``ops/paged_grid_steps`` counter, ``ops/paged_us_per_grid_step`` gauge)
+    so that a trace says which launch geometry ran. What a grid step costs
+    depends on what it moves (a v5e at 4 kv heads of 128, page 128; PERF.md
+    §6, PR 32): 0.37 us with one page of one head inside, 0.55 us with one
+    page of all four heads, 1.5 us with a row's three to five pages and one
+    softmax — the fewer and larger steps are the faster call, 479 / 176 /
+    98 us.
 
-    Counts per impl: "native" runs a (B, K, pps) grid; "native_folded"
-    folds kv heads into the block — (B, pps); "native_blocked" additionally
-    collapses the page axis — (B, ceil(pps / pages_per_block));
-    "native_verify" is the FUSED draft-block verify: the whole (d+1)-query
-    speculative verify step in ONE blocked sweep — same (B,
-    ceil(pps / pages_per_block)) count as "native_blocked", where the
+    Counts per impl: "native" (what "auto" is on a TPU) moves all kv heads
+    and ``native_pages_per_step`` pages of a row a step — (B, ceil(pps /
+    ppb)), with ppb from ``head_dim``, ``page_size`` and the pages' dtype
+    (``kv_itemsize``, ``quantized``), which this impl therefore needs;
+    "native_folded" folds kv heads into the block — (B, pps);
+    "native_blocked" additionally collapses the page axis — (B, ceil(pps /
+    pages_per_block)); "native_verify" is the FUSED draft-block verify: the
+    whole (d+1)-query speculative verify step in ONE blocked sweep — same
+    (B, ceil(pps / pages_per_block)) count as "native_blocked", where the
     unrolled verify paid that count (d+1) TIMES per step; jaxlib's kernel
     ("kernel") walks pages with manual DMA inside a (1, B, K) grid; the jnp
     reference has no Pallas grid (0)."""
     if impl == "native":
-        return batch * num_kv_heads * pps
+        from distrl_llm_tpu.ops.paged_native import native_pages_per_step
+
+        if not head_dim or not page_size:
+            raise ValueError(
+                '"native" chooses its pages a step from the shapes: '
+                "head_dim and page_size are needed"
+            )
+        ppb = native_pages_per_step(
+            num_kv_heads=num_kv_heads, head_dim=head_dim,
+            page_size=page_size, pps=pps, kv_itemsize=kv_itemsize,
+            quantized=quantized,
+        )
+        return batch * -(-pps // ppb)
     if impl == "native_folded":
         return batch * pps
     if impl in ("native_blocked", "native_verify"):
@@ -359,7 +379,7 @@ def dispatch_choice_key(
 
 def divisor_blocks(pages_per_compute_block: int, pps: int) -> int:
     """Largest divisor of ``pps`` that fits ``pages_per_compute_block`` —
-    the per-call block count the one-page kernels launch with. Shared so
+    the ``pages_per_compute_block`` jaxlib's launch ("kernel") takes. Shared so
     consumers derive it from the geometry instead of indexing the dispatch
     key tuple positionally."""
     return max(
@@ -382,19 +402,27 @@ dispatch_choices: dict = {}
 #: every spelling ``paged_attention_op(impl=...)`` takes
 PAGED_IMPLS = ("auto", "reference", "kernel", "native", "native_folded",
                "native_blocked")
-#: what "auto" is on a TPU backend, at every geometry: the one-page native
-#: kernel — the only family that lowers for head_dim 64 AND 128
-#: (tests/test_tpu_compile.py) and the one chip_smoke.py holds to the
-#: reference on the chip. The folded/blocked variants and jaxlib's kernel
-#: run where a caller or a stored plan names them.
+#: what "auto" is on a TPU backend, at every geometry:
+#: ``paged_native.paged_attention_native`` — a row's KV for all kv heads and
+#: as many of its pages as the VMEM budget holds a grid step, no page past
+#: the row's length fetched, one softmax a step (98 us a call where the
+#: one-page kernel it replaced took 479, a v5e at the 7B geometry; PERF.md
+#: §6, PR 32). Of the pipelined family, the only one that lowers for
+#: head_dim 64 AND 128 (tests/test_tpu_compile.py); chip_smoke.py holds it to
+#: the reference on the chip at both. Its launch function's NAME is what the
+#: benchmark's kernel metrics find it by, so "auto" stays this spelling. The
+#: folded/blocked variants and jaxlib's kernel run where a caller or a stored
+#: plan names them.
 AUTO_TPU_IMPL = "native"
 
 
 def resolve_paged_impl(impl: str) -> str:
     """The concrete impl a request runs as. "auto" is ``AUTO_TPU_IMPL`` on a
-    TPU backend and the jnp reference on any other; every other spelling is
-    itself. Nothing here probes and nothing downstream catches: on the TPU a
-    kernel that fails to lower or to run fails the step that called it."""
+    TPU backend (``paged_attention_native``, whose block of pages a grid step
+    adapts to the shapes: one decision for every geometry, no probe) and the
+    jnp reference on any other; every other spelling is itself. Nothing
+    downstream catches: on the TPU a kernel that fails to lower or to run
+    fails the step that called it."""
     if impl not in PAGED_IMPLS:
         raise ValueError(f"impl must be one of {PAGED_IMPLS}, got {impl!r}")
     if impl == "auto":
@@ -411,10 +439,10 @@ def _native_call(q, k_pages, v_pages, lengths, page_indices,
                  pages_per_block: int = 0, interpret: bool = False):
     """Adapter: the dispatch's launch signature → our native kernels
     (ops/paged_native.py), which take int8 weights and compact scales as
-    separate arrays. ``folded`` selects the kv-heads-in-block variant with
-    a (B, pps) grid (1/K of the grid steps; ``paged_grid_steps``);
-    ``blocked`` the multi-page grid-collapsed variant with a
-    (B, ceil(pps / pages_per_block)) grid on top of the folding."""
+    separate arrays. Neither flag: ``paged_attention_native``, which sizes
+    its own blocks. ``folded`` selects the older kv-heads-in-block variant
+    with a (B, pps) grid; ``blocked`` the older multi-page variant with a
+    (B, ceil(pps / pages_per_block)) grid (``paged_grid_steps``)."""
     from distrl_llm_tpu.ops.paged_native import (
         paged_attention_native,
         paged_attention_native_blocked,
@@ -477,11 +505,12 @@ def paged_attention_op(
 
     ``impl``: "auto" (``resolve_paged_impl``: the native kernel on a TPU
     backend, the reference elsewhere), "native" (our pipeline-gather
-    kernel, ops/paged_native.py), "native_folded" / "native_blocked" (its
-    kv-folded and grid-collapsed variants — ``pages_per_block`` sizes the
-    blocked kernel's page collapse; 0 = DEFAULT_PAGES_PER_BLOCK), "kernel"
-    (jaxlib's own launch) or "reference". What ran is recorded in
-    ``dispatch_choices``."""
+    kernel, ops/paged_native.py: all kv heads and a length-bounded run of a
+    row's pages a grid step, the block sized by the launch from the shapes),
+    "native_folded" / "native_blocked" (the older one-page-a-softmax
+    variants — ``pages_per_block`` sizes the blocked kernel's page collapse
+    and nothing else; 0 = DEFAULT_PAGES_PER_BLOCK), "kernel" (jaxlib's own
+    launch) or "reference". What ran is recorded in ``dispatch_choices``."""
     resolved = resolve_paged_impl(impl)
     pps = page_indices.shape[1]
     quantized = is_quantized_pages(k_pages)

@@ -1,6 +1,6 @@
-"""Native paged decode attention — our own Pallas TPU kernel.
+"""Native paged decode attention — our own Pallas TPU kernels.
 
-Why this exists (round 3, first silicon): both jaxlib paged-attention
+Why this file exists (round 3, first silicon): both jaxlib paged-attention
 kernels are unusable for head_dim % 128 != 0 models (e.g. Qwen2.5-0.5B,
 hd=64, 14q/2kv). Their manual-DMA design slices the KV page array per
 kv-head (``pages.at[head_index]`` — MultiPageAsyncCopyDescriptor,
@@ -9,19 +9,41 @@ whose minor dimension is not lane-aligned: "Slice shape along dimension 3
 must be aligned to tiling (128), but is 64". The newer ragged kernel
 hard-asserts 128-lane accumulator shapes at trace time instead.
 
-This kernel takes the other road: **no manual DMA at all**. The grid is
-(batch, kv_head, page) and the page gather happens in the k/v BlockSpec
-``index_map``, which reads the scalar-prefetched page table —
-``(b, kv, j) -> (kv, table[b, j], 0, 0)``. The pipeline emitter then moves
-whole ``[1, page_size, head_dim]`` blocks, never slicing inside the minor
-dims — the pattern the flash/splash launches use at d=64.
+These kernels take the other road: **no manual DMA at all**. The page gather
+happens in the k/v BlockSpec ``index_map``, which reads the scalar-prefetched
+page table, and the pipeline emitter moves whole ``[K, 1, page_size,
+head_dim]`` blocks, never slicing inside the minor dims — the pattern the
+flash/splash launches use at d=64.
 
-Per (b, kv) series the kernel runs classic online softmax over the pages:
-m/l/acc VMEM scratch carried across the innermost grid dimension, page
-positions masked against the sequence length, output emitted at the last
-page. Compute is skipped (``pl.when``) for pages past the length; their
-DMAs still run — the admission/capacity win of paging is unchanged, and
-bounding the DMA walk per row is a follow-up (bucketed pps compiles).
+**What ``paged_impl="auto"`` runs on a TPU is ``paged_attention_native``**
+(PR 32; timed on a v5e at 28 / 4 heads of 128, page 128, 64 rows of 129-640
+tokens, 14 calls a step; PERF.md §6 has the table of every launch). One grid
+step is one row's KV for ALL kv heads and up to ``native_pages_per_step``
+pages — grid (B, ceil(pps / ppb)), one to two steps a row at that geometry,
+0.25-1.3 MB a step:
+
+* **The page walk is bounded by the row's length.** ``live_page_walk``
+  rewrites the table so that a page slot past the row's last live page names
+  the block that slot fetched at the previous grid step; the pipeline sees an
+  unchanged block index and issues no copy. A row of length 0 fetches nothing
+  and emits zeros.
+* **One softmax a grid step, not one a page.** The body has one branch per
+  count of live pages in the block (``pl.when(live == n)``, n = 1..ppb);
+  branch n computes the n score tiles, ONE running-max / running-sum update
+  over all of them, and n p·V products. A chain of per-page online-softmax
+  updates, which the older launches below still are, costs 1.7x the
+  arithmetic's time at the same transfers (121.6 against 72.3 us a call with
+  the DMAs taken out), and pages past the length are never computed on, so a
+  poisoned page there cannot reach the output.
+
+What the timing said about the older launches, which stay for a caller or a
+stored plan that names them (ROADMAP D2 deletes the losers): the one-page
+kernel this one replaced ran a (B, K, pps) grid at 0.37 us a grid step with
+the page's arithmetic inside, 479 us a call; ``native_folded`` (grid (B,
+pps)) 176 us; ``native_blocked`` (grid (B, ceil(pps / ppb))) 131 us; jaxlib's
+``kernel`` 236-435 us; this launch 98 us, against 86 us for its DMAs alone.
+Float32 operands cost the MXU nothing here: Mosaic's default-precision
+float32 dot is one bf16 pass, bit for bit what a bf16 operand gives.
 
 The int8 path consumes the engine's COMPACT per-token scales ([K, P, ps,
 1] f32, ops/paged.py::quantize_pages) directly: dequantization is one broadcast
@@ -48,77 +70,147 @@ from jax.experimental.pallas.ops.tpu.paged_attention.quantization_utils import (
 
 NEG_INF = -1e30
 
+#: VMEM one grid step's K and V blocks may take, double-buffered by the
+#: pipeline (2.6 MB at 4 kv heads of 128, page 128, 5 pages a row)
+KV_VMEM_BUDGET_BYTES = 4 * 2**20
+#: most pages one grid step moves: the body holds one unrolled branch per
+#: live-page count, and past 4 pages of 128 a step nothing was gained
+#: (8k contexts: 312.6 / 311.8 / 309.7 us a call at 4 / 8 / 16; PERF.md §6)
+MAX_PAGES_PER_STEP = 8
+_LANES = 128  # a VMEM tile's minor dimension; narrower blocks are padded to it
 
-def _paged_kernel(
-    lengths_ref,  # SMEM [B] i32 (scalar prefetch)
-    tables_ref,  # SMEM [B, pps] i32 (scalar prefetch)
-    q_ref,  # VMEM [G, hd] — this (b, kv)'s query group
-    k_ref,  # VMEM [1, ps, hd] — page j of kv head kv (gathered by index_map)
-    v_ref,  # VMEM [1, ps, hd]
-    k_s_ref,  # VMEM [1, ps, 1] f32 compact scales, or None (unquantized)
-    v_s_ref,
-    o_ref,  # VMEM [G, hd]
-    m_scr,  # VMEM [G, 1] f32 running max
-    l_scr,  # VMEM [G, 1] f32 running denominator
-    acc_scr,  # VMEM [G, hd] f32 running numerator
-    *,
-    page_size: int,
-    pps: int,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+def native_pages_per_step(
+    *, num_kv_heads: int, head_dim: int, page_size: int, pps: int,
+    kv_itemsize: int = 2, quantized: bool = False,
+) -> int:
+    """Pages of one row (all kv heads) that one grid step of
+    ``paged_attention_native`` moves: as many as ``KV_VMEM_BUDGET_BYTES``
+    holds of K and V, double-buffered, at most ``MAX_PAGES_PER_STEP`` and at
+    most the row's ``pps``. Chosen from what the launch observes (the shapes
+    and the pages' dtype) and from nothing else."""
+    page = num_kv_heads * page_size * max(head_dim, _LANES) * kv_itemsize
+    if quantized:
+        # the compact [ps, 1] float32 scales take a whole lane tile a token
+        page += num_kv_heads * page_size * _LANES * 4
+    return max(1, min(pps, MAX_PAGES_PER_STEP,
+                      KV_VMEM_BUDGET_BYTES // (2 * 2 * page)))
 
-    length = lengths_ref[b]
 
-    @pl.when(j * page_size < length)
-    def _page():
-        q = q_ref[...].astype(jnp.float32)  # [G, hd] (pre-scaled)
-        k = k_ref[0].astype(jnp.float32)  # [ps, hd]
-        v = v_ref[0].astype(jnp.float32)
-        if k_s_ref is not None:
-            # compact per-token absmax scales; dequant = w * scale /
-            # MAX_INT8 (quantization_utils.from_int8 contract — 127.5,
-            # not 127: /127 would bias every K/V value by +0.39%)
-            k = k * (k_s_ref[0] * (1.0 / MAX_INT8))  # [ps, 1] broadcast
-            v = v * (v_s_ref[0] * (1.0 / MAX_INT8))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, ps]
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
+def live_page_walk(tables: jax.Array, lengths: jax.Array, *, page_size: int,
+                   ppb: int) -> jax.Array:
+    """The page table ``paged_attention_native``'s index maps read: [B,
+    nblk·ppb] page ids in grid order (row, block), where a slot past its
+    row's length repeats what the SAME in-block slot held at the grid step
+    before. Each in-block slot is its own pipelined operand, and the pipeline
+    copies a block only when its index changes between consecutive grid
+    steps, so a repeated id is a page that is never fetched: the walk is
+    bounded by the length (85.5 against 117.7 us a call for the DMAs alone,
+    table entries past the length naming arbitrary pages; PERF.md §6)."""
+    batch, width = tables.shape
+    steps = batch * width // ppb
+    slot = jnp.arange(width, dtype=jnp.int32)[None, :]
+    live = (slot * page_size < lengths[:, None]).reshape(steps, ppb)
+    step = jnp.arange(steps, dtype=jnp.int32)[:, None]
+    last_live = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    # before any live step there is nothing to repeat: step 0's own entry
+    return jnp.take_along_axis(
+        tables.reshape(steps, ppb), jnp.maximum(last_live, 0), axis=0
+    ).reshape(batch, width)
+
+
+def _make_native_kernel(*, page_size: int, ppb: int, nblk: int,
+                        quantized: bool):
+    """Kernel body for ``paged_attention_native`` (module header): grid (B,
+    nblk), ``ppb`` pages of all kv heads a step, each page its own operand."""
+    dims_qk = (((2,), (2,)), ((0,), (0,)))  # [K,G,hd] x [K,ps,hd] -> [K,G,ps]
+    dims_pv = (((2,), (1,)), ((0,), (0,)))  # [K,G,ps] x [K,ps,hd] -> [K,G,hd]
+
+    def kernel(lengths_ref, tables_ref, q_ref, *rest):
+        k_refs = rest[0:ppb]
+        v_refs = rest[ppb:2 * ppb]
+        ks_refs = rest[2 * ppb:3 * ppb] if quantized else None
+        vs_refs = rest[3 * ppb:4 * ppb] if quantized else None
+        o_ref, m_scr, l_scr, acc_scr = rest[-4:]
+        b = pl.program_id(0)
+        jb = pl.program_id(1)
+
+        @pl.when(jb == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        length = lengths_ref[b]
+        first = jb * (ppb * page_size)  # the block's first position
+
+        def load(refs, scale_refs, i):
+            x = refs[i][:, 0].astype(jnp.float32)  # [K, ps, hd]
+            if quantized:
+                # compact per-token absmax scales; dequant = w * scale /
+                # MAX_INT8 (quantization_utils.from_int8 contract — 127.5,
+                # not 127: /127 would bias every K/V value by +0.39%)
+                x = x * (scale_refs[i][:, 0] * (1.0 / MAX_INT8))
+            return x
+
+        def attend(n):
+            """The block's first ``n`` pages, all live, under ONE softmax
+            update; only the last of them can hold positions past the
+            length."""
+            q = q_ref[...].astype(jnp.float32)  # [K, G, hd] (pre-scaled)
+            scores = [
+                jax.lax.dot_general(
+                    q, load(k_refs, ks_refs, i),
+                    dims_qk, preferred_element_type=jnp.float32,
+                )
+                for i in range(n)
+            ]  # n x [K, G, ps]
+            pos = first + (n - 1) * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, page_size), 2
+            )
+            scores[-1] = jnp.where(pos < length, scores[-1], NEG_INF)
+            m_prev = m_scr[...]  # [K, G, 1]
+            m_new = functools.reduce(
+                jnp.maximum,
+                [jnp.max(s, axis=2, keepdims=True) for s in scores], m_prev,
+            )
+            alpha = jnp.exp(m_prev - m_new)
+            probs = [jnp.exp(s - m_new) for s in scores]
+            l_scr[...] = alpha * l_scr[...] + sum(
+                jnp.sum(p, axis=2, keepdims=True) for p in probs
+            )
+            acc_scr[...] = acc_scr[...] * alpha + sum(
+                jax.lax.dot_general(
+                    p, load(v_refs, vs_refs, i),
+                    dims_pv, preferred_element_type=jnp.float32,
+                )
+                for i, p in enumerate(probs)
+            )
+            m_scr[...] = m_new
+
+        # live pages of THIS block: 0 for a block past the length (and for a
+        # row of length 0), else 1..ppb — one static branch each
+        live = jnp.clip(
+            (length - first + page_size - 1) // page_size, 0, ppb
         )
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]  # [G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [G, ps]
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = m_new
+        for n in range(1, ppb + 1):
+            pl.when(live == n)(functools.partial(attend, n))
 
-    @pl.when(j == pps - 1)
-    def _emit():
-        # rows with length 0 (empty decode slots) never accumulate: emit 0
-        # instead of 0/0 — their logits are discarded by the done mask, but
-        # NaNs must not exist to propagate
-        o_ref[...] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        ).astype(o_ref.dtype)
+        @pl.when(jb == nblk - 1)
+        def _emit():
+            # rows with length 0 (empty decode slots) never accumulate: emit 0
+            # instead of 0/0 — their logits are discarded by the done mask, but
+            # NaNs must not exist to propagate
+            o_ref[...] = (
+                acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            ).astype(o_ref.dtype)
+
+    return kernel
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "interpret"),
+    static_argnames=("page_size", "pages_per_block", "interpret"),
 )
 def paged_attention_native(
     q: jax.Array,  # [B, H, hd] — pre-scaled by hd**-0.5 (op contract)
@@ -130,8 +222,15 @@ def paged_attention_native(
     v_scales: jax.Array | None = None,
     *,
     page_size: int | None = None,
+    pages_per_block: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
+    """The launch ``paged_impl="auto"`` runs on a TPU (module header). Its
+    name is what the benchmark's kernel metrics select the trace events by
+    (``%paged_attention_native ``; tests/test_tpu_compile.py holds it).
+    ``pages_per_block`` 0 is the launch's own choice from the shapes
+    (``native_pages_per_step``); the tests name one to reach rows of several
+    blocks at small sizes."""
     batch, num_q_heads, head_dim = q.shape
     num_kv_heads, total_pages, ps, head_dim_k = k_pages.shape
     if page_size is None:
@@ -142,68 +241,76 @@ def paged_attention_native(
         raise ValueError(
             f"H={num_q_heads} not divisible by K={num_kv_heads}"
         )
+    if pages_per_block < 0:
+        raise ValueError(
+            f"pages_per_block must be >= 0, got {pages_per_block}"
+        )
     groups = num_q_heads // num_kv_heads
     _, pps = page_indices.shape
     quantized = k_scales is not None
+    ppb = min(pps, pages_per_block) or native_pages_per_step(
+        num_kv_heads=num_kv_heads, head_dim=head_dim, page_size=page_size,
+        pps=pps, kv_itemsize=k_pages.dtype.itemsize, quantized=quantized,
+    )
+    nblk = -(-pps // ppb)
 
-    # index_map gathers pages from the table for EVERY j, including slots
-    # past a row's allocation — clamp so garbage entries stay addressable
-    # (their compute is masked by the length check)
+    lengths = lengths.astype(jnp.int32)
+    # live entries are clamped so that a stale id stays addressable; entries
+    # past the length (and the ragged final block's padding) are replaced by
+    # the walk and never fetched
     tables = jnp.clip(page_indices.astype(jnp.int32), 0, total_pages - 1)
+    tables = jnp.pad(tables, ((0, 0), (0, nblk * ppb - pps)))
+    tables = live_page_walk(tables, lengths, page_size=page_size, ppb=ppb)
     q4 = q.reshape(batch, num_kv_heads, groups, head_dim)
 
     # index_maps receive the grid indices plus EVERY scalar-prefetch ref
     # (lengths, tables) appended — the page gather reads the table ref
     q_spec = pl.BlockSpec(
-        (None, None, groups, head_dim),
-        lambda b, kv, j, lens, tabs: (b, kv, 0, 0),
+        (None, num_kv_heads, groups, head_dim),
+        lambda b, j, lens, tabs: (b, 0, 0, 0),
     )
-    kv_spec = pl.BlockSpec(
-        (None, 1, page_size, head_dim),
-        lambda b, kv, j, lens, tabs: (kv, tabs[b, j], 0, 0),
-    )
-    scale_spec = pl.BlockSpec(
-        (None, 1, page_size, 1),
-        lambda b, kv, j, lens, tabs: (kv, tabs[b, j], 0, 0),
-    )
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [q4, k_pages, v_pages]
-    if quantized:
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
-        body = functools.partial(_paged_kernel, page_size=page_size, pps=pps)
-    else:
 
-        def body(lens, tabs, qr, kr, vr, o, m, l, a):  # noqa: E741
-            _paged_kernel(
-                lens, tabs, qr, kr, vr, None, None, o, m, l, a,
-                page_size=page_size, pps=pps,
-            )
+    def page_spec(i, minor):
+        return pl.BlockSpec(
+            (num_kv_heads, 1, page_size, minor),
+            lambda b, j, lens, tabs, i=i: (0, tabs[b, j * ppb + i], 0, 0),
+        )
+
+    # the SAME pool array rides as ppb inputs, one per in-block page — each
+    # gets its own index_map gather, so the pipeline emitter still only
+    # ever moves whole [K, 1, ps, hd] blocks (never slicing the minor dims)
+    in_specs = [q_spec] + [page_spec(i, head_dim) for i in range(ppb)] * 2
+    operands = [q4] + [k_pages] * ppb + [v_pages] * ppb
+    if quantized:
+        in_specs += [page_spec(i, 1) for i in range(ppb)] * 2
+        operands += [k_scales] * ppb + [v_scales] * ppb
 
     out = pl.pallas_call(
-        body,
+        _make_native_kernel(
+            page_size=page_size, ppb=ppb, nblk=nblk, quantized=quantized
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # lengths, tables ride SMEM
-            grid=(batch, num_kv_heads, pps),
+            grid=(batch, nblk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (None, None, groups, head_dim),
-                lambda b, kv, j, lens, tabs: (b, kv, 0, 0),
+                (None, num_kv_heads, groups, head_dim),
+                lambda b, j, lens, tabs: (b, 0, 0, 0),
             ),
             scratch_shapes=[
-                pltpu.VMEM((groups, 1), jnp.float32),
-                pltpu.VMEM((groups, 1), jnp.float32),
-                pltpu.VMEM((groups, head_dim), jnp.float32),
+                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
+                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
+                pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
             ],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(
             (batch, num_kv_heads, groups, head_dim), q.dtype
         ),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), tables, *operands)
+    )(lengths, tables, *operands)
     return out.reshape(batch, num_q_heads, head_dim)
 
 
@@ -223,13 +330,13 @@ def _paged_kernel_folded(
     page_size: int,
     pps: int,
 ):
-    """kv-heads-folded variant of ``_paged_kernel``: the kv-head axis rides
-    INSIDE the block instead of the grid, dividing the grid-step count by
-    K (at (B × K × pps) granularity Mosaic's per-grid-step floor of about
-    1 µs dominates a 0.5B-width step; PERF.md §7) and multiplying each DMA
-    by K. Compute is the same online softmax, batched over K via
-    dot_general batch dims — no in-kernel head slicing, so the hd%128
-    Mosaic constraint this file exists for is still never violated."""
+    """One page of ALL kv heads a grid step, grid (B, pps): the kv-head axis
+    rides INSIDE the block instead of the grid, and one online-softmax update
+    runs per page. A grid step of it costs 0.55 µs on a v5e at 4 kv heads of
+    128, where a one-head step cost 0.37 (176 against 479 µs a call; PERF.md
+    §6, PR 32); ``paged_attention_native`` moves a row's pages in one step.
+    Batched over K via dot_general batch dims — no in-kernel head slicing,
+    so the hd%128 Mosaic constraint this file exists for is never violated."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -369,10 +476,10 @@ def _make_blocked_kernel(*, page_size: int, ppb: int, nblk: int,
     """Kernel body for ``paged_attention_native_blocked``: ``ppb`` pages of
     ALL kv heads folded into one grid step (grid (B, ceil(pps/ppb)) — the
     kv-heads folding of ``_paged_kernel_folded`` composed with a page-axis
-    collapse). The one-page kernel sits at Mosaic's per-grid-step floor of
-    about 1 µs with (B × K × pps) steps per layer (PERF.md §7): it is
-    LAUNCH-bound, not bandwidth-bound, so the lever is fewer grid steps
-    moving the same bytes.
+    collapse): fewer and larger grid steps, 131 µs a call where the folded
+    kernel takes 176 (a v5e, 4 kv heads of 128, five pages a row; PERF.md
+    §6, PR 32). Its chain of per-page softmax updates is what
+    ``paged_attention_native`` replaced by one update a step (98 µs).
 
     The per-page gather stays in BlockSpec ``index_map``s — one per
     in-block page, each reading its own scalar-prefetched table slot
@@ -388,8 +495,9 @@ def _make_blocked_kernel(*, page_size: int, ppb: int, nblk: int,
     in-block page is valid, so ``m`` is finite before any fully-masked page
     folds in — the 0/0 hazard of an all-masked softmax cannot arise), and
     blocks entirely past the length are skipped by ``pl.when``; their DMAs
-    still run against edge-padded table slots, same as the one-page
-    kernels' past-allocation slots."""
+    still run against edge-padded table slots wherever those name another
+    page than the step before (``paged_attention_native`` bounds the walk:
+    ``live_page_walk``)."""
 
     def kernel(lengths_ref, tables_ref, q_ref, *rest):
         k_refs = rest[0:ppb]
@@ -422,7 +530,7 @@ def _make_blocked_kernel(*, page_size: int, ppb: int, nblk: int,
                 k = k_refs[i][:, 0].astype(jnp.float32)  # [K, ps, hd]
                 v = v_refs[i][:, 0].astype(jnp.float32)
                 if quantized:
-                    # compact per-token scales (see _paged_kernel: 127.5,
+                    # compact per-token scales (see _make_native_kernel: 127.5,
                     # the from_int8 contract)
                     k = k * (ks_refs[i][:, 0] * (1.0 / MAX_INT8))
                     v = v * (vs_refs[i][:, 0] * (1.0 / MAX_INT8))
@@ -464,10 +572,9 @@ def _make_verify_kernel(*, page_size: int, ppb: int, nblk: int, s_len: int,
 
     Before this kernel, the verify forward unrolled attention per draft
     position (models/transformer.py issued S separate ``paged_attention_op``
-    dispatches per step), multiplying the launch-bound grid walk by (d+1)
-    and forfeiting the amortization speculation exists to buy (the round-5
-    regime: decode cost ≈ grid steps × Mosaic's ~1 µs/grid-step floor, so S
-    sweeps cost S× even though they move the same KV bytes). Here the S
+    dispatches per step), multiplying the grid walk by (d+1) and forfeiting
+    the amortization speculation exists to buy: S sweeps cost S× although
+    they move the same KV bytes. Here the S
     queries ride INSIDE the block — folded into the query-group axis as
     [K, S·G, hd], the same trick the folded kernel plays with kv heads — so
     the whole (d+1)-token verify costs exactly one blocked sweep:

@@ -21,13 +21,21 @@ from distrl_llm_tpu.ops.paged import (
 import functools
 
 from distrl_llm_tpu.ops.paged_native import (
+    live_page_walk,
+    native_pages_per_step,
     paged_attention_native,
     paged_attention_native_blocked,
     paged_attention_native_folded,
 )
 
 KERNELS = {
+    # what "auto" launches on a TPU: all kv heads and a length-bounded run of
+    # pages a grid step, the block sized from the shapes (here: the whole row)
     "native": paged_attention_native,
+    # ... and with rows of several blocks, ragged on most of the shared cases
+    "native_ppb2": functools.partial(
+        paged_attention_native, pages_per_block=2
+    ),
     "folded": paged_attention_native_folded,
     # grid-collapsed kernel at a block size that leaves ragged tails on
     # most of the shared parity cases (pps ∈ {1, 2, 3})
@@ -52,8 +60,8 @@ def _setup(b, h, kh, hd, ps, pps, seed=0, lengths=None):
 
 @pytest.fixture(params=sorted(KERNELS))
 def _native(request):
-    """Both launch variants share every parity case: the folded kernel's
-    only difference is grid/block shape (kv heads inside the block)."""
+    """Every launch shares every parity case: they differ in grid and block
+    shape, and in how many pages one softmax update covers."""
     kernel = KERNELS[request.param]
 
     def call(q, kp, vp, lengths, table, **kw):
@@ -146,6 +154,122 @@ class TestNativePagedParity:
             paged_attention_native(
                 q[:, :3], kp, vp, lengths, table, interpret=True
             )
+        with pytest.raises(ValueError, match="pages_per_block"):
+            paged_attention_native(
+                q, kp, vp, lengths, table, pages_per_block=-1, interpret=True
+            )
+
+
+# the decode geometries "auto" serves: the benchmark's rollout cell
+# (Qwen2.5-7B: 28 / 4 heads of 128, page 128, five pages a row) and the CLI's
+# small model (Qwen2.5-0.5B: 14 / 2 heads of 64)
+AUTO_GEOMETRIES = {
+    "cell-28x4x128": dict(h=28, kh=4, hd=128),
+    "qwen05b-14x2x64": dict(h=14, kh=2, hd=64),
+}
+PAGE, PPS = 128, 5
+#: every length the walk has a case for: an empty slot, one token, a page to
+#: the token, one past it, a row in its third page, the full table
+EDGE_LENGTHS = (0, 1, PAGE, PAGE + 1, 2 * PAGE + 37, PPS * PAGE)
+
+
+class TestAutoLaunch:
+    """What ``paged_impl="auto"`` launches on a TPU (PR 32), under the
+    interpreter at the geometries it serves: one grid step a row at five
+    pages of 128 (``native_pages_per_step``), two and three with a block
+    named; bf16 pages and the int8 container; the page walk bounded by each
+    row's length."""
+
+    @staticmethod
+    def _case(h, kh, hd, pages, poisoned):
+        rng = np.random.default_rng(hd + kh)
+        b = len(EDGE_LENGTHS)
+        shape = (kh, b * PPS, PAGE, hd)
+        kp = rng.standard_normal(shape).astype(np.float32)
+        vp = rng.standard_normal(shape).astype(np.float32)
+        q = jnp.asarray(rng.standard_normal((b, h, hd)), jnp.bfloat16)
+        table = make_page_table(b, PPS * PAGE, PAGE)
+        lengths = jnp.asarray(EDGE_LENGTHS, jnp.int32)
+        kp, vp = jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16)
+        want = paged_attention_reference(
+            q, *((quantize_pages(kp), quantize_pages(vp))
+                 if pages == "int8" else (kp, vp)),
+            lengths, jnp.asarray(table),
+        )
+        if poisoned:
+            # every page past a row's last live one holds NaN: a fetch that
+            # reached the arithmetic would show in the output
+            dead = np.concatenate([
+                table[r, -(-n // PAGE):] for r, n in enumerate(EDGE_LENGTHS)
+            ])
+            kp = kp.at[:, dead].set(jnp.nan)
+            vp = vp.at[:, dead].set(jnp.nan)
+        scales = {}
+        if pages == "int8":
+            kq, vq = quantize_pages(kp), quantize_pages(vp)
+            kp, vp = kq.weight, vq.weight
+            scales = dict(k_scales=kq.scales, v_scales=vq.scales)
+        return q, kp, vp, lengths, jnp.asarray(table), scales, want
+
+    @pytest.mark.parametrize(
+        "poisoned", [False, True], ids=["clean", "nan-past-length"])
+    @pytest.mark.parametrize("ppb", [0, 2, 3], ids=["own-block", "ppb2", "ppb3"])
+    @pytest.mark.parametrize("pages", ["bf16", "int8"])
+    @pytest.mark.parametrize("geom", sorted(AUTO_GEOMETRIES))
+    def test_parity_at_edge_lengths(self, geom, pages, ppb, poisoned):
+        g = AUTO_GEOMETRIES[geom]
+        q, kp, vp, lengths, table, scales, want = self._case(
+            **g, pages=pages, poisoned=poisoned)
+        got = np.asarray(paged_attention_native(
+            q * g["hd"]**-0.5, kp, vp, lengths, table, **scales,
+            pages_per_block=ppb, interpret=True,
+        ), np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got[0], 0.0)  # the empty slot
+        tol = 3e-2 if pages == "int8" else 1e-2  # the bf16 output's rounding
+        np.testing.assert_allclose(
+            got[1:], np.asarray(want, np.float32)[1:], atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize(
+        "geom,pages,pps,want",
+        [
+            ("cell-28x4x128", "bf16", 5, 5),  # the cell: a row is one step
+            ("cell-28x4x128", "bf16", 64, 8),  # 8k contexts: the cap
+            ("cell-28x4x128", "int8", 64, 3),  # the compact scales' lane tiles
+            ("qwen05b-14x2x64", "bf16", 5, 5),
+            ("qwen05b-14x2x64", "bf16", 64, 8),
+        ],
+    )
+    def test_pages_per_step_come_from_the_shapes(self, geom, pages, pps, want):
+        g = AUTO_GEOMETRIES[geom]
+        assert native_pages_per_step(
+            num_kv_heads=g["kh"], head_dim=g["hd"], page_size=PAGE, pps=pps,
+            kv_itemsize=1 if pages == "int8" else 2,
+            quantized=pages == "int8",
+        ) == want
+
+    @pytest.mark.parametrize("ppb", [1, 2, 5])
+    def test_walk_names_a_new_page_only_where_one_is_live(self, ppb):
+        """The pipeline copies a block when its index changes between two
+        grid steps: over the whole walk the changes are the live pages, and
+        every entry past a row's length repeats the entry one step before."""
+        lengths = np.asarray(EDGE_LENGTHS + (3 * PAGE, 0, 5), np.int32)
+        b, width = len(lengths), -(-PPS // ppb) * ppb
+        rng = np.random.default_rng(ppb)
+        table = rng.permutation(b * width).astype(np.int32).reshape(b, width)
+        walk = np.asarray(live_page_walk(
+            jnp.asarray(table), jnp.asarray(lengths), page_size=PAGE, ppb=ppb,
+        )).reshape(-1, ppb)
+        flat = table.reshape(-1, ppb)
+        live = (
+            np.arange(width)[None, :] * PAGE < lengths[:, None]
+        ).reshape(-1, ppb)
+        np.testing.assert_array_equal(walk[live], flat[live])
+        later = np.s_[1:]
+        np.testing.assert_array_equal(
+            walk[later][~live[later]], walk[:-1][~live[later]])
+        changes = int((walk[1:] != walk[:-1]).sum())
+        assert changes <= int(live.sum())
 
 
 class TestBlockedKernel:
@@ -236,15 +360,17 @@ class TestBlockedKernel:
         from distrl_llm_tpu.ops.paged import paged_grid_steps
 
         r5 = dict(batch=480, num_kv_heads=2, pps=13)
-        one_page = paged_grid_steps("native", **r5)
+        one_page = 480 * 2 * 13  # the (B, K, pps) grid "native" was
         blocked = paged_grid_steps(
             "native_blocked", pages_per_block=8, **r5
         )
-        assert one_page == 480 * 2 * 13
         assert blocked == 480 * 2  # ceil(13/8) = 2 blocks per row
         assert blocked * 8 <= one_page
         # folded sits between: the kv fold alone halves the count here
         assert paged_grid_steps("native_folded", **r5) == 480 * 13
+        # what "auto" launches now sizes its own block: 8 pages of 128 x 64
+        assert paged_grid_steps(
+            "native", head_dim=64, page_size=128, **r5) == blocked
 
     def test_grid_step_model_shapes(self):
         from distrl_llm_tpu.ops.paged import (
@@ -261,8 +387,22 @@ class TestBlockedKernel:
         assert paged_grid_steps("native_blocked", **g) == 8 * -(
             -12 // DEFAULT_PAGES_PER_BLOCK
         )
-        # one-page native: a (B, K, pps) grid; the reference has no grid
-        assert paged_grid_steps("native", **g) == 8 * 2 * 12
+        # native: (B, ceil(pps / its own pages a step)), from the shapes and
+        # the pages' dtype, whatever pages_per_block a plan names; the
+        # benchmark's cell is one step a row; the reference has no grid
+        assert paged_grid_steps(
+            "native", head_dim=64, page_size=16, **g) == 8 * 2
+        assert paged_grid_steps(
+            "native", head_dim=64, page_size=16, pages_per_block=1, **g
+        ) == 8 * 2
+        assert paged_grid_steps(
+            "native", batch=64, num_kv_heads=4, pps=5, head_dim=128,
+            page_size=128) == 64
+        assert paged_grid_steps(
+            "native", batch=64, num_kv_heads=4, pps=64, head_dim=128,
+            page_size=128, kv_itemsize=1, quantized=True) == 64 * 22
+        with pytest.raises(ValueError, match="head_dim"):
+            paged_grid_steps("native", **g)
         assert paged_grid_steps("reference", **g) == 0
         # jaxlib's kernel walks pages inside a (1, B, K) grid
         assert paged_grid_steps("kernel", **g) == 8 * 2

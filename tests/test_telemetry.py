@@ -235,11 +235,17 @@ class TestRegistry:
              blocked_key: "native_blocked",
              own_key: "native"},
         )
-        # one-page native at 8 rows: 8 × K × pps — computed at the live
-        # batch, so a later 3-row wave reports 3-row counts, no retrace
-        k = TINY.num_kv_heads
-        assert eng._grid_steps_per_call(8) == 8 * k * pps
-        assert eng._grid_steps_per_call(3) == 3 * k * pps
+        # native at 8 rows: 8 × the blocks a row, the block sized from this
+        # engine's own shapes — computed at the live batch, so a later 3-row
+        # wave reports 3-row counts, no retrace
+        from distrl_llm_tpu.ops.paged_native import native_pages_per_step
+
+        blocks = -(-pps // native_pages_per_step(
+            num_kv_heads=TINY.num_kv_heads, head_dim=TINY.head_dim,
+            page_size=8, pps=pps, kv_itemsize=4,
+        ))
+        assert eng._grid_steps_per_call(8) == 8 * blocks
+        assert eng._grid_steps_per_call(3) == 3 * blocks
         # no record yet (fresh process) → 0, telemetry stays silent
         monkeypatch.setattr(paged_ops, "dispatch_choices", {})
         assert eng._grid_steps_per_call(8) == 0

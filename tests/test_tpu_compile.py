@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 QWEN_0_5B = dict(heads=14, kv_heads=2, head_dim=64)  # QWEN2_0_5B attention
 HD128 = dict(heads=32, kv_heads=8, head_dim=128)  # Llama-3-8B attention
+QWEN_7B = dict(heads=28, kv_heads=4, head_dim=128)  # the benchmark's cells
 VOCAB = 151936
 ROWS = 64
 
@@ -60,6 +61,7 @@ def chip(topo):
 def assert_kernel(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def kv_pages(chip, shape, quantized: bool):
@@ -96,6 +98,48 @@ def test_paged_decode(chip, impl, geom, quantized):
         chip((ROWS, h, hd), jnp.bfloat16), pages, pages,
         chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32),
     )
+
+
+@pytest.mark.parametrize(
+    "metric", ["paged_attn_roofline", "kernel.paged_attn_share"])
+@pytest.mark.parametrize(
+    "geom,quantized",
+    [(QWEN_7B, False), (QWEN_0_5B, False), (QWEN_0_5B, True)],
+    ids=["cell-28x4x128", "hd64", "hd64-int8kv"],
+)
+def test_auto_decode_kernel_is_what_the_benchmark_reads(
+    chip, geom, quantized, metric
+):
+    """What ``auto`` resolves to on a TPU compiles at the rollout cell's
+    geometry (64 rows, page 128, five pages a row, a pool of 241) and at
+    head_dim 64, holds a Mosaic kernel, and that kernel's name, as the trace
+    reduction spells it, is what the benchmark's two kernel metrics select
+    events by: a renamed launch function would turn both to null in silence."""
+    import json
+    import pathlib
+    import re
+
+    from distrl_llm_tpu.ops.paged import AUTO_TPU_IMPL, paged_attention_op
+    from perfbench.trace_reduce import op_name
+
+    page_size, pps, pool = 128, 5, 241
+    h, kh, hd = geom["heads"], geom["kv_heads"], geom["head_dim"]
+    pages = kv_pages(chip, (kh, pool, page_size, hd), quantized)
+    text = assert_kernel(
+        functools.partial(paged_attention_op, impl=AUTO_TPU_IMPL),
+        chip((ROWS, h, hd), jnp.bfloat16), pages, pages,
+        chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32),
+    )
+    calls = [
+        line.strip().removeprefix("ROOT ") for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert len(calls) == 1, calls
+    spec = json.loads((
+        pathlib.Path(__file__).parents[1] / "perfbench" / "layer_metrics"
+        / f"{metric}.json"
+    ).read_text())
+    assert re.search(spec["args"]["regex"], op_name(calls[0])), op_name(calls[0])
 
 
 def test_jaxlib_launch_where_it_applies(chip):

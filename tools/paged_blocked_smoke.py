@@ -71,7 +71,7 @@ def main() -> int:
     print(f"{'PASS' if ok else 'FAIL'} blocked_ppb1_bit_identical_to_folded")
 
     r5 = dict(batch=480, num_kv_heads=2, pps=13)
-    one_page = paged_grid_steps("native", **r5)
+    one_page = 480 * 2 * 13  # the (B, K, pps) grid of a one-page kernel
     blocked = paged_grid_steps("native_blocked", pages_per_block=8, **r5)
     ok = blocked * 8 <= one_page
     failures += not ok
