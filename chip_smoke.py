@@ -147,6 +147,83 @@ def _sampler_case(key, logits_dtype, *, rows, vocab):
             "nucleus_support": support}
 
 
+def _latent_attention_case(key, *, rows, heads, nope, rope, v_dim, rank, context):
+    """Absorbed decode attention over latent rows (bf16, folded in two blocks)
+    against the expanded form, K and V rebuilt per head, in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    kw, kl, kq, kn = jax.random.split(key, 4)
+    w = (0.02 * jax.random.normal(kw, (rank, heads * (nope + v_dim)))).astype(jnp.bfloat16)
+    latent = jax.random.normal(kl, (rows, context, rank + rope), jnp.bfloat16)
+    q = jax.random.normal(kq, (rows, heads, nope + rope), jnp.bfloat16)
+    seen = jnp.arange(context)[None, :] < jax.random.randint(
+        kn, (rows, 1), context // 2, context + 1)
+    w_k, w_v = la.split_kvb(w, heads, nope, v_dim)
+    scale = (nope + rope) ** -0.5
+
+    @jax.jit
+    def absorbed(q, latent, seen):
+        q_row = la.absorbed_query(q[..., :nope], q[..., nope:], w_k)
+        carry, half = None, context // 2
+        for cut in (slice(0, half), slice(half, context)):
+            carry = la.absorbed_attention(q_row, latent[:, cut], seen[:, cut], scale, carry)
+        return la.absorbed_output(carry, w_v, jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        f32 = latent.astype(jnp.float32)
+        kv = (f32[..., :rank] @ w.astype(jnp.float32)).reshape(
+            rows, context, heads, nope + v_dim)
+        qf = q.astype(jnp.float32)[:, None]
+        want = la.expanded_finish(la.expanded_attention(
+            qf[..., :nope], qf[..., nope:], kv, f32[..., rank:], seen[:, None, :]),
+            jnp.float32)[:, 0]
+    err = float(jnp.max(jnp.abs(absorbed(q, latent, seen) - want)))
+    assert np.isfinite(err) and err < 5e-2, f"absorbed latent attention max|err| {err}"
+    return {"max_abs_err": round(err, 5), "context": context}
+
+
+def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
+    """An expert layer's routed part (bf16; ``tokens`` decides the form: every
+    expert on every token up to ``moe.DENSE_MAX_TOKENS``, single-expert blocks of
+    sorted pairs above) against every pair computed one expert at a time in
+    float32; no pair may be dropped."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.models import moe
+
+    kh, kg, ku, kd, ki, kw = jax.random.split(key, 6)
+    h = jax.random.normal(kh, (tokens, hidden), jnp.bfloat16)
+    draw = lambda k, shape: (0.02 * jax.random.normal(k, shape)).astype(jnp.bfloat16)
+    stack = {"gate": draw(kg, (experts, hidden, width)),
+             "up": draw(ku, (experts, hidden, width)),
+             "down": draw(kd, (experts, width, hidden))}
+    idx = jnp.argsort(jax.random.uniform(ki, (tokens, experts)), axis=-1)[:, :per_token]
+    w = jax.random.uniform(kw, (tokens, per_token), jnp.float32, 0.2, 1.0)
+    got, load = jax.jit(lambda h, idx, w: moe.routed_experts(
+        h, idx.astype(jnp.int32), w, stack, n_experts=experts))(h, idx, w)
+    comb = jnp.zeros((tokens, experts), jnp.float32).at[
+        jnp.arange(tokens)[:, None], idx].set(w)
+    with jax.default_matmul_precision("highest"):
+        hf = h.astype(jnp.float32)
+        want = sum(
+            comb[:, e, None] * ((jax.nn.silu(hf @ stack["gate"][e].astype(jnp.float32))
+                                 * (hf @ stack["up"][e].astype(jnp.float32)))
+                                @ stack["down"][e].astype(jnp.float32))
+            for e in range(experts))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    assert int(load.sum()) == tokens * per_token, "a token-expert pair was dropped"
+    assert np.isfinite(err) and err < 5e-2, f"expert layer max|err| {err}"
+    return {"max_abs_err": round(err, 5), "pairs": int(load.sum()),
+            "fullest_expert": int(load.max()),
+            "form": "dense" if tokens <= moe.DENSE_MAX_TOKENS else "grouped"}
+
+
 def phase_kernels(seed: int, compiles: CompileLog) -> None:
     """Each Pallas kernel the trainer phases use, compiled (never
     interpreted) at the 0.5B geometry, against its reference on the chip."""
@@ -185,6 +262,14 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     assert use_fused and not interpret, "auto sampler is not the fused kernel"
     for name, dt in (("sampler_f32", jnp.float32), ("sampler_bf16", jnp.bfloat16)):
         out[name] = _sampler_case(key, dt, rows=64, vocab=cfg.vocab_size)
+    # the benchmark's third configuration at its published widths
+    # (Kimi-VL-A3B's language model): absorbed against expanded latent
+    # attention, and one expert layer against the plain form (plain XLA both)
+    out["latent_attention"] = _latent_attention_case(
+        key, rows=8, heads=16, nope=128, rope=64, v_dim=128, rank=512, context=2048)
+    for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
+        out[name] = _expert_layer_case(
+            key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)
     # the learner's attention: the trainer phases run the CLI's default
     # attn_impl="reference" (XLA), so no attention kernel is on their path
     out["learner_attention"] = "reference (XLA): no kernel selected"

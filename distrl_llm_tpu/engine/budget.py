@@ -55,7 +55,11 @@ def tree_bytes(params) -> int:
 
 
 def page_bytes(model_cfg, page_size: int, kv_quant: str = "none") -> int:
-    """HBM bytes one KV page costs across ALL layers (k + v)."""
+    """HBM bytes one KV page costs across ALL layers (k + v; a latent page is
+    one row a token, ``latent_dim`` values in ``latent_row`` lanes, one array a
+    layer, no V beside it and no kv-head factor)."""
+    if model_cfg.latent:
+        return page_size * model_cfg.latent_row * 2 * model_cfg.num_layers
     per_layer_one = model_cfg.num_kv_heads * page_size * model_cfg.head_dim
     if kv_quant == "int8":
         # int8 payload + f32 per-token absmax scales [K, P, ps, 1]

@@ -94,6 +94,13 @@ ENGINE_TURN_PREFILL_SAVED = "engine/turn_prefill_saved_tokens"  # counter
 ENGINE_SPARSE_BLOCKS_ATTENDED = "engine/sparse_blocks_attended"  # counter
 ENGINE_SPARSE_BLOCKS_VISIBLE = "engine/sparse_blocks_visible"    # counter
 
+# routed experts (models/moe.py): token-expert pairs a round's decode steps
+# computed, and the fullest expert's pairs, summed over expert layers and
+# steps (fullest over mean = max_load * experts / assignments). Carried in the
+# decode state like the block counters above.
+ENGINE_MOE_ASSIGNMENTS = "engine/moe_assignments"          # counter
+ENGINE_MOE_MAX_EXPERT_LOAD = "engine/moe_max_expert_load"  # counter
+
 Params = dict[str, Any]
 
 
@@ -150,13 +157,17 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
 
 
 
-def _count_sparse_blocks(mixer) -> None:
-    """File a round's block counter (``mixer["sel_stats"]``) with telemetry."""
-    if mixer is None:
-        return
-    attended, visible = (int(x) for x in np.asarray(mixer["sel_stats"]))
-    telemetry.counter_add(ENGINE_SPARSE_BLOCKS_ATTENDED, attended)
-    telemetry.counter_add(ENGINE_SPARSE_BLOCKS_VISIBLE, visible)
+def _count_mixer_stats(mixer) -> None:
+    """File a round's counters (``mixer["sel_stats"]``: the block-sparse
+    layers' blocks; ``mixer["moe_stats"]``: the expert layers' pairs) with
+    telemetry."""
+    for key, names in (
+        ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
+        ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
+    ):
+        if mixer is not None and key in mixer:
+            for name, value in zip(names, np.asarray(mixer[key])):
+                telemetry.counter_add(name, int(value))
 
 
 def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
@@ -262,13 +273,14 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
         packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
         packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
         packed_mask = jnp.pad(packed_mask, ((0, 0), (0, pad_to - p)))
-    shape = (cfg.num_kv_heads, b * prompt_pages, page_size, cfg.head_dim)
-    n_sparse = cfg.kind_count("sparse")
+    shape = cfg.page_pool_shape(b * prompt_pages, page_size)
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         mixer = init_mixer_state(cfg, b, total_tokens, cache_dtype)
+        pool = lambda: tuple(
+            jnp.zeros(shape, cache_dtype) for _ in range(cfg.paged_layers))
         cache = {
-            "k": tuple(jnp.zeros(shape, cache_dtype) for _ in range(n_sparse)),
-            "v": tuple(jnp.zeros(shape, cache_dtype) for _ in range(n_sparse)),
+            # a latent layer's pages are one array: there is no V pool
+            "k": pool(), "v": () if cfg.latent else pool(),
             "lin": mixer["lin"], "pooled": mixer["pooled"],
         }
     table = jnp.asarray(make_page_table(b, pad_to, page_size))
@@ -331,14 +343,21 @@ def _mixer_from_cache(mixer, cache):
     return None if mixer is None else {name: cache[name] for name in mixer}
 
 
+def _at_pages(arr, idx) -> tuple:
+    """The index of pages ``idx`` in a pool: ``[K, pages, ps, hd]`` keeps its
+    pages on axis 1, a latent pool ``[pages, ps, row]`` on axis 0."""
+    return (slice(None),) * (arr.ndim - 3) + (idx,)
+
+
 def _grow_pool(pages, extra_pages: int):
     """Append ``extra_pages`` zeroed pages to a pool [K, S, ps, tail] →
-    [K, S+extra, ps, tail] (quantized pools grow weight + scales alike)."""
+    [K, S+extra, ps, tail] (quantized pools grow weight + scales alike; a
+    latent pool [S, ps, row] grows on its first axis)."""
 
     def grow(arr):
-        kh, s_, ps, tail = arr.shape
-        out = jnp.zeros((kh, s_ + extra_pages, ps, tail), arr.dtype)
-        return out.at[:, :s_].set(arr)
+        lead, (s_, ps, tail) = arr.shape[:-3], arr.shape[-3:]
+        out = jnp.zeros((*lead, s_ + extra_pages, ps, tail), arr.dtype)
+        return out.at[_at_pages(arr, slice(0, s_))].set(arr)
 
     from distrl_llm_tpu.ops.paged import is_quantized_pages
 
@@ -354,10 +373,11 @@ def _copy_pages(pages, src_idx, dst_idx, keep_mask=None):
     weight + scales alike, preserving the (int8, scale) pairing)."""
 
     def cp(arr):
-        tile = arr[:, src_idx]
+        tile = arr[_at_pages(arr, src_idx)]
         if keep_mask is not None:
-            tile = jnp.where(keep_mask[None, :, None, None], tile, arr[:, dst_idx])
-        return arr.at[:, dst_idx].set(tile)
+            keep = keep_mask[(None,) * (arr.ndim - 3) + (slice(None), None, None)]
+            tile = jnp.where(keep, tile, arr[_at_pages(arr, dst_idx)])
+        return arr.at[_at_pages(arr, dst_idx)].set(tile)
 
     from distrl_llm_tpu.ops.paged import is_quantized_pages
 
@@ -3769,7 +3789,7 @@ class PagedGenerationEngine(LoraMailbox):
             if c < total:
                 mark_finished(int(c))
         alive_h = int(np.asarray(state.alive_steps))
-        _count_sparse_blocks(getattr(state, "mixer", None))
+        _count_mixer_stats(getattr(state, "mixer", None))
         if cache_on:
             # park every resident cached page host-side: device page ids
             # are round-scoped, so the tree survives between rounds as a
@@ -4119,7 +4139,7 @@ class PagedGenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
-            _count_sparse_blocks(state.mixer)
+            _count_mixer_stats(state.mixer)
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
         decode_s = time.perf_counter() - t1

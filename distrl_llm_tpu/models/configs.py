@@ -16,6 +16,14 @@ published per-layer list. ``None`` means every layer is the dense GQA layer
 above, and nothing else in this file applies. The list is kept WHOLE when the
 depth is cut: ``num_layers`` runs its first entries, and a lightning layer's
 decay reads its published index.
+
+A latent-attention model with routed experts (``deepseek_v3``: Kimi-VL-A3B's
+language model) sets ``kv_lora_rank`` and ``n_routed_experts``. Every layer
+keeps ONE latent row a token (``kv_lora_rank + qk_rope_head_dim`` values, no
+K or V per head); the first ``first_dense_layers`` layers have a dense gated
+MLP (kind "latent"), the rest a router over ``n_routed_experts`` experts and
+one shared expert (kind "latent_moe"). It runs through ``models/hybrid.py``
+like any model whose layers are not all alike.
 """
 
 from __future__ import annotations
@@ -26,7 +34,11 @@ from dataclasses import dataclass
 #: published ``mixer_types`` entry -> the kind the program names its stacks by
 MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
-KNOWN_MODEL_TYPES = ("", "qwen2", "llama", "mistral", "gemma", "minicpm_sala")
+KNOWN_MODEL_TYPES = (
+    "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
+)
+#: layer kind -> the published name a refusal gives it
+_LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
 
 
 @dataclass(frozen=True)
@@ -74,6 +86,20 @@ class ModelConfig:
     scale_emb: float = 1.0
     scale_depth: float = 0.0
     dim_model_base: int = 0
+    # ---- latent attention (MLA) and routed experts (deepseek_v3). 0 = off.
+    # ``head_dim`` is then the QUERY head (nope + rope); a cached token is one
+    # row of ``latent_dim`` values for all heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0  # run as ONE gated MLP of n x moe_intermediate_size
+    experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    first_dense_layers: int = 0  # first_k_dense_replace
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
@@ -102,16 +128,62 @@ class ModelConfig:
     # ------------------------------------------------------ per-layer pattern
 
     @property
+    def latent(self) -> bool:
+        """True for latent attention (``kv_lora_rank`` is set)."""
+        return self.kv_lora_rank > 0
+
+    @property
     def hybrid(self) -> bool:
-        """True where layers differ in kind (``mixer_types`` is set)."""
-        return self.mixer_types is not None
+        """True where a layer is not the dense GQA layer: ``mixer_types`` is
+        set, or attention is latent. Such a model runs through
+        ``models/hybrid.py`` and keeps one parameter stack per layer kind."""
+        return self.mixer_types is not None or self.latent
 
     @property
     def layer_kinds(self) -> tuple[str, ...]:
-        """Kind of each layer that is RUN ("dense" | "sparse" | "lightning")."""
+        """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
+        "latent" (latent attention, dense MLP) | "latent_moe" (experts)."""
+        if self.latent:
+            dense = min(self.first_dense_layers, self.num_layers)
+            if not self.n_routed_experts:
+                dense = self.num_layers
+            return ("latent",) * dense + ("latent_moe",) * (self.num_layers - dense)
         if self.mixer_types is None:
             return ("dense",) * self.num_layers
         return tuple(MIXER_KINDS[m] for m in self.mixer_types[: self.num_layers])
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a cached token holds in a latent layer: ``[c, k_pe]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes a cached token's row takes in a page: ``latent_dim`` rounded
+        up to whole 128-lane tiles (576 -> 640), the rest zeros. The TPU's
+        tiling pads the row to that in any case, and with a width that is no
+        multiple of 128 the compiler keeps the pool token-minor, which the
+        decode step's row write would pay for with two pool copies a layer
+        (tests/test_tpu_compile.py)."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def paged_layers(self) -> int:
+        """Layers that keep pages in the paged engine's pool."""
+        return self.num_layers if self.latent else (
+            self.kind_count("sparse") if self.hybrid else self.num_layers)
+
+    def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
+        """Shape of one layer's page array: K beside V ``[K, pages, page,
+        head_dim]``, or one latent array ``[pages, page, latent_row]`` with no
+        kv-head axis (and no V array beside it), ``latent_row`` lanes a token."""
+        if self.latent:
+            return (pages, page_size, self.latent_row)
+        return (self.num_kv_heads, pages, page_size, self.head_dim)
+
+    @property
+    def shared_expert_size(self) -> int:
+        return self.n_shared_experts * self.moe_intermediate_size
 
     def kind_count(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds if k == kind)
@@ -133,6 +205,9 @@ class ModelConfig:
     @property
     def mixer_names(self) -> str:
         """The published names of the non-dense layer kinds, for a refusal."""
+        if self.latent:
+            return " and ".join(
+                _LATENT_NAMES[k] for k in dict.fromkeys(self.layer_kinds))
         return ", ".join(sorted(set(self.mixer_types or ())))
 
     def refuse_hybrid(self, what: str) -> None:
@@ -142,9 +217,10 @@ class ModelConfig:
         if self.hybrid:
             raise ValueError(
                 f"{what} cannot hold a model with {self.mixer_names} layers: it "
-                "keeps one kind of K/V for every layer, and these layers keep a "
-                "recurrent state or a selector cache. Use engine_impl='paged' "
-                "without it."
+                "keeps one kind of K/V for every layer, and these layers keep "
+                + ("one latent row a token in place of K and V per head. "
+                   if self.latent else "a recurrent state or a selector cache. ")
+                + "Use engine_impl='paged' without it."
             )
 
     @property
@@ -213,6 +289,8 @@ class ModelConfig:
             2 * self.hidden_size * self.q_dim       # q, o proj
             + 2 * self.hidden_size * self.kv_dim    # k, v proj
         )
+        if self.latent:
+            return self._latent_param_count(self.experts_per_token)
         if not self.hybrid:
             return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
         sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
@@ -224,6 +302,33 @@ class ModelConfig:
             + self.kind_count("lightning") * (lightning + mlp)
             + self.hidden_size * self.vocab_size
         )
+
+    def _latent_param_count(self, experts: int) -> int:
+        """Matmul parameters of a latent-attention model with ``experts``
+        routed experts counted a layer: the ones a token RUNS
+        (``experts_per_token``) for operations, all that are held for bytes."""
+        d, h = self.hidden_size, self.num_heads
+        attn = (
+            d * self.q_dim + d * self.latent_dim
+            + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+            + h * self.v_head_dim * d
+        )
+        moe = 3 * d * (
+            experts * self.moe_intermediate_size + self.shared_expert_size
+        ) + d * self.n_routed_experts
+        return (
+            self.kind_count("latent") * (attn + 3 * d * self.intermediate_size)
+            + self.kind_count("latent_moe") * (attn + moe)
+            + d * self.vocab_size
+        )
+
+    @property
+    def total_matmul_param_count(self) -> int:
+        """``matmul_param_count`` over every expert HELD, not only those a
+        token runs: what a decode step of many rows reads."""
+        if self.latent:
+            return self._latent_param_count(self.n_routed_experts)
+        return self.matmul_param_count
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
         """Model FLOPs per decoded token: 2·(matmul params) for the dense
@@ -242,6 +347,8 @@ class ModelConfig:
     def model_type(self) -> str:
         """The HF model_type this config round-trips through
         ``from_hf_config`` as (used by HF-format snapshot export)."""
+        if self.latent:
+            return "deepseek_v3"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -314,6 +421,10 @@ class ModelConfig:
                     if key in sparse
                 },
             )
+        head_dim = get("head_dim", None) or hf.hidden_size // num_heads
+        if mt == "deepseek_v3":
+            hybrid = _latent_fields(get)
+            head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -324,7 +435,7 @@ class ModelConfig:
             num_layers=hf.num_hidden_layers,
             num_heads=num_heads,
             num_kv_heads=get("num_key_value_heads", num_heads),
-            head_dim=get("head_dim", None) or hf.hidden_size // num_heads,
+            head_dim=head_dim,
             rope_theta=get("rope_theta", 10000.0),
             rms_norm_eps=get("rms_norm_eps", 1e-6),
             attention_bias=mt == "qwen2" or bool(get("attention_bias", False)),
@@ -336,6 +447,47 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+def _latent_fields(get) -> dict:
+    """The ``deepseek_v3`` keys as ``ModelConfig`` fields. A variant that is
+    not implemented is REFUSED by name: every key this function did not read
+    would be ignored, and the model would load as something else."""
+    def refuse(key: str, why: str):
+        raise ValueError(
+            f"deepseek_v3 with {key}={get(key)!r} is not supported: {why}")
+
+    if get("q_lora_rank") is not None:
+        refuse("q_lora_rank", "the low-rank query path (q_a_proj, q_a_layernorm, "
+               "q_b_proj) is not implemented; only a plain q_proj is")
+    if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
+        refuse("n_group" if get("n_group", 1) != 1 else "topk_group",
+               "grouped routing (choose groups, then experts inside them) is "
+               "not implemented; n_group and topk_group must be 1")
+    if str(get("scoring_func", "sigmoid")) != "sigmoid":
+        refuse("scoring_func", "the router scores by sigmoid only")
+    if str(get("topk_method", "noaux_tc")) != "noaux_tc":
+        refuse("topk_method", "the router chooses by score plus "
+               "e_score_correction_bias (noaux_tc) only")
+    if get("rope_scaling") is not None:
+        refuse("rope_scaling", "scaled RoPE (YaRN and its softmax-scale "
+               "correction) is not implemented")
+    if get("moe_layer_freq", 1) != 1:
+        refuse("moe_layer_freq", "every layer after first_k_dense_replace is "
+               "an expert layer; another period is not implemented")
+    return dict(
+        kv_lora_rank=int(get("kv_lora_rank")),
+        qk_nope_head_dim=int(get("qk_nope_head_dim")),
+        qk_rope_head_dim=int(get("qk_rope_head_dim")),
+        v_head_dim=int(get("v_head_dim")),
+        n_routed_experts=int(get("n_routed_experts") or 0),
+        n_shared_experts=int(get("n_shared_experts") or 0),
+        experts_per_token=int(get("num_experts_per_tok") or 0),
+        moe_intermediate_size=int(get("moe_intermediate_size") or 0),
+        first_dense_layers=int(get("first_k_dense_replace", 0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+    )
 
 
 # Tiny config for unit/golden tests — shapes chosen to exercise GQA (heads !=
@@ -351,6 +503,17 @@ TINY = ModelConfig(
     rope_theta=10000.0,
     attention_bias=True,
     tie_word_embeddings=False,
+)
+
+# latent attention and routed experts at a size the CPU tests run: a dense
+# first layer, then 8 experts, 2 a token, 1 shared (Kimi-VL-A3B's shape)
+TINY_LATENT_MOE = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=4, num_kv_heads=4, head_dim=24, rope_theta=10000.0,
+    rms_norm_eps=1e-5, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8, n_shared_experts=1,
+    experts_per_token=2, moe_intermediate_size=32, first_dense_layers=1,
+    routed_scaling_factor=2.446,
 )
 
 QWEN2_0_5B = ModelConfig(
@@ -402,6 +565,7 @@ GEMMA_7B = ModelConfig(
 
 PRESETS: dict[str, ModelConfig] = {
     "tiny": TINY,
+    "tiny-latent-moe": TINY_LATENT_MOE,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

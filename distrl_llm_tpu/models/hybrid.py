@@ -41,6 +41,23 @@ published order: runs of like layers are scanned (no cache) or unrolled
   segment after segment (``engine/paged_engine.py``): a 20k-token prompt at
   once would need the MLP's activations and the attention scores for all of it.
 
+**Latent attention with routed experts** (``cfg.latent``: DeepSeek-V3's block,
+Kimi-VL-A3B's language model) is two more kinds through the same three modes:
+"latent" (a dense gated MLP) and "latent_moe" (``models/moe.py`` beside ONE
+shared expert, which is this module's ``_mlp_half``)::
+
+    q = W_q h [T, H, nope + rope];  [c_raw, k_pe] = W_kva h;  c = RMSNorm(c_raw)
+    q_pe, k_pe <- RoPE (interleaved pairs);  the cache row is [c, k_pe]
+    no cache, segment: K, V = c W_kvb a head, scores over nope + rope (expanded)
+    decode:            the heads attend over the latent rows (absorbed)
+    y = W_o o;   x <- x + y;   x <- x + Shared(h') + sum_k w_k E_k(h')
+
+Its cache has ``k`` alone: one latent array ``[pages, page, latent_row]`` a
+layer (``rank + rope`` values and zeros to whole 128-lane tiles), no kv-head
+axis and no V (``v`` is an empty tuple), and ``moe_stats``
+[2] int32 (token-expert pairs computed, the fullest expert's, summed over
+expert layers and steps).
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -63,7 +80,13 @@ from distrl_llm_tpu.models.transformer import (
     _head, _init_around_layers, _init_layer_stack, _mlp_half, _normal_init, _proj,
     _slice_layer, apply_rope, rms_norm, rope_cos_sin,
 )
+from distrl_llm_tpu.models.moe import moe_half
 from distrl_llm_tpu.ops.attention import attention
+from distrl_llm_tpu.ops.latent_attention import (
+    absorbed_attention, absorbed_output, absorbed_query, absorbed_start,
+    expanded_attention, expanded_finish, expanded_start,
+    rope_interleaved, split_kvb,
+)
 from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
 from distrl_llm_tpu.ops.sparse_attention import (
@@ -71,6 +94,10 @@ from distrl_llm_tpu.ops.sparse_attention import (
 )
 
 Params = dict[str, Any]
+#: columns of a row's page table absorbed decode attention gathers at a time,
+#: and rows that walk their tables together
+LATENT_DECODE_PAGES = 16
+LATENT_DECODE_ROWS = 16
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -89,7 +116,38 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             p["o_norm"] = jnp.ones((n, q_dim), dtype)
         return p
 
+    def latent_stack(n: int, moe: bool) -> Params:
+        d, heads, experts = cfg.hidden_size, cfg.num_heads, cfg.n_routed_experts
+        p = {
+            "attn_norm": jnp.ones((n, d), dtype),
+            "mlp_norm": jnp.ones((n, d), dtype),
+            "wq": init((n, d, cfg.q_dim)),
+            "wkv_a": init((n, d, cfg.latent_dim)),
+            "kv_a_norm": jnp.ones((n, cfg.kv_lora_rank), dtype),
+            "wkv_b": init((n, cfg.kv_lora_rank,
+                           heads * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wo": init((n, heads * cfg.v_head_dim, d)),
+        }
+        # the dense MLP, or the shared expert: one gated MLP under these names
+        f = cfg.shared_expert_size if moe else cfg.intermediate_size
+        if f:
+            p.update(w_gate=init((n, d, f)), w_up=init((n, d, f)),
+                     w_down=init((n, f, d)))
+        if moe:
+            fm = cfg.moe_intermediate_size
+            p.update(
+                router=init((n, d, experts)),
+                e_score_bias=jnp.zeros((n, experts), dtype),
+                experts_gate=init((n, experts, d, fm)),
+                experts_up=init((n, experts, d, fm)),
+                experts_down=init((n, experts, fm, d)),
+            )
+        return p
+
     layers: Params = {}
+    for kind in ("latent", "latent_moe"):
+        if cfg.kind_count(kind):
+            layers[kind] = latent_stack(cfg.kind_count(kind), kind == "latent_moe")
     if cfg.kind_count("sparse"):
         layers["sparse"] = stack(
             cfg.kind_count("sparse"), cfg.q_dim, cfg.kv_dim, cfg.head_dim,
@@ -108,6 +166,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                      cache_dtype=jnp.bfloat16) -> Params:
     """What a slot holds beside its K/V pages: a float32 state per lightning
     layer, the selector's pooled keys per sparse layer, the round's counter."""
+    if cfg.latent:  # all of a slot's cache is in pages; the round's counter
+        return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32)}
     h, d = cfg.lightning_heads, cfg.lightning_head_dim
     pooled = (rows, pooled_count(max_tokens, cfg), cfg.num_kv_heads, cfg.head_dim)
     return {
@@ -207,9 +267,12 @@ def _lightning_mix(q, k, v, state, rate, *, cfg, mode, env):
 
 def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
            env: dict, lora_scale: float, lora_dropout: float, dropout_rng):
-    """One layer of either kind: (x, new cache pieces, stats)."""
+    """One layer of any kind: (x, new cache pieces, stats)."""
     b, s, _ = x.shape
     proj = partial(_proj, lora_dropout=lora_dropout, dropout_rng=dropout_rng)
+    if kind in ("latent", "latent_moe"):
+        return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
+                             mode=mode, env=env, proj=proj, lora_scale=lora_scale)
     c = jnp.asarray(cfg.residual_scale, x.dtype)
     sparse = kind == "sparse"
     heads, kv_heads, hd = (
@@ -242,6 +305,130 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale,
                   residual_scale=c)
     return x, cache, stats
+
+
+def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale):
+    """One decode token a row: write its latent row, then attend over the
+    row's pages with W_kvb absorbed (its adapter too). ``q_nope [B, H, nope]``,
+    ``q_pe [B, H, rope]``, ``row [B, latent_row]``. Returns (o [B, 1, H, v],
+    the page array)."""
+    b, heads, nope = q_nope.shape
+    idx, ps, lengths = env["page_indices"], env["page_size"], env["lengths"]
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        # a point scatter: page and slot are indices, the row the window
+        at = (idx[jnp.arange(b), lengths // ps], lengths % ps)
+        pages = pages.at[at].set(row, mode="drop")
+    with jax.named_scope(telemetry.MODEL_LATENT_ATTN):
+        w = p["wkv_b"]
+        if lora is not None and "wkv_b" in lora:
+            ab = lora["wkv_b"]
+            w = (w.astype(jnp.float32)
+                 + lora_scale * (ab["a"] @ ab["b"])).astype(w.dtype)
+        w_k, w_v = split_kvb(w, heads, nope, cfg.v_head_dim)
+        q_row = absorbed_query(q_nope, q_pe, w_k)
+        q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, cfg.latent_row - cfg.latent_dim)))
+        # a row's pages LATENT_DECODE_PAGES columns of its table at a time,
+        # LATENT_DECODE_ROWS rows together, as far as the longest of them
+        # reaches: a short row's group does not walk a long row's width
+        per = min(LATENT_DECODE_PAGES, idx.shape[1])
+        rows = LATENT_DECODE_ROWS if b % LATENT_DECODE_ROWS == 0 else b
+        scale = cfg.head_dim ** -0.5
+        cols = jnp.pad(idx, ((0, 0), (0, -idx.shape[1] % per)), mode="edge")
+
+        def group(q_g, idx_g, len_g):
+            def fold(j, carry):
+                at = jax.lax.dynamic_slice_in_dim(idx_g, j * per, per, axis=1)
+                seen = (j * per * ps + jnp.arange(per * ps))[None, :] <= len_g[:, None]
+                return absorbed_attention(
+                    q_g, pages[at].reshape(rows, per * ps, -1), seen, scale, carry)
+
+            return jax.lax.fori_loop(
+                0, len_g.max() // (per * ps) + 1, fold, absorbed_start(*q_g.shape))
+
+        carry = jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(parts, axis=0),
+            *(group(q_row[r: r + rows], cols[r: r + rows], lengths[r: r + rows])
+              for r in range(0, b, rows)))
+        return absorbed_output(carry, w_v, q_nope.dtype)[:, None], pages
+
+
+def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,
+                lora_scale):
+    """Latent attention in each mode. Returns (o [B, S, H, v], the layer's
+    page array or None)."""
+    b, s, heads, nope = q_nope.shape
+    expand = lambda rows: proj(rows, p, lora, "wkv_b", "bkv_b", lora_scale).reshape(
+        rows.shape[0], rows.shape[1], heads, nope + cfg.v_head_dim)
+    if mode == "full":
+        with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+            kv = expand(c)
+        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+            pos = jnp.arange(s)
+            mask = (pos[None, :] <= pos[:, None])[None] & (env["valid"] > 0)[:, None, :]
+            return expanded_finish(
+                expanded_attention(q_nope, q_pe, kv, k_pe, mask), c.dtype), None
+    idx, ps, rank = env["page_indices"], env["page_size"], cfg.kv_lora_rank
+    rope = cfg.qk_rope_head_dim
+    row = jnp.concatenate(  # [B, S, latent_row]: [c, k_pe] and zeros to whole tiles
+        [c, k_pe, jnp.zeros((b, s, cfg.latent_row - cfg.latent_dim), c.dtype)],
+        axis=-1).astype(pages.dtype)
+    if mode == "decode":
+        return _absorbed_decode(q_nope[:, 0], q_pe[:, 0], row[:, 0], pages, p, lora,
+                                cfg=cfg, env=env, lora_scale=lora_scale)
+    # one page-aligned segment of a prefill, every row at offset ``start``:
+    # write its pages whole, then attend over the row's pages up to and
+    # including them, a segment's worth of keys at a time, expanded
+    start, per = env["segment_start"], s // ps
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, per, axis=1)
+        pages = pages.at[dest.reshape(-1)].set(row.reshape(b * per, ps, -1))
+
+    def fold(j, carry):
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)
+            held = pages[at].reshape(b, s, -1).astype(c.dtype)
+        with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+            kv = expand(held[..., :rank])
+        mask = (j * s + jnp.arange(s))[None, :] <= env["q_pos"][0][:, None]
+        return expanded_attention(
+            q_nope, q_pe, kv, held[..., rank: rank + rope],
+            jnp.broadcast_to(mask, (b, s, s)),
+            carry)
+
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        carry = jax.lax.fori_loop(  # the segment's own keys come from its pages
+            0, start // s + 1, fold, expanded_start(b, s, heads, cfg.v_head_dim))
+        return expanded_finish(carry, c.dtype), pages
+
+
+def _latent_block(x, p, lora, pages, *, moe: bool, cfg: ModelConfig, mode: str,
+                  env: dict, proj, lora_scale: float):
+    """One latent-attention layer with a dense MLP or routed experts:
+    (x, the layer's page array, the expert layer's stats or None)."""
+    b, s, _ = x.shape
+    heads, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, cfg.head_dim)
+        kva = proj(h, p, lora, "wkv_a", "bkv_a", lora_scale)
+        c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        q_pe = rope_interleaved(q[..., nope:], env["cos"], env["sin"])
+        k_pe = rope_interleaved(kva[..., rank:], env["cos"], env["sin"])
+    o, pages = _latent_mix(
+        q[..., :nope], q_pe, c, k_pe, pages, p, lora, cfg=cfg, mode=mode, env=env,
+        proj=proj, lora_scale=lora_scale)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        x = x + proj(o.reshape(b, s, heads * cfg.v_head_dim), p, lora, "wo", "bo",
+                     lora_scale)
+    if not moe:
+        return _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale), pages, None
+    with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+    routed, stats = moe_half(h, p, cfg, alive=env.get("alive"))
+    if "w_gate" in p:  # the shared expert: x + S(h)
+        x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    return x + routed, pages, stats
 
 
 def _pack_left(ids, mask):
@@ -302,16 +489,19 @@ def forward_hybrid(
             "valid": attention_mask, "page_indices": kv_cache["page_indices"],
             "page_size": page_size,
         }
-    with jax.named_scope(telemetry.MODEL_LINEAR_ATTN):
+    with jax.named_scope(
+            telemetry.MODEL_ATTN_CORE if cfg.latent else telemetry.MODEL_LINEAR_ATTN):
         env["cos"], env["sin"] = rope_cos_sin(
-            rope_pos, cfg.lightning_head_dim or cfg.head_dim, cfg.rope_theta)
+            rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
+            cfg.rope_theta)
 
     with jax.named_scope(telemetry.MODEL_EMBED):
         x = jnp.take(params["embed"], input_ids, axis=0)
         if cfg.scale_emb != 1.0:
             x = x * jnp.asarray(cfg.scale_emb, x.dtype)
 
-    rates = jnp.asarray(cfg.lightning_decay_rates())
+    rates = (jnp.asarray(cfg.lightning_decay_rates())
+             if cfg.kind_count("lightning") else None)
     use_dropout = dropout_rng is not None and lora_dropout > 0.0
     layer_keys = jax.random.split(dropout_rng, cfg.num_layers) if use_dropout else None
     block = partial(
@@ -352,13 +542,24 @@ def forward_hybrid(
     # cache carried through a scan is ping-ponged whole: transformer.forward)
     new = {name: list(kv_cache[name]) for name in ("k", "v", "pooled", "lin")}
     stats = kv_cache.get("sel_stats")
-    at = {"sparse": 0, "lightning": 0}
+    moe_stats = kv_cache.get("moe_stats")
+    at = dict.fromkeys(cfg.layer_kinds, 0)
     for i, kind in enumerate(cfg.layer_kinds):
         j = at[kind]
         at[kind] += 1
-        p = _slice_layer(stacks[kind], j)
+        whole = {k: v for k, v in stacks[kind].items() if k.startswith("experts_")}
+        p = _slice_layer(
+            {k: v for k, v in stacks[kind].items() if k not in whole}, j)
+        if whole:  # the grouped products read the stack whole (models/moe.py)
+            p.update(whole, experts_layer=j)
         lora_p = _slice_layer(lora_stacks[kind], j) if kind in lora_stacks else None
-        if kind == "sparse":
+        if cfg.latent:  # every layer keeps pages: layer i's are new["k"][i]
+            x, new["k"][i], layer_stats = block(
+                x, p, lora_p, None, new["k"][i], kind=kind,
+                dropout_rng=layer_keys[i] if use_dropout else None)
+            if moe_stats is not None and layer_stats is not None:
+                moe_stats = moe_stats + layer_stats
+        elif kind == "sparse":
             held = (new["k"][j], new["v"][j], new["pooled"][j])
             x, held, layer_stats = block(
                 x, p, lora_p, None, held, kind=kind,
@@ -375,4 +576,6 @@ def forward_hybrid(
     out = {**kv_cache, **{name: tuple(vals) for name, vals in new.items()}}
     if stats is not None:
         out["sel_stats"] = stats
+    if moe_stats is not None:
+        out["moe_stats"] = moe_stats
     return logits, out

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 from typing import Any, Callable, Mapping
 
 import numpy as np
@@ -50,6 +51,102 @@ _HF_HYBRID_MAP = {
 }
 
 
+# a latent-attention model with routed experts (deepseek_v3; Kimi-VL-A3B keeps
+# its language model under ``language_model.`` beside a vision tower and a
+# projector, which are not loaded). Layer kind "latent" has the dense MLP's
+# names, "latent_moe" the shared expert's under the same keys, the router, and
+# ``mlp.experts.<e>.<proj>.weight`` stacked over e.
+_HF_LATENT_MAP = {
+    "attn_norm": "input_layernorm.weight",
+    "mlp_norm": "post_attention_layernorm.weight",
+    "wq": "self_attn.q_proj.weight",
+    "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+    "kv_a_norm": "self_attn.kv_a_layernorm.weight",
+    "wkv_b": "self_attn.kv_b_proj.weight",
+    "wo": "self_attn.o_proj.weight",
+}
+_HF_LATENT_MLP = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+_HF_ROUTER = {"router": "mlp.gate.weight",
+              "e_score_bias": "mlp.gate.e_score_correction_bias"}
+#: tensors of a multimodal checkpoint that are not the language model's
+_SKIPPED_PREFIXES = ("vision_tower.", "multi_modal_projector.")
+
+
+def _latent_layer_names(cfg: ModelConfig, kind: str) -> dict[str, Any]:
+    """Leaf -> the layer's checkpoint name (a list over experts for an expert
+    stack), for one layer of ``kind``."""
+    names: dict[str, Any] = dict(_HF_LATENT_MAP)
+    if kind == "latent":
+        names.update({k: f"mlp.{v}.weight" for k, v in _HF_LATENT_MLP.items()})
+        return names
+    if cfg.shared_expert_size:
+        names.update({
+            k: f"mlp.shared_experts.{v}.weight" for k, v in _HF_LATENT_MLP.items()})
+    names.update(_HF_ROUTER)
+    for k, v in _HF_LATENT_MLP.items():
+        names["experts" + k[1:]] = [
+            f"mlp.experts.{e}.{v}.weight" for e in range(cfg.n_routed_experts)]
+    return names
+
+
+def _is_matrix(key: str) -> bool:
+    """HF Linear stores [out, in]; ours is [in, out]."""
+    return key.startswith(("w", "experts_")) or key == "router"
+
+
+def _latent_params_from_state_dict(sd, cfg: ModelConfig, dtype) -> Params:
+    """The published names into one stack per layer kind. Every tensor of the
+    language model's layers that are run is used exactly once; one that is
+    missing (an expert, say) or left over is an error, and the vision tower's
+    and the projector's are skipped by prefix."""
+    prefix = "language_model." if any(
+        k.startswith("language_model.") for k in sd) else ""
+    used: set[str] = set()
+
+    def take(name: str) -> np.ndarray:
+        full = prefix + name
+        if full not in sd:
+            raise KeyError(f"the checkpoint has no tensor {full!r}")
+        if full in used:
+            raise ValueError(f"tensor {full!r} is read twice")
+        used.add(full)
+        return np.asarray(sd[full])
+
+    layers: Params = {}
+    for kind in dict.fromkeys(cfg.layer_kinds):
+        at = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+        layers[kind] = {}
+        for key, name in _latent_layer_names(cfg, kind).items():
+            per_layer = [
+                np.stack([take(f"model.layers.{i}.{n}") for n in name])
+                if isinstance(name, list) else take(f"model.layers.{i}.{name}")
+                for i in at
+            ]
+            out = np.stack(per_layer).astype(dtype)
+            layers[kind][key] = out.swapaxes(-1, -2) if _is_matrix(key) else out
+    params: Params = {
+        "embed": take("model.embed_tokens.weight").astype(dtype),
+        "final_norm": take("model.norm.weight").astype(dtype),
+        "layers": layers,
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = take("lm_head.weight").astype(dtype).T
+    def of_a_layer_not_run(k: str) -> bool:
+        m = re.match(re.escape(prefix) + r"model\.layers\.(\d+)\.", k)
+        return m is not None and int(m.group(1)) >= cfg.num_layers
+
+    left = [
+        k for k in sd
+        if k not in used and not k.startswith(_SKIPPED_PREFIXES)
+        and not k.endswith("rotary_emb.inv_freq") and not of_a_layer_not_run(k)
+    ]
+    if left:
+        raise ValueError(
+            f"{len(left)} tensors of the checkpoint were not loaded, e.g. "
+            f"{sorted(left)[:4]}: the model would run without them")
+    return params
+
+
 def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
     if name in sd:
         return np.asarray(sd[name])
@@ -64,6 +161,8 @@ def params_from_state_dict(
     sd: Mapping[str, np.ndarray], cfg: ModelConfig, dtype=np.float32
 ) -> Params:
     """Numpy state dict (HF names) → our stacked param pytree."""
+    if cfg.latent:
+        return _latent_params_from_state_dict(sd, cfg, dtype)
     if cfg.hybrid:
         return _hybrid_params_from_state_dict(sd, cfg, dtype)
 
@@ -145,7 +244,21 @@ def state_dict_from_params(params: Params, cfg: ModelConfig) -> dict[str, np.nda
     inverse of ``params_from_state_dict``)."""
     sd: dict[str, np.ndarray] = {}
     layers = params["layers"]
-    if cfg.hybrid:
+    if cfg.latent:
+        for kind, stack in layers.items():
+            at = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
+            names = _latent_layer_names(cfg, kind)
+            for key, stacked in stack.items():
+                stacked = np.asarray(stacked)
+                if _is_matrix(key):
+                    stacked = stacked.swapaxes(-1, -2)
+                for j, i in enumerate(at):
+                    each = names[key] if isinstance(names[key], list) else None
+                    for e, name in enumerate(each or [names[key]]):
+                        sd[f"model.layers.{i}.{name}"] = np.ascontiguousarray(
+                            stacked[j, e] if each else stacked[j])
+        layers = {}
+    elif cfg.hybrid:
         for kind, stack in layers.items():
             at = [i for i, k in enumerate(cfg.layer_kinds) if k == kind]
             for key, stacked in stack.items():
@@ -201,6 +314,7 @@ def save_hf_checkpoint(
         "llama": "LlamaForCausalLM",
         "mistral": "MistralForCausalLM",
         "gemma": "GemmaForCausalLM",
+        "deepseek_v3": "DeepseekV3ForCausalLM",
     }.get(model_type, "LlamaForCausalLM")
     hf_cfg = {
         "model_type": model_type,
@@ -218,6 +332,22 @@ def save_hf_checkpoint(
         "max_position_embeddings": cfg.max_position_embeddings,
         "torch_dtype": torch_dtype,
     }
+    if cfg.latent:
+        del hf_cfg["head_dim"]  # the query head is nope + rope
+        hf_cfg.update(
+            kv_lora_rank=cfg.kv_lora_rank, q_lora_rank=None,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+            n_routed_experts=cfg.n_routed_experts,
+            n_shared_experts=cfg.n_shared_experts,
+            num_experts_per_tok=cfg.experts_per_token,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            first_k_dense_replace=cfg.first_dense_layers, moe_layer_freq=1,
+            norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
+            topk_group=1, rope_scaling=None,
+        )
     if cfg.hidden_act == "gelu_tanh":
         hf_cfg["hidden_act"] = "gelu_pytorch_tanh"
     if cfg.sliding_window is not None:

@@ -23,14 +23,21 @@ _TARGET_DIMS = {
     "wq": ("hidden_size", "q_dim"),
     "wk": ("hidden_size", "kv_dim"),
     "wv": ("hidden_size", "kv_dim"),
-    "wo": ("q_dim", "hidden_size"),
+    "wo": ("o_dim", "hidden_size"),
     "w_gate": ("hidden_size", "intermediate_size"),
     "w_up": ("hidden_size", "intermediate_size"),
     "w_down": ("intermediate_size", "hidden_size"),
+    # latent attention (kv_a_proj_with_mqa, kv_b_proj); wo's input is then H x v
+    "wkv_a": ("hidden_size", "latent_dim"),
+    "wkv_b": ("kv_lora_rank", "kvb_dim"),
 }
 
 # reference target_modules (helper.py:29–37) in our key naming
 DEFAULT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# a latent-attention model has no k or v projection: its targets are q, kv_a,
+# kv_b, o, and the gated MLP that is a dense layer's MLP or an expert layer's
+# shared expert. The router and the routed experts are frozen.
+LATENT_TARGETS = ("wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down")
 
 
 def lora_scale(rank: int, alpha: float) -> float:
@@ -41,7 +48,7 @@ def init_lora_params(
     rng: jax.Array,
     cfg: ModelConfig,
     rank: int,
-    targets: Sequence[str] = DEFAULT_TARGETS,
+    targets: Sequence[str] | None = None,
     dtype=jnp.float32,
 ) -> Params:
     """A ~ N(0, 1/r) (std r^-1/2), B = 0 — output delta starts at 0 and the
@@ -50,7 +57,12 @@ def init_lora_params(
     A model whose layers differ in kind (``cfg.hybrid``) gets one set of
     factors per kind, ``{"layers": {kind: {target: {"a", "b"}}}}``, stacked
     like that kind's base weights: a lightning layer's k and v project to all
-    its heads, a sparse layer's to its few KV heads."""
+    its heads, a sparse layer's to its few KV heads. ``targets`` left out are
+    ``DEFAULT_TARGETS``, or ``LATENT_TARGETS`` for a latent-attention model
+    (whose expert layers' w_gate / w_up / w_down are the SHARED expert's)."""
+    if targets is None:
+        targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
+
     def factors(rng, n_layers: int, dims: dict[str, int]) -> Params:
         layers: Params = {}
         for key, target in zip(jax.random.split(rng, len(targets)), targets):
@@ -66,12 +78,22 @@ def init_lora_params(
         attr: getattr(cfg, attr)
         for attr in ("hidden_size", "intermediate_size", "q_dim", "kv_dim")
     }
+    dims["o_dim"] = cfg.q_dim  # what wo reads
     if not cfg.hybrid:
         return {"layers": factors(rng, cfg.num_layers, dims)}
-    per_kind = {
-        "sparse": dims,
-        "lightning": {**dims, "q_dim": cfg.lightning_dim, "kv_dim": cfg.lightning_dim},
-    }
+    if cfg.latent:
+        dims.update(
+            kv_lora_rank=cfg.kv_lora_rank, latent_dim=cfg.latent_dim,
+            kvb_dim=cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+            o_dim=cfg.num_heads * cfg.v_head_dim,
+        )
+        per_kind = {
+            "latent": dims,
+            "latent_moe": {**dims, "intermediate_size": cfg.shared_expert_size},
+        }
+    else:
+        lightning = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.lightning_dim)
+        per_kind = {"sparse": dims, "lightning": {**dims, **lightning}}
     kinds = [k for k in per_kind if cfg.kind_count(k)]
     return {"layers": {
         kind: factors(key, cfg.kind_count(kind), per_kind[kind])

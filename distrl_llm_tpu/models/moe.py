@@ -1,0 +1,180 @@
+"""Routed experts (DeepSeek-V3's block: sigmoid router with a correction bias,
+top-k without groups, a shared expert beside the routed ones).
+
+``h`` is the layer's normed input, ``[T, D]`` (any leading shape is flattened)::
+
+    s   = sigmoid(h W_g)                         float32, [T, E]
+    idx = top-k of (s + b)                       b: e_score_correction_bias
+    w   = s[idx] / (sum s[idx] + 1e-20) * routed_scaling_factor
+    y   = sum_k w_k E_idx_k(h)                   E_e(h) = W_down_e(silu(W_gate_e h) * (W_up_e h))
+
+The shared expert is a plain gated MLP and goes through the decoder's own
+``_mlp_half`` (``models/hybrid.py``); this module is the routed part.
+
+**Dropless.** There is no capacity: every (token, expert) pair is computed at
+any imbalance. Plain XLA throughout, in two forms chosen by the number of
+tokens alone (``DENSE_MAX_TOKENS``), each timed on the v5e at the published
+widths before it was kept (PERF.md, PR 33):
+
+* **grouped** (a prefill segment of 4,096 tokens, the learner with its
+  backward): the pairs are sorted by expert and laid out so that every block
+  of ``block_rows`` rows belongs to ONE expert (a group is padded to whole
+  blocks: at most one block of padding an expert, whatever the imbalance); a
+  ``lax.scan`` over the blocks multiplies each by its expert's three matrices,
+  picked from the stack by a dynamic index that fuses into the products; the
+  results go back by one gather and a weighted sum over k. No scatter-add: the
+  combine is deterministic and its transpose cheap. ``lax.ragged_dot`` (XLA's
+  native grouped kernel) read the same at 4,096 tokens and 1.6x slower at 64,
+  carries no scope name into the trace, and copies a layer sliced from the
+  stack for its custom call; it was not kept.
+* **dense** (a decode step: 64 tokens x 6 choices touch all 64 experts): every
+  expert held runs on every token, one batched product an expert matrix, and a
+  combine matrix ``[T, E]``, zero outside the chosen k, weights the results.
+  The step must read every expert once in any case; this form reads them once
+  and nothing else (90% of the experts' bandwidth roofline where grouped
+  blocks of 64 rows read 62%), and its extra arithmetic is free under the
+  transfer up to about a hundred tokens.
+
+**Experts held.** ``held`` names the experts whose weights this program holds
+(``experts`` is stacked over them, in that order), the layer a chip of an
+expert-parallel deployment runs: the router scores ALL experts and chooses
+among all; pairs whose expert is elsewhere add nothing here. Over disjoint
+shares the results sum to the whole layer's (``tests/test_latent_moe.py``).
+``None`` holds all.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from distrl_llm_tpu import telemetry
+from distrl_llm_tpu.models.configs import ModelConfig
+
+#: added to the sum of the chosen scores before dividing (the published code's)
+NORM_EPS = 1e-20
+#: tokens up to which every expert runs on every token (module docstring)
+DENSE_MAX_TOKENS = 128
+
+
+def route(h: jax.Array, router: jax.Array, bias: jax.Array, cfg: ModelConfig):
+    """``h [T, D]`` -> ``(idx [T, k] int32, w [T, k] float32)``. Scores, the
+    choice and the weights are float32 at full precision: with bf16 scores two
+    experts tie often, and the choice is not continuous."""
+    with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
+        scores = jax.nn.sigmoid(jnp.dot(
+            h.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        ))
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                               cfg.experts_per_token)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.norm_topk_prob:
+            w = w / (w.sum(axis=-1, keepdims=True) + NORM_EPS)
+        return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def block_rows(pairs: int, groups: int) -> int:
+    """Rows of one block of the grouped form: the power of two at or above the
+    mean rows a group, within [64, 256] (timed at 384 and 24,576 pairs over 64
+    groups: 64 and 256 were the fastest of 16-128 and 128-512)."""
+    mean = max(pairs // max(groups, 1), 1)
+    return min(256, max(64, 1 << (mean - 1).bit_length()))
+
+
+def _gated(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def _local_ids(idx, n: int, n_experts: int, held):
+    """Each pair's place in the stack of experts held, or ``n``: not here."""
+    if held is None:
+        return idx
+    place = jnp.full((n_experts,), n, jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(jnp.arange(n, dtype=jnp.int32))
+    return place[idx]
+
+
+def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
+                   *, n_experts: int, held=None, alive=None, layer=None):
+    """``sum_k w_k E_idx_k(h)`` over the experts held. ``h [T, D]``, ``idx`` /
+    ``w [T, k]``; ``experts = {"gate", "up" [n, D, F], "down" [n, F, D]}``.
+    Returns ``(y [T, D], load [n] int32)``: ``load`` counts the pairs each
+    held expert computed (of ``alive`` tokens, if given).
+
+    With ``layer`` the stacks are ALL layers' ``[L, n, ...]``: the grouped
+    form indexes (layer, expert) in one step, so no layer's experts are
+    sliced out of the stack first."""
+    t, k = idx.shape
+    n = experts["gate"].shape[-3]
+    with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+        local = _local_ids(idx, n, n_experts, held)  # [T, k]
+        counted = jnp.ones((t,), jnp.int32) if alive is None else alive.astype(jnp.int32)
+        load = jnp.zeros((n + 1,), jnp.int32).at[local.reshape(-1)].add(
+            jnp.repeat(counted, k))[:n]
+    if t <= DENSE_MAX_TOKENS:
+        if layer is not None:
+            experts = {name: x[layer] for name, x in experts.items()}
+        with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+            comb = jnp.zeros((t, n + 1), jnp.float32).at[
+                jnp.arange(t)[:, None], local].set(w)[:, :n]
+        with jax.named_scope(telemetry.MODEL_MOE_EXPERTS):
+            act = jax.nn.silu(jnp.einsum("td,edf->etf", h, experts["gate"])) * (
+                jnp.einsum("td,edf->etf", h, experts["up"]))
+            y = jnp.einsum("etf,efd->etd", act, experts["down"])
+        with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+            y = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32)).astype(h.dtype)
+        return y, load
+    groups = n + (held is not None)  # the pairs of experts held elsewhere: one more
+    rows_a, bm = t * k, block_rows(t * k, groups)
+    blocks = rows_a // bm + groups
+    with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+        flat = local.reshape(rows_a)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1)
+        padded = -(-sizes // bm) * bm
+        ends = jnp.cumsum(padded)
+        # the padded row of each pair: its group's first block, then its rank there
+        rank = jnp.zeros((rows_a,), jnp.int32).at[order].set(
+            jnp.arange(rows_a, dtype=jnp.int32) - (jnp.cumsum(sizes) - sizes)[flat[order]])
+        at = (ends - padded)[flat] + rank  # [T*k]
+        src = jnp.full((blocks * bm,), t, jnp.int32).at[at].set(
+            jnp.arange(rows_a, dtype=jnp.int32) // k)
+        rows = jnp.concatenate([h, jnp.zeros((1, h.shape[1]), h.dtype)])[src]
+        owner = jnp.minimum(
+            jnp.searchsorted(ends, jnp.arange(blocks) * bm, side="right"), n - 1)
+        if layer is not None:
+            owner = owner + layer * n
+    with jax.named_scope(telemetry.MODEL_MOE_EXPERTS):
+        stacks = [experts[name].reshape(-1, *experts[name].shape[-2:])
+                  for name in ("gate", "up", "down")]
+
+        def one(_, block):
+            x, e = block
+            return None, _gated(x, *(stack[e] for stack in stacks))
+
+        _, y = jax.lax.scan(one, None, (rows.reshape(blocks, bm, -1), owner))
+    with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+        y = y.reshape(blocks * bm, -1)[at].reshape(t, k, -1)
+        y = jnp.where((local < n)[..., None], y, 0)  # held elsewhere: nothing here
+        y = jnp.einsum("tk,tkd->td", w, y.astype(jnp.float32)).astype(h.dtype)
+    return y, load
+
+
+def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None):
+    """The routed part of an expert layer on ``h [..., D]`` (normed). Returns
+    ``(y like h, stats [2] int32)``: pairs computed, and the fullest expert's.
+    ``p["experts_layer"]``, if there, says that ``p["experts_*"]`` are every
+    layer's and which is this one (``routed_experts``)."""
+    lead = h.shape[:-1]
+    flat = h.reshape(-1, h.shape[-1])
+    idx, w = route(flat, p["router"], p["e_score_bias"], cfg)
+    y, load = routed_experts(
+        flat, idx, w,
+        {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]},
+        n_experts=cfg.n_routed_experts, held=held, layer=p.get("experts_layer"),
+        # ``alive`` is a flag a ROW: each of the row's tokens takes it
+        alive=None if alive is None else jnp.repeat(
+            alive, flat.shape[0] // alive.shape[0]),
+    )
+    return y.reshape(*lead, -1), jnp.stack([load.sum(), load.max()])

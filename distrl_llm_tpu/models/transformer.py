@@ -64,6 +64,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 # stable per-target stream ids so each projection's dropout mask differs
 _TARGET_STREAM = {
     "wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5, "w_down": 6,
+    "wkv_a": 7, "wkv_b": 8,  # latent attention (models/hybrid.py)
 }
 
 
@@ -371,6 +372,14 @@ def _attend(
 _MLP_KEYS = frozenset(
     ("mlp_norm", "w_gate", "w_up", "w_down", "b_gate", "b_up", "b_down")
 )
+# an expert layer's own leaves (models/moe.py); any other key is attention's
+_SLICE_SCOPES = {
+    **dict.fromkeys(_MLP_KEYS, telemetry.MODEL_MLP),
+    "router": telemetry.MODEL_MOE_ROUTER,
+    "e_score_bias": telemetry.MODEL_MOE_ROUTER,
+    **dict.fromkeys(("experts_gate", "experts_up", "experts_down"),
+                    telemetry.MODEL_MOE_EXPERTS),
+}
 
 
 def _slice_layer(stacked: Params, i: int) -> Params:
@@ -382,8 +391,7 @@ def _slice_layer(stacked: Params, i: int) -> Params:
         return jax.tree_util.tree_map(lambda w: w[i], stacked)
     out = {}
     for key in sorted(stacked):
-        scope = telemetry.MODEL_MLP if key in _MLP_KEYS else telemetry.MODEL_ATTN_PROJ
-        with jax.named_scope(scope):
+        with jax.named_scope(_SLICE_SCOPES.get(key, telemetry.MODEL_ATTN_PROJ)):
             out[key] = jax.tree_util.tree_map(lambda w: w[i], stacked[key])
     return out
 

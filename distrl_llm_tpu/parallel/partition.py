@@ -19,6 +19,11 @@ Layout conventions (models/transformer.py):
   hybrid:      a model whose layers differ in kind keeps one stack per kind,
                layers/<kind>/w* (models/hybrid.py); the rules read a leaf's
                own name and its target's, so the extra level changes nothing.
+  latent/moe:  a latent-attention model's wkv_a / wkv_b / kv_a_norm and an
+               expert layer's router, e_score_bias and experts_{gate,up,down}
+               [L, E, in, out] are REPLICATED (``_REPLICATED``): one chip holds
+               every expert; sharding E over chips, and the all-to-all that
+               needs, come with a four-chip cell.
 """
 
 from __future__ import annotations
@@ -35,6 +40,9 @@ Params = dict[str, Any]
 _COL = {"wq", "wk", "wv", "wz", "w_gate", "w_up"}
 # layer weights whose IN dim is tp-sharded (row parallel)
 _ROW = {"wo", "w_down"}
+# a latent-attention / expert layer's own leaves: whole on every chip
+_REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
+               "experts_up", "experts_down"}
 
 
 def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:
@@ -49,7 +57,8 @@ def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:
         return P("tp", "fsdp")
     if name == "lm_head":
         return P("fsdp", "tp")
-    if name.endswith("norm"):  # final/attn/mlp, and a hybrid layer's q/k/o norms
+    if name.endswith("norm") or name in _REPLICATED:
+        # final/attn/mlp norms, a hybrid layer's q/k/o/kv_a norms; _REPLICATED
         return P(*([None] * ndim))
     if name in _COL:
         return P(None, "fsdp", "tp")

@@ -96,6 +96,14 @@ MODEL_HEAD = "model/head"
 MODEL_LINEAR_ATTN = "model/linear_attn"
 MODEL_SPARSE_SELECT = "model/sparse_select"
 MODEL_SPARSE_ATTN = "model/sparse_attn"
+# routed experts (models/moe.py): scores, top-k and weights; the sort of
+# (token, expert) pairs, the gather of rows and the combine; the grouped
+# products. Absorbed latent attention in decode (ops/latent_attention.py); the
+# expanded form is ``model/attn_core``
+MODEL_MOE_ROUTER = "model/moe_router"
+MODEL_MOE_DISPATCH = "model/moe_dispatch"
+MODEL_MOE_EXPERTS = "model/moe_experts"
+MODEL_LATENT_ATTN = "model/latent_attn"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -127,6 +135,7 @@ SCOPE_NAMES = (
     LEARNER_LOSS, LEARNER_LOSS_LOGPROB, LEARNER_GRAD_ACCUM, LEARNER_OPTIMIZER,
     LEARNER_OPTIMIZER_CODEC,
     MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
+    MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
 )
 
 

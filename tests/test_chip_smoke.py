@@ -36,6 +36,23 @@ def compiles():
     return chip_smoke.CompileLog()
 
 
+def test_the_latent_and_expert_checks_hold_at_tiny_size():
+    """The two checks the kernels phase runs at Kimi-VL-A3B's widths, here at
+    the test preset's: absorbed against expanded attention, and an expert layer
+    against every pair computed in float32, no pair dropped."""
+    import jax
+
+    key = jax.random.PRNGKey(0)
+    att = chip_smoke._latent_attention_case(
+        key, rows=3, heads=4, nope=16, rope=8, v_dim=16, rank=32, context=40)
+    assert att["max_abs_err"] < 5e-2 and att["context"] == 40
+    for tokens, form in ((24, "dense"), (160, "grouped")):
+        layer = chip_smoke._expert_layer_case(
+            key, tokens=tokens, hidden=64, width=32, experts=8, per_token=2)
+        assert layer["pairs"] == 2 * tokens and layer["form"] == form
+        assert layer["fullest_expert"] >= tokens // 4
+
+
 @pytest.mark.parametrize("engine_impl,steps", [("paged", 3), ("dense", 1)])
 def test_trainer_phase_runs_end_to_end_at_tiny_size(compiles, engine_impl, steps):
     """The assembly the chip runs at Qwen2.5-0.5B width, here at TINY: every

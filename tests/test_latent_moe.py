@@ -1,0 +1,662 @@
+"""A latent-attention model with routed experts (Kimi-VL-A3B's language model,
+``deepseek_v3``) against its plain reference, ``perfbench/reference_latent_moe.py``,
+at a small size on the CPU: the ``tiny-latent-moe`` preset (hidden 64, a dense
+first layer, then 8 experts, 2 a token, 1 shared; ``kv_lora_rank`` 32, nope 16,
+rope 8, v 16). Float32 throughout, seeded weights with every term alive.
+
+The learner's update through ``trainer.train_step`` and the rollout through
+``perfbench/run.py`` are held by ``tests/perfbench/test_perfbench_rehearsal_latent_moe.py``.
+"""
+
+import os
+import sys
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
+from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
+from distrl_llm_tpu.models import moe  # noqa: E402
+from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
+from perfbench import reference_latent_moe as ref  # noqa: E402
+
+CFG = PRESETS["tiny-latent-moe"]
+LORA_SCALE = 2.0
+
+
+@pytest.fixture(scope="module", autouse=True)
+def exact_matmuls():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(autouse=True)
+def both_expert_forms(monkeypatch):
+    """Eight tokens or fewer take the dense form (a decode step of 8 rows), more
+    the grouped one (a prefill segment, the learner's rows), as 128 divides
+    the 64-row decode step from the 4,096-token segment at the real size."""
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    """Seeded weights with every term alive: norms off 1, a correction bias
+    that changes the choice, projections large enough that a dropped term
+    moves the logits, an adapter (kv_b's too) whose b is not zero."""
+    def base(path, x):
+        name = str(path[-1].key)
+        key = jax.random.PRNGKey(sum(map(ord, str(path))) % 9973)
+        if name.endswith("norm"):
+            return 1.0 + 0.3 * jax.random.normal(key, x.shape)
+        if name == "e_score_bias":
+            return 0.05 * jax.random.normal(key, x.shape)
+        return 3.0 * x
+
+    params = jax.tree_util.tree_map_with_path(
+        base, init_params(jax.random.PRNGKey(0), CFG))
+    lora = jax.tree_util.tree_map_with_path(
+        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
+        if str(path[-1].key) == "b" else x,
+        init_lora_params(jax.random.PRNGKey(1), CFG, 4),
+    )
+    return params, lora
+
+
+def reference_logprobs(params, lora, ids, mask):
+    return np.asarray(ref.next_token_logprobs(
+        params, CFG, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
+        lora_scale=LORA_SCALE))
+
+
+def moe_layer(params, j=0):
+    return jax.tree_util.tree_map(lambda w: w[j], params["layers"]["latent_moe"])
+
+
+# ------------------------------------------------------------- the forward
+
+
+def test_forward_equals_the_reference_with_padding_on_both_sides(weights):
+    params, lora = weights
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, 40), 1, 256))
+    mask = np.ones((3, 40), np.int32)
+    mask[0, :7] = 0
+    mask[1, 33:] = 0
+    logits, _ = forward(params, CFG, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
+                        lora=lora, lora_scale=LORA_SCALE)
+    got = jnp.take_along_axis(
+        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0]
+    both = (mask[:, 1:] * mask[:, :-1]) > 0
+    want = reference_logprobs(params, lora, ids, mask)
+    assert np.abs(np.asarray(got) - want)[both].max() < 2e-5
+
+
+@pytest.mark.parametrize("control", [
+    "top1", "no_shared", "no_scaling", "no_bias", "no_k_rope", "no_kvb_adapter"])
+def test_the_forward_can_tell_each_mechanism(weights, control, monkeypatch):
+    """Each term the chip's controls drop moves this file's agreement by far
+    more than its tolerance: a check that passes with one missing is no check."""
+    from distrl_llm_tpu.models import hybrid
+
+    params, lora = weights
+    cfg = CFG
+    if control == "top1":
+        cfg = ModelConfig(**{**CFG.__dict__, "experts_per_token": 1})
+    elif control == "no_scaling":
+        cfg = ModelConfig(**{**CFG.__dict__, "routed_scaling_factor": 1.0})
+    elif control == "no_shared":
+        stack = params["layers"]["latent_moe"]
+        params = {**params, "layers": {**params["layers"], "latent_moe": {
+            k: v for k, v in stack.items() if k not in ("w_gate", "w_up", "w_down")}}}
+        lora = None
+    elif control == "no_bias":
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, x: jnp.zeros_like(x) if str(path[-1].key) == "e_score_bias" else x,
+            params)
+    elif control == "no_k_rope":
+        rope = hybrid.rope_interleaved
+        monkeypatch.setattr(
+            hybrid, "rope_interleaved",
+            lambda x, cos, sin: rope(x, cos, sin) if x.ndim == 4 else jnp.concatenate(
+                [x[..., 0::2], x[..., 1::2]], -1))
+    else:
+        lora = {"layers": {kind: {k: v for k, v in stack.items() if k != "wkv_b"}
+                           for kind, stack in lora["layers"].items()}}
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (2, 40), 1, 256))
+    mask = np.ones((2, 40), np.int32)
+    logits, _ = forward(params, cfg, jnp.asarray(ids), lora=lora, lora_scale=LORA_SCALE)
+    got = jnp.take_along_axis(
+        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0]
+    want = reference_logprobs(*weights, ids, mask)
+    assert np.abs(np.asarray(got) - want).mean() > 50 * 2e-5
+
+
+# ------------------------------------------------------------ the attention
+
+
+def test_rope_pairs_are_the_interleaved_ones():
+    """(x[2i], x[2i+1]) rotate together: multiplication by e^{i pos w_i} of the
+    complex number x[2i] + i x[2i+1]."""
+    from distrl_llm_tpu.models.transformer import rope_cos_sin
+    from distrl_llm_tpu.ops.latent_attention import rope_interleaved
+
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(0), (1, 5, 8)))
+    pos = jnp.arange(5)[None, :]
+    cos, sin = rope_cos_sin(pos, 8, 10000.0)
+    got = np.asarray(rope_interleaved(jnp.asarray(x), cos, sin))
+    freq = 1.0 / (10000.0 ** (np.arange(0, 8, 2) / 8))
+    z = (x[..., 0::2] + 1j * x[..., 1::2]) * np.exp(1j * np.arange(5)[None, :, None] * freq)
+    np.testing.assert_allclose(got[..., :4], z.real, atol=1e-5)
+    np.testing.assert_allclose(got[..., 4:], z.imag, atol=1e-5)
+
+
+@pytest.mark.parametrize("blocks", [1, 3])
+def test_absorbed_equals_expanded_with_an_adapter_on_kv_b(blocks):
+    """The same function of the cache: one query over the latent rows
+    (absorbed, folded in ``blocks`` pieces) against K and V rebuilt per head
+    (expanded), with W_kvb carrying a non-zero adapter."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    heads, nope, rope, v_dim, rank, sk = 4, 16, 8, 16, 32, 24
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    w = jax.random.normal(keys[0], (rank, heads * (nope + v_dim))) * 0.3
+    w = w + 0.5 * (jax.random.normal(keys[1], (rank, 4)) @ jax.random.normal(keys[2], (4, w.shape[1])))
+    latent = jax.random.normal(keys[3], (2, sk, rank + rope))
+    q = jax.random.normal(keys[4], (2, heads, nope + rope))
+    seen = jnp.arange(sk)[None, :] < jnp.asarray([sk, 17])[:, None]
+    kv = (latent[..., :rank] @ w).reshape(2, sk, heads, nope + v_dim)
+    want = la.expanded_finish(la.expanded_attention(
+        q[:, None, :, :nope], q[:, None, :, nope:], kv, latent[..., rank:],
+        seen[:, None, :]), jnp.float32)[:, 0]
+    w_k, w_v = la.split_kvb(w, heads, nope, v_dim)
+    q_row = la.absorbed_query(q[..., :nope], q[..., nope:], w_k)
+    carry = None
+    for part in range(blocks):
+        cut = slice(part * sk // blocks, (part + 1) * sk // blocks)
+        carry = la.absorbed_attention(
+            q_row, latent[:, cut], seen[:, cut], (nope + rope) ** -0.5, carry)
+    np.testing.assert_allclose(
+        la.absorbed_output(carry, w_v, jnp.float32), want, atol=2e-5)
+
+
+# -------------------------------------------------------------- the experts
+
+
+def test_every_tokens_chosen_experts_are_the_references(weights):
+    params, _ = weights
+    layer = moe_layer(params)
+    h = jax.random.normal(jax.random.PRNGKey(4), (64, CFG.hidden_size))
+    idx, w = moe.route(h, layer["router"], layer["e_score_bias"], CFG)
+    comb = np.asarray(ref.combine_matrix(h, layer, CFG))
+    chosen = np.zeros_like(comb, bool)
+    np.put_along_axis(chosen, np.asarray(idx), True, axis=-1)
+    assert (chosen == (comb != 0)).all() and chosen.sum(-1).tolist() == [2] * 64
+    np.testing.assert_allclose(np.take_along_axis(comb, np.asarray(idx), -1), w, rtol=1e-6)
+    # the bias is in the choice: without it some token chooses otherwise
+    plain, _ = moe.route(h, layer["router"], 0 * layer["e_score_bias"], CFG)
+    assert (np.sort(plain, -1) != np.sort(idx, -1)).any()
+
+
+def dense_experts(h, idx, w, experts):
+    """Every pair, one at a time."""
+    out = np.zeros(h.shape, np.float64)
+    for t in range(h.shape[0]):
+        for e, weight in zip(np.asarray(idx[t]), np.asarray(w[t])):
+            g = jax.nn.silu(h[t] @ experts["gate"][e]) * (h[t] @ experts["up"][e])
+            out[t] += weight * np.asarray(g @ experts["down"][e])
+    return out
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+@pytest.mark.parametrize("case", ["all_on_one_pair", "one_gets_none", "drawn"])
+def test_no_token_is_dropped_at_any_imbalance(weights, case, form, monkeypatch):
+    """Dropless, in either form: 48 tokens that ALL choose the same two experts
+    (six of the eight get none), a batch in which one expert gets none, and a
+    drawn one lose nothing against the pairs computed one at a time."""
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 48)
+    params, _ = weights
+    layer = moe_layer(params)
+    experts = {k: layer[f"experts_{k}"] for k in ("gate", "up", "down")}
+    h = jax.random.normal(jax.random.PRNGKey(6), (48, CFG.hidden_size))
+    rng = np.random.default_rng(0)
+    if case == "all_on_one_pair":
+        idx = np.tile([[5, 2]], (48, 1))
+    elif case == "one_gets_none":
+        idx = np.stack([rng.permutation([0, 1, 2, 4, 5, 6, 7])[:2] for _ in range(48)])
+    else:
+        idx = np.stack([rng.permutation(8)[:2] for _ in range(48)])
+    w = jnp.asarray(rng.uniform(0.2, 1.5, (48, 2)), jnp.float32)
+    y, load = moe.routed_experts(
+        h, jnp.asarray(idx, jnp.int32), w, experts, n_experts=8)
+    np.testing.assert_allclose(y, dense_experts(h, idx, w, experts), atol=2e-5)
+    assert load.tolist() == np.bincount(idx.reshape(-1), minlength=8).tolist()
+    assert int(load.sum()) == 96  # every pair computed
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+def test_the_shares_of_an_expert_parallel_layer_sum_to_the_whole(weights, form, monkeypatch):
+    """The layer told which experts it holds, over 4 disjoint shares of the 8:
+    the router scores all 8 and chooses among all; each share computes its own
+    experts' part; with the shared expert counted ONCE the parts sum to the
+    uncut reference's layer."""
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 40)
+    params, _ = weights
+    layer = moe_layer(params, 1)
+    h = jax.random.normal(jax.random.PRNGKey(7), (40, CFG.hidden_size))
+    shared = ref._gated(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+    want = np.asarray(ref._experts(h, layer, CFG) + shared)
+    total, pairs = np.asarray(shared), 0
+    for held in ([0, 1], [2, 3], [6, 7], [4, 5]):
+        part = {**layer, **{f"experts_{k}": layer[f"experts_{k}"][jnp.asarray(held)]
+                            for k in ("gate", "up", "down")}}
+        y, stats = moe.moe_half(h, part, CFG, held=held)
+        total = total + np.asarray(y)
+        pairs += int(stats[0])
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert pairs == 40 * 2
+    whole, _ = moe.moe_half(h, layer, CFG)
+    np.testing.assert_allclose(np.asarray(whole + shared), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+def test_a_layer_read_from_the_whole_stack_equals_its_slice(weights, form, monkeypatch):
+    """The cache modes hand the experts' products every layer's stack and the
+    layer's index: (layer, expert) is one index, no layer is sliced out first."""
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 24)
+    params, _ = weights
+    stack = params["layers"]["latent_moe"]
+    h = jax.random.normal(jax.random.PRNGKey(8), (24, CFG.hidden_size))
+    want, _ = moe.moe_half(h, moe_layer(params, 1), CFG)
+    whole = {**moe_layer(params, 1), "experts_layer": 1,
+             **{k: stack[k] for k in stack if k.startswith("experts_")}}
+    got, _ = moe.moe_half(h, whole, CFG)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+# -------------------------------------------------------------- the learner
+
+
+def test_the_learners_loss_and_adapter_gradient_are_the_references(weights):
+    """No cache, remat, chunked cross-entropy: the policy-gradient loss over
+    the answers and its gradient in every adapter factor (kv_a's, kv_b's, the
+    shared expert's) against plain reverse mode through the reference."""
+    from distrl_llm_tpu.learner.losses import answer_logprobs, pg_loss
+
+    params, lora = weights
+    rng = np.random.default_rng(1)
+    prompt = rng.integers(1, 256, (4, 12)).astype(np.int32)
+    pmask = np.ones((4, 12), np.int32)
+    pmask[0, :5] = 0
+    answer = rng.integers(1, 256, (4, 20)).astype(np.int32)
+    amask = np.ones((4, 20), np.int32)
+    amask[2, 14:] = 0
+    coeffs = jnp.asarray([0.7, -1.1, 0.4, 1.3])
+
+    def loss(lo):
+        logp = answer_logprobs(
+            params, CFG, jnp.asarray(prompt), jnp.asarray(pmask), jnp.asarray(answer),
+            jnp.asarray(amask), lora=lo, lora_scale=LORA_SCALE, remat=True, logit_chunk=8)
+        return pg_loss(logp, jnp.asarray(amask), coeffs)
+
+    got_loss, got = jax.value_and_grad(loss)(lora)
+    ids = np.concatenate([prompt, answer], 1)
+    mask = np.concatenate([pmask, amask], 1)
+    scored = np.concatenate([np.zeros_like(pmask), amask], 1)
+    want_loss, want = ref.pg_loss_and_lora_grad(
+        params, CFG, lora, LORA_SCALE, jnp.asarray(ids), jnp.asarray(mask),
+        jnp.asarray(scored), coeffs)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(w).max()) > 0, path
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-7,
+                                   err_msg=str(path))
+
+
+# -------------------------------------------------------------- the engine
+
+
+def make_engine(scheduler, slots, **kw):
+    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
+
+    kw.setdefault("cache_dtype", jnp.float32)
+    kw.setdefault("page_size", 8)
+    return PagedGenerationEngine(
+        CFG, max_prompt_tokens=64, max_new_tokens=24, eos_token_ids=[-1],
+        pad_token_id=0, lora_scale=LORA_SCALE,
+        scheduler=scheduler, max_concurrent_rows=slots, capture_logprobs=True,
+        autotune=False, **kw)
+
+
+def prompts(lengths, width=64, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = np.zeros((len(lengths), width), np.int32)
+    mask = np.zeros((len(lengths), width), np.int32)
+    for r, n in enumerate(lengths):
+        ids[r, width - n:] = rng.integers(1, 256, n)
+        mask[r, width - n:] = 1
+    return ids, mask
+
+
+@pytest.fixture
+def small_pieces(monkeypatch):
+    """Prefill in segments of 16 tokens and decode attention over 3 pages and
+    4 rows at a time, so that 40-57-token prompts in pages of 8 cross every
+    boundary the 21k-token cell crosses."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import hybrid
+
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
+    monkeypatch.setattr(hybrid, "LATENT_DECODE_PAGES", 3)
+    monkeypatch.setattr(hybrid, "LATENT_DECODE_ROWS", 4)
+    assert moe.DENSE_MAX_TOKENS == 8  # 8 decode rows dense, 32-token segments grouped
+
+
+def worst_difference(params, lora, ids, mask, result):
+    worst = 0.0
+    for b in range(ids.shape[0]):
+        prompt = ids[b][mask[b] > 0]
+        for j in range(result.tokens.shape[1]):
+            row = np.concatenate([prompt, result.tokens[b, j]])
+            want = reference_logprobs(
+                params, lora, row[None], np.ones((1, len(row)), np.int32))[0]
+            worst = max(worst, np.abs(
+                result.logprobs[b, j] - want[len(prompt) - 1:]).max())
+    return worst
+
+
+@pytest.mark.parametrize("scheduler,slots", [
+    ("refill", 4),  # 8 rows through 4 slots: a freed slot aliases another prompt
+    ("refill", 8),  # every candidate admitted at once
+    ("waves", 0),   # prefill, fan-out, lockstep
+])
+def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
+                                                      small_pieces):
+    """Prefill in segments over earlier segments' latent pages (expanded), the
+    fan-out aliasing the prompt's pages, then absorbed decode through the
+    cache: the engine's own captured log-probability of every token it sampled
+    is the reference's full forward's."""
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    ids, mask = prompts((40, 57))
+    before = telemetry.observe_snapshot()["counters"]
+    result = make_engine(scheduler, slots).generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
+        jax.random.PRNGKey(3))
+    assert (result.lengths == 24).all()
+    assert result.steps_dispatched >= 24 * (2 if slots == 4 else 1)
+    assert result.alive_slot_steps == 8 * 24
+    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    after = telemetry.observe_snapshot()["counters"]
+    moved = lambda name: after[name] - before.get(name, 0)
+    # 2 expert layers x 8 rows x 24 steps x 2 experts a token, live slots only
+    assert moved("engine/moe_assignments") == 2 * 8 * 24 * 2
+    # the fullest of 8 experts holds at least the mean, at most every pair's half
+    assert 2 * 24 * 2 <= moved("engine/moe_max_expert_load") <= 2 * 8 * 24
+
+
+def test_this_files_agreement_can_tell_lower_precision_pages(weights, small_pieces):
+    """bf16 latent pages leave the 2e-5 agreement by a wide margin: it is what
+    holds the pages' precision, whatever the chip's check can tell."""
+    params, lora = weights
+    ids, mask = prompts((40, 57))
+    result = make_engine("waves", 0, cache_dtype=jnp.bfloat16).generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
+        jax.random.PRNGKey(3))
+    assert worst_difference(params, lora, ids, mask, result) > 20 * 2e-5
+
+
+def test_sixteen_candidates_equal_sixteen_single_rows(weights, small_pieces):
+    """A group's fan-out aliases one prompt's latent pages: at temperature 0
+    its 16 candidates are what 16 rows of the same prompt give, one at a time."""
+    params, lora = weights
+    ids, mask = prompts((50,))
+    greedy = dict(temperature=0.0, top_p=1.0, max_tokens=24)
+    group = make_engine("refill", 16).generate(
+        params, lora, ids, mask, SamplingConfig(n=16, **greedy), jax.random.PRNGKey(0))
+    single = make_engine("refill", 16).generate(
+        params, lora, np.repeat(ids, 16, 0), np.repeat(mask, 16, 0),
+        SamplingConfig(n=1, **greedy), jax.random.PRNGKey(0))
+    np.testing.assert_array_equal(group.tokens[0], single.tokens[:, 0])
+    np.testing.assert_allclose(group.logprobs[0], single.logprobs[:, 0], atol=1e-5)
+
+
+def test_the_pool_is_one_latent_array_a_layer_and_its_budget():
+    from distrl_llm_tpu.engine.budget import page_bytes
+    from distrl_llm_tpu.engine.paged_engine import _copy_pages, _grow_pool
+
+    assert CFG.page_pool_shape(10, 8) == (10, 8, 128) and CFG.paged_layers == 3
+    assert (CFG.latent_dim, CFG.latent_row) == (40, 128)  # whole 128-lane tiles
+    assert page_bytes(CFG, 8) == 8 * 128 * 2 * 3  # no V, no kv-head factor
+    published = ModelConfig.from_hf_config(hf_config())
+    assert (published.latent_dim, published.latent_row) == (576, 640)
+    assert page_bytes(published, 128) == 128 * 640 * 2 * 7
+    pool = jnp.arange(4 * 2 * 3, dtype=jnp.float32).reshape(4, 2, 3)
+    grown = _grow_pool(pool, 2)
+    assert grown.shape == (6, 2, 3) and (grown[:4] == pool).all() and not grown[4:].any()
+    copied = _copy_pages(grown, jnp.asarray([0, 1]), jnp.asarray([4, 5]),
+                         keep_mask=jnp.asarray([True, False]))
+    assert (copied[4] == pool[0]).all() and not copied[5].any()
+
+
+# ------------------------------------------------------------ the refusals
+
+
+def _paged(**kw):
+    return lambda: make_engine(kw.pop("scheduler", "refill"), 4, **kw)
+
+
+def _dense():
+    from distrl_llm_tpu.engine.engine import GenerationEngine
+
+    return GenerationEngine(
+        CFG, max_prompt_tokens=16, max_new_tokens=8, eos_token_ids=[1], pad_token_id=0)
+
+
+def _sharded():
+    from distrl_llm_tpu.engine.sharded_paged import ShardedPagedEngine
+
+    return ShardedPagedEngine(
+        CFG, mesh=None, max_prompt_tokens=16, max_new_tokens=8, eos_token_ids=[1],
+        pad_token_id=0)
+
+
+@pytest.mark.parametrize("build,what", [
+    (_dense, "dense engine"),
+    (_sharded, "dp-sharded"),
+    (_paged(spec_draft=2), "spec_draft"),
+    (_paged(kv_quant="int8"), "int8"),
+    (_paged(continuous_admission=True, prefix_cache=True), "prefix_sharing"),
+    (_paged(prefix_sharing=True), "prefix_sharing"),
+    (_paged(continuous_admission=True), "continuous_admission"),
+    (_paged(max_kv_pages=64), "re-prefill"),
+], ids=["dense", "sharded", "speculation", "int8_kv", "radix_cache", "prefix_sharing",
+        "continuous_admission", "preemption"])
+def test_what_holds_k_and_v_of_one_kind_refuses_the_model_by_name(build, what):
+    with pytest.raises(ValueError) as err:
+        build()
+    assert what in str(err.value)
+    assert "latent-attention (MLA)" in str(err.value) and "routed-expert" in str(err.value)
+    assert "latent row" in str(err.value)
+
+
+def test_spill_and_turn_hook_refuse_too(weights):
+    with pytest.raises(ValueError, match="kv_spill.*latent-attention"):
+        CFG.refuse_hybrid("kv_spill (K/V pages parked in host memory)")
+    engine = make_engine("refill", 4)
+    engine.turn_hook = lambda cand, tokens: None
+    params, lora = weights
+    with pytest.raises(ValueError, match="turn_hook.*latent-attention"):
+        engine.generate(params, lora, *prompts((20,)), SamplingConfig(n=1, max_tokens=4),
+                        jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("switch", ["paged_verify", "paged_chunked", "paged_prefix"])
+def test_forward_refuses_the_dense_decoders_other_cache_modes(weights, switch):
+    params, _ = weights
+    cache = {"k": (), "v": (), "lin": (), "pooled": (), "lengths": jnp.zeros((1,), jnp.int32),
+             "page_indices": jnp.zeros((1, 4), jnp.int32)}
+    with pytest.raises(NotImplementedError, match=switch):
+        forward(params, CFG, jnp.zeros((1, 4), jnp.int32), kv_cache=cache, page_size=4,
+                **{switch: True})
+
+
+# -------------------------------------------------- the config and the loader
+
+
+def hf_config(**changes):
+    import json
+
+    with open(os.path.join(REPO, "perfbench/configs/kimi-vl-a3b-L7.json")) as f:
+        return SimpleNamespace(**{**json.load(f), **changes})
+
+
+def test_from_hf_config_reads_the_published_file():
+    cfg = ModelConfig.from_hf_config(hf_config())
+    assert cfg.latent and cfg.hybrid and cfg.model_type == "deepseek_v3"
+    assert cfg.layer_kinds == ("latent",) + ("latent_moe",) * 6
+    assert (cfg.head_dim, cfg.q_dim, cfg.latent_dim) == (192, 3072, 576)
+    assert (cfg.n_routed_experts, cfg.experts_per_token, cfg.shared_expert_size) == (64, 6, 2816)
+    assert (cfg.rope_theta, cfg.rms_norm_eps, cfg.routed_scaling_factor) == (800000, 1e-5, 2.446)
+    # what a token runs against what is held: 917M against 3,928M (+ 336M embedding)
+    assert cfg.matmul_param_count == 917_110_784
+    assert cfg.total_matmul_param_count + cfg.vocab_size * cfg.hidden_size == 4_263_116_800
+    full = ModelConfig.from_hf_config(hf_config(num_hidden_layers=27))
+    assert full.layer_kinds.count("latent_moe") == 26
+
+
+@pytest.mark.parametrize("changes,named", [
+    ({"q_lora_rank": 1536}, "q_lora_rank"),
+    ({"n_group": 8}, "n_group"),
+    ({"topk_group": 4}, "topk_group"),
+    ({"scoring_func": "softmax"}, "scoring_func"),
+    ({"topk_method": "greedy"}, "topk_method"),
+    ({"rope_scaling": {"rope_type": "yarn", "factor": 64}}, "rope_scaling"),
+    ({"moe_layer_freq": 2}, "moe_layer_freq"),
+    ({"model_type": "deepseek_v2"}, "deepseek_v2"),
+])
+def test_from_hf_config_refuses_what_it_cannot_represent(changes, named):
+    with pytest.raises(ValueError, match=named):
+        ModelConfig.from_hf_config(hf_config(**changes))
+
+
+def published_state_dict(params, cfg):
+    from distrl_llm_tpu.models.loading import state_dict_from_params
+
+    sd = {f"language_model.{k}": v for k, v in state_dict_from_params(params, cfg).items()}
+    sd["vision_tower.encoder.blocks.0.wqkv.weight"] = np.ones((3, 3), np.float32)
+    sd["multi_modal_projector.linear_1.weight"] = np.ones((2, 2), np.float32)
+    return sd
+
+
+def test_the_published_names_load_into_the_stacked_tree(weights):
+    """A synthetic state dict in the checkpoint's names, the vision tower's and
+    the projector's tensors present: every language-model tensor is used exactly
+    once, the tower's are skipped, and the loaded model is the reference's
+    function (so the RoPE pair layout is the published one on both sides)."""
+    from distrl_llm_tpu.models.loading import params_from_state_dict
+
+    params, lora = weights
+    sd = published_state_dict(params, CFG)
+    assert sd["language_model.model.layers.1.mlp.gate.weight"].shape == (8, 64)
+    assert sd["language_model.model.layers.2.mlp.gate.e_score_correction_bias"].shape == (8,)
+    assert sd["language_model.model.layers.1.mlp.experts.7.down_proj.weight"].shape == (64, 32)
+    assert sd["language_model.model.layers.0.self_attn.kv_a_proj_with_mqa.weight"].shape == (40, 64)
+    assert "language_model.model.layers.0.mlp.gate_proj.weight" in sd
+    assert "language_model.model.layers.1.mlp.shared_experts.up_proj.weight" in sd
+
+    class Counting(dict):
+        reads: dict = {}
+
+        def __getitem__(self, key):
+            self.reads[key] = self.reads.get(key, 0) + 1
+            return super().__getitem__(key)
+
+    counted = Counting(sd)
+    loaded = params_from_state_dict(counted, CFG)
+    language = [k for k in sd if k.startswith("language_model.")]
+    assert all(counted.reads.get(k) == 1 for k in language)
+    assert not any(k.startswith(("vision_tower.", "multi_modal")) for k in counted.reads)
+    for a, b in zip(jax.tree_util.tree_leaves(loaded), jax.tree_util.tree_leaves(params)):
+        np.testing.assert_array_equal(a, b)
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (1, 30), 1, 256))
+    logits, _ = forward(jax.tree_util.tree_map(jnp.asarray, loaded), CFG, jnp.asarray(ids))
+    got = jnp.take_along_axis(
+        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0]
+    want = np.asarray(ref.next_token_logprobs(
+        params, CFG, jnp.asarray(ids), jnp.ones((1, 30), jnp.int32)))
+    assert np.abs(np.asarray(got) - want).max() < 2e-5
+
+
+@pytest.mark.parametrize("change,error,named", [
+    (lambda sd: sd.pop("language_model.model.layers.2.mlp.experts.5.up_proj.weight"),
+     KeyError, "experts.5.up_proj"),
+    (lambda sd: sd.update({"language_model.model.layers.1.mlp.experts.8.up_proj.weight": 0}),
+     ValueError, "not loaded"),
+    (lambda sd: sd.update({"language_model.model.layers.9.self_attn.q_proj.weight": 0}),
+     None, ""),  # a layer the cut does not run is left alone
+])
+def test_a_missing_or_leftover_tensor_is_a_loud_error(weights, change, error, named):
+    from distrl_llm_tpu.models.loading import params_from_state_dict
+
+    sd = published_state_dict(weights[0], CFG)
+    change(sd)
+    if error is None:
+        params_from_state_dict(sd, CFG)
+        return
+    with pytest.raises(error, match=named):
+        params_from_state_dict(sd, CFG)
+
+
+def test_a_saved_snapshot_loads_back_as_the_same_model(weights, tmp_path):
+    from distrl_llm_tpu.models.loading import load_pretrained, save_hf_checkpoint
+
+    params, _ = weights
+    save_hf_checkpoint(jax.tree_util.tree_map(np.asarray, params), CFG, str(tmp_path))
+    loaded, cfg = load_pretrained(str(tmp_path))
+    assert cfg == ModelConfig(**{**CFG.__dict__, "max_position_embeddings":
+                                 cfg.max_position_embeddings})
+    for a, b in zip(jax.tree_util.tree_leaves(loaded), jax.tree_util.tree_leaves(params)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_adapter_factors_follow_each_kinds_shapes_and_merge(weights):
+    from distrl_llm_tpu.models.lora import LATENT_TARGETS, merge_lora
+
+    params, lora = weights
+    assert set(lora["layers"]) == {"latent", "latent_moe"}
+    for kind, width in (("latent", 128), ("latent_moe", 32)):
+        stack = lora["layers"][kind]
+        assert set(stack) == set(LATENT_TARGETS)  # no router, no routed expert
+        assert stack["wkv_a"]["b"].shape[-1] == 40 and stack["wkv_b"]["a"].shape[1] == 32
+        assert stack["wo"]["a"].shape[1] == 4 * 16  # H x v, not the query's width
+        assert stack["w_gate"]["b"].shape[-1] == width
+    merged = merge_lora(params, lora, alpha=8.0)
+    ids = jax.random.randint(jax.random.PRNGKey(2), (1, 20), 1, 256)
+    a, _ = forward(merged, CFG, ids)
+    b, _ = forward(params, CFG, ids, lora=lora, lora_scale=2.0)
+    np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+def test_every_new_leaf_has_a_partition_spec_and_is_whole_on_a_chip(weights):
+    from jax.sharding import PartitionSpec as P
+
+    from distrl_llm_tpu.parallel.partition import param_specs
+
+    params, lora = weights
+    specs = param_specs(params)["layers"]["latent_moe"]
+    for name in ("wkv_a", "wkv_b", "kv_a_norm", "router", "e_score_bias",
+                 "experts_gate", "experts_up", "experts_down"):
+        leaf = params["layers"]["latent_moe"][name]
+        assert specs[name] == P(*([None] * leaf.ndim)), name
+    assert specs["wq"] == P(None, "fsdp", "tp") and specs["w_down"] == P(None, "tp", "fsdp")
+    assert param_specs(lora)["layers"]["latent"]["wkv_b"]["a"] == P(None, "fsdp", None)

@@ -238,6 +238,58 @@ def test_page_write_keeps_the_pool_layout(chip, pool, page_size, width, consumer
         assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
+    """One layer's fragment of Kimi-VL-A3B's decode step at the cell's sizes
+    (64 rows, a table of 165 pages of 128 rows of 576 values in 640 lanes, 64 experts of
+    2,048 x 1,408 in a stack of 6 layers): the latent row's point scatter
+    keeps the pool's layout (at 576 lanes a row the compiler keeps the pool
+    token-minor and copies it round the write), the page gather copies no pool,
+    and the experts' products read their layer out of the stack in place: a
+    layer copied out of it first (as ``lax.ragged_dot``'s custom call had it)
+    is 369 MB a matrix, three a layer, 6.6 GB of temporaries a step."""
+    from distrl_llm_tpu.models import ModelConfig, moe
+    from distrl_llm_tpu.models.hybrid import _latent_mix
+    from distrl_llm_tpu.models.transformer import _proj
+
+    cfg = ModelConfig(
+        vocab_size=163840, hidden_size=2048, intermediate_size=11264, num_layers=7,
+        num_heads=16, num_kv_heads=16, head_dim=192, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=64, n_shared_experts=2, experts_per_token=6,
+        moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446)
+    pool, layers = (960, 128, 640), 6
+
+    def fragment(pages, q, c, k_pe, lengths, table, w_kvb, h, p):
+        env = {"page_indices": table, "page_size": 128, "lengths": lengths}
+        o, pages = _latent_mix(
+            q[..., :128], q[..., 128:], c, k_pe, pages, {"wkv_b": w_kvb}, None,
+            cfg=cfg, mode="decode", env=env, proj=_proj, lora_scale=1.0)
+        y, stats = moe.moe_half(h, {**p, "experts_layer": 3}, cfg)
+        return pages, o, y, stats
+
+    bf = jnp.bfloat16
+    experts = lambda a, b: chip((layers, 64, a, b), bf)
+    compiled = jax.jit(fragment, donate_argnums=0).lower(
+        chip(pool, bf), chip((ROWS, 1, 16, 192), bf), chip((ROWS, 1, 512), bf),
+        chip((ROWS, 1, 64), bf), chip((ROWS,), jnp.int32), chip((ROWS, 165), jnp.int32),
+        chip((512, 16 * 256), bf), chip((ROWS, 1, 2048), bf),
+        {"router": chip((2048, 64), bf), "e_score_bias": chip((64,), bf),
+         "experts_gate": experts(2048, 1408), "experts_up": experts(2048, 1408),
+         "experts_down": experts(1408, 2048)},
+    ).compile()
+    text = compiled.as_text()
+    held = ("bf16[960,128,640]", "bf16[64,2048,1408]", "bf16[64,1408,2048]",
+            "bf16[6,64,2048,1408]", "bf16[6,64,1408,2048]", "bf16[384,2048,1408]",
+            "bf16[384,1408,2048]")
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if any(op in line for op in (" copy(", " slice(", " dynamic-slice("))
+        and any(shape in line.split("(")[0] for shape in held)
+    ]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
