@@ -186,6 +186,67 @@ def _latent_attention_case(key, *, rows, heads, nope, rope, v_dim, rank, context
     return {"max_abs_err": round(err, 5), "context": context}
 
 
+def _shared_prefix_attention_case(key, *, rows, heads, nope, rope, v_dim, rank,
+                                  latent_row, prompt, page, per):
+    """Absorbed decode attention through the page pool (bf16), ``rows``
+    candidates of ONE prompt laid out as the engine's fan-out lays them out
+    (its full pages shared, the partial page and a ragged answer private): the
+    walk that reads the shared blocks once a group, against the expanded form
+    over each row's own gathered context in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.engine.paged_engine import _page_table_rows
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    kw, kl, kq, kn = jax.random.split(key, 4)
+    w = (0.02 * jax.random.normal(kw, (rank, heads * (nope + v_dim)))).astype(jnp.bfloat16)
+    prompt_pages, private_pages = -(-prompt // page), 3
+    table = _page_table_rows(
+        jnp.zeros((rows,), jnp.int32), jnp.full((rows,), prompt // page),
+        prompt_pages + jnp.arange(rows) * private_pages,
+        prompt_pages=prompt_pages, private_pages=private_pages)
+    pool = jax.random.normal(
+        kl, (prompt_pages + rows * private_pages, page, latent_row), jnp.bfloat16)
+    pool = pool.at[..., rank + rope:].set(0)  # a row is [c, k_pe] and zeros
+    q = jax.random.normal(kq, (rows, heads, nope + rope), jnp.bfloat16)
+    # a row's newest position: the prompt and up to two pages of answer
+    lengths = prompt + jax.random.randint(kn, (rows,), 0, 2 * page)
+    w_k, w_v = la.split_kvb(w, heads, nope, v_dim)
+    scale = (nope + rope) ** -0.5
+    wide = la.shared_pages_per_block(rows, heads, page, per, table.shape[1])
+    walk = la.shared_page_walk(table, lengths, page_size=page, wide=wide, rows=rows)
+
+    @jax.jit
+    def absorbed(q, pool, walk, lengths):
+        q_row = la.absorbed_query(q[..., :nope], q[..., nope:], w_k)
+        q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, latent_row - rank - rope)))
+        carry = la.absorbed_paged_attention(
+            q_row, pool, walk, lengths, scale, per=per, wide=wide, rows=rows)
+        return la.absorbed_output(carry, w_v, jnp.float32)
+
+    @jax.jit
+    def expanded(q, pool, table_row, length):  # one row: K and V of its context
+        f32 = pool[table_row].reshape(1, -1, latent_row).astype(jnp.float32)
+        kv = (f32[..., :rank] @ w.astype(jnp.float32)).reshape(1, -1, heads, nope + v_dim)
+        qf = q.astype(jnp.float32)[None, None]
+        seen = (jnp.arange(f32.shape[1]) <= length)[None, None, :]
+        return la.expanded_finish(la.expanded_attention(
+            qf[..., :nope], qf[..., nope:], kv, f32[..., rank: rank + rope], seen),
+            jnp.float32)[0, 0]
+
+    got = absorbed(q, pool, walk, lengths)
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([expanded(q[r], pool, table[r], lengths[r]) for r in range(rows)])
+    err = float(jnp.max(jnp.abs(got - want)))
+    attended, read = (int(x) for x in walk.stats)
+    assert int(walk.shared[0]) == prompt // page // wide, "the prompt's blocks are not read once"
+    assert np.isfinite(err) and err < 5e-2, f"shared-prefix latent attention max|err| {err}"
+    return {"max_abs_err": round(err, 5), "shared_blocks": int(walk.shared[0]),
+            "pages_attended": attended, "pages_read": read}
+
+
 def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
     """An expert layer's routed part (bf16; ``tokens`` decides the form: every
     expert on every token up to ``moe.DENSE_MAX_TOKENS``, single-expert blocks of
@@ -267,6 +328,10 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     # attention, and one expert layer against the plain form (plain XLA both)
     out["latent_attention"] = _latent_attention_case(
         key, rows=8, heads=16, nope=128, rope=64, v_dim=128, rank=512, context=2048)
+    # 16 candidates over one 10k-token prompt: its pages read once for all
+    out["latent_attention_shared_prefix"] = _shared_prefix_attention_case(
+        key, rows=16, heads=16, nope=128, rope=64, v_dim=128, rank=512, latent_row=640,
+        prompt=10300, page=128, per=8)
     for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
         out[name] = _expert_layer_case(
             key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)

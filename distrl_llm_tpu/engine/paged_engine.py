@@ -100,6 +100,13 @@ ENGINE_SPARSE_BLOCKS_VISIBLE = "engine/sparse_blocks_visible"    # counter
 # decode state like the block counters above.
 ENGINE_MOE_ASSIGNMENTS = "engine/moe_assignments"          # counter
 ENGINE_MOE_MAX_EXPERT_LOAD = "engine/moe_max_expert_load"  # counter
+# absorbed latent attention (ops/latent_attention.py): live (row, page) pairs
+# a round's decode steps attended over, and live pages they fetched from the
+# pool (a page that a group's rows share is fetched once a group), summed over
+# layers and steps: attended / read is how often the shared walk engages
+# (1.0 where nothing is shared). Pages: rows would overflow an int32 a round.
+ENGINE_LATENT_PAGES_ATTENDED = "engine/latent_pages_attended"  # counter
+ENGINE_LATENT_PAGES_READ = "engine/latent_pages_read"          # counter
 
 Params = dict[str, Any]
 
@@ -159,11 +166,12 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
 
 def _count_mixer_stats(mixer) -> None:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
-    layers' blocks; ``mixer["moe_stats"]``: the expert layers' pairs) with
-    telemetry."""
+    layers' blocks; ``mixer["moe_stats"]``: the expert layers' pairs;
+    ``mixer["latent_stats"]``: absorbed attention's pages) with telemetry."""
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
+        ("latent_stats", (ENGINE_LATENT_PAGES_ATTENDED, ENGINE_LATENT_PAGES_READ)),
     ):
         if mixer is not None and key in mixer:
             for name, value in zip(names, np.asarray(mixer[key])):

@@ -54,9 +54,11 @@ shared expert, which is this module's ``_mlp_half``)::
 
 Its cache has ``k`` alone: one latent array ``[pages, page, latent_row]`` a
 layer (``rank + rope`` values and zeros to whole 128-lane tiles), no kv-head
-axis and no V (``v`` is an empty tuple), and ``moe_stats``
-[2] int32 (token-expert pairs computed, the fullest expert's, summed over
-expert layers and steps).
+axis and no V (``v`` is an empty tuple), and two counters of [2] int32, summed
+over layers and decode steps: ``moe_stats`` (token-expert pairs computed, the
+fullest expert's) and ``latent_stats`` (the (row, page) pairs absorbed
+attention covered, the pages it fetched: a page that a group's rows share is
+fetched once, ``ops/latent_attention.py``).
 
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
@@ -83,9 +85,9 @@ from distrl_llm_tpu.models.transformer import (
 from distrl_llm_tpu.models.moe import moe_half
 from distrl_llm_tpu.ops.attention import attention
 from distrl_llm_tpu.ops.latent_attention import (
-    absorbed_attention, absorbed_output, absorbed_query, absorbed_start,
+    absorbed_output, absorbed_paged_attention, absorbed_query,
     expanded_attention, expanded_finish, expanded_start,
-    rope_interleaved, split_kvb,
+    rope_interleaved, shared_page_walk, shared_pages_per_block, split_kvb,
 )
 from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
@@ -94,9 +96,9 @@ from distrl_llm_tpu.ops.sparse_attention import (
 )
 
 Params = dict[str, Any]
-#: columns of a row's page table absorbed decode attention gathers at a time,
-#: and rows that walk their tables together
-LATENT_DECODE_PAGES = 16
+#: columns of a row's page table absorbed decode attention gathers at a time
+#: for that row alone, and rows that walk their tables together
+LATENT_DECODE_PAGES = 8
 LATENT_DECODE_ROWS = 16
 
 
@@ -167,7 +169,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     """What a slot holds beside its K/V pages: a float32 state per lightning
     layer, the selector's pooled keys per sparse layer, the round's counter."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counter
-        return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32)}
+        return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
+                "latent_stats": jnp.zeros((2,), jnp.int32)}
     h, d = cfg.lightning_heads, cfg.lightning_head_dim
     pooled = (rows, pooled_count(max_tokens, cfg), cfg.num_kv_heads, cfg.head_dim)
     return {
@@ -307,6 +310,24 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     return x, cache, stats
 
 
+def _latent_page_walk(env: dict, cfg: ModelConfig):
+    """How absorbed decode attention walks a step's page tables ``[B, W]``:
+    LATENT_DECODE_ROWS rows together, a row's own pages LATENT_DECODE_PAGES
+    columns at a time as far as the longest of the group reaches (a short
+    row's group does not walk a long row's width), the columns every row of
+    the group holds in common in blocks of ``wide``, once for all of them.
+    Returns (the block shapes, what ``shared_page_walk`` read off the tables):
+    the same for every layer of the step."""
+    idx, ps = env["page_indices"], env["page_size"]
+    b, width = idx.shape
+    per = min(LATENT_DECODE_PAGES, width)
+    rows = LATENT_DECODE_ROWS if b % LATENT_DECODE_ROWS == 0 else b
+    wide = shared_pages_per_block(rows, cfg.num_heads, ps, per, width)
+    walk = shared_page_walk(
+        idx, env["lengths"], env.get("alive"), page_size=ps, wide=wide, rows=rows)
+    return {"per": per, "wide": wide, "rows": rows}, walk
+
+
 def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale):
     """One decode token a row: write its latent row, then attend over the
     row's pages with W_kvb absorbed (its adapter too). ``q_nope [B, H, nope]``,
@@ -327,28 +348,9 @@ def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale)
         w_k, w_v = split_kvb(w, heads, nope, cfg.v_head_dim)
         q_row = absorbed_query(q_nope, q_pe, w_k)
         q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, cfg.latent_row - cfg.latent_dim)))
-        # a row's pages LATENT_DECODE_PAGES columns of its table at a time,
-        # LATENT_DECODE_ROWS rows together, as far as the longest of them
-        # reaches: a short row's group does not walk a long row's width
-        per = min(LATENT_DECODE_PAGES, idx.shape[1])
-        rows = LATENT_DECODE_ROWS if b % LATENT_DECODE_ROWS == 0 else b
-        scale = cfg.head_dim ** -0.5
-        cols = jnp.pad(idx, ((0, 0), (0, -idx.shape[1] % per)), mode="edge")
-
-        def group(q_g, idx_g, len_g):
-            def fold(j, carry):
-                at = jax.lax.dynamic_slice_in_dim(idx_g, j * per, per, axis=1)
-                seen = (j * per * ps + jnp.arange(per * ps))[None, :] <= len_g[:, None]
-                return absorbed_attention(
-                    q_g, pages[at].reshape(rows, per * ps, -1), seen, scale, carry)
-
-            return jax.lax.fori_loop(
-                0, len_g.max() // (per * ps) + 1, fold, absorbed_start(*q_g.shape))
-
-        carry = jax.tree_util.tree_map(
-            lambda *parts: jnp.concatenate(parts, axis=0),
-            *(group(q_row[r: r + rows], cols[r: r + rows], lengths[r: r + rows])
-              for r in range(0, b, rows)))
+        shape, walk = env["page_walk"]
+        carry = absorbed_paged_attention(
+            q_row, pages, walk, lengths, cfg.head_dim ** -0.5, **shape)
         return absorbed_output(carry, w_v, q_nope.dtype)[:, None], pages
 
 
@@ -479,6 +481,9 @@ def forward_hybrid(
             "lengths": lengths, "page_indices": kv_cache["page_indices"],
             "page_size": page_size, "alive": kv_cache.get("alive"),
         }
+        if cfg.latent:  # read off the table once a step, for every layer
+            with jax.named_scope(telemetry.MODEL_LATENT_ATTN):
+                env["page_walk"] = _latent_page_walk(env, cfg)
     else:
         start = kv_cache["segment_start"]
         q_pos = start + jnp.broadcast_to(
@@ -578,4 +583,7 @@ def forward_hybrid(
         out["sel_stats"] = stats
     if moe_stats is not None:
         out["moe_stats"] = moe_stats
+    if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
+        out["latent_stats"] = (
+            kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)
     return logits, out

@@ -16,6 +16,19 @@ has two forms, and which is cheaper depends on queries a cached token:
   (``absorbed_attention``'s running softmax): the gathered context of 64 rows
   of 21k tokens, 1.5 GB a layer, never exists whole.
 
+**What is read once** (``absorbed_paged_attention``). Rows that walk their
+page tables together (a GRPO prompt's candidates) mostly name the SAME
+physical pages: the prompt's. ``shared_page_walk`` reads off the tables how
+many leading blocks of columns every row of a group holds in common, and the
+walk is split there: a shared block is gathered ONCE (row 0's pages) and all
+the group's (row, head) queries meet it in one product, ``[rows * H, row]`` by
+``[row, block]``; the columns after it are gathered a row, as before (in
+narrower blocks: most of a row's private columns are not reached yet),
+carrying the same running softmax on. Equal table entries are equal pages, so
+this is exact whatever made them equal, and a group that shares nothing walks
+as it always did. A column past a row's newest page repeats that page: no page
+that the row does not hold is ever fetched.
+
 Both are plain XLA here (no Mosaic kernel yet: ROADMAP). The softmax scale is
 ``(nope + rope)^-0.5`` in both. RoPE pairs are DeepSeek's interleaved ones,
 ``(x[2i], x[2i+1])``; the output keeps the halves apart (evens first), which a
@@ -23,6 +36,8 @@ score cannot tell as long as q and k are rotated alike.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -114,10 +129,13 @@ def absorbed_attention(
     """One block of cached rows folded into the running softmax of a decode
     step. ``carry = (m [B, H], l [B, H], acc [B, H, rank + rope])`` in float32,
     ``None`` to start. The score is one contraction over the row, and the
-    values are the same row: the block is read for both."""
+    values are the same row: the block is read for both. ``latent [Sk, rank +
+    rope]`` is ONE block that every row attends over (``seen`` still a row's
+    own): all B * H queries against it in one product."""
     m, l, acc = carry or absorbed_start(*q_row.shape)
+    block = "bkd" if latent.ndim == 3 else "kd"
     scores = jnp.einsum(
-        "bhd,bkd->bhk", q_row.astype(latent.dtype), latent,
+        f"bhd,{block}->bhk", q_row.astype(latent.dtype), latent,
         preferred_element_type=jnp.float32)
     scores = jnp.where(seen[:, None], scores * scale, NEG_INF)
     m_new = jnp.maximum(m, scores.max(axis=-1))
@@ -126,7 +144,7 @@ def absorbed_attention(
     # over the whole row, cut in ``absorbed_output``: a slice of the block
     # would be a copy of it
     acc = acc * fix[..., None] + jnp.einsum(
-        "bhk,bkd->bhd", p.astype(latent.dtype), latent,
+        f"bhk,{block}->bhd", p.astype(latent.dtype), latent,
         preferred_element_type=jnp.float32)
     return m_new, l * fix + p.sum(axis=-1), acc
 
@@ -136,6 +154,99 @@ def absorbed_start(b: int, heads: int, row: int):
     return (jnp.full((b, heads), NEG_INF, jnp.float32),
             jnp.zeros((b, heads), jnp.float32),
             jnp.zeros((b, heads, row), jnp.float32))
+
+
+#: float32 scores of one shared block, ``[rows * heads, pages * page_size]``. At
+#: 2 MiB (16 pages of 128 rows for 16 rows' 16 heads) the block's two products
+#: fill the MXU's rows; twice that is no faster and half is 13% slower a page
+#: (a fragment of the Kimi cell's 7 layers on the v5e: PERF.md §6, PR 34)
+SHARED_SCORE_BYTES = 2 << 20
+
+
+def shared_pages_per_block(rows: int, heads: int, page_size: int, per: int,
+                           width: int) -> int:
+    """Columns of a shared block: a multiple of ``per`` (the columns a row
+    gathers for itself at a time), as many as ``SHARED_SCORE_BYTES`` of scores
+    and the table's width allow. From shapes alone."""
+    fit = SHARED_SCORE_BYTES // (rows * heads * page_size * 4)
+    return max(per, min(fit, width) // per * per)
+
+
+class PageWalk(NamedTuple):
+    """What ``shared_page_walk`` reads off a step's page tables."""
+
+    cols: jax.Array  # [B, blocks * wide] page ids; past a row's newest page, that page
+    shared: jax.Array  # [B // rows] leading blocks of ``wide`` columns a group's rows all hold
+    newest: jax.Array  # [B // rows] the column of the newest page of a group's longest row
+    stats: jax.Array  # [2] int32: (row, page) pairs attended, pages fetched
+
+
+def shared_page_walk(tables: jax.Array, lengths: jax.Array, alive=None, *,
+                     page_size: int, wide: int, rows: int) -> PageWalk:
+    """How ``absorbed_paged_attention`` walks ``tables [B, W]``, ``rows`` rows
+    together (``B`` a multiple of ``rows``), a row seeing positions ``0 ..
+    lengths`` (its newest cached row is AT ``lengths``).
+
+    A group's ``shared`` is the count of leading whole blocks of ``wide``
+    columns in which every row's entries equal row 0's, no further than the
+    group's longest row reaches. The counters are of live pages (a row's
+    ``lengths // page_size + 1``; a row not ``alive`` has none): ``attended``
+    every (row, page) pair, ``read`` a shared block's pages once a group and
+    the others once a row. A few integer operations on the table: the same for
+    every layer of a step."""
+    b, width = tables.shape
+    held = lengths // page_size  # the column of a row's newest page
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    last = jnp.take_along_axis(tables, jnp.minimum(held, width - 1)[:, None], axis=1)
+    cols = jnp.where(col <= held[:, None], tables, last)
+    cols = jnp.pad(cols, ((0, 0), (0, -width % wide)), mode="edge")
+    groups = cols.reshape(b // rows, rows, -1, wide)
+    same = (groups == groups[:, :1]).all(axis=(1, 3))  # [groups, blocks]
+    newest = held.reshape(-1, rows).max(axis=1)
+    shared = jnp.minimum(jnp.cumprod(same, axis=1).sum(axis=1), newest // wide + 1)
+    live = held + 1 if alive is None else jnp.where(alive, held + 1, 0)
+    live = live.reshape(-1, rows)
+    once = shared[:, None] * wide  # columns fetched once a group
+    read = jnp.minimum(once[:, 0], live.max(axis=1)) + jnp.maximum(live - once, 0).sum(axis=1)
+    return PageWalk(cols, shared, newest, jnp.stack([live.sum(), read.sum()]))
+
+
+def absorbed_paged_attention(
+    q_row: jax.Array,  # [B, H, latent_row] from ``absorbed_query``, padded like a page's row
+    pages: jax.Array,  # [pages, page_size, latent_row]: a layer's pool
+    walk: PageWalk,
+    lengths: jax.Array,  # [B]
+    scale: float,
+    *, per: int, wide: int, rows: int,
+):
+    """A decode step's attention over each row's pages; returns the running
+    softmax for ``absorbed_output``. A group walks its shared blocks first
+    (``wide`` columns each, gathered once for all its rows), then the rest of
+    its tables ``per`` columns a row at a time (module docstring); a group
+    whose rows share nothing runs the second loop alone."""
+    page_size = pages.shape[1]
+
+    def group(q_g, cols_g, len_g, shared, newest):
+        def block(j, n, of):
+            at = jax.lax.dynamic_slice_in_dim(of, j * n, n, axis=of.ndim - 1)
+            seen = (j * n * page_size + jnp.arange(n * page_size))[None, :] <= len_g[:, None]
+            return pages[at].reshape(*of.shape[:-1], n * page_size, -1), seen
+
+        def fold_shared(j, carry):
+            return absorbed_attention(q_g, *block(j, wide, cols_g[0]), scale, carry)
+
+        def fold_private(j, carry):
+            return absorbed_attention(q_g, *block(j, per, cols_g), scale, carry)
+
+        carry = jax.lax.fori_loop(0, shared, fold_shared, absorbed_start(*q_g.shape))
+        return jax.lax.fori_loop(
+            shared * (wide // per), newest // per + 1, fold_private, carry)
+
+    return jax.tree_util.tree_map(
+        lambda *parts: jnp.concatenate(parts, axis=0),
+        *(group(q_row[r: r + rows], walk.cols[r: r + rows], lengths[r: r + rows],
+                walk.shared[r // rows], walk.newest[r // rows])
+          for r in range(0, q_row.shape[0], rows)))
 
 
 def absorbed_output(carry, w_v: jax.Array, dtype) -> jax.Array:

@@ -36,7 +36,7 @@ def compiles():
     return chip_smoke.CompileLog()
 
 
-def test_the_latent_and_expert_checks_hold_at_tiny_size():
+def test_the_latent_and_expert_checks_hold_at_tiny_size(monkeypatch):
     """The two checks the kernels phase runs at Kimi-VL-A3B's widths, here at
     the test preset's: absorbed against expanded attention, and an expert layer
     against every pair computed in float32, no pair dropped."""
@@ -46,6 +46,16 @@ def test_the_latent_and_expert_checks_hold_at_tiny_size():
     att = chip_smoke._latent_attention_case(
         key, rows=3, heads=4, nope=16, rope=8, v_dim=16, rank=32, context=40)
     assert att["max_abs_err"] < 5e-2 and att["context"] == 40
+    from distrl_llm_tpu.ops import latent_attention
+
+    # a shared block of 3 pages: the scores of 4 rows' 4 heads over 3 pages of 8
+    monkeypatch.setattr(latent_attention, "SHARED_SCORE_BYTES", 4 * 4 * 3 * 8 * 4)
+    shared = chip_smoke._shared_prefix_attention_case(
+        key, rows=4, heads=4, nope=16, rope=8, v_dim=16, rank=32, latent_row=48,
+        prompt=53, page=8, per=3)
+    # 53 tokens are 6 full pages, two blocks read once; what is left a row
+    assert shared["max_abs_err"] < 5e-2 and shared["shared_blocks"] == 2
+    assert shared["pages_read"] == 6 + shared["pages_attended"] - 4 * 6
     for tokens, form in ((24, "dense"), (160, "grouped")):
         layer = chip_smoke._expert_layer_case(
             key, tokens=tokens, hidden=64, width=32, experts=8, per_token=2)

@@ -185,6 +185,143 @@ def test_absorbed_equals_expanded_with_an_adapter_on_kv_b(blocks):
         la.absorbed_output(carry, w_v, jnp.float32), want, atol=2e-5)
 
 
+# ------------------------------------------------ the walk over shared pages
+
+WALK = dict(heads=4, nope=16, rope=8, v_dim=16, rank=32, row=48, ps=8, per=3,
+            prompt_pages=16, private_pages=4)
+
+
+def paged_walk_case(groups, dtype=jnp.float32, seed=0):
+    """A pool and page tables as the engine's fan-out lays them out
+    (``_page_table_rows``: a prompt's full pages shared, the partial page and
+    the answer private), one group a ``(prompt tokens, [tokens generated a
+    candidate])``; a generated count of -1 is a row of length 0. A row's
+    newest cached position is its length, every whole page past it is NaN,
+    and the slots past it in its newest page hold zeros."""
+    from distrl_llm_tpu.engine.paged_engine import _page_table_rows
+
+    w = SimpleNamespace(**WALK)
+    rng = np.random.default_rng(seed)
+    prompt_of = np.repeat(np.arange(len(groups)), [len(g[1]) for g in groups])
+    prompt_len = np.asarray([g[0] for g in groups])[prompt_of]
+    generated = np.concatenate([g[1] for g in groups])
+    lengths = np.where(generated < 0, 0, prompt_len + generated)
+    b = len(lengths)
+    priv0 = len(groups) * w.prompt_pages + np.arange(b) * w.private_pages
+    tables = np.asarray(_page_table_rows(
+        jnp.asarray(prompt_of), jnp.asarray(prompt_len // w.ps), jnp.asarray(priv0),
+        prompt_pages=w.prompt_pages, private_pages=w.private_pages))
+    pool = np.full((priv0[-1] + w.private_pages, w.ps, w.row), np.nan, np.float32)
+    prompt_rows = [rng.standard_normal((g[0], w.row)) for g in groups]
+    context = []
+    for r in range(b):
+        rows = np.concatenate([
+            prompt_rows[prompt_of[r]], rng.standard_normal((64, w.row))])[: lengths[r] + 1]
+        rows[:, w.rank + w.rope:] = 0.0  # a row is [c, k_pe] and zeros to whole tiles
+        rows = np.asarray(jnp.asarray(rows, dtype).astype(jnp.float32))
+        for page in tables[r, : lengths[r] // w.ps + 1]:
+            pool[page] = np.nan_to_num(pool[page], nan=0.0)
+        pos = np.arange(lengths[r] + 1)
+        pool[tables[r, pos // w.ps], pos % w.ps] = rows
+        context.append(rows)
+    return (jnp.asarray(pool, dtype), jnp.asarray(tables), jnp.asarray(lengths, jnp.int32),
+            context)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("wide", [3, 6])  # a shared block of a row's own 3 columns, or twice
+@pytest.mark.parametrize("case,groups,rows,shared,dead", [
+    # 96 prompt tokens are 12 full pages: all of the prompt is read once, and
+    # the private copy of a page-aligned prompt's (clamped) partial page never
+    ("fully_shared_page_aligned", [(96, [0, 1, 2, 3])], 4, {3: [4], 6: [2]}, ()),
+    # every row another prompt: today's walk, a row at a time
+    ("nothing_shared", [(40, [5]), (41, [5]), (57, [0]), (30, [9])], 4, {3: [0], 6: [0]}, ()),
+    # 9 full pages: whole blocks of them shared, the rest of them read a row
+    ("prefix_no_multiple_of_the_block", [(76, [0, 3, 9, 20])], 4, {3: [3], 6: [1]}, ()),
+    # a candidate that stopped early beside one a page and a half further on
+    ("unequal_generated_lengths", [(105, [0, 1, 13, 27])], 4, {3: [4], 6: [2]}, ()),
+    # a dead slot of length 0 names its first page everywhere: nothing shared
+    ("dead_row_of_length_0", [(57, [4, -1, 4, 6])], 4, {3: [0], 6: [0]}, (1,)),
+    ("two_groups_of_different_prompts", [(50, [1, 2, 3, 4]), (110, [7, 7, 0, 1])], 4,
+     {3: [2, 4], 6: [1, 2]}, ()),
+    # 6 rows are no multiple of 4: one group of all six, two prompts in it
+    ("b_no_multiple_of_the_rows", [(50, [0, 1, 2]), (50, [3, 4, 5])], 6, {3: [0], 6: [0]}, ()),
+    ("b_no_multiple_of_the_rows_one_prompt", [(100, [0, 1, 2, 3, 4, 20])], 6,
+     {3: [4], 6: [2]}, ()),
+])
+def test_the_split_walk_equals_the_row_walk_and_expanded_attention(
+        case, groups, rows, shared, dead, wide, dtype):
+    """Absorbed attention that reads a group's shared page prefix once
+    (``absorbed_paged_attention``) against the same walk with nothing taken as
+    shared (the parent's: a gather a row) and against K and V rebuilt per head
+    over each row's own context; whole pages past a row's length hold NaN."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    w = SimpleNamespace(**WALK)
+    pool, tables, lengths, context = paged_walk_case(groups, dtype)
+    b = len(context)
+    alive = np.ones(b, bool)
+    alive[list(dead)] = False
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    w_kvb = 0.3 * jax.random.normal(keys[0], (w.rank, w.heads * (w.nope + w.v_dim)))
+    q = jax.random.normal(keys[1], (b, w.heads, w.nope + w.rope))
+    w_k, w_v = la.split_kvb(w_kvb, w.heads, w.nope, w.v_dim)
+    q_row = jnp.pad(la.absorbed_query(q[..., :w.nope], q[..., w.nope:], w_k),
+                    ((0, 0), (0, 0), (0, w.row - w.rank - w.rope)))
+    scale, shared = (w.nope + w.rope) ** -0.5, shared[wide]
+    walk = la.shared_page_walk(
+        tables, lengths, jnp.asarray(alive), page_size=w.ps, wide=wide, rows=rows)
+    np.testing.assert_array_equal(walk.shared, shared)
+
+    attend = jax.jit(lambda walk: la.absorbed_output(
+        la.absorbed_paged_attention(
+            q_row, pool, walk, lengths, scale, per=w.per, wide=wide, rows=rows),
+        w_v, jnp.float32))
+    got = np.asarray(attend(walk))
+    by_row = np.asarray(attend(walk._replace(shared=jnp.zeros_like(walk.shared))))
+    assert np.isfinite(got).all() and np.isfinite(by_row).all()
+    exact = dtype == jnp.float32
+    np.testing.assert_allclose(got, by_row, atol=2e-5 if exact else 2e-2)
+    for r, rows_r in enumerate(context):  # a row's own context, K and V rebuilt
+        latent = jnp.asarray(rows_r)[None]
+        kv = (latent[..., :w.rank] @ w_kvb).reshape(1, -1, w.heads, w.nope + w.v_dim)
+        want = la.expanded_finish(la.expanded_attention(
+            q[r, None, None, :, :w.nope], q[r, None, None, :, w.nope:], kv,
+            latent[..., w.rank: w.rank + w.rope],
+            jnp.ones((1, 1, len(rows_r)), bool)), jnp.float32)[0, 0]
+        np.testing.assert_allclose(got[r], want, atol=2e-5 if exact else 3e-2)
+
+    # the counters, reckoned from the table by hand: a live row's pages, and
+    # each fetched once a group below the split and once a row above it
+    live = np.where(alive, np.asarray(lengths) // w.ps + 1, 0).reshape(-1, rows)
+    once = np.asarray(shared)[:, None] * wide
+    read = np.minimum(once[:, 0], live.max(axis=1)) + np.maximum(live - once, 0).sum(axis=1)
+    np.testing.assert_array_equal(walk.stats, [live.sum(), read.sum()])
+    if not any(shared):
+        assert walk.stats[0] == walk.stats[1]
+
+
+def test_a_column_past_a_rows_newest_page_repeats_that_page():
+    """No page that a row does not hold is fetched, whatever its table names
+    there (the engine clamps trailing columns to a private page the row has
+    not reached; a refilled slot's may name another row's)."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    tables = jnp.asarray([[4, 5, 6, 7, 8], [4, 5, 9, 10, 11]], jnp.int32)
+    walk = la.shared_page_walk(
+        tables, jnp.asarray([17, 8], jnp.int32), page_size=8, wide=2, rows=2)
+    np.testing.assert_array_equal(
+        walk.cols, [[4, 5, 6, 6, 6, 6], [4, 5, 5, 5, 5, 5]])
+    np.testing.assert_array_equal(walk.shared, [1])
+    np.testing.assert_array_equal(walk.newest, [2])
+    # rows 0 and 1 hold 3 and 2 pages; two are fetched once for both
+    np.testing.assert_array_equal(walk.stats, [5, 2 + 1 + 0])
+    # a shared block's columns, from shapes alone
+    assert la.shared_pages_per_block(16, 16, 128, 8, 165) == 16  # the Kimi cell's
+    assert la.shared_pages_per_block(4, 4, 8, 3, 11) == 9  # no wider than the table
+    assert la.shared_pages_per_block(64, 128, 128, 8, 165) == 8  # nor narrower than a row's
+
+
 # -------------------------------------------------------------- the experts
 
 
@@ -346,15 +483,19 @@ def prompts(lengths, width=64, seed=0):
 
 @pytest.fixture
 def small_pieces(monkeypatch):
-    """Prefill in segments of 16 tokens and decode attention over 3 pages and
-    4 rows at a time, so that 40-57-token prompts in pages of 8 cross every
-    boundary the 21k-token cell crosses."""
+    """Prefill in segments of 16 tokens and decode attention over 3 pages (6
+    where a group shares them) and 4 rows at a time, so that 40-57-token
+    prompts in pages of 8 cross every boundary the 21k-token cell crosses."""
     from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.models import hybrid
+    from distrl_llm_tpu.ops import latent_attention
 
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
     monkeypatch.setattr(hybrid, "LATENT_DECODE_PAGES", 3)
     monkeypatch.setattr(hybrid, "LATENT_DECODE_ROWS", 4)
+    # a shared block of 6 columns: the scores of 4 rows' heads over 6 pages of 8
+    monkeypatch.setattr(latent_attention, "SHARED_SCORE_BYTES",
+                        4 * CFG.num_heads * 6 * 8 * 4)
     assert moe.DENSE_MAX_TOKENS == 8  # 8 decode rows dense, 32-token segments grouped
 
 
@@ -401,6 +542,36 @@ def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
     assert moved("engine/moe_assignments") == 2 * 8 * 24 * 2
     # the fullest of 8 experts holds at least the mean, at most every pair's half
     assert 2 * 24 * 2 <= moved("engine/moe_max_expert_load") <= 2 * 8 * 24
+    # absorbed attention's pages, from the page table by hand: at its step t a
+    # row of a P-token prompt holds (P + t) // 8 + 1 pages in each of 3 layers
+    held = np.asarray([[(p + t) // 8 + 1 for t in range(24)] for p in (40, 57)])
+    assert moved("engine/latent_pages_attended") == 3 * 4 * held.sum()
+    if scheduler == "waves":
+        # a group of 4 is one prompt's candidates: its full pages (5 and 7) in
+        # whole blocks of 6 columns are fetched once, every other page a row
+        once = np.asarray([[5 // 6 * 6], [7 // 6 * 6]])
+        assert moved("engine/latent_pages_read") == 3 * (once + 4 * (held - once)).sum()
+    assert moved("engine/latent_pages_read") <= moved("engine/latent_pages_attended")
+
+
+def test_slots_of_mixed_prompts_fetch_every_page_a_row(weights, small_pieces):
+    """The refill scheduler with two prompts' candidates in one group of four
+    slots: no block is every row's, so pages attended = pages read, exactly."""
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    ids, mask = prompts((40, 57))
+    before = telemetry.observe_snapshot()["counters"]
+    result = make_engine("refill", 4).generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=2, max_tokens=24),
+        jax.random.PRNGKey(3))
+    assert result.alive_slot_steps == 4 * 24
+    after = telemetry.observe_snapshot()["counters"]
+    moved = lambda name: after[name] - before.get(name, 0)
+    held = sum((p + t) // 8 + 1 for t in range(24) for p in (40, 57))
+    assert moved("engine/latent_pages_attended") == 3 * 2 * held
+    assert moved("engine/latent_pages_read") == moved("engine/latent_pages_attended")
 
 
 def test_this_files_agreement_can_tell_lower_precision_pages(weights, small_pieces):

@@ -248,7 +248,7 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     layer copied out of it first (as ``lax.ragged_dot``'s custom call had it)
     is 369 MB a matrix, three a layer, 6.6 GB of temporaries a step."""
     from distrl_llm_tpu.models import ModelConfig, moe
-    from distrl_llm_tpu.models.hybrid import _latent_mix
+    from distrl_llm_tpu.models.hybrid import _latent_mix, _latent_page_walk
     from distrl_llm_tpu.models.transformer import _proj
 
     cfg = ModelConfig(
@@ -261,6 +261,7 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
 
     def fragment(pages, q, c, k_pe, lengths, table, w_kvb, h, p):
         env = {"page_indices": table, "page_size": 128, "lengths": lengths}
+        env["page_walk"] = _latent_page_walk(env, cfg)
         o, pages = _latent_mix(
             q[..., :128], q[..., 128:], c, k_pe, pages, {"wkv_b": w_kvb}, None,
             cfg=cfg, mode="decode", env=env, proj=_proj, lora_scale=1.0)
@@ -288,6 +289,9 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     ]
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+    # a group's shared blocks are gathered once, 16 pages for its 16 rows, and a
+    # row's own columns 8 pages at a time: never 16 pages for each of 16 rows
+    assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
 
 
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
