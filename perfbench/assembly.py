@@ -58,7 +58,7 @@ def eos_ids(traffic: dict[str, Any], vocab_size: int, seed: int,
     * ``"eos_rate": r``: a seeded random subset covering ``r`` of the
       vocabulary. A random-weight policy then stops geometrically with mean
       1/r, the one way to draw a spread of answer lengths from an engine that
-      takes a single cap for a round (the trick is ``bench.py``'s). The round's
+      takes a single cap for a round. The round's
       work is then the draw's, and tok/s swings with the seed by 3.4% (my chip
       runs, PR 23): traffic for a per-layer look at the scheduler, which the
       tiny rehearsal cell runs, and for no end-to-end bound.

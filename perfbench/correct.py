@@ -175,3 +175,32 @@ def learner_update_check(reference, model_cfg, params, lora_before, lora_after,
         and out["grad_sign_mass"] >= tol_mass
     )
     return out
+
+
+#: a check's readings, the key of the limit each is held to, and the side
+_COMPARED = (
+    ("mean_abs", "tol_mean_abs", "most"), ("max_abs", "tol_max_abs", "most"),
+    ("loss_scaled_err", "tol_loss_scaled", "most"),
+    ("grad_sign_mass", "tol_grad_sign_mass", "least"),
+)
+
+
+def compared(check: Mapping[str, Any], window_programs: int) -> dict[str, Any]:
+    """Every number ``correct`` was decided by, beside its limit, under short
+    plain names: ``{name: {"value": v, "limit": l, "at": "most" | "least"}}``.
+    The result line carries it as its last key and standard error as its last
+    lines, so that the record of a run that is not correct says by how much.
+    An invariant of the loop reads 1 where it held; a check that could not be
+    made says ``why``."""
+    out: dict[str, Any] = {
+        name: {"value": float(check[name]), "limit": float(check[tol]), "at": side}
+        for name, tol, side in _COMPARED if name in check and tol in check
+    }
+    if "elements_moved" in check:
+        out["elements_moved"] = {"value": int(check["elements_moved"]), "limit": 1, "at": "least"}
+    for name, held in check.get("invariants", {}).items():
+        out[name] = {"value": int(bool(held)), "limit": 1, "at": "least"}
+    out["window_compiles"] = {"value": int(window_programs), "limit": 0, "at": "most"}
+    if "why" in check:
+        out["why"] = str(check["why"])
+    return out

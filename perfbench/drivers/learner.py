@@ -74,7 +74,8 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
     config = assembly.train_config(traffic, ctx.seed, dtype)
     if (config.max_prompt_tokens, config.max_new_tokens) != (prompt_w, answer_w):
         raise spec.SpecError("learner traffic: train_config's caps must equal the row shape")
-    params = weights.make_base_params(model_cfg, dtype, ctx.seed)
+    params = weights.make_base_params(
+        model_cfg, dtype, ctx.seed, rules=weights.load_rules(cell.paths, cell.config))
     # the trainer is built for its train step, optimizer, adapter and optimizer
     # state only: no engine, and its two one-problem datasets are never read
     nothing = {"problem": ["-"], "solution": ["-"]}

@@ -138,10 +138,11 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
     # each role holds the frozen base on its own submesh, as
     # Trainer.from_pretrained places a checkpoint; timeshared roles alias one copy
     meshes = build_role_meshes(config.mesh, ctx.devices)
-    base_rollout = weights.make_base_params(model_cfg, dtype, ctx.seed, meshes.rollout)
+    rules = weights.load_rules(cell.paths, cell.config)
+    base_rollout = weights.make_base_params(model_cfg, dtype, ctx.seed, meshes.rollout, rules)
     base_learner = (
         base_rollout if meshes.timeshared
-        else weights.make_base_params(model_cfg, dtype, ctx.seed, meshes.learner)
+        else weights.make_base_params(model_cfg, dtype, ctx.seed, meshes.learner, rules)
     )
     engine = assembly.build_engine(
         config, model_cfg, eos=[tokenizer.eos_token_id], pad_id=tokenizer.pad_token_id

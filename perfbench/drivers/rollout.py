@@ -31,7 +31,8 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
     pad_id = 0
     eos = assembly.eos_ids(traffic, model_cfg.vocab_size, ctx.seed, real_eos=3)
     engine = assembly.build_engine(config, model_cfg, eos=eos, pad_id=pad_id)
-    params = weights.make_base_params(model_cfg, dtype, ctx.seed)
+    params = weights.make_base_params(
+        model_cfg, dtype, ctx.seed, rules=weights.load_rules(cell.paths, cell.config))
     # an adapter as the trainer holds it (float32 factors over the bf16 base),
     # with b drawn from the seed so that the adapter's term is not zero
     lora = weights.randomize_lora_b(
@@ -73,7 +74,9 @@ def run(ctx: harness.RunContext) -> harness.RunResult:
             "tokens": int(lengths.sum()),
             "steps_dispatched": result.steps_dispatched,
             "alive_slot_steps": result.alive_slot_steps,
+            # a prompt's rows are consecutive: group_size of them share it
             "prompt_lens": np.repeat(mask.sum(-1), sampling.n).tolist(),
+            "group_size": sampling.n,
             "gen_lens": lengths.reshape(-1).tolist(),
             "slots": min(config.max_concurrent_sequences or lengths.size, lengths.size),
         }

@@ -114,17 +114,39 @@ def expert_flops_per_token(model: Mapping[str, Any]) -> float:
 
 
 def kv_read_bytes(model: Mapping[str, Any], prompt_lens, gen_lens, *,
-                  kv_bytes: int = 2) -> float:
-    """Bytes of cache a round's decode must read: each decoded token attends
-    over its prompt and the tokens before it, ``kv_lora_rank +
+                  kv_bytes: int = 2, group_size: int = 1) -> float:
+    """Bytes of cache a round's decode must read: ``kv_lora_rank +
     qk_rope_head_dim`` values a cached token a layer. K and V are ONE read (the
     values are the row's first ``kv_lora_rank``), and there is no kv-head
-    factor: every head reads the same row."""
+    factor: every head reads the same row.
+
+    ``group_size`` is the number of consecutive rows that share a prompt (a
+    GRPO group's candidates). Absorbed attention scores a query against the
+    latent row itself, so the prompt's rows serve every candidate of the group
+    at once: at each decoded position they count ONCE a group, for as long as
+    the group's longest answer runs, and each row's own generated tail
+    (contexts 1 .. g) counts a row as before. With ``group_size`` 1 every row
+    is its own group and reads its prompt alone: the count before PR 35, to
+    the digit. How far the program gets there is its own report: the counters
+    ``engine/latent_pages_attended`` over ``engine/latent_pages_read`` (8.91 in
+    the cell, 16 with whole prompts shared, 1 where nothing is).
+
+    The dense and block-sparse decoders' counts (``roofline.py``,
+    ``sala_counts.py``) take no ``group_size``: their K/V is per head and their
+    programs read a shared prompt once a candidate."""
     w = _sizes(model)
+    prompt_lens, gen_lens = list(prompt_lens), list(gen_lens)
+    if group_size < 1 or len(prompt_lens) % group_size or len(prompt_lens) != len(gen_lens):
+        raise ValueError(
+            f"{len(prompt_lens)} prompts and {len(gen_lens)} answers are no whole "
+            f"number of groups of {group_size}")
     tokens = 0
-    for p, g in zip(prompt_lens, gen_lens):
-        p, g = int(p), int(g)
-        tokens += g * p + g * (g + 1) // 2  # contexts p + 1 .. p + g
+    for at in range(0, len(prompt_lens), group_size):
+        prompts = {int(p) for p in prompt_lens[at:at + group_size]}
+        answers = [int(g) for g in gen_lens[at:at + group_size]]
+        if len(prompts) != 1:
+            raise ValueError(f"rows {at}..{at + group_size - 1} share no one prompt: {prompts}")
+        tokens += max(answers) * prompts.pop() + sum(g * (g + 1) // 2 for g in answers)
     return float(int(model["num_layers"]) * w["latent"] * kv_bytes * tokens)
 
 

@@ -7,10 +7,11 @@ configuration names under ``counts``).
 
 * ``moe_experts_roofline`` / ``latent_attn_roofline``: the bytes the traced
   rounds' DECODE steps must read there (every expert held, once a step; the
-  latent rows each decoded token attends over) / peak HBM bandwidth / the
+  latent rows each decoded token attends over, a shared prompt's once a
+  group: ``required_work.cache_bytes``) / peak HBM bandwidth / the
   device time under ``args["scope"]`` inside the rounds' decode spans
-  (``args["span"]``), in %. Bound: memory. The window is cut as
-  ``sala_work`` cuts it, by the same function.
+  (``args["span"]``), in %. Bound: memory. The window's cut is
+  ``trace_scopes.seconds_in_spans``.
 * ``expert_load_imbalance``: the program's own counters, the fullest expert's
   pairs over the mean expert's (``max_load * experts / assignments``), over
   everything the process ran.
@@ -18,18 +19,12 @@ configuration names under ``counts``).
 A program without these scopes, spans or counters (the parent of the PR that
 added them), an untraced run, a configuration whose ``counts`` has no such
 functions and a call without a run all give None.
-
-It lives beside the rehearsal's files, with the seven metrics of
-``tests/perfbench/latent_moe_spec.py`` (which says why), until a ``benchmark``
-PR can declare them in ``BENCHMARK.json``; then it moves to
-``perfbench/readers/``.
 """
 
 from __future__ import annotations
 
-from perfbench import spec
-
-SALA_DIR = "tests/perfbench/sala"
+from perfbench import spec, trace_scopes
+from perfbench.readers.required_work import cache_bytes
 
 
 def read(observed, args, ctx):
@@ -61,14 +56,11 @@ def read(observed, args, ctx):
             model, weight_bytes=layout["weight_bytes"])
     elif what == "latent_attn_roofline":
         needed = sum(
-            counts.latent_attn_bytes(model, u["prompt_lens"], u["gen_lens"],
-                                     kv_bytes=layout["kv_bytes"])
+            cache_bytes(counts.latent_attn_bytes, model, u, kv_bytes=layout["kv_bytes"])
             for u in units)
     else:
         raise ValueError(f"latent_moe_work cannot read {what!r}")
-    window = spec.load_module(
-        list(ctx.cell.paths) + [SALA_DIR], "readers", "sala_work")._decode_scope_seconds
-    seconds = window(ctx, args["scope"], args["span"])
+    seconds = trace_scopes.seconds_in_spans(ctx, args["scope"], args["span"])
     if seconds is None:
         return None
     return 100.0 * needed / peaks["hbm_bytes_per_s"] / seconds

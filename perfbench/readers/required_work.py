@@ -12,7 +12,10 @@ and ``kv_read_bytes``, called as ``roofline.py`` defines them.
   utilization (host clock), not a kernel's roofline share.
 * ``decode_bandwidth_util``: bytes the untraced rounds' decode steps must move
   (weights once a step, each live row's KV at its context) / peak HBM
-  bandwidth / the rounds' wall seconds, in %. Bound: memory.
+  bandwidth / the rounds' wall seconds, in %. Bound: memory. A counts module
+  whose ``kv_read_bytes`` takes ``group_size`` (latent rows, which one read
+  serves a whole group of candidates with) is told how many rows share a
+  prompt; any other is called as ever (``cache_bytes``).
 * ``paged_attn_roofline``: KV bytes paged attention must read for the TRACED
   rounds' rows (every decoded token attends over its prompt and the tokens
   before it, exact token granularity) / peak HBM bandwidth / the kernel's
@@ -23,10 +26,24 @@ and ``kv_read_bytes``, called as ``roofline.py`` defines them.
 
 from __future__ import annotations
 
+import inspect
+
 from perfbench import spec
 from perfbench.readers.trace_ops import matching_seconds
 
 DEFAULT_COUNTS = "roofline"
+
+
+def cache_bytes(count, model, unit, *, kv_bytes: int) -> float:
+    """``count`` (a counts module's ``kv_read_bytes``, or a function called
+    like it) over one unit's rows. The unit's ``group_size`` (the rows that
+    share a prompt) goes to a function whose signature takes it and to no
+    other: whether one read of a prompt's cache can serve a whole group is the
+    algorithm's, so the counts module says, and the others' numbers stay."""
+    kwargs = {"kv_bytes": kv_bytes}
+    if unit.get("group_size") and "group_size" in inspect.signature(count).parameters:
+        kwargs["group_size"] = unit["group_size"]
+    return count(model, unit["prompt_lens"], unit["gen_lens"], **kwargs)
 
 
 def read(observed, args, ctx):
@@ -58,8 +75,8 @@ def read(observed, args, ctx):
             lora_rank=layout["lora_rank"],
         )
         needed = sum(
-            u["steps_dispatched"] * weights + counts.kv_read_bytes(
-                model, u["prompt_lens"], u["gen_lens"], kv_bytes=layout["kv_bytes"])
+            u["steps_dispatched"] * weights + cache_bytes(
+                counts.kv_read_bytes, model, u, kv_bytes=layout["kv_bytes"])
             for u in units
         )
         seconds = sum(u["t1"] - u["t0"] for u in units)
@@ -72,8 +89,7 @@ def read(observed, args, ctx):
         if kernel_s <= 0:
             return None
         needed = sum(
-            counts.kv_read_bytes(model, u["prompt_lens"], u["gen_lens"],
-                                   kv_bytes=layout["kv_bytes"])
+            cache_bytes(counts.kv_read_bytes, model, u, kv_bytes=layout["kv_bytes"])
             for u in units
         )
         return 100.0 * needed / peaks["hbm_bytes_per_s"] / kernel_s

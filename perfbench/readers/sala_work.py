@@ -18,51 +18,12 @@ or whatever module the cell's configuration names under ``counts``).
 A program without these scopes, spans or counters (the parent of the PR that
 added them), an untraced run, and a call without a run all give None.
 
-It lives beside the rehearsal's files, with the six metrics that name it
-(``tests/perfbench/sala_spec.py`` says why), until a ``benchmark`` PR can
-declare them in ``BENCHMARK.json``; then it moves to ``perfbench/readers/``.
+The decode window's cut is ``trace_scopes.seconds_in_spans``.
 """
 
 from __future__ import annotations
 
-from perfbench import spec, trace_reduce, trace_scopes
-
-_LOADED: dict[str, dict] = {}
-
-
-def _decode_scope_seconds(ctx, scope: str, span: str) -> float | None:
-    from perfbench import harness
-
-    tracer = getattr(ctx, "tracer", None)
-    if tracer is None or tracer.window_wall_ns is None:
-        return None
-    spans = [(t0, t1) for name, t0, t1 in tracer.host_spans if name == span]
-    if not spans:
-        return None
-    try:
-        path = tracer.xplane_path()
-    except FileNotFoundError:
-        return None
-    if path not in _LOADED:
-        trace = trace_scopes.load(path)
-        host = trace_reduce.load_xplane(path, keep_host_events=(harness.SYNC_EVENT,))
-        try:
-            offset = trace_reduce.sync_offset_ns(
-                host, harness.SYNC_EVENT, tracer.sync_wall_ns)
-        except LookupError:
-            offset = None
-        _LOADED[path] = {"trace": trace, "offset": offset}
-    held = _LOADED[path]
-    if held["offset"] is None or not any(p["events"] for p in held["trace"]["planes"]):
-        return None
-    vocabulary = spec.load_scope_names(ctx.cell.paths)
-    seconds = 0.0
-    for t0, t1 in spans:
-        tab = trace_scopes.table(
-            held["trace"], vocabulary, (t0 - held["offset"], t1 - held["offset"]))
-        if tab is not None:
-            seconds += trace_scopes.seconds_under(tab, scope)
-    return seconds if seconds > 0 else None
+from perfbench import spec, trace_scopes
 
 
 def read(observed, args, ctx):
@@ -99,7 +60,7 @@ def read(observed, args, ctx):
             for u in units)
     else:
         raise ValueError(f"sala_work cannot read {what!r}")
-    seconds = _decode_scope_seconds(ctx, args["scope"], args["span"])
+    seconds = trace_scopes.seconds_in_spans(ctx, args["scope"], args["span"])
     if seconds is None:
         return None
     return 100.0 * needed / peaks["hbm_bytes_per_s"] / seconds

@@ -6,9 +6,10 @@ shapes: recomputed operations (remat), padding the program adds and bytes it
 re-reads do not count. ``model`` is the ``model`` object of a configuration
 file (the keyword arguments of the program's ``ModelConfig``).
 
-Origin: the decode-step byte count is a copy of ``bench.py``'s
-``_decode_roofline_tok_s`` arithmetic (weights once a step plus each row's KV
-at its context), with the LoRA factors and the page granularity added. The
+Origin: the decode-step byte count (weights once a step plus each row's KV at
+its context, with the LoRA factors and the page granularity) began as a copy
+of the arithmetic of a benchmark script that PR 31 deleted; this is now its
+only copy. The
 training count replaces ``ModelConfig.train_flops_per_token`` (3 x forward),
 which counts base-weight gradient matmuls that LoRA training never runs.
 """

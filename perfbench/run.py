@@ -5,7 +5,8 @@
 
 The last line of standard output is the result: one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` in a traced run). ``--trace 0`` reports the cell's end-to-end
+``breakdown`` in a traced run), then ``check``: each number ``correct`` was
+decided by beside its limit, which are also the last lines of standard error. ``--trace 0`` reports the cell's end-to-end
 metrics, ``--trace 1`` its per-layer metrics. Everything else worth keeping is
 on earlier lines, each a JSON object with a ``note``.
 
@@ -31,7 +32,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
-from perfbench import harness, roofline, spec, trace_reduce  # noqa: E402
+from perfbench import correct, harness, roofline, spec, trace_reduce  # noqa: E402
 
 #: units a CPU rehearsal may report: what the program counts, never a device number
 COUNT_UNITS = ("count",)
@@ -229,6 +230,9 @@ def run(args, t0: float, scrubbed: list[str]) -> dict:
                 end_to_end_in_this_run=result.end_to_end,
             )
     line["device"] = device
+    # last, each number that decided ``correct`` beside its limit
+    line["check"] = correct.compared(
+        result.check, observed["compiles"]["window"]["programs"])
     return line
 
 
@@ -240,6 +244,10 @@ def main(argv=None, t0: float | None = None) -> int:
         line = run(args, _PROCESS_START if t0 is None else t0, sorted(scrubbed))
     finally:
         os.environ.update(scrubbed)
+    for name, held in line["check"].items():  # the last lines of standard error
+        said = (f"{held['value']!r} (at {held['at']} {held['limit']!r})"
+                if isinstance(held, dict) else held)
+        print(f"perfbench: check {name} {said}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -31,6 +31,78 @@ class SpecError(ValueError):
     """``BENCHMARK.json`` or a file it names is missing or inconsistent."""
 
 
+#: the one key of ``reduced`` that cuts depth
+DEPTH_KEY = "num_hidden_layers"
+#: keys of ``reduced`` that COUNT the routed experts this chip holds of a layer
+#: (whichever the published config uses), and the fewest it may hold
+EXPERT_KEYS = ("n_routed_experts", "num_experts", "num_local_experts")
+MIN_EXPERTS_HELD = 8
+#: the smallest slice of the published vocabulary a chip may hold: an eighth
+MIN_VOCAB_SHARE = 8
+#: every key that counts what one chip holds of a layer; any other key of
+#: ``reduced`` but depth is a width
+SHARE_KEYS = (*EXPERT_KEYS, "vocab_size", "num_attention_heads", "num_key_value_heads")
+
+
+def check_reduced(config_file: dict[str, Any], where: str) -> None:
+    """What a configuration file may cut from its source, held for
+    ``load_cell`` (a run) and ``test_config_file`` (the tests) alike.
+
+    ``reduced`` may hold the depth key and, only beside a ``share`` object,
+    keys that count what THIS chip holds of a layer as one of the
+    ``chips_per_layer`` chips a stated deployment divides each layer over
+    (``SHARE_KEYS``). ``share`` is ``{"chips_per_layer": n, "published":
+    {<key>: <published value>}}`` with one ``published`` entry for every such
+    key and none else; what is held, times ``n``, is what was published (one
+    chip's share, not a number chosen to fit), at least ``MIN_EXPERTS_HELD``
+    routed experts and an eighth of the vocabulary. Any other key is a width,
+    and a width is never cut. The harness does nothing else with ``share``:
+    the whole file goes to ``ModelConfig.from_hf_config``."""
+    reduced = list(config_file.get("reduced", []))
+    share = config_file.get("share")
+    share_keys = [k for k in reduced if k != DEPTH_KEY]
+    for key in share_keys:
+        if key not in SHARE_KEYS:
+            raise SpecError(
+                f"{where}: 'reduced' names {key!r}: a width is never cut (only "
+                f"{DEPTH_KEY!r} and, beside a 'share', the counts {list(SHARE_KEYS)})")
+    if share is None:
+        if share_keys:
+            raise SpecError(
+                f"{where}: 'reduced' names {share_keys}, one chip's share of a layer, "
+                "and the file has no 'share' that states the deployment")
+        return
+    if not share_keys:
+        raise SpecError(f"{where}: a 'share' and no key in 'reduced' that it cuts")
+    if not isinstance(share, dict) or set(share) != {"chips_per_layer", "published"}:
+        raise SpecError(
+            f"{where}: 'share' is {{'chips_per_layer': n, 'published': {{key: value}}}}")
+    chips, published = share["chips_per_layer"], share["published"]
+    if not isinstance(chips, int) or isinstance(chips, bool) or chips < 2:
+        raise SpecError(
+            f"{where}: 'chips_per_layer' is {chips!r}; a layer is shared by 2 chips or "
+            "more (one chip holds it whole and cuts nothing)")
+    if not isinstance(published, dict) or set(published) != set(share_keys):
+        raise SpecError(
+            f"{where}: 'published' has {sorted(published)} and 'reduced' cuts "
+            f"{sorted(share_keys)}: one published value for each, and none else")
+    for key in share_keys:
+        held = config_file.get(key)
+        if not isinstance(held, int) or held * chips != published[key]:
+            raise SpecError(
+                f"{where}: {key} holds {held!r}, and {chips} chips of that make "
+                f"{held * chips if isinstance(held, int) else None}, not the "
+                f"published {published[key]!r}: the share is what one of the chips holds")
+        if key in EXPERT_KEYS and held < MIN_EXPERTS_HELD:
+            raise SpecError(
+                f"{where}: {key} holds {held} routed experts; a chip holds at least "
+                f"{MIN_EXPERTS_HELD}")
+        if key == "vocab_size" and held * MIN_VOCAB_SHARE < published[key]:
+            raise SpecError(
+                f"{where}: vocab_size holds {held} of {published[key]}; a chip holds "
+                f"at least an eighth of the vocabulary")
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
@@ -105,6 +177,7 @@ def load_cell(bench: dict[str, Any], workload: str) -> Cell:
     if cfg_entry is None:
         raise SpecError(f"workload {workload!r} names no known config")
     config = load_json(os.path.join(ROOT, cfg_entry["file"]))
+    check_reduced(config, cfg_entry["file"])
     traffic = load_json(find_file(paths, "traffic", f"{entry['traffic']}.json"))
     if "kind" not in traffic:
         raise SpecError(f"traffic {entry['traffic']!r} has no 'kind'")

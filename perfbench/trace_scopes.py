@@ -315,6 +315,70 @@ def seconds_under(tab: dict[str, Any], scope: str) -> float:
 
 
 _RUNS: dict[str, dict[str, Any] | None] = {}
+_LOADED: dict[str, dict[str, Any] | None] = {}
+
+
+def _traced_file(ctx) -> str | None:
+    """The traced run's ``.xplane.pb``; None for a call without a run, an
+    untraced run and a run whose profiler wrote no file."""
+    tracer = getattr(ctx, "tracer", None)
+    if tracer is None or tracer.window_wall_ns is None:
+        return None
+    try:
+        return tracer.xplane_path()
+    except FileNotFoundError:
+        return None
+
+
+def loaded_for(ctx) -> dict[str, Any] | None:
+    """The traced run's file, parsed once a run and kept for every reader that
+    cuts it: ``{"trace": load(path), "host": the host plane with the sync
+    annotation and the spans' own, "offset": wall clock less profiler clock in
+    ns, or None where the sync annotation is missing, "spans": the tables
+    ``seconds_in_spans`` has cut}``. None for a call
+    without a run, an untraced run and a trace with no device event (the CPU)."""
+    from perfbench import harness
+
+    path = _traced_file(ctx)
+    if path is None:
+        return None
+    if path not in _LOADED:
+        tracer = ctx.tracer
+        trace = load(path)
+        held = None
+        if any(p["events"] for p in trace["planes"]):
+            names = {name for name, _, _ in tracer.host_spans}
+            host = trace_reduce.load_xplane(
+                path, keep_host_events=(harness.SYNC_EVENT, *names)
+            )
+            try:
+                offset = trace_reduce.sync_offset_ns(
+                    host, harness.SYNC_EVENT, tracer.sync_wall_ns
+                )
+            except LookupError:
+                offset = None
+            held = {"trace": trace, "host": host, "offset": offset, "spans": {}}
+        _LOADED[path] = held
+    return _LOADED[path]
+
+
+def seconds_in_spans(ctx, scope: str, span: str) -> float | None:
+    """Device seconds under the scope rows ``scope`` names (a regex), inside
+    the traced run's host spans called ``span`` (``engine/decode``: a round's
+    decode loop, which starts once its prefill has finished); None where there
+    is no such span, no trace, no clock offset or no second under the scope.
+    The trace is the one ``loaded_for`` parsed, and a span's tables are cut
+    once for every metric that reads them."""
+    held = loaded_for(ctx)
+    if held is None or held["offset"] is None:
+        return None
+    if span not in held["spans"]:
+        vocabulary = spec.load_scope_names(ctx.cell.paths)
+        cut = (table(held["trace"], vocabulary, (t0 - held["offset"], t1 - held["offset"]))
+               for name, t0, t1 in ctx.tracer.host_spans if name == span)
+        held["spans"][span] = [tab for tab in cut if tab is not None]
+    seconds = sum(seconds_under(tab, scope) for tab in held["spans"][span])
+    return seconds if seconds > 0 else None
 
 
 def table_for(ctx) -> dict[str, Any] | None:
@@ -324,33 +388,19 @@ def table_for(ctx) -> dict[str, Any] | None:
     with no device plane (the CPU) or a trace that carries no scope."""
     from perfbench import harness
 
-    tracer = getattr(ctx, "tracer", None)
-    if tracer is None or tracer.window_wall_ns is None:
-        return None
-    try:
-        path = tracer.xplane_path()
-    except FileNotFoundError:
+    path = _traced_file(ctx)
+    if path is None:
         return None
     if path in _RUNS:
         return _RUNS[path]
-    trace = load(path)
+    held, tracer = loaded_for(ctx), ctx.tracer
     tab = None
-    if any(p["events"] for p in trace["planes"]):
-        # the host plane once: the sync annotation, and the spans' own annotations
-        names = {name for name, _, _ in tracer.host_spans}
-        host = trace_reduce.load_xplane(
-            path, keep_host_events=(harness.SYNC_EVENT, *names)
-        )
-        try:
-            offset = trace_reduce.sync_offset_ns(
-                host, harness.SYNC_EVENT, tracer.sync_wall_ns
-            )
-        except LookupError:
-            offset = None
+    if held is not None:
+        offset = held["offset"]
         window = None if offset is None else (
             tracer.window_wall_ns[0] - offset, tracer.window_wall_ns[1] - offset
         )
-        tab = table(trace, spec.load_scope_names(ctx.cell.paths), window)
+        tab = table(held["trace"], spec.load_scope_names(ctx.cell.paths), window)
         if tab is None:
             harness.emit(
                 "trace_scopes", problem=(
@@ -362,7 +412,7 @@ def table_for(ctx) -> dict[str, Any] | None:
             )
         else:
             harness.emit("trace_scopes", **tab)
-            clock = span_clock(host, tracer.host_spans, offset)
+            clock = span_clock(held["host"], tracer.host_spans, offset)
             if clock is not None:
                 harness.emit("span_clock", **clock)
     _RUNS[path] = tab

@@ -6,15 +6,11 @@ rollout engine (segmented prefill over latent pages, fan-out, absorbed decode)
 and one learner update against the reference's loss and adapter gradient.
 
 Beside them the seven per-layer metrics that read what these layers add to the
-program (four scope shares, two rooflines, the experts' load imbalance), with
-their files under ``latent_moe/layer_metrics/`` and their reader under
-``latent_moe/readers/``. The real ``BENCHMARK.json`` does not hold them, for
-the reason ``sala_spec.py`` gives for PR 29's six: the driver takes new
-``per_layer`` entries at the end of the list only, and
-``test_perfbench_trace_scopes.py`` holds PR 24's fifteen to be its tail. To
-declare them once a ``benchmark`` PR has rewritten that line: move the eight
-files under ``perfbench/`` and append ``LATENT_MOE_METRICS`` with the real
-cell's name."""
+program (four scope shares, two rooflines, the experts' load imbalance). Their
+files and their reader lie under ``perfbench/layer_metrics/`` and
+``perfbench/readers/`` (PR 35 declared them in the real ``BENCHMARK.json``, for
+``kimi-vl-a3b-L7.rollout-longctx-latent``); this benchmark declares them by
+name for its own rollout cell and finds the same files over its second path."""
 
 from __future__ import annotations
 
@@ -32,7 +28,7 @@ CELLS = {
 }
 
 #: (name, unit, source, layer, better) of the metrics this family brings, each
-#: moving ``rollout_tok_s``, as its file under ``latent_moe/layer_metrics/`` says
+#: moving ``rollout_tok_s``, as its file under ``perfbench/layer_metrics/`` says
 LATENT_MOE_METRICS = (
     ("model.moe_router_share", "%", "device_trace", "model forward", "lower"),
     ("model.moe_dispatch_share", "%", "device_trace", "model forward", "lower"),
@@ -42,6 +38,14 @@ LATENT_MOE_METRICS = (
     ("kernel.latent_attn_roofline", "%", "device_trace", "kernels", "higher"),
     ("engine.expert_load_imbalance", "x", "program_counter", "engine", "lower"),
 )
+
+
+#: what a rollout cell's PR appends its cell's name to (PR 29 did, PR 33 did):
+#: the end-to-end metric and the ten accepted per-layer lists
+JOINED = ("rollout_tok_s", "engine.decode_bandwidth_util", "engine.decode_step_ms",
+          "engine.slot_occupancy", "kernel.sampler_share", "model.attn_proj_share",
+          "model.mlp_share", "model.head_share", "engine.kv_write_share",
+          "rollout.unscoped_share", "engine.snapshot_wait_ms")
 
 
 def latent_moe_benchmark() -> dict:
@@ -68,7 +72,8 @@ def latent_moe_benchmark() -> dict:
              "why": "rehearsal"} for cell, (traffic, _) in CELLS.items()
         ],
         "end_to_end": [over(m, "name") for m in real["end_to_end"]],
-        "per_layer": [over(m, "moves") for m in real["per_layer"]] + [{
+        "per_layer": [over(m, "moves") for m in real["per_layer"]
+                      if m["name"] not in {name for name, *_ in LATENT_MOE_METRICS}] + [{
             "name": name, "unit": unit, "better": better, "source": source,
             "layer": layer, "moves": "rollout_tok_s", "workloads": [CELL],
         } for name, unit, source, layer, better in LATENT_MOE_METRICS],

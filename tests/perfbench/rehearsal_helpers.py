@@ -47,7 +47,11 @@ def shared_cell(benchmark, cell, trace, seed=3):
 
 
 def assert_contract(line, trace):
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device", "check"}
+    # each number compared beside its limit comes last; none compiled in the window
+    assert list(line)[-1] == "check"
+    assert line["check"]["window_compiles"] == {"value": 0, "limit": 0, "at": "most"}
+    assert all(set(held) == {"value", "limit", "at"} for held in line["check"].values())
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] >= 1
     # no idle share either: a CPU trace has no device plane

@@ -7,13 +7,11 @@ engine.
 
 Beside them the six per-layer metrics that read what these layers add to the
 program (three scope shares, two rooflines, the share of visible blocks the
-sparse layers attended), with their files under ``sala/layer_metrics/`` and
-their reader under ``sala/readers/``. The real ``BENCHMARK.json`` does not hold
-them yet: the driver takes new ``per_layer`` entries at the end of the list
-only, and ``test_perfbench_trace_scopes.py`` holds PR 24's fifteen to be its
-tail, so no entry can follow them until a ``benchmark`` PR rewrites that line
-(``PERF.md`` section 7). To declare them then: move the seven files under
-``perfbench/`` and append ``SALA_METRICS`` with the real cell's name."""
+sparse layers attended). Their files and their reader lie under
+``perfbench/layer_metrics/`` and ``perfbench/readers/`` (PR 35 declared them in
+the real ``BENCHMARK.json``, for ``minicpm-sala-L10.rollout-longctx``); this
+benchmark declares them by name for its own rollout cell and finds the same
+files over its second path."""
 
 from __future__ import annotations
 
@@ -33,7 +31,7 @@ CELLS = {
 
 
 #: (name, source, layer, better) of the metrics this family brings; every one in
-#: %, moving ``rollout_tok_s``, as its file under ``sala/layer_metrics/`` says
+#: %, moving ``rollout_tok_s``, as its file under ``perfbench/layer_metrics/`` says
 SALA_METRICS = (
     ("model.linear_attn_share", "device_trace", "model forward", "lower"),
     ("model.sparse_select_share", "device_trace", "model forward", "lower"),
@@ -68,7 +66,8 @@ def sala_benchmark() -> dict:
              "why": "rehearsal"} for cell, (traffic, _) in CELLS.items()
         ],
         "end_to_end": [over(m, "name") for m in real["end_to_end"]],
-        "per_layer": [over(m, "moves") for m in real["per_layer"]] + [{
+        "per_layer": [over(m, "moves") for m in real["per_layer"]
+                      if m["name"] not in {name for name, *_ in SALA_METRICS}] + [{
             "name": name, "unit": "%", "better": better, "source": source,
             "layer": layer, "moves": "rollout_tok_s", "workloads": [CELL],
         } for name, source, layer, better in SALA_METRICS],

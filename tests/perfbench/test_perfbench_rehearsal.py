@@ -9,6 +9,8 @@ control flow, the correctness check's own logic, the same traffic from the
 same seed.
 """
 
+import json
+
 import pytest
 
 from rehearsal_helpers import SWITCH, assert_cell_ran, run_cell, shared_cell
@@ -86,3 +88,47 @@ def test_a_wrong_gradient_is_not_correct(tiny_benchmark, monkeypatch):
     monkeypatch.setattr(correct, "GRAD_SIGN_MASS_TOL", 1.5)
     line, notes = run_cell(tiny_benchmark, "tiny.learner", 0)
     assert line["correct"] is False and notes["check"]["ok"] is False
+
+
+# ------------------------------------ each number compared, beside its limit (PR 35)
+
+
+def test_the_compared_numbers_stand_beside_their_limits():
+    from perfbench import correct
+
+    rollout = {"tokens": 96, "mean_abs": 0.04, "max_abs": 0.9, "tol_mean_abs": 0.0654,
+               "tol_max_abs": 1.895, "ok": True}
+    assert correct.compared(rollout, 0) == {
+        "mean_abs": {"value": 0.04, "limit": 0.0654, "at": "most"},
+        "max_abs": {"value": 0.9, "limit": 1.895, "at": "most"},
+        "window_compiles": {"value": 0, "limit": 0, "at": "most"}}
+    learner = {"loss": 4.5, "reference_loss": 4.4, "loss_scaled_err": 3e-5, "grad_sign_mass": 0.9994,
+               "elements_moved": 7, "tol_loss_scaled": 2e-3, "tol_grad_sign_mass": 0.995, "ok": True}
+    assert correct.compared(learner, 2) == {
+        "loss_scaled_err": {"value": 3e-5, "limit": 2e-3, "at": "most"},
+        "grad_sign_mass": {"value": 0.9994, "limit": 0.995, "at": "least"},
+        "elements_moved": {"value": 7, "limit": 1, "at": "least"},
+        "window_compiles": {"value": 2, "limit": 0, "at": "most"}}
+    loop = {**rollout, "invariants": {"versions_in_step": True, "losses_finite": False}}
+    held = correct.compared(loop, 0)
+    assert held["versions_in_step"] == {"value": 1, "limit": 1, "at": "least"}
+    assert held["losses_finite"]["value"] == 0 and list(held)[-1] == "window_compiles"
+    none = correct.compared({"ok": False, "why": "the warm-up never finished"}, 0)
+    assert none["why"] == "the warm-up never finished" and "mean_abs" not in none
+
+
+def test_the_compared_numbers_are_the_last_lines_of_standard_error(monkeypatch, capsys):
+    from perfbench import run
+
+    line = {"correct": False, "attempted": 1, "failed": 0, "metrics": {}, "device": {},
+            "check": {"mean_abs": {"value": 0.07, "limit": 0.0654, "at": "most"},
+                      "window_compiles": {"value": 0, "limit": 0, "at": "most"},
+                      "why": "a reason"}}
+    monkeypatch.setattr(run, "run", lambda args, t0, scrubbed: line)
+    assert run.main(["--workload", "x", "--seed", "1", "--seconds", "1", "--trace", "0"]) == 0
+    said = capsys.readouterr()
+    assert json.loads(said.out.strip().splitlines()[-1]) == line
+    assert said.err.strip().splitlines()[-3:] == [
+        "perfbench: check mean_abs 0.07 (at most 0.0654)",
+        "perfbench: check window_compiles 0 (at most 0)",
+        "perfbench: check why a reason"]

@@ -13,13 +13,19 @@ import os
 import pytest
 
 from latent_moe_spec import (
-    CELL, CELLS, LATENT_MOE_DIR, LATENT_MOE_METRICS, latent_moe_benchmark,
+    CELL, CELLS, JOINED, LATENT_MOE_DIR, LATENT_MOE_METRICS, latent_moe_benchmark,
     write_latent_moe_benchmark,
 )
 from rehearsal_helpers import assert_contract, run_cell, shared_cell
+from sala_spec import SALA_METRICS
 from tiny_spec import REPO, real_benchmark
 
 REAL_CELL = "kimi-vl-a3b-L7.rollout-longctx-latent"
+#: the cells of the two other families as they stand beside it, by name
+OTHER_FAMILIES_CELLS = (
+    "qwen2.5-7b-L14.rollout-lockstep", "qwen2.5-7b-L14.learner-1k",
+    "qwen2.5-7b-L14.rl-step-dense", "minicpm-sala-L10.rollout-longctx",
+)
 
 
 @pytest.fixture(scope="module")
@@ -99,8 +105,9 @@ def test_the_real_cell_is_the_issues_letter_for_letter():
             "rollout.unscoped_share"} <= reported
     # absorbed decode is not launched as paged_attention_native
     assert not {"kernel.paged_attn_share", "paged_attn_roofline"} & reported
-    # this family's own seven wait in the rehearsal's benchmark
-    assert not {name for name, *_ in LATENT_MOE_METRICS} & reported
+    # this family's own seven are declared for this cell, and SALA's six are not
+    assert {name for name, *_ in LATENT_MOE_METRICS} <= reported
+    assert not {name for name, *_ in SALA_METRICS} & reported
     check = cell.traffic["check"]
     assert 0 < check["logprob_mean_abs_tol"] < 0.1 < check["logprob_max_abs_tol"] < 3
     for control in ("3 mantissa bits", "latent pages", "top-5", "shared expert",
@@ -109,24 +116,35 @@ def test_the_real_cell_is_the_issues_letter_for_letter():
 
 
 def test_the_benchmark_gained_one_configuration_and_one_cell_at_the_end():
+    """What PR 33 added, held by NAME: the next PR appends after it, so no
+    position and no count of the real benchmark's lists is held here."""
     real = real_benchmark()
-    assert real["configs"][-1]["name"] == "kimi-vl-a3b-L7"
-    assert real["configs"][-1]["reduced"] == ["num_hidden_layers"]
-    assert real["workloads"][-1]["name"] == REAL_CELL
-    assert len(real["per_layer"]) == 30  # no per-layer entry was added
-    lists = [m["name"] for m in real["per_layer"] + real["end_to_end"]
-             if REAL_CELL in m.get("workloads", ())]
-    assert len(lists) == 11 and all(
-        m["workloads"][-1] == REAL_CELL for m in real["per_layer"] + real["end_to_end"]
-        if REAL_CELL in m.get("workloads", ()))
+    config = {c["name"]: c for c in real["configs"]}["kimi-vl-a3b-L7"]
+    assert config["reduced"] == ["num_hidden_layers"]
+    cell = {w["name"]: w for w in real["workloads"]}[REAL_CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "kimi-vl-a3b-L7", "rollout-longctx-latent", 1)
+    metrics = {m["name"]: m for m in real["per_layer"] + real["end_to_end"]}
+    own = [name for name, *_ in LATENT_MOE_METRICS]
+    for name in (*JOINED, *own):
+        assert REAL_CELL in metrics[name]["workloads"], name
+    # its own seven (PR 35 declared them) are read in none of the cells of
+    # another family that stand today, each by name: a later cell that runs
+    # the same expert layers appends its name after this one
+    for name in own:
+        assert not set(OTHER_FAMILIES_CELLS) & set(metrics[name]["workloads"]), name
+    # and none of another family's: the paged kernel's, the refill path's, SALA's
+    for name in ("kernel.paged_attn_share", "paged_attn_roofline", "engine.admit_host_ms",
+                 *(name for name, *_ in SALA_METRICS)):
+        assert REAL_CELL not in metrics[name]["workloads"], name
 
 
 @pytest.mark.parametrize("name, unit, source, layer, better", LATENT_MOE_METRICS,
                          ids=[m[0] for m in LATENT_MOE_METRICS])
 def test_this_familys_metric_has_its_file_and_its_reader(name, unit, source, layer, better):
-    """Each of the seven resolves from ``latent_moe/layer_metrics/`` to a reader
-    the rehearsal's paths hold, agrees with its entry, and is reported in the
-    rollout cell alone."""
+    """Each of the seven resolves from ``perfbench/layer_metrics/`` to a reader
+    under ``perfbench/readers/``, agrees with its entry in the rehearsal's
+    benchmark and in the real one, and is reported in the rollout cell alone."""
     from perfbench import spec
 
     bench = latent_moe_benchmark()
@@ -134,14 +152,21 @@ def test_this_familys_metric_has_its_file_and_its_reader(name, unit, source, lay
     assert (held["source"], held["layer"], held["better"]) == (source, layer, better)
     assert (held["unit"], held["moves"]) == (unit, "rollout_tok_s")
     assert callable(spec.load_module(bench["paths"], "readers", held["reader"]).read)
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [CELL]
     assert name in {m["name"] for m in spec.load_cell(bench, CELL).per_layer}
+    (real,) = [m for m in real_benchmark()["per_layer"] if m["name"] == name]
+    assert {**real, "workloads": [CELL]} == entry
 
 
-def test_no_metric_file_of_this_family_waits_for_an_entry():
-    files = os.listdir(os.path.join(REPO, LATENT_MOE_DIR, "layer_metrics"))
-    assert sorted(files) == sorted(f"{name}.json" for name, *_ in LATENT_MOE_METRICS)
+def test_this_familys_files_lie_under_perfbench_and_nowhere_else():
+    """The seven files and the reader moved to ``perfbench/`` whole (PR 35):
+    no copy stays beside the rehearsal's files."""
+    for sub in ("layer_metrics", "readers"):
+        assert not os.path.exists(os.path.join(REPO, LATENT_MOE_DIR, sub))
+    for name, *_ in LATENT_MOE_METRICS:
+        assert os.path.isfile(os.path.join(REPO, "perfbench", "layer_metrics", f"{name}.json"))
+    assert os.path.isfile(os.path.join(REPO, "perfbench", "readers", "latent_moe_work.py"))
 
 
 def test_the_reader_reads_hand_worked_counters_and_nothing_from_a_parent(monkeypatch):

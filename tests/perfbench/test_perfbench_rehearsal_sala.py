@@ -15,6 +15,12 @@ from rehearsal_helpers import assert_contract, run_cell, shared_cell
 from sala_spec import CELL, SALA_DIR, SALA_METRICS, sala_benchmark, write_sala_benchmark
 from tiny_spec import REPO, real_benchmark
 
+#: the cells of the two other families as they stand beside the real cell, by name
+OTHER_FAMILIES_CELLS = (
+    "qwen2.5-7b-L14.rollout-lockstep", "qwen2.5-7b-L14.learner-1k",
+    "qwen2.5-7b-L14.rl-step-dense", "kimi-vl-a3b-L7.rollout-longctx-latent",
+)
+
 
 @pytest.fixture(scope="module")
 def sala_file(tmp_path_factory):
@@ -71,16 +77,22 @@ def test_the_real_cell_is_the_issues_letter_for_letter():
             "rollout.unscoped_share"} <= reported
     # the paged-attention kernel does not run in it (the sparse layers gather)
     assert not {"kernel.paged_attn_share", "paged_attn_roofline"} & reported
-    # this family's own six wait in the rehearsal's benchmark (sala_spec.py says why)
-    assert not {name for name, *_ in SALA_METRICS} & reported
+    # this family's own six are declared for this cell (PR 35), and for none of
+    # the cells of another family that stand today, each by name: a later cell
+    # that runs the same mixers appends its name after this one
+    own = {name for name, *_ in SALA_METRICS}
+    assert own <= reported
+    for m in cell.per_layer:
+        if m["name"] in own:
+            assert not set(OTHER_FAMILIES_CELLS) & set(m["workloads"]), m["name"]
 
 
 @pytest.mark.parametrize("name, source, layer, better", SALA_METRICS,
                          ids=[m[0] for m in SALA_METRICS])
 def test_this_familys_metric_has_its_file_and_its_reader(name, source, layer, better):
-    """Each of the six resolves from ``sala/layer_metrics/`` to a reader the
-    rehearsal's paths hold, agrees with its entry, and is reported in the
-    rollout cell alone."""
+    """Each of the six resolves from ``perfbench/layer_metrics/`` to a reader
+    under ``perfbench/readers/``, agrees with its entry in the rehearsal's
+    benchmark and in the real one, and is reported in the rollout cell alone."""
     from perfbench import spec
 
     bench = sala_benchmark()
@@ -88,14 +100,21 @@ def test_this_familys_metric_has_its_file_and_its_reader(name, source, layer, be
     assert (held["source"], held["layer"], held["better"]) == (source, layer, better)
     assert (held["unit"], held["moves"]) == ("%", "rollout_tok_s")
     assert callable(spec.load_module(bench["paths"], "readers", held["reader"]).read)
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [CELL]
     assert name in {m["name"] for m in spec.load_cell(bench, CELL).per_layer}
+    (real,) = [m for m in real_benchmark()["per_layer"] if m["name"] == name]
+    assert {**real, "workloads": [CELL]} == entry
 
 
-def test_no_metric_file_of_this_family_waits_for_an_entry():
-    files = os.listdir(os.path.join(REPO, SALA_DIR, "layer_metrics"))
-    assert sorted(files) == sorted(f"{name}.json" for name, *_ in SALA_METRICS)
+def test_this_familys_files_lie_under_perfbench_and_nowhere_else():
+    """The six files and the reader moved to ``perfbench/`` whole (PR 35): no
+    copy stays beside the rehearsal's files."""
+    for sub in ("layer_metrics", "readers"):
+        assert not os.path.exists(os.path.join(REPO, SALA_DIR, sub))
+    for name, *_ in SALA_METRICS:
+        assert os.path.isfile(os.path.join(REPO, "perfbench", "layer_metrics", f"{name}.json"))
+    assert os.path.isfile(os.path.join(REPO, "perfbench", "readers", "sala_work.py"))
 
 
 def test_the_configuration_file_holds_the_catalogs_numbers_and_every_assumption():

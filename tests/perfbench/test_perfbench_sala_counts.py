@@ -1,4 +1,4 @@
-"""``perfbench/sala_counts.py`` and ``sala/readers/sala_work.py`` on a toy shape
+"""``perfbench/sala_counts.py`` and ``perfbench/readers/sala_work.py`` on a toy shape
 worked by hand."""
 
 from types import SimpleNamespace
@@ -85,14 +85,14 @@ def test_the_reader_divides_what_is_needed_by_what_was_taken(monkeypatch):
     observed = {"peaks": {"hbm_bytes_per_s": 1e4}, "model": TOY,
                 "rollout": {"kv_bytes": 2}, "traced_units": [unit, unit]}
     # the traced rounds' decode spent 0.5 s under the scope the metric names
-    monkeypatch.setattr(reader, "_decode_scope_seconds", lambda ctx, scope, span: 0.5)
+    monkeypatch.setattr(reader.trace_scopes, "seconds_in_spans", lambda ctx, scope, span: 0.5)
     args = {"scope": "^model/linear_attn$", "span": "engine/decode"}
     assert reader.read(observed, {"what": "linear_attn_roofline", **args}, ctx) == (
         pytest.approx(100.0 * 2 * 1536 / 1e4 / 0.5))
     assert reader.read(observed, {"what": "sparse_attn_roofline", **args}, ctx) == (
         pytest.approx(100.0 * 2 * 1384 / 1e4 / 0.5))
     # nothing traced, another family's counts, no run: nothing to read
-    monkeypatch.setattr(reader, "_decode_scope_seconds", lambda ctx, scope, span: None)
+    monkeypatch.setattr(reader.trace_scopes, "seconds_in_spans", lambda ctx, scope, span: None)
     assert reader.read(observed, {"what": "linear_attn_roofline", **args}, ctx) is None
     dense = SimpleNamespace(cell=SimpleNamespace(paths=("perfbench",), config={}))
     assert reader.read(observed, {"what": "linear_attn_roofline", **args}, dense) is None

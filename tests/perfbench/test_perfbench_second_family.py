@@ -215,3 +215,133 @@ def test_the_cells_control_is_not_correct_under_its_tolerances(tmp_path):
     assert check["grad_sign_mass"] < check["tol_grad_sign_mass"]
     assert check["loss_scaled_err"] <= correct.LOSS_SCALED_TOL
     assert check["grad_sign_mass"] >= correct.GRAD_SIGN_MASS_TOL
+
+
+# ------------------------------------------------ a family's weights by rule (PR 35)
+
+#: sha256 over every leaf (key path, then float32 bytes) of the tiny tree as
+#: ``weights.py`` made it at PR 34, before any rule file could be named
+TINY_TREE_AT_PR_34 = {
+    ("tiny", "float32", 11): "eadef47dd05a7e0418c7cb1e821504f2ddfb8e48a2a9109fc2ccd015bc7273a1",
+    ("tiny", "bfloat16", 5): "a1b83de7706232cab564057b1ecf41cd6d2e8dc992e52469ae7c52efd95df91b",
+    ("tiny-latent-moe", "float32", 11):
+        "8da83a99ff9cfea0532794744d95d54d2c8b7a405f325f9d6a58e4b84964c6e0",
+}
+
+
+def tree_digest(tree, leave_out=()):
+    import hashlib
+
+    import jax
+
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if jax.tree_util.keystr(path) not in leave_out:
+            h.update(jax.tree_util.keystr(path).encode())
+            h.update(np.asarray(leaf, np.float32).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("preset, dtype, seed", TINY_TREE_AT_PR_34, ids=lambda v: str(v))
+def test_with_no_rule_file_the_seeded_weights_are_the_parents_bit_for_bit(preset, dtype, seed):
+    from distrl_llm_tpu.models.configs import PRESETS
+    from perfbench import weights
+
+    made = weights.make_base_params(PRESETS[preset], dtype, seed)
+    assert tree_digest(made) == TINY_TREE_AT_PR_34[preset, dtype, seed]
+    assert weights.load_rules(TINY["paths"], {"reference": "reference"}) == ()
+
+
+def test_a_rule_draws_the_leaf_it_names_and_leaves_every_other_as_it_was():
+    from distrl_llm_tpu.models.configs import PRESETS
+    from perfbench import weights
+
+    cfg = PRESETS["tiny-latent-moe"]
+    plain = weights.make_base_params(cfg, "float32", 11)
+    rules = (
+        {"leaf": "e_score_bias$", "draw": "constant", "value": 0.0},
+        {"leaf": "^layers/latent_moe/router$", "draw": "uniform", "low": -0.5, "high": 0.5},
+        {"leaf": "^layers/latent/wq$", "draw": "constant", "value": 9.0},
+        {"leaf": "/wq$", "draw": "normal", "std": 0.3, "mean": 2.0},  # the first match won
+    )
+    ruled = weights.make_base_params(cfg, "float32", 11, rules=rules)
+    named = ("['layers']['latent_moe']['e_score_bias']", "['layers']['latent_moe']['router']",
+             "['layers']['latent']['wq']", "['layers']['latent_moe']['wq']")
+    assert tree_digest(ruled, named) == tree_digest(plain, named)
+    assert tree_digest(ruled) != TINY_TREE_AT_PR_34["tiny-latent-moe", "float32", 11]
+    moe = ruled["layers"]["latent_moe"]
+    assert not np.asarray(moe["e_score_bias"]).any()
+    assert np.asarray(plain["layers"]["latent_moe"]["e_score_bias"]).any()  # Normal(0, 0.02)
+    router = np.asarray(moe["router"])
+    assert -0.5 <= router.min() < -0.4 and 0.4 < router.max() <= 0.5
+    assert (np.asarray(ruled["layers"]["latent"]["wq"]) == 9.0).all()
+    wq = np.asarray(moe["wq"])
+    assert abs(wq.mean() - 2.0) < 0.02 and abs(wq.std() - 0.3) < 0.02
+    # the same seed, the same values; a stacked leaf differs layer by layer
+    again = weights.make_base_params(cfg, "float32", 11, rules=rules)
+    assert tree_digest(again) == tree_digest(ruled)
+    assert not np.array_equal(np.asarray(moe["wq"][0]), np.asarray(moe["wq"][1]))
+
+
+RULE_FILES = {
+    "not-a-list": ({"leaf": "wq", "draw": "constant", "value": 1}, "a list of rules"),
+    "empty": ([], "a list of rules"),
+    "no-leaf": ([{"draw": "constant", "value": 1}], "needs a 'leaf' regex"),
+    "unknown-draw": ([{"leaf": "wq", "draw": "lognormal", "std": 1}], "needs a 'leaf' regex"),
+    "normal-without-std": ([{"leaf": "wq", "draw": "normal"}], "holds the numbers ('std',)"),
+    "uniform-with-std": ([{"leaf": "wq", "draw": "uniform", "low": 0, "high": 1, "std": 2}],
+                         "holds the numbers ('low', 'high')"),
+    "a-string-for-a-number": ([{"leaf": "wq", "draw": "constant", "value": "0"}],
+                              "holds the numbers ('value',)"),
+    "no-regex": ([{"leaf": "wq(", "draw": "constant", "value": 0}], "is no regex"),
+}
+
+
+@pytest.mark.parametrize("held, said", RULE_FILES.values(), ids=RULE_FILES)
+def test_a_rule_file_that_is_not_plain_rules_is_refused(tmp_path, held, said):
+    import re
+
+    from perfbench import weights
+
+    os.makedirs(tmp_path / "weight_rules")
+    with open(tmp_path / "weight_rules" / "bad.json", "w", encoding="utf-8") as f:
+        json.dump(held, f)
+    with pytest.raises(spec.SpecError, match=re.escape(said)):
+        weights.load_rules([str(tmp_path)], {"weight_rules": "bad"})
+
+
+def test_a_rule_that_names_no_leaf_and_a_file_that_is_not_there_are_refused():
+    from distrl_llm_tpu.models.configs import TINY as TINY_MODEL
+    from perfbench import weights
+
+    with pytest.raises(spec.SpecError, match=r"\['A_log\$'\] draw no leaf"):
+        weights.make_base_params(TINY_MODEL, "float32", 1, rules=(
+            {"leaf": "^layers/wq$", "draw": "constant", "value": 1.0},
+            {"leaf": "A_log$", "draw": "uniform", "low": -4.0, "high": -1.0}))
+    with pytest.raises(spec.SpecError, match="an earlier rule takes every leaf"):
+        weights.make_base_params(TINY_MODEL, "float32", 1, rules=(
+            {"leaf": "wq$", "draw": "constant", "value": 1.0},
+            {"leaf": "^layers/wq$", "draw": "constant", "value": 2.0}))
+    with pytest.raises(spec.SpecError, match="weight_rules/elsewhere.json"):
+        weights.load_rules(TINY["paths"], {"weight_rules": "elsewhere"})
+
+
+def test_the_counted_configuration_names_a_rule_file_and_its_cell_runs_under_it(tiny_bench_file):
+    """The seam end to end on the CPU: ``tiny-counted.json`` names
+    ``tiny/weight_rules/tiny-counted.json``, the learner driver draws ``bq`` by
+    it, and the cell is correct under its float32 tolerances. ``tiny.json``
+    names none."""
+    from perfbench import assembly, weights
+
+    cell = spec.load_cell(TINY, CHECKED)
+    rules = weights.load_rules(cell.paths, cell.config)
+    assert [(r["leaf"], r["draw"], r["std"]) for r in rules] == [("^layers/bq$", "normal", 0.1)]
+    assert weights.load_rules(cell.paths, spec.load_cell(TINY, "tiny.learner").config) == ()
+    model_cfg = assembly.model_config(cell.config)
+    plain = weights.make_base_params(model_cfg, "float32", 3)
+    ruled = weights.make_base_params(model_cfg, "float32", 3, rules=rules)
+    assert abs(np.asarray(plain["layers"]["bq"]).std() - 0.25) < 0.05
+    assert abs(np.asarray(ruled["layers"]["bq"]).std() - 0.1) < 0.02
+    assert tree_digest(ruled, ("['layers']['bq']",)) == tree_digest(plain, ("['layers']['bq']",))
+    line, notes = shared_cell(tiny_bench_file, CHECKED, 0)
+    assert line["correct"] is True and notes["check"]["ok"] is True

@@ -75,11 +75,118 @@ def test_config_file(config):
         held = json.load(f)
     assert held["source"] == config["source"]
     assert held["reduced"] == config["reduced"]
-    # a width is never cut: only depth may be named
-    assert set(config["reduced"]) <= {"num_hidden_layers"}
+    # depth, or one chip's share of a stated deployment; a width is never cut:
+    # the rule is spec.check_reduced's, which a run (spec.load_cell) calls too
+    from perfbench import spec
+
+    spec.check_reduced(held, config["file"])
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
     # the plain reference beside it
     assert os.path.isfile(os.path.join(REPO, "perfbench", held["reference"] + ".py"))
+
+
+#: one of 8 chips that share each layer: 40 of 320 routed experts, an eighth
+#: of a vocabulary of 196,608 (the sizes ISSUE 35 works out for its draw)
+SHARED = {
+    "num_hidden_layers": 4, "n_routed_experts": 40, "vocab_size": 24576,
+    "num_experts_per_tok": 8, "hidden_size": 4096, "moe_intermediate_size": 1280,
+    "reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+    "share": {"chips_per_layer": 8,
+              "published": {"n_routed_experts": 320, "vocab_size": 196608}},
+}
+
+
+def shared(reduced=None, share="keep", **sizes):
+    held = {**SHARED, **sizes}
+    if reduced is not None:
+        held["reduced"] = reduced
+    if share is None:
+        del held["share"]
+    elif share != "keep":
+        held["share"] = share
+    return held
+
+
+REDUCED_CASES = {
+    # accepted
+    "depth-alone": ({"reduced": ["num_hidden_layers"]}, None),
+    "nothing-cut": ({"reduced": []}, None),
+    "experts-and-vocabulary-over-8-chips": (SHARED, None),
+    "heads-as-a-share": (shared(
+        ["num_attention_heads", "num_key_value_heads"],
+        {"chips_per_layer": 8,
+         "published": {"num_attention_heads": 64, "num_key_value_heads": 8}},
+        num_attention_heads=8, num_key_value_heads=1), None),
+    "num_experts-is-a-count-too": (shared(
+        ["num_experts"], {"chips_per_layer": 4, "published": {"num_experts": 64}},
+        num_experts=16), None),
+    # refused, each by its own sentence
+    "hidden_size": (shared([*SHARED["reduced"], "hidden_size"]), "'hidden_size': a width is never cut"),
+    "moe_intermediate_size": (shared(["moe_intermediate_size"], None),
+                              "'moe_intermediate_size': a width is never cut"),
+    "num_experts_per_tok": (shared([*SHARED["reduced"], "num_experts_per_tok"]),
+                            "'num_experts_per_tok': a width is never cut"),
+    "head_dim-without-a-share": ({"reduced": ["head_dim"]}, "'head_dim': a width is never cut"),
+    "a-share-key-without-share": (shared(share=None), "has no 'share'"),
+    "share-without-a-share-key": (shared(["num_hidden_layers"]), "no key in 'reduced' that it cuts"),
+    "held-times-chips-is-not-published": (shared(n_routed_experts=48), "not the published 320"),
+    "four-experts-held": (shared(
+        share={"chips_per_layer": 8, "published": {"n_routed_experts": 32, "vocab_size": 196608}},
+        n_routed_experts=4), "holds 4 routed experts"),
+    "a-sixteenth-of-the-vocabulary": (shared(
+        share={"chips_per_layer": 16,
+               "published": {"n_routed_experts": 320, "vocab_size": 196608}},
+        n_routed_experts=20, vocab_size=12288), "an eighth of the vocabulary"),
+    "one-chip-a-layer": (shared(
+        share={"chips_per_layer": 1, "published": {"n_routed_experts": 40, "vocab_size": 24576}}),
+        "'chips_per_layer' is 1"),
+    "published-for-a-key-not-reduced": (shared(
+        share={"chips_per_layer": 8, "published": {
+            "n_routed_experts": 320, "vocab_size": 196608, "num_attention_heads": 64}}),
+        "one published value for each, and none else"),
+    "a-reduced-key-with-nothing-published": (shared(
+        share={"chips_per_layer": 8, "published": {"n_routed_experts": 320}}),
+        "one published value for each, and none else"),
+    "a-share-with-another-key": (shared(
+        share={**SHARED["share"], "stands_in_for": "the absent chips"}), "'share' is {"),
+}
+
+
+@pytest.mark.parametrize("held, refusal", REDUCED_CASES.values(), ids=REDUCED_CASES)
+def test_reduced_names_depth_or_one_chips_share_and_never_a_width(held, refusal):
+    """``spec.check_reduced``: what the guide's section 4 lets a configuration
+    cut. Every refusal says which rule, by a sentence of its own."""
+    from perfbench import spec
+
+    if refusal is None:
+        assert spec.check_reduced(held, "a.json") is None
+    else:
+        with pytest.raises(spec.SpecError, match=re.escape(refusal)) as said:
+            spec.check_reduced(held, "a.json")
+        assert str(said.value).startswith("a.json: ")
+
+
+@pytest.mark.parametrize("case", ["experts-and-vocabulary-over-8-chips", "hidden_size",
+                                  "held-times-chips-is-not-published"])
+def test_a_run_refuses_what_the_test_refuses(tmp_path, case):
+    """``spec.load_cell`` goes through the same function: a configuration the
+    tests would refuse never reaches a driver."""
+    from perfbench import spec
+    from tiny_spec import tiny_benchmark
+
+    held, refusal = REDUCED_CASES[case]
+    bench = tiny_benchmark()
+    entry = next(c for c in bench["configs"] if c["name"] == "tiny")
+    with open(os.path.join(REPO, entry["file"]), encoding="utf-8") as f:
+        tiny = json.load(f)
+    path = tmp_path / "tiny.json"
+    path.write_text(json.dumps({**tiny, **held}), encoding="utf-8")
+    entry["file"] = str(path)
+    if refusal is None:
+        assert spec.load_cell(bench, "tiny.learner").config["share"] == held["share"]
+    else:
+        with pytest.raises(spec.SpecError, match=re.escape(refusal)):
+            spec.load_cell(bench, "tiny.learner")
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
@@ -116,6 +223,10 @@ def test_per_layer_metric_has_its_file_and_its_reader(metric):
     held = spec.load_layer_metric(BENCH["paths"], metric["name"])
     for key in ("unit", "better", "source", "layer", "moves"):
         assert held[key] == metric[key], (metric["name"], key)
+    # one file of that name anywhere under the paths: a metric that moved left no copy
+    copies = [path for p in BENCH["paths"] for path in glob.glob(
+        os.path.join(REPO, p, "**", "layer_metrics", metric["name"] + ".json"), recursive=True)]
+    assert len(set(copies)) == 1, copies
     assert metric["source"] in SOURCES
     assert metric["moves"] in [m["name"] for m in BENCH["end_to_end"]]
     assert "bound" not in metric

@@ -239,9 +239,11 @@ def entry(key, message):
     return field(1, key) + field(2, message)
 
 
-def hand_encoded_space(scope_path="jit(f)/model/mlp/dot_general:"):
+def hand_encoded_space(scope_path="jit(f)/model/mlp/dot_general:", sync_at_ns=None):
     """One device plane: two instructions (one with a ``tf_op``), an ``XLA Ops``
-    line of three events starting at 5,000 ns, and a line that is not read."""
+    line of three events starting at 5,000 ns, and a line that is not read.
+    With ``sync_at_ns`` the host plane carries the harness's sync annotation
+    there, as a traced run's does."""
     scope_stat = field(1, 7) + field(5, scope_path)
     other_stat = field(1, 8) + field(4, 123)
     plane = (
@@ -260,6 +262,13 @@ def hand_encoded_space(scope_path="jit(f)/model/mlp/dot_general:"):
                 + field(4, field(1, 2) + field(2, 0) + field(3, 9_000_000)))
     )
     host = field(2, "/host:CPU") + field(3, field(2, "python3"))
+    if sync_at_ns is not None:
+        from perfbench import harness
+
+        host = (field(2, "/host:CPU")
+                + field(4, entry(1, field(1, 1) + field(2, harness.SYNC_EVENT)))
+                + field(3, field(2, "python3") + field(3, sync_at_ns)
+                        + field(4, field(1, 1) + field(2, 0) + field(3, 1_000))))
     return field(1, plane) + field(1, host)
 
 
@@ -428,6 +437,37 @@ def test_stale_executables_carry_no_scope_and_give_no_table():
 
 # ----------------------------------------------------------------- the readers
 
+def test_one_parse_of_the_file_serves_the_table_and_the_decode_window(tmp_path, monkeypatch):
+    """``loaded_for`` parses a run's trace once; ``table_for`` and the decode
+    window's readers (``seconds_in_spans``, which ``sala_work`` and
+    ``latent_moe_work`` call) cut that one. The wall clock runs 1,000,000 ns
+    ahead of the profiler's here."""
+    path = tmp_path / "run.xplane.pb"
+    path.write_bytes(hand_encoded_space(sync_at_ns=3_000))
+    ahead = 1_000_000
+    spans = [("engine/decode", ahead + 6_000, ahead + 9_250), ("engine/prefill", 0, 1)]
+    tracer = SimpleNamespace(xplane_path=lambda: str(path), sync_wall_ns=ahead + 3_000,
+                             window_wall_ns=(ahead, ahead + 20_000), host_spans=spans)
+    ctx = SimpleNamespace(tracer=tracer, cell=SimpleNamespace(paths=tuple(BENCH["paths"])))
+    parses = []
+    load = trace_scopes.load
+    monkeypatch.setattr(trace_scopes, "load", lambda p: parses.append(p) or load(p))
+    # the events of [6,000, 9,250) on the profiler's clock: 1,000 + 250 ns of model/mlp
+    assert trace_scopes.seconds_in_spans(ctx, "^model/mlp$", "engine/decode") == pytest.approx(1.25e-6)
+    assert trace_scopes.seconds_in_spans(ctx, "^unscoped$", "engine/decode") == pytest.approx(1.0e-6)
+    assert trace_scopes.seconds_in_spans(ctx, "^model/head$", "engine/decode") is None
+    assert trace_scopes.seconds_in_spans(ctx, "^model/mlp$", "engine/refill_decode") is None
+    assert trace_scopes.table_for(ctx)["rows_s"]["model/mlp"] == pytest.approx(2.5e-6)
+    assert parses == [str(path)]
+    assert trace_scopes.loaded_for(ctx)["offset"] == ahead
+    # an untraced run, and a trace without the sync annotation
+    assert trace_scopes.loaded_for(SimpleNamespace(tracer=None)) is None
+    bare = tmp_path / "bare.xplane.pb"
+    bare.write_bytes(hand_encoded_space())
+    tracer.xplane_path = lambda: str(bare)
+    assert trace_scopes.loaded_for(ctx)["offset"] is None
+    assert trace_scopes.seconds_in_spans(ctx, "^model/mlp$", "engine/decode") is None
+
 
 def fake_run(table, host_spans=()):
     """A run context whose trace's table is already reduced."""
@@ -556,8 +596,10 @@ def test_every_new_metric_resolves_from_its_files_and_is_in_the_benchmark():
         assert declared[name]["moves"] == held["moves"]
         assert held["reader"] in ("trace_scopes", "host_spans")
         assert callable(reader.read)
-    # appended after everything the benchmark had, in nobody's place
-    assert [m["name"] for m in BENCH["per_layer"]][-len(NEW):] == NEW
+    # one block, in order, wherever it stands: a later PR appends after it
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
 
 
 def test_a_cpu_rehearsal_line_leaves_the_new_metrics_out(tmp_path):
