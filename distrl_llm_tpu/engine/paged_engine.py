@@ -100,6 +100,10 @@ ENGINE_SPARSE_BLOCKS_VISIBLE = "engine/sparse_blocks_visible"    # counter
 # decode state like the block counters above.
 ENGINE_MOE_ASSIGNMENTS = "engine/moe_assignments"          # counter
 ENGINE_MOE_MAX_EXPERT_LOAD = "engine/moe_max_expert_load"  # counter
+# token-expert pairs the router chose over ALL the experts it scores (live
+# rows x experts_per_token), where a program holds one chip's share of them:
+# moe_assignments (pairs of experts HELD) over this is the share that landed here
+ENGINE_MOE_PAIRS_ROUTED = "engine/moe_pairs_routed"        # counter
 # absorbed latent attention (ops/latent_attention.py): live (row, page) pairs
 # a round's decode steps attended over, and live pages they fetched from the
 # pool (a page that a group's rows share is fetched once a group), summed over
@@ -166,11 +170,13 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
 
 def _count_mixer_stats(mixer) -> None:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
-    layers' blocks; ``mixer["moe_stats"]``: the expert layers' pairs;
-    ``mixer["latent_stats"]``: absorbed attention's pages) with telemetry."""
+    layers' blocks; ``mixer["moe_stats"]`` / ``["moe_routed"]``: the expert
+    layers' pairs, held here / chosen over all experts; ``mixer["latent_stats"]``:
+    absorbed attention's pages) with telemetry."""
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
+        ("moe_routed", (ENGINE_MOE_PAIRS_ROUTED,)),
         ("latent_stats", (ENGINE_LATENT_PAGES_ATTENDED, ENGINE_LATENT_PAGES_READ)),
     ):
         if mixer is not None and key in mixer:
@@ -289,7 +295,7 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
         cache = {
             # a latent layer's pages are one array: there is no V pool
             "k": pool(), "v": () if cfg.latent else pool(),
-            "lin": mixer["lin"], "pooled": mixer["pooled"],
+            **_row_states(mixer),
         }
     table = jnp.asarray(make_page_table(b, pad_to, page_size))
     last = jnp.maximum(real_len - 1, 0)
@@ -320,14 +326,24 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
     with jax.named_scope(telemetry.MODEL_HEAD):
         head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
         logits = linear(hidden, head).astype(jnp.float32)
-    mixer = {**mixer, "lin": cache["lin"], "pooled": cache["pooled"]}
+    mixer = {**mixer, **_row_states(cache)}
     return cache["k"], cache["v"], logits, real_len, mixer
 
 
+def _row_states(mixer) -> dict:
+    """The entries of a slot state (or a cache) that hold one array a row a
+    layer: lightning states, pooled keys, delta-rule states and convolution
+    tails (``models/hybrid.py::ROW_STATES``). The round's counters are not."""
+    from distrl_llm_tpu.models.hybrid import ROW_STATES
+
+    return {name: mixer[name] for name in ROW_STATES if name in mixer}
+
+
 def _hand_mixer(mixer, prompt_mixer, prompt_of, admit_mask):
-    """Admitted slots take their prompt's lightning states and pooled keys
-    (a candidate aliases its prompt's K/V pages; what is not in pages is
-    copied). Other slots and the round's counter are kept."""
+    """Admitted slots take their prompt's row states: lightning states and
+    pooled keys, delta-rule states and convolution tails (a candidate aliases
+    its prompt's K/V pages; what is not in pages is copied). Other slots and
+    the round's counters are kept."""
     if mixer is None:
         return None
 
@@ -337,8 +353,8 @@ def _hand_mixer(mixer, prompt_mixer, prompt_of, admit_mask):
 
     return {
         **mixer,
-        "lin": tuple(map(hand, mixer["lin"], prompt_mixer["lin"])),
-        "pooled": tuple(map(hand, mixer["pooled"], prompt_mixer["pooled"])),
+        **{name: tuple(map(hand, held, prompt_mixer[name]))
+           for name, held in _row_states(mixer).items()},
     }
 
 
@@ -490,12 +506,11 @@ def _paged_fanout(prompt_k, prompt_v, last_logits, real_len, row_alive,
         seq_lengths=jnp.repeat(real_len, n, axis=0),
         k_pages=k_pages,
         v_pages=v_pages,
-        # each candidate starts from its prompt's states and pooled keys
+        # each candidate starts from its prompt's row states
         mixer=None if prompt_mixer is None else {
             **prompt_mixer,
-            "lin": tuple(jnp.repeat(x, n, axis=0) for x in prompt_mixer["lin"]),
-            "pooled": tuple(
-                jnp.repeat(x, n, axis=0) for x in prompt_mixer["pooled"]),
+            **{name: tuple(jnp.repeat(x, n, axis=0) for x in held)
+               for name, held in _row_states(prompt_mixer).items()},
         },
     )
     return state, page_indices

@@ -24,6 +24,18 @@ K or V per head); the first ``first_dense_layers`` layers have a dense gated
 MLP (kind "latent"), the rest a router over ``n_routed_experts`` experts and
 one shared expert (kind "latent_moe"). It runs through ``models/hybrid.py``
 like any model whose layers are not all alike.
+
+A gated delta-rule model with routed experts (``solar_open2``) has two layer
+kinds in a published period: "softmax" (gated GQA attention without RoPE, K/V
+pages) and "delta" (linear attention by a gated delta rule with a per-channel
+decay behind short convolutions: a float32 matrix state and a convolution
+tail a slot, ``ops/delta_attention.py``), each followed by routed experts
+beside one shared expert. **A share.** Its configuration may state ONE CHIP'S
+share of a deployment that divides each layer over several chips:
+``n_routed_experts`` is then the experts this program HOLDS, ``router_experts``
+the published width the router scores and chooses over, ``expert_shard`` which
+contiguous run of ids is held (``held_experts``); pairs routed elsewhere add
+nothing here. With no share the two counts are equal and every expert is held.
 """
 
 from __future__ import annotations
@@ -32,11 +44,25 @@ import math
 from dataclasses import dataclass
 
 #: published ``mixer_types`` entry -> the kind the program names its stacks by
-MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
+               # solar_open2 publishes ``gqa_layers``; from_hf_config names the rest
+               "gqa": "softmax", "kda": "delta"}
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
+    "solar_open2",
 )
+#: layer kinds whose second half is routed experts beside a shared expert
+EXPERT_KINDS = ("latent_moe", "softmax", "delta")
+#: what a slot holds for a layer of each kind, for a refusal
+_STATE_NAMES = {
+    "sparse": "a selector cache of pooled keys",
+    "lightning": "a recurrent float32 state",
+    "latent": "one latent row a token in place of K and V per head",
+    "latent_moe": "one latent row a token in place of K and V per head",
+    "softmax": "K/V pages for its softmax layers only",
+    "delta": "a float32 delta-rule state and a convolution tail",
+}
 #: layer kind -> the published name a refusal gives it
 _LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
 
@@ -100,12 +126,29 @@ class ModelConfig:
     first_dense_layers: int = 0  # first_k_dense_replace
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # ---- one chip's share of the routed experts (module docstring). 0 = the
+    # router is as wide as the experts held: every expert is here
+    router_experts: int = 0
+    expert_shard: int = 0  # which run of n_routed_experts ids is held
+    # ---- gated delta-rule layers (solar_open2's linear_attn_config, kda_*)
+    delta_heads: int = 0  # q, k and v heads alike
+    delta_head_dim: int = 0  # d_k = d_v
+    delta_conv_size: int = 4  # short_conv_kernel_size
+    delta_low_rank: int = 0  # inner width of the decay's and the gate's pair
+    delta_beta_scale: float = 1.0  # 2 with kda_allow_neg_eigval
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
                 f"hidden_act must be silu/gelu_tanh, got {self.hidden_act!r}"
             )
+        if self.router_experts and (
+                self.router_experts % max(self.n_routed_experts, 1)
+                or self.expert_shard * self.n_routed_experts >= self.router_experts):
+            raise ValueError(
+                f"a chip holds run {self.expert_shard} of {self.n_routed_experts} "
+                f"routed experts, and the router scores {self.router_experts}: "
+                "the held run must be one of a whole number of runs")
         if self.mixer_types is not None:
             unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
             if unknown:
@@ -133,6 +176,13 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def delta_moe(self) -> bool:
+        """True for a gated delta-rule model (``solar_open2``): "softmax" and
+        "delta" layers, each with routed experts."""
+        return self.mixer_types is not None and bool(
+            {"gqa", "kda"} & set(self.mixer_types[: self.num_layers]))
+
+    @property
     def hybrid(self) -> bool:
         """True where a layer is not the dense GQA layer: ``mixer_types`` is
         set, or attention is latent. Such a model runs through
@@ -153,6 +203,24 @@ class ModelConfig:
         return tuple(MIXER_KINDS[m] for m in self.mixer_types[: self.num_layers])
 
     @property
+    def router_width(self) -> int:
+        """Experts the router scores and chooses among: the published count."""
+        return self.router_experts or self.n_routed_experts
+
+    @property
+    def held_experts(self) -> tuple[int, ...] | None:
+        """Ids of the routed experts this program holds, in the order its
+        stacks keep them, or None: all of them."""
+        if self.router_width == self.n_routed_experts:
+            return None
+        first = self.expert_shard * self.n_routed_experts
+        return tuple(range(first, first + self.n_routed_experts))
+
+    @property
+    def delta_dim(self) -> int:
+        return self.delta_heads * self.delta_head_dim
+
+    @property
     def latent_dim(self) -> int:
         """Values a cached token holds in a latent layer: ``[c, k_pe]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim
@@ -170,8 +238,9 @@ class ModelConfig:
     @property
     def paged_layers(self) -> int:
         """Layers that keep pages in the paged engine's pool."""
-        return self.num_layers if self.latent else (
-            self.kind_count("sparse") if self.hybrid else self.num_layers)
+        if self.latent or not self.hybrid:
+            return self.num_layers
+        return self.kind_count("sparse") + self.kind_count("softmax")
 
     def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
         """Shape of one layer's page array: K beside V ``[K, pages, page,
@@ -210,17 +279,23 @@ class ModelConfig:
                 _LATENT_NAMES[k] for k in dict.fromkeys(self.layer_kinds))
         return ", ".join(sorted(set(self.mixer_types or ())))
 
+    @property
+    def slot_state_names(self) -> str:
+        """What a slot holds for this model's layers that is no K/V of one
+        kind for every layer, for a refusal."""
+        return " and ".join(dict.fromkeys(
+            _STATE_NAMES[k] for k in self.layer_kinds if k in _STATE_NAMES))
+
     def refuse_hybrid(self, what: str) -> None:
-        """Raise, naming the mixer kinds, where ``what`` holds K/V of one
-        kind for every layer and so cannot hold this model. The single owner
-        of that sentence for every engine and feature."""
+        """Raise, naming the mixer kinds and the state they keep, where
+        ``what`` holds K/V of one kind for every layer and so cannot hold
+        this model. The single owner of that sentence for every engine and
+        feature."""
         if self.hybrid:
             raise ValueError(
                 f"{what} cannot hold a model with {self.mixer_names} layers: it "
                 "keeps one kind of K/V for every layer, and these layers keep "
-                + ("one latent row a token in place of K and V per head. "
-                   if self.latent else "a recurrent state or a selector cache. ")
-                + "Use engine_impl='paged' without it."
+                f"{self.slot_state_names}. Use engine_impl='paged' without it."
             )
 
     @property
@@ -291,6 +366,8 @@ class ModelConfig:
         )
         if self.latent:
             return self._latent_param_count(self.experts_per_token)
+        if self.delta_moe:
+            return self._delta_moe_param_count(self.experts_per_token)
         if not self.hybrid:
             return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
         sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
@@ -322,12 +399,32 @@ class ModelConfig:
             + d * self.vocab_size
         )
 
+    def _delta_moe_param_count(self, experts: int) -> int:
+        """Matmul parameters of a ``solar_open2`` model with ``experts`` routed
+        experts counted a layer (``_latent_param_count`` says which). Under a
+        share the experts HELD are the bytes; a token's operations are its
+        ``experts_per_token`` wherever they are held."""
+        d, r = self.hidden_size, self.delta_low_rank
+        moe = 3 * d * (
+            experts * self.moe_intermediate_size + self.shared_expert_size
+        ) + d * self.router_width
+        softmax = 3 * d * self.q_dim + 2 * d * self.kv_dim  # q, o, gate; k, v
+        delta = 4 * d * self.delta_dim + 2 * (d * r + r * self.delta_dim) + (
+            d * self.delta_heads)
+        return (
+            self.kind_count("softmax") * (softmax + moe)
+            + self.kind_count("delta") * (delta + moe)
+            + d * self.vocab_size
+        )
+
     @property
     def total_matmul_param_count(self) -> int:
         """``matmul_param_count`` over every expert HELD, not only those a
         token runs: what a decode step of many rows reads."""
         if self.latent:
             return self._latent_param_count(self.n_routed_experts)
+        if self.delta_moe:
+            return self._delta_moe_param_count(self.n_routed_experts)
         return self.matmul_param_count
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
@@ -335,6 +432,12 @@ class ModelConfig:
         path plus the attention score/value dot-products (2 FLOPs × q_dim
         keys-side + values-side) at the mean resident KV length."""
         attn = 4.0 * self.num_layers * self.q_dim * mean_kv_len
+        if self.kind_count("delta"):
+            # softmax layers attend over the context; a delta-rule layer's
+            # token costs its state whatever the context (decay, S^T k, the
+            # outer product, S^T q: 7 D^2 a head)
+            attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
+                7.0 * self.kind_count("delta") * self.delta_dim * self.delta_head_dim)
         return 2.0 * self.matmul_param_count + attn
 
     def train_flops_per_token(self, seq_len: int) -> float:
@@ -349,6 +452,8 @@ class ModelConfig:
         ``from_hf_config`` as (used by HF-format snapshot export)."""
         if self.latent:
             return "deepseek_v3"
+        if self.delta_moe:
+            return "solar_open2"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -425,6 +530,8 @@ class ModelConfig:
         if mt == "deepseek_v3":
             hybrid = _latent_fields(get)
             head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
+        if mt == "solar_open2":
+            hybrid = _delta_moe_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -449,6 +556,17 @@ class ModelConfig:
         )
 
 
+def _refuse_router_variants(get, refuse) -> None:
+    """The routers ``models/moe.py`` does not run, for every family with
+    DeepSeek-V3's key names."""
+    if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
+        refuse("n_group" if get("n_group", 1) != 1 else "topk_group",
+               "grouped routing (choose groups, then experts inside them) is "
+               "not implemented; n_group and topk_group must be 1")
+    if str(get("scoring_func", "sigmoid")) != "sigmoid":
+        refuse("scoring_func", "the router scores by sigmoid only")
+
+
 def _latent_fields(get) -> dict:
     """The ``deepseek_v3`` keys as ``ModelConfig`` fields. A variant that is
     not implemented is REFUSED by name: every key this function did not read
@@ -460,12 +578,7 @@ def _latent_fields(get) -> dict:
     if get("q_lora_rank") is not None:
         refuse("q_lora_rank", "the low-rank query path (q_a_proj, q_a_layernorm, "
                "q_b_proj) is not implemented; only a plain q_proj is")
-    if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
-        refuse("n_group" if get("n_group", 1) != 1 else "topk_group",
-               "grouped routing (choose groups, then experts inside them) is "
-               "not implemented; n_group and topk_group must be 1")
-    if str(get("scoring_func", "sigmoid")) != "sigmoid":
-        refuse("scoring_func", "the router scores by sigmoid only")
+    _refuse_router_variants(get, refuse)
     if str(get("topk_method", "noaux_tc")) != "noaux_tc":
         refuse("topk_method", "the router chooses by score plus "
                "e_score_correction_bias (noaux_tc) only")
@@ -485,6 +598,63 @@ def _latent_fields(get) -> dict:
         experts_per_token=int(get("num_experts_per_tok") or 0),
         moe_intermediate_size=int(get("moe_intermediate_size") or 0),
         first_dense_layers=int(get("first_k_dense_replace", 0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _delta_moe_fields(get) -> dict:
+    """The ``solar_open2`` keys as ``ModelConfig`` fields: the layer pattern
+    from ``gqa_layers`` over the layers that are run, ``linear_attn_config``,
+    the experts, and the share (module docstring). A variant that is not
+    implemented is REFUSED by name, as ``_latent_fields`` does."""
+    def refuse(key: str, why: str):
+        raise ValueError(
+            f"solar_open2 with {key}={get(key)!r} is not supported: {why}")
+
+    linear = dict(get("linear_attn_config") or {})
+    if not linear or get("gqa_layers") is None:
+        raise ValueError(
+            "model_type 'solar_open2' needs its gqa_layers list and its "
+            "linear_attn_config")
+    if get("use_rope", False):
+        refuse("use_rope", "the softmax layers rotate nothing (use_rope false); "
+               "RoPE in them is not implemented")
+    if get("kda_use_full_proj", False):
+        refuse("kda_use_full_proj", "the decay and the output gate are low-rank "
+               "pairs; full projections are not implemented")
+    if get("first_k_dense_replace", 0) != 0:
+        refuse("first_k_dense_replace", "every layer is an expert layer; a dense "
+               "MLP layer is not implemented for this family")
+    _refuse_router_variants(get, refuse)
+    heads = int(linear.get("num_heads") or get("num_attention_heads"))
+    if linear.get("num_kv_heads") not in (None, heads):
+        raise ValueError(
+            f"solar_open2 with linear_attn_config.num_kv_heads="
+            f"{linear['num_kv_heads']!r} is not supported: the delta-rule layers' "
+            "k and v have as many heads as q")
+    layers = int(get("num_hidden_layers"))
+    softmax = {int(i) for i in get("gqa_layers")}
+    held = int(get("n_routed_experts") or 0)
+    share = get("share")
+    published = dict((share or {}).get("published") or {})
+    width = int(published.get("n_routed_experts", held))
+    head_dim = int(linear.get("head_dim") or get("head_dim"))
+    return dict(
+        mixer_types=tuple("gqa" if i in softmax else "kda" for i in range(layers)),
+        attn_use_rope=False,
+        attn_output_gate=bool(get("use_gqa_gate", False)),
+        delta_heads=heads,
+        delta_head_dim=head_dim,
+        delta_conv_size=int(linear.get("short_conv_kernel_size", 4)),
+        delta_low_rank=head_dim,
+        delta_beta_scale=2.0 if get("kda_allow_neg_eigval", False) else 1.0,
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
+        n_shared_experts=int(get("n_shared_experts") or 0),
+        experts_per_token=int(get("num_experts_per_tok") or 0),
+        moe_intermediate_size=int(get("moe_intermediate_size") or 0),
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
     )
@@ -514,6 +684,17 @@ TINY_LATENT_MOE = ModelConfig(
     qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8, n_shared_experts=1,
     experts_per_token=2, moe_intermediate_size=32, first_dense_layers=1,
     routed_scaling_factor=2.446,
+)
+
+# a gated delta-rule model with routed experts at a size the CPU tests run: a
+# period of four (softmax at 0, delta rule at 1-3), 2 of 16 experts a chip of 8
+TINY_DELTA_MOE = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=4,
+    num_heads=4, num_kv_heads=2, head_dim=16, rms_norm_eps=1e-5,
+    mixer_types=("gqa", "kda", "kda", "kda"), attn_use_rope=False,
+    attn_output_gate=True, delta_heads=4, delta_head_dim=16, delta_low_rank=16,
+    delta_beta_scale=2.0, n_routed_experts=2, router_experts=16,
+    n_shared_experts=1, experts_per_token=4, moe_intermediate_size=32,
 )
 
 QWEN2_0_5B = ModelConfig(
@@ -566,6 +747,7 @@ GEMMA_7B = ModelConfig(
 PRESETS: dict[str, ModelConfig] = {
     "tiny": TINY,
     "tiny-latent-moe": TINY_LATENT_MOE,
+    "tiny-delta-moe": TINY_DELTA_MOE,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

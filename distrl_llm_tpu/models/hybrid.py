@@ -60,6 +60,28 @@ fullest expert's) and ``latent_stats`` (the (row, page) pairs absorbed
 attention covered, the pages it fetched: a page that a group's rows share is
 fetched once, ``ops/latent_attention.py``).
 
+**A gated delta rule with routed experts** (``solar_open2``) is two more
+kinds, "softmax" and "delta", each followed by routed experts told which
+experts this program HOLDS (``cfg.held_experts``: one chip's share of a layer)
+beside one shared expert (``_mlp_half``), with no RoPE anywhere::
+
+    softmax: q [T, H, D], k, v [T, K, D] = W h;  o = causal softmax attention
+             y = W_o(o * sigmoid(W_g h))
+    delta:   [q', k', v] = silu(conv4([W_q h, W_k h, W_v h]))   causal, depth-wise
+             q = l2norm(q') / sqrt(D);  k = l2norm(k')          a head
+             g = -exp(A_log) * softplus(W_fb W_fa h + dt_bias)  [T, H, D], a = exp(g)
+             beta = beta_scale * sigmoid(W_b h)                 [T, H]
+             S_t = (I - beta k k^T) diag(a) S_{t-1} + beta k v^T;  o_t = S_t^T q_t
+             y = W_o(RMSNorm_D(o) * sigmoid(W_gb W_ga h))
+    x <- x + y;   x <- x + Shared(h') + sum_{k held here} w_k E_k(h')
+
+A softmax layer keeps K/V pages (decode through ``ops/paged.py``'s kernel, a
+prefill segment over the row's pages a few at a time); a delta layer keeps a
+THIRD kind of slot state: ``delta`` (a float32 ``[B, H, D, D]`` state) and
+``conv`` (the last three tokens' ``[W_q h, W_k h, W_v h]``, ``[B, 3, 3 H D]``),
+tuples over the delta layers. ``moe_routed`` [1] int32 counts the pairs the
+router chose over ALL experts (``moe_stats`` counts those of experts held).
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -84,6 +106,9 @@ from distrl_llm_tpu.models.transformer import (
 )
 from distrl_llm_tpu.models.moe import moe_half
 from distrl_llm_tpu.ops.attention import attention
+from distrl_llm_tpu.ops.delta_attention import (
+    delta_chunked, delta_step, l2norm, short_conv,
+)
 from distrl_llm_tpu.ops.latent_attention import (
     absorbed_output, absorbed_paged_attention, absorbed_query,
     expanded_attention, expanded_finish, expanded_start,
@@ -100,6 +125,12 @@ Params = dict[str, Any]
 #: for that row alone, and rows that walk their tables together
 LATENT_DECODE_PAGES = 8
 LATENT_DECODE_ROWS = 16
+#: pages of keys a softmax layer's prefill segment scores at a time (the
+#: scores of a segment of 8 x 1,024 queries over 64 heads: 0.5 GiB at 2)
+SOFTMAX_SEGMENT_PAGES = 2
+#: the entries of a slot's state that hold one array a ROW for each layer of a
+#: kind (tuples): what a candidate is handed from its prompt
+ROW_STATES = ("lin", "pooled", "delta", "conv")
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -146,7 +177,51 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             )
         return p
 
+    def expert_half(n: int) -> Params:
+        """An expert layer's second half: the shared expert under the MLP's
+        names, the router at its published width, the experts HELD."""
+        d, fm, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts
+        p = {
+            "mlp_norm": jnp.ones((n, d), dtype),
+            "router": init((n, d, cfg.router_width)),
+            "e_score_bias": jnp.zeros((n, cfg.router_width), dtype),
+            "experts_gate": init((n, held, d, fm)),
+            "experts_up": init((n, held, d, fm)),
+            "experts_down": init((n, held, fm, d)),
+        }
+        f = cfg.shared_expert_size
+        if f:
+            p.update(w_gate=init((n, d, f)), w_up=init((n, d, f)),
+                     w_down=init((n, f, d)))
+        return p
+
+    def mixer_stack(n: int, q_dim: int, kv_dim: int) -> Params:
+        d = cfg.hidden_size
+        return {
+            "attn_norm": jnp.ones((n, d), dtype),
+            "wq": init((n, d, q_dim)), "wk": init((n, d, kv_dim)),
+            "wv": init((n, d, kv_dim)), "wo": init((n, q_dim, d)),
+        }
+
     layers: Params = {}
+    if cfg.kind_count("softmax"):
+        n = cfg.kind_count("softmax")
+        layers["softmax"] = {**mixer_stack(n, cfg.q_dim, cfg.kv_dim), **expert_half(n)}
+        if cfg.attn_output_gate:
+            layers["softmax"]["wg"] = init((n, cfg.hidden_size, cfg.q_dim))
+    if cfg.kind_count("delta"):
+        n, d, r, wide = (cfg.kind_count("delta"), cfg.hidden_size, cfg.delta_low_rank,
+                         cfg.delta_dim)
+        layers["delta"] = {
+            **mixer_stack(n, wide, wide), **expert_half(n),
+            "conv": init((n, cfg.delta_conv_size, 3 * wide)),
+            "wf_a": init((n, d, r)), "wf_b": init((n, r, wide)),
+            "A_log": jnp.zeros((n, cfg.delta_heads), dtype),
+            "dt_bias": jnp.zeros((n, wide), dtype),
+            "wb": init((n, d, cfg.delta_heads)),
+            "wg_a": init((n, d, r)), "wg_b": init((n, r, wide)),
+            "head_norm": jnp.ones((n, cfg.delta_head_dim), dtype),
+        }
     for kind in ("latent", "latent_moe"):
         if cfg.kind_count(kind):
             layers[kind] = latent_stack(cfg.kind_count(kind), kind == "latent_moe")
@@ -167,10 +242,23 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
 def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                      cache_dtype=jnp.bfloat16) -> Params:
     """What a slot holds beside its K/V pages: a float32 state per lightning
-    layer, the selector's pooled keys per sparse layer, the round's counter."""
+    layer, the selector's pooled keys per sparse layer, a float32 state and a
+    convolution tail per delta-rule layer, the round's counters. The entries
+    named in ``ROW_STATES`` are tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counter
         return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
                 "latent_stats": jnp.zeros((2,), jnp.int32)}
+    if cfg.delta_moe:
+        h, d, n = cfg.delta_heads, cfg.delta_head_dim, cfg.kind_count("delta")
+        return {
+            "lin": (), "pooled": (),
+            "delta": tuple(jnp.zeros((rows, h, d, d), jnp.float32) for _ in range(n)),
+            "conv": tuple(
+                jnp.zeros((rows, cfg.delta_conv_size - 1, 3 * cfg.delta_dim), cache_dtype)
+                for _ in range(n)),
+            "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_routed": jnp.zeros((1,), jnp.int32),
+        }
     h, d = cfg.lightning_heads, cfg.lightning_head_dim
     pooled = (rows, pooled_count(max_tokens, cfg), cfg.num_kv_heads, cfg.head_dim)
     return {
@@ -203,6 +291,16 @@ def _mode(kv_cache, s: int, flags: dict) -> str:
             "(a page-aligned prefill segment)"
         )
     return "decode"
+
+
+def _write_segment_pages(pages, new, dest, page_size: int):
+    """A page-aligned segment's K or V ``new [B, S, K, hd]`` written whole into
+    the pages ``dest [B, S // page_size]`` of a pool ``[K, pages, ps, hd]``."""
+    b, s, kv, hd = new.shape
+    per = s // page_size
+    tiles = new.reshape(b, per, page_size, kv, hd)
+    tiles = tiles.transpose(3, 0, 1, 2, 4).reshape(kv, b * per, page_size, hd)
+    return pages.at[:, dest.reshape(-1)].set(tiles.astype(pages.dtype))
 
 
 def _sparse_mix(q, k, v, cache, *, cfg, mode, env):
@@ -239,15 +337,8 @@ def _sparse_mix(q, k, v, cache, *, cfg, mode, env):
     start = env["segment_start"]
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, s // ps, axis=1)
-
-        def write(pages, new):
-            tiles = new.reshape(b, s // ps, ps, new.shape[2], new.shape[3])
-            tiles = tiles.transpose(3, 0, 1, 2, 4).reshape(
-                new.shape[2], b * (s // ps), ps, new.shape[3]
-            )
-            return pages.at[:, dest.reshape(-1)].set(tiles.astype(pages.dtype))
-
-        pages_k, pages_v = write(pages_k, k), write(pages_v, v)
+        pages_k = _write_segment_pages(pages_k, k, dest, ps)
+        pages_v = _write_segment_pages(pages_v, v, dest, ps)
         ctx_k = gather_pages_dense(pages_k, idx, dtype=q.dtype)
         ctx_v = gather_pages_dense(pages_v, idx, dtype=q.dtype)
     with jax.named_scope(telemetry.MODEL_SPARSE_SELECT):
@@ -276,6 +367,13 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     if kind in ("latent", "latent_moe"):
         return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
                              mode=mode, env=env, proj=proj, lora_scale=lora_scale)
+    if kind in ("softmax", "delta"):
+        mix = _softmax_mix if kind == "softmax" else _delta_mix
+        x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
+                       lora_scale=lora_scale)
+        x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
+                                lora_scale=lora_scale)
+        return x, cache, stats
     c = jnp.asarray(cfg.residual_scale, x.dtype)
     sparse = kind == "sparse"
     heads, kv_heads, hd = (
@@ -308,6 +406,148 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale,
                   residual_scale=c)
     return x, cache, stats
+
+
+def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale):
+    """An expert layer's second half: the routed experts HELD here
+    (``cfg.held_experts``; the router scores them all) beside the shared
+    expert. Returns (x, the layer's [2] stats)."""
+    with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+    routed, stats = moe_half(h, p, cfg, held=cfg.held_experts, alive=env.get("alive"))
+    if "w_gate" in p:  # the shared expert: x + S(h)
+        x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    return x + routed, stats
+
+
+def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
+    """A prefill segment's causal attention over the rows' PAGES (the
+    segment's own are written already), ``SOFTMAX_SEGMENT_PAGES`` pages of
+    keys at a time under a running softmax: the scores of all of a 2k-token
+    context at once would not fit. ``q [B, S, H, hd]`` -> ``[B, S, H, hd]``."""
+    b, s, heads, hd = q.shape
+    kv = pages_k.shape[0]
+    per = max(d for d in range(1, SOFTMAX_SEGMENT_PAGES + 1)
+              if (s // page_size) % d == 0)
+    qg = q.reshape(b, s, kv, heads // kv, hd) * jnp.asarray(hd ** -0.5, q.dtype)
+
+    def keys(pages, at):  # [K, B, per, ps, hd] -> [B, per * ps, K, hd]
+        return pages[:, at].transpose(1, 2, 3, 0, 4).reshape(b, per * page_size, kv, hd)
+
+    def fold(j, carry):
+        m, l, acc = carry
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)
+            k, v = keys(pages_k, at).astype(q.dtype), keys(pages_v, at).astype(q.dtype)
+        scores = jnp.einsum("bskgd,bjkd->bkgsj", qg, k,
+                            preferred_element_type=jnp.float32)
+        seen = (j * per * page_size + jnp.arange(per * page_size))[None, None, :] <= (
+            q_pos[:, :, None])  # [B, S, j]
+        scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
+        m_new = jnp.maximum(m, scores.max(-1))
+        # a query that has seen no key yet keeps a finite maximum
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        w = jnp.exp(scores - safe[..., None])
+        fix = jnp.exp(jnp.where(jnp.isfinite(m), m - safe, -jnp.inf))
+        acc = acc * fix[..., None] + jnp.einsum(
+            "bkgsj,bjkd->bkgsd", w.astype(q.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l * fix + w.sum(-1), acc
+
+    shape = (b, kv, heads // kv, s)
+    start_carry = (jnp.full(shape, -jnp.inf, jnp.float32), jnp.zeros(shape, jnp.float32),
+                   jnp.zeros(shape + (hd,), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(
+        0, (start + s) // (per * page_size), fold, start_carry)
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, heads, hd).astype(q.dtype)
+
+
+def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A gated softmax layer without RoPE: (x + y, (pages_k, pages_v) or None)."""
+    from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
+
+    b, s, _ = x.shape
+    heads, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    if cfg.attn_use_rope:
+        raise NotImplementedError("softmax layers with RoPE (use_rope)")
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, hd)
+        k = proj(h, p, lora, "wk", "bk", lora_scale).reshape(b, s, kv, hd)
+        v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(b, s, kv, hd)
+    if mode == "full":
+        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+            o = attention(q, k, v, None, impl=env["attn_impl"], key_valid=env["valid"])
+    elif mode == "decode":
+        pages_k, pages_v = cache
+        idx, ps, lengths = env["page_indices"], env["page_size"], env["lengths"]
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            pages_k = write_token_to_pages(pages_k, k[:, 0], lengths, idx, ps)
+            pages_v = write_token_to_pages(pages_v, v[:, 0], lengths, idx, ps)
+        o = paged_attention_op(
+            q[:, 0], pages_k, pages_v, lengths + 1, idx, impl=env["paged_impl"],
+            pages_per_block=env["pages_per_block"])[:, None]
+        cache = (pages_k, pages_v)
+    else:  # one page-aligned segment of a prefill, every row at ``start``
+        pages_k, pages_v = cache
+        idx, ps, start = env["page_indices"], env["page_size"], env["segment_start"]
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, s // ps, axis=1)
+            pages_k = _write_segment_pages(pages_k, k, dest, ps)
+            pages_v = _write_segment_pages(pages_v, v, dest, ps)
+        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+            o = _segment_softmax(q, pages_k, pages_v, idx, env["q_pos"], start, ps)
+        cache = (pages_k, pages_v)
+    o = o.reshape(b, s, heads * hd)
+    if "wg" in p:
+        with jax.named_scope(telemetry.MODEL_ATTN_GATE):
+            o = o * jax.nn.sigmoid(linear(h, p["wg"]))
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        return x + proj(o, p, lora, "wo", "bo", lora_scale), cache
+
+
+def _delta_gate(h, p):
+    """A delta-rule layer's output gate, ``sigmoid(W_gb W_ga h)``: a low-rank pair."""
+    return jax.nn.sigmoid(linear(linear(h, p["wg_a"]), p["wg_b"]))
+
+
+def _delta_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A gated delta-rule layer: (x + y, (state, tail) or None)."""
+    b, s, _ = x.shape
+    heads, hd, wide = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_dim
+    state, tail = cache if cache is not None else (None, None)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        qkv = jnp.concatenate(
+            [proj(h, p, lora, name, None, lora_scale) for name in ("wq", "wk", "wv")],
+            axis=-1)
+    with jax.named_scope(telemetry.MODEL_SHORT_CONV):
+        mixed, kept = short_conv(
+            qkv, p["conv"], None if mode == "decode" else env["valid"], tail)
+        tail = None if tail is None else kept.astype(tail.dtype)  # the cache's type
+        mixed = jax.nn.silu(mixed).reshape(b, s, 3, heads, hd)
+    with jax.named_scope(telemetry.MODEL_DELTA_ATTN):
+        q = l2norm(mixed[:, :, 0]) * hd ** -0.5
+        k = l2norm(mixed[:, :, 1])
+        v = mixed[:, :, 2]
+        rate = linear(linear(h, p["wf_a"]), p["wf_b"]).astype(jnp.float32) + (
+            p["dt_bias"].astype(jnp.float32))
+        g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+            rate).reshape(b, s, heads, hd)
+        beta = cfg.delta_beta_scale * jax.nn.sigmoid(
+            linear(h, p["wb"]).astype(jnp.float32))
+        if mode == "decode":
+            o, state = delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+            o = o[:, None]
+        else:
+            o, state = delta_chunked(q, k, v, g, beta, env["valid"], state=state)
+    with jax.named_scope(telemetry.MODEL_ATTN_GATE):
+        o = rms_norm(o, p["head_norm"], cfg.rms_norm_eps).astype(x.dtype).reshape(
+            b, s, wide) * _delta_gate(h, p)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        x = x + proj(o, p, lora, "wo", "bo", lora_scale)
+    return x, (None if mode == "full" else (state, tail))
 
 
 def _latent_page_walk(env: dict, cfg: ModelConfig):
@@ -450,7 +690,8 @@ def forward_hybrid(
     kv_cache: Params | None = None, remat: bool = False,
     attn_impl: str = "reference", logits_slice=None, logits_positions=None,
     page_size: int = 0, lora_dropout: float = 0.0, dropout_rng=None,
-    skip_lm_head: bool = False, **unsupported,
+    skip_lm_head: bool = False, paged_impl: str = "auto", pages_per_block: int = 0,
+    **unsupported,
 ):
     """``transformer.forward`` for a model with per-layer mixers: same
     arguments, same returns. ``unsupported`` holds the dense decoder's other
@@ -480,6 +721,7 @@ def forward_hybrid(
         env = {
             "lengths": lengths, "page_indices": kv_cache["page_indices"],
             "page_size": page_size, "alive": kv_cache.get("alive"),
+            "paged_impl": paged_impl, "pages_per_block": pages_per_block,
         }
         if cfg.latent:  # read off the table once a step, for every layer
             with jax.named_scope(telemetry.MODEL_LATENT_ATTN):
@@ -494,11 +736,12 @@ def forward_hybrid(
             "valid": attention_mask, "page_indices": kv_cache["page_indices"],
             "page_size": page_size,
         }
-    with jax.named_scope(
-            telemetry.MODEL_ATTN_CORE if cfg.latent else telemetry.MODEL_LINEAR_ATTN):
-        env["cos"], env["sin"] = rope_cos_sin(
-            rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
-            cfg.rope_theta)
+    if cfg.latent or cfg.kind_count("lightning"):  # the other kinds rotate nothing
+        with jax.named_scope(
+                telemetry.MODEL_ATTN_CORE if cfg.latent else telemetry.MODEL_LINEAR_ATTN):
+            env["cos"], env["sin"] = rope_cos_sin(
+                rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
+                cfg.rope_theta)
 
     with jax.named_scope(telemetry.MODEL_EMBED):
         x = jnp.take(params["embed"], input_ids, axis=0)
@@ -545,7 +788,8 @@ def forward_hybrid(
 
     # cache modes: an unrolled loop over per-layer cache buffers (a stacked
     # cache carried through a scan is ping-ponged whole: transformer.forward)
-    new = {name: list(kv_cache[name]) for name in ("k", "v", "pooled", "lin")}
+    new = {name: list(kv_cache[name]) for name in ("k", "v", *ROW_STATES)
+           if name in kv_cache}
     stats = kv_cache.get("sel_stats")
     moe_stats = kv_cache.get("moe_stats")
     at = dict.fromkeys(cfg.layer_kinds, 0)
@@ -563,6 +807,15 @@ def forward_hybrid(
                 x, p, lora_p, None, new["k"][i], kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
             if moe_stats is not None and layer_stats is not None:
+                moe_stats = moe_stats + layer_stats
+        elif kind in ("softmax", "delta"):
+            names = ("k", "v") if kind == "softmax" else ("delta", "conv")
+            x, held, layer_stats = block(
+                x, p, lora_p, None, tuple(new[name][j] for name in names), kind=kind,
+                dropout_rng=layer_keys[i] if use_dropout else None)
+            for name, piece in zip(names, held):
+                new[name][j] = piece
+            if moe_stats is not None:
                 moe_stats = moe_stats + layer_stats
         elif kind == "sparse":
             held = (new["k"][j], new["v"][j], new["pooled"][j])
@@ -583,6 +836,10 @@ def forward_hybrid(
         out["sel_stats"] = stats
     if moe_stats is not None:
         out["moe_stats"] = moe_stats
+    if "moe_routed" in kv_cache:  # the router's choices over ALL experts: live tokens x k a layer
+        live = b * s if env.get("alive") is None else env["alive"].sum() * s
+        out["moe_routed"] = kv_cache["moe_routed"] + jnp.asarray(
+            cfg.num_layers * cfg.experts_per_token * live, jnp.int32)
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
             kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)

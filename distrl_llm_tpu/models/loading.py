@@ -157,10 +157,23 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
     raise KeyError(name)
 
 
+def _refuse_unnamed(cfg: ModelConfig) -> None:
+    """A ``solar_open2`` checkpoint is refused by name, in both directions:
+    its published tensor names cannot be read here, and names guessed for the
+    delta-rule layers' convolutions, low-rank pairs and gates would load or
+    save something else under the model's name. Seeded weights only."""
+    if cfg.delta_moe:
+        raise NotImplementedError(
+            "model_type 'solar_open2' checkpoints are not supported: the published "
+            "tensor names of its delta-rule and gated softmax layers are not known "
+            "to this loader; the model runs from seeded weights only (init_params)")
+
+
 def params_from_state_dict(
     sd: Mapping[str, np.ndarray], cfg: ModelConfig, dtype=np.float32
 ) -> Params:
     """Numpy state dict (HF names) → our stacked param pytree."""
+    _refuse_unnamed(cfg)
     if cfg.latent:
         return _latent_params_from_state_dict(sd, cfg, dtype)
     if cfg.hybrid:
@@ -242,6 +255,7 @@ def load_safetensors_dir(path: str) -> dict[str, np.ndarray]:
 def state_dict_from_params(params: Params, cfg: ModelConfig) -> dict[str, np.ndarray]:
     """Our stacked param pytree → HF-named numpy state dict (the exact
     inverse of ``params_from_state_dict``)."""
+    _refuse_unnamed(cfg)
     sd: dict[str, np.ndarray] = {}
     layers = params["layers"]
     if cfg.latent:

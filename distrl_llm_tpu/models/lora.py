@@ -59,7 +59,8 @@ def init_lora_params(
     like that kind's base weights: a lightning layer's k and v project to all
     its heads, a sparse layer's to its few KV heads. ``targets`` left out are
     ``DEFAULT_TARGETS``, or ``LATENT_TARGETS`` for a latent-attention model
-    (whose expert layers' w_gate / w_up / w_down are the SHARED expert's)."""
+    (whose expert layers' w_gate / w_up / w_down are the SHARED expert's, as
+    they are in a gated delta-rule model's "softmax" and "delta" layers)."""
     if targets is None:
         targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
 
@@ -91,6 +92,12 @@ def init_lora_params(
             "latent": dims,
             "latent_moe": {**dims, "intermediate_size": cfg.shared_expert_size},
         }
+    elif cfg.delta_moe:
+        # q, k, v, o of both mixers and the shared expert's three; the router,
+        # the routed experts, the low-rank pairs, beta and the convolutions are frozen
+        shared = {**dims, "intermediate_size": cfg.shared_expert_size}
+        delta = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.delta_dim)
+        per_kind = {"softmax": shared, "delta": {**shared, **delta}}
     else:
         lightning = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.lightning_dim)
         per_kind = {"sparse": dims, "lightning": {**dims, **lightning}}

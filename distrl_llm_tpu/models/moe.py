@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.models.configs import ModelConfig
@@ -90,9 +91,9 @@ def _local_ids(idx, n: int, n_experts: int, held):
     """Each pair's place in the stack of experts held, or ``n``: not here."""
     if held is None:
         return idx
-    place = jnp.full((n_experts,), n, jnp.int32).at[
-        jnp.asarray(held, jnp.int32)].set(jnp.arange(n, dtype=jnp.int32))
-    return place[idx]
+    place = np.full((n_experts,), n, np.int32)  # ``held`` is static: a constant
+    place[np.asarray(held)] = np.arange(n, dtype=np.int32)
+    return jnp.asarray(place)[idx]
 
 
 def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
@@ -149,6 +150,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
         stacks = [experts[name].reshape(-1, *experts[name].shape[-2:])
                   for name in ("gate", "up", "down")]
 
+        @jax.checkpoint  # reverse mode keeps a block's rows, not its expert's matrices
         def one(_, block):
             x, e = block
             return None, _gated(x, *(stack[e] for stack in stacks))
@@ -172,7 +174,7 @@ def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None):
     y, load = routed_experts(
         flat, idx, w,
         {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]},
-        n_experts=cfg.n_routed_experts, held=held, layer=p.get("experts_layer"),
+        n_experts=cfg.router_width, held=held, layer=p.get("experts_layer"),
         # ``alive`` is a flag a ROW: each of the row's tokens takes it
         alive=None if alive is None else jnp.repeat(
             alive, flat.shape[0] // alive.shape[0]),

@@ -379,6 +379,11 @@ _SLICE_SCOPES = {
     "e_score_bias": telemetry.MODEL_MOE_ROUTER,
     **dict.fromkeys(("experts_gate", "experts_up", "experts_down"),
                     telemetry.MODEL_MOE_EXPERTS),
+    # a gated delta-rule model's own leaves (models/hybrid.py)
+    "conv": telemetry.MODEL_SHORT_CONV,
+    **dict.fromkeys(("wf_a", "wf_b", "wb", "A_log", "dt_bias"),
+                    telemetry.MODEL_DELTA_ATTN),
+    **dict.fromkeys(("wg", "wg_a", "wg_b", "head_norm"), telemetry.MODEL_ATTN_GATE),
 }
 
 
@@ -456,6 +461,7 @@ def forward(
             dropout_rng=dropout_rng, skip_lm_head=skip_lm_head,
             attn_mesh=attn_mesh, paged_verify=paged_verify,
             paged_chunked=paged_chunked, paged_prefix=paged_prefix,
+            paged_impl=paged_impl, pages_per_block=pages_per_block,
         )
     b, s = input_ids.shape
     paged = kv_cache is not None and "page_indices" in kv_cache

@@ -24,6 +24,9 @@ Layout conventions (models/transformer.py):
                [L, E, in, out] are REPLICATED (``_REPLICATED``): one chip holds
                every expert; sharding E over chips, and the all-to-all that
                needs, come with a four-chip cell.
+  delta rule:  a delta-rule layer's convolution filters, low-rank pairs
+               (wf_a/wf_b, wg_a/wg_b), wb, A_log and dt_bias are small and
+               fall to the last rule: whole on every chip.
 """
 
 from __future__ import annotations
@@ -36,8 +39,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Params = dict[str, Any]
 
 # layer weights whose OUT dim is tp-sharded (column parallel)
-# (wz: the output gate of a sparse or lightning layer, models/hybrid.py)
-_COL = {"wq", "wk", "wv", "wz", "w_gate", "w_up"}
+# (wz, wg: the output gate of a sparse or lightning layer and of a gated
+# softmax layer, models/hybrid.py)
+_COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up"}
 # layer weights whose IN dim is tp-sharded (row parallel)
 _ROW = {"wo", "w_down"}
 # a latent-attention / expert layer's own leaves: whole on every chip

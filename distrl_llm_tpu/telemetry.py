@@ -104,6 +104,13 @@ MODEL_MOE_ROUTER = "model/moe_router"
 MODEL_MOE_DISPATCH = "model/moe_dispatch"
 MODEL_MOE_EXPERTS = "model/moe_experts"
 MODEL_LATENT_ATTN = "model/latent_attn"
+# a gated delta-rule model (solar_open2, ops/delta_attention.py): the
+# recurrence in both forms with its l2norm, decay and beta; the short
+# convolutions and their tail's update; both mixers' output gates and the
+# delta-rule layers' head-wise norm. q, k, v, o stay ``model/attn_proj``
+MODEL_DELTA_ATTN = "model/delta_attn"
+MODEL_SHORT_CONV = "model/short_conv"
+MODEL_ATTN_GATE = "model/attn_gate"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -136,6 +143,7 @@ SCOPE_NAMES = (
     LEARNER_OPTIMIZER_CODEC,
     MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
+    MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE,
 )
 
 

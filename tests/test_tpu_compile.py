@@ -294,6 +294,78 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
 
 
+def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
+    """The segmented prefill scans an expert layer that holds a SHARE of its
+    experts. The table from expert id to place in the stack is a constant of
+    the trace: built on the device as a scatter into a constant, inside a
+    ``while`` body it is not folded, and the TPU compiler aborts on it
+    (``scatter_emitter.cc: operand_indices.size() == 1``; PR 36 found it in
+    Solar-Open2's prefill, here at the tiny preset's sizes)."""
+    from distrl_llm_tpu.models import moe
+    from distrl_llm_tpu.models.configs import PRESETS
+
+    cfg = PRESETS["tiny-delta-moe"]
+    assert cfg.held_experts == (0, 1) and cfg.router_width == 16
+
+    def scanned(h, p):
+        def body(total, x):
+            y, stats = moe.moe_half(x, p, cfg, held=cfg.held_experts)
+            return total + stats, y
+        return jax.lax.scan(body, jnp.zeros((2,), jnp.int32), h)
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(scanned).lower(chip((3, 256, 64), bf), {
+        "router": chip((64, 16), bf), "e_score_bias": chip((16,), bf),
+        "experts_gate": chip((2, 64, 32), bf), "experts_up": chip((2, 64, 32), bf),
+        "experts_down": chip((2, 32, 64), bf)}).compile()
+    assert "while" in compiled.as_text()
+
+
+def test_delta_rule_decode_fragment_updates_the_state_in_place(chip):
+    """One delta-rule layer's decode step at the cell's sizes (128 rows, 64
+    heads, a 128 x 128 float32 state a head: 512 MiB a layer): the state is
+    updated in place: no ``copy`` of it, and no temporary of its size beside
+    the donated one. What the plain form cannot avoid is a second READ of the
+    state (one fusion reduces it against k and q, the next reads it again to
+    write it), and one relayout of the convolution's tail a step (its 3 taps
+    sit on the sublane axis: 19 MB, 51 us a layer on the chip): PERF.md, PR 36."""
+    from distrl_llm_tpu.models import ModelConfig
+    from distrl_llm_tpu.models.hybrid import _delta_mix
+    from distrl_llm_tpu.models.transformer import _proj
+
+    cfg = ModelConfig(
+        vocab_size=24576, hidden_size=4096, intermediate_size=10240, num_layers=4,
+        num_heads=64, num_kv_heads=8, head_dim=128, rms_norm_eps=1e-5,
+        mixer_types=("gqa", "kda", "kda", "kda"), attn_use_rope=False,
+        attn_output_gate=True, delta_heads=64, delta_head_dim=128, delta_low_rank=128,
+        delta_beta_scale=2.0, n_routed_experts=40, router_experts=320,
+        n_shared_experts=1, experts_per_token=8, moe_intermediate_size=1280)
+    rows, wide, bf = 128, 8192, jnp.bfloat16
+
+    def fragment(state, tail, x, p):
+        x, (state, tail) = _delta_mix(
+            x, p, None, (state, tail), cfg=cfg, mode="decode", env={}, proj=_proj,
+            lora_scale=1.0)
+        return state, tail, x
+
+    p = {"attn_norm": chip((4096,), bf), "wq": chip((4096, wide), bf),
+         "wk": chip((4096, wide), bf), "wv": chip((4096, wide), bf),
+         "wo": chip((wide, 4096), bf), "conv": chip((4, 3 * wide), bf),
+         "wf_a": chip((4096, 128), bf), "wf_b": chip((128, wide), bf),
+         "A_log": chip((64,), bf), "dt_bias": chip((wide,), bf),
+         "wb": chip((4096, 64), bf), "wg_a": chip((4096, 128), bf),
+         "wg_b": chip((128, wide), bf), "head_norm": chip((128,), bf)}
+    compiled = jax.jit(fragment, donate_argnums=(0, 1)).lower(
+        chip((rows, 64, 128, 128), jnp.float32), chip((rows, 3, 3 * wide), bf),
+        chip((rows, 1, 4096), bf), p).compile()
+    text = compiled.as_text()
+    held = ("f32[128,64,128,128]",)
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and any(shape in line.split("(")[0] for shape in held)]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 600e6  # one state, not two
+
+
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
