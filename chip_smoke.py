@@ -285,6 +285,38 @@ def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
             "form": "dense" if tokens <= moe.DENSE_MAX_TOKENS else "grouped"}
 
 
+def _delta_step_case(key, *, rows, heads, head_dim, steps=4):
+    """The one-token delta rule as ``delta_step`` dispatches it (the Mosaic
+    kernel on a TPU at heads of 128, float32 throughout) against the plain
+    form, ``steps`` tokens from one carried state; what ran is read from the
+    dispatch record."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops import delta_attention as da
+
+    ks = jax.random.split(key, 6)
+    shape = (steps, rows, heads, head_dim)
+    q = da.l2norm(jax.random.normal(ks[0], shape)) * head_dim ** -0.5
+    k = da.l2norm(jax.random.normal(ks[1], shape))
+    v = jax.random.normal(ks[2], shape)
+    g = -jax.random.uniform(ks[3], shape, minval=1e-3, maxval=0.15)  # a_t in 0.86-0.999
+    beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(ks[4], shape[:3]))
+    got_s = want_s = jax.random.normal(ks[5], (rows, heads, head_dim, head_dim))
+    step, plain = jax.jit(da.delta_step), jax.jit(da.delta_step_plain)
+    err = 0.0
+    for t in range(steps):
+        got_o, got_s = step(q[t], k[t], v[t], g[t], beta[t], got_s)
+        want_o, want_s = plain(q[t], k[t], v[t], g[t], beta[t], want_s)
+        err = max(err, float(jnp.max(jnp.abs(got_o - want_o))),
+                  float(jnp.max(jnp.abs(got_s - want_s))))
+    ran = da.dispatch_choices[da.dispatch_key(heads, head_dim, head_dim)]
+    assert got_s.dtype == jnp.float32
+    assert np.isfinite(err) and err < 2e-5, f"delta step {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": float(f"{err:.3g}")}
+
+
 def phase_kernels(seed: int, compiles: CompileLog) -> None:
     """Each Pallas kernel the trainer phases use, compiled (never
     interpreted) at the 0.5B geometry, against its reference on the chip."""
@@ -335,6 +367,10 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
         out[name] = _expert_layer_case(
             key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)
+    # the benchmark's fourth configuration (Solar-Open2): the one-token delta
+    # rule at its 64 heads of 128, the Mosaic kernel against the plain form
+    out["delta_step"] = _delta_step_case(key, rows=16, heads=64, head_dim=128)
+    assert out["delta_step"]["impl"] == "kernel", out["delta_step"]
     # the learner's attention: the trainer phases run the CLI's default
     # attn_impl="reference" (XLA), so no attention kernel is on their path
     out["learner_attention"] = "reference (XLA): no kernel selected"

@@ -208,6 +208,22 @@ def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
         telemetry.gauge_set(OPS_PAGED_US_PER_GRID_STEP, decode_s * 1e6 / total)
 
 
+def _record_delta_telemetry(cfg: ModelConfig, steps: int) -> None:
+    """``ops/delta_kernel_steps``: the round's delta-rule layer-steps that ran
+    as the one-token Mosaic kernel, read from what ``delta_step`` recorded for
+    this model's heads when the step was traced (0 where it took the plain
+    form). A model without such layers files nothing."""
+    layers = cfg.kind_count("delta")
+    if not layers or not steps:
+        return
+    from distrl_llm_tpu.ops.delta_attention import dispatch_choices, dispatch_key
+
+    head = cfg.delta_head_dim
+    ran = dispatch_choices.get(dispatch_key(cfg.delta_heads, head, head))
+    telemetry.counter_add(
+        telemetry.OPS_DELTA_KERNEL_STEPS, layers * steps * (ran == "kernel"))
+
+
 def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
                    prompt_pages: int, page_size: int, lora_scale: float,
                    cache_dtype, attn_impl: str, kv_quant: str = "none"):
@@ -4057,6 +4073,7 @@ class PagedGenerationEngine(LoraMailbox):
                 self.cfg.num_layers, dispatched, decode_s,
                 per_call=self._grid_steps_per_call(r_slots),
             )
+        _record_delta_telemetry(self.cfg, dispatched)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4170,6 +4187,7 @@ class PagedGenerationEngine(LoraMailbox):
             self.cfg.num_layers, steps_seen[0], decode_s,
             per_call=self._grid_steps_per_call(b * n),
         )
+        _record_delta_telemetry(self.cfg, steps_seen[0])
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

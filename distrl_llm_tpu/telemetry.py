@@ -125,6 +125,12 @@ KERNEL_PAGED_ATTENTION = "kernel/paged_attention"
 KERNEL_QUANT_MATMUL = "kernel/quant_matmul"
 KERNEL_FLASH = "kernel/flash"
 KERNEL_SPLASH = "kernel/splash"
+# counter: a round's delta-rule layer-steps that went through the one-token
+# Mosaic kernel (ops/delta_attention.py::delta_step_kernel): layers x decode
+# steps where ``delta_step`` chose it, 0 where it took the plain form (a CPU,
+# small heads). Filed by the paged engine next to ``ops/paged_grid_steps``; no
+# metric reads it
+OPS_DELTA_KERNEL_STEPS = "ops/delta_kernel_steps"
 # device scopes: the train step (learner/). JAX writes the rest of the path:
 # ``transpose(jvp(learner/loss))`` is the backward pass and
 # ``rematted_computation`` under it the recomputed forward

@@ -1,7 +1,8 @@
 """The gated delta rule with a per-channel decay (``ops/delta_attention.py``):
 the chunked form and the one-token step against the plain recurrence written
 here, token by token, and the short convolution with its tail. Float32 on the
-CPU at small sizes."""
+CPU at small sizes; the one-token Mosaic kernel in interpret mode against the
+plain form at heads of 128, and which of the two ``delta_step`` takes."""
 
 import os
 import sys
@@ -15,8 +16,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from distrl_llm_tpu.ops import delta_attention  # noqa: E402
 from distrl_llm_tpu.ops.delta_attention import (  # noqa: E402
-    delta_chunked, delta_step, l2norm, short_conv,
+    delta_chunked, delta_step, delta_step_kernel, delta_step_plain, l2norm, short_conv,
 )
 
 B, S, H, D = 2, 50, 3, 8
@@ -178,3 +180,94 @@ def test_a_left_padded_row_starts_from_nothing():
     alone, tail_alone = short_conv(x[:, 6:], w, valid[:, 6:])
     np.testing.assert_allclose(np.asarray(y)[:, 6:], np.asarray(alone), atol=1e-6)
     np.testing.assert_allclose(np.asarray(tail), np.asarray(tail_alone), atol=1e-6)
+
+
+# ------------------------------------------------ the one-token Mosaic kernel
+
+KD = 128  # the kernel takes whole 128-lane tiles of state
+# rows x heads: a whole head block, one and a half (the last block runs past
+# the heads), fewer heads than a block
+GEOMETRIES = {"1x16": (1, 16), "3x24": (3, 24), "8x3": (8, 3)}
+FLAVOURS = {
+    "drawn": lambda g, beta: (g, beta),
+    "no-decay": lambda g, beta: (jnp.zeros_like(g), beta),  # g = 0: a = 1
+    "no-write": lambda g, beta: (g, jnp.zeros_like(beta)),  # beta = 0: only the decay
+    "decay-0.86": lambda g, beta: (jnp.full_like(g, np.log(0.86)), beta),
+    "decay-0.999": lambda g, beta: (jnp.full_like(g, np.log(0.999)), beta),
+}
+
+
+def step_inputs(rows, heads, seed=7):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = l2norm(jax.random.normal(ks[0], (rows, heads, KD))) * KD ** -0.5
+    k = l2norm(jax.random.normal(ks[1], (rows, heads, KD)))
+    v = jax.random.normal(ks[2], (rows, heads, KD))
+    g = -jax.random.uniform(ks[3], (rows, heads, KD), minval=1e-3, maxval=0.15)
+    beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(ks[4], (rows, heads)))
+    state = jax.random.normal(ks[5], (rows, heads, KD, KD))
+    return q, k, v, g, beta, state
+
+
+@pytest.mark.parametrize("flavour", sorted(FLAVOURS))
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_the_kernel_is_the_plain_step(geometry, flavour):
+    q, k, v, g, beta, state = step_inputs(*GEOMETRIES[geometry])
+    g, beta = FLAVOURS[flavour](g, beta)
+    want_o, want_s = delta_step_plain(q, k, v, g, beta, state)
+    got_o, got_s = delta_step_kernel(q, k, v, g, beta, state, interpret=True)
+    assert got_s.dtype == jnp.float32 and got_s.shape == state.shape
+    assert got_o.dtype == q.dtype and got_o.shape == v.shape
+    assert float(jnp.abs(got_o - want_o).max()) < 2e-5
+    assert float(jnp.abs(got_s - want_s).max()) < 2e-5
+    if flavour == "no-write":  # what was there, decayed, and nothing else
+        np.testing.assert_allclose(
+            np.asarray(got_s), np.asarray(state * jnp.exp(g)[..., None]), atol=2e-6)
+
+
+def test_a_prompt_then_kernel_steps_are_one_recurrence():
+    """A prompt through the chunked form, then 32 tokens through the kernel
+    from the carried state, against the chunked form over the whole."""
+    rows, heads, prompt, steps = 2, 2, 24, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    shape = (rows, prompt + steps, heads, KD)
+    q = l2norm(jax.random.normal(ks[0], shape)) * KD ** -0.5
+    k = l2norm(jax.random.normal(ks[1], shape))
+    v = jax.random.normal(ks[2], shape)
+    g = -jax.random.uniform(ks[3], shape, minval=1e-3, maxval=0.15)
+    beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(ks[4], shape[:3]))
+    valid = jnp.ones(shape[:2], jnp.int32)
+    want_o, want_s = delta_chunked(q, k, v, g, beta, valid)
+    o, s = delta_chunked(*(x[:, :prompt] for x in (q, k, v, g, beta, valid)))
+    outs = [o]
+    for t in range(prompt, prompt + steps):
+        o, s = delta_step_kernel(
+            q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], s, interpret=True)
+        outs.append(o[:, None])
+    assert s.dtype == jnp.float32
+    assert float(jnp.abs(jnp.concatenate(outs, 1) - want_o).max()) < 2e-5
+    assert float(jnp.abs(s - want_s).max()) < 2e-5
+
+
+@pytest.mark.parametrize("backend,head,dtype,want", [
+    ("tpu", 128, jnp.float32, "kernel"),
+    ("tpu", 256, jnp.float32, "kernel"),
+    ("tpu", 16, jnp.float32, "plain"),  # the CPU tests' heads: no whole tile
+    ("tpu", 128, jnp.bfloat16, "plain"),  # the kernel is float32 throughout
+    ("cpu", 128, jnp.float32, "plain"),
+])
+def test_the_step_takes_the_form_it_can_observe(monkeypatch, backend, head, dtype, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    state = jnp.zeros((2, 4, head, head), dtype)
+    assert delta_attention.delta_step_impl(state) == want
+    # and delta_step records it; the kernel itself is not run off the TPU
+    seen = []
+    monkeypatch.setattr(delta_attention, "delta_step_kernel",
+                        lambda *a: seen.append("kernel") or (a[2], a[5]))
+    monkeypatch.setattr(delta_attention, "delta_step_plain",
+                        lambda *a: seen.append("plain") or (a[2], a[5]))
+    monkeypatch.setattr(delta_attention, "dispatch_choices", {})
+    x = jnp.zeros((2, 4, head))
+    delta_step(x, x, x, x, jnp.zeros((2, 4)), state)
+    assert seen == [want]
+    assert delta_attention.dispatch_choices == {
+        delta_attention.dispatch_key(4, head, head, dtype): want}

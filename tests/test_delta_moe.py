@@ -546,6 +546,45 @@ def test_the_readers_read_the_share_off_the_counters(weights, small_pieces, monk
         "routed": "engine/moe_pairs_routed"}, object()) is None
 
 
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_cpu_round_counts_no_kernel_steps(weights, small_pieces, scheduler, slots):
+    """``ops/delta_kernel_steps`` is filed by both schedulers and reads 0 here:
+    heads of 16 on a CPU take the plain form, and ``delta_step`` says so."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import delta_attention
+
+    params, lora = weights
+    before = telemetry.observe_snapshot()["counters"].get(telemetry.OPS_DELTA_KERNEL_STEPS, 0)
+    generate(make_engine(scheduler, slots), params, lora)
+    head = CFG.delta_head_dim
+    assert delta_attention.dispatch_choices[
+        delta_attention.dispatch_key(CFG.delta_heads, head, head)] == "plain"
+    after = telemetry.observe_snapshot()["counters"]
+    assert after[telemetry.OPS_DELTA_KERNEL_STEPS] == before
+
+
+@pytest.mark.parametrize("ran,steps,want", [
+    ("kernel", 768, 3 * 768), ("plain", 768, 0), (None, 768, 0), ("kernel", 0, None)])
+def test_the_counter_is_layers_times_steps_where_the_kernel_ran(monkeypatch, ran, steps, want):
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.ops import delta_attention
+
+    assert CFG.kind_count("delta") == 3
+    head = CFG.delta_head_dim
+    monkeypatch.setattr(delta_attention, "dispatch_choices", {} if ran is None else {
+        delta_attention.dispatch_key(CFG.delta_heads, head, head): ran})
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_delta_telemetry(CFG, steps)
+    assert filed == ([] if want is None else [("ops/delta_kernel_steps", want)])
+    # a model without such layers files nothing
+    from distrl_llm_tpu.models.configs import PRESETS
+    filed.clear()
+    paged_engine._record_delta_telemetry(PRESETS["tiny"], 768)
+    assert filed == []
+
+
 # ------------------------------------------------------------ the refusals
 
 

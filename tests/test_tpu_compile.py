@@ -17,6 +17,7 @@ TPU's library, and every xdist worker imports every test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -321,18 +322,22 @@ def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
     assert "while" in compiled.as_text()
 
 
-def test_delta_rule_decode_fragment_updates_the_state_in_place(chip):
+def test_delta_rule_decode_fragment_updates_the_state_in_place(chip, monkeypatch):
     """One delta-rule layer's decode step at the cell's sizes (128 rows, 64
-    heads, a 128 x 128 float32 state a head: 512 MiB a layer): the state is
-    updated in place: no ``copy`` of it, and no temporary of its size beside
-    the donated one. What the plain form cannot avoid is a second READ of the
-    state (one fusion reduces it against k and q, the next reads it again to
-    write it), and one relayout of the convolution's tail a step (its 3 taps
-    sit on the sublane axis: 19 MB, 51 us a layer on the chip): PERF.md, PR 36."""
+    heads, a 128 x 128 float32 state a head: 512 MiB a layer): the one-token
+    rule is the Mosaic kernel (``delta_step`` asks the backend, which is the
+    CPU here: the test answers for the chip it compiles for), the state is
+    updated in place (no ``copy`` of it, no temporary of its size beside the
+    donated one) and exactly ONE operation reads it: the kernel, which brings a
+    head's tile into VMEM once (the plain form's two fusions read it twice:
+    PERF.md, PR 36 and PR 37). What stays is one relayout of the convolution's
+    tail a step (its 3 taps sit on the sublane axis: 19 MB, 51 us a layer on
+    the chip)."""
     from distrl_llm_tpu.models import ModelConfig
     from distrl_llm_tpu.models.hybrid import _delta_mix
     from distrl_llm_tpu.models.transformer import _proj
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(
         vocab_size=24576, hidden_size=4096, intermediate_size=10240, num_layers=4,
         num_heads=64, num_kv_heads=8, head_dim=128, rms_norm_eps=1e-5,
@@ -359,11 +364,18 @@ def test_delta_rule_decode_fragment_updates_the_state_in_place(chip):
         chip((rows, 64, 128, 128), jnp.float32), chip((rows, 3, 3 * wide), bf),
         chip((rows, 1, 4096), bf), p).compile()
     text = compiled.as_text()
+    assert "tpu_custom_call" in text
     held = ("f32[128,64,128,128]",)
     copies = [line.strip()[:160] for line in text.splitlines()
               if " copy(" in line and any(shape in line.split("(")[0] for shape in held)]
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 600e6  # one state, not two
+    # the entry computation's operations that take the state as an operand
+    entry = text[text.index("ENTRY "):]
+    state = re.search(r"(%[\w.-]+) = f32\[128,64,128,128\]\S* parameter\(", entry).group(1)
+    readers = [line.strip()[:120] for line in entry.splitlines()
+               if re.search(re.escape(state) + r"[,)]", line.split(" = ", 1)[-1])]
+    assert len(readers) == 1 and "custom-call" in readers[0], readers
 
 
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
