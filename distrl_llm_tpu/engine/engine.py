@@ -426,20 +426,84 @@ def cached_chunk_program(cache: dict, mu, key, fn_jit, alias_bytes: int,
         return cache[key]
 
 
+class RoundHostAccount:
+    """The host's side of one wave's decode loop, on ``perf_counter``, with
+    tracing on or off. The clock is read per host BOUNDARY (where the loops
+    stop to read a snapshot), never per step: four scalars a wave, which
+    ``accumulate_round_stats`` folds into ``last_round_stats`` and files as
+    the gauges ``engine/host_busy_share``, ``engine/slowest_boundary_ms`` and
+    ``engine/slowest_boundary_host_ms``.
+
+    ``loop_s`` is the loop's wall (construction to ``stop()``); ``blocked_s``
+    the seconds inside the snapshot waits and the readback's blocking reads;
+    ``slowest_s`` the longest interval between two consecutive returns from
+    the snapshot wait and ``slowest_host_s`` the part of THAT interval before
+    its wait began: a long interval with a large host part is a host that
+    stalled, with a small one a device that was late."""
+
+    __slots__ = ("t0", "loop_s", "blocked_s", "slowest_s", "slowest_host_s",
+                 "_last_return")
+
+    def __init__(self):
+        self.loop_s = self.blocked_s = 0.0
+        self.slowest_s = self.slowest_host_s = 0.0
+        self._last_return: float | None = None
+        self.t0 = time.perf_counter()
+
+    def waited(self, since: float) -> None:
+        """A snapshot wait that began at ``since`` has just returned."""
+        now = time.perf_counter()
+        self.blocked_s += now - since
+        last = self._last_return
+        if last is not None and now - last > self.slowest_s:
+            self.slowest_s = now - last
+            self.slowest_host_s = since - last
+        self._last_return = now
+
+    def blocked(self, since: float) -> None:
+        """The host has been blocked on the device from ``since`` to now (the
+        readback's reads)."""
+        self.blocked_s += time.perf_counter() - since
+
+    def stop(self) -> float:
+        self.loop_s = time.perf_counter() - self.t0
+        return self.loop_s
+
+
 def accumulate_round_stats(
     stats: dict | None, *, prefill_s: float, prefill_tokens: int,
     prompt_rows: int, decode_s: float, gen_tokens: int, gen_rows: int,
+    host: RoundHostAccount | None = None,
 ) -> dict:
     """Fold one wave's timing/token counts into a round's running stats —
     the ``last_round_stats`` contract every engine shares. The trainer
     snapshots this per round (like ``last_pool_stats``) and derives the
     ``engine/prefill_tok_s`` / ``engine/decode_tok_s`` / ``engine/mfu``
-    metric series from it."""
+    metric series from it. ``host`` is the wave's ``RoundHostAccount``: its
+    walls are summed, its longest boundary is the maximum over the round's
+    waves, and the three gauges of the round so far are set from the sums."""
     if stats is None:
         stats = {
             "prefill_s": 0.0, "prefill_tokens": 0, "prompt_rows": 0,
             "decode_s": 0.0, "gen_tokens": 0, "gen_rows": 0,
         }
+    if host is not None:
+        stats["loop_s"] = stats.get("loop_s", 0.0) + host.loop_s
+        stats["host_blocked_s"] = stats.get("host_blocked_s", 0.0) + host.blocked_s
+        if host.slowest_s >= stats.get("slowest_boundary_s", 0.0):
+            stats["slowest_boundary_s"] = host.slowest_s
+            stats["slowest_boundary_host_s"] = host.slowest_host_s
+        if stats["loop_s"] > 0:
+            telemetry.gauge_set(
+                telemetry.ENGINE_HOST_BUSY_SHARE,
+                100.0 * (1.0 - stats["host_blocked_s"] / stats["loop_s"]),
+            )
+        telemetry.gauge_set(
+            telemetry.ENGINE_SLOWEST_BOUNDARY_MS, 1e3 * stats["slowest_boundary_s"])
+        telemetry.gauge_set(
+            telemetry.ENGINE_SLOWEST_BOUNDARY_HOST_MS,
+            1e3 * stats["slowest_boundary_host_s"],
+        )
     stats["prefill_s"] += prefill_s
     stats["prefill_tokens"] += prefill_tokens
     stats["prompt_rows"] += prompt_rows
@@ -566,22 +630,30 @@ def run_nondivisor_tail(mailbox, lora_cell: list, steps_seen: list,
     for _ in range(rem):
         mailbox._take_pending_lora(lora_cell, steps_seen[0])
         steps_seen[0] += 1
-        state = run_step(lora_cell[0], state)
+        with telemetry.span(telemetry.ENGINE_DISPATCH,
+                            step=steps_seen[0] - 1, steps=1):
+            state = run_step(lora_cell[0], state)
     # graftcheck: end-hot-region
     return state
 
 
-def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int):
+def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int, *,
+                    steps_per_call: int = 1,
+                    host: RoundHostAccount | None = None):
     """Host-dispatched decode loop shared by the dense and paged engines:
     call ``step_fn(state) -> state`` up to ``max_steps`` times with async
-    early exit.
+    early exit. ``steps_per_call`` is what one call runs (k for a scanned
+    chunk): the ``engine/dispatch`` span round each call says so.
 
     Every ``check`` steps a COPY of the done flags (the original is donated
     into the next step) starts an async device→host transfer; the oldest
     snapshot is read only once a newer one is in flight, so the read waits on
     a transfer that finished steps ago, never on the device's current step.
     Worst-case overshoot after all rows hit EOS is ~2·check steps — the
-    fixed-shape analogue of continuous batching draining its tail."""
+    fixed-shape analogue of continuous batching draining its tail.
+
+    ``host`` is the wave's account of the host's side: the clock is read
+    round each snapshot wait, once a boundary, never per step."""
     from collections import deque
 
     check = max(1, min(decode_chunk, 16))
@@ -589,7 +661,9 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int):
     steps_done = 0
     # graftcheck: hot-region decode
     while steps_done < max_steps:
-        state = step_fn(state)
+        with telemetry.span(telemetry.ENGINE_DISPATCH,
+                            step=steps_done * steps_per_call, steps=steps_per_call):
+            state = step_fn(state)
         steps_done += 1
         if steps_done % check == 0 or steps_done == max_steps:
             snap = jnp.copy(state.done)
@@ -603,9 +677,12 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int):
                 # delayed read of an ASYNC-copied snapshot: a newer copy is
                 # already in flight, so this waits on a transfer that
                 # finished ~check steps ago, never on the current step
+                t_wait = time.perf_counter()
                 with telemetry.span(telemetry.ENGINE_SNAPSHOT_WAIT):
                     # graftcheck: disable=GC301 -- reads a finished async copy >=1 check-intervals old
                     all_done = bool(np.asarray(snapshots.popleft()).all())
+                if host is not None:
+                    host.waited(t_wait)
                 if all_done:
                     stop = True
                     break
@@ -1071,7 +1148,7 @@ class GenerationEngine(LoraMailbox):
         steps_seen = [0]
         # explicit enter/exit: the span must cover BOTH dispatch branches
         # and the final device→host readback that syncs the decode
-        t1 = time.perf_counter()
+        host = RoundHostAccount()
         dec_span = telemetry.span(telemetry.ENGINE_DECODE, rows=b * sampling.n,
                                   bucket=bucket)
         dec_span.__enter__()
@@ -1111,7 +1188,8 @@ class GenerationEngine(LoraMailbox):
             # one "step" per chunk; snapshot done flags every chunk
             # (check=1), then the shared non-divisor tail
             full, rem = divmod(max_steps, k)
-            state = run_decode_loop(step, state, full, 1)
+            state = run_decode_loop(step, state, full, 1,
+                                    steps_per_call=k, host=host)
             state = run_nondivisor_tail(
                 self, lora_cell, steps_seen, rem, state, run_step)
         else:
@@ -1128,7 +1206,9 @@ class GenerationEngine(LoraMailbox):
                     top_p_impl=top_p_impl,
                 )
 
-            state = run_decode_loop(step, state, max_steps, self.decode_chunk)
+            state = run_decode_loop(step, state, max_steps, self.decode_chunk,
+                                    host=host)
+        t_read = time.perf_counter()
         with telemetry.span(telemetry.ENGINE_READBACK):
             out = np.asarray(state.out).reshape(b, sampling.n, max_steps)
             lengths = np.asarray(state.lengths).reshape(b, sampling.n)
@@ -1137,12 +1217,13 @@ class GenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
+        host.blocked(t_read)
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
-            decode_s=time.perf_counter() - t1, gen_tokens=gen_tokens,
-            gen_rows=b * sampling.n,
+            decode_s=host.stop(), gen_tokens=gen_tokens,
+            gen_rows=b * sampling.n, host=host,
         )
         return GenerationResult(tokens=out, lengths=lengths, logprobs=logps)

@@ -46,6 +46,7 @@ from distrl_llm_tpu.control.governor import CONTROL_SHED_GROUPS
 from distrl_llm_tpu.engine.engine import (
     GenerationResult,
     LoraMailbox,
+    RoundHostAccount,
     accumulate_round_stats,
     cached_chunk_program,
     generate_in_waves,
@@ -72,7 +73,6 @@ from distrl_llm_tpu.ops.sampling import sample_with_logprob, token_logprob
 # engine/spec_* are the speculative-decoding accounting trace_report and
 # the spec smoke read.
 OPS_PAGED_GRID_STEPS = "ops/paged_grid_steps"              # counter
-OPS_PAGED_US_PER_GRID_STEP = "ops/paged_us_per_grid_step"  # gauge
 ENGINE_SPEC_DRAFT_RESIZES = "engine/spec_draft_resizes"    # counter
 ENGINE_SPEC_ACCEPT_RATE = "engine/spec_accept_rate"        # gauge
 ENGINE_SPEC_EMIT_TOKENS = "engine/spec_emit_tokens"        # hist (binned)
@@ -184,10 +184,10 @@ def _count_mixer_stats(mixer) -> None:
                 telemetry.counter_add(name, int(value))
 
 
-def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
+def _record_grid_telemetry(num_layers: int, steps: int,
                            *, per_call: int, calls_per_step: int = 1):
-    """Paged grid telemetry: which launch geometry ran, and what a grid
-    step of it cost (0.37-1.5 µs on a v5e, by what the step moves:
+    """Paged grid telemetry: which launch geometry ran (a grid step costs
+    0.37-1.5 µs on a v5e, by what the step moves:
     ``ops.paged.paged_grid_steps``). ``per_call`` is the
     dispatch chain's trace-time analytic count for the CALLER's own
     geometry and LIVE row count (engines derive it from their exact
@@ -196,16 +196,10 @@ def _record_grid_telemetry(num_layers: int, steps: int, decode_s: float,
     stale batch);
     ``calls_per_step`` is the op calls per layer per dispatched step (1
     for plain decode, draft_len+1 for the speculative verify fan-out).
-    Total grid steps this round = per-call × calls/step × layers × steps.
-    The realized µs/grid-step gauge is an upper bound (decode seconds also
-    carry sampling and non-attention layers), but it makes the
-    launch-overhead regime visible in every trace."""
-    if not per_call or not steps:
-        return
-    total = per_call * calls_per_step * num_layers * steps
-    telemetry.counter_add(OPS_PAGED_GRID_STEPS, total)
-    if decode_s > 0:
-        telemetry.gauge_set(OPS_PAGED_US_PER_GRID_STEP, decode_s * 1e6 / total)
+    Total grid steps this round = per-call × calls/step × layers × steps."""
+    if per_call and steps:
+        telemetry.counter_add(
+            OPS_PAGED_GRID_STEPS, per_call * calls_per_step * num_layers * steps)
 
 
 def _record_delta_telemetry(cfg: ModelConfig, steps: int) -> None:
@@ -2448,7 +2442,7 @@ class PagedGenerationEngine(LoraMailbox):
                 prompt_mixer = tuple(held)
                 jax.block_until_ready(last_logits)
             t_prefill = time.perf_counter() - t0
-        t_decode0 = time.perf_counter()
+        host = RoundHostAccount()
         dec_span = telemetry.span(telemetry.ENGINE_REFILL_DECODE, slots=r_slots,
                                   candidates=total)
         dec_span.__enter__()
@@ -3496,25 +3490,29 @@ class PagedGenerationEngine(LoraMailbox):
             fused_snap = None
             fused_spec = None
             if chunk_fn is not None and since_host % k_conf == 0:
-                if self.spec_draft:
-                    state, done_c, seq_c, dtot_c, acc_c = chunk_fn(
-                        params, lora_cell[0], state, rng, drafter_cell[0],
-                        eos_ids=self.eos_ids,
-                        temperature=temperature, top_p=top_p,
-                    )
-                    fused_spec = (dtot_c, acc_c)
-                else:
-                    state, done_c, seq_c = chunk_fn(
-                        params, lora_cell[0], state, rng,
-                        eos_ids=self.eos_ids,
-                        temperature=temperature, top_p=top_p,
-                    )
+                with telemetry.span(telemetry.ENGINE_DISPATCH,
+                                    step=dispatched, steps=k_chunk):
+                    if self.spec_draft:
+                        state, done_c, seq_c, dtot_c, acc_c = chunk_fn(
+                            params, lora_cell[0], state, rng, drafter_cell[0],
+                            eos_ids=self.eos_ids,
+                            temperature=temperature, top_p=top_p,
+                        )
+                        fused_spec = (dtot_c, acc_c)
+                    else:
+                        state, done_c, seq_c = chunk_fn(
+                            params, lora_cell[0], state, rng,
+                            eos_ids=self.eos_ids,
+                            temperature=temperature, top_p=top_p,
+                        )
                 fused_snap = (done_c, seq_c)
                 dispatched += k_chunk
                 since_host += k_chunk
                 n_new = k_chunk
             else:
-                state = step(state)
+                with telemetry.span(telemetry.ENGINE_DISPATCH,
+                                    step=dispatched, steps=1):
+                    state = step(state)
                 dispatched += 1
                 since_host += 1
                 n_new = 1
@@ -3621,11 +3619,13 @@ class PagedGenerationEngine(LoraMailbox):
             # `check` decode steps ran
             # (the one place the host waits on the device: a long span here
             # is a device-side stall, not host work)
+            t_wait = time.perf_counter()
             with telemetry.span(telemetry.ENGINE_SNAPSHOT_WAIT):
                 # graftcheck: disable=GC301 -- reads a finished async copy one boundary old
                 done_h = np.asarray(done_snap)
                 # graftcheck: disable=GC301 -- same delayed snapshot as the line above
                 seq_h = np.asarray(seq_snap)
+            host.waited(t_wait)
             if sl is not None:
                 # first-token detection off the same boundary snapshot: a
                 # slot whose resident length moved past its occupant's
@@ -3820,6 +3820,7 @@ class PagedGenerationEngine(LoraMailbox):
         # final blocking read closes the snapshot lag on the last occupants
         # (mark_finished, not a bare flag write: the serving ledger's
         # finish events and the sharing chain drops stay exactly-once)
+        t_read = time.perf_counter()
         readback_span = telemetry.span(telemetry.ENGINE_READBACK)
         readback_span.__enter__()
         done_h = np.asarray(state.done)
@@ -3987,6 +3988,7 @@ class PagedGenerationEngine(LoraMailbox):
         )
         gen_tokens = int(lengths.sum())
         readback_span.__exit__(None, None, None)
+        host.blocked(t_read)
         if self.spec_draft:
             # acceptance accounting off the device-carried histogram: one
             # read at round end, zero per-step host traffic
@@ -4056,21 +4058,21 @@ class PagedGenerationEngine(LoraMailbox):
                          if self.spec_draft else {}
                      ))
         dec_span.__exit__(None, None, None)
-        decode_s = time.perf_counter() - t_decode0
+        decode_s = host.stop()
         if continuous:
             # lazy group prefills ran inside the decode loop; decode
-            # throughput must not absorb their time
+            # throughput must not absorb their time, and each one blocked
+            # the host on the device
             decode_s = max(decode_s - t_prefill, 1e-9)
+            host.blocked_s += t_prefill
         if self.spec_draft:
             # aggregate attention grid steps (verify + drafter) — computed
             # directly, since the fused verify sweep and the drafter's
             # decode calls have different per-call counts
-            _record_grid_telemetry(
-                1, 1, decode_s, per_call=verify_grid + draft_grid,
-            )
+            _record_grid_telemetry(1, 1, per_call=verify_grid + draft_grid)
         else:
             _record_grid_telemetry(
-                self.cfg.num_layers, dispatched, decode_s,
+                self.cfg.num_layers, dispatched,
                 per_call=self._grid_steps_per_call(r_slots),
             )
         _record_delta_telemetry(self.cfg, dispatched)
@@ -4078,7 +4080,7 @@ class PagedGenerationEngine(LoraMailbox):
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
             decode_s=decode_s, gen_tokens=gen_tokens,
-            gen_rows=total,
+            gen_rows=total, host=host,
         )
         return GenerationResult(
             tokens=out, lengths=lengths, steps_dispatched=dispatched,
@@ -4108,7 +4110,7 @@ class PagedGenerationEngine(LoraMailbox):
             jax.block_until_ready(last_logits)
         t_prefill = time.perf_counter() - t0
         row_alive = jnp.asarray(prompt_mask).sum(axis=-1) > 0
-        t1 = time.perf_counter()
+        host = RoundHostAccount()
         dec_span = telemetry.span(telemetry.ENGINE_DECODE, rows=b * n)
         dec_span.__enter__()
         state, page_indices = self._fanout(
@@ -4156,7 +4158,8 @@ class PagedGenerationEngine(LoraMailbox):
             # floor chunks + shared non-divisor tail (run_nondivisor_tail
             # has the cadence invariant)
             full, rem = divmod(max_steps, k)
-            state = run_decode_loop(step, state, full, 1)
+            state = run_decode_loop(step, state, full, 1,
+                                    steps_per_call=k, host=host)
             state = run_nondivisor_tail(
                 self, lora_cell, steps_seen, rem, state, run_step)
         else:
@@ -4170,7 +4173,9 @@ class PagedGenerationEngine(LoraMailbox):
                     top_p_impl=top_p_impl,
                 )
 
-            state = run_decode_loop(step, state, max_steps, self.decode_chunk)
+            state = run_decode_loop(step, state, max_steps, self.decode_chunk,
+                                    host=host)
+        t_read = time.perf_counter()
         with telemetry.span(telemetry.ENGINE_READBACK):
             out = np.asarray(state.out).reshape(b, n, max_steps)
             lengths = np.asarray(state.gen_lengths).reshape(b, n)
@@ -4180,11 +4185,12 @@ class PagedGenerationEngine(LoraMailbox):
             )
             gen_tokens = int(lengths.sum())
             _count_mixer_stats(state.mixer)
+        host.blocked(t_read)
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
         dec_span.__exit__(None, None, None)
-        decode_s = time.perf_counter() - t1
+        decode_s = host.stop()
         _record_grid_telemetry(
-            self.cfg.num_layers, steps_seen[0], decode_s,
+            self.cfg.num_layers, steps_seen[0],
             per_call=self._grid_steps_per_call(b * n),
         )
         _record_delta_telemetry(self.cfg, steps_seen[0])
@@ -4192,7 +4198,7 @@ class PagedGenerationEngine(LoraMailbox):
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
             decode_s=decode_s, gen_tokens=gen_tokens,
-            gen_rows=b * n,
+            gen_rows=b * n, host=host,
         )
         # a wave steps in lockstep with no speculation: a row is alive at a
         # step exactly when it emits a token there

@@ -51,6 +51,7 @@ from distrl_llm_tpu import obs
 from distrl_llm_tpu.engine.engine import (
     GenerationResult,
     LoraMailbox,
+    RoundHostAccount,
     accumulate_round_stats,
     cached_chunk_program,
     lora_signature,
@@ -379,6 +380,7 @@ class ShardedPagedEngine(LoraMailbox):
         lora_cell = [lora]
         steps_seen = [0]
 
+        host = RoundHostAccount()
         chunk_fn = None
         if chunk_jit is not None:
             chunk_fn = cached_chunk_program(
@@ -412,7 +414,8 @@ class ShardedPagedEngine(LoraMailbox):
             # floor chunks + shared non-divisor tail (run_nondivisor_tail
             # has the cadence invariant)
             full, rem = divmod(max_steps, k)
-            state = run_decode_loop(step_fn, state, full, 1)
+            state = run_decode_loop(step_fn, state, full, 1,
+                                    steps_per_call=k, host=host)
             state = run_nondivisor_tail(
                 self, lora_cell, steps_seen, rem, state, run_step)
         else:
@@ -424,13 +427,17 @@ class ShardedPagedEngine(LoraMailbox):
                     params, lora_cell[0], s, rng, table, temperature, top_p
                 )
 
-            state = run_decode_loop(step_fn, state, max_steps, self.decode_chunk)
+            state = run_decode_loop(step_fn, state, max_steps, self.decode_chunk,
+                                    host=host)
+        t_read = time.perf_counter()
         out = np.asarray(state.out).reshape(b_pad, n, max_steps)[:b]
         lengths = np.asarray(state.gen_lengths).reshape(b_pad, n)[:b]
         logps = (
             np.asarray(state.logps).reshape(b_pad, n, max_steps)[:b]
             if self.capture_logprobs else None
         )
+        host.blocked(t_read)
+        host.stop()
         # round stats (engine.accumulate_round_stats contract, new here):
         # the sharded path previously published no throughput at all —
         # like RemoteEngine, the whole round is accounted as decode time
@@ -444,7 +451,7 @@ class ShardedPagedEngine(LoraMailbox):
             prefill_tokens=int(np.asarray(prompt_mask)[:b].sum()),
             prompt_rows=b,
             decode_s=time.perf_counter() - t_round,
-            gen_tokens=int(lengths.sum()), gen_rows=b * n,
+            gen_tokens=int(lengths.sum()), gen_rows=b * n, host=host,
         )
         self.last_round_stats["whole_round"] = True
         return GenerationResult(tokens=out, lengths=lengths, logprobs=logps)

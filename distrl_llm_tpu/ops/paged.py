@@ -312,9 +312,8 @@ def paged_grid_steps(
     kv_itemsize: int = 2, quantized: bool = False,
 ) -> int:
     """Analytic Pallas grid-step count of ONE paged-attention call (one
-    layer, one decode step) for ``impl``. The engines record it
-    (``ops/paged_grid_steps`` counter, ``ops/paged_us_per_grid_step`` gauge)
-    so that a trace says which launch geometry ran. What a grid step costs
+    layer, one decode step) for ``impl``. The engines record it (the counter
+    ``ops/paged_grid_steps``) so that a trace says which launch geometry ran. What a grid step costs
     depends on what it moves (a v5e at 4 kv heads of 128, page 128; PERF.md
     §6, PR 32): 0.37 us with one page of one head inside, 0.55 us with one
     page of all four heads, 1.5 us with a row's three to five pages and one

@@ -76,6 +76,18 @@ ENGINE_GRANT = "engine/grant"  # a budgeted pool's page-grant pass
 ENGINE_SNAPSHOT_WAIT = "engine/snapshot_wait"  # the host waits on the device here
 ENGINE_PREEMPT = "engine/preempt"
 ENGINE_READBACK = "engine/readback"  # the round's final blocking reads
+# one span round every launch of a decode step program from a host loop (args
+# ``step``: the first step it runs, ``steps``: 1, or k for a scanned chunk).
+# The one name recorded per STEP; every other span is per host boundary
+ENGINE_DISPATCH = "engine/dispatch"
+# gauges of the round so far, filed a wave by ``engine.accumulate_round_stats``
+# with tracing on or off (``engine.RoundHostAccount`` takes the clock per
+# host boundary): % of the decode loop's wall the host was NOT blocked on the
+# device; the longest interval between two returns from the snapshot wait;
+# the part of THAT interval outside the wait
+ENGINE_HOST_BUSY_SHARE = "engine/host_busy_share"
+ENGINE_SLOWEST_BOUNDARY_MS = "engine/slowest_boundary_ms"
+ENGINE_SLOWEST_BOUNDARY_HOST_MS = "engine/slowest_boundary_host_ms"
 # trainer, host side, nested in the PhaseSpans phases (driver/<phase>)
 DRIVER_SHAPING = "driver/shaping"
 DRIVER_UPDATE_BATCH = "driver/update/batch"

@@ -7,6 +7,9 @@ Everything here runs at tiny size on the CPU: names and counts, never a time.
 
 import glob
 import os
+import sys
+import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +18,12 @@ import pytest
 
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.config import SamplingConfig
-from distrl_llm_tpu.engine.engine import GenerationEngine
+from distrl_llm_tpu.engine import engine as engine_mod
+from distrl_llm_tpu.engine.engine import (
+    GenerationEngine,
+    RoundHostAccount,
+    run_decode_loop,
+)
 from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
 from distrl_llm_tpu.models import TINY, init_lora_params, init_params
 
@@ -47,19 +55,19 @@ def prompts(b=4, width=16, seed=0):
     return ids, mask
 
 
-def paged_engine(rows=4, max_new=24, pool=0):
+def paged_engine(rows=4, max_new=24, pool=0, **more):
     return PagedGenerationEngine(
         TINY, max_prompt_tokens=16, max_new_tokens=max_new,
         eos_token_ids=[1], pad_token_id=0, page_size=8,
         max_concurrent_rows=rows, scheduler="refill", max_kv_pages=pool,
-        decode_chunk=4,
+        decode_chunk=4, **more,
     )
 
 
-def dense_engine(max_new=12):
+def dense_engine(max_new=12, **more):
     return GenerationEngine(
         TINY, max_prompt_tokens=16, max_new_tokens=max_new,
-        eos_token_ids=[1], pad_token_id=0, decode_chunk=4,
+        eos_token_ids=[1], pad_token_id=0, decode_chunk=4, **more,
     )
 
 
@@ -233,8 +241,34 @@ def inside(child, parent):
             and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1)
 
 
-def run_paged_round(params, *, rows=4, b=6, max_tokens=24, pool=0):
-    eng = paged_engine(rows=rows, max_new=max_tokens, pool=pool)
+GAUGES = (T.ENGINE_HOST_BUSY_SHARE, T.ENGINE_SLOWEST_BOUNDARY_MS,
+          T.ENGINE_SLOWEST_BOUNDARY_HOST_MS)
+#: every span name that is NOT the launch's: at most once a host boundary
+PER_BOUNDARY = (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_GRANT, T.ENGINE_PREEMPT)
+
+
+def by_names():
+    out = {}
+    for e in spans():
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def assert_launches(launches, loop_span, steps, sizes=None):
+    """The new rule: one ``engine/dispatch`` a launched program, each inside
+    the loop's span, ``step`` rising from 0 and ``steps`` summing to the
+    round's (``sizes``: what each launch ran, all 1 where not given)."""
+    assert all(set(e["args"]) == {"step", "steps"} for e in launches)
+    assert all(inside(e, loop_span) for e in launches)
+    ran = [e["args"]["steps"] for e in launches]
+    assert ran == (sizes if sizes is not None else [1] * steps)
+    assert sum(ran) == steps
+    first = [e["args"]["step"] for e in launches]
+    assert first == [sum(ran[:i]) for i in range(len(ran))]
+
+
+def run_paged_round(params, *, rows=4, b=6, max_tokens=24, pool=0, **more):
+    eng = paged_engine(rows=rows, max_new=max_tokens, pool=pool, **more)
     ids, mask = prompts(b=b)
     out = eng.generate(
         params, None, ids, mask,
@@ -247,9 +281,7 @@ def run_paged_round(params, *, rows=4, b=6, max_tokens=24, pool=0):
 def test_paged_round_records_its_sub_spans_nested_and_per_boundary(tiny_params):
     telemetry.configure(True)
     _, out = run_paged_round(tiny_params)
-    by_name = {}
-    for e in spans():
-        by_name.setdefault(e["name"], []).append(e)
+    by_name = by_names()
     (round_span,) = by_name[T.ENGINE_REFILL_DECODE]
     for name in (T.ENGINE_SETUP, T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_READBACK):
         assert by_name.get(name), name
@@ -261,13 +293,17 @@ def test_paged_round_records_its_sub_spans_nested_and_per_boundary(tiny_params):
     assert len(admits) >= 2
     assert all(set(e["args"]) == {"groups", "slots"} for e in admits)
     assert sum(e["args"]["slots"] for e in admits) == 12
-    # nothing is recorded per decode step: a boundary is `check` = 4 steps
+    # per decode step the launch's span and nothing else: exactly one
+    # `engine/dispatch` a dispatched step; a boundary is `check` = 4 steps
     steps = round_span["args"]["steps"]
+    assert steps == out.steps_dispatched
     boundaries = -(-steps // 4)
     assert steps > 2 * boundaries
+    assert_launches(by_name[T.ENGINE_DISPATCH], round_span, steps)
     for name in (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT):
         assert len(by_name[name]) <= boundaries + 1, (name, steps)
-    program = [e for e in spans() if not e["name"].startswith(T.COMPILE_PREFIX + "/")]
+    program = [e for e in spans() if not e["name"].startswith(T.COMPILE_PREFIX + "/")
+               and e["name"] != T.ENGINE_DISPATCH]
     assert len(program) <= 3 * boundaries + 8
 
 
@@ -275,9 +311,7 @@ def test_budgeted_round_names_its_grant_passes_and_preemptions(tiny_params):
     telemetry.configure(True)
     eng, _ = run_paged_round(tiny_params, pool=12)
     assert eng.last_pool_stats["budgeted"]
-    by_name = {}
-    for e in spans():
-        by_name.setdefault(e["name"], []).append(e)
+    by_name = by_names()
     (round_span,) = by_name[T.ENGINE_REFILL_DECODE]
     assert by_name.get(T.ENGINE_GRANT)
     assert all(inside(e, round_span) for e in by_name[T.ENGINE_GRANT])
@@ -296,9 +330,7 @@ def test_dense_round_records_setup_snapshot_wait_and_readback():
     eng.generate(params, None, ids, mask,
                  SamplingConfig(max_tokens=12, temperature=0.0, top_p=1.0, n=2),
                  jax.random.PRNGKey(0))
-    by_name = {}
-    for e in spans():
-        by_name.setdefault(e["name"], []).append(e)
+    by_name = by_names()
     for name in (T.ENGINE_SETUP, T.ENGINE_PREFILL, T.ENGINE_DECODE, T.ENGINE_READBACK):
         assert len(by_name.get(name, [])) == 1, name
     (decode,) = by_name[T.ENGINE_DECODE]
@@ -306,13 +338,205 @@ def test_dense_round_records_setup_snapshot_wait_and_readback():
     waits = by_name.get(T.ENGINE_SNAPSHOT_WAIT, [])
     assert waits and all(inside(e, decode) for e in waits)
     assert len(waits) <= -(-decode["args"]["steps"] // 4)
+    # the dense loop is the shared one: a launch a step, nothing else per step
+    assert decode["args"]["steps"] == 12
+    assert_launches(by_name[T.ENGINE_DISPATCH], decode, 12)
+    assert {n for n in by_name if n.startswith("engine/")} == {
+        T.ENGINE_SETUP, T.ENGINE_PREFILL, T.ENGINE_DECODE, T.ENGINE_READBACK,
+        T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_DISPATCH}
+
+
+@pytest.mark.parametrize("engine_kind", ["dense", "paged_wave"])
+def test_chunked_round_records_a_launch_a_chunk_and_the_tail_per_step(engine_kind):
+    """``scan_chunk`` 4 over 14 steps: three launches of 4 steps and the
+    non-divisor tail of two, one step a launch."""
+    params = init_params(jax.random.PRNGKey(0), TINY)  # f32: the CPU has no bf16 dot
+    telemetry.configure(True)
+    if engine_kind == "dense":
+        eng = dense_engine(max_new=14, scan_chunk=4)
+    else:
+        eng = PagedGenerationEngine(
+            TINY, max_prompt_tokens=16, max_new_tokens=14, eos_token_ids=[1],
+            pad_token_id=0, page_size=8, decode_chunk=4, scan_chunk=4,
+        )
+    ids, mask = prompts()
+    eng.generate(params, None, ids, mask,
+                 SamplingConfig(max_tokens=14, temperature=0.0, top_p=1.0, n=2),
+                 jax.random.PRNGKey(0))
+    assert eng.scan_chunk_active
+    by_name = by_names()
+    (decode,) = by_name[T.ENGINE_DECODE]
+    assert decode["args"]["steps"] == 14
+    assert_launches(by_name[T.ENGINE_DISPATCH], decode, 14, sizes=[4, 4, 4, 1, 1])
+
+
+def test_chunked_refill_round_records_a_launch_a_chunk(tiny_params):
+    """The refill loop's chunk launch site: ``check`` = 4 is one chunk of 4."""
+    telemetry.configure(True)
+    eng, out = run_paged_round(tiny_params, scan_chunk=4)
+    assert eng.scan_chunk_active
+    by_name = by_names()
+    (round_span,) = by_name[T.ENGINE_REFILL_DECODE]
+    steps = out.steps_dispatched
+    assert steps % 4 == 0
+    assert_launches(by_name[T.ENGINE_DISPATCH], round_span, steps,
+                    sizes=[4] * (steps // 4))
+    for name in PER_BOUNDARY:
+        assert len(by_name.get(name, [])) <= steps // 4 + 1, name
 
 
 def test_tracing_off_records_nothing_and_spans_are_the_singleton(tiny_params):
     assert telemetry.span(T.ENGINE_ADMIT, groups=1) is telemetry._NULL_SPAN
-    run_paged_round(tiny_params)
+    assert telemetry.span(T.ENGINE_DISPATCH, step=0, steps=1) is telemetry._NULL_SPAN
+    eng, _ = run_paged_round(tiny_params)
     assert telemetry.recent_events(100_000) == []
     assert telemetry.span(T.DRIVER_PUSH, version=1) is telemetry._NULL_SPAN
+    # the round's host account is filed all the same: the three gauges reach
+    # any sink through metrics_snapshot(), and last_round_stats holds the sums
+    filed = telemetry.metrics_snapshot()
+    assert set(GAUGES) <= set(filed)
+    assert 0.0 <= filed[T.ENGINE_HOST_BUSY_SHARE] <= 100.0
+    assert 0.0 <= filed[T.ENGINE_SLOWEST_BOUNDARY_HOST_MS] <= filed[T.ENGINE_SLOWEST_BOUNDARY_MS]
+    stats = eng.last_round_stats
+    assert 0.0 < stats["host_blocked_s"] < stats["loop_s"]
+    assert filed[T.ENGINE_SLOWEST_BOUNDARY_MS] == pytest.approx(1e3 * stats["slowest_boundary_s"])
+    assert telemetry.observe_snapshot()["gauges"][T.ENGINE_HOST_BUSY_SHARE] == pytest.approx(
+        100.0 * (1.0 - stats["host_blocked_s"] / stats["loop_s"]))
+
+
+def test_dense_round_files_the_three_gauges_with_tracing_off():
+    params = init_params(jax.random.PRNGKey(0), TINY)  # f32: the CPU has no bf16 dot
+    eng = dense_engine()
+    ids, mask = prompts()
+    eng.generate(params, None, ids, mask,
+                 SamplingConfig(max_tokens=12, temperature=0.0, top_p=1.0, n=2),
+                 jax.random.PRNGKey(0))
+    assert telemetry.recent_events(100_000) == []
+    assert set(GAUGES) <= set(telemetry.observe_snapshot()["gauges"])
+    assert eng.last_round_stats["loop_s"] > 0.0
+
+
+def test_trace_report_prints_the_host_account_of_each_round_kind(tiny_params, tmp_path):
+    import re
+
+    from tools import trace_report
+
+    eng, _ = run_paged_round(tiny_params)  # warm-up: no compile/ span in the traced round
+    telemetry.configure(True)
+    ids, mask = prompts(b=6)
+    eng.generate(tiny_params, None, ids, mask,
+                 SamplingConfig(max_tokens=24, temperature=0.0, top_p=1.0, n=2),
+                 jax.random.PRNGKey(0))
+    path = telemetry.export_chrome_trace(str(tmp_path / "trace.json"), clear=False)
+    events, metadata = trace_report.load_trace(path)
+    lines = trace_report.build_report(events, metadata).splitlines()
+    (at,) = [i for i, line in enumerate(lines) if line.split()[:1] == [T.ENGINE_REFILL_DECODE]]
+    said = re.fullmatch(
+        r"    host s: launches ([\d.]+), waits ([\d.]+), admissions ([\d.]+), "
+        r"readback ([\d.]+), self ([\d.]+)", lines[at + 1])
+    assert said, lines[at + 1]
+    by_name = by_names()
+    (round_span,) = by_name[T.ENGINE_REFILL_DECODE]
+    launches, waits, admissions, readback, own = map(float, said.groups())
+    assert launches == pytest.approx(sum(e["dur"] for e in by_name[T.ENGINE_DISPATCH]) / 1e6, abs=1e-3)
+    assert waits == pytest.approx(sum(e["dur"] for e in by_name[T.ENGINE_SNAPSHOT_WAIT]) / 1e6, abs=1e-3)
+    setup = by_name[T.ENGINE_SETUP][0]["dur"] / 1e6
+    assert launches + waits + admissions + readback + own + setup == pytest.approx(
+        round_span["dur"] / 1e6, abs=5e-3)
+    assert sum(line.startswith("    host s:") for line in lines) == 1  # one a round kind
+
+
+# ------------------------------------------ the round's host account (ISSUE 38)
+
+
+def clock_reads_in_the_engines(monkeypatch):
+    """Counts ``time.perf_counter`` calls made from ``distrl_llm_tpu/engine/``."""
+    reads = []
+    real = time.perf_counter
+
+    def counting():
+        caller = sys._getframe(1).f_code.co_filename
+        if os.sep + os.path.join("distrl_llm_tpu", "engine") + os.sep in caller:
+            reads.append(caller)
+        return real()
+
+    monkeypatch.setattr(time, "perf_counter", counting)
+    return reads
+
+
+def test_the_decode_loops_read_the_clock_per_boundary_never_per_step(
+        tiny_params, monkeypatch):
+    reads = clock_reads_in_the_engines(monkeypatch)
+    eng, out = run_paged_round(tiny_params)
+    steps = out.steps_dispatched
+    boundaries = -(-steps // 4)
+    assert steps > 2 * boundaries
+    # two reads a boundary (the wait's start and its return) and a round's few
+    assert 2 * (boundaries - 2) <= len(reads) <= 2 * boundaries + 12 < steps
+    # the same for the loop the dense and wave engines share
+    del reads[:]
+    fake = types.SimpleNamespace(done=jnp.zeros(3, bool))
+    run_decode_loop(lambda s: s, fake, 40, 4, host=RoundHostAccount())
+    assert len(reads) == 1 + 2 * 9  # ten boundaries, nine waits, one account
+
+
+class LateFlags:
+    """Done flags whose host read takes ``late`` seconds: a device that is late."""
+
+    def __init__(self, late=0.0):
+        self.late = late
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.late)
+        return np.zeros(3, bool)
+
+
+@pytest.mark.parametrize("stall", ["host", "device"])
+def test_the_boundary_account_tells_a_stalled_host_from_a_late_device(stall, monkeypatch):
+    """Ten boundaries of four steps; in the sixth either the host sleeps
+    between two launches or the snapshot's read is late by as much."""
+    monkeypatch.setattr(engine_mod.jnp, "copy", lambda flags: flags)
+    calls = [0]
+
+    def step_fn(state):
+        calls[0] += 1
+        if calls[0] == 22 and stall == "host":
+            time.sleep(0.2)
+        if calls[0] % 4:
+            return state
+        late = 0.2 if calls[0] == 24 and stall == "device" else 0.0
+        return types.SimpleNamespace(done=LateFlags(late))
+
+    host = RoundHostAccount()
+    run_decode_loop(step_fn, types.SimpleNamespace(done=LateFlags()), 40, 4, host=host)
+    loop_s = host.stop()
+    assert calls[0] == 40
+    assert 0.2 <= host.slowest_s <= loop_s
+    if stall == "host":
+        assert host.slowest_host_s >= 0.2 and host.blocked_s < 0.1
+    else:
+        assert host.slowest_host_s < 0.1 and host.blocked_s >= 0.2
+    stats = engine_mod.accumulate_round_stats(
+        None, prefill_s=0.0, prefill_tokens=0, prompt_rows=0, decode_s=loop_s,
+        gen_tokens=0, gen_rows=0, host=host)
+    gauges = telemetry.observe_snapshot()["gauges"]
+    assert gauges[T.ENGINE_SLOWEST_BOUNDARY_MS] == pytest.approx(1e3 * host.slowest_s)
+    assert gauges[T.ENGINE_SLOWEST_BOUNDARY_HOST_MS] == pytest.approx(1e3 * host.slowest_host_s)
+    share = gauges[T.ENGINE_HOST_BUSY_SHARE]
+    assert share == pytest.approx(100.0 * (1.0 - host.blocked_s / loop_s))
+    assert (share > 50.0) is (stall == "host")
+    # a second wave of the round: walls sum, the longest boundary is the maximum
+    calm = RoundHostAccount()
+    calm.loop_s, calm.blocked_s, calm.slowest_s, calm.slowest_host_s = 1.0, 0.5, 0.01, 0.001
+    engine_mod.accumulate_round_stats(
+        stats, prefill_s=0.0, prefill_tokens=0, prompt_rows=0, decode_s=1.0,
+        gen_tokens=0, gen_rows=0, host=calm)
+    assert stats["loop_s"] == pytest.approx(loop_s + 1.0)
+    assert stats["slowest_boundary_s"] == host.slowest_s
+    assert stats["slowest_boundary_host_s"] == host.slowest_host_s
 
 
 def test_trainer_step_records_its_sub_spans_inside_their_phases():

@@ -62,6 +62,32 @@ def _intersect_us(a: list[tuple[int, int]], b: list[tuple[int, int]]) -> int:
     return total
 
 
+#: the two kinds of round, and the named parts of the host's side of one
+#: (telemetry.py's vocabulary): what is left of the round's span is its self time
+_ROUND_KINDS = ("engine/decode", "engine/refill_decode")
+_HOST_PARTS = (("launches", "engine/dispatch"), ("waits", "engine/snapshot_wait"),
+               ("admissions", "engine/admit"), ("readback", "engine/readback"))
+
+
+def _host_account(rounds: list[dict], track: list[dict]) -> str:
+    """The host's account of one kind of round, summed over ``rounds`` (its
+    spans on one track): seconds inside each named part and the rounds' self
+    time, the span less the union of every span of its thread inside it."""
+    part_us = dict.fromkeys((label for label, _ in _HOST_PARTS), 0)
+    self_us = 0
+    for r in rounds:
+        lo, hi = r["ts"], r["ts"] + r.get("dur", 0)
+        # (name, start, end cut to the round's) of every span that begins inside it
+        inside = [(e["name"], e["ts"], min(e["ts"] + e.get("dur", 0), hi))
+                  for e in track if e is not r and e.get("tid") == r.get("tid")
+                  and lo <= e["ts"] < hi]
+        for label, name in _HOST_PARTS:
+            part_us[label] += _union_us([(a, b) for n, a, b in inside if n == name])
+        self_us += (hi - lo) - _union_us([(a, b) for _, a, b in inside])
+    parts = ", ".join(f"{label} {us / 1e6:.3f}" for label, us in part_us.items())
+    return f"    host s: {parts}, self {self_us / 1e6:.3f}"
+
+
 def resilience_section(spans: dict[tuple[int, str], list[dict]]) -> list[str]:
     """Per-worker fault-handling summary from the driver's resilience spans
     (control_plane.py): reconnect attempts (``cp/reconnect``, with ok=),
@@ -579,6 +605,10 @@ def build_report(events: list[dict], metadata: dict,
                 f"{total_us / count / 1e3:>10.2f} "
                 f"{100 * total_us / track_us:>6.1f}%"
             )
+            if name in _ROUND_KINDS:
+                lines.append(_host_account(
+                    spans[(pid, name)],
+                    [e for _, evs in by_pid[pid] for e in evs]))
         lines.append("")
 
     # throughput from engine span args (every engine records tokens= on its
