@@ -218,6 +218,23 @@ def _record_delta_telemetry(cfg: ModelConfig, steps: int) -> None:
         telemetry.OPS_DELTA_KERNEL_STEPS, layers * steps * (ran == "kernel"))
 
 
+def _record_sparse_telemetry(cfg: ModelConfig, steps: int, cache_dtype) -> None:
+    """``ops/sparse_kernel_steps``: the round's sparse layer-steps whose
+    attention ran as the Mosaic launch over the chosen pages, read from what
+    ``sparse_decode`` recorded for this model's heads and pages when the step
+    was traced (0 where it took the plain form). A model without such layers
+    files nothing."""
+    layers = cfg.kind_count("sparse")
+    if not layers or not steps:
+        return
+    from distrl_llm_tpu.ops.sparse_attention import dispatch_choices, dispatch_key
+
+    ran = dispatch_choices.get(dispatch_key(
+        cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.sparse_block_size, cache_dtype))
+    telemetry.counter_add(
+        telemetry.OPS_SPARSE_KERNEL_STEPS, layers * steps * (ran == "kernel"))
+
+
 def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
                    prompt_pages: int, page_size: int, lora_scale: float,
                    cache_dtype, attn_impl: str, kv_quant: str = "none"):
@@ -4076,6 +4093,7 @@ class PagedGenerationEngine(LoraMailbox):
                 per_call=self._grid_steps_per_call(r_slots),
             )
         _record_delta_telemetry(self.cfg, dispatched)
+        _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4194,6 +4212,7 @@ class PagedGenerationEngine(LoraMailbox):
             per_call=self._grid_steps_per_call(b * n),
         )
         _record_delta_telemetry(self.cfg, steps_seen[0])
+        _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

@@ -17,20 +17,50 @@ Three entry points over one ``choose_blocks``:
   at a time per row, so the operations are a dense attention's and the memory
   is one query block's.
 * ``sparse_decode``: one query per slot over the paged cache with pages of
-  one block: the chosen blocks ARE a page list per (slot, KV head), gathered
-  and attended.
+  one block: the chosen blocks ARE a page list per (slot, KV head)
+  (``chosen_pages``: the pages in position order, the last of them the
+  query's own block), attended in one of two forms.
 * ``pool_keys`` / ``update_pooled``: the selector cache, whole or one key.
+
+**The decode attention** is one algorithm in two forms, chosen by what
+``sparse_decode`` can observe (``sparse_decode_impl``; no argument, no
+environment variable): on a TPU backend, with heads of whole 128-lane tiles
+over bf16 or float32 pages, ``attend_pages_kernel``, a Mosaic launch that
+copies each LIVE page of a list once from the pool where it lies into VMEM
+and writes only ``[R, H, hd]``; anywhere else (the CPU tests' small heads,
+quantized pages, a CPU run) ``attend_pages_plain``, the same mathematics in
+``jnp``, which is also the launch's reference. The plain form writes a
+gathered copy ``[R, K, n_sel, ps, hd]`` of K and of V to HBM and reads both
+back, dead entries included: 3.17 ms a layer at the long-context cell's sizes
+where the launch takes 0.67 (PERF.md §6, PR 39). ``dispatch_choices`` records
+which form each geometry took.
+
+The launch's grid is ``(R x K,)``, a (slot, KV head)'s list a step. The chosen
+blocks differ by KV head, so a copy is one head's page, 16 KB at 64 tokens of
+128 in bf16: too small for the pipeline's block operands (one operand a page:
+1.46-1.84 ms a layer, bound by the operands' bookkeeping and not by the
+copies), so the kernel starts the copies itself: the NEXT list's into the
+other half of a double buffer, before it attends this one, whose copies were
+started a step ago. Only the ``count`` live entries are ever read from the
+table. The whole list is then one contiguous ``[n_sel x ps, hd]`` tile of K
+and of V: one product, ONE softmax update for up to ``CHUNK_TOKENS`` tokens,
+and positions masked by one compare (every live page is whole but the last).
 
 ``cfg`` is the program's ``ModelConfig`` (the ``sparse_*`` sizes).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.ops.attention import NEG_INF
+from distrl_llm_tpu.ops.per_device import per_device
 
 _F32 = jnp.float32
 DEFAULT_Q_BLOCK = 128
@@ -190,6 +220,250 @@ def sparse_attend(q, k, v, pooled, q_pos, cfg, q_block: int = DEFAULT_Q_BLOCK):
     return out.reshape(b, nq * q_block, h, hd)[:, :s]
 
 
+def chosen_pages(q, pooled, lengths, page_indices, cfg, alive=None):
+    """The decode step's choice as a page list: (pages [R, K, n_sel] the chosen
+    blocks' pages first and in position order, order [R, K, n_sel] their block
+    numbers, count [R, K] how many are chosen, stats [2] int32: blocks attended
+    and blocks visible, summed over ``alive`` slots and KV heads)."""
+    r, kh = q.shape[0], pooled.shape[2]
+    ps = cfg.sparse_block_size  # a page is a block
+    n_blocks = page_indices.shape[1]
+    n_sel = selected_blocks_cap(cfg, n_blocks)
+    blocks = choose_blocks(
+        q[:, None], pooled, lengths[:, None], cfg, n_blocks
+    )[:, 0]  # [R, K, NB]
+    # chosen blocks first, in position order (a stable sort of the mask)
+    order = jnp.argsort(~blocks, axis=-1, stable=True)[..., :n_sel]
+    count = blocks.sum(axis=-1)  # [R, K]
+    pages = jnp.take_along_axis(
+        jnp.broadcast_to(page_indices[:, None, :], (r, kh, n_blocks)), order, axis=-1
+    )  # [R, K, n_sel]
+    live = jnp.ones((r,), jnp.int32) if alive is None else alive.astype(jnp.int32)
+    stats = jnp.stack([
+        (count.astype(jnp.int32) * live[:, None]).sum(),
+        ((lengths // ps + 1).astype(jnp.int32) * live).sum() * kh,
+    ])
+    return pages, order, count, stats
+
+
+def attend_pages_plain(q, k_pages, v_pages, pages, order, count, lengths):
+    """``sparse_decode``'s attention in plain ``jnp``: the chosen pages gathered
+    into ``[R, K, n_sel, ps, hd]`` copies of K and V, full scores under a mask.
+    The launch's reference, and the path off the TPU."""
+    r, h, hd = q.shape
+    kh, _, ps, _ = k_pages.shape
+    g = h // kh
+    n_sel = pages.shape[-1]
+    tok_pos = order[..., None] * ps + jnp.arange(ps)  # [R, K, n_sel, ps]
+    allowed = (jnp.arange(n_sel) < count[..., None])[..., None] & (
+        tok_pos <= lengths[:, None, None, None]
+    )
+    head = jnp.arange(kh)[None, :, None]
+    k_sel = k_pages[head, pages]  # [R, K, n_sel, ps, hd]
+    v_sel = v_pages[head, pages]
+    qg = q.reshape(r, kh, g, hd)
+    logits = jnp.einsum(
+        "rkgd,rknpd->rkgnp", qg, k_sel, preferred_element_type=_F32
+    ) * hd**-0.5
+    logits = jnp.where(allowed[:, :, None], logits, NEG_INF)
+    probs = jax.nn.softmax(
+        logits.reshape(r, kh, g, n_sel * ps), axis=-1
+    ).reshape(logits.shape).astype(v_sel.dtype)
+    out = jnp.einsum("rkgnp,rknpd->rkgd", probs, v_sel, preferred_element_type=_F32)
+    return out.reshape(r, h, hd).astype(q.dtype)
+
+
+#: most tokens of a page list that one softmax update covers: float32 scores
+#: ``[g, 8192]`` for the 16 query heads of a KV head are 512 KB. Timed on a v5e
+#: at 64 slots x 2 KV heads, 98 live pages of 64 tokens of 128 (PERF.md §6, PR
+#: 39): an update costs 0.29 us beside 15 ns a page, so a list of 128 pages
+#: attended 32 / 64 / 128 pages an update takes 756 / 699 / 666 us a layer
+CHUNK_TOKENS = 8192
+#: pages whose copies are started, and later awaited, as one unrolled unit (the
+#: pages left over of a list are started and awaited one by one): 8 / 16 / 32
+#: read 732 / 699 / 682 us a layer at 64 pages an update
+COPY_UNIT_PAGES = 16
+#: VMEM the launch may take for its two buffered lists of K and of V (8 MiB at
+#: 128 bf16 pages of 64 x 128); a longer list takes the plain form
+LIST_VMEM_BYTES = 48 * 2**20
+_LANES = 128
+
+#: what each geometry's decode attention resolved to, "kernel" or "plain", under
+#: ``dispatch_key``: the engine's counter ``ops/sparse_kernel_steps`` reads it,
+#: so a run on the plain form cannot pass for the launch
+dispatch_choices: dict = {}
+
+
+def dispatch_key(heads: int, kv_heads: int, head_dim: int, page_size: int,
+                 dtype=jnp.bfloat16) -> tuple:
+    """The key ``sparse_decode`` records its choice under: the query's heads
+    and the pool's pages, and not the rows or the table's width."""
+    return (heads, kv_heads, head_dim, page_size, jnp.dtype(dtype).name)
+
+
+def sparse_decode_impl(q: jax.Array, k_pages: jax.Array, n_sel: int) -> str:
+    """The form ``sparse_decode``'s attention takes over lists of ``n_sel``
+    pages: "kernel" on a TPU backend for heads of whole 128-lane tiles over
+    bf16 or float32 pages whose buffered lists fit ``LIST_VMEM_BYTES``,
+    "plain" otherwise (small heads, quantized pages, a CPU). On the TPU
+    nothing falls back: a kernel that fails to lower fails the step that
+    called it."""
+    _, _, ps, hd = k_pages.shape
+    fits = 4 * n_sel * ps * hd * k_pages.dtype.itemsize <= LIST_VMEM_BYTES
+    pages_ok = k_pages.dtype in (jnp.bfloat16, jnp.float32) and q.dtype == k_pages.dtype
+    if jax.default_backend() == "tpu" and hd % _LANES == 0 and pages_ok and fits:
+        return "kernel"
+    return "plain"
+
+
+def _make_pages_kernel(*, ps: int, cpp: int, unit: int, kh: int, steps: int, scale: float):
+    """Kernel body for ``attend_pages_kernel``: grid (R x K,), one (slot, KV
+    head)'s page list a step, in three phases: start every copy of the NEXT
+    list into the other half of the buffers, wait for every copy of this one
+    (started a step ago, behind the last list's arithmetic), attend ``cpp``
+    pages a softmax update."""
+    rows = cpp * ps
+    dims_qk = (((1,), (1,)), ((), ()))  # [g, hd] x [rows, hd] -> [g, rows]
+    dims_pv = (((1,), (0,)), ((), ()))  # [g, rows] x [rows, hd] -> [g, hd]
+
+    def kernel(len_ref, cnt_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem):
+        s = pl.program_id(0)
+        slot = s % 2
+
+        def units(step, whole, one):
+            """Over a list's live pages, ``unit`` at a time: ``whole(first)``
+            for ``unit`` live pages from ``first`` on, ``one(j)`` for each
+            page of the shorter last run. A dead entry is never touched."""
+            n = cnt_ref[step]
+
+            def run(u, _):
+                left = n - u * unit
+                pl.when(left >= unit)(lambda: whole(u * unit))
+
+                @pl.when(left < unit)
+                def _():
+                    jax.lax.fori_loop(0, left, lambda i, _: one(u * unit + i), None)
+
+            jax.lax.fori_loop(0, (n + unit - 1) // unit, run, None)
+
+        def fetch(step, into):
+            head = step % kh
+
+            def one(j):
+                page = tab_ref[step, j]
+                dst = pl.ds(pl.multiple_of(j * ps, ps), ps)
+                pltpu.make_async_copy(
+                    k_hbm.at[head, page], kbuf.at[into, dst], sem.at[0, into]).start()
+                pltpu.make_async_copy(
+                    v_hbm.at[head, page], vbuf.at[into, dst], sem.at[1, into]).start()
+
+            def whole(first):
+                for i in range(unit):
+                    one(first + i)
+
+            units(step, whole, one)
+
+        def land(step, into):
+            def landed(first, pages):
+                # a wait is for a size: one descriptor answers for a unit's copies
+                at = pl.ds(pl.multiple_of(first * ps, ps), pages * ps)
+                pltpu.make_async_copy(
+                    kbuf.at[into, at], kbuf.at[into, at], sem.at[0, into]).wait()
+                pltpu.make_async_copy(
+                    vbuf.at[into, at], vbuf.at[into, at], sem.at[1, into]).wait()
+
+            units(step, lambda first: landed(first, unit), lambda j: landed(j, 1))
+
+        @pl.when(s == 0)
+        def _first():
+            # a list's last chunk is attended whole: what lies past its live
+            # pages is masked, and V there must be finite for the mask to do
+            vbuf[...] = jnp.zeros_like(vbuf)
+            fetch(0, 0)
+
+        pl.when(s + 1 < steps)(lambda: fetch(s + 1, 1 - slot))
+        land(s, slot)
+
+        count = cnt_ref[s]
+        # the list's tokens the query sees: every live page whole but the last,
+        # its own block, which it sees up to its own place
+        limit = (count - 1) * ps + len_ref[s // kh] % ps + 1
+        q = q_ref[...]
+
+        def attend(c, carry):
+            m_prev, l_prev, acc = carry
+            at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            scores = jax.lax.dot_general(
+                q, kbuf[slot, at, :], dims_qk, preferred_element_type=_F32) * scale
+            tok = c * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+            scores = jnp.where(tok < limit, scores, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(vbuf.dtype), vbuf[slot, at, :], dims_pv,
+                preferred_element_type=_F32)
+            return m_new, l_new, acc
+
+        g, hd = q.shape
+        _, l_end, acc = jax.lax.fori_loop(
+            0, (count + cpp - 1) // cpp, attend,
+            (jnp.full((g, 1), NEG_INF, _F32), jnp.zeros((g, 1), _F32),
+             jnp.zeros((g, hd), _F32)))
+        # a list of no live page (a slot that is not alive) emits zeros, not 0/0
+        o_ref[...] = (acc / jnp.maximum(l_end, 1e-30)).astype(o_ref.dtype)
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_step", "interpret"))
+def attend_pages_kernel(q, k_pages, v_pages, pages, count, lengths, *,
+                        pages_per_step: int = 0, interpret: bool = False):
+    """``sparse_decode``'s attention as one Mosaic launch (a TPU; ``interpret``
+    for the CPU's tests): each live page of a list ``pages [R, K, n_sel]``
+    (``count [R, K]`` live entries, the last the query's own block) is copied
+    once from the pool where it lies into VMEM, and only ``[R, H, hd]`` is
+    written. Float32 scores, running maximum, sum and accumulator; K, V and
+    the probabilities in the pages' type, as the plain form has them. A list
+    with no live entry emits zeros. ``pages_per_step`` 0 is the launch's own
+    choice (``CHUNK_TOKENS``); the tests name one to reach lists of several
+    updates at small sizes."""
+    r, h, hd = q.shape
+    kh, total, ps, _ = k_pages.shape
+    g = h // kh
+    n_sel = pages.shape[-1]
+    cpp = min(pages_per_step or max(CHUNK_TOKENS // ps, 1), n_sel)
+    width = -(-n_sel // cpp) * cpp  # the buffers hold whole updates
+    steps = r * kh
+    spec = pl.BlockSpec((None, None, g, hd), lambda s, *_: (s // kh, s % kh, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        _make_pages_kernel(ps=ps, cpp=cpp, unit=min(COPY_UNIT_PAGES, cpp), kh=kh,
+                           steps=steps, scale=hd**-0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # lengths, counts, page lists ride SMEM
+            grid=(steps,),
+            in_specs=[spec, pool, pool],
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, width * ps, hd), k_pages.dtype),
+                pltpu.VMEM((2, width * ps, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # K / V x buffer half
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            # a step starts the next step's copies: the grid runs in order
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * width * ps * hd * k_pages.dtype.itemsize + 16 * 2**20),
+        out_shape=jax.ShapeDtypeStruct((r, kh, g, hd), q.dtype),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), count.astype(jnp.int32).reshape(-1),
+      jnp.clip(pages.astype(jnp.int32), 0, total - 1).reshape(steps, n_sel),
+      q.reshape(r, kh, g, hd), k_pages, v_pages)
+    return out.reshape(r, h, hd)
+
+
 def sparse_decode(q, k_pages, v_pages, pooled, lengths, page_indices, cfg,
                   alive=None):
     """One query per slot over the paged cache (pages of one block).
@@ -198,47 +472,27 @@ def sparse_decode(q, k_pages, v_pages, pooled, lengths, page_indices, cfg,
     [R, NP, K, hd]; lengths [R] the query's position (tokens resident before
     it; its own K/V are already written); page_indices [R, W], column c the
     page of block c. Returns (out [R, H, hd], stats [2] int32: blocks
-    attended and blocks visible, summed over ``alive`` slots and KV heads)."""
-    r, h, hd = q.shape
-    kh, _, ps, _ = k_pages.shape
-    g = h // kh
+    attended and blocks visible, summed over ``alive`` slots and KV heads).
+    The attention takes the form ``sparse_decode_impl`` names (module header)
+    and the choice is recorded in ``dispatch_choices``; as the launch, a slot
+    that is not ``alive`` fetches nothing and emits zeros."""
+    ps = k_pages.shape[2]
     if ps != cfg.sparse_block_size:
         raise ValueError(
             f"the sparse layers attend by page: page_size {ps} must be the "
             f"selector's block_size {cfg.sparse_block_size}"
         )
-    n_blocks = page_indices.shape[1]
-    n_sel = selected_blocks_cap(cfg, n_blocks)
     with jax.named_scope(telemetry.MODEL_SPARSE_SELECT):
-        blocks = choose_blocks(
-            q[:, None], pooled, lengths[:, None], cfg, n_blocks
-        )[:, 0]  # [R, K, NB]
-        # chosen blocks first, in position order (a stable sort of the mask)
-        order = jnp.argsort(~blocks, axis=-1, stable=True)[..., :n_sel]
-        count = blocks.sum(axis=-1)  # [R, K]
-        pages = jnp.take_along_axis(
-            jnp.broadcast_to(page_indices[:, None, :], (r, kh, n_blocks)), order, axis=-1
-        )  # [R, K, n_sel]
-        tok_pos = order[..., None] * ps + jnp.arange(ps)  # [R, K, n_sel, ps]
-        allowed = (jnp.arange(n_sel) < count[..., None])[..., None] & (
-            tok_pos <= lengths[:, None, None, None]
-        )
-        live = jnp.ones((r,), jnp.int32) if alive is None else alive.astype(jnp.int32)
-        stats = jnp.stack([
-            (count.astype(jnp.int32) * live[:, None]).sum(),
-            ((lengths // ps + 1).astype(jnp.int32) * live).sum() * kh,
-        ])
+        pages, order, count, stats = chosen_pages(
+            q, pooled, lengths, page_indices, cfg, alive)
+    impl = sparse_decode_impl(q, k_pages, pages.shape[-1])
+    dispatch_choices[dispatch_key(
+        q.shape[1], k_pages.shape[0], q.shape[2], ps, k_pages.dtype)] = impl
     with jax.named_scope(telemetry.MODEL_SPARSE_ATTN):
-        head = jnp.arange(kh)[None, :, None]
-        k_sel = k_pages[head, pages]  # [R, K, n_sel, ps, hd]
-        v_sel = v_pages[head, pages]
-        qg = q.reshape(r, kh, g, hd)
-        logits = jnp.einsum(
-            "rkgd,rknpd->rkgnp", qg, k_sel, preferred_element_type=_F32
-        ) * hd**-0.5
-        logits = jnp.where(allowed[:, :, None], logits, NEG_INF)
-        probs = jax.nn.softmax(
-            logits.reshape(r, kh, g, n_sel * ps), axis=-1
-        ).reshape(logits.shape).astype(v_sel.dtype)
-        out = jnp.einsum("rkgnp,rknpd->rkgd", probs, v_sel, preferred_element_type=_F32)
-    return out.reshape(r, h, hd).astype(q.dtype), stats
+        if impl == "kernel":
+            live = count if alive is None else count * alive.astype(count.dtype)[:, None]
+            out = per_device(attend_pages_kernel)(
+                q, k_pages, v_pages, pages, live, lengths)
+        else:
+            out = attend_pages_plain(q, k_pages, v_pages, pages, order, count, lengths)
+    return out, stats

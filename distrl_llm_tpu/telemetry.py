@@ -143,6 +143,12 @@ KERNEL_SPLASH = "kernel/splash"
 # small heads). Filed by the paged engine next to ``ops/paged_grid_steps``; no
 # metric reads it
 OPS_DELTA_KERNEL_STEPS = "ops/delta_kernel_steps"
+# counter: a round's sparse layer-steps whose attention over the chosen pages
+# ran as the Mosaic launch (ops/sparse_attention.py::attend_pages_kernel):
+# layers x decode steps where ``sparse_decode`` chose it, 0 where it took the
+# plain form (a CPU, small heads, quantized pages). Filed beside the counter
+# above; no metric reads it
+OPS_SPARSE_KERNEL_STEPS = "ops/sparse_kernel_steps"
 # device scopes: the train step (learner/). JAX writes the rest of the path:
 # ``transpose(jvp(learner/loss))`` is the backward pass and
 # ``rematted_computation`` under it the recomputed forward

@@ -10,6 +10,8 @@ The learner's update and ``Trainer.train()`` with the paged engine are held by
 own drivers and the same reference.
 """
 
+import dataclasses
+import functools
 import os
 import sys
 
@@ -197,6 +199,131 @@ def test_the_decode_steps_pooled_key_is_a_plain_loops(kh):
             want[r, j, h] = np.asarray(jnp.asarray(keys).mean(axis=0))
     np.testing.assert_array_equal(np.asarray(got), want)
     assert (want != pooled).any()
+
+
+def _decode_case(lengths, dtype, seed=0):
+    """A pool, a page table a row and pooled keys for ``sparse_decode`` at this
+    file's sizes (blocks of 4, 2 KV heads of 2 query heads each)."""
+    rng = np.random.default_rng(seed)
+    kh, g, hd, ps = CFG.num_kv_heads, CFG.num_heads // CFG.num_kv_heads, CFG.head_dim, 4
+    rows, width = len(lengths), max(lengths) // ps + 2
+    total = rows * width + 3
+    table = rng.permutation(total)[: rows * width].reshape(rows, width).astype(np.int32)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
+    from distrl_llm_tpu.ops.sparse_attention import pooled_count
+
+    return dict(
+        q=normal(rows, kh * g, hd), k_pages=normal(kh, total, ps, hd),
+        v_pages=normal(kh, total, ps, hd),
+        pooled=normal(rows, pooled_count(width * ps, CFG), kh, hd),
+        lengths=jnp.asarray(lengths, jnp.int32), page_indices=jnp.asarray(table))
+
+
+DECODE_CASES = {
+    # (the query's position a row, alive or None, chosen blocks a (row, KV head))
+    "over_dense_len": ((70, 95, 49, 83, 90), None, (5, 6)),  # count <= n_sel = 6 of 13-24
+    "every_block_of_a_short_context": ((10, 15, 3, 7, 12), None, (1, 2, 3, 4)),
+    "a_blocks_first_and_last_position": ((64, 67, 68, 71, 16), None, (5, 6)),
+    "a_dead_slot_and_a_length_of_0": ((70, 0, 0, 33, 95), (1, 0, 1, 1, 0), (1, 5, 6)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_the_decode_launch_is_the_plain_form_over_the_chosen_pages(case, dtype, monkeypatch):
+    """``attend_pages_kernel`` under the Pallas interpreter against
+    ``sparse_decode``'s plain form: the same output from the same page lists,
+    over pools in which every (KV head, page) that no live entry of a list
+    names is NaN: what lies past a list's count is neither fetched into the
+    result nor computed on. Two pages a step, so a list is one to three steps
+    and its last live page (the query's own block, the one masked) falls on
+    either place. Then the whole of ``sparse_decode`` told to take the launch:
+    the same output and ``stats`` equal to the integer."""
+    from distrl_llm_tpu.ops import sparse_attention as sa
+
+    lengths, alive, counts = DECODE_CASES[case]
+    x = _decode_case(lengths, dtype)
+    alive = None if alive is None else jnp.asarray(alive, bool)
+    want, want_stats = sa.sparse_decode(**x, cfg=CFG, alive=alive)
+    pages, _, count, _ = sa.chosen_pages(
+        x["q"], x["pooled"], x["lengths"], x["page_indices"], CFG, alive)
+    assert sorted(set(np.asarray(count).ravel().tolist())) == list(counts)
+    assert pages.shape[-1] >= max(counts)
+    if case == "over_dense_len":  # the KV heads choose apart
+        assert (np.asarray(pages[:, 0]) != np.asarray(pages[:, 1])).any()
+    live = np.asarray(count) * (1 if alive is None else np.asarray(alive)[:, None])
+    named = np.zeros(x["k_pages"].shape[:2], bool)
+    for r, k in np.ndindex(*live.shape):
+        named[k, np.asarray(pages)[r, k, : live[r, k]]] = True
+    assert not named.all()
+    poison = lambda pool: jnp.where(jnp.asarray(named)[:, :, None, None], pool, jnp.nan)
+    launch = functools.partial(sa.attend_pages_kernel, pages_per_step=2, interpret=True)
+    got = launch(x["q"], poison(x["k_pages"]), poison(x["v_pages"]), pages,
+                 jnp.asarray(live), x["lengths"])
+    tol = dict(atol=2e-5) if dtype == jnp.float32 else dict(atol=2**-6, rtol=2**-7)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    rows = np.ones(len(lengths), bool) if alive is None else np.asarray(alive)
+    np.testing.assert_allclose(got[rows], want[rows], **tol)
+    assert (got[~rows] == 0).all() and np.isfinite(want).all()
+    # the whole step through the dispatcher, as a TPU backend takes it
+    monkeypatch.setattr(sa, "sparse_decode_impl", lambda q, k_pages, n_sel: "kernel")
+    monkeypatch.setattr(sa, "attend_pages_kernel", launch)
+    out, stats = sa.sparse_decode(**x, cfg=CFG, alive=alive)
+    np.testing.assert_array_equal(np.asarray(out, np.float32), got)
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(want_stats))
+
+
+@pytest.mark.parametrize("backend,head,dtype,want", [
+    ("tpu", 128, jnp.bfloat16, "kernel"),
+    ("tpu", 128, jnp.float32, "kernel"),
+    ("tpu", 64, jnp.bfloat16, "plain"),  # not a whole 128-lane tile
+    ("tpu", 128, jnp.int8, "plain"),  # quantized pages
+    ("cpu", 128, jnp.bfloat16, "plain"),
+])
+def test_the_decode_attention_takes_the_form_it_can_observe(
+        monkeypatch, backend, head, dtype, want):
+    from distrl_llm_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = dataclasses.replace(CFG, head_dim=head)
+    rows, kh, ps = 2, 2, 4
+    q = jnp.zeros((rows, 4, head), jnp.float32 if dtype == jnp.int8 else dtype)
+    pool = jnp.zeros((kh, 8, ps, head), dtype)
+    assert sa.sparse_decode_impl(q, pool, 2) == want
+    assert sa.sparse_decode_impl(q, pool, 2**20) == "plain"  # lists no VMEM holds
+    # and sparse_decode records it; the launch itself is not run off the TPU
+    seen = []
+    monkeypatch.setattr(sa, "attend_pages_kernel", lambda *a: seen.append("kernel") or a[0])
+    monkeypatch.setattr(sa, "attend_pages_plain", lambda *a: seen.append("plain") or a[0])
+    monkeypatch.setattr(sa, "dispatch_choices", {})
+    pooled = jnp.zeros((rows, sa.pooled_count(8, cfg), kh, head), q.dtype)
+    sa.sparse_decode(q, pool, pool, pooled, jnp.asarray([5, 7]),
+                     jnp.arange(4, dtype=jnp.int32).reshape(2, 2), cfg)
+    assert seen == [want]
+    assert sa.dispatch_choices == {sa.dispatch_key(4, kh, head, ps, dtype): want}
+
+
+@pytest.mark.parametrize("ran,steps,want", [
+    ("kernel", 512, 2 * 512), ("plain", 512, 0), (None, 512, 0), ("kernel", 0, None)])
+def test_the_counter_is_sparse_layers_times_steps_where_the_launch_ran(
+        monkeypatch, ran, steps, want):
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.ops import sparse_attention as sa
+
+    assert CFG.kind_count("sparse") == 2
+    key = sa.dispatch_key(CFG.num_heads, CFG.num_kv_heads, CFG.head_dim, 4, jnp.bfloat16)
+    monkeypatch.setattr(sa, "dispatch_choices", {} if ran is None else {key: ran})
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_sparse_telemetry(CFG, steps, jnp.bfloat16)
+    assert filed == ([] if want is None else [("ops/sparse_kernel_steps", want)])
+    # pages of another dtype are another geometry; a model without such layers files nothing
+    filed.clear()
+    paged_engine._record_sparse_telemetry(CFG, 512, jnp.float32)
+    from distrl_llm_tpu.models.configs import PRESETS
+    paged_engine._record_sparse_telemetry(PRESETS["tiny"], 512, jnp.bfloat16)
+    assert filed == [("ops/sparse_kernel_steps", 0)]
 
 
 # -------------------------------------------------------------- the engine

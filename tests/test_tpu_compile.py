@@ -408,13 +408,19 @@ def sala_config():
     )
 
 
-@pytest.mark.parametrize("mode", ["decode", "segment"])
-def test_sala_mixers_at_published_widths(chip, mode):
-    """The two mixers of MiniCPM-SALA are plain XLA (no Mosaic kernel yet):
-    what is held here is that the chip's compiler takes them at the published
-    widths, 21k tokens of context a slot, in both cache modes: the decode
-    step's sort, top-k and page gather, and a prefill segment's masked scores
-    and float32 HIGHEST chunked scan."""
+@pytest.mark.parametrize("mode", ["decode", "launch", "segment"])
+def test_sala_mixers_at_published_widths(chip, monkeypatch, mode):
+    """The two mixers of MiniCPM-SALA at the published widths, 21k tokens of
+    context a slot, in both cache modes. The decode step: the selector's sort
+    and top-k in plain XLA, then the attention over the chosen pages as the
+    Mosaic launch (``sparse_decode`` asks the backend, which is the CPU in a
+    compile for the described chip: the test answers for it), which reads the
+    pages where they lie: no gathered copy ``[rows, 2, 128, 64, 128]`` of the
+    pool is left in the step. The launch alone at the cell's 64 slots: one
+    custom call, named after ``attend_pages_kernel`` as the trace reduction
+    spells it (no metric selects by the name yet; one that comes to finds it
+    held here). A prefill segment: masked scores and the float32 HIGHEST
+    chunked scan, plain XLA both."""
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
     from distrl_llm_tpu.ops.sparse_attention import (
@@ -428,7 +434,21 @@ def test_sala_mixers_at_published_widths(chip, mode):
     pooled = chip(state["pooled"][0].shape, jnp.bfloat16)
     lin = chip(state["lin"][0].shape, jnp.float32)
     rates = chip((32,), jnp.float32)
+    if mode == "launch":
+        from distrl_llm_tpu.ops.sparse_attention import attend_pages_kernel
+        from perfbench.trace_reduce import op_name
+
+        pool = chip((2, 1545, 64, 128), jnp.bfloat16)
+        text = assert_kernel(
+            attend_pages_kernel, chip((64, 32, 128), jnp.bfloat16), pool, pool,
+            chip((64, 2, 128), jnp.int32), chip((64, 2), jnp.int32), chip((64,), jnp.int32))
+        calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 1 and op_name(calls[0]).startswith("%attend_pages_kernel"), calls
+        return
     if mode == "decode":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
         def step(q, k_pages, v_pages, pooled, lengths, table, ql, kl, vl, lin, rates):
             pooled = update_pooled(pooled, k_pages, lengths + 1, table, cfg)
             out, stats = sparse_decode(q, k_pages, v_pages, pooled, lengths, table, cfg)
@@ -439,6 +459,9 @@ def test_sala_mixers_at_published_widths(chip, mode):
             head, pages, pages, pooled, chip((rows,), jnp.int32),
             chip((rows, width), jnp.int32), head, head, head, lin, rates,
         ).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert not re.search(r"bf16\[[\d,]*128,64,128\]", text)
     else:
         def segment(q, k, v, pos, ql, kl, vl, valid, lin, rates):
             out = sparse_attend(q, k, v, pool_keys(k, cfg), pos, cfg)
