@@ -55,18 +55,49 @@ def tree_bytes(params) -> int:
 
 
 def page_bytes(model_cfg, page_size: int, kv_quant: str = "none") -> int:
-    """HBM bytes one KV page costs across ALL layers (k + v; a latent page is
-    one row a token, ``latent_dim`` values in ``latent_row`` lanes, one array a
-    layer, no V beside it and no kv-head factor)."""
+    """HBM bytes one KV page costs across the layers that KEEP pages
+    (``paged_layers``: all of a dense or latent model's, the sparse or softmax
+    layers of one whose layers differ in kind, none of a power-retention
+    model's, whose page costs 0 bytes); k + v, or for a latent page one row a
+    token, ``latent_dim`` values in ``latent_row`` lanes, one array a layer, no
+    V beside it and no kv-head factor."""
+    layers = model_cfg.paged_layers
     if model_cfg.latent:
-        return page_size * model_cfg.latent_row * 2 * model_cfg.num_layers
+        return page_size * model_cfg.latent_row * 2 * layers
     per_layer_one = model_cfg.num_kv_heads * page_size * model_cfg.head_dim
     if kv_quant == "int8":
         # int8 payload + f32 per-token absmax scales [K, P, ps, 1]
         one = per_layer_one * 1 + model_cfg.num_kv_heads * page_size * 4
     else:
         one = per_layer_one * 2  # bf16
-    return one * 2 * model_cfg.num_layers
+    return one * 2 * layers
+
+
+def slot_state_bytes(model_cfg, max_tokens: int) -> int:
+    """Bytes ONE slot holds beside its pages: the row states of a model whose
+    layers differ in kind (``models/hybrid.py::ROW_STATES``: recurrent states,
+    normalisers, convolution tails, a selector's pooled keys over
+    ``max_tokens``), read off ``init_mixer_state``'s shapes. 0 for a dense
+    model."""
+    if not model_cfg.hybrid:
+        return 0
+    from distrl_llm_tpu.models.hybrid import ROW_STATES, init_mixer_state
+
+    shapes = jax.eval_shape(lambda: init_mixer_state(model_cfg, 1, max_tokens))
+    return sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for name in ROW_STATES for x in shapes.get(name, ()))
+
+
+def state_slots(model_cfg, *, gpu_usage: float, param_bytes: int, max_tokens: int,
+                hbm_bytes: int | None = None) -> int:
+    """Decode slots a budget allows where a slot's cache is state alone (no
+    layer keeps a page, so ``kv_pool_pages`` has nothing to count): what is
+    left of ``gpu_usage`` after the reserve and the weights, over
+    ``slot_state_bytes``. At least 1."""
+    hbm = hbm_bytes if hbm_bytes is not None else device_hbm_bytes()
+    budget = int(hbm * (gpu_usage - ACTIVATION_RESERVE) - param_bytes)
+    return max(1, budget // max(slot_state_bytes(model_cfg, max_tokens), 1))
 
 
 def kv_pool_pages(
@@ -83,6 +114,7 @@ def kv_pool_pages(
     hbm_bytes: int | None = None,
     continuous: bool = False,
     prefix_cache: bool = False,
+    slots: int = 0,
 ) -> int:
     """Pages available to the refill decode pool under ``gpu_usage``.
 
@@ -94,16 +126,28 @@ def kv_pool_pages(
     drops — those bytes become pool capacity — and the single-sequence
     floor carries one prompt chain. Clamped below at that minimum, so a
     too-small budget degrades to serial decoding instead of refusing to
-    run (with a warning naming the shortfall)."""
+    run (with a warning naming the shortfall).
+
+    A model whose layers differ in kind pays for pages in its PAGED layers
+    only (``page_bytes``), and the row states of its ``slots`` decode slots
+    and ``batch_prompts`` prompts (``slot_state_bytes``) come off the budget
+    before pages. Where no layer keeps a page a page costs nothing and 0 is
+    returned: the engine's worst-case table, and ``state_slots`` says how many
+    slots the budget holds."""
     from distrl_llm_tpu.ops.paged import pages_per_seq
 
     hbm = hbm_bytes if hbm_bytes is not None else device_hbm_bytes()
     pb = page_bytes(model_cfg, page_size, kv_quant)
     prompt_pages = pages_per_seq(max_prompt_tokens, page_size)
     shared_bytes = 0 if continuous else batch_prompts * prompt_pages * pb
+    state_bytes = (slots + batch_prompts) * slot_state_bytes(
+        model_cfg, max_prompt_tokens + max_new_tokens)
     budget = int(
         hbm * (gpu_usage - ACTIVATION_RESERVE) - param_bytes - shared_bytes
+        - state_bytes
     )
+    if pb == 0:  # no layer keeps a page: there is nothing to budget
+        return 0
     pool = budget // pb if budget > 0 else 0
     private_pages = 1 + pages_per_seq(max_new_tokens + max(spec_draft, 0),
                                       page_size)

@@ -104,6 +104,15 @@ ENGINE_MOE_MAX_EXPERT_LOAD = "engine/moe_max_expert_load"  # counter
 # rows x experts_per_token), where a program holds one chip's share of them:
 # moe_assignments (pairs of experts HELD) over this is the share that landed here
 ENGINE_MOE_PAIRS_ROUTED = "engine/moe_pairs_routed"        # counter
+# power retention (ops/power_retention.py): bytes of S and z that live rows'
+# decode steps read and wrote, summed over layers and steps (carried as a count
+# of (live row, layer) states, ``mixer["power_stats"]``, and fetched with the
+# round's result: a round's bytes pass what an int32 holds)
+ENGINE_POWER_STATE_BYTES = "engine/power_state_bytes"      # counter
+# bytes the decode state's row states hold (models/hybrid.py::ROW_STATES, every
+# kind: recurrent states, normalisers, convolution tails, pooled keys), filed
+# when a round's decode state is built, with tracing on or off
+ENGINE_SLOT_STATE_BYTES = "engine/slot_state_bytes"        # gauge
 # absorbed latent attention (ops/latent_attention.py): live (row, page) pairs
 # a round's decode steps attended over, and live pages they fetched from the
 # pool (a page that a group's rows share is fetched once a group), summed over
@@ -168,11 +177,21 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
 
 
 
-def _count_mixer_stats(mixer) -> None:
+def _count_mixer_stats(mixer) -> dict:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
     layers' blocks; ``mixer["moe_stats"]`` / ``["moe_routed"]``: the expert
     layers' pairs, held here / chosen over all experts; ``mixer["latent_stats"]``:
-    absorbed attention's pages) with telemetry."""
+    absorbed attention's pages; ``mixer["power_stats"]``: the (live row, layer)
+    power-retention states the decode steps read and wrote, filed as the bytes
+    of S and z that is, a read and a write each) with telemetry. Returns what
+    the round's span says of them: ``power_state_bytes``, where there are any."""
+    said = {}
+    if mixer is not None and "power_stats" in mixer:
+        a_state = sum(x.nbytes // x.shape[0]
+                      for x in (mixer["power"][0], mixer["power_z"][0]))
+        moved = 2 * a_state * int(np.asarray(mixer["power_stats"])[0])
+        telemetry.counter_add(ENGINE_POWER_STATE_BYTES, moved)
+        said["power_state_bytes"] = moved
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
@@ -182,6 +201,7 @@ def _count_mixer_stats(mixer) -> None:
         if mixer is not None and key in mixer:
             for name, value in zip(names, np.asarray(mixer[key])):
                 telemetry.counter_add(name, int(value))
+    return said
 
 
 def _record_grid_telemetry(num_layers: int, steps: int,
@@ -364,6 +384,18 @@ def _row_states(mixer) -> dict:
     from distrl_llm_tpu.models.hybrid import ROW_STATES
 
     return {name: mixer[name] for name in ROW_STATES if name in mixer}
+
+
+def _file_slot_state(mixer) -> dict:
+    """File the bytes a decode state's row states hold as the gauge
+    ``engine/slot_state_bytes``, and return them as the round's span and
+    ``last_round_stats`` say them: ``{"slot_state_bytes": n}``; nothing for a
+    model that has no such state."""
+    if mixer is None:
+        return {}
+    held = pool_nbytes(_row_states(mixer))
+    telemetry.gauge_set(ENGINE_SLOT_STATE_BYTES, float(held))
+    return {"slot_state_bytes": held}
 
 
 def _hand_mixer(mixer, prompt_mixer, prompt_of, admit_mask):
@@ -3846,7 +3878,8 @@ class PagedGenerationEngine(LoraMailbox):
             if c < total:
                 mark_finished(int(c))
         alive_h = int(np.asarray(state.alive_steps))
-        _count_mixer_stats(getattr(state, "mixer", None))
+        slot_state = _file_slot_state(getattr(state, "mixer", None))
+        state_said = {**_count_mixer_stats(getattr(state, "mixer", None)), **slot_state}
         if cache_on:
             # park every resident cached page host-side: device page ids
             # are round-scoped, so the tree survives between rounds as a
@@ -4063,7 +4096,7 @@ class PagedGenerationEngine(LoraMailbox):
                 ),
             }
         dec_span.set(tokens=gen_tokens, steps=dispatched,
-                     preemptions=pool.preemptions,
+                     preemptions=pool.preemptions, **state_said,
                      **(
                          {
                              "spec_drafter": self.spec_drafter,
@@ -4100,6 +4133,7 @@ class PagedGenerationEngine(LoraMailbox):
             decode_s=decode_s, gen_tokens=gen_tokens,
             gen_rows=total, host=host,
         )
+        self.last_round_stats.update(slot_state)
         return GenerationResult(
             tokens=out, lengths=lengths, steps_dispatched=dispatched,
             alive_slot_steps=alive_h,
@@ -4136,6 +4170,7 @@ class PagedGenerationEngine(LoraMailbox):
             prompt_mixer=prompt_mixer[0] if prompt_mixer else None,
             n=n, b=b, max_steps=max_steps,
         )
+        slot_state = _file_slot_state(state.mixer)
 
         temperature = jnp.asarray(sampling.temperature, jnp.float32)
         top_p = jnp.asarray(sampling.top_p, jnp.float32)
@@ -4202,9 +4237,9 @@ class PagedGenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
-            _count_mixer_stats(state.mixer)
+            state_said = _count_mixer_stats(state.mixer)
         host.blocked(t_read)
-        dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
+        dec_span.set(tokens=gen_tokens, steps=steps_seen[0], **state_said, **slot_state)
         dec_span.__exit__(None, None, None)
         decode_s = host.stop()
         _record_grid_telemetry(
@@ -4219,6 +4254,7 @@ class PagedGenerationEngine(LoraMailbox):
             decode_s=decode_s, gen_tokens=gen_tokens,
             gen_rows=b * n, host=host,
         )
+        self.last_round_stats.update(slot_state)
         # a wave steps in lockstep with no speculation: a row is alive at a
         # step exactly when it emits a token there
         return GenerationResult(

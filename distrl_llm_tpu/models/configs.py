@@ -36,6 +36,14 @@ share of a deployment that divides each layer over several chips:
 the published width the router scores and chooses over, ``expert_shard`` which
 contiguous run of ids is held (``held_experts``); pairs routed elsewhere add
 nothing here. With no share the two counts are equal and every expert is held.
+
+A power-retention model (``brumby``) is Qwen3's block with every attention
+layer replaced by one kind, "power" (``ops/power_retention.py``): GQA q/k/v
+with a per-head RMSNorm and RoPE, a scalar log-decay a KV head, and in place
+of a K/V cache a float32 state of the symmetric second power of the keys,
+``[D, head_dim]`` with ``D = head_dim (head_dim + 1) / 2``, and a normaliser
+``[D]``, ONE a KV head, read by the query heads that share it. No layer keeps
+a page: ``paged_layers`` is 0 and a slot's whole cache is state.
 """
 
 from __future__ import annotations
@@ -46,11 +54,13 @@ from dataclasses import dataclass
 #: published ``mixer_types`` entry -> the kind the program names its stacks by
 MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
                # solar_open2 publishes ``gqa_layers``; from_hf_config names the rest
-               "gqa": "softmax", "kda": "delta"}
+               "gqa": "softmax", "kda": "delta",
+               # brumby publishes no list: from_hf_config names every layer
+               "power-retention": "power"}
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
-    "solar_open2",
+    "solar_open2", "brumby",
 )
 #: layer kinds whose second half is routed experts beside a shared expert
 EXPERT_KINDS = ("latent_moe", "softmax", "delta")
@@ -62,6 +72,8 @@ _STATE_NAMES = {
     "latent_moe": "one latent row a token in place of K and V per head",
     "softmax": "K/V pages for its softmax layers only",
     "delta": "a float32 delta-rule state and a convolution tail",
+    "power": "a float32 power-retention state and its normaliser a KV head, "
+             "and no K/V at all",
 }
 #: layer kind -> the published name a refusal gives it
 _LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
@@ -183,6 +195,17 @@ class ModelConfig:
             {"gqa", "kda"} & set(self.mixer_types[: self.num_layers]))
 
     @property
+    def power(self) -> bool:
+        """True for a power-retention model (``brumby``): every layer "power"."""
+        return self.mixer_types is not None and (
+            "power-retention" in self.mixer_types[: self.num_layers])
+
+    @property
+    def power_state_dim(self) -> int:
+        """D: entries of the symmetric second power of a ``head_dim`` key."""
+        return self.head_dim * (self.head_dim + 1) // 2
+
+    @property
     def hybrid(self) -> bool:
         """True where a layer is not the dense GQA layer: ``mixer_types`` is
         set, or attention is latent. Such a model runs through
@@ -192,7 +215,8 @@ class ModelConfig:
     @property
     def layer_kinds(self) -> tuple[str, ...]:
         """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
-        "latent" (latent attention, dense MLP) | "latent_moe" (experts)."""
+        "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
+        "softmax" | "delta" | "power"."""
         if self.latent:
             dense = min(self.first_dense_layers, self.num_layers)
             if not self.n_routed_experts:
@@ -374,9 +398,11 @@ class ModelConfig:
         lightning = self.hidden_size * self.lightning_dim * (
             4 + self.lightning_output_gate
         )
+        power = attn + self.hidden_size * self.num_kv_heads  # and the decay's W_g
         return (
             self.kind_count("sparse") * (sparse + mlp)
             + self.kind_count("lightning") * (lightning + mlp)
+            + self.kind_count("power") * (power + mlp)
             + self.hidden_size * self.vocab_size
         )
 
@@ -438,6 +464,11 @@ class ModelConfig:
             # outer product, S^T q: 7 D^2 a head)
             attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
                 7.0 * self.kind_count("delta") * self.delta_dim * self.delta_head_dim)
+        if self.power:
+            # a token costs its state whatever the context: decay and the outer
+            # product a KV head (3 D d), S^T phi(q) a query head (2 D d)
+            attn = float(self.kind_count("power") * self.power_state_dim * self.head_dim
+                         * (3 * self.num_kv_heads + 2 * self.num_heads))
         return 2.0 * self.matmul_param_count + attn
 
     def train_flops_per_token(self, seq_len: int) -> float:
@@ -454,6 +485,8 @@ class ModelConfig:
             return "deepseek_v3"
         if self.delta_moe:
             return "solar_open2"
+        if self.power:
+            return "brumby"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -532,6 +565,8 @@ class ModelConfig:
             head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
         if mt == "solar_open2":
             hybrid = _delta_moe_fields(get)
+        if mt == "brumby":
+            hybrid = _power_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -554,6 +589,27 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+def _power_fields(get) -> dict:
+    """The ``brumby`` keys as ``ModelConfig`` fields: Qwen3's shape keys, every
+    layer a power-retention layer (per-head RMSNorm on q and k, full-width
+    RoPE). The published config gives no degree: 2 is what runs, and a
+    ``power_degree`` key that says otherwise is refused, like the variants of
+    Qwen3's attention that are not implemented."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"brumby with {key}={get(key)!r} is not supported: {why}")
+
+    if get("use_sliding_window", False):
+        refuse("use_sliding_window", "a power-retention layer keeps a state of every "
+               "token it has seen; a window over it is not implemented")
+    if get("rope_scaling") is not None:
+        refuse("rope_scaling", "q and k are rotated by plain RoPE at rope_theta; "
+               "scaled positions are not implemented")
+    if int(get("power_degree", 2) or 2) != 2:
+        refuse("power_degree", "the state is the symmetric SECOND power of a key")
+    return dict(
+        mixer_types=("power-retention",) * int(get("num_hidden_layers")), qk_norm=True)
 
 
 def _refuse_router_variants(get, refuse) -> None:
@@ -697,6 +753,14 @@ TINY_DELTA_MOE = ModelConfig(
     n_shared_experts=1, experts_per_token=4, moe_intermediate_size=32,
 )
 
+# a power-retention model at a size the CPU tests run: 10 query heads over 2 KV
+# heads (five read one state, the sixth the next), a state of 136 x 16 a KV head
+TINY_POWER = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=10, num_kv_heads=2, head_dim=16, rope_theta=1000000.0,
+    mixer_types=("power-retention",) * 3, qk_norm=True,
+)
+
 QWEN2_0_5B = ModelConfig(
     vocab_size=151936, hidden_size=896, intermediate_size=4864, num_layers=24,
     num_heads=14, num_kv_heads=2, head_dim=64, rope_theta=1000000.0,
@@ -748,6 +812,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny": TINY,
     "tiny-latent-moe": TINY_LATENT_MOE,
     "tiny-delta-moe": TINY_DELTA_MOE,
+    "tiny-power": TINY_POWER,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

@@ -82,6 +82,21 @@ THIRD kind of slot state: ``delta`` (a float32 ``[B, H, D, D]`` state) and
 tuples over the delta layers. ``moe_routed`` [1] int32 counts the pairs the
 router chose over ALL experts (``moe_stats`` counts those of experts held).
 
+**Power retention** (``brumby``) is one more kind, "power", in every layer of
+its model: Qwen3's block (this module's ``_block`` with ``qk_norm``, RoPE and
+the dense MLP) around ``ops/power_retention.py``::
+
+    q [T, H, D], k, v [T, K, D] = W h;  q, k <- RMSNorm_D;  q, k <- RoPE
+    g = logsigmoid(W_g h + b_g)  [T, K] float32, one value a KV head
+    S_t = e^{g_t} S_{t-1} + phi(k_t) v_t^T;  z_t = e^{g_t} z_{t-1} + phi(k_t)
+    o_t = S_t^T phi(q_t) / (z_t . phi(q_t) + eps);   y = W_o o
+
+It keeps a FOURTH kind of slot state and nothing else: ``power`` (a float32
+``[B, K, D2, D]`` state a layer, ``D2 = D (D + 1) / 2``, ONE a KV head, read by
+the query heads that share it) and ``power_z`` (its normaliser ``[B, K, D2]``).
+No layer keeps a page: ``k`` and ``v`` are empty tuples. ``power_stats`` [1]
+int32 counts the (live row, layer) states the decode steps read and wrote.
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -116,6 +131,7 @@ from distrl_llm_tpu.ops.latent_attention import (
 )
 from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
+from distrl_llm_tpu.ops.power_retention import init_state, power_chunked, power_step
 from distrl_llm_tpu.ops.sparse_attention import (
     pool_keys, pooled_count, sparse_attend, sparse_decode, update_pooled,
 )
@@ -130,7 +146,7 @@ LATENT_DECODE_ROWS = 16
 SOFTMAX_SEGMENT_PAGES = 2
 #: the entries of a slot's state that hold one array a ROW for each layer of a
 #: kind (tuples): what a candidate is handed from its prompt
-ROW_STATES = ("lin", "pooled", "delta", "conv")
+ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z")
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -230,6 +246,14 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             cfg.kind_count("sparse"), cfg.q_dim, cfg.kv_dim, cfg.head_dim,
             cfg.attn_output_gate, False,
         )
+    if cfg.kind_count("power"):
+        n = cfg.kind_count("power")
+        layers["power"] = {
+            **stack(n, cfg.q_dim, cfg.kv_dim, cfg.head_dim, False, False),
+            # the log-decay's projection, one value a KV head, and its bias
+            "w_decay": init((n, cfg.hidden_size, cfg.num_kv_heads)),
+            "b_decay": jnp.zeros((n, cfg.num_kv_heads), dtype),
+        }
     if cfg.kind_count("lightning"):
         layers["lightning"] = stack(
             cfg.kind_count("lightning"), cfg.lightning_dim, cfg.lightning_dim,
@@ -243,8 +267,9 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                      cache_dtype=jnp.bfloat16) -> Params:
     """What a slot holds beside its K/V pages: a float32 state per lightning
     layer, the selector's pooled keys per sparse layer, a float32 state and a
-    convolution tail per delta-rule layer, the round's counters. The entries
-    named in ``ROW_STATES`` are tuples of one array a row."""
+    convolution tail per delta-rule layer, a float32 state and its normaliser
+    per power-retention layer, the round's counters. The entries named in
+    ``ROW_STATES`` are tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counter
         return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
                 "latent_stats": jnp.zeros((2,), jnp.int32)}
@@ -258,6 +283,14 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                 for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
+        }
+    if cfg.power:
+        held = [init_state(rows, cfg.num_kv_heads, cfg.head_dim)
+                for _ in range(cfg.kind_count("power"))]
+        return {
+            "lin": (), "pooled": (),
+            "power": tuple(s for s, _ in held), "power_z": tuple(z for _, z in held),
+            "power_stats": jnp.zeros((1,), jnp.int32),
         }
     h, d = cfg.lightning_heads, cfg.lightning_head_dim
     pooled = (rows, pooled_count(max_tokens, cfg), cfg.num_kv_heads, cfg.head_dim)
@@ -359,6 +392,28 @@ def _lightning_mix(q, k, v, state, rate, *, cfg, mode, env):
         return o, (state if mode == "segment" else None)
 
 
+def _power_decay(h, p):
+    """A power-retention layer's log-decay, ``logsigmoid(W_g h + b_g)``: one
+    float32 value a KV head, <= 0."""
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        raw = jnp.einsum("bsh,hk->bsk", h, p["w_decay"],
+                         preferred_element_type=jnp.float32)
+    with jax.named_scope(telemetry.MODEL_POWER_ATTN):
+        return jax.nn.log_sigmoid(raw + p["b_decay"].astype(jnp.float32))
+
+
+def _power_mix(q, k, v, g, state, *, mode, env):
+    """Power retention in each mode: (o [B, S, H, hd], (S, z) or None)."""
+    with jax.named_scope(telemetry.MODEL_POWER_ATTN):
+        q = apply_rope(q, env["cos"], env["sin"])
+        k = apply_rope(k, env["cos"], env["sin"])
+        if mode == "decode":
+            o, state = power_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], state)
+            return o[:, None], state
+        o, state = power_chunked(q, k, v, g, env["valid"], state=state)
+        return o, (state if mode == "segment" else None)
+
+
 def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
            env: dict, lora_scale: float, lora_dropout: float, dropout_rng):
     """One layer of any kind: (x, new cache pieces, stats)."""
@@ -377,8 +432,8 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     c = jnp.asarray(cfg.residual_scale, x.dtype)
     sparse = kind == "sparse"
     heads, kv_heads, hd = (
-        (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) if sparse
-        else (cfg.lightning_heads, cfg.lightning_heads, cfg.lightning_head_dim)
+        (cfg.lightning_heads, cfg.lightning_heads, cfg.lightning_head_dim)
+        if kind == "lightning" else (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
     )
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
@@ -390,7 +445,9 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
             k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
         gate = jax.nn.sigmoid(linear(h, p["wz"])) if "wz" in p else None
     stats = None
-    if sparse:
+    if kind == "power":
+        o, cache = _power_mix(q, k, v, _power_decay(h, p), cache, mode=mode, env=env)
+    elif sparse:
         if cfg.attn_use_rope:
             raise NotImplementedError("sparse layers with RoPE (attn_use_rope)")
         o, cache, stats = _sparse_mix(q, k, v, cache, cfg=cfg, mode=mode, env=env)
@@ -736,9 +793,10 @@ def forward_hybrid(
             "valid": attention_mask, "page_indices": kv_cache["page_indices"],
             "page_size": page_size,
         }
-    if cfg.latent or cfg.kind_count("lightning"):  # the other kinds rotate nothing
+    if cfg.latent or cfg.power or cfg.kind_count("lightning"):  # the others rotate nothing
         with jax.named_scope(
-                telemetry.MODEL_ATTN_CORE if cfg.latent else telemetry.MODEL_LINEAR_ATTN):
+                telemetry.MODEL_ATTN_CORE if cfg.latent else
+                telemetry.MODEL_POWER_ATTN if cfg.power else telemetry.MODEL_LINEAR_ATTN):
             env["cos"], env["sin"] = rope_cos_sin(
                 rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
                 cfg.rope_theta)
@@ -817,6 +875,10 @@ def forward_hybrid(
                 new[name][j] = piece
             if moe_stats is not None:
                 moe_stats = moe_stats + layer_stats
+        elif kind == "power":
+            x, (new["power"][j], new["power_z"][j]), _ = block(
+                x, p, lora_p, None, (new["power"][j], new["power_z"][j]), kind=kind,
+                dropout_rng=layer_keys[i] if use_dropout else None)
         elif kind == "sparse":
             held = (new["k"][j], new["v"][j], new["pooled"][j])
             x, held, layer_stats = block(
@@ -840,6 +902,10 @@ def forward_hybrid(
         live = b * s if env.get("alive") is None else env["alive"].sum() * s
         out["moe_routed"] = kv_cache["moe_routed"] + jnp.asarray(
             cfg.num_layers * cfg.experts_per_token * live, jnp.int32)
+    if "power_stats" in kv_cache and mode == "decode":  # live rows' states, every layer
+        live = b if env.get("alive") is None else env["alive"].sum()
+        out["power_stats"] = kv_cache["power_stats"] + jnp.asarray(
+            cfg.num_layers * live, jnp.int32)
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
             kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)

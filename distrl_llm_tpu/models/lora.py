@@ -60,7 +60,9 @@ def init_lora_params(
     its heads, a sparse layer's to its few KV heads. ``targets`` left out are
     ``DEFAULT_TARGETS``, or ``LATENT_TARGETS`` for a latent-attention model
     (whose expert layers' w_gate / w_up / w_down are the SHARED expert's, as
-    they are in a gated delta-rule model's "softmax" and "delta" layers)."""
+    they are in a gated delta-rule model's "softmax" and "delta" layers). A
+    power-retention layer has the dense decoder's seven targets and no factor
+    on its log-decay."""
     if targets is None:
         targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
 
@@ -98,6 +100,9 @@ def init_lora_params(
         shared = {**dims, "intermediate_size": cfg.shared_expert_size}
         delta = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.delta_dim)
         per_kind = {"softmax": shared, "delta": {**shared, **delta}}
+    elif cfg.power:
+        # Qwen3's seven targets; the log-decay's projection and bias are frozen
+        per_kind = {"power": dims}
     else:
         lightning = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.lightning_dim)
         per_kind = {"sparse": dims, "lightning": {**dims, **lightning}}

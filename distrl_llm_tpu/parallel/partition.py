@@ -27,6 +27,10 @@ Layout conventions (models/transformer.py):
   delta rule:  a delta-rule layer's convolution filters, low-rank pairs
                (wf_a/wf_b, wg_a/wg_b), wb, A_log and dt_bias are small and
                fall to the last rule: whole on every chip.
+  retention:   a power-retention layer's log-decay (w_decay [L, hidden, K],
+               b_decay [L, K]: one column a KV head) is whole on every chip
+               (``_REPLICATED``); q, k, v, o and the MLP go as the dense
+               decoder's.
 """
 
 from __future__ import annotations
@@ -46,7 +50,9 @@ _COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up"}
 _ROW = {"wo", "w_down"}
 # a latent-attention / expert layer's own leaves: whole on every chip
 _REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
-               "experts_up", "experts_down"}
+               "experts_up", "experts_down",
+               # a power-retention layer's log-decay: one column a KV head
+               "w_decay", "b_decay"}
 
 
 def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:

@@ -123,6 +123,11 @@ MODEL_LATENT_ATTN = "model/latent_attn"
 MODEL_DELTA_ATTN = "model/delta_attn"
 MODEL_SHORT_CONV = "model/short_conv"
 MODEL_ATTN_GATE = "model/attn_gate"
+# power retention (brumby, ops/power_retention.py): the symmetric second power
+# of q and k, the one-token step, the chunked form, the normaliser, the
+# log-decay's logsigmoid and cumulation, and RoPE. q, k, v, o and the decay's
+# projection stay ``model/attn_proj``
+MODEL_POWER_ATTN = "model/power_attn"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -167,7 +172,7 @@ SCOPE_NAMES = (
     LEARNER_OPTIMIZER_CODEC,
     MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
-    MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE,
+    MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
 )
 
 

@@ -764,6 +764,8 @@ class Trainer:
                     # only the EXPLICIT flag bumps the floor (same rule):
                     # a plan-resolved cache rides the refill slack instead
                     prefix_cache=bool(config.prefix_cache),
+                    # a slot's row states come off the budget before pages
+                    slots=config.max_concurrent_sequences or 0,
                 )
             engine = engine_cls(
                 model_cfg,

@@ -378,6 +378,57 @@ def test_delta_rule_decode_fragment_updates_the_state_in_place(chip, monkeypatch
     assert len(readers) == 1 and "custom-call" in readers[0], readers
 
 
+def test_power_retention_decode_fragment_updates_the_state_in_place(chip):
+    """One power-retention layer's decode step at the cell's sizes (32 rows, 40
+    query heads over 8 KV heads of 128: a float32 state of 8,256 x 128 a KV
+    head, 1.08 GB a layer, and its normaliser): the state is decayed and written
+    in place by ONE fusion (no ``copy`` of it, no temporary of its size beside
+    the donated one) and read once more by the product with phi(q), which is
+    what plain XLA gives (1.5 x the bytes: PERF.md, PR 40); phi(q) for all 40
+    heads (42 MB) is the largest temporary."""
+    from functools import partial
+
+    from distrl_llm_tpu.models import ModelConfig
+    from distrl_llm_tpu.models.hybrid import _block
+    from distrl_llm_tpu.models.transformer import rope_cos_sin
+
+    cfg = ModelConfig(
+        vocab_size=VOCAB, hidden_size=5120, intermediate_size=17408, num_layers=4,
+        num_heads=40, num_kv_heads=8, head_dim=128, rope_theta=1e6,
+        mixer_types=("power-retention",) * 4, qk_norm=True)
+    rows, bf = 32, jnp.bfloat16
+
+    def fragment(state, z, x, lengths, p):
+        cos, sin = rope_cos_sin(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+        x, (state, z), _ = _block(
+            x, p, None, None, (state, z), kind="power", cfg=cfg, mode="decode",
+            env={"cos": cos, "sin": sin}, lora_scale=1.0, lora_dropout=0.0,
+            dropout_rng=None)
+        return state, z, x
+
+    p = {"attn_norm": chip((5120,), bf), "mlp_norm": chip((5120,), bf),
+         "q_norm": chip((128,), bf), "k_norm": chip((128,), bf),
+         "wq": chip((5120, 5120), bf), "wk": chip((5120, 1024), bf),
+         "wv": chip((5120, 1024), bf), "wo": chip((5120, 5120), bf),
+         "w_decay": chip((5120, 8), bf), "b_decay": chip((8,), bf),
+         "w_gate": chip((5120, 17408), bf), "w_up": chip((5120, 17408), bf),
+         "w_down": chip((17408, 5120), bf)}
+    compiled = jax.jit(fragment, donate_argnums=(0, 1)).lower(
+        chip((rows, 8, 8256, 128), jnp.float32), chip((rows, 8, 8256), jnp.float32),
+        chip((rows, 1, 5120), bf), chip((rows,), jnp.int32), p).compile()
+    text = compiled.as_text()
+    held = "f32[32,8,8256,128]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and held in line.split("(")[0]]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6  # no second state
+    entry = text[text.index("ENTRY "):]
+    state = re.search(r"(%[\w.-]+) = f32\[32,8,8256,128\]\S* parameter\(", entry).group(1)
+    readers = [line.strip()[:120] for line in entry.splitlines()
+               if re.search(re.escape(state) + r"[,)]", line.split(" = ", 1)[-1])]
+    assert len(readers) == 1 and "fusion(" in readers[0], readers  # decays and writes it
+
+
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])

@@ -85,7 +85,17 @@ def _host_account(rounds: list[dict], track: list[dict]) -> str:
             part_us[label] += _union_us([(a, b) for n, a, b in inside if n == name])
         self_us += (hi - lo) - _union_us([(a, b) for _, a, b in inside])
     parts = ", ".join(f"{label} {us / 1e6:.3f}" for label, us in part_us.items())
-    return f"    host s: {parts}, self {self_us / 1e6:.3f}"
+    line = f"    host s: {parts}, self {self_us / 1e6:.3f}"
+    # what the slots' row states hold (the gauge engine/slot_state_bytes, the
+    # largest round's) and what the decode steps moved of a power-retention
+    # model's (the counter engine/power_state_bytes, summed), where a round says
+    held = max((r.get("args", {}).get("slot_state_bytes", 0) for r in rounds), default=0)
+    moved = sum(r.get("args", {}).get("power_state_bytes", 0) for r in rounds)
+    if held:
+        line += f"; slot state {held / 1e9:.3f} GB"
+    if moved:
+        line += f", moved {moved / 1e9:.1f} GB"
+    return line
 
 
 def resilience_section(spans: dict[tuple[int, str], list[dict]]) -> list[str]:
