@@ -317,6 +317,41 @@ def _delta_step_case(key, *, rows, heads, head_dim, steps=4):
     return {"impl": ran, "max_abs_err": float(f"{err:.3g}")}
 
 
+def _power_step_case(key, *, rows, kv_heads, group, head_dim, steps=4):
+    """The one-token power-retention step as ``power_step`` dispatches it (the
+    Mosaic kernel on a TPU at heads of 128, float32 throughout) against the
+    plain form, ``steps`` tokens from one carried ``(S, z)``; what ran is read
+    from the dispatch record."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops import power_retention as pr
+
+    ks = jax.random.split(key, 6)
+    heads = kv_heads * group
+    q = jax.random.normal(ks[0], (steps, rows, heads, head_dim)) + 1.0
+    k = jax.random.normal(ks[1], (steps, rows, kv_heads, head_dim)) + 1.0
+    v = jax.random.normal(ks[2], (steps, rows, kv_heads, head_dim))
+    g = jax.nn.log_sigmoid(2.0 * jax.random.normal(ks[3], (steps, rows, kv_heads)) + 4.0)
+    # a state that remembers a prompt: 64 keys' second powers and their values
+    pk = pr.phi((jax.random.normal(ks[4], (rows, kv_heads, 64, head_dim)) + 1.0)
+                * head_dim ** -0.25)
+    got = want = (jnp.einsum("bkjd,bkjv->bkdv", pk, jax.random.normal(
+        ks[5], (rows, kv_heads, 64, head_dim)), precision="highest"), pk.sum(2))
+    step, plain = jax.jit(pr.power_step), jax.jit(pr.power_step_plain)
+    err = 0.0
+    for t in range(steps):
+        got_o, got = step(q[t], k[t], v[t], g[t], got)
+        want_o, want = plain(q[t], k[t], v[t], g[t], want)
+        err = max(err, float(jnp.max(jnp.abs(got_o - want_o))),
+                  *(float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, want)))
+    ran = pr.dispatch_choices[pr.dispatch_key(kv_heads, group, head_dim, head_dim)]
+    assert got[0].dtype == got[1].dtype == jnp.float32
+    assert np.isfinite(err) and err < 2e-5, f"power step {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": float(f"{err:.3g}")}
+
+
 def phase_kernels(seed: int, compiles: CompileLog) -> None:
     """Each Pallas kernel the trainer phases use, compiled (never
     interpreted) at the 0.5B geometry, against its reference on the chip."""
@@ -371,6 +406,11 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     # rule at its 64 heads of 128, the Mosaic kernel against the plain form
     out["delta_step"] = _delta_step_case(key, rows=16, heads=64, head_dim=128)
     assert out["delta_step"]["impl"] == "kernel", out["delta_step"]
+    # the benchmark's fifth configuration (Brumby-14B): the one-token power
+    # retention step over 8 KV heads' states of 8,256 x 128, five query heads a
+    # state, the Mosaic kernel against the plain form
+    out["power_step"] = _power_step_case(key, rows=8, kv_heads=8, group=5, head_dim=128)
+    assert out["power_step"]["impl"] == "kernel", out["power_step"]
     # the learner's attention: the trainer phases run the CLI's default
     # attn_impl="reference" (XLA), so no attention kernel is on their path
     out["learner_attention"] = "reference (XLA): no kernel selected"

@@ -255,6 +255,22 @@ def _record_sparse_telemetry(cfg: ModelConfig, steps: int, cache_dtype) -> None:
         telemetry.OPS_SPARSE_KERNEL_STEPS, layers * steps * (ran == "kernel"))
 
 
+def _record_power_telemetry(cfg: ModelConfig, steps: int) -> None:
+    """``ops/power_kernel_steps``: the round's power-retention layer-steps that
+    ran as the one-token Mosaic kernel, read from what ``power_step`` recorded
+    for this model's heads when the step was traced (0 where it took the plain
+    form). A model without such layers files nothing."""
+    layers = cfg.kind_count("power")
+    if not layers or not steps:
+        return
+    from distrl_llm_tpu.ops.power_retention import dispatch_choices, dispatch_key
+
+    ran = dispatch_choices.get(dispatch_key(
+        cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim, cfg.head_dim))
+    telemetry.counter_add(
+        telemetry.OPS_POWER_KERNEL_STEPS, layers * steps * (ran == "kernel"))
+
+
 def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
                    prompt_pages: int, page_size: int, lora_scale: float,
                    cache_dtype, attn_impl: str, kv_quant: str = "none"):
@@ -4127,6 +4143,7 @@ class PagedGenerationEngine(LoraMailbox):
             )
         _record_delta_telemetry(self.cfg, dispatched)
         _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
+        _record_power_telemetry(self.cfg, dispatched)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4248,6 +4265,7 @@ class PagedGenerationEngine(LoraMailbox):
         )
         _record_delta_telemetry(self.cfg, steps_seen[0])
         _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
+        _record_power_telemetry(self.cfg, steps_seen[0])
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

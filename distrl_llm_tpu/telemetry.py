@@ -154,6 +154,12 @@ OPS_DELTA_KERNEL_STEPS = "ops/delta_kernel_steps"
 # plain form (a CPU, small heads, quantized pages). Filed beside the counter
 # above; no metric reads it
 OPS_SPARSE_KERNEL_STEPS = "ops/sparse_kernel_steps"
+# counter: a round's power-retention layer-steps that went through the one-token
+# Mosaic kernel (ops/power_retention.py::power_step_kernel): layers x decode
+# steps where ``power_step`` chose it, 0 where it took the plain form (a CPU,
+# small heads, a bf16 state). Filed beside the two counters above; no metric
+# reads it
+OPS_POWER_KERNEL_STEPS = "ops/power_kernel_steps"
 # device scopes: the train step (learner/). JAX writes the rest of the path:
 # ``transpose(jvp(learner/loss))`` is the backward pass and
 # ``rematted_computation`` under it the recomputed forward

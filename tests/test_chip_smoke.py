@@ -73,6 +73,17 @@ def test_the_delta_step_check_holds_at_tiny_size():
     assert case == {"impl": "plain", "max_abs_err": 0.0}
 
 
+def test_the_power_step_check_holds_at_tiny_size():
+    """The kernels phase's check of the one-token power-retention step, here on
+    the form a CPU takes: the dispatch record says "plain", which the chip's
+    phase refuses (it asserts "kernel")."""
+    import jax
+
+    case = chip_smoke._power_step_case(
+        jax.random.PRNGKey(0), rows=2, kv_heads=2, group=5, head_dim=16)
+    assert case == {"impl": "plain", "max_abs_err": 0.0}
+
+
 @pytest.mark.parametrize("engine_impl,steps", [("paged", 3), ("dense", 1)])
 def test_trainer_phase_runs_end_to_end_at_tiny_size(compiles, engine_impl, steps):
     """The assembly the chip runs at Qwen2.5-0.5B width, here at TINY: every

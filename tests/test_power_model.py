@@ -430,7 +430,41 @@ def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
     held = (slots or 8) * 3 * STATE_BYTES
     assert after["gauges"]["engine/slot_state_bytes"] == held
     assert engine.last_round_stats["slot_state_bytes"] == held
-    assert power_retention.dispatch_choices[(slots or 8, 2, 5, 16)] == "plain"
+    assert power_retention.dispatch_choices[power_retention.dispatch_key(2, 5, 16, 16)] == "plain"
+
+
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_cpu_round_counts_no_kernel_steps(weights, small_pieces, scheduler, slots):
+    """``ops/power_kernel_steps`` is filed by both schedulers and reads 0 here:
+    heads of 16 on a CPU take the plain form, and ``power_step`` says so."""
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    before = telemetry.observe_snapshot()["counters"].get(telemetry.OPS_POWER_KERNEL_STEPS, 0)
+    generate(make_engine(scheduler, slots), params, lora)
+    assert power_retention.dispatch_choices[
+        power_retention.dispatch_key(2, 5, 16, 16)] == "plain"
+    after = telemetry.observe_snapshot()["counters"]
+    assert after[telemetry.OPS_POWER_KERNEL_STEPS] == before
+
+
+@pytest.mark.parametrize("ran,steps,want", [
+    ("kernel", 256, 3 * 256), ("plain", 256, 0), (None, 256, 0), ("kernel", 0, None)])
+def test_the_counter_is_layers_times_steps_where_the_kernel_ran(monkeypatch, ran, steps, want):
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+
+    assert CFG.kind_count("power") == 3
+    monkeypatch.setattr(power_retention, "dispatch_choices", {} if ran is None else {
+        power_retention.dispatch_key(2, 5, 16, 16): ran})
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_power_telemetry(CFG, steps)
+    assert filed == ([] if want is None else [("ops/power_kernel_steps", want)])
+    # a model without such layers files nothing
+    filed.clear()
+    paged_engine._record_power_telemetry(PRESETS["tiny"], 256)
+    assert filed == []
 
 
 ENGINE_CONTROLS = {

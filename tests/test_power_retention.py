@@ -7,6 +7,8 @@ zero, and what is divided by it is large).
 The model around them is held by ``tests/test_power_model.py``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,7 +102,7 @@ def test_the_step_is_the_attention_form_and_ends_in_the_chunked_forms_state(rows
     _, (big_c, z_c) = pr.power_chunked(q, k, v, g, valid, chunk=8)
     np.testing.assert_allclose(big, big_c, atol=TOL)
     np.testing.assert_allclose(z, z_c, atol=TOL)
-    assert pr.dispatch_choices[(B, K, H // K, D)] == "plain"
+    assert pr.dispatch_choices[pr.dispatch_key(K, H // K, D, D)] == "plain"
 
 
 def test_a_padded_token_neither_decays_nor_writes(rows):
@@ -187,3 +189,101 @@ def test_reverse_mode_through_the_chunks_is_the_attention_forms(rows):
     for a, b in zip(got, want):
         assert float(jnp.abs(b).max()) > 1e-3
         np.testing.assert_allclose(a, b, atol=4e-5 * float(jnp.abs(b).max()))
+
+
+# ------------------------------------------- the one-token step as a kernel
+
+WIDE = 128  # the kernel takes whole 128-lane heads: a tile of 8,256 x 128
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))  # eager, phi alone is 65 dispatches
+def wide_step(rows, kv, group, decay, seed=0):
+    """One token's q, k, v, g at heads of 128 and a state that remembers 48
+    keys, so that the sum of weights is far from zero and ``o`` is of order 1."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (rows, kv * group, WIDE)) + 1.0
+    k = jax.random.normal(ks[1], (rows, kv, WIDE)) + 1.0
+    v = jax.random.normal(ks[2], (rows, kv, WIDE))
+    g = jnp.full((rows, kv), np.log(decay), jnp.float32)
+    pk = pr.phi((jax.random.normal(ks[3], (rows, kv, 48, WIDE)) + 1.0) * WIDE ** -0.25)
+    big = jnp.einsum("bkjd,bkjv->bkdv", pk, jax.random.normal(ks[4], (rows, kv, 48, WIDE)))
+    return q, k, v, g, (big, pk.sum(2))
+
+
+plain_step = jax.jit(pr.power_step_plain)
+
+
+@pytest.mark.parametrize("rows,kv,group,decay", [
+    (1, 1, 5, 0.99), (3, 2, 1, 1.0), (1, 2, 5, 1.0)])
+def test_the_kernel_is_the_plain_step(rows, kv, group, decay):
+    """``power_step_kernel`` through the interpreter against ``power_step_plain``
+    on ``o``, S and z: phi(k) and phi(q) expanded inside from the 128-vectors,
+    the squares once and every other pair by sqrt(2), the half diagonal's 64
+    rows, five query heads reading one tile (or one), a log-decay of zero."""
+    q, k, v, g, state = wide_step(rows, kv, group, decay, seed=rows + 10 * kv + group)
+    want_o, (want_s, want_z) = plain_step(q, k, v, g, state)
+    got_o, (got_s, got_z) = pr.power_step_kernel(q, k, v, g, *state, interpret=True)
+    assert got_s.shape == (rows, kv, 8256, WIDE) and got_s.dtype == got_z.dtype == jnp.float32
+    assert 0.1 < float(jnp.abs(want_o).max()) < 10.0
+    close(got_o, want_o)
+    np.testing.assert_allclose(got_s, want_s, atol=TOL)
+    np.testing.assert_allclose(got_z, want_z, atol=TOL)
+
+
+def test_a_prompt_through_the_chunks_then_tokens_through_the_kernel():
+    """Prefill's carry is the kernel's state as it stands (packed, float32, one
+    a KV head): 24 tokens through ``power_chunked``, then 16 through the kernel
+    from its (S, z), against the attention form over the whole row of 40."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    rows, kv, group, prompt, more = 1, 1, 2, 24, 16
+    s = prompt + more
+    q = jax.random.normal(ks[0], (rows, s, kv * group, WIDE)) + 1.0
+    k = jax.random.normal(ks[1], (rows, s, kv, WIDE)) + 1.0
+    v = jax.random.normal(ks[2], (rows, s, kv, WIDE))
+    g = jax.nn.log_sigmoid(2.0 * jax.random.normal(ks[3], (rows, s, kv)) + 4.0)
+    valid = jnp.ones((rows, s), jnp.int32)
+    want = pr.power_attention(q, k, v, g, valid)
+    first, state = pr.power_chunked(
+        q[:, :prompt], k[:, :prompt], v[:, :prompt], g[:, :prompt], valid[:, :prompt], chunk=8)
+    outs = [first]
+    for t in range(prompt, s):
+        o, state = pr.power_step_kernel(q[:, t], k[:, t], v[:, t], g[:, t], *state,
+                                        interpret=True)
+        outs.append(o[:, None])
+    close(jnp.concatenate(outs, 1), want)
+
+
+@pytest.mark.parametrize("backend,dtype,head,want", [
+    ("tpu", jnp.float32, 128, "kernel"), ("cpu", jnp.float32, 128, "plain"),
+    ("tpu", jnp.bfloat16, 128, "plain"), ("tpu", jnp.float32, 16, "plain"),
+    ("cpu", jnp.bfloat16, 16, "plain")])
+def test_the_step_chooses_from_what_it_can_observe(monkeypatch, backend, dtype, head, want):
+    """The backend, the state's type and whether a head is whole 128-lane
+    tiles: no argument, field or variable selects the form."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    big = pr.state_dim(head)
+    state = (jax.ShapeDtypeStruct((4, 2, big, head), dtype),
+             jax.ShapeDtypeStruct((4, 2, big), dtype))
+    assert pr.power_step_impl(state) == want
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_the_step_records_its_choice_under_a_key_without_the_rows(monkeypatch, backend):
+    """``power_step`` on a "TPU" goes to the kernel (here through the
+    interpreter) and says so; the record's key has no rows, so an engine that
+    traces the step at its shard's rows reads the same entry."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pr, "power_step_kernel",
+                        functools.partial(pr.power_step_kernel, interpret=True))
+    monkeypatch.setattr(pr, "dispatch_choices", {})
+    want = {"tpu": "kernel", "cpu": "plain"}[backend]
+    for rows in (1, 2):
+        q, k, v, g, state = wide_step(rows, 1, 1, 0.99, seed=rows)
+        # a function of this test's own: the record is made when it is traced
+        got_o, got = jax.jit(lambda *xs: pr.power_step(*xs))(q, k, v, g, state)
+        want_o, wanted = plain_step(q, k, v, g, state)
+        close(got_o, want_o)
+        np.testing.assert_allclose(got[0], wanted[0], atol=TOL)
+        np.testing.assert_allclose(got[1], wanted[1], atol=TOL)
+        assert pr.dispatch_choices == {pr.dispatch_key(1, 1, WIDE, WIDE): want}
+    assert pr.dispatch_key(1, 1, WIDE, WIDE) == (1, 1, WIDE, WIDE, "float32")

@@ -378,16 +378,20 @@ def test_delta_rule_decode_fragment_updates_the_state_in_place(chip, monkeypatch
     assert len(readers) == 1 and "custom-call" in readers[0], readers
 
 
-def test_power_retention_decode_fragment_updates_the_state_in_place(chip):
+@pytest.mark.parametrize("backend,reader", [("tpu", "custom-call"), ("cpu", "fusion(")])
+def test_power_retention_decode_fragment_updates_the_state_in_place(
+        chip, monkeypatch, backend, reader):
     """One power-retention layer's decode step at the cell's sizes (32 rows, 40
     query heads over 8 KV heads of 128: a float32 state of 8,256 x 128 a KV
-    head, 1.08 GB a layer, and its normaliser): the state is decayed and written
-    in place by ONE fusion (no ``copy`` of it, no temporary of its size beside
-    the donated one) and read once more by the product with phi(q), which is
-    what plain XLA gives (1.5 x the bytes: PERF.md, PR 40); phi(q) for all 40
-    heads (42 MB) is the largest temporary."""
-    from functools import partial
-
+    head, 1.08 GB a layer, and its normaliser). The one-token step is the
+    Mosaic kernel (``power_step`` asks the backend, which is the CPU here: the
+    test answers for the chip it compiles for): the state is updated in place
+    (no ``copy`` of it, no temporary of its size beside the donated one) and
+    exactly ONE operation reads it, the kernel, which brings a KV head's tile
+    into VMEM once. Answered "cpu", the step is the plain form: ONE fusion
+    decays and writes the state in place and the product with phi(q) reads it
+    once more (1.5 x the bytes: PERF.md, PR 40 and PR 41)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
     from distrl_llm_tpu.models import ModelConfig
     from distrl_llm_tpu.models.hybrid import _block
     from distrl_llm_tpu.models.transformer import rope_cos_sin
@@ -426,7 +430,8 @@ def test_power_retention_decode_fragment_updates_the_state_in_place(chip):
     state = re.search(r"(%[\w.-]+) = f32\[32,8,8256,128\]\S* parameter\(", entry).group(1)
     readers = [line.strip()[:120] for line in entry.splitlines()
                if re.search(re.escape(state) + r"[,)]", line.split(" = ", 1)[-1])]
-    assert len(readers) == 1 and "fusion(" in readers[0], readers  # decays and writes it
+    assert len(readers) == 1 and reader in readers[0], readers  # decays and writes it
+    assert ("tpu_custom_call" in text) == (backend == "tpu")
 
 
 @pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
