@@ -109,6 +109,11 @@ ENGINE_MOE_PAIRS_ROUTED = "engine/moe_pairs_routed"        # counter
 # of (live row, layer) states, ``mixer["power_stats"]``, and fetched with the
 # round's result: a round's bytes pass what an int32 holds)
 ENGINE_POWER_STATE_BYTES = "engine/power_state_bytes"      # counter
+# state-space layers (ops/selective_scan.py): (live row, layer) states the
+# decode steps read and wrote, summed over Mamba layers and steps (carried in
+# ``mixer["ssm_stats"]`` as a count, not bytes, and fetched with the round's
+# result); x a state's bytes, twice, is what the steps had to move
+ENGINE_SSM_STATES_STEPPED = "engine/ssm_states_stepped"    # counter
 # bytes the decode state's row states hold (models/hybrid.py::ROW_STATES, every
 # kind: recurrent states, normalisers, convolution tails, pooled keys), filed
 # when a round's decode state is built, with tracing on or off
@@ -183,8 +188,10 @@ def _count_mixer_stats(mixer) -> dict:
     layers' pairs, held here / chosen over all experts; ``mixer["latent_stats"]``:
     absorbed attention's pages; ``mixer["power_stats"]``: the (live row, layer)
     power-retention states the decode steps read and wrote, filed as the bytes
-    of S and z that is, a read and a write each) with telemetry. Returns what
-    the round's span says of them: ``power_state_bytes``, where there are any."""
+    of S and z that is, a read and a write each; ``mixer["ssm_stats"]``: the
+    (live row, layer) state-space states they read and wrote, filed as that
+    count) with telemetry. Returns what the round's span says of them:
+    ``power_state_bytes`` / ``ssm_states_stepped``, where there are any."""
     said = {}
     if mixer is not None and "power_stats" in mixer:
         a_state = sum(x.nbytes // x.shape[0]
@@ -192,6 +199,10 @@ def _count_mixer_stats(mixer) -> dict:
         moved = 2 * a_state * int(np.asarray(mixer["power_stats"])[0])
         telemetry.counter_add(ENGINE_POWER_STATE_BYTES, moved)
         said["power_state_bytes"] = moved
+    if mixer is not None and "ssm_stats" in mixer:
+        stepped = int(np.asarray(mixer["ssm_stats"])[0])
+        telemetry.counter_add(ENGINE_SSM_STATES_STEPPED, stepped)
+        said["ssm_states_stepped"] = stepped
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),

@@ -44,6 +44,14 @@ of a K/V cache a float32 state of the symmetric second power of the keys,
 ``[D, head_dim]`` with ``D = head_dim (head_dim + 1) / 2``, and a normaliser
 ``[D]``, ONE a KV head, read by the query heads that share it. No layer keeps
 a page: ``paged_layers`` is 0 and a slot's whole cache is state.
+
+A state-space model (``jamba``, AI21-Jamba2-3B) has two kinds, placed by
+``attn_layer_period`` / ``attn_layer_offset`` (layer ``i`` is attention where
+``i % period == offset``): "softmax" (multi-query attention without RoPE, K/V
+pages; published name "attention") and "mamba" (Mamba-1,
+``ops/selective_scan.py``: a float32 state ``[d_state, d_inner]`` and the last
+``d_conv - 1`` tokens of its convolution's input a slot), each followed by the
+dense gated MLP: its "softmax" layers have no routed experts.
 """
 
 from __future__ import annotations
@@ -56,14 +64,15 @@ MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
                # solar_open2 publishes ``gqa_layers``; from_hf_config names the rest
                "gqa": "softmax", "kda": "delta",
                # brumby publishes no list: from_hf_config names every layer
-               "power-retention": "power"}
+               "power-retention": "power",
+               # jamba publishes a period and an offset; from_hf_config names
+               # every layer. Its attention is a "softmax" layer with a dense MLP
+               "attention": "softmax", "mamba": "mamba"}
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
-    "solar_open2", "brumby",
+    "solar_open2", "brumby", "jamba",
 )
-#: layer kinds whose second half is routed experts beside a shared expert
-EXPERT_KINDS = ("latent_moe", "softmax", "delta")
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
     "sparse": "a selector cache of pooled keys",
@@ -74,6 +83,7 @@ _STATE_NAMES = {
     "delta": "a float32 delta-rule state and a convolution tail",
     "power": "a float32 power-retention state and its normaliser a KV head, "
              "and no K/V at all",
+    "mamba": "a float32 state-space state and a convolution window",
 }
 #: layer kind -> the published name a refusal gives it
 _LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
@@ -148,6 +158,11 @@ class ModelConfig:
     delta_conv_size: int = 4  # short_conv_kernel_size
     delta_low_rank: int = 0  # inner width of the decay's and the gate's pair
     delta_beta_scale: float = 1.0  # 2 with kda_allow_neg_eigval
+    # ---- Mamba-1 state-space layers (jamba's mamba_* keys). 0 = none
+    mamba_d_state: int = 0  # N: columns of a channel's state
+    mamba_d_conv: int = 4  # taps of the causal depth-wise convolution
+    mamba_expand: int = 2  # d_inner = expand x hidden
+    mamba_dt_rank: int = 0  # inner width of the step size's low-rank pair
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
@@ -201,6 +216,18 @@ class ModelConfig:
             "power-retention" in self.mixer_types[: self.num_layers])
 
     @property
+    def mamba(self) -> bool:
+        """True for a state-space model (``jamba``): "mamba" layers beside
+        "softmax" layers, each with a dense MLP."""
+        return self.mixer_types is not None and (
+            "mamba" in self.mixer_types[: self.num_layers])
+
+    @property
+    def mamba_inner(self) -> int:
+        """E: channels of a Mamba layer (``d_inner``)."""
+        return self.mamba_expand * self.hidden_size
+
+    @property
     def power_state_dim(self) -> int:
         """D: entries of the symmetric second power of a ``head_dim`` key."""
         return self.head_dim * (self.head_dim + 1) // 2
@@ -216,7 +243,7 @@ class ModelConfig:
     def layer_kinds(self) -> tuple[str, ...]:
         """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
         "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
-        "softmax" | "delta" | "power"."""
+        "softmax" | "delta" | "power" | "mamba"."""
         if self.latent:
             dense = min(self.first_dense_layers, self.num_layers)
             if not self.n_routed_experts:
@@ -399,6 +426,17 @@ class ModelConfig:
             4 + self.lightning_output_gate
         )
         power = attn + self.hidden_size * self.num_kv_heads  # and the decay's W_g
+        if self.mamba:
+            inner = self.mamba_inner
+            mamba = (  # W_in and W_out, W_x, W_dt
+                3 * self.hidden_size * inner
+                + inner * (self.mamba_dt_rank + 2 * self.mamba_d_state)
+                + self.mamba_dt_rank * inner)
+            return (
+                self.kind_count("softmax") * (attn + mlp)
+                + self.kind_count("mamba") * (mamba + mlp)
+                + self.hidden_size * self.vocab_size
+            )
         return (
             self.kind_count("sparse") * (sparse + mlp)
             + self.kind_count("lightning") * (lightning + mlp)
@@ -469,6 +507,12 @@ class ModelConfig:
             # product a KV head (3 D d), S^T phi(q) a query head (2 D d)
             attn = float(self.kind_count("power") * self.power_state_dim * self.head_dim
                          * (3 * self.num_kv_heads + 2 * self.num_heads))
+        if self.mamba:
+            # the attention layers attend over the context; a Mamba layer's token
+            # costs its state whatever the context (the decay, dt c B, the
+            # multiply-add, the reduction against C: 6 a state entry, and an exp)
+            attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
+                7.0 * self.kind_count("mamba") * self.mamba_inner * self.mamba_d_state)
         return 2.0 * self.matmul_param_count + attn
 
     def train_flops_per_token(self, seq_len: int) -> float:
@@ -487,6 +531,8 @@ class ModelConfig:
             return "solar_open2"
         if self.power:
             return "brumby"
+        if self.mamba:
+            return "jamba"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -567,6 +613,8 @@ class ModelConfig:
             hybrid = _delta_moe_fields(get)
         if mt == "brumby":
             hybrid = _power_fields(get)
+        if mt == "jamba":
+            hybrid = _jamba_fields(get, vars(hf))
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -589,6 +637,57 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+#: the ``mamba_*`` keys ``_jamba_fields`` reads; another one is a variant
+_MAMBA_KEYS = ("mamba_conv_bias", "mamba_d_conv", "mamba_d_state", "mamba_dt_rank",
+               "mamba_expand", "mamba_proj_bias")
+
+
+def _jamba_fields(get, keys) -> dict:
+    """The ``jamba`` keys as ``ModelConfig`` fields: the layer pattern from
+    ``attn_layer_period`` / ``attn_layer_offset`` (the published rule: layer
+    ``i`` is attention where ``i % period == offset``), the ``mamba_*`` sizes,
+    no RoPE. A variant that is not implemented is REFUSED by key, as
+    ``_latent_fields`` does: routed experts beside the Mamba layers (the
+    family's larger members), a bias on the Mamba projections, a convolution
+    without its bias, a window, and any ``mamba_*`` key this function does not
+    read (Mamba-2's heads and groups)."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"jamba with {key}={get(key)!r} is not supported: {why}")
+
+    if int(get("num_experts", 1) or 1) > 1:
+        refuse("num_experts", "every layer's second half is the dense gated MLP; "
+               "routed experts beside Mamba layers (expert_layer_period / _offset, "
+               "num_experts_per_tok) are not implemented, and a guessed router is "
+               "worse than none")
+    for key in sorted(k for k in keys if k.startswith("mamba_") and k not in _MAMBA_KEYS):
+        refuse(key, "the state-space layers are Mamba-1's (mamba_d_state, "
+               "mamba_d_conv, mamba_dt_rank, mamba_expand); another mamba_* key "
+               "names a variant that is not implemented")
+    if get("mamba_proj_bias", False):
+        refuse("mamba_proj_bias", "W_in and W_out carry no bias")
+    if not get("mamba_conv_bias", True):
+        refuse("mamba_conv_bias", "the convolution's bias is always read")
+    if get("sliding_window") is not None:
+        refuse("sliding_window", "the attention layers attend over the whole "
+               "context; a window is not implemented")
+    period, offset = get("attn_layer_period"), get("attn_layer_offset")
+    if not period or offset is None:
+        raise ValueError(
+            "model_type 'jamba' needs attn_layer_period and attn_layer_offset")
+    rank = get("mamba_dt_rank", "auto")
+    hidden = int(get("hidden_size"))
+    return dict(
+        mixer_types=tuple(
+            "attention" if i % int(period) == int(offset) else "mamba"
+            for i in range(int(get("num_hidden_layers")))),
+        attn_use_rope=False,
+        mamba_d_state=int(get("mamba_d_state", 16)),
+        mamba_d_conv=int(get("mamba_d_conv", 4)),
+        mamba_expand=int(get("mamba_expand", 2)),
+        mamba_dt_rank=-(-hidden // 16) if rank == "auto" else int(rank),
+    )
 
 
 def _power_fields(get) -> dict:
@@ -761,6 +860,15 @@ TINY_POWER = ModelConfig(
     mixer_types=("power-retention",) * 3, qk_norm=True,
 )
 
+# a state-space model at a size the CPU tests run: one period's kinds (attention
+# at 1, Mamba at 0, 2, 3), 4 query heads over ONE KV head, a state of 16 x 64
+TINY_JAMBA = ModelConfig(
+    vocab_size=256, hidden_size=32, intermediate_size=64, num_layers=4,
+    num_heads=4, num_kv_heads=1, head_dim=16, tie_word_embeddings=True,
+    mixer_types=("mamba", "attention", "mamba", "mamba"), attn_use_rope=False,
+    mamba_d_state=16, mamba_dt_rank=8,
+)
+
 QWEN2_0_5B = ModelConfig(
     vocab_size=151936, hidden_size=896, intermediate_size=4864, num_layers=24,
     num_heads=14, num_kv_heads=2, head_dim=64, rope_theta=1000000.0,
@@ -813,6 +921,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-latent-moe": TINY_LATENT_MOE,
     "tiny-delta-moe": TINY_DELTA_MOE,
     "tiny-power": TINY_POWER,
+    "tiny-jamba": TINY_JAMBA,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

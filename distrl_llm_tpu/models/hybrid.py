@@ -97,6 +97,26 @@ the query heads that share it) and ``power_z`` (its normaliser ``[B, K, D2]``).
 No layer keeps a page: ``k`` and ``v`` are empty tuples. ``power_stats`` [1]
 int32 counts the (live row, layer) states the decode steps read and wrote.
 
+**State-space layers** (``jamba``, AI21-Jamba2-3B) are one more kind, "mamba"
+(Mamba-1, ``ops/selective_scan.py``), beside "softmax" layers (here
+multi-query attention without RoPE and without a gate), each followed by the
+dense gated MLP (``_mlp_half``; this family's "softmax" layers have no routed
+experts), with no positional encoding anywhere::
+
+    [u, z]      = W_in h                                   u first, z second
+    c_t         = silu(b_conv + conv4(u)_t)                causal, depth-wise
+    [d, B, C]_t = W_x c_t;   d, B, C <- RMSNorm            three inner norms
+    dt_t        = softplus(W_dt d_t + b_dt);   A = -exp(A_log)      float32
+    h_t         = exp(dt_t A) h_{t-1} + (dt_t c_t) B_t^T;  y_t = h_t C_t + D c_t
+    out         = W_out (y_t * silu(z_t))
+
+A Mamba layer keeps a FIFTH kind of slot state: ``ssm`` (a float32
+``[B, d_state, d_inner]`` state, the published ``[d_inner, d_state]``
+transposed so that the channels lie along the lanes) and its convolution
+window, which is the entry ``conv`` (the last three tokens' ``u``,
+``[B, 3, d_inner]``), tuples over the Mamba layers. ``ssm_stats`` [1] int32
+counts the (live row, layer) states the decode steps read and wrote.
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -132,6 +152,7 @@ from distrl_llm_tpu.ops.latent_attention import (
 from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
 from distrl_llm_tpu.ops.power_retention import init_state, power_chunked, power_step
+from distrl_llm_tpu.ops.selective_scan import ssm_chunked, ssm_step
 from distrl_llm_tpu.ops.sparse_attention import (
     pool_keys, pooled_count, sparse_attend, sparse_decode, update_pooled,
 )
@@ -146,7 +167,7 @@ LATENT_DECODE_ROWS = 16
 SOFTMAX_SEGMENT_PAGES = 2
 #: the entries of a slot's state that hold one array a ROW for each layer of a
 #: kind (tuples): what a candidate is handed from its prompt
-ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z")
+ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm")
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -219,10 +240,18 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             "wv": init((n, d, kv_dim)), "wo": init((n, q_dim, d)),
         }
 
+    def mlp_half(n: int) -> Params:
+        d, f = cfg.hidden_size, cfg.intermediate_size
+        return {"mlp_norm": jnp.ones((n, d), dtype), "w_gate": init((n, d, f)),
+                "w_up": init((n, d, f)), "w_down": init((n, f, d))}
+
     layers: Params = {}
     if cfg.kind_count("softmax"):
         n = cfg.kind_count("softmax")
-        layers["softmax"] = {**mixer_stack(n, cfg.q_dim, cfg.kv_dim), **expert_half(n)}
+        layers["softmax"] = {
+            **mixer_stack(n, cfg.q_dim, cfg.kv_dim),
+            # a state-space model's attention layers have the dense MLP
+            **(expert_half(n) if cfg.delta_moe else mlp_half(n))}
         if cfg.attn_output_gate:
             layers["softmax"]["wg"] = init((n, cfg.hidden_size, cfg.q_dim))
     if cfg.kind_count("delta"):
@@ -237,6 +266,28 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             "wb": init((n, d, cfg.delta_heads)),
             "wg_a": init((n, d, r)), "wg_b": init((n, r, wide)),
             "head_norm": jnp.ones((n, cfg.delta_head_dim), dtype),
+        }
+    if cfg.kind_count("mamba"):
+        n, d, e = cfg.kind_count("mamba"), cfg.hidden_size, cfg.mamba_inner
+        cols, r = cfg.mamba_d_state, cfg.mamba_dt_rank
+        layers["mamba"] = {
+            "attn_norm": jnp.ones((n, d), dtype),
+            "w_in": init((n, d, 2 * e)),  # u first, z second
+            "conv": init((n, cfg.mamba_d_conv, e)), "b_conv": jnp.zeros((n, e), dtype),
+            "w_x": init((n, e, r + 2 * cols)),  # d, B, C
+            "ssm_dt_norm": jnp.ones((n, r), dtype),
+            "ssm_b_norm": jnp.ones((n, cols), dtype),
+            "ssm_c_norm": jnp.ones((n, cols), dtype),
+            "w_dt": init((n, r, e)),
+            # the inverse softplus of a step of 0.01
+            "b_dt": jnp.full((n, e), -4.6, dtype),
+            # A = -(1..N) a channel, held [N, E] (ops/selective_scan.py)
+            "ssm_a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, cols + 1, dtype=jnp.float32))[None, :, None],
+                (n, cols, e)).astype(dtype),
+            "ssm_d": jnp.ones((n, e), dtype),
+            "w_out": init((n, e, d)),
+            **mlp_half(n),
         }
     for kind in ("latent", "latent_moe"):
         if cfg.kind_count(kind):
@@ -268,8 +319,9 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     """What a slot holds beside its K/V pages: a float32 state per lightning
     layer, the selector's pooled keys per sparse layer, a float32 state and a
     convolution tail per delta-rule layer, a float32 state and its normaliser
-    per power-retention layer, the round's counters. The entries named in
-    ``ROW_STATES`` are tuples of one array a row."""
+    per power-retention layer, a float32 state and a convolution window per
+    Mamba layer, the round's counters. The entries named in ``ROW_STATES`` are
+    tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counter
         return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
                 "latent_stats": jnp.zeros((2,), jnp.int32)}
@@ -283,6 +335,16 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                 for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
+        }
+    if cfg.mamba:
+        n, e = cfg.kind_count("mamba"), cfg.mamba_inner
+        return {
+            "lin": (), "pooled": (),
+            "ssm": tuple(
+                jnp.zeros((rows, cfg.mamba_d_state, e), jnp.float32) for _ in range(n)),
+            "conv": tuple(
+                jnp.zeros((rows, cfg.mamba_d_conv - 1, e), cache_dtype) for _ in range(n)),
+            "ssm_stats": jnp.zeros((1,), jnp.int32),
         }
     if cfg.power:
         held = [init_state(rows, cfg.num_kv_heads, cfg.head_dim)
@@ -422,10 +484,13 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     if kind in ("latent", "latent_moe"):
         return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
                              mode=mode, env=env, proj=proj, lora_scale=lora_scale)
-    if kind in ("softmax", "delta"):
-        mix = _softmax_mix if kind == "softmax" else _delta_mix
+    if kind in ("softmax", "delta", "mamba"):
+        mix = {"softmax": _softmax_mix, "delta": _delta_mix, "mamba": _mamba_mix}[kind]
         x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
                        lora_scale=lora_scale)
+        if not cfg.delta_moe:  # a state-space model's layers: the dense MLP
+            return _mlp_half(x, p, lora, cfg=cfg, proj=proj,
+                             lora_scale=lora_scale), cache, None
         x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
                                 lora_scale=lora_scale)
         return x, cache, stats
@@ -604,6 +669,50 @@ def _delta_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
             b, s, wide) * _delta_gate(h, p)
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         x = x + proj(o, p, lora, "wo", "bo", lora_scale)
+    return x, (None if mode == "full" else (state, tail))
+
+
+def _mamba_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A Mamba-1 layer: (x + y, (state, window) or None)."""
+    inner, cols, rank = cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    state, tail = cache if cache is not None else (None, None)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        uz = proj(h, p, lora, "w_in", None, lora_scale)
+        u, z = uz[..., :inner], uz[..., inner:]
+    with jax.named_scope(telemetry.MODEL_SHORT_CONV):
+        mixed, kept = short_conv(
+            u, p["conv"], None if mode == "decode" else env["valid"], tail)
+        if mode == "segment":
+            # the window is read out of the segment's u before the scan runs: left
+            # to the scheduler, every layer's u (0.3 GB at 30 x 1,024) is kept to
+            # the end of the segment for a gather of three tokens
+            mixed, kept = jax.lax.optimization_barrier((mixed, kept))
+        tail = None if tail is None else kept.astype(tail.dtype)  # the cache's type
+        c = jax.nn.silu(mixed + p["b_conv"].astype(mixed.dtype))
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        dbc = linear(c, p["w_x"])
+    with jax.named_scope(telemetry.MODEL_SSM):
+        d, b, cc = (
+            rms_norm(dbc[..., lo:hi], p[name], cfg.rms_norm_eps)
+            for name, lo, hi in (("ssm_dt_norm", 0, rank),
+                                 ("ssm_b_norm", rank, rank + cols),
+                                 ("ssm_c_norm", rank + cols, rank + 2 * cols)))
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        raw = jnp.einsum("bsr,re->bse", d, p["w_dt"],
+                         preferred_element_type=jnp.float32)
+    with jax.named_scope(telemetry.MODEL_SSM):
+        dt = jax.nn.softplus(raw + p["b_dt"].astype(jnp.float32))
+        a = -jnp.exp(p["ssm_a_log"].astype(jnp.float32))
+        if mode == "decode":
+            y, state = ssm_step(
+                c[:, 0], dt[:, 0], b[:, 0], cc[:, 0], a, p["ssm_d"], state, z[:, 0])
+            y = y[:, None]
+        else:
+            y, state = ssm_chunked(
+                c, dt, b, cc, a, p["ssm_d"], env["valid"], state=state, z=z)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        x = x + proj(y, p, lora, "w_out", None, lora_scale)
     return x, (None if mode == "full" else (state, tail))
 
 
@@ -866,8 +975,9 @@ def forward_hybrid(
                 dropout_rng=layer_keys[i] if use_dropout else None)
             if moe_stats is not None and layer_stats is not None:
                 moe_stats = moe_stats + layer_stats
-        elif kind in ("softmax", "delta"):
-            names = ("k", "v") if kind == "softmax" else ("delta", "conv")
+        elif kind in ("softmax", "delta", "mamba"):
+            names = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
+                     "mamba": ("ssm", "conv")}[kind]
             x, held, layer_stats = block(
                 x, p, lora_p, None, tuple(new[name][j] for name in names), kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
@@ -906,6 +1016,10 @@ def forward_hybrid(
         live = b if env.get("alive") is None else env["alive"].sum()
         out["power_stats"] = kv_cache["power_stats"] + jnp.asarray(
             cfg.num_layers * live, jnp.int32)
+    if "ssm_stats" in kv_cache and mode == "decode":  # live rows' states, Mamba layers
+        live = b if env.get("alive") is None else env["alive"].sum()
+        out["ssm_stats"] = kv_cache["ssm_stats"] + jnp.asarray(
+            cfg.kind_count("mamba") * live, jnp.int32)
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
             kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)

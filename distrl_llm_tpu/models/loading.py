@@ -158,7 +158,7 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
 
 
 def _refuse_unnamed(cfg: ModelConfig) -> None:
-    """A ``solar_open2`` or ``brumby`` checkpoint is refused by name, in both directions:
+    """A ``solar_open2``, ``brumby`` or ``jamba`` checkpoint is refused by name, in both directions:
     its published tensor names cannot be read here, and names guessed for the
     delta-rule layers' convolutions, low-rank pairs and gates would load or
     save something else under the model's name. Seeded weights only."""
@@ -169,6 +169,13 @@ def _refuse_unnamed(cfg: ModelConfig) -> None:
             "and bias) are not known to this loader, and a guessed name would load "
             "or save something else under the model's name; the model runs from "
             "seeded weights only (init_params)")
+    if cfg.mamba:
+        raise NotImplementedError(
+            "model_type 'jamba' checkpoints are not supported: the published tensor "
+            "names of its Mamba layers (in_proj, conv1d, x_proj, dt_proj, A_log, D, "
+            "the three inner norms) cannot be checked here, the state's A_log is held "
+            "transposed, and a guessed name would load or save something else under "
+            "the model's name; the model runs from seeded weights only (init_params)")
     if cfg.delta_moe:
         raise NotImplementedError(
             "model_type 'solar_open2' checkpoints are not supported: the published "

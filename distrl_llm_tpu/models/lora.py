@@ -30,6 +30,9 @@ _TARGET_DIMS = {
     # latent attention (kv_a_proj_with_mqa, kv_b_proj); wo's input is then H x v
     "wkv_a": ("hidden_size", "latent_dim"),
     "wkv_b": ("kv_lora_rank", "kvb_dim"),
+    # a Mamba layer's two projections: [u, z] from the stream, and back
+    "w_in": ("hidden_size", "mamba_in_dim"),
+    "w_out": ("mamba_inner", "hidden_size"),
 }
 
 # reference target_modules (helper.py:29–37) in our key naming
@@ -38,6 +41,9 @@ DEFAULT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # kv_b, o, and the gated MLP that is a dense layer's MLP or an expert layer's
 # shared expert. The router and the routed experts are frozen.
 LATENT_TARGETS = ("wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down")
+# a Mamba layer has no q, k, v or o: its targets are W_in, W_out and the MLP's
+# three. W_x, W_dt, the convolution, A_log, D and the inner norms are frozen.
+MAMBA_TARGETS = ("w_in", "w_out", "w_gate", "w_up", "w_down")
 
 
 def lora_scale(rank: int, alpha: float) -> float:
@@ -62,13 +68,18 @@ def init_lora_params(
     (whose expert layers' w_gate / w_up / w_down are the SHARED expert's, as
     they are in a gated delta-rule model's "softmax" and "delta" layers). A
     power-retention layer has the dense decoder's seven targets and no factor
-    on its log-decay."""
+    on its log-decay. A state-space model's attention layers have the seven and
+    its Mamba layers ``MAMBA_TARGETS``; targets the caller names go to the
+    layers that have them."""
+    named = targets is not None
     if targets is None:
         targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
 
-    def factors(rng, n_layers: int, dims: dict[str, int]) -> Params:
+    def factors(rng, n_layers: int, dims: dict[str, int], targets=targets) -> Params:
         layers: Params = {}
         for key, target in zip(jax.random.split(rng, len(targets)), targets):
+            if not all(attr in dims for attr in _TARGET_DIMS[target]):
+                continue  # this kind of layer has no such projection
             d_in, d_out = (dims[attr] for attr in _TARGET_DIMS[target])
             a = jax.random.normal(key, (n_layers, d_in, rank)) * (rank**-0.5)
             layers[target] = {
@@ -82,6 +93,7 @@ def init_lora_params(
         for attr in ("hidden_size", "intermediate_size", "q_dim", "kv_dim")
     }
     dims["o_dim"] = cfg.q_dim  # what wo reads
+    kind_targets: dict[str, Sequence[str]] = {}  # a kind whose targets are its own
     if not cfg.hybrid:
         return {"layers": factors(rng, cfg.num_layers, dims)}
     if cfg.latent:
@@ -103,12 +115,18 @@ def init_lora_params(
     elif cfg.power:
         # Qwen3's seven targets; the log-decay's projection and bias are frozen
         per_kind = {"power": dims}
+    elif cfg.mamba:
+        per_kind = {"softmax": dims, "mamba": {
+            "hidden_size": cfg.hidden_size, "intermediate_size": cfg.intermediate_size,
+            "mamba_inner": cfg.mamba_inner, "mamba_in_dim": 2 * cfg.mamba_inner}}
+        kind_targets = {} if named else {"mamba": MAMBA_TARGETS}
     else:
         lightning = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.lightning_dim)
         per_kind = {"sparse": dims, "lightning": {**dims, **lightning}}
     kinds = [k for k in per_kind if cfg.kind_count(k)]
     return {"layers": {
-        kind: factors(key, cfg.kind_count(kind), per_kind[kind])
+        kind: factors(key, cfg.kind_count(kind), per_kind[kind],
+                      kind_targets.get(kind, targets))
         for key, kind in zip(jax.random.split(rng, len(kinds)), kinds)
     }}
 

@@ -65,6 +65,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 _TARGET_STREAM = {
     "wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5, "w_down": 6,
     "wkv_a": 7, "wkv_b": 8,  # latent attention (models/hybrid.py)
+    "w_in": 9, "w_out": 10,  # a Mamba layer's two projections
 }
 
 
@@ -384,6 +385,10 @@ _SLICE_SCOPES = {
     **dict.fromkeys(("wf_a", "wf_b", "wb", "A_log", "dt_bias"),
                     telemetry.MODEL_DELTA_ATTN),
     **dict.fromkeys(("wg", "wg_a", "wg_b", "head_norm"), telemetry.MODEL_ATTN_GATE),
+    # a state-space model's own leaves
+    "b_conv": telemetry.MODEL_SHORT_CONV,
+    **dict.fromkeys(("ssm_dt_norm", "ssm_b_norm", "ssm_c_norm", "b_dt", "ssm_a_log",
+                     "ssm_d"), telemetry.MODEL_SSM),
 }
 
 

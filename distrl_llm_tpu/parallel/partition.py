@@ -31,6 +31,11 @@ Layout conventions (models/transformer.py):
                b_decay [L, K]: one column a KV head) is whole on every chip
                (``_REPLICATED``); q, k, v, o and the MLP go as the dense
                decoder's.
+  state space: a Mamba layer's W_in [L, hidden, 2 d_inner] goes column
+               parallel and its W_out [L, d_inner, hidden] row parallel, like
+               the MLP's pair (39 of its 41 M parameters); W_x, W_dt, the
+               convolution, A_log, D and the inner norms are small and whole
+               on every chip.
 """
 
 from __future__ import annotations
@@ -45,9 +50,9 @@ Params = dict[str, Any]
 # layer weights whose OUT dim is tp-sharded (column parallel)
 # (wz, wg: the output gate of a sparse or lightning layer and of a gated
 # softmax layer, models/hybrid.py)
-_COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up"}
+_COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up", "w_in"}
 # layer weights whose IN dim is tp-sharded (row parallel)
-_ROW = {"wo", "w_down"}
+_ROW = {"wo", "w_down", "w_out"}
 # a latent-attention / expert layer's own leaves: whole on every chip
 _REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
                "experts_up", "experts_down",

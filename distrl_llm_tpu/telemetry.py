@@ -128,6 +128,11 @@ MODEL_ATTN_GATE = "model/attn_gate"
 # log-decay's logsigmoid and cumulation, and RoPE. q, k, v, o and the decay's
 # projection stay ``model/attn_proj``
 MODEL_POWER_ATTN = "model/power_attn"
+# state-space layers (jamba, ops/selective_scan.py): the three inner norms,
+# softplus, the discretisation, the one-token step or the chunked scan, the D
+# skip and the silu(z) gate. W_in, W_x, W_dt and W_out stay ``model/attn_proj``;
+# the convolution with its bias and SiLU is ``model/short_conv``
+MODEL_SSM = "model/ssm"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -179,6 +184,7 @@ SCOPE_NAMES = (
     MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
+    MODEL_SSM,
 )
 
 

@@ -434,17 +434,117 @@ def test_power_retention_decode_fragment_updates_the_state_in_place(
     assert ("tpu_custom_call" in text) == (backend == "tpu")
 
 
-@pytest.mark.parametrize("vocab", [VOCAB, 73448], ids=["v152k", "v73448"])
+def jamba_config(layers: int, period: int = 14, offset: int = 7):
+    """AI21-Jamba2-3B's widths (perfbench/configs/jamba2-3b.json), ``layers``
+    of them: attention where ``i % period == offset`` (the published 14 and 7),
+    Mamba elsewhere."""
+    from distrl_llm_tpu.models import ModelConfig
+
+    return ModelConfig(
+        vocab_size=65536, hidden_size=2560, intermediate_size=8192, num_layers=layers,
+        num_heads=20, num_kv_heads=1, head_dim=128, tie_word_embeddings=True,
+        mixer_types=tuple(
+            "attention" if i % period == offset else "mamba" for i in range(layers)),
+        attn_use_rope=False, mamba_d_state=16, mamba_dt_rank=160)
+
+
+def _jamba_decode_step(chip, monkeypatch, cfg, rows=480, page=128):
+    """The decode forward of ``cfg`` compiled for the chip from shapes alone:
+    (compiled, the cache's shapes)."""
+    from distrl_llm_tpu.models import forward, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    bf, width = jnp.bfloat16, (2048 + 384) // page
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0)))
+    pool = chip((1, 30 * 16 + rows * 4 + 8, page, 128), bf)
+    cache = {
+        "k": (pool,) * cfg.paged_layers, "v": (pool,) * cfg.paged_layers,
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, cache, ids):
+        return forward(params, cfg, ids, kv_cache=cache, page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, chip((rows, 1), jnp.int32)).compile()
+    return compiled, cache
+
+
+def test_state_space_decode_step_at_published_widths(chip, monkeypatch):
+    """One period of AI21-Jamba2-3B (14 layers at the published widths:
+    attention at 7, thirteen Mamba layers, the tied head over 65,536) as a
+    decode step of the cell's 480 rows. The attention layer's decode is
+    ``paged_attention_native`` at ONE KV head and a query group of 20 (no other
+    cell runs K < 2 or a group that is no multiple of 8). The head reads the
+    ``[65536, 2560]`` table where it lies: no copy and no transpose of it. The
+    thirteen states (``[480, 16, 5120]`` float32, the channels along the lanes)
+    are donated and updated in place: ONE fusion a layer reads a state, and it
+    both writes the new state and reduces it against C, so a step moves a
+    state once in and once out; no second state is kept live."""
+    cfg = jamba_config(14)
+    compiled, cache = _jamba_decode_step(chip, monkeypatch, cfg)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "%paged_attention_native" in calls[0], calls
+    assert "bf16[480,1,20,128]" in calls[0], calls  # rows, ONE KV head, its group of 20
+    table = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"bf16\[(65536,2560|2560,65536)\]", line.split(" = ")[-1].split("(")[0])
+             and (" copy(" in line or " transpose(" in line)]
+    assert not table, table
+    held = "f32[480,16,5120]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and held in line.split("(")[0]]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    states = 13 * 480 * 16 * 5120 * 4
+    assert memory.alias_size_in_bytes >= states  # every state's output is its input's buffer
+    assert memory.temp_size_in_bytes < 480 * 16 * 5120 * 4 + 250e6  # not a second set of states
+    entry = text[text.index("ENTRY "):]
+    for name in re.findall(r"(%[\w.-]+) = f32\[480,16,5120\]\S* parameter\(", entry):
+        readers = [line.strip()[:120] for line in entry.splitlines()
+                   if re.search(re.escape(name) + r"[,)]", line.split(" = ", 1)[-1])]
+        assert len(readers) == 1 and "fusion(" in readers[0], readers
+        assert "f32[480,5120]" in readers[0] and held in readers[0], readers  # y and the state
+
+
+def test_state_space_prefill_keeps_one_layers_segment(chip):
+    """The cell's prefill (30 prompts of 2,048 in segments of 1,024 through two
+    attention and six Mamba layers at the published widths): the window is read
+    out of a segment's ``u`` before the scan runs (``_mamba_mix``'s barrier),
+    so the temporaries are one layer's and do not grow with the depth. Left to
+    the scheduler every Mamba layer's u, 0.3 GB, was kept to the end of the
+    segment: 9.8 GB of temporaries at the whole depth."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import init_params
+
+    cfg = jamba_config(8, period=4, offset=1)  # attention at 1 and 5
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(
+        init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    prefill = functools.partial(
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=16, page_size=128,
+        lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference", total_tokens=2432)
+    compiled = jax.jit(prefill).lower(
+        params, None, chip((30, 2048), jnp.int32), chip((30, 2048), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("rows,vocab", [(ROWS, VOCAB), (ROWS, 73448), (480, 65536)],
+                         ids=["v152k", "v73448", "480xv65536"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_fused_sampler(chip, dtype, vocab):
+def test_fused_sampler(chip, dtype, rows, vocab):
     """73,448 (MiniCPM-SALA) is no multiple of 128: the row is padded to whole
     (8, 128) tiles with columns that can neither win nor carry mass."""
     from distrl_llm_tpu.ops.sampling import fused_sample
 
     assert_kernel(
         fused_sample,
-        chip((2,), jnp.uint32), chip((ROWS, vocab), dtype),
+        chip((2,), jnp.uint32), chip((rows, vocab), dtype),
         chip((), jnp.float32), chip((), jnp.float32),
     )
 

@@ -95,6 +95,10 @@ def _host_account(rounds: list[dict], track: list[dict]) -> str:
         line += f"; slot state {held / 1e9:.3f} GB"
     if moved:
         line += f", moved {moved / 1e9:.1f} GB"
+    # and the (live row, layer) states a state-space model's steps read and wrote
+    stepped = sum(r.get("args", {}).get("ssm_states_stepped", 0) for r in rounds)
+    if stepped:
+        line += f", {stepped} states stepped"
     return line
 
 
