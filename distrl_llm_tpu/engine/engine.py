@@ -29,8 +29,10 @@ TP/DP-sharded; pass host arrays and it runs single-chip.
 from __future__ import annotations
 
 import logging
+import math
 import threading
 import time
+import weakref
 from functools import partial
 from typing import Any, NamedTuple, Sequence
 
@@ -41,8 +43,9 @@ import numpy as np
 from distrl_llm_tpu import obs, telemetry
 from distrl_llm_tpu.config import SamplingConfig
 from distrl_llm_tpu.models.configs import ModelConfig
+from distrl_llm_tpu.engine.budget import ACTIVATION_RESERVE
 from distrl_llm_tpu.models.transformer import (
-    forward, init_kv_cache, init_kv_cache_int8,
+    decode_view, decode_view_leaves, forward, init_kv_cache, init_kv_cache_int8,
 )
 from distrl_llm_tpu.ops.per_device import params_mesh
 from distrl_llm_tpu.ops.sampling import sample_with_logprob
@@ -692,6 +695,27 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int, *,
     return state
 
 
+def _view_shortfall(held: list) -> int:
+    """Bytes by which a second copy of ``held`` (the stacked leaves a decode
+    view holds a layer at a time) would cut into the share of the device's
+    memory that engine/budget.py keeps for a round's workspace
+    (``ACTIVATION_RESERVE``): a device's shard of each leaf against the first
+    local device's memory as it stands now (``obs.hbm_stats``). 0 where the
+    copy fits, and where the backend reports no memory (the CPU). What the
+    round allocates after the view is built is not seen here."""
+    stats = obs.hbm_stats()
+    if not held or not stats or not stats.get("bytes_limit"):
+        return 0
+
+    def shard_bytes(leaf):
+        sharding = getattr(leaf, "sharding", None)
+        shape = sharding.shard_shape(leaf.shape) if sharding else leaf.shape
+        return math.prod(shape) * leaf.dtype.itemsize
+
+    room = int((1 - ACTIVATION_RESERVE) * stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+    return max(0, sum(map(shard_bytes, held)) - room)
+
+
 class LoraMailbox:
     """In-flight weight-update mailbox shared by every engine (PipelineRL —
     see ``push_lora``). ``_swapped_lora`` carries a consumed swap across the
@@ -718,6 +742,49 @@ class LoraMailbox:
     _track_prev_lora = False
     _prev_lora = None
     _prev_lora_version: int | None = None
+
+    # the frozen base as the cache-mode programs read it
+    # (models/transformer.py::decode_view), single-slot: (weak references to
+    # the leaves it was built from, the view, or None where it did not fit).
+    # Weak, so the slot neither keeps a base its caller dropped nor mistakes a
+    # new array at a dead one's address for it
+    _view_slot: tuple | None = None
+
+    def _decode_params(self, params):
+        """``params`` with the mixer's projections one ``[out, in]`` array a
+        layer, built once a base: the caller hands the stacked tree every
+        round, and the same leaves give the same view. New leaves (full
+        fine-tuning, a checkpoint load) drop the old view before the new one
+        is built. The view is a second copy of those leaves: where the device
+        has no room for it (``_view_shortfall``) the round reads the stacked
+        tree as it is, and a warning says so once a base. Files
+        ``engine/decode_view_builds`` and ``engine/decode_view_bytes``."""
+        leaves = jax.tree_util.tree_leaves(params)
+        slot = self._view_slot
+        if (
+            slot is not None and len(slot[0]) == len(leaves)
+            and all(ref() is leaf for ref, leaf in zip(slot[0], leaves))
+        ):
+            return params if slot[1] is None else slot[1]
+        self._view_slot = None
+        held = [leaf for _, leaf in decode_view_leaves(params["layers"])]
+        short = _view_shortfall(held)
+        if short:
+            _logger.warning(
+                "no decode view of this base: holding its q/k/v/o projections "
+                "a second time, one array a layer, would leave %.0f MB less "
+                "than the %.0f%% of the device's memory kept for a round's "
+                "workspace; every decode step slices and transposes them "
+                "from the stacked tree instead", short / 1e6,
+                100 * ACTIVATION_RESERVE)
+            view, held = None, []
+        else:
+            view = decode_view(params)
+            telemetry.counter_add(telemetry.ENGINE_DECODE_VIEW_BUILDS, 1)
+        telemetry.gauge_set(
+            telemetry.ENGINE_DECODE_VIEW_BYTES, float(sum(leaf.nbytes for leaf in held)))
+        self._view_slot = (tuple(weakref.ref(leaf) for leaf in leaves), view)
+        return params if view is None else view
 
     def _pending_mu(self) -> threading.Lock:
         # lazily per-instance (the mixin has no __init__); dict.setdefault
@@ -1095,6 +1162,7 @@ class GenerationEngine(LoraMailbox):
         # (the trainer hands the freshest adapter at round entry)
         self._reset_lora_mailbox_round()
         self.last_round_stats = None  # waves of THIS round accumulate below
+        params = self._decode_params(params)
         # on a role submesh of several chips the round's programs span them:
         # their Pallas kernels need the mesh in context (ops/per_device.py)
         with params_mesh(params):

@@ -2403,6 +2403,7 @@ class PagedGenerationEngine(LoraMailbox):
                 "scheduler with max_concurrent_rows set and no spec_draft — "
                 "turn continuation lives in the refill idle pass"
             )
+        params = self._decode_params(params)
         if (
             self.scheduler == "refill"
             and self.max_concurrent_rows

@@ -351,6 +351,7 @@ class ShardedPagedEngine(LoraMailbox):
                 f"prompts must be padded to {self.max_prompt_tokens}, got {p}"
             )
         t_round = time.perf_counter()
+        params = self._decode_params(params)
         max_steps = min(sampling.max_tokens, self.max_new_tokens)
         n = max(sampling.n, 1)
         # pad the prompt batch to a dp multiple; padding rows have all-zero

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.ops.attention import attention, attention_cached, causal_padding_mask
-from distrl_llm_tpu.ops.linear import linear, lora_delta
+from distrl_llm_tpu.ops.linear import OutIn, linear, lora_delta
 
 Params = dict[str, Any]
 
@@ -392,18 +392,84 @@ _SLICE_SCOPES = {
 }
 
 
+# The leaves a cache-mode program reads one ``[out, in]`` array a layer
+# (``decode_view``), chosen by a census of the decode steps compiled for a v5e
+# (tests/test_tpu_compile.py): at a decode step's 32-64 rows the compiler keeps
+# each of these resident in the chip's fast memory ahead of its matmul, and
+# sliced from a stacked ``[layers, in, out]`` leaf that costs a synchronous
+# fusion materialising every layer's slice plus a transpose a layer. The MLP's
+# and the experts' matrices are too large to be made resident, and their
+# slices fuse into the matmuls; a Mamba layer's ``w_in`` / ``w_out`` show
+# neither operation and stay stacked.
+DECODE_VIEW_KEYS = frozenset(("wq", "wk", "wv", "wo"))
+# The kinds whose census differs: of a latent layer's projections ``wq`` alone
+# shows the two operations; its ``wo``, ``wkv_a`` and ``wkv_b`` show neither
+# and stay stacked.
+_KIND_VIEW_KEYS = {"latent": frozenset(("wq",)), "latent_moe": frozenset(("wq",))}
+
+
 def _slice_layer(stacked: Params, i: int) -> Params:
     """Layer ``i`` of a stacked tree (base weights or LoRA factors), each
-    weight sliced under the scope of the block that reads it, so a slice the
-    compiler does not fuse into its matmul is still that block's time. Same
-    leaves in the same order as ``tree_map(lambda w: w[i], stacked)``."""
+    weight taken under the scope of the block that reads it, so a slice the
+    compiler does not fuse into its matmul is still that block's time: the
+    MLP's slices fuse (``model/mlp`` read 84.5% of its bytes' roofline in
+    ``rollout-lockstep``; ledger, PR 44), the mixer's projections did not
+    (``model/attn_proj`` 39%: one fusion a step wrote all 14 slices of wq,
+    then a transposed copy a layer). A decode view's tuple of one ``OutIn`` a
+    layer is indexed, not sliced. Same leaves in the same order as
+    ``tree_map(lambda w: w[i], stacked)``."""
     if not isinstance(stacked, dict):
         return jax.tree_util.tree_map(lambda w: w[i], stacked)
     out = {}
     for key in sorted(stacked):
         with jax.named_scope(_SLICE_SCOPES.get(key, telemetry.MODEL_ATTN_PROJ)):
-            out[key] = jax.tree_util.tree_map(lambda w: w[i], stacked[key])
+            out[key] = jax.tree_util.tree_map(
+                lambda w: w[i], stacked[key],
+                is_leaf=lambda w: isinstance(w, tuple))
     return out
+
+
+@jax.jit
+def _per_layer(w: jax.Array) -> tuple[OutIn, ...]:
+    """``[layers, in, out]`` as ``layers`` arrays ``[out, in]``, on the
+    devices ``w`` is on: a leaf placed on a mesh keeps its sharding with the
+    two axes swapped (the partitioner carries it through the transpose)."""
+    return tuple(OutIn(w[i].T) for i in range(w.shape[0]))
+
+
+def decode_view_leaves(layers: dict, keys=DECODE_VIEW_KEYS, path=()):
+    """``(path, leaf)`` of every stacked array of ``params["layers"]`` that a
+    decode view holds a layer at a time, and so a second time: a table key's
+    leaf (the kind's own table where it has one) that is an array. A quantized
+    container under such a key is a dict of leaves named otherwise, so none
+    of it is listed."""
+    for key, leaf in layers.items():
+        if isinstance(leaf, dict):
+            yield from decode_view_leaves(
+                leaf, _KIND_VIEW_KEYS.get(key, keys), path + (key,))
+        elif key in keys and getattr(leaf, "ndim", 0) == 3:
+            yield path + (key,), leaf
+
+
+def decode_view(params: Params) -> Params:
+    """``params`` as the cache-mode programs read it: every stacked array of
+    ``params["layers"]`` under a ``DECODE_VIEW_KEYS`` key (a kind's stack
+    under its own keys in a model with per-layer mixers) becomes a tuple of
+    one ``OutIn`` a layer; every other leaf, a quantized container under one
+    of those keys too, is shared with ``params`` as it is. The learner's path
+    (``kv_cache is None``: a scan over stacked leaves) takes the stacked tree,
+    never this."""
+    layers = params["layers"]
+    for path, leaf in decode_view_leaves(layers):
+        layers = _with_leaf(layers, path, _per_layer(leaf))
+    return {**params, "layers": layers}
+
+
+def _with_leaf(tree: dict, path: tuple, leaf) -> dict:
+    """``tree`` with ``leaf`` at ``path``; the dicts along it are copied."""
+    if len(path) == 1:
+        return {**tree, path[0]: leaf}
+    return {**tree, path[0]: _with_leaf(tree[path[0]], path[1:], leaf)}
 
 
 def forward(
@@ -563,8 +629,11 @@ def forward(
         # defeats XLA's in-place buffer aliasing: the while-loop ping-pongs the
         # whole cache, costing a full cache-sized HBM temp (~9 GB at the
         # reference rollout volume, measured via compile memory_analysis).
-        # Separate per-layer carry leaves alias to zero temp bytes. Weight
-        # slices params["layers"][w][i] are static and fuse into their matmuls.
+        # Separate per-layer carry leaves alias to zero temp bytes. Of the
+        # static weight slices params["layers"][w][i] the MLP's fuse into
+        # their matmuls; the mixer's projections do not (0.870 s of the 8.28 s
+        # ``rollout-lockstep`` round; ledger, PR 44), so the engines hand these
+        # programs a ``decode_view`` that holds them one array a layer.
         kv_quant = "k_scale" in kv_cache  # int8 dense cache carries scales
         new_k, new_v, new_ks, new_vs = [], [], [], []
         for i in range(cfg.num_layers):

@@ -13,9 +13,32 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.tree_util.register_pytree_node_class
+class OutIn:
+    """One layer's weight stored ``[out, in]``: the contraction dimension
+    minor, which is how the TPU compiler wants a weight it keeps resident
+    beside a matmul of a decode step's few rows. ``decode_view`` in
+    models/transformer.py holds a stack's weight as a tuple of these, one a
+    layer, where the tree holds ONE ``[layers, in, out]`` array; ``linear``
+    contracts over the last axis."""
+
+    __slots__ = ("w",)
+
+    def __init__(self, w):
+        self.w = w
+
+    def tree_flatten(self):
+        return (self.w,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
+
+
 def linear(x: jax.Array, w, b: jax.Array | None = None) -> jax.Array:
-    """y = x @ w (+ b). ``w`` is either a plain [in, out] array or a quantized
-    container dict (ops/quant.py): {"q": [G, g, out], "scale": [G, 1, out]}.
+    """y = x @ w (+ b). ``w`` is a plain [in, out] array, an ``OutIn`` (the
+    same weight stored [out, in]) or a quantized container dict
+    (ops/quant.py): {"q": [G, g, out], "scale": [G, 1, out]}.
 
     Quantized containers dispatch to the fused Pallas dequant-matmul
     (ops/quant_matmul.py) when it is enabled for this backend
@@ -42,6 +65,8 @@ def linear(x: jax.Array, w, b: jax.Array | None = None) -> jax.Array:
         wq = (w["q"].astype(jnp.float32) * w["scale"]).astype(x.dtype)
         G, g, d_out = wq.shape[-3:]
         y = jnp.einsum("...i,io->...o", x, wq.reshape(G * g, d_out))
+    elif isinstance(w, OutIn):
+        y = jnp.einsum("...i,oi->...o", x, w.w)
     else:
         y = jnp.einsum("...i,io->...o", x, w)
     if b is not None:

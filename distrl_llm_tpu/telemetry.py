@@ -88,6 +88,14 @@ ENGINE_DISPATCH = "engine/dispatch"
 ENGINE_HOST_BUSY_SHARE = "engine/host_busy_share"
 ENGINE_SLOWEST_BOUNDARY_MS = "engine/slowest_boundary_ms"
 ENGINE_SLOWEST_BOUNDARY_HOST_MS = "engine/slowest_boundary_host_ms"
+# the decode view of the frozen base (models/transformer.py::decode_view):
+# a counter, 1 each time an engine builds one (once a base: the memo is
+# ``engine.LoraMailbox._decode_params``), and a gauge, the bytes of the
+# stacked leaves the present view holds a second time, one array a layer
+# (0 where the device had no room and the view was left out). No metric reads
+# them
+ENGINE_DECODE_VIEW_BUILDS = "engine/decode_view_builds"
+ENGINE_DECODE_VIEW_BYTES = "engine/decode_view_bytes"
 # trainer, host side, nested in the PhaseSpans phases (driver/<phase>)
 DRIVER_SHAPING = "driver/shaping"
 DRIVER_UPDATE_BATCH = "driver/update/batch"

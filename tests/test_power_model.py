@@ -502,17 +502,20 @@ def test_this_files_agreement_can_tell_a_wrong_state(weights, small_pieces, cont
 
 
 def test_the_fan_out_hands_s_and_z(weights, small_pieces):
-    """Greedy, 16 candidates of one prompt are 16 times the single row: every
-    candidate starts from its prompt's state AND its normaliser."""
+    """Greedy, 16 candidates of one prompt are the prompt sixteen times over,
+    each row on its own: every candidate starts from its prompt's state AND
+    its normaliser. (Sixteen rows on both sides, so both decode through the
+    same products.)"""
     params, lora = weights
     ids, mask = prompts((45,))
     greedy = dict(temperature=0.0, top_p=1.0, max_tokens=12)
     many = make_engine("waves", 0).generate(
         params, lora, ids, mask, SamplingConfig(n=16, **greedy), jax.random.PRNGKey(0))
-    one = make_engine("waves", 0).generate(
-        params, lora, ids, mask, SamplingConfig(n=1, **greedy), jax.random.PRNGKey(0))
-    assert (many.tokens == one.tokens[:, :1]).all()
-    np.testing.assert_allclose(many.logprobs, np.repeat(one.logprobs, 16, 1), atol=2e-6)
+    each = make_engine("waves", 0).generate(
+        params, lora, ids.repeat(16, 0), mask.repeat(16, 0), SamplingConfig(n=1, **greedy),
+        jax.random.PRNGKey(0))
+    assert (many.tokens[0] == each.tokens[:, 0]).all()
+    np.testing.assert_allclose(many.logprobs[0], each.logprobs[:, 0], atol=2e-6)
 
 
 def test_the_prompts_state_is_the_chunked_forms_after_its_last_real_token(weights,

@@ -633,6 +633,83 @@ def test_sala_mixers_at_published_widths(chip, monkeypatch, mode):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+def _weight_sized_operations(text: str, sizes: set) -> list:
+    """The synchronous operations of a compiled program's entry computation
+    that WRITE an array of a projection's element count: a ``copy`` (not a
+    ``copy-start``), a ``slice_bitcast_fusion``, a fusion with several such
+    outputs (one a layer, sliced from a stacked leaf)."""
+    found = []
+    for line in text[text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (.*?) (copy|fusion)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        outs = [dims for dims in re.findall(r"bf16\[([\d,]+)\]", shape)
+                if functools.reduce(lambda a, b: a * int(b), dims.split(","), 1) in sizes]
+        if outs and (op == "copy" or "slice_bitcast" in name or len(outs) > 1):
+            found.append(f"{name} {op} {len(outs)} x bf16[{outs[0]}]")
+    return found
+
+
+@pytest.mark.parametrize("family", ["qwen", "lightning"])
+def test_decode_step_reads_the_view_without_a_copy_of_a_projection(chip, family):
+    """The one-token cache-mode step at two layers of (a) Qwen2.5-7B's widths,
+    64 rows over a paged cache, through ``transformer.forward`` and (b)
+    MiniCPM-SALA's lightning kind through ``models/hybrid.py``, each with a
+    rank-32 float32 adapter. Fed the stacked tree (the control: the test looks
+    for the right thing) the compiler materialises every layer's slice of a
+    projection with one synchronous fusion and transposes it with a copy a
+    layer (0.870 s of the 8.28 s ``rollout-lockstep`` round; ledger, PR 44).
+    Fed the decode view it does neither: what is left moves each weight once,
+    asynchronously."""
+    from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import DECODE_VIEW_KEYS, decode_view
+
+    rows, page, width, bf = 64, 128, 5, jnp.bfloat16
+    if family == "qwen":
+        cfg = ModelConfig(
+            vocab_size=152064, hidden_size=3584, intermediate_size=18944, num_layers=2,
+            num_heads=28, num_kv_heads=4, head_dim=128, rope_theta=1000000.0,
+            attention_bias=True)
+        stacks = lambda tree: [tree["layers"]]
+        pool = chip((4, rows * width, page, 128), bf)
+        cache = {"k": (pool,) * 2, "v": (pool,) * 2}
+    else:
+        cfg = ModelConfig(
+            vocab_size=73448, hidden_size=4096, intermediate_size=16384, num_layers=2,
+            num_heads=32, num_kv_heads=2, head_dim=128, mixer_types=("lightning-attn",) * 2,
+            lightning_heads=32, lightning_head_dim=128, qk_norm=True, attn_use_rope=False,
+            lightning_output_gate=True, lightning_output_norm=True,
+            scale_emb=12.0, scale_depth=1.4, dim_model_base=256)
+        stacks = lambda tree: list(tree["layers"].values())
+        state = jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))
+        cache = {"k": (), "v": (), "alive": chip((rows,), jnp.bool_),
+                 **jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), state)}
+    cache.update(lengths=chip((rows,), jnp.int32), page_indices=chip((rows, width), jnp.int32))
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    view = jax.eval_shape(decode_view, params)
+    sizes = {stack[key].shape[1] * stack[key].shape[2]
+             for stack in stacks(params) for key in DECODE_VIEW_KEYS}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    found = {}
+    for name, tree in (("stacked", params), ("view", view)):
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            place(tree), lora, cache, chip((rows, 1), jnp.int32)).compile().as_text()
+        found[name] = _weight_sized_operations(text, sizes)
+    assert any("slice_bitcast" in line for line in found["stacked"]), found["stacked"]
+    assert any(" copy " in line for line in found["stacked"]), found["stacked"]
+    assert not found["view"], found["view"]
+
+
 @pytest.mark.parametrize("impl", ["flash", "splash"])
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_learner_attention(chip, monkeypatch, impl, grad):
