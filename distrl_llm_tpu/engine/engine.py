@@ -700,19 +700,20 @@ def _view_shortfall(held: list) -> int:
     view holds a layer at a time) would cut into the share of the device's
     memory that engine/budget.py keeps for a round's workspace
     (``ACTIVATION_RESERVE``): a device's shard of each leaf against the first
-    local device's memory as it stands now (``obs.hbm_stats``). 0 where the
+    local device's memory as it stands now (``obs.hbm_free``). 0 where the
     copy fits, and where the backend reports no memory (the CPU). What the
     round allocates after the view is built is not seen here."""
-    stats = obs.hbm_stats()
-    if not held or not stats or not stats.get("bytes_limit"):
+    reading = obs.hbm_free()
+    if not held or reading is None:
         return 0
+    limit, in_use, _ = reading
 
     def shard_bytes(leaf):
         sharding = getattr(leaf, "sharding", None)
         shape = sharding.shard_shape(leaf.shape) if sharding else leaf.shape
         return math.prod(shape) * leaf.dtype.itemsize
 
-    room = int((1 - ACTIVATION_RESERVE) * stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+    room = int((1 - ACTIVATION_RESERVE) * limit) - in_use
     return max(0, sum(map(shard_bytes, held)) - room)
 
 

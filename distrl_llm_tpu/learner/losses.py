@@ -51,7 +51,7 @@ def answer_logprobs(
     *,
     lora=None,
     lora_scale: float = 1.0,
-    remat: bool = True,
+    remat=True,  # True, or the layer scan's checkpoint policy (forward)
     attn_impl: str = "reference",
     attn_mesh=None,
     lora_dropout: float = 0.0,

@@ -32,6 +32,7 @@ import numpy as np
 import optax
 
 from distrl_llm_tpu import telemetry
+from distrl_llm_tpu.learner import remat as remat_lib
 from distrl_llm_tpu.learner.losses import (
     answer_logprobs, grpo_aipo_loss, grpo_clip_loss, grpo_loss, kl_to_ref,
     pg_loss,
@@ -193,7 +194,8 @@ def _derive_dynamics(sums, grads, *, train_mode: str) -> dict:
 
 def _microbatch_loss(
     lora, base_params, cfg: ModelConfig, mb: UpdateBatch, *,
-    learner_type: str, lora_scale: float, skip_semantics: str, remat: bool,
+    learner_type: str, lora_scale: float, skip_semantics: str,
+    remat,  # False, True, or the layer scan's checkpoint policy (forward)
     attn_impl: str, attn_mesh=None, lora_dropout: float = 0.0,
     dropout_rng=None, logit_chunk: int = 0, train_mode: str = "lora",
     clip_ratio: float = 0.0, kl_coeff: float = 0.0,
@@ -325,6 +327,12 @@ def make_train_step(
     the loss/update subgraph is unchanged and the bundle rides the caller's
     existing single host fetch. Off compiles to the exact pre-ISSUE-16
     program.
+
+    ``remat=True`` fits the device's memory: the layer scan keeps the weights'
+    products for the backward pass as far as they fit beside the step
+    (learner/remat.py), decided at a batch shape's first call from the memory
+    of the devices that hold the trainable tree, and held for every later
+    trace of that shape. ``step.lower(...)`` takes the call's arguments.
     """
 
     if train_mode == "full" and kl_coeff > 0.0:
@@ -341,7 +349,6 @@ def make_train_step(
         learner_type=learner_type,
         lora_scale=lora_scale,
         skip_semantics=skip_semantics,
-        remat=remat,
         attn_impl=attn_impl,
         attn_mesh=attn_mesh,
         lora_dropout=lora_dropout,
@@ -356,7 +363,8 @@ def make_train_step(
     )
 
     def step(lora, opt_state, base_params, batch: UpdateBatch,
-             dropout_rng=None):
+             dropout_rng=None, *, keep: tuple[str, ...] = ()):
+        scan_remat = remat and remat_lib.policy(keep)
         n = batch.prompt_ids.shape[0]
         assert n % micro_size == 0, f"batch {n} not divisible by micro {micro_size}"
         num_micro = n // micro_size
@@ -369,7 +377,8 @@ def make_train_step(
             # transpose(jvp(learner/loss)) is the backward pass, and
             # rematted_computation under it the recomputed forward
             with jax.named_scope(telemetry.LEARNER_LOSS):
-                return loss_fn(lo, base_params, mb=mb, dropout_rng=key)
+                return loss_fn(lo, base_params, mb=mb, dropout_rng=key,
+                               remat=scan_remat)
 
         grad_fn = jax.value_and_grad(scoped_loss, has_aux=True)
         # independent dropout masks per microbatch (None → dropout disabled)
@@ -419,7 +428,39 @@ def make_train_step(
             return lora, opt_state, loss_sum, dynamics
         return lora, opt_state, loss_sum
 
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    jitted = jax.jit(step, static_argnames="keep",
+                     donate_argnums=(0, 1) if donate else ())
+    if not remat:
+        return jitted
+    kept: dict[tuple, tuple[str, ...]] = {}  # batch shape -> the names it keeps
+
+    def keep_for(lora, base_params, batch: UpdateBatch) -> tuple[str, ...]:
+        weights = lora if train_mode == "full" else base_params
+        dtype = weights["embed"].dtype  # the activations', so the products'
+        shape = (batch.prompt_ids.shape[1], batch.answer_ids.shape[1], dtype)
+        if shape not in kept:
+            trainable = jax.tree_util.tree_leaves(lora)
+            sharding = getattr(trainable[0], "sharding", None)
+            devices = sharding.addressable_devices if sharding else ()
+            answer = shape[1]
+            kept[shape] = remat_lib.choose_kept(
+                cfg, rows=micro_size, seq=shape[0] + answer,
+                head_positions=logit_chunk if 0 < logit_chunk < answer else answer,
+                itemsize=dtype.itemsize,
+                trainable_bytes=sum(x.size * x.dtype.itemsize for x in trainable),
+                devices=sorted(devices, key=lambda d: d.id),
+            )
+        return kept[shape]
+
+    def with_kept(fn):
+        def call(lora, opt_state, base_params, batch, dropout_rng=None):
+            return fn(lora, opt_state, base_params, batch, dropout_rng,
+                      keep=keep_for(lora, base_params, batch))
+        return call
+
+    train_step = with_kept(jitted)
+    train_step.lower = with_kept(jitted.lower)
+    return train_step
 
 
 def _bucket_width(mask, buckets, cap: int) -> int:

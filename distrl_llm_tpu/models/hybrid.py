@@ -943,6 +943,13 @@ def forward_hybrid(
                              dropout_rng=key)[0], None
 
             if remat:
+                # keeps a layer's input and nothing else, whatever policy the
+                # caller brings. The dense decoder's scan keeps the weights'
+                # products that fit the device's memory (learner/remat.py);
+                # here a layer's cost differs by kind (states, experts) and no
+                # benchmark cell times these families' learners (ROADMAP R15),
+                # so a policy for them would be a guess: left for the PR that
+                # measures one
                 body = jax.checkpoint(
                     body, policy=jax.checkpoint_policies.nothing_saveable)
             x, _ = jax.lax.scan(body, x, xs)

@@ -25,6 +25,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.models.configs import ModelConfig
@@ -69,9 +70,28 @@ _TARGET_STREAM = {
 }
 
 
+# The products a rematerialised layer scan may be told to keep for the backward
+# pass, in the order the train step fills the room it has (learner/remat.py):
+# q/k/v, then the MLP's gate, then its up. ``_proj`` names each where it is
+# made; nothing else is worth a byte (the attention core's float32 scores are
+# 470 MB a layer at [4, 1024]; the backward pass needs neither ``wo``'s nor
+# ``w_down``'s output). A name lowers to nothing, so no program without a
+# rematerialised scan, the cache-mode programs among them, changes by a
+# character.
+KEPT_PRODUCT_GROUPS = (("wq", "wk", "wv"), ("w_gate",), ("w_up",))
+KEPT_PRODUCTS = frozenset(n for group in KEPT_PRODUCT_GROUPS for n in group)
+
+
 def _proj(h, p, lora, key, bias_key, lora_scale,
           lora_dropout: float = 0.0, dropout_rng=None):
-    """One projection with optional bias and optional LoRA delta.
+    """One projection with optional bias and optional LoRA delta, named for
+    the rematerialised scan's policy where it is one of ``KEPT_PRODUCTS``."""
+    y = _proj_value(h, p, lora, key, bias_key, lora_scale, lora_dropout, dropout_rng)
+    return checkpoint_name(y, key) if key in KEPT_PRODUCTS else y
+
+
+def _proj_value(h, p, lora, key, bias_key, lora_scale, lora_dropout, dropout_rng):
+    """``_proj``'s value: base product, bias, and the adapter's delta.
 
     A quantized base weight with an active adapter (and no LoRA dropout —
     dropout perturbs the adapter INPUT, which the epilogue can't express)
@@ -483,7 +503,7 @@ def forward(
     lora_scale: float = 1.0,
     kv_cache: Params | None = None,  # {"k","v": L-tuples of [B, K, hd, Smax]}
     cache_offset: jax.Array | int = 0,
-    remat: bool = False,
+    remat=False,  # True, or the checkpoint policy of the no-cache layer scan
     attn_impl: str = "reference",
     attn_mesh=None,  # jax Mesh with an "sp" axis; required for attn_impl="ring"
     logits_slice: tuple[int, int] | None = None,  # (start, length) along seq
@@ -618,9 +638,12 @@ def forward(
             return y, None
 
         if remat:
-            scan_body = jax.checkpoint(
-                scan_body, policy=jax.checkpoint_policies.nothing_saveable
-            )
+            # the backward pass keeps each layer's input and recomputes the
+            # layer, but for what a caller's policy keeps: the train step
+            # (learner/remat.py) names the weights' products that fit the
+            # device's memory; ``True`` keeps nothing, as models/hybrid.py does
+            scan_body = jax.checkpoint(scan_body, policy=(
+                jax.checkpoint_policies.nothing_saveable if remat is True else remat))
         x, _ = jax.lax.scan(scan_body, x, xs)
         new_k = new_v = None
     else:

@@ -107,10 +107,10 @@ def _jsonable(v: Any) -> Any:
 # ------------------------------------------------------------ HBM sampling
 
 
-def hbm_stats() -> dict[str, float] | None:
-    """``memory_stats()`` of the first local device, or None when the
-    backend exposes none (CPU hosts). ``DISTRL_OBS_FAKE_HBM`` (a JSON
-    object) substitutes deterministic numbers for tests/smokes."""
+def hbm_stats(device=None) -> dict[str, float] | None:
+    """``memory_stats()`` of ``device`` (the first local device by default),
+    or None when the backend exposes none (CPU hosts). ``DISTRL_OBS_FAKE_HBM``
+    (a JSON object) substitutes deterministic numbers for tests/smokes."""
     fake = os.environ.get("DISTRL_OBS_FAKE_HBM")
     if fake:
         try:
@@ -121,13 +121,28 @@ def hbm_stats() -> dict[str, float] | None:
     try:
         import jax
 
-        stats = jax.local_devices()[0].memory_stats()
+        stats = (device or jax.local_devices()[0]).memory_stats()
     except Exception:  # noqa: BLE001 — no backend at all
         return None
     if not stats:
         return None
     return {k: float(v) for k, v in stats.items()
             if isinstance(v, (int, float))}
+
+
+def hbm_free(device=None) -> tuple[int, int, int] | None:
+    """``(bytes_limit, bytes_in_use, largest free block)`` of ``device`` (the
+    first local device by default) as it stands now, or None where the backend
+    reports no limit (the CPU). The one reading behind every "does it still
+    fit" decision: the engines' decode view (``engine._view_shortfall``) and
+    what the learner's rematerialised scan keeps (``learner/remat.py``). A
+    backend that does not report its largest free block gets all that is not
+    in use."""
+    stats = hbm_stats(device)
+    if not stats or not stats.get("bytes_limit"):
+        return None
+    limit, in_use = int(stats["bytes_limit"]), int(stats.get("bytes_in_use", 0))
+    return limit, in_use, int(stats.get("largest_free_block_bytes", limit - in_use))
 
 
 _phase_mu = threading.Lock()

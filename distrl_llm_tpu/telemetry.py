@@ -96,6 +96,14 @@ ENGINE_SLOWEST_BOUNDARY_HOST_MS = "engine/slowest_boundary_host_ms"
 # them
 ENGINE_DECODE_VIEW_BUILDS = "engine/decode_view_builds"
 ENGINE_DECODE_VIEW_BYTES = "engine/decode_view_bytes"
+# what the learner's rematerialised layer scan keeps for the backward pass
+# (learner/remat.py), filed when a train step first meets a batch shape: how
+# many of the five named products of the frozen weights (q, k, v, the MLP's
+# gate and up; 0 where the device had no room or reports no memory), and the
+# bytes they take a micro-batch over all layers. ``learner.kept_share`` reads
+# the bytes
+LEARNER_KEPT_PRODUCTS = "learner/kept_products"
+LEARNER_KEPT_PRODUCT_BYTES = "learner/kept_product_bytes"
 # trainer, host side, nested in the PhaseSpans phases (driver/<phase>)
 DRIVER_SHAPING = "driver/shaping"
 DRIVER_UPDATE_BATCH = "driver/update/batch"

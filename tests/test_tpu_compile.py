@@ -16,6 +16,7 @@ file has started, and in this process: only one process at a time may load the
 TPU's library, and every xdist worker imports every test file.
 """
 
+import collections
 import functools
 import re
 
@@ -708,6 +709,162 @@ def test_decode_step_reads_the_view_without_a_copy_of_a_projection(chip, family)
     assert any("slice_bitcast" in line for line in found["stacked"]), found["stacked"]
     assert any(" copy " in line for line in found["stacked"]), found["stacked"]
     assert not found["view"], found["view"]
+
+
+def test_kept_products_leave_the_backward_and_take_their_own_bytes(chip):
+    """The learner's micro-batch gradient at ``learner-1k``'s shape (Qwen2.5-7B's
+    widths, 14 layers, ``[4, 1024]``, rank 32, chunked cross-entropy) with
+    nothing kept, with q/k/v and the gate kept (what the cell holds on the
+    chip) and with all five (``learner/remat.py``'s policies). Kept, each
+    name's product leaves the recomputed forward: the base weight's and, of
+    the same shape, the adapter's. The program's peak beside its arguments
+    grows by the bytes the rule counted, within a tenth
+    (``peak_memory_in_bytes``, which the compiler holds to the chip's memory;
+    ``temp_size_in_bytes`` sums allocations that are not live together and
+    reads 0.9-2.2 GB higher), and stays under the working set the rule
+    subtracts. 14 layers because at 2 the peak is the head's and the stacks
+    hide under it. No synchronous operation writes an array of a frozen
+    weight's size that the parent's program did not. All five is the control:
+    beside 8.5 GB of arguments the compiler then rematerialises ON ITS OWN to
+    fit (``.remat`` operations; on the chip the attention core ran three times
+    and the update was slower than with q/k/v alone), which is what the
+    reserve ``choose_kept`` leaves keeps the rule's choice clear of."""
+    from distrl_llm_tpu.learner import remat
+    from distrl_llm_tpu.learner.train_step import UpdateBatch, _microbatch_loss
+    from distrl_llm_tpu.models import ModelConfig, init_lora_params, init_params
+
+    rows, prompt, answer, chunk, bf = 4, 256, 768, 128, jnp.bfloat16
+    cfg = ModelConfig(
+        vocab_size=152064, hidden_size=3584, intermediate_size=18944, num_layers=14,
+        num_heads=28, num_kv_heads=4, head_dim=128, rope_theta=1000000.0,
+        attention_bias=True)
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    ids = lambda width: chip((rows, width), jnp.int32)
+    batch = UpdateBatch(
+        prompt_ids=ids(prompt), prompt_mask=ids(prompt), answer_ids=ids(answer),
+        answer_mask=ids(answer), coeffs=chip((rows,), jnp.float32),
+        sample_mask=chip((rows,), jnp.float32))
+
+    def products(text):
+        """Matrix products of the compiled program by the shape they write."""
+        return {dims: len(re.findall(
+            rf"= bf16\[{rows},{prompt + answer},{dims}\]\S* convolution\(", text))
+            for dims in (cfg.intermediate_size, cfg.q_dim, cfg.kv_dim)}
+
+    weights = {leaf.shape[1:] for leaf in jax.tree_util.tree_leaves(params["layers"])
+               if leaf.ndim == 3}
+
+    def weight_sized(text):
+        """Synchronous operations anywhere in the program that write one
+        layer's frozen weight or a whole stack of them, by kind and shape."""
+        found = collections.Counter()
+        for name, dims, op in re.findall(
+                r"(%[\w.-]+) = bf16\[([\d,]+)\]\S* (copy|fusion)\(", text):
+            shape = tuple(int(d) for d in dims.split(",") if d != "1")
+            if shape[-2:] in weights and len(shape) <= 3:
+                found[re.sub(r"[\d.]|clone", "", name), op, shape] += 1
+        return found
+
+    work = remat.step_working_set(
+        cfg, rows=rows, seq=prompt + answer, head_positions=chunk, itemsize=2,
+        trainable_bytes=0)  # a micro-batch's gradients are this program's output
+    rule = functools.partial(
+        remat.kept_products, cfg, tokens=rows * (prompt + answer), itemsize=2)
+    read = {}
+    for kept, room in (("none", 0), ("gate", 3 * 10**9), ("five", 1 << 40)):
+        names, spent = rule(room=room)
+
+        def loss(lora, params, batch):
+            return _microbatch_loss(
+                lora, params, cfg, batch, learner_type="pg", lora_scale=0.5,
+                skip_semantics="all_zero", remat=remat.policy(names),
+                attn_impl="reference", logit_chunk=chunk)[0]
+
+        compiled = jax.jit(jax.grad(loss)).lower(lora, params, batch).compile()
+        memory, text = compiled.memory_analysis(), compiled.as_text()
+        read[kept] = dict(
+            products=products(text), weight_sized=weight_sized(text),
+            own_remat=text.count(".remat"), bytes=spent, names=len(names),
+            peak=memory.peak_memory_in_bytes - memory.argument_size_in_bytes)
+    none, gate, five = read["none"], read["gate"], read["five"]
+    assert (none["names"], none["bytes"]) == (0, 0)
+    assert (gate["names"], gate["bytes"]) == (4, 2_701_131_776)
+    assert (five["names"], five["bytes"]) == (5, 4_873_781_248)
+
+    def fewer(kept):
+        return {dims: none["products"][dims] - kept["products"][dims]
+                for dims in none["products"]}
+    # the base's product and the adapter's, each: gate (and up); q; k and v
+    assert fewer(gate) == {cfg.intermediate_size: 2, cfg.q_dim: 2, cfg.kv_dim: 4}, (none, gate)
+    assert fewer(five) == {cfg.intermediate_size: 4, cfg.q_dim: 2, cfg.kv_dim: 4}, (none, five)
+    assert five["products"][cfg.intermediate_size] > 0  # w_down's cotangent is still a product
+    assert 0.9 * gate["bytes"] <= gate["peak"] - none["peak"] <= 1.1 * gate["bytes"], (none, gate)
+    # the working set the rule subtracts holds what the compiler found
+    assert none["peak"] <= work and gate["peak"] - gate["bytes"] <= work, (none, gate, work)
+    assert (none["own_remat"], gate["own_remat"]) == (0, 0) and five["own_remat"] > 0
+    for kept in (gate, five):
+        assert not [found for found in kept["weight_sized"] if found[1] == "copy"], kept
+        assert not kept["weight_sized"] - none["weight_sized"], (none, kept)
+
+
+def test_a_full_mode_step_stays_under_the_working_set_the_rule_subtracts(chip, monkeypatch):
+    """The WHOLE train step in ``full`` mode (the trainable tree a float32
+    copy of every weight, so float32 products; the accumulator, a
+    micro-batch's gradients and the 8-bit optimizer's update beside the
+    activations) at Qwen2.5-0.5B's widths, 12 layers, ``[4, 1024]``, on a
+    device that leaves the rule room for all five names: the program's peak
+    beside its arguments stays under the rule's working set plus the bytes it
+    kept, and the compiler is not driven to rematerialise on its own. 12
+    layers because the shorter stack read the most over the activations
+    (4.60 GB with nothing kept; 5.73 GB at 24)."""
+    import dataclasses
+    import json
+    import math
+
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine.budget import ACTIVATION_RESERVE
+    from distrl_llm_tpu.learner import remat
+    from distrl_llm_tpu.learner.optim import make_optimizer
+    from distrl_llm_tpu.learner.train_step import UpdateBatch, make_train_step
+    from distrl_llm_tpu.models import init_params
+    from distrl_llm_tpu.models.configs import QWEN2_0_5B
+
+    micro, prompt, answer, chunk = 4, 256, 768, 128
+    cfg = dataclasses.replace(QWEN2_0_5B, num_layers=12)
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    weights = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=jnp.float32), jax.random.PRNGKey(0))
+    optimizer = make_optimizer(1e-5, use_8bit=True)
+    state = place(jax.eval_shape(optimizer.init, weights))
+    weights = place(weights)
+    ids = lambda width: chip((2 * micro, width), jnp.int32)
+    batch = UpdateBatch(
+        prompt_ids=ids(prompt), prompt_mask=ids(prompt), answer_ids=ids(answer),
+        answer_mask=ids(answer), coeffs=chip((2 * micro,), jnp.float32),
+        sample_mask=chip((2 * micro,), jnp.float32))
+    work = remat.step_working_set(
+        cfg, rows=micro, seq=prompt + answer, head_positions=chunk, itemsize=4,
+        trainable_bytes=sum(
+            math.prod(x.shape) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(weights)))
+    _, every = remat.kept_products(
+        cfg, tokens=micro * (prompt + answer), itemsize=4, room=1 << 40)
+    monkeypatch.setenv("DISTRL_OBS_FAKE_HBM", json.dumps({
+        "bytes_limit": math.ceil((every + work) / (1 - ACTIVATION_RESERVE)), "bytes_in_use": 0}))
+    step = make_train_step(
+        cfg, learner_type="pg", optimizer=optimizer, lora_scale=1.0, micro_size=micro,
+        logit_chunk=chunk, train_mode="full")
+    compiled = step.lower(weights, state, None, batch).compile()
+    gauges = telemetry.observe_snapshot()["gauges"]
+    assert gauges[telemetry.LEARNER_KEPT_PRODUCTS] == 5
+    assert gauges[telemetry.LEARNER_KEPT_PRODUCT_BYTES] == every == 2_139_095_040
+    memory = compiled.memory_analysis()
+    beside = memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+    assert every < beside <= work + every, (beside, work, every)
+    assert compiled.as_text().count(".remat") == 0
 
 
 @pytest.mark.parametrize("impl", ["flash", "splash"])
