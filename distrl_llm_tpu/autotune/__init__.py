@@ -16,8 +16,6 @@ Public surface:
 
 from distrl_llm_tpu.autotune.plan import (
     DEFAULT_PLAN,
-    IMPL_TO_PAGED_KERNEL,
-    PAGED_KERNEL_TO_IMPL,
     ExecutionPlan,
     TUNABLE_FIELDS,
     candidate_plans,
@@ -42,8 +40,6 @@ __all__ = [
     "DEFAULT_PLAN",
     "DB_ENV",
     "ENABLE_ENV",
-    "IMPL_TO_PAGED_KERNEL",
-    "PAGED_KERNEL_TO_IMPL",
     "ExecutionPlan",
     "PlanStore",
     "ResolvedPlan",

@@ -145,14 +145,7 @@ def build_engine_for_plan(
             prompt_buckets=plan.prompt_buckets or None,
             **common,
         )
-    from distrl_llm_tpu.autotune.plan import PAGED_KERNEL_TO_IMPL
-
-    paged_kw = dict(
-        # candidate paged-kernel variant rides as the engine kwargs the
-        # plan fields map to ("auto" when the candidate leaves it derived)
-        paged_impl=PAGED_KERNEL_TO_IMPL.get(plan.paged_kernel, "auto"),
-        pages_per_block=plan.pages_per_block,
-    )
+    paged_kw: dict = {}
     if plan.cb_mode is not None:
         # the admission-regime candidate pins continuous admission on or
         # off ("batch" measures the fixed-batch control); it needs the

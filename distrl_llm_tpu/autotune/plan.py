@@ -29,7 +29,6 @@ from dataclasses import dataclass
 
 DECODE_PATHS = ("dense", "paged", "speculative")
 FORMULATIONS = (None, "dot", "mulred")
-PAGED_KERNELS = (None, "one_page", "folded", "blocked")
 SPEC_DRAFTERS = (None, "ngram", "self")
 SPEC_VERIFIES = (None, "fused", "unrolled")
 #: continuous-batching admission regimes for the paged refill scheduler
@@ -63,16 +62,6 @@ PREFIX_CACHES = (None, "off", "on")
 #: weight reads (and the engine rejects them) — plan validation mirrors it
 MAX_SPEC_DRAFT_LEN = 16
 
-#: plan-field ↔ engine ``paged_impl`` spellings of the native paged-kernel
-#: variants (the engine kwarg predates the plan field; "auto"/"kernel"/
-#: "reference" have no plan spelling — they stay engine-kwarg-only)
-PAGED_KERNEL_TO_IMPL = {
-    "one_page": "native",
-    "folded": "native_folded",
-    "blocked": "native_blocked",
-}
-IMPL_TO_PAGED_KERNEL = {v: k for k, v in PAGED_KERNEL_TO_IMPL.items()}
-
 
 @dataclass(frozen=True)
 class ExecutionPlan:
@@ -101,16 +90,6 @@ class ExecutionPlan:
     # prompt length buckets for the dense engine; () = the single
     # max_prompt_tokens bucket (engine-compiled per bucket used)
     prompt_buckets: tuple[int, ...] = ()
-    # paged-attention kernel variant (paged/speculative paths); None derives
-    # exactly as the engine always has (paged_impl="auto"). "one_page" /
-    # "folded" / "blocked" pin paged_impl "native" / "native_folded" /
-    # "native_blocked" (ops/paged_native.py); "one_page" keeps its spelling
-    # though "native" moves a row's pages a grid step since PR 32
-    paged_kernel: str | None = None
-    # blocked-kernel page collapse (pages folded per grid step); 0 = the
-    # kernel default (ops.paged.DEFAULT_PAGES_PER_BLOCK). Only consumed by
-    # paged_kernel="blocked"
-    pages_per_block: int = 0
     # ---- speculative decoding (decode_path="speculative"; engines only
     # adopt these from the DB when they run the refill scheduler — the
     # slot machinery that hosts speculation). 0/None = the engines'
@@ -176,16 +155,6 @@ class ExecutionPlan:
         if any(b <= 0 for b in self.prompt_buckets):
             raise ValueError(
                 f"prompt_buckets must be positive, got {self.prompt_buckets}"
-            )
-        if self.paged_kernel not in PAGED_KERNELS:
-            raise ValueError(
-                f"paged_kernel must be one of {PAGED_KERNELS}, got "
-                f"{self.paged_kernel!r}"
-            )
-        if not isinstance(self.pages_per_block, int) or self.pages_per_block < 0:
-            raise ValueError(
-                f"pages_per_block must be an int >= 0, got "
-                f"{self.pages_per_block!r}"
             )
         if (
             not isinstance(self.spec_draft_len, int)
@@ -345,8 +314,6 @@ def candidate_plans(
     scan_chunks=(0, 16),
     formulations=(None,),
     top_p_impls=(None,),
-    paged_kernels=(None,),
-    pages_per_blocks=(0,),
     spec_draft_lens=(0,),
     spec_drafters=(None,),
     spec_verifies=(None,),
@@ -358,8 +325,7 @@ def candidate_plans(
     """Enumerate a candidate space for the tuner (cartesian product, with
     the always-meaningless combos dropped: a formulation override without a
     dense path, a scan_chunk of 1 — scan-of-one has no fusion benefit and
-    the engines refuse to report it as chunked, a paged-kernel pin on the
-    dense path, a pages_per_block without the blocked kernel, spec knobs
+    the engines refuse to report it as chunked, spec knobs
     anywhere but the speculative path, a cb_mode on the dense path — the
     admission scheduler is paged-refill machinery — and a speculative path
     with no draft length, which is just the paged path wearing a costume).
@@ -375,48 +341,40 @@ def candidate_plans(
             for form in formulations:
                 if form is not None and path != "dense":
                     continue
-                for pk in paged_kernels:
-                    if pk is not None and path == "dense":
+                for sd in spec_draft_lens:
+                    if (sd > 0) != (path == "speculative"):
                         continue
-                    for ppb in pages_per_blocks:
-                        if ppb and pk != "blocked":
+                    for drafter in spec_drafters:
+                        if drafter is not None and not sd:
                             continue
-                        for sd in spec_draft_lens:
-                            if (sd > 0) != (path == "speculative"):
+                        for sv in spec_verifies:
+                            if sv is not None and not sd:
                                 continue
-                            for drafter in spec_drafters:
-                                if drafter is not None and not sd:
+                            for cb in cb_modes:
+                                if cb is not None and path == "dense":
                                     continue
-                                for sv in spec_verifies:
-                                    if sv is not None and not sd:
+                                for pc in prefix_caches:
+                                    # the radix cache rides the
+                                    # continuous-admission chain
+                                    # machinery (ISSUE 18)
+                                    if pc == "on" and cb != "continuous":
                                         continue
-                                    for cb in cb_modes:
-                                        if cb is not None and path == "dense":
-                                            continue
-                                        for pc in prefix_caches:
-                                            # the radix cache rides the
-                                            # continuous-admission chain
-                                            # machinery (ISSUE 18)
-                                            if pc == "on" and cb != "continuous":
-                                                continue
-                                            if pc is not None and path == "dense":
-                                                continue
-                                            for kvf in kv_formats:
-                                                for bq in base_quants:
-                                                    for tp in top_p_impls:
-                                                        out.append(ExecutionPlan(
-                                                            decode_path=path,
-                                                            scan_chunk=chunk,
-                                                            cache_read_formulation=form,
-                                                            top_p_impl=tp,
-                                                            paged_kernel=pk,
-                                                            pages_per_block=ppb,
-                                                            spec_draft_len=sd,
-                                                            spec_drafter=drafter,
-                                                            spec_verify=sv,
-                                                            cb_mode=cb,
-                                                            kv_format=kvf,
-                                                            base_quant=bq,
-                                                            prefix_cache=pc,
-                                                        ))
+                                    if pc is not None and path == "dense":
+                                        continue
+                                    for kvf in kv_formats:
+                                        for bq in base_quants:
+                                            for tp in top_p_impls:
+                                                out.append(ExecutionPlan(
+                                                    decode_path=path,
+                                                    scan_chunk=chunk,
+                                                    cache_read_formulation=form,
+                                                    top_p_impl=tp,
+                                                    spec_draft_len=sd,
+                                                    spec_drafter=drafter,
+                                                    spec_verify=sv,
+                                                    cb_mode=cb,
+                                                    kv_format=kvf,
+                                                    base_quant=bq,
+                                                    prefix_cache=pc,
+                                                ))
     return out

@@ -29,7 +29,9 @@ from distrl_llm_tpu.autotune.plan import ExecutionPlan
 
 log = logging.getLogger(__name__)
 
-SCHEMA_VERSION = 1
+#: 2 since the plan lost its two fields that named a paged-kernel variant
+#: (PR 47): a database written with them is met as a mismatch and re-tuned
+SCHEMA_VERSION = 2
 
 DB_ENV = "DISTRL_PLAN_DB"
 ENABLE_ENV = "DISTRL_AUTOTUNE"

@@ -605,7 +605,6 @@ def _paged_fanout(prompt_k, prompt_v, last_logits, real_len, row_alive,
 def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices,
                        *, cfg: ModelConfig, page_size: int, eos_ids, pad_id: int,
                        temperature, top_p, lora_scale: float, paged_impl: str,
-                       pages_per_block: int = 0,
                        top_p_impl: str = "bisect", capture_logprobs: bool = False):
     """One donated decode step over the paged cache (host-loop dispatched,
     zero cache-sized temps — same design as engine._decode_step)."""
@@ -645,7 +644,6 @@ def _paged_decode_step(params, lora, state: _PagedDecodeState, rng, page_indices
         positions=s.seq_lengths[:, None],
         lora=lora, lora_scale=lora_scale,
         kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
-        pages_per_block=pages_per_block,
     )
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         seq_lengths = s.seq_lengths + (~s.done).astype(jnp.int32)
@@ -662,7 +660,7 @@ def _paged_decode_chunk(params, lora, state: _PagedDecodeState, rng,
                         page_indices, *, chunk: int,
                         cfg: ModelConfig, page_size: int, eos_ids,
                         pad_id: int, temperature, top_p, lora_scale: float,
-                        paged_impl: str, pages_per_block: int = 0,
+                        paged_impl: str,
                         top_p_impl: str = "bisect",
                         capture_logprobs: bool = False):
     """``chunk`` wave-mode paged decode steps in ONE dispatch via
@@ -676,7 +674,7 @@ def _paged_decode_chunk(params, lora, state: _PagedDecodeState, rng,
             params, lora, s, rng, page_indices, cfg=cfg,
             page_size=page_size, eos_ids=eos_ids, pad_id=pad_id,
             temperature=temperature, top_p=top_p, lora_scale=lora_scale,
-            paged_impl=paged_impl, pages_per_block=pages_per_block,
+            paged_impl=paged_impl,
             top_p_impl=top_p_impl,
             capture_logprobs=capture_logprobs,
         )
@@ -1065,7 +1063,6 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
                         *, cfg: ModelConfig, page_size: int, eos_ids,
                         pad_id: int, temperature, top_p, lora_scale: float,
                         paged_impl: str, max_steps: int,
-                        pages_per_block: int = 0,
                         top_p_impl: str = "bisect",
                         capture_logprobs: bool = False):
     """One donated decode step over R slots. Differences from the wave step:
@@ -1111,7 +1108,6 @@ def _refill_decode_step(params, lora, state: _RefillState, rng,
         positions=s.seq_lengths[:, None],
         lora=lora, lora_scale=lora_scale,
         kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
-        pages_per_block=pages_per_block,
     )
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         seq_lengths = s.seq_lengths + alive.astype(jnp.int32)
@@ -1133,7 +1129,6 @@ def _refill_decode_chunk(params, lora, state: _RefillState, rng,
                          *, chunk: int, cfg: ModelConfig, page_size: int,
                          eos_ids, pad_id: int, temperature, top_p,
                          lora_scale: float, paged_impl: str, max_steps: int,
-                         pages_per_block: int = 0,
                          top_p_impl: str = "bisect",
                          capture_logprobs: bool = False):
     """``chunk`` refill decode steps in ONE dispatch via ``lax.scan`` — the
@@ -1164,7 +1159,7 @@ def _refill_decode_chunk(params, lora, state: _RefillState, rng,
             params, lora, s, rng, cfg=cfg, page_size=page_size,
             eos_ids=eos_ids, pad_id=pad_id, temperature=temperature,
             top_p=top_p, lora_scale=lora_scale, paged_impl=paged_impl,
-            max_steps=max_steps, pages_per_block=pages_per_block,
+            max_steps=max_steps,
             top_p_impl=top_p_impl,
             capture_logprobs=capture_logprobs,
         )
@@ -1180,7 +1175,6 @@ def _spec_decode_chunk(params, lora, state, rng, drafter_lora=None,
                        lora_scale: float, paged_impl: str, max_steps: int, draft_len: int, ngram_k: int,
                        drafter: str = "ngram", spec_verify: str = "fused",
                        hist_width: int = 0,
-                       pages_per_block: int = 0,
                        top_p_impl: str = "bisect",
                        capture_logprobs: bool = False):
     """``chunk`` speculative decode steps in ONE dispatch — same contract
@@ -1201,7 +1195,7 @@ def _spec_decode_chunk(params, lora, state, rng, drafter_lora=None,
             params, lora, s, rng, drafter_lora, cfg=cfg, page_size=page_size,
             eos_ids=eos_ids, pad_id=pad_id, temperature=temperature,
             top_p=top_p, lora_scale=lora_scale, paged_impl=paged_impl,
-            max_steps=max_steps, pages_per_block=pages_per_block,
+            max_steps=max_steps,
             draft_len=draft_len, ngram_k=ngram_k,
             drafter=drafter, spec_verify=spec_verify, hist_width=hist_width,
             top_p_impl=top_p_impl, capture_logprobs=capture_logprobs,
@@ -1322,7 +1316,7 @@ def _spec_admit(state, new_cand, admit_mask, last_logits, real_len,
 
 def _self_draft(params, drafter_lora, state, step_rng, *, cfg: ModelConfig,
                 page_size: int, lora_scale: float, paged_impl: str,
-                pages_per_block: int, d: int, temperature, top_p,
+                d: int, temperature, top_p,
                 top_p_impl: str):
     """Online self-drafting: run the policy's own PREVIOUS LoRA version (the
     LoraMailbox swap log's superseded adapter) as the draft model — d
@@ -1354,7 +1348,6 @@ def _self_draft(params, drafter_lora, state, step_rng, *, cfg: ModelConfig,
             positions=(s.seq_lengths + i)[:, None],
             lora=drafter_lora, lora_scale=lora_scale,
             kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
-            pages_per_block=pages_per_block,
         )
         k_pages, v_pages = cache["k"], cache["v"]
         q_i = sampling_probs(
@@ -1377,7 +1370,6 @@ def _spec_step(params, lora, state, rng, drafter_lora=None, *,
                paged_impl: str, max_steps: int, draft_len: int, ngram_k: int,
                drafter: str = "ngram", spec_verify: str = "fused",
                hist_width: int = 0,
-               pages_per_block: int = 0,
                top_p_impl: str = "bisect", capture_logprobs: bool = False):
     """One speculative decode step: propose d draft tokens (n-gram prompt
     lookup, or the previous-version policy itself — ``drafter``), verify
@@ -1401,7 +1393,7 @@ def _spec_step(params, lora, state, rng, drafter_lora=None, *,
             params, drafter_lora if drafter_lora is not None else lora,
             s, step_rng, cfg=cfg, page_size=page_size,
             lora_scale=lora_scale, paged_impl=paged_impl,
-            pages_per_block=pages_per_block, d=d,
+            d=d,
             temperature=temperature, top_p=top_p, top_p_impl=top_p_impl,
         )
     else:
@@ -1419,7 +1411,7 @@ def _spec_step(params, lora, state, rng, drafter_lora=None, *,
         params, cfg, inputs, positions=positions,
         lora=lora, lora_scale=lora_scale,
         kv_cache=cache, page_size=page_size, paged_impl=paged_impl,
-        pages_per_block=pages_per_block, paged_verify=True,
+        paged_verify=True,
         paged_verify_impl=spec_verify,
     )  # [R, d+1, V]
     with jax.named_scope(telemetry.ENGINE_SAMPLE):
@@ -1575,10 +1567,6 @@ class PagedGenerationEngine(LoraMailbox):
         # None = consult the autotune plan DB (falls back to 0, the
         # historical default); an explicit int — including 0 — always wins
         scan_chunk: int | None = None,
-        # blocked-kernel page collapse. None = consult the plan DB (falls
-        # back to 0 — the kernel default); an explicit int, including 0,
-        # always wins. Only consumed when the blocked kernel dispatches.
-        pages_per_block: int | None = None,
         capture_logprobs: bool = False,  # record behavior logprobs (clip_ratio)
         autotune: bool = True,  # False pins the static defaults (no DB read)
         plan_db: str | None = None,  # plan-DB path; None = env/default path
@@ -1592,17 +1580,11 @@ class PagedGenerationEngine(LoraMailbox):
             # validated BEFORE plan resolution so a typo'd kwarg fails with
             # the engine's own contract, not a plan-field error
             raise ValueError(f"kv_quant must be none/int8, got {kv_quant!r}")
-        if pages_per_block is not None and pages_per_block < 0:
-            raise ValueError(
-                f"pages_per_block must be >= 0, got {pages_per_block}"
-            )
         # Execution-plan resolution (distrl_llm_tpu/autotune): explicit
         # kwargs win, a stored measured plan fills the rest, no DB entry =
         # the static defaults byte-identically. decode_path is pinned to
         # what this construction actually is (honest trace records).
-        from distrl_llm_tpu.autotune import (
-            IMPL_TO_PAGED_KERNEL, PAGED_KERNEL_TO_IMPL, resolve_plan,
-        )
+        from distrl_llm_tpu.autotune import resolve_plan
 
         requested: dict[str, Any] = {}
         if spec_draft is not None:
@@ -1629,8 +1611,6 @@ class PagedGenerationEngine(LoraMailbox):
             requested["spec_verify"] = spec_verify
         if scan_chunk is not None:
             requested["scan_chunk"] = scan_chunk
-        if pages_per_block is not None:
-            requested["pages_per_block"] = pages_per_block
         if continuous_admission is not None:
             # explicit bool pins the admission regime past any stored plan
             # (False is a real A/B control, not "unset")
@@ -1648,12 +1628,6 @@ class PagedGenerationEngine(LoraMailbox):
             raise ValueError(
                 f"kv_spill_host_mb must be >= 0, got {kv_spill_host_mb}"
             )
-        # the paged_kernel plan field and the paged_impl kwarg name the same
-        # choice: any explicit non-"auto" kwarg wins over the DB ("kernel"/
-        # "reference" have no plan spelling, so they pin the field to None —
-        # a stored native-variant plan must not override them either)
-        if paged_impl != "auto":
-            requested["paged_kernel"] = IMPL_TO_PAGED_KERNEL.get(paged_impl)
         self.resolved_plan = resolve_plan(
             model_cfg=cfg, max_prompt_tokens=max_prompt_tokens,
             max_new_tokens=max_new_tokens, rows=plan_rows,
@@ -1661,13 +1635,6 @@ class PagedGenerationEngine(LoraMailbox):
         )
         scan_chunk = self.resolved_plan.plan.scan_chunk
         self.plan_top_p_impl = self.resolved_plan.plan.top_p_impl
-        if paged_impl == "auto" and self.resolved_plan.plan.paged_kernel:
-            # a measured plan picked a native kernel variant for this
-            # geometry — adopt it (empty DB: field is None, "auto" stands)
-            paged_impl = PAGED_KERNEL_TO_IMPL[
-                self.resolved_plan.plan.paged_kernel
-            ]
-        self.pages_per_block = self.resolved_plan.plan.pages_per_block
         self.scan_chunk = scan_chunk
         self._chunk_compiled: dict = {}
         self._chunk_mu = threading.Lock()
@@ -2092,7 +2059,6 @@ class PagedGenerationEngine(LoraMailbox):
             partial(
                 _paged_decode_step, cfg=cfg, page_size=page_size,
                 pad_id=self.pad_id, lora_scale=lora_scale, paged_impl=paged_impl,
-                pages_per_block=self.pages_per_block,
                 capture_logprobs=capture_logprobs,
             ),
             donate_argnames=("state",),
@@ -2165,7 +2131,6 @@ class PagedGenerationEngine(LoraMailbox):
             partial(
                 _refill_decode_step, cfg=cfg, page_size=page_size,
                 pad_id=self.pad_id, lora_scale=lora_scale, paged_impl=paged_impl,
-                pages_per_block=self.pages_per_block,
                 capture_logprobs=capture_logprobs,
             ),
             donate_argnames=("state",),
@@ -2194,7 +2159,6 @@ class PagedGenerationEngine(LoraMailbox):
             partial(
                 _spec_step, cfg=cfg, page_size=page_size,
                 pad_id=self.pad_id, lora_scale=lora_scale, paged_impl=paged_impl,
-                pages_per_block=self.pages_per_block,
                 drafter=self.spec_drafter, spec_verify=self.spec_verify,
                 hist_width=self.spec_draft + 2,
                 capture_logprobs=capture_logprobs,
@@ -2217,7 +2181,7 @@ class PagedGenerationEngine(LoraMailbox):
             head_dim=self.cfg.head_dim,
             page_size=self.page_size,
             pps=self.prompt_pages + self.private_pages,
-            impl=self.paged_impl, pages_per_block=self.pages_per_block,
+            impl=self.paged_impl,
             verify_len=verify_len,
         )
 
@@ -2239,7 +2203,6 @@ class PagedGenerationEngine(LoraMailbox):
         return paged_grid_steps(
             choice, batch=rows, num_kv_heads=self.cfg.num_kv_heads,
             pps=self.prompt_pages + self.private_pages,
-            pages_per_block=self.pages_per_block,
             head_dim=self.cfg.head_dim, page_size=self.page_size,
             kv_itemsize=1 if quantized else jnp.dtype(self.cache_dtype).itemsize,
             quantized=quantized,
@@ -2309,7 +2272,6 @@ class PagedGenerationEngine(LoraMailbox):
                 _refill_decode_chunk, chunk=chunk, cfg=self.cfg,
                 page_size=self.page_size, pad_id=self.pad_id,
                 lora_scale=self.lora_scale, paged_impl=self.paged_impl,
-                pages_per_block=self.pages_per_block,
                 max_steps=max_steps, top_p_impl=top_p_impl,
                 capture_logprobs=self.capture_logprobs,
             ),
@@ -2330,7 +2292,6 @@ class PagedGenerationEngine(LoraMailbox):
                 _spec_decode_chunk, chunk=chunk, cfg=self.cfg,
                 page_size=self.page_size, pad_id=self.pad_id,
                 lora_scale=self.lora_scale, paged_impl=self.paged_impl,
-                pages_per_block=self.pages_per_block,
                 max_steps=max_steps, draft_len=d,
                 ngram_k=self.spec_ngram,
                 drafter=self.spec_drafter, spec_verify=self.spec_verify,
@@ -2353,7 +2314,6 @@ class PagedGenerationEngine(LoraMailbox):
                 cfg=self.cfg, page_size=self.page_size,
                 pad_id=self.pad_id, lora_scale=self.lora_scale,
                 paged_impl=self.paged_impl,
-                pages_per_block=self.pages_per_block,
                 top_p_impl=top_p_impl,
                 capture_logprobs=self.capture_logprobs,
             ),
@@ -3613,7 +3573,6 @@ class PagedGenerationEngine(LoraMailbox):
                             "native_verify", batch=r_slots,
                             num_kv_heads=self.cfg.num_kv_heads,
                             pps=self.prompt_pages + self.private_pages,
-                            pages_per_block=self.pages_per_block,
                         )
                     else:
                         grid_units_step = (

@@ -107,9 +107,6 @@ class ShardedPagedEngine(LoraMailbox):
         # None = consult the autotune plan DB (falls back to 0, the
         # historical default); an explicit int — including 0 — always wins
         scan_chunk: int | None = None,
-        # blocked-kernel page collapse; None = consult the plan DB (falls
-        # back to 0, the kernel default); an explicit int incl. 0 wins
-        pages_per_block: int | None = None,
         capture_logprobs: bool = False,
         autotune: bool = True,  # False pins the static defaults (no DB read)
         plan_db: str | None = None,  # plan-DB path; None = env/default path
@@ -133,29 +130,16 @@ class ShardedPagedEngine(LoraMailbox):
             # validated BEFORE plan resolution so a typo'd kwarg fails with
             # the engine's own contract, not a plan-field error
             raise ValueError(f"kv_quant must be none/int8, got {kv_quant!r}")
-        if pages_per_block is not None and pages_per_block < 0:
-            raise ValueError(
-                f"pages_per_block must be >= 0, got {pages_per_block}"
-            )
         # execution-plan resolution (distrl_llm_tpu/autotune): explicit
         # kwargs win; no DB entry = the static defaults byte-identically
-        from distrl_llm_tpu.autotune import (
-            IMPL_TO_PAGED_KERNEL, PAGED_KERNEL_TO_IMPL, resolve_plan,
-        )
+        from distrl_llm_tpu.autotune import resolve_plan
 
         requested: dict[str, Any] = {"decode_path": "paged"}
         if scan_chunk is not None:
             requested["scan_chunk"] = scan_chunk
-        if pages_per_block is not None:
-            requested["pages_per_block"] = pages_per_block
         if kv_quant is not None:
             # explicit "none" is a real pin (the int8-default A/B control)
             requested["kv_format"] = kv_quant
-        if paged_impl != "auto":
-            # same contract as PagedGenerationEngine: an explicit kwarg —
-            # including the plan-unrepresentable "kernel"/"reference" —
-            # always wins over a stored paged_kernel
-            requested["paged_kernel"] = IMPL_TO_PAGED_KERNEL.get(paged_impl)
         self.resolved_plan = resolve_plan(
             model_cfg=cfg, max_prompt_tokens=max_prompt_tokens,
             max_new_tokens=max_new_tokens, rows=plan_rows,
@@ -163,12 +147,7 @@ class ShardedPagedEngine(LoraMailbox):
         )
         scan_chunk = self.resolved_plan.plan.scan_chunk
         self.plan_top_p_impl = self.resolved_plan.plan.top_p_impl
-        if paged_impl == "auto" and self.resolved_plan.plan.paged_kernel:
-            paged_impl = PAGED_KERNEL_TO_IMPL[
-                self.resolved_plan.plan.paged_kernel
-            ]
         self.paged_impl = paged_impl
-        self.pages_per_block = self.resolved_plan.plan.pages_per_block
         if "dp" not in mesh.shape:
             raise ValueError(f"mesh needs a 'dp' axis, got {dict(mesh.shape)}")
         other = {k: v for k, v in mesh.shape.items() if k != "dp" and v > 1}
@@ -207,7 +186,6 @@ class ShardedPagedEngine(LoraMailbox):
         self._step_kw = dict(
             cfg=cfg, page_size=page_size, pad_id=self.pad_id,
             lora_scale=lora_scale, paged_impl=paged_impl,
-            pages_per_block=self.pages_per_block,
             capture_logprobs=capture_logprobs,
         )
         self.scan_chunk = scan_chunk

@@ -609,7 +609,7 @@ def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
             pages_v = write_token_to_pages(pages_v, v[:, 0], lengths, idx, ps)
         o = paged_attention_op(
             q[:, 0], pages_k, pages_v, lengths + 1, idx, impl=env["paged_impl"],
-            pages_per_block=env["pages_per_block"])[:, None]
+        )[:, None]
         cache = (pages_k, pages_v)
     else:  # one page-aligned segment of a prefill, every row at ``start``
         pages_k, pages_v = cache
@@ -856,8 +856,7 @@ def forward_hybrid(
     kv_cache: Params | None = None, remat: bool = False,
     attn_impl: str = "reference", logits_slice=None, logits_positions=None,
     page_size: int = 0, lora_dropout: float = 0.0, dropout_rng=None,
-    skip_lm_head: bool = False, paged_impl: str = "auto", pages_per_block: int = 0,
-    **unsupported,
+    skip_lm_head: bool = False, paged_impl: str = "auto", **unsupported,
 ):
     """``transformer.forward`` for a model with per-layer mixers: same
     arguments, same returns. ``unsupported`` holds the dense decoder's other
@@ -887,7 +886,7 @@ def forward_hybrid(
         env = {
             "lengths": lengths, "page_indices": kv_cache["page_indices"],
             "page_size": page_size, "alive": kv_cache.get("alive"),
-            "paged_impl": paged_impl, "pages_per_block": pages_per_block,
+            "paged_impl": paged_impl,
         }
         if cfg.latent:  # read off the table once a step, for every layer
             with jax.named_scope(telemetry.MODEL_LATENT_ATTN):

@@ -155,7 +155,6 @@ def _layer(
     page_indices: jax.Array | None = None,  # [B, pps]
     page_size: int = 0,
     paged_impl: str = "auto",
-    pages_per_block: int = 0,  # blocked-kernel page collapse (0 = kernel default)
     paged_verify: bool = False,  # S>1 per-row draft-block decode (spec decode)
     paged_verify_impl: str = "fused",  # "fused" | "unrolled" verify sweep
     paged_chunked: bool = False,  # S>1 continuation (chunked) prefill
@@ -180,9 +179,8 @@ def _layer(
             attn_mesh=attn_mesh, key_valid=key_valid,
             paged_lengths=paged_lengths, page_indices=page_indices,
             page_size=page_size, paged_impl=paged_impl,
-            pages_per_block=pages_per_block, paged_verify=paged_verify,
-            paged_verify_impl=paged_verify_impl, paged_chunked=paged_chunked,
-            paged_prefix=paged_prefix,
+            paged_verify=paged_verify, paged_verify_impl=paged_verify_impl,
+            paged_chunked=paged_chunked, paged_prefix=paged_prefix,
             cache_read_formulation=cache_read_formulation,
         )
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
@@ -216,8 +214,8 @@ def _attend(
     cache_k, cache_v, cache_k_scale, cache_v_scale,
     *, mask, cache_offset, attn_impl: str, attn_mesh, key_valid,
     paged_lengths, page_indices, page_size: int, paged_impl: str,
-    pages_per_block: int, paged_verify: bool, paged_verify_impl: str,
-    paged_chunked: bool, paged_prefix: bool, cache_read_formulation: str,
+    paged_verify: bool, paged_verify_impl: str, paged_chunked: bool,
+    paged_prefix: bool, cache_read_formulation: str,
 ):
     """The layer's KV write (under ``engine/kv_write``) and its attention
     call, whichever implementation: paged, dense cache, ring, ulysses or plain.
@@ -240,7 +238,7 @@ def _attend(
                     cache_v, v[:, 0], paged_lengths, page_indices, page_size)
             att = paged_attention_op(
                 q[:, 0], cache_k, cache_v, paged_lengths + 1, page_indices,
-                impl=paged_impl, pages_per_block=pages_per_block,
+                impl=paged_impl,
             )[:, None]
         elif paged_chunked:
             # continuation (chunked) prefill: S tokens extend each row's
@@ -324,8 +322,7 @@ def _attend(
                     cache_v, v, paged_lengths, page_indices, page_size)
             att = paged_verify_op(
                 q, cache_k, cache_v, paged_lengths, page_indices,
-                impl=paged_impl, pages_per_block=pages_per_block,
-                verify_impl=paged_verify_impl,
+                impl=paged_impl, verify_impl=paged_verify_impl,
             )
         else:
             # packed prefill: write the prompt pages, attend over the input
@@ -510,7 +507,6 @@ def forward(
     logits_positions: jax.Array | None = None,  # [B] per-row position gather
     page_size: int = 0,  # static; paged-cache mode (ops/paged.py)
     paged_impl: str = "auto",
-    pages_per_block: int = 0,  # blocked-kernel page collapse (0 = kernel default)
     paged_verify: bool = False,  # speculative-decode draft-block verify
     paged_verify_impl: str = "fused",  # verify sweep: "fused" | "unrolled"
     paged_chunked: bool = False,  # continuation (chunked) prefill over pages
@@ -552,7 +548,7 @@ def forward(
             dropout_rng=dropout_rng, skip_lm_head=skip_lm_head,
             attn_mesh=attn_mesh, paged_verify=paged_verify,
             paged_chunked=paged_chunked, paged_prefix=paged_prefix,
-            paged_impl=paged_impl, pages_per_block=pages_per_block,
+            paged_impl=paged_impl,
         )
     b, s = input_ids.shape
     paged = kv_cache is not None and "page_indices" in kv_cache
@@ -612,7 +608,6 @@ def forward(
         page_indices=kv_cache.get("page_indices") if paged else None,
         page_size=page_size,
         paged_impl=paged_impl,
-        pages_per_block=pages_per_block,
         paged_verify=paged_verify,
         paged_verify_impl=paged_verify_impl,
         paged_chunked=paged_chunked,

@@ -19,9 +19,9 @@ distributed_actor.py:148–150). TPU-native design:
   sequences grow and rewrites rows on admission/preemption; wave mode uses
   a per-round constant layout). The indirection layer is also what lets
   prompt-prefix sharing land without touching the kernel.
-* **Kernel**: our Pallas TPU kernels (ops/paged_native.py, compact int8
-  scales) on a TPU backend; a jnp reference with identical semantics
-  elsewhere and for parity tests.
+* **Kernel**: on a TPU backend our Pallas TPU kernel (ops/paged_native.py,
+  compact int8 scales); a jnp reference with identical semantics elsewhere
+  and for parity tests.
 """
 
 from __future__ import annotations
@@ -299,16 +299,9 @@ def paged_attention_reference(
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
-#: kernel default for the blocked and verify launches' page-axis collapse —
-#: callers passing 0 get this (kept here so plan resolution and the analytic
-#: grid-step model agree on what "default" means). "native" chooses its own
-#: from the shapes: ``paged_native.native_pages_per_step``.
-DEFAULT_PAGES_PER_BLOCK = 8
-
-
 def paged_grid_steps(
     impl: str, *, batch: int, num_kv_heads: int, pps: int,
-    pages_per_block: int = 0, head_dim: int = 0, page_size: int = 0,
+    head_dim: int = 0, page_size: int = 0,
     kv_itemsize: int = 2, quantized: bool = False,
 ) -> int:
     """Analytic Pallas grid-step count of ONE paged-attention call (one
@@ -320,18 +313,15 @@ def paged_grid_steps(
     softmax — the fewer and larger steps are the faster call, 479 / 176 /
     98 us.
 
-    Counts per impl: "native" (what "auto" is on a TPU) moves all kv heads
-    and ``native_pages_per_step`` pages of a row a step — (B, ceil(pps /
-    ppb)), with ppb from ``head_dim``, ``page_size`` and the pages' dtype
+    Counts per impl: "native" moves all kv heads and
+    ``native_pages_per_step`` pages of a row a step — (B, ceil(pps / ppb)),
+    with ppb from ``head_dim``, ``page_size`` and the pages' dtype
     (``kv_itemsize``, ``quantized``), which this impl therefore needs;
-    "native_folded" folds kv heads into the block — (B, pps);
-    "native_blocked" additionally collapses the page axis — (B, ceil(pps /
-    pages_per_block)); "native_verify" is the FUSED draft-block verify: the
-    whole (d+1)-query speculative verify step in ONE blocked sweep — same
-    (B, ceil(pps / pages_per_block)) count as "native_blocked", where the
-    unrolled verify paid that count (d+1) TIMES per step; jaxlib's kernel
-    ("kernel") walks pages with manual DMA inside a (1, B, K) grid; the jnp
-    reference has no Pallas grid (0)."""
+    "native_verify" is the FUSED draft-block verify: the whole (d+1)-query
+    speculative verify step in ONE sweep of ``VERIFY_PAGES_PER_BLOCK`` pages
+    a step — (B, ceil(pps / ppb)), where the unrolled verify pays the decode
+    launch's count (d+1) TIMES per step; the jnp reference has no Pallas
+    grid (0)."""
     if impl == "native":
         from distrl_llm_tpu.ops.paged_native import native_pages_per_step
 
@@ -346,72 +336,46 @@ def paged_grid_steps(
             quantized=quantized,
         )
         return batch * -(-pps // ppb)
-    if impl == "native_folded":
-        return batch * pps
-    if impl in ("native_blocked", "native_verify"):
-        ppb = max(1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps))
-        return batch * -(-pps // ppb)
-    if impl == "kernel":
-        return batch * num_kv_heads
+    if impl == "native_verify":
+        return batch * -(-pps // min(VERIFY_PAGES_PER_BLOCK, pps))
     return 0
 
 
 def dispatch_choice_key(
     *, quantized: bool, num_kv_heads: int, num_groups: int, head_dim: int,
-    page_size: int, pps: int, pages_per_compute_block: int = 4,
-    impl: str = "auto", pages_per_block: int = 0, verify_len: int = 0,
+    page_size: int, pps: int, impl: str = "auto", verify_len: int = 0,
 ) -> tuple:
     """The per-config key ``paged_attention_op`` records its dispatch
     decision under ``dispatch_choices``. One function so engines can look
     up THEIR OWN entry instead of guessing across a process-global dict
     (several engines can trace in one process — the autotuner's candidate
-    sweep). The REQUESTED ``impl`` and ``pages_per_block`` are part of the
-    key: two same-geometry engines pinned to different kernels must not
-    share (and overwrite) one record. ``verify_len`` > 0 marks the
-    speculative draft-block verify dispatch (``paged_verify_op``) — its
-    decision ("native_verify" fused sweep vs "unrolled") is a different
-    choice than the single-query decode's and must not alias it."""
-    blocks = divisor_blocks(pages_per_compute_block, pps)
-    return (impl, pages_per_block, quantized, num_kv_heads, num_groups,
-            head_dim, page_size, blocks, pps, verify_len)
-
-
-def divisor_blocks(pages_per_compute_block: int, pps: int) -> int:
-    """Largest divisor of ``pps`` that fits ``pages_per_compute_block`` —
-    the ``pages_per_compute_block`` jaxlib's launch ("kernel") takes. Shared so
-    consumers derive it from the geometry instead of indexing the dispatch
-    key tuple positionally."""
-    return max(
-        (d for d in range(1, min(pages_per_compute_block, pps) + 1)
-         if pps % d == 0),
-        default=1,
-    )
+    sweep). The REQUESTED ``impl`` is part of the key: two same-geometry
+    engines pinned to different kernels must not share (and overwrite) one
+    record. ``verify_len`` > 0 marks the speculative draft-block verify
+    dispatch (``paged_verify_op``) — its decision ("native_verify" fused
+    sweep vs "unrolled") is a different choice than the single-query
+    decode's and must not alias it."""
+    return (impl, quantized, num_kv_heads, num_groups, head_dim, page_size,
+            pps, verify_len)
 
 
 # per-config record of what each paged dispatch resolved to ("native" |
-# "native_folded" | "native_blocked" | "kernel" | "reference") — engines
-# and chip_smoke.py read it, so a run on the reference can never pass for a
-# kernel measurement
+# "reference") — engines and chip_smoke.py read it, so a run on
+# the reference can never pass for a kernel measurement
 dispatch_choices: dict = {}
 # NOTE on grid-step accounting: the analytic count is batch-dependent, so
 # it is never cached here — consumers read WHICH impl ran from
 # dispatch_choices (keyed per requested impl + geometry) and compute
-# paged_grid_steps() against their own live batch/ppb.
+# paged_grid_steps() against their own live batch.
 
 #: every spelling ``paged_attention_op(impl=...)`` takes
-PAGED_IMPLS = ("auto", "reference", "kernel", "native", "native_folded",
-               "native_blocked")
-#: what "auto" is on a TPU backend, at every geometry:
-#: ``paged_native.paged_attention_native`` — a row's KV for all kv heads and
-#: as many of its pages as the VMEM budget holds a grid step, no page past
-#: the row's length fetched, one softmax a step (98 us a call where the
-#: one-page kernel it replaced took 479, a v5e at the 7B geometry; PERF.md
-#: §6, PR 32). Of the pipelined family, the only one that lowers for
-#: head_dim 64 AND 128 (tests/test_tpu_compile.py); chip_smoke.py holds it to
-#: the reference on the chip at both. Its launch function's NAME is what the
-#: benchmark's kernel metrics find it by, so "auto" stays this spelling. The
-#: folded/blocked variants and jaxlib's kernel run where a caller or a stored
-#: plan names them.
+PAGED_IMPLS = ("auto", "reference", "native")
+#: what "auto" is on a TPU backend: ``paged_native.paged_attention_native`` —
+#: a row's KV for all kv heads and as many of its pages as the VMEM budget
+#: holds a grid step, no page past the row's length fetched, one softmax a
+#: step. It lowers for head_dim 64 AND 128 (tests/test_tpu_compile.py);
+#: chip_smoke.py holds it to the reference on the chip at both. Its launch
+#: function's NAME is what the benchmark's kernel metrics find it by.
 AUTO_TPU_IMPL = "native"
 
 
@@ -433,41 +397,31 @@ def resolve_paged_impl(impl: str) -> str:
 # would rename the custom call and re-key the program (ops/sampling.py)
 @jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION)
 def _native_call(q, k_pages, v_pages, lengths, page_indices,
-                 *, quantized: bool,
-                 folded: bool = False, blocked: bool = False,
-                 pages_per_block: int = 0, interpret: bool = False):
-    """Adapter: the dispatch's launch signature → our native kernels
-    (ops/paged_native.py), which take int8 weights and compact scales as
-    separate arrays. Neither flag: ``paged_attention_native``, which sizes
-    its own blocks. ``folded`` selects the older kv-heads-in-block variant
-    with a (B, pps) grid; ``blocked`` the older multi-page variant with a
-    (B, ceil(pps / pages_per_block)) grid (``paged_grid_steps``)."""
-    from distrl_llm_tpu.ops.paged_native import (
-        paged_attention_native,
-        paged_attention_native_blocked,
-        paged_attention_native_folded,
+                 *, quantized: bool, interpret: bool = False):
+    """Adapter: the dispatch's launch signature → ``paged_attention_native``
+    (ops/paged_native.py), which takes int8 weights and compact scales as
+    separate arrays and sizes its own blocks."""
+    from distrl_llm_tpu.ops.paged_native import paged_attention_native
+
+    if quantized:
+        return paged_attention_native(
+            q, k_pages.weight, v_pages.weight, lengths, page_indices,
+            k_scales=k_pages.scales, v_scales=v_pages.scales,
+            interpret=interpret,
+        )
+    return paged_attention_native(
+        q, k_pages, v_pages, lengths, page_indices, interpret=interpret
     )
 
-    kw: dict = {"interpret": interpret}
-    if blocked:
-        kernel = paged_attention_native_blocked
-        kw["pages_per_block"] = pages_per_block or DEFAULT_PAGES_PER_BLOCK
-    elif folded:
-        kernel = paged_attention_native_folded
-    else:
-        kernel = paged_attention_native
-    if quantized:
-        return kernel(
-            q, k_pages.weight, v_pages.weight, lengths, page_indices,
-            k_scales=k_pages.scales, v_scales=v_pages.scales, **kw,
-        )
-    return kernel(q, k_pages, v_pages, lengths, page_indices, **kw)
+
+#: pages of all kv heads one grid step of the fused verify launch moves
+#: (``paged_native.paged_attention_native_verify``), at most the table's width
+VERIFY_PAGES_PER_BLOCK = 8
 
 
 @jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION)
 def _native_verify_call(q, k_pages, v_pages, lengths, page_indices,
-                        *, quantized: bool, pages_per_block: int = 0,
-                        interpret: bool = False):
+                        *, quantized: bool, interpret: bool = False):
     """Adapter for the fused draft-block verify kernel
     (ops/paged_native.py::paged_attention_native_verify): q is the whole
     [B, S, H, hd] draft block, pre-scaled; ``lengths`` are the RESIDENT
@@ -476,8 +430,7 @@ def _native_verify_call(q, k_pages, v_pages, lengths, page_indices,
     from distrl_llm_tpu.ops.paged_native import paged_attention_native_verify
 
     kw: dict = {
-        "interpret": interpret,
-        "pages_per_block": pages_per_block or DEFAULT_PAGES_PER_BLOCK,
+        "interpret": interpret, "pages_per_block": VERIFY_PAGES_PER_BLOCK,
     }
     if quantized:
         return paged_attention_native_verify(
@@ -497,60 +450,33 @@ def paged_attention_op(
     page_indices: jax.Array,
     *,
     impl: str = "auto",
-    pages_per_compute_block: int = 4,
-    pages_per_block: int = 0,
 ) -> jax.Array:
     """Dispatch one decode query per row over the paged cache.
 
     ``impl``: "auto" (``resolve_paged_impl``: the native kernel on a TPU
-    backend, the reference elsewhere), "native" (our pipeline-gather
-    kernel, ops/paged_native.py: all kv heads and a length-bounded run of a
-    row's pages a grid step, the block sized by the launch from the shapes),
-    "native_folded" / "native_blocked" (the older one-page-a-softmax
-    variants — ``pages_per_block`` sizes the blocked kernel's page collapse
-    and nothing else; 0 = DEFAULT_PAGES_PER_BLOCK), "kernel" (jaxlib's own
-    launch) or "reference". What ran is recorded in ``dispatch_choices``."""
-    resolved = resolve_paged_impl(impl)
+    backend, the reference elsewhere), "native" (our pipeline-gather kernel,
+    ops/paged_native.py: all kv heads and a length-bounded run of a row's
+    pages a grid step, the block sized by the launch from the shapes) or
+    "reference". What ran is recorded in ``dispatch_choices``."""
     pps = page_indices.shape[1]
     quantized = is_quantized_pages(k_pages)
     kw = k_pages.weight if quantized else k_pages
     num_kv_heads = kw.shape[0]
+    resolved = resolve_paged_impl(impl)
     choice_key = dispatch_choice_key(
         quantized=quantized, num_kv_heads=num_kv_heads,
         num_groups=q.shape[1] // num_kv_heads, head_dim=kw.shape[-1],
-        page_size=kw.shape[-2], pps=pps,
-        pages_per_compute_block=pages_per_compute_block,
-        impl=impl, pages_per_block=pages_per_block,
+        page_size=kw.shape[-2], pps=pps, impl=impl,
     )
     dispatch_choices[choice_key] = resolved
     if resolved == "reference":
         return paged_attention_reference(
             q, k_pages, v_pages, lengths, page_indices
         )
-    # the kernels compute raw q·k (no internal scaling)
-    scaled_q = q * (q.shape[-1] ** -0.5)
-    if resolved == "kernel":
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention,
-        )
-
-        # requires pages_per_sequence % pages_per_compute_block == 0
-        with jax.named_scope(telemetry.KERNEL_PAGED_ATTENTION):
-            return per_device(paged_attention)(
-                scaled_q, k_pages, v_pages, lengths.astype(jnp.int32),
-                page_indices,
-                pages_per_compute_block=divisor_blocks(
-                    pages_per_compute_block, pps
-                ),
-            ).astype(q.dtype)
+    # the kernel computes raw q·k (no internal scaling)
     return per_device(_native_call)(
-        scaled_q, k_pages, v_pages, lengths.astype(jnp.int32), page_indices,
-        quantized=quantized,
-        folded=resolved == "native_folded",
-        blocked=resolved == "native_blocked",
-        pages_per_block=max(
-            1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps)
-        ),
+        q * (q.shape[-1] ** -0.5), k_pages, v_pages,
+        lengths.astype(jnp.int32), page_indices, quantized=quantized,
     ).astype(q.dtype)
 
 
@@ -584,18 +510,16 @@ def paged_verify_op(
     page_indices: jax.Array,
     *,
     impl: str = "auto",
-    pages_per_compute_block: int = 4,
-    pages_per_block: int = 0,
     verify_impl: str = "fused",
 ) -> jax.Array:
     """Speculative-decode draft-block verify dispatch: the S-query
-    attention of one verify forward, in ONE fused blocked sweep when the
-    hardware can (``paged_attention_native_verify``), else unrolled into S
+    attention of one verify forward, in ONE fused sweep when the hardware
+    can (``paged_attention_native_verify``), else unrolled into S
     per-position ``paged_attention_op`` dispatches (the pre-fusion
     behavior, exact to the dispatch).
 
-    ``verify_impl``: "fused" (the fused kernel on a TPU backend for the
-    native impl family, unrolled elsewhere) or "unrolled" (force
+    ``verify_impl``: "fused" (the fused kernel on a TPU backend under
+    "auto" / "native", unrolled elsewhere) or "unrolled" (force
     per-position dispatch — the A/B control and the interpreter-parity
     anchor). The decision is recorded in ``dispatch_choices`` under the
     verify-marked key (``dispatch_choice_key(..., verify_len=S)``):
@@ -611,27 +535,23 @@ def paged_verify_op(
     quantized = is_quantized_pages(k_pages)
     kw = k_pages.weight if quantized else k_pages
     num_kv_heads = kw.shape[0]
-    num_groups = h // num_kv_heads
-    head_dim, page_size = kw.shape[-1], kw.shape[-2]
-    pps = page_indices.shape[1]
-    ppb_eff = max(1, min(pages_per_block or DEFAULT_PAGES_PER_BLOCK, pps))
     choice_key = dispatch_choice_key(
         quantized=quantized, num_kv_heads=num_kv_heads,
-        num_groups=num_groups, head_dim=head_dim, page_size=page_size,
-        pps=pps, pages_per_compute_block=pages_per_compute_block,
-        impl=impl, pages_per_block=pages_per_block, verify_len=s,
+        num_groups=h // num_kv_heads, head_dim=kw.shape[-1],
+        page_size=kw.shape[-2], pps=page_indices.shape[1],
+        impl=impl, verify_len=s,
     )
-    # the fused kernel is a native-family launch; "kernel"/"reference"
-    # pins have no fused spelling and always unroll onto their own impl
+    # the fused kernel is a native launch; a "reference" pin has no fused
+    # spelling and unrolls onto the reference
     if (
         verify_impl == "fused"
-        and resolve_paged_impl(impl).startswith("native")
+        and resolve_paged_impl(impl) == AUTO_TPU_IMPL
         and jax.default_backend() == "tpu"
     ):
         dispatch_choices[choice_key] = "native_verify"
         return per_device(_native_verify_call)(
             q * (hd ** -0.5), k_pages, v_pages, lengths.astype(jnp.int32),
-            page_indices, quantized=quantized, pages_per_block=ppb_eff,
+            page_indices, quantized=quantized,
         ).astype(q.dtype)
     # unrolled: S per-position dispatches (each records its own decode
     # dispatch choice; the verify key records that the step ran unrolled)
@@ -640,8 +560,7 @@ def paged_verify_op(
         [
             paged_attention_op(
                 q[:, i], k_pages, v_pages, lengths + i + 1, page_indices,
-                impl=impl, pages_per_compute_block=pages_per_compute_block,
-                pages_per_block=pages_per_block,
+                impl=impl,
             )
             for i in range(s)
         ],

@@ -31,17 +31,12 @@ pages — grid (B, ceil(pps / ppb)), one to two steps a row at that geometry,
   count of live pages in the block (``pl.when(live == n)``, n = 1..ppb);
   branch n computes the n score tiles, ONE running-max / running-sum update
   over all of them, and n p·V products. A chain of per-page online-softmax
-  updates, which the older launches below still are, costs 1.7x the
-  arithmetic's time at the same transfers (121.6 against 72.3 us a call with
-  the DMAs taken out), and pages past the length are never computed on, so a
-  poisoned page there cannot reach the output.
+  updates costs 1.7x the arithmetic's time at the same transfers (121.6
+  against 72.3 us a call with the DMAs taken out), and pages past the length
+  are never computed on, so a poisoned page there cannot reach the output.
 
-What the timing said about the older launches, which stay for a caller or a
-stored plan that names them (ROADMAP D2 deletes the losers): the one-page
-kernel this one replaced ran a (B, K, pps) grid at 0.37 us a grid step with
-the page's arithmetic inside, 479 us a call; ``native_folded`` (grid (B,
-pps)) 176 us; ``native_blocked`` (grid (B, ceil(pps / ppb))) 131 us; jaxlib's
-``kernel`` 236-435 us; this launch 98 us, against 86 us for its DMAs alone.
+A call takes 91-98 us at that geometry against 86 us for its DMAs alone
+(PERF.md §6, PR 32 and PR 47), where jaxlib's launch takes 242-435 us.
 Float32 operands cost the MXU nothing here: Mosaic's default-precision
 float32 dot is one bf16 pass, bit for bit what a bf16 operand gives.
 
@@ -314,284 +309,38 @@ def paged_attention_native(
     return out.reshape(batch, num_q_heads, head_dim)
 
 
-def _paged_kernel_folded(
-    lengths_ref,  # SMEM [B] i32
-    tables_ref,  # SMEM [B, pps] i32
-    q_ref,  # VMEM [K, G, hd] — this row's full query head set
-    k_ref,  # VMEM [K, 1, ps, hd] — page j for ALL kv heads (one block)
-    v_ref,  # VMEM [K, 1, ps, hd]
-    k_s_ref,  # VMEM [K, 1, ps, 1] f32 compact scales, or None
-    v_s_ref,
-    o_ref,  # VMEM [K, G, hd]
-    m_scr,  # VMEM [K, G, 1] f32
-    l_scr,  # VMEM [K, G, 1] f32
-    acc_scr,  # VMEM [K, G, hd] f32
-    *,
-    page_size: int,
-    pps: int,
-):
-    """One page of ALL kv heads a grid step, grid (B, pps): the kv-head axis
-    rides INSIDE the block instead of the grid, and one online-softmax update
-    runs per page. A grid step of it costs 0.55 µs on a v5e at 4 kv heads of
-    128, where a one-head step cost 0.37 (176 against 479 µs a call; PERF.md
-    §6, PR 32); ``paged_attention_native`` moves a row's pages in one step.
-    Batched over K via dot_general batch dims — no in-kernel head slicing,
-    so the hd%128 Mosaic constraint this file exists for is never violated."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = lengths_ref[b]
-
-    @pl.when(j * page_size < length)
-    def _page():
-        q = q_ref[...].astype(jnp.float32)  # [K, G, hd] (pre-scaled)
-        k = k_ref[:, 0].astype(jnp.float32)  # [K, ps, hd]
-        v = v_ref[:, 0].astype(jnp.float32)
-        if k_s_ref is not None:
-            k = k * (k_s_ref[:, 0] * (1.0 / MAX_INT8))  # [K, ps, 1] bcast
-            v = v * (v_s_ref[:, 0] * (1.0 / MAX_INT8))
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [K, G, ps]
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2
-        )
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]  # [K, G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [K, G, ps]
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [K, G, hd]
-        m_scr[...] = m_new
-
-    @pl.when(j == pps - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("page_size", "interpret"),
-)
-def paged_attention_native_folded(
-    q: jax.Array,  # [B, H, hd] — pre-scaled by hd**-0.5 (op contract)
-    k_pages: jax.Array,  # [K, P, ps, hd] bf16/f32, or int8 weight
-    v_pages: jax.Array,
-    lengths: jax.Array,  # i32 [B]
-    page_indices: jax.Array,  # i32 [B, pps]
-    k_scales: jax.Array | None = None,  # f32 [K, P, ps, 1] compact (int8)
-    v_scales: jax.Array | None = None,
-    *,
-    page_size: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Launch for ``_paged_kernel_folded`` — same contract as
-    ``paged_attention_native`` with a (B, pps) grid."""
-    batch, num_q_heads, head_dim = q.shape
-    num_kv_heads, total_pages, ps, head_dim_k = k_pages.shape
-    if page_size is None:
-        page_size = ps
-    if head_dim_k != head_dim:
-        raise ValueError(f"head_dim mismatch: {head_dim_k} vs {head_dim}")
-    if num_q_heads % num_kv_heads:
-        raise ValueError(
-            f"H={num_q_heads} not divisible by K={num_kv_heads}"
-        )
-    groups = num_q_heads // num_kv_heads
-    _, pps = page_indices.shape
-    quantized = k_scales is not None
-
-    tables = jnp.clip(page_indices.astype(jnp.int32), 0, total_pages - 1)
-    q4 = q.reshape(batch, num_kv_heads, groups, head_dim)
-
-    q_spec = pl.BlockSpec(
-        (None, num_kv_heads, groups, head_dim),
-        lambda b, j, lens, tabs: (b, 0, 0, 0),
-    )
-    kv_spec = pl.BlockSpec(
-        (num_kv_heads, 1, page_size, head_dim),
-        lambda b, j, lens, tabs: (0, tabs[b, j], 0, 0),
-    )
-    scale_spec = pl.BlockSpec(
-        (num_kv_heads, 1, page_size, 1),
-        lambda b, j, lens, tabs: (0, tabs[b, j], 0, 0),
-    )
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [q4, k_pages, v_pages]
-    if quantized:
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
-        body = functools.partial(
-            _paged_kernel_folded, page_size=page_size, pps=pps)
-    else:
-
-        def body(lens, tabs, qr, kr, vr, o, m, l, a):  # noqa: E741
-            _paged_kernel_folded(
-                lens, tabs, qr, kr, vr, None, None, o, m, l, a,
-                page_size=page_size, pps=pps,
-            )
-
-    out = pl.pallas_call(
-        body,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(batch, pps),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (None, num_kv_heads, groups, head_dim),
-                lambda b, j, lens, tabs: (b, 0, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
-                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
-                pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (batch, num_kv_heads, groups, head_dim), q.dtype
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), tables, *operands)
-    return out.reshape(batch, num_q_heads, head_dim)
-
-
-def _make_blocked_kernel(*, page_size: int, ppb: int, nblk: int,
-                         quantized: bool):
-    """Kernel body for ``paged_attention_native_blocked``: ``ppb`` pages of
-    ALL kv heads folded into one grid step (grid (B, ceil(pps/ppb)) — the
-    kv-heads folding of ``_paged_kernel_folded`` composed with a page-axis
-    collapse): fewer and larger grid steps, 131 µs a call where the folded
-    kernel takes 176 (a v5e, 4 kv heads of 128, five pages a row; PERF.md
-    §6, PR 32). Its chain of per-page softmax updates is what
-    ``paged_attention_native`` replaced by one update a step (98 µs).
-
-    The per-page gather stays in BlockSpec ``index_map``s — one per
-    in-block page, each reading its own scalar-prefetched table slot
-    ``tabs[b, jb·ppb + i]`` — because whole-block pipelined moves are the
-    one DMA pattern this Mosaic version has proven at head_dim 64 (the
-    reason this file exists; manual in-kernel DMA is exactly what it was
-    built to avoid). The kernel body carries the online softmax across the
-    in-kernel page loop in REGISTERS, touching the m/l/acc scratch once per
-    grid step instead of once per page.
-
-    Ragged tails: pages whose positions all sit past ``length`` contribute
-    ``exp(NEG_INF − m)`` = 0 exactly (the block guard ensures the first
-    in-block page is valid, so ``m`` is finite before any fully-masked page
-    folds in — the 0/0 hazard of an all-masked softmax cannot arise), and
-    blocks entirely past the length are skipped by ``pl.when``; their DMAs
-    still run against edge-padded table slots wherever those name another
-    page than the step before (``paged_attention_native`` bounds the walk:
-    ``live_page_walk``)."""
-
-    def kernel(lengths_ref, tables_ref, q_ref, *rest):
-        k_refs = rest[0:ppb]
-        v_refs = rest[ppb:2 * ppb]
-        if quantized:
-            ks_refs = rest[2 * ppb:3 * ppb]
-            vs_refs = rest[3 * ppb:4 * ppb]
-            o_ref, m_scr, l_scr, acc_scr = rest[4 * ppb:]
-        else:
-            ks_refs = vs_refs = None
-            o_ref, m_scr, l_scr, acc_scr = rest[2 * ppb:]
-        b = pl.program_id(0)
-        jb = pl.program_id(1)
-
-        @pl.when(jb == 0)
-        def _init():
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-            acc_scr[...] = jnp.zeros_like(acc_scr)
-
-        length = lengths_ref[b]
-
-        @pl.when(jb * (ppb * page_size) < length)
-        def _block():
-            q = q_ref[...].astype(jnp.float32)  # [K, G, hd] (pre-scaled)
-            m = m_scr[...]  # [K, G, 1]
-            l = l_scr[...]  # noqa: E741
-            acc = acc_scr[...]  # [K, G, hd]
-            for i in range(ppb):  # static unroll: ppb block loads per step
-                k = k_refs[i][:, 0].astype(jnp.float32)  # [K, ps, hd]
-                v = v_refs[i][:, 0].astype(jnp.float32)
-                if quantized:
-                    # compact per-token scales (see _make_native_kernel: 127.5,
-                    # the from_int8 contract)
-                    k = k * (ks_refs[i][:, 0] * (1.0 / MAX_INT8))
-                    v = v * (vs_refs[i][:, 0] * (1.0 / MAX_INT8))
-                s = jax.lax.dot_general(
-                    q, k, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )  # [K, G, ps]
-                pos = (jb * ppb + i) * page_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, 1, page_size), 2
-                )
-                s = jnp.where(pos < length, s, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)  # [K, G, ps]
-                l = alpha * l + jnp.sum(p, axis=2, keepdims=True)  # noqa: E741
-                acc = acc * alpha + jax.lax.dot_general(
-                    p, v, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                m = m_new
-            m_scr[...] = m
-            l_scr[...] = l
-            acc_scr[...] = acc
-
-        @pl.when(jb == nblk - 1)
-        def _emit():
-            o_ref[...] = (
-                acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-            ).astype(o_ref.dtype)
-
-    return kernel
-
-
 def _make_verify_kernel(*, page_size: int, ppb: int, nblk: int, s_len: int,
                         groups: int, quantized: bool):
-    """Kernel body for ``paged_attention_native_verify``: the blocked kernel
-    (``_make_blocked_kernel``) extended to an S-QUERY draft block per row —
-    the speculative-decode verify forward in ONE grid sweep.
+    """Kernel body for ``paged_attention_native_verify``: an S-QUERY draft
+    block per row — the speculative-decode verify forward in ONE grid sweep,
+    grid (B, ceil(pps/ppb)), ``ppb`` pages of ALL kv heads a step, each page
+    its own operand gathered by an ``index_map`` over the scalar-prefetched
+    table (whole-block pipelined moves: the one DMA pattern proven at
+    head_dim 64, the reason this file exists).
 
-    Before this kernel, the verify forward unrolled attention per draft
-    position (models/transformer.py issued S separate ``paged_attention_op``
-    dispatches per step), multiplying the grid walk by (d+1) and forfeiting
-    the amortization speculation exists to buy: S sweeps cost S× although
-    they move the same KV bytes. Here the S
-    queries ride INSIDE the block — folded into the query-group axis as
-    [K, S·G, hd], the same trick the folded kernel plays with kv heads — so
-    the whole (d+1)-token verify costs exactly one blocked sweep:
-    grid (B, ceil(pps/ppb)).
+    Unrolled, the verify forward issues S separate ``paged_attention_op``
+    dispatches a step, S sweeps over the same KV bytes. Here the S queries
+    ride INSIDE the block, folded into the query-group axis as [K, S·G, hd],
+    so the whole (d+1)-token verify costs one sweep. The body is a chain of
+    per-page online-softmax updates carried in registers, the m/l/acc
+    scratch touched once a grid step; it bounds neither its page walk by the
+    length nor its softmax to one update a step, as ``paged_attention_native``
+    does (no cell runs speculation: ROADMAP D5 gives it that body or retires
+    it). Padded table slots of a ragged final block repeat the row's last
+    page and are fully masked.
 
     Causality is per QUERY: draft position i (query rows i·G..(i+1)·G−1)
     attends key positions < lengths + i + 1 — the prefix plus draft tokens
-    ≤ i, exactly the ``lengths + i + 1`` ladder the unrolled path passed
+    ≤ i, exactly the ``lengths + i + 1`` ladder the unrolled path passes
     per dispatch. The limit is a per-row vector built from a static
     row→position iota, so the mask is one vectorized compare, not a loop.
 
-    Numerical-safety note (why the blocked kernel's first-block-valid
-    argument still holds): every query row has at least one attendable
+    Numerical safety: every query row has at least one attendable
     position — query i's own token sits at position lengths + i <
     lengths + i + 1, and block 0 always covers position 0 < lengths + 1 —
-    so the running max is finite after block 0 for every row and
-    fully-masked later pages fold in as exact zeros."""
+    so the running max is finite after block 0 for every row, and a page
+    whose positions all sit past the limit folds in as
+    ``exp(NEG_INF − m)`` = 0 exactly."""
 
     sg = s_len * groups
 
@@ -785,117 +534,3 @@ def paged_attention_native_verify(
         .transpose(0, 2, 1, 3, 4)
         .reshape(batch, s_len, num_q_heads, head_dim)
     )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("page_size", "pages_per_block", "interpret"),
-)
-def paged_attention_native_blocked(
-    q: jax.Array,  # [B, H, hd] — pre-scaled by hd**-0.5 (op contract)
-    k_pages: jax.Array,  # [K, P, ps, hd] bf16/f32, or int8 weight
-    v_pages: jax.Array,
-    lengths: jax.Array,  # i32 [B]
-    page_indices: jax.Array,  # i32 [B, pps]
-    k_scales: jax.Array | None = None,  # f32 [K, P, ps, 1] compact (int8)
-    v_scales: jax.Array | None = None,
-    *,
-    page_size: int | None = None,
-    pages_per_block: int = 8,
-    interpret: bool = False,
-) -> jax.Array:
-    """Launch for ``_make_blocked_kernel`` — same contract as
-    ``paged_attention_native`` with a (B, ceil(pps / pages_per_block))
-    grid. ``pages_per_block`` is clamped to [1, pps]; at 1 this is the
-    folded kernel bit-for-bit (same op order — pinned by tests)."""
-    batch, num_q_heads, head_dim = q.shape
-    num_kv_heads, total_pages, ps, head_dim_k = k_pages.shape
-    if page_size is None:
-        page_size = ps
-    if head_dim_k != head_dim:
-        raise ValueError(f"head_dim mismatch: {head_dim_k} vs {head_dim}")
-    if num_q_heads % num_kv_heads:
-        raise ValueError(
-            f"H={num_q_heads} not divisible by K={num_kv_heads}"
-        )
-    if pages_per_block < 1:
-        raise ValueError(
-            f"pages_per_block must be >= 1, got {pages_per_block}"
-        )
-    groups = num_q_heads // num_kv_heads
-    _, pps = page_indices.shape
-    quantized = k_scales is not None
-    ppb = min(pages_per_block, pps)
-    nblk = -(-pps // ppb)
-
-    tables = jnp.clip(page_indices.astype(jnp.int32), 0, total_pages - 1)
-    pad = nblk * ppb - pps
-    if pad:
-        # ragged final block: edge-pad the table so every in-block
-        # index_map slot is addressable; padded pages are fully
-        # length-masked in the kernel
-        tables = jnp.concatenate(
-            [tables, jnp.broadcast_to(tables[:, -1:], (batch, pad))], axis=1
-        )
-    q4 = q.reshape(batch, num_kv_heads, groups, head_dim)
-
-    q_spec = pl.BlockSpec(
-        (None, num_kv_heads, groups, head_dim),
-        lambda b, j, lens, tabs: (b, 0, 0, 0),
-    )
-
-    def kv_spec(i):
-        return pl.BlockSpec(
-            (num_kv_heads, 1, page_size, head_dim),
-            lambda b, j, lens, tabs, i=i: (0, tabs[b, j * ppb + i], 0, 0),
-        )
-
-    def scale_spec(i):
-        return pl.BlockSpec(
-            (num_kv_heads, 1, page_size, 1),
-            lambda b, j, lens, tabs, i=i: (0, tabs[b, j * ppb + i], 0, 0),
-        )
-
-    # the SAME pool array rides as ppb inputs, one per in-block page — each
-    # gets its own index_map gather, so the pipeline emitter still only
-    # ever moves whole [K, 1, ps, hd] blocks (never slicing the minor dims)
-    in_specs = (
-        [q_spec]
-        + [kv_spec(i) for i in range(ppb)]
-        + [kv_spec(i) for i in range(ppb)]
-    )
-    operands = [q4] + [k_pages] * ppb + [v_pages] * ppb
-    if quantized:
-        in_specs += (
-            [scale_spec(i) for i in range(ppb)]
-            + [scale_spec(i) for i in range(ppb)]
-        )
-        operands += [k_scales] * ppb + [v_scales] * ppb
-
-    out = pl.pallas_call(
-        _make_blocked_kernel(
-            page_size=page_size, ppb=ppb, nblk=nblk, quantized=quantized
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(batch, nblk),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (None, num_kv_heads, groups, head_dim),
-                lambda b, j, lens, tabs: (b, 0, 0, 0),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
-                pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
-                pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (batch, num_kv_heads, groups, head_dim), q.dtype
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), tables, *operands)
-    return out.reshape(batch, num_q_heads, head_dim)

@@ -26,6 +26,7 @@ from distrl_llm_tpu.autotune import (
     ExecutionPlan,
     PlanStore,
     SCHEMA_VERSION,
+    TUNABLE_FIELDS,
     canonical_device_kind,
     current_device_kind,
     model_config_hash,
@@ -312,88 +313,80 @@ class TestEngineIntegration:
         p0 = PagedGenerationEngine(TINY, plan_db=db, scan_chunk=0, **ENGINE_KW)
         assert p0.scan_chunk == 0
 
-    def test_paged_kernel_empty_db_keeps_auto(self, tmp_path):
-        """Byte-identity pin for the ISSUE-3 fields: with no DB entry the
-        engine's paged dispatch stays exactly the historical 'auto' probe
-        chain and pages_per_block stays 0 (the kernel default)."""
-        p = PagedGenerationEngine(
-            TINY, plan_db=str(tmp_path / "no.json"), **ENGINE_KW
-        )
-        assert p.paged_impl == "auto"
-        assert p.pages_per_block == 0
-        assert p.resolved_plan.plan.paged_kernel is None
-
-    def test_paged_kernel_db_plan_applies(self, tmp_path):
+    def test_the_paged_launch_is_the_callers_word_alone(self, tmp_path):
+        """No plan field names a paged kernel: with an empty database or a
+        stored plan the engine's ``paged_impl`` is what the caller said
+        ("auto": the launch the backend and the shapes choose)."""
         db = str(tmp_path / "db.json")
         store = PlanStore(db)
-        store.put(_key(), ExecutionPlan(
-            decode_path="paged", paged_kernel="blocked", pages_per_block=4,
-        ))
+        store.put(_key(), ExecutionPlan(decode_path="paged", scan_chunk=4))
         store.save()
-        p = PagedGenerationEngine(TINY, plan_db=db, **ENGINE_KW)
-        assert p.paged_impl == "native_blocked"
-        assert p.pages_per_block == 4
-        assert p.resolved_plan.sources["paged_kernel"] == "db"
+        for path in (str(tmp_path / "no.json"), db):
+            p = PagedGenerationEngine(TINY, plan_db=path, **ENGINE_KW)
+            assert p.paged_impl == "auto"
+            r = PagedGenerationEngine(
+                TINY, plan_db=path, paged_impl="reference", **ENGINE_KW)
+            assert r.paged_impl == "reference"
+        assert "paged_impl" not in TUNABLE_FIELDS
 
-    def test_paged_kernel_explicit_impl_beats_db(self, tmp_path):
-        db = str(tmp_path / "db.json")
-        store = PlanStore(db)
-        store.put(_key(), ExecutionPlan(
-            decode_path="paged", paged_kernel="blocked", pages_per_block=4,
-        ))
-        store.save()
-        # a native-variant pin maps into the plan field and wins
-        p = PagedGenerationEngine(
-            TINY, plan_db=db, paged_impl="native", **ENGINE_KW
-        )
-        assert p.paged_impl == "native"
-        assert p.resolved_plan.sources["paged_kernel"] == "user"
-        # a plan-unrepresentable pin ("reference") must not be retuned
-        # out from under the caller either
-        r = PagedGenerationEngine(
-            TINY, plan_db=db, paged_impl="reference", **ENGINE_KW
-        )
-        assert r.paged_impl == "reference"
-        # explicit pages_per_block — including 0 — beats the stored 4
-        z = PagedGenerationEngine(
-            TINY, plan_db=db, pages_per_block=0, **ENGINE_KW
-        )
-        assert z.pages_per_block == 0
-        assert z.resolved_plan.sources["pages_per_block"] == "user"
+    def test_a_database_written_with_the_paged_kernel_fields_is_retuned(
+            self, tmp_path, caplog):
+        """The version before this one stored ``paged_kernel`` and
+        ``pages_per_block``: such a file is a version mismatch (warn, empty,
+        static defaults), never a ``TypeError`` at an engine's construction."""
+        db = tmp_path / "db.json"
+        db.write_text(json.dumps({
+            "schema_version": SCHEMA_VERSION - 1,
+            "entries": {_key(): {"plan": {
+                "decode_path": "paged", "scan_chunk": 4,
+                "paged_kernel": "blocked", "pages_per_block": 4}}},
+        }))
+        assert SCHEMA_VERSION == 2
+        with caplog.at_level("WARNING"):
+            assert PlanStore(str(db)).entries == {}
+        assert "schema_version" in caplog.text and "re-run" in caplog.text
+        p = PagedGenerationEngine(TINY, plan_db=str(db), **ENGINE_KW)
+        assert p.scan_chunk == 0 and p.resolved_plan.source != "db"
 
-    def test_paged_kernel_field_validation(self):
-        with pytest.raises(ValueError, match="paged_kernel"):
-            ExecutionPlan(paged_kernel="bogus")
-        with pytest.raises(ValueError, match="pages_per_block"):
-            ExecutionPlan(pages_per_block=-1)
-        # round-trips through the store vocabulary
-        p = ExecutionPlan(
-            decode_path="paged", paged_kernel="blocked", pages_per_block=8
-        )
-        assert ExecutionPlan.from_dict(p.to_dict()) == p
+    def test_an_entry_that_still_names_a_paged_kernel_is_read_without_it(
+            self, tmp_path):
+        """Inside one schema version ``from_dict`` drops a key it does not
+        know: the rest of the entry is the plan."""
+        db = tmp_path / "db.json"
+        _write_db(str(db), {_key(): {"plan": {
+            "decode_path": "paged", "scan_chunk": 4,
+            "paged_kernel": "blocked", "pages_per_block": 4}}})
+        plan = PlanStore(str(db)).get(_key())
+        assert plan == ExecutionPlan(decode_path="paged", scan_chunk=4)
+        assert ExecutionPlan.from_dict(plan.to_dict()) == plan
+        assert not {"paged_kernel", "pages_per_block"} & set(plan.to_dict())
 
-    def test_candidate_plans_prune_meaningless_kernel_combos(self):
+    @pytest.mark.parametrize("gone", ["paged_kernel", "pages_per_block"])
+    def test_neither_the_plan_nor_an_engine_takes_a_paged_kernel_field(self, gone):
+        value = "blocked" if gone == "paged_kernel" else 4
+        assert gone not in TUNABLE_FIELDS
+        with pytest.raises(TypeError, match=gone):
+            ExecutionPlan(**{gone: value})
+        if gone == "pages_per_block":
+            with pytest.raises(TypeError, match=gone):
+                PagedGenerationEngine(TINY, pages_per_block=4, **ENGINE_KW)
+
+    def test_candidate_plans_enumerate_no_kernel_variant(self):
         from distrl_llm_tpu.autotune import candidate_plans
 
+        with pytest.raises(TypeError, match="paged_kernels"):
+            candidate_plans(paged_kernels=(None, "blocked"))
         plans = candidate_plans(
-            decode_paths=("dense", "paged"),
-            scan_chunks=(0,),
-            paged_kernels=(None, "folded", "blocked"),
-            pages_per_blocks=(0, 4),
+            decode_paths=("dense", "paged"), scan_chunks=(0, 4),
+            cb_modes=(None, "continuous"),
         )
-        assert all(
-            p.paged_kernel is None for p in plans
-            if p.decode_path == "dense"
-        )
-        assert all(
-            p.paged_kernel == "blocked" for p in plans
-            if p.pages_per_block
-        )
-        # the paged path enumerates every kernel and the blocked sizes
-        paged = [p for p in plans if p.decode_path == "paged"]
-        assert {(p.paged_kernel, p.pages_per_block) for p in paged} == {
-            (None, 0), ("folded", 0), ("blocked", 0), ("blocked", 4),
-        }
+        # one candidate a (path, chunk, admission regime) that can run: the
+        # dense path has no admission scheduler
+        assert [(p.decode_path, p.scan_chunk, p.cb_mode) for p in plans] == [
+            ("dense", 0, None), ("dense", 4, None),
+            ("paged", 0, None), ("paged", 0, "continuous"),
+            ("paged", 4, None), ("paged", 4, "continuous"),
+        ]
 
     def test_generation_identical_with_and_without_empty_db(self, tmp_path):
         """The empty-DB fallback path produces byte-identical output to an

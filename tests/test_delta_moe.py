@@ -82,8 +82,14 @@ def weights():
     return seeded(CFG)
 
 
+#: the reference's whole program, traced once a configuration and a shape
+#: and not once a call (a test asks for it a row group at a time)
+_reference = jax.jit(
+    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
+
+
 def reference_logprobs(params, lora, ids, mask, cfg=CFG):
-    return np.asarray(ref.next_token_logprobs(
+    return np.asarray(_reference(
         params, cfg, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
         lora_scale=LORA_SCALE))
 
@@ -418,12 +424,10 @@ def worst_difference(params, lora, ids, mask, result):
     worst = 0.0
     for b in range(ids.shape[0]):
         prompt = ids[b][mask[b] > 0]
-        for j in range(result.tokens.shape[1]):
-            row = np.concatenate([prompt, result.tokens[b, j]])
-            want = reference_logprobs(
-                params, lora, row[None], np.ones((1, len(row)), np.int32))[0]
-            worst = max(worst, np.abs(
-                result.logprobs[b, j] - want[len(prompt) - 1:]).max())
+        rows = np.stack([np.concatenate([prompt, result.tokens[b, j]])
+                         for j in range(result.tokens.shape[1])])
+        want = reference_logprobs(params, lora, rows, np.ones_like(rows))
+        worst = max(worst, np.abs(result.logprobs[b] - want[:, len(prompt) - 1:]).max())
     return worst
 
 

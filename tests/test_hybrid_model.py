@@ -69,8 +69,14 @@ def weights():
     return params, lora
 
 
+#: the reference's whole program, traced once a shape and not once a call
+#: (a test asks for it a row group at a time)
+_reference = jax.jit(
+    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
+
+
 def reference_logprobs(params, lora, ids, mask):
-    return np.asarray(ref.next_token_logprobs(
+    return np.asarray(_reference(
         params, CFG, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
         lora_scale=LORA_SCALE))
 

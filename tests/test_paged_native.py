@@ -24,8 +24,6 @@ from distrl_llm_tpu.ops.paged_native import (
     live_page_walk,
     native_pages_per_step,
     paged_attention_native,
-    paged_attention_native_blocked,
-    paged_attention_native_folded,
 )
 
 KERNELS = {
@@ -35,12 +33,6 @@ KERNELS = {
     # ... and with rows of several blocks, ragged on most of the shared cases
     "native_ppb2": functools.partial(
         paged_attention_native, pages_per_block=2
-    ),
-    "folded": paged_attention_native_folded,
-    # grid-collapsed kernel at a block size that leaves ragged tails on
-    # most of the shared parity cases (pps ∈ {1, 2, 3})
-    "blocked2": functools.partial(
-        paged_attention_native_blocked, pages_per_block=2
     ),
 }
 
@@ -60,8 +52,8 @@ def _setup(b, h, kh, hd, ps, pps, seed=0, lengths=None):
 
 @pytest.fixture(params=sorted(KERNELS))
 def _native(request):
-    """Every launch shares every parity case: they differ in grid and block
-    shape, and in how many pages one softmax update covers."""
+    """Both block sizes share every parity case: they differ in grid and
+    block shape, and in how many pages one softmax update covers."""
     kernel = KERNELS[request.param]
 
     def call(q, kp, vp, lengths, table, **kw):
@@ -160,12 +152,16 @@ class TestNativePagedParity:
             )
 
 
-# the decode geometries "auto" serves: the benchmark's rollout cell
-# (Qwen2.5-7B: 28 / 4 heads of 128, page 128, five pages a row) and the CLI's
-# small model (Qwen2.5-0.5B: 14 / 2 heads of 64)
+# the decode geometries "auto" serves: the benchmark's rollout cells
+# (Qwen2.5-7B: 28 / 4 heads of 128, page 128, five pages a row; Jamba2-3B's
+# two attention layers: ONE kv head under a group of 20; Solar-Open2's
+# softmax layer: 64 / 8) and the CLI's small model (Qwen2.5-0.5B: 14 / 2
+# heads of 64)
 AUTO_GEOMETRIES = {
     "cell-28x4x128": dict(h=28, kh=4, hd=128),
+    "jamba-20x1x128": dict(h=20, kh=1, hd=128),
     "qwen05b-14x2x64": dict(h=14, kh=2, hd=64),
+    "solar-64x8x128": dict(h=64, kh=8, hd=128),
 }
 PAGE, PPS = 128, 5
 #: every length the walk has a case for: an empty slot, one token, a page to
@@ -238,6 +234,12 @@ class TestAutoLaunch:
             ("cell-28x4x128", "int8", 64, 3),  # the compact scales' lane tiles
             ("qwen05b-14x2x64", "bf16", 5, 5),
             ("qwen05b-14x2x64", "bf16", 64, 8),
+            # one kv head: the cap of 8 pages long before the VMEM budget
+            ("jamba-20x1x128", "bf16", 19, 8),  # the cell's table: 3 steps a row
+            ("jamba-20x1x128", "int8", 19, 8),
+            # eight kv heads: the budget holds four pages of them, two as int8
+            ("solar-64x8x128", "bf16", 22, 4),  # the cell's table: 6 steps a row
+            ("solar-64x8x128", "int8", 22, 1),
         ],
     )
     def test_pages_per_step_come_from_the_shapes(self, geom, pages, pps, want):
@@ -272,11 +274,12 @@ class TestAutoLaunch:
         assert changes <= int(live.sum())
 
 
-class TestBlockedKernel:
-    """Grid-collapsed multi-page kernel (ISSUE 3): interpret parity at the
-    real on-chip geometries, ragged-tail handling for every pps % ppb
-    combination, ppb=1 bit-identity with the one-page folded kernel, and
-    the analytic grid-step budget the whole PR exists to win."""
+class TestBlocksOfARow:
+    """``paged_attention_native`` with its block named, so that a row is
+    several grid steps at small sizes: a ragged final block for every pps %
+    ppb, a block wider than the table, the int8 container, empty slots; and
+    the analytic grid-step count the engines record against the launch's
+    real grid."""
 
     @pytest.mark.parametrize("ppb", [1, 2, 4, 8])
     def test_r5_geometry_parity_nondivisor_tail(self, ppb):
@@ -285,7 +288,7 @@ class TestBlockedKernel:
         q, kp, vp, lengths, table = _setup(
             b=4, h=14, kh=2, hd=64, ps=8, pps=13
         )
-        got = paged_attention_native_blocked(
+        got = paged_attention_native(
             q * 64**-0.5, kp, vp, lengths, table,
             pages_per_block=ppb, interpret=True,
         )
@@ -299,7 +302,7 @@ class TestBlockedKernel:
         q, kp, vp, lengths, table = _setup(
             b=3, h=28, kh=4, hd=128, ps=8, pps=3, seed=7
         )
-        got = paged_attention_native_blocked(
+        got = paged_attention_native(
             q * 128**-0.5, kp, vp, lengths, table,
             pages_per_block=8, interpret=True,
         )
@@ -313,7 +316,7 @@ class TestBlockedKernel:
         q, kp, vp, lengths, table = _setup(b=4, h=14, kh=2, hd=64, ps=8, pps=5)
         kq = quantize_pages(jnp.asarray(kp, jnp.bfloat16))
         vq = quantize_pages(jnp.asarray(vp, jnp.bfloat16))
-        got = paged_attention_native_blocked(
+        got = paged_attention_native(
             q.astype(jnp.bfloat16) * 64**-0.5, kq.weight, vq.weight,
             lengths, table, k_scales=kq.scales, v_scales=vq.scales,
             pages_per_block=ppb, interpret=True,
@@ -326,75 +329,72 @@ class TestBlockedKernel:
             atol=3e-2, rtol=3e-2,
         )
 
-    def test_ppb1_bit_identical_to_one_page_folded(self):
-        """pages_per_block=1 IS the one-page (kv-folded) kernel: same grid,
-        same op order — outputs must match bit for bit, making the blocked
-        kernel a strict generalization rather than a reimplementation."""
-        for seed, pps in ((0, 1), (1, 3), (2, 13)):
-            q, kp, vp, lengths, table = _setup(
-                b=3, h=14, kh=2, hd=64, ps=8, pps=pps, seed=seed
-            )
-            fold = paged_attention_native_folded(
-                q * 64**-0.5, kp, vp, lengths, table, interpret=True
-            )
-            blk = paged_attention_native_blocked(
-                q * 64**-0.5, kp, vp, lengths, table,
-                pages_per_block=1, interpret=True,
-            )
-            np.testing.assert_array_equal(np.asarray(fold), np.asarray(blk))
-
     def test_dead_rows_emit_zeros_not_nan(self):
         q, kp, vp, _, table = _setup(b=3, h=4, kh=2, hd=64, ps=8, pps=5)
         lengths = jnp.asarray([10, 0, 37], jnp.int32)
-        got = np.asarray(paged_attention_native_blocked(
+        got = np.asarray(paged_attention_native(
             q * 64**-0.5, kp, vp, lengths, table,
             pages_per_block=4, interpret=True,
         ))
         assert np.isfinite(got).all()
         np.testing.assert_array_equal(got[1], 0.0)
 
+    @pytest.mark.parametrize("pages", ["bf16", "int8"])
+    @pytest.mark.parametrize("geom,pps", [
+        ("cell-28x4x128", 5), ("jamba-20x1x128", 19), ("solar-64x8x128", 22),
+        ("qwen05b-14x2x64", 13),
+    ])
+    def test_the_count_of_grid_steps_is_the_launchs_grid(self, geom, pps, pages,
+                                                         monkeypatch):
+        """``paged_grid_steps("native", ...)`` against the grid the launch
+        hands Pallas at the same shapes, read where it is handed over."""
+        from distrl_llm_tpu.ops import paged_native
+        from distrl_llm_tpu.ops.paged import init_quantized_pages, paged_grid_steps
+
+        g, rows, seen = AUTO_GEOMETRIES[geom], 3, []
+        spec = paged_native.pltpu.PrefetchScalarGridSpec
+        monkeypatch.setattr(
+            paged_native.pltpu, "PrefetchScalarGridSpec",
+            lambda **kw: seen.append(kw["grid"]) or spec(**kw))
+        shape = (g["kh"], rows * pps, PAGE, g["hd"])
+        if pages == "int8":
+            pool = init_quantized_pages(shape)
+            kw = dict(k_scales=pool.scales, v_scales=pool.scales)
+            pool = pool.weight
+        else:
+            pool, kw = jnp.zeros(shape, jnp.bfloat16), {}
+        jax.eval_shape(
+            functools.partial(paged_attention_native.__wrapped__, interpret=True),
+            jnp.zeros((rows, g["h"], g["hd"]), jnp.bfloat16), pool, pool,
+            jnp.zeros((rows,), jnp.int32),
+            jnp.zeros((rows, pps), jnp.int32), **kw)
+        (grid,) = seen
+        assert grid[0] * grid[1] == paged_grid_steps(
+            "native", batch=rows, num_kv_heads=g["kh"], pps=pps,
+            head_dim=g["hd"], page_size=PAGE,
+            kv_itemsize=1 if pages == "int8" else 2, quantized=pages == "int8")
+
     def test_grid_step_budget_r5_geometry(self):
-        """The acceptance criterion: ≥ 8× fewer grid steps than the
-        one-page kernel at the benched r5 paged geometry (480 rows × 2 kv
-        × 13 pages: about 300k grid steps a decode step; PERF.md §7)."""
+        """At the benched r5 paged geometry (480 rows × 2 kv × 13 pages) a
+        (B, K, pps) grid of one page of one head a step is about 12k grid
+        steps a call, 300k a decode step (PERF.md §7): the launch sizes its
+        own block, 8 pages of 128 x 64 over both heads, an eighth and less."""
         from distrl_llm_tpu.ops.paged import paged_grid_steps
 
         r5 = dict(batch=480, num_kv_heads=2, pps=13)
-        one_page = 480 * 2 * 13  # the (B, K, pps) grid "native" was
-        blocked = paged_grid_steps(
-            "native_blocked", pages_per_block=8, **r5
-        )
-        assert blocked == 480 * 2  # ceil(13/8) = 2 blocks per row
-        assert blocked * 8 <= one_page
-        # folded sits between: the kv fold alone halves the count here
-        assert paged_grid_steps("native_folded", **r5) == 480 * 13
-        # what "auto" launches now sizes its own block: 8 pages of 128 x 64
-        assert paged_grid_steps(
-            "native", head_dim=64, page_size=128, **r5) == blocked
+        steps = paged_grid_steps("native", head_dim=64, page_size=128, **r5)
+        assert steps == 480 * 2  # ceil(13/8) = 2 blocks per row
+        assert steps * 8 <= 480 * 2 * 13
 
     def test_grid_step_model_shapes(self):
-        from distrl_llm_tpu.ops.paged import (
-            DEFAULT_PAGES_PER_BLOCK, paged_grid_steps,
-        )
+        from distrl_llm_tpu.ops.paged import paged_grid_steps
 
         g = dict(batch=8, num_kv_heads=2, pps=12)
-        # ceil semantics + clamping: ppb > pps collapses to one block
-        assert paged_grid_steps(
-            "native_blocked", pages_per_block=5, **g) == 8 * 3
-        assert paged_grid_steps(
-            "native_blocked", pages_per_block=100, **g) == 8
-        # 0 = the kernel default
-        assert paged_grid_steps("native_blocked", **g) == 8 * -(
-            -12 // DEFAULT_PAGES_PER_BLOCK
-        )
         # native: (B, ceil(pps / its own pages a step)), from the shapes and
-        # the pages' dtype, whatever pages_per_block a plan names; the
-        # benchmark's cell is one step a row; the reference has no grid
+        # the pages' dtype; the benchmark's cell is one step a row; the
+        # reference has no grid
         assert paged_grid_steps(
             "native", head_dim=64, page_size=16, **g) == 8 * 2
-        assert paged_grid_steps(
-            "native", head_dim=64, page_size=16, pages_per_block=1, **g
-        ) == 8 * 2
         assert paged_grid_steps(
             "native", batch=64, num_kv_heads=4, pps=5, head_dim=128,
             page_size=128) == 64
@@ -404,15 +404,57 @@ class TestBlockedKernel:
         with pytest.raises(ValueError, match="head_dim"):
             paged_grid_steps("native", **g)
         assert paged_grid_steps("reference", **g) == 0
-        # jaxlib's kernel walks pages inside a (1, B, K) grid
-        assert paged_grid_steps("kernel", **g) == 8 * 2
 
-    def test_validation(self):
+
+class TestTheLaunchTheBackendChooses:
+    """``resolve_paged_impl``: the three spellings a caller may name, and
+    what "auto" is: one launch a backend, whatever the shapes (PERF.md §6,
+    PR 47)."""
+
+    @pytest.mark.parametrize("gone", ["kernel", "native_folded", "native_blocked"])
+    def test_a_deleted_spelling_is_refused_by_name(self, gone):
+        from distrl_llm_tpu.ops.paged import (
+            PAGED_IMPLS, paged_attention_op, resolve_paged_impl,
+        )
+
+        assert PAGED_IMPLS == ("auto", "reference", "native")
+        with pytest.raises(ValueError, match=gone) as err:
+            resolve_paged_impl(gone)
+        assert all(name in str(err.value) for name in PAGED_IMPLS)
         q, kp, vp, lengths, table = _setup(b=2, h=4, kh=2, hd=64, ps=8, pps=2)
-        with pytest.raises(ValueError, match="pages_per_block"):
-            paged_attention_native_blocked(
-                q, kp, vp, lengths, table, pages_per_block=0, interpret=True
-            )
+        with pytest.raises(ValueError, match=gone):
+            paged_attention_op(q, kp, vp, lengths, table, impl=gone)
+
+    @pytest.mark.parametrize("backend,want", [("tpu", "native"), ("cpu", "reference")])
+    @pytest.mark.parametrize("h,kh,hd,pps", [
+        (28, 4, 128, 5), (20, 1, 128, 19), (64, 8, 128, 22),  # the cells' tables
+        (28, 4, 128, 64), (20, 1, 128, 72), (14, 2, 64, 64),  # rows of 8k-9k
+    ])
+    def test_auto_is_one_launch_a_backend(
+            self, backend, want, h, kh, hd, pps, monkeypatch):
+        """What "auto" hands the step at the cells' geometries and at tables
+        three times as wide: the native adapter on a TPU backend, the
+        reference elsewhere, and the record under the geometry's key."""
+        from distrl_llm_tpu.ops import paged
+
+        ran = []
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(
+            paged, "_native_call",
+            lambda q, *a, quantized: ran.append("native") or q)
+        monkeypatch.setattr(
+            paged, "paged_attention_reference",
+            lambda q, *a: ran.append("reference") or q)
+        assert paged.resolve_paged_impl("auto") == want
+        for named in ("reference", "native"):
+            assert paged.resolve_paged_impl(named) == named
+        q, kp, vp, lengths, table = _setup(b=2, h=h, kh=kh, hd=hd, ps=8, pps=pps)
+        paged.paged_attention_op(q, kp, vp, lengths, table)
+        assert ran == [want]
+        assert paged.dispatch_choices[paged.dispatch_choice_key(
+            quantized=False, num_kv_heads=kh, num_groups=h // kh,
+            head_dim=hd, page_size=8, pps=pps,
+        )] == want
 
 
 class TestVerifyKernel:
@@ -494,9 +536,29 @@ class TestVerifyKernel:
             np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
     def test_s1_matches_blocked_decode_at_length_plus_one(self):
-        """A 1-token 'draft block' is a decode step over length+1 keys: the
-        verify kernel must agree with the blocked decode kernel exactly
-        (same op order, same online-softmax carry)."""
+        """A 1-token 'draft block' is a decode step over length+1 keys: at
+        one page a grid step the verify kernel must agree with the decode
+        kernel exactly (same op order, same online-softmax carry)."""
+        q, kp, vp, lengths, table = self._setup_verify(
+            b=4, s=1, h=14, kh=2, hd=64, ps=8, pps=3)
+        from distrl_llm_tpu.ops.paged_native import (
+            paged_attention_native_verify,
+        )
+
+        got = paged_attention_native_verify(
+            q * 64**-0.5, kp, vp, lengths, table,
+            pages_per_block=1, interpret=True)
+        want = paged_attention_native(
+            q[:, 0] * 64**-0.5, kp, vp, lengths + 1, table,
+            pages_per_block=1, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got[:, 0]), np.asarray(want))
+
+    def test_s1_is_within_rounding_of_native_at_two_pages_a_step(self):
+        """NEW with PR 47, and looser than the case above on purpose: at
+        several pages a grid step ``paged_attention_native`` makes ONE
+        softmax update of them where the verify kernel still chains one a
+        page (the deleted blocked body's order; ROADMAP D5), so the two
+        agree to float32 rounding and not to the bit."""
         q, kp, vp, lengths, table = self._setup_verify(
             b=4, s=1, h=14, kh=2, hd=64, ps=8, pps=3)
         from distrl_llm_tpu.ops.paged_native import (
@@ -506,10 +568,11 @@ class TestVerifyKernel:
         got = paged_attention_native_verify(
             q * 64**-0.5, kp, vp, lengths, table,
             pages_per_block=2, interpret=True)
-        want = paged_attention_native_blocked(
+        want = paged_attention_native(
             q[:, 0] * 64**-0.5, kp, vp, lengths + 1, table,
             pages_per_block=2, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got[:, 0]), np.asarray(want))
+        np.testing.assert_allclose(
+            np.asarray(got[:, 0]), np.asarray(want), atol=2e-6, rtol=2e-6)
 
     def test_zero_length_rows_emit_finite(self):
         """Dead refill slots verify garbage over scratch pages — outputs
@@ -530,19 +593,19 @@ class TestVerifyKernel:
         """The acceptance pin: a (d+1)-token verify step at the r5 geometry
         costs ONE blocked sweep — B·ceil(pps/ppb) — not (d+1) sweeps."""
         from distrl_llm_tpu.ops.paged import (
-            DEFAULT_PAGES_PER_BLOCK, paged_grid_steps,
+            VERIFY_PAGES_PER_BLOCK, paged_grid_steps,
         )
 
         r5 = dict(batch=480, num_kv_heads=2, pps=13)
-        fused = paged_grid_steps("native_verify", pages_per_block=8, **r5)
-        blocked = paged_grid_steps("native_blocked", pages_per_block=8, **r5)
-        assert fused == blocked == 480 * -(-13 // 8)  # ONE sweep
-        # the unrolled fan-out this PR removes paid (d+1)× per step
-        for d in (2, 4):
-            assert fused * (d + 1) == blocked * (d + 1)
-        # default block size matches the blocked kernel's
-        assert paged_grid_steps("native_verify", **r5) == paged_grid_steps(
-            "native_verify", pages_per_block=DEFAULT_PAGES_PER_BLOCK, **r5)
+        fused = paged_grid_steps("native_verify", **r5)
+        assert VERIFY_PAGES_PER_BLOCK == 8
+        assert fused == 480 * -(-13 // 8)  # ONE sweep
+        # the unrolled fan-out pays the decode launch's count (d+1)× a step
+        decode = paged_grid_steps("native", head_dim=64, page_size=128, **r5)
+        assert fused == decode
+        # a table narrower than the block is one block a row
+        assert paged_grid_steps(
+            "native_verify", batch=480, num_kv_heads=2, pps=5) == 480
 
     def test_validation(self):
         from distrl_llm_tpu.ops.paged_native import (
@@ -595,7 +658,7 @@ class TestVerifyDispatch:
         paged_mod.paged_verify_op(q, kp, vp, lengths, table)
         key = paged_mod.dispatch_choice_key(
             quantized=False, num_kv_heads=2, num_groups=2, head_dim=32,
-            page_size=4, pps=2, impl="auto", pages_per_block=0, verify_len=3)
+            page_size=4, pps=2, impl="auto", verify_len=3)
         assert paged_mod.dispatch_choices[key] == "unrolled"  # CPU backend
         # verify keys never alias the single-query decode record
         assert key[-1] == 3

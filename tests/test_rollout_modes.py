@@ -88,12 +88,17 @@ def _fresh_adapter():
 
 
 @functools.lru_cache(maxsize=None)
-def _sync_run(clip: float, repeat: int = 0, env: str | None = None):
-    """(losses, adapter checksum, mean behavior logprobs) of one sync run.
-    ``repeat`` only keys the cache: another value is another, independent
-    run of the same configuration."""
+def _sync_trained(clip: float, repeat: int = 0, env: str | None = None):
+    """(trainer, sink, engine) of one sync run, for the cases that only read
+    what a run leaves behind. ``repeat`` only keys the cache: another value
+    is another, independent run of the same configuration."""
     kw = {} if env is None else {"env": env}
-    trainer, sink, _ = _run_tiny(clip_ratio=clip, **kw)
+    return _run_tiny(clip_ratio=clip, **kw)
+
+
+def _sync_run(clip: float, repeat: int = 0, env: str | None = None):
+    """(losses, adapter checksum, mean behavior logprobs) of one sync run."""
+    trainer, sink, _ = _sync_trained(clip, repeat, env)
     recs = [m for _, m in sink.records if "loss" in m]
     return (
         tuple(m["loss"] for m in recs),
@@ -125,7 +130,7 @@ class TestSyncByteIdentity:
             assert checksum != _sync_run(0.0)[1]
 
     def test_sync_records_carry_regime_fields(self):
-        trainer, sink, _ = _run_tiny()
+        trainer, sink, _ = _sync_trained(TrainConfig().clip_ratio)
         recs = [m for _, m in sink.records if "loss" in m]
         assert all(m["rollout_mode"] == "sync" for m in recs)
         assert all(m["max_staleness"] == 0 for m in recs)
@@ -148,12 +153,12 @@ class TestEnvRouting:
         assert explicit[1] == default[1]
 
     def test_math_env_never_arms_driver_or_hook(self):
-        trainer, _, engine = _run_tiny(env="math")
+        trainer, _, engine = _sync_trained(TrainConfig().clip_ratio, env="math")
         assert trainer._env_driver is None
         assert getattr(engine, "turn_hook", None) is None
 
     def test_math_records_carry_no_env_metrics(self):
-        _, sink, _ = _run_tiny(env="math")
+        _, sink, _ = _sync_trained(TrainConfig().clip_ratio, env="math")
         recs = [m for _, m in sink.records if "loss" in m]
         assert recs and not any(
             k.startswith("env/") for m in recs for k in m

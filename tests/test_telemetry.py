@@ -211,20 +211,20 @@ class TestRegistry:
             quantized=False, num_kv_heads=TINY.num_kv_heads,
             num_groups=TINY.num_heads // TINY.num_kv_heads,
             head_dim=TINY.head_dim, page_size=8, pps=pps,
-            impl="auto", pages_per_block=0,
+            impl="auto",
         )
         # a same-geometry engine pinned to a DIFFERENT kernel keys apart
-        blocked_key = dispatch_choice_key(
+        pinned_key = dispatch_choice_key(
             quantized=False, num_kv_heads=TINY.num_kv_heads,
             num_groups=TINY.num_heads // TINY.num_kv_heads,
             head_dim=TINY.head_dim, page_size=8, pps=pps,
-            impl="native_blocked", pages_per_block=0,
+            impl="reference",
         )
-        assert blocked_key != own_key
+        assert pinned_key != own_key
         monkeypatch.setattr(
             paged_ops, "dispatch_choices",
-            {("stale", "other", "geometry"): "native_blocked",
-             blocked_key: "native_blocked",
+            {("stale", "other", "geometry"): "reference",
+             pinned_key: "reference",
              own_key: "native"},
         )
         # native at 8 rows: 8 × the blocks a row, the block sized from this

@@ -28,6 +28,8 @@ from jax.sharding import SingleDeviceSharding
 QWEN_0_5B = dict(heads=14, kv_heads=2, head_dim=64)  # QWEN2_0_5B attention
 HD128 = dict(heads=32, kv_heads=8, head_dim=128)  # Llama-3-8B attention
 QWEN_7B = dict(heads=28, kv_heads=4, head_dim=128)  # the benchmark's cells
+JAMBA = dict(heads=20, kv_heads=1, head_dim=128)  # rollout-wide-480: ONE kv head
+SOLAR = dict(heads=64, kv_heads=8, head_dim=128)  # rollout-reasoning's softmax layer
 VOCAB = 151936
 ROWS = 64
 
@@ -84,10 +86,10 @@ def kv_pages(chip, shape, quantized: bool):
     [
         ("native", QWEN_0_5B),  # what "auto" is on a TPU backend
         ("native", HD128),
-        ("native_folded", QWEN_0_5B),  # what a stored plan may name
-        ("native_blocked", QWEN_0_5B),
+        ("native", JAMBA),
+        ("native", SOLAR),
     ],
-    ids=["native-hd64", "native-hd128", "folded-hd64", "blocked-hd64"],
+    ids=["native-hd64", "native-hd128", "native-20x1x128", "native-64x8x128"],
 )
 def test_paged_decode(chip, impl, geom, quantized):
     from distrl_llm_tpu.ops.paged import paged_attention_op
@@ -144,24 +146,28 @@ def test_auto_decode_kernel_is_what_the_benchmark_reads(
     assert re.search(spec["args"]["regex"], op_name(calls[0])), op_name(calls[0])
 
 
-def test_jaxlib_launch_where_it_applies(chip):
-    """``impl="kernel"`` (jaxlib's own launch, only ever named explicitly)
-    compiles at head_dim 128; at head_dim 64 Mosaic refuses its block specs,
-    and the refusal reaches the caller."""
-    from distrl_llm_tpu.ops.paged import paged_attention_op
+@pytest.mark.parametrize("pps", [48, 72])
+def test_auto_is_the_native_launch_on_long_rows(chip, pps, monkeypatch):
+    """The rollout cell's heads (28 / 4 of 128, page 128) over rows of 6k and
+    9k tokens, wider than any cell's table: "auto" compiles as
+    ``paged_attention_native`` there too, and the dispatch record says so."""
+    from distrl_llm_tpu.ops import paged
 
-    page_size, pps = 16, 64
-
-    def args(geom):
-        h, kh, hd = geom["heads"], geom["kv_heads"], geom["head_dim"]
-        pages = kv_pages(chip, (kh, ROWS * pps, page_size, hd), False)
-        return (chip((ROWS, h, hd), jnp.bfloat16), pages, pages,
-                chip((ROWS,), jnp.int32), chip((ROWS, pps), jnp.int32))
-
-    kernel = functools.partial(paged_attention_op, impl="kernel")
-    assert_kernel(kernel, *args(HD128))
-    with pytest.raises(ValueError, match="block shape"):
-        jax.jit(kernel).lower(*args(QWEN_0_5B))
+    # "auto" asks the backend, which is the CPU in a compile for a described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, page_size = 8, 128
+    h, kh, hd = QWEN_7B["heads"], QWEN_7B["kv_heads"], QWEN_7B["head_dim"]
+    pages = kv_pages(chip, (kh, rows * pps, page_size, hd), False)
+    text = assert_kernel(
+        paged.paged_attention_op,
+        chip((rows, h, hd), jnp.bfloat16), pages, pages,
+        chip((rows,), jnp.int32), chip((rows, pps), jnp.int32),
+    )
+    assert "paged_attention_native" in text
+    assert paged.dispatch_choices[paged.dispatch_choice_key(
+        quantized=False, num_kv_heads=kh, num_groups=h // kh, head_dim=hd,
+        page_size=page_size, pps=pps,
+    )] == "native"
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])

@@ -247,13 +247,6 @@ def cmd_measure(args) -> int:
             (None if x in ("", "auto") else x)
             for x in args.top_p_impls.split(",")
         ),
-        paged_kernels=tuple(
-            (None if x in ("", "auto") else x)
-            for x in args.paged_kernels.split(",")
-        ),
-        pages_per_blocks=tuple(
-            int(x) for x in args.pages_per_blocks.split(",")
-        ),
         spec_draft_lens=tuple(
             int(x) for x in args.spec_draft_lens.split(",")
         ),
@@ -290,12 +283,8 @@ def cmd_measure(args) -> int:
     for r in results:
         status = f"{r.tok_s:9.1f} tok/s" if r.feasible else "INFEASIBLE"
         note = f"  [{r.note}]" if r.note else ""
-        kern = r.plan.paged_kernel or "auto"
-        if r.plan.paged_kernel == "blocked":
-            kern += f":{r.plan.pages_per_block or 'default'}"
         print(f"  {status}  path={r.plan.decode_path} "
               f"chunk={r.plan.scan_chunk} "
-              f"kernel={kern} "
               f"top_p={r.plan.top_p_impl or 'auto'}"
               f" (warmup {r.warmup_s:.2f}s, steady {r.steady_s:.3f}s)"
               f"{note}")
@@ -356,13 +345,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="comma list of scan_chunk candidates (0 = host loop)")
     m.add_argument("--top-p-impls", dest="top_p_impls", default="auto",
                    help="comma list of top-p impls ('auto' = derive)")
-    m.add_argument("--paged-kernels", dest="paged_kernels", default="auto",
-                   help="comma list from auto,one_page,folded,blocked "
-                        "('auto' = the engine's probe chain; paged/"
-                        "speculative paths only)")
-    m.add_argument("--pages-per-block", dest="pages_per_blocks", default="0",
-                   help="comma list of blocked-kernel page collapses "
-                        "(0 = kernel default; only with blocked)")
     m.add_argument("--spec-draft-lens", dest="spec_draft_lens", default="0,4",
                    help="comma list of speculative draft lengths (0 rides "
                         "the non-speculative paths; >0 only pairs with the "

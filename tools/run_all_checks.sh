@@ -34,11 +34,6 @@ stage "telemetry_smoke" env JAX_PLATFORMS=cpu \
 # round-trip, deterministic resolve, kwarg override, corrupt-DB fallback
 stage "autotune_smoke" env JAX_PLATFORMS=cpu \
   timeout 600 python tools/autotune_smoke.py
-# blocked paged-kernel gate (ISSUE 3): interpret parity incl. ragged tail,
-# ppb=1 bit-identity with the folded kernel, and the ≥8× grid-step budget
-# at the r5 geometry — catches grid-count regressions without silicon
-stage "paged_blocked_smoke" env JAX_PLATFORMS=cpu \
-  timeout 600 python tools/paged_blocked_smoke.py
 # async-rollout gate (ISSUE 4): sync/pipelined/async tiny runs through the
 # real engine — finite losses, buffer/staleness telemetry in the trace, and
 # the trace_report rollout section
