@@ -190,8 +190,11 @@ def _count_mixer_stats(mixer) -> dict:
     power-retention states the decode steps read and wrote, filed as the bytes
     of S and z that is, a read and a write each; ``mixer["ssm_stats"]``: the
     (live row, layer) state-space states they read and wrote, filed as that
-    count) with telemetry. Returns what the round's span says of them:
-    ``power_state_bytes`` / ``ssm_states_stepped``, where there are any."""
+    count; ``mixer["window_stats"]``: the window layers' keys attended and the
+    keys a full layer would attend, in units of 128) with telemetry. Returns
+    what the round's span says of them: ``power_state_bytes`` /
+    ``ssm_states_stepped`` / ``window_pages_attended`` and ``_visible``, where
+    there are any."""
     said = {}
     if mixer is not None and "power_stats" in mixer:
         a_state = sum(x.nbytes // x.shape[0]
@@ -203,6 +206,11 @@ def _count_mixer_stats(mixer) -> dict:
         stepped = int(np.asarray(mixer["ssm_stats"])[0])
         telemetry.counter_add(ENGINE_SSM_STATES_STEPPED, stepped)
         said["ssm_states_stepped"] = stepped
+    if mixer is not None and "window_stats" in mixer:
+        attended, visible = (int(x) for x in np.asarray(mixer["window_stats"]))
+        telemetry.counter_add(telemetry.ENGINE_WINDOW_PAGES_ATTENDED, attended)
+        telemetry.counter_add(telemetry.ENGINE_WINDOW_PAGES_VISIBLE, visible)
+        said.update(window_pages_attended=attended, window_pages_visible=visible)
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),

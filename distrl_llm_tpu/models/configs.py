@@ -52,6 +52,18 @@ pages; published name "attention") and "mamba" (Mamba-1,
 ``ops/selective_scan.py``: a float32 state ``[d_state, d_inner]`` and the last
 ``d_conv - 1`` tokens of its convolution's input a slot), each followed by the
 dense gated MLP: its "softmax" layers have no routed experts.
+
+A window model with routed experts (``exaone_moe``, K-EXAONE-236B-A23B) has
+two mixers, placed by the published ``layer_types``: "window"
+(``sliding_attention``: GQA with a per-head RMSNorm of q and k and RoPE, token
+t attending the last ``sliding_window`` tokens, itself included; a slot keeps
+a RING of that many tokens' K and V and no page) and "softmax"
+(``full_attention``: the same norm, NO positional encoding, K/V pages). The
+second half of a layer is read from ``mlp_layer_types`` A LAYER (``mlp_types``):
+``sparse`` is routed experts beside one shared expert, under a share as above;
+``dense`` is the gated MLP of ``intermediate_size``, and its layer's kind
+carries the suffix ``_dense`` (its stack has other leaves). ``sliding_window``
+is then the ring's length and refuses nothing.
 """
 
 from __future__ import annotations
@@ -67,11 +79,21 @@ MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
                "power-retention": "power",
                # jamba publishes a period and an offset; from_hf_config names
                # every layer. Its attention is a "softmax" layer with a dense MLP
-               "attention": "softmax", "mamba": "mamba"}
+               "attention": "softmax", "mamba": "mamba",
+               # exaone_moe publishes ``layer_types``
+               "sliding_attention": "window", "full_attention": "softmax"}
+#: what a kind's name carries where its layer's second half is the dense gated
+#: MLP in a model whose other layers have routed experts (``mlp_types``)
+DENSE_FFN = "_dense"
+
+
+def mixer_of(kind: str) -> str:
+    """A layer kind's MIXER, whatever its second half is."""
+    return kind.removesuffix(DENSE_FFN)
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
-    "solar_open2", "brumby", "jamba",
+    "solar_open2", "brumby", "jamba", "exaone_moe",
 )
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
@@ -84,6 +106,7 @@ _STATE_NAMES = {
     "power": "a float32 power-retention state and its normaliser a KV head, "
              "and no K/V at all",
     "mamba": "a float32 state-space state and a convolution window",
+    "window": "a ring of the last sliding_window tokens' K and V and no page",
 }
 #: layer kind -> the published name a refusal gives it
 _LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
@@ -163,6 +186,9 @@ class ModelConfig:
     mamba_d_conv: int = 4  # taps of the causal depth-wise convolution
     mamba_expand: int = 2  # d_inner = expand x hidden
     mamba_dt_rank: int = 0  # inner width of the step size's low-rank pair
+    # ---- a layer's second half, a LAYER (exaone_moe's ``mlp_layer_types``, the
+    # published list whole: "dense" | "sparse"). None = the family's own rule
+    mlp_types: tuple[str, ...] | None = None
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
@@ -188,6 +214,17 @@ class ModelConfig:
                     f"num_layers {self.num_layers} exceeds the {len(self.mixer_types)} "
                     "published mixer_types"
                 )
+            if self.mlp_types is not None and (
+                    len(self.mlp_types) != len(self.mixer_types)
+                    or set(self.mlp_types) - {"dense", "sparse"}):
+                raise ValueError(
+                    f"mlp_types holds {sorted(set(self.mlp_types))} over "
+                    f"{len(self.mlp_types)} layers: one of 'dense' / 'sparse' for "
+                    f"each of the {len(self.mixer_types)} published layers")
+            if self.window_moe and not self.sliding_window:
+                raise ValueError(
+                    "sliding_attention layers need sliding_window: the tokens a "
+                    "slot's ring keeps")
             if (self.sparse_block_size % self.sparse_kernel_stride
                     or self.sparse_kernel_size % self.sparse_kernel_stride):
                 raise ValueError(
@@ -223,6 +260,13 @@ class ModelConfig:
             "mamba" in self.mixer_types[: self.num_layers])
 
     @property
+    def window_moe(self) -> bool:
+        """True for a window model (``exaone_moe``): "window" layers beside
+        "softmax" layers, the second half read from ``mlp_types`` a layer."""
+        return self.mixer_types is not None and bool(
+            {"sliding_attention", "full_attention"} & set(self.mixer_types[: self.num_layers]))
+
+    @property
     def mamba_inner(self) -> int:
         """E: channels of a Mamba layer (``d_inner``)."""
         return self.mamba_expand * self.hidden_size
@@ -243,7 +287,8 @@ class ModelConfig:
     def layer_kinds(self) -> tuple[str, ...]:
         """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
         "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
-        "softmax" | "delta" | "power" | "mamba"."""
+        "softmax" | "delta" | "power" | "mamba" | "window"; with ``mlp_types``
+        a layer whose second half is the dense MLP carries ``DENSE_FFN``."""
         if self.latent:
             dense = min(self.first_dense_layers, self.num_layers)
             if not self.n_routed_experts:
@@ -251,7 +296,22 @@ class ModelConfig:
             return ("latent",) * dense + ("latent_moe",) * (self.num_layers - dense)
         if self.mixer_types is None:
             return ("dense",) * self.num_layers
-        return tuple(MIXER_KINDS[m] for m in self.mixer_types[: self.num_layers])
+        kinds = tuple(MIXER_KINDS[m] for m in self.mixer_types[: self.num_layers])
+        if self.mlp_types is None:
+            return kinds
+        return tuple(k + DENSE_FFN * (f == "dense") for k, f in zip(kinds, self.mlp_types))
+
+    def layer_ffn(self, kind: str) -> str:
+        """"dense" | "experts": the second half of a "softmax", "delta",
+        "mamba" or "window" layer of ``kind``. A kind that says so is dense;
+        otherwise the model's routed experts, where it has any."""
+        if kind.endswith(DENSE_FFN) or not self.n_routed_experts:
+            return "dense"
+        return "experts"
+
+    def mixer_count(self, mixer: str) -> int:
+        """Layers that are run whose MIXER is ``mixer``, whatever follows it."""
+        return sum(1 for k in self.layer_kinds if mixer_of(k) == mixer)
 
     @property
     def router_width(self) -> int:
@@ -291,7 +351,7 @@ class ModelConfig:
         """Layers that keep pages in the paged engine's pool."""
         if self.latent or not self.hybrid:
             return self.num_layers
-        return self.kind_count("sparse") + self.kind_count("softmax")
+        return self.mixer_count("sparse") + self.mixer_count("softmax")
 
     def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
         """Shape of one layer's page array: K beside V ``[K, pages, page,
@@ -335,7 +395,7 @@ class ModelConfig:
         """What a slot holds for this model's layers that is no K/V of one
         kind for every layer, for a refusal."""
         return " and ".join(dict.fromkeys(
-            _STATE_NAMES[k] for k in self.layer_kinds if k in _STATE_NAMES))
+            _STATE_NAMES[m] for m in map(mixer_of, self.layer_kinds) if m in _STATE_NAMES))
 
     def refuse_hybrid(self, what: str) -> None:
         """Raise, naming the mixer kinds and the state they keep, where
@@ -396,6 +456,8 @@ class ModelConfig:
         checkpoint's sliding window — full attention ≡ SWA only within it;
         running past it would silently change the model (Mistral v0.1).
         Single owner of the check for the forward and both engines."""
+        if self.window_moe:  # the window layers keep a ring: nothing to refuse
+            return
         if self.sliding_window is not None and key_span > self.sliding_window:
             raise ValueError(
                 f"key window {key_span} exceeds the checkpoint's "
@@ -419,6 +481,8 @@ class ModelConfig:
             return self._latent_param_count(self.experts_per_token)
         if self.delta_moe:
             return self._delta_moe_param_count(self.experts_per_token)
+        if self.window_moe:
+            return self._window_moe_param_count(self.experts_per_token)
         if not self.hybrid:
             return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
         sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
@@ -481,6 +545,21 @@ class ModelConfig:
             + d * self.vocab_size
         )
 
+    def _window_moe_param_count(self, experts: int) -> int:
+        """Matmul parameters of an ``exaone_moe`` model with ``experts`` routed
+        experts counted an expert layer (``_delta_moe_param_count`` says
+        which): q, k, v, o in every layer, then the layer's own second half."""
+        d = self.hidden_size
+        attn = 2 * d * self.q_dim + 2 * d * self.kv_dim
+        moe = 3 * d * (
+            experts * self.moe_intermediate_size + self.shared_expert_size
+        ) + d * self.router_width
+        dense = sum(1 for k in self.layer_kinds if self.layer_ffn(k) == "dense")
+        return (
+            self.num_layers * attn + dense * 3 * d * self.intermediate_size
+            + (self.num_layers - dense) * moe + d * self.vocab_size
+        )
+
     @property
     def total_matmul_param_count(self) -> int:
         """``matmul_param_count`` over every expert HELD, not only those a
@@ -489,6 +568,8 @@ class ModelConfig:
             return self._latent_param_count(self.n_routed_experts)
         if self.delta_moe:
             return self._delta_moe_param_count(self.n_routed_experts)
+        if self.window_moe:
+            return self._window_moe_param_count(self.n_routed_experts)
         return self.matmul_param_count
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
@@ -513,6 +594,11 @@ class ModelConfig:
             # multiply-add, the reduction against C: 6 a state entry, and an exp)
             attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
                 7.0 * self.kind_count("mamba") * self.mamba_inner * self.mamba_d_state)
+        if self.window_moe:
+            # a window layer's token attends at most ``sliding_window`` keys
+            attn = 4.0 * self.q_dim * (
+                self.mixer_count("softmax") * mean_kv_len
+                + self.mixer_count("window") * min(mean_kv_len, self.sliding_window))
         return 2.0 * self.matmul_param_count + attn
 
     def train_flops_per_token(self, seq_len: int) -> float:
@@ -533,6 +619,8 @@ class ModelConfig:
             return "brumby"
         if self.mamba:
             return "jamba"
+        if self.window_moe:
+            return "exaone_moe"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -615,6 +703,8 @@ class ModelConfig:
             hybrid = _power_fields(get)
         if mt == "jamba":
             hybrid = _jamba_fields(get, vars(hf))
+        if mt == "exaone_moe":
+            hybrid = _window_moe_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -626,7 +716,7 @@ class ModelConfig:
             num_heads=num_heads,
             num_kv_heads=get("num_key_value_heads", num_heads),
             head_dim=head_dim,
-            rope_theta=get("rope_theta", 10000.0),
+            rope_theta=hybrid.pop("rope_theta", get("rope_theta", 10000.0)),
             rms_norm_eps=get("rms_norm_eps", 1e-6),
             attention_bias=mt == "qwen2" or bool(get("attention_bias", False)),
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
@@ -637,6 +727,64 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+def _window_moe_fields(get) -> dict:
+    """The ``exaone_moe`` keys as ``ModelConfig`` fields: the mixers from
+    ``layer_types`` (checked against ``sliding_windows``), each layer's second
+    half from ``mlp_layer_types``, the experts under DeepSeek-V3's router keys
+    and their own count keys (``num_experts``: the experts HELD under a
+    ``share``, as ``_delta_moe_fields`` reads ``n_routed_experts``), RoPE's
+    base from ``rope_parameters``. q and k take a per-head RMSNorm in every
+    layer; only the window layers rotate. The multi-token-prediction module
+    (``num_nextn_predict_layers``, ``mtp_*``) is a draft head no logit of the
+    main head depends on: its keys are read by nothing. A variant that is not
+    implemented is REFUSED by key."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"exaone_moe with {key}={get(key)!r} is not supported: {why}")
+
+    layers, mlps = get("layer_types"), get("mlp_layer_types")
+    window = get("sliding_window")
+    if not layers or not mlps or len(layers) != len(mlps):
+        raise ValueError(
+            "model_type 'exaone_moe' needs its layer_types and mlp_layer_types "
+            "lists, one entry a published layer each")
+    unknown = sorted(set(layers) - {"sliding_attention", "full_attention"})
+    if unknown:
+        refuse("layer_types", f"a layer is sliding_attention or full_attention, not {unknown}")
+    if set(mlps) - {"dense", "sparse"}:
+        refuse("mlp_layer_types", "a layer's second half is dense or sparse")
+    windows = get("sliding_windows")
+    if windows is not None and list(windows) != [
+            int(window or 0) * (t == "sliding_attention") for t in layers]:
+        refuse("sliding_windows", "every sliding_attention layer keeps sliding_window "
+               "tokens and every full_attention layer 0; a window a layer of its own "
+               "is not implemented")
+    dense = int(get("first_k_dense_replace", 0) or 0)
+    if list(mlps) != ["dense"] * dense + ["sparse"] * (len(mlps) - dense):
+        refuse("first_k_dense_replace", "it disagrees with mlp_layer_types, which is "
+               "what is read")
+    rope = dict(get("rope_parameters") or {})
+    if str(rope.get("rope_type", "default")) != "default" or get("rope_scaling") is not None:
+        refuse("rope_parameters", "the window layers' q and k are rotated by plain "
+               "RoPE at rope_theta; scaled positions are not implemented")
+    _refuse_router_variants(get, refuse)
+    held = int(get("num_experts") or 0)
+    published = dict((get("share") or {}).get("published") or {})
+    width = int(published.get("num_experts", held))
+    return dict(
+        mixer_types=tuple(layers), mlp_types=tuple(mlps), qk_norm=True,
+        attn_use_rope=False,  # the full_attention layers; a window layer always rotates
+        rope_theta=float(rope.get("rope_theta", get("rope_theta", 10000.0))),
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
+        n_shared_experts=int(get("num_shared_experts") or 0),
+        experts_per_token=int(get("num_experts_per_tok") or 0),
+        moe_intermediate_size=int(get("moe_intermediate_size") or 0),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+    )
 
 
 #: the ``mamba_*`` keys ``_jamba_fields`` reads; another one is a variant
@@ -869,6 +1017,20 @@ TINY_JAMBA = ModelConfig(
     mamba_d_state=16, mamba_dt_rank=8,
 )
 
+# a window model with routed experts at a size the CPU tests run: the published
+# period (window, window, window, full) and one more, a dense layer 0 before
+# expert layers, a ring of 8 tokens, 2 of 16 experts a chip of 8
+TINY_EXAONE_MOE = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=5,
+    num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=1000000.0,
+    rms_norm_eps=1e-5, sliding_window=8,
+    mixer_types=("sliding_attention",) * 3 + ("full_attention",)
+    + ("sliding_attention",) * 3 + ("full_attention",),
+    mlp_types=("dense",) + ("sparse",) * 7, qk_norm=True, attn_use_rope=False,
+    n_routed_experts=2, router_experts=16, n_shared_experts=1,
+    experts_per_token=4, moe_intermediate_size=32, routed_scaling_factor=2.5,
+)
+
 QWEN2_0_5B = ModelConfig(
     vocab_size=151936, hidden_size=896, intermediate_size=4864, num_layers=24,
     num_heads=14, num_kv_heads=2, head_dim=64, rope_theta=1000000.0,
@@ -922,6 +1084,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-delta-moe": TINY_DELTA_MOE,
     "tiny-power": TINY_POWER,
     "tiny-jamba": TINY_JAMBA,
+    "tiny-exaone-moe": TINY_EXAONE_MOE,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

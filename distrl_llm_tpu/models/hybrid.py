@@ -117,6 +117,26 @@ window, which is the entry ``conv`` (the last three tokens' ``u``,
 ``[B, 3, d_inner]``), tuples over the Mamba layers. ``ssm_stats`` [1] int32
 counts the (live row, layer) states the decode steps read and wrote.
 
+**Window layers with routed experts** (``exaone_moe``, K-EXAONE-236B-A23B) are
+one more kind, "window" (``sliding_attention``), beside "softmax" layers
+(``full_attention``; here with a per-head norm of q and k, no gate and no
+RoPE). The second half is the LAYER's (``cfg.layer_ffn(kind)``): routed experts
+told what this program holds beside one shared expert, or the dense gated MLP
+where the kind carries ``_dense`` (the model's layer 0)::
+
+    q [T, H, D], k, v [T, K, D] = W h;  q, k <- RMSNorm_D
+    window: q, k <- RoPE;  token t attends tokens max(0, t - W + 1) .. t
+    softmax: no positional encoding;  token t attends 0 .. t
+    y = W_o softmax(q k^T / sqrt(D)) v
+
+A window layer keeps a SIXTH kind of slot state and no page: ``win_k`` /
+``win_v``, a RING of the last W tokens' K (rotated before it is kept, so the
+ring's order does not matter to a softmax) and V, ``[B, K, W, D]`` in the
+cache's type, written at ``position % W``, tuples over the window layers.
+``window_stats`` [2] int32 counts, per live row, window layer and decode step,
+the keys attended and the keys a full layer would attend, in units of
+``WINDOW_COUNT_UNIT`` keys rounded up.
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -134,13 +154,13 @@ import jax
 import jax.numpy as jnp
 
 from distrl_llm_tpu import telemetry
-from distrl_llm_tpu.models.configs import ModelConfig
+from distrl_llm_tpu.models.configs import ModelConfig, mixer_of
 from distrl_llm_tpu.models.transformer import (
     _head, _init_around_layers, _init_layer_stack, _mlp_half, _normal_init, _proj,
     _slice_layer, apply_rope, rms_norm, rope_cos_sin,
 )
 from distrl_llm_tpu.models.moe import moe_half
-from distrl_llm_tpu.ops.attention import attention
+from distrl_llm_tpu.ops.attention import attention, attention_reference
 from distrl_llm_tpu.ops.delta_attention import (
     delta_chunked, delta_step, l2norm, short_conv,
 )
@@ -167,7 +187,14 @@ LATENT_DECODE_ROWS = 16
 SOFTMAX_SEGMENT_PAGES = 2
 #: the entries of a slot's state that hold one array a ROW for each layer of a
 #: kind (tuples): what a candidate is handed from its prompt
-ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm")
+ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm",
+              "win_k", "win_v")
+#: keys a unit of ``window_stats`` stands for (module docstring)
+WINDOW_COUNT_UNIT = 128
+#: the mixers whose layers ``_block`` runs as a mixer and then the layer's own
+#: second half, and the cache entries each keeps a layer
+_MIXER_CACHE = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
+                "mamba": ("ssm", "conv"), "window": ("win_k", "win_v")}
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -246,7 +273,15 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
                 "w_up": init((n, d, f)), "w_down": init((n, f, d))}
 
     layers: Params = {}
-    if cfg.kind_count("softmax"):
+    if cfg.window_moe:  # q/k norms in both mixers; the second half is the layer's
+        for kind in dict.fromkeys(cfg.layer_kinds):
+            n = cfg.kind_count(kind)
+            layers[kind] = {
+                **mixer_stack(n, cfg.q_dim, cfg.kv_dim),
+                "q_norm": jnp.ones((n, cfg.head_dim), dtype),
+                "k_norm": jnp.ones((n, cfg.head_dim), dtype),
+                **(mlp_half(n) if cfg.layer_ffn(kind) == "dense" else expert_half(n))}
+    elif cfg.kind_count("softmax"):
         n = cfg.kind_count("softmax")
         layers["softmax"] = {
             **mixer_stack(n, cfg.q_dim, cfg.kv_dim),
@@ -335,6 +370,17 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                 for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
+        }
+    if cfg.window_moe:
+        ring = (rows, cfg.num_kv_heads, cfg.sliding_window, cfg.head_dim)
+        n = cfg.mixer_count("window")
+        return {
+            "lin": (), "pooled": (),
+            "win_k": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
+            "win_v": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
+            "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_routed": jnp.zeros((1,), jnp.int32),
+            "window_stats": jnp.zeros((2,), jnp.int32),
         }
     if cfg.mamba:
         n, e = cfg.kind_count("mamba"), cfg.mamba_inner
@@ -484,11 +530,13 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     if kind in ("latent", "latent_moe"):
         return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
                              mode=mode, env=env, proj=proj, lora_scale=lora_scale)
-    if kind in ("softmax", "delta", "mamba"):
-        mix = {"softmax": _softmax_mix, "delta": _delta_mix, "mamba": _mamba_mix}[kind]
+    mixer = mixer_of(kind)
+    if mixer in _MIXER_CACHE:
+        mix = {"softmax": _softmax_mix, "delta": _delta_mix, "mamba": _mamba_mix,
+               "window": _window_mix}[mixer]
         x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
                        lora_scale=lora_scale)
-        if not cfg.delta_moe:  # a state-space model's layers: the dense MLP
+        if cfg.layer_ffn(kind) == "dense":  # the layer's own second half
             return _mlp_half(x, p, lora, cfg=cfg, proj=proj,
                              lora_scale=lora_scale), cache, None
         x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
@@ -585,19 +633,93 @@ def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
     return o.transpose(0, 3, 1, 2, 4).reshape(b, s, heads, hd).astype(q.dtype)
 
 
-def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-    """A gated softmax layer without RoPE: (x + y, (pages_k, pages_v) or None)."""
-    from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
-
+def _qkv_heads(x, p, lora, *, cfg, proj, lora_scale):
+    """A GQA layer's normed input and its q ``[B, S, H, hd]``, k and v ``[B, S,
+    K, hd]``, with the per-head RMSNorm of q and k where the layer has one."""
     b, s, _ = x.shape
     heads, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    if cfg.attn_use_rope:
-        raise NotImplementedError("softmax layers with RoPE (use_rope)")
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
         q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, hd)
         k = proj(h, p, lora, "wk", "bk", lora_scale).reshape(b, s, kv, hd)
         v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(b, s, kv, hd)
+        if "q_norm" in p:
+            q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+    return h, q, k, v
+
+
+def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A sliding-window layer: q/k norm, RoPE, token t over tokens
+    ``max(0, t - W + 1) .. t``: (x + y, (ring_k, ring_v) or None). The ring
+    ``[B, K, W, hd]`` holds position ``p`` at ``p % W``, k rotated already."""
+    b, s, _ = x.shape
+    kv, hd, win = cfg.num_kv_heads, cfg.head_dim, cfg.sliding_window
+    _, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
+        q = apply_rope(q, env["cos"], env["sin"])
+        k = apply_rope(k, env["cos"], env["sin"])
+    if mode == "full":
+        with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
+            o = attention(q, k, v, None, impl=env["attn_impl"],
+                          key_valid=env["valid"], window=win)
+    elif mode == "decode":
+        ring_k, ring_v = cache
+        at = env["lengths"]  # the token's position: tokens before it
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            # a point scatter: row, KV head and slot are indices, the head's
+            # values the window (a KV head taken as a slice relays the array out)
+            where = (jnp.arange(b)[:, None], jnp.arange(kv)[None, :], (at % win)[:, None])
+            ring_k = ring_k.at[where].set(k[:, 0].astype(ring_k.dtype))
+            ring_v = ring_v.at[where].set(v[:, 0].astype(ring_v.dtype))
+        with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
+            # slots 0 .. min(t, W - 1) are filled, in whatever order
+            seen = jnp.arange(win)[None, :] < jnp.minimum(at + 1, win)[:, None]
+            o = attention_reference(  # the ring's keys lie in no order: a softmax
+                q, ring_k.transpose(0, 2, 1, 3).astype(q.dtype),
+                ring_v.transpose(0, 2, 1, 3).astype(q.dtype), seen[:, None, None, :])
+        cache = (ring_k, ring_v)
+    else:  # one segment of a prefill, every row at ``start``: the ring, then itself
+        ring_k, ring_v = cache
+        start, slots = env["segment_start"], jnp.arange(win)
+        with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
+            # the position a slot holds before this segment: the largest p < start
+            # with p % W == slot (negative: nothing yet)
+            held = start - 1 - ((start - 1 - slots) % win)
+            key_pos = jnp.concatenate([held, start + jnp.arange(s)])  # [W + S]
+            q_pos = env["q_pos"][:, :, None]  # [B, S, 1]
+            seen = (key_pos >= 0) & (key_pos <= q_pos) & (q_pos - key_pos < win)
+            seen = seen & jnp.concatenate(
+                [jnp.ones((b, win), bool), env["valid"] > 0], axis=1)[:, None, :]
+            o = attention_reference(
+                q, jnp.concatenate([ring_k.transpose(0, 2, 1, 3).astype(k.dtype), k], 1),
+                jnp.concatenate([ring_v.transpose(0, 2, 1, 3).astype(v.dtype), v], 1),
+                seen[:, None])
+        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+            # each slot takes the row's LAST real token of the segment that falls
+            # on it (rows are packed left: real tokens first), or keeps what it has
+            last = (start + (env["valid"] > 0).sum(-1).astype(jnp.int32) - 1)[:, None]
+            newest = last - ((last - slots[None, :]) % win)  # [B, W]
+            take = (newest >= start)[:, None, :, None]
+            src = jnp.clip(newest - start, 0, s - 1)[:, :, None, None]
+            pick = lambda new: jnp.take_along_axis(new, src, axis=1).transpose(0, 2, 1, 3)
+            ring_k = jnp.where(take, pick(k).astype(ring_k.dtype), ring_k)
+            ring_v = jnp.where(take, pick(v).astype(ring_v.dtype), ring_v)
+        cache = (ring_k, ring_v)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        return x + proj(o.reshape(b, s, -1), p, lora, "wo", "bo", lora_scale), cache
+
+
+def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A softmax layer without RoPE, gated or with a per-head norm of q and k
+    where its stack says so: (x + y, (pages_k, pages_v) or None)."""
+    from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
+
+    b, s, _ = x.shape
+    heads, hd = cfg.num_heads, cfg.head_dim
+    if cfg.attn_use_rope:
+        raise NotImplementedError("softmax layers with RoPE (use_rope)")
+    h, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
     if mode == "full":
         with jax.named_scope(telemetry.MODEL_ATTN_CORE):
             o = attention(q, k, v, None, impl=env["attn_impl"], key_valid=env["valid"])
@@ -901,10 +1023,13 @@ def forward_hybrid(
             "valid": attention_mask, "page_indices": kv_cache["page_indices"],
             "page_size": page_size,
         }
-    if cfg.latent or cfg.power or cfg.kind_count("lightning"):  # the others rotate nothing
+    if (cfg.latent or cfg.power or cfg.window_moe
+            or cfg.kind_count("lightning")):  # the others rotate nothing
         with jax.named_scope(
                 telemetry.MODEL_ATTN_CORE if cfg.latent else
-                telemetry.MODEL_POWER_ATTN if cfg.power else telemetry.MODEL_LINEAR_ATTN):
+                telemetry.MODEL_POWER_ATTN if cfg.power else
+                telemetry.MODEL_WINDOW_ATTN if cfg.window_moe else
+                telemetry.MODEL_LINEAR_ATTN):
             env["cos"], env["sin"] = rope_cos_sin(
                 rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
                 cfg.rope_theta)
@@ -966,9 +1091,11 @@ def forward_hybrid(
     stats = kv_cache.get("sel_stats")
     moe_stats = kv_cache.get("moe_stats")
     at = dict.fromkeys(cfg.layer_kinds, 0)
+    held_at = dict.fromkeys(_MIXER_CACHE, 0)  # a mixer's layers, whatever follows them
     for i, kind in enumerate(cfg.layer_kinds):
         j = at[kind]
         at[kind] += 1
+        mixer = mixer_of(kind)
         whole = {k: v for k, v in stacks[kind].items() if k.startswith("experts_")}
         p = _slice_layer(
             {k: v for k, v in stacks[kind].items() if k not in whole}, j)
@@ -981,15 +1108,15 @@ def forward_hybrid(
                 dropout_rng=layer_keys[i] if use_dropout else None)
             if moe_stats is not None and layer_stats is not None:
                 moe_stats = moe_stats + layer_stats
-        elif kind in ("softmax", "delta", "mamba"):
-            names = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
-                     "mamba": ("ssm", "conv")}[kind]
+        elif mixer in _MIXER_CACHE:
+            names, m = _MIXER_CACHE[mixer], held_at[mixer]
+            held_at[mixer] += 1
             x, held, layer_stats = block(
-                x, p, lora_p, None, tuple(new[name][j] for name in names), kind=kind,
+                x, p, lora_p, None, tuple(new[name][m] for name in names), kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
             for name, piece in zip(names, held):
-                new[name][j] = piece
-            if moe_stats is not None:
+                new[name][m] = piece
+            if moe_stats is not None and layer_stats is not None:
                 moe_stats = moe_stats + layer_stats
         elif kind == "power":
             x, (new["power"][j], new["power_z"][j]), _ = block(
@@ -1016,8 +1143,9 @@ def forward_hybrid(
         out["moe_stats"] = moe_stats
     if "moe_routed" in kv_cache:  # the router's choices over ALL experts: live tokens x k a layer
         live = b * s if env.get("alive") is None else env["alive"].sum() * s
+        expert_layers = sum(1 for k in cfg.layer_kinds if cfg.layer_ffn(k) == "experts")
         out["moe_routed"] = kv_cache["moe_routed"] + jnp.asarray(
-            cfg.num_layers * cfg.experts_per_token * live, jnp.int32)
+            expert_layers * cfg.experts_per_token * live, jnp.int32)
     if "power_stats" in kv_cache and mode == "decode":  # live rows' states, every layer
         live = b if env.get("alive") is None else env["alive"].sum()
         out["power_stats"] = kv_cache["power_stats"] + jnp.asarray(
@@ -1026,6 +1154,12 @@ def forward_hybrid(
         live = b if env.get("alive") is None else env["alive"].sum()
         out["ssm_stats"] = kv_cache["ssm_stats"] + jnp.asarray(
             cfg.kind_count("mamba") * live, jnp.int32)
+    if "window_stats" in kv_cache and mode == "decode":  # every window layer alike
+        alive = env.get("alive")
+        keys = (env["lengths"] + 1) * (1 if alive is None else alive.astype(jnp.int32))
+        units = lambda n: (-(-n // WINDOW_COUNT_UNIT)).sum()
+        out["window_stats"] = kv_cache["window_stats"] + cfg.mixer_count("window") * (
+            jnp.stack([units(jnp.minimum(keys, cfg.sliding_window)), units(keys)]))
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
             kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)

@@ -70,7 +70,9 @@ def init_lora_params(
     power-retention layer has the dense decoder's seven targets and no factor
     on its log-decay. A state-space model's attention layers have the seven and
     its Mamba layers ``MAMBA_TARGETS``; targets the caller names go to the
-    layers that have them."""
+    layers that have them. A window model (``exaone_moe``) has the seven in
+    every kind of layer: the MLP's three are the dense MLP's in a ``_dense``
+    kind and the shared expert's in the others."""
     named = targets is not None
     if targets is None:
         targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
@@ -112,6 +114,13 @@ def init_lora_params(
         shared = {**dims, "intermediate_size": cfg.shared_expert_size}
         delta = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.delta_dim)
         per_kind = {"softmax": shared, "delta": {**shared, **delta}}
+    elif cfg.window_moe:
+        # q, k, v, o of both mixers, then the layer's own second half: the dense
+        # MLP's three, or the shared expert's; the router and the routed experts
+        # are frozen
+        shared = {**dims, "intermediate_size": cfg.shared_expert_size}
+        per_kind = {kind: dims if cfg.layer_ffn(kind) == "dense" else shared
+                    for kind in dict.fromkeys(cfg.layer_kinds)}
     elif cfg.power:
         # Qwen3's seven targets; the log-decay's projection and bias are frozen
         per_kind = {"power": dims}

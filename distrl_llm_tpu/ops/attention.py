@@ -52,17 +52,21 @@ def causal_padding_mask(
     attention_mask: jax.Array,  # [B, Sk] 1 = real token
     q_len: int,
     q_offset: jax.Array | int = 0,
+    window: int = 0,
 ) -> jax.Array:
     """[B, 1, Sq, Sk] boolean mask combining causality with key padding.
 
     ``q_offset`` is the absolute position of the first query row — 0 for a
     training/prefill forward, the current decode length for single-token decode
-    steps against a KV cache.
+    steps against a KV cache. ``window`` > 0 makes the mask a BAND: a query
+    attends the last ``window`` keys, itself included (sliding-window layers).
     """
     sk = attention_mask.shape[-1]
     q_pos = q_offset + jnp.arange(q_len)[:, None]  # [Sq, 1]
     k_pos = jnp.arange(sk)[None, :]  # [1, Sk]
     causal = k_pos <= q_pos  # [Sq, Sk]
+    if window:
+        causal = causal & (q_pos - k_pos < window)
     pad = attention_mask[:, None, None, :].astype(bool)  # [B, 1, 1, Sk]
     return causal[None, None, :, :] & pad
 
@@ -264,9 +268,14 @@ def attention(
     scale: float | None = None,
     impl: str = "reference",
     key_valid: jax.Array | None = None,
+    window: int = 0,
 ) -> jax.Array:
     """Dispatching front door. ``impl``: "reference" (XLA), "flash" or
     "splash" (Pallas kernels).
+
+    ``window`` > 0 (with ``key_valid`` and no ``mask``) is a sliding-window
+    layer's band, in the reference's mask; the kernels take a causal mask only
+    and refuse the band by name rather than attend everything.
 
     On a TPU backend a named kernel is what runs: a kernel that fails to
     lower or to run fails the step, it never gives way to the reference
@@ -280,6 +289,11 @@ def attention(
     consume it directly (no [B, 1, Sq, Sk] mask needs to exist). When only
     ``key_valid`` is given and the reference runs, the dense causal mask is
     built here."""
+    if window and impl in ("flash", "splash"):
+        raise NotImplementedError(
+            f"attn_impl={impl!r} masks causally and has no band: a sliding-window "
+            f"layer (window {window}) would attend its whole context; use "
+            "attn_impl='reference'")
     if impl in ("flash", "splash"):
         if jax.default_backend() != "tpu":
             _note_reference(impl, "no TPU backend")
@@ -296,5 +310,5 @@ def attention(
 
             return flash_attention(q, k, v, mask, scale=scale, key_valid=key_valid)
     if mask is None and key_valid is not None:
-        mask = causal_padding_mask(key_valid, q_len=q.shape[1])
+        mask = causal_padding_mask(key_valid, q_len=q.shape[1], window=window)
     return attention_reference(q, k, v, mask, scale=scale)

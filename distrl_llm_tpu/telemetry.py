@@ -96,6 +96,13 @@ ENGINE_SLOWEST_BOUNDARY_HOST_MS = "engine/slowest_boundary_host_ms"
 # them
 ENGINE_DECODE_VIEW_BUILDS = "engine/decode_view_builds"
 ENGINE_DECODE_VIEW_BYTES = "engine/decode_view_bytes"
+# sliding-window layers: per live row, window layer and decode step, the keys
+# attended (the ring: min(context, window)) and the keys a full-attention layer
+# would attend (the context), IN UNITS OF 128 KEYS, rounded up (a round's sum
+# in keys passes an int32). Carried in the decode state (``mixer["window_stats"]``)
+# and filed at readback; attended / visible is what the window saves
+ENGINE_WINDOW_PAGES_ATTENDED = "engine/window_pages_attended"  # counter
+ENGINE_WINDOW_PAGES_VISIBLE = "engine/window_pages_visible"    # counter
 # what the learner's rematerialised layer scan keeps for the backward pass
 # (learner/remat.py), filed when a train step first meets a batch shape: how
 # many of the five named products of the frozen weights (q, k, v, the MLP's
@@ -149,6 +156,12 @@ MODEL_POWER_ATTN = "model/power_attn"
 # skip and the silu(z) gate. W_in, W_x, W_dt and W_out stay ``model/attn_proj``;
 # the convolution with its bias and SiLU is ``model/short_conv``
 MODEL_SSM = "model/ssm"
+# sliding-window layers (exaone_moe, models/hybrid.py::_window_mix): a window
+# layer's attention in all three modes (the band over a whole row, over a
+# prefill segment and the ring before it, one token over a slot's ring), RoPE
+# included. q, k, v, o stay ``model/attn_proj``, the ring's write
+# ``engine/kv_write``
+MODEL_WINDOW_ATTN = "model/window_attn"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -200,7 +213,7 @@ SCOPE_NAMES = (
     MODEL_LINEAR_ATTN, MODEL_SPARSE_SELECT, MODEL_SPARSE_ATTN,
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
-    MODEL_SSM,
+    MODEL_SSM, MODEL_WINDOW_ATTN,
 )
 
 

@@ -540,6 +540,98 @@ def test_state_space_prefill_keeps_one_layers_segment(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+def _exaone_cell():
+    """The cell's configuration, as ``perfbench/configs/k-exaone-236b-ep8-L5.json``
+    states it: five layers at the published widths, 16 of 128 experts held."""
+    import json
+    import os
+    from types import SimpleNamespace
+
+    from distrl_llm_tpu.models import ModelConfig
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "perfbench", "configs", "k-exaone-236b-ep8-L5.json")
+    with open(path) as f:
+        return ModelConfig.from_hf_config(SimpleNamespace(**json.load(f)))
+
+
+def test_window_decode_step_at_published_widths(chip, monkeypatch):
+    """``k-exaone-236b-ep8-L5.rollout-longctx-window``'s decode step (64 rows,
+    a table of 164 pages, a rank-32 adapter) fed the decode view. The full
+    layer's decode is the ONE ``paged_attention_native`` launch, at 8 KV heads
+    and a group of 8, where the ``kernel.*`` regexes look for it; the four
+    window layers are plain XLA over their rings. The eight rings
+    (``[64, 8, 128, 128]`` bf16) and the two pools are donated and written in
+    place: no synchronous copy of a ring (the point scatter indexes row, KV
+    head and slot). No projection of the new kinds is copied or sliced: the
+    view holds their q, k, v and o."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import DECODE_VIEW_KEYS, decode_view
+
+    cfg = _exaone_cell()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 64, 128, jnp.bfloat16
+    width = (20480 + 512) // page
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pool = chip((8, 4 * 160 + rows * 4 + 8, page, 128), bf)
+    cache = {
+        "k": (pool,), "v": (pool,),
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "%paged_attention_native" in calls[0], calls
+    assert "bf16[64,8,8,128]" in calls[0], calls  # rows, 8 KV heads, their groups of 8
+    ring = "bf16[64,8,128,128]"
+    copies = [line.strip()[:160] for line in text[text.index("ENTRY "):].splitlines()
+              if " copy(" in line and ring in line.split("(")[0]]
+    assert not copies, copies
+    rings, pools = 8 * 64 * 8 * 128 * 128 * 2, 2 * pool.size * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= rings + pools
+    sizes = {stack[key].shape[1] * stack[key].shape[2]
+             for stack in params["layers"].values() for key in DECODE_VIEW_KEYS}
+    assert sizes == {6144 * 8192, 6144 * 1024}
+    assert not _weight_sized_operations(text, sizes)
+
+
+def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
+    """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through
+    four window layers, one full layer and four expert layers in the grouped
+    form, at the published widths): a window layer's scores are over its ring
+    and the segment (1,152 keys), not the context, and the temporaries are one
+    layer's: 1.51 GB when this was written, beside 7.47 GB of weights."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import init_lora_params, init_params
+
+    cfg = _exaone_cell()
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(
+        init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    prefill = functools.partial(
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=160, page_size=128,
+        lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
+        total_tokens=20480 + 512)
+    compiled = jax.jit(prefill).lower(
+        params, lora, chip((4, 20480), jnp.int32), chip((4, 20480), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
 @pytest.mark.parametrize("rows,vocab", [(ROWS, VOCAB), (ROWS, 73448), (480, 65536)],
                          ids=["v152k", "v73448", "480xv65536"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],

@@ -99,6 +99,11 @@ def _host_account(rounds: list[dict], track: list[dict]) -> str:
     stepped = sum(r.get("args", {}).get("ssm_states_stepped", 0) for r in rounds)
     if stepped:
         line += f", {stepped} states stepped"
+    # and what a window model's rings attended of what full attention would
+    attended, visible = (sum(r.get("args", {}).get(f"window_pages_{k}", 0) for r in rounds)
+                         for k in ("attended", "visible"))
+    if visible:
+        line += f", window {attended} of {visible} x 128 keys"
     return line
 
 
