@@ -352,6 +352,45 @@ def _power_step_case(key, *, rows, kv_heads, group, head_dim, steps=4):
     return {"impl": ran, "max_abs_err": float(f"{err:.3g}")}
 
 
+def _latent_fold_case(key, *, rows, heads, nope, rope, v_dim, rank, segment, blocks=3):
+    """A latent-attention prefill segment as ``expanded_segment`` dispatches it
+    (every fold of a block of keys the Mosaic kernel on a TPU at bf16 heads of
+    128) against the XLA form's folds, ``blocks`` blocks of ``segment`` keys
+    rebuilt from their latents, the queries on the last; what ran is read from
+    the dispatch record."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    ks = jax.random.split(key, 5)
+    bf = jnp.bfloat16
+    q_nope = jax.random.normal(ks[0], (rows, segment, heads, nope), bf)
+    q_pe = jax.random.normal(ks[1], (rows, segment, heads, rope), bf)
+    latent = jax.random.normal(ks[2], (blocks, rows, segment, rank), bf)
+    k_pe = jax.random.normal(ks[3], (blocks, rows, segment, rope), bf)
+    w = (jax.random.normal(ks[4], (rank, heads * (nope + v_dim))) * rank ** -0.5).astype(bf)
+    start = jnp.int32((blocks - 1) * segment)
+
+    def block(j):
+        kv = jax.lax.dynamic_index_in_dim(latent, j, 0, keepdims=False) @ w
+        return (kv.reshape(rows, segment, heads, nope + v_dim),
+                jax.lax.dynamic_index_in_dim(k_pe, j, 0, keepdims=False))
+
+    got = jax.jit(lambda: la.expanded_segment(q_nope, q_pe, block, start, v_dim, bf))()
+    ran = la.dispatch_choices[la.dispatch_key(heads, nope, rope, v_dim, segment, bf)]
+    carry = None
+    for j in range(blocks):
+        carry = jax.jit(la.expanded_fold)(
+            q_nope, q_pe, *block(j), start, jnp.int32(j * segment), carry)
+    want = la.expanded_finish(carry, jnp.float32)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    # outputs of unit size rounded to bf16: half a unit in the last place of 2
+    assert np.isfinite(err) and err < 2e-2, f"latent fold {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": float(f"{err:.3g}"), "folds": blocks}
+
+
 def phase_kernels(seed: int, compiles: CompileLog) -> None:
     """Each Pallas kernel the trainer phases use, compiled (never
     interpreted) at the 0.5B geometry, against its reference on the chip."""
@@ -399,6 +438,12 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     out["latent_attention_shared_prefix"] = _shared_prefix_attention_case(
         key, rows=16, heads=16, nope=128, rope=64, v_dim=128, rank=512, latent_row=640,
         prompt=10300, page=128, per=8)
+    # a prefill segment of the same configuration: three folds of 1,024 keys
+    # into 4 rows' 16 heads of 1,024 queries, the Mosaic kernel against the
+    # XLA form
+    out["latent_fold"] = _latent_fold_case(
+        key, rows=4, heads=16, nope=128, rope=64, v_dim=128, rank=512, segment=1024)
+    assert out["latent_fold"]["impl"] == "kernel", out["latent_fold"]
     for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
         out[name] = _expert_layer_case(
             key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)

@@ -290,6 +290,27 @@ def _record_power_telemetry(cfg: ModelConfig, steps: int) -> None:
         telemetry.OPS_POWER_KERNEL_STEPS, layers * steps * (ran == "kernel"))
 
 
+def _record_latent_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
+                             dtype) -> None:
+    """``ops/latent_kernel_folds``: the folds of the round's prefill (one call,
+    every latent layer, segment ``j`` folding ``j + 1`` blocks of keys) that
+    ran as the Mosaic kernel, read from what ``expanded_segment`` recorded for
+    this model's heads and segment when the prefill was traced (0 where it
+    took the XLA form); ``dtype`` is the activations', the embedding's. A
+    model without latent layers files nothing."""
+    if not cfg.latent:
+        return
+    from distrl_llm_tpu.ops.latent_attention import dispatch_choices, dispatch_key
+
+    seg, n_seg = _hybrid_segments(prompt_pages, page_size)
+    ran = dispatch_choices.get(dispatch_key(
+        cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+        seg, dtype))
+    telemetry.counter_add(
+        telemetry.OPS_LATENT_KERNEL_FOLDS,
+        cfg.num_layers * (n_seg * (n_seg + 1) // 2) * (ran == "kernel"))
+
+
 def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
                    prompt_pages: int, page_size: int, lora_scale: float,
                    cache_dtype, attn_impl: str, kv_quant: str = "none"):
@@ -340,6 +361,18 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
 HYBRID_PREFILL_SEGMENT = 1024
 
 
+def _hybrid_segments(prompt_pages: int, page_size: int) -> tuple[int, int]:
+    """(tokens of one segment, segments) of a hybrid model's prefill: the
+    most whole pages that divide the prompt's and hold no more than
+    HYBRID_PREFILL_SEGMENT tokens (one page where a page holds more)."""
+    seg_pages = max(
+        d for d in range(1, prompt_pages + 1)
+        if prompt_pages % d == 0 and d * page_size <= max(
+            HYBRID_PREFILL_SEGMENT, page_size)
+    )
+    return seg_pages * page_size, prompt_pages // seg_pages
+
+
 def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
                           cfg: ModelConfig, prompt_pages: int, page_size: int,
                           lora_scale: float, cache_dtype, attn_impl: str,
@@ -359,12 +392,7 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
 
     b, p = prompt_ids.shape
     pad_to = prompt_pages * page_size
-    seg_pages = max(
-        d for d in range(1, prompt_pages + 1)
-        if prompt_pages % d == 0 and d * page_size <= max(
-            HYBRID_PREFILL_SEGMENT, page_size)
-    )
-    seg, n_seg = seg_pages * page_size, prompt_pages // seg_pages
+    seg, n_seg = _hybrid_segments(prompt_pages, page_size)
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
         packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
@@ -4123,6 +4151,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_delta_telemetry(self.cfg, dispatched)
         _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
         _record_power_telemetry(self.cfg, dispatched)
+        _record_latent_telemetry(
+            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4245,6 +4275,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_delta_telemetry(self.cfg, steps_seen[0])
         _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
         _record_power_telemetry(self.cfg, steps_seen[0])
+        _record_latent_telemetry(
+            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

@@ -166,7 +166,7 @@ from distrl_llm_tpu.ops.delta_attention import (
 )
 from distrl_llm_tpu.ops.latent_attention import (
     absorbed_output, absorbed_paged_attention, absorbed_query,
-    expanded_attention, expanded_finish, expanded_start,
+    expanded_attention, expanded_finish, expanded_segment,
     rope_interleaved, shared_page_walk, shared_pages_per_block, split_kvb,
 )
 from distrl_llm_tpu.ops.linear import linear
@@ -913,22 +913,16 @@ def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,
         dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, per, axis=1)
         pages = pages.at[dest.reshape(-1)].set(row.reshape(b * per, ps, -1))
 
-    def fold(j, carry):
+    def block(j):  # the segment's own keys come from its pages too
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
             at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)
             held = pages[at].reshape(b, s, -1).astype(c.dtype)
         with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
-            kv = expand(held[..., :rank])
-        mask = (j * s + jnp.arange(s))[None, :] <= env["q_pos"][0][:, None]
-        return expanded_attention(
-            q_nope, q_pe, kv, held[..., rank: rank + rope],
-            jnp.broadcast_to(mask, (b, s, s)),
-            carry)
+            return expand(held[..., :rank]), held[..., rank: rank + rope]
 
     with jax.named_scope(telemetry.MODEL_ATTN_CORE):
-        carry = jax.lax.fori_loop(  # the segment's own keys come from its pages
-            0, start // s + 1, fold, expanded_start(b, s, heads, cfg.v_head_dim))
-        return expanded_finish(carry, c.dtype), pages
+        return expanded_segment(
+            q_nope, q_pe, block, start, cfg.v_head_dim, c.dtype), pages
 
 
 def _latent_block(x, p, lora, pages, *, moe: bool, cfg: ModelConfig, mode: str,

@@ -7,7 +7,13 @@ shared by every head). A head's key is ``[c W_k[h], k_pe]`` and its value
 has two forms, and which is cheaper depends on queries a cached token:
 
 * **expanded** (many queries: prefill, the learner): rebuild K and V per head
-  from the latent, ``nope + rope``-wide scores, ``v``-wide values;
+  from the latent, ``nope + rope``-wide scores, ``v``-wide values. The
+  learner and the no-cache forward (``full`` mode) run ``expanded_attention``,
+  plain XLA, which they differentiate; a prefill segment runs
+  ``expanded_segment``, which folds each block of earlier keys into the
+  segment's running softmax with one Mosaic kernel on a TPU
+  (``expanded_fold_kernel``: the block's scores never leave VMEM) and with
+  ``expanded_attention`` elsewhere;
 * **absorbed** (one query a row: decode): fold ``W_k`` into the query and
   ``W_v`` into the output, so every head attends over the latent row itself:
   ``scores = (q_nope W_k[h]^T) . c + q_pe . k_pe``, ``o = (sum p c) W_v[h]``.
@@ -29,20 +35,30 @@ this is exact whatever made them equal, and a group that shares nothing walks
 as it always did. A column past a row's newest page repeats that page: no page
 that the row does not hold is ever fetched.
 
-Both are plain XLA here (no Mosaic kernel yet: ROADMAP). The softmax scale is
-``(nope + rope)^-0.5`` in both. RoPE pairs are DeepSeek's interleaved ones,
+The absorbed form is plain XLA (no Mosaic kernel yet: ROADMAP). The softmax
+scale is ``(nope + rope)^-0.5`` in both. RoPE pairs are DeepSeek's interleaved ones,
 ``(x[2i], x[2i+1])``; the output keeps the halves apart (evens first), which a
 score cannot tell as long as q and k are rotated alike.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distrl_llm_tpu.ops.attention import NEG_INF
+from distrl_llm_tpu.ops.per_device import per_device
+
+_LANES = 128  # a VMEM tile's minor axis: the kernel takes whole tiles
+#: what each geometry's prefill segment resolved to, "kernel" or "xla", under
+#: ``dispatch_key``: the engine's counter ``ops/latent_kernel_folds`` and
+#: chip_smoke.py read it, so a run on the XLA form cannot pass for the kernel
+dispatch_choices: dict[tuple, str] = {}
 
 
 def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -71,12 +87,13 @@ def expanded_attention(
     carry=None,
 ):
     """Attention with K and V rebuilt per head, one block of keys folded into
-    a running softmax (flash-style, in XLA). ``carry = (m [B, H, Sq],
-    l [B, H, Sq], acc [B, Sq, H, v])`` in float32, ``None`` to start;
-    ``expanded_finish`` gives ``[B, Sq, H, v]``. A prefill segment attends over
-    the earlier segments' pages block by block, so the scores of a 21k-token
-    context never exist at once; the learner's rows are one block. The shared
-    ``k_pe`` is contracted on its own: it is never copied a head."""
+    a running softmax (flash-style, in XLA: the block's float32 scores are an
+    array in HBM). ``carry = (m [B, H, Sq], l [B, H, Sq], acc [B, Sq, H, v])``
+    in float32, ``None`` to start; ``expanded_finish`` gives ``[B, Sq, H, v]``.
+    ``full`` mode's one form (the learner's rows are one block, and this is
+    what it differentiates); a prefill segment's where ``expanded_segment``
+    does not take the kernel, and the kernel's reference. The shared ``k_pe``
+    is contracted on its own: it is never copied a head."""
     b, sq, h, nope = q_nope.shape
     m, l, acc = carry or expanded_start(b, sq, h, kv.shape[-1] - nope)
     scale = (nope + q_pe.shape[-1]) ** -0.5
@@ -94,6 +111,235 @@ def expanded_attention(
         "bhqk,bkhd->bqhd", p.astype(kv.dtype), kv[..., nope:],
         preferred_element_type=jnp.float32)
     return m_new, l, acc
+
+
+def dispatch_key(heads: int, nope: int, rope: int, v_dim: int, segment: int,
+                 dtype) -> tuple:
+    """The key ``expanded_segment`` records its choice under: everything of a
+    segment but its rows, which the choice does not depend on."""
+    return (heads, nope, rope, v_dim, segment, jnp.dtype(dtype).name)
+
+
+def expanded_segment_impl(q_nope: jax.Array, v_dim: int) -> str:
+    """The form the folds of a prefill segment of ``q_nope [B, S, H, nope]``
+    take: "kernel" on a TPU backend for bf16 operands whose head sizes (K's and
+    V's, one width) and segment are whole 128-lane tiles, "xla" otherwise (the
+    CPU, the tests' tiny heads, float32). On the TPU nothing falls back: a
+    kernel that fails to lower fails the segment that called it."""
+    s, nope = q_nope.shape[1], q_nope.shape[-1]
+    whole = nope == v_dim and nope % _LANES == 0 and s % _LANES == 0
+    if jax.default_backend() == "tpu" and q_nope.dtype == jnp.bfloat16 and whole:
+        return "kernel"
+    return "xla"
+
+
+def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype) -> jax.Array:
+    """A prefill segment's attention, ``[B, S, H, v]``: queries at positions
+    ``start ..`` (every row alike) over the blocks ``0 .. start // S`` of ``S``
+    keys each, block ``j`` at positions ``j * S ..`` and the last one the
+    segment's own; a query sees the keys at or before it. ``block(j)`` gives
+    ``(kv [B, S, H, nope + v], k_pe [B, S, rope])`` of block ``j``. Each block
+    is folded into the segment's running softmax by the form
+    ``expanded_segment_impl`` names (recorded in ``dispatch_choices``): one
+    ``expanded_fold_kernel`` launch, whose scores never leave VMEM and whose
+    carry keeps the kernel's layout from the first fold to the last, or
+    ``expanded_fold``, which is ``expanded_attention``."""
+    b, s, h, nope = q_nope.shape
+    impl = expanded_segment_impl(q_nope, v_dim)
+    dispatch_choices[dispatch_key(h, nope, q_pe.shape[-1], v_dim, s, q_nope.dtype)] = impl
+    if impl == "kernel":
+        queries, first, finish = fold_queries(q_nope, q_pe), fold_start, fold_finish
+        one = per_device(expanded_fold_kernel)
+    else:
+        queries, first, finish = (q_nope, q_pe), expanded_start, expanded_finish
+        one = expanded_fold
+    carry = jax.lax.fori_loop(
+        0, start // s + 1,
+        lambda j, carry: one(*queries, *block(j), start, j * s, carry),
+        first(b, s, h, v_dim))
+    return finish(carry, dtype)
+
+
+def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None):
+    """``expanded_attention`` with the mask given as two positions: the queries
+    stand at ``q_start ..``, the keys at ``k_start ..``, and a query sees the
+    keys at or before it (every row alike). ``expanded_fold_kernel``'s
+    reference, argument for argument."""
+    b, sq = q_nope.shape[:2]
+    sk = kv.shape[1]
+    mask = (k_start + jnp.arange(sk))[None, :] <= (q_start + jnp.arange(sq))[:, None]
+    return expanded_attention(
+        q_nope, q_pe, kv, k_pe, jnp.broadcast_to(mask, (b, sq, sk)), carry)
+
+
+#: queries and keys of one tile of ``expanded_fold_kernel``: the widest
+#: divisors of the segment no wider than these. Timed on a v5e over one layer's
+#: folds at [4, 16, 1024] (PERF.md §6, PR 50), a fold with its block's product
+#: through W_kvb: 0.543 ms at 1,024 x 1,024 (a head's whole block in one step:
+#: one maximum, as ``expanded_attention`` takes it), 0.564 at 512 x 1,024,
+#: 0.768 at 512 x 512 and 1.18-1.43 at 256 keys; the XLA form takes 2.108
+FOLD_TILE_Q = 1024
+FOLD_TILE_K = 1024
+
+
+def _tile(n: int, most: int) -> int:
+    """The widest tile of whole 128-lane vectors that divides ``n`` and is no
+    wider than ``most``; ``n`` itself where it has none (the tests' blocks)."""
+    fits = [t for t in range(_LANES, min(n, most) + 1, _LANES) if n % t == 0]
+    return max(fits, default=n)
+
+
+def fold_queries(q_nope: jax.Array, q_pe: jax.Array):
+    """A segment's queries as ``expanded_fold_kernel`` reads them, made once a
+    segment: a head's queries together, ``[B, H, S, .]``, the rope part padded
+    with zeros to whole lanes (exact: a product with zero adds nothing)."""
+    pad = -q_pe.shape[-1] % _LANES
+    q_pe = jnp.pad(q_pe, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    return q_nope.transpose(0, 2, 1, 3), q_pe.transpose(0, 2, 1, 3)
+
+
+def fold_start(b: int, sq: int, heads: int, v_dim: int):
+    """``expanded_start`` in the kernel's layout: ``(m [B, H, 1, S],
+    l [B, H, 1, S], acc [B, H, S, v])``."""
+    return (jnp.full((b, heads, 1, sq), NEG_INF, jnp.float32),
+            jnp.zeros((b, heads, 1, sq), jnp.float32),
+            jnp.zeros((b, heads, sq, v_dim), jnp.float32))
+
+
+def fold_carry(carry):
+    """The kernel's carry as ``expanded_attention`` holds it: ``(m [B, H, S],
+    l [B, H, S], acc [B, S, H, v])``."""
+    m, l, acc = carry
+    return m[:, :, 0], l[:, :, 0], acc.transpose(0, 2, 1, 3)
+
+
+def fold_finish(carry, dtype) -> jax.Array:
+    """``expanded_finish`` of the kernel's carry: ``[B, S, H, v]``."""
+    return expanded_finish(fold_carry(carry), dtype)
+
+
+def _column(row: jax.Array) -> jax.Array:
+    """``[1, n]`` along the lanes -> ``[n, 1]`` down the sublanes."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _row(col: jax.Array) -> jax.Array:
+    """``[n, 1]`` down the sublanes -> ``[1, n]`` along the lanes."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _fold_body(pos_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, m_in, l_in, acc_in,
+               m_out, l_out, acc_out, m_s, l_s, acc_s, *, scale: float):
+    """One (row, head, tile of queries) against one tile of keys: the tile's
+    scores, their maximum and their sum live and die in VMEM."""
+    tq, tk = qn_ref.shape[0], kn_ref.shape[0]
+    ki = pl.program_id(3)
+    q0 = pos_ref[0] + pl.program_id(2) * tq  # the tile's first query, its first key
+    k0 = pos_ref[1] + ki * tk
+
+    @pl.when(ki == 0)
+    def _():
+        m_s[...] = _column(m_in[...])
+        l_s[...] = _column(l_in[...])
+        acc_s[...] = acc_in[...]
+
+    def fold(masked: bool):
+        nt = (((1,), (1,)), ((), ()))  # q . k^T
+        s = jax.lax.dot_general(
+            qn_ref[...], kn_ref[...], nt, preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            qp_ref[...], kp_ref[...], nt, preferred_element_type=jnp.float32)
+        s = s * scale
+        if masked:
+            seen = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+                    <= q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0))
+            s = jnp.where(seen, s, NEG_INF)
+        m = m_s[...]
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:  # a query that has seen no key yet: exp(0) of masked scores
+            p = jnp.where(seen, p, 0.0)
+        fix = jnp.exp(m - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * fix + p.sum(axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * fix + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
+
+    # a tile of keys that no query of the tile sees is skipped (it changes
+    # nothing); one that every query sees whole needs no mask
+    pl.when(k0 + tk - 1 <= q0)(functools.partial(fold, False))
+    pl.when((k0 + tk - 1 > q0) & (k0 <= q0 + tq - 1))(functools.partial(fold, True))
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _():
+        m_out[...] = _row(m_s[...])
+        l_out[...] = _row(l_s[...])
+        acc_out[...] = acc_s[...]
+
+
+@functools.partial(jax.jit, static_argnames=("tile_q", "tile_k", "interpret"))
+def expanded_fold_kernel(q_nope, q_pe, kv, k_pe, q_start, k_start, carry, *,
+                         tile_q: int = FOLD_TILE_Q, tile_k: int = FOLD_TILE_K,
+                         interpret: bool = False):
+    """``expanded_attention``'s fold of one block of keys as one Mosaic kernel
+    (a TPU; ``interpret`` for the CPU's tests). The mask is two positions: the
+    queries stand at ``q_start ..``, the keys at ``k_start ..``, and a query
+    sees the keys at or before it. ``q_nope [B, H, S, nope]`` and ``q_pe [B, H,
+    S, lanes]`` from ``fold_queries``, ``kv [B, Sk, H, nope + v]`` and ``k_pe
+    [B, Sk, rope]`` as ``expanded_attention`` takes them, ``carry`` from
+    ``fold_start`` or an earlier fold, updated in place.
+
+    Grid (B, H, tiles of queries, tiles of keys), the keys innermost: a tile of
+    queries keeps its running softmax in VMEM from its first tile of keys to
+    its last, and a tile's scores, their maximum and their sum exist only
+    there. bf16 operands into float32 products, float32 ``m`` and ``l``, the
+    weights cast to the values' type before their product: ``expanded_
+    attention``'s roundings. ``k_pe`` stays one vector a key, contracted on its
+    own. Tiles of keys past the last one a tile of queries can see are neither
+    copied nor computed."""
+    b, h, s, nope = q_nope.shape
+    sk, v_dim, rope = kv.shape[1], kv.shape[-1] - nope, q_pe.shape[-1]
+    if nope != v_dim:
+        raise ValueError(
+            f"the kernel reads K and V as blocks of one width, got {nope} and {v_dim}")
+    tq, tk = _tile(s, tile_q), _tile(sk, tile_k)
+    pos = jnp.stack([q_start, k_start]).astype(jnp.int32)
+    scale = (nope + k_pe.shape[-1]) ** -0.5
+    k_pe = jnp.pad(k_pe, ((0, 0), (0, 0), (0, rope - k_pe.shape[-1])))
+    kv = kv.transpose(0, 2, 1, 3)  # [B, H, Sk, nope + v]: a head's keys together
+
+    def keys(i, j, pos):
+        """The tile of keys that step ``j`` of queries' tile ``i`` reads: past
+        the last one a query of the tile sees, that one again (no new copy)."""
+        last = jnp.maximum(pos[0] + (i + 1) * tq - 1 - pos[1], 0) // tk
+        return jnp.minimum(j, last)
+
+    of_q = lambda w: pl.BlockSpec((None, None, tq, w), lambda b, h, i, j, pos: (b, h, i, 0))
+    of_kv = lambda part: pl.BlockSpec(
+        (None, None, tk, nope), lambda b, h, i, j, pos: (b, h, keys(i, j, pos), part))
+    stat = pl.BlockSpec((None, None, 1, tq), lambda b, h, i, j, pos: (b, h, 0, i))
+    return tuple(pl.pallas_call(
+        functools.partial(_fold_body, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, s // tq, sk // tk),
+            in_specs=[
+                of_q(nope), of_q(rope), of_kv(0), of_kv(1),
+                pl.BlockSpec((None, tk, rope),
+                             lambda b, h, i, j, pos: (b, keys(i, j, pos), 0)),
+                stat, stat, of_q(v_dim),
+            ],
+            out_specs=[stat, stat, of_q(v_dim)],
+            scratch_shapes=[pltpu.VMEM((tq, 1), jnp.float32),
+                            pltpu.VMEM((tq, 1), jnp.float32),
+                            pltpu.VMEM((tq, v_dim), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32) for x in carry],
+        input_output_aliases={6: 0, 7: 1, 8: 2},  # the carry, in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(pos, q_nope, q_pe, kv, kv, k_pe, *carry))
 
 
 def expanded_start(b: int, sq: int, heads: int, v_dim: int):

@@ -194,6 +194,13 @@ OPS_SPARSE_KERNEL_STEPS = "ops/sparse_kernel_steps"
 # small heads, a bf16 state). Filed beside the two counters above; no metric
 # reads it
 OPS_POWER_KERNEL_STEPS = "ops/power_kernel_steps"
+# counter: a round's folds of a block of keys into a latent-attention prefill
+# segment's running softmax that ran as the Mosaic kernel
+# (ops/latent_attention.py::expanded_fold_kernel): latent layers x the
+# prefill's folds (segment j makes j + 1) where ``expanded_segment`` chose it,
+# 0 where it took the XLA form (a CPU, small heads, float32). Filed beside the
+# three counters above; no metric reads it
+OPS_LATENT_KERNEL_FOLDS = "ops/latent_kernel_folds"
 # device scopes: the train step (learner/). JAX writes the rest of the path:
 # ``transpose(jvp(learner/loss))`` is the backward pass and
 # ``rematted_computation`` under it the recomputed forward

@@ -191,6 +191,135 @@ def test_absorbed_equals_expanded_with_an_adapter_on_kv_b(blocks):
         la.absorbed_output(carry, w_v, jnp.float32), want, atol=2e-5)
 
 
+# ------------------------------------------- a prefill segment's fold kernel
+
+FOLD = dict(b=2, s=256, h=2, nope=16, rope=8, v_dim=16)
+
+
+def fold_case(seed, b, s, h, nope, rope, v_dim):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(keys[0], (b, s, h, nope)),
+            jax.random.normal(keys[1], (b, s, h, rope)),
+            jax.random.normal(keys[2], (b, s, h, nope + v_dim)),
+            jax.random.normal(keys[3], (b, s, rope)))
+
+
+@pytest.mark.parametrize("case,q_start,real", [
+    ("the_causal_fold_last", 512, None),
+    # row 1's tokens end 100 into the segment: its later queries and keys are
+    # the engine's padding (zero rows), which the positions still order
+    ("a_row_ends_mid_segment", 512, 100),
+    # queries 64 tokens short of the third block: its last tile of keys is
+    # seen by no query of the first tile, and only in part by the second
+    ("queries_across_a_block", 448, None),
+    # the first 32 queries stand before every key: they have seen none, keep
+    # the start's (m, l, acc) and finish as zeros
+    ("a_query_that_has_seen_no_key", -32, None),
+])
+def test_the_fold_kernel_is_expanded_attention(case, q_start, real):
+    """``expanded_fold_kernel`` (interpreted, tiles of 128 so that a block is
+    2 x 2 of them) against ``expanded_attention`` in float32, three blocks of
+    keys at 0, 256 and 512 chained, the one that crosses the queries' own
+    positions last: the carry after every fold and the finished output."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    b, s, h, v_dim = FOLD["b"], FOLD["s"], FOLD["h"], FOLD["v_dim"]
+    q_nope, q_pe, _, _ = fold_case(0, **FOLD)
+    live = jnp.ones((b, s, 1))
+    if real is not None:
+        live = live.at[1, real:].set(0.0)
+        q_nope, q_pe = q_nope * live[..., None], q_pe * live[..., None]
+    want, got = la.expanded_start(b, s, h, v_dim), la.fold_start(b, s, h, v_dim)
+    heads = la.fold_queries(q_nope, q_pe)
+    for j in range(3):
+        _, _, kv, k_pe = fold_case(j + 1, **FOLD)
+        if j == 2:
+            kv, k_pe = kv * live[..., None], k_pe * live
+        mask = (j * s + jnp.arange(s))[None, :] <= (q_start + jnp.arange(s))[:, None]
+        want = la.expanded_attention(
+            q_nope, q_pe, kv, k_pe, jnp.broadcast_to(mask, (b, s, s)), want)
+        by_positions = la.expanded_fold(
+            q_nope, q_pe, kv, k_pe, q_start, j * s, None if j == 0 else by_positions)
+        got = la.expanded_fold_kernel(
+            *heads, kv, k_pe, jnp.int32(q_start), jnp.int32(j * s), got,
+            tile_q=128, tile_k=128, interpret=True)
+        for name, x, y in zip("mla", la.fold_carry(got), want):
+            # l and acc are sums of up to 768 weights: 2e-5 of their size
+            np.testing.assert_allclose(
+                x, y, rtol=2e-5, atol=2e-5, err_msg=f"{name} after fold {j}")
+    for x, y in zip(by_positions, want):  # the XLA form the segment takes elsewhere
+        np.testing.assert_array_equal(x, y)
+    out = la.fold_finish(got, jnp.float32)
+    np.testing.assert_allclose(out, la.expanded_finish(want, jnp.float32), atol=2e-5)
+    if q_start < 0:
+        assert (np.asarray(out)[:, :-q_start] == 0).all()
+        assert (np.asarray(got[1])[..., :-q_start] == 0).all()
+
+
+@pytest.mark.parametrize("backend,dtype,nope,v_dim,segment,want", [
+    ("tpu", jnp.bfloat16, 128, 128, 1024, "kernel"),  # the Kimi cell's segment
+    ("tpu", jnp.bfloat16, 128, 128, 384, "kernel"),
+    ("cpu", jnp.bfloat16, 128, 128, 1024, "xla"),
+    ("tpu", jnp.bfloat16, 16, 16, 1024, "xla"),  # the tests' tiny heads
+    ("tpu", jnp.float32, 128, 128, 1024, "xla"),
+    ("tpu", jnp.bfloat16, 128, 128, 1000, "xla"),  # no whole tiles of queries
+    ("tpu", jnp.bfloat16, 128, 256, 1024, "xla"),  # K and V of two widths
+])
+def test_the_segments_form_is_read_off_the_backend_and_the_shapes(
+        monkeypatch, backend, dtype, nope, v_dim, segment, want):
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q_nope = jax.ShapeDtypeStruct((4, segment, 16, nope), dtype)
+    assert la.expanded_segment_impl(q_nope, v_dim) == want
+
+
+def test_a_segment_records_the_form_it_took(monkeypatch):
+    """``expanded_segment`` files its choice under the segment's geometry, and
+    on the CPU it is the XLA form: the parent's folds, mask and all."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(la, "dispatch_choices", {})
+    small = dict(FOLD, s=16)
+    q_nope, q_pe, _, _ = fold_case(0, **small)
+    blocks = [fold_case(j + 1, **small)[2:] for j in range(3)]
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *blocks)
+    block = lambda j: jax.tree_util.tree_map(lambda x: x[j], stacked)
+    got = la.expanded_segment(q_nope, q_pe, block, jnp.int32(32), 16, jnp.float32)
+    assert la.dispatch_choices == {la.dispatch_key(2, 16, 8, 16, 16, jnp.float32): "xla"}
+    carry = None
+    for j, (kv, k_pe) in enumerate(blocks):
+        carry = la.expanded_fold(q_nope, q_pe, kv, k_pe, 32, j * 16, carry)
+    np.testing.assert_allclose(got, la.expanded_finish(carry, jnp.float32), atol=1e-6)
+
+
+def test_full_mode_never_asks_for_the_kernel(weights, monkeypatch):
+    """The learner's and the no-cache forward differentiate
+    ``expanded_attention``: their program is the same whatever the backend
+    answers, holds no custom call, and never reaches ``expanded_segment``."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    ids = jnp.ones((2, 32), jnp.int32)
+
+    def lowered():
+        step = jax.jit(lambda p, l, i: forward(p, CFG, i, lora=l, lora_scale=LORA_SCALE)[0])
+        grad = jax.jit(jax.grad(lambda l, p, i: forward(
+            p, CFG, i, lora=l, lora_scale=LORA_SCALE)[0].sum()))
+        return step.lower(params, lora, ids).as_text(), grad.lower(lora, params, ids).as_text()
+
+    on_cpu = lowered()
+
+    def refuse(*args, **kw):
+        raise AssertionError("full mode reached the prefill segment's dispatch")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(la, "expanded_segment", refuse)
+    monkeypatch.setattr(la, "expanded_fold_kernel", refuse)
+    assert lowered() == on_cpu
+    assert "custom_call" not in on_cpu[0] and "custom_call" not in on_cpu[1]
+
+
 # ------------------------------------------------ the walk over shared pages
 
 WALK = dict(heads=4, nope=16, rope=8, v_dim=16, rank=32, row=48, ps=8, per=3,
@@ -556,6 +685,77 @@ def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
         once = np.asarray([[5 // 6 * 6], [7 // 6 * 6]])
         assert moved("engine/latent_pages_read") == 3 * (once + 4 * (held - once)).sum()
     assert moved("engine/latent_pages_read") <= moved("engine/latent_pages_attended")
+
+
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_cpu_round_counts_no_kernel_folds(weights, small_pieces, scheduler, slots):
+    """``ops/latent_kernel_folds`` is filed by both schedulers and reads 0 here:
+    float32 heads of 16 on a CPU take the XLA form, and ``expanded_segment``
+    says so under the segment's geometry."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    before = telemetry.observe_snapshot()["counters"].get(
+        telemetry.OPS_LATENT_KERNEL_FOLDS, 0)
+    make_engine(scheduler, slots).generate(
+        params, lora, *prompts((40, 57)),
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=4),
+        jax.random.PRNGKey(3))
+    assert la.dispatch_choices[la.dispatch_key(4, 16, 8, 16, 16, jnp.float32)] == "xla"
+    after = telemetry.observe_snapshot()["counters"]
+    assert after[telemetry.OPS_LATENT_KERNEL_FOLDS] == before  # filed, and 0
+
+
+@pytest.mark.parametrize("ran,want", [("kernel", 3 * 10), ("xla", 0), (None, 0)])
+def test_the_counter_is_layers_times_folds_where_the_kernel_ran(monkeypatch, ran, want):
+    """A prefill of 64 tokens in segments of 16 makes 1 + 2 + 3 + 4 folds in
+    each of the 3 layers (the Kimi cell: 7 x 210 = 1,470)."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
+    monkeypatch.setattr(la, "dispatch_choices", {} if ran is None else {
+        la.dispatch_key(4, 16, 8, 16, 16, jnp.float32): ran,
+        la.dispatch_key(4, 16, 8, 16, 32, jnp.float32): "kernel"})  # another segment's
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_latent_telemetry(CFG, 8, 8, jnp.float32)
+    assert filed == [("ops/latent_kernel_folds", want)]
+    assert paged_engine._hybrid_segments(8, 8) == (16, 4)
+    # a model without latent layers files nothing
+    filed.clear()
+    paged_engine._record_latent_telemetry(PRESETS["tiny"], 8, 8, jnp.float32)
+    assert filed == []
+
+
+def test_a_prefill_through_the_kernel_equals_the_reference(weights, small_pieces,
+                                                           monkeypatch):
+    """The engine's prefill with every fold run by ``expanded_fold_kernel``
+    (interpreted; the dispatch answered for it): prompts of 40 and 57 tokens in
+    segments of 16, so both rows end mid-segment and their last segments' later
+    queries are padding. The captured log-probabilities are the reference's,
+    and the counter reads 3 layers x (1 + 2 + 3 + 4) folds."""
+    import functools
+
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    ids, mask = prompts((40, 57))
+    monkeypatch.setattr(la, "expanded_segment_impl", lambda q_nope, v_dim: "kernel")
+    monkeypatch.setattr(la, "expanded_fold_kernel", functools.partial(
+        la.expanded_fold_kernel, interpret=True))
+    before = telemetry.observe_snapshot()["counters"].get(
+        telemetry.OPS_LATENT_KERNEL_FOLDS, 0)
+    result = make_engine("waves", 0).generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=2, max_tokens=4),
+        jax.random.PRNGKey(3))
+    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    after = telemetry.observe_snapshot()["counters"][telemetry.OPS_LATENT_KERNEL_FOLDS]
+    assert after - before == 3 * 10
 
 
 def test_slots_of_mixed_prompts_fetch_every_page_a_row(weights, small_pieces):

@@ -246,6 +246,19 @@ def test_page_write_keeps_the_pool_layout(chip, pool, page_size, width, consumer
         assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def _kimi_cell():
+    """Kimi-VL-A3B's language model as ``kimi-vl-a3b-L7`` runs it: 7 layers,
+    every width as published."""
+    from distrl_llm_tpu.models import ModelConfig
+
+    return ModelConfig(
+        vocab_size=163840, hidden_size=2048, intermediate_size=11264, num_layers=7,
+        num_heads=16, num_kv_heads=16, head_dim=192, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=64, n_shared_experts=2, experts_per_token=6,
+        moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446)
+
+
 def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     """One layer's fragment of Kimi-VL-A3B's decode step at the cell's sizes
     (64 rows, a table of 165 pages of 128 rows of 576 values in 640 lanes, 64 experts of
@@ -255,16 +268,11 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     and the experts' products read their layer out of the stack in place: a
     layer copied out of it first (as ``lax.ragged_dot``'s custom call had it)
     is 369 MB a matrix, three a layer, 6.6 GB of temporaries a step."""
-    from distrl_llm_tpu.models import ModelConfig, moe
+    from distrl_llm_tpu.models import moe
     from distrl_llm_tpu.models.hybrid import _latent_mix, _latent_page_walk
     from distrl_llm_tpu.models.transformer import _proj
 
-    cfg = ModelConfig(
-        vocab_size=163840, hidden_size=2048, intermediate_size=11264, num_layers=7,
-        num_heads=16, num_kv_heads=16, head_dim=192, kv_lora_rank=512,
-        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-        n_routed_experts=64, n_shared_experts=2, experts_per_token=6,
-        moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446)
+    cfg = _kimi_cell()
     pool, layers = (960, 128, 640), 6
 
     def fragment(pages, q, c, k_pe, lengths, table, w_kvb, h, p):
@@ -300,6 +308,52 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     # a group's shared blocks are gathered once, 16 pages for its 16 rows, and a
     # row's own columns 8 pages at a time: never 16 pages for each of 16 rows
     assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
+
+
+def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch):
+    """``rollout-longctx-latent``'s prefill (4 prompts of 20,480 in segments of
+    1,024 through Kimi-VL-A3B's 7 layers at the published widths): on a TPU
+    every fold of a block of keys is ``expanded_fold_kernel`` under
+    ``model/attn_core``, a block's float32 scores ``[4, 16, 1024, 1024]``
+    (268 MB, written and re-read about six times a fold by the XLA form) are
+    no buffer of the program, and its temporaries are under the XLA form's
+    (the program of the tree before the kernel: what a CPU backend answers
+    for). Two compiles of the whole prefill, 35 s each."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import init_lora_params, init_params
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    cfg = _kimi_cell()
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(
+        init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    prefill = functools.partial(
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=160, page_size=128,
+        lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
+        total_tokens=20480 + 640)
+    tokens = chip((4, 20480), jnp.int32)
+
+    def compiled(backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        # a function of its own: the trace is not the other backend's, cached
+        program = jax.jit(lambda *args: prefill(*args)).lower(
+            params, lora, tokens, tokens).compile()
+        assert la.dispatch_choices[la.dispatch_key(16, 128, 64, 128, 1024, jnp.bfloat16)] == {
+            "tpu": "kernel", "cpu": "xla"}[backend]
+        return program.as_text(), program.memory_analysis().temp_size_in_bytes
+
+    text, temporaries = compiled("tpu")
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all("model/attn_core" in line for line in calls), calls
+    assert "f32[4,16,1024,1024]" not in text
+    parents_text, parents = compiled("cpu")
+    assert "f32[4,16,1024,1024]" in parents_text and "tpu_custom_call" not in parents_text
+    # 457.6 MB against 464.0 MB when this was written: the scores' buffers
+    # shared their bytes with the expert layers' temporaries, which remain
+    assert temporaries < parents, (temporaries, parents)
 
 
 def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
@@ -1067,6 +1121,31 @@ class TestProgramOverTwoChips:
                 on((ROWS,), jnp.int32), on((ROWS, pps), jnp.int32),
             ).compile().as_text()
         assert text.count("tpu_custom_call") >= 2
+
+    def test_latent_prefill_segment(self, two_chips, monkeypatch):
+        """A segment's folds through ``per_device``: the kernel's scalars and
+        its carry of three arrays replicated like its other operands."""
+        from distrl_llm_tpu.ops import latent_attention as la
+
+        mesh, on = two_chips
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        b, s, h = 4, 1024, 16
+
+        def segment(q_nope, q_pe, latent, k_pe, w, start):
+            def block(j):
+                kv = jax.lax.dynamic_index_in_dim(latent, j, 0, keepdims=False) @ w
+                return (kv.reshape(b, s, h, 256),
+                        jax.lax.dynamic_index_in_dim(k_pe, j, 0, keepdims=False))
+
+            return la.expanded_segment(q_nope, q_pe, block, start, 128, jnp.bfloat16)
+
+        bf = jnp.bfloat16
+        with jax.set_mesh(mesh):
+            text = jax.jit(segment).lower(
+                on((b, s, h, 128), bf), on((b, s, h, 64), bf), on((3, b, s, 512), bf),
+                on((3, b, s, 64), bf), on((512, h * 256), bf), on((), jnp.int32),
+            ).compile().as_text()
+        assert "tpu_custom_call" in text and "f32[4,16,1024,1024]" not in text
 
     def test_dequant_matmul_forward_and_backward(self, two_chips):
         from distrl_llm_tpu.ops.quant_matmul import quant_matmul
