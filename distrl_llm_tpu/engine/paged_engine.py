@@ -118,6 +118,10 @@ ENGINE_SSM_STATES_STEPPED = "engine/ssm_states_stepped"    # counter
 # kind: recurrent states, normalisers, convolution tails, pooled keys), filed
 # when a round's decode state is built, with tracing on or off
 ENGINE_SLOT_STATE_BYTES = "engine/slot_state_bytes"        # gauge
+# a hybrid model's staged prefill (``_paged_prefill_hybrid``): the round's real
+# prompt tokens over the tokens its stages ran, x 100, from host arithmetic
+# over the prompts' lengths and the ladder; filed a round, tracing on or off
+ENGINE_PREFILL_REAL_SHARE = "engine/prefill_real_share"    # gauge
 # absorbed latent attention (ops/latent_attention.py): live (row, page) pairs
 # a round's decode steps attended over, and live pages they fetched from the
 # pool (a page that a group's rows share is fetched once a group), summed over
@@ -291,18 +295,21 @@ def _record_power_telemetry(cfg: ModelConfig, steps: int) -> None:
 
 
 def _record_latent_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
-                             dtype) -> None:
+                             dtype, segments: int | None = None) -> None:
     """``ops/latent_kernel_folds``: the folds of the round's prefill (one call,
-    every latent layer, segment ``j`` folding ``j + 1`` blocks of keys) that
-    ran as the Mosaic kernel, read from what ``expanded_segment`` recorded for
-    this model's heads and segment when the prefill was traced (0 where it
-    took the XLA form); ``dtype`` is the activations', the embedding's. A
-    model without latent layers files nothing."""
+    every latent layer, segment ``j`` folding ``j + 1`` blocks of keys, whatever
+    stage runs it and for as many rows as the stage holds) that ran as the
+    Mosaic kernel, read from what ``expanded_segment`` recorded for this
+    model's heads and segment when the prefill was traced (0 where it took the
+    XLA form); ``dtype`` is the activations', the embedding's; ``segments`` the
+    longest row's, where the stages end (every segment of the prompt's width
+    where it is not given). A model without latent layers files nothing."""
     if not cfg.latent:
         return
     from distrl_llm_tpu.ops.latent_attention import dispatch_choices, dispatch_key
 
     seg, n_seg = _hybrid_segments(prompt_pages, page_size)
+    n_seg = n_seg if segments is None else segments
     ran = dispatch_choices.get(dispatch_key(
         cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
         seg, dtype))
@@ -357,8 +364,18 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
     return cache["k"], cache["v"], logits[:, 0], real_len
 
 
-#: tokens of one prefill segment of a model whose layers differ in kind
+#: tokens of one prefill segment of a model whose layers differ in kind. A
+#: hybrid prefill runs its segments in STAGES: stage k runs the ``b_k`` longest
+#: rows, one segment a trip, until the longest row it drops has ended
+#: (``_paged_prefill_hybrid``); ``_stage_sizes`` is the rule that gives the
+#: ``b_k`` of a batch
 HYBRID_PREFILL_SEGMENT = 1024
+
+#: segment bodies one prefill program may hold, whatever its batch: a body is
+#: every layer unrolled once more, and each is compiled, kept in the persistent
+#: cache and traced, lowered and loaded at every start (2.3-2.9 s of a warm
+#: start and 20-50 s of a cold one in the long-context cells: PERF.md §6, PR 52)
+HYBRID_PREFILL_STAGES = 2
 
 
 def _hybrid_segments(prompt_pages: int, page_size: int) -> tuple[int, int]:
@@ -373,16 +390,87 @@ def _hybrid_segments(prompt_pages: int, page_size: int) -> tuple[int, int]:
     return seg_pages * page_size, prompt_pages // seg_pages
 
 
+def _stage_sizes(b: int, segments: int) -> tuple[int, ...]:
+    """The ladder of a hybrid prefill of ``b`` rows whose prompts hold
+    ``segments`` segments: the batch of each stage, ``b`` first and strictly
+    falling. A second stage holds half the rows, rounded down and never under
+    two; there is one where the prompt has four segments or more (a stage for
+    every two segments, HYBRID_PREFILL_STAGES at most: with fewer a second body
+    has little to save and costs what any body costs). A prefill of one or two
+    rows, or of prompts under four segments, is one stage. It reads the shapes
+    alone: one program serves every mix of lengths.
+
+    Why half: over lengths spread evenly, the rows a second stage saves times
+    the segments it saves them is largest there. Why no stage of a single row
+    (PERF.md §6, PR 52): a body is compiled for its batch, and the compiler
+    builds a one-row body unlike the others (the retention cell's is 2.6 times
+    the two-row body's text, 8 MB more executable to load at every start;
+    MiniCPM-SALA's takes 1.3 GB more temporaries, and its trips run no faster
+    than two rows'), so the last row's segments cost a body's set-up and buy
+    the least."""
+    stages = max(min(HYBRID_PREFILL_STAGES, segments // 2), 1)
+    sizes = {min(b, max(b >> k, 2)) for k in range(stages)}
+    return tuple(sorted(sizes, reverse=True))
+
+
+def _stage_trips(segments, sizes: tuple[int, ...]) -> list[tuple]:
+    """``(first, end)`` segment of each stage of the ladder ``sizes``, from
+    ``segments [b]``, the segments each row holds a real token in, LONGEST
+    FIRST: stage k runs rows ``0 .. sizes[k]`` from where stage k - 1 ended to
+    the end of the longest row that stage k + 1 drops, the last stage to the
+    end of the longest row. Traced values inside the program and numpy's on
+    the host give the same trips."""
+    ends = [segments[size] for size in sizes[1:]] + [segments[0]]
+    return list(zip([0, *ends[:-1]], ends))
+
+
+def _file_prefill_share(prompt_lens, prompt_pages: int, page_size: int) -> int:
+    """File the gauge ``engine/prefill_real_share`` for a round's one prefill:
+    real prompt tokens over the tokens the program's stages ran, x 100, from
+    host arithmetic over the prompts' lengths and the ladder, nothing fetched
+    from the device (100 where every row ends with the stage that drops it;
+    under it by partly filled last segments and by the rows the ladder
+    carries past their end). Returns the segments of the longest row, where
+    the stages end: ``ops/latent_kernel_folds`` is counted off it."""
+    seg, n_seg = _hybrid_segments(prompt_pages, page_size)
+    lens = np.sort(np.asarray(prompt_lens, np.int64))[::-1]
+    segments = -(-lens // seg)
+    sizes = _stage_sizes(len(lens), n_seg)
+    ran = sum(size * int(end - first)
+              for size, (first, end) in zip(sizes, _stage_trips(segments, sizes)))
+    telemetry.gauge_set(
+        ENGINE_PREFILL_REAL_SHARE, 100.0 * int(lens.sum()) / max(ran * seg, 1))
+    return int(segments[0])
+
+
 def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
                           cfg: ModelConfig, prompt_pages: int, page_size: int,
                           lora_scale: float, cache_dtype, attn_impl: str,
                           total_tokens: int):
     """``_paged_prefill`` for a model with sparse and lightning layers: the
-    packed prompts run SEGMENT after segment (every row at the same
-    page-aligned offset), each through all layers, the cache carried between
-    them: K/V pages and pooled selector keys for the sparse layers, a state
-    for each lightning layer. A 20k-token prompt run whole would hold the
-    MLP's activations and a sparse layer's scores for all of it at once.
+    packed prompts run SEGMENT after segment, each through all layers, the
+    cache carried between them: K/V pages and pooled selector keys for the
+    sparse layers, a state for each lightning layer. A 20k-token prompt run
+    whole would hold the MLP's activations and a sparse layer's scores for all
+    of it at once.
+
+    The segments run in STAGES of a shrinking batch, so that few rows run a
+    segment after the one that holds their last real token: the rows are
+    sorted by length inside the program, longest first, and stage k runs the
+    first ``_stage_sizes(b, segments)[k]`` of them, every row of the stage at
+    the same page-aligned offset, for a TRACED number of segments
+    (``_stage_trips``: to the end of the longest row the next stage drops).
+    Nothing about the lengths is static: one program for every mix. A sorted
+    row writes its own pages through its own row of the page table, so the
+    pools come out in the caller's order; the row states are carried in sorted
+    order, a stage's dropped rows set aside as they end, and put back in the
+    caller's order once, after the last stage (a ladder of one size drops no
+    row and sorts none: the one loop a prefill of one or two rows always was,
+    to the longest row's end). A row that rides on in a stage
+    with a longer one (the ladder skips sizes, and holds no stage of one row)
+    runs those segments masked, as every row did before the stages: its states
+    and logits stay those of its last real token, and the pages past its end
+    take what nobody reads. Rows that are all empty run nothing.
 
     Returns ``(k tiles, v tiles, logits, real_len, mixer)``: ``mixer`` is each
     PROMPT's state after its last real token and its pooled keys, which a
@@ -393,51 +481,64 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
     b, p = prompt_ids.shape
     pad_to = prompt_pages * page_size
     seg, n_seg = _hybrid_segments(prompt_pages, page_size)
+    sizes = _stage_sizes(b, n_seg)
+    staged = len(sizes) > 1  # one stage drops no row: the rows keep the caller's order
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         packed_ids, packed_mask, real_len = _pack_rows(prompt_ids, prompt_mask)
-        packed_ids = jnp.pad(packed_ids, ((0, 0), (0, pad_to - p)))
-        packed_mask = jnp.pad(packed_mask, ((0, 0), (0, pad_to - p)))
+        order = jnp.argsort(-real_len, stable=True)  # longest first
+        trips = _stage_trips(-(-real_len[order] // seg), sizes)
+        place = (lambda x: x[order]) if staged else (lambda x: x)
+        ids = place(jnp.pad(packed_ids, ((0, 0), (0, pad_to - p))))
+        mask = place(jnp.pad(packed_mask, ((0, 0), (0, pad_to - p))))
+        table = place(jnp.asarray(make_page_table(b, pad_to, page_size)))
+        sorted_len = place(real_len)
+        last = jnp.maximum(sorted_len - 1, 0)
     shape = cfg.page_pool_shape(b * prompt_pages, page_size)
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         mixer = init_mixer_state(cfg, b, total_tokens, cache_dtype)
         pool = lambda: tuple(
             jnp.zeros(shape, cache_dtype) for _ in range(cfg.paged_layers))
-        cache = {
-            # a latent layer's pages are one array: there is no V pool
-            "k": pool(), "v": () if cfg.latent else pool(),
-            **_row_states(mixer),
-        }
-    table = jnp.asarray(make_page_table(b, pad_to, page_size))
-    last = jnp.maximum(real_len - 1, 0)
+        # a latent layer's pages are one array: there is no V pool
+        pools = {"k": pool(), "v": () if cfg.latent else pool()}
+        # every row's states start alike: these are the sorted rows' too
+        rows = (_row_states(mixer),
+                jnp.zeros((b, cfg.hidden_size), params["final_norm"].dtype))
 
-    def one_segment(carry, xs):
-        cache, hidden = carry
-        ids, mask, start = xs
+    def one_segment(j, carry):
+        """Segment ``j`` of the stage's rows: the first ``n`` sorted ones."""
+        pools, (states, hidden) = carry
+        n = hidden.shape[0]
+        start = j * seg
         x, out = forward(
-            params, cfg, ids, attention_mask=mask, lora=lora,
-            lora_scale=lora_scale, attn_impl=attn_impl, page_size=page_size,
-            kv_cache={**cache, "lengths": real_len, "page_indices": table,
-                      "segment_start": start},
+            params, cfg, jax.lax.dynamic_slice_in_dim(ids[:n], start, seg, axis=1),
+            attention_mask=jax.lax.dynamic_slice_in_dim(mask[:n], start, seg, axis=1),
+            lora=lora, lora_scale=lora_scale, attn_impl=attn_impl, page_size=page_size,
+            kv_cache={**pools, **states, "lengths": sorted_len[:n],
+                      "page_indices": table[:n], "segment_start": start},
             # the row's last real token, if it lies in this segment
-            logits_positions=jnp.clip(last - start, 0, seg - 1),
+            logits_positions=jnp.clip(last[:n] - start, 0, seg - 1),
             skip_lm_head=True,
         )
-        here = (last >= start) & (last < start + seg)
+        here = (last[:n] >= start) & (last[:n] < start + seg)
         hidden = jnp.where(here[:, None], x[:, 0], hidden)
-        return ({name: out[name] for name in cache}, hidden), None
+        return ({name: out[name] for name in pools},
+                ({name: out[name] for name in states}, hidden))
 
-    segments = (
-        packed_ids.reshape(b, n_seg, seg).swapaxes(0, 1),
-        packed_mask.reshape(b, n_seg, seg).swapaxes(0, 1),
-        jnp.arange(n_seg, dtype=jnp.int32) * seg,
-    )
-    hidden0 = jnp.zeros((b, cfg.hidden_size), params["final_norm"].dtype)
-    (cache, hidden), _ = jax.lax.scan(one_segment, (cache, hidden0), segments)
+    ended = []  # the rows each stage drops as it ends, the last stage's all
+    for (first, end), keep in zip(trips, (*sizes[1:], 0)):
+        pools, rows = jax.lax.fori_loop(first, end, one_segment, (pools, rows))
+        ended.append(jax.tree_util.tree_map(lambda x: x[keep:], rows))
+        rows = jax.tree_util.tree_map(lambda x: x[:keep], rows)
+    states, hidden = ended[0]
+    if staged:
+        with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
+            back = jnp.argsort(order)  # sorted row i is the caller's row order[i]
+            states, hidden = jax.tree_util.tree_map(
+                lambda *parts: jnp.concatenate(parts)[back], *reversed(ended))
     with jax.named_scope(telemetry.MODEL_HEAD):
         head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
         logits = linear(hidden, head).astype(jnp.float32)
-    mixer = {**mixer, **_row_states(cache)}
-    return cache["k"], cache["v"], logits, real_len, mixer
+    return pools["k"], pools["v"], logits, real_len, {**mixer, **states}
 
 
 def _row_states(mixer) -> dict:
@@ -2479,6 +2580,7 @@ class PagedGenerationEngine(LoraMailbox):
         row_alive = real_len_h > 0
         ps = self.page_size
         prefill_tokens = int(real_len_h.sum())
+        prompt_segments = None  # a hybrid model's: where its prefill's stages end
         if continuous:
             # lazy per-group prefill (continuous admission): the pool
             # arrays start with ZERO prompt pages — each group's prompt KV
@@ -2515,6 +2617,9 @@ class PagedGenerationEngine(LoraMailbox):
                 prompt_mixer = tuple(held)
                 jax.block_until_ready(last_logits)
             t_prefill = time.perf_counter() - t0
+            if self.cfg.hybrid:
+                prompt_segments = _file_prefill_share(
+                    real_len_h, self.prompt_pages, ps)
         host = RoundHostAccount()
         dec_span = telemetry.span(telemetry.ENGINE_REFILL_DECODE, slots=r_slots,
                                   candidates=total)
@@ -4152,7 +4257,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
         _record_power_telemetry(self.cfg, dispatched)
         _record_latent_telemetry(
-            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype)
+            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
+            prompt_segments)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4179,7 +4285,8 @@ class PagedGenerationEngine(LoraMailbox):
         # this wave's prefill (its rows haven't sampled yet)
         lora = self._round_entry_lora(lora)
 
-        prefill_tokens = int(np.asarray(prompt_mask).sum())
+        prompt_lens = np.asarray(prompt_mask).sum(axis=-1)
+        prefill_tokens = int(prompt_lens.sum())
         t0 = time.perf_counter()
         with telemetry.span(telemetry.ENGINE_PREFILL, rows=b, tokens=prefill_tokens):
             prompt_k, prompt_v, last_logits, real_len, *prompt_mixer = self._prefill(
@@ -4187,6 +4294,9 @@ class PagedGenerationEngine(LoraMailbox):
             )
             jax.block_until_ready(last_logits)
         t_prefill = time.perf_counter() - t0
+        prompt_segments = (
+            _file_prefill_share(prompt_lens, self.prompt_pages, self.page_size)
+            if self.cfg.hybrid else None)
         row_alive = jnp.asarray(prompt_mask).sum(axis=-1) > 0
         host = RoundHostAccount()
         dec_span = telemetry.span(telemetry.ENGINE_DECODE, rows=b * n)
@@ -4276,7 +4386,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
         _record_power_telemetry(self.cfg, steps_seen[0])
         _record_latent_telemetry(
-            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype)
+            self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
+            prompt_segments)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

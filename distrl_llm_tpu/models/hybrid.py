@@ -955,6 +955,32 @@ def _latent_block(x, p, lora, pages, *, moe: bool, cfg: ModelConfig, mode: str,
     return x + routed, pages, stats
 
 
+def _shared_layers(block):
+    """``block`` with the layers of one kind traced and lowered ONCE a call of
+    ``forward``: each is a ``jax.jit`` of its own, so a second layer of the
+    same kind and shapes is a call of the first one's function (the compiler
+    inlines it: the executable is what the unrolled layers give). A prefill
+    program holds a segment body a stage (``paged_engine._paged_prefill_hybrid``)
+    and every body is every layer again: at start-up the tracing and lowering
+    of a body of 28 layers of two kinds is that of two. An expert layer reads
+    its own slice of the stacked experts at a static index
+    (``p["experts_layer"]``), so it shares with no other and is traced in
+    line, as before: a ``jax.jit`` of its own would cost it a trace more."""
+    traced: dict = {}
+
+    def run(kind, x, p, lora_p, rate, held, key):
+        return block(x, p, lora_p, rate, held, kind=kind, dropout_rng=key)
+
+    def call(x, p, lora_p, rate, held, *, kind: str, dropout_rng):
+        if "experts_layer" in p:  # nothing to share, and a jit of its own costs a trace
+            return block(x, p, lora_p, rate, held, kind=kind, dropout_rng=dropout_rng)
+        if kind not in traced:
+            traced[kind] = jax.jit(partial(run, kind))
+        return traced[kind](x, p, lora_p, rate, held, dropout_rng)
+
+    return call
+
+
 def _pack_left(ids, mask):
     """Rows whose real tokens are contiguous, moved to column 0. Returns
     (ids, valid, the column each packed column came from)."""
@@ -1043,6 +1069,8 @@ def forward_hybrid(
     )
     stacks = params["layers"]
     lora_stacks = lora["layers"] if lora is not None else {}
+    if mode == "segment":
+        block = _shared_layers(block)
 
     if mode == "full":
         for kind, first, at, count in cfg.layer_runs:
