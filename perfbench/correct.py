@@ -75,24 +75,15 @@ def pick_rows(lengths: np.ndarray, seed: int, rows: int = CHECK_ROWS,
     return [long_enough[i] for i in order[:rows]]
 
 
-def rollout_rows_check(reference, model_cfg, params, lora, lora_scale: float,
-                       prompt_ids, prompt_mask, result, *, seed: int,
-                       width: int, check: Mapping[str, Any] | None = None,
-                       ) -> dict[str, Any]:
-    """The engine's captured raw log-probabilities of the tokens it sampled,
-    for a few seeded rows (prefill, then decoding through the cache), against
-    the reference's teacher-forced log-probabilities of the same tokens.
-    ``width`` is the static row length the reference is compiled for
-    (prompt cap + answer cap); ``check`` is the traffic file's own, whose
-    ``logprob_mean_abs_tol`` / ``logprob_max_abs_tol`` replace the defaults."""
+def reference_rows(reference, model_cfg, params, lora, lora_scale: float,
+                   prompt_ids, prompt_mask, result, *, seed: int, width: int):
+    """The rows ``rollout_rows_check`` compares: ``pick_rows``' (prompt,
+    candidate) pairs, each row's (prompt length, decoded tokens), and the
+    reference's teacher-forced next-token log-probabilities of each row
+    (prompt, then the tokens the engine sampled), ``[rows, width]``."""
     import jax
     import jax.numpy as jnp
 
-    check = check or {}
-    tol_mean = float(check.get("logprob_mean_abs_tol", LOGPROB_MEAN_ABS_TOL))
-    tol_max = float(check.get("logprob_max_abs_tol", LOGPROB_MAX_ABS_TOL))
-    if result.logprobs is None:
-        return {"ok": False, "why": "the engine captured no log-probabilities"}
     lengths = np.asarray(result.lengths)
     picked = pick_rows(lengths, seed)
     ids = np.zeros((len(picked), width), np.int32)
@@ -110,7 +101,27 @@ def rollout_rows_check(reference, model_cfg, params, lora, lora_scale: float,
             p, model_cfg, i, m, lora=lo, lora_scale=lora_scale
         )
     )
-    want = np.asarray(fn(params, lora, jnp.asarray(ids), jnp.asarray(mask)))
+    return picked, spans, np.asarray(fn(params, lora, jnp.asarray(ids), jnp.asarray(mask)))
+
+
+def rollout_rows_check(reference, model_cfg, params, lora, lora_scale: float,
+                       prompt_ids, prompt_mask, result, *, seed: int,
+                       width: int, check: Mapping[str, Any] | None = None,
+                       ) -> dict[str, Any]:
+    """The engine's captured raw log-probabilities of the tokens it sampled,
+    for a few seeded rows (prefill, then decoding through the cache), against
+    the reference's teacher-forced log-probabilities of the same tokens.
+    ``width`` is the static row length the reference is compiled for
+    (prompt cap + answer cap); ``check`` is the traffic file's own, whose
+    ``logprob_mean_abs_tol`` / ``logprob_max_abs_tol`` replace the defaults."""
+    check = check or {}
+    tol_mean = float(check.get("logprob_mean_abs_tol", LOGPROB_MEAN_ABS_TOL))
+    tol_max = float(check.get("logprob_max_abs_tol", LOGPROB_MAX_ABS_TOL))
+    if result.logprobs is None:
+        return {"ok": False, "why": "the engine captured no log-probabilities"}
+    picked, spans, want = reference_rows(
+        reference, model_cfg, params, lora, lora_scale, prompt_ids, prompt_mask, result,
+        seed=seed, width=width)
     diffs = []
     for r, ((b, j), (p_len, n)) in enumerate(zip(picked, spans)):
         got = np.asarray(result.logprobs[b, j, :n], np.float64)

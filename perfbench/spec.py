@@ -31,41 +31,68 @@ class SpecError(ValueError):
     """``BENCHMARK.json`` or a file it names is missing or inconsistent."""
 
 
-#: the one key of ``reduced`` that cuts depth
+#: the key of ``reduced`` that cuts depth, and the second that may stand beside
+#: it: the count of leading dense layers, of which the first stage holds ONE
 DEPTH_KEY = "num_hidden_layers"
+DENSE_KEY = "first_k_dense_replace"
+DEPTH_KEYS = (DEPTH_KEY, DENSE_KEY)
+#: the fewest layers that follow the one leading dense layer
+MIN_LAYERS_AFTER_DENSE = 4
 #: keys of ``reduced`` that COUNT the routed experts this chip holds of a layer
 #: (whichever the published config uses), and the fewest it may hold
 EXPERT_KEYS = ("n_routed_experts", "num_experts", "num_local_experts")
 MIN_EXPERTS_HELD = 8
-#: the smallest slice of the published vocabulary a chip may hold: an eighth
+#: the smallest slice of the published vocabulary a chip may hold: an eighth.
+#: So the vocabulary lies in at most 8 slices however many chips share a layer
 MIN_VOCAB_SHARE = 8
 #: every key that counts what one chip holds of a layer; any other key of
-#: ``reduced`` but depth is a width
+#: ``reduced`` but the depth keys is a width
 SHARE_KEYS = (*EXPERT_KEYS, "vocab_size", "num_attention_heads", "num_key_value_heads")
 
 
 def check_reduced(config_file: dict[str, Any], where: str) -> None:
-    """What a configuration file may cut from its source, held for
-    ``load_cell`` (a run) and ``test_config_file`` (the tests) alike.
+    """What a configuration file may cut from its source (the guide's section
+    4, whole), held for ``load_cell`` (a run) and ``test_config_file`` (the
+    tests) alike.
+
+    The deployment the cut stands for: each layer is shared by ``n`` chips,
+    its routed experts expert-parallel over the ``n``, the embedding and the
+    head in ``min(n, 8)`` slices of the vocabulary (each on ``n / 8`` chips
+    where ``n > 8``: a chip holds at least an eighth); the layers left out lie
+    on further chips as the stages of a pipeline, and the first stage holds
+    ONE of the model's leading dense layers.
 
     ``reduced`` may hold the depth key and, only beside a ``share`` object,
-    keys that count what THIS chip holds of a layer as one of the
-    ``chips_per_layer`` chips a stated deployment divides each layer over
-    (``SHARE_KEYS``). ``share`` is ``{"chips_per_layer": n, "published":
-    {<key>: <published value>}}`` with one ``published`` entry for every such
-    key and none else; what is held, times ``n``, is what was published (one
-    chip's share, not a number chosen to fit), at least ``MIN_EXPERTS_HELD``
-    routed experts and an eighth of the vocabulary. Any other key is a width,
-    and a width is never cut. The harness does nothing else with ``share``:
-    the whole file goes to ``ModelConfig.from_hf_config``."""
+    keys that count what THIS chip holds of a layer (``SHARE_KEYS``).
+    ``share`` is ``{"chips_per_layer": n, "published": {<key>: <published
+    value>}}`` with one ``published`` entry for every such key and none else.
+    What is held follows from ``n`` and the published count (one chip's share,
+    not a number chosen to fit): for the experts and the heads, held times
+    ``n`` is what was published, and at least ``MIN_EXPERTS_HELD`` routed
+    experts are held; for ``vocab_size`` the divisor is ``n`` up to 8 chips
+    and 8 beyond, where ``n`` is then a multiple of 8.
+
+    ``reduced`` may also hold ``first_k_dense_replace``, a second DEPTH key
+    (leading dense layers count once; it needs no ``share``): only beside
+    ``num_hidden_layers``, only with 1 held, only where the file's ``depth``
+    object ``{"published": {"num_hidden_layers": N, "first_k_dense_replace":
+    K}}`` states the published counts (``K > 1``), and only where at least
+    ``MIN_LAYERS_AFTER_DENSE`` layers follow the one dense layer. ``depth``
+    stands in a file exactly when that key is reduced.
+
+    Any other key is a width, and a width is never cut. The harness does
+    nothing else with ``share`` or ``depth``: the whole file goes to
+    ``ModelConfig.from_hf_config``."""
     reduced = list(config_file.get("reduced", []))
     share = config_file.get("share")
-    share_keys = [k for k in reduced if k != DEPTH_KEY]
+    share_keys = [k for k in reduced if k not in DEPTH_KEYS]
     for key in share_keys:
         if key not in SHARE_KEYS:
             raise SpecError(
                 f"{where}: 'reduced' names {key!r}: a width is never cut (only "
-                f"{DEPTH_KEY!r} and, beside a 'share', the counts {list(SHARE_KEYS)})")
+                f"{DEPTH_KEY!r}, beside it {DENSE_KEY!r} held once, and, beside a "
+                f"'share', the counts {list(SHARE_KEYS)})")
+    _check_dense_once(config_file, reduced, where)
     if share is None:
         if share_keys:
             raise SpecError(
@@ -88,19 +115,78 @@ def check_reduced(config_file: dict[str, Any], where: str) -> None:
             f"{sorted(share_keys)}: one published value for each, and none else")
     for key in share_keys:
         held = config_file.get(key)
-        if not isinstance(held, int) or held * chips != published[key]:
+        divisor, of_what = chips, "chips"
+        if key == "vocab_size" and chips > MIN_VOCAB_SHARE:
+            # more chips than slices: 8 slices, each on chips / 8 of the chips
+            if chips % MIN_VOCAB_SHARE:
+                raise SpecError(
+                    f"{where}: vocab_size is cut and {chips} chips share a layer: past "
+                    f"{MIN_VOCAB_SHARE} chips the vocabulary lies in {MIN_VOCAB_SHARE} "
+                    f"slices, each on chips_per_layer / {MIN_VOCAB_SHARE} chips, so "
+                    f"'chips_per_layer' is a multiple of {MIN_VOCAB_SHARE}")
+            if _is_count(held) and held * chips == published[key]:
+                raise SpecError(
+                    f"{where}: vocab_size holds {held} of {published[key]}; a chip holds "
+                    f"at least an eighth of the vocabulary")
+            divisor, of_what = MIN_VOCAB_SHARE, f"slices over the {chips} chips"
+        if not _is_count(held) or held * divisor != published[key]:
             raise SpecError(
-                f"{where}: {key} holds {held!r}, and {chips} chips of that make "
-                f"{held * chips if isinstance(held, int) else None}, not the "
+                f"{where}: {key} holds {held!r}, and {divisor} {of_what} of that make "
+                f"{held * divisor if _is_count(held) else None}, not the "
                 f"published {published[key]!r}: the share is what one of the chips holds")
         if key in EXPERT_KEYS and held < MIN_EXPERTS_HELD:
             raise SpecError(
                 f"{where}: {key} holds {held} routed experts; a chip holds at least "
                 f"{MIN_EXPERTS_HELD}")
-        if key == "vocab_size" and held * MIN_VOCAB_SHARE < published[key]:
+
+
+def _is_count(value: Any) -> bool:
+    return isinstance(value, int) and not isinstance(value, bool) and value >= 0
+
+
+def _check_dense_once(config_file: dict[str, Any], reduced: list[str], where: str) -> None:
+    """``first_k_dense_replace`` in ``reduced``: leading dense layers count
+    once (``check_reduced`` says the rule)."""
+    depth = config_file.get("depth")
+    if DENSE_KEY not in reduced:
+        if depth is not None:
             raise SpecError(
-                f"{where}: vocab_size holds {held} of {published[key]}; a chip holds "
-                f"at least an eighth of the vocabulary")
+                f"{where}: a 'depth' and no {DENSE_KEY!r} in 'reduced': it states the "
+                "published counts for that cut alone")
+        return
+    if DEPTH_KEY not in reduced:
+        raise SpecError(
+            f"{where}: 'reduced' names {DENSE_KEY!r} and not {DEPTH_KEY!r}: leading "
+            "dense layers count once only where depth is cut (a model at its whole "
+            "depth keeps them all)")
+    wanted = {"published": {DEPTH_KEY: "N", DENSE_KEY: "K"}}
+    if (not isinstance(depth, dict) or set(depth) != {"published"}
+            or not isinstance(depth["published"], dict)
+            or set(depth["published"]) != set(DEPTH_KEYS)):
+        raise SpecError(
+            f"{where}: 'reduced' names {DENSE_KEY!r} and the file's 'depth' is "
+            f"{depth!r}, not {wanted}: the published counts it was cut from")
+    published = depth["published"]
+    layers, dense = config_file.get(DEPTH_KEY), config_file.get(DENSE_KEY)
+    if (not all(map(_is_count, (layers, dense, *published.values())))
+            or published[DENSE_KEY] < 2 or published[DEPTH_KEY] <= layers):
+        raise SpecError(
+            f"{where}: {DEPTH_KEY} {layers!r} and {DENSE_KEY} {dense!r} are held and "
+            f"'depth' publishes {published}: {DENSE_KEY!r} is reduced where the model "
+            "has 2 leading dense layers or more, and more layers than are held")
+    if dense == 0:
+        raise SpecError(
+            f"{where}: {DENSE_KEY} holds 0 of {published[DENSE_KEY]}: the first stage "
+            "holds one of the leading dense layers")
+    if dense != 1:
+        raise SpecError(
+            f"{where}: {DENSE_KEY} holds {dense} of {published[DENSE_KEY]}: leading dense "
+            f"layers count once, so 1 is held (or all, with the key left out of 'reduced')")
+    if layers < 1 + MIN_LAYERS_AFTER_DENSE:
+        raise SpecError(
+            f"{where}: {DEPTH_KEY} holds {layers}: the one dense layer and {layers - 1} "
+            f"after it; at least {MIN_LAYERS_AFTER_DENSE} layers follow the leading "
+            "dense one")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -41,15 +41,14 @@ JOINED = ("rollout_tok_s", "engine.decode_bandwidth_util", "engine.decode_step_m
           "engine.slot_occupancy", "engine.snapshot_wait_ms", "kernel.sampler_share",
           "model.attn_proj_share", "model.mlp_share", "model.head_share",
           "rollout.unscoped_share")
+#: the eight of PR 38 (the round's host account), which the cell joined in
+#: PR 53: until then a test of PR 38 held their lists equal to its four cells
+JOINED += ("engine.dispatch_host_ms", "engine.dispatch_median_ms", "engine.prefill_ms",
+           "engine.readback_ms", "engine.loop_self_ms", "engine.host_busy_share",
+           "engine.slowest_boundary_ms", "engine.slowest_boundary_host_ms")
 #: what it does not report. No layer keeps a page: ``engine.kv_write_share``,
-#: ``kernel.paged_attn_share``, ``paged_attn_roofline``. And the eight of PR 38
-#: (the round's host account), which ISSUE 40 asked for: a test of PR 38 pins
-#: their ``workloads`` to its four cells, and no file under the ``paths`` may be
-#: edited (PERF.md section 7 names the line for a ``benchmark`` PR)
-NOT_JOINED = ("engine.kv_write_share", "kernel.paged_attn_share", "paged_attn_roofline",
-              "engine.dispatch_host_ms", "engine.dispatch_median_ms", "engine.prefill_ms",
-              "engine.readback_ms", "engine.loop_self_ms", "engine.host_busy_share",
-              "engine.slowest_boundary_ms", "engine.slowest_boundary_host_ms")
+#: ``kernel.paged_attn_share``, ``paged_attn_roofline``
+NOT_JOINED = ("engine.kv_write_share", "kernel.paged_attn_share", "paged_attn_roofline")
 
 
 def power_benchmark() -> dict:

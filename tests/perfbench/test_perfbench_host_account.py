@@ -16,6 +16,11 @@ CELLS = [
     "qwen2.5-7b-L14.rollout-lockstep", "minicpm-sala-L10.rollout-longctx",
     "kimi-vl-a3b-L7.rollout-longctx-latent", "solar-open2-250b-ep8-L4.rollout-reasoning",
 ]
+#: the three newer rollout cells that joined the eight lists in PR 53
+JOINED_IN_PR_53 = [
+    "brumby-14b-L4.rollout-retention-16k", "jamba2-3b.rollout-wide-480",
+    "k-exaone-236b-ep8-L5.rollout-longctx-window",
+]
 #: name -> (unit, source, reader, what the reader is pointed at)
 NEW = {
     "engine.dispatch_host_ms": ("ms", "program_span", "host_spans", telemetry.ENGINE_DISPATCH),
@@ -59,11 +64,27 @@ def test_the_metric_resolves_from_its_file_and_is_in_the_benchmark_by_name(name)
     else:
         assert held["args"]["name"] == target
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry == {"name": name, "unit": unit, "better": "lower", "source": source,
-                     "layer": "engine", "moves": "rollout_tok_s", "workloads": CELLS}
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        "name": name, "unit": unit, "better": "lower", "source": source,
+        "layer": "engine", "moves": "rollout_tok_s"}
+    # PR 38's four cells are in the list, and a later cell may join it (PR 53)
+    assert set(CELLS) <= set(entry["workloads"])
     # each cell is there, and reports the end-to-end metric these move
     (moved,) = [m for m in BENCH["end_to_end"] if m["name"] == "rollout_tok_s"]
-    assert set(CELLS) <= {w["name"] for w in BENCH["workloads"]} & set(moved["workloads"])
+    assert set(entry["workloads"]) <= {w["name"] for w in BENCH["workloads"]} & set(
+        moved["workloads"])
+
+
+def test_the_eight_read_in_every_rollout_cell_alike():
+    """One list for the eight: the account closes only where all its parts are
+    read, so a cell joins all eight or none (PR 53 appended three)."""
+    lists = {tuple(m["workloads"]) for m in BENCH["per_layer"] if m["name"] in NEW}
+    (cells,) = lists
+    assert list(cells[:len(CELLS)]) == CELLS  # appended after PR 38's four
+    assert set(JOINED_IN_PR_53) <= set(cells)
+    # and the part of the sum that every rollout cell listed before
+    (wait,) = [m for m in BENCH["per_layer"] if m["name"] == "engine.snapshot_wait_ms"]
+    assert set(cells) <= set(wait["workloads"])
 
 
 def test_the_eight_are_one_block_after_what_was_there():

@@ -176,7 +176,7 @@ def test_the_benchmark_gained_this_configuration_and_this_cell_by_name():
     # its own three are read in this cell alone of those that stand today
     for name in own:
         assert not set(OTHER_FAMILIES_CELLS) & set(metrics[name]["workloads"]), name
-    # and it reads none of what a test pins, or another family's layers
+    # and not what divides the whole cache by the paged kernel's time, nor another family's layers
     for name in (*NOT_JOINED, "engine.admit_host_ms", *OTHERS_OWN):
         assert REAL_CELL not in metrics[name]["workloads"], name
     # the four entry.* hold for every cell: they have no list

@@ -115,8 +115,7 @@ def test_the_real_cell_is_the_issues_letter_for_letter():
     reported = {m["name"] for m in cell.per_layer}
     assert set(JOINED) - {"rollout_tok_s"} <= reported
     assert {name for name, *_ in POWER_METRICS} <= reported
-    # no layer keeps a page: nothing writes K/V, and no paged kernel runs; and
-    # PR 38's eight are pinned to its four cells by a test that may not be edited
+    # no layer keeps a page: nothing writes K/V, and no paged kernel runs
     assert not set(NOT_JOINED) & reported
     # no other family's mixer, no expert layer, no refill admissions
     assert not {"engine.admit_host_ms", *(name for group in (

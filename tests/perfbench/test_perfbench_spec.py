@@ -107,6 +107,33 @@ def shared(reduced=None, share="keep", **sizes):
     return held
 
 
+#: the cut ISSUE 53 works out for its draw, letter for letter: one of 16 chips
+#: that share each layer holds 16 of 256 routed experts and an eighth of a
+#: vocabulary of 154,880 (8 slices, each on 2 chips), the first pipeline stage
+#: one of the 3 leading dense layers and 4 of the 75 layers that follow
+CUT_WHOLE = {
+    "num_hidden_layers": 5, "first_k_dense_replace": 1, "n_routed_experts": 16,
+    "vocab_size": 19360, "num_experts_per_tok": 8, "hidden_size": 6144,
+    "moe_intermediate_size": 2048,
+    "reduced": ["num_hidden_layers", "first_k_dense_replace", "n_routed_experts", "vocab_size"],
+    "share": {"chips_per_layer": 16,
+              "published": {"n_routed_experts": 256, "vocab_size": 154880}},
+    "depth": {"published": {"num_hidden_layers": 78, "first_k_dense_replace": 3}},
+}
+#: the dense layers counted once under depth alone: no share
+DENSE_ONCE = {k: CUT_WHOLE[k] for k in ("num_hidden_layers", "first_k_dense_replace", "depth")}
+DENSE_ONCE["reduced"] = ["num_hidden_layers", "first_k_dense_replace"]
+
+
+def over(chips, experts, vocab=None, held_vocab=None):
+    """``experts`` published over ``chips`` chips a layer, and the vocabulary
+    ``vocab`` with ``held_vocab`` rows here (uncut where ``vocab`` is None)."""
+    published = {"n_routed_experts": experts, **({} if vocab is None else {"vocab_size": vocab})}
+    return shared(["num_hidden_layers", *published],
+                  {"chips_per_layer": chips, "published": published},
+                  n_routed_experts=experts // chips, vocab_size=held_vocab or 154880)
+
+
 REDUCED_CASES = {
     # accepted
     "depth-alone": ({"reduced": ["num_hidden_layers"]}, None),
@@ -149,6 +176,39 @@ REDUCED_CASES = {
         "one published value for each, and none else"),
     "a-share-with-another-key": (shared(
         share={**SHARED["share"], "stands_in_for": "the absent chips"}), "'share' is {"),
+    # since PR 53: the vocabulary in at most 8 slices, leading dense layers once
+    "cut-whole-16-chips-an-eighth-and-one-dense-layer": (CUT_WHOLE, None),
+    "experts-over-16-chips-the-vocabulary-uncut": (over(16, 256), None),
+    "experts-over-24-chips-an-eighth-of-the-vocabulary": (over(24, 384, 154880, 19360), None),
+    "dense-layers-once-under-depth-alone": (DENSE_ONCE, None),
+    "twelve-chips-and-a-vocabulary-cut": (
+        over(12, 384, 154880, 19360), "'chips_per_layer' is a multiple of 8"),
+    "a-sixteenth-of-the-vocabulary-beside-16-experts": (
+        over(16, 256, 154880, 9680), "an eighth of the vocabulary"),
+    "an-eighth-of-the-vocabulary-at-4-chips": (
+        over(4, 256, 154880, 19360), "4 chips of that make 77440, not the published 154880"),
+    "a-quarter-of-the-vocabulary-at-16-chips": (
+        over(16, 256, 154880, 38720),
+        "8 slices over the 16 chips of that make 309760, not the published 154880"),
+    "no-dense-layer-held": ({**DENSE_ONCE, "first_k_dense_replace": 0},
+                            "holds one of the leading dense layers"),
+    "two-of-three-dense-layers-held": ({**DENSE_ONCE, "first_k_dense_replace": 2},
+                                       "leading dense layers count once, so 1 is held"),
+    "dense-layers-cut-with-depth-uncut": (
+        {**DENSE_ONCE, "reduced": ["first_k_dense_replace"]},
+        "count once only where depth is cut"),
+    "dense-layers-cut-with-nothing-published": (
+        {k: v for k, v in DENSE_ONCE.items() if k != "depth"},
+        "the published counts it was cut from"),
+    "one-dense-layer-and-three-after-it": ({**DENSE_ONCE, "num_hidden_layers": 4},
+                                           "at least 4 layers follow the leading dense one"),
+    "one-dense-layer-published": (
+        {**DENSE_ONCE, "depth": {"published": {"num_hidden_layers": 78,
+                                               "first_k_dense_replace": 1}}},
+        "the model has 2 leading dense layers or more"),
+    "a-depth-and-no-dense-key-reduced": (
+        {**DENSE_ONCE, "reduced": ["num_hidden_layers"]},
+        "a 'depth' and no 'first_k_dense_replace' in 'reduced'"),
 }
 
 
@@ -167,7 +227,9 @@ def test_reduced_names_depth_or_one_chips_share_and_never_a_width(held, refusal)
 
 
 @pytest.mark.parametrize("case", ["experts-and-vocabulary-over-8-chips", "hidden_size",
-                                  "held-times-chips-is-not-published"])
+                                  "held-times-chips-is-not-published",
+                                  "cut-whole-16-chips-an-eighth-and-one-dense-layer",
+                                  "two-of-three-dense-layers-held"])
 def test_a_run_refuses_what_the_test_refuses(tmp_path, case):
     """``spec.load_cell`` goes through the same function: a configuration the
     tests would refuse never reaches a driver."""
@@ -183,7 +245,8 @@ def test_a_run_refuses_what_the_test_refuses(tmp_path, case):
     path.write_text(json.dumps({**tiny, **held}), encoding="utf-8")
     entry["file"] = str(path)
     if refusal is None:
-        assert spec.load_cell(bench, "tiny.learner").config["share"] == held["share"]
+        config = spec.load_cell(bench, "tiny.learner").config
+        assert (config["share"], config.get("depth")) == (held["share"], held.get("depth"))
     else:
         with pytest.raises(spec.SpecError, match=re.escape(refusal)):
             spec.load_cell(bench, "tiny.learner")
