@@ -60,10 +60,12 @@ def page_bytes(model_cfg, page_size: int, kv_quant: str = "none") -> int:
     layers of one whose layers differ in kind, none of a power-retention
     model's, whose page costs 0 bytes); k + v, or for a latent page one row a
     token, ``latent_dim`` values in ``latent_row`` lanes, one array a layer, no
-    V beside it and no kv-head factor."""
+    V beside it and no kv-head factor, and where the model has a learned index
+    a token's index key (``index_head_dim`` values) in a second array."""
     layers = model_cfg.paged_layers
     if model_cfg.latent:
-        return page_size * model_cfg.latent_row * 2 * layers
+        index = model_cfg.index_head_dim if model_cfg.index_topk else 0
+        return page_size * (model_cfg.latent_row + index) * 2 * layers
     per_layer_one = model_cfg.num_kv_heads * page_size * model_cfg.head_dim
     if kv_quant == "int8":
         # int8 payload + f32 per-token absmax scales [K, P, ps, 1]

@@ -31,6 +31,8 @@ the shard-local program).
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import threading
 import time
 from functools import partial
@@ -186,6 +188,24 @@ def _pack_rows(ids: jax.Array, mask: jax.Array) -> tuple[jax.Array, jax.Array, j
 
 
 
+@contextlib.contextmanager
+def _no_full_collection():
+    """A round without the cyclic collector. The decode loop runs at most a
+    second of queued steps ahead of the chip, and a full collection of a
+    process that has traced fifty programs (and, in the benchmark, a float32
+    reference) takes longer: it idled the chip for 0.6-2 s in three of 18
+    rounds of a 35 s cell (PERF.md section 6, PR 54). A round makes few
+    cycles; they wait for its end. Left as it was where a caller had the
+    collector off already."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
 def _count_mixer_stats(mixer) -> dict:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
     layers' blocks; ``mixer["moe_stats"]`` / ``["moe_routed"]``: the expert
@@ -195,10 +215,12 @@ def _count_mixer_stats(mixer) -> dict:
     of S and z that is, a read and a write each; ``mixer["ssm_stats"]``: the
     (live row, layer) state-space states they read and wrote, filed as that
     count; ``mixer["window_stats"]``: the window layers' keys attended and the
-    keys a full layer would attend, in units of 128) with telemetry. Returns
-    what the round's span says of them: ``power_state_bytes`` /
-    ``ssm_states_stepped`` / ``window_pages_attended`` and ``_visible``, where
-    there are any."""
+    keys a full layer would attend, in units of 128; ``mixer["index_stats"]``:
+    the same two of a model with a learned index over tokens, every layer's)
+    with telemetry. Returns what the round's span says of them:
+    ``power_state_bytes`` / ``ssm_states_stepped`` / ``window_pages_attended``
+    and ``_visible`` / ``index_tokens_attended`` and ``_visible``, where there
+    are any."""
     said = {}
     if mixer is not None and "power_stats" in mixer:
         a_state = sum(x.nbytes // x.shape[0]
@@ -210,11 +232,17 @@ def _count_mixer_stats(mixer) -> dict:
         stepped = int(np.asarray(mixer["ssm_stats"])[0])
         telemetry.counter_add(ENGINE_SSM_STATES_STEPPED, stepped)
         said["ssm_states_stepped"] = stepped
-    if mixer is not None and "window_stats" in mixer:
-        attended, visible = (int(x) for x in np.asarray(mixer["window_stats"]))
-        telemetry.counter_add(telemetry.ENGINE_WINDOW_PAGES_ATTENDED, attended)
-        telemetry.counter_add(telemetry.ENGINE_WINDOW_PAGES_VISIBLE, visible)
-        said.update(window_pages_attended=attended, window_pages_visible=visible)
+    for key, (said_as, names) in (
+        ("window_stats", ("window_pages", (telemetry.ENGINE_WINDOW_PAGES_ATTENDED,
+                                           telemetry.ENGINE_WINDOW_PAGES_VISIBLE))),
+        ("index_stats", ("index_tokens", (telemetry.ENGINE_INDEX_TOKENS_ATTENDED,
+                                          telemetry.ENGINE_INDEX_TOKENS_VISIBLE))),
+    ):
+        if mixer is not None and key in mixer:
+            for which, name, value in zip(
+                    ("attended", "visible"), names, np.asarray(mixer[key])):
+                telemetry.counter_add(name, int(value))
+                said[f"{said_as}_{which}"] = int(value)
     for key, names in (
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
@@ -494,12 +522,14 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
         sorted_len = place(real_len)
         last = jnp.maximum(sorted_len - 1, 0)
     shape = cfg.page_pool_shape(b * prompt_pages, page_size)
+    second = cfg.second_pool_shape(b * prompt_pages, page_size)
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         mixer = init_mixer_state(cfg, b, total_tokens, cache_dtype)
-        pool = lambda: tuple(
+        pool = lambda shape: () if shape is None else tuple(
             jnp.zeros(shape, cache_dtype) for _ in range(cfg.paged_layers))
-        # a latent layer's pages are one array: there is no V pool
-        pools = {"k": pool(), "v": () if cfg.latent else pool()}
+        # a latent layer's pages are one array: there is no V pool, and the
+        # second slot is empty or holds its index keys, one array a layer
+        pools = {"k": pool(shape), "v": pool(second)}
         # every row's states start alike: these are the sorted rows' too
         rows = (_row_states(mixer),
                 jnp.zeros((b, cfg.hidden_size), params["final_norm"].dtype))
@@ -2474,7 +2504,7 @@ class PagedGenerationEngine(LoraMailbox):
     ) -> GenerationResult:
         # on a role submesh of several chips the round's programs span them:
         # their Pallas kernels need the mesh in context (ops/per_device.py)
-        with params_mesh(params):
+        with params_mesh(params), _no_full_collection():
             return self._generate(
                 params, lora, prompt_ids, prompt_mask, sampling, rng
             )

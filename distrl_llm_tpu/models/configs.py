@@ -23,7 +23,17 @@ keeps ONE latent row a token (``kv_lora_rank + qk_rope_head_dim`` values, no
 K or V per head); the first ``first_dense_layers`` layers have a dense gated
 MLP (kind "latent"), the rest a router over ``n_routed_experts`` experts and
 one shared expert (kind "latent_moe"). It runs through ``models/hybrid.py``
-like any model whose layers are not all alike.
+like any model whose layers are not all alike. With ``q_lora_rank`` the query
+goes through a normed latent of its own (``q_a -> RMSNorm -> q_b``), and the
+model may state a share as below (``router_experts`` / ``expert_shard``).
+
+**A learned index over tokens** (``glm_moe_dsa``: GLM-5, DeepSeek's sparse
+attention) stands beside latent attention in every layer where ``index_topk``
+is set: ``index_heads`` small query heads of ``index_head_dim`` (projected from
+the normed query latent) score ONE cached index key a token, and token ``t``
+attends the ``min(index_topk, t + 1)`` tokens of largest score and no other
+(``ops/token_index.py``). A cached token is then the latent row and, in a
+second paged array under the same page table, its index key.
 
 A gated delta-rule model with routed experts (``solar_open2``) has two layer
 kinds in a published period: "softmax" (gated GQA attention without RoPE, K/V
@@ -93,7 +103,7 @@ def mixer_of(kind: str) -> str:
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
-    "solar_open2", "brumby", "jamba", "exaone_moe",
+    "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa",
 )
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
@@ -108,6 +118,8 @@ _STATE_NAMES = {
     "mamba": "a float32 state-space state and a convolution window",
     "window": "a ring of the last sliding_window tokens' K and V and no page",
 }
+#: what a latent layer's token keeps beside its row where the model has an index
+_INDEX_STATE = " beside one index key a token in a second paged array"
 #: layer kind -> the published name a refusal gives it
 _LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
 
@@ -169,6 +181,12 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_intermediate_size: int = 0
     first_dense_layers: int = 0  # first_k_dense_replace
+    q_lora_rank: int = 0  # the query's own normed latent (q_a, q_b); 0 = one q_proj
+    # ---- a learned index over tokens beside latent attention (glm_moe_dsa's
+    # index_* keys; module docstring). index_topk 0 = no index
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     # ---- one chip's share of the routed experts (module docstring). 0 = the
@@ -202,6 +220,13 @@ class ModelConfig:
                 f"a chip holds run {self.expert_shard} of {self.n_routed_experts} "
                 f"routed experts, and the router scores {self.router_experts}: "
                 "the held run must be one of a whole number of runs")
+        if self.index_topk and not (
+                self.latent and self.q_lora_rank and self.index_heads
+                and self.index_head_dim >= self.qk_rope_head_dim):
+            raise ValueError(
+                "index_topk needs latent attention with q_lora_rank (the index's "
+                "queries are projected from the normed query latent), index_heads "
+                "and an index_head_dim no smaller than qk_rope_head_dim")
         if self.mixer_types is not None:
             unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
             if unknown:
@@ -305,7 +330,7 @@ class ModelConfig:
         """"dense" | "experts": the second half of a "softmax", "delta",
         "mamba" or "window" layer of ``kind``. A kind that says so is dense;
         otherwise the model's routed experts, where it has any."""
-        if kind.endswith(DENSE_FFN) or not self.n_routed_experts:
+        if kind.endswith(DENSE_FFN) or kind == "latent" or not self.n_routed_experts:
             return "dense"
         return "experts"
 
@@ -361,6 +386,15 @@ class ModelConfig:
             return (pages, page_size, self.latent_row)
         return (self.num_kv_heads, pages, page_size, self.head_dim)
 
+    def second_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...] | None:
+        """Shape of the array a layer keeps in the pool's SECOND slot, under
+        the same page table: V like K; a latent layer's index keys ``[pages,
+        page, index_head_dim]`` where the model has an index; None where a
+        latent layer keeps its rows alone (the slot is an empty tuple)."""
+        if not self.latent:
+            return self.page_pool_shape(pages, page_size)
+        return (pages, page_size, self.index_head_dim) if self.index_topk else None
+
     @property
     def shared_expert_size(self) -> int:
         return self.n_shared_experts * self.moe_intermediate_size
@@ -395,7 +429,8 @@ class ModelConfig:
         """What a slot holds for this model's layers that is no K/V of one
         kind for every layer, for a refusal."""
         return " and ".join(dict.fromkeys(
-            _STATE_NAMES[m] for m in map(mixer_of, self.layer_kinds) if m in _STATE_NAMES))
+            _STATE_NAMES[m] for m in map(mixer_of, self.layer_kinds) if m in _STATE_NAMES
+        )) + _INDEX_STATE * bool(self.index_topk)
 
     def refuse_hybrid(self, what: str) -> None:
         """Raise, naming the mixer kinds and the state they keep, where
@@ -512,15 +547,18 @@ class ModelConfig:
         """Matmul parameters of a latent-attention model with ``experts``
         routed experts counted a layer: the ones a token RUNS
         (``experts_per_token``) for operations, all that are held for bytes."""
-        d, h = self.hidden_size, self.num_heads
+        d, h, r = self.hidden_size, self.num_heads, self.q_lora_rank
         attn = (
-            d * self.q_dim + d * self.latent_dim
+            (d * r + r * self.q_dim if r else d * self.q_dim) + d * self.latent_dim
             + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
             + h * self.v_head_dim * d
         )
+        if self.index_topk:  # the index's queries, its one key and its head weights
+            attn += (r * self.index_heads * self.index_head_dim
+                     + d * self.index_head_dim + d * self.index_heads)
         moe = 3 * d * (
             experts * self.moe_intermediate_size + self.shared_expert_size
-        ) + d * self.n_routed_experts
+        ) + d * self.router_width
         return (
             self.kind_count("latent") * (attn + 3 * d * self.intermediate_size)
             + self.kind_count("latent_moe") * (attn + moe)
@@ -577,6 +615,12 @@ class ModelConfig:
         path plus the attention score/value dot-products (2 FLOPs × q_dim
         keys-side + values-side) at the mean resident KV length."""
         attn = 4.0 * self.num_layers * self.q_dim * mean_kv_len
+        if self.index_topk:
+            # a token attends at most ``index_topk`` tokens, and scores every
+            # visible token's index key with every index head
+            attn = self.num_layers * (
+                4.0 * self.q_dim * min(mean_kv_len, self.index_topk)
+                + 2.0 * self.index_heads * self.index_head_dim * mean_kv_len)
         if self.kind_count("delta"):
             # softmax layers attend over the context; a delta-rule layer's
             # token costs its state whatever the context (decay, S^T k, the
@@ -612,7 +656,7 @@ class ModelConfig:
         """The HF model_type this config round-trips through
         ``from_hf_config`` as (used by HF-format snapshot export)."""
         if self.latent:
-            return "deepseek_v3"
+            return "glm_moe_dsa" if self.index_topk else "deepseek_v3"
         if self.delta_moe:
             return "solar_open2"
         if self.power:
@@ -694,8 +738,10 @@ class ModelConfig:
                 },
             )
         head_dim = get("head_dim", None) or hf.hidden_size // num_heads
-        if mt == "deepseek_v3":
-            hybrid = _latent_fields(get)
+        if mt in ("deepseek_v3", "glm_moe_dsa"):
+            # the published ``head_dim`` of a glm_moe_dsa file is the rope width
+            # and is read by nothing: the query head is nope + rope
+            hybrid = _latent_fields(get, mt)
             head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
         if mt == "solar_open2":
             hybrid = _delta_moe_fields(get)
@@ -870,40 +916,69 @@ def _refuse_router_variants(get, refuse) -> None:
         refuse("scoring_func", "the router scores by sigmoid only")
 
 
-def _latent_fields(get) -> dict:
-    """The ``deepseek_v3`` keys as ``ModelConfig`` fields. A variant that is
-    not implemented is REFUSED by name: every key this function did not read
-    would be ignored, and the model would load as something else."""
+def _latent_fields(get, family: str = "deepseek_v3") -> dict:
+    """The ``deepseek_v3`` keys as ``ModelConfig`` fields, and ``glm_moe_dsa``'s
+    (the same keys, RoPE's base under ``rope_parameters``, and the ``index_*``
+    keys of its learned index over tokens). A ``share`` is read as
+    ``_delta_moe_fields`` reads one. The multi-token-prediction module
+    (``num_nextn_predict_layers``) is a draft head no logit of the main head
+    depends on: its key is read by nothing. A variant that is not implemented
+    is REFUSED by name: every key this function did not read would be ignored,
+    and the model would load as something else."""
     def refuse(key: str, why: str):
         raise ValueError(
-            f"deepseek_v3 with {key}={get(key)!r} is not supported: {why}")
+            f"{family} with {key}={get(key)!r} is not supported: {why}")
 
-    if get("q_lora_rank") is not None:
-        refuse("q_lora_rank", "the low-rank query path (q_a_proj, q_a_layernorm, "
-               "q_b_proj) is not implemented; only a plain q_proj is")
     _refuse_router_variants(get, refuse)
     if str(get("topk_method", "noaux_tc")) != "noaux_tc":
         refuse("topk_method", "the router chooses by score plus "
                "e_score_correction_bias (noaux_tc) only")
+    rope = dict(get("rope_parameters") or {})
     if get("rope_scaling") is not None:
         refuse("rope_scaling", "scaled RoPE (YaRN and its softmax-scale "
                "correction) is not implemented")
+    if str(rope.get("rope_type", "default")) != "default":
+        refuse("rope_parameters", "scaled RoPE (a rope_type that is not "
+               "'default') is not implemented")
     if get("moe_layer_freq", 1) != 1:
         refuse("moe_layer_freq", "every layer after first_k_dense_replace is "
                "an expert layer; another period is not implemented")
-    return dict(
+    if get("attention_bias", False):
+        refuse("attention_bias", "the latent projections carry no bias")
+    index = {}
+    if family == "glm_moe_dsa":
+        if not get("indexer_rope_interleave", True):
+            refuse("indexer_rope_interleave", "the index rotates interleaved "
+                   "pairs (x[2i], x[2i+1]); the half-split layout is not implemented")
+        if not get("rope_interleave", True):
+            refuse("rope_interleave", "q_pe and k_pe are rotated in interleaved "
+                   "pairs; the half-split layout is not implemented")
+        index = dict(index_heads=int(get("index_n_heads")),
+                     index_head_dim=int(get("index_head_dim")),
+                     index_topk=int(get("index_topk")))
+    held = int(get("n_routed_experts") or 0)
+    published = dict((get("share") or {}).get("published") or {})
+    width = int(published.get("n_routed_experts", held))
+    fields = dict(
         kv_lora_rank=int(get("kv_lora_rank")),
+        q_lora_rank=int(get("q_lora_rank") or 0),
         qk_nope_head_dim=int(get("qk_nope_head_dim")),
         qk_rope_head_dim=int(get("qk_rope_head_dim")),
         v_head_dim=int(get("v_head_dim")),
-        n_routed_experts=int(get("n_routed_experts") or 0),
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
         n_shared_experts=int(get("n_shared_experts") or 0),
         experts_per_token=int(get("num_experts_per_tok") or 0),
         moe_intermediate_size=int(get("moe_intermediate_size") or 0),
         first_dense_layers=int(get("first_k_dense_replace", 0)),
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        **index,
     )
+    if "rope_theta" in rope:
+        fields["rope_theta"] = float(rope["rope_theta"])
+    return fields
 
 
 def _delta_moe_fields(get) -> dict:
@@ -987,6 +1062,19 @@ TINY_LATENT_MOE = ModelConfig(
     qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8, n_shared_experts=1,
     experts_per_token=2, moe_intermediate_size=32, first_dense_layers=1,
     routed_scaling_factor=2.446,
+)
+
+# latent attention behind a learned index over tokens at a size the CPU tests
+# run (GLM-5's shape): a low-rank query path, 4 index heads choosing 8 tokens,
+# a dense first layer, then 2 of 16 experts a chip of 8, 4 a token, 1 shared
+TINY_DSA = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=3,
+    num_heads=4, num_kv_heads=4, head_dim=24, rope_theta=1000000.0,
+    rms_norm_eps=1e-5, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=20, index_heads=4, index_head_dim=16,
+    index_topk=8, n_routed_experts=2, router_experts=16, n_shared_experts=1,
+    experts_per_token=4, moe_intermediate_size=32, first_dense_layers=1,
+    routed_scaling_factor=2.5,
 )
 
 # a gated delta-rule model with routed experts at a size the CPU tests run: a
@@ -1085,6 +1173,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-power": TINY_POWER,
     "tiny-jamba": TINY_JAMBA,
     "tiny-exaone-moe": TINY_EXAONE_MOE,
+    "tiny-dsa": TINY_DSA,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

@@ -58,7 +58,31 @@ axis and no V (``v`` is an empty tuple), and two counters of [2] int32, summed
 over layers and decode steps: ``moe_stats`` (token-expert pairs computed, the
 fullest expert's) and ``latent_stats`` (the (row, page) pairs absorbed
 attention covered, the pages it fetched: a page that a group's rows share is
-fetched once, ``ops/latent_attention.py``).
+fetched once, ``ops/latent_attention.py``). With ``cfg.q_lora_rank`` the query
+is ``q = W_qb RMSNorm(W_qa h)``, and an expert layer is told which experts this
+program holds (``cfg.held_experts``) like the families below.
+
+**A learned index over tokens** (``cfg.index_topk``: ``glm_moe_dsa``, GLM-5)
+stands beside latent attention in every layer (``ops/token_index.py``)::
+
+    q_I = W_qI c_q [T, H_I, D_I];  k_I = LayerNorm(W_kI h) [T, D_I];  w = W_w h [T, H_I]
+    q_I, k_I <- RoPE on their first ``rope`` values (interleaved pairs)
+    I[t, s] = sum_h w[t, h] relu(q_I[t, h] . k_I[s]);  token t attends the
+    min(index_topk, t + 1) tokens of largest I[t, .] and no other
+
+``k_I`` is cached: the cache's ``v`` slot holds one array ``[pages, page,
+D_I]`` a layer under the latent rows' own page table, so whatever aliases,
+copies or grows a prompt's pages does it to both. No cache: the ``[S, S]``
+scores and the choice as a mask over the expanded form. A prefill segment:
+the segment's scores over the row's cached keys, the choice as a mask over the
+folds (``expanded_attention``, never the kernel: it takes no mask). Decode:
+the scores walk the page table as absorbed attention did (a group's shared
+blocks of keys once), the chosen positions by ``top_k``, their latent rows
+GATHERED ``[B, index_topk, latent_row]`` and attended in the absorbed form.
+The choice is not differentiated and the index carries no adapter. The round's
+counter ``index_stats`` [2] int32 takes the place of ``latent_stats``: tokens
+attended and tokens visible, a live row, layer and decode step, in units of
+``INDEX_COUNT_UNIT``.
 
 **A gated delta rule with routed experts** (``solar_open2``) is two more
 kinds, "softmax" and "delta", each followed by routed experts told which
@@ -165,7 +189,7 @@ from distrl_llm_tpu.ops.delta_attention import (
     delta_chunked, delta_step, l2norm, short_conv,
 )
 from distrl_llm_tpu.ops.latent_attention import (
-    absorbed_output, absorbed_paged_attention, absorbed_query,
+    absorbed_attention, absorbed_output, absorbed_paged_attention, absorbed_query,
     expanded_attention, expanded_finish, expanded_segment,
     rope_interleaved, shared_page_walk, shared_pages_per_block, split_kvb,
 )
@@ -173,6 +197,9 @@ from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
 from distrl_llm_tpu.ops.power_retention import init_state, power_chunked, power_step
 from distrl_llm_tpu.ops.selective_scan import ssm_chunked, ssm_step
+from distrl_llm_tpu.ops.token_index import (
+    chosen_mask, chosen_tokens, index_paged_scores, index_scores,
+)
 from distrl_llm_tpu.ops.sparse_attention import (
     pool_keys, pooled_count, sparse_attend, sparse_decode, update_pooled,
 )
@@ -191,6 +218,10 @@ ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm",
               "win_k", "win_v")
 #: keys a unit of ``window_stats`` stands for (module docstring)
 WINDOW_COUNT_UNIT = 128
+#: tokens a unit of ``index_stats`` stands for (module docstring)
+INDEX_COUNT_UNIT = 128
+#: eps of the index key's LayerNorm (torch's default; the config has no key for it)
+INDEX_NORM_EPS = 1e-6
 #: the mixers whose layers ``_block`` runs as a mixer and then the layer's own
 #: second half, and the cache entries each keeps a layer
 _MIXER_CACHE = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
@@ -218,7 +249,8 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
         p = {
             "attn_norm": jnp.ones((n, d), dtype),
             "mlp_norm": jnp.ones((n, d), dtype),
-            "wq": init((n, d, cfg.q_dim)),
+            # q_proj, or q_b_proj from the query's normed latent
+            "wq": init((n, cfg.q_lora_rank or d, cfg.q_dim)),
             "wkv_a": init((n, d, cfg.latent_dim)),
             "kv_a_norm": jnp.ones((n, cfg.kv_lora_rank), dtype),
             "wkv_b": init((n, cfg.kv_lora_rank,
@@ -233,11 +265,23 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
         if moe:
             fm = cfg.moe_intermediate_size
             p.update(
-                router=init((n, d, experts)),
-                e_score_bias=jnp.zeros((n, experts), dtype),
+                router=init((n, d, cfg.router_width)),
+                e_score_bias=jnp.zeros((n, cfg.router_width), dtype),
                 experts_gate=init((n, experts, d, fm)),
                 experts_up=init((n, experts, d, fm)),
                 experts_down=init((n, experts, fm, d)),
+            )
+        # drawn after every leaf a model without them has: its draws stay
+        if cfg.q_lora_rank:
+            p.update(wq_a=init((n, d, cfg.q_lora_rank)),
+                     q_a_norm=jnp.ones((n, cfg.q_lora_rank), dtype))
+        if cfg.index_topk:
+            p.update(
+                w_index_q=init((n, cfg.q_lora_rank, cfg.index_heads * cfg.index_head_dim)),
+                w_index_k=init((n, d, cfg.index_head_dim)),
+                index_k_norm=jnp.ones((n, cfg.index_head_dim), dtype),
+                b_index_k=jnp.zeros((n, cfg.index_head_dim), dtype),
+                w_index_w=init((n, d, cfg.index_heads)),
             )
         return p
 
@@ -357,9 +401,13 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     per power-retention layer, a float32 state and a convolution window per
     Mamba layer, the round's counters. The entries named in ``ROW_STATES`` are
     tuples of one array a row."""
-    if cfg.latent:  # all of a slot's cache is in pages; the round's counter
-        return {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
-                "latent_stats": jnp.zeros((2,), jnp.int32)}
+    if cfg.latent:  # all of a slot's cache is in pages; the round's counters
+        state = {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32)}
+        if cfg.held_experts is not None:  # the pairs chosen over ALL experts
+            state["moe_routed"] = jnp.zeros((1,), jnp.int32)
+        state["index_stats" if cfg.index_topk else "latent_stats"] = jnp.zeros(
+            (2,), jnp.int32)
+        return state
     if cfg.delta_moe:
         h, d, n = cfg.delta_heads, cfg.delta_head_dim, cfg.kind_count("delta")
         return {
@@ -845,29 +893,46 @@ def _latent_page_walk(env: dict, cfg: ModelConfig):
     row's group does not walk a long row's width), the columns every row of
     the group holds in common in blocks of ``wide``, once for all of them.
     Returns (the block shapes, what ``shared_page_walk`` read off the tables):
-    the same for every layer of the step."""
+    the same for every layer of the step. A model with an index walks its
+    INDEX KEYS so (``index_paged_scores``: the index's heads size a block)."""
     idx, ps = env["page_indices"], env["page_size"]
     b, width = idx.shape
     per = min(LATENT_DECODE_PAGES, width)
     rows = LATENT_DECODE_ROWS if b % LATENT_DECODE_ROWS == 0 else b
-    wide = shared_pages_per_block(rows, cfg.num_heads, ps, per, width)
+    heads = cfg.index_heads if cfg.index_topk else cfg.num_heads
+    wide = shared_pages_per_block(rows, heads, ps, per, width)
     walk = shared_page_walk(
         idx, env["lengths"], env.get("alive"), page_size=ps, wide=wide, rows=rows)
     return {"per": per, "wide": wide, "rows": rows}, walk
 
 
-def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale):
+def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale,
+                     index=None):
     """One decode token a row: write its latent row, then attend over the
     row's pages with W_kvb absorbed (its adapter too). ``q_nope [B, H, nope]``,
-    ``q_pe [B, H, rope]``, ``row [B, latent_row]``. Returns (o [B, 1, H, v],
-    the page array)."""
+    ``q_pe [B, H, rope]``, ``row [B, latent_row]``. With ``index`` (``q_i [B,
+    H_I, D_I]``, ``w [B, H_I]``, ``k_i [B, D_I]``, the layer's index-key pages)
+    the new token's index key is written beside its row, and the row attends
+    the tokens its index chooses, their latent rows gathered, and no other.
+    Returns (o [B, 1, H, v], the page array, the index-key pages or None)."""
     b, heads, nope = q_nope.shape
     idx, ps, lengths = env["page_indices"], env["page_size"], env["lengths"]
+    shape, walk = env["page_walk"]
+    key_pages = None
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         # a point scatter: page and slot are indices, the row the window
         at = (idx[jnp.arange(b), lengths // ps], lengths % ps)
         pages = pages.at[at].set(row, mode="drop")
-    with jax.named_scope(telemetry.MODEL_LATENT_ATTN):
+        if index is not None:
+            q_i, w_i, k_i, key_pages = index
+            key_pages = key_pages.at[at].set(k_i.astype(key_pages.dtype), mode="drop")
+    if index is not None:
+        with jax.named_scope(telemetry.MODEL_INDEX_SCORE):
+            scores = index_paged_scores(q_i, w_i, key_pages, walk, **shape)
+        with jax.named_scope(telemetry.MODEL_INDEX_SELECT):
+            chosen, seen = chosen_tokens(scores, lengths, cfg.index_topk)
+    with jax.named_scope(
+            telemetry.MODEL_LATENT_ATTN if index is None else telemetry.MODEL_INDEXED_ATTN):
         w = p["wkv_b"]
         if lora is not None and "wkv_b" in lora:
             ab = lora["wkv_b"]
@@ -876,42 +941,118 @@ def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale)
         w_k, w_v = split_kvb(w, heads, nope, cfg.v_head_dim)
         q_row = absorbed_query(q_nope, q_pe, w_k)
         q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, cfg.latent_row - cfg.latent_dim)))
-        shape, walk = env["page_walk"]
-        carry = absorbed_paged_attention(
-            q_row, pages, walk, lengths, cfg.head_dim ** -0.5, **shape)
-        return absorbed_output(carry, w_v, q_nope.dtype)[:, None], pages
+        scale = cfg.head_dim ** -0.5
+        if index is None:
+            carry = absorbed_paged_attention(q_row, pages, walk, lengths, scale, **shape)
+        else:  # the chosen tokens' rows, [B, index_topk, latent_row], in one block
+            # a token's page by a compare over the row's few columns: a gather of
+            # 131k scalars took 6.7 ms a step on the v5e where this takes none
+            column = jnp.arange(walk.cols.shape[1], dtype=jnp.int32)
+            page = jnp.where((chosen // ps)[..., None] == column, walk.cols[:, None], 0).sum(-1)
+            held = pages[page, chosen % ps]
+            carry = absorbed_attention(q_row, held, seen, scale)
+        return absorbed_output(carry, w_v, q_nope.dtype)[:, None], pages, key_pages
+
+
+def _layer_norm(x, weight, bias, eps: float):
+    """LayerNorm over the last axis, float32 inside."""
+    y = x.astype(jnp.float32)
+    y = y - y.mean(axis=-1, keepdims=True)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _index_inputs(h, c_q, p, *, cfg, env):
+    """The index's side of a layer (module docstring): ``(q_I [B, S, H_I, D_I]``
+    from the normed query latent, ``w [B, S, H_I]`` and ``k_I [B, S, D_I])``
+    from the layer's normed input; q_I and k_I rotated on their first
+    ``qk_rope_head_dim`` values. No adapter, and nothing differentiated: the
+    choice they make is a set."""
+    b, s, _ = h.shape
+    rope = cfg.qk_rope_head_dim
+    rotate = lambda x: jnp.concatenate(
+        [rope_interleaved(x[..., :rope], env["cos"], env["sin"]), x[..., rope:]], axis=-1)
+    q_i = linear(c_q, p["w_index_q"]).reshape(b, s, cfg.index_heads, cfg.index_head_dim)
+    k_i = _layer_norm(linear(h, p["w_index_k"]), p["index_k_norm"], p["b_index_k"],
+                      INDEX_NORM_EPS)
+    return jax.lax.stop_gradient((rotate(q_i), linear(h, p["w_index_w"]), rotate(k_i)))
+
+
+def _segment_choice(q_i, w_i, key_pages, *, cfg, env):
+    """A prefill segment's choice, ``[B, S, W * page_size]`` bool over the
+    positions of the row's page table: each query's index scores over the
+    blocks of keys up to and including its own (from the pages, the segment's
+    own just written), then ``chosen_mask``. While the segment ends within
+    ``index_topk`` tokens every query attends all it sees, and nothing is
+    scored."""
+    idx, ps, start = env["page_indices"], env["page_size"], env["segment_start"]
+    b, s = q_i.shape[:2]
+    per, width = s // ps, idx.shape[1] * ps
+    visible = jnp.arange(width, dtype=jnp.int32)[None, None, :] <= env["q_pos"][:, :, None]
+
+    def choose():
+        def block(j, out):
+            at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)
+            keys = key_pages[at].reshape(b, s, -1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, index_scores(q_i, w_i, keys), j * s, axis=2)
+
+        with jax.named_scope(telemetry.MODEL_INDEX_SCORE):
+            scores = jax.lax.fori_loop(
+                0, start // s + 1, block, jnp.zeros((b, s, width), jnp.float32))
+        with jax.named_scope(telemetry.MODEL_INDEX_SELECT):
+            return chosen_mask(scores, visible, cfg.index_topk)
+
+    return jax.lax.cond(start + s <= cfg.index_topk, lambda: visible, choose)
 
 
 def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,
-                lora_scale):
-    """Latent attention in each mode. Returns (o [B, S, H, v], the layer's
-    page array or None)."""
+                lora_scale, index=None):
+    """Latent attention in each mode, behind the index's choice where
+    ``index = (q_I, w, k_I, the layer's index-key pages or None)`` is given.
+    Returns (o [B, S, H, v], the layer's page array or None, its index-key
+    pages or None)."""
     b, s, heads, nope = q_nope.shape
     expand = lambda rows: proj(rows, p, lora, "wkv_b", "bkv_b", lora_scale).reshape(
         rows.shape[0], rows.shape[1], heads, nope + cfg.v_head_dim)
     if mode == "full":
-        with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
-            kv = expand(c)
         with jax.named_scope(telemetry.MODEL_ATTN_CORE):
             pos = jnp.arange(s)
             mask = (pos[None, :] <= pos[:, None])[None] & (env["valid"] > 0)[:, None, :]
+        if index is not None:
+            with jax.named_scope(telemetry.MODEL_INDEX_SCORE):
+                scores = index_scores(*index[:3])
+            with jax.named_scope(telemetry.MODEL_INDEX_SELECT):
+                mask = chosen_mask(scores, mask, cfg.index_topk)
+        with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+            kv = expand(c)
+        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
             return expanded_finish(
-                expanded_attention(q_nope, q_pe, kv, k_pe, mask), c.dtype), None
+                expanded_attention(q_nope, q_pe, kv, k_pe, mask), c.dtype), None, None
     idx, ps, rank = env["page_indices"], env["page_size"], cfg.kv_lora_rank
     rope = cfg.qk_rope_head_dim
     row = jnp.concatenate(  # [B, S, latent_row]: [c, k_pe] and zeros to whole tiles
         [c, k_pe, jnp.zeros((b, s, cfg.latent_row - cfg.latent_dim), c.dtype)],
         axis=-1).astype(pages.dtype)
     if mode == "decode":
+        if index is not None:  # one token a row
+            index = (*(x[:, 0] for x in index[:3]), index[3])
         return _absorbed_decode(q_nope[:, 0], q_pe[:, 0], row[:, 0], pages, p, lora,
-                                cfg=cfg, env=env, lora_scale=lora_scale)
+                                cfg=cfg, env=env, lora_scale=lora_scale, index=index)
     # one page-aligned segment of a prefill, every row at offset ``start``:
     # write its pages whole, then attend over the row's pages up to and
     # including them, a segment's worth of keys at a time, expanded
     start, per = env["segment_start"], s // ps
+    key_pages = chosen = None
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, per, axis=1)
         pages = pages.at[dest.reshape(-1)].set(row.reshape(b * per, ps, -1))
+        if index is not None:
+            q_i, w_i, k_i, key_pages = index
+            key_pages = key_pages.at[dest.reshape(-1)].set(
+                k_i.reshape(b * per, ps, -1).astype(key_pages.dtype))
+    if index is not None:
+        chosen = _segment_choice(q_i, w_i, key_pages, cfg=cfg, env=env)
 
     def block(j):  # the segment's own keys come from its pages too
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
@@ -922,37 +1063,46 @@ def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,
 
     with jax.named_scope(telemetry.MODEL_ATTN_CORE):
         return expanded_segment(
-            q_nope, q_pe, block, start, cfg.v_head_dim, c.dtype), pages
+            q_nope, q_pe, block, start, cfg.v_head_dim, c.dtype, chosen), pages, key_pages
 
 
-def _latent_block(x, p, lora, pages, *, moe: bool, cfg: ModelConfig, mode: str,
+def _latent_block(x, p, lora, cache, *, moe: bool, cfg: ModelConfig, mode: str,
                   env: dict, proj, lora_scale: float):
     """One latent-attention layer with a dense MLP or routed experts:
-    (x, the layer's page array, the expert layer's stats or None)."""
+    (x, the layer's page array, the expert layer's stats or None). Where the
+    model has an index, ``cache`` and what is returned in its place are the
+    pair (latent pages, index-key pages)."""
     b, s, _ = x.shape
     heads, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    pages, key_pages = cache if cfg.index_topk and cache is not None else (cache, None)
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, cfg.head_dim)
+        c_q = h
+        if "wq_a" in p:  # the query's own normed latent
+            c_q = rms_norm(proj(h, p, lora, "wq_a", "bq_a", lora_scale), p["q_a_norm"],
+                           cfg.rms_norm_eps)
+        q = proj(c_q, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, cfg.head_dim)
         kva = proj(h, p, lora, "wkv_a", "bkv_a", lora_scale)
         c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
     with jax.named_scope(telemetry.MODEL_ATTN_CORE):
         q_pe = rope_interleaved(q[..., nope:], env["cos"], env["sin"])
         k_pe = rope_interleaved(kva[..., rank:], env["cos"], env["sin"])
-    o, pages = _latent_mix(
+    index = None
+    if cfg.index_topk:
+        with jax.named_scope(telemetry.MODEL_INDEX_SCORE):
+            index = (*_index_inputs(h, c_q, p, cfg=cfg, env=env), key_pages)
+    o, pages, key_pages = _latent_mix(
         q[..., :nope], q_pe, c, k_pe, pages, p, lora, cfg=cfg, mode=mode, env=env,
-        proj=proj, lora_scale=lora_scale)
+        proj=proj, lora_scale=lora_scale, index=index)
+    if cfg.index_topk:
+        pages = (pages, key_pages)
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         x = x + proj(o.reshape(b, s, heads * cfg.v_head_dim), p, lora, "wo", "bo",
                      lora_scale)
     if not moe:
         return _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale), pages, None
-    with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
-        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    routed, stats = moe_half(h, p, cfg, alive=env.get("alive"))
-    if "w_gate" in p:  # the shared expert: x + S(h)
-        x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
-    return x + routed, pages, stats
+    x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj, lora_scale=lora_scale)
+    return x, pages, stats
 
 
 def _shared_layers(block):
@@ -1031,7 +1181,8 @@ def forward_hybrid(
             "paged_impl": paged_impl,
         }
         if cfg.latent:  # read off the table once a step, for every layer
-            with jax.named_scope(telemetry.MODEL_LATENT_ATTN):
+            with jax.named_scope(telemetry.MODEL_INDEX_SCORE if cfg.index_topk
+                                 else telemetry.MODEL_LATENT_ATTN):
                 env["page_walk"] = _latent_page_walk(env, cfg)
     else:
         start = kv_cache["segment_start"]
@@ -1125,9 +1276,14 @@ def forward_hybrid(
             p.update(whole, experts_layer=j)
         lora_p = _slice_layer(lora_stacks[kind], j) if kind in lora_stacks else None
         if cfg.latent:  # every layer keeps pages: layer i's are new["k"][i]
-            x, new["k"][i], layer_stats = block(
-                x, p, lora_p, None, new["k"][i], kind=kind,
+            held = (new["k"][i], new["v"][i]) if cfg.index_topk else new["k"][i]
+            x, held, layer_stats = block(
+                x, p, lora_p, None, held, kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
+            if cfg.index_topk:  # and its index keys new["v"][i]
+                new["k"][i], new["v"][i] = held
+            else:
+                new["k"][i] = held
             if moe_stats is not None and layer_stats is not None:
                 moe_stats = moe_stats + layer_stats
         elif mixer in _MIXER_CACHE:
@@ -1176,12 +1332,17 @@ def forward_hybrid(
         live = b if env.get("alive") is None else env["alive"].sum()
         out["ssm_stats"] = kv_cache["ssm_stats"] + jnp.asarray(
             cfg.kind_count("mamba") * live, jnp.int32)
-    if "window_stats" in kv_cache and mode == "decode":  # every window layer alike
-        alive = env.get("alive")
-        keys = (env["lengths"] + 1) * (1 if alive is None else alive.astype(jnp.int32))
-        units = lambda n: (-(-n // WINDOW_COUNT_UNIT)).sum()
-        out["window_stats"] = kv_cache["window_stats"] + cfg.mixer_count("window") * (
-            jnp.stack([units(jnp.minimum(keys, cfg.sliding_window)), units(keys)]))
+    # keys a live row's decoded token attends of those it sees, in whole units:
+    # every window layer alike, and every layer of a model with an index
+    for name, layers, most, unit in (
+            ("window_stats", cfg.mixer_count("window"), cfg.sliding_window, WINDOW_COUNT_UNIT),
+            ("index_stats", cfg.num_layers, cfg.index_topk, INDEX_COUNT_UNIT)):
+        if name in kv_cache and mode == "decode":
+            alive = env.get("alive")
+            keys = (env["lengths"] + 1) * (1 if alive is None else alive.astype(jnp.int32))
+            units = lambda n: (-(-n // unit)).sum()
+            out[name] = kv_cache[name] + layers * (
+                jnp.stack([units(jnp.minimum(keys, most)), units(keys)]))
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
             kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)

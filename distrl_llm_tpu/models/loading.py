@@ -76,6 +76,9 @@ def _latent_layer_names(cfg: ModelConfig, kind: str) -> dict[str, Any]:
     """Leaf -> the layer's checkpoint name (a list over experts for an expert
     stack), for one layer of ``kind``."""
     names: dict[str, Any] = dict(_HF_LATENT_MAP)
+    if cfg.q_lora_rank:  # deepseek_v3's low-rank query path: ``wq`` is q_b_proj
+        names.update(wq="self_attn.q_b_proj.weight", wq_a="self_attn.q_a_proj.weight",
+                     q_a_norm="self_attn.q_a_layernorm.weight")
     if kind == "latent":
         names.update({k: f"mlp.{v}.weight" for k, v in _HF_LATENT_MLP.items()})
         return names
@@ -158,10 +161,17 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
 
 
 def _refuse_unnamed(cfg: ModelConfig) -> None:
-    """A ``solar_open2``, ``brumby``, ``jamba`` or ``exaone_moe`` checkpoint is refused by name, in both directions:
+    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe`` or ``glm_moe_dsa`` checkpoint is refused by name, in both directions:
     its published tensor names cannot be read here, and names guessed for the
     delta-rule layers' convolutions, low-rank pairs and gates would load or
     save something else under the model's name. Seeded weights only."""
+    if cfg.index_topk:
+        raise NotImplementedError(
+            "model_type 'glm_moe_dsa' checkpoints are not supported: the published "
+            "tensor names of its index (the three projections and the key's "
+            "LayerNorm) and of its multi-token-prediction module cannot be read "
+            "here, and a guessed name would load or save something else under the "
+            "model's name; the model runs from seeded weights only (init_params)")
     if cfg.power:
         raise NotImplementedError(
             "model_type 'brumby' checkpoints are not supported: the published "
@@ -371,7 +381,7 @@ def save_hf_checkpoint(
     if cfg.latent:
         del hf_cfg["head_dim"]  # the query head is nope + rope
         hf_cfg.update(
-            kv_lora_rank=cfg.kv_lora_rank, q_lora_rank=None,
+            kv_lora_rank=cfg.kv_lora_rank, q_lora_rank=cfg.q_lora_rank or None,
             qk_nope_head_dim=cfg.qk_nope_head_dim,
             qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
             n_routed_experts=cfg.n_routed_experts,

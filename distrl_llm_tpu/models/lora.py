@@ -20,7 +20,7 @@ Params = dict[str, Any]
 
 # layer-param key → (in_dim_attr, out_dim_attr) resolved against ModelConfig
 _TARGET_DIMS = {
-    "wq": ("hidden_size", "q_dim"),
+    "wq": ("q_in", "q_dim"),
     "wk": ("hidden_size", "kv_dim"),
     "wv": ("hidden_size", "kv_dim"),
     "wo": ("o_dim", "hidden_size"),
@@ -30,6 +30,8 @@ _TARGET_DIMS = {
     # latent attention (kv_a_proj_with_mqa, kv_b_proj); wo's input is then H x v
     "wkv_a": ("hidden_size", "latent_dim"),
     "wkv_b": ("kv_lora_rank", "kvb_dim"),
+    # its low-rank query path: q_a_proj, and ``wq`` is then q_b_proj (q_in the rank)
+    "wq_a": ("hidden_size", "q_lora_rank"),
     # a Mamba layer's two projections: [u, z] from the stream, and back
     "w_in": ("hidden_size", "mamba_in_dim"),
     "w_out": ("mamba_inner", "hidden_size"),
@@ -41,6 +43,9 @@ DEFAULT_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # kv_b, o, and the gated MLP that is a dense layer's MLP or an expert layer's
 # shared expert. The router and the routed experts are frozen.
 LATENT_TARGETS = ("wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down")
+# with a low-rank query path q_a joins them, LAST: the others' keys stay theirs.
+# A learned index (its three projections and its norm) carries no adapter
+LATENT_RANK_TARGETS = (*LATENT_TARGETS, "wq_a")
 # a Mamba layer has no q, k, v or o: its targets are W_in, W_out and the MLP's
 # three. W_x, W_dt, the convolution, A_log, D and the inner norms are frozen.
 MAMBA_TARGETS = ("w_in", "w_out", "w_gate", "w_up", "w_down")
@@ -75,7 +80,8 @@ def init_lora_params(
     kind and the shared expert's in the others."""
     named = targets is not None
     if targets is None:
-        targets = LATENT_TARGETS if cfg.latent else DEFAULT_TARGETS
+        targets = ((LATENT_RANK_TARGETS if cfg.q_lora_rank else LATENT_TARGETS)
+                   if cfg.latent else DEFAULT_TARGETS)
 
     def factors(rng, n_layers: int, dims: dict[str, int], targets=targets) -> Params:
         layers: Params = {}
@@ -95,6 +101,7 @@ def init_lora_params(
         for attr in ("hidden_size", "intermediate_size", "q_dim", "kv_dim")
     }
     dims["o_dim"] = cfg.q_dim  # what wo reads
+    dims["q_in"] = cfg.hidden_size  # and wq
     kind_targets: dict[str, Sequence[str]] = {}  # a kind whose targets are its own
     if not cfg.hybrid:
         return {"layers": factors(rng, cfg.num_layers, dims)}
@@ -104,6 +111,8 @@ def init_lora_params(
             kvb_dim=cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
             o_dim=cfg.num_heads * cfg.v_head_dim,
         )
+        if cfg.q_lora_rank:
+            dims.update(q_lora_rank=cfg.q_lora_rank, q_in=cfg.q_lora_rank)
         per_kind = {
             "latent": dims,
             "latent_moe": {**dims, "intermediate_size": cfg.shared_expert_size},

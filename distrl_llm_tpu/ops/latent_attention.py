@@ -133,7 +133,8 @@ def expanded_segment_impl(q_nope: jax.Array, v_dim: int) -> str:
     return "xla"
 
 
-def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype) -> jax.Array:
+def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
+                     chosen=None) -> jax.Array:
     """A prefill segment's attention, ``[B, S, H, v]``: queries at positions
     ``start ..`` (every row alike) over the blocks ``0 .. start // S`` of ``S``
     keys each, block ``j`` at positions ``j * S ..`` and the last one the
@@ -143,9 +144,13 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype) -> jax.Array
     ``expanded_segment_impl`` names (recorded in ``dispatch_choices``): one
     ``expanded_fold_kernel`` launch, whose scores never leave VMEM and whose
     carry keeps the kernel's layout from the first fold to the last, or
-    ``expanded_fold``, which is ``expanded_attention``."""
+    ``expanded_fold``, which is ``expanded_attention``. ``chosen [B, S, keys]``
+    bool, if given, is the whole mask over the row's key positions (a learned
+    index's choice, causality in it): the folds are then ``expanded_attention``
+    under its slices, whatever the backend (the kernel's mask is two
+    positions)."""
     b, s, h, nope = q_nope.shape
-    impl = expanded_segment_impl(q_nope, v_dim)
+    impl = "xla" if chosen is not None else expanded_segment_impl(q_nope, v_dim)
     dispatch_choices[dispatch_key(h, nope, q_pe.shape[-1], v_dim, s, q_nope.dtype)] = impl
     if impl == "kernel":
         queries, first, finish = fold_queries(q_nope, q_pe), fold_start, fold_finish
@@ -153,6 +158,10 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype) -> jax.Array
     else:
         queries, first, finish = (q_nope, q_pe), expanded_start, expanded_finish
         one = expanded_fold
+        if chosen is not None:
+            one = lambda qn, qp, kv, k_pe, _, k_start, carry: expanded_attention(
+                qn, qp, kv, k_pe,
+                jax.lax.dynamic_slice_in_dim(chosen, k_start, s, axis=2), carry)
     carry = jax.lax.fori_loop(
         0, start // s + 1,
         lambda j, carry: one(*queries, *block(j), start, j * s, carry),

@@ -56,6 +56,9 @@ _ROW = {"wo", "w_down", "w_out"}
 # a latent-attention / expert layer's own leaves: whole on every chip
 _REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
                "experts_up", "experts_down",
+               # its low-rank query path's first half and a learned index's
+               # leaves (models/hybrid.py): small beside the experts
+               "wq_a", "w_index_q", "w_index_k", "w_index_w", "b_index_k",
                # a power-retention layer's log-decay: one column a KV head
                "w_decay", "b_decay"}
 

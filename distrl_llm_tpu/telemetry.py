@@ -103,6 +103,13 @@ ENGINE_DECODE_VIEW_BYTES = "engine/decode_view_bytes"
 # and filed at readback; attended / visible is what the window saves
 ENGINE_WINDOW_PAGES_ATTENDED = "engine/window_pages_attended"  # counter
 ENGINE_WINDOW_PAGES_VISIBLE = "engine/window_pages_visible"    # counter
+# a learned index over tokens (glm_moe_dsa): per live row, layer and decode
+# step, the tokens attended (min(context, index_topk)) and the tokens latent
+# attention without the index would attend (the context), in the same units of
+# 128 rounded up. Carried in ``mixer["index_stats"]`` and filed at readback;
+# 100% the day a layer silently attends everything
+ENGINE_INDEX_TOKENS_ATTENDED = "engine/index_tokens_attended"  # counter
+ENGINE_INDEX_TOKENS_VISIBLE = "engine/index_tokens_visible"    # counter
 # what the learner's rematerialised layer scan keeps for the backward pass
 # (learner/remat.py), filed when a train step first meets a batch shape: how
 # many of the five named products of the frozen weights (q, k, v, the MLP's
@@ -162,6 +169,16 @@ MODEL_SSM = "model/ssm"
 # included. q, k, v, o stay ``model/attn_proj``, the ring's write
 # ``engine/kv_write``
 MODEL_WINDOW_ATTN = "model/window_attn"
+# a learned index over tokens beside latent attention (glm_moe_dsa,
+# ops/token_index.py through models/hybrid.py::_latent_block): the index's
+# three projections, its key's LayerNorm, RoPE and the scores of a query's
+# index heads over the cached index keys; the choice of the top tokens; and,
+# in decode, the gather of the chosen latent rows and absorbed attention over
+# them. A prefill segment's masked folds stay ``model/attn_core``, the index
+# key's write ``engine/kv_write``
+MODEL_INDEX_SCORE = "model/index_score"
+MODEL_INDEX_SELECT = "model/index_select"
+MODEL_INDEXED_ATTN = "model/indexed_attn"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -221,6 +238,7 @@ SCOPE_NAMES = (
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
     MODEL_SSM, MODEL_WINDOW_ATTN,
+    MODEL_INDEX_SCORE, MODEL_INDEX_SELECT, MODEL_INDEXED_ATTN,
 )
 
 

@@ -909,8 +909,49 @@ def test_from_hf_config_reads_the_published_file():
     assert full.layer_kinds.count("latent_moe") == 26
 
 
+def test_a_deepseek_v3_with_a_query_rank_loads_and_equals_the_reference():
+    """``q_lora_rank`` was refused by name until PR 54: the low-rank query path
+    (q_a_proj, q_a_layernorm, q_b_proj) now loads, takes an adapter on both
+    halves, and equals ``perfbench/reference_dsa_moe.py`` told an index that
+    chooses every token (that reference with no choice to make IS a
+    deepseek_v3 with a rank; ``reference_latent_moe.py`` states
+    ``q_lora_rank null``)."""
+    import dataclasses
+
+    from perfbench import reference_dsa_moe
+
+    loaded = ModelConfig.from_hf_config(hf_config(q_lora_rank=1536))
+    assert (loaded.q_lora_rank, loaded.index_topk, loaded.model_type) == (
+        1536, 0, "deepseek_v3")
+    cfg = dataclasses.replace(CFG, q_lora_rank=48)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: 1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(3), x.shape)
+        if str(path[-1].key).endswith("norm") else 3.0 * x,
+        init_params(jax.random.PRNGKey(0), cfg))
+    lora = jax.tree_util.tree_map_with_path(
+        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
+        if str(path[-1].key) == "b" else x, init_lora_params(jax.random.PRNGKey(1), cfg, 4))
+    assert params["layers"]["latent"]["wq"].shape == (1, 48, 4 * 24)
+    assert params["layers"]["latent"]["wq_a"].shape == (1, 64, 48)
+    assert set(lora["layers"]["latent_moe"]) == {
+        "wq_a", "wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down"}
+    ids = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 1, 256)
+    got, _ = forward(params, cfg, ids, lora=lora, lora_scale=LORA_SCALE)
+    # the same weights under an index of one head that chooses all 40 tokens
+    told = dataclasses.replace(cfg, index_heads=1, index_head_dim=8, index_topk=64)
+    index = lambda n: {
+        "w_index_q": jnp.ones((n, 48, 8)), "w_index_k": jnp.ones((n, 64, 8)),
+        "index_k_norm": jnp.ones((n, 8)), "b_index_k": jnp.zeros((n, 8)),
+        "w_index_w": jnp.ones((n, 64, 1))}
+    with_index = {**params, "layers": {
+        kind: {**stack, **index(stack["wq"].shape[0])}
+        for kind, stack in params["layers"].items()}}
+    want = reference_dsa_moe.full_logits(
+        with_index, told, ids, jnp.ones_like(ids), lora=lora, lora_scale=LORA_SCALE)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
 @pytest.mark.parametrize("changes,named", [
-    ({"q_lora_rank": 1536}, "q_lora_rank"),
     ({"n_group": 8}, "n_group"),
     ({"topk_group": 4}, "topk_group"),
     ({"scoring_func": "softmax"}, "scoring_func"),

@@ -285,7 +285,7 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     def fragment(pages, q, c, k_pe, lengths, table, w_kvb, h, p):
         env = {"page_indices": table, "page_size": 128, "lengths": lengths}
         env["page_walk"] = _latent_page_walk(env, cfg)
-        o, pages = _latent_mix(
+        o, pages, _ = _latent_mix(  # no index: the third piece is None
             q[..., :128], q[..., 128:], c, k_pe, pages, {"wkv_b": w_kvb}, None,
             cfg=cfg, mode="decode", env=env, proj=_proj, lora_scale=1.0)
         y, stats = moe.moe_half(h, {**p, "experts_layer": 3}, cfg)
@@ -707,6 +707,120 @@ def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
     # the second body's buffers does not give)
     assert _entry_whiles(compiled.as_text()) == len(paged_engine._stage_sizes(4, 20)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 1.08 * 1.5064e9
+
+
+def _glm_cell():
+    """Five layers at the published widths, 16 of 256 experts held, the index whole."""
+    return _cell_config("glm-5-ep16-L5")
+
+
+def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
+    """``glm-5-ep16-L5.rollout-longctx-indexed``'s decode step (64 rows, a table
+    of 164 pages, a rank-32 adapter) fed the decode view: plain XLA throughout
+    (no Mosaic launch: the choice and the gather have no kernel yet). Both paged
+    arrays of every layer (latent rows ``[pages, 128, 640]``, index keys
+    ``[pages, 128, 128]``) are donated and written in place by a point scatter:
+    no copy of a pool. The exact choice is a sort a layer (``top_k`` of 2,048
+    over 21k scores), the chosen rows one gather ``[64, 2048, 640]`` a layer,
+    and the temporaries stay under half a gigabyte (0.29 GB when this was
+    written, beside 8.85 GB of arguments)."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    cfg = _glm_cell()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 64, 128, jnp.bfloat16
+    width = (20480 + 512) // page
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pages = 4 * 160 + rows * 5 + 8
+    cache = {
+        "k": tuple(chip((pages, page, 640), bf) for _ in range(5)),
+        "v": tuple(chip((pages, page, 128), bf) for _ in range(5)),
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    entry = text[text.index("ENTRY "):]
+    for pool in (f"bf16[{pages},128,640]", f"bf16[{pages},128,128]"):
+        copies = [line.strip()[:160] for line in entry.splitlines()
+                  if " copy(" in line and pool in line.split("(")[0]]
+        assert not copies, copies
+    assert "bf16[64,2048,640]" in text  # the chosen rows, gathered
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 5 * pages * page * (640 + 128) * 2
+    assert memory.temp_size_in_bytes < 0.5e9
+
+
+def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip):
+    """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through five
+    layers of latent attention behind the index, a dense MLP and four expert
+    layers in the grouped form, at the published widths): a segment's index
+    scores are made a block of 1,024 keys at a time into one ``[4, 1024,
+    20480]`` float32 array (336 MB) and its choice is a mask of the same shape,
+    not a ``[.., 32 heads, 20480]`` product (10.7 GB); the folds run under the
+    mask in the XLA form. 2.77 GB when this was written, beside 7.89 GB of
+    weights: the program peaks at 10.8 GB of the chip's 15.75."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import init_lora_params, init_params
+
+    cfg = _glm_cell()
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(
+        init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    prefill = functools.partial(
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=160, page_size=128,
+        lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
+        total_tokens=20480 + 512)
+    compiled = jax.jit(lambda *a: prefill(*a)).lower(
+        params, lora, chip((4, 20480), jnp.int32), chip((4, 20480), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    assert 'custom_call_target="tpu_custom_call"' not in compiled.as_text()
+
+
+def test_the_indexed_cells_float32_check_fits_beside_the_engine(chip):
+    """The check of ``glm-5-ep16-L5.rollout-longctx-indexed`` runs
+    ``perfbench/reference_dsa_moe.py`` over 4 rows of 20,992 tokens beside 7.9
+    GB of weights AND what the engine still holds (pages, the decode view: a
+    dummy argument of 1.5 GB stands for it). Its first form needed 8.85 GB of
+    temporaries and died on the chip in the compile (PERF.md section 6, PR 54);
+    an expert is now indexed in its stack where it is used and the heads run
+    in blocks through one ``lax.map``. A compile that succeeds IS the
+    assertion: one over the chip's 15.75 GB fails it with "Used X of 15.75G"."""
+    import os
+    import sys
+
+    from distrl_llm_tpu.models import init_lora_params, init_params
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from perfbench import reference_dsa_moe as ref
+
+    cfg = _glm_cell()
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(
+        init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    fn = jax.jit(lambda p, lo, i, m, held: ref.next_token_logprobs(
+        p, cfg, i, m, lora=lo, lora_scale=0.5) + held[0])
+    compiled = fn.lower(params, lora, chip((4, 20992), jnp.int32), chip((4, 20992), jnp.int32),
+                        chip((3 * 2**29,), jnp.uint8)).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 9.4e9
 
 
 def test_retention_prefill_is_one_stage_and_no_larger_than_it_was(chip):

@@ -104,6 +104,11 @@ def _host_account(rounds: list[dict], track: list[dict]) -> str:
                          for k in ("attended", "visible"))
     if visible:
         line += f", window {attended} of {visible} x 128 keys"
+    # and what a learned index let latent attention attend of what it saw
+    attended, visible = (sum(r.get("args", {}).get(f"index_tokens_{k}", 0) for r in rounds)
+                         for k in ("attended", "visible"))
+    if visible:
+        line += f", index {attended} of {visible} x 128 tokens"
     return line
 
 
