@@ -346,6 +346,25 @@ def _record_latent_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int
         cfg.num_layers * (n_seg * (n_seg + 1) // 2) * (ran == "kernel"))
 
 
+def _record_index_telemetry(cfg: ModelConfig, steps: int, prompt_pages: int,
+                            private_pages: int, page_size: int,
+                            segments: int | None = None) -> None:
+    """``ops/index_counted_choices``: the round's choices of the learned index
+    that were made by counting (``ops/token_index.py``), every layer's: a
+    decode step's where a row's page table is wider than ``index_topk``, and
+    those of the prefill's segments that end past ``index_topk`` (as far as
+    the longest row's ``segments``; every segment of the prompt's width where
+    it is not given). A model without an index files nothing."""
+    if not cfg.index_topk:
+        return
+    seg, n_seg = _hybrid_segments(prompt_pages, page_size)
+    n_seg = n_seg if segments is None else segments
+    chose = n_seg - min(n_seg, cfg.index_topk // seg)
+    if (prompt_pages + private_pages) * page_size > cfg.index_topk:
+        chose += steps
+    telemetry.counter_add(telemetry.OPS_INDEX_COUNTED_CHOICES, cfg.num_layers * chose)
+
+
 def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
                    prompt_pages: int, page_size: int, lora_scale: float,
                    cache_dtype, attn_impl: str, kv_quant: str = "none"):
@@ -4289,6 +4308,9 @@ class PagedGenerationEngine(LoraMailbox):
         _record_latent_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
+        _record_index_telemetry(
+            self.cfg, dispatched, self.prompt_pages, self.private_pages,
+            self.page_size, prompt_segments)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,
@@ -4418,6 +4440,9 @@ class PagedGenerationEngine(LoraMailbox):
         _record_latent_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
+        _record_index_telemetry(
+            self.cfg, steps_seen[0], self.prompt_pages, self.private_pages,
+            self.page_size, prompt_segments)
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

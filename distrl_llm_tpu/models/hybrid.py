@@ -77,7 +77,7 @@ scores and the choice as a mask over the expanded form. A prefill segment:
 the segment's scores over the row's cached keys, the choice as a mask over the
 folds (``expanded_attention``, never the kernel: it takes no mask). Decode:
 the scores walk the page table as absorbed attention did (a group's shared
-blocks of keys once), the chosen positions by ``top_k``, their latent rows
+blocks of keys once), the chosen positions off the same mask, their latent rows
 GATHERED ``[B, index_topk, latent_row]`` and attended in the absorbed form.
 The choice is not differentiated and the index carries no adapter. The round's
 counter ``index_stats`` [2] int32 takes the place of ``latent_stats``: tokens

@@ -9,22 +9,31 @@ query brings ``H_I`` small heads ``q_I [H_I, D_I]`` and a weight a head
 
 Token ``t`` attends the ``min(k, t + 1)`` tokens of largest ``I[t, .]``, the
 lower index among equal scores, AND NO OTHER. The choice is exact in every
-form here: ``jax.lax.top_k`` (which orders equals by index) gives a decode
-row's chosen positions, and a row of many queries takes its k-th largest
-score from a sort of the scores and keeps what lies above it and the first of
-what equals it, by counting (``chosen_mask``): the same set, as a mask. relu
-makes exact zeros, so equal scores are the rule at the bottom of a ranking and
-not an accident. ``approx_max_k`` and any recall under 1 are another model.
+form here, and NOTHING IS SORTED: the k-th largest score of a query is found
+by counting (``kth_largest``: the float32 scores are mapped to unsigned
+integers of the same order and the threshold is built from its top bits down,
+two bits a pass, each pass three compares on one read of the row), what lies
+above it is chosen and of what equals it the first few, again by counting
+(``chosen_mask``). A row of many queries uses the mask; a decode row reads
+its positions off the same mask in ascending order by rank within blocks of
+128 columns (``mask_positions``: two compares and one small one-hot product,
+no scatter and no gather of scalars). relu makes exact zeros, so equal scores
+are the rule at the bottom of a ranking and not an accident. ``approx_max_k``
+and any recall under 1 are another model.
 
 The scores are float32 products of the operands as they are cached (bf16 on
-the chip). Plain XLA throughout: a kernel for the choice and for the gather
-of the chosen rows is ROADMAP's.
+the chip). Plain XLA throughout (on a v5e a segment's ``[4, 1024, 20480]``
+k-th score takes 8 ms where the sort took 58, a decode step's positions for
+64 rows 0.58 ms where ``top_k`` took 1.24: PERF.md section 6, PR 55); the
+engine counts the choices made so in ``ops/index_counted_choices``. A kernel
+for the gather of the chosen rows is ROADMAP's.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from distrl_llm_tpu.ops.attention import NEG_INF
 
@@ -48,19 +57,65 @@ def index_scores(q_i: jax.Array, w: jax.Array, k_i: jax.Array) -> jax.Array:
                       jax.nn.relu(dots))
 
 
+#: the bits of a score's ordered image that one counting pass settles: a pass
+#: compares the row against ``2 ** COUNT_BITS - 1`` candidates on one read
+COUNT_BITS = 2
+#: positions are read off a choice mask by rank within blocks of this many
+#: columns: a lane tile, and a count that bf16 holds exactly
+RANK_BLOCK = 128
+#: a float32's sign bit. A NUMPY scalar on purpose: a jax Array made outside a
+#: trace is hoisted into the jitted program's arguments, and the engine's
+#: decode step was then called with layers + 1 buffers too few (jax 0.9.0)
+_SIGN = np.int32(-2 ** 31)
+
+
+def ordered_image(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose INTEGER order is the floats' order: a negative's
+    low 31 bits flipped, then the sign bit of every value. ``-0.0`` (a negative
+    head weight times relu's zero) first becomes ``+0.0``, which it equals
+    under the float compare that follows the count. No NaN is expected."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    return jax.lax.bitcast_convert_type(bits ^ ((bits >> 31) | _SIGN), jnp.uint32)
+
+
+def from_ordered_image(image: jax.Array) -> jax.Array:
+    """``ordered_image``'s inverse (``-0.0`` comes back as ``+0.0``)."""
+    bits = jax.lax.bitcast_convert_type(image, jnp.int32)
+    return jax.lax.bitcast_convert_type(bits ^ (~(bits >> 31) | _SIGN), jnp.float32)
+
+
+def kth_largest(held: jax.Array, k: int) -> jax.Array:
+    """The k-th largest value of every row of ``held [..., Sk]`` float32, as
+    ``[..., 1]``, WITHOUT ordering the row: the threshold's ordered image is
+    built from its top bits down, each pass keeping the largest candidate
+    prefix that ``k`` or more of the row's images still reach (counts fall as
+    the candidate rises, so the digit is how many candidates hold). 32 /
+    COUNT_BITS passes over the row, each one read and a few compares."""
+    image = ordered_image(held)
+    digits = range(1, 1 << COUNT_BITS)
+
+    def settle(i, prefix):
+        shift = (32 - COUNT_BITS * (i + 1)).astype(jnp.uint32)
+        holds = [(image >= (prefix | (np.uint32(d) << shift))).sum(axis=-1, keepdims=True) >= k
+                 for d in digits]
+        return prefix | (sum(h.astype(jnp.uint32) for h in holds) << shift)
+
+    prefix = jax.lax.fori_loop(
+        0, 32 // COUNT_BITS, settle, jnp.zeros((*held.shape[:-1], 1), jnp.uint32))
+    return from_ordered_image(prefix)
+
+
 def chosen_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
     """The choice of every query as a mask ``[..., Sk]``: True at the
     ``min(k, visible tokens)`` keys of largest score among ``visible [..., Sk]``,
-    the lower index among equals. The k-th largest score is read off the sorted
-    scores; everything above it is chosen, and of what equals it the first few, as
-    many as are still wanted."""
+    the lower index among equals. The k-th largest score is found by counting
+    (``kth_largest``); everything above it is chosen, and of what equals it the
+    first few, as many as are still wanted."""
     width = scores.shape[-1]
     if k >= width:
         return visible
     held = jnp.where(visible, scores, NEG_INF)
-    # a sort of the values alone, and not a stable one: ``top_k`` and a stable
-    # sort each carry an index beside every value (equal values are one value)
-    kth = jnp.sort(held, axis=-1, stable=False)[..., width - k: width - k + 1]
+    kth = kth_largest(held, k)
     above = held > kth
     equal = held == kth
     wanted = k - above.sum(axis=-1, keepdims=True)
@@ -68,17 +123,45 @@ def chosen_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
     return (above | (equal & first)) & visible
 
 
+def mask_positions(mask: jax.Array, k: int):
+    """The first ``k`` True columns of every row of ``mask [B, Sk]`` in
+    ascending order: (positions ``[B, k]`` int32, which of them are one ``[B,
+    k]`` bool; the rest read 0). No sort, scatter or gather: a row's columns
+    are counted in blocks of RANK_BLOCK, output slot ``j`` finds its block by
+    comparing ``j`` with the blocks' cumulative counts, takes the block's
+    running counts through a one-hot product (counts to 128 are exact in
+    bf16) and its place in the block from one compare over them."""
+    b, width = mask.shape
+    blocks = -(-width // RANK_BLOCK)
+    tiles = jnp.pad(mask, ((0, 0), (0, blocks * RANK_BLOCK - width))).reshape(
+        b, blocks, RANK_BLOCK)
+    running = jnp.cumsum(tiles, axis=-1, dtype=jnp.int32)  # within a block, inclusive
+    total = running[..., -1]
+    ends = jnp.cumsum(total, axis=-1)  # [B, blocks]: True columns to a block's end
+    slot = jnp.arange(k, dtype=jnp.int32)
+    before = ends[:, None, :] <= slot[None, :, None]  # [B, k, blocks]: those that end before slot j
+    block = before.sum(axis=-1, dtype=jnp.int32)
+    rank = slot[None, :] - jnp.where(before, total[:, None, :], 0).sum(axis=-1)
+    own = block[..., None] == jnp.arange(blocks, dtype=jnp.int32)
+    counts = jnp.einsum("bjn,bnw->bjw", own.astype(jnp.bfloat16), running.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    place = (counts <= rank[..., None].astype(jnp.float32)).sum(axis=-1, dtype=jnp.int32)
+    seen = slot[None, :] < ends[:, -1:]
+    return jnp.where(seen, block * RANK_BLOCK + place, 0), seen
+
+
 def chosen_tokens(scores: jax.Array, lengths: jax.Array, k: int):
     """One query a row at position ``lengths [B]``: ``scores [B, Sk]`` over
     positions ``0 .. Sk``. Returns (positions ``[B, min(k, Sk)]`` int32, seen
-    ``[B, min(k, Sk)]`` bool): the chosen tokens, and which entries are one (a
-    row with fewer than ``k`` tokens has the rest False)."""
+    ``[B, min(k, Sk)]`` bool): the chosen tokens in ascending position
+    (``chosen_mask``'s set, read off by ``mask_positions``), and which entries
+    are one (a row with fewer than ``k`` tokens has the rest False)."""
     width = scores.shape[-1]
     pos = jnp.arange(width, dtype=jnp.int32)
-    held = jnp.where(pos[None, :] <= lengths[:, None], scores, NEG_INF)
-    _, at = jax.lax.top_k(held, min(k, width))
-    at = at.astype(jnp.int32)
-    return at, at <= lengths[:, None]
+    visible = pos[None, :] <= lengths[:, None]
+    if k >= width:
+        return jnp.broadcast_to(pos, visible.shape), visible
+    return mask_positions(chosen_mask(scores, visible, k), k)
 
 
 def index_paged_scores(q_i: jax.Array, w: jax.Array, key_pages: jax.Array, walk,

@@ -174,8 +174,11 @@ MODEL_WINDOW_ATTN = "model/window_attn"
 # three projections, its key's LayerNorm, RoPE and the scores of a query's
 # index heads over the cached index keys; the choice of the top tokens; and,
 # in decode, the gather of the chosen latent rows and absorbed attention over
-# them. A prefill segment's masked folds stay ``model/attn_core``, the index
-# key's write ``engine/kv_write``
+# them. The choice sorts nothing: the k-th largest score is found by counting
+# over the scores' ordered bits, a decode row's positions are read off the
+# mask by rank within blocks (the counter ``ops/index_counted_choices`` below
+# says how often a round chose so). A prefill segment's masked folds stay
+# ``model/attn_core``, the index key's write ``engine/kv_write``
 MODEL_INDEX_SCORE = "model/index_score"
 MODEL_INDEX_SELECT = "model/index_select"
 MODEL_INDEXED_ATTN = "model/indexed_attn"
@@ -218,6 +221,14 @@ OPS_POWER_KERNEL_STEPS = "ops/power_kernel_steps"
 # 0 where it took the XLA form (a CPU, small heads, float32). Filed beside the
 # three counters above; no metric reads it
 OPS_LATENT_KERNEL_FOLDS = "ops/latent_kernel_folds"
+# counter: a round's choices of a learned index that ran by counting
+# (ops/token_index.py::kth_largest, no sort): layers x the decode steps whose
+# row sees more than ``index_topk`` columns, plus layers x the prefill's
+# segments that end past ``index_topk`` (the earlier ones choose all they see
+# and count nothing). Host arithmetic a round, tracing on or off, nothing
+# fetched; a model without an index files nothing. Filed beside the four
+# counters above; no metric reads it
+OPS_INDEX_COUNTED_CHOICES = "ops/index_counted_choices"
 # device scopes: the train step (learner/). JAX writes the rest of the path:
 # ``transpose(jvp(learner/loss))`` is the backward pass and
 # ``rematted_computation`` under it the recomputed forward

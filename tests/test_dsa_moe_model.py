@@ -837,8 +837,68 @@ def test_the_new_scopes_and_counters_are_the_programs_constants():
             telemetry.MODEL_INDEXED_ATTN} <= set(telemetry.SCOPE_NAMES)
     assert telemetry.ENGINE_INDEX_TOKENS_ATTENDED == "engine/index_tokens_attended"
     assert telemetry.ENGINE_INDEX_TOKENS_VISIBLE == "engine/index_tokens_visible"
+    assert telemetry.OPS_INDEX_COUNTED_CHOICES == "ops/index_counted_choices"
     assert hybrid.INDEX_COUNT_UNIT == dsa_moe_counts.COUNT_UNIT == 128
     assert hybrid.INDEX_NORM_EPS == ref.INDEX_NORM_EPS == 1e-6
+
+
+@pytest.mark.parametrize("topk,steps,segments,want", [
+    (8, 24, None, 3 * (4 + 24)),   # a segment of 16 ends past 8: all four choose
+    (8, 24, 3, 3 * (3 + 24)),      # the longest row ends in its third segment
+    (40, 24, None, 3 * (2 + 24)),  # the first two segments end within 40 and choose all
+    (40, 0, 1, 0),
+    (64, 5, None, 3 * 5),          # the prompt's 64 columns are all chosen, a step's 88 are not
+    (88, 5, None, 0),              # ``k >= width`` everywhere: nothing is counted
+])
+def test_the_counter_is_layers_times_the_choices_made_by_counting(monkeypatch, topk, steps,
+                                                                  segments, want):
+    """``ops/index_counted_choices``: 3 layers x (the decode steps whose table of
+    88 columns is wider than ``index_topk`` + the segments of a 64-token prompt
+    that end past it); the cell's 5 x (512 + 18 of 20) = 2,650 a round."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_index_telemetry(
+        dataclasses.replace(CFG, index_topk=topk), steps, 8, 3, 8, segments)
+    assert filed == [("ops/index_counted_choices", want)]
+    filed.clear()  # a model without an index files nothing
+    paged_engine._record_index_telemetry(PRESETS["tiny-latent-moe"], steps, 8, 3, 8, segments)
+    assert filed == []
+
+
+def test_the_cells_round_counts_2650_choices(monkeypatch):
+    """``glm-5-ep16-L5.rollout-longctx-indexed``: 5 layers x (512 steps over a
+    table of 168 pages + the 18 of 20 segments of 1,024 that end past 2,048)."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    cell = SimpleNamespace(num_layers=5, index_topk=2048)  # what the function reads
+    paged_engine._record_index_telemetry(cell, 512, 160, 8, 128, 20)
+    assert filed == [("ops/index_counted_choices", 2650)]
+
+
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_round_files_its_counted_choices(weights, small_pieces, scheduler, slots):
+    """Both schedulers file the counter, tracing off: prompts of 40 and 57 tokens
+    in segments of 16 (the longest row's four segments all end past
+    ``index_topk`` = 8) and every dispatched step, in each of the 3 layers."""
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    before = telemetry.observe_snapshot()["counters"].get(
+        telemetry.OPS_INDEX_COUNTED_CHOICES, 0)
+    result = make_engine(scheduler, slots).generate(
+        params, lora, *prompts((40, 57)),
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=4),
+        jax.random.PRNGKey(3))
+    after = telemetry.observe_snapshot()["counters"][telemetry.OPS_INDEX_COUNTED_CHOICES]
+    assert result.steps_dispatched > 0
+    assert after - before == 3 * (4 + result.steps_dispatched)
 
 
 def test_a_round_runs_without_the_cyclic_collector_and_leaves_it_as_it_was():

@@ -253,6 +253,12 @@ def _entry_whiles(text: str) -> int:
     return entry[: entry.index("\n}")].count(" while(")
 
 
+def _sorts_under(text: str, scope: str) -> list[str]:
+    """The sort instructions of a compiled program whose metadata names ``scope``."""
+    return [line.strip()[:200] for line in text.splitlines()
+            if " sort(" in line and scope in line]
+
+
 def _kimi_cell():
     """Kimi-VL-A3B's language model as ``kimi-vl-a3b-L7`` runs it: 7 layers,
     every width as published."""
@@ -760,6 +766,7 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
                   if " copy(" in line and pool in line.split("(")[0]]
         assert not copies, copies
     assert "bf16[64,2048,640]" in text  # the chosen rows, gathered
+    assert _sorts_under(text, "model/index_select") == []  # chosen by counting (PR 55)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 5 * pages * page * (640 + 128) * 2
     assert memory.temp_size_in_bytes < 0.5e9
@@ -791,6 +798,59 @@ def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip
         params, lora, chip((4, 20480), jnp.int32), chip((4, 20480), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
     assert 'custom_call_target="tpu_custom_call"' not in compiled.as_text()
+    assert _sorts_under(compiled.as_text(), "model/index_select") == []  # PR 55
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_indexed_models_choice_compiles_to_no_sort(chip, monkeypatch, program):
+    """The test-size indexed model (``tiny-dsa``: 8 of 64-88 tokens chosen, 16-token
+    segments so that the prefill runs in two stages and every segment after the
+    first chooses): neither the decode step nor the staged prefill holds a
+    ``sort`` under ``model/index_select``. The k-th score is found by counting
+    and a decode row's positions are read off the mask by rank within blocks
+    (``ops/token_index.py``; PERF.md section 6, PR 55, has the chip's times that
+    took ``top_k`` out of decode as well). The router's ``top_k`` still sorts,
+    under ``model/moe_router``, and with the sort-based forms put back both
+    programs hold one here (tried when this was written), so the guard can tell."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.configs import PRESETS
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+
+    cfg = PRESETS["tiny-dsa"]
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(functools.partial(init_params, cfg=cfg), jax.random.PRNGKey(0)))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 4), jax.random.PRNGKey(1)))
+    rows, page, width, f32 = 8, 8, 11, jnp.float32
+    if program == "prefill":
+        prefill = functools.partial(
+            paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=8, page_size=page,
+            lora_scale=2.0, cache_dtype=f32, attn_impl="reference", total_tokens=width * page)
+        compiled = jax.jit(lambda *a: prefill(*a)).lower(
+            params, lora, chip((4, 64), jnp.int32), chip((4, 64), jnp.int32)).compile()
+        assert _entry_whiles(compiled.as_text()) == 2
+    else:
+        pages = 4 * 8 + rows * 3 + 1
+        cache = {
+            "k": tuple(chip((pages, page, cfg.latent_row), f32) for _ in range(cfg.num_layers)),
+            "v": tuple(chip((pages, page, cfg.index_head_dim), f32)
+                       for _ in range(cfg.num_layers)),
+            **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, f32))),
+            "lengths": chip((rows,), jnp.int32),
+            "page_indices": chip((rows, width), jnp.int32), "alive": chip((rows,), jnp.bool_)}
+
+        def step(params, lora, cache, ids):
+            return forward(params, cfg, ids, lora=lora, lora_scale=2.0, kv_cache=cache,
+                           page_size=page, paged_impl="auto")
+
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(
+            params, lora, cache, chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert telemetry.MODEL_INDEX_SELECT in text  # the choice is there, and named
+    assert _sorts_under(text, telemetry.MODEL_INDEX_SELECT) == []
 
 
 def test_the_indexed_cells_float32_check_fits_beside_the_engine(chip):
