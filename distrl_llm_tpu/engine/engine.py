@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import logging
 import math
+import statistics
 import threading
 import time
 import weakref
@@ -429,48 +430,128 @@ def cached_chunk_program(cache: dict, mu, key, fn_jit, alias_bytes: int,
         return cache[key]
 
 
+try:
+    import resource
+
+    def _involuntary_switches() -> int | None:
+        return resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
+
+    def _major_faults() -> int | None:
+        return resource.getrusage(resource.RUSAGE_SELF).ru_majflt
+except ImportError:  # a platform without getrusage
+
+    def _involuntary_switches() -> int | None:
+        return None
+
+    _major_faults = _involuntary_switches
+
+#: an unmarked boundary longer than this many of its round's median intervals
+#: is ``stalled``. The steady rounds of all eight rollout cells stay under 1.2
+#: (PERF.md section 6, PR 56, says from which readings)
+STALL_FACTOR = 1.5
+
+
 class RoundHostAccount:
     """The host's side of one wave's decode loop, on ``perf_counter``, with
-    tracing on or off. The clock is read per host BOUNDARY (where the loops
-    stop to read a snapshot), never per step: four scalars a wave, which
-    ``accumulate_round_stats`` folds into ``last_round_stats`` and files as
-    the gauges ``engine/host_busy_share``, ``engine/slowest_boundary_ms`` and
-    ``engine/slowest_boundary_host_ms``.
+    tracing on or off. The clocks are read per host BOUNDARY (where the loops
+    stop to read a snapshot), never per step, and every boundary is kept:
+    ``boundaries`` holds, for each interval between two consecutive returns
+    from the snapshot wait, ``(interval_s, host_s, cpu_s, nivcsw, steps,
+    marks)``: the interval, the part of it before its wait began, the CPU
+    seconds of ALL the process's threads over it (``time.process_time``), the
+    involuntary context switches over it (``getrusage``; None where the
+    platform has none), the decode steps dispatched in it, and which passes
+    that are long by design ran in it (``mark``: ``a`` admission, ``g`` grant,
+    ``p`` preemption; empty for a plain boundary).
 
     ``loop_s`` is the loop's wall (construction to ``stop()``); ``blocked_s``
-    the seconds inside the snapshot waits and the readback's blocking reads;
-    ``slowest_s`` the longest interval between two consecutive returns from
-    the snapshot wait and ``slowest_host_s`` the part of THAT interval before
-    its wait began: a long interval with a large host part is a host that
-    stalled, with a small one a device that was late."""
+    the seconds inside the snapshot waits and the readback's blocking reads
+    (``readback_s`` that last part alone); ``first_s`` construction to the
+    first return (set-up, the fan-out, the first admissions: in no interval of
+    the list); ``slowest_s`` the longest interval
+    and ``slowest_host_s`` the host part of THAT interval, which ``stop()``
+    derives from the list: a long interval with a large host part is a host
+    that stalled, with a small one a device that was late.
+    ``accumulate_round_stats`` folds a wave's account into
+    ``last_round_stats`` and files the gauges and the histogram."""
 
-    __slots__ = ("t0", "loop_s", "blocked_s", "slowest_s", "slowest_host_s",
-                 "_last_return")
+    __slots__ = ("t0", "loop_s", "blocked_s", "readback_s", "first_s",
+                 "slowest_s", "slowest_host_s", "boundaries", "_last_return",
+                 "_cpu", "_switches", "_steps", "_marks")
 
     def __init__(self):
-        self.loop_s = self.blocked_s = 0.0
+        self.loop_s = self.blocked_s = self.readback_s = self.first_s = 0.0
         self.slowest_s = self.slowest_host_s = 0.0
+        self.boundaries: list[tuple] = []
         self._last_return: float | None = None
+        self._cpu = self._steps = 0
+        self._switches: int | None = None
+        self._marks = ""
         self.t0 = time.perf_counter()
 
-    def waited(self, since: float) -> None:
-        """A snapshot wait that began at ``since`` has just returned."""
+    def waited(self, since: float, steps: int = 0) -> None:
+        """A snapshot wait that began at ``since`` has just returned;
+        ``steps`` decode steps have been dispatched so far."""
         now = time.perf_counter()
+        cpu = time.process_time()
+        switches = _involuntary_switches()
         self.blocked_s += now - since
         last = self._last_return
-        if last is not None and now - last > self.slowest_s:
-            self.slowest_s = now - last
-            self.slowest_host_s = since - last
+        if last is not None:
+            self.boundaries.append((
+                now - last, since - last, cpu - self._cpu,
+                None if switches is None else switches - self._switches,
+                steps - self._steps, self._marks,
+            ))
+        else:
+            self.first_s = now - self.t0
         self._last_return = now
+        self._cpu, self._switches, self._steps = cpu, switches, steps
+        self._marks = ""
+
+    def mark(self, what: str) -> None:
+        """A pass that is long by design (``a``, ``g`` or ``p``) ran in the
+        interval that the next return from the wait ends."""
+        if what not in self._marks:
+            self._marks += what
 
     def blocked(self, since: float) -> None:
         """The host has been blocked on the device from ``since`` to now (the
         readback's reads)."""
-        self.blocked_s += time.perf_counter() - since
+        waited = time.perf_counter() - since
+        self.blocked_s += waited
+        self.readback_s += waited
 
     def stop(self) -> float:
         self.loop_s = time.perf_counter() - self.t0
+        if self.boundaries:
+            self.slowest_s, self.slowest_host_s = max(
+                self.boundaries, key=lambda b: b[0])[:2]
         return self.loop_s
+
+
+def stalled_boundaries(boundaries) -> tuple[float, list[int], float]:
+    """``(median_s, stalled, recovered_s)`` of a round's boundaries: the
+    median interval; the indices of the unmarked boundaries longer than
+    ``STALL_FACTOR`` medians; and, for the longest of those, how much of it
+    the device had already worked off: the sum, over the boundaries that
+    follow it in the round with no fewer steps, of what each came back under
+    the median. If only the snapshot's arrival was late the device ran on
+    through its queue and the next boundaries are short (the round loses
+    less than the stall); if the device sat idle they come back at the median
+    and this reads 0."""
+    if not boundaries:
+        return 0.0, [], 0.0
+    median_s = statistics.median(b[0] for b in boundaries)
+    stalled = [i for i, b in enumerate(boundaries)
+               if not b[5] and b[0] > STALL_FACTOR * median_s]
+    if not stalled:
+        return median_s, stalled, 0.0
+    worst = max(stalled, key=lambda i: boundaries[i][0])
+    steps = boundaries[worst][4]
+    recovered_s = sum(max(0.0, median_s - b[0])
+                      for b in boundaries[worst + 1:] if b[4] >= steps)
+    return median_s, stalled, recovered_s
 
 
 def accumulate_round_stats(
@@ -484,7 +565,9 @@ def accumulate_round_stats(
     ``engine/prefill_tok_s`` / ``engine/decode_tok_s`` / ``engine/mfu``
     metric series from it. ``host`` is the wave's ``RoundHostAccount``: its
     walls are summed, its longest boundary is the maximum over the round's
-    waves, and the three gauges of the round so far are set from the sums."""
+    waves, its boundaries join the round's list (each interval also goes into
+    the histogram ``engine/boundary_ms``), and the four gauges of the round
+    so far are set from the sums."""
     if stats is None:
         stats = {
             "prefill_s": 0.0, "prefill_tokens": 0, "prompt_rows": 0,
@@ -493,9 +576,19 @@ def accumulate_round_stats(
     if host is not None:
         stats["loop_s"] = stats.get("loop_s", 0.0) + host.loop_s
         stats["host_blocked_s"] = stats.get("host_blocked_s", 0.0) + host.blocked_s
+        stats["readback_s"] = stats.get("readback_s", 0.0) + host.readback_s
+        stats["first_boundary_s"] = stats.get("first_boundary_s", 0.0) + host.first_s
         if host.slowest_s >= stats.get("slowest_boundary_s", 0.0):
             stats["slowest_boundary_s"] = host.slowest_s
             stats["slowest_boundary_host_s"] = host.slowest_host_s
+        boundaries = stats.setdefault("boundaries", [])
+        boundaries.extend(host.boundaries)
+        for b in host.boundaries:
+            telemetry.hist_observe(telemetry.ENGINE_BOUNDARY_MS, 1e3 * b[0])
+        if boundaries:
+            stats["boundary_median_s"] = statistics.median(b[0] for b in boundaries)
+            telemetry.gauge_set(
+                telemetry.ENGINE_BOUNDARY_MEDIAN_MS, 1e3 * stats["boundary_median_s"])
         if stats["loop_s"] > 0:
             telemetry.gauge_set(
                 telemetry.ENGINE_HOST_BUSY_SHARE,
@@ -519,6 +612,88 @@ def accumulate_round_stats(
     if gen_tokens:
         telemetry.counter_add(obs.OBS_GEN_TOKENS, gen_tokens)
     return stats
+
+
+_PRESSURE = ("cpu", "memory", "io")
+
+
+def _pressure_us() -> dict | None:
+    """``some total=`` microseconds of ``/proc/pressure/{cpu,memory,io}``:
+    how long at least one task of the machine has waited for each so far.
+    None where the kernel offers no such file."""
+    out = {}
+    try:
+        for what in _PRESSURE:
+            with open(f"/proc/pressure/{what}", encoding="ascii") as f:
+                some = f.readline()
+            out[what] = int(some.rsplit("total=", 1)[1])
+    except (OSError, IndexError, ValueError):
+        return None
+    return out
+
+
+class RoundMarks:
+    """What ``file_round`` needs of a round's START, read at the entry of an
+    engine's ``generate``: the wall and ``perf_counter``, the programs built
+    and the full collections' milliseconds so far (``telemetry``), the major
+    page faults and the machine's pressure totals. Two small reads of
+    ``/proc`` and a ``getrusage`` a round; tracing on or off."""
+
+    __slots__ = ("t0_ns", "t0", "programs", "gc_ms", "majflt", "pressure")
+
+    def __init__(self):
+        self.programs = telemetry.programs_built()
+        self.gc_ms = telemetry.gc_full_ms()
+        self.majflt = _major_faults()
+        self.pressure = _pressure_us()
+        self.t0_ns = time.time_ns()
+        self.t0 = time.perf_counter()
+
+
+def file_round(marks: RoundMarks, stats: dict | None) -> dict | None:
+    """The end of an engine's ``generate``: one record of the round into
+    ``telemetry.round_filed`` (the ring ``telemetry.round_records()`` reads),
+    the counter ``engine/stalled_boundaries``, the round's verdict into
+    ``stats`` (``stalled``, ``recovered_s``), and ONE warning where a
+    boundary stalled, so that standard error of an untraced run holds what
+    its result line cannot. ``stats`` is the round's ``last_round_stats``;
+    an engine that kept no host account files nothing."""
+    wall_s = time.perf_counter() - marks.t0
+    if not stats or "boundaries" not in stats:
+        return None
+    boundaries = stats["boundaries"]
+    median_s, stalled, recovered_s = stalled_boundaries(boundaries)
+    stats["stalled"], stats["recovered_s"] = stalled, recovered_s
+    majflt, pressure = _major_faults(), _pressure_us()
+    record = telemetry.round_filed({
+        "t0_ns": marks.t0_ns, "wall_s": wall_s,
+        "prefill_s": stats["prefill_s"], "loop_s": stats["loop_s"],
+        "blocked_s": stats["host_blocked_s"], "readback_s": stats["readback_s"],
+        "first_s": stats["first_boundary_s"],
+        "boundaries": [list(b) for b in boundaries],
+        "median_s": median_s, "stalled": stalled, "recovered_s": recovered_s,
+        "programs_built": telemetry.programs_built() - marks.programs,
+        "gc_full_s": (telemetry.gc_full_ms() - marks.gc_ms) / 1e3,
+        "majflt": None if majflt is None else majflt - marks.majflt,
+        "pressure_us": None if pressure is None or marks.pressure is None else {
+            k: pressure[k] - marks.pressure[k] for k in _PRESSURE},
+    })
+    if stalled:
+        telemetry.counter_add(telemetry.ENGINE_STALLED_BOUNDARIES, len(stalled))
+        worst = max(stalled, key=lambda i: boundaries[i][0])
+        interval_s, host_s, cpu_s, switches, _, _ = boundaries[worst]
+        _logger.warning(
+            "round %d: boundary %d of %d stalled: %.1f ms for a median of %.1f "
+            "(host part %.1f ms, process CPU %.1f ms, %s involuntary switches); "
+            "%d stalled in the round; the boundaries after it came back %.1f ms "
+            "under the median; full collections %.1f ms, programs built %d, "
+            "major faults %s, pressure gained (us) %s",
+            record["round"], worst, len(boundaries), 1e3 * interval_s,
+            1e3 * median_s, 1e3 * host_s, 1e3 * cpu_s, switches, len(stalled),
+            1e3 * recovered_s, 1e3 * record["gc_full_s"],
+            record["programs_built"], record["majflt"], record["pressure_us"],
+        )
+    return record
 
 
 def pool_nbytes(*trees) -> int:
@@ -655,8 +830,10 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int, *,
     Worst-case overshoot after all rows hit EOS is ~2·check steps — the
     fixed-shape analogue of continuous batching draining its tail.
 
-    ``host`` is the wave's account of the host's side: the clock is read
-    round each snapshot wait, once a boundary, never per step."""
+    ``host`` is the wave's account of the host's side: the clocks are read
+    round each snapshot wait, once a boundary, never per step. The boundary's
+    own launches (the copy and its transfer) are the span
+    ``engine/snapshot_launch``."""
     from collections import deque
 
     check = max(1, min(decode_chunk, 16))
@@ -669,11 +846,12 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int, *,
             state = step_fn(state)
         steps_done += 1
         if steps_done % check == 0 or steps_done == max_steps:
-            snap = jnp.copy(state.done)
-            try:
-                snap.copy_to_host_async()
-            except AttributeError:
-                pass
+            with telemetry.span(telemetry.ENGINE_SNAPSHOT_LAUNCH):
+                snap = jnp.copy(state.done)
+                try:
+                    snap.copy_to_host_async()
+                except AttributeError:
+                    pass
             snapshots.append(snap)
             stop = False
             while len(snapshots) > 1:
@@ -685,7 +863,7 @@ def run_decode_loop(step_fn, state, max_steps: int, decode_chunk: int, *,
                     # graftcheck: disable=GC301 -- reads a finished async copy >=1 check-intervals old
                     all_done = bool(np.asarray(snapshots.popleft()).all())
                 if host is not None:
-                    host.waited(t_wait)
+                    host.waited(t_wait, steps_done * steps_per_call)
                 if all_done:
                     stop = True
                     break
@@ -1161,16 +1339,19 @@ class GenerationEngine(LoraMailbox):
     ) -> GenerationResult:
         # a new round supersedes any swap consumed during the previous one
         # (the trainer hands the freshest adapter at round entry)
+        marks = RoundMarks()
         self._reset_lora_mailbox_round()
         self.last_round_stats = None  # waves of THIS round accumulate below
         params = self._decode_params(params)
         # on a role submesh of several chips the round's programs span them:
         # their Pallas kernels need the mesh in context (ops/per_device.py)
         with params_mesh(params):
-            return generate_in_waves(
+            result = generate_in_waves(
                 self._generate_wave, self.max_concurrent_rows, params, lora,
                 prompt_ids, prompt_mask, sampling, rng, self.pad_id,
             )
+        file_round(marks, self.last_round_stats)
+        return result
 
     def _generate_wave(
         self, params, lora, prompt_ids, prompt_mask,

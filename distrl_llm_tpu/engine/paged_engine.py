@@ -49,7 +49,9 @@ from distrl_llm_tpu.engine.engine import (
     GenerationResult,
     LoraMailbox,
     RoundHostAccount,
+    RoundMarks,
     accumulate_round_stats,
+    file_round,
     cached_chunk_program,
     generate_in_waves,
     scan_steps_guarded,
@@ -2523,10 +2525,13 @@ class PagedGenerationEngine(LoraMailbox):
     ) -> GenerationResult:
         # on a role submesh of several chips the round's programs span them:
         # their Pallas kernels need the mesh in context (ops/per_device.py)
+        marks = RoundMarks()
         with params_mesh(params), _no_full_collection():
-            return self._generate(
+            result = self._generate(
                 params, lora, prompt_ids, prompt_mask, sampling, rng
             )
+        file_round(marks, self.last_round_stats)
+        return result
 
     def _generate(self, params, lora, prompt_ids, prompt_mask, sampling, rng):
         total = prompt_ids.shape[0] * max(sampling.n, 1)
@@ -3820,20 +3825,22 @@ class PagedGenerationEngine(LoraMailbox):
                                 lora_cell[0], state, rng, temperature, top_p,
                             )
                             k_chunk = k_conf if chunk_fn is not None else 1
-            if fused_snap is not None:
-                # chunked steady state: the (done, seq) copies rode INSIDE
-                # the decode dispatch (_refill_decode_chunk's fused
-                # snapshot) — a boundary with no admissions or preemptions
-                # costs zero extra device round-trips
-                done_snap, seq_snap = fused_snap
-            else:
-                done_snap = jnp.copy(state.done)
-                seq_snap = jnp.copy(state.seq_lengths)
-            try:
-                done_snap.copy_to_host_async()
-                seq_snap.copy_to_host_async()
-            except AttributeError:
-                pass
+            with telemetry.span(telemetry.ENGINE_SNAPSHOT_LAUNCH,
+                                fused=fused_snap is not None):
+                if fused_snap is not None:
+                    # chunked steady state: the (done, seq) copies rode INSIDE
+                    # the decode dispatch (_refill_decode_chunk's fused
+                    # snapshot) — a boundary with no admissions or preemptions
+                    # costs zero extra device round-trips
+                    done_snap, seq_snap = fused_snap
+                else:
+                    done_snap = jnp.copy(state.done)
+                    seq_snap = jnp.copy(state.seq_lengths)
+                try:
+                    done_snap.copy_to_host_async()
+                    seq_snap.copy_to_host_async()
+                except AttributeError:
+                    pass
             snapshots.append(
                 (done_snap, seq_snap, epoch.copy(), host_cand.copy())
             )
@@ -3851,7 +3858,7 @@ class PagedGenerationEngine(LoraMailbox):
                 done_h = np.asarray(done_snap)
                 # graftcheck: disable=GC301 -- same delayed snapshot as the line above
                 seq_h = np.asarray(seq_snap)
-            host.waited(t_wait)
+            host.waited(t_wait, dispatched)
             if sl is not None:
                 # first-token detection off the same boundary snapshot: a
                 # slot whose resident length moved past its occupant's
@@ -3900,6 +3907,7 @@ class PagedGenerationEngine(LoraMailbox):
                 ):
                     # episode continues in place: occupant, pages and KV all
                     # kept — do not release or retire the slot
+                    host.mark("a")  # the next turn's prefill ran in this pass
                     continue
                 if pool.owned[s_i] or pool.shared[s_i]:
                     pool.release(s_i)  # frees pages + redirects to scratch
@@ -3910,6 +3918,7 @@ class PagedGenerationEngine(LoraMailbox):
                 host_cand[s_i] = total
             table_dirty = bool(idle)
             if budgeted:
+                host.mark("g")
                 with telemetry.span(telemetry.ENGINE_GRANT):
                     # grant pass: extend every occupied slot's pages to cover its
                     # write frontier through the next grant window (spec: the
@@ -3954,6 +3963,7 @@ class PagedGenerationEngine(LoraMailbox):
                                 )
                             else:
                                 victim = s_i  # nothing else to evict: self-evict
+                            host.mark("p")
                             with telemetry.span(telemetry.ENGINE_PREEMPT):
                                 preempt(victim)
                             if victim == s_i:
@@ -3970,6 +3980,7 @@ class PagedGenerationEngine(LoraMailbox):
                 else None
             )
             if admit_span is not None:
+                host.mark("a")
                 admit_span.__enter__()
                 groups0, slots0 = groups_prefilled, pool.total_admissions
             if continuous and group_queue:

@@ -52,7 +52,9 @@ from distrl_llm_tpu.engine.engine import (
     GenerationResult,
     LoraMailbox,
     RoundHostAccount,
+    RoundMarks,
     accumulate_round_stats,
+    file_round,
     cached_chunk_program,
     lora_signature,
     make_swap_aware_chunk_step,
@@ -328,6 +330,7 @@ class ShardedPagedEngine(LoraMailbox):
             raise ValueError(
                 f"prompts must be padded to {self.max_prompt_tokens}, got {p}"
             )
+        marks = RoundMarks()
         t_round = time.perf_counter()
         params = self._decode_params(params)
         max_steps = min(sampling.max_tokens, self.max_new_tokens)
@@ -433,4 +436,5 @@ class ShardedPagedEngine(LoraMailbox):
             gen_tokens=int(lengths.sum()), gen_rows=b * n, host=host,
         )
         self.last_round_stats["whole_round"] = True
+        file_round(marks, self.last_round_stats)
         return GenerationResult(tokens=out, lengths=lengths, logprobs=logps)

@@ -31,6 +31,8 @@ tok/s and MFU from the same file.
 from __future__ import annotations
 
 import bisect
+import collections
+import gc
 import json
 import os
 import threading
@@ -74,6 +76,11 @@ ENGINE_REFILL_DECODE = "engine/refill_decode"
 ENGINE_ADMIT = "engine/admit"  # host: one admission pass; device: the admit program
 ENGINE_GRANT = "engine/grant"  # a budgeted pool's page-grant pass
 ENGINE_SNAPSHOT_WAIT = "engine/snapshot_wait"  # the host waits on the device here
+# the boundary's own launches: the copies of the done flags (and, in the refill
+# loop, the lengths) and their ``copy_to_host_async()``; a fused snapshot that
+# rode inside the chunk's dispatch records its two ``copy_to_host_async`` calls
+# alone. Once a boundary. ``engine.snapshot_launch_ms`` reads it
+ENGINE_SNAPSHOT_LAUNCH = "engine/snapshot_launch"
 ENGINE_PREEMPT = "engine/preempt"
 ENGINE_READBACK = "engine/readback"  # the round's final blocking reads
 # one span round every launch of a decode step program from a host loop (args
@@ -88,6 +95,20 @@ ENGINE_DISPATCH = "engine/dispatch"
 ENGINE_HOST_BUSY_SHARE = "engine/host_busy_share"
 ENGINE_SLOWEST_BOUNDARY_MS = "engine/slowest_boundary_ms"
 ENGINE_SLOWEST_BOUNDARY_HOST_MS = "engine/slowest_boundary_host_ms"
+# every boundary of the round, filed with tracing on or off: each interval
+# between two returns from the snapshot wait into a histogram (the obs endpoint
+# serves its buckets, a sink its p50 / p90 / max), the round's median interval
+# as a gauge, and a counter of the boundaries ``engine.stalled_boundaries``
+# counts: unmarked ones longer than ``STALL_FACTOR`` x their round's median
+ENGINE_BOUNDARY_MS = "engine/boundary_ms"                # histogram
+ENGINE_BOUNDARY_MEDIAN_MS = "engine/boundary_median_ms"  # gauge
+ENGINE_STALLED_BOUNDARIES = "engine/stalled_boundaries"  # counter
+# a full (generation 2) collection of Python's cyclic collector: a span while
+# tracing is on (``collected``, ``uncollectable``), so that an idle gap under
+# one is named ``host/gc``; its milliseconds in a counter always. A round's
+# ``gc_full_s`` is the counter's gain over the round
+HOST_GC = "host/gc"
+HOST_GC_FULL_MS = "host/gc_full_ms"  # counter
 # the decode view of the frozen base (models/transformer.py::decode_view):
 # a counter, 1 each time an engine builds one (once a base: the memo is
 # ``engine.LoraMailbox._decode_params``), and a gauge, the bytes of the
@@ -299,7 +320,20 @@ class _State:
         # onto its predecessor's timeline (the killed-and-restarted merge
         # bug trace_report used to inherit)
         self.remote_incarnations: dict[str, Any] = {}
+        # --- the round ledger (ISSUE 56) ---------------------------------
+        # the last ROUND_RING rounds' records, as the engines filed them
+        self.rounds: collections.deque = collections.deque(maxlen=ROUND_RING)
+        self.rounds_filed = 0
+        # full collections: milliseconds so far, and the part not yet in the
+        # registry's counter (the collector's callback may run under
+        # ``lock``, so it takes no lock: the snapshots fold the part in)
+        self.gc_full_ms = 0.0
+        self.gc_unfiled_ms = 0.0
+        self.gc_open: tuple | None = None  # (t0 ns, its TraceAnnotation)
 
+
+#: rounds the ledger keeps
+ROUND_RING = 64
 
 _STATE = _State()
 
@@ -312,6 +346,7 @@ def configure(enabled: bool) -> None:
     _STATE.enabled = enabled
     if enabled:
         _ensure_compile_spans()
+        _ensure_gc_account()
 
 
 def enabled() -> bool:
@@ -636,6 +671,7 @@ def metrics_snapshot() -> dict[str, float]:
     and reset. Only series touched since the previous snapshot appear, so a
     run without (say) RPCs never logs ``cp/*`` zeros."""
     st = _STATE
+    _file_gc_ms(st)
     out: dict[str, float] = {}
     with st.lock:
         for name in sorted(st.touched):
@@ -677,6 +713,7 @@ def observe_snapshot() -> dict[str, Any]:
     histogram summaries. Unlike ``metrics_snapshot`` this never consumes
     anything, so scraping and the MetricsSink feed cannot fight."""
     st = _STATE
+    _file_gc_ms(st)
     with st.lock:
         return {
             "counters": dict(st.counters_total),
@@ -742,6 +779,97 @@ def recent_events(n: int = 512) -> list[dict]:
     st = _STATE
     with st.lock:
         return [dict(e) for e in st.events[-n:]]
+
+
+# ------------------------------------------- the round ledger and host/gc
+
+
+def round_filed(record: dict) -> dict:
+    """Keep one round's record (``engine.file_round`` builds it) in the ring of
+    the last ``ROUND_RING``; ``record["round"]`` becomes the count of rounds
+    this process filed before it. Tracing on or off."""
+    st = _STATE
+    with st.lock:
+        record["round"] = st.rounds_filed
+        st.rounds_filed += 1
+        st.rounds.append(record)
+    return record
+
+
+def round_records() -> list[dict]:
+    """The ring, oldest first. ``reset()`` drops it."""
+    st = _STATE
+    with st.lock:
+        return list(st.rounds)
+
+
+def programs_built() -> int:
+    """Programs JAX built (compiled, or loaded from the persistent cache) in
+    this process since the first call: the process's one ``CompileLog``,
+    started here if nothing has. Costs nothing between builds."""
+    _ensure_compile_spans()
+    return len(_COMPILE_SPANS.events) if _COMPILE_SPANS else 0
+
+
+def gc_full_ms() -> float:
+    """Milliseconds inside full collections so far (0 until the first
+    ``configure(True)`` or round installs the callback: ``_ensure_gc_account``)."""
+    _ensure_gc_account()
+    return _STATE.gc_full_ms
+
+
+_GC_ACCOUNT = False
+
+
+def _ensure_gc_account() -> None:
+    global _GC_ACCOUNT
+    if not _GC_ACCOUNT:
+        _GC_ACCOUNT = True
+        gc.callbacks.append(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry. Young collections return at once; a full one is
+    timed, and named by a span while tracing is on. Runs wherever an
+    allocation tripped the collector, possibly under ``_STATE.lock``: it takes
+    no lock and files nothing itself."""
+    if info["generation"] != 2:
+        return
+    st = _STATE
+    if phase == "start":
+        annotation = _trace_annotation(HOST_GC) if st.enabled else None
+        if annotation is not None:
+            annotation.__enter__()
+        st.gc_open = (time.time_ns(), annotation)
+        return
+    if st.gc_open is None:  # a reset() between the two phases
+        return
+    t1 = time.time_ns()
+    t0, annotation = st.gc_open
+    st.gc_open = None
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    ms = (t1 - t0) / 1e6
+    st.gc_full_ms += ms
+    st.gc_unfiled_ms += ms
+    if st.enabled:
+        st.events.append({
+            "ph": "X",
+            "name": HOST_GC,
+            "ts": t0 // 1000,
+            "dur": max((t1 - t0) // 1000, 1),
+            "tid": threading.get_ident(),
+            "args": {"collected": info.get("collected", 0),
+                     "uncollectable": info.get("uncollectable", 0)},
+        })
+
+
+def _file_gc_ms(st: _State) -> None:
+    """Fold the collections' milliseconds the callback could not file into
+    the counter ``host/gc_full_ms``."""
+    ms, st.gc_unfiled_ms = st.gc_unfiled_ms, 0.0
+    if ms:
+        counter_add(HOST_GC_FULL_MS, ms)
 
 
 # -------------------------------------------------- cross-process propagation
@@ -918,8 +1046,10 @@ class CompileLog:
                 [n for n in late if n not in before])
 
 
-# the process's one span-recording listener, started by the first
-# ``configure(True)``; False where JAX is not installed
+# the process's one listener to JAX's build events, started by the first
+# ``configure(True)`` or the first round's ``programs_built()``: it counts
+# every build and records a span for those made while tracing is on; False
+# where JAX is not installed
 _COMPILE_SPANS: Any = None
 
 

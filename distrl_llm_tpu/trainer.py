@@ -2163,6 +2163,9 @@ class Trainer:
                 # trace_report divides whole-engine tok/s by this before
                 # comparing against the single-chip peak
                 "chips": self._rollout_chips,
+                # the round ledger (the last 64 rounds' boundaries): one line
+                # a stalled round in trace_report
+                "rounds": telemetry.round_records(),
             },
         )
         log.info("telemetry trace written to %s", path)

@@ -713,6 +713,47 @@ class TestRegistry:
         assert snap["pool/spilled_pages"] == 2.0
         assert snap["pool/restore_ms_count"] == 1.0
 
+    def test_round_ledger_series_schema(self):
+        """Schema pin for the names ISSUE 56 added and their TYPES:
+        engine/snapshot_launch and host/gc are SPANS, engine/boundary_ms a
+        HISTOGRAM (served as Prometheus buckets by the obs endpoint),
+        engine/boundary_median_ms a GAUGE, engine/stalled_boundaries and
+        host/gc_full_ms COUNTERS, and the ledger a ring of ROUND_RING
+        records beside the registry."""
+        assert telemetry.ENGINE_SNAPSHOT_LAUNCH == "engine/snapshot_launch"
+        assert telemetry.HOST_GC == "host/gc"
+        assert telemetry.HOST_GC_FULL_MS == "host/gc_full_ms"
+        assert telemetry.ENGINE_BOUNDARY_MS == "engine/boundary_ms"
+        assert telemetry.ENGINE_BOUNDARY_MEDIAN_MS == "engine/boundary_median_ms"
+        assert telemetry.ENGINE_STALLED_BOUNDARIES == "engine/stalled_boundaries"
+        assert telemetry.ROUND_RING == 64
+        # host spans are not device scopes: no jitted program carries them
+        assert not {telemetry.ENGINE_SNAPSHOT_LAUNCH, telemetry.HOST_GC} & set(
+            telemetry.SCOPE_NAMES)
+        telemetry.hist_observe(telemetry.ENGINE_BOUNDARY_MS, 212.0)
+        telemetry.hist_observe(telemetry.ENGINE_BOUNDARY_MS, 2354.9)
+        telemetry.gauge_set(telemetry.ENGINE_BOUNDARY_MEDIAN_MS, 212.0)
+        telemetry.counter_add(telemetry.ENGINE_STALLED_BOUNDARIES, 1)
+        telemetry.counter_add(telemetry.HOST_GC_FULL_MS, 31.5)
+        live = telemetry.observe_snapshot()
+        buckets = live["hists"]["engine/boundary_ms"]["buckets"]
+        at = telemetry.HIST_BUCKET_BOUNDS.index
+        assert buckets[at(250.0)] == 1 and buckets[at(2500.0)] == 1 and sum(buckets) == 2
+        snap = telemetry.metrics_snapshot()
+        assert snap["engine/boundary_ms_count"] == 2.0
+        assert snap["engine/boundary_ms_max"] == 2354.9
+        assert snap["engine/boundary_median_ms"] == 212.0
+        assert snap["engine/stalled_boundaries"] == 1.0
+        assert snap["host/gc_full_ms"] == 31.5
+        telemetry.configure(enabled=True)
+        with telemetry.span(telemetry.ENGINE_SNAPSHOT_LAUNCH, fused=True):
+            pass
+        (ev,) = [e for e in events() if e["name"] == "engine/snapshot_launch"]
+        assert ev["ph"] == "X" and ev["args"] == {"fused": True}
+        assert telemetry.round_records() == []
+        assert telemetry.round_filed({"wall_s": 1.0}) == {"wall_s": 1.0, "round": 0}
+        assert telemetry.round_records() == [{"wall_s": 1.0, "round": 0}]
+
     def test_observe_snapshot_carries_hist_buckets(self):
         """Cumulative per-bucket counts ride observe_snapshot (the obs
         endpoint's and the worker blob's feed), aligned to

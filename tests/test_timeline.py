@@ -244,7 +244,8 @@ def inside(child, parent):
 GAUGES = (T.ENGINE_HOST_BUSY_SHARE, T.ENGINE_SLOWEST_BOUNDARY_MS,
           T.ENGINE_SLOWEST_BOUNDARY_HOST_MS)
 #: every span name that is NOT the launch's: at most once a host boundary
-PER_BOUNDARY = (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_GRANT, T.ENGINE_PREEMPT)
+PER_BOUNDARY = (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_SNAPSHOT_LAUNCH,
+                T.ENGINE_GRANT, T.ENGINE_PREEMPT)
 
 
 def by_names():
@@ -300,11 +301,17 @@ def test_paged_round_records_its_sub_spans_nested_and_per_boundary(tiny_params):
     boundaries = -(-steps // 4)
     assert steps > 2 * boundaries
     assert_launches(by_name[T.ENGINE_DISPATCH], round_span, steps)
-    for name in (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT):
+    for name in (T.ENGINE_ADMIT, T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_SNAPSHOT_LAUNCH):
         assert len(by_name[name]) <= boundaries + 1, (name, steps)
+    # the refill loop's snapshot launches: one a boundary inside the round's
+    # span, one more than its waits, and (no chunk program here) none fused
+    launched = by_name[T.ENGINE_SNAPSHOT_LAUNCH]
+    assert all(inside(e, round_span) for e in launched)
+    assert len(launched) == len(by_name[T.ENGINE_SNAPSHOT_WAIT]) + 1
+    assert all(e["args"] == {"fused": False} for e in launched)
     program = [e for e in spans() if not e["name"].startswith(T.COMPILE_PREFIX + "/")
                and e["name"] != T.ENGINE_DISPATCH]
-    assert len(program) <= 3 * boundaries + 8
+    assert len(program) <= 4 * boundaries + 8
 
 
 def test_budgeted_round_names_its_grant_passes_and_preemptions(tiny_params):
@@ -343,7 +350,13 @@ def test_dense_round_records_setup_snapshot_wait_and_readback():
     assert_launches(by_name[T.ENGINE_DISPATCH], decode, 12)
     assert {n for n in by_name if n.startswith("engine/")} == {
         T.ENGINE_SETUP, T.ENGINE_PREFILL, T.ENGINE_DECODE, T.ENGINE_READBACK,
-        T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_DISPATCH}
+        T.ENGINE_SNAPSHOT_WAIT, T.ENGINE_SNAPSHOT_LAUNCH, T.ENGINE_DISPATCH}
+    # the boundary's own launches (the flags' copy and its transfer): one span
+    # a boundary inside the loop's, one more than the waits (the first
+    # snapshot is launched before anything is waited for)
+    launched = by_name[T.ENGINE_SNAPSHOT_LAUNCH]
+    assert all(inside(e, decode) for e in launched)
+    assert len(launched) == -(-decode["args"]["steps"] // 4) == len(waits) + 1
 
 
 @pytest.mark.parametrize("engine_kind", ["dense", "paged_wave"])
@@ -383,6 +396,9 @@ def test_chunked_refill_round_records_a_launch_a_chunk(tiny_params):
                     sizes=[4] * (steps // 4))
     for name in PER_BOUNDARY:
         assert len(by_name.get(name, [])) <= steps // 4 + 1, name
+    # the snapshot rode inside the chunk's dispatch: the span holds the two
+    # transfers' launches alone, and says so
+    assert all(e["args"] == {"fused": True} for e in by_name[T.ENGINE_SNAPSHOT_LAUNCH])
 
 
 def test_tracing_off_records_nothing_and_spans_are_the_singleton(tiny_params):
@@ -432,16 +448,18 @@ def test_trace_report_prints_the_host_account_of_each_round_kind(tiny_params, tm
     lines = trace_report.build_report(events, metadata).splitlines()
     (at,) = [i for i, line in enumerate(lines) if line.split()[:1] == [T.ENGINE_REFILL_DECODE]]
     said = re.fullmatch(
-        r"    host s: launches ([\d.]+), waits ([\d.]+), admissions ([\d.]+), "
-        r"readback ([\d.]+), self ([\d.]+)", lines[at + 1])
+        r"    host s: launches ([\d.]+), waits ([\d.]+), snapshot_launch ([\d.]+), "
+        r"admissions ([\d.]+), readback ([\d.]+), self ([\d.]+)", lines[at + 1])
     assert said, lines[at + 1]
     by_name = by_names()
     (round_span,) = by_name[T.ENGINE_REFILL_DECODE]
-    launches, waits, admissions, readback, own = map(float, said.groups())
+    launches, waits, snapshots, admissions, readback, own = map(float, said.groups())
     assert launches == pytest.approx(sum(e["dur"] for e in by_name[T.ENGINE_DISPATCH]) / 1e6, abs=1e-3)
     assert waits == pytest.approx(sum(e["dur"] for e in by_name[T.ENGINE_SNAPSHOT_WAIT]) / 1e6, abs=1e-3)
     setup = by_name[T.ENGINE_SETUP][0]["dur"] / 1e6
-    assert launches + waits + admissions + readback + own + setup == pytest.approx(
+    assert snapshots == pytest.approx(
+        sum(e["dur"] for e in by_name[T.ENGINE_SNAPSHOT_LAUNCH]) / 1e6, abs=1e-3)
+    assert launches + waits + snapshots + admissions + readback + own + setup == pytest.approx(
         round_span["dur"] / 1e6, abs=5e-3)
     assert sum(line.startswith("    host s:") for line in lines) == 1  # one a round kind
 

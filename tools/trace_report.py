@@ -66,6 +66,7 @@ def _intersect_us(a: list[tuple[int, int]], b: list[tuple[int, int]]) -> int:
 #: (telemetry.py's vocabulary): what is left of the round's span is its self time
 _ROUND_KINDS = ("engine/decode", "engine/refill_decode")
 _HOST_PARTS = (("launches", "engine/dispatch"), ("waits", "engine/snapshot_wait"),
+               ("snapshot_launch", "engine/snapshot_launch"),
                ("admissions", "engine/admit"), ("readback", "engine/readback"))
 
 
@@ -110,6 +111,33 @@ def _host_account(rounds: list[dict], track: list[dict]) -> str:
     if visible:
         line += f", index {attended} of {visible} x 128 tokens"
     return line
+
+
+def stalled_rounds_section(metadata: dict) -> list[str]:
+    """One line a stalled round of the ledger the trace's metadata carries
+    (``rounds``: ``telemetry.round_records()`` as the trainer exported them),
+    with the fields of the engine's own warning: the longest stalled boundary's
+    interval for the round's median, its host part, the process's CPU in it,
+    involuntary switches, what the boundaries after it came back under the
+    median, and the round's collections, builds, faults and pressure. Empty
+    where no round stalled or the trace has no ledger."""
+    lines = []
+    for r in metadata.get("rounds") or ():
+        stalled, boundaries = r.get("stalled") or (), r.get("boundaries") or ()
+        if not stalled:
+            continue
+        worst = max(stalled, key=lambda i: boundaries[i][0])
+        interval_s, host_s, cpu_s, switches = boundaries[worst][:4]
+        lines.append(
+            f"  round {r.get('round')}: boundary {worst} of {len(boundaries)} stalled: "
+            f"{1e3 * interval_s:.1f} ms for a median of {1e3 * r['median_s']:.1f} "
+            f"(host part {1e3 * host_s:.1f} ms, process CPU {1e3 * cpu_s:.1f} ms, "
+            f"{switches} involuntary switches); {len(stalled)} stalled in the round; "
+            f"recovered {1e3 * r.get('recovered_s', 0.0):.1f} ms; full collections "
+            f"{1e3 * r.get('gc_full_s', 0.0):.1f} ms, programs built "
+            f"{r.get('programs_built')}, major faults {r.get('majflt')}, "
+            f"pressure gained (us) {r.get('pressure_us')}")
+    return ["stalled rounds:", *lines, ""] if lines else []
 
 
 def resilience_section(spans: dict[tuple[int, str], list[dict]]) -> list[str]:
@@ -648,6 +676,7 @@ def build_report(events: list[dict], metadata: dict,
             return toks * 1e6 / us
         return None
 
+    lines.extend(stalled_rounds_section(metadata))
     lines.extend(resilience_section(spans))
     lines.extend(weight_bus_section(spans))
     lines.extend(rollout_section(events, spans))
