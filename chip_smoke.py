@@ -352,12 +352,15 @@ def _power_step_case(key, *, rows, kv_heads, group, head_dim, steps=4):
     return {"impl": ran, "max_abs_err": float(f"{err:.3g}")}
 
 
-def _latent_fold_case(key, *, rows, heads, nope, rope, v_dim, rank, segment, blocks=3):
+def _latent_fold_case(key, *, rows, heads, nope, rope, v_dim, rank, segment, blocks=3,
+                      choose=None):
     """A latent-attention prefill segment as ``expanded_segment`` dispatches it
     (every fold of a block of keys the Mosaic kernel on a TPU at bf16 heads of
-    128) against the XLA form's folds, ``blocks`` blocks of ``segment`` keys
-    rebuilt from their latents, the queries on the last; what ran is read from
-    the dispatch record."""
+    whole or half tiles) against the XLA form's folds, ``blocks`` blocks of
+    ``segment`` keys rebuilt from their latents, the queries on the last; what
+    ran is read from the dispatch record. ``choose``: the share of the keys a
+    query sees that a drawn choice keeps (a learned index's mask, over a table
+    half a block wider than the blocks), handed to both forms."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -378,12 +381,18 @@ def _latent_fold_case(key, *, rows, heads, nope, rope, v_dim, rank, segment, blo
         return (kv.reshape(rows, segment, heads, nope + v_dim),
                 jax.lax.dynamic_index_in_dim(k_pe, j, 0, keepdims=False))
 
-    got = jax.jit(lambda: la.expanded_segment(q_nope, q_pe, block, start, v_dim, bf))()
+    chosen = None
+    if choose is not None:
+        width = blocks * segment + segment // 2
+        seen = jnp.arange(width)[None, :] <= (start + jnp.arange(segment))[:, None]
+        drawn = jax.random.uniform(jax.random.fold_in(key, 1), (rows, segment, width))
+        chosen = (seen[None] & (drawn < choose)).astype(la.FOLD_MASK_DTYPE)
+    got = jax.jit(lambda: la.expanded_segment(q_nope, q_pe, block, start, v_dim, bf, chosen))()
     ran = la.dispatch_choices[la.dispatch_key(heads, nope, rope, v_dim, segment, bf)]
     carry = None
     for j in range(blocks):
         carry = jax.jit(la.expanded_fold)(
-            q_nope, q_pe, *block(j), start, jnp.int32(j * segment), carry)
+            q_nope, q_pe, *block(j), start, jnp.int32(j * segment), carry, chosen)
     want = la.expanded_finish(carry, jnp.float32)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
     # outputs of unit size rounded to bf16: half a unit in the last place of 2
@@ -444,6 +453,12 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     out["latent_fold"] = _latent_fold_case(
         key, rows=4, heads=16, nope=128, rope=64, v_dim=128, rank=512, segment=1024)
     assert out["latent_fold"]["impl"] == "kernel", out["latent_fold"]
+    # the eighth configuration's (GLM-5): 64 heads, K 192 + 64 wide beside V of
+    # 256, each query attending a fifth of what it sees (a learned index's mask)
+    out["latent_fold_chosen"] = _latent_fold_case(
+        key, rows=2, heads=64, nope=192, rope=64, v_dim=256, rank=512, segment=1024,
+        choose=0.2)
+    assert out["latent_fold_chosen"]["impl"] == "kernel", out["latent_fold_chosen"]
     for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
         out[name] = _expert_layer_case(
             key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)

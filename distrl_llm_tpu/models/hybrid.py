@@ -189,8 +189,8 @@ from distrl_llm_tpu.ops.delta_attention import (
     delta_chunked, delta_step, l2norm, short_conv,
 )
 from distrl_llm_tpu.ops.latent_attention import (
-    absorbed_attention, absorbed_output, absorbed_paged_attention, absorbed_query,
-    expanded_attention, expanded_finish, expanded_segment,
+    FOLD_MASK_DTYPE, absorbed_attention, absorbed_output, absorbed_paged_attention,
+    absorbed_query, expanded_attention, expanded_finish, expanded_segment,
     rope_interleaved, shared_page_walk, shared_pages_per_block, split_kvb,
 )
 from distrl_llm_tpu.ops.linear import linear
@@ -979,10 +979,12 @@ def _index_inputs(h, c_q, p, *, cfg, env):
 
 
 def _segment_choice(q_i, w_i, key_pages, *, cfg, env):
-    """A prefill segment's choice, ``[B, S, W * page_size]`` bool over the
-    positions of the row's page table: each query's index scores over the
-    blocks of keys up to and including its own (from the pages, the segment's
-    own just written), then ``chosen_mask``. While the segment ends within
+    """A prefill segment's choice, ``[B, S, W * page_size]`` over the positions
+    of the row's page table, non-zero where the query attends, in the type the
+    folds read it in (``FOLD_MASK_DTYPE``, made by the operation that makes the
+    mask, not by a pass of its own): each query's index scores over the blocks
+    of keys up to and including its own (from the pages, the segment's own just
+    written), then ``chosen_mask``. While the segment ends within
     ``index_topk`` tokens every query attends all it sees, and nothing is
     scored."""
     idx, ps, start = env["page_indices"], env["page_size"], env["segment_start"]
@@ -1001,9 +1003,10 @@ def _segment_choice(q_i, w_i, key_pages, *, cfg, env):
             scores = jax.lax.fori_loop(
                 0, start // s + 1, block, jnp.zeros((b, s, width), jnp.float32))
         with jax.named_scope(telemetry.MODEL_INDEX_SELECT):
-            return chosen_mask(scores, visible, cfg.index_topk)
+            return chosen_mask(scores, visible, cfg.index_topk).astype(FOLD_MASK_DTYPE)
 
-    return jax.lax.cond(start + s <= cfg.index_topk, lambda: visible, choose)
+    return jax.lax.cond(
+        start + s <= cfg.index_topk, lambda: visible.astype(FOLD_MASK_DTYPE), choose)
 
 
 def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,

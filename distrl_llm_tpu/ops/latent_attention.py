@@ -13,7 +13,12 @@ has two forms, and which is cheaper depends on queries a cached token:
   ``expanded_segment``, which folds each block of earlier keys into the
   segment's running softmax with one Mosaic kernel on a TPU
   (``expanded_fold_kernel``: the block's scores never leave VMEM) and with
-  ``expanded_attention`` elsewhere;
+  ``expanded_attention`` elsewhere. The fold is ONE algorithm whose
+  parameters are read off what it is handed: the head's layout off the
+  shapes (K 128 + 64 wide beside V of 128 for Kimi-VL, 192 + 64 beside 256 for
+  GLM-5), and which keys a query attends off whether a choice arrived (a
+  learned index's mask, a tile of it read beside the tile of keys) or not
+  (the two positions' order);
 * **absorbed** (one query a row: decode): fold ``W_k`` into the query and
   ``W_v`` into the output, so every head attends over the latent row itself:
   ``scores = (q_nope W_k[h]^T) . c + q_pe . k_pe``, ``o = (sum p c) W_v[h]``.
@@ -122,12 +127,15 @@ def dispatch_key(heads: int, nope: int, rope: int, v_dim: int, segment: int,
 
 def expanded_segment_impl(q_nope: jax.Array, v_dim: int) -> str:
     """The form the folds of a prefill segment of ``q_nope [B, S, H, nope]``
-    take: "kernel" on a TPU backend for bf16 operands whose head sizes (K's and
-    V's, one width) and segment are whole 128-lane tiles, "xla" otherwise (the
-    CPU, the tests' tiny heads, float32). On the TPU nothing falls back: a
-    kernel that fails to lower fails the segment that called it."""
+    take: "kernel" on a TPU backend for bf16 operands whose segment and values
+    are whole 128-lane tiles and whose keys' width is whole or half tiles
+    (GLM-5's 192: the kernel reads a head's ``[K | V]`` as one block as wide as
+    the array, and cuts it in VMEM), "xla" otherwise (the CPU, the tests' tiny
+    heads, float32). K and V need not be one width, and a learned index's
+    choice changes nothing here. On the TPU nothing falls back: a kernel that
+    fails to lower fails the segment that called it."""
     s, nope = q_nope.shape[1], q_nope.shape[-1]
-    whole = nope == v_dim and nope % _LANES == 0 and s % _LANES == 0
+    whole = nope % (_LANES // 2) == 0 and v_dim % _LANES == 0 and s % _LANES == 0
     if jax.default_backend() == "tpu" and q_nope.dtype == jnp.bfloat16 and whole:
         return "kernel"
     return "xla"
@@ -145,12 +153,12 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
     ``expanded_fold_kernel`` launch, whose scores never leave VMEM and whose
     carry keeps the kernel's layout from the first fold to the last, or
     ``expanded_fold``, which is ``expanded_attention``. ``chosen [B, S, keys]``
-    bool, if given, is the whole mask over the row's key positions (a learned
-    index's choice, causality in it): the folds are then ``expanded_attention``
-    under its slices, whatever the backend (the kernel's mask is two
-    positions)."""
+    of ``FOLD_MASK_DTYPE``, if given, is what each query attends (non-zero) over
+    the row's key positions ``0 .. keys`` (a learned index's choice, causality
+    and the row's length in it): either form then reads its block's columns of
+    it in place of the two positions' order."""
     b, s, h, nope = q_nope.shape
-    impl = "xla" if chosen is not None else expanded_segment_impl(q_nope, v_dim)
+    impl = expanded_segment_impl(q_nope, v_dim)
     dispatch_choices[dispatch_key(h, nope, q_pe.shape[-1], v_dim, s, q_nope.dtype)] = impl
     if impl == "kernel":
         queries, first, finish = fold_queries(q_nope, q_pe), fold_start, fold_finish
@@ -158,27 +166,34 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
     else:
         queries, first, finish = (q_nope, q_pe), expanded_start, expanded_finish
         one = expanded_fold
-        if chosen is not None:
-            one = lambda qn, qp, kv, k_pe, _, k_start, carry: expanded_attention(
-                qn, qp, kv, k_pe,
-                jax.lax.dynamic_slice_in_dim(chosen, k_start, s, axis=2), carry)
     carry = jax.lax.fori_loop(
         0, start // s + 1,
-        lambda j, carry: one(*queries, *block(j), start, j * s, carry),
+        lambda j, carry: one(*queries, *block(j), start, j * s, carry, chosen),
         first(b, s, h, v_dim))
     return finish(carry, dtype)
 
 
-def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None):
+#: the type a choice is handed to the folds in, one byte a (query, key) pair:
+#: the narrowest Mosaic loads on a v5e (a ``pred`` operand of a kernel is
+#: widened to int32 at its boundary, four times the bytes)
+FOLD_MASK_DTYPE = jnp.int8
+
+
+def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=None):
     """``expanded_attention`` with the mask given as two positions: the queries
     stand at ``q_start ..``, the keys at ``k_start ..``, and a query sees the
-    keys at or before it (every row alike). ``expanded_fold_kernel``'s
-    reference, argument for argument."""
+    keys at or before it (every row alike); or, where ``chosen [B, S, keys]`` is
+    given, as its columns ``k_start ..`` (non-zero = attend). ``expanded_fold_
+    kernel``'s reference, argument for argument after the queries (the kernel
+    takes ``fold_queries``' one array for these two)."""
     b, sq = q_nope.shape[:2]
     sk = kv.shape[1]
-    mask = (k_start + jnp.arange(sk))[None, :] <= (q_start + jnp.arange(sq))[:, None]
-    return expanded_attention(
-        q_nope, q_pe, kv, k_pe, jnp.broadcast_to(mask, (b, sq, sk)), carry)
+    if chosen is None:
+        mask = (k_start + jnp.arange(sk))[None, :] <= (q_start + jnp.arange(sq))[:, None]
+        mask = jnp.broadcast_to(mask, (b, sq, sk))
+    else:
+        mask = jax.lax.dynamic_slice_in_dim(chosen, k_start, sk, axis=2) != 0
+    return expanded_attention(q_nope, q_pe, kv, k_pe, mask, carry)
 
 
 #: queries and keys of one tile of ``expanded_fold_kernel``: the widest
@@ -186,9 +201,19 @@ def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None):
 #: folds at [4, 16, 1024] (PERF.md §6, PR 50), a fold with its block's product
 #: through W_kvb: 0.543 ms at 1,024 x 1,024 (a head's whole block in one step:
 #: one maximum, as ``expanded_attention`` takes it), 0.564 at 512 x 1,024,
-#: 0.768 at 512 x 512 and 1.18-1.43 at 256 keys; the XLA form takes 2.108
+#: 0.768 at 512 x 512 and 1.18-1.43 at 256 keys; the XLA form takes 2.108.
+#: At [4, 64, 1024] with K 192 + 64 wide, V 256 and a choice of one byte a
+#: pair (PERF.md §6, PR 57): 3.153 ms at 1,024 x 1,024 and 3.293 at 512 x 1,024;
+#: with ``k_pe`` contracted in a tile of its own 3.499, 3.503 at 1,024 x 512,
+#: 3.589 at 512 x 1,024, 3.763 at 512 x 512, the choice as bf16 3.488, K and V
+#: sliced apart by XLA before the launch 4.427; the XLA form 10.05
 FOLD_TILE_Q = 1024
 FOLD_TILE_K = 1024
+
+
+def _whole_lanes(width: int) -> int:
+    """``width`` rounded up to whole 128-lane tiles."""
+    return -(-width // _LANES) * _LANES
 
 
 def _tile(n: int, most: int) -> int:
@@ -200,11 +225,14 @@ def _tile(n: int, most: int) -> int:
 
 def fold_queries(q_nope: jax.Array, q_pe: jax.Array):
     """A segment's queries as ``expanded_fold_kernel`` reads them, made once a
-    segment: a head's queries together, ``[B, H, S, .]``, the rope part padded
-    with zeros to whole lanes (exact: a product with zero adds nothing)."""
-    pad = -q_pe.shape[-1] % _LANES
-    q_pe = jnp.pad(q_pe, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    return q_nope.transpose(0, 2, 1, 3), q_pe.transpose(0, 2, 1, 3)
+    segment: a head's queries together, ``[B, H, S, .]``, ``[q_nope | q_pe]``
+    padded with zeros to whole lanes (exact: a product with zero adds
+    nothing), so that the rope part lies in the tile that ``q_nope``'s last
+    values leave unfilled (GLM-5's 192 + 64: two whole tiles) or in one of its
+    own (Kimi-VL's 128 + 64). A 1-tuple: the kernel's leading argument."""
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    pad = _whole_lanes(q.shape[-1]) - q.shape[-1]
+    return (jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad))).transpose(0, 2, 1, 3),)
 
 
 def fold_start(b: int, sq: int, heads: int, v_dim: int):
@@ -237,11 +265,15 @@ def _row(col: jax.Array) -> jax.Array:
     return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
 
 
-def _fold_body(pos_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, m_in, l_in, acc_in,
-               m_out, l_out, acc_out, m_s, l_s, acc_s, *, scale: float):
+def _fold_body(pos_ref, q_ref, kv_ref, kp_ref, m_in, l_in, acc_in, *rest,
+               scale: float, nope: int):
     """One (row, head, tile of queries) against one tile of keys: the tile's
-    scores, their maximum and their sum live and die in VMEM."""
-    tq, tk = qn_ref.shape[0], kn_ref.shape[0]
+    scores, their maximum and their sum live and die in VMEM. Which keys a
+    query attends is the tile of the choice where the launch was handed one
+    (``chosen_ref``, causality in it), the two positions' order otherwise."""
+    *chosen_ref, m_out, l_out, acc_out, m_s, l_s, acc_s = rest
+    tq, tk, v_dim = q_ref.shape[0], kv_ref.shape[0], acc_s.shape[1]
+    whole = nope // _LANES * _LANES  # K's whole tiles; the rest shares ``k_pe``'s
     ki = pl.program_id(3)
     q0 = pos_ref[0] + pl.program_id(2) * tq  # the tile's first query, its first key
     k0 = pos_ref[1] + ki * tk
@@ -254,14 +286,25 @@ def _fold_body(pos_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, m_in, l_in, acc_i
 
     def fold(masked: bool):
         nt = (((1,), (1,)), ((), ()))  # q . k^T
+        # the keys' last tile: K's values past its whole tiles, then ``k_pe``
+        # (which arrives at those lanes, zeros before it)
+        last = kp_ref[...]
+        if nope > whole:
+            lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
+            last = jnp.where(lane < nope - whole, kv_ref[:, whole: whole + last.shape[1]], last)
         s = jax.lax.dot_general(
-            qn_ref[...], kn_ref[...], nt, preferred_element_type=jnp.float32,
-        ) + jax.lax.dot_general(
-            qp_ref[...], kp_ref[...], nt, preferred_element_type=jnp.float32)
+            q_ref[:, whole:], last, nt, preferred_element_type=jnp.float32)
+        if whole:
+            s = jax.lax.dot_general(
+                q_ref[:, :whole], kv_ref[:, :whole], nt,
+                preferred_element_type=jnp.float32) + s
         s = s * scale
         if masked:
-            seen = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-                    <= q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0))
+            if chosen_ref:
+                seen = chosen_ref[0][...].astype(jnp.int32) != 0
+            else:
+                seen = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+                        <= q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0))
             s = jnp.where(seen, s, NEG_INF)
         m = m_s[...]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
@@ -272,12 +315,17 @@ def _fold_body(pos_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, m_in, l_in, acc_i
         m_s[...] = m_new
         l_s[...] = l_s[...] * fix + p.sum(axis=1, keepdims=True)
         acc_s[...] = acc_s[...] * fix + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
+            p.astype(kv_ref.dtype), kv_ref[:, nope: nope + v_dim],
+            preferred_element_type=jnp.float32)
 
     # a tile of keys that no query of the tile sees is skipped (it changes
-    # nothing); one that every query sees whole needs no mask
-    pl.when(k0 + tk - 1 <= q0)(functools.partial(fold, False))
-    pl.when((k0 + tk - 1 > q0) & (k0 <= q0 + tq - 1))(functools.partial(fold, True))
+    # nothing); a choice is read wherever a query sees a key, and of the
+    # positions' tiles the one that every query sees whole needs no mask
+    if chosen_ref:
+        pl.when(k0 <= q0 + tq - 1)(functools.partial(fold, True))
+    else:
+        pl.when(k0 + tk - 1 <= q0)(functools.partial(fold, False))
+        pl.when((k0 + tk - 1 > q0) & (k0 <= q0 + tq - 1))(functools.partial(fold, True))
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -286,36 +334,72 @@ def _fold_body(pos_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, m_in, l_in, acc_i
         acc_out[...] = acc_s[...]
 
 
+#: what Mosaic gives a kernel's buffers unasked on a v5e; a launch whose tiles
+#: need more says so (``_fold_vmem``)
+_VMEM_DEFAULT = 16 << 20
+
+
+def _fold_vmem(tq: int, tk: int, q_width: int, kv_width: int, last: int, v_dim: int,
+               chosen: bool):
+    """``vmem_limit_bytes`` of a fold's launch, from its tiles: ``None`` where
+    the default holds them (16 heads of 128 at 1,024 x 1,024: 15 MB by this
+    count), twice the count otherwise (64 heads of 192 + 256 under a choice:
+    25 MB by this count, 21.5 MB by Mosaic's, which refuses the launch under
+    the default). Counted: the operands' blocks twice (the pipeline's two
+    buffers), the accumulator's scratch, and a tile's float32 scores, their
+    weights, the weights' bf16 cast and the choice widened to the scores'
+    layout."""
+    blocks = 2 * (tq * q_width + tk * (_whole_lanes(kv_width) + last))
+    blocks += 2 * tq * v_dim * 4 + chosen * tq * tk  # the carry in and out, the choice
+    need = 2 * blocks + tq * v_dim * 4 + tq * tk * (4 + 4 + 2 + 4 * chosen)
+    return None if need <= _VMEM_DEFAULT else 2 * need
+
+
 @functools.partial(jax.jit, static_argnames=("tile_q", "tile_k", "interpret"))
-def expanded_fold_kernel(q_nope, q_pe, kv, k_pe, q_start, k_start, carry, *,
-                         tile_q: int = FOLD_TILE_Q, tile_k: int = FOLD_TILE_K,
+def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
+                         *, tile_q: int = FOLD_TILE_Q, tile_k: int = FOLD_TILE_K,
                          interpret: bool = False):
     """``expanded_attention``'s fold of one block of keys as one Mosaic kernel
-    (a TPU; ``interpret`` for the CPU's tests). The mask is two positions: the
-    queries stand at ``q_start ..``, the keys at ``k_start ..``, and a query
-    sees the keys at or before it. ``q_nope [B, H, S, nope]`` and ``q_pe [B, H,
-    S, lanes]`` from ``fold_queries``, ``kv [B, Sk, H, nope + v]`` and ``k_pe
-    [B, Sk, rope]`` as ``expanded_attention`` takes them, ``carry`` from
+    (a TPU; ``interpret`` for the CPU's tests). Without ``chosen`` the mask is
+    two positions: the queries stand at ``q_start ..``, the keys at ``k_start
+    ..``, and a query sees the keys at or before it. With ``chosen [B, S,
+    keys]`` (``FOLD_MASK_DTYPE``; non-zero = attend, causality in it) a tile of
+    it is read beside the tile of keys, the key axis at ``k_start ..``, and the
+    positions only say which tiles lie wholly above the diagonal. ``q [B, H, S,
+    lanes]`` from ``fold_queries``, ``kv [B, Sk, H, nope + v]`` and ``k_pe [B,
+    Sk, rope]`` as ``expanded_attention`` takes them, ``carry`` from
     ``fold_start`` or an earlier fold, updated in place.
+
+    The head's layout is read off the shapes: ``v`` off the carry, ``nope`` off
+    ``kv`` less ``v``. K and V are the two parts of a head's block of ``kv``,
+    ONE operand cut apart in VMEM (the product through W_kvb is written once, a
+    head's keys together; sliced apart by XLA before the launch each part is a
+    pass over it). ``k_pe`` stays one vector a key, never copied a head in
+    HBM: it arrives in the lanes that K's last tile leaves unfilled and joins
+    that tile in VMEM, so that 192 + 64 is two whole tiles of one contraction
+    (three matrix passes where K and ``k_pe`` are contracted apart: 3.50 ->
+    3.15 ms a fold), and 128 + 64 a tile of K and a tile of ``k_pe``.
 
     Grid (B, H, tiles of queries, tiles of keys), the keys innermost: a tile of
     queries keeps its running softmax in VMEM from its first tile of keys to
     its last, and a tile's scores, their maximum and their sum exist only
     there. bf16 operands into float32 products, float32 ``m`` and ``l``, the
     weights cast to the values' type before their product: ``expanded_
-    attention``'s roundings. ``k_pe`` stays one vector a key, contracted on its
-    own. Tiles of keys past the last one a tile of queries can see are neither
-    copied nor computed."""
-    b, h, s, nope = q_nope.shape
-    sk, v_dim, rope = kv.shape[1], kv.shape[-1] - nope, q_pe.shape[-1]
-    if nope != v_dim:
-        raise ValueError(
-            f"the kernel reads K and V as blocks of one width, got {nope} and {v_dim}")
+    attention``'s roundings. Tiles of keys past the last one a tile of queries
+    can see are neither copied nor computed; a query whose tile holds none of
+    its choices keeps its ``(m, l, acc)``."""
+    b, h, s, q_width = q.shape
+    sk, v_dim, rope = kv.shape[1], carry[2].shape[-1], k_pe.shape[-1]
+    nope = kv.shape[-1] - v_dim
+    whole = nope // _LANES * _LANES
+    last = q_width - whole  # the keys' last tile: K past its whole tiles, then k_pe
     tq, tk = _tile(s, tile_q), _tile(sk, tile_k)
     pos = jnp.stack([q_start, k_start]).astype(jnp.int32)
-    scale = (nope + k_pe.shape[-1]) ** -0.5
-    k_pe = jnp.pad(k_pe, ((0, 0), (0, 0), (0, rope - k_pe.shape[-1])))
+    scale = (nope + rope) ** -0.5
+    k_pe = jnp.pad(k_pe, ((0, 0), (0, 0), (nope - whole, last - (nope - whole) - rope)))
     kv = kv.transpose(0, 2, 1, 3)  # [B, H, Sk, nope + v]: a head's keys together
+    # the tests' tiny heads: the last tile is read whole out of kv's row
+    kv = jnp.pad(kv, ((0, 0), (0, 0), (0, 0), (0, max(0, q_width - kv.shape[-1]))))
 
     def keys(i, j, pos):
         """The tile of keys that step ``j`` of queries' tile ``i`` reads: past
@@ -324,19 +408,27 @@ def expanded_fold_kernel(q_nope, q_pe, kv, k_pe, q_start, k_start, carry, *,
         return jnp.minimum(j, last)
 
     of_q = lambda w: pl.BlockSpec((None, None, tq, w), lambda b, h, i, j, pos: (b, h, i, 0))
-    of_kv = lambda part: pl.BlockSpec(
-        (None, None, tk, nope), lambda b, h, i, j, pos: (b, h, keys(i, j, pos), part))
+    of_kv = pl.BlockSpec(
+        (None, None, tk, kv.shape[-1]), lambda b, h, i, j, pos: (b, h, keys(i, j, pos), 0))
     stat = pl.BlockSpec((None, None, 1, tq), lambda b, h, i, j, pos: (b, h, 0, i))
+    # the choice's tile beside the tile of keys (``k_start`` is a multiple of
+    # the block, the block of ``tk``). Its key axis is the page table's width
+    # (20,992 positions in GLM-5's cell: no multiple of the tile), but a fold's
+    # keys end at or before the segment's own, which whole blocks hold: the
+    # ragged last tile is never named
+    choice = () if chosen is None else (chosen,)
+    of_choice = pl.BlockSpec(
+        (None, tq, tk), lambda b, h, i, j, pos: (b, i, pos[1] // tk + keys(i, j, pos)))
     return tuple(pl.pallas_call(
-        functools.partial(_fold_body, scale=scale),
+        functools.partial(_fold_body, scale=scale, nope=nope),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h, s // tq, sk // tk),
             in_specs=[
-                of_q(nope), of_q(rope), of_kv(0), of_kv(1),
-                pl.BlockSpec((None, tk, rope),
+                of_q(q_width), of_kv,
+                pl.BlockSpec((None, tk, last),
                              lambda b, h, i, j, pos: (b, keys(i, j, pos), 0)),
-                stat, stat, of_q(v_dim),
+                stat, stat, of_q(v_dim), *[of_choice] * len(choice),
             ],
             out_specs=[stat, stat, of_q(v_dim)],
             scratch_shapes=[pltpu.VMEM((tq, 1), jnp.float32),
@@ -344,11 +436,13 @@ def expanded_fold_kernel(q_nope, q_pe, kv, k_pe, q_start, k_start, carry, *,
                             pltpu.VMEM((tq, v_dim), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32) for x in carry],
-        input_output_aliases={6: 0, 7: 1, 8: 2},  # the carry, in place
+        input_output_aliases={4: 0, 5: 1, 6: 2},  # the carry, in place
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_fold_vmem(
+                tq, tk, q_width, kv.shape[-1], last, v_dim, chosen is not None)),
         interpret=interpret,
-    )(pos, q_nope, q_pe, kv, kv, k_pe, *carry))
+    )(pos, q, kv, k_pe, *carry, *choice))
 
 
 def expanded_start(b: int, sq: int, heads: int, v_dim: int):

@@ -238,9 +238,10 @@ OPS_POWER_KERNEL_STEPS = "ops/power_kernel_steps"
 # counter: a round's folds of a block of keys into a latent-attention prefill
 # segment's running softmax that ran as the Mosaic kernel
 # (ops/latent_attention.py::expanded_fold_kernel): latent layers x the
-# prefill's folds (segment j makes j + 1) where ``expanded_segment`` chose it,
-# 0 where it took the XLA form (a CPU, small heads, float32). Filed beside the
-# three counters above; no metric reads it
+# prefill's folds (segment j makes j + 1) where ``expanded_segment`` chose it
+# (under a learned index's choice too), 0 where it took the XLA form (a CPU,
+# small heads, float32). Filed beside the three counters above; no metric
+# reads it
 OPS_LATENT_KERNEL_FOLDS = "ops/latent_kernel_folds"
 # counter: a round's choices of a learned index that ran by counting
 # (ops/token_index.py::kth_largest, no sort): layers x the decode steps whose

@@ -63,15 +63,18 @@ def test_the_latent_and_expert_checks_hold_at_tiny_size(monkeypatch):
         assert layer["fullest_expert"] >= tokens // 4
 
 
-def test_the_latent_fold_check_holds_at_tiny_size():
+@pytest.mark.parametrize("heads", [
+    dict(nope=16, v_dim=16),  # K and V of one width, the positions' mask
+    dict(nope=24, v_dim=32, choose=0.3),  # two widths under a drawn choice
+], ids=["positions", "chosen"])
+def test_the_latent_fold_check_holds_at_tiny_size(heads):
     """The kernels phase's check of a latent prefill segment's folds, here on
     the form a CPU takes: the dispatch record says "xla", which the chip's
     phase refuses (it asserts "kernel")."""
     import jax
 
     case = chip_smoke._latent_fold_case(
-        jax.random.PRNGKey(0), rows=2, heads=2, nope=16, rope=8, v_dim=16, rank=32,
-        segment=16)
+        jax.random.PRNGKey(0), rows=2, heads=2, rope=8, rank=32, segment=16, **heads)
     assert case["impl"] == "xla" and case["folds"] == 3 and case["max_abs_err"] < 2e-2
 
 

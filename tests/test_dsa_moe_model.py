@@ -577,6 +577,35 @@ def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
     assert engine.last_round_stats["slot_state_bytes"] == 0  # all of a slot is in pages
 
 
+def test_a_prefill_through_the_fold_kernel_is_the_xla_forms(weights, small_pieces, monkeypatch):
+    """The engine's prefill with every fold run by ``expanded_fold_kernel``
+    under the index's choice (interpreted; the dispatch answered for it; the
+    preset's K is 16 + 8 wide beside a V of 20): prompts of 40 and 57 tokens in
+    segments of 16, so that every segment after the first chooses 8 of what it
+    sees and the last ones are part padding. The captured
+    log-probabilities are the XLA form's to the kernel's own rounding and the
+    reference's; the sampled tokens are the same; ``ops/latent_kernel_folds``
+    files 3 layers x (1 + 2 + 3 + 4) folds, as the stages run them."""
+    import functools
+
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    folds = lambda: telemetry.observe_snapshot()["counters"].get(
+        telemetry.OPS_LATENT_KERNEL_FOLDS, 0)
+    before = folds()
+    _, _, plain = generate(make_engine("waves", 0), params, lora)
+    assert folds() == before  # the CPU's own form: none
+    monkeypatch.setattr(latent_attention, "expanded_segment_impl", lambda q_nope, v_dim: "kernel")
+    monkeypatch.setattr(latent_attention, "expanded_fold_kernel", functools.partial(
+        latent_attention.expanded_fold_kernel, interpret=True))
+    ids, mask, result = generate(make_engine("waves", 0), params, lora)
+    assert folds() - before == 3 * 10
+    np.testing.assert_array_equal(result.tokens, plain.tokens)
+    np.testing.assert_allclose(result.logprobs, plain.logprobs, atol=2e-5)
+    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+
+
 def test_past_128_tokens_the_counters_say_what_was_spared(monkeypatch):
     """An index of 128 tokens, prompts of 150 and 260 in segments of 64: a step
     attends one unit of 128 tokens where latent attention without the index

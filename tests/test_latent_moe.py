@@ -8,6 +8,7 @@ The learner's update through ``trainer.train_step`` and the rollout through
 ``perfbench/run.py`` are held by ``tests/perfbench/test_perfbench_rehearsal_latent_moe.py``.
 """
 
+import functools
 import os
 import sys
 from types import SimpleNamespace
@@ -256,22 +257,144 @@ def test_the_fold_kernel_is_expanded_attention(case, q_start, real):
         assert (np.asarray(got[1])[..., :-q_start] == 0).all()
 
 
-@pytest.mark.parametrize("backend,dtype,nope,v_dim,segment,want", [
-    ("tpu", jnp.bfloat16, 128, 128, 1024, "kernel"),  # the Kimi cell's segment
-    ("tpu", jnp.bfloat16, 128, 128, 384, "kernel"),
-    ("cpu", jnp.bfloat16, 128, 128, 1024, "xla"),
-    ("tpu", jnp.bfloat16, 16, 16, 1024, "xla"),  # the tests' tiny heads
-    ("tpu", jnp.float32, 128, 128, 1024, "xla"),
-    ("tpu", jnp.bfloat16, 128, 128, 1000, "xla"),  # no whole tiles of queries
-    ("tpu", jnp.bfloat16, 128, 256, 1024, "xla"),  # K and V of two widths
+FOLD_WIDE_V = dict(b=2, s=256, h=2, nope=24, rope=8, v_dim=32)
+#: head layouts the kernel reads off its shapes: (nope, rope, v)
+LAYOUTS = {
+    "k24+8_v32": (24, 8, 32),  # the rope part in K's only, unfilled tile
+    "k192+64_v128": (192, 64, 128),  # GLM-5's keys: a whole tile, then K's rest and k_pe in one
+    "k128+64_v128": (128, 64, 128),  # Kimi-VL's: a tile of K, a tile of k_pe
+}
+KEYS = 3 * 256 + 128  # a page table's width: the blocks the folds reach and a ragged rest
+
+
+def causal(q_start, b=2, s=256):
+    """What two positions say, as a choice: ``[B, S, KEYS]`` bool."""
+    seen = jnp.arange(KEYS)[None, :] <= (q_start + jnp.arange(s))[:, None]
+    return jnp.broadcast_to(seen, (b, s, KEYS))
+
+
+def choice_case(case):
+    """(q_start, the choice ``[B, S, KEYS]`` bool) of a case below."""
+    from distrl_llm_tpu.ops import token_index
+
+    if case == "the_positions_themselves":
+        return 448, causal(448)
+    if case == "a_query_that_has_seen_no_key":
+        return -32, causal(-32)
+    visible = causal(512)
+    if case == "eight_of_what_a_query_sees_with_ties_at_zero":
+        # an index's scores: a relu's zeros on half the keys, so that most
+        # queries' eighth score is one of many equals (the first few are kept)
+        scores = jax.nn.relu(jax.random.normal(jax.random.PRNGKey(9), visible.shape))
+        scores = jnp.round(scores * 4) / 4
+        return 512, token_index.chosen_mask(scores, visible, 8)
+    assert case == "a_tile_that_holds_none_of_a_querys_choices"
+    # queries 0-127 choose nothing of keys 128-383 (a whole tile of block 0 and
+    # the first of block 1), queries 128-199 nothing of the segment's own block
+    drawn = jax.random.uniform(jax.random.PRNGKey(9), visible.shape) < 0.1
+    keys, queries = jnp.arange(KEYS)[None, None, :], jnp.arange(256)[None, :, None]
+    drawn &= ~((queries < 128) & (keys >= 128) & (keys < 384))
+    drawn &= ~((queries >= 128) & (queries < 200) & (keys >= 512))
+    return 512, drawn & visible
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("case", [
+    "eight_of_what_a_query_sees_with_ties_at_zero",
+    "a_tile_that_holds_none_of_a_querys_choices",
+    "a_query_that_has_seen_no_key",
+    "the_positions_themselves",
+])
+def test_the_fold_kernel_under_a_choice_is_expanded_attention(case, layout):
+    """``expanded_fold_kernel`` handed a choice (interpreted, tiles of 128, K
+    and V of two widths in each of ``LAYOUTS``) against ``expanded_attention``
+    under the same mask, three blocks of keys chained: the carry after every
+    fold and the finished output. A query whose tile holds none of its choices keeps its
+    ``(m, l, acc)``, one that has seen no key at all finishes as zeros, and a
+    choice that says what the two positions say gives what the kernel gives
+    without one: bit for bit where both mask (the block that crosses the
+    diagonal, alone), and to an ulp of the CPU's exponential over the chain
+    (of a tile seen whole the kernel without a choice masks nothing, and the
+    CPU's compiler rounds the two programs apart)."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    nope, rope, v_dim = LAYOUTS[layout]
+    dims = dict(FOLD_WIDE_V, nope=nope, rope=rope, v_dim=v_dim)
+    b, s, h = (dims[k] for k in ("b", "s", "h"))
+    q_start, mask = choice_case(case)
+    chosen = mask.astype(la.FOLD_MASK_DTYPE)
+    q_nope, q_pe, _, _ = fold_case(0, **dims)
+    want, got = la.expanded_start(b, s, h, v_dim), la.fold_start(b, s, h, v_dim)
+    by_positions, by_choice = got, None
+    heads = la.fold_queries(q_nope, q_pe)
+    kernel = functools.partial(la.expanded_fold_kernel, tile_q=128, tile_k=128, interpret=True)
+    for j in range(3):
+        _, _, kv, k_pe = fold_case(j + 1, **dims)
+        at = jnp.int32(q_start), jnp.int32(j * s)
+        before = got
+        want = la.expanded_attention(
+            q_nope, q_pe, kv, k_pe, mask[:, :, j * s: (j + 1) * s], want)
+        by_choice = la.expanded_fold(q_nope, q_pe, kv, k_pe, *at, by_choice, chosen)
+        got = kernel(*heads, kv, k_pe, *at, got, chosen)
+        by_positions = kernel(*heads, kv, k_pe, *at, by_positions)
+        for name, x, y in zip("mla", la.fold_carry(got), want):
+            np.testing.assert_allclose(
+                x, y, rtol=2e-5, atol=2e-5, err_msg=f"{name} after fold {j}")
+        if case == "a_tile_that_holds_none_of_a_querys_choices" and j == 2:
+            for x, y in zip(before, got):  # m and l [B, H, 1, S], acc [B, H, S, v]
+                x, y = np.asarray(x).reshape(b, h, s, -1), np.asarray(y).reshape(b, h, s, -1)
+                np.testing.assert_array_equal(x[:, :, 128:200], y[:, :, 128:200])
+                assert (x[:, :, 200:] != y[:, :, 200:]).any()
+    for x, y in zip(by_choice, want):  # the XLA form under a choice
+        np.testing.assert_array_equal(x, y)
+    out = la.fold_finish(got, jnp.float32)
+    np.testing.assert_allclose(out, la.expanded_finish(want, jnp.float32), atol=2e-5)
+    if case in ("the_positions_themselves", "a_query_that_has_seen_no_key"):
+        np.testing.assert_array_equal(got[0], by_positions[0])
+        for x, y in zip(got[1:], by_positions[1:]):
+            np.testing.assert_allclose(x, y, rtol=2e-6, atol=4e-6)
+        fresh = la.fold_start(b, s, h, v_dim)
+        for x, y in zip(kernel(*heads, kv, k_pe, *at, fresh, chosen),
+                        kernel(*heads, kv, k_pe, *at, fresh)):
+            np.testing.assert_array_equal(x, y)
+    if q_start < 0:
+        assert (np.asarray(out)[:, :-q_start] == 0).all()
+        assert (np.asarray(got[1])[..., :-q_start] == 0).all()
+
+
+@pytest.mark.parametrize("backend,dtype,nope,v_dim,segment,chosen,want", [
+    ("tpu", jnp.bfloat16, 128, 128, 1024, False, "kernel"),  # the Kimi cell's segment
+    ("tpu", jnp.bfloat16, 128, 128, 384, False, "kernel"),
+    ("cpu", jnp.bfloat16, 128, 128, 1024, False, "xla"),
+    ("tpu", jnp.bfloat16, 16, 16, 1024, False, "xla"),  # the tests' tiny heads
+    ("tpu", jnp.float32, 128, 128, 1024, False, "xla"),
+    ("tpu", jnp.bfloat16, 128, 128, 1000, False, "xla"),  # no whole tiles of queries
+    ("tpu", jnp.bfloat16, 128, 256, 1024, False, "kernel"),  # K and V of two widths
+    ("tpu", jnp.bfloat16, 192, 256, 1024, False, "kernel"),  # GLM-5's: K a tile and a half
+    ("tpu", jnp.bfloat16, 192, 256, 1024, True, "kernel"),  # the GLM-5 cell's segment
+    ("cpu", jnp.bfloat16, 192, 256, 1024, True, "xla"),
+    ("tpu", jnp.bfloat16, 192, 192, 1024, True, "xla"),  # values of no whole tiles
 ])
 def test_the_segments_form_is_read_off_the_backend_and_the_shapes(
-        monkeypatch, backend, dtype, nope, v_dim, segment, want):
+        monkeypatch, backend, dtype, nope, v_dim, segment, chosen, want):
+    """The rule, and what a segment traced under it records: a choice handed
+    to ``expanded_segment`` changes neither."""
     from distrl_llm_tpu.ops import latent_attention as la
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    q_nope = jax.ShapeDtypeStruct((4, segment, 16, nope), dtype)
+    monkeypatch.setattr(la, "dispatch_choices", {})
+    shape = lambda *s, t=dtype: jax.ShapeDtypeStruct(s, t)
+    q_nope = shape(2, segment, 4, nope)
     assert la.expanded_segment_impl(q_nope, v_dim) == want
+    block = lambda j: (jnp.zeros((2, segment, 4, nope + v_dim), dtype),
+                       jnp.zeros((2, segment, 64), dtype))
+    out = jax.eval_shape(  # traced, never lowered: the kernel's launch is an equation
+        lambda q_nope, q_pe, chosen: la.expanded_segment(
+            q_nope, q_pe, block, jnp.int32(segment), v_dim, dtype, chosen),
+        q_nope, shape(2, segment, 4, 64),
+        shape(2, segment, 2 * segment + 128, t=la.FOLD_MASK_DTYPE) if chosen else None)
+    assert out.shape == (2, segment, 4, v_dim)
+    assert la.dispatch_choices == {la.dispatch_key(4, nope, 64, v_dim, segment, dtype): want}
 
 
 def test_a_segment_records_the_form_it_took(monkeypatch):

@@ -772,19 +772,28 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
     assert memory.temp_size_in_bytes < 0.5e9
 
 
-def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip):
+def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip, monkeypatch):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through five
     layers of latent attention behind the index, a dense MLP and four expert
     layers in the grouped form, at the published widths): a segment's index
     scores are made a block of 1,024 keys at a time into one ``[4, 1024,
     20480]`` float32 array (336 MB) and its choice is a mask of the same shape,
-    not a ``[.., 32 heads, 20480]`` product (10.7 GB); the folds run under the
-    mask in the XLA form. 2.77 GB when this was written, beside 7.89 GB of
-    weights: the program peaks at 10.8 GB of the chip's 15.75."""
+    one byte a pair, not a ``[.., 32 heads, 20480]`` product (10.7 GB). On a
+    TPU every fold runs under that mask in ``expanded_fold_kernel`` (K 192 + 64
+    wide beside V of 256, a tile of the mask read beside the tile of keys;
+    PR 57): the launches stand under ``model/attn_core`` and nowhere else (the
+    index's scopes hold none), in both stages' bodies, and a block's float32
+    scores ``[rows, 64, 1024, 1024]`` (1 GB at 4 rows, which the XLA form wrote
+    and read back about six times a fold) are no buffer of the program. 2.31 GB
+    of temporaries when this was written, where the XLA form under the same
+    mask reads 2.55 and PR 56's program read 2.77, beside 7.89 GB of weights."""
     from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.models import init_lora_params, init_params
+    from distrl_llm_tpu.ops import latent_attention as la
 
     cfg = _glm_cell()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(la, "dispatch_choices", {})
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     params = place(jax.eval_shape(functools.partial(
         init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
@@ -796,9 +805,20 @@ def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip
         total_tokens=20480 + 512)
     compiled = jax.jit(lambda *a: prefill(*a)).lower(
         params, lora, chip((4, 20480), jnp.int32), chip((4, 20480), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
-    assert 'custom_call_target="tpu_custom_call"' not in compiled.as_text()
-    assert _sorts_under(compiled.as_text(), "model/index_select") == []  # PR 55
+    assert la.dispatch_choices == {
+        la.dispatch_key(64, 192, 64, 256, 1024, jnp.bfloat16): "kernel"}
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all("model/attn_core" in line for line in calls), calls
+    sizes = paged_engine._stage_sizes(4, 20)
+    assert sizes == (4, 2) and _entry_whiles(text) == len(sizes)
+    for rows in sizes:
+        assert any(f"f32[{rows},64,1024,256]" in line and f"s8[{rows},1024,20480]" in line
+                   for line in calls), (rows, calls)
+        assert f"f32[{rows},64,1024,1024]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.45e9
+    assert _sorts_under(text, "model/index_select") == []  # PR 55
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
