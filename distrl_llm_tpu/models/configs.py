@@ -74,6 +74,18 @@ second half of a layer is read from ``mlp_layer_types`` A LAYER (``mlp_types``):
 ``dense`` is the gated MLP of ``intermediate_size``, and its layer's kind
 carries the suffix ``_dense`` (its stack has other leaves). ``sliding_window``
 is then the ring's length and refuses nothing.
+
+Compressed convolutional attention with an MLP router (``zaya``, ZAYA1-8B) is
+one kind, "cca", in every layer (published name ``hybrid``): softmax attention
+that runs WHOLE in a latent of ``num_heads + num_kv_heads`` heads below the
+hidden width, its queries and keys mixed over the sequence by two causal
+convolutions of ``cca_time0`` and ``cca_time1`` taps, half of its value heads
+taken from the token before. A slot keeps K/V pages AND, in the same layer, a
+row state: the convolutions' tail and the late value (``cca_tail_dim`` values).
+The second half of every layer is routed experts chosen ONE a token by an MLP
+router of ``router_hidden_size`` whose input carries the previous layer's
+(``models/moe.py::route_mlp``), and each sublayer joins the stream through
+learned scales and shifts (``models/hybrid.py``).
 """
 
 from __future__ import annotations
@@ -91,7 +103,9 @@ MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
                # every layer. Its attention is a "softmax" layer with a dense MLP
                "attention": "softmax", "mamba": "mamba",
                # exaone_moe publishes ``layer_types``
-               "sliding_attention": "window", "full_attention": "softmax"}
+               "sliding_attention": "window", "full_attention": "softmax",
+               # zaya publishes ``layer_types``, every entry "hybrid"
+               "hybrid": "cca"}
 #: what a kind's name carries where its layer's second half is the dense gated
 #: MLP in a model whose other layers have routed experts (``mlp_types``)
 DENSE_FFN = "_dense"
@@ -103,7 +117,7 @@ def mixer_of(kind: str) -> str:
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
-    "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa",
+    "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa", "zaya",
 )
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
@@ -117,6 +131,8 @@ _STATE_NAMES = {
              "and no K/V at all",
     "mamba": "a float32 state-space state and a convolution window",
     "window": "a ring of the last sliding_window tokens' K and V and no page",
+    "cca": "K/V pages and, beside them in the same layer, a row state: the "
+           "convolutions' tail and the value taken a token late",
 }
 #: what a latent layer's token keeps beside its row where the model has an index
 _INDEX_STATE = " beside one index key a token in a second paged array"
@@ -207,6 +223,12 @@ class ModelConfig:
     # ---- a layer's second half, a LAYER (exaone_moe's ``mlp_layer_types``, the
     # published list whole: "dense" | "sparse"). None = the family's own rule
     mlp_types: tuple[str, ...] | None = None
+    # ---- compressed convolutional attention and an MLP router (zaya's cca_* and
+    # router_hidden_size keys; module docstring). 0 = none
+    cca_time0: int = 0  # taps of the depth-wise convolution over [q~ | k~]
+    cca_time1: int = 0  # taps of the convolution grouped by head after it
+    rotary_dim: int = 0  # values of a head RoPE rotates, its first; 0 = all
+    router_hidden_size: int = 0  # width of the router's MLP and of what it hands on
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
@@ -292,6 +314,20 @@ class ModelConfig:
             {"sliding_attention", "full_attention"} & set(self.mixer_types[: self.num_layers]))
 
     @property
+    def cca(self) -> bool:
+        """True for compressed convolutional attention (``zaya``): every layer
+        "cca", each with routed experts behind an MLP router."""
+        return self.mixer_types is not None and (
+            "hybrid" in self.mixer_types[: self.num_layers])
+
+    @property
+    def cca_tail_dim(self) -> int:
+        """Values a slot keeps a layer beside its pages: the last token's
+        ``[q~ | k~]``, its first convolution's output, and the half of the
+        value heads the NEXT token reads."""
+        return 2 * (self.q_dim + self.kv_dim) + self.kv_dim // 2
+
+    @property
     def mamba_inner(self) -> int:
         """E: channels of a Mamba layer (``d_inner``)."""
         return self.mamba_expand * self.hidden_size
@@ -312,7 +348,7 @@ class ModelConfig:
     def layer_kinds(self) -> tuple[str, ...]:
         """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
         "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
-        "softmax" | "delta" | "power" | "mamba" | "window"; with ``mlp_types``
+        "softmax" | "delta" | "power" | "mamba" | "window" | "cca"; with ``mlp_types``
         a layer whose second half is the dense MLP carries ``DENSE_FFN``."""
         if self.latent:
             dense = min(self.first_dense_layers, self.num_layers)
@@ -376,7 +412,7 @@ class ModelConfig:
         """Layers that keep pages in the paged engine's pool."""
         if self.latent or not self.hybrid:
             return self.num_layers
-        return self.mixer_count("sparse") + self.mixer_count("softmax")
+        return sum(self.mixer_count(m) for m in ("sparse", "softmax", "cca"))
 
     def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
         """Shape of one layer's page array: K beside V ``[K, pages, page,
@@ -518,6 +554,8 @@ class ModelConfig:
             return self._delta_moe_param_count(self.experts_per_token)
         if self.window_moe:
             return self._window_moe_param_count(self.experts_per_token)
+        if self.cca:
+            return self._cca_param_count(self.experts_per_token)
         if not self.hybrid:
             return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
         sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
@@ -598,6 +636,19 @@ class ModelConfig:
             + (self.num_layers - dense) * moe + d * self.vocab_size
         )
 
+    def _cca_param_count(self, experts: int) -> int:
+        """Matmul parameters of a ``zaya`` model with ``experts`` routed experts
+        counted a layer (``_latent_param_count`` says which): q and o at the
+        latent's query width, k and the two value halves at its KV width, the
+        convolution grouped by head, the router's four matrices."""
+        d, r, hd = self.hidden_size, self.router_hidden_size, self.head_dim
+        attn = 2 * d * self.q_dim + 2 * d * self.kv_dim
+        conv = self.cca_time1 * (self.num_heads + self.num_kv_heads) * hd * hd
+        router = d * r + 2 * r * r + r * self.n_routed_experts
+        return self.num_layers * (
+            attn + conv + router + 3 * d * self.moe_intermediate_size * experts
+        ) + d * self.vocab_size
+
     @property
     def total_matmul_param_count(self) -> int:
         """``matmul_param_count`` over every expert HELD, not only those a
@@ -608,6 +659,8 @@ class ModelConfig:
             return self._delta_moe_param_count(self.n_routed_experts)
         if self.window_moe:
             return self._window_moe_param_count(self.n_routed_experts)
+        if self.cca:
+            return self._cca_param_count(self.n_routed_experts)
         return self.matmul_param_count
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
@@ -665,6 +718,8 @@ class ModelConfig:
             return "jamba"
         if self.window_moe:
             return "exaone_moe"
+        if self.cca:
+            return "zaya"
         if self.hybrid:
             return "minicpm_sala"
         if self.rmsnorm_offset:
@@ -751,13 +806,16 @@ class ModelConfig:
             hybrid = _jamba_fields(get, vars(hf))
         if mt == "exaone_moe":
             hybrid = _window_moe_fields(get)
+        if mt == "zaya":
+            hybrid = _cca_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
         return ModelConfig(
             vocab_size=hf.vocab_size,
             hidden_size=hf.hidden_size,
-            intermediate_size=hf.intermediate_size,
+            # a zaya file has no dense MLP and no such key: its experts' width
+            intermediate_size=hybrid.pop("intermediate_size", None) or hf.intermediate_size,
             num_layers=hf.num_hidden_layers,
             num_heads=num_heads,
             num_kv_heads=get("num_key_value_heads", num_heads),
@@ -773,6 +831,72 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+def _cca_fields(get) -> dict:
+    """The ``zaya`` keys as ``ModelConfig`` fields: every ``layer_types`` entry
+    ``hybrid`` (compressed convolutional attention, then routed experts), the
+    two convolutions' taps, the router's width, the experts under their own
+    count keys, RoPE's base and share of a head from ``rope_parameters.hybrid``.
+    What the published file does not state (the readings under ``assumed`` in
+    ``perfbench/configs/zaya1-8b-L20.json``) is fixed in the program: the
+    convolutions' groups and biases, the q-k mean, the value split, the
+    router's three layers with the value it hands through depth, the scaled
+    residual. A variant that is not implemented is REFUSED by key."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"zaya with {key}={get(key)!r} is not supported: {why}")
+
+    layers = get("layer_types")
+    if not layers:
+        raise ValueError("model_type 'zaya' needs its layer_types list")
+    if set(layers) - {"hybrid"}:
+        refuse("layer_types", "every layer is 'hybrid' (compressed convolutional "
+               "attention, then routed experts); a sliding variant of the layer "
+               f"({sorted(set(layers) - {'hybrid'})}) is not implemented")
+    if get("sliding_window") is not None:
+        refuse("sliding_window", "a layer attends over the whole context; a window "
+               "is not implemented")
+    if int(get("num_experts_per_tok", 1) or 0) != 1:
+        refuse("num_experts_per_tok", "the router chooses ONE expert a token and "
+               "weights it by its probability; top-k over it is not implemented")
+    for key in ("cca_time0", "cca_time1"):
+        if int(get(key, 2) or 0) != 2:
+            refuse(key, "both convolutions have two taps (a slot's tail keeps ONE "
+                   "token of each); another reach is not implemented")
+    for key in ("attention_bias", "lm_head_bias"):
+        if get(key, False):
+            refuse(key, "the attention projections and the head carry no bias")
+    if get("share") is not None:
+        refuse("share", "this family reads no share yet: every expert and the whole "
+               "vocabulary are held (one chip's share of the experts, with their "
+               "exchange, is not implemented)")
+    rope = dict(get("rope_parameters") or {})
+    own = dict(rope.get("hybrid") or {})
+    kind = str(own.get("rope_type", rope.get("rope_type", "default")))
+    if get("rope_scaling") or kind != "default":
+        refuse("rope_scaling" if get("rope_scaling") else "rope_parameters",
+               "q and k are rotated by plain RoPE at rope_parameters.hybrid."
+               "rope_theta; scaled positions are not implemented")
+    for key, must in (("zaya_use_mod", False), ("zaya_use_eda", True),
+                      ("scale_residual_merge", True)):
+        if get(key) is not None and bool(get(key)) != must:
+            refuse(key, "the router scores the published experts and no choice that "
+                   "skips them, hands its value on through depth, and each sublayer "
+                   "joins the stream through learned scales and shifts; the other "
+                   "setting is not implemented")
+    head_dim = int(get("head_dim") or get("hidden_size") // get("num_attention_heads"))
+    share = float(own.get("partial_rotary_factor", get("partial_rotary_factor", 1.0)))
+    width = int(get("moe_intermediate_size") or 0)
+    return dict(
+        mixer_types=tuple(layers),
+        cca_time0=int(get("cca_time0", 2)), cca_time1=int(get("cca_time1", 2)),
+        rotary_dim=int(head_dim * share),
+        rope_theta=float(own.get("rope_theta", get("rope_theta", 10000.0))),
+        router_hidden_size=int(get("router_hidden_size")),
+        n_routed_experts=int(get("num_experts") or 0),
+        experts_per_token=1,
+        moe_intermediate_size=width, intermediate_size=width,
+    )
 
 
 def _window_moe_fields(get) -> dict:
@@ -1119,6 +1243,17 @@ TINY_EXAONE_MOE = ModelConfig(
     experts_per_token=4, moe_intermediate_size=32, routed_scaling_factor=2.5,
 )
 
+# compressed convolutional attention with an MLP router at a size the CPU tests
+# run (ZAYA1-8B's shape): 4 query heads over 2 KV heads of 16 in the latent, the
+# first 8 of a head rotated, 1 of 4 experts a token behind a router of width 16
+TINY_CCA = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=32, num_layers=3,
+    num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=5000000.0,
+    rms_norm_eps=1e-5, tie_word_embeddings=True, mixer_types=("hybrid",) * 3,
+    cca_time0=2, cca_time1=2, rotary_dim=8, router_hidden_size=16,
+    n_routed_experts=4, experts_per_token=1, moe_intermediate_size=32,
+)
+
 QWEN2_0_5B = ModelConfig(
     vocab_size=151936, hidden_size=896, intermediate_size=4864, num_layers=24,
     num_heads=14, num_kv_heads=2, head_dim=64, rope_theta=1000000.0,
@@ -1174,6 +1309,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-jamba": TINY_JAMBA,
     "tiny-exaone-moe": TINY_EXAONE_MOE,
     "tiny-dsa": TINY_DSA,
+    "tiny-cca": TINY_CCA,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

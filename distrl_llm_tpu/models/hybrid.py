@@ -161,6 +161,30 @@ cache's type, written at ``position % W``, tuples over the window layers.
 the keys attended and the keys a full layer would attend, in units of
 ``WINDOW_COUNT_UNIT`` keys rounded up.
 
+**Compressed convolutional attention with an MLP router** (``zaya``,
+ZAYA1-8B) is one more kind, "cca", in every layer of its model: softmax
+attention that runs whole in a latent of H + K heads below the hidden width,
+then routed experts chosen one a token (``models/moe.py::route_mlp``), each
+sublayer joined to the stream through learned scales and shifts (``_merge``)::
+
+    h = RMSNorm(x);  q~ = W_q h [T, H, D];  k~ = W_k h [T, K, D];  u = [q~ | k~]
+    c1_t = a_0 u_{t-1} + a_1 u_t + b_1                       depth-wise; zeros before a row
+    c2_t[g] = C_0[g] c1_{t-1}[g] + C_1[g] c1_t[g] + b_2[g]   a head g of the H + K
+    m^q[i] = (q~[i] + k~[i // (H/K)]) / 2;   m^k[j] = mean of its group's m^q
+    q[i] = c2[i] + m^q[i];   k[j] = c2[H + j] + m^k[j]
+    q <- sqrt(D) l2norm(q);  k <- tau_j sqrt(D) l2norm(k);  RoPE on the first rotary_dim
+    v_t = [W_v1 h_t | W_v2 h_{t-1}]          the later half of the KV heads a token late
+    y = W_o softmax(q k^T / sqrt(D)) v;   x <- (a_r x + b_r) + (a_o y + b_o)
+    x <- (a_r' x + b_r') + (a_o' p_e E_e(h') + b_o'),   e chosen by the router from h' and r_{l-1}
+
+A layer keeps K/V pages like a "softmax" layer (k and v AFTER all of the above)
+AND a SEVENTH kind of slot state, ``cca_tail`` ``[B, 2 (H + K) D + K D / 2]``:
+the last token's ``u``, its ``c1`` and ``W_v2 h``, what the next token's
+convolutions and value read. A prefill segment takes it at each ROW's last
+real token, a candidate from its prompt. The router's ``r`` is a second value
+that flows from layer to layer: for this family alone the layer loop carries
+``(x, r)``.
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -183,7 +207,7 @@ from distrl_llm_tpu.models.transformer import (
     _head, _init_around_layers, _init_layer_stack, _mlp_half, _normal_init, _proj,
     _slice_layer, apply_rope, rms_norm, rope_cos_sin,
 )
-from distrl_llm_tpu.models.moe import moe_half
+from distrl_llm_tpu.models.moe import moe_half, route_mlp
 from distrl_llm_tpu.ops.attention import attention, attention_reference
 from distrl_llm_tpu.ops.delta_attention import (
     delta_chunked, delta_step, l2norm, short_conv,
@@ -215,7 +239,7 @@ SOFTMAX_SEGMENT_PAGES = 2
 #: the entries of a slot's state that hold one array a ROW for each layer of a
 #: kind (tuples): what a candidate is handed from its prompt
 ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm",
-              "win_k", "win_v")
+              "win_k", "win_v", "cca_tail")
 #: keys a unit of ``window_stats`` stands for (module docstring)
 WINDOW_COUNT_UNIT = 128
 #: tokens a unit of ``index_stats`` stands for (module docstring)
@@ -225,7 +249,8 @@ INDEX_NORM_EPS = 1e-6
 #: the mixers whose layers ``_block`` runs as a mixer and then the layer's own
 #: second half, and the cache entries each keeps a layer
 _MIXER_CACHE = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
-                "mamba": ("ssm", "conv"), "window": ("win_k", "win_v")}
+                "mamba": ("ssm", "conv"), "window": ("win_k", "win_v"),
+                "cca": ("k", "v", "cca_tail")}
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -368,6 +393,34 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             "w_out": init((n, e, d)),
             **mlp_half(n),
         }
+    if cfg.kind_count("cca"):
+        n, d, r = cfg.kind_count("cca"), cfg.hidden_size, cfg.router_hidden_size
+        mixed, groups, hd = cfg.q_dim + cfg.kv_dim, cfg.num_heads + cfg.num_kv_heads, cfg.head_dim
+        experts, fm = cfg.n_routed_experts, cfg.moe_intermediate_size
+        # the residual's vectors, [n, 2, d]: what x takes, what f(x) takes
+        scales, shifts = jnp.ones((n, 2, d), dtype), jnp.zeros((n, 2, d), dtype)
+        layers["cca"] = {
+            "attn_norm": jnp.ones((n, d), dtype), "mlp_norm": jnp.ones((n, d), dtype),
+            "wq": init((n, d, cfg.q_dim)), "wk": init((n, d, cfg.kv_dim)),
+            # the value's two halves: of this token, and of the one before
+            "wv1": init((n, d, cfg.kv_dim // 2)), "wv2": init((n, d, cfg.kv_dim // 2)),
+            "wo": init((n, cfg.q_dim, d)),
+            "conv0": init((n, cfg.cca_time0, mixed)), "b_conv0": jnp.zeros((n, mixed), dtype),
+            "conv1": init((n, cfg.cca_time1, groups, hd, hd)),
+            "b_conv1": jnp.zeros((n, mixed), dtype),
+            "k_temp": jnp.ones((n, cfg.num_kv_heads), dtype),
+            "attn_res_scale": scales, "attn_res_shift": shifts,
+            "mlp_res_scale": scales, "mlp_res_shift": shifts,
+            "router_down": init((n, d, r)), "b_router_down": jnp.zeros((n, r), dtype),
+            "router_gamma": jnp.ones((n, r), dtype), "router_norm": jnp.ones((n, r), dtype),
+            "router_w1": init((n, r, r)), "b_router_w1": jnp.zeros((n, r), dtype),
+            "router_w2": init((n, r, r)), "b_router_w2": jnp.zeros((n, r), dtype),
+            "router_w3": init((n, r, experts)),
+            "e_score_bias": jnp.zeros((n, experts), dtype),
+            "experts_gate": init((n, experts, d, fm)),
+            "experts_up": init((n, experts, d, fm)),
+            "experts_down": init((n, experts, fm, d)),
+        }
     for kind in ("latent", "latent_moe"):
         if cfg.kind_count(kind):
             layers[kind] = latent_stack(cfg.kind_count(kind), kind == "latent_moe")
@@ -399,8 +452,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     layer, the selector's pooled keys per sparse layer, a float32 state and a
     convolution tail per delta-rule layer, a float32 state and its normaliser
     per power-retention layer, a float32 state and a convolution window per
-    Mamba layer, the round's counters. The entries named in ``ROW_STATES`` are
-    tuples of one array a row."""
+    Mamba layer, a tail per compressed-convolutional layer, the round's
+    counters. The entries named in ``ROW_STATES`` are tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counters
         state = {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32)}
         if cfg.held_experts is not None:  # the pairs chosen over ALL experts
@@ -408,6 +461,13 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
         state["index_stats" if cfg.index_topk else "latent_stats"] = jnp.zeros(
             (2,), jnp.int32)
         return state
+    if cfg.cca:  # pages AND a tail in every layer; every expert is held
+        return {
+            "lin": (), "pooled": (),
+            "cca_tail": tuple(jnp.zeros((rows, cfg.cca_tail_dim), cache_dtype)
+                              for _ in range(cfg.num_layers)),
+            "moe_stats": jnp.zeros((2,), jnp.int32),
+        }
     if cfg.delta_moe:
         h, d, n = cfg.delta_heads, cfg.delta_head_dim, cfg.kind_count("delta")
         return {
@@ -573,7 +633,6 @@ def _power_mix(q, k, v, g, state, *, mode, env):
 def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
            env: dict, lora_scale: float, lora_dropout: float, dropout_rng):
     """One layer of any kind: (x, new cache pieces, stats)."""
-    b, s, _ = x.shape
     proj = partial(_proj, lora_dropout=lora_dropout, dropout_rng=dropout_rng)
     if kind in ("latent", "latent_moe"):
         return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
@@ -581,15 +640,19 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     mixer = mixer_of(kind)
     if mixer in _MIXER_CACHE:
         mix = {"softmax": _softmax_mix, "delta": _delta_mix, "mamba": _mamba_mix,
-               "window": _window_mix}[mixer]
+               "window": _window_mix, "cca": _cca_mix}[mixer]
+        carried = None
+        if mixer == "cca":  # the stream, and the router's value from the layer before
+            x, carried = x
         x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
                        lora_scale=lora_scale)
         if cfg.layer_ffn(kind) == "dense":  # the layer's own second half
             return _mlp_half(x, p, lora, cfg=cfg, proj=proj,
                              lora_scale=lora_scale), cache, None
         x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
-                                lora_scale=lora_scale)
+                                lora_scale=lora_scale, carried=carried)
         return x, cache, stats
+    b, s, _ = x.shape
     c = jnp.asarray(cfg.residual_scale, x.dtype)
     sparse = kind == "sparse"
     heads, kv_heads, hd = (
@@ -626,16 +689,34 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     return x, cache, stats
 
 
-def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale):
+def _merge(x, y, p, half: str):
+    """A sublayer's output ``y`` joined to the stream: ``x + y``, or, where the
+    layer has the vectors, ``(a_r x + b_r) + (a_o y + b_o)`` (module docstring)."""
+    scale = p.get(half + "_res_scale")
+    if scale is None:
+        return x + y
+    scale, shift = scale.astype(x.dtype), p[half + "_res_shift"].astype(x.dtype)
+    return (scale[0] * x + shift[0]) + (scale[1] * y + shift[1])
+
+
+def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale, carried=None):
     """An expert layer's second half: the routed experts HELD here
     (``cfg.held_experts``; the router scores them all) beside the shared
-    expert. Returns (x, the layer's [2] stats)."""
+    expert. Returns (x, the layer's [2] stats). With ``carried`` (the MLP
+    router's value from the layer before, ``route_mlp``) the router reads it
+    and what is returned in ``x``'s place is ``(x, the value to hand on)``."""
     with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    routed, stats = moe_half(h, p, cfg, held=cfg.held_experts, alive=env.get("alive"))
+    if carried is None:
+        routed, stats = moe_half(h, p, cfg, held=cfg.held_experts, alive=env.get("alive"))
+    else:  # the MLP router chooses, and hands its value on
+        *choice, carried = route_mlp(h, carried, p, cfg)
+        routed, stats = moe_half(h, p, cfg, held=cfg.held_experts, alive=env.get("alive"),
+                                 choice=tuple(choice))
     if "w_gate" in p:  # the shared expert: x + S(h)
         x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
-    return x + routed, stats
+    x = _merge(x, routed, p, "mlp")
+    return (x if carried is None else (x, carried)), stats
 
 
 def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
@@ -758,39 +839,115 @@ def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
         return x + proj(o.reshape(b, s, -1), p, lora, "wo", "bo", lora_scale), cache
 
 
-def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-    """A softmax layer without RoPE, gated or with a per-head norm of q and k
-    where its stack says so: (x + y, (pages_k, pages_v) or None)."""
+def _paged_softmax(q, k, v, cache, *, mode, env):
+    """Causal softmax attention of ``q [B, S, H, hd]`` over ``k``, ``v [B, S,
+    K, hd]`` in each mode, K and V kept in pages: (o [B, S, H, hd], (pages_k,
+    pages_v) or None)."""
     from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
 
-    b, s, _ = x.shape
-    heads, hd = cfg.num_heads, cfg.head_dim
-    if cfg.attn_use_rope:
-        raise NotImplementedError("softmax layers with RoPE (use_rope)")
-    h, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    s = q.shape[1]
     if mode == "full":
         with jax.named_scope(telemetry.MODEL_ATTN_CORE):
-            o = attention(q, k, v, None, impl=env["attn_impl"], key_valid=env["valid"])
-    elif mode == "decode":
-        pages_k, pages_v = cache
-        idx, ps, lengths = env["page_indices"], env["page_size"], env["lengths"]
+            return attention(q, k, v, None, impl=env["attn_impl"],
+                             key_valid=env["valid"]), None
+    pages_k, pages_v = cache
+    idx, ps = env["page_indices"], env["page_size"]
+    if mode == "decode":
+        lengths = env["lengths"]
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
             pages_k = write_token_to_pages(pages_k, k[:, 0], lengths, idx, ps)
             pages_v = write_token_to_pages(pages_v, v[:, 0], lengths, idx, ps)
         o = paged_attention_op(
             q[:, 0], pages_k, pages_v, lengths + 1, idx, impl=env["paged_impl"],
         )[:, None]
-        cache = (pages_k, pages_v)
-    else:  # one page-aligned segment of a prefill, every row at ``start``
-        pages_k, pages_v = cache
-        idx, ps, start = env["page_indices"], env["page_size"], env["segment_start"]
-        with jax.named_scope(telemetry.ENGINE_KV_WRITE):
-            dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, s // ps, axis=1)
-            pages_k = _write_segment_pages(pages_k, k, dest, ps)
-            pages_v = _write_segment_pages(pages_v, v, dest, ps)
-        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
-            o = _segment_softmax(q, pages_k, pages_v, idx, env["q_pos"], start, ps)
-        cache = (pages_k, pages_v)
+        return o, (pages_k, pages_v)
+    # one page-aligned segment of a prefill, every row at ``start``
+    start = env["segment_start"]
+    with jax.named_scope(telemetry.ENGINE_KV_WRITE):
+        dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, s // ps, axis=1)
+        pages_k = _write_segment_pages(pages_k, k, dest, ps)
+        pages_v = _write_segment_pages(pages_v, v, dest, ps)
+    with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+        o = _segment_softmax(q, pages_k, pages_v, idx, env["q_pos"], start, ps)
+    return o, (pages_k, pages_v)
+
+
+def _shifted(x, before, valid):
+    """``x [B, S, C]`` a token late, ``before [B, C]`` (None: zeros, a row's
+    start) ahead of its first: ``(x_{t-1} [B, S, C], what the NEXT token reads
+    [B, C])``, which is the row's last REAL token's ``x`` (``valid [B, S]``,
+    real tokens first; ``before`` where the row has none here; None: all are)."""
+    b, _, c = x.shape
+    before = jnp.zeros((b, c), x.dtype) if before is None else before.astype(x.dtype)
+    whole = jnp.concatenate([before[:, None], x], axis=1)
+    if valid is None:
+        return whole[:, :-1], x[:, -1]
+    end = (valid > 0).sum(-1).astype(jnp.int32)
+    return whole[:, :-1], jnp.take_along_axis(whole, end[:, None, None], axis=1)[:, 0]
+
+
+def _qk_mean(q_raw, k_raw):
+    """The mean of a query head and its group's key, from BEFORE the
+    convolutions, ``[B, S, K, H/K, D]``, and a group's mean of those ``[B, S,
+    K, D]``: what joins the convolutions' q and k."""
+    mean_q = (q_raw + k_raw) * jnp.asarray(0.5, q_raw.dtype)
+    return mean_q, mean_q.mean(axis=3)
+
+
+def _cca_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A compressed-convolutional-attention layer (module docstring): (the
+    stream with y merged in, (pages_k, pages_v, tail) or None)."""
+    b, s, _ = x.shape
+    heads, kv, hd, rot = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.rotary_dim
+    mixed = cfg.q_dim + cfg.kv_dim
+    pages, tail = (cache[:2], cache[2]) if cache is not None else (None, None)
+    # what the last token left: its u, its c1, its W_v2 h (None: a row's start)
+    late_u, late_c1, late_v = (None,) * 3 if tail is None else (
+        tail[:, :mixed], tail[:, mixed: 2 * mixed], tail[:, 2 * mixed:])
+    valid = None if mode == "decode" else env["valid"]
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_raw, k_raw, v_now, v_next = (
+            proj(h, p, lora, name, None, lora_scale) for name in ("wq", "wk", "wv1", "wv2"))
+    with jax.named_scope(telemetry.MODEL_CCA_MIX):
+        u = jnp.concatenate([q_raw, k_raw], axis=-1)
+        u_late, u_last = _shifted(u, late_u, valid)
+        taps = p["conv0"].astype(u.dtype)
+        c1 = taps[0] * u_late + taps[1] * u + p["b_conv0"].astype(u.dtype)
+        c1_late, c1_last = _shifted(c1, late_c1, valid)
+        by_head = lambda z, w: jnp.einsum(
+            "bsgi,gio->bsgo", z.reshape(b, s, heads + kv, hd), w)
+        c2 = (by_head(c1_late, p["conv1"][0]) + by_head(c1, p["conv1"][1])
+              + p["b_conv1"].astype(u.dtype).reshape(heads + kv, hd))
+        v_late, v_last = _shifted(v_next, late_v, valid)
+        mean_q, mean_k = _qk_mean(q_raw.reshape(b, s, kv, heads // kv, hd),
+                                  k_raw.reshape(b, s, kv, 1, hd))
+        q = c2[:, :, :heads] + mean_q.reshape(b, s, heads, hd)
+        k = c2[:, :, heads:] + mean_k
+        q = (l2norm(q) * hd ** 0.5).astype(u.dtype)
+        k = (l2norm(k) * (hd ** 0.5 * p["k_temp"].astype(jnp.float32))[:, None]).astype(u.dtype)
+        rotate = lambda z: jnp.concatenate(
+            [apply_rope(z[..., :rot], env["cos"], env["sin"]), z[..., rot:]], axis=-1)
+        q, k = rotate(q), rotate(k)
+        v = jnp.concatenate([v_now, v_late], axis=-1).reshape(b, s, kv, hd)
+        if tail is not None:
+            tail = jnp.concatenate([u_last, c1_last, v_last], axis=-1).astype(tail.dtype)
+    o, pages = _paged_softmax(q, k, v, pages, mode=mode, env=env)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        y = proj(o.reshape(b, s, heads * hd), p, lora, "wo", None, lora_scale)
+        x = _merge(x, y, p, "attn")
+    return x, (None if pages is None else (*pages, tail))
+
+
+def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A softmax layer without RoPE, gated or with a per-head norm of q and k
+    where its stack says so: (x + y, (pages_k, pages_v) or None)."""
+    b, s, _ = x.shape
+    heads, hd = cfg.num_heads, cfg.head_dim
+    if cfg.attn_use_rope:
+        raise NotImplementedError("softmax layers with RoPE (use_rope)")
+    h, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    o, cache = _paged_softmax(q, k, v, cache, mode=mode, env=env)
     o = o.reshape(b, s, heads * hd)
     if "wg" in p:
         with jax.named_scope(telemetry.MODEL_ATTN_GATE):
@@ -1197,21 +1354,24 @@ def forward_hybrid(
             "valid": attention_mask, "page_indices": kv_cache["page_indices"],
             "page_size": page_size,
         }
-    if (cfg.latent or cfg.power or cfg.window_moe
+    if (cfg.latent or cfg.power or cfg.window_moe or cfg.cca
             or cfg.kind_count("lightning")):  # the others rotate nothing
         with jax.named_scope(
                 telemetry.MODEL_ATTN_CORE if cfg.latent else
                 telemetry.MODEL_POWER_ATTN if cfg.power else
                 telemetry.MODEL_WINDOW_ATTN if cfg.window_moe else
+                telemetry.MODEL_CCA_MIX if cfg.cca else
                 telemetry.MODEL_LINEAR_ATTN):
             env["cos"], env["sin"] = rope_cos_sin(
-                rope_pos, cfg.qk_rope_head_dim or cfg.lightning_head_dim or cfg.head_dim,
-                cfg.rope_theta)
+                rope_pos, cfg.rotary_dim or cfg.qk_rope_head_dim
+                or cfg.lightning_head_dim or cfg.head_dim, cfg.rope_theta)
 
     with jax.named_scope(telemetry.MODEL_EMBED):
         x = jnp.take(params["embed"], input_ids, axis=0)
         if cfg.scale_emb != 1.0:
             x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+    if cfg.cca:  # the layer loop's carry: the stream, and the router's r_{l-1}
+        x = (x, jnp.zeros((b, s, cfg.router_hidden_size), jnp.float32))
 
     rates = (jnp.asarray(cfg.lightning_decay_rates())
              if cfg.kind_count("lightning") else None)
@@ -1253,6 +1413,8 @@ def forward_hybrid(
                 body = jax.checkpoint(
                     body, policy=jax.checkpoint_policies.nothing_saveable)
             x, _ = jax.lax.scan(body, x, xs)
+        if cfg.cca:
+            x = x[0]
         with jax.named_scope(telemetry.MODEL_HEAD):
             # back to the caller's columns before it slices the positions it wants
             cols = (jnp.arange(s)[None, :] - shift[:, None]) % s
@@ -1315,6 +1477,8 @@ def forward_hybrid(
             x, new["lin"][j], _ = block(
                 x, p, lora_p, rates[j], new["lin"][j], kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
+    if cfg.cca:
+        x = x[0]
     with jax.named_scope(telemetry.MODEL_HEAD):
         logits = _head(x, params, cfg, logits_slice, logits_positions, skip_lm_head)
     out = {**kv_cache, **{name: tuple(vals) for name, vals in new.items()}}

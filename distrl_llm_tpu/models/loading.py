@@ -161,7 +161,7 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
 
 
 def _refuse_unnamed(cfg: ModelConfig) -> None:
-    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe`` or ``glm_moe_dsa`` checkpoint is refused by name, in both directions:
+    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe``, ``glm_moe_dsa`` or ``zaya`` checkpoint is refused by name, in both directions:
     its published tensor names cannot be read here, and names guessed for the
     delta-rule layers' convolutions, low-rank pairs and gates would load or
     save something else under the model's name. Seeded weights only."""
@@ -172,6 +172,14 @@ def _refuse_unnamed(cfg: ModelConfig) -> None:
             "LayerNorm) and of its multi-token-prediction module cannot be read "
             "here, and a guessed name would load or save something else under the "
             "model's name; the model runs from seeded weights only (init_params)")
+    if cfg.cca:
+        raise NotImplementedError(
+            "model_type 'zaya' checkpoints are not supported: the published tensor "
+            "names of its layers (the two convolutions, the keys' temperature, the "
+            "value's two halves, the router's MLP with the vector it weights the "
+            "previous layer's value by, the residual's scales and shifts) cannot be "
+            "read here, and a guessed name would load or save something else under "
+            "the model's name; the model runs from seeded weights only (init_params)")
     if cfg.power:
         raise NotImplementedError(
             "model_type 'brumby' checkpoints are not supported: the published "

@@ -35,6 +35,9 @@ _TARGET_DIMS = {
     # a Mamba layer's two projections: [u, z] from the stream, and back
     "w_in": ("hidden_size", "mamba_in_dim"),
     "w_out": ("mamba_inner", "hidden_size"),
+    # compressed convolutional attention's value: this token's half, the last one's
+    "wv1": ("hidden_size", "v_half"),
+    "wv2": ("hidden_size", "v_half"),
 }
 
 # reference target_modules (helper.py:29–37) in our key naming
@@ -49,6 +52,10 @@ LATENT_RANK_TARGETS = (*LATENT_TARGETS, "wq_a")
 # a Mamba layer has no q, k, v or o: its targets are W_in, W_out and the MLP's
 # three. W_x, W_dt, the convolution, A_log, D and the inner norms are frozen.
 MAMBA_TARGETS = ("w_in", "w_out", "w_gate", "w_up", "w_down")
+# compressed convolutional attention: q, k, the value's two halves and o, all in
+# the latent. The convolutions, the temperature, the residual's vectors, the
+# router and the routed experts are frozen; there is no shared expert.
+CCA_TARGETS = ("wq", "wk", "wv1", "wv2", "wo")
 
 
 def lora_scale(rank: int, alpha: float) -> float:
@@ -77,11 +84,12 @@ def init_lora_params(
     its Mamba layers ``MAMBA_TARGETS``; targets the caller names go to the
     layers that have them. A window model (``exaone_moe``) has the seven in
     every kind of layer: the MLP's three are the dense MLP's in a ``_dense``
-    kind and the shared expert's in the others."""
+    kind and the shared expert's in the others. A compressed-convolutional
+    layer has ``CCA_TARGETS`` and nothing in its second half."""
     named = targets is not None
     if targets is None:
         targets = ((LATENT_RANK_TARGETS if cfg.q_lora_rank else LATENT_TARGETS)
-                   if cfg.latent else DEFAULT_TARGETS)
+                   if cfg.latent else CCA_TARGETS if cfg.cca else DEFAULT_TARGETS)
 
     def factors(rng, n_layers: int, dims: dict[str, int], targets=targets) -> Params:
         layers: Params = {}
@@ -130,6 +138,10 @@ def init_lora_params(
         shared = {**dims, "intermediate_size": cfg.shared_expert_size}
         per_kind = {kind: dims if cfg.layer_ffn(kind) == "dense" else shared
                     for kind in dict.fromkeys(cfg.layer_kinds)}
+    elif cfg.cca:
+        per_kind = {"cca": {
+            "hidden_size": cfg.hidden_size, "q_in": cfg.hidden_size, "q_dim": cfg.q_dim,
+            "kv_dim": cfg.kv_dim, "v_half": cfg.kv_dim // 2, "o_dim": cfg.q_dim}}
     elif cfg.power:
         # Qwen3's seven targets; the log-decay's projection and bias are frozen
         per_kind = {"power": dims}

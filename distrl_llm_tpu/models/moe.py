@@ -13,8 +13,9 @@ The shared expert is a plain gated MLP and goes through the decoder's own
 
 **Dropless.** There is no capacity: every (token, expert) pair is computed at
 any imbalance. Plain XLA throughout, in two forms chosen by the number of
-tokens alone (``DENSE_MAX_TOKENS``), each timed on the v5e at the published
-widths before it was kept (PERF.md, PR 33):
+tokens (``DENSE_MAX_TOKENS``; ``DENSE_MAX_TOKENS_TOP1`` where a token has one
+choice), each timed on the v5e at the published widths before it was kept
+(PERF.md, PR 33 and PR 58):
 
 * **grouped** (a prefill segment of 4,096 tokens, the learner with its
   backward): the pairs are sorted by expert and laid out so that every block
@@ -33,7 +34,12 @@ widths before it was kept (PERF.md, PR 33):
   The step must read every expert once in any case; this form reads them once
   and nothing else (90% of the experts' bandwidth roofline where grouped
   blocks of 64 rows read 62%), and its extra arithmetic is free under the
-  transfer up to about a hundred tokens.
+  transfer up to about a hundred tokens. With ONE choice a token it holds
+  further (``DENSE_MAX_TOKENS_TOP1``): 192 tokens x 1 choice over 16 experts
+  of 2,048 x 2,048 are 19 grouped blocks of 64 rows, 19 reads of an expert's
+  matrices where 16 suffice, 15.99 ms a step of 20 layers where this form
+  takes 12.27 (80% of the experts' bandwidth roofline; blocks of 32, 128 and
+  256 rows took 17.57, 16.83 and 17.34: PERF.md, PR 58).
 
 **Experts held.** ``held`` names the experts whose weights this program holds
 (``experts`` is stacked over them, in that order), the layer a chip of an
@@ -41,6 +47,16 @@ expert-parallel deployment runs: the router scores ALL experts and chooses
 among all; pairs whose expert is elsewhere add nothing here. Over disjoint
 shares the results sum to the whole layer's (``tests/test_latent_moe.py``).
 ``None`` holds all.
+
+**An MLP router that reads the layer before** (``zaya``: ``route_mlp``) is the
+second routing function: ONE expert a token, weighted by its probability::
+
+    r_l = h W_d + b_d + gamma_l * r_{l-1}        [T, R] float32; r_{-1} = 0, r_l handed on
+    z   = RMSNorm_R(r_l)
+    p   = softmax(W_3 gelu(W_2 gelu(W_1 z + b_1) + b_2))        [T, E]
+    e   = argmax(p + beta), the lower index among equals;   y = p_e E_e(h)
+
+The experts' side is ``routed_experts`` as it stands, ``k = 1``.
 """
 
 from __future__ import annotations
@@ -56,6 +72,8 @@ from distrl_llm_tpu.models.configs import ModelConfig
 NORM_EPS = 1e-20
 #: tokens up to which every expert runs on every token (module docstring)
 DENSE_MAX_TOKENS = 128
+#: and where a token has ONE choice (timed at 192 x 1 over 16: module docstring)
+DENSE_MAX_TOKENS_TOP1 = 192
 
 
 def route(h: jax.Array, router: jax.Array, bias: jax.Array, cfg: ModelConfig):
@@ -73,6 +91,28 @@ def route(h: jax.Array, router: jax.Array, bias: jax.Array, cfg: ModelConfig):
         if cfg.norm_topk_prob:
             w = w / (w.sum(axis=-1, keepdims=True) + NORM_EPS)
         return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def route_mlp(h: jax.Array, carried: jax.Array, p: dict, cfg: ModelConfig):
+    """``h [..., D]``, ``carried [..., R]`` (the previous layer's ``r``, zeros
+    before the first) -> ``(idx [T, 1] int32, w [T, 1] float32, r [..., R])``
+    (module docstring). Float32 at full precision throughout, as ``route`` is
+    and for its reason; the balancing bias ``beta`` (``e_score_bias``) is in
+    the choice and not in the weight."""
+    with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
+        f32 = lambda name: p[name].astype(jnp.float32)
+        dot = lambda x, name: jnp.dot(x, f32(name), precision=jax.lax.Precision.HIGHEST)
+        r = dot(h.astype(jnp.float32), "router_down") + f32("b_router_down")
+        r = r + f32("router_gamma") * carried
+        z = r * jax.lax.rsqrt(
+            jnp.mean(r * r, axis=-1, keepdims=True) + cfg.rms_norm_eps) * f32("router_norm")
+        gelu = lambda x: jax.nn.gelu(x, approximate=False)
+        z = gelu(dot(z, "router_w1") + f32("b_router_w1"))
+        z = gelu(dot(z, "router_w2") + f32("b_router_w2"))
+        prob = jax.nn.softmax(dot(z, "router_w3"), axis=-1)
+        prob = prob.reshape(-1, prob.shape[-1])
+        idx = jnp.argmax(prob + f32("e_score_bias"), axis=-1)[:, None]
+        return idx.astype(jnp.int32), jnp.take_along_axis(prob, idx, axis=-1), r
 
 
 def block_rows(pairs: int, groups: int) -> int:
@@ -113,7 +153,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
         counted = jnp.ones((t,), jnp.int32) if alive is None else alive.astype(jnp.int32)
         load = jnp.zeros((n + 1,), jnp.int32).at[local.reshape(-1)].add(
             jnp.repeat(counted, k))[:n]
-    if t <= DENSE_MAX_TOKENS:
+    if t <= (DENSE_MAX_TOKENS_TOP1 if k == 1 else DENSE_MAX_TOKENS):
         if layer is not None:
             experts = {name: x[layer] for name, x in experts.items()}
         with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
@@ -163,14 +203,16 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
     return y, load
 
 
-def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None):
+def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
+             choice=None):
     """The routed part of an expert layer on ``h [..., D]`` (normed). Returns
     ``(y like h, stats [2] int32)``: pairs computed, and the fullest expert's.
     ``p["experts_layer"]``, if there, says that ``p["experts_*"]`` are every
-    layer's and which is this one (``routed_experts``)."""
+    layer's and which is this one (``routed_experts``). ``choice`` is ``(idx,
+    w)`` where the caller's own router chose (``route_mlp``)."""
     lead = h.shape[:-1]
     flat = h.reshape(-1, h.shape[-1])
-    idx, w = route(flat, p["router"], p["e_score_bias"], cfg)
+    idx, w = choice or route(flat, p["router"], p["e_score_bias"], cfg)
     y, load = routed_experts(
         flat, idx, w,
         {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]},

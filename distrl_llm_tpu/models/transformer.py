@@ -67,6 +67,7 @@ _TARGET_STREAM = {
     "wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 4, "w_up": 5, "w_down": 6,
     "wkv_a": 7, "wkv_b": 8,  # latent attention (models/hybrid.py)
     "w_in": 9, "w_out": 10,  # a Mamba layer's two projections
+    "wv1": 11, "wv2": 12,  # compressed convolutional attention's two value halves
 }
 
 
@@ -422,7 +423,9 @@ DECODE_VIEW_KEYS = frozenset(("wq", "wk", "wv", "wo"))
 # The kinds whose census differs: of a latent layer's projections ``wq`` alone
 # shows the two operations; its ``wo``, ``wkv_a`` and ``wkv_b`` show neither
 # and stay stacked.
-_KIND_VIEW_KEYS = {"latent": frozenset(("wq",)), "latent_moe": frozenset(("wq",))}
+_KIND_VIEW_KEYS = {"latent": frozenset(("wq",)), "latent_moe": frozenset(("wq",)),
+                   # compressed convolutional attention: v is two halves
+                   "cca": frozenset(("wq", "wk", "wv1", "wv2", "wo"))}
 
 
 def _slice_layer(stacked: Params, i: int) -> Params:

@@ -50,7 +50,7 @@ Params = dict[str, Any]
 # layer weights whose OUT dim is tp-sharded (column parallel)
 # (wz, wg: the output gate of a sparse or lightning layer and of a gated
 # softmax layer, models/hybrid.py)
-_COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up", "w_in"}
+_COL = {"wq", "wk", "wv", "wz", "wg", "w_gate", "w_up", "w_in", "wv1", "wv2"}
 # layer weights whose IN dim is tp-sharded (row parallel)
 _ROW = {"wo", "w_down", "w_out"}
 # a latent-attention / expert layer's own leaves: whole on every chip
@@ -60,7 +60,14 @@ _REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
                # leaves (models/hybrid.py): small beside the experts
                "wq_a", "w_index_q", "w_index_k", "w_index_w", "b_index_k",
                # a power-retention layer's log-decay: one column a KV head
-               "w_decay", "b_decay"}
+               "w_decay", "b_decay",
+               # compressed convolutional attention's convolutions and key
+               # temperature, its MLP router and the residual's scales and
+               # shifts: small beside the experts
+               "conv0", "b_conv0", "conv1", "b_conv1", "k_temp", "router_down",
+               "b_router_down", "router_gamma", "router_w1", "b_router_w1",
+               "router_w2", "b_router_w2", "router_w3", "attn_res_scale",
+               "attn_res_shift", "mlp_res_scale", "mlp_res_shift"}
 
 
 def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:

@@ -203,6 +203,13 @@ MODEL_WINDOW_ATTN = "model/window_attn"
 MODEL_INDEX_SCORE = "model/index_score"
 MODEL_INDEX_SELECT = "model/index_select"
 MODEL_INDEXED_ATTN = "model/indexed_attn"
+# compressed convolutional attention (zaya, models/hybrid.py::_cca_mix): what
+# the mixer does before and after its page walk in all three modes: the two
+# causal convolutions over [q~ | k~], the q-k mean, the per-head norm and the
+# keys' temperature, RoPE, the value taken a token late, and the tail's
+# update. q, k, v, o stay ``model/attn_proj``, the page write
+# ``engine/kv_write``, the walk ``kernel/paged_attention`` / ``model/attn_core``
+MODEL_CCA_MIX = "model/cca_mix"
 # device scopes: the engines' step programs
 ENGINE_KV_WRITE = "engine/kv_write"
 ENGINE_SAMPLE = "engine/sample"
@@ -271,7 +278,7 @@ SCOPE_NAMES = (
     MODEL_MOE_ROUTER, MODEL_MOE_DISPATCH, MODEL_MOE_EXPERTS, MODEL_LATENT_ATTN,
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
     MODEL_SSM, MODEL_WINDOW_ATTN,
-    MODEL_INDEX_SCORE, MODEL_INDEX_SELECT, MODEL_INDEXED_ATTN,
+    MODEL_INDEX_SCORE, MODEL_INDEX_SELECT, MODEL_INDEXED_ATTN, MODEL_CCA_MIX,
 )
 
 

@@ -715,6 +715,61 @@ def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.08 * 1.5064e9
 
 
+def test_cca_decode_step_at_published_widths(chip, monkeypatch):
+    """``zaya1-8b-L20.rollout-reasoning-cca``'s decode step (192 rows, a table
+    of 21 pages, a rank-32 adapter) fed the decode view. Every layer's page walk
+    is the ONE ``paged_attention_native`` launch the ``kernel.*`` regexes look
+    for, at 2 KV heads and a group of 4; what compressed convolutional attention
+    adds round it is plain XLA over ``[192, 1280]`` rows. The forty pools and
+    the twenty tails (``[192, 2688]`` bf16) are donated and written in place: no
+    copy of a pool or a tail. No projection is copied or sliced (the view holds
+    q, k, the value's two halves and o), and no layer's sixteen experts are
+    sliced out of the stack: the grouped form indexes (layer, expert)."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    cfg = _cell_config("zaya1-8b-L20")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 192, 128, jnp.bfloat16
+    width = (2048 + 512) // page + 1
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pool = chip((2, 12 * 16 + rows * 5 + 8, page, 128), bf)
+    cache = {
+        "k": (pool,) * 20, "v": (pool,) * 20,
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+    assert [x.shape for x in cache["cca_tail"]] == [(rows, 2688)] * 20
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 20 and all("%paged_attention_native" in c for c in calls), calls[:2]
+    assert "bf16[192,2,4,128]" in calls[0], calls[0]  # rows, 2 KV heads, their groups of 4
+    entry = text[text.index("ENTRY "):]
+    held = ("bf16[2,1160,128,128]", "bf16[192,2688]")
+    copies = [line.strip()[:160] for line in entry.splitlines()
+              if " copy(" in line and any(shape in line.split("(")[0] for shape in held)]
+    assert not copies, copies
+    pools, tails = 40 * pool.size * 2, 20 * rows * 2688 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools + tails
+    sizes = {2048 * 1024, 2048 * 256, 2048 * 128}  # q and o, k, a half of the value
+    assert not _weight_sized_operations(text, sizes)
+    assert "bf16[16,2048,2048]" not in entry  # no layer's experts copied out whole
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 def _glm_cell():
     """Five layers at the published widths, 16 of 256 experts held, the index whole."""
     return _cell_config("glm-5-ep16-L5")
