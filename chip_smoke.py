@@ -266,7 +266,7 @@ def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
              "down": draw(kd, (experts, width, hidden))}
     idx = jnp.argsort(jax.random.uniform(ki, (tokens, experts)), axis=-1)[:, :per_token]
     w = jax.random.uniform(kw, (tokens, per_token), jnp.float32, 0.2, 1.0)
-    got, load = jax.jit(lambda h, idx, w: moe.routed_experts(
+    got, load, blocks = jax.jit(lambda h, idx, w: moe.routed_experts(
         h, idx.astype(jnp.int32), w, stack, n_experts=experts))(h, idx, w)
     comb = jnp.zeros((tokens, experts), jnp.float32).at[
         jnp.arange(tokens)[:, None], idx].set(w)
@@ -281,7 +281,7 @@ def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
     assert int(load.sum()) == tokens * per_token, "a token-expert pair was dropped"
     assert np.isfinite(err) and err < 5e-2, f"expert layer max|err| {err}"
     return {"max_abs_err": round(err, 5), "pairs": int(load.sum()),
-            "fullest_expert": int(load.max()),
+            "fullest_expert": int(load.max()), "blocks_run_laid": blocks.tolist(),
             "form": "dense" if tokens <= moe.DENSE_MAX_TOKENS else "grouped"}
 
 

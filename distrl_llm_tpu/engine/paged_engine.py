@@ -211,8 +211,9 @@ def _no_full_collection():
 def _count_mixer_stats(mixer) -> dict:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
     layers' blocks; ``mixer["moe_stats"]`` / ``["moe_routed"]``: the expert
-    layers' pairs, held here / chosen over all experts; ``mixer["latent_stats"]``:
-    absorbed attention's pages; ``mixer["power_stats"]``: the (live row, layer)
+    layers' pairs, held here / chosen over all experts; ``mixer["moe_blocks"]``:
+    the blocks their grouped form ran and laid, the prefill's too;
+    ``mixer["latent_stats"]``: absorbed attention's pages; ``mixer["power_stats"]``: the (live row, layer)
     power-retention states the decode steps read and wrote, filed as the bytes
     of S and z that is, a read and a write each; ``mixer["ssm_stats"]``: the
     (live row, layer) state-space states they read and wrote, filed as that
@@ -249,6 +250,7 @@ def _count_mixer_stats(mixer) -> dict:
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
         ("moe_routed", (ENGINE_MOE_PAIRS_ROUTED,)),
+        ("moe_blocks", (telemetry.ENGINE_MOE_BLOCKS_RUN, telemetry.ENGINE_MOE_BLOCKS_LAID)),
         ("latent_stats", (ENGINE_LATENT_PAGES_ATTENDED, ENGINE_LATENT_PAGES_READ)),
     ):
         if mixer is not None and key in mixer:
@@ -427,6 +429,13 @@ HYBRID_PREFILL_SEGMENT = 1024
 HYBRID_PREFILL_STAGES = 2
 
 
+#: the round's counters (``models/hybrid.py::init_mixer_state``) that a
+#: prefill's segments add to and hand on to the decode state: the blocks of the
+#: experts' grouped form, which a decode step of up to ``moe.DENSE_MAX_TOKENS``
+#: rows never lays
+PREFILL_COUNTERS = ("moe_blocks",)
+
+
 def _hybrid_segments(prompt_pages: int, page_size: int) -> tuple[int, int]:
     """(tokens of one segment, segments) of a hybrid model's prefill: the
     most whole pages that divide the prompt's and hold no more than
@@ -523,7 +532,9 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
 
     Returns ``(k tiles, v tiles, logits, real_len, mixer)``: ``mixer`` is each
     PROMPT's state after its last real token and its pooled keys, which a
-    candidate that aliases the prompt's pages is also handed at admission."""
+    candidate that aliases the prompt's pages is also handed at admission, and
+    the round's counters as the segments leave them (``PREFILL_COUNTERS``: the
+    decode state starts from them; the others count decode steps alone)."""
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.ops.linear import linear
 
@@ -554,17 +565,18 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
         # every row's states start alike: these are the sorted rows' too
         rows = (_row_states(mixer),
                 jnp.zeros((b, cfg.hidden_size), params["final_norm"].dtype))
+        counted = _prefill_counters(mixer)
 
     def one_segment(j, carry):
         """Segment ``j`` of the stage's rows: the first ``n`` sorted ones."""
-        pools, (states, hidden) = carry
+        pools, (states, hidden), counted = carry
         n = hidden.shape[0]
         start = j * seg
         x, out = forward(
             params, cfg, jax.lax.dynamic_slice_in_dim(ids[:n], start, seg, axis=1),
             attention_mask=jax.lax.dynamic_slice_in_dim(mask[:n], start, seg, axis=1),
             lora=lora, lora_scale=lora_scale, attn_impl=attn_impl, page_size=page_size,
-            kv_cache={**pools, **states, "lengths": sorted_len[:n],
+            kv_cache={**pools, **states, **counted, "lengths": sorted_len[:n],
                       "page_indices": table[:n], "segment_start": start},
             # the row's last real token, if it lies in this segment
             logits_positions=jnp.clip(last[:n] - start, 0, seg - 1),
@@ -573,11 +585,13 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
         here = (last[:n] >= start) & (last[:n] < start + seg)
         hidden = jnp.where(here[:, None], x[:, 0], hidden)
         return ({name: out[name] for name in pools},
-                ({name: out[name] for name in states}, hidden))
+                ({name: out[name] for name in states}, hidden),
+                {name: out[name] for name in counted})
 
     ended = []  # the rows each stage drops as it ends, the last stage's all
     for (first, end), keep in zip(trips, (*sizes[1:], 0)):
-        pools, rows = jax.lax.fori_loop(first, end, one_segment, (pools, rows))
+        pools, rows, counted = jax.lax.fori_loop(
+            first, end, one_segment, (pools, rows, counted))
         ended.append(jax.tree_util.tree_map(lambda x: x[keep:], rows))
         rows = jax.tree_util.tree_map(lambda x: x[:keep], rows)
     states, hidden = ended[0]
@@ -589,7 +603,13 @@ def _paged_prefill_hybrid(params, lora, prompt_ids, prompt_mask, *,
     with jax.named_scope(telemetry.MODEL_HEAD):
         head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
         logits = linear(hidden, head).astype(jnp.float32)
-    return pools["k"], pools["v"], logits, real_len, {**mixer, **states}
+    return pools["k"], pools["v"], logits, real_len, {**mixer, **states, **counted}
+
+
+def _prefill_counters(mixer) -> dict:
+    """Of the round's counters in a mixer state, those a prefill adds to
+    (``PREFILL_COUNTERS``); nothing for a model that has none."""
+    return {name: mixer[name] for name in PREFILL_COUNTERS if name in (mixer or {})}
 
 
 def _row_states(mixer) -> dict:
@@ -870,7 +890,8 @@ def _paged_decode_chunk(params, lora, state: _PagedDecodeState, rng,
     return scan_steps_guarded(run, state, chunk)
 
 
-def _refill_init(prompt_k, prompt_v, *, b: int, r_slots: int, total: int,
+def _refill_init(prompt_k, prompt_v, counted=None,
+                 *, b: int, r_slots: int, total: int,
                  max_steps: int, vocab: int, pool_pages: int,
                  prompt_pages: int, private_pages: int, pad_id: int,
                  shared_pages: int | None = None, cfg: ModelConfig | None = None,
@@ -890,14 +911,18 @@ def _refill_init(prompt_k, prompt_v, *, b: int, r_slots: int, total: int,
     ``shared_pages`` overrides the static prompt-region size (None = the
     historical ``b·prompt_pages``): continuous admission passes 0 — prompt
     chains are pool-allocated, ``prompt_k``/``prompt_v`` arrive as 0-page
-    tiles, and the scratch page is physical page 0."""
+    tiles, and the scratch page is physical page 0.
+
+    ``counted`` is what a hybrid model's prefill left of the round's counters
+    (``PREFILL_COUNTERS``): the state's start from it, not from zero."""
     total_shared = b * prompt_pages if shared_pages is None else shared_pages
     width = prompt_pages + private_pages
     mixer = None
     if cfg is not None and cfg.hybrid:  # what a slot holds beside K/V pages
         from distrl_llm_tpu.models.hybrid import init_mixer_state
 
-        mixer = init_mixer_state(cfg, r_slots, width * page_size, cache_dtype)
+        mixer = {**init_mixer_state(cfg, r_slots, width * page_size, cache_dtype),
+                 **(counted or {})}
 
     return _RefillState(
         step=jnp.zeros((), jnp.int32),
@@ -2864,7 +2889,9 @@ class PagedGenerationEngine(LoraMailbox):
         else:
             write_ceiling_extra = 0
             state = self._refill_init(
-                prompt_k, prompt_v, b=b, r_slots=r_slots, total=total,
+                prompt_k, prompt_v,
+                _prefill_counters(prompt_mixer[0] if prompt_mixer else None),
+                b=b, r_slots=r_slots, total=total,
                 max_steps=max_steps, vocab=self.cfg.vocab_size,
                 pool_pages=pool_pages, shared_pages=shared_static,
             )

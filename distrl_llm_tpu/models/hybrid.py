@@ -54,13 +54,16 @@ shared expert, which is this module's ``_mlp_half``)::
 
 Its cache has ``k`` alone: one latent array ``[pages, page, latent_row]`` a
 layer (``rank + rope`` values and zeros to whole 128-lane tiles), no kv-head
-axis and no V (``v`` is an empty tuple), and two counters of [2] int32, summed
+axis and no V (``v`` is an empty tuple), and three counters of [2] int32, summed
 over layers and decode steps: ``moe_stats`` (token-expert pairs computed, the
-fullest expert's) and ``latent_stats`` (the (row, page) pairs absorbed
-attention covered, the pages it fetched: a page that a group's rows share is
-fetched once, ``ops/latent_attention.py``). With ``cfg.q_lora_rank`` the query
-is ``q = W_qb RMSNorm(W_qa h)``, and an expert layer is told which experts this
-program holds (``cfg.held_experts``) like the families below.
+fullest expert's), ``moe_blocks`` (the blocks of the experts' grouped form
+that ran and that were laid, ``models/moe.py``: every expert family's, and the
+one counter a prefill's segments carry too) and ``latent_stats`` (the (row,
+page) pairs absorbed attention covered, the pages it fetched: a page that a
+group's rows share is fetched once, ``ops/latent_attention.py``). With
+``cfg.q_lora_rank`` the query is ``q = W_qb RMSNorm(W_qa h)``, and an expert
+layer is told which experts this program holds (``cfg.held_experts``) like the
+families below.
 
 **A learned index over tokens** (``cfg.index_topk``: ``glm_moe_dsa``, GLM-5)
 stands beside latent attention in every layer (``ops/token_index.py``)::
@@ -455,7 +458,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     Mamba layer, a tail per compressed-convolutional layer, the round's
     counters. The entries named in ``ROW_STATES`` are tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counters
-        state = {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32)}
+        state = {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
+                 "moe_blocks": jnp.zeros((2,), jnp.int32)}
         if cfg.held_experts is not None:  # the pairs chosen over ALL experts
             state["moe_routed"] = jnp.zeros((1,), jnp.int32)
         state["index_stats" if cfg.index_topk else "latent_stats"] = jnp.zeros(
@@ -467,6 +471,7 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
             "cca_tail": tuple(jnp.zeros((rows, cfg.cca_tail_dim), cache_dtype)
                               for _ in range(cfg.num_layers)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_blocks": jnp.zeros((2,), jnp.int32),
         }
     if cfg.delta_moe:
         h, d, n = cfg.delta_heads, cfg.delta_head_dim, cfg.kind_count("delta")
@@ -477,6 +482,7 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                 jnp.zeros((rows, cfg.delta_conv_size - 1, 3 * cfg.delta_dim), cache_dtype)
                 for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_blocks": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
         }
     if cfg.window_moe:
@@ -487,6 +493,7 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
             "win_k": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
             "win_v": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_blocks": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
             "window_stats": jnp.zeros((2,), jnp.int32),
         }
@@ -702,9 +709,10 @@ def _merge(x, y, p, half: str):
 def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale, carried=None):
     """An expert layer's second half: the routed experts HELD here
     (``cfg.held_experts``; the router scores them all) beside the shared
-    expert. Returns (x, the layer's [2] stats). With ``carried`` (the MLP
-    router's value from the layer before, ``route_mlp``) the router reads it
-    and what is returned in ``x``'s place is ``(x, the value to hand on)``."""
+    expert. Returns (x, the layer's [4] stats: ``moe_half``). With ``carried``
+    (the MLP router's value from the layer before, ``route_mlp``) the router
+    reads it and what is returned in ``x``'s place is ``(x, the value to hand
+    on)``."""
     with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
     if carried is None:
@@ -1427,7 +1435,10 @@ def forward_hybrid(
     new = {name: list(kv_cache[name]) for name in ("k", "v", *ROW_STATES)
            if name in kv_cache}
     stats = kv_cache.get("sel_stats")
-    moe_stats = kv_cache.get("moe_stats")
+    # an expert layer's four (``moe_half``): the pairs' two, the blocks' two; a
+    # prefill's cache carries the blocks alone
+    moe_stats = (jnp.zeros((4,), jnp.int32)
+                 if "moe_stats" in kv_cache or "moe_blocks" in kv_cache else None)
     at = dict.fromkeys(cfg.layer_kinds, 0)
     held_at = dict.fromkeys(_MIXER_CACHE, 0)  # a mixer's layers, whatever follows them
     for i, kind in enumerate(cfg.layer_kinds):
@@ -1484,8 +1495,9 @@ def forward_hybrid(
     out = {**kv_cache, **{name: tuple(vals) for name, vals in new.items()}}
     if stats is not None:
         out["sel_stats"] = stats
-    if moe_stats is not None:
-        out["moe_stats"] = moe_stats
+    for name, part in (("moe_stats", slice(0, 2)), ("moe_blocks", slice(2, 4))):
+        if name in kv_cache:
+            out[name] = kv_cache[name] + moe_stats[part]
     if "moe_routed" in kv_cache:  # the router's choices over ALL experts: live tokens x k a layer
         live = b * s if env.get("alive") is None else env["alive"].sum() * s
         expert_layers = sum(1 for k in cfg.layer_kinds if cfg.layer_ffn(k) == "experts")

@@ -23,7 +23,14 @@ choice), each timed on the v5e at the published widths before it was kept
   blocks: at most one block of padding an expert, whatever the imbalance); a
   ``lax.scan`` over the blocks multiplies each by its expert's three matrices,
   picked from the stack by a dynamic index that fuses into the products; the
-  results go back by one gather and a weighted sum over k. No scatter-add: the
+  results go back by one gather and a weighted sum over k. The scan steps
+  over ``pairs // block_rows + groups`` blocks, enough for any imbalance, and a
+  block does work only if it holds a pair of an expert held HERE (a
+  ``lax.cond`` a block, in forward and in reverse mode alike: the live branch
+  gathers the block's own rows and multiplies them, the other returns zeros
+  that no pair reads). The pairs held elsewhere sort last, so the live blocks
+  are a prefix: an eighth or a sixteenth of the blocks where a program holds
+  16 of 128 or of 256 experts (``blocks`` says how many). No scatter-add: the
   combine is deterministic and its transpose cheap. ``lax.ragged_dot`` (XLA's
   native grouped kernel) read the same at 4,096 tokens and 1.6x slower at 64,
   carries no scope name into the trace, and copies a layer sliced from the
@@ -140,8 +147,10 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
                    *, n_experts: int, held=None, alive=None, layer=None):
     """``sum_k w_k E_idx_k(h)`` over the experts held. ``h [T, D]``, ``idx`` /
     ``w [T, k]``; ``experts = {"gate", "up" [n, D, F], "down" [n, F, D]}``.
-    Returns ``(y [T, D], load [n] int32)``: ``load`` counts the pairs each
-    held expert computed (of ``alive`` tokens, if given).
+    Returns ``(y [T, D], load [n] int32, blocks [2] int32)``: ``load`` counts
+    the pairs each held expert computed (of ``alive`` tokens, if given),
+    ``blocks`` the grouped form's blocks that ran and that were laid (zeros
+    from the dense form).
 
     With ``layer`` the stacks are ALL layers' ``[L, n, ...]``: the grouped
     form indexes (layer, expert) in one step, so no layer's experts are
@@ -165,7 +174,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
             y = jnp.einsum("etf,efd->etd", act, experts["down"])
         with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
             y = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32)).astype(h.dtype)
-        return y, load
+        return y, load, jnp.zeros((2,), jnp.int32)
     groups = n + (held is not None)  # the pairs of experts held elsewhere: one more
     rows_a, bm = t * k, block_rows(t * k, groups)
     blocks = rows_a // bm + groups
@@ -179,41 +188,52 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
         rank = jnp.zeros((rows_a,), jnp.int32).at[order].set(
             jnp.arange(rows_a, dtype=jnp.int32) - (jnp.cumsum(sizes) - sizes)[flat[order]])
         at = (ends - padded)[flat] + rank  # [T*k]
-        src = jnp.full((blocks * bm,), t, jnp.int32).at[at].set(
+        # the token of each padded row (a row of padding reads token 0: no pair reads it back)
+        src = jnp.zeros((blocks * bm,), jnp.int32).at[at].set(
             jnp.arange(rows_a, dtype=jnp.int32) // k)
-        rows = jnp.concatenate([h, jnp.zeros((1, h.shape[1]), h.dtype)])[src]
-        owner = jnp.minimum(
-            jnp.searchsorted(ends, jnp.arange(blocks) * bm, side="right"), n - 1)
+        first = jnp.arange(blocks) * bm
+        owner = jnp.minimum(jnp.searchsorted(ends, first, side="right"), n - 1)
         if layer is not None:
             owner = owner + layer * n
+        # the pairs held elsewhere sort LAST: the blocks of the pairs held here
+        # are a prefix, and the blocks after it (that group's, and what
+        # ``blocks`` allots beyond the groups' padded sizes) hold nothing
+        live = first < ends[n - 1]
     with jax.named_scope(telemetry.MODEL_MOE_EXPERTS):
         stacks = [experts[name].reshape(-1, *experts[name].shape[-2:])
                   for name in ("gate", "up", "down")]
+        nothing = jnp.zeros((bm, stacks[2].shape[-1]), jnp.result_type(h, *stacks))
 
-        @jax.checkpoint  # reverse mode keeps a block's rows, not its expert's matrices
+        def product(tokens, e):
+            with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
+                x = h[tokens]  # this block's rows, and no other block's
+            return _gated(x, *(stack[e] for stack in stacks))
+
+        @jax.checkpoint  # reverse mode keeps a block's tokens, not its rows or matrices
         def one(_, block):
-            x, e = block
-            return None, _gated(x, *(stack[e] for stack in stacks))
+            tokens, e, run = block
+            return None, jax.lax.cond(run, product, lambda *_: nothing, tokens, e)
 
-        _, y = jax.lax.scan(one, None, (rows.reshape(blocks, bm, -1), owner))
+        _, y = jax.lax.scan(one, None, (src.reshape(blocks, bm), owner, live))
     with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
         y = y.reshape(blocks * bm, -1)[at].reshape(t, k, -1)
         y = jnp.where((local < n)[..., None], y, 0)  # held elsewhere: nothing here
         y = jnp.einsum("tk,tkd->td", w, y.astype(jnp.float32)).astype(h.dtype)
-    return y, load
+    return y, load, jnp.stack([live.sum(dtype=jnp.int32), jnp.int32(blocks)])
 
 
 def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
              choice=None):
     """The routed part of an expert layer on ``h [..., D]`` (normed). Returns
-    ``(y like h, stats [2] int32)``: pairs computed, and the fullest expert's.
+    ``(y like h, stats [4] int32)``: pairs computed, the fullest expert's, and
+    the grouped form's blocks that ran and that were laid (``routed_experts``).
     ``p["experts_layer"]``, if there, says that ``p["experts_*"]`` are every
     layer's and which is this one (``routed_experts``). ``choice`` is ``(idx,
     w)`` where the caller's own router chose (``route_mlp``)."""
     lead = h.shape[:-1]
     flat = h.reshape(-1, h.shape[-1])
     idx, w = choice or route(flat, p["router"], p["e_score_bias"], cfg)
-    y, load = routed_experts(
+    y, load, blocks = routed_experts(
         flat, idx, w,
         {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]},
         n_experts=cfg.router_width, held=held, layer=p.get("experts_layer"),
@@ -221,4 +241,5 @@ def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
         alive=None if alive is None else jnp.repeat(
             alive, flat.shape[0] // alive.shape[0]),
     )
-    return y.reshape(*lead, -1), jnp.stack([load.sum(), load.max()])
+    return y.reshape(*lead, -1), jnp.concatenate(
+        [jnp.stack([load.sum(), load.max()]), blocks])

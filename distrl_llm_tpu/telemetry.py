@@ -131,6 +131,14 @@ ENGINE_WINDOW_PAGES_VISIBLE = "engine/window_pages_visible"    # counter
 # 100% the day a layer silently attends everything
 ENGINE_INDEX_TOKENS_ATTENDED = "engine/index_tokens_attended"  # counter
 ENGINE_INDEX_TOKENS_VISIBLE = "engine/index_tokens_visible"    # counter
+# routed experts' grouped form (models/moe.py::routed_experts: a prefill
+# segment's token-rows): blocks whose products ran (those that hold a pair of
+# an expert held here) and blocks the scan stepped over, summed over expert
+# layers and calls. Carried in ``mixer["moe_blocks"]`` through the prefill's
+# segments and the decode steps (whose dense form lays none) and filed at
+# readback; run / laid is the share of the laid blocks that did work
+ENGINE_MOE_BLOCKS_RUN = "engine/moe_blocks_run"    # counter
+ENGINE_MOE_BLOCKS_LAID = "engine/moe_blocks_laid"  # counter
 # what the learner's rematerialised layer scan keeps for the backward pass
 # (learner/remat.py), filed when a train step first meets a batch shape: how
 # many of the five named products of the frozen weights (q, k, v, the MLP's

@@ -61,6 +61,8 @@ def test_the_latent_and_expert_checks_hold_at_tiny_size(monkeypatch):
             key, tokens=tokens, hidden=64, width=32, experts=8, per_token=2)
         assert layer["pairs"] == 2 * tokens and layer["form"] == form
         assert layer["fullest_expert"] >= tokens // 4
+        run, laid = layer["blocks_run_laid"]  # the dense form lays no block
+        assert (laid > 0) == (form == "grouped") and run <= laid
 
 
 @pytest.mark.parametrize("heads", [

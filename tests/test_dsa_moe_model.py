@@ -122,11 +122,12 @@ def test_the_layers_and_what_a_token_keeps():
     assert PRESETS["tiny-latent-moe"].second_pool_shape(9, 8) is None
     assert PRESETS["tiny"].second_pool_shape(9, 8) == PRESETS["tiny"].page_pool_shape(9, 8)
     state = hybrid.init_mixer_state(CFG, 5, 64, jnp.bfloat16)
-    assert set(state) == {"lin", "pooled", "moe_stats", "moe_routed", "index_stats"}
+    assert set(state) == {
+        "lin", "pooled", "moe_stats", "moe_blocks", "moe_routed", "index_stats"}
     assert state["index_stats"].shape == (2,) and state["moe_routed"].shape == (1,)
     # Kimi-VL's shape holds what it held: no share, no index, its own counter
     assert set(hybrid.init_mixer_state(PRESETS["tiny-latent-moe"], 5, 64)) == {
-        "lin", "pooled", "moe_stats", "latent_stats"}
+        "lin", "pooled", "moe_stats", "moe_blocks", "latent_stats"}
     params = init_params(jax.random.PRNGKey(0), CFG)
     index = {"w_index_q", "w_index_k", "index_k_norm", "b_index_k", "w_index_w"}
     attention = {"attn_norm", "wq_a", "q_a_norm", "wq", "wkv_a", "kv_a_norm", "wkv_b", "wo",

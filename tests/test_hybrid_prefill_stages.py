@@ -213,9 +213,13 @@ def test_the_staged_prefill_is_each_row_alone(family, mix):
                     mine, want = mine[:max(windows, 0)], want[:max(windows, 0)]
                 close(mine, want, f"{name}, layer {layer}, row {row}")
         close(logits[row], want_logits, f"logits, row {row}")
-    # the round's counters pass through whole
-    for name in set(fresh) - set(hybrid.ROW_STATES):
+    # the round's counters pass through whole, but the one a prefill adds to:
+    # the blocks its segments' experts ran, of those they laid
+    for name in set(fresh) - set(hybrid.ROW_STATES) - set(paged_engine.PREFILL_COUNTERS):
         np.testing.assert_array_equal(np.asarray(mixer[name]), np.asarray(fresh[name]))
+    if "moe_blocks" in fresh:
+        run, laid = map(int, mixer["moe_blocks"])
+        assert 0 < run <= laid
 
 
 @pytest.mark.parametrize("family", FAMILIES)

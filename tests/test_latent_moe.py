@@ -627,7 +627,7 @@ def test_no_token_is_dropped_at_any_imbalance(weights, case, form, monkeypatch):
     else:
         idx = np.stack([rng.permutation(8)[:2] for _ in range(48)])
     w = jnp.asarray(rng.uniform(0.2, 1.5, (48, 2)), jnp.float32)
-    y, load = moe.routed_experts(
+    y, load, _ = moe.routed_experts(
         h, jnp.asarray(idx, jnp.int32), w, experts, n_experts=8)
     np.testing.assert_allclose(y, dense_experts(h, idx, w, experts), atol=2e-5)
     assert load.tolist() == np.bincount(idx.reshape(-1), minlength=8).tolist()

@@ -381,7 +381,8 @@ def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
     the trace: built on the device as a scatter into a constant, inside a
     ``while`` body it is not folded, and the TPU compiler aborts on it
     (``scatter_emitter.cc: operand_indices.size() == 1``; PR 36 found it in
-    Solar-Open2's prefill, here at the tiny preset's sizes)."""
+    Solar-Open2's prefill, here at the tiny preset's sizes). And the grouped
+    form's ``lax.cond`` a block stays a conditional inside that body (PR 59)."""
     from distrl_llm_tpu.models import moe
     from distrl_llm_tpu.models.configs import PRESETS
 
@@ -392,7 +393,7 @@ def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
         def body(total, x):
             y, stats = moe.moe_half(x, p, cfg, held=cfg.held_experts)
             return total + stats, y
-        return jax.lax.scan(body, jnp.zeros((2,), jnp.int32), h)
+        return jax.lax.scan(body, jnp.zeros((4,), jnp.int32), h)
 
     bf = jnp.bfloat16
     compiled = jax.jit(scanned).lower(chip((3, 256, 64), bf), {
@@ -400,6 +401,10 @@ def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
         "experts_gate": chip((2, 64, 32), bf), "experts_up": chip((2, 64, 32), bf),
         "experts_down": chip((2, 32, 64), bf)}).compile()
     assert "while" in compiled.as_text()
+    # 256 tokens a step are over ``DENSE_MAX_TOKENS``: the grouped form, whose
+    # blocks run behind a ``lax.cond`` each. It must reach the chip as a
+    # conditional: a select would run both branches, every block's products
+    assert " conditional(" in compiled.as_text()
 
 
 def test_delta_rule_decode_fragment_updates_the_state_in_place(chip, monkeypatch):
