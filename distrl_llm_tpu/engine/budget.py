@@ -58,7 +58,10 @@ def page_bytes(model_cfg, page_size: int, kv_quant: str = "none") -> int:
     """HBM bytes one KV page costs across the layers that KEEP pages
     (``paged_layers``: all of a dense or latent model's, the sparse or softmax
     layers of one whose layers differ in kind, none of a power-retention
-    model's, whose page costs 0 bytes); k + v, or for a latent page one row a
+    model's, whose page costs 0 bytes); k + v, each at its own width
+    (``key_row``: ``head_dim`` in whole lane tiles where it is wider than one;
+    ``value_head_dim``) over the paged layers' KV heads, or for a
+    latent page one row a
     token, ``latent_dim`` values in ``latent_row`` lanes, one array a layer, no
     V beside it and no kv-head factor, and where the model has a learned index
     a token's index key (``index_head_dim`` values) in a second array."""
@@ -66,13 +69,12 @@ def page_bytes(model_cfg, page_size: int, kv_quant: str = "none") -> int:
     if model_cfg.latent:
         index = model_cfg.index_head_dim if model_cfg.index_topk else 0
         return page_size * (model_cfg.latent_row + index) * 2 * layers
-    per_layer_one = model_cfg.num_kv_heads * page_size * model_cfg.head_dim
+    tokens = model_cfg.num_kv_heads * page_size  # a KV head's, K's and V's alike
+    values = tokens * (model_cfg.key_row + model_cfg.value_head_dim)
     if kv_quant == "int8":
-        # int8 payload + f32 per-token absmax scales [K, P, ps, 1]
-        one = per_layer_one * 1 + model_cfg.num_kv_heads * page_size * 4
-    else:
-        one = per_layer_one * 2  # bf16
-    return one * 2 * layers
+        # int8 payload + f32 per-token absmax scales [K, P, ps, 1], K's and V's
+        return (values + 2 * tokens * 4) * layers
+    return values * 2 * layers  # bf16
 
 
 def slot_state_bytes(model_cfg, max_tokens: int) -> int:

@@ -621,11 +621,25 @@ def _row_states(mixer) -> dict:
     return {name: mixer[name] for name in ROW_STATES if name in mixer}
 
 
-def _file_slot_state(mixer) -> dict:
-    """File the bytes a decode state's row states hold as the gauge
-    ``engine/slot_state_bytes``, and return them as the round's span and
-    ``last_round_stats`` say them: ``{"slot_state_bytes": n}``; nothing for a
-    model that has no such state."""
+def _cache_token_bytes(k_pages, v_pages) -> int:
+    """Bytes ONE more token of context costs a slot, summed over the layers
+    that keep pages, read off the pools' own shapes, K's and V's apart: an
+    array ``[.., pages, page, width]`` holds its leading axes x ``width``
+    values a token (int8 pages: the weights and their scales)."""
+    return sum(
+        int(np.prod(x.shape[:-3], dtype=np.int64)) * x.shape[-1] * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves((k_pages, v_pages)))
+
+
+def _file_slot_state(mixer, k_pages=(), v_pages=()) -> dict:
+    """File what a round's decode state costs, tracing on or off, nothing
+    fetched: ``engine/cache_token_bytes``, what one more token of context costs
+    a slot in the pools ``k_pages`` / ``v_pages`` (every paged family), and the
+    bytes the decode state's row states hold as ``engine/slot_state_bytes``,
+    returned as the round's span and ``last_round_stats`` say them:
+    ``{"slot_state_bytes": n}``; nothing for a model that has no such state."""
+    telemetry.gauge_set(telemetry.ENGINE_CACHE_TOKEN_BYTES,
+                        float(_cache_token_bytes(k_pages, v_pages)))
     if mixer is None:
         return {}
     held = pool_nbytes(_row_states(mixer))
@@ -2418,7 +2432,7 @@ class PagedGenerationEngine(LoraMailbox):
             pps=self.prompt_pages + self.private_pages,
             head_dim=self.cfg.head_dim, page_size=self.page_size,
             kv_itemsize=1 if quantized else jnp.dtype(self.cache_dtype).itemsize,
-            quantized=quantized,
+            quantized=quantized, v_head_dim=self.cfg.value_head_dim,
         )
 
     def _verify_dispatch_choice(self, draft_len: int | None = None):
@@ -4093,7 +4107,9 @@ class PagedGenerationEngine(LoraMailbox):
             if c < total:
                 mark_finished(int(c))
         alive_h = int(np.asarray(state.alive_steps))
-        slot_state = _file_slot_state(getattr(state, "mixer", None))
+        slot_state = _file_slot_state(
+            getattr(state, "mixer", None), getattr(state, "k_pages", ()),
+            getattr(state, "v_pages", ()))
         state_said = {**_count_mixer_stats(getattr(state, "mixer", None)), **slot_state}
         if cache_on:
             # park every resident cached page host-side: device page ids
@@ -4396,7 +4412,7 @@ class PagedGenerationEngine(LoraMailbox):
             prompt_mixer=prompt_mixer[0] if prompt_mixer else None,
             n=n, b=b, max_steps=max_steps,
         )
-        slot_state = _file_slot_state(state.mixer)
+        slot_state = _file_slot_state(state.mixer, state.k_pages, state.v_pages)
 
         temperature = jnp.asarray(sampling.temperature, jnp.float32)
         top_p = jnp.asarray(sampling.top_p, jnp.float32)

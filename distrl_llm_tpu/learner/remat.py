@@ -31,7 +31,8 @@ def kept_products(cfg: ModelConfig, *, tokens: int, itemsize: int,
     value a byte is the same for all five (the product's own operations over
     its output's bytes), so the order sets only the grain. Arithmetic alone:
     nothing is compiled or run to decide."""
-    width = {"wq": cfg.q_dim, "wk": cfg.kv_dim, "wv": cfg.kv_dim,
+    width = {"wq": cfg.q_dim, "wk": cfg.kv_dim,
+             "wv": cfg.num_kv_heads * cfg.value_head_dim,
              "w_gate": cfg.intermediate_size, "w_up": cfg.intermediate_size}
     names: tuple[str, ...] = ()
     spent = 0

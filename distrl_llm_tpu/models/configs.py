@@ -75,6 +75,18 @@ second half of a layer is read from ``mlp_layer_types`` A LAYER (``mlp_types``):
 carries the suffix ``_dense`` (its stack has other leaves). ``sliding_window``
 is then the ring's length and refuses nothing.
 
+A second window family (``mimo_v2_flash``, MiMo-V2-Flash) runs through the same
+two mixers with what ITS configuration states, each a field and none a second
+code path: KV heads a KIND (``num_kv_heads`` in the "softmax" layers and their
+pages, ``window_kv_heads`` in the "window" layers and their rings), keys and
+values of two widths (``head_dim`` for q and k, ``v_head_dim`` for v, so
+``W_o`` reads ``num_heads x v_head_dim``), RoPE on the first ``rotary_dim``
+values of a head in BOTH kinds with a base a kind (``rope_theta``,
+``window_rope_theta``; ``attn_use_rope`` says that the "softmax" layers rotate),
+a value scaled by ``value_scale`` before it is kept, one learned sink logit a
+query head in a window layer's softmax (``window_sink``: a column of the
+denominator whose value is nothing), and no q/k norm (``qk_norm`` false).
+
 Compressed convolutional attention with an MLP router (``zaya``, ZAYA1-8B) is
 one kind, "cca", in every layer (published name ``hybrid``): softmax attention
 that runs WHOLE in a latent of ``num_heads + num_kv_heads`` heads below the
@@ -111,6 +123,11 @@ MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
 DENSE_FFN = "_dense"
 
 
+#: lanes of a tile's minor dimension on the chip: what ``ModelConfig.key_row``
+#: rounds a wide key up to (the tests name a smaller one to reach it at their size)
+KEY_ROW_LANES = 128
+
+
 def mixer_of(kind: str) -> str:
     """A layer kind's MIXER, whatever its second half is."""
     return kind.removesuffix(DENSE_FFN)
@@ -118,6 +135,7 @@ def mixer_of(kind: str) -> str:
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
     "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa", "zaya",
+    "mimo_v2_flash",
 )
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
@@ -229,6 +247,13 @@ class ModelConfig:
     cca_time1: int = 0  # taps of the convolution grouped by head after it
     rotary_dim: int = 0  # values of a head RoPE rotates, its first; 0 = all
     router_hidden_size: int = 0  # width of the router's MLP and of what it hands on
+    # ---- what a window family's two mixers may state apart (mimo_v2_flash;
+    # module docstring). With ``v_head_dim`` (the VALUE head's width outside the
+    # latent family; 0 = ``head_dim``), ``rotary_dim`` and ``attn_use_rope``
+    window_kv_heads: int = 0  # KV heads of a "window" layer; 0 = num_kv_heads
+    window_rope_theta: float = 0.0  # RoPE's base in a "window" layer; 0 = rope_theta
+    window_sink: bool = False  # one learned sink logit a query head, window layers
+    value_scale: float = 1.0  # v = value_scale * (h W_v), before it is cached
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
@@ -415,20 +440,21 @@ class ModelConfig:
         return sum(self.mixer_count(m) for m in ("sparse", "softmax", "cca"))
 
     def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
-        """Shape of one layer's page array: K beside V ``[K, pages, page,
-        head_dim]``, or one latent array ``[pages, page, latent_row]`` with no
-        kv-head axis (and no V array beside it), ``latent_row`` lanes a token."""
+        """Shape of one layer's page array: K ``[K, pages, page, key_row]``
+        (``head_dim`` lanes a token unless that is a tile and a half), or one
+        latent array ``[pages, page, latent_row]`` with no kv-head axis (and no
+        V array beside it), ``latent_row`` lanes a token."""
         if self.latent:
             return (pages, page_size, self.latent_row)
-        return (self.num_kv_heads, pages, page_size, self.head_dim)
+        return (self.num_kv_heads, pages, page_size, self.key_row)
 
     def second_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...] | None:
         """Shape of the array a layer keeps in the pool's SECOND slot, under
-        the same page table: V like K; a latent layer's index keys ``[pages,
+        the same page table: V beside K, ``value_head_dim`` wide; a latent layer's index keys ``[pages,
         page, index_head_dim]`` where the model has an index; None where a
         latent layer keeps its rows alone (the slot is an empty tuple)."""
-        if not self.latent:
-            return self.page_pool_shape(pages, page_size)
+        if not self.latent:  # V at its own width, under K's table
+            return (self.num_kv_heads, pages, page_size, self.value_head_dim)
         return (pages, page_size, self.index_head_dim) if self.index_topk else None
 
     @property
@@ -521,6 +547,45 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def value_head_dim(self) -> int:
+        """Width of a VALUE head of a GQA layer: ``head_dim`` unless the
+        configuration states another (``v_head_dim``)."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def o_dim(self) -> int:
+        """What a GQA layer's ``W_o`` reads: every query head's value."""
+        return self.num_heads * self.value_head_dim
+
+    @property
+    def key_row(self) -> int:
+        """Lanes a cached KEY takes in a page or a ring: ``head_dim``, rounded
+        up to whole 128-lane tiles where it is wider than one tile and no
+        multiple of it (192 -> 256), the rest zeros. The TPU's row-major tiling
+        pads the row to that in any case, and at a width that is no multiple of
+        128 the compiler keeps such an array TOKEN-MINOR at every program's
+        boundary, which the paged launch (row-major operands) and the ring's
+        write would pay for with a copy of the pool and of each ring in and out
+        a step (as ``latent_row``; tests/test_tpu_compile.py). A width within
+        one tile (64, 128) is kept as it is."""
+        lanes = KEY_ROW_LANES
+        if self.head_dim <= lanes or self.head_dim % lanes == 0:
+            return self.head_dim
+        return -(-self.head_dim // lanes) * lanes
+
+    def kv_heads_of(self, mixer: str) -> int:
+        """KV heads of a layer whose mixer is ``mixer``: a "window" layer's own
+        count where the configuration states one."""
+        return (self.window_kv_heads if mixer == "window" else 0) or self.num_kv_heads
+
+    def ring_shapes(self, rows: int) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """A window layer's two rings for ``rows`` slots: K ``[B, K_w, W,
+        key_row]`` and V ``[B, K_w, W, value_head_dim]``."""
+        kv = self.kv_heads_of("window")
+        return ((rows, kv, self.sliding_window, self.key_row),
+                (rows, kv, self.sliding_window, self.value_head_dim))
 
     def check_within_window(self, key_span: int) -> None:
         """Raise if attending over ``key_span`` keys would exceed the
@@ -624,15 +689,20 @@ class ModelConfig:
     def _window_moe_param_count(self, experts: int) -> int:
         """Matmul parameters of an ``exaone_moe`` model with ``experts`` routed
         experts counted an expert layer (``_delta_moe_param_count`` says
-        which): q, k, v, o in every layer, then the layer's own second half."""
+        which): q, k, v, o in every layer (k and v at the KV heads of the
+        layer's KIND, v and o at the value's width), then the layer's own
+        second half."""
         d = self.hidden_size
-        attn = 2 * d * self.q_dim + 2 * d * self.kv_dim
+        attn = sum(
+            d * self.q_dim + self.o_dim * d
+            + d * self.kv_heads_of(m) * (self.head_dim + self.value_head_dim)
+            for m in map(mixer_of, self.layer_kinds))
         moe = 3 * d * (
             experts * self.moe_intermediate_size + self.shared_expert_size
         ) + d * self.router_width
         dense = sum(1 for k in self.layer_kinds if self.layer_ffn(k) == "dense")
         return (
-            self.num_layers * attn + dense * 3 * d * self.intermediate_size
+            attn + dense * 3 * d * self.intermediate_size
             + (self.num_layers - dense) * moe + d * self.vocab_size
         )
 
@@ -692,8 +762,9 @@ class ModelConfig:
             attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
                 7.0 * self.kind_count("mamba") * self.mamba_inner * self.mamba_d_state)
         if self.window_moe:
-            # a window layer's token attends at most ``sliding_window`` keys
-            attn = 4.0 * self.q_dim * (
+            # a window layer's token attends at most ``sliding_window`` keys;
+            # a key costs a query head its q.k and its p v, each at its width
+            attn = 2.0 * self.num_heads * (self.head_dim + self.value_head_dim) * (
                 self.mixer_count("softmax") * mean_kv_len
                 + self.mixer_count("window") * min(mean_kv_len, self.sliding_window))
         return 2.0 * self.matmul_param_count + attn
@@ -717,7 +788,7 @@ class ModelConfig:
         if self.mamba:
             return "jamba"
         if self.window_moe:
-            return "exaone_moe"
+            return "mimo_v2_flash" if self.window_sink else "exaone_moe"
         if self.cca:
             return "zaya"
         if self.hybrid:
@@ -808,6 +879,8 @@ class ModelConfig:
             hybrid = _window_moe_fields(get)
         if mt == "zaya":
             hybrid = _cca_fields(get)
+        if mt == "mimo_v2_flash":
+            hybrid = _swa_sink_moe_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -821,7 +894,7 @@ class ModelConfig:
             num_kv_heads=get("num_key_value_heads", num_heads),
             head_dim=head_dim,
             rope_theta=hybrid.pop("rope_theta", get("rope_theta", 10000.0)),
-            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            rms_norm_eps=hybrid.pop("rms_norm_eps", get("rms_norm_eps", 1e-6)),
             attention_bias=mt == "qwen2" or bool(get("attention_bias", False)),
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
             max_position_embeddings=get("max_position_embeddings", 32768),
@@ -954,6 +1027,91 @@ def _window_moe_fields(get) -> dict:
         moe_intermediate_size=int(get("moe_intermediate_size") or 0),
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _swa_sink_moe_fields(get) -> dict:
+    """The ``mimo_v2_flash`` keys as ``ModelConfig`` fields: the mixers from
+    ``hybrid_layer_pattern`` (0 a full layer, 1 a window layer), each layer's
+    second half from ``moe_layer_freq`` (0 the dense MLP, 1 routed experts),
+    the full layers' KV heads and the window layers' (``num_key_value_heads``,
+    ``swa_num_key_value_heads``), q/k and v widths (``head_dim``,
+    ``v_head_dim``), the rotated share of a head and a base a kind
+    (``partial_rotary_factor``, ``rope_theta``, ``swa_rope_theta``), the
+    window layers' sink, the value's scale, the experts under DeepSeek-V3's
+    router keys and a ``share`` as ``_delta_moe_fields`` reads one. The norm's
+    eps is ``layernorm_epsilon``. What the published file does not state (the
+    readings under ``assumed`` in ``perfbench/configs/mimo-v2-flash-ep16-L7.json``)
+    is fixed in the program. The multi-token-prediction layers have no key
+    here and are not instantiated. A variant that is not implemented is
+    REFUSED by key."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"mimo_v2_flash with {key}={get(key)!r} is not supported: {why}")
+
+    pattern, freq = get("hybrid_layer_pattern"), get("moe_layer_freq")
+    window = get("sliding_window")
+    if not pattern or not freq or len(pattern) != len(freq):
+        raise ValueError(
+            "model_type 'mimo_v2_flash' needs its hybrid_layer_pattern and "
+            "moe_layer_freq lists, one entry a published layer each")
+    if set(pattern) - {0, 1}:
+        refuse("hybrid_layer_pattern", "a layer is 0 (full attention) or 1 (window)")
+    if set(freq) - {0, 1}:
+        refuse("moe_layer_freq", "a layer's second half is 0 (dense) or 1 (experts)")
+    heads = get("num_attention_heads")
+    if get("swa_num_attention_heads", heads) != heads:
+        refuse("swa_num_attention_heads", "the window layers have the full layers' "
+               "query heads (one W_o width); another count is not implemented")
+    for key, full in (("swa_head_dim", "head_dim"), ("swa_v_head_dim", "v_head_dim")):
+        if get(key, get(full)) != get(full):
+            refuse(key, f"the window layers' widths are the full layers' ({full} "
+                   f"{get(full)}); a width a kind is not implemented")
+    if get("add_full_attention_sink_bias", False):
+        refuse("add_full_attention_sink_bias", "the sink is a column of a window "
+               "layer's softmax over its ring; the paged launch of a full layer "
+               "has no such column")
+    for key in ("sliding_window_size", "attention_chunk_size"):
+        if get(key, window) != window:
+            refuse(key, f"it must equal sliding_window {window}, which is what is "
+                   "read; chunked attention is not implemented")
+    if get("n_shared_experts") is not None:
+        refuse("n_shared_experts", "an expert layer is the routed experts alone; a "
+               "shared expert beside them is not implemented for this family")
+    _refuse_router_variants(get, refuse)
+    if str(get("topk_method", "noaux_tc")) != "noaux_tc":
+        refuse("topk_method", "the router chooses by score plus "
+               "e_score_correction_bias (noaux_tc) only")
+    if get("attention_bias", False):
+        refuse("attention_bias", "q, k, v and o carry no bias")
+    if get("rope_scaling") is not None:
+        refuse("rope_scaling", "q and k are rotated by plain RoPE at rope_theta / "
+               "swa_rope_theta; scaled positions are not implemented")
+    if str(get("hidden_act", "silu")) != "silu":
+        refuse("hidden_act", "the gated MLPs and the experts are SiLU's")
+    head_dim = int(get("head_dim"))
+    held = int(get("n_routed_experts") or 0)
+    published = dict((get("share") or {}).get("published") or {})
+    width = int(published.get("n_routed_experts", held))
+    scale = get("routed_scaling_factor")
+    return dict(
+        mixer_types=tuple("sliding_attention" if p else "full_attention" for p in pattern),
+        mlp_types=tuple("sparse" if f else "dense" for f in freq),
+        attn_use_rope=True,  # the full layers rotate too, at their own base
+        rotary_dim=int(head_dim * float(get("partial_rotary_factor", 1.0))),
+        rope_theta=float(get("rope_theta", 10000.0)),
+        window_rope_theta=float(get("swa_rope_theta", get("rope_theta", 10000.0))),
+        window_kv_heads=int(get("swa_num_key_value_heads", get("num_key_value_heads"))),
+        v_head_dim=int(get("v_head_dim", head_dim)),
+        window_sink=bool(get("add_swa_attention_sink_bias", False)),
+        value_scale=float(get("attention_value_scale") or 1.0),
+        rms_norm_eps=float(get("layernorm_epsilon", get("rms_norm_eps", 1e-6))),
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
+        experts_per_token=int(get("num_experts_per_tok") or 0),
+        moe_intermediate_size=int(get("moe_intermediate_size") or 0),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=1.0 if scale is None else float(scale),
     )
 
 
@@ -1243,6 +1401,23 @@ TINY_EXAONE_MOE = ModelConfig(
     experts_per_token=4, moe_intermediate_size=32, routed_scaling_factor=2.5,
 )
 
+# the second window family at a size the CPU tests run (MiMo-V2-Flash's shape):
+# layer 0 full and dense, then one whole period (five window layers, one full);
+# 2 KV heads in the full layers and 4 in the window layers under 8 query heads,
+# q and k of 24 over v of 16, the first 8 of a head rotated at a base a kind, a
+# sink a query head in the window layers, a ring of 8, 2 of 8 experts a chip of 4
+TINY_SWA_SINK_MOE = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=7,
+    num_heads=8, num_kv_heads=2, head_dim=24, rope_theta=5000000.0,
+    rms_norm_eps=1e-5, sliding_window=8,
+    mixer_types=("full_attention",) + ("sliding_attention",) * 4
+    + ("full_attention",) + ("sliding_attention",) * 5 + ("full_attention",),
+    mlp_types=("dense",) + ("sparse",) * 11, attn_use_rope=True, rotary_dim=8,
+    window_rope_theta=10000.0, window_kv_heads=4, v_head_dim=16, window_sink=True,
+    value_scale=0.707, n_routed_experts=2, router_experts=8,
+    experts_per_token=3, moe_intermediate_size=32,
+)
+
 # compressed convolutional attention with an MLP router at a size the CPU tests
 # run (ZAYA1-8B's shape): 4 query heads over 2 KV heads of 16 in the latent, the
 # first 8 of a head rotated, 1 of 4 experts a token behind a router of width 16
@@ -1308,6 +1483,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-power": TINY_POWER,
     "tiny-jamba": TINY_JAMBA,
     "tiny-exaone-moe": TINY_EXAONE_MOE,
+    "tiny-swa-sink-moe": TINY_SWA_SINK_MOE,
     "tiny-dsa": TINY_DSA,
     "tiny-cca": TINY_CCA,
     "qwen2.5-0.5b": QWEN2_0_5B,

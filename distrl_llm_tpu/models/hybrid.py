@@ -188,6 +188,21 @@ real token, a candidate from its prompt. The router's ``r`` is a second value
 that flows from layer to layer: for this family alone the layer loop carries
 ``(x, r)``.
 
+The second window family (``mimo_v2_flash``, MiMo-V2-Flash) is the SAME two
+kinds with what its configuration states (``models/configs.py``): ``K_l`` KV
+heads a kind (the pages' ``num_kv_heads``, the rings' ``window_kv_heads``),
+k of ``head_dim`` and v of ``v_head_dim`` (pages, rings and ``W_o`` at the two
+widths), RoPE on a head's first ``rotary_dim`` values in both kinds with a base
+a kind, ``v <- value_scale * v`` before it is kept, no q/k norm, and in a
+window layer one learned sink logit a query head (the stack's ``sink`` [H])::
+
+    p[t, j] = exp(s[t, j]) / (exp(sink_h) + sum_j' exp(s[t, j']))
+
+in all three modes, float32: a column of the softmax whose value is nothing.
+A cached key takes ``cfg.key_row`` lanes (192 -> 256, zeros after its values:
+``_to_row``) in the pages and in the rings; q is given as many where it meets
+them, and the scores keep ``1 / sqrt(head_dim)``.
+
 The cache is a dict: ``k``/``v``/``pooled`` (a tuple over SPARSE layers: pages
 ``[K, pages, block, hd]`` and selector keys ``[B, NP, K, hd]``), ``lin`` (a
 tuple over LIGHTNING layers of ``[B, H, D, D]`` float32), ``lengths`` [B],
@@ -331,12 +346,13 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
                      w_down=init((n, f, d)))
         return p
 
-    def mixer_stack(n: int, q_dim: int, kv_dim: int) -> Params:
+    def mixer_stack(n: int, q_dim: int, kv_dim: int, v_dim: int = 0,
+                    o_dim: int = 0) -> Params:
         d = cfg.hidden_size
         return {
             "attn_norm": jnp.ones((n, d), dtype),
             "wq": init((n, d, q_dim)), "wk": init((n, d, kv_dim)),
-            "wv": init((n, d, kv_dim)), "wo": init((n, q_dim, d)),
+            "wv": init((n, d, v_dim or kv_dim)), "wo": init((n, o_dim or q_dim, d)),
         }
 
     def mlp_half(n: int) -> Params:
@@ -345,14 +361,17 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
                 "w_up": init((n, d, f)), "w_down": init((n, f, d))}
 
     layers: Params = {}
-    if cfg.window_moe:  # q/k norms in both mixers; the second half is the layer's
+    if cfg.window_moe:  # the mixer as its kind states it; the second half is the layer's
         for kind in dict.fromkeys(cfg.layer_kinds):
-            n = cfg.kind_count(kind)
+            n, kv = cfg.kind_count(kind), cfg.kv_heads_of(mixer_of(kind))
             layers[kind] = {
-                **mixer_stack(n, cfg.q_dim, cfg.kv_dim),
-                "q_norm": jnp.ones((n, cfg.head_dim), dtype),
-                "k_norm": jnp.ones((n, cfg.head_dim), dtype),
+                **mixer_stack(n, cfg.q_dim, kv * cfg.head_dim,
+                              kv * cfg.value_head_dim, cfg.o_dim),
+                **({"q_norm": jnp.ones((n, cfg.head_dim), dtype),
+                    "k_norm": jnp.ones((n, cfg.head_dim), dtype)} if cfg.qk_norm else {}),
                 **(mlp_half(n) if cfg.layer_ffn(kind) == "dense" else expert_half(n))}
+            if cfg.window_sink and mixer_of(kind) == "window":
+                layers[kind]["sink"] = jnp.zeros((n, cfg.num_heads), dtype)
     elif cfg.kind_count("softmax"):
         n = cfg.kind_count("softmax")
         layers["softmax"] = {
@@ -486,12 +505,12 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
             "moe_routed": jnp.zeros((1,), jnp.int32),
         }
     if cfg.window_moe:
-        ring = (rows, cfg.num_kv_heads, cfg.sliding_window, cfg.head_dim)
+        ring_k, ring_v = cfg.ring_shapes(rows)
         n = cfg.mixer_count("window")
         return {
             "lin": (), "pooled": (),
-            "win_k": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
-            "win_v": tuple(jnp.zeros(ring, cache_dtype) for _ in range(n)),
+            "win_k": tuple(jnp.zeros(ring_k, cache_dtype) for _ in range(n)),
+            "win_v": tuple(jnp.zeros(ring_v, cache_dtype) for _ in range(n)),
             "moe_stats": jnp.zeros((2,), jnp.int32),
             "moe_blocks": jnp.zeros((2,), jnp.int32),
             "moe_routed": jnp.zeros((1,), jnp.int32),
@@ -731,15 +750,18 @@ def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
     """A prefill segment's causal attention over the rows' PAGES (the
     segment's own are written already), ``SOFTMAX_SEGMENT_PAGES`` pages of
     keys at a time under a running softmax: the scores of all of a 2k-token
-    context at once would not fit. ``q [B, S, H, hd]`` -> ``[B, S, H, hd]``."""
+    context at once would not fit. ``q [B, S, H, hd]`` -> ``[B, S, H, hv]``,
+    ``hv`` the value pages' width."""
     b, s, heads, hd = q.shape
-    kv = pages_k.shape[0]
+    kv, hv = pages_k.shape[0], pages_v.shape[-1]
     per = max(d for d in range(1, SOFTMAX_SEGMENT_PAGES + 1)
               if (s // page_size) % d == 0)
     qg = q.reshape(b, s, kv, heads // kv, hd) * jnp.asarray(hd ** -0.5, q.dtype)
+    qg = _to_row(qg, pages_k.shape[-1])  # the lanes a key's row takes in a page
 
     def keys(pages, at):  # [K, B, per, ps, hd] -> [B, per * ps, K, hd]
-        return pages[:, at].transpose(1, 2, 3, 0, 4).reshape(b, per * page_size, kv, hd)
+        return pages[:, at].transpose(1, 2, 3, 0, 4).reshape(
+            b, per * page_size, kv, pages.shape[-1])
 
     def fold(j, carry):
         m, l, acc = carry
@@ -763,43 +785,70 @@ def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
 
     shape = (b, kv, heads // kv, s)
     start_carry = (jnp.full(shape, -jnp.inf, jnp.float32), jnp.zeros(shape, jnp.float32),
-                   jnp.zeros(shape + (hd,), jnp.float32))
+                   jnp.zeros(shape + (hv,), jnp.float32))
     _, l, acc = jax.lax.fori_loop(
         0, (start + s) // (per * page_size), fold, start_carry)
     o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, heads, hd).astype(q.dtype)
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, heads, hv).astype(q.dtype)
 
 
-def _qkv_heads(x, p, lora, *, cfg, proj, lora_scale):
-    """A GQA layer's normed input and its q ``[B, S, H, hd]``, k and v ``[B, S,
-    K, hd]``, with the per-head RMSNorm of q and k where the layer has one."""
+def _qkv_heads(x, p, lora, *, cfg, proj, lora_scale, mixer: str = "softmax"):
+    """A GQA layer's normed input and its q ``[B, S, H, hd]``, k ``[B, S, K,
+    hd]`` and v ``[B, S, K, hv]`` at the KV heads of its ``mixer``, with the
+    per-head RMSNorm of q and k where the layer has one and the value's scale
+    where the configuration states one (a cached value holds it)."""
     b, s, _ = x.shape
-    heads, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    heads, kv, hd = cfg.num_heads, cfg.kv_heads_of(mixer), cfg.head_dim
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
         q = proj(h, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, hd)
         k = proj(h, p, lora, "wk", "bk", lora_scale).reshape(b, s, kv, hd)
-        v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(b, s, kv, hd)
+        v = proj(h, p, lora, "wv", "bv", lora_scale).reshape(
+            b, s, kv, cfg.value_head_dim)
         if "q_norm" in p:
             q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+        if cfg.value_scale != 1.0:
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
     return h, q, k, v
 
 
+def _rotate(z, cos, sin, rot: int):
+    """RoPE on the first ``rot`` values of each head of ``z [B, S, H, hd]`` and
+    none on the rest; ``rot`` 0 (or the whole head) rotates all of it."""
+    if not rot or rot == z.shape[-1]:
+        return apply_rope(z, cos, sin)
+    return jnp.concatenate([apply_rope(z[..., :rot], cos, sin), z[..., rot:]], axis=-1)
+
+
+def _to_row(x, width: int):
+    """``x [..., hd]`` in the ``width`` lanes a cached key takes
+    (``ModelConfig.key_row``): zeros after its own values, which add nothing to
+    a score; ``x`` itself where the row is the head."""
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),))
+
+
 def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-    """A sliding-window layer: q/k norm, RoPE, token t over tokens
-    ``max(0, t - W + 1) .. t``: (x + y, (ring_k, ring_v) or None). The ring
-    ``[B, K, W, hd]`` holds position ``p`` at ``p % W``, k rotated already."""
+    """A sliding-window layer: q/k norm where it has one, RoPE, token t over
+    tokens ``max(0, t - W + 1) .. t`` and the layer's sink where it has one:
+    (x + y, (ring_k, ring_v) or None). The rings ``[B, K, W, hd]`` and ``[B, K,
+    W, hv]`` hold position ``p`` at ``p % W``, k rotated already, in
+    ``cfg.key_row`` lanes (q then takes as many, and the scores their own scale)."""
     b, s, _ = x.shape
-    kv, hd, win = cfg.num_kv_heads, cfg.head_dim, cfg.sliding_window
-    _, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    kv, win, sink = cfg.kv_heads_of("window"), cfg.sliding_window, p.get("sink")
+    row = cfg.key_row  # a padded row keeps the head's own scale, not its width's
+    scale = None if row == cfg.head_dim else cfg.head_dim ** -0.5
+    _, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale,
+                            mixer="window")
     with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
-        q = apply_rope(q, env["cos"], env["sin"])
-        k = apply_rope(k, env["cos"], env["sin"])
+        q = _rotate(q, env["cos"], env["sin"], cfg.rotary_dim)
+        k = _rotate(k, env["cos"], env["sin"], cfg.rotary_dim)
     if mode == "full":
         with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
             o = attention(q, k, v, None, impl=env["attn_impl"],
-                          key_valid=env["valid"], window=win)
+                          key_valid=env["valid"], window=win, sink=sink)
     elif mode == "decode":
         ring_k, ring_v = cache
         at = env["lengths"]  # the token's position: tokens before it
@@ -807,18 +856,20 @@ def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
             # a point scatter: row, KV head and slot are indices, the head's
             # values the window (a KV head taken as a slice relays the array out)
             where = (jnp.arange(b)[:, None], jnp.arange(kv)[None, :], (at % win)[:, None])
-            ring_k = ring_k.at[where].set(k[:, 0].astype(ring_k.dtype))
+            ring_k = ring_k.at[where].set(_to_row(k[:, 0], row).astype(ring_k.dtype))
             ring_v = ring_v.at[where].set(v[:, 0].astype(ring_v.dtype))
         with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
             # slots 0 .. min(t, W - 1) are filled, in whatever order
             seen = jnp.arange(win)[None, :] < jnp.minimum(at + 1, win)[:, None]
             o = attention_reference(  # the ring's keys lie in no order: a softmax
-                q, ring_k.transpose(0, 2, 1, 3).astype(q.dtype),
-                ring_v.transpose(0, 2, 1, 3).astype(q.dtype), seen[:, None, None, :])
+                _to_row(q, row), ring_k.transpose(0, 2, 1, 3).astype(q.dtype),
+                ring_v.transpose(0, 2, 1, 3).astype(q.dtype), seen[:, None, None, :],
+                scale=scale, sink=sink)
         cache = (ring_k, ring_v)
     else:  # one segment of a prefill, every row at ``start``: the ring, then itself
         ring_k, ring_v = cache
         start, slots = env["segment_start"], jnp.arange(win)
+        k = _to_row(k, row)
         with jax.named_scope(telemetry.MODEL_WINDOW_ATTN):
             # the position a slot holds before this segment: the largest p < start
             # with p % W == slot (negative: nothing yet)
@@ -829,9 +880,10 @@ def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
             seen = seen & jnp.concatenate(
                 [jnp.ones((b, win), bool), env["valid"] > 0], axis=1)[:, None, :]
             o = attention_reference(
-                q, jnp.concatenate([ring_k.transpose(0, 2, 1, 3).astype(k.dtype), k], 1),
+                _to_row(q, row),
+                jnp.concatenate([ring_k.transpose(0, 2, 1, 3).astype(k.dtype), k], 1),
                 jnp.concatenate([ring_v.transpose(0, 2, 1, 3).astype(v.dtype), v], 1),
-                seen[:, None])
+                seen[:, None], scale=scale, sink=sink)
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
             # each slot takes the row's LAST real token of the segment that falls
             # on it (rows are packed left: real tokens first), or keeps what it has
@@ -848,9 +900,9 @@ def _window_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
 
 
 def _paged_softmax(q, k, v, cache, *, mode, env):
-    """Causal softmax attention of ``q [B, S, H, hd]`` over ``k``, ``v [B, S,
-    K, hd]`` in each mode, K and V kept in pages: (o [B, S, H, hd], (pages_k,
-    pages_v) or None)."""
+    """Causal softmax attention of ``q [B, S, H, hd]`` over ``k [B, S, K, hd]``
+    and ``v [B, S, K, hv]`` in each mode, K and V kept in pages of their own
+    widths: (o [B, S, H, hv], (pages_k, pages_v) or None)."""
     from distrl_llm_tpu.ops.paged import paged_attention_op, write_token_to_pages
 
     s = q.shape[1]
@@ -860,13 +912,19 @@ def _paged_softmax(q, k, v, cache, *, mode, env):
                              key_valid=env["valid"]), None
     pages_k, pages_v = cache
     idx, ps = env["page_indices"], env["page_size"]
+    # a key's row in a page (``ModelConfig.key_row``): its head, or the head in
+    # whole lane tiles with zeros after it; q takes as many, the scores their scale
+    hd, row = q.shape[-1], pages_k.shape[-1]
+    scale = None if row == hd else hd ** -0.5
+    k = _to_row(k, row)
     if mode == "decode":
         lengths = env["lengths"]
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
             pages_k = write_token_to_pages(pages_k, k[:, 0], lengths, idx, ps)
             pages_v = write_token_to_pages(pages_v, v[:, 0], lengths, idx, ps)
         o = paged_attention_op(
-            q[:, 0], pages_k, pages_v, lengths + 1, idx, impl=env["paged_impl"],
+            _to_row(q[:, 0], row), pages_k, pages_v, lengths + 1, idx,
+            impl=env["paged_impl"], scale=scale,
         )[:, None]
         return o, (pages_k, pages_v)
     # one page-aligned segment of a prefill, every row at ``start``
@@ -934,9 +992,7 @@ def _cca_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
         k = c2[:, :, heads:] + mean_k
         q = (l2norm(q) * hd ** 0.5).astype(u.dtype)
         k = (l2norm(k) * (hd ** 0.5 * p["k_temp"].astype(jnp.float32))[:, None]).astype(u.dtype)
-        rotate = lambda z: jnp.concatenate(
-            [apply_rope(z[..., :rot], env["cos"], env["sin"]), z[..., rot:]], axis=-1)
-        q, k = rotate(q), rotate(k)
+        q, k = (_rotate(z, env["cos"], env["sin"], rot) for z in (q, k))
         v = jnp.concatenate([v_now, v_late], axis=-1).reshape(b, s, kv, hd)
         if tail is not None:
             tail = jnp.concatenate([u_last, c1_last, v_last], axis=-1).astype(tail.dtype)
@@ -948,15 +1004,19 @@ def _cca_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
 
 
 def _softmax_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-    """A softmax layer without RoPE, gated or with a per-head norm of q and k
-    where its stack says so: (x + y, (pages_k, pages_v) or None)."""
+    """A softmax layer, gated or with a per-head norm of q and k where its
+    stack says so, rotated (``env["cos_full"]``: the full layers' own base)
+    where the configuration says so: (x + y, (pages_k, pages_v) or None)."""
     b, s, _ = x.shape
-    heads, hd = cfg.num_heads, cfg.head_dim
-    if cfg.attn_use_rope:
-        raise NotImplementedError("softmax layers with RoPE (use_rope)")
     h, q, k, v = _qkv_heads(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    if cfg.attn_use_rope:
+        if not cfg.window_moe:  # no other family's full layers have a table
+            raise NotImplementedError("softmax layers with RoPE (use_rope)")
+        with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+            q = _rotate(q, env["cos_full"], env["sin_full"], cfg.rotary_dim)
+            k = _rotate(k, env["cos_full"], env["sin_full"], cfg.rotary_dim)
     o, cache = _paged_softmax(q, k, v, cache, mode=mode, env=env)
-    o = o.reshape(b, s, heads * hd)
+    o = o.reshape(b, s, -1)
     if "wg" in p:
         with jax.named_scope(telemetry.MODEL_ATTN_GATE):
             o = o * jax.nn.sigmoid(linear(h, p["wg"]))
@@ -1372,7 +1432,12 @@ def forward_hybrid(
                 telemetry.MODEL_LINEAR_ATTN):
             env["cos"], env["sin"] = rope_cos_sin(
                 rope_pos, cfg.rotary_dim or cfg.qk_rope_head_dim
-                or cfg.lightning_head_dim or cfg.head_dim, cfg.rope_theta)
+                or cfg.lightning_head_dim or cfg.head_dim,
+                (cfg.window_rope_theta if cfg.window_moe else 0.0) or cfg.rope_theta)
+        if cfg.window_moe and cfg.attn_use_rope:  # the full layers' own base
+            with jax.named_scope(telemetry.MODEL_ATTN_CORE):
+                env["cos_full"], env["sin_full"] = rope_cos_sin(
+                    rope_pos, cfg.rotary_dim or cfg.head_dim, cfg.rope_theta)
 
     with jax.named_scope(telemetry.MODEL_EMBED):
         x = jnp.take(params["embed"], input_ids, axis=0)

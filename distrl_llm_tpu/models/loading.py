@@ -161,7 +161,7 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
 
 
 def _refuse_unnamed(cfg: ModelConfig) -> None:
-    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe``, ``glm_moe_dsa`` or ``zaya`` checkpoint is refused by name, in both directions:
+    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe``, ``mimo_v2_flash``, ``glm_moe_dsa`` or ``zaya`` checkpoint is refused by name, in both directions:
     its published tensor names cannot be read here, and names guessed for the
     delta-rule layers' convolutions, low-rank pairs and gates would load or
     save something else under the model's name. Seeded weights only."""
@@ -194,6 +194,14 @@ def _refuse_unnamed(cfg: ModelConfig) -> None:
             "the three inner norms) cannot be checked here, the state's A_log is held "
             "transposed, and a guessed name would load or save something else under "
             "the model's name; the model runs from seeded weights only (init_params)")
+    if cfg.model_type == "mimo_v2_flash":
+        raise NotImplementedError(
+            "model_type 'mimo_v2_flash' checkpoints are not supported: the published "
+            "tensor names of its layers (the window layers' sinks, k and v of two head "
+            "counts and two widths, the router and its correction bias, the experts, "
+            "the multi-token-prediction layers) cannot be read here, and a guessed "
+            "name would load or save something else under the model's name; the "
+            "model runs from seeded weights only (init_params)")
     if cfg.window_moe:
         raise NotImplementedError(
             "model_type 'exaone_moe' checkpoints are not supported: the published "

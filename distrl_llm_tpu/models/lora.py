@@ -14,7 +14,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from distrl_llm_tpu.models.configs import ModelConfig
+from distrl_llm_tpu.models.configs import ModelConfig, mixer_of
 
 Params = dict[str, Any]
 
@@ -22,7 +22,7 @@ Params = dict[str, Any]
 _TARGET_DIMS = {
     "wq": ("q_in", "q_dim"),
     "wk": ("hidden_size", "kv_dim"),
-    "wv": ("hidden_size", "kv_dim"),
+    "wv": ("hidden_size", "v_dim"),
     "wo": ("o_dim", "hidden_size"),
     "w_gate": ("hidden_size", "intermediate_size"),
     "w_up": ("hidden_size", "intermediate_size"),
@@ -82,9 +82,10 @@ def init_lora_params(
     power-retention layer has the dense decoder's seven targets and no factor
     on its log-decay. A state-space model's attention layers have the seven and
     its Mamba layers ``MAMBA_TARGETS``; targets the caller names go to the
-    layers that have them. A window model (``exaone_moe``) has the seven in
-    every kind of layer: the MLP's three are the dense MLP's in a ``_dense``
-    kind and the shared expert's in the others. A compressed-convolutional
+    layers that have them. A window model (``exaone_moe``, ``mimo_v2_flash``)
+    has the seven in every kind of layer: the MLP's three are the dense MLP's in
+    a ``_dense`` kind and the shared expert's in the others, or absent where
+    the family has no shared expert. A compressed-convolutional
     layer has ``CCA_TARGETS`` and nothing in its second half."""
     named = targets is not None
     if targets is None:
@@ -109,6 +110,7 @@ def init_lora_params(
         for attr in ("hidden_size", "intermediate_size", "q_dim", "kv_dim")
     }
     dims["o_dim"] = cfg.q_dim  # what wo reads
+    dims["v_dim"] = cfg.kv_dim  # what wv writes: k's width unless a family says
     dims["q_in"] = cfg.hidden_size  # and wq
     kind_targets: dict[str, Sequence[str]] = {}  # a kind whose targets are its own
     if not cfg.hybrid:
@@ -129,15 +131,25 @@ def init_lora_params(
         # q, k, v, o of both mixers and the shared expert's three; the router,
         # the routed experts, the low-rank pairs, beta and the convolutions are frozen
         shared = {**dims, "intermediate_size": cfg.shared_expert_size}
-        delta = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.delta_dim)
+        delta = dict.fromkeys(("q_dim", "kv_dim", "v_dim", "o_dim"), cfg.delta_dim)
         per_kind = {"softmax": shared, "delta": {**shared, **delta}}
     elif cfg.window_moe:
-        # q, k, v, o of both mixers, then the layer's own second half: the dense
-        # MLP's three, or the shared expert's; the router and the routed experts
-        # are frozen
-        shared = {**dims, "intermediate_size": cfg.shared_expert_size}
-        per_kind = {kind: dims if cfg.layer_ffn(kind) == "dense" else shared
-                    for kind in dict.fromkeys(cfg.layer_kinds)}
+        # q, k, v, o of both mixers (k and v at the KV heads of the layer's kind,
+        # v and o at the value's width), then the layer's own second half: the
+        # dense MLP's three, or the shared expert's where the family has one; the
+        # router, the routed experts and a window layer's sinks are frozen
+        def mixer_dims(kind: str) -> dict:
+            kv = cfg.kv_heads_of(mixer_of(kind))
+            held = {**dims, "kv_dim": kv * cfg.head_dim,
+                    "v_dim": kv * cfg.value_head_dim, "o_dim": cfg.o_dim}
+            if cfg.layer_ffn(kind) != "dense":  # the shared expert's three, or none
+                if cfg.shared_expert_size:
+                    held["intermediate_size"] = cfg.shared_expert_size
+                else:
+                    del held["intermediate_size"]
+            return held
+
+        per_kind = {kind: mixer_dims(kind) for kind in dict.fromkeys(cfg.layer_kinds)}
     elif cfg.cca:
         per_kind = {"cca": {
             "hidden_size": cfg.hidden_size, "q_in": cfg.hidden_size, "q_dim": cfg.q_dim,
@@ -151,7 +163,7 @@ def init_lora_params(
             "mamba_inner": cfg.mamba_inner, "mamba_in_dim": 2 * cfg.mamba_inner}}
         kind_targets = {} if named else {"mamba": MAMBA_TARGETS}
     else:
-        lightning = dict.fromkeys(("q_dim", "kv_dim", "o_dim"), cfg.lightning_dim)
+        lightning = dict.fromkeys(("q_dim", "kv_dim", "v_dim", "o_dim"), cfg.lightning_dim)
         per_kind = {"sparse": dims, "lightning": {**dims, **lightning}}
     kinds = [k for k in per_kind if cfg.kind_count(k)]
     return {"layers": {

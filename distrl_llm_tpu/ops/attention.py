@@ -39,13 +39,20 @@ def attention_reference(
     v: jax.Array,  # [B, Sk, K, D]
     mask: jax.Array | None,  # [B, 1|H, Sq, Sk]; True = attend
     scale: float | None = None,
+    sink: jax.Array | None = None,  # [H]: one learned logit a query head
 ) -> jax.Array:
     """Plain-XLA masked attention. Softmax in f32 regardless of input dtype.
+    ``v`` may be narrower or wider than ``k``: the output has its width.
 
     GQA contracts the grouped query heads [B, Sq, K, G, D] directly against the
     K kv heads — never materializing ``repeat_kv``, which would multiply KV
-    HBM traffic by G (7× for Qwen2.5-0.5B) in the decode hot loop."""
-    return _gqa_attention(q, k, v, mask, scale, kv_subscript="bskd", kv_heads_axis=2)
+    HBM traffic by G (7× for Qwen2.5-0.5B) in the decode hot loop.
+
+    ``sink`` is a column of the softmax whose value is nothing: ``p[t, j] =
+    exp(s[t, j]) / (exp(sink_h) + sum_j' exp(s[t, j']))``, joined to the
+    running maximum and the denominator in float32."""
+    return _gqa_attention(q, k, v, mask, scale, kv_subscript="bskd", kv_heads_axis=2,
+                          sink=sink)
 
 
 def causal_padding_mask(
@@ -145,7 +152,7 @@ def attention_cached_quant(
 
 def _gqa_attention(q, k, v, mask, scale, *, kv_subscript: str,
                    kv_heads_axis: int, k_scale=None, v_scale=None,
-                   formulation: str = "dot"):
+                   formulation: str = "dot", sink=None):
     """Shared GQA attention body; only the kv einsum layout differs between
     the training ([B,S,K,D]) and decode-cache ([B,K,D,S]) paths.
 
@@ -186,14 +193,21 @@ def _gqa_attention(q, k, v, mask, scale, *, kv_subscript: str,
         else:
             m = mask.reshape(b, kh, g, *mask.shape[2:])
         logits = jnp.where(m, logits, NEG_INF)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    else:  # one more column, of no value: in the maximum and the denominator
+        logits = logits.astype(jnp.float32)
+        col = sink.astype(jnp.float32).reshape(kh, g)[None, :, :, None, None]
+        top = jnp.maximum(logits.max(-1, keepdims=True), col)
+        e = jnp.exp(logits - top)
+        probs = e / (e.sum(-1, keepdims=True) + jnp.exp(col - top))
     if quant:
         probs = probs * v_scale[:, :, :, None, :]
         v = v.astype(jnp.float32)
     else:
         probs = probs.astype(v.dtype)
     out = jnp.einsum(f"bkgqs,{kv_subscript}->bqkgd", probs, v)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, out.shape[-1])
 
 
 def mulred_broadcast_bytes(batch_rows: int, kv_heads: int, groups: int,
@@ -269,13 +283,16 @@ def attention(
     impl: str = "reference",
     key_valid: jax.Array | None = None,
     window: int = 0,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Dispatching front door. ``impl``: "reference" (XLA), "flash" or
     "splash" (Pallas kernels).
 
     ``window`` > 0 (with ``key_valid`` and no ``mask``) is a sliding-window
     layer's band, in the reference's mask; the kernels take a causal mask only
-    and refuse the band by name rather than attend everything.
+    and refuse the band by name rather than attend everything. ``sink`` [H] is
+    the reference's (``attention_reference``); the kernels have no such column
+    and refuse it likewise.
 
     On a TPU backend a named kernel is what runs: a kernel that fails to
     lower or to run fails the step, it never gives way to the reference
@@ -294,6 +311,10 @@ def attention(
             f"attn_impl={impl!r} masks causally and has no band: a sliding-window "
             f"layer (window {window}) would attend its whole context; use "
             "attn_impl='reference'")
+    if sink is not None and impl in ("flash", "splash"):
+        raise NotImplementedError(
+            f"attn_impl={impl!r} has no sink column in its softmax: a layer with a "
+            "learned sink would lose it; use attn_impl='reference'")
     if impl in ("flash", "splash"):
         if jax.default_backend() != "tpu":
             _note_reference(impl, "no TPU backend")
@@ -311,4 +332,4 @@ def attention(
             return flash_attention(q, k, v, mask, scale=scale, key_valid=key_valid)
     if mask is None and key_valid is not None:
         mask = causal_padding_mask(key_valid, q_len=q.shape[1], window=window)
-    return attention_reference(q, k, v, mask, scale=scale)
+    return attention_reference(q, k, v, mask, scale=scale, sink=sink)

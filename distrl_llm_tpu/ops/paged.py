@@ -289,20 +289,20 @@ def paged_attention_reference(
     v = gather(v_pages).transpose(1, 0, 2, 3, 4)
     s = k.shape[2] * ps
     k = k.reshape(b, kh, s, hd)
-    v = v.reshape(b, kh, s, hd)
+    v = v.reshape(b, kh, s, v.shape[-1])  # V's own width, which is the output's
     qg = q.reshape(b, kh, g, hd).astype(jnp.float32)
     logits = jnp.einsum("bkgd,bksd->bkgs", qg, k.astype(jnp.float32)) * scale
     valid = jnp.arange(s)[None, :] < lengths[:, None]  # [B, S]
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bksd->bkgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, h, hd).astype(q.dtype)
+    return out.reshape(b, h, v.shape[-1]).astype(q.dtype)
 
 
 def paged_grid_steps(
     impl: str, *, batch: int, num_kv_heads: int, pps: int,
     head_dim: int = 0, page_size: int = 0,
-    kv_itemsize: int = 2, quantized: bool = False,
+    kv_itemsize: int = 2, quantized: bool = False, v_head_dim: int = 0,
 ) -> int:
     """Analytic Pallas grid-step count of ONE paged-attention call (one
     layer, one decode step) for ``impl``. The engines record it (the counter
@@ -315,8 +315,9 @@ def paged_grid_steps(
 
     Counts per impl: "native" moves all kv heads and
     ``native_pages_per_step`` pages of a row a step — (B, ceil(pps / ppb)),
-    with ppb from ``head_dim``, ``page_size`` and the pages' dtype
-    (``kv_itemsize``, ``quantized``), which this impl therefore needs;
+    with ppb from ``head_dim`` (and ``v_head_dim`` where V's width is not
+    K's), ``page_size`` and the pages' dtype (``kv_itemsize``,
+    ``quantized``), which this impl therefore needs;
     "native_verify" is the FUSED draft-block verify: the whole (d+1)-query
     speculative verify step in ONE sweep of ``VERIFY_PAGES_PER_BLOCK`` pages
     a step — (B, ceil(pps / ppb)), where the unrolled verify pays the decode
@@ -333,7 +334,7 @@ def paged_grid_steps(
         ppb = native_pages_per_step(
             num_kv_heads=num_kv_heads, head_dim=head_dim,
             page_size=page_size, pps=pps, kv_itemsize=kv_itemsize,
-            quantized=quantized,
+            quantized=quantized, v_head_dim=v_head_dim,
         )
         return batch * -(-pps // ppb)
     if impl == "native_verify":
@@ -450,8 +451,11 @@ def paged_attention_op(
     page_indices: jax.Array,
     *,
     impl: str = "auto",
+    scale: float | None = None,
 ) -> jax.Array:
-    """Dispatch one decode query per row over the paged cache.
+    """Dispatch one decode query per row over the paged cache. ``scale`` is
+    what the scores are multiplied by where it is not ``q``'s width's root (a
+    query and keys padded with zeros to whole lane tiles keep their own).
 
     ``impl``: "auto" (``resolve_paged_impl``: the native kernel on a TPU
     backend, the reference elsewhere), "native" (our pipeline-gather kernel,
@@ -471,11 +475,12 @@ def paged_attention_op(
     dispatch_choices[choice_key] = resolved
     if resolved == "reference":
         return paged_attention_reference(
-            q, k_pages, v_pages, lengths, page_indices
+            q, k_pages, v_pages, lengths, page_indices,
+            **({} if scale is None else {"scale": scale}),
         )
     # the kernel computes raw q·k (no internal scaling)
     return per_device(_native_call)(
-        q * (q.shape[-1] ** -0.5), k_pages, v_pages,
+        q * (q.shape[-1] ** -0.5 if scale is None else scale), k_pages, v_pages,
         lengths.astype(jnp.int32), page_indices, quantized=quantized,
     ).astype(q.dtype)
 

@@ -77,19 +77,23 @@ _LANES = 128  # a VMEM tile's minor dimension; narrower blocks are padded to it
 
 def native_pages_per_step(
     *, num_kv_heads: int, head_dim: int, page_size: int, pps: int,
-    kv_itemsize: int = 2, quantized: bool = False,
+    kv_itemsize: int = 2, quantized: bool = False, v_head_dim: int = 0,
 ) -> int:
     """Pages of one row (all kv heads) that one grid step of
     ``paged_attention_native`` moves: as many as ``KV_VMEM_BUDGET_BYTES``
     holds of K and V, double-buffered, at most ``MAX_PAGES_PER_STEP`` and at
     most the row's ``pps``. Chosen from what the launch observes (the shapes
-    and the pages' dtype) and from nothing else."""
-    page = num_kv_heads * page_size * max(head_dim, _LANES) * kv_itemsize
+    and the pages' dtype) and from nothing else. ``v_head_dim`` is V's width
+    where it is not K's (0: it is); a width takes whole lane tiles in VMEM
+    (64 -> 128, 192 -> 256)."""
+    lanes = lambda width: -(-width // _LANES) * _LANES
+    token = lanes(head_dim) + lanes(v_head_dim or head_dim)  # K and V
     if quantized:
         # the compact [ps, 1] float32 scales take a whole lane tile a token
-        page += num_kv_heads * page_size * _LANES * 4
+        token += 2 * _LANES * 4 // kv_itemsize
+    page = num_kv_heads * page_size * token * kv_itemsize
     return max(1, min(pps, MAX_PAGES_PER_STEP,
-                      KV_VMEM_BUDGET_BYTES // (2 * 2 * page)))
+                      KV_VMEM_BUDGET_BYTES // (2 * page)))
 
 
 def live_page_walk(tables: jax.Array, lengths: jax.Array, *, page_size: int,
@@ -119,7 +123,7 @@ def _make_native_kernel(*, page_size: int, ppb: int, nblk: int,
     """Kernel body for ``paged_attention_native`` (module header): grid (B,
     nblk), ``ppb`` pages of all kv heads a step, each page its own operand."""
     dims_qk = (((2,), (2,)), ((0,), (0,)))  # [K,G,hd] x [K,ps,hd] -> [K,G,ps]
-    dims_pv = (((2,), (1,)), ((0,), (0,)))  # [K,G,ps] x [K,ps,hd] -> [K,G,hd]
+    dims_pv = (((2,), (1,)), ((0,), (0,)))  # [K,G,ps] x [K,ps,hv] -> [K,G,hv]
 
     def kernel(lengths_ref, tables_ref, q_ref, *rest):
         k_refs = rest[0:ppb]
@@ -210,7 +214,7 @@ def _make_native_kernel(*, page_size: int, ppb: int, nblk: int,
 def paged_attention_native(
     q: jax.Array,  # [B, H, hd] — pre-scaled by hd**-0.5 (op contract)
     k_pages: jax.Array,  # [K, P, ps, hd] bf16/f32, or int8 weight
-    v_pages: jax.Array,
+    v_pages: jax.Array,  # [K, P, ps, hv]: V's own width, which need not be K's
     lengths: jax.Array,  # i32 [B]
     page_indices: jax.Array,  # i32 [B, pps]
     k_scales: jax.Array | None = None,  # f32 [K, P, ps, 1] compact (int8)
@@ -225,9 +229,11 @@ def paged_attention_native(
     (``%paged_attention_native ``; tests/test_tpu_compile.py holds it).
     ``pages_per_block`` 0 is the launch's own choice from the shapes
     (``native_pages_per_step``); the tests name one to reach rows of several
-    blocks at small sizes."""
+    blocks at small sizes. K's width is q's; V's is the output's ``[B, H,
+    hv]``, read off the V pages: each array is moved at its own width."""
     batch, num_q_heads, head_dim = q.shape
     num_kv_heads, total_pages, ps, head_dim_k = k_pages.shape
+    head_dim_v = v_pages.shape[-1]
     if page_size is None:
         page_size = ps
     if head_dim_k != head_dim:
@@ -246,6 +252,7 @@ def paged_attention_native(
     ppb = min(pps, pages_per_block) or native_pages_per_step(
         num_kv_heads=num_kv_heads, head_dim=head_dim, page_size=page_size,
         pps=pps, kv_itemsize=k_pages.dtype.itemsize, quantized=quantized,
+        v_head_dim=head_dim_v,
     )
     nblk = -(-pps // ppb)
 
@@ -274,7 +281,8 @@ def paged_attention_native(
     # the SAME pool array rides as ppb inputs, one per in-block page — each
     # gets its own index_map gather, so the pipeline emitter still only
     # ever moves whole [K, 1, ps, hd] blocks (never slicing the minor dims)
-    in_specs = [q_spec] + [page_spec(i, head_dim) for i in range(ppb)] * 2
+    in_specs = [q_spec] + [page_spec(i, head_dim) for i in range(ppb)] + [
+        page_spec(i, head_dim_v) for i in range(ppb)]
     operands = [q4] + [k_pages] * ppb + [v_pages] * ppb
     if quantized:
         in_specs += [page_spec(i, 1) for i in range(ppb)] * 2
@@ -289,24 +297,24 @@ def paged_attention_native(
             grid=(batch, nblk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (None, num_kv_heads, groups, head_dim),
+                (None, num_kv_heads, groups, head_dim_v),
                 lambda b, j, lens, tabs: (b, 0, 0, 0),
             ),
             scratch_shapes=[
                 pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
                 pltpu.VMEM((num_kv_heads, groups, 1), jnp.float32),
-                pltpu.VMEM((num_kv_heads, groups, head_dim), jnp.float32),
+                pltpu.VMEM((num_kv_heads, groups, head_dim_v), jnp.float32),
             ],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (batch, num_kv_heads, groups, head_dim), q.dtype
+            (batch, num_kv_heads, groups, head_dim_v), q.dtype
         ),
         interpret=interpret,
     )(lengths, tables, *operands)
-    return out.reshape(batch, num_q_heads, head_dim)
+    return out.reshape(batch, num_q_heads, head_dim_v)
 
 
 def _make_verify_kernel(*, page_size: int, ppb: int, nblk: int, s_len: int,

@@ -124,6 +124,13 @@ ENGINE_DECODE_VIEW_BYTES = "engine/decode_view_bytes"
 # and filed at readback; attended / visible is what the window saves
 ENGINE_WINDOW_PAGES_ATTENDED = "engine/window_pages_attended"  # counter
 ENGINE_WINDOW_PAGES_VISIBLE = "engine/window_pages_visible"    # counter
+# what ONE more token of context costs a slot, in bytes, summed over the layers
+# that keep pages and read off the pools' own shapes, K's and V's apart (a
+# window layer's ring costs a token nothing: ``engine/slot_state_bytes`` holds
+# what the slots cost at ANY context). Filed beside that gauge when a round's
+# decode state is built (engine/paged_engine.py::_file_slot_state), by every
+# paged family, tracing on or off, nothing fetched
+ENGINE_CACHE_TOKEN_BYTES = "engine/cache_token_bytes"          # gauge
 # a learned index over tokens (glm_moe_dsa): per live row, layer and decode
 # step, the tokens attended (min(context, index_topk)) and the tokens latent
 # attention without the index would attend (the context), in the same units of

@@ -1,0 +1,664 @@
+"""The second window family (MiMo-V2-Flash, ``mimo_v2_flash``) against its plain
+reference, ``perfbench/reference_swa_sink_moe.py`` (causal scores with the band
+and the sink written out: no ring, no cache, no segment, no page), at a small
+size on the CPU: the ``tiny-swa-sink-moe`` preset (hidden 64, seven layers as
+the cut's: layer 0 full and dense, then one whole period of five window layers
+and a full one; 8 query heads over 2 KV heads in the full layers and 4 in the
+window layers; q and k of 24 over v of 16; the first 8 of a head rotated at a
+base a kind; a sink a query head in the window layers; a ring of 8 tokens; 2 of
+8 experts held, 3 a token, no shared expert). Float32 throughout, seeded
+weights with every term alive.
+
+One engine of each scheduler is built a module; the bent mechanisms are the
+cases of one parametrised test. The rollout through ``perfbench/run.py`` is
+held by ``tests/perfbench/test_perfbench_rehearsal_swa_sink_moe.py``.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
+from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
+from distrl_llm_tpu.models import hybrid, moe  # noqa: E402
+from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
+from perfbench import reference_swa_sink_moe as ref  # noqa: E402
+from perfbench import swa_sink_moe_counts as counts  # noqa: E402
+
+CFG = PRESETS["tiny-swa-sink-moe"]
+LORA_SCALE = 2.0
+CONFIG_FILE = os.path.join(REPO, "perfbench", "configs", "mimo-v2-flash-ep16-L7.json")
+#: lanes a cached key's row takes in the engine tests: a "tile" of 16 lanes there
+#: (``small_pieces``), so that a head of 24 is a tile and a half as 192 is of 128
+KEY_ROW = 32
+#: bytes of one slot's rings in one window layer (float32 caches here): 4 KV
+#: heads x 8 tokens x (a key's row + 16 values)
+RING_BYTES = 4 * 8 * (KEY_ROW + 16) * 4
+#: bytes one token costs a full layer's pages: 2 KV heads x (a key's row + 16 values)
+TOKEN_BYTES = 2 * (KEY_ROW + 16) * 4
+
+
+@pytest.fixture(scope="module", autouse=True)
+def exact_matmuls():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def seeded(cfg, rank=4):
+    """Seeded weights with every term alive: norms off 1, sinks Normal(0, 1), a
+    correction bias and an adapter's b that are not zero."""
+    def base(path, x):
+        name = str(path[-1].key)
+        key = jax.random.PRNGKey(sum(map(ord, str(path))) % 9973)
+        if name.endswith("norm"):
+            return 1.0 + 0.3 * jax.random.normal(key, x.shape)
+        if name == "sink":
+            return jax.random.normal(key, x.shape)
+        if name == "e_score_bias":
+            return 0.05 * jax.random.normal(key, x.shape)
+        return 6.0 * x
+
+    params = jax.tree_util.tree_map_with_path(base, init_params(jax.random.PRNGKey(0), cfg))
+    lora = jax.tree_util.tree_map_with_path(
+        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
+        if str(path[-1].key) == "b" else x,
+        init_lora_params(jax.random.PRNGKey(1), cfg, rank),
+    )
+    return params, lora
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return seeded(CFG)
+
+
+#: the reference's whole program, traced once a configuration and a shape
+_reference = jax.jit(
+    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
+
+
+def reference_logprobs(params, lora, ids, mask, cfg=CFG):
+    return np.asarray(_reference(
+        params, cfg, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
+        lora_scale=LORA_SCALE))
+
+
+def forward_logprobs(params, lora, ids, mask, cfg=CFG, **kw):
+    logits, _ = forward(params, cfg, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
+                        lora=lora, lora_scale=LORA_SCALE, **kw)
+    return np.asarray(jnp.take_along_axis(
+        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0])
+
+
+def padded_rows(width=40):
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, width), 1, 256))
+    mask = np.ones((3, width), np.int32)
+    mask[0, :7] = 0
+    mask[1, width - 7:] = 0
+    return ids, mask, (mask[:, 1:] * mask[:, :-1]) > 0
+
+
+# --------------------------------------------------- what the program is told
+
+
+def test_the_two_kinds_state_their_own_heads_widths_bases_and_sink():
+    assert CFG.layer_kinds == ("softmax_dense", "window", "window", "window", "window",
+                               "softmax", "window")
+    assert CFG.hybrid and CFG.window_moe and CFG.model_type == "mimo_v2_flash"
+    assert PRESETS["tiny-exaone-moe"].model_type == "exaone_moe"
+    assert [CFG.layer_ffn(k) for k in CFG.layer_kinds] == ["dense"] + ["experts"] * 6
+    assert CFG.mixer_count("window") == 5 and CFG.paged_layers == 2
+    assert (CFG.kv_heads_of("softmax"), CFG.kv_heads_of("window")) == (2, 4)
+    assert (CFG.head_dim, CFG.value_head_dim, CFG.o_dim, CFG.rotary_dim) == (24, 16, 128, 8)
+    assert CFG.held_experts == (0, 1) and CFG.router_width == 8
+    # the pool's K and V at their own widths under one table, the rings likewise
+    assert CFG.page_pool_shape(6, 8) == (2, 6, 8, 24)
+    assert CFG.second_pool_shape(6, 8) == (2, 6, 8, 16)
+    assert CFG.ring_shapes(5) == ((5, 4, 8, 24), (5, 4, 8, 16))
+    # a key wider than one lane tile and no multiple of it takes whole tiles
+    assert CFG.key_row == 24 and dataclasses.replace(CFG, head_dim=192).key_row == 256
+    assert [dataclasses.replace(CFG, head_dim=d).key_row for d in (64, 128, 256)] == [
+        64, 128, 256]
+    state = hybrid.init_mixer_state(CFG, 5, 64, jnp.bfloat16)
+    assert [x.shape for x in state["win_k"]] == [(5, 4, 8, 24)] * 5
+    assert [x.shape for x in state["win_v"]] == [(5, 4, 8, 16)] * 5
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    mixer = {"attn_norm", "wq", "wk", "wv", "wo", "mlp_norm"}  # no q/k norm
+    experts = {"router", "e_score_bias", "experts_gate", "experts_up", "experts_down"}
+    assert set(params["layers"]["softmax_dense"]) == mixer | {"w_gate", "w_up", "w_down"}
+    assert set(params["layers"]["softmax"]) == mixer | experts  # no shared expert
+    assert set(params["layers"]["window"]) == mixer | experts | {"sink"}
+    shapes = {k: params["layers"]["window"][k].shape[1:] for k in ("wq", "wk", "wv", "wo", "sink")}
+    assert shapes == {"wq": (64, 192), "wk": (64, 96), "wv": (64, 64), "wo": (128, 64),
+                      "sink": (8,)}
+    assert params["layers"]["softmax"]["wk"].shape == (1, 64, 48)
+    assert params["layers"]["softmax"]["wv"].shape == (1, 64, 32)
+    # the other window family's stacks are what they were
+    old = init_params(jax.random.PRNGKey(0), PRESETS["tiny-exaone-moe"])
+    assert "q_norm" in old["layers"]["window"] and "sink" not in old["layers"]["window"]
+
+
+def test_parameters_operations_and_the_counts_module_agree_with_the_tree():
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    held = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    model = dataclasses.asdict(CFG)
+    assert counts.param_count(model) == held
+    matmul = held - 256 * 64 - sum(  # less the embedding, the norms, sinks and biases
+        x.size for path, x in jax.tree_util.tree_leaves_with_path(params)
+        if str(path[-1].key).endswith("norm") or str(path[-1].key) in ("sink", "e_score_bias"))
+    assert CFG.total_matmul_param_count == matmul
+    # a token's operations: its own three experts of eight, both widths in a key
+    per_key = 2.0 * 8 * (24 + 16)
+    assert CFG.decode_flops_per_token(100.0) == 2.0 * CFG.matmul_param_count + per_key * (
+        2 * 100.0 + 5 * 8)
+
+
+def test_from_hf_config_reads_the_benchmarks_file():
+    file = json.load(open(CONFIG_FILE))
+    cfg = ModelConfig.from_hf_config(SimpleNamespace(**file))
+    assert (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.window_kv_heads) == (
+        4096, 64, 4, 8)
+    assert (cfg.head_dim, cfg.v_head_dim, cfg.sliding_window, cfg.rotary_dim) == (
+        192, 128, 128, 64)
+    assert (cfg.rope_theta, cfg.window_rope_theta, cfg.value_scale) == (5e6, 1e4, 0.707)
+    assert (cfg.intermediate_size, cfg.moe_intermediate_size) == (16384, 2048)
+    assert (cfg.router_width, cfg.experts_per_token, cfg.n_routed_experts) == (256, 8, 16)
+    assert cfg.window_sink and cfg.attn_use_rope and not cfg.qk_norm
+    assert cfg.n_shared_experts == 0 and cfg.routed_scaling_factor == 1.0
+    assert cfg.rms_norm_eps == 1e-5 and cfg.vocab_size == 19072 and cfg.num_layers == 7
+    assert cfg.layer_kinds == CFG.layer_kinds and cfg.model_type == "mimo_v2_flash"
+    assert cfg.total_matmul_param_count + 19072 * 4096 == 3_429_892_096  # 3,430M: 6.86 GB
+    from distrl_llm_tpu.engine import budget
+
+    model = dataclasses.asdict(cfg)
+    # what the algorithm moves: 2,560 B a token a full layer, 655,360 B of ring a
+    # window layer; what the program holds: a key's 192 values in 256 lanes
+    assert counts.cache_token_bytes(model) == 2 * 2560 == 2 * counts.kv_token_bytes(model)
+    assert counts.slot_state_bytes(model) == 5 * 655_360 == 5 * counts.ring_bytes(model)
+    assert cfg.key_row == 256 and cfg.page_pool_shape(7, 128) == (4, 7, 128, 256)
+    assert cfg.second_pool_shape(7, 128) == (4, 7, 128, 128)
+    assert budget.page_bytes(cfg, 128) == 128 * 2 * 4 * (256 + 128) * 2 == 128 * 6144
+    assert budget.slot_state_bytes(cfg, 20992) == 5 * 8 * 128 * (256 + 128) * 2
+    assert counts.param_count(model) == 3_429_955_392
+    assert file["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    for reading in ("norm_placement", "value_scale", "qk_norm", "rope", "score_scale",
+                    "grouping", "window", "sink", "router", "routed_scaling_factor",
+                    "mtp_layers", "towers", "unread_keys", "adapter_targets", "frozen"):
+        assert file["assumed"][reading], reading
+
+
+@pytest.mark.parametrize("changes,named", [
+    ({"swa_num_attention_heads": 32}, "swa_num_attention_heads"),
+    ({"swa_head_dim": 128}, "swa_head_dim"),
+    ({"swa_v_head_dim": 64}, "swa_v_head_dim"),
+    ({"add_full_attention_sink_bias": True}, "add_full_attention_sink_bias"),
+    ({"sliding_window_size": 256}, "sliding_window_size"),
+    ({"attention_chunk_size": 64}, "attention_chunk_size"),
+    ({"n_shared_experts": 1}, "n_shared_experts"),
+    ({"n_group": 8}, "n_group"),
+    ({"topk_group": 4}, "topk_group"),
+    ({"scoring_func": "softmax"}, "scoring_func"),
+    ({"topk_method": "greedy"}, "topk_method"),
+    ({"attention_bias": True}, "attention_bias"),
+    ({"rope_scaling": {"type": "yarn", "factor": 4.0}}, "rope_scaling"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"hybrid_layer_pattern": [0, 2] * 24}, "hybrid_layer_pattern"),
+    ({"moe_layer_freq": [0] * 7}, "moe_layer_freq"),
+])
+def test_from_hf_config_refuses_what_it_cannot_represent(changes, named):
+    file = {**json.load(open(CONFIG_FILE)), **changes}
+    with pytest.raises(ValueError, match=named):
+        ModelConfig.from_hf_config(SimpleNamespace(**file))
+
+
+def test_the_loader_refuses_a_checkpoint_by_name(weights):
+    from distrl_llm_tpu.models import loading
+
+    with pytest.raises(NotImplementedError, match="mimo_v2_flash"):
+        loading._refuse_unnamed(CFG)
+    with pytest.raises(NotImplementedError, match="exaone_moe"):
+        loading._refuse_unnamed(PRESETS["tiny-exaone-moe"])
+
+
+# ------------------------------------------------------------- the forward
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_forward_equals_the_reference(weights, remat):
+    """``full`` mode: the band and the sink's column in a mask, K and V of two
+    widths in two einsums, over rows padded on either side and past the window."""
+    params, lora = weights
+    ids, mask, both = padded_rows()
+    want = reference_logprobs(params, lora, ids, mask)
+    got = forward_logprobs(params, lora, ids, mask, remat=remat)
+    assert np.abs(got - want)[both].max() < 2e-5
+
+
+def _with_proj(monkeypatch, name, bend):
+    """``name`` (a mixer) handed a ``proj`` whose outputs ``bend(key, y)`` bent."""
+    mix = getattr(hybrid, name)
+
+    def run(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+        def bent(h, p_, lora_, key, bias, scale):
+            return bend(key, proj(h, p_, lora_, key, bias, scale))
+        return mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=bent,
+                   lora_scale=lora_scale)
+    monkeypatch.setattr(hybrid, name, run)
+
+
+def _with_attention(monkeypatch, bend):
+    """Every softmax of the PROGRAM's three modes (``hybrid.attention``, the
+    ring's and the segment's ``hybrid.attention_reference``) called through
+    ``bend(fn, q, k, v, mask, kw)``; a window layer's call is told by its
+    ``sink`` keyword."""
+    for name in ("attention", "attention_reference"):
+        fn = getattr(hybrid, name)
+        monkeypatch.setattr(
+            hybrid, name,
+            lambda q, k, v, mask, fn=fn, **kw: bend(fn, q, k, v, mask, kw))
+
+
+def _control(monkeypatch, name, params):
+    """Bend the PROGRAM in one place (never the reference). Returns the
+    configuration the program is then given."""
+    cfg = CFG
+    if name == "no_sink":
+        mix = hybrid._window_mix
+        monkeypatch.setattr(hybrid, "_window_mix", lambda x, p, *a, **kw: mix(
+            x, {k: v for k, v in p.items() if k != "sink"}, *a, **kw))
+    elif name == "sink_in_full_layers":
+        fake = params["layers"]["window"]["sink"][0]
+        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
+            q, k, v, mask, **{**kw, "sink": kw.get("sink", fake)}))
+    elif name == "sink_with_a_value":
+        # the sink's column given a value row of 0.5: what it takes of the
+        # denominator comes back as output (a row of zeros would be right)
+        def counted(fn, q, k, v, mask, kw):
+            o = fn(q, k, v, mask, **kw)
+            if kw.get("sink") is None:
+                return o
+            kept = fn(q, k, jnp.ones_like(v), mask, **kw)  # sum_j p[t, j] = 1 - p_sink
+            return o + 0.5 * (1.0 - kept)
+        _with_attention(monkeypatch, counted)
+    elif name == "window_grouped_as_full":
+        # a query head reads KV head i // (H / 2) in a window layer too: its
+        # first 2 KV heads of 4, in groups of 4 for 2
+        def grouped(fn, q, k, v, mask, kw):
+            if kw.get("sink") is None:
+                return fn(q, k, v, mask, **kw)
+            return fn(q, k[:, :, :CFG.num_kv_heads], v[:, :, :CFG.num_kv_heads], mask, **kw)
+        _with_attention(monkeypatch, grouped)
+    elif name == "no_value_scale":
+        cfg = dataclasses.replace(cfg, value_scale=1.0)
+    elif name == "value_scaled_twice":
+        for mix in ("_window_mix", "_softmax_mix"):
+            _with_proj(monkeypatch, mix,
+                       lambda key, y: y * CFG.value_scale if key == "wo" else y)
+    elif name == "rope_on_all_dims":
+        cfg = dataclasses.replace(cfg, rotary_dim=0)
+    elif name == "bases_swapped":
+        cfg = dataclasses.replace(cfg, rope_theta=cfg.window_rope_theta,
+                                  window_rope_theta=cfg.rope_theta)
+    elif name == "scores_over_sqrt_v":
+        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
+            q * (q.shape[-1] / v.shape[-1]) ** 0.5, k, v, mask, **kw))
+    elif name in ("window_7", "window_9"):
+        cfg = dataclasses.replace(cfg, sliding_window=int(name[-1]))
+    elif name == "no_bias":
+        route = moe.route
+        monkeypatch.setattr(moe, "route", lambda h, router, bias, c: route(
+            h, router, jnp.zeros_like(bias), c))
+    elif name == "top_2":
+        cfg = dataclasses.replace(cfg, experts_per_token=2)
+    else:
+        raise AssertionError(name)
+    return cfg
+
+
+#: each bends what ``full`` mode runs; the last two are the router's
+FORWARD_CONTROLS = ["no_sink", "sink_in_full_layers", "sink_with_a_value",
+                    "window_grouped_as_full", "no_value_scale", "value_scaled_twice",
+                    "rope_on_all_dims", "bases_swapped", "scores_over_sqrt_v",
+                    "window_7", "window_9", "no_bias", "top_2"]
+#: what only the cache path can get wrong: ``change(mixer)`` over what the
+#: prefill hands the fan-out
+ENGINE_CONTROLS = {
+    "ring_not_handed": lambda m: {
+        **m, **{n: tuple(jnp.zeros_like(x) for x in m[n]) for n in ("win_k", "win_v")}},
+    # V's ring read with K's stride: slot w starts w x 32 values in, not w x 16
+    "ring_v_read_at_k_width": lambda m: {**m, "win_v": tuple(
+        jnp.pad(x.reshape(*x.shape[:2], -1), ((0, 0), (0, 0), (0, 8 * 16)))
+        .reshape(*x.shape[:2], 8, KEY_ROW)[..., :16] for x in m["win_v"])},
+}
+
+
+@pytest.mark.parametrize("control", FORWARD_CONTROLS + sorted(ENGINE_CONTROLS) + [
+    "engine:no_sink", "engine:window_9", "engine:scores_over_sqrt_v"])
+def test_this_files_agreement_can_tell_each_mechanism(weights, control, monkeypatch,
+                                                      small_pieces):
+    """Each mechanism dropped or bent IN THE PROGRAM moves the log-probabilities
+    a hundred times further from the reference than the sound program's 2e-5:
+    the sink dropped, added to the full layers, or counted with a value; the
+    window layers grouped as the full layers are; the value's scale dropped or
+    applied twice; RoPE on every dim; the two bases swapped; scores over
+    sqrt(16); a window one token short or long; the router's bias and count.
+    Through the engine (segments, fan-out, decode over rings and pages): a ring
+    not handed at the fan-out, a ring's V read at K's width, and three of the
+    forward's bends again, where the ring's softmax and the segment's run."""
+    params, lora = weights
+    if control in FORWARD_CONTROLS:
+        ids, mask, both = padded_rows()
+        want = reference_logprobs(params, lora, ids, mask)
+        cfg = _control(monkeypatch, control, params)
+        assert np.abs(forward_logprobs(params, lora, ids, mask, cfg) - want)[both].max() > 2e-3
+        return
+    from distrl_llm_tpu.engine import paged_engine
+
+    cfg = CFG
+    if control.startswith("engine:"):
+        cfg = _control(monkeypatch, control.split(":")[1], params)
+    else:
+        prefill, change = paged_engine._paged_prefill_hybrid, ENGINE_CONTROLS[control]
+
+        def patched(*a, **kw):
+            k, v, logits, real_len, mixer = prefill(*a, **kw)
+            return k, v, logits, real_len, change(mixer)
+        monkeypatch.setattr(paged_engine, "_paged_prefill_hybrid", patched)
+    ids, mask, result = generate(make_engine("waves", 0, cfg, bent=True), params, lora)
+    assert worst_difference(params, lora, ids, mask, result) > 2e-3
+
+
+def test_the_sink_is_a_column_the_kernels_refuse_by_name():
+    from distrl_llm_tpu.ops.attention import attention, attention_reference
+
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 4, 8))
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, 6, 2, 8))
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, 6, 2, 5))  # V narrower than K
+    sink = jnp.asarray([0.3, -1.0, 2.0, 0.0])
+    mask = jnp.tril(jnp.ones((6, 6), bool))[None, None]
+    got = attention_reference(q, k, v, mask, sink=sink)
+    # a zero value row added to V beside a key whose score is the sink: the same
+    scores = jnp.einsum("bqhd,bshd->bhqs", q, jnp.repeat(k, 2, 2)) / jnp.sqrt(8.0)
+    scores = jnp.where(mask, scores, -jnp.inf)
+    wide = jnp.concatenate(
+        [scores, jnp.broadcast_to(sink[None, :, None, None], (1, 4, 6, 1))], -1)
+    p = jax.nn.softmax(wide, -1)[..., :-1]
+    want = jnp.einsum("bhqs,bshd->bqhd", p, jnp.repeat(v, 2, 2))
+    assert got.shape == (1, 6, 4, 5)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert float(jnp.abs(got - attention_reference(q, k, v, mask)).max()) > 1e-2
+    for impl in ("flash", "splash"):
+        with pytest.raises(NotImplementedError, match="no sink column"):
+            attention(q, k, v, None, impl=impl, key_valid=jnp.ones((1, 6), jnp.int32),
+                      sink=sink)
+
+
+def test_the_learners_loss_and_adapter_gradient_are_the_references(weights):
+    """No cache, remat, chunked cross-entropy over rows five windows long: the
+    policy-gradient loss over the answers and its gradient in every adapter
+    factor against plain reverse mode through the reference; then a train step
+    moves every factor and nothing else."""
+    import optax
+
+    from distrl_llm_tpu.learner.losses import answer_logprobs, pg_loss
+    from distrl_llm_tpu.learner.train_step import UpdateBatch, make_train_step
+
+    params, lora = weights
+    rng = np.random.default_rng(1)
+    prompt = rng.integers(1, 256, (4, 12)).astype(np.int32)
+    pmask = np.ones((4, 12), np.int32)
+    pmask[0, :5] = 0
+    answer = rng.integers(1, 256, (4, 28)).astype(np.int32)
+    amask = np.ones((4, 28), np.int32)
+    amask[2, 14:] = 0
+    coeffs = jnp.asarray([0.7, -1.1, 0.4, 1.3])
+
+    def loss(lo):
+        logp = answer_logprobs(
+            params, CFG, jnp.asarray(prompt), jnp.asarray(pmask), jnp.asarray(answer),
+            jnp.asarray(amask), lora=lo, lora_scale=LORA_SCALE, remat=True, logit_chunk=8)
+        return pg_loss(logp, jnp.asarray(amask), coeffs)
+
+    got_loss, got = jax.value_and_grad(loss)(lora)
+    ids = np.concatenate([prompt, answer], 1)
+    mask = np.concatenate([pmask, amask], 1)
+    scored = np.concatenate([np.zeros_like(pmask), amask], 1)
+    want_loss, want = jax.jit(ref.pg_loss_and_lora_grad, static_argnums=(1, 3))(
+        params, CFG, lora, LORA_SCALE, jnp.asarray(ids), jnp.asarray(mask),
+        jnp.asarray(scored), coeffs)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5
+    leaves = jax.tree_util.tree_leaves_with_path(got)
+    # a and b: q, k, v, o in each of three stacks, and layer 0's dense MLP's three
+    assert len(leaves) == 2 * (4 * 3 + 3)
+    for (path, g), w in zip(leaves, jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(w).max()) > 0, path
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-6,
+                                   err_msg=str(path))
+    batch = UpdateBatch(
+        prompt_ids=jnp.asarray(prompt), prompt_mask=jnp.asarray(pmask),
+        answer_ids=jnp.asarray(answer[:, :12]), answer_mask=jnp.ones((4, 12), jnp.int32),
+        coeffs=coeffs, sample_mask=jnp.ones((4,), jnp.float32))
+    optimizer = optax.adam(1e-3)
+    step = make_train_step(CFG, learner_type="pg", optimizer=optimizer,
+                           lora_scale=LORA_SCALE, micro_size=2, donate=False)
+    new_lora, _, step_loss = step(lora, optimizer.init(lora), params, batch)[:3]
+    assert np.isfinite(float(step_loss))
+    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.abs(a - b).max()), new_lora, lora)
+    assert all(m > 0 for m in jax.tree_util.tree_leaves(moved))
+    assert set(new_lora["layers"]["window"]) == {"wq", "wk", "wv", "wo"}
+    assert set(new_lora["layers"]["softmax_dense"]) == {
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+
+
+# --------------------------------------------------------------- the share
+
+
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """The guide's section 4 at the tiny size: the four chips' parts of an
+    expert layer's result (``expert_shard`` 0..3, two of eight experts each) add
+    up to what the uncut reference gives for the whole layer, and the program's
+    part for a share is the reference's."""
+    uncut = dataclasses.replace(CFG, n_routed_experts=8, router_experts=0)
+    whole, _ = seeded(uncut)
+    layer = jax.tree_util.tree_map(lambda w: w[1], whole["layers"]["window"])
+    h = jax.random.normal(jax.random.PRNGKey(7), (24, 64))
+    want = ref.routed_part(h, layer, uncut)
+    total = jnp.zeros_like(want)
+    for shard in range(4):
+        share = dataclasses.replace(CFG, expert_shard=shard)
+        assert ref.held_ids(share) == [2 * shard, 2 * shard + 1]
+        held = {**layer, **{name: layer[name][2 * shard: 2 * shard + 2]
+                            for name in ("experts_gate", "experts_up", "experts_down")}}
+        part = ref.routed_part(h, held, share)
+        got, _ = moe.moe_half(h, held, share, held=share.held_experts)
+        np.testing.assert_allclose(got, part, atol=2e-5)
+        total = total + part
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert float(jnp.abs(want).max()) > 0.1
+
+
+# -------------------------------------------------------------- the engine
+
+
+_ENGINES: dict = {}
+
+
+def make_engine(scheduler, slots, cfg=CFG, bent=False):
+    """The sound program's engine of a scheduler, built once a module; a
+    ``bent`` program's is traced anew and not kept."""
+    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
+
+    build = lambda: PagedGenerationEngine(
+        cfg, max_prompt_tokens=60, max_new_tokens=20, eos_token_ids=[-1],
+        pad_token_id=0, lora_scale=LORA_SCALE, scheduler=scheduler,
+        max_concurrent_rows=slots, capture_logprobs=True, autotune=False,
+        cache_dtype=jnp.float32, page_size=4)
+    if bent:
+        return build()
+    if (scheduler, slots) not in _ENGINES:
+        _ENGINES[scheduler, slots] = build()
+    return _ENGINES[scheduler, slots]
+
+
+def prompts(lengths, width=60, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = np.zeros((len(lengths), width), np.int32)
+    mask = np.zeros((len(lengths), width), np.int32)
+    for r, n in enumerate(lengths):
+        ids[r, width - n:] = rng.integers(1, 256, n)
+        mask[r, width - n:] = 1
+    return ids, mask
+
+
+@pytest.fixture
+def small_pieces(monkeypatch):
+    """Prefill in segments of 12 tokens (three pages of 4) under a window of 8:
+    a segment does NOT end on the window's edge, so 40- and 57-token prompts
+    cross every boundary the cell's 10k-20k-token prompts cross and one more:
+    the ring carried from segment to segment mid-window, a window that starts
+    in the segment before, the full layers over earlier segments' pages a page
+    of keys at a time, a last segment that is part padding."""
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.models import configs
+
+    # a "lane tile" of 16: a key of 24 is kept in 32 lanes, zeros after it, in
+    # the pages and in the rings, as the chip keeps 192 in 256
+    monkeypatch.setattr(configs, "KEY_ROW_LANES", 16)
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 12)
+    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # decode rows dense, segments grouped
+
+
+def worst_difference(params, lora, ids, mask, result, cfg=CFG):
+    worst = 0.0
+    for b in range(ids.shape[0]):
+        prompt = ids[b][mask[b] > 0]
+        rows = np.stack([np.concatenate([prompt, result.tokens[b, j]])
+                         for j in range(result.tokens.shape[1])])
+        want = reference_logprobs(params, lora, rows, np.ones_like(rows))
+        worst = max(worst, np.abs(result.logprobs[b] - want[:, len(prompt) - 1:]).max())
+    return worst
+
+
+def generate(engine, params, lora, lengths=(40, 57)):
+    ids, mask = prompts(lengths)
+    result = engine.generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=20),
+        jax.random.PRNGKey(3))
+    return ids, mask, result
+
+
+@pytest.mark.parametrize("scheduler,slots", [
+    ("refill", 4),  # 8 rows through 4 slots: a freed slot takes another prompt's rings
+    ("waves", 0),   # prefill, fan-out, lockstep
+])
+def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
+                                                      small_pieces):
+    """Both schedulers hold a model with 5 window layers of 4 KV heads and 2
+    full layers of 2: prefill in segments that end inside windows, each
+    prompt's rings COPIED (K's and V's at their widths) and its page chain
+    aliased to its 4 candidates, then 20 tokens a row, past the window, over
+    the slots' rings with their sink and the pages of two widths. The engine's
+    own captured log-probability of every token it sampled is the reference's;
+    the two gauges are what the pools' and the rings' shapes say."""
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    before = dict(telemetry.observe_snapshot()["counters"])
+    engine = make_engine(scheduler, slots)
+    ids, mask, result = generate(engine, params, lora)
+    assert (result.lengths == 20).all() and result.alive_slot_steps == 8 * 20
+    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    after = telemetry.observe_snapshot()
+    said = tuple(after["counters"][f"engine/window_pages_{k}"]
+                 - before.get(f"engine/window_pages_{k}", 0) for k in ("attended", "visible"))
+    model = dataclasses.asdict(CFG)
+    assert said == counts.window_pages(
+        model, [40] * 4 + [57] * 4, result.lengths.reshape(-1)) == (5 * 8 * 20,) * 2
+    routed = after["counters"]["engine/moe_pairs_routed"] - before.get(
+        "engine/moe_pairs_routed", 0)
+    assert routed == 6 * 3 * 8 * 20  # expert layers x choices x rows x steps: not layer 0
+    held = (slots or 8) * 5 * RING_BYTES
+    assert after["gauges"]["engine/slot_state_bytes"] == held
+    assert engine.last_round_stats["slot_state_bytes"] == held
+    # one more token of context: two full layers' K (its row) and V (its width)
+    assert after["gauges"]["engine/cache_token_bytes"] == 2 * TOKEN_BYTES
+    # the counts module says what the ALGORITHM moves: a key's own 24 values
+    assert counts.slot_state_bytes(model, kv_bytes=4) == 5 * 4 * 8 * (24 + 16) * 4
+    assert counts.cache_token_bytes(model, kv_bytes=4) == 2 * 2 * (24 + 16) * 4
+
+
+def test_every_paged_family_files_what_a_token_costs():
+    """The gauge is read off the pools' own shapes, K's and V's apart, so a V
+    array allocated at K's width shows in it; a dense model files its own."""
+    from distrl_llm_tpu.engine.paged_engine import _cache_token_bytes
+
+    k, v = CFG.page_pool_shape(6, 4), CFG.second_pool_shape(6, 4)
+    pools = lambda shape: tuple(jnp.zeros(shape, jnp.bfloat16) for _ in range(2))
+    assert _cache_token_bytes(pools(k), pools(v)) == 2 * 2 * (24 + 16) * 2
+    assert _cache_token_bytes(pools(k), pools(k)) == 2 * 2 * (24 + 24) * 2
+    dense = PRESETS["tiny"]  # 2 layers of 2 KV heads of 16
+    shape = (dense.num_kv_heads, 0, 8, dense.head_dim)
+    assert _cache_token_bytes(pools(shape), pools(shape)) == 2 * 2 * 2 * 16 * 2
+    assert _cache_token_bytes((jnp.zeros((5, 8, 640), jnp.bfloat16),), ()) == 640 * 2
+    assert _cache_token_bytes((), ()) == 0
+
+
+def test_the_paged_launch_takes_k_and_v_of_two_widths():
+    """The launch at a group of 4 with K wider than V (interpreted here; the
+    compile for the chip is tests/test_tpu_compile.py's): its output has V's
+    width and equals the reference's, and its pages a step count both widths."""
+    from distrl_llm_tpu.ops import paged, paged_native
+
+    rng = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(rng[0], (3, 8, 24))
+    k_pages = jax.random.normal(rng[1], (2, 12, 4, 24))
+    v_pages = jax.random.normal(rng[2], (2, 12, 4, 16))
+    table = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    lengths = jnp.asarray([16, 5, 9], jnp.int32)
+    want = paged.paged_attention_reference(q, k_pages, v_pages, lengths, table)
+    got = paged_native.paged_attention_native(
+        q * 24 ** -0.5, k_pages, v_pages, lengths, table, pages_per_block=2, interpret=True)
+    assert got.shape == want.shape == (3, 8, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # 192 takes two lane tiles beside V's one: 5 pages a step where 128 + 128 take 8
+    at = dict(num_kv_heads=4, page_size=128, pps=164)
+    assert paged_native.native_pages_per_step(head_dim=192, v_head_dim=128, **at) == 5
+    assert paged_native.native_pages_per_step(head_dim=128, **at) == 8
+
+
+def test_what_holds_k_and_v_of_one_kind_names_the_rings_it_cannot_hold():
+    from distrl_llm_tpu.engine.engine import GenerationEngine
+    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
+
+    paged = lambda **kw: PagedGenerationEngine(
+        CFG, max_prompt_tokens=60, max_new_tokens=8, eos_token_ids=[-1], pad_token_id=0,
+        scheduler="refill", max_concurrent_rows=4, autotune=False, **kw)
+    builds = {
+        "dense engine": lambda: GenerationEngine(
+            CFG, max_prompt_tokens=60, max_new_tokens=8, eos_token_ids=[-1],
+            pad_token_id=0, autotune=False),
+        "kv_quant": lambda: paged(kv_quant="int8"),
+        "spec_draft": lambda: paged(spec_draft=2),
+        "prefix_sharing": lambda: paged(continuous_admission=True, prefix_cache=True),
+        "max_kv_pages": lambda: paged(max_kv_pages=64),
+        "kv_spill": lambda: paged(kv_spill=True),
+    }
+    for what, build in builds.items():
+        with pytest.raises(ValueError) as e:
+            build()
+        said = str(e.value)
+        assert what in said and "full_attention, sliding_attention layers" in said
+        assert "a ring of the last sliding_window tokens' K and V and no page" in said

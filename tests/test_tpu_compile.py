@@ -690,6 +690,69 @@ def test_window_decode_step_at_published_widths(chip, monkeypatch):
     assert not _weight_sized_operations(text, sizes)
 
 
+def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
+    """``mimo-v2-flash-ep16-L7.rollout-longctx-sink-128``'s decode step (128
+    rows, a table of 164 pages, a rank-32 adapter) fed the decode view. The two
+    full layers' decode is the ``paged_attention_native`` launch, where the
+    ``kernel.*`` regexes look for it, at 4 KV heads and a GROUP OF 16, K in 256
+    lanes (a key's 192 values and zeros: ``ModelConfig.key_row``) and V at its
+    own 128: the output is V's width. The five window layers are plain XLA
+    over their rings of 8 KV heads with the sink's column. The ten rings and
+    the four pools are donated and written in place: no copy of a ring or of a
+    pool, and next to no temporaries. At K's own width of 192 the compiler
+    kept each K ring and K pool token-minor at the program's boundary and
+    copied it in and out a step (14 copies, 0.88 GB of temporaries: PERF.md
+    section 6, PR 60): the row of whole lane tiles is what this test holds."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    cfg = _cell_config("mimo-v2-flash-ep16-L7")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 128, 128, jnp.bfloat16
+    width = (20480 + 512) // page
+    pages = 8 * 160 + rows * 5 + 8
+    assert cfg.page_pool_shape(pages, page) == (4, pages, 128, 256)
+    assert cfg.second_pool_shape(pages, page) == (4, pages, 128, 128)
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    k_pool = chip(cfg.page_pool_shape(pages, page), bf)
+    v_pool = chip(cfg.second_pool_shape(pages, page), bf)
+    cache = {
+        "k": (k_pool, k_pool), "v": (v_pool, v_pool),
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("%paged_attention_native" in c for c in calls), calls
+    # rows, 4 KV heads, their groups of 16, V's width
+    assert all("bf16[128,4,16,128]" in c for c in calls), calls
+    entry = text[text.index("ENTRY "):]
+    for held in ("bf16[128,8,128,256]", "bf16[128,8,128,128]",
+                 f"bf16[4,{pages},128,256]", f"bf16[4,{pages},128,128]"):
+        assert held in entry, held
+        copies = [line.strip()[:160] for line in entry.splitlines()
+                  if " copy(" in line and held in line.split("(")[0]]
+        assert not copies, copies
+    rings = 5 * rows * 8 * 128 * (256 + 128) * 2
+    pools = 2 * 4 * pages * 128 * (256 + 128) * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= rings + pools
+    assert memory.temp_size_in_bytes < 64e6
+
+
 def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through
     four window layers, one full layer and four expert layers in the grouped
