@@ -26,6 +26,7 @@ are Qwen2.5-0.5B's own.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -400,6 +401,46 @@ def _latent_fold_case(key, *, rows, heads, nope, rope, v_dim, rank, segment, blo
     return {"impl": ran, "max_abs_err": float(f"{err:.3g}"), "folds": blocks}
 
 
+def _softmax_fold_case(key, *, rows, kv_heads, group, head_dim, key_row, v_dim, segment,
+                       page=128, blocks=3):
+    """A full-attention layer's prefill segment over the rows' K/V pages as
+    ``hybrid._segment_softmax`` dispatches it (the same fold kernel on a TPU,
+    ``group`` query heads a KV head, a key of ``head_dim`` in ``key_row`` lanes)
+    against the XLA form's folds of the same gathered blocks, the queries on
+    the last of ``blocks``; what ran is read from the dispatch record."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distrl_llm_tpu.models import hybrid
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    ks = jax.random.split(key, 4)
+    bf, heads, per = jnp.bfloat16, kv_heads * group, segment // page
+    pages = rows * blocks * per
+    q = jax.random.normal(ks[0], (rows, segment, heads, head_dim), bf)
+    pages_k = jax.random.normal(ks[1], (kv_heads, pages, page, key_row), bf)
+    pages_k = pages_k.at[..., head_dim:].set(0)
+    pages_v = jax.random.normal(ks[2], (kv_heads, pages, page, v_dim), bf)
+    idx = jax.random.permutation(ks[3], pages).reshape(rows, -1).astype(jnp.int32)
+    start = jnp.int32((blocks - 1) * segment)
+    got = jax.jit(lambda: hybrid._segment_softmax(q, pages_k, pages_v, idx, start, page))()
+    ran = la.dispatch_choices[la.dispatch_key(heads, key_row, 0, v_dim, segment, bf)]
+    carry = None
+    for j in range(blocks):
+        at = idx[:, j * per: (j + 1) * per]
+        held = lambda pool: pool[:, at].transpose(1, 0, 2, 3, 4).reshape(
+            rows, kv_heads, segment, -1)
+        carry = jax.jit(functools.partial(la.expanded_fold, scale=head_dim ** -0.5))(
+            hybrid._to_row(q, key_row), q[..., :0], (held(pages_k), held(pages_v)),
+            jnp.zeros((rows, segment, 0), bf), start, jnp.int32(j * segment), carry)
+    want = la.expanded_finish(carry, jnp.float32)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    # outputs of unit size rounded to bf16: half a unit in the last place of 2
+    assert np.isfinite(err) and err < 2e-2, f"softmax fold {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": float(f"{err:.3g}"), "folds": blocks}
+
+
 def phase_kernels(seed: int, compiles: CompileLog) -> None:
     """Each Pallas kernel the trainer phases use, compiled (never
     interpreted) at the 0.5B geometry, against its reference on the chip."""
@@ -459,6 +500,13 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
         key, rows=2, heads=64, nope=192, rope=64, v_dim=256, rank=512, segment=1024,
         choose=0.2)
     assert out["latent_fold_chosen"]["impl"] == "kernel", out["latent_fold_chosen"]
+    # the same kernel over K/V pages, the twelfth configuration's full layers
+    # (MiMo-V2-Flash): 16 query heads a KV head, a key of 192 in 256 lanes
+    # beside a value of 128
+    out["softmax_fold"] = _softmax_fold_case(
+        key, rows=2, kv_heads=4, group=16, head_dim=192, key_row=256, v_dim=128,
+        segment=1024)
+    assert out["softmax_fold"]["impl"] == "kernel", out["softmax_fold"]
     for name, tokens in (("expert_layer_decode", 64), ("expert_layer_grouped", 1024)):
         out[name] = _expert_layer_case(
             key, tokens=tokens, hidden=2048, width=1408, experts=64, per_token=6)

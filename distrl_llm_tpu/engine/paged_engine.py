@@ -326,28 +326,35 @@ def _record_power_telemetry(cfg: ModelConfig, steps: int) -> None:
         telemetry.OPS_POWER_KERNEL_STEPS, layers * steps * (ran == "kernel"))
 
 
-def _record_latent_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
-                             dtype, segments: int | None = None) -> None:
-    """``ops/latent_kernel_folds``: the folds of the round's prefill (one call,
-    every latent layer, segment ``j`` folding ``j + 1`` blocks of keys, whatever
-    stage runs it and for as many rows as the stage holds) that ran as the
-    Mosaic kernel, read from what ``expanded_segment`` recorded for this
-    model's heads and segment when the prefill was traced (0 where it took the
-    XLA form); ``dtype`` is the activations', the embedding's; ``segments`` the
-    longest row's, where the stages end (every segment of the prompt's width
-    where it is not given). A model without latent layers files nothing."""
-    if not cfg.latent:
+def _record_fold_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
+                           dtype, segments: int | None = None) -> None:
+    """``ops/latent_kernel_folds`` / ``ops/softmax_kernel_folds``: the folds of
+    the round's prefill (one call; segment ``j`` folds ``j + 1`` blocks of keys
+    in every layer that folds, whatever stage runs it and for as many rows as
+    the stage holds) that ran as the Mosaic kernel, read from what
+    ``expanded_segment`` recorded for the layers' head layout and segment when
+    the prefill was traced (0 where it took the XLA form). The first counter
+    is a latent model's (every layer; K ``nope + rope`` wide), the second that
+    of a model whose "softmax" and "cca" layers fold the rows' K/V pages
+    (``hybrid._segment_softmax``: a key ``key_row`` lanes wide, no rope part);
+    a model with neither files nothing. ``dtype`` is the activations', the
+    embedding's; ``segments`` the longest row's, where the stages end (every
+    segment of the prompt's width where it is not given)."""
+    if cfg.latent:
+        name, layers = telemetry.OPS_LATENT_KERNEL_FOLDS, cfg.num_layers
+        layout = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)
+    else:
+        name = telemetry.OPS_SOFTMAX_KERNEL_FOLDS
+        layers = cfg.mixer_count("softmax") + cfg.mixer_count("cca")
+        layout = (cfg.key_row, 0, cfg.value_head_dim)
+    if not layers:
         return
     from distrl_llm_tpu.ops.latent_attention import dispatch_choices, dispatch_key
 
     seg, n_seg = _hybrid_segments(prompt_pages, page_size)
     n_seg = n_seg if segments is None else segments
-    ran = dispatch_choices.get(dispatch_key(
-        cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-        seg, dtype))
-    telemetry.counter_add(
-        telemetry.OPS_LATENT_KERNEL_FOLDS,
-        cfg.num_layers * (n_seg * (n_seg + 1) // 2) * (ran == "kernel"))
+    ran = dispatch_choices.get(dispatch_key(cfg.num_heads, *layout, seg, dtype))
+    telemetry.counter_add(name, layers * (n_seg * (n_seg + 1) // 2) * (ran == "kernel"))
 
 
 def _record_index_telemetry(cfg: ModelConfig, steps: int, prompt_pages: int,
@@ -4359,7 +4366,7 @@ class PagedGenerationEngine(LoraMailbox):
         _record_delta_telemetry(self.cfg, dispatched)
         _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
         _record_power_telemetry(self.cfg, dispatched)
-        _record_latent_telemetry(
+        _record_fold_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
         _record_index_telemetry(
@@ -4491,7 +4498,7 @@ class PagedGenerationEngine(LoraMailbox):
         _record_delta_telemetry(self.cfg, steps_seen[0])
         _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
         _record_power_telemetry(self.cfg, steps_seen[0])
-        _record_latent_telemetry(
+        _record_fold_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
         _record_index_telemetry(

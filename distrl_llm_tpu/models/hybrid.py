@@ -103,7 +103,9 @@ beside one shared expert (``_mlp_half``), with no RoPE anywhere::
     x <- x + y;   x <- x + Shared(h') + sum_{k held here} w_k E_k(h')
 
 A softmax layer keeps K/V pages (decode through ``ops/paged.py``'s kernel, a
-prefill segment over the row's pages a few at a time); a delta layer keeps a
+prefill segment over the row's pages a segment's width of keys at a time: the
+fold ``ops/latent_attention.py::expanded_segment`` dispatches, the Mosaic
+kernel on a TPU); a delta layer keeps a
 THIRD kind of slot state: ``delta`` (a float32 ``[B, H, D, D]`` state) and
 ``conv`` (the last three tokens' ``[W_q h, W_k h, W_v h]``, ``[B, 3, 3 H D]``),
 tuples over the delta layers. ``moe_routed`` [1] int32 counts the pairs the
@@ -251,9 +253,6 @@ Params = dict[str, Any]
 #: for that row alone, and rows that walk their tables together
 LATENT_DECODE_PAGES = 8
 LATENT_DECODE_ROWS = 16
-#: pages of keys a softmax layer's prefill segment scores at a time (the
-#: scores of a segment of 8 x 1,024 queries over 64 heads: 0.5 GiB at 2)
-SOFTMAX_SEGMENT_PAGES = 2
 #: the entries of a slot's state that hold one array a ROW for each layer of a
 #: kind (tuples): what a candidate is handed from its prompt
 ROW_STATES = ("lin", "pooled", "delta", "conv", "power", "power_z", "ssm",
@@ -746,50 +745,32 @@ def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale, carried=None):
     return (x if carried is None else (x, carried)), stats
 
 
-def _segment_softmax(q, pages_k, pages_v, idx, q_pos, start, page_size: int):
+def _segment_softmax(q, pages_k, pages_v, idx, start, page_size: int):
     """A prefill segment's causal attention over the rows' PAGES (the
-    segment's own are written already), ``SOFTMAX_SEGMENT_PAGES`` pages of
-    keys at a time under a running softmax: the scores of all of a 2k-token
-    context at once would not fit. ``q [B, S, H, hd]`` -> ``[B, S, H, hv]``,
-    ``hv`` the value pages' width."""
+    segment's own are written already), a segment's width of keys at a time
+    under a running softmax: the scores of all of a 20k-token context at once
+    would not fit. ``q [B, S, H, hd]`` at positions ``start ..`` (every row
+    alike) -> ``[B, S, H, hv]``, ``hv`` the value pages' width. Each block of
+    keys is gathered from the pools once (25 MB at 8 rows of 4 KV heads) and
+    folded by ``expanded_segment``: the fold the latent layers run, handed a
+    GQA layer's head layout (K and V two arrays a KV head, no rope part: the
+    keys were rotated before they were written) and, where a key's row is
+    wider than its head, the head's own scale."""
     b, s, heads, hd = q.shape
-    kv, hv = pages_k.shape[0], pages_v.shape[-1]
-    per = max(d for d in range(1, SOFTMAX_SEGMENT_PAGES + 1)
-              if (s // page_size) % d == 0)
-    qg = q.reshape(b, s, kv, heads // kv, hd) * jnp.asarray(hd ** -0.5, q.dtype)
-    qg = _to_row(qg, pages_k.shape[-1])  # the lanes a key's row takes in a page
+    kv, per = pages_k.shape[0], s // page_size
+    head = jnp.arange(kv)[None, :, None]
+    no_rope = jnp.zeros((b, s, 0), q.dtype)
 
-    def keys(pages, at):  # [K, B, per, ps, hd] -> [B, per * ps, K, hd]
-        return pages[:, at].transpose(1, 2, 3, 0, 4).reshape(
-            b, per * page_size, kv, pages.shape[-1])
-
-    def fold(j, carry):
-        m, l, acc = carry
+    def block(j):
         with jax.named_scope(telemetry.ENGINE_KV_WRITE):
-            at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)
-            k, v = keys(pages_k, at).astype(q.dtype), keys(pages_v, at).astype(q.dtype)
-        scores = jnp.einsum("bskgd,bjkd->bkgsj", qg, k,
-                            preferred_element_type=jnp.float32)
-        seen = (j * per * page_size + jnp.arange(per * page_size))[None, None, :] <= (
-            q_pos[:, :, None])  # [B, S, j]
-        scores = jnp.where(seen[:, None, None], scores, -jnp.inf)
-        m_new = jnp.maximum(m, scores.max(-1))
-        # a query that has seen no key yet keeps a finite maximum
-        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        w = jnp.exp(scores - safe[..., None])
-        fix = jnp.exp(jnp.where(jnp.isfinite(m), m - safe, -jnp.inf))
-        acc = acc * fix[..., None] + jnp.einsum(
-            "bkgsj,bjkd->bkgsd", w.astype(q.dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, l * fix + w.sum(-1), acc
+            at = jax.lax.dynamic_slice_in_dim(idx, j * per, per, axis=1)[:, None]
+            held = lambda pages: pages[head, at].reshape(  # [B, K, per, ps, .]
+                b, kv, s, pages.shape[-1]).astype(q.dtype)
+            return (held(pages_k), held(pages_v)), no_rope
 
-    shape = (b, kv, heads // kv, s)
-    start_carry = (jnp.full(shape, -jnp.inf, jnp.float32), jnp.zeros(shape, jnp.float32),
-                   jnp.zeros(shape + (hv,), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(
-        0, (start + s) // (per * page_size), fold, start_carry)
-    o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, heads, hv).astype(q.dtype)
+    return expanded_segment(
+        _to_row(q, pages_k.shape[-1]),  # the lanes a key's row takes in a page
+        q[..., :0], block, start, pages_v.shape[-1], q.dtype, scale=hd ** -0.5)
 
 
 def _qkv_heads(x, p, lora, *, cfg, proj, lora_scale, mixer: str = "softmax"):
@@ -934,7 +915,7 @@ def _paged_softmax(q, k, v, cache, *, mode, env):
         pages_k = _write_segment_pages(pages_k, k, dest, ps)
         pages_v = _write_segment_pages(pages_v, v, dest, ps)
     with jax.named_scope(telemetry.MODEL_ATTN_CORE):
-        o = _segment_softmax(q, pages_k, pages_v, idx, env["q_pos"], start, ps)
+        o = _segment_softmax(q, pages_k, pages_v, idx, start, ps)
     return o, (pages_k, pages_v)
 
 

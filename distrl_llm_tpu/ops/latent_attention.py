@@ -16,9 +16,13 @@ has two forms, and which is cheaper depends on queries a cached token:
   ``expanded_attention`` elsewhere. The fold is ONE algorithm whose
   parameters are read off what it is handed: the head's layout off the
   shapes (K 128 + 64 wide beside V of 128 for Kimi-VL, 192 + 64 beside 256 for
-  GLM-5), and which keys a query attends off whether a choice arrived (a
+  GLM-5), which keys a query attends off whether a choice arrived (a
   learned index's mask, a tile of it read beside the tile of keys) or not
-  (the two positions' order);
+  (the two positions' order), and where the keys come from off what a block
+  is: one array a head, the latent's product through W_kvb, or a pair ``(k, v)``
+  a KV head, a block of the rows' K/V pages. The second is how a GQA layer's
+  prefill segment runs the same fold (``models/hybrid.py::_segment_softmax``:
+  several query heads a KV head, no rope part, the head's own scale);
 * **absorbed** (one query a row: decode): fold ``W_k`` into the query and
   ``W_v`` into the output, so every head attends over the latent row itself:
   ``scores = (q_nope W_k[h]^T) . c + q_pe . k_pe``, ``o = (sum p c) W_v[h]``.
@@ -61,8 +65,9 @@ from distrl_llm_tpu.ops.per_device import per_device
 
 _LANES = 128  # a VMEM tile's minor axis: the kernel takes whole tiles
 #: what each geometry's prefill segment resolved to, "kernel" or "xla", under
-#: ``dispatch_key``: the engine's counter ``ops/latent_kernel_folds`` and
-#: chip_smoke.py read it, so a run on the XLA form cannot pass for the kernel
+#: ``dispatch_key``: the engine's counters ``ops/latent_kernel_folds`` and
+#: ``ops/softmax_kernel_folds`` and chip_smoke.py read it, so a run on the XLA
+#: form cannot pass for the kernel
 dispatch_choices: dict[tuple, str] = {}
 
 
@@ -83,38 +88,63 @@ def split_kvb(w_kvb: jax.Array, heads: int, nope: int, v_dim: int):
     return w[..., :nope], w[..., nope:]
 
 
+def _heads_apart(kv, nope: int):
+    """One block's K and V, a KV head's keys together: ``(k [B, K, Sk, .],
+    v [B, K, Sk, .])``. A pair is that already (the rows' pages as a pool keeps
+    them); one array ``[B, Sk, H, nope + v]`` (the latent through W_kvb) is cut
+    at ``nope``."""
+    if isinstance(kv, tuple):
+        return kv
+    kv = kv.transpose(0, 2, 1, 3)
+    return kv[..., :nope], kv[..., nope:]
+
+
 def expanded_attention(
     q_nope: jax.Array,  # [B, Sq, H, nope]
     q_pe: jax.Array,  # [B, Sq, H, rope], rotated
-    kv: jax.Array,  # [B, Sk, H, nope + v]: the latent through W_kvb
+    kv,  # [B, Sk, H, nope + v]: the latent through W_kvb; or (k, v) a KV head
     k_pe: jax.Array,  # [B, Sk, rope], rotated
     mask: jax.Array,  # [B, Sq, Sk] bool; True = attend
     carry=None,
+    scale: float | None = None,
 ):
-    """Attention with K and V rebuilt per head, one block of keys folded into
-    a running softmax (flash-style, in XLA: the block's float32 scores are an
-    array in HBM). ``carry = (m [B, H, Sq], l [B, H, Sq], acc [B, Sq, H, v])``
-    in float32, ``None`` to start; ``expanded_finish`` gives ``[B, Sq, H, v]``.
+    """Attention with K and V a head, one block of keys folded into a running
+    softmax (flash-style, in XLA: the block's float32 scores are an array in
+    HBM). ``carry = (m [B, H, Sq], l [B, H, Sq], acc [B, Sq, H, v])`` in
+    float32, ``None`` to start; ``expanded_finish`` gives ``[B, Sq, H, v]``.
     ``full`` mode's one form (the learner's rows are one block, and this is
     what it differentiates); a prefill segment's where ``expanded_segment``
     does not take the kernel, and the kernel's reference. The shared ``k_pe``
-    is contracted on its own: it is never copied a head."""
+    is contracted on its own: it is never copied a head.
+
+    The head's layout is read off what arrives. ``kv`` one array is latent
+    attention's (K and V rebuilt a head from the latent); a pair ``(k [B, K, Sk,
+    nope], v [B, K, Sk, v])`` is a GQA layer's, K and V of their own widths as
+    the pages keep them, query head ``h`` reading KV head ``h // (H / K)``; a
+    rope part of width 0 is none (the keys were rotated before they were
+    kept). ``scale`` is the scores', ``(nope + rope)^-0.5`` where none is given
+    (a key kept in more lanes than its head has hands the head's own)."""
     b, sq, h, nope = q_nope.shape
-    m, l, acc = carry or expanded_start(b, sq, h, kv.shape[-1] - nope)
-    scale = (nope + q_pe.shape[-1]) ** -0.5
+    k, v = _heads_apart(kv, nope)
+    kh = k.shape[1]
+    m, l, acc = carry or expanded_start(b, sq, h, v.shape[-1])
+    if scale is None:
+        scale = (nope + q_pe.shape[-1]) ** -0.5
     scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q_nope, kv[..., :nope],
+        "bqkgd,bkjd->bkgqj", q_nope.reshape(b, sq, kh, h // kh, nope), k,
         preferred_element_type=jnp.float32,
-    ) + jnp.einsum(
+    ).reshape(b, h, sq, -1) + jnp.einsum(
         "bqhd,bkd->bhqk", q_pe, k_pe, preferred_element_type=jnp.float32)
     scores = jnp.where(mask[:, None], scores * scale, NEG_INF)
     m_new = jnp.maximum(m, scores.max(axis=-1))
     p = jnp.where(mask[:, None], jnp.exp(scores - m_new[..., None]), 0.0)
     fix = jnp.exp(m - m_new)
     l = l * fix + p.sum(axis=-1)
+    # the product head-major, then transposed: the CPU's dot refuses bf16
+    # operands where the einsum itself names the token-major result
     acc = acc * fix.transpose(0, 2, 1)[..., None] + jnp.einsum(
-        "bhqk,bkhd->bqhd", p.astype(kv.dtype), kv[..., nope:],
-        preferred_element_type=jnp.float32)
+        "bkgqj,bkjd->bkgqd", p.astype(v.dtype).reshape(b, kh, h // kh, sq, -1), v,
+        preferred_element_type=jnp.float32).reshape(b, h, sq, -1).transpose(0, 2, 1, 3)
     return m_new, l, acc
 
 
@@ -130,10 +160,12 @@ def expanded_segment_impl(q_nope: jax.Array, v_dim: int) -> str:
     take: "kernel" on a TPU backend for bf16 operands whose segment and values
     are whole 128-lane tiles and whose keys' width is whole or half tiles
     (GLM-5's 192: the kernel reads a head's ``[K | V]`` as one block as wide as
-    the array, and cuts it in VMEM), "xla" otherwise (the CPU, the tests' tiny
-    heads, float32). K and V need not be one width, and a learned index's
-    choice changes nothing here. On the TPU nothing falls back: a kernel that
-    fails to lower fails the segment that called it."""
+    the array, and cuts it in VMEM; a GQA layer's key row is whole tiles by
+    ``ModelConfig.key_row``), "xla" otherwise (the CPU, the tests' tiny heads,
+    float32). K and V need not be one width, and neither a learned index's
+    choice nor the KV heads a layer's queries share change anything here. On
+    the TPU nothing falls back: a kernel that fails to lower fails the segment
+    that called it."""
     s, nope = q_nope.shape[1], q_nope.shape[-1]
     whole = nope % (_LANES // 2) == 0 and v_dim % _LANES == 0 and s % _LANES == 0
     if jax.default_backend() == "tpu" and q_nope.dtype == jnp.bfloat16 and whole:
@@ -142,12 +174,16 @@ def expanded_segment_impl(q_nope: jax.Array, v_dim: int) -> str:
 
 
 def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
-                     chosen=None) -> jax.Array:
+                     chosen=None, scale: float | None = None) -> jax.Array:
     """A prefill segment's attention, ``[B, S, H, v]``: queries at positions
     ``start ..`` (every row alike) over the blocks ``0 .. start // S`` of ``S``
     keys each, block ``j`` at positions ``j * S ..`` and the last one the
     segment's own; a query sees the keys at or before it. ``block(j)`` gives
-    ``(kv [B, S, H, nope + v], k_pe [B, S, rope])`` of block ``j``. Each block
+    ``(kv, k_pe [B, S, rope])`` of block ``j``, ``kv`` as ``expanded_attention``
+    takes it: ``[B, S, H, nope + v]`` (latent attention: the block's product
+    through W_kvb) or a GQA layer's pair ``(k [B, K, S, nope], v [B, K, S, v])``
+    (the rows' K and V pages; ``q_pe`` and ``k_pe`` of width 0, ``scale`` the
+    head's where a key's row is wider than its head). Each block
     is folded into the segment's running softmax by the form
     ``expanded_segment_impl`` names (recorded in ``dispatch_choices``): one
     ``expanded_fold_kernel`` launch, whose scores never leave VMEM and whose
@@ -168,7 +204,7 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
         one = expanded_fold
     carry = jax.lax.fori_loop(
         0, start // s + 1,
-        lambda j, carry: one(*queries, *block(j), start, j * s, carry, chosen),
+        lambda j, carry: one(*queries, *block(j), start, j * s, carry, chosen, scale=scale),
         first(b, s, h, v_dim))
     return finish(carry, dtype)
 
@@ -179,7 +215,8 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
 FOLD_MASK_DTYPE = jnp.int8
 
 
-def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=None):
+def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=None,
+                  *, scale: float | None = None):
     """``expanded_attention`` with the mask given as two positions: the queries
     stand at ``q_start ..``, the keys at ``k_start ..``, and a query sees the
     keys at or before it (every row alike); or, where ``chosen [B, S, keys]`` is
@@ -187,13 +224,13 @@ def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=N
     kernel``'s reference, argument for argument after the queries (the kernel
     takes ``fold_queries``' one array for these two)."""
     b, sq = q_nope.shape[:2]
-    sk = kv.shape[1]
+    sk = k_pe.shape[1]
     if chosen is None:
         mask = (k_start + jnp.arange(sk))[None, :] <= (q_start + jnp.arange(sq))[:, None]
         mask = jnp.broadcast_to(mask, (b, sq, sk))
     else:
         mask = jax.lax.dynamic_slice_in_dim(chosen, k_start, sk, axis=2) != 0
-    return expanded_attention(q_nope, q_pe, kv, k_pe, mask, carry)
+    return expanded_attention(q_nope, q_pe, kv, k_pe, mask, carry, scale)
 
 
 #: queries and keys of one tile of ``expanded_fold_kernel``: the widest
@@ -206,7 +243,15 @@ def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=N
 #: pair (PERF.md §6, PR 57): 3.153 ms at 1,024 x 1,024 and 3.293 at 512 x 1,024;
 #: with ``k_pe`` contracted in a tile of its own 3.499, 3.503 at 1,024 x 512,
 #: 3.589 at 512 x 1,024, 3.763 at 512 x 512, the choice as bf16 3.488, K and V
-#: sliced apart by XLA before the launch 4.427; the XLA form 10.05
+#: sliced apart by XLA before the launch 4.427; the XLA form 10.05.
+#: Over a GQA layer's K/V pages (PERF.md §6, PR 61; a fold of 1,024 keys with its
+#: gather of the block's pages, 25 MB at 8 rows of 4 KV heads): at [8, 4 x 16,
+#: 1024] with K 256 lanes / V 128, 3.424 ms at 1,024 x 1,024 (61% of the matrix
+#: unit's peak), 3.640 at 512 x 1,024, 5.422 at 1,024 x 512, 5.162 at 512 x 512;
+#: THE OTHER SOURCE OF KEYS, a page a tile read from the pool where it lies through
+#: a scalar-prefetched page table (tiles of 128 keys), 12.455 at 1,024 queries and
+#: 15.795 at 512; the XLA form 14.451. At [4, 8 x 8, 1024] with 128 / 128: 1.386,
+#: 1.479 at 512 x 1,024, the table's 4.919, the XLA form 6.177
 FOLD_TILE_Q = 1024
 FOLD_TILE_K = 1024
 
@@ -265,13 +310,16 @@ def _row(col: jax.Array) -> jax.Array:
     return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
 
 
-def _fold_body(pos_ref, q_ref, kv_ref, kp_ref, m_in, l_in, acc_in, *rest,
-               scale: float, nope: int):
+def _fold_body(pos_ref, *refs, names: tuple, scale: float, nope: int):
     """One (row, head, tile of queries) against one tile of keys: the tile's
-    scores, their maximum and their sum live and die in VMEM. Which keys a
-    query attends is the tile of the choice where the launch was handed one
-    (``chosen_ref``, causality in it), the two positions' order otherwise."""
-    *chosen_ref, m_out, l_out, acc_out, m_s, l_s, acc_s = rest
+    scores, their maximum and their sum live and die in VMEM. ``refs`` are the
+    launch's operands, results and scratch under ``names``. The tile of keys is
+    ``kv`` (K, then V where no ``v`` came apart from it) and, where the head
+    has lanes past K's whole tiles, ``k_pe``. Which keys a query attends is the
+    tile of the choice where the launch was handed one (``chosen``, causality
+    in it), the two positions' order otherwise."""
+    ref = dict(zip(names, refs))
+    q_ref, kv_ref, acc_s = ref["q"], ref["kv"], ref["acc_s"]
     tq, tk, v_dim = q_ref.shape[0], kv_ref.shape[0], acc_s.shape[1]
     whole = nope // _LANES * _LANES  # K's whole tiles; the rest shares ``k_pe``'s
     ki = pl.program_id(3)
@@ -280,48 +328,52 @@ def _fold_body(pos_ref, q_ref, kv_ref, kp_ref, m_in, l_in, acc_in, *rest,
 
     @pl.when(ki == 0)
     def _():
-        m_s[...] = _column(m_in[...])
-        l_s[...] = _column(l_in[...])
-        acc_s[...] = acc_in[...]
+        ref["m_s"][...] = _column(ref["m"][...])
+        ref["l_s"][...] = _column(ref["l"][...])
+        acc_s[...] = ref["acc"][...]
 
     def fold(masked: bool):
         nt = (((1,), (1,)), ((), ()))  # q . k^T
-        # the keys' last tile: K's values past its whole tiles, then ``k_pe``
-        # (which arrives at those lanes, zeros before it)
-        last = kp_ref[...]
-        if nope > whole:
-            lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
-            last = jnp.where(lane < nope - whole, kv_ref[:, whole: whole + last.shape[1]], last)
-        s = jax.lax.dot_general(
-            q_ref[:, whole:], last, nt, preferred_element_type=jnp.float32)
-        if whole:
+        s = None
+        if "k_pe" in ref:
+            # the keys' last tile: K's values past its whole tiles, then ``k_pe``
+            # (which arrives at those lanes, zeros before it)
+            last = ref["k_pe"][...]
+            if nope > whole:
+                lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
+                last = jnp.where(
+                    lane < nope - whole, kv_ref[:, whole: whole + last.shape[1]], last)
             s = jax.lax.dot_general(
+                q_ref[:, whole:], last, nt, preferred_element_type=jnp.float32)
+        if whole:
+            whole_s = jax.lax.dot_general(
                 q_ref[:, :whole], kv_ref[:, :whole], nt,
-                preferred_element_type=jnp.float32) + s
+                preferred_element_type=jnp.float32)
+            s = whole_s if s is None else whole_s + s
         s = s * scale
         if masked:
-            if chosen_ref:
-                seen = chosen_ref[0][...].astype(jnp.int32) != 0
+            if "chosen" in ref:
+                seen = ref["chosen"][...].astype(jnp.int32) != 0
             else:
                 seen = (k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
                         <= q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0))
             s = jnp.where(seen, s, NEG_INF)
-        m = m_s[...]
+        m = ref["m_s"][...]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         if masked:  # a query that has seen no key yet: exp(0) of masked scores
             p = jnp.where(seen, p, 0.0)
         fix = jnp.exp(m - m_new)
-        m_s[...] = m_new
-        l_s[...] = l_s[...] * fix + p.sum(axis=1, keepdims=True)
+        ref["m_s"][...] = m_new
+        ref["l_s"][...] = ref["l_s"][...] * fix + p.sum(axis=1, keepdims=True)
+        values = ref["v"][...] if "v" in ref else kv_ref[:, nope: nope + v_dim]
         acc_s[...] = acc_s[...] * fix + jnp.dot(
-            p.astype(kv_ref.dtype), kv_ref[:, nope: nope + v_dim],
-            preferred_element_type=jnp.float32)
+            p.astype(values.dtype), values, preferred_element_type=jnp.float32)
 
     # a tile of keys that no query of the tile sees is skipped (it changes
     # nothing); a choice is read wherever a query sees a key, and of the
     # positions' tiles the one that every query sees whole needs no mask
-    if chosen_ref:
+    if "chosen" in ref:
         pl.when(k0 <= q0 + tq - 1)(functools.partial(fold, True))
     else:
         pl.when(k0 + tk - 1 <= q0)(functools.partial(fold, False))
@@ -329,9 +381,9 @@ def _fold_body(pos_ref, q_ref, kv_ref, kp_ref, m_in, l_in, acc_in, *rest,
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
-        m_out[...] = _row(m_s[...])
-        l_out[...] = _row(l_s[...])
-        acc_out[...] = acc_s[...]
+        ref["m_out"][...] = _row(ref["m_s"][...])
+        ref["l_out"][...] = _row(ref["l_s"][...])
+        ref["acc_out"][...] = acc_s[...]
 
 
 #: what Mosaic gives a kernel's buffers unasked on a v5e; a launch whose tiles
@@ -339,26 +391,25 @@ def _fold_body(pos_ref, q_ref, kv_ref, kp_ref, m_in, l_in, acc_in, *rest,
 _VMEM_DEFAULT = 16 << 20
 
 
-def _fold_vmem(tq: int, tk: int, q_width: int, kv_width: int, last: int, v_dim: int,
-               chosen: bool):
+def _fold_vmem(tq: int, tk: int, q_width: int, kv_width: int, v_dim: int, chosen: bool):
     """``vmem_limit_bytes`` of a fold's launch, from its tiles: ``None`` where
     the default holds them (16 heads of 128 at 1,024 x 1,024: 15 MB by this
     count), twice the count otherwise (64 heads of 192 + 256 under a choice:
     25 MB by this count, 21.5 MB by Mosaic's, which refuses the launch under
     the default). Counted: the operands' blocks twice (the pipeline's two
-    buffers), the accumulator's scratch, and a tile's float32 scores, their
-    weights, the weights' bf16 cast and the choice widened to the scores'
-    layout."""
-    blocks = 2 * (tq * q_width + tk * (_whole_lanes(kv_width) + last))
+    buffers; ``kv_width`` the lanes of a key's K, V and last tile together),
+    the accumulator's scratch, and a tile's float32 scores, their weights, the
+    weights' bf16 cast and the choice widened to the scores' layout."""
+    blocks = 2 * (tq * q_width + tk * kv_width)
     blocks += 2 * tq * v_dim * 4 + chosen * tq * tk  # the carry in and out, the choice
     need = 2 * blocks + tq * v_dim * 4 + tq * tk * (4 + 4 + 2 + 4 * chosen)
     return None if need <= _VMEM_DEFAULT else 2 * need
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "tile_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "tile_q", "tile_k", "interpret"))
 def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
-                         *, tile_q: int = FOLD_TILE_Q, tile_k: int = FOLD_TILE_K,
-                         interpret: bool = False):
+                         *, scale: float | None = None, tile_q: int = FOLD_TILE_Q,
+                         tile_k: int = FOLD_TILE_K, interpret: bool = False):
     """``expanded_attention``'s fold of one block of keys as one Mosaic kernel
     (a TPU; ``interpret`` for the CPU's tests). Without ``chosen`` the mask is
     two positions: the queries stand at ``q_start ..``, the keys at ``k_start
@@ -366,19 +417,28 @@ def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
     keys]`` (``FOLD_MASK_DTYPE``; non-zero = attend, causality in it) a tile of
     it is read beside the tile of keys, the key axis at ``k_start ..``, and the
     positions only say which tiles lie wholly above the diagonal. ``q [B, H, S,
-    lanes]`` from ``fold_queries``, ``kv [B, Sk, H, nope + v]`` and ``k_pe [B,
-    Sk, rope]`` as ``expanded_attention`` takes them, ``carry`` from
-    ``fold_start`` or an earlier fold, updated in place.
+    lanes]`` from ``fold_queries``, ``kv`` and ``k_pe [B, Sk, rope]`` as
+    ``expanded_attention`` takes them, ``carry`` from ``fold_start`` or an
+    earlier fold, updated in place; ``scale`` the scores', ``(nope + rope)^-0.5``
+    where none is given.
 
-    The head's layout is read off the shapes: ``v`` off the carry, ``nope`` off
-    ``kv`` less ``v``. K and V are the two parts of a head's block of ``kv``,
-    ONE operand cut apart in VMEM (the product through W_kvb is written once, a
-    head's keys together; sliced apart by XLA before the launch each part is a
-    pass over it). ``k_pe`` stays one vector a key, never copied a head in
-    HBM: it arrives in the lanes that K's last tile leaves unfilled and joins
-    that tile in VMEM, so that 192 + 64 is two whole tiles of one contraction
-    (three matrix passes where K and ``k_pe`` are contracted apart: 3.50 ->
-    3.15 ms a fold), and 128 + 64 a tile of K and a tile of ``k_pe``.
+    The head's layout is read off what arrives. ``kv [B, Sk, H, nope + v]``
+    (latent attention): ``v`` off the carry, ``nope`` off ``kv`` less ``v``; K
+    and V are the two parts of a head's block of ``kv``, ONE operand cut apart
+    in VMEM (the product through W_kvb is written once, a head's keys together;
+    sliced apart by XLA before the launch each part is a pass over it).
+    ``kv = (k [B, K, Sk, nope], v [B, K, Sk, v])`` (a GQA layer: a block of the
+    rows' K and V pages, gathered once a fold): two operands of their own
+    widths, and query head ``h``'s tile of keys is KV head ``h // (H / K)``'s,
+    an index map: the heads of a group follow one another in the grid and name
+    the same block, which the pipeline then copies once. ``k_pe`` stays one
+    vector a key, never copied a head in HBM: it arrives in the lanes that K's
+    last tile leaves unfilled and joins that tile in VMEM, so that 192 + 64 is
+    two whole tiles of one contraction (three matrix passes where K and
+    ``k_pe`` are contracted apart: 3.50 -> 3.15 ms a fold), and 128 + 64 a tile
+    of K and a tile of ``k_pe``; where K fills its tiles and there is no rope
+    part (a GQA layer's 128 or 256 lanes) there is no such tile and no such
+    operand.
 
     Grid (B, H, tiles of queries, tiles of keys), the keys innermost: a tile of
     queries keeps its running softmax in VMEM from its first tile of keys to
@@ -389,15 +449,18 @@ def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
     can see are neither copied nor computed; a query whose tile holds none of
     its choices keeps its ``(m, l, acc)``."""
     b, h, s, q_width = q.shape
-    sk, v_dim, rope = kv.shape[1], carry[2].shape[-1], k_pe.shape[-1]
-    nope = kv.shape[-1] - v_dim
+    sk, v_dim, rope = k_pe.shape[1], carry[2].shape[-1], k_pe.shape[-1]
+    # a head's keys together; ``values`` where V is an operand of its own
+    kv, values = kv if isinstance(kv, tuple) else (kv.transpose(0, 2, 1, 3), None)
+    apart = values is not None
+    group = h // kv.shape[1]  # query heads that read one head of ``kv``
+    nope = kv.shape[-1] - (0 if apart else v_dim)
     whole = nope // _LANES * _LANES
     last = q_width - whole  # the keys' last tile: K past its whole tiles, then k_pe
     tq, tk = _tile(s, tile_q), _tile(sk, tile_k)
     pos = jnp.stack([q_start, k_start]).astype(jnp.int32)
-    scale = (nope + rope) ** -0.5
-    k_pe = jnp.pad(k_pe, ((0, 0), (0, 0), (nope - whole, last - (nope - whole) - rope)))
-    kv = kv.transpose(0, 2, 1, 3)  # [B, H, Sk, nope + v]: a head's keys together
+    if scale is None:
+        scale = (nope + rope) ** -0.5
     # the tests' tiny heads: the last tile is read whole out of kv's row
     kv = jnp.pad(kv, ((0, 0), (0, 0), (0, 0), (0, max(0, q_width - kv.shape[-1]))))
 
@@ -408,41 +471,47 @@ def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
         return jnp.minimum(j, last)
 
     of_q = lambda w: pl.BlockSpec((None, None, tq, w), lambda b, h, i, j, pos: (b, h, i, 0))
-    of_kv = pl.BlockSpec(
-        (None, None, tk, kv.shape[-1]), lambda b, h, i, j, pos: (b, h, keys(i, j, pos), 0))
+    of_kv = lambda w: pl.BlockSpec(
+        (None, None, tk, w), lambda b, h, i, j, pos: (b, h // group, keys(i, j, pos), 0))
     stat = pl.BlockSpec((None, None, 1, tq), lambda b, h, i, j, pos: (b, h, 0, i))
-    # the choice's tile beside the tile of keys (``k_start`` is a multiple of
-    # the block, the block of ``tk``). Its key axis is the page table's width
-    # (20,992 positions in GLM-5's cell: no multiple of the tile), but a fold's
-    # keys end at or before the segment's own, which whole blocks hold: the
-    # ragged last tile is never named
-    choice = () if chosen is None else (chosen,)
-    of_choice = pl.BlockSpec(
-        (None, tq, tk), lambda b, h, i, j, pos: (b, i, pos[1] // tk + keys(i, j, pos)))
+    operands = {"q": (q, of_q(q_width)), "kv": (kv, of_kv(kv.shape[-1]))}
+    if apart:
+        operands["v"] = (values, of_kv(v_dim))
+    if last:
+        operands["k_pe"] = (
+            jnp.pad(k_pe, ((0, 0), (0, 0), (nope - whole, last - (nope - whole) - rope))),
+            pl.BlockSpec((None, tk, last), lambda b, h, i, j, pos: (b, keys(i, j, pos), 0)))
+    held = len(operands) + 1  # where the carry stands among the launch's arguments
+    operands.update(zip(("m", "l", "acc"), zip(carry, (stat, stat, of_q(v_dim)))))
+    if chosen is not None:
+        # the choice's tile beside the tile of keys (``k_start`` is a multiple of
+        # the block, the block of ``tk``). Its key axis is the page table's width
+        # (20,992 positions in GLM-5's cell: no multiple of the tile), but a fold's
+        # keys end at or before the segment's own, which whole blocks hold: the
+        # ragged last tile is never named
+        operands["chosen"] = (chosen, pl.BlockSpec(
+            (None, tq, tk), lambda b, h, i, j, pos: (b, i, pos[1] // tk + keys(i, j, pos))))
+    names = (*operands, "m_out", "l_out", "acc_out", "m_s", "l_s", "acc_s")
     return tuple(pl.pallas_call(
-        functools.partial(_fold_body, scale=scale, nope=nope),
+        functools.partial(_fold_body, names=names, scale=scale, nope=nope),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h, s // tq, sk // tk),
-            in_specs=[
-                of_q(q_width), of_kv,
-                pl.BlockSpec((None, tk, last),
-                             lambda b, h, i, j, pos: (b, keys(i, j, pos), 0)),
-                stat, stat, of_q(v_dim), *[of_choice] * len(choice),
-            ],
+            in_specs=[spec for _, spec in operands.values()],
             out_specs=[stat, stat, of_q(v_dim)],
             scratch_shapes=[pltpu.VMEM((tq, 1), jnp.float32),
                             pltpu.VMEM((tq, 1), jnp.float32),
                             pltpu.VMEM((tq, v_dim), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32) for x in carry],
-        input_output_aliases={4: 0, 5: 1, 6: 2},  # the carry, in place
+        input_output_aliases={held + i: i for i in range(3)},  # the carry, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_fold_vmem(
-                tq, tk, q_width, kv.shape[-1], last, v_dim, chosen is not None)),
+                tq, tk, q_width, _whole_lanes(kv.shape[-1]) + apart * v_dim + last, v_dim,
+                chosen is not None)),
         interpret=interpret,
-    )(pos, q, kv, k_pe, *carry, *choice))
+    )(pos, *(x for x, _ in operands.values())))
 
 
 def expanded_start(b: int, sq: int, heads: int, v_dim: int):

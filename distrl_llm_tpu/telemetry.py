@@ -265,6 +265,12 @@ OPS_POWER_KERNEL_STEPS = "ops/power_kernel_steps"
 # small heads, float32). Filed beside the three counters above; no metric
 # reads it
 OPS_LATENT_KERNEL_FOLDS = "ops/latent_kernel_folds"
+# counter: the same for the layers whose prefill folds the rows' K/V pages
+# (models/hybrid.py::_segment_softmax, the same ``expanded_fold_kernel`` handed
+# a GQA layer's head layout): "softmax" and "cca" layers x the prefill's folds
+# where ``expanded_segment`` chose the kernel, 0 where it took the XLA form.
+# Filed beside the counter above; no metric reads it
+OPS_SOFTMAX_KERNEL_FOLDS = "ops/softmax_kernel_folds"
 # counter: a round's choices of a learned index that ran by counting
 # (ops/token_index.py::kth_largest, no sort): layers x the decode steps whose
 # row sees more than ``index_topk`` columns, plus layers x the prefill's

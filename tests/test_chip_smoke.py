@@ -80,6 +80,21 @@ def test_the_latent_fold_check_holds_at_tiny_size(heads):
     assert case["impl"] == "xla" and case["folds"] == 3 and case["max_abs_err"] < 2e-2
 
 
+@pytest.mark.parametrize("heads", [
+    dict(kv_heads=2, group=4, head_dim=16, key_row=16, v_dim=16),
+    dict(kv_heads=1, group=5, head_dim=24, key_row=32, v_dim=16),  # a padded key row
+], ids=["a_head_a_row", "a_padded_row"])
+def test_the_softmax_fold_check_holds_at_tiny_size(heads):
+    """The kernels phase's check of a full layer's prefill folds over K/V
+    pages, here on the form a CPU takes ("xla": the chip's phase asserts
+    "kernel")."""
+    import jax
+
+    case = chip_smoke._softmax_fold_case(
+        jax.random.PRNGKey(0), rows=2, segment=16, page=8, **heads)
+    assert case["impl"] == "xla" and case["folds"] == 3 and case["max_abs_err"] < 2e-2
+
+
 def test_the_delta_step_check_holds_at_tiny_size():
     """The kernels phase's check of the one-token delta rule, here on the form
     a CPU takes: the dispatch record says "plain", which the chip's phase

@@ -416,7 +416,6 @@ def small_pieces(monkeypatch):
     from distrl_llm_tpu.engine import paged_engine
 
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
     assert moe.DENSE_MAX_TOKENS == 8  # 8 decode rows dense, 32-token segments grouped
 
 

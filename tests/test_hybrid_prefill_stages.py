@@ -73,7 +73,6 @@ def small_pieces(monkeypatch):
     """Segments of 16 tokens in pages of 8 and every inner piece as small, so
     that 64-token prompts cross every boundary the cells' 20k-token ones do."""
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", SEGMENT)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # a segment's tokens grouped
     monkeypatch.setattr(power_retention, "DEFAULT_CHUNK", SEGMENT)
     monkeypatch.setattr(selective_scan, "DEFAULT_CHUNK", SEGMENT)
@@ -359,7 +358,7 @@ def test_the_folds_filed_are_those_the_stages_ran(monkeypatch, longest, folds):
         SEGMENT, jnp.float32): "kernel"})
     filed = []
     monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
-    paged_engine._record_latent_telemetry(cfg, PAGES, PAGE, jnp.float32, longest)
+    paged_engine._record_fold_telemetry(cfg, PAGES, PAGE, jnp.float32, longest)
     assert filed == [("ops/latent_kernel_folds", cfg.num_layers * folds)]
 
 

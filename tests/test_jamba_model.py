@@ -413,7 +413,6 @@ def small_pieces(monkeypatch):
     from distrl_llm_tpu.engine import paged_engine
 
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
 
 
 def worst_difference(params, lora, ids, mask, result):

@@ -844,12 +844,12 @@ def test_the_counter_is_layers_times_folds_where_the_kernel_ran(monkeypatch, ran
         la.dispatch_key(4, 16, 8, 16, 32, jnp.float32): "kernel"})  # another segment's
     filed = []
     monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
-    paged_engine._record_latent_telemetry(CFG, 8, 8, jnp.float32)
+    paged_engine._record_fold_telemetry(CFG, 8, 8, jnp.float32)
     assert filed == [("ops/latent_kernel_folds", want)]
     assert paged_engine._hybrid_segments(8, 8) == (16, 4)
     # a model without latent layers files nothing
     filed.clear()
-    paged_engine._record_latent_telemetry(PRESETS["tiny"], 8, 8, jnp.float32)
+    paged_engine._record_fold_telemetry(PRESETS["tiny"], 8, 8, jnp.float32)
     assert filed == []
 
 

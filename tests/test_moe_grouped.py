@@ -186,12 +186,11 @@ def test_a_round_files_the_blocks_its_prefill_ran_and_laid(scheduler, slots, mon
     from distrl_llm_tpu import telemetry
     from distrl_llm_tpu.config import SamplingConfig
     from distrl_llm_tpu.engine import paged_engine
-    from distrl_llm_tpu.models import hybrid, init_lora_params, init_params
+    from distrl_llm_tpu.models import init_lora_params, init_params
     from distrl_llm_tpu.models.configs import PRESETS
 
     cfg = PRESETS["tiny-exaone-moe"]
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
     params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
     lora = init_lora_params(jax.random.PRNGKey(1), cfg, rank=4)

@@ -536,7 +536,6 @@ def small_pieces(monkeypatch):
     # the pages and in the rings, as the chip keeps 192 in 256
     monkeypatch.setattr(configs, "KEY_ROW_LANES", 16)
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 12)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # decode rows dense, segments grouped
 
 
@@ -598,6 +597,62 @@ def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
     # the counts module says what the ALGORITHM moves: a key's own 24 values
     assert counts.slot_state_bytes(model, kv_bytes=4) == 5 * 4 * 8 * (24 + 16) * 4
     assert counts.cache_token_bytes(model, kv_bytes=4) == 2 * 2 * (24 + 16) * 4
+
+
+@pytest.mark.parametrize("form,folds", [("xla", 0), ("kernel", 2 * 15)])
+def test_a_round_files_the_full_layers_folds_that_ran_as_the_kernel(
+        weights, small_pieces, monkeypatch, form, folds):
+    """``ops/softmax_kernel_folds`` beside the other kernels' counters: 0 on the
+    CPU's own path (float32 heads of 24 in 32 lanes take the XLA form, and
+    ``expanded_segment`` says so under the full layers' geometry), and with the
+    dispatch answered for and the kernel interpreted, 2 full layers x the
+    1 + 2 + 3 + 4 + 5 folds of a 57-token prompt in segments of 12, the
+    captured log-probabilities still the reference's."""
+    import functools
+
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    if form == "kernel":  # a program of its own, traced under the answer
+        monkeypatch.setattr(la, "expanded_segment_impl", lambda q_nope, v_dim: "kernel")
+        monkeypatch.setattr(la, "expanded_fold_kernel", functools.partial(
+            la.expanded_fold_kernel, interpret=True))
+        monkeypatch.setattr(la, "dispatch_choices", {})
+    name = telemetry.OPS_SOFTMAX_KERNEL_FOLDS
+    before = telemetry.observe_snapshot()["counters"].get(name, 0)
+    ids, mask, result = generate(
+        make_engine("waves", 0, bent=form == "kernel"), params, lora)
+    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    assert la.dispatch_choices[la.dispatch_key(8, KEY_ROW, 0, 16, 12, jnp.float32)] == form
+    assert telemetry.observe_snapshot()["counters"][name] - before == folds  # filed, even 0
+
+
+@pytest.mark.parametrize("preset,longest,want", [
+    ("tiny-swa-sink-moe", None, 2 * 15), ("tiny-swa-sink-moe", 3, 2 * 6),
+    ("tiny-cca", None, 3 * 15), ("tiny-jamba", 1, 1), ("tiny", None, None)])
+def test_the_counter_is_the_paged_softmax_layers_times_the_folds(
+        monkeypatch, preset, longest, want):
+    """"softmax" and "cca" layers x the folds the stages ran (to the longest
+    row's segments), under the layers' own key: query heads, the key's row, no
+    rope part, the value's width. A dense model files nothing (a latent
+    model's counter is its own: tests/test_latent_moe.py)."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    cfg = PRESETS[preset]
+    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 12)
+    monkeypatch.setattr(la, "dispatch_choices", {la.dispatch_key(
+        cfg.num_heads, cfg.key_row, 0, cfg.value_head_dim, 12, jnp.float32): "kernel"})
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_fold_telemetry(cfg, 15, 4, jnp.float32, longest)
+    assert filed == ([] if want is None else [("ops/softmax_kernel_folds", want)])
+    filed.clear()
+    la.dispatch_choices.clear()  # the XLA form, or a prefill never traced
+    paged_engine._record_fold_telemetry(cfg, 15, 4, jnp.float32, longest)
+    assert filed == ([] if want is None else [("ops/softmax_kernel_folds", 0)])
 
 
 def test_every_paged_family_files_what_a_token_costs():

@@ -375,6 +375,43 @@ def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch
     assert temporaries < parents, (temporaries, parents)
 
 
+@pytest.mark.parametrize("rows,kv,hd,row", [
+    (8, 4, 192, 256),  # rollout-longctx-sink-128's first stage: 16 query heads a KV head
+    (4, 8, 128, 128),  # rollout-longctx-window's: 8 a KV head
+], ids=["sink_cell", "window_cell"])
+def test_a_full_layers_prefill_folds_keep_their_scores_in_the_kernel(
+        chip, monkeypatch, rows, kv, hd, row):
+    """A full-attention layer's prefill folds ALONE (``hybrid._segment_softmax``
+    over the rows' K/V pages, 64 query heads, a segment of 1,024, values of
+    128): on a TPU every fold is the ONE ``expanded_fold_kernel`` launch under
+    the default scoped VMEM, no float32 block of scores is a buffer of the
+    program (the XLA form's ``[.., 1024, 256]`` a fold; ``[.., 1024, 1024]`` at
+    the kernel's block), and the loop's temporaries are the carry's and a
+    block's gathered keys (the XLA form's: 1.35 GB and 0.54 GB)."""
+    from distrl_llm_tpu.models import hybrid
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(la, "dispatch_choices", {})
+    bf, pages = jnp.bfloat16, rows * 160
+    compiled = jax.jit(
+        lambda q, k, v, idx, start: hybrid._segment_softmax(q, k, v, idx, start, 128)
+    ).lower(chip((rows, 1024, 64, hd), bf), chip((kv, pages, 128, row), bf),
+            chip((kv, pages, 128, 128), bf), chip((rows, 160), jnp.int32),
+            chip((), jnp.int32)).compile()
+    assert la.dispatch_choices == {la.dispatch_key(64, row, 0, 128, 1024, bf): "kernel"}
+    text = compiled.as_text()
+    (call,) = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert f"f32[{rows},64,1024,128]" in call  # the carry, in the kernel's layout
+    # the launch asked for no more than Mosaic's default, and compiled under it
+    assert la._fold_vmem(1024, 1024, row, row + 128, 128, False) is None
+    assert not re.search(r"f32\[[\d,]*1024,(256|1024)\]", text)
+    # the carry (in and out of the loop) and a block's K and V, gathered
+    carry, block = rows * 64 * 1024 * 128 * 4, rows * kv * 1024 * (row + 128) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * carry + 2 * block
+
+
 def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
     """The segmented prefill scans an expert layer that holds a SHARE of its
     experts. The table from expert id to place in the stack is a constant of
@@ -596,16 +633,18 @@ def test_state_space_decode_step_at_published_widths(chip, monkeypatch):
         assert "f32[480,5120]" in readers[0] and held in readers[0], readers  # y and the state
 
 
-def test_state_space_prefill_keeps_one_layers_segment(chip):
+def test_state_space_prefill_keeps_one_layers_segment(chip, monkeypatch):
     """The cell's prefill (30 prompts of 2,048 in segments of 1,024 through two
     attention and six Mamba layers at the published widths): the window is read
     out of a segment's ``u`` before the scan runs (``_mamba_mix``'s barrier),
     so the temporaries are one layer's and do not grow with the depth. Left to
     the scheduler every Mamba layer's u, 0.3 GB, was kept to the end of the
-    segment: 9.8 GB of temporaries at the whole depth."""
+    segment: 9.8 GB of temporaries at the whole depth. The attention layers'
+    folds are the chip's (the fold kernel: no block of scores a buffer)."""
     from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.models import init_params
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = jamba_config(8, period=4, offset=1)  # attention at 1 and 5
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     params = place(jax.eval_shape(functools.partial(
@@ -753,7 +792,7 @@ def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
     assert memory.temp_size_in_bytes < 64e6
 
 
-def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
+def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip, monkeypatch):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through
     four window layers, one full layer and four expert layers in the grouped
     form, at the published widths): a window layer's scores are over its ring
@@ -763,6 +802,7 @@ def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip):
     from distrl_llm_tpu.models import init_lora_params, init_params
 
     cfg = _exaone_cell()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the full layer's folds: the kernel
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     params = place(jax.eval_shape(functools.partial(
         init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))

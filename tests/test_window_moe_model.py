@@ -493,7 +493,6 @@ def small_pieces(monkeypatch):
     from distrl_llm_tpu.engine import paged_engine
 
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-    monkeypatch.setattr(hybrid, "SOFTMAX_SEGMENT_PAGES", 1)
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # decode rows dense, segments grouped
 
 
