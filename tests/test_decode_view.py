@@ -4,6 +4,7 @@ the cache-mode programs where the stacked tree would be sliced and transposed
 on every step; built once a base by the engines
 (``engine.LoraMailbox._decode_params``)."""
 
+import dataclasses
 import gc
 import json
 import weakref
@@ -13,10 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_suite
 from distrl_llm_tpu import telemetry
 from distrl_llm_tpu.config import SamplingConfig
 from distrl_llm_tpu.models import (
-    PRESETS, ModelConfig, forward, init_kv_cache, init_lora_params, init_params,
+    PRESETS, forward, init_kv_cache, init_lora_params, init_params,
 )
 from distrl_llm_tpu.models.hybrid import init_mixer_state
 from distrl_llm_tpu.models.transformer import (
@@ -24,24 +26,20 @@ from distrl_llm_tpu.models.transformer import (
 )
 from distrl_llm_tpu.ops.linear import OutIn, linear
 
-SALA = ModelConfig(  # tests/test_hybrid_model.py's: sparse at both ends, lightning between
-    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=4,
-    num_heads=4, num_kv_heads=2, head_dim=16,
-    mixer_types=("minicpm4", "lightning-attn", "lightning-attn", "minicpm4"),
-    lightning_heads=4, lightning_head_dim=16, qk_norm=True, attn_use_rope=False,
-    attn_output_gate=True, lightning_output_gate=True, lightning_output_norm=True,
-    sparse_kernel_size=4, sparse_kernel_stride=2, sparse_block_size=4,
-    sparse_topk=2, sparse_window_size=8, sparse_dense_len=16,
-    scale_emb=12.0, scale_depth=1.4, dim_model_base=32,
-)
+#: the families' tiny configurations, by ``Family.name``
+FAMILIES = {fam.name: fam.cfg for fam in family_suite.families()}
+#: tests/test_hybrid_model.py's SALA at four layers: sparse at both ends, lightning between
+SALA = dataclasses.replace(
+    FAMILIES["sala"], num_layers=4,
+    mixer_types=("minicpm4", "lightning-attn", "lightning-attn", "minicpm4"))
 #: every layer kind that has a key in the table: (config, page size)
 KINDS = {
     "softmax": (PRESETS["tiny"], 8),
     "sparse+lightning": (SALA, 4),
-    "softmax+delta": (PRESETS["tiny-delta-moe"], 8),
-    "power": (PRESETS["tiny-power"], 8),
-    "softmax+mamba": (PRESETS["tiny-jamba"], 8),
-    "latent": (PRESETS["tiny-latent-moe"], 8),
+    "softmax+delta": (FAMILIES["delta-moe"], 8),
+    "power": (FAMILIES["power"], 8),
+    "softmax+mamba": (FAMILIES["jamba"], 8),
+    "latent": (FAMILIES["latent-moe"], 8),
 }
 ROWS, SEG_PAGES, WIDTH = 3, 2, 4
 

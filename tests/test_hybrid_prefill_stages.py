@@ -8,6 +8,7 @@ as a table, the trips it gives, one program for every mix, and the gauge
 ``engine/prefill_real_share`` on both schedulers.
 """
 
+import dataclasses
 import functools
 import json
 import os
@@ -22,30 +23,23 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+import family_suite  # noqa: E402
 from distrl_llm_tpu import telemetry  # noqa: E402
 from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
 from distrl_llm_tpu.engine import paged_engine  # noqa: E402
-from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
+from distrl_llm_tpu.models import forward, init_lora_params, init_params  # noqa: E402
 from distrl_llm_tpu.models import hybrid, moe  # noqa: E402
 from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
 from distrl_llm_tpu.ops import power_retention, selective_scan  # noqa: E402
 
-#: MiniCPM-SALA's shape at hidden 64 (``tests/test_hybrid_model.py``'s): sparse
-#: layers at both ends, lightning layers between
-SALA = ModelConfig(
-    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=4,
-    num_heads=4, num_kv_heads=2, head_dim=16,
-    mixer_types=("minicpm4",) + ("lightning-attn",) * 2 + ("minicpm4",),
-    lightning_heads=4, lightning_head_dim=16, qk_norm=True, attn_use_rope=False,
-    attn_output_gate=True, lightning_output_gate=True, lightning_output_norm=True,
-    sparse_kernel_size=4, sparse_kernel_stride=2, sparse_block_size=4,
-    sparse_topk=2, sparse_window_size=8, sparse_dense_len=16,
-    scale_emb=12.0, scale_depth=1.4, dim_model_base=32,
-)
+#: the families' tiny configurations (``tests/family_suite.py``); MiniCPM-SALA's at
+#: four layers: sparse layers at both ends, lightning layers between
+TINY = {fam.name: fam.cfg for fam in family_suite.families()}
 FAMILIES = {
-    "sala": SALA, "latent-moe": PRESETS["tiny-latent-moe"],
-    "delta-moe": PRESETS["tiny-delta-moe"], "power": PRESETS["tiny-power"],
-    "jamba": PRESETS["tiny-jamba"], "window-moe": PRESETS["tiny-exaone-moe"],
+    "sala": dataclasses.replace(
+        TINY["sala"], num_layers=4,
+        mixer_types=("minicpm4",) + ("lightning-attn",) * 2 + ("minicpm4",)),
+    **{name: TINY[name] for name in ("latent-moe", "delta-moe", "power", "jamba", "window-moe")},
 }
 WIDTH, PAGE, SEGMENT, NEW_TOKENS, LORA_SCALE = 64, 8, 16, 16, 2.0
 PAGES = WIDTH // PAGE
@@ -99,14 +93,7 @@ def weights(family: str):
 
 
 def prompts(lengths, seed=0):
-    """Left-padded ``[B, WIDTH]`` ids and mask, as the engines take them."""
-    rng = np.random.default_rng(seed)
-    ids = np.zeros((len(lengths), WIDTH), np.int32)
-    mask = np.zeros((len(lengths), WIDTH), np.int32)
-    for i, n in enumerate(lengths):
-        ids[i, WIDTH - n:] = rng.integers(1, 256, n)
-        mask[i, WIDTH - n:] = 1
-    return ids, mask
+    return family_suite.prompts(lengths, WIDTH, seed)
 
 
 def prefill_of(family: str):

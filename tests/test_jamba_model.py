@@ -6,107 +6,156 @@ attention at 1, 4 query heads over ONE KV head of 16, a state of 16 x 64 a
 Mamba layer, a tied head). Float32 throughout, seeded weights with every term
 alive.
 
-The rollout through ``perfbench/run.py`` is held by
+This file holds the family's record and the cases of its own mechanism; the
+cases every family repeats are ``tests/test_family_conformance.py``'s. The
+rollout through ``perfbench/run.py`` is held by
 ``tests/perfbench/test_perfbench_rehearsal_jamba.py``, the ops by
 ``tests/test_selective_scan.py``.
 """
 
 import dataclasses
+import functools
 import json
-import os
-import sys
 from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
-from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
-from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
-from distrl_llm_tpu.models import hybrid, transformer  # noqa: E402
-from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
-from distrl_llm_tpu.ops import selective_scan  # noqa: E402
-from perfbench import reference_jamba as ref  # noqa: E402
+import family_suite as fs
+from distrl_llm_tpu.engine import paged_engine
+from distrl_llm_tpu.models import ModelConfig, init_lora_params, init_params
+from distrl_llm_tpu.models import hybrid, transformer
+from distrl_llm_tpu.models.configs import PRESETS
+from distrl_llm_tpu.ops import selective_scan
+from perfbench import reference_jamba as ref
 
 CFG = PRESETS["tiny-jamba"]
-LORA_SCALE = 2.0
-CONFIG_FILE = os.path.join(REPO, "perfbench", "configs", "jamba2-3b.json")
 #: bytes of one slot's state and window in one Mamba layer (float32 caches here)
 STATE_BYTES = 16 * 64 * 4
 WINDOW_BYTES = 3 * 64 * 4
 
 
-@pytest.fixture(scope="module", autouse=True)
-def exact_matmuls():
-    with jax.default_matmul_precision("highest"):
-        yield
+def _with_params(monkeypatch, change):
+    """``_mamba_mix`` reading a layer whose leaves ``change`` bent."""
+    mix = hybrid._mamba_mix
+    monkeypatch.setattr(hybrid, "_mamba_mix", lambda x, p, *a, **kw: mix(
+        x, {**p, **change(p)}, *a, **kw))
 
 
-def seeded(cfg, rank=4):
-    """Seeded weights with every term alive: norms off 1, steps between 0.001
-    and 0.1 that move with the token, A over -1..-16, a skip off 1, biases and
-    an adapter's b that are not zero."""
-    def base(path, x):
-        name = str(path[-1].key)
-        key = jax.random.PRNGKey(sum(map(ord, str(path))) % 9973)
-        if name.endswith("norm"):
-            return 1.0 + 0.3 * jax.random.normal(key, x.shape)
-        if name == "b_dt":
-            return jax.random.uniform(key, x.shape, minval=-6.9, maxval=-2.2)
-        if name == "ssm_a_log":
-            return jax.random.uniform(key, x.shape, minval=0.0, maxval=2.77)
-        if name == "ssm_d":
-            return 1.0 + 0.2 * jax.random.normal(key, x.shape)
-        if name == "b_conv":
-            return 0.25 * jax.random.normal(key, x.shape)
-        if name == "conv":
-            return 0.5 * jax.random.normal(key, x.shape)
-        return 6.0 * x
-
-    params = jax.tree_util.tree_map_with_path(base, init_params(jax.random.PRNGKey(0), cfg))
-    lora = jax.tree_util.tree_map_with_path(
-        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
-        if str(path[-1].key) == "b" else x,
-        init_lora_params(jax.random.PRNGKey(1), cfg, rank),
-    )
-    return params, lora
-
-
-@pytest.fixture(scope="module")
-def weights():
-    return seeded(CFG)
+def _control(name, monkeypatch):
+    """Bend the PROGRAM in one place (never the reference)."""
+    zero = lambda leaf: (lambda p: {leaf: jnp.zeros_like(p[leaf])})
+    if name == "no_inner_norms":
+        norm = hybrid.rms_norm
+        monkeypatch.setattr(hybrid, "rms_norm", lambda x, w, eps, **kw: (
+            x if w.shape[-1] < CFG.hidden_size else norm(x, w, eps, **kw)))
+    elif name in ("no_b_conv", "no_b_dt", "no_d_skip"):
+        _with_params(monkeypatch, zero({"no_b_conv": "b_conv", "no_b_dt": "b_dt",
+                                        "no_d_skip": "ssm_d"}[name]))
+    elif name == "no_gate":
+        monkeypatch.setattr(selective_scan, "gate", lambda y, z: y)
+    elif name == "a_log_as_a":  # A = -A_log where it is -exp(A_log)
+        _with_params(monkeypatch, lambda p: {
+            "ssm_a_log": jnp.log(jnp.maximum(p["ssm_a_log"].astype(jnp.float32), 1e-30))})
+    elif name == "u_z_swapped":
+        fs.with_proj(monkeypatch, "_mamba_mix", lambda key, y, env, mode: (
+            jnp.roll(y, y.shape[-1] // 2, axis=-1) if key == "w_in" else y))
+    elif name == "rope_in_attention":
+        fs.rope_in_the_softmax_layers(monkeypatch, CFG.head_dim, 10000.0)
+    elif name == "group_as_two_halves":  # the one KV head's group read as 2 + 2, swapped
+        fs.with_proj(monkeypatch, "_softmax_mix", lambda key, y, env, mode: (
+            jnp.roll(y, y.shape[-1] // 2, axis=-1) if key == "wq" else y))
+    elif name in ("state_3_bits", "bf16_state"):  # the state rounded before every step
+        step, bits = hybrid.ssm_step, 3 if name == "state_3_bits" else 7
+        monkeypatch.setattr(hybrid, "ssm_step", lambda *a: step(
+            *a[:6], jax.lax.reduce_precision(a[6], 8, bits), *a[7:]))
+    else:
+        raise AssertionError(name)
 
 
-#: the reference's whole program, traced once a configuration and a shape
-#: and not once a call (a test asks for it a row group at a time)
-_reference = jax.jit(
-    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
+def _round_check(moved, result, engine, scheduler, slots):
+    """The counter x a state's bytes is what ``ssm_counts`` says the same rows
+    must move."""
+    from perfbench import ssm_counts
+
+    stepped = moved("engine/ssm_states_stepped")
+    assert stepped == 3 * 8 * 24  # Mamba layers x rows x steps
+    model = dataclasses.asdict(CFG)
+    assert ssm_counts.state_bytes(model) == STATE_BYTES
+    assert 2 * stepped * STATE_BYTES == ssm_counts.ssm_state_bytes(
+        model, [40] * 4 + [57] * 4, result.lengths.reshape(-1))
 
 
-def reference_logprobs(params, lora, ids, mask, cfg=CFG):
-    return np.asarray(_reference(
-        params, cfg, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
-        lora_scale=LORA_SCALE))
+FORWARD_CONTROLS = ["no_inner_norms", "no_b_conv", "no_b_dt", "no_d_skip", "no_gate",
+                    "a_log_as_a", "u_z_swapped", "rope_in_attention", "group_as_two_halves"]
 
-
-def forward_logprobs(params, lora, ids, mask, **kw):
-    logits, _ = forward(params, CFG, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
-                        lora=lora, lora_scale=LORA_SCALE, **kw)
-    return np.asarray(jnp.take_along_axis(
-        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0])
-
-
-def padded_rows():
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, 40), 1, 256))
-    mask = np.ones((3, 40), np.int32)
-    mask[0, :7] = 0
-    mask[1, 33:] = 0
-    return ids, mask, (mask[:, 1:] * mask[:, :-1]) > 0
+FAMILY = fs.Family(
+    name="jamba", cfg=CFG, ref=ref, config_file="jamba2-3b.json",
+    # steps between 0.001 and 0.1 that move with the token, A over -1..-16, a
+    # skip off 1, biases that are not zero
+    seed_rules=(
+        (fs.named("b_dt"), fs.uniform(-6.9, -2.2)),
+        (fs.named("ssm_a_log"), fs.uniform(0.0, 2.77)),
+        (fs.named("ssm_d"), fs.normal(0.2, 1.0)),
+        (fs.named("b_conv"), fs.normal(0.25)),
+        (fs.named("conv"), fs.normal(0.5))),
+    # Prefill in segments of 16 tokens (two pages of 8) and the attention layer's
+    # segment a page of keys at a time, so that 40-57-token prompts cross every
+    # boundary the cell's 2k-token prompts cross: the state and the window
+    # carried from segment to segment, the attention layer over earlier
+    # segments' pages, a last segment that is part padding.
+    engine_pieces=((paged_engine, "HYBRID_PREFILL_SEGMENT", 16),),
+    refusals=(
+        ({"num_experts": 16, "num_experts_per_tok": 2}, "num_experts=16"),
+        ({"mamba_n_heads": 128}, "mamba_n_heads"),
+        ({"mamba_n_groups": 8}, "mamba_n_groups"),
+        ({"mamba_proj_bias": True}, "mamba_proj_bias"),
+        ({"mamba_conv_bias": False}, "mamba_conv_bias"),
+        ({"sliding_window": 4096}, "sliding_window"),
+        ({"attn_layer_period": None}, "attn_layer_period"),
+        ({"model_type": "jamba2"}, "jamba2")),
+    loader_refusal=("jamba.*seeded weights", "jamba.*seeded weights"),
+    # one chunk; chunks that carry the state between them under remat as the
+    # learner runs it; chunks that do not divide the row
+    forward_cases=(
+        ("one_chunk", False, ()),
+        ("chunks_of_16_remat", True, ((selective_scan, "DEFAULT_CHUNK", 16),)),
+        ("chunks_of_7", False, ((selective_scan, "DEFAULT_CHUNK", 7),))),
+    forward_full_logits=True,
+    forward_controls={name: functools.partial(_control, name) for name in FORWARD_CONTROLS},
+    # reverse mode through the rematerialised chunk scan across two chunks; a
+    # and b: seven targets in attention, five in Mamba
+    learner={"answer": 20, "leaves": 2 * (7 + 5),
+             "pieces": ((selective_scan, "DEFAULT_CHUNK", 16),)},
+    train_targets={"mamba": {"w_in", "w_out", "w_gate", "w_up", "w_down"},
+                   "softmax": {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}},
+    # 8 rows through 4 slots (a freed slot takes another prompt's state); every
+    # candidate admitted at once; prefill, fan-out, lockstep
+    rounds=(("refill", 4), ("refill", 8), ("waves", 0)),
+    slot_bytes=3 * (STATE_BYTES + WINDOW_BYTES), round_check=_round_check,
+    # a state kept in bf16 or at 3 bits of mantissa, a window or a state that the
+    # candidates are not handed, a state handed from the other prompt
+    engine_controls={
+        "bf16_state": functools.partial(_control, "bf16_state"),
+        "state_3_bits": functools.partial(_control, "state_3_bits"),
+        "state_from_other_prompt": fs.handed_each(("ssm",), lambda x: jnp.roll(x, 1, axis=0)),
+        "state_not_handed": fs.handed_each(("ssm",), jnp.zeros_like),
+        "window_not_handed": fs.handed_each(("conv",), jnp.zeros_like)},
+    # through segments, fan-out and the decode steps (the paged kernel at one KV head)
+    engine_mechanisms=("rope_in_attention", "group_as_two_halves", "no_b_conv",
+                       "no_inner_norms"),
+    fan_out={"scheduler": "waves", "slots": 0, "length": 45, "n": 16, "max_tokens": 12,
+             "atol": 1e-5},
+    state_refusals=fs.NINE_REFUSALS,
+    state_refusal_says=("attention, mamba layers",
+                        "a float32 state-space state and a convolution window",
+                        "K/V pages for its softmax layers only"),
+    span_args={"ssm_states_stepped": 3 * 8 * 24},
+    report_tail="; slot state 0.000 GB, 576 states stepped",
+)
+family, small_pieces, weights = fs.fixtures(FAMILY)
+CONFIG_FILE = fs.config_path(FAMILY)
 
 
 # --------------------------------------------------- what the program is told
@@ -187,470 +236,33 @@ def test_from_hf_config_reads_the_benchmarks_file():
     assert auto.mamba_dt_rank == 160
 
 
-@pytest.mark.parametrize("changes,named", [
-    ({"num_experts": 16, "num_experts_per_tok": 2}, "num_experts=16"),
-    ({"mamba_n_heads": 128}, "mamba_n_heads"),
-    ({"mamba_n_groups": 8}, "mamba_n_groups"),
-    ({"mamba_proj_bias": True}, "mamba_proj_bias"),
-    ({"mamba_conv_bias": False}, "mamba_conv_bias"),
-    ({"sliding_window": 4096}, "sliding_window"),
-    ({"attn_layer_period": None}, "attn_layer_period"),
-    ({"model_type": "jamba2"}, "jamba2"),
-])
-def test_from_hf_config_refuses_what_it_cannot_represent(changes, named):
-    file = {**json.load(open(CONFIG_FILE)), **changes}
-    with pytest.raises(ValueError, match=named):
-        ModelConfig.from_hf_config(SimpleNamespace(**file))
-
-
-def test_the_loader_refuses_a_checkpoint_by_name_in_both_directions(weights):
-    from distrl_llm_tpu.models.loading import params_from_state_dict, state_dict_from_params
-
-    with pytest.raises(NotImplementedError, match="jamba.*seeded weights"):
-        params_from_state_dict({}, CFG)
-    with pytest.raises(NotImplementedError, match="jamba.*seeded weights"):
-        state_dict_from_params(weights[0], CFG)
-
-
-# ------------------------------------------------------------- the forward
-
-
-@pytest.mark.parametrize("chunk,remat", [(0, False), (16, True), (7, False)])
-def test_forward_equals_the_reference_with_padding_on_both_sides(weights, chunk, remat,
-                                                                 monkeypatch):
-    """``full`` mode (the learner's and the scorer's): left- and right-padded
-    rows packed; one chunk, or chunks that carry the state between them under
-    remat as the learner runs it, or chunks that do not divide the row."""
-    params, lora = weights
-    ids, mask, both = padded_rows()
-    if chunk:
-        monkeypatch.setattr(selective_scan, "DEFAULT_CHUNK", chunk)
-    want = reference_logprobs(params, lora, ids, mask)
-    got = forward_logprobs(params, lora, ids, mask, remat=remat)
-    assert np.abs(got - want)[both].max() < 2e-5
-    logits, _ = forward(params, CFG, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
-                        lora=lora, lora_scale=LORA_SCALE)
-    whole = np.asarray(ref.full_logits(params, CFG, jnp.asarray(ids), jnp.asarray(mask),
-                                       lora=lora, lora_scale=LORA_SCALE))
-    assert np.abs(np.asarray(logits) - whole)[mask > 0].max() < 2e-5
-
-
-def _with_params(monkeypatch, change):
-    """``_mamba_mix`` reading a layer whose leaves ``change`` bent."""
-    mix = hybrid._mamba_mix
-    monkeypatch.setattr(hybrid, "_mamba_mix", lambda x, p, *a, **kw: mix(
-        x, {**p, **change(p)}, *a, **kw))
-
-
-def _with_proj(monkeypatch, name, bend):
-    """``name`` (a mixer) handed a ``proj`` whose outputs ``bend(key, y, env,
-    mode)`` bent."""
-    mix = getattr(hybrid, name)
-
-    def run(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-        def bent(h, p_, lora_, key, bias, scale):
-            return bend(key, proj(h, p_, lora_, key, bias, scale), env, mode)
-        return mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=bent,
-                   lora_scale=lora_scale)
-    monkeypatch.setattr(hybrid, name, run)
-
-
-def _control(monkeypatch, name):
-    """Bend the PROGRAM in one place (never the reference)."""
-    zero = lambda leaf: (lambda p: {leaf: jnp.zeros_like(p[leaf])})
-    if name == "no_inner_norms":
-        norm = hybrid.rms_norm
-        monkeypatch.setattr(hybrid, "rms_norm", lambda x, w, eps, **kw: (
-            x if w.shape[-1] < CFG.hidden_size else norm(x, w, eps, **kw)))
-    elif name in ("no_b_conv", "no_b_dt", "no_d_skip"):
-        _with_params(monkeypatch, zero({"no_b_conv": "b_conv", "no_b_dt": "b_dt",
-                                        "no_d_skip": "ssm_d"}[name]))
-    elif name == "no_gate":
-        monkeypatch.setattr(selective_scan, "gate", lambda y, z: y)
-    elif name == "a_log_as_a":  # A = -A_log where it is -exp(A_log)
-        _with_params(monkeypatch, lambda p: {
-            "ssm_a_log": jnp.log(jnp.maximum(p["ssm_a_log"].astype(jnp.float32), 1e-30))})
-    elif name == "u_z_swapped":
-        _with_proj(monkeypatch, "_mamba_mix", lambda key, y, env, mode: (
-            jnp.roll(y, y.shape[-1] // 2, axis=-1) if key == "w_in" else y))
-    elif name == "rope_in_attention":
-        def rotate(key, y, env, mode):
-            if key not in ("wq", "wk"):
-                return y
-            pos = env["lengths"][:, None] if mode == "decode" else env["q_pos"]
-            cos, sin = transformer.rope_cos_sin(pos, CFG.head_dim, 10000.0)
-            b, s, wide = y.shape
-            return transformer.apply_rope(
-                y.reshape(b, s, -1, CFG.head_dim), cos, sin).reshape(b, s, wide)
-        _with_proj(monkeypatch, "_softmax_mix", rotate)
-    elif name == "group_as_two_halves":  # the one KV head's group read as 2 + 2, swapped
-        _with_proj(monkeypatch, "_softmax_mix", lambda key, y, env, mode: (
-            jnp.roll(y, y.shape[-1] // 2, axis=-1) if key == "wq" else y))
-    elif name in ("state_3_bits", "bf16_state"):  # the state rounded before every step
-        step, bits = hybrid.ssm_step, 3 if name == "state_3_bits" else 7
-        monkeypatch.setattr(hybrid, "ssm_step", lambda *a: step(
-            *a[:6], jax.lax.reduce_precision(a[6], 8, bits), *a[7:]))
-    else:
-        raise AssertionError(name)
-
-
-FORWARD_CONTROLS = ["no_inner_norms", "no_b_conv", "no_b_dt", "no_d_skip", "no_gate",
-                    "a_log_as_a", "u_z_swapped", "rope_in_attention", "group_as_two_halves"]
-
-
-@pytest.mark.parametrize("control", FORWARD_CONTROLS)
-def test_the_forward_can_tell_each_mechanism(weights, control, monkeypatch):
-    """Each mechanism dropped or bent moves the log-probabilities a hundred
-    times further from the reference than the sound program's 2e-5."""
-    params, lora = weights
-    ids, mask, both = padded_rows()
-    want = reference_logprobs(params, lora, ids, mask)
-    _control(monkeypatch, control)
-    assert np.abs(forward_logprobs(params, lora, ids, mask) - want)[both].max() > 2e-3
-
-
-def test_the_learners_loss_and_adapter_gradient_are_the_references(weights, monkeypatch):
-    """No cache, remat, chunked cross-entropy, reverse mode through the
-    rematerialised chunk scan across two chunks: the policy-gradient loss over
-    the answers and its gradient in every adapter factor against plain reverse
-    mode through the reference's token-by-token scan."""
-    from distrl_llm_tpu.learner.losses import answer_logprobs, pg_loss
-
-    params, lora = weights
-    monkeypatch.setattr(selective_scan, "DEFAULT_CHUNK", 16)
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(1, 256, (4, 12)).astype(np.int32)
-    pmask = np.ones((4, 12), np.int32)
-    pmask[0, :5] = 0
-    answer = rng.integers(1, 256, (4, 20)).astype(np.int32)
-    amask = np.ones((4, 20), np.int32)
-    amask[2, 14:] = 0
-    coeffs = jnp.asarray([0.7, -1.1, 0.4, 1.3])
-
-    def loss(lo):
-        logp = answer_logprobs(
-            params, CFG, jnp.asarray(prompt), jnp.asarray(pmask), jnp.asarray(answer),
-            jnp.asarray(amask), lora=lo, lora_scale=LORA_SCALE, remat=True, logit_chunk=8)
-        return pg_loss(logp, jnp.asarray(amask), coeffs)
-
-    got_loss, got = jax.value_and_grad(loss)(lora)
-    ids = np.concatenate([prompt, answer], 1)
-    mask = np.concatenate([pmask, amask], 1)
-    scored = np.concatenate([np.zeros_like(pmask), amask], 1)
-    want_loss, want = ref.pg_loss_and_lora_grad(
-        params, CFG, lora, LORA_SCALE, jnp.asarray(ids), jnp.asarray(mask),
-        jnp.asarray(scored), coeffs)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-5
-    leaves = jax.tree_util.tree_leaves_with_path(got)
-    assert len(leaves) == 2 * (7 + 5)  # a and b: seven targets in attention, five in Mamba
-    for (path, g), w in zip(leaves, jax.tree_util.tree_leaves(want)):
-        assert float(jnp.abs(w).max()) > 0, path
-        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-6,
-                                   err_msg=str(path))
-
-
-def test_a_train_step_moves_the_adapter_and_nothing_else(weights):
-    """The learner's own update on this model: a finite loss, every adapter
-    factor moved, and the targets a Mamba layer has."""
-    import optax
-
-    from distrl_llm_tpu.learner.train_step import UpdateBatch, make_train_step
-
-    params, lora = weights
-    rng = np.random.default_rng(2)
-    batch = UpdateBatch(
-        prompt_ids=jnp.asarray(rng.integers(1, 256, (4, 12)), jnp.int32),
-        prompt_mask=jnp.ones((4, 12), jnp.int32),
-        answer_ids=jnp.asarray(rng.integers(1, 256, (4, 12)), jnp.int32),
-        answer_mask=jnp.ones((4, 12), jnp.int32),
-        coeffs=jnp.asarray([1.0, -1.0, 0.5, -0.5]),
-        sample_mask=jnp.ones((4,), jnp.float32),
-    )
-    optimizer = optax.adam(1e-3)
-    step = make_train_step(CFG, learner_type="pg", optimizer=optimizer,
-                           lora_scale=LORA_SCALE, micro_size=2, donate=False)
-    new_lora, _, loss = step(lora, optimizer.init(lora), params, batch)[:3]
-    assert np.isfinite(float(loss))
-    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.abs(a - b).max()), new_lora, lora)
-    assert all(m > 0 for m in jax.tree_util.tree_leaves(moved))
-    assert set(new_lora["layers"]["mamba"]) == {"w_in", "w_out", "w_gate", "w_up", "w_down"}
-    assert set(new_lora["layers"]["softmax"]) == {
-        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
-
-
 # -------------------------------------------------------------- the engine
-
-
-def make_engine(scheduler, slots, **kw):
-    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
-
-    kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("page_size", 8)
-    return PagedGenerationEngine(
-        CFG, max_prompt_tokens=64, max_new_tokens=24, eos_token_ids=[-1],
-        pad_token_id=0, lora_scale=LORA_SCALE,
-        scheduler=scheduler, max_concurrent_rows=slots, capture_logprobs=True,
-        autotune=False, **kw)
-
-
-def prompts(lengths, width=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = np.zeros((len(lengths), width), np.int32)
-    mask = np.zeros((len(lengths), width), np.int32)
-    for r, n in enumerate(lengths):
-        ids[r, width - n:] = rng.integers(1, 256, n)
-        mask[r, width - n:] = 1
-    return ids, mask
-
-
-@pytest.fixture
-def small_pieces(monkeypatch):
-    """Prefill in segments of 16 tokens (two pages of 8) and the attention
-    layer's segment a page of keys at a time, so that 40-57-token prompts cross
-    every boundary the cell's 2k-token prompts cross: the state and the window
-    carried from segment to segment, the attention layer over earlier segments'
-    pages, a last segment that is part padding."""
-    from distrl_llm_tpu.engine import paged_engine
-
-    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-
-
-def worst_difference(params, lora, ids, mask, result):
-    worst = 0.0
-    for b in range(ids.shape[0]):
-        prompt = ids[b][mask[b] > 0]
-        rows = np.stack([np.concatenate([prompt, result.tokens[b, j]])
-                         for j in range(result.tokens.shape[1])])
-        want = reference_logprobs(params, lora, rows, np.ones_like(rows))
-        worst = max(worst, np.abs(result.logprobs[b] - want[:, len(prompt) - 1:]).max())
-    return worst
-
-
-def generate(engine, params, lora, lengths=(40, 57)):
-    ids, mask = prompts(lengths)
-    result = engine.generate(
-        params, lora, ids, mask,
-        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
-        jax.random.PRNGKey(3))
-    return ids, mask, result
-
-
-@pytest.mark.parametrize("scheduler,slots", [
-    ("refill", 4),  # 8 rows through 4 slots: a freed slot takes another prompt's state
-    ("refill", 8),  # every candidate admitted at once
-    ("waves", 0),   # prefill, fan-out, lockstep
-])
-def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
-                                                      small_pieces):
-    """Both schedulers hold a model with 3 Mamba layers and 1 attention layer:
-    prefill in segments (the scan from the carried state, the convolution from
-    the carried window, the attention layer over earlier segments' pages), each
-    prompt's states, windows and page chain handed to its 4 candidates, then
-    the one-token step through the slots' state. The engine's own captured
-    log-probability of every token it sampled is the reference's full
-    forward's; the counter x a state's bytes is what ``ssm_counts`` says the
-    same rows must move, and the gauge what the slots' states and windows hold."""
-    from distrl_llm_tpu import telemetry
-    from perfbench import ssm_counts
-
-    params, lora = weights
-    before = telemetry.observe_snapshot()["counters"]
-    engine = make_engine(scheduler, slots)
-    ids, mask, result = generate(engine, params, lora)
-    assert (result.lengths == 24).all()
-    assert result.alive_slot_steps == 8 * 24
-    assert worst_difference(params, lora, ids, mask, result) < 2e-5
-    after = telemetry.observe_snapshot()
-    stepped = after["counters"]["engine/ssm_states_stepped"] - before.get(
-        "engine/ssm_states_stepped", 0)
-    assert stepped == 3 * 8 * 24  # Mamba layers x rows x steps
-    model = dataclasses.asdict(CFG)
-    assert ssm_counts.state_bytes(model) == STATE_BYTES
-    assert 2 * stepped * STATE_BYTES == ssm_counts.ssm_state_bytes(
-        model, [40] * 4 + [57] * 4, result.lengths.reshape(-1))
-    held = (slots or 8) * 3 * (STATE_BYTES + WINDOW_BYTES)
-    assert after["gauges"]["engine/slot_state_bytes"] == held
-    assert engine.last_round_stats["slot_state_bytes"] == held
-
-
-ENGINE_CONTROLS = {
-    "bf16_state": None,
-    "state_3_bits": None,
-    "window_not_handed": lambda m: {
-        **m, "conv": tuple(jnp.zeros_like(x) for x in m["conv"])},
-    "state_not_handed": lambda m: {
-        **m, "ssm": tuple(jnp.zeros_like(x) for x in m["ssm"])},
-    "state_from_other_prompt": lambda m: {
-        **m, "ssm": tuple(jnp.roll(x, 1, axis=0) for x in m["ssm"])},
-}
-
-
-@pytest.mark.parametrize("control", sorted(ENGINE_CONTROLS))
-def test_this_files_agreement_can_tell_a_wrong_state(weights, small_pieces, control,
-                                                     monkeypatch):
-    """What only the cache path can get wrong: a state kept in bf16 or at 3
-    bits of mantissa, a window or a state that the candidates are not handed,
-    a state handed from the other prompt."""
-    from distrl_llm_tpu.engine import paged_engine
-
-    params, lora = weights
-    change = ENGINE_CONTROLS[control]
-    if change is None:
-        _control(monkeypatch, control)
-    else:
-        prefill = paged_engine._paged_prefill_hybrid
-
-        def patched(*a, **kw):
-            k, v, logits, real_len, mixer = prefill(*a, **kw)
-            return k, v, logits, real_len, change(mixer)
-        monkeypatch.setattr(paged_engine, "_paged_prefill_hybrid", patched)
-    ids, mask, result = generate(make_engine("waves", 0), params, lora)
-    assert worst_difference(params, lora, ids, mask, result) > 5e-4
-
-
-@pytest.mark.parametrize("control", ["rope_in_attention", "group_as_two_halves", "no_b_conv",
-                                     "no_inner_norms"])
-def test_the_engines_agreement_can_tell_the_mechanisms_too(weights, small_pieces, control,
-                                                           monkeypatch):
-    """The controls of the chip's check that bend a mixer, through segments,
-    fan-out and the decode steps (the paged kernel at one KV head)."""
-    params, lora = weights
-    _control(monkeypatch, control)
-    ids, mask, result = generate(make_engine("waves", 0), params, lora)
-    assert worst_difference(params, lora, ids, mask, result) > 2e-3
-
-
-def test_the_fan_out_hands_the_state_the_window_and_the_pages(weights, small_pieces):
-    """Greedy, 16 candidates of one prompt are 16 times the single row."""
-    params, lora = weights
-    ids, mask = prompts((45,))
-    greedy = dict(temperature=0.0, top_p=1.0, max_tokens=12)
-    many = make_engine("waves", 0).generate(
-        params, lora, ids, mask, SamplingConfig(n=16, **greedy), jax.random.PRNGKey(0))
-    one = make_engine("waves", 0).generate(
-        params, lora, ids, mask, SamplingConfig(n=1, **greedy), jax.random.PRNGKey(0))
-    assert (many.tokens == one.tokens[:, :1]).all()
-    np.testing.assert_allclose(many.logprobs, np.repeat(one.logprobs, 16, 1), atol=1e-5)
 
 
 def test_the_prompts_state_is_the_scans_after_its_last_real_token(weights, small_pieces):
     """What the prefill returns for the fan-out: a state and a window a Mamba
     layer a prompt (the state float32, neither zero, the window the prompt's
     last three tokens' u), and pages for the one attention layer only."""
-    from distrl_llm_tpu.engine import paged_engine
-
     params, lora = weights
-    ids, mask = prompts((40, 57))
-    k, v, logits, real_len, mixer = paged_engine._paged_prefill_hybrid(
-        params, lora, jnp.asarray(ids), jnp.asarray(mask), cfg=CFG, prompt_pages=8,
-        page_size=8, lora_scale=LORA_SCALE, cache_dtype=jnp.float32,
-        attn_impl="reference", total_tokens=88)
+    ids, mask, (k, v, logits, real_len, mixer) = fs.prefilled(FAMILY, params, lora)
     assert len(k) == len(v) == 1 and k[0].shape == (1, 16, 8, 16)
     assert list(np.asarray(real_len)) == [40, 57]
     assert [x.shape for x in mixer["ssm"]] == [(2, 16, 64)] * 3
     assert [x.shape for x in mixer["conv"]] == [(2, 3, 64)] * 3
     assert all(float(jnp.abs(x).max()) > 0 for x in mixer["ssm"] + mixer["conv"])
     want = ref.full_logits(params, CFG, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
-                           lora_scale=LORA_SCALE)[:, -1]
+                           lora_scale=fs.LORA_SCALE)[:, -1]
     np.testing.assert_allclose(logits, want, atol=2e-5)
     # the first Mamba layer's window is W_in's u of the last three real tokens
     layer = jax.tree_util.tree_map(lambda w: w[0], params["layers"]["mamba"])
     x = jnp.take(params["embed"], jnp.asarray(ids[:, -3:]), axis=0)
     h = transformer.rms_norm(x, layer["attn_norm"], CFG.rms_norm_eps)
     ab = jax.tree_util.tree_map(lambda w: w[0], lora["layers"]["mamba"]["w_in"])
-    u = (h @ layer["w_in"] + LORA_SCALE * (h @ ab["a"]) @ ab["b"])[..., :64]
+    u = (h @ layer["w_in"] + fs.LORA_SCALE * (h @ ab["a"]) @ ab["b"])[..., :64]
     np.testing.assert_allclose(mixer["conv"][0], u, atol=2e-5)
 
 
-def test_the_rounds_span_and_trace_reports_line_say_what_was_stepped(weights, tmp_path):
-    """With tracing on the round's span carries the gauge and the counter, and
-    ``tools/trace_report.py`` prints them on the round's host line."""
-    from distrl_llm_tpu import telemetry
-    from tools import trace_report
-
-    params, lora = weights
-    engine = make_engine("waves", 0)
-    generate(engine, params, lora)  # warm-up: no compile/ span in the traced round
-    telemetry.configure(True)
-    try:
-        telemetry.export_chrome_trace(str(tmp_path / "before.json"), clear=True)  # others' spans
-        generate(engine, params, lora)
-        path = telemetry.export_chrome_trace(str(tmp_path / "trace.json"), clear=True)
-    finally:
-        telemetry.configure(False)
-    events, metadata = trace_report.load_trace(path)
-    (span,) = [e for e in events if e.get("name") == telemetry.ENGINE_DECODE]
-    assert span["args"]["slot_state_bytes"] == 8 * 3 * (STATE_BYTES + WINDOW_BYTES)
-    assert span["args"]["ssm_states_stepped"] == 3 * 8 * 24
-    lines = trace_report.build_report(events, metadata).splitlines()
-    (said,) = [line for line in lines if line.startswith("    host s:")]
-    assert said.endswith("; slot state 0.000 GB, 576 states stepped")
-
-
-# ------------------------------------------------------------ the refusals
-
-
-def _paged(**kw):
-    return lambda: make_engine("refill", 4, **kw)
-
-
-def _dense():
-    from distrl_llm_tpu.engine.engine import GenerationEngine
-
-    return GenerationEngine(CFG, max_prompt_tokens=64, max_new_tokens=8,
-                            eos_token_ids=[-1], pad_token_id=0, autotune=False)
-
-
-def _sharded():
-    from distrl_llm_tpu.engine.sharded_paged import ShardedPagedEngine
-
-    return ShardedPagedEngine(
-        CFG, mesh=None, max_prompt_tokens=16, max_new_tokens=8, eos_token_ids=[1],
-        pad_token_id=0)
-
-
-def _turn_hook():
-    engine = make_engine("refill", 4)
-    engine.turn_hook = lambda *a: None
-    ids, mask = prompts((20,))
-    return engine.generate(
-        None, None, ids, mask, SamplingConfig(n=2, max_tokens=4), jax.random.PRNGKey(0))
-
-
-@pytest.mark.parametrize("build,what", [
-    (_dense, "dense engine"),
-    (_sharded, "dp-sharded"),
-    (_paged(kv_quant="int8"), "kv_quant"),
-    (_paged(spec_draft=2), "spec_draft"),
-    (_paged(prefix_sharing=True), "prefix_sharing"),
-    (_paged(max_kv_pages=64), "max_kv_pages"),
-    (_paged(continuous_admission=True, prefix_cache=True), "prefix_sharing"),
-    (_paged(kv_spill=True), "kv_spill"),
-    (_turn_hook, "turn_hook"),
-], ids=["dense", "sharded", "int8_pool", "speculation", "pool_chains", "preemption",
-        "radix_cache", "spill", "turn_resumption"])
-def test_what_holds_k_and_v_of_one_kind_names_the_state_it_cannot_hold(build, what):
-    """One sentence for every engine and feature that keeps K/V of one kind:
-    it names the layers and the state a slot holds for them."""
-    with pytest.raises(ValueError) as e:
-        build()
-    said = str(e.value)
-    assert what in said and "attention, mamba layers" in said
-    assert "a float32 state-space state and a convolution window" in said
-    assert "K/V pages for its softmax layers only" in said
-
-
-@pytest.mark.parametrize("switch", ["paged_verify", "paged_chunked", "paged_prefix"])
-def test_forward_refuses_the_dense_decoders_other_cache_modes(weights, switch):
-    params, _ = weights
-    cache = {"k": (), "v": (), "page_indices": jnp.zeros((1, 2), jnp.int32),
-             "lengths": jnp.zeros((1,), jnp.int32)}
-    with pytest.raises(NotImplementedError, match=switch):
-        forward(params, CFG, jnp.ones((1, 1), jnp.int32), kv_cache=cache, page_size=8,
-                **{switch: True})
-
-
-# --------------------------------------------------------------- the budget
+# --------------------------------------------- the budget, adapters and placement
 
 
 def test_a_page_costs_its_two_paged_layers_and_a_slot_its_states():
@@ -674,11 +286,8 @@ def test_a_page_costs_its_two_paged_layers_and_a_slot_its_states():
     assert budget.page_bytes(full, 128) == 128 * 1024
 
 
-# ----------------------------------------------------- adapters and placement
-
-
 def test_adapter_factors_are_each_kinds_own_and_merge(weights):
-    from distrl_llm_tpu.models.lora import DEFAULT_TARGETS, MAMBA_TARGETS, merge_lora
+    from distrl_llm_tpu.models.lora import DEFAULT_TARGETS, MAMBA_TARGETS
 
     params, lora = weights
     assert set(lora["layers"]) == {"softmax", "mamba"}
@@ -691,11 +300,7 @@ def test_adapter_factors_are_each_kinds_own_and_merge(weights):
     named = init_lora_params(jax.random.PRNGKey(0), CFG, 4, targets=("wq", "w_in", "w_up"))
     assert set(named["layers"]["softmax"]) == {"wq", "w_up"}
     assert set(named["layers"]["mamba"]) == {"w_in", "w_up"}
-    merged = merge_lora(params, lora, alpha=8.0)
-    ids = jax.random.randint(jax.random.PRNGKey(2), (1, 20), 1, 256)
-    a, _ = forward(merged, CFG, ids)
-    b, _ = forward(params, CFG, ids, lora=lora, lora_scale=2.0)
-    np.testing.assert_allclose(a, b, atol=2e-4)
+    fs.merged_equals_adapted(FAMILY, params, lora)
 
 
 def test_every_new_leaf_has_a_partition_spec(weights):

@@ -6,11 +6,12 @@ rope 8, v 16). Float32 throughout, seeded weights with every term alive.
 
 The learner's update through ``trainer.train_step`` and the rollout through
 ``perfbench/run.py`` are held by ``tests/perfbench/test_perfbench_rehearsal_latent_moe.py``.
+
+This file holds the family's record and the cases of its own mechanism; the
+cases every family repeats are ``tests/test_family_conformance.py``'s.
 """
 
 import functools
-import os
-import sys
 from types import SimpleNamespace
 
 import jax
@@ -18,68 +19,83 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
-from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
-from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
-from distrl_llm_tpu.models import moe  # noqa: E402
-from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
-from perfbench import reference_latent_moe as ref  # noqa: E402
+import family_suite as fs
+from distrl_llm_tpu.config import SamplingConfig
+from distrl_llm_tpu.engine import paged_engine
+from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params
+from distrl_llm_tpu.models import hybrid, moe
+from distrl_llm_tpu.models.configs import PRESETS
+from distrl_llm_tpu.ops import latent_attention
+from perfbench import reference_latent_moe as ref
 
 CFG = PRESETS["tiny-latent-moe"]
-LORA_SCALE = 2.0
 
 
-@pytest.fixture(scope="module", autouse=True)
-def exact_matmuls():
-    with jax.default_matmul_precision("highest"):
-        yield
+def _round_check(moved, result, engine, scheduler, slots):
+    # either scheduler counts its steps (the benchmark's step time and occupancy)
+    assert result.steps_dispatched >= 24 * (2 if slots == 4 else 1)
+    # 2 expert layers x 8 rows x 24 steps x 2 experts a token, live slots only
+    assert moved("engine/moe_assignments") == 2 * 8 * 24 * 2
+    # the fullest of 8 experts holds at least the mean, at most every pair's half
+    assert 2 * 24 * 2 <= moved("engine/moe_max_expert_load") <= 2 * 8 * 24
+    # absorbed attention's pages, from the page table by hand: at its step t a
+    # row of a P-token prompt holds (P + t) // 8 + 1 pages in each of 3 layers
+    held = np.asarray([[(p + t) // 8 + 1 for t in range(24)] for p in (40, 57)])
+    assert moved("engine/latent_pages_attended") == 3 * 4 * held.sum()
+    if scheduler == "waves":
+        # a group of 4 is one prompt's candidates: its full pages (5 and 7) in
+        # whole blocks of 6 columns are fetched once, every other page a row
+        once = np.asarray([[5 // 6 * 6], [7 // 6 * 6]])
+        assert moved("engine/latent_pages_read") == 3 * (once + 4 * (held - once)).sum()
+    assert moved("engine/latent_pages_read") <= moved("engine/latent_pages_attended")
 
 
-@pytest.fixture(autouse=True)
-def both_expert_forms(monkeypatch):
-    """Eight tokens or fewer take the dense form (a decode step of 8 rows), more
-    the grouped one (a prefill segment, the learner's rows), as 128 divides
-    the 64-row decode step from the 4,096-token segment at the real size."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
-
-
-@pytest.fixture(scope="module")
-def weights():
-    """Seeded weights with every term alive: norms off 1, a correction bias
-    that changes the choice, projections large enough that a dropped term
-    moves the logits, an adapter (kv_b's too) whose b is not zero."""
-    def base(path, x):
-        name = str(path[-1].key)
-        key = jax.random.PRNGKey(sum(map(ord, str(path))) % 9973)
-        if name.endswith("norm"):
-            return 1.0 + 0.3 * jax.random.normal(key, x.shape)
-        if name == "e_score_bias":
-            return 0.05 * jax.random.normal(key, x.shape)
-        return 3.0 * x
-
-    params = jax.tree_util.tree_map_with_path(
-        base, init_params(jax.random.PRNGKey(0), CFG))
-    lora = jax.tree_util.tree_map_with_path(
-        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
-        if str(path[-1].key) == "b" else x,
-        init_lora_params(jax.random.PRNGKey(1), CFG, 4),
-    )
-    return params, lora
-
-
-#: the reference's whole program, traced once a shape and not once a call
-#: (a test asks for it a row group at a time)
-_reference = jax.jit(
-    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
-
-
-def reference_logprobs(params, lora, ids, mask):
-    return np.asarray(_reference(
-        params, CFG, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
-        lora_scale=LORA_SCALE))
+FAMILY = fs.Family(
+    name="latent-moe", cfg=CFG, ref=ref, config_file="kimi-vl-a3b-L7.json",
+    # projections large enough that a dropped term moves the logits, an adapter
+    # (kv_b's too) whose b is not zero
+    weight_scale=3.0,
+    # Eight tokens or fewer take the dense form (a decode step of 8 rows), more
+    # the grouped one (a prefill segment, the learner's rows), as 128 divides
+    # the 64-row decode step from the 4,096-token segment at the real size.
+    pieces=((moe, "DENSE_MAX_TOKENS", 8),),
+    # Prefill in segments of 16 tokens and decode attention over 3 pages (6 where
+    # a group shares them: the scores of 4 rows' heads over 6 pages of 8) and 4
+    # rows at a time, so that 40-57-token prompts in pages of 8 cross every
+    # boundary the 21k-token cell crosses.
+    engine_pieces=(
+        (paged_engine, "HYBRID_PREFILL_SEGMENT", 16),
+        (hybrid, "LATENT_DECODE_PAGES", 3), (hybrid, "LATENT_DECODE_ROWS", 4),
+        (latent_attention, "SHARED_SCORE_BYTES", 4 * CFG.num_heads * 6 * 8 * 4)),
+    refusals=(
+        ({"n_group": 8}, "n_group"),
+        ({"topk_group": 4}, "topk_group"),
+        ({"scoring_func": "softmax"}, "scoring_func"),
+        ({"topk_method": "greedy"}, "topk_method"),
+        ({"rope_scaling": {"rope_type": "yarn", "factor": 64}}, "rope_scaling"),
+        ({"moe_layer_freq": 2}, "moe_layer_freq"),
+        ({"model_type": "deepseek_v2"}, "deepseek_v2")),
+    forward_cases=(("plain", False, ()),),
+    # every adapter factor (kv_a's, kv_b's, the shared expert's)
+    learner={"answer": 20, "leaves": None, "floor": 1e-7},
+    # prefill in segments over earlier segments' latent pages (expanded), the
+    # fan-out aliasing the prompt's pages, then absorbed decode through the cache
+    rounds=(("refill", 4), ("refill", 8), ("waves", 0)), round_check=_round_check,
+    # bf16 latent pages leave the 2e-5 agreement by a wide margin: it is what
+    # holds the pages' precision, whatever the chip's check can tell
+    engine_controls={"bf16_pages": lambda monkeypatch: {"cache_dtype": jnp.bfloat16}},
+    engine_limit=20 * 2e-5,
+    # sixteen candidates equal sixteen single rows, one at a time
+    fan_out={"scheduler": "refill", "slots": 16, "length": 50, "n": 16, "max_tokens": 24,
+             "atol": 1e-5, "rows": True},
+    state_refusals=(
+        ("dense", "dense engine"), ("sharded", "dp-sharded"), ("speculation", "spec_draft"),
+        ("int8_pool", "int8"), ("radix_cache", "prefix_sharing"),
+        ("pool_chains", "prefix_sharing"), ("continuous_admission", "continuous_admission"),
+        ("preemption", "re-prefill")),
+    state_refusal_says=("latent-attention (MLA)", "routed-expert", "latent row"),
+)
+family, small_pieces, weights = fs.fixtures(FAMILY)
 
 
 def moe_layer(params, j=0):
@@ -89,28 +105,11 @@ def moe_layer(params, j=0):
 # ------------------------------------------------------------- the forward
 
 
-def test_forward_equals_the_reference_with_padding_on_both_sides(weights):
-    params, lora = weights
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, 40), 1, 256))
-    mask = np.ones((3, 40), np.int32)
-    mask[0, :7] = 0
-    mask[1, 33:] = 0
-    logits, _ = forward(params, CFG, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
-                        lora=lora, lora_scale=LORA_SCALE)
-    got = jnp.take_along_axis(
-        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0]
-    both = (mask[:, 1:] * mask[:, :-1]) > 0
-    want = reference_logprobs(params, lora, ids, mask)
-    assert np.abs(np.asarray(got) - want)[both].max() < 2e-5
-
-
 @pytest.mark.parametrize("control", [
     "top1", "no_shared", "no_scaling", "no_bias", "no_k_rope", "no_kvb_adapter"])
 def test_the_forward_can_tell_each_mechanism(weights, control, monkeypatch):
     """Each term the chip's controls drop moves this file's agreement by far
     more than its tolerance: a check that passes with one missing is no check."""
-    from distrl_llm_tpu.models import hybrid
-
     params, lora = weights
     cfg = CFG
     if control == "top1":
@@ -137,11 +136,10 @@ def test_the_forward_can_tell_each_mechanism(weights, control, monkeypatch):
                            for kind, stack in lora["layers"].items()}}
     ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (2, 40), 1, 256))
     mask = np.ones((2, 40), np.int32)
-    logits, _ = forward(params, cfg, jnp.asarray(ids), lora=lora, lora_scale=LORA_SCALE)
-    got = jnp.take_along_axis(
-        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0]
-    want = reference_logprobs(*weights, ids, mask)
-    assert np.abs(np.asarray(got) - want).mean() > 50 * 2e-5
+    fs.fresh_traces(monkeypatch)  # ``no_k_rope`` patches a function the trace reads
+    got = fs.forward_logprobs(FAMILY, params, lora, ids, mask, cfg)
+    want = fs.reference_logprobs(FAMILY, *weights, ids, mask)
+    assert np.abs(got - want).mean() > 50 * 2e-5
 
 
 # ------------------------------------------------------------ the attention
@@ -193,6 +191,7 @@ def test_absorbed_equals_expanded_with_an_adapter_on_kv_b(blocks):
 
 
 # ------------------------------------------- a prefill segment's fold kernel
+
 
 FOLD = dict(b=2, s=256, h=2, nope=16, rope=8, v_dim=16)
 
@@ -259,11 +258,15 @@ def test_the_fold_kernel_is_expanded_attention(case, q_start, real):
 
 FOLD_WIDE_V = dict(b=2, s=256, h=2, nope=24, rope=8, v_dim=32)
 #: head layouts the kernel reads off its shapes: (nope, rope, v)
+
+
 LAYOUTS = {
     "k24+8_v32": (24, 8, 32),  # the rope part in K's only, unfilled tile
     "k192+64_v128": (192, 64, 128),  # GLM-5's keys: a whole tile, then K's rest and k_pe in one
     "k128+64_v128": (128, 64, 128),  # Kimi-VL's: a tile of K, a tile of k_pe
 }
+
+
 KEYS = 3 * 256 + 128  # a page table's width: the blocks the folds reach and a ragged rest
 
 
@@ -426,9 +429,9 @@ def test_full_mode_never_asks_for_the_kernel(weights, monkeypatch):
     ids = jnp.ones((2, 32), jnp.int32)
 
     def lowered():
-        step = jax.jit(lambda p, l, i: forward(p, CFG, i, lora=l, lora_scale=LORA_SCALE)[0])
+        step = jax.jit(lambda p, l, i: forward(p, CFG, i, lora=l, lora_scale=fs.LORA_SCALE)[0])
         grad = jax.jit(jax.grad(lambda l, p, i: forward(
-            p, CFG, i, lora=l, lora_scale=LORA_SCALE)[0].sum()))
+            p, CFG, i, lora=l, lora_scale=fs.LORA_SCALE)[0].sum()))
         return step.lower(params, lora, ids).as_text(), grad.lower(lora, params, ids).as_text()
 
     on_cpu = lowered()
@@ -442,8 +445,6 @@ def test_full_mode_never_asks_for_the_kernel(weights, monkeypatch):
     assert lowered() == on_cpu
     assert "custom_call" not in on_cpu[0] and "custom_call" not in on_cpu[1]
 
-
-# ------------------------------------------------ the walk over shared pages
 
 WALK = dict(heads=4, nope=16, rope=8, v_dim=16, rank=32, row=48, ps=8, per=3,
             prompt_pages=16, private_pages=4)
@@ -674,140 +675,7 @@ def test_a_layer_read_from_the_whole_stack_equals_its_slice(weights, form, monke
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
-# -------------------------------------------------------------- the learner
-
-
-def test_the_learners_loss_and_adapter_gradient_are_the_references(weights):
-    """No cache, remat, chunked cross-entropy: the policy-gradient loss over
-    the answers and its gradient in every adapter factor (kv_a's, kv_b's, the
-    shared expert's) against plain reverse mode through the reference."""
-    from distrl_llm_tpu.learner.losses import answer_logprobs, pg_loss
-
-    params, lora = weights
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(1, 256, (4, 12)).astype(np.int32)
-    pmask = np.ones((4, 12), np.int32)
-    pmask[0, :5] = 0
-    answer = rng.integers(1, 256, (4, 20)).astype(np.int32)
-    amask = np.ones((4, 20), np.int32)
-    amask[2, 14:] = 0
-    coeffs = jnp.asarray([0.7, -1.1, 0.4, 1.3])
-
-    def loss(lo):
-        logp = answer_logprobs(
-            params, CFG, jnp.asarray(prompt), jnp.asarray(pmask), jnp.asarray(answer),
-            jnp.asarray(amask), lora=lo, lora_scale=LORA_SCALE, remat=True, logit_chunk=8)
-        return pg_loss(logp, jnp.asarray(amask), coeffs)
-
-    got_loss, got = jax.value_and_grad(loss)(lora)
-    ids = np.concatenate([prompt, answer], 1)
-    mask = np.concatenate([pmask, amask], 1)
-    scored = np.concatenate([np.zeros_like(pmask), amask], 1)
-    want_loss, want = ref.pg_loss_and_lora_grad(
-        params, CFG, lora, LORA_SCALE, jnp.asarray(ids), jnp.asarray(mask),
-        jnp.asarray(scored), coeffs)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-5
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree_util.tree_leaves(want)):
-        assert float(jnp.abs(w).max()) > 0, path
-        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-7,
-                                   err_msg=str(path))
-
-
 # -------------------------------------------------------------- the engine
-
-
-def make_engine(scheduler, slots, **kw):
-    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
-
-    kw.setdefault("cache_dtype", jnp.float32)
-    kw.setdefault("page_size", 8)
-    return PagedGenerationEngine(
-        CFG, max_prompt_tokens=64, max_new_tokens=24, eos_token_ids=[-1],
-        pad_token_id=0, lora_scale=LORA_SCALE,
-        scheduler=scheduler, max_concurrent_rows=slots, capture_logprobs=True,
-        autotune=False, **kw)
-
-
-def prompts(lengths, width=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = np.zeros((len(lengths), width), np.int32)
-    mask = np.zeros((len(lengths), width), np.int32)
-    for r, n in enumerate(lengths):
-        ids[r, width - n:] = rng.integers(1, 256, n)
-        mask[r, width - n:] = 1
-    return ids, mask
-
-
-@pytest.fixture
-def small_pieces(monkeypatch):
-    """Prefill in segments of 16 tokens and decode attention over 3 pages (6
-    where a group shares them) and 4 rows at a time, so that 40-57-token
-    prompts in pages of 8 cross every boundary the 21k-token cell crosses."""
-    from distrl_llm_tpu.engine import paged_engine
-    from distrl_llm_tpu.models import hybrid
-    from distrl_llm_tpu.ops import latent_attention
-
-    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
-    monkeypatch.setattr(hybrid, "LATENT_DECODE_PAGES", 3)
-    monkeypatch.setattr(hybrid, "LATENT_DECODE_ROWS", 4)
-    # a shared block of 6 columns: the scores of 4 rows' heads over 6 pages of 8
-    monkeypatch.setattr(latent_attention, "SHARED_SCORE_BYTES",
-                        4 * CFG.num_heads * 6 * 8 * 4)
-    assert moe.DENSE_MAX_TOKENS == 8  # 8 decode rows dense, 32-token segments grouped
-
-
-def worst_difference(params, lora, ids, mask, result):
-    worst = 0.0
-    for b in range(ids.shape[0]):
-        prompt = ids[b][mask[b] > 0]
-        rows = np.stack([np.concatenate([prompt, result.tokens[b, j]])
-                         for j in range(result.tokens.shape[1])])
-        want = reference_logprobs(params, lora, rows, np.ones_like(rows))
-        worst = max(worst, np.abs(result.logprobs[b] - want[:, len(prompt) - 1:]).max())
-    return worst
-
-
-@pytest.mark.parametrize("scheduler,slots", [
-    ("refill", 4),  # 8 rows through 4 slots: a freed slot aliases another prompt
-    ("refill", 8),  # every candidate admitted at once
-    ("waves", 0),   # prefill, fan-out, lockstep
-])
-def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
-                                                      small_pieces):
-    """Prefill in segments over earlier segments' latent pages (expanded), the
-    fan-out aliasing the prompt's pages, then absorbed decode through the
-    cache: the engine's own captured log-probability of every token it sampled
-    is the reference's full forward's."""
-    from distrl_llm_tpu import telemetry
-
-    params, lora = weights
-    ids, mask = prompts((40, 57))
-    before = telemetry.observe_snapshot()["counters"]
-    result = make_engine(scheduler, slots).generate(
-        params, lora, ids, mask,
-        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
-        jax.random.PRNGKey(3))
-    assert (result.lengths == 24).all()
-    assert result.steps_dispatched >= 24 * (2 if slots == 4 else 1)
-    assert result.alive_slot_steps == 8 * 24
-    assert worst_difference(params, lora, ids, mask, result) < 2e-5
-    after = telemetry.observe_snapshot()["counters"]
-    moved = lambda name: after[name] - before.get(name, 0)
-    # 2 expert layers x 8 rows x 24 steps x 2 experts a token, live slots only
-    assert moved("engine/moe_assignments") == 2 * 8 * 24 * 2
-    # the fullest of 8 experts holds at least the mean, at most every pair's half
-    assert 2 * 24 * 2 <= moved("engine/moe_max_expert_load") <= 2 * 8 * 24
-    # absorbed attention's pages, from the page table by hand: at its step t a
-    # row of a P-token prompt holds (P + t) // 8 + 1 pages in each of 3 layers
-    held = np.asarray([[(p + t) // 8 + 1 for t in range(24)] for p in (40, 57)])
-    assert moved("engine/latent_pages_attended") == 3 * 4 * held.sum()
-    if scheduler == "waves":
-        # a group of 4 is one prompt's candidates: its full pages (5 and 7) in
-        # whole blocks of 6 columns are fetched once, every other page a row
-        once = np.asarray([[5 // 6 * 6], [7 // 6 * 6]])
-        assert moved("engine/latent_pages_read") == 3 * (once + 4 * (held - once)).sum()
-    assert moved("engine/latent_pages_read") <= moved("engine/latent_pages_attended")
 
 
 @pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
@@ -821,8 +689,8 @@ def test_a_cpu_round_counts_no_kernel_folds(weights, small_pieces, scheduler, sl
     params, lora = weights
     before = telemetry.observe_snapshot()["counters"].get(
         telemetry.OPS_LATENT_KERNEL_FOLDS, 0)
-    make_engine(scheduler, slots).generate(
-        params, lora, *prompts((40, 57)),
+    fs.engine(FAMILY, scheduler, slots).generate(
+        params, lora, *fs.prompts((40, 57)),
         SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=4),
         jax.random.PRNGKey(3))
     assert la.dispatch_choices[la.dispatch_key(4, 16, 8, 16, 16, jnp.float32)] == "xla"
@@ -835,7 +703,6 @@ def test_the_counter_is_layers_times_folds_where_the_kernel_ran(monkeypatch, ran
     """A prefill of 64 tokens in segments of 16 makes 1 + 2 + 3 + 4 folds in
     each of the 3 layers (the Kimi cell: 7 x 210 = 1,470)."""
     from distrl_llm_tpu import telemetry
-    from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.ops import latent_attention as la
 
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 16)
@@ -860,23 +727,21 @@ def test_a_prefill_through_the_kernel_equals_the_reference(weights, small_pieces
     segments of 16, so both rows end mid-segment and their last segments' later
     queries are padding. The captured log-probabilities are the reference's,
     and the counter reads 3 layers x (1 + 2 + 3 + 4) folds."""
-    import functools
-
     from distrl_llm_tpu import telemetry
     from distrl_llm_tpu.ops import latent_attention as la
 
     params, lora = weights
-    ids, mask = prompts((40, 57))
+    ids, mask = fs.prompts((40, 57))
     monkeypatch.setattr(la, "expanded_segment_impl", lambda q_nope, v_dim: "kernel")
     monkeypatch.setattr(la, "expanded_fold_kernel", functools.partial(
         la.expanded_fold_kernel, interpret=True))
     before = telemetry.observe_snapshot()["counters"].get(
         telemetry.OPS_LATENT_KERNEL_FOLDS, 0)
-    result = make_engine("waves", 0).generate(
+    result = fs.make_engine(FAMILY, "waves", 0).generate(
         params, lora, ids, mask,
         SamplingConfig(temperature=1.0, top_p=1.0, n=2, max_tokens=4),
         jax.random.PRNGKey(3))
-    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    assert fs.worst_difference(FAMILY, params, lora, ids, mask, result) < 2e-5
     after = telemetry.observe_snapshot()["counters"][telemetry.OPS_LATENT_KERNEL_FOLDS]
     assert after - before == 3 * 10
 
@@ -887,9 +752,9 @@ def test_slots_of_mixed_prompts_fetch_every_page_a_row(weights, small_pieces):
     from distrl_llm_tpu import telemetry
 
     params, lora = weights
-    ids, mask = prompts((40, 57))
+    ids, mask = fs.prompts((40, 57))
     before = telemetry.observe_snapshot()["counters"]
-    result = make_engine("refill", 4).generate(
+    result = fs.engine(FAMILY, "refill", 4).generate(
         params, lora, ids, mask,
         SamplingConfig(temperature=1.0, top_p=1.0, n=2, max_tokens=24),
         jax.random.PRNGKey(3))
@@ -901,33 +766,6 @@ def test_slots_of_mixed_prompts_fetch_every_page_a_row(weights, small_pieces):
     assert moved("engine/latent_pages_read") == moved("engine/latent_pages_attended")
 
 
-def test_this_files_agreement_can_tell_lower_precision_pages(weights, small_pieces):
-    """bf16 latent pages leave the 2e-5 agreement by a wide margin: it is what
-    holds the pages' precision, whatever the chip's check can tell."""
-    params, lora = weights
-    ids, mask = prompts((40, 57))
-    result = make_engine("waves", 0, cache_dtype=jnp.bfloat16).generate(
-        params, lora, ids, mask,
-        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
-        jax.random.PRNGKey(3))
-    assert worst_difference(params, lora, ids, mask, result) > 20 * 2e-5
-
-
-def test_sixteen_candidates_equal_sixteen_single_rows(weights, small_pieces):
-    """A group's fan-out aliases one prompt's latent pages: at temperature 0
-    its 16 candidates are what 16 rows of the same prompt give, one at a time."""
-    params, lora = weights
-    ids, mask = prompts((50,))
-    greedy = dict(temperature=0.0, top_p=1.0, max_tokens=24)
-    group = make_engine("refill", 16).generate(
-        params, lora, ids, mask, SamplingConfig(n=16, **greedy), jax.random.PRNGKey(0))
-    single = make_engine("refill", 16).generate(
-        params, lora, np.repeat(ids, 16, 0), np.repeat(mask, 16, 0),
-        SamplingConfig(n=1, **greedy), jax.random.PRNGKey(0))
-    np.testing.assert_array_equal(group.tokens[0], single.tokens[:, 0])
-    np.testing.assert_allclose(group.logprobs[0], single.logprobs[:, 0], atol=1e-5)
-
-
 def test_the_pool_is_one_latent_array_a_layer_and_its_budget():
     from distrl_llm_tpu.engine.budget import page_bytes
     from distrl_llm_tpu.engine.paged_engine import _copy_pages, _grow_pool
@@ -935,7 +773,7 @@ def test_the_pool_is_one_latent_array_a_layer_and_its_budget():
     assert CFG.page_pool_shape(10, 8) == (10, 8, 128) and CFG.paged_layers == 3
     assert (CFG.latent_dim, CFG.latent_row) == (40, 128)  # whole 128-lane tiles
     assert page_bytes(CFG, 8) == 8 * 128 * 2 * 3  # no V, no kv-head factor
-    published = ModelConfig.from_hf_config(hf_config())
+    published = ModelConfig.from_hf_config(fs.hf_config(FAMILY))
     assert (published.latent_dim, published.latent_row) == (576, 640)
     assert page_bytes(published, 128) == 128 * 640 * 2 * 7
     pool = jnp.arange(4 * 2 * 3, dtype=jnp.float32).reshape(4, 2, 3)
@@ -946,80 +784,22 @@ def test_the_pool_is_one_latent_array_a_layer_and_its_budget():
     assert (copied[4] == pool[0]).all() and not copied[5].any()
 
 
-# ------------------------------------------------------------ the refusals
-
-
-def _paged(**kw):
-    return lambda: make_engine(kw.pop("scheduler", "refill"), 4, **kw)
-
-
-def _dense():
-    from distrl_llm_tpu.engine.engine import GenerationEngine
-
-    return GenerationEngine(
-        CFG, max_prompt_tokens=16, max_new_tokens=8, eos_token_ids=[1], pad_token_id=0)
-
-
-def _sharded():
-    from distrl_llm_tpu.engine.sharded_paged import ShardedPagedEngine
-
-    return ShardedPagedEngine(
-        CFG, mesh=None, max_prompt_tokens=16, max_new_tokens=8, eos_token_ids=[1],
-        pad_token_id=0)
-
-
-@pytest.mark.parametrize("build,what", [
-    (_dense, "dense engine"),
-    (_sharded, "dp-sharded"),
-    (_paged(spec_draft=2), "spec_draft"),
-    (_paged(kv_quant="int8"), "int8"),
-    (_paged(continuous_admission=True, prefix_cache=True), "prefix_sharing"),
-    (_paged(prefix_sharing=True), "prefix_sharing"),
-    (_paged(continuous_admission=True), "continuous_admission"),
-    (_paged(max_kv_pages=64), "re-prefill"),
-], ids=["dense", "sharded", "speculation", "int8_kv", "radix_cache", "prefix_sharing",
-        "continuous_admission", "preemption"])
-def test_what_holds_k_and_v_of_one_kind_refuses_the_model_by_name(build, what):
-    with pytest.raises(ValueError) as err:
-        build()
-    assert what in str(err.value)
-    assert "latent-attention (MLA)" in str(err.value) and "routed-expert" in str(err.value)
-    assert "latent row" in str(err.value)
-
-
 def test_spill_and_turn_hook_refuse_too(weights):
     with pytest.raises(ValueError, match="kv_spill.*latent-attention"):
         CFG.refuse_hybrid("kv_spill (K/V pages parked in host memory)")
-    engine = make_engine("refill", 4)
+    engine = fs.make_engine(FAMILY, "refill", 4)
     engine.turn_hook = lambda cand, tokens: None
     params, lora = weights
     with pytest.raises(ValueError, match="turn_hook.*latent-attention"):
-        engine.generate(params, lora, *prompts((20,)), SamplingConfig(n=1, max_tokens=4),
+        engine.generate(params, lora, *fs.prompts((20,)), SamplingConfig(n=1, max_tokens=4),
                         jax.random.PRNGKey(0))
-
-
-@pytest.mark.parametrize("switch", ["paged_verify", "paged_chunked", "paged_prefix"])
-def test_forward_refuses_the_dense_decoders_other_cache_modes(weights, switch):
-    params, _ = weights
-    cache = {"k": (), "v": (), "lin": (), "pooled": (), "lengths": jnp.zeros((1,), jnp.int32),
-             "page_indices": jnp.zeros((1, 4), jnp.int32)}
-    with pytest.raises(NotImplementedError, match=switch):
-        forward(params, CFG, jnp.zeros((1, 4), jnp.int32), kv_cache=cache, page_size=4,
-                **{switch: True})
 
 
 # -------------------------------------------------- the config and the loader
 
 
-def hf_config(**changes):
-    import json
-
-    with open(os.path.join(REPO, "perfbench/configs/kimi-vl-a3b-L7.json")) as f:
-        return SimpleNamespace(**{**json.load(f), **changes})
-
-
 def test_from_hf_config_reads_the_published_file():
-    cfg = ModelConfig.from_hf_config(hf_config())
+    cfg = ModelConfig.from_hf_config(fs.hf_config(FAMILY))
     assert cfg.latent and cfg.hybrid and cfg.model_type == "deepseek_v3"
     assert cfg.layer_kinds == ("latent",) + ("latent_moe",) * 6
     assert (cfg.head_dim, cfg.q_dim, cfg.latent_dim) == (192, 3072, 576)
@@ -1028,7 +808,7 @@ def test_from_hf_config_reads_the_published_file():
     # what a token runs against what is held: 917M against 3,928M (+ 336M embedding)
     assert cfg.matmul_param_count == 917_110_784
     assert cfg.total_matmul_param_count + cfg.vocab_size * cfg.hidden_size == 4_263_116_800
-    full = ModelConfig.from_hf_config(hf_config(num_hidden_layers=27))
+    full = ModelConfig.from_hf_config(fs.hf_config(FAMILY, num_hidden_layers=27))
     assert full.layer_kinds.count("latent_moe") == 26
 
 
@@ -1043,7 +823,7 @@ def test_a_deepseek_v3_with_a_query_rank_loads_and_equals_the_reference():
 
     from perfbench import reference_dsa_moe
 
-    loaded = ModelConfig.from_hf_config(hf_config(q_lora_rank=1536))
+    loaded = ModelConfig.from_hf_config(fs.hf_config(FAMILY, q_lora_rank=1536))
     assert (loaded.q_lora_rank, loaded.index_topk, loaded.model_type) == (
         1536, 0, "deepseek_v3")
     cfg = dataclasses.replace(CFG, q_lora_rank=48)
@@ -1059,7 +839,7 @@ def test_a_deepseek_v3_with_a_query_rank_loads_and_equals_the_reference():
     assert set(lora["layers"]["latent_moe"]) == {
         "wq_a", "wq", "wkv_a", "wkv_b", "wo", "w_gate", "w_up", "w_down"}
     ids = jax.random.randint(jax.random.PRNGKey(2), (2, 40), 1, 256)
-    got, _ = forward(params, cfg, ids, lora=lora, lora_scale=LORA_SCALE)
+    got, _ = forward(params, cfg, ids, lora=lora, lora_scale=fs.LORA_SCALE)
     # the same weights under an index of one head that chooses all 40 tokens
     told = dataclasses.replace(cfg, index_heads=1, index_head_dim=8, index_topk=64)
     index = lambda n: {
@@ -1070,22 +850,8 @@ def test_a_deepseek_v3_with_a_query_rank_loads_and_equals_the_reference():
         kind: {**stack, **index(stack["wq"].shape[0])}
         for kind, stack in params["layers"].items()}}
     want = reference_dsa_moe.full_logits(
-        with_index, told, ids, jnp.ones_like(ids), lora=lora, lora_scale=LORA_SCALE)
+        with_index, told, ids, jnp.ones_like(ids), lora=lora, lora_scale=fs.LORA_SCALE)
     np.testing.assert_allclose(got, want, atol=2e-5)
-
-
-@pytest.mark.parametrize("changes,named", [
-    ({"n_group": 8}, "n_group"),
-    ({"topk_group": 4}, "topk_group"),
-    ({"scoring_func": "softmax"}, "scoring_func"),
-    ({"topk_method": "greedy"}, "topk_method"),
-    ({"rope_scaling": {"rope_type": "yarn", "factor": 64}}, "rope_scaling"),
-    ({"moe_layer_freq": 2}, "moe_layer_freq"),
-    ({"model_type": "deepseek_v2"}, "deepseek_v2"),
-])
-def test_from_hf_config_refuses_what_it_cannot_represent(changes, named):
-    with pytest.raises(ValueError, match=named):
-        ModelConfig.from_hf_config(hf_config(**changes))
 
 
 def published_state_dict(params, cfg):
@@ -1169,7 +935,7 @@ def test_a_saved_snapshot_loads_back_as_the_same_model(weights, tmp_path):
 
 
 def test_adapter_factors_follow_each_kinds_shapes_and_merge(weights):
-    from distrl_llm_tpu.models.lora import LATENT_TARGETS, merge_lora
+    from distrl_llm_tpu.models.lora import LATENT_TARGETS
 
     params, lora = weights
     assert set(lora["layers"]) == {"latent", "latent_moe"}
@@ -1179,11 +945,7 @@ def test_adapter_factors_follow_each_kinds_shapes_and_merge(weights):
         assert stack["wkv_a"]["b"].shape[-1] == 40 and stack["wkv_b"]["a"].shape[1] == 32
         assert stack["wo"]["a"].shape[1] == 4 * 16  # H x v, not the query's width
         assert stack["w_gate"]["b"].shape[-1] == width
-    merged = merge_lora(params, lora, alpha=8.0)
-    ids = jax.random.randint(jax.random.PRNGKey(2), (1, 20), 1, 256)
-    a, _ = forward(merged, CFG, ids)
-    b, _ = forward(params, CFG, ids, lora=lora, lora_scale=2.0)
-    np.testing.assert_allclose(a, b, atol=2e-4)
+    fs.merged_equals_adapted(FAMILY, params, lora)
 
 
 def test_every_new_leaf_has_a_partition_spec_and_is_whole_on_a_chip(weights):

@@ -9,15 +9,16 @@ base a kind; a sink a query head in the window layers; a ring of 8 tokens; 2 of
 8 experts held, 3 a token, no shared expert). Float32 throughout, seeded
 weights with every term alive.
 
-One engine of each scheduler is built a module; the bent mechanisms are the
-cases of one parametrised test. The rollout through ``perfbench/run.py`` is
+The rollout through ``perfbench/run.py`` is
 held by ``tests/perfbench/test_perfbench_rehearsal_swa_sink_moe.py``.
+
+This file holds the family's record and the cases of its own mechanism; the
+cases every family repeats are ``tests/test_family_conformance.py``'s.
 """
 
 import dataclasses
+import functools
 import json
-import os
-import sys
 from types import SimpleNamespace
 
 import jax
@@ -25,88 +26,184 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
-
-from distrl_llm_tpu.config import SamplingConfig  # noqa: E402
-from distrl_llm_tpu.models import ModelConfig, forward, init_lora_params, init_params  # noqa: E402
-from distrl_llm_tpu.models import hybrid, moe  # noqa: E402
-from distrl_llm_tpu.models.configs import PRESETS  # noqa: E402
-from perfbench import reference_swa_sink_moe as ref  # noqa: E402
-from perfbench import swa_sink_moe_counts as counts  # noqa: E402
+import family_suite as fs
+from distrl_llm_tpu.engine import paged_engine
+from distrl_llm_tpu.models import ModelConfig, configs, init_params
+from distrl_llm_tpu.models import hybrid, moe
+from distrl_llm_tpu.models.configs import PRESETS
+from perfbench import reference_swa_sink_moe as ref
+from perfbench import swa_sink_moe_counts as counts
 
 CFG = PRESETS["tiny-swa-sink-moe"]
-LORA_SCALE = 2.0
-CONFIG_FILE = os.path.join(REPO, "perfbench", "configs", "mimo-v2-flash-ep16-L7.json")
 #: lanes a cached key's row takes in the engine tests: a "tile" of 16 lanes there
-#: (``small_pieces``), so that a head of 24 is a tile and a half as 192 is of 128
+#: (``SMALL_PIECES``), so that a head of 24 is a tile and a half as 192 is of 128
 KEY_ROW = 32
 #: bytes of one slot's rings in one window layer (float32 caches here): 4 KV
 #: heads x 8 tokens x (a key's row + 16 values)
 RING_BYTES = 4 * 8 * (KEY_ROW + 16) * 4
 #: bytes one token costs a full layer's pages: 2 KV heads x (a key's row + 16 values)
 TOKEN_BYTES = 2 * (KEY_ROW + 16) * 4
+#: Prefill in segments of 12 tokens (three pages of 4) under a window of 8: a
+#: segment does NOT end on the window's edge, so 40- and 57-token prompts cross
+#: every boundary the cell's 10k-20k-token prompts cross and one more: the ring
+#: carried from segment to segment mid-window, a window that starts in the
+#: segment before, the full layers over earlier segments' pages a page of keys
+#: at a time, a last segment that is part padding. A "lane tile" of 16: a key of
+#: 24 is kept in 32 lanes, zeros after it, in the pages and in the rings, as the
+#: chip keeps 192 in 256. Decode rows dense, segments grouped.
+SMALL_PIECES = ((configs, "KEY_ROW_LANES", 16), (paged_engine, "HYBRID_PREFILL_SEGMENT", 12),
+                (moe, "DENSE_MAX_TOKENS", 8))
 
 
-@pytest.fixture(scope="module", autouse=True)
-def exact_matmuls():
-    with jax.default_matmul_precision("highest"):
-        yield
+def _with_attention(monkeypatch, bend):
+    """Every softmax of the PROGRAM's three modes (``hybrid.attention``, the
+    ring's and the segment's ``hybrid.attention_reference``) called through
+    ``bend(fn, q, k, v, mask, kw)``; a window layer's call is told by its
+    ``sink`` keyword."""
+    for name in ("attention", "attention_reference"):
+        fn = getattr(hybrid, name)
+        monkeypatch.setattr(
+            hybrid, name,
+            lambda q, k, v, mask, fn=fn, **kw: bend(fn, q, k, v, mask, kw))
 
 
-def seeded(cfg, rank=4):
-    """Seeded weights with every term alive: norms off 1, sinks Normal(0, 1), a
-    correction bias and an adapter's b that are not zero."""
-    def base(path, x):
-        name = str(path[-1].key)
-        key = jax.random.PRNGKey(sum(map(ord, str(path))) % 9973)
-        if name.endswith("norm"):
-            return 1.0 + 0.3 * jax.random.normal(key, x.shape)
-        if name == "sink":
-            return jax.random.normal(key, x.shape)
-        if name == "e_score_bias":
-            return 0.05 * jax.random.normal(key, x.shape)
-        return 6.0 * x
+def _control(name, monkeypatch):
+    """Bend the PROGRAM in one place (never the reference). Returns the
+    configuration the program is then given."""
+    for module, attribute, value in SMALL_PIECES:  # this file's controls always ran under them
+        monkeypatch.setattr(module, attribute, value)
+    cfg, params = CFG, fs.weights(FAMILY)[0]
+    if name == "no_sink":
+        mix = hybrid._window_mix
+        monkeypatch.setattr(hybrid, "_window_mix", lambda x, p, *a, **kw: mix(
+            x, {k: v for k, v in p.items() if k != "sink"}, *a, **kw))
+    elif name == "sink_in_full_layers":
+        fake = params["layers"]["window"]["sink"][0]
+        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
+            q, k, v, mask, **{**kw, "sink": kw.get("sink", fake)}))
+    elif name == "sink_with_a_value":
+        # the sink's column given a value row of 0.5: what it takes of the
+        # denominator comes back as output (a row of zeros would be right)
+        def counted(fn, q, k, v, mask, kw):
+            o = fn(q, k, v, mask, **kw)
+            if kw.get("sink") is None:
+                return o
+            kept = fn(q, k, jnp.ones_like(v), mask, **kw)  # sum_j p[t, j] = 1 - p_sink
+            return o + 0.5 * (1.0 - kept)
+        _with_attention(monkeypatch, counted)
+    elif name == "window_grouped_as_full":
+        # a query head reads KV head i // (H / 2) in a window layer too: its
+        # first 2 KV heads of 4, in groups of 4 for 2
+        def grouped(fn, q, k, v, mask, kw):
+            if kw.get("sink") is None:
+                return fn(q, k, v, mask, **kw)
+            return fn(q, k[:, :, :CFG.num_kv_heads], v[:, :, :CFG.num_kv_heads], mask, **kw)
+        _with_attention(monkeypatch, grouped)
+    elif name == "no_value_scale":
+        cfg = dataclasses.replace(cfg, value_scale=1.0)
+    elif name == "value_scaled_twice":
+        for mix in ("_window_mix", "_softmax_mix"):
+            fs.with_proj(monkeypatch, mix, lambda key, y, env, mode: (
+                y * CFG.value_scale if key == "wo" else y))
+    elif name == "rope_on_all_dims":
+        cfg = dataclasses.replace(cfg, rotary_dim=0)
+    elif name == "bases_swapped":
+        cfg = dataclasses.replace(cfg, rope_theta=cfg.window_rope_theta,
+                                  window_rope_theta=cfg.rope_theta)
+    elif name == "scores_over_sqrt_v":
+        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
+            q * (q.shape[-1] / v.shape[-1]) ** 0.5, k, v, mask, **kw))
+    elif name in ("window_7", "window_9"):
+        cfg = dataclasses.replace(cfg, sliding_window=int(name[-1]))
+    elif name == "no_bias":
+        route = moe.route
+        monkeypatch.setattr(moe, "route", lambda h, router, bias, c: route(
+            h, router, jnp.zeros_like(bias), c))
+    elif name == "top_2":
+        cfg = dataclasses.replace(cfg, experts_per_token=2)
+    else:
+        raise AssertionError(name)
+    return cfg
 
-    params = jax.tree_util.tree_map_with_path(base, init_params(jax.random.PRNGKey(0), cfg))
-    lora = jax.tree_util.tree_map_with_path(
-        lambda path, x: 0.05 * jax.random.normal(jax.random.PRNGKey(5), x.shape)
-        if str(path[-1].key) == "b" else x,
-        init_lora_params(jax.random.PRNGKey(1), cfg, rank),
-    )
-    return params, lora
+
+def _round_check(moved, result, engine, scheduler, slots):
+    """20 tokens a row, past the window, over the slots' rings with their sink
+    and the pages of two widths: the two gauges are what the pools' and the
+    rings' shapes say."""
+    from distrl_llm_tpu import telemetry
+
+    said = moved("engine/window_pages_attended"), moved("engine/window_pages_visible")
+    model = dataclasses.asdict(CFG)
+    assert said == counts.window_pages(
+        model, [40] * 4 + [57] * 4, result.lengths.reshape(-1)) == (5 * 8 * 20,) * 2
+    # expert layers x choices x rows x steps: not layer 0
+    assert moved("engine/moe_pairs_routed") == 6 * 3 * 8 * 20
+    # one more token of context: two full layers' K (its row) and V (its width)
+    assert telemetry.observe_snapshot()["gauges"]["engine/cache_token_bytes"] == 2 * TOKEN_BYTES
+    # the counts module says what the ALGORITHM moves: a key's own 24 values
+    assert counts.slot_state_bytes(model, kv_bytes=4) == 5 * 4 * 8 * (24 + 16) * 4
+    assert counts.cache_token_bytes(model, kv_bytes=4) == 2 * 2 * (24 + 16) * 4
 
 
-@pytest.fixture(scope="module")
-def weights():
-    return seeded(CFG)
+#: each bends what ``full`` mode runs; the last two are the router's
+FORWARD_CONTROLS = ["no_sink", "sink_in_full_layers", "sink_with_a_value",
+                    "window_grouped_as_full", "no_value_scale", "value_scaled_twice",
+                    "rope_on_all_dims", "bases_swapped", "scores_over_sqrt_v",
+                    "window_7", "window_9", "no_bias", "top_2"]
 
-
-#: the reference's whole program, traced once a configuration and a shape
-_reference = jax.jit(
-    ref.next_token_logprobs, static_argnums=1, static_argnames=("lora_scale",))
-
-
-def reference_logprobs(params, lora, ids, mask, cfg=CFG):
-    return np.asarray(_reference(
-        params, cfg, jnp.asarray(ids), jnp.asarray(mask), lora=lora,
-        lora_scale=LORA_SCALE))
-
-
-def forward_logprobs(params, lora, ids, mask, cfg=CFG, **kw):
-    logits, _ = forward(params, cfg, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
-                        lora=lora, lora_scale=LORA_SCALE, **kw)
-    return np.asarray(jnp.take_along_axis(
-        jax.nn.log_softmax(logits, -1)[:, :-1], jnp.asarray(ids)[:, 1:, None], -1)[..., 0])
-
-
-def padded_rows(width=40):
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (3, width), 1, 256))
-    mask = np.ones((3, width), np.int32)
-    mask[0, :7] = 0
-    mask[1, width - 7:] = 0
-    return ids, mask, (mask[:, 1:] * mask[:, :-1]) > 0
+FAMILY = fs.Family(
+    name="swa-sink-moe", cfg=CFG, ref=ref, config_file="mimo-v2-flash-ep16-L7.json",
+    seed_rules=((fs.named("sink"), fs.normal(1.0)),),  # sinks Normal(0, 1)
+    engine_pieces=SMALL_PIECES,
+    engine_kw={"prompt": 60, "max_new_tokens": 20, "page_size": 4},
+    refusals=(
+        ({"swa_num_attention_heads": 32}, "swa_num_attention_heads"),
+        ({"swa_head_dim": 128}, "swa_head_dim"),
+        ({"swa_v_head_dim": 64}, "swa_v_head_dim"),
+        ({"add_full_attention_sink_bias": True}, "add_full_attention_sink_bias"),
+        ({"sliding_window_size": 256}, "sliding_window_size"),
+        ({"attention_chunk_size": 64}, "attention_chunk_size"),
+        ({"n_shared_experts": 1}, "n_shared_experts"),
+        ({"n_group": 8}, "n_group"),
+        ({"topk_group": 4}, "topk_group"),
+        ({"scoring_func": "softmax"}, "scoring_func"),
+        ({"topk_method": "greedy"}, "topk_method"),
+        ({"attention_bias": True}, "attention_bias"),
+        ({"rope_scaling": {"type": "yarn", "factor": 4.0}}, "rope_scaling"),
+        ({"hidden_act": "gelu"}, "hidden_act"),
+        ({"hybrid_layer_pattern": [0, 2] * 24}, "hybrid_layer_pattern"),
+        ({"moe_layer_freq": [0] * 7}, "moe_layer_freq")),
+    # the band and the sink's column in a mask, K and V of two widths in two
+    # einsums, over rows padded on either side and past the window
+    forward_cases=(("plain", False, ()), ("remat", True, ())),
+    # the sink dropped, added to the full layers, or counted with a value; the
+    # window layers grouped as the full layers are; the value's scale dropped or
+    # applied twice; RoPE on every dim; the two bases swapped; scores over
+    # sqrt(16); a window one token short or long; the router's bias and count
+    forward_controls={name: functools.partial(_control, name) for name in FORWARD_CONTROLS},
+    # rows five windows long; a and b: q, k, v, o in each of three stacks, and
+    # layer 0's dense MLP's three
+    learner={"answer": 28, "leaves": 2 * (4 * 3 + 3)},
+    train_targets={"window": {"wq", "wk", "wv", "wo"}, "softmax": {"wq", "wk", "wv", "wo"},
+                   "softmax_dense": {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}},
+    # 5 window layers of 4 KV heads and 2 full layers of 2: 8 rows through 4 slots
+    # (a freed slot takes another prompt's rings); prefill, fan-out, lockstep
+    rounds=(("refill", 4), ("waves", 0)), slot_bytes=5 * RING_BYTES, round_check=_round_check,
+    # through the engine (segments, fan-out, decode over rings and pages): a ring
+    # not handed at the fan-out, a ring's V read at K's width (slot w starts
+    # w x 32 values in, not w x 16), and three of the forward's bends again,
+    # where the ring's softmax and the segment's run
+    engine_controls={
+        "ring_not_handed": fs.handed_each(("win_k", "win_v"), jnp.zeros_like),
+        "ring_v_read_at_k_width": fs.handed_each(
+            ("win_v",),
+            lambda x: jnp.pad(x.reshape(*x.shape[:2], -1), ((0, 0), (0, 0), (0, 8 * 16)))
+            .reshape(*x.shape[:2], 8, KEY_ROW)[..., :16])},
+    engine_limit=2e-3,
+    engine_mechanisms=("no_sink", "window_9", "scores_over_sqrt_v"),
+)
+family, small_pieces, weights = fs.fixtures(FAMILY)
+CONFIG_FILE = fs.config_path(FAMILY)
 
 
 # --------------------------------------------------- what the program is told
@@ -198,30 +295,6 @@ def test_from_hf_config_reads_the_benchmarks_file():
         assert file["assumed"][reading], reading
 
 
-@pytest.mark.parametrize("changes,named", [
-    ({"swa_num_attention_heads": 32}, "swa_num_attention_heads"),
-    ({"swa_head_dim": 128}, "swa_head_dim"),
-    ({"swa_v_head_dim": 64}, "swa_v_head_dim"),
-    ({"add_full_attention_sink_bias": True}, "add_full_attention_sink_bias"),
-    ({"sliding_window_size": 256}, "sliding_window_size"),
-    ({"attention_chunk_size": 64}, "attention_chunk_size"),
-    ({"n_shared_experts": 1}, "n_shared_experts"),
-    ({"n_group": 8}, "n_group"),
-    ({"topk_group": 4}, "topk_group"),
-    ({"scoring_func": "softmax"}, "scoring_func"),
-    ({"topk_method": "greedy"}, "topk_method"),
-    ({"attention_bias": True}, "attention_bias"),
-    ({"rope_scaling": {"type": "yarn", "factor": 4.0}}, "rope_scaling"),
-    ({"hidden_act": "gelu"}, "hidden_act"),
-    ({"hybrid_layer_pattern": [0, 2] * 24}, "hybrid_layer_pattern"),
-    ({"moe_layer_freq": [0] * 7}, "moe_layer_freq"),
-])
-def test_from_hf_config_refuses_what_it_cannot_represent(changes, named):
-    file = {**json.load(open(CONFIG_FILE)), **changes}
-    with pytest.raises(ValueError, match=named):
-        ModelConfig.from_hf_config(SimpleNamespace(**file))
-
-
 def test_the_loader_refuses_a_checkpoint_by_name(weights):
     from distrl_llm_tpu.models import loading
 
@@ -232,151 +305,6 @@ def test_the_loader_refuses_a_checkpoint_by_name(weights):
 
 
 # ------------------------------------------------------------- the forward
-
-
-@pytest.mark.parametrize("remat", [False, True])
-def test_forward_equals_the_reference(weights, remat):
-    """``full`` mode: the band and the sink's column in a mask, K and V of two
-    widths in two einsums, over rows padded on either side and past the window."""
-    params, lora = weights
-    ids, mask, both = padded_rows()
-    want = reference_logprobs(params, lora, ids, mask)
-    got = forward_logprobs(params, lora, ids, mask, remat=remat)
-    assert np.abs(got - want)[both].max() < 2e-5
-
-
-def _with_proj(monkeypatch, name, bend):
-    """``name`` (a mixer) handed a ``proj`` whose outputs ``bend(key, y)`` bent."""
-    mix = getattr(hybrid, name)
-
-    def run(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
-        def bent(h, p_, lora_, key, bias, scale):
-            return bend(key, proj(h, p_, lora_, key, bias, scale))
-        return mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=bent,
-                   lora_scale=lora_scale)
-    monkeypatch.setattr(hybrid, name, run)
-
-
-def _with_attention(monkeypatch, bend):
-    """Every softmax of the PROGRAM's three modes (``hybrid.attention``, the
-    ring's and the segment's ``hybrid.attention_reference``) called through
-    ``bend(fn, q, k, v, mask, kw)``; a window layer's call is told by its
-    ``sink`` keyword."""
-    for name in ("attention", "attention_reference"):
-        fn = getattr(hybrid, name)
-        monkeypatch.setattr(
-            hybrid, name,
-            lambda q, k, v, mask, fn=fn, **kw: bend(fn, q, k, v, mask, kw))
-
-
-def _control(monkeypatch, name, params):
-    """Bend the PROGRAM in one place (never the reference). Returns the
-    configuration the program is then given."""
-    cfg = CFG
-    if name == "no_sink":
-        mix = hybrid._window_mix
-        monkeypatch.setattr(hybrid, "_window_mix", lambda x, p, *a, **kw: mix(
-            x, {k: v for k, v in p.items() if k != "sink"}, *a, **kw))
-    elif name == "sink_in_full_layers":
-        fake = params["layers"]["window"]["sink"][0]
-        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
-            q, k, v, mask, **{**kw, "sink": kw.get("sink", fake)}))
-    elif name == "sink_with_a_value":
-        # the sink's column given a value row of 0.5: what it takes of the
-        # denominator comes back as output (a row of zeros would be right)
-        def counted(fn, q, k, v, mask, kw):
-            o = fn(q, k, v, mask, **kw)
-            if kw.get("sink") is None:
-                return o
-            kept = fn(q, k, jnp.ones_like(v), mask, **kw)  # sum_j p[t, j] = 1 - p_sink
-            return o + 0.5 * (1.0 - kept)
-        _with_attention(monkeypatch, counted)
-    elif name == "window_grouped_as_full":
-        # a query head reads KV head i // (H / 2) in a window layer too: its
-        # first 2 KV heads of 4, in groups of 4 for 2
-        def grouped(fn, q, k, v, mask, kw):
-            if kw.get("sink") is None:
-                return fn(q, k, v, mask, **kw)
-            return fn(q, k[:, :, :CFG.num_kv_heads], v[:, :, :CFG.num_kv_heads], mask, **kw)
-        _with_attention(monkeypatch, grouped)
-    elif name == "no_value_scale":
-        cfg = dataclasses.replace(cfg, value_scale=1.0)
-    elif name == "value_scaled_twice":
-        for mix in ("_window_mix", "_softmax_mix"):
-            _with_proj(monkeypatch, mix,
-                       lambda key, y: y * CFG.value_scale if key == "wo" else y)
-    elif name == "rope_on_all_dims":
-        cfg = dataclasses.replace(cfg, rotary_dim=0)
-    elif name == "bases_swapped":
-        cfg = dataclasses.replace(cfg, rope_theta=cfg.window_rope_theta,
-                                  window_rope_theta=cfg.rope_theta)
-    elif name == "scores_over_sqrt_v":
-        _with_attention(monkeypatch, lambda fn, q, k, v, mask, kw: fn(
-            q * (q.shape[-1] / v.shape[-1]) ** 0.5, k, v, mask, **kw))
-    elif name in ("window_7", "window_9"):
-        cfg = dataclasses.replace(cfg, sliding_window=int(name[-1]))
-    elif name == "no_bias":
-        route = moe.route
-        monkeypatch.setattr(moe, "route", lambda h, router, bias, c: route(
-            h, router, jnp.zeros_like(bias), c))
-    elif name == "top_2":
-        cfg = dataclasses.replace(cfg, experts_per_token=2)
-    else:
-        raise AssertionError(name)
-    return cfg
-
-
-#: each bends what ``full`` mode runs; the last two are the router's
-FORWARD_CONTROLS = ["no_sink", "sink_in_full_layers", "sink_with_a_value",
-                    "window_grouped_as_full", "no_value_scale", "value_scaled_twice",
-                    "rope_on_all_dims", "bases_swapped", "scores_over_sqrt_v",
-                    "window_7", "window_9", "no_bias", "top_2"]
-#: what only the cache path can get wrong: ``change(mixer)`` over what the
-#: prefill hands the fan-out
-ENGINE_CONTROLS = {
-    "ring_not_handed": lambda m: {
-        **m, **{n: tuple(jnp.zeros_like(x) for x in m[n]) for n in ("win_k", "win_v")}},
-    # V's ring read with K's stride: slot w starts w x 32 values in, not w x 16
-    "ring_v_read_at_k_width": lambda m: {**m, "win_v": tuple(
-        jnp.pad(x.reshape(*x.shape[:2], -1), ((0, 0), (0, 0), (0, 8 * 16)))
-        .reshape(*x.shape[:2], 8, KEY_ROW)[..., :16] for x in m["win_v"])},
-}
-
-
-@pytest.mark.parametrize("control", FORWARD_CONTROLS + sorted(ENGINE_CONTROLS) + [
-    "engine:no_sink", "engine:window_9", "engine:scores_over_sqrt_v"])
-def test_this_files_agreement_can_tell_each_mechanism(weights, control, monkeypatch,
-                                                      small_pieces):
-    """Each mechanism dropped or bent IN THE PROGRAM moves the log-probabilities
-    a hundred times further from the reference than the sound program's 2e-5:
-    the sink dropped, added to the full layers, or counted with a value; the
-    window layers grouped as the full layers are; the value's scale dropped or
-    applied twice; RoPE on every dim; the two bases swapped; scores over
-    sqrt(16); a window one token short or long; the router's bias and count.
-    Through the engine (segments, fan-out, decode over rings and pages): a ring
-    not handed at the fan-out, a ring's V read at K's width, and three of the
-    forward's bends again, where the ring's softmax and the segment's run."""
-    params, lora = weights
-    if control in FORWARD_CONTROLS:
-        ids, mask, both = padded_rows()
-        want = reference_logprobs(params, lora, ids, mask)
-        cfg = _control(monkeypatch, control, params)
-        assert np.abs(forward_logprobs(params, lora, ids, mask, cfg) - want)[both].max() > 2e-3
-        return
-    from distrl_llm_tpu.engine import paged_engine
-
-    cfg = CFG
-    if control.startswith("engine:"):
-        cfg = _control(monkeypatch, control.split(":")[1], params)
-    else:
-        prefill, change = paged_engine._paged_prefill_hybrid, ENGINE_CONTROLS[control]
-
-        def patched(*a, **kw):
-            k, v, logits, real_len, mixer = prefill(*a, **kw)
-            return k, v, logits, real_len, change(mixer)
-        monkeypatch.setattr(paged_engine, "_paged_prefill_hybrid", patched)
-    ids, mask, result = generate(make_engine("waves", 0, cfg, bent=True), params, lora)
-    assert worst_difference(params, lora, ids, mask, result) > 2e-3
 
 
 def test_the_sink_is_a_column_the_kernels_refuse_by_name():
@@ -404,63 +332,6 @@ def test_the_sink_is_a_column_the_kernels_refuse_by_name():
                       sink=sink)
 
 
-def test_the_learners_loss_and_adapter_gradient_are_the_references(weights):
-    """No cache, remat, chunked cross-entropy over rows five windows long: the
-    policy-gradient loss over the answers and its gradient in every adapter
-    factor against plain reverse mode through the reference; then a train step
-    moves every factor and nothing else."""
-    import optax
-
-    from distrl_llm_tpu.learner.losses import answer_logprobs, pg_loss
-    from distrl_llm_tpu.learner.train_step import UpdateBatch, make_train_step
-
-    params, lora = weights
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(1, 256, (4, 12)).astype(np.int32)
-    pmask = np.ones((4, 12), np.int32)
-    pmask[0, :5] = 0
-    answer = rng.integers(1, 256, (4, 28)).astype(np.int32)
-    amask = np.ones((4, 28), np.int32)
-    amask[2, 14:] = 0
-    coeffs = jnp.asarray([0.7, -1.1, 0.4, 1.3])
-
-    def loss(lo):
-        logp = answer_logprobs(
-            params, CFG, jnp.asarray(prompt), jnp.asarray(pmask), jnp.asarray(answer),
-            jnp.asarray(amask), lora=lo, lora_scale=LORA_SCALE, remat=True, logit_chunk=8)
-        return pg_loss(logp, jnp.asarray(amask), coeffs)
-
-    got_loss, got = jax.value_and_grad(loss)(lora)
-    ids = np.concatenate([prompt, answer], 1)
-    mask = np.concatenate([pmask, amask], 1)
-    scored = np.concatenate([np.zeros_like(pmask), amask], 1)
-    want_loss, want = jax.jit(ref.pg_loss_and_lora_grad, static_argnums=(1, 3))(
-        params, CFG, lora, LORA_SCALE, jnp.asarray(ids), jnp.asarray(mask),
-        jnp.asarray(scored), coeffs)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-5
-    leaves = jax.tree_util.tree_leaves_with_path(got)
-    # a and b: q, k, v, o in each of three stacks, and layer 0's dense MLP's three
-    assert len(leaves) == 2 * (4 * 3 + 3)
-    for (path, g), w in zip(leaves, jax.tree_util.tree_leaves(want)):
-        assert float(jnp.abs(w).max()) > 0, path
-        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()) + 1e-6,
-                                   err_msg=str(path))
-    batch = UpdateBatch(
-        prompt_ids=jnp.asarray(prompt), prompt_mask=jnp.asarray(pmask),
-        answer_ids=jnp.asarray(answer[:, :12]), answer_mask=jnp.ones((4, 12), jnp.int32),
-        coeffs=coeffs, sample_mask=jnp.ones((4,), jnp.float32))
-    optimizer = optax.adam(1e-3)
-    step = make_train_step(CFG, learner_type="pg", optimizer=optimizer,
-                           lora_scale=LORA_SCALE, micro_size=2, donate=False)
-    new_lora, _, step_loss = step(lora, optimizer.init(lora), params, batch)[:3]
-    assert np.isfinite(float(step_loss))
-    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.abs(a - b).max()), new_lora, lora)
-    assert all(m > 0 for m in jax.tree_util.tree_leaves(moved))
-    assert set(new_lora["layers"]["window"]) == {"wq", "wk", "wv", "wo"}
-    assert set(new_lora["layers"]["softmax_dense"]) == {
-        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
-
-
 # --------------------------------------------------------------- the share
 
 
@@ -469,23 +340,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     expert layer's result (``expert_shard`` 0..3, two of eight experts each) add
     up to what the uncut reference gives for the whole layer, and the program's
     part for a share is the reference's."""
-    uncut = dataclasses.replace(CFG, n_routed_experts=8, router_experts=0)
-    whole, _ = seeded(uncut)
-    layer = jax.tree_util.tree_map(lambda w: w[1], whole["layers"]["window"])
-    h = jax.random.normal(jax.random.PRNGKey(7), (24, 64))
-    want = ref.routed_part(h, layer, uncut)
-    total = jnp.zeros_like(want)
-    for shard in range(4):
-        share = dataclasses.replace(CFG, expert_shard=shard)
-        assert ref.held_ids(share) == [2 * shard, 2 * shard + 1]
-        held = {**layer, **{name: layer[name][2 * shard: 2 * shard + 2]
-                            for name in ("experts_gate", "experts_up", "experts_down")}}
-        part = ref.routed_part(h, held, share)
-        got, _ = moe.moe_half(h, held, share, held=share.held_experts)
-        np.testing.assert_allclose(got, part, atol=2e-5)
-        total = total + part
-    np.testing.assert_allclose(total, want, atol=2e-5)
-    assert float(jnp.abs(want).max()) > 0.1
+    fs.shares_add_up(FAMILY, "window", 4)
 
 
 # -------------------------------------------------------------- the engine
@@ -494,109 +349,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
 _ENGINES: dict = {}
 
 
-def make_engine(scheduler, slots, cfg=CFG, bent=False):
-    """The sound program's engine of a scheduler, built once a module; a
-    ``bent`` program's is traced anew and not kept."""
-    from distrl_llm_tpu.engine.paged_engine import PagedGenerationEngine
-
-    build = lambda: PagedGenerationEngine(
-        cfg, max_prompt_tokens=60, max_new_tokens=20, eos_token_ids=[-1],
-        pad_token_id=0, lora_scale=LORA_SCALE, scheduler=scheduler,
-        max_concurrent_rows=slots, capture_logprobs=True, autotune=False,
-        cache_dtype=jnp.float32, page_size=4)
-    if bent:
-        return build()
-    if (scheduler, slots) not in _ENGINES:
-        _ENGINES[scheduler, slots] = build()
-    return _ENGINES[scheduler, slots]
-
-
-def prompts(lengths, width=60, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = np.zeros((len(lengths), width), np.int32)
-    mask = np.zeros((len(lengths), width), np.int32)
-    for r, n in enumerate(lengths):
-        ids[r, width - n:] = rng.integers(1, 256, n)
-        mask[r, width - n:] = 1
-    return ids, mask
-
-
-@pytest.fixture
-def small_pieces(monkeypatch):
-    """Prefill in segments of 12 tokens (three pages of 4) under a window of 8:
-    a segment does NOT end on the window's edge, so 40- and 57-token prompts
-    cross every boundary the cell's 10k-20k-token prompts cross and one more:
-    the ring carried from segment to segment mid-window, a window that starts
-    in the segment before, the full layers over earlier segments' pages a page
-    of keys at a time, a last segment that is part padding."""
-    from distrl_llm_tpu.engine import paged_engine
-    from distrl_llm_tpu.models import configs
-
-    # a "lane tile" of 16: a key of 24 is kept in 32 lanes, zeros after it, in
-    # the pages and in the rings, as the chip keeps 192 in 256
-    monkeypatch.setattr(configs, "KEY_ROW_LANES", 16)
-    monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", 12)
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # decode rows dense, segments grouped
-
-
-def worst_difference(params, lora, ids, mask, result, cfg=CFG):
-    worst = 0.0
-    for b in range(ids.shape[0]):
-        prompt = ids[b][mask[b] > 0]
-        rows = np.stack([np.concatenate([prompt, result.tokens[b, j]])
-                         for j in range(result.tokens.shape[1])])
-        want = reference_logprobs(params, lora, rows, np.ones_like(rows))
-        worst = max(worst, np.abs(result.logprobs[b] - want[:, len(prompt) - 1:]).max())
-    return worst
-
-
-def generate(engine, params, lora, lengths=(40, 57)):
-    ids, mask = prompts(lengths)
-    result = engine.generate(
-        params, lora, ids, mask,
-        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=20),
-        jax.random.PRNGKey(3))
-    return ids, mask, result
-
-
-@pytest.mark.parametrize("scheduler,slots", [
-    ("refill", 4),  # 8 rows through 4 slots: a freed slot takes another prompt's rings
-    ("waves", 0),   # prefill, fan-out, lockstep
-])
-def test_generate_equals_the_reference_token_by_token(weights, scheduler, slots,
-                                                      small_pieces):
-    """Both schedulers hold a model with 5 window layers of 4 KV heads and 2
-    full layers of 2: prefill in segments that end inside windows, each
-    prompt's rings COPIED (K's and V's at their widths) and its page chain
-    aliased to its 4 candidates, then 20 tokens a row, past the window, over
-    the slots' rings with their sink and the pages of two widths. The engine's
-    own captured log-probability of every token it sampled is the reference's;
-    the two gauges are what the pools' and the rings' shapes say."""
-    from distrl_llm_tpu import telemetry
-
-    params, lora = weights
-    before = dict(telemetry.observe_snapshot()["counters"])
-    engine = make_engine(scheduler, slots)
-    ids, mask, result = generate(engine, params, lora)
-    assert (result.lengths == 20).all() and result.alive_slot_steps == 8 * 20
-    assert worst_difference(params, lora, ids, mask, result) < 2e-5
-    after = telemetry.observe_snapshot()
-    said = tuple(after["counters"][f"engine/window_pages_{k}"]
-                 - before.get(f"engine/window_pages_{k}", 0) for k in ("attended", "visible"))
-    model = dataclasses.asdict(CFG)
-    assert said == counts.window_pages(
-        model, [40] * 4 + [57] * 4, result.lengths.reshape(-1)) == (5 * 8 * 20,) * 2
-    routed = after["counters"]["engine/moe_pairs_routed"] - before.get(
-        "engine/moe_pairs_routed", 0)
-    assert routed == 6 * 3 * 8 * 20  # expert layers x choices x rows x steps: not layer 0
-    held = (slots or 8) * 5 * RING_BYTES
-    assert after["gauges"]["engine/slot_state_bytes"] == held
-    assert engine.last_round_stats["slot_state_bytes"] == held
-    # one more token of context: two full layers' K (its row) and V (its width)
-    assert after["gauges"]["engine/cache_token_bytes"] == 2 * TOKEN_BYTES
-    # the counts module says what the ALGORITHM moves: a key's own 24 values
-    assert counts.slot_state_bytes(model, kv_bytes=4) == 5 * 4 * 8 * (24 + 16) * 4
-    assert counts.cache_token_bytes(model, kv_bytes=4) == 2 * 2 * (24 + 16) * 4
+# -------------------------------------------------------------- the engine
 
 
 @pytest.mark.parametrize("form,folds", [("xla", 0), ("kernel", 2 * 15)])
@@ -621,9 +374,10 @@ def test_a_round_files_the_full_layers_folds_that_ran_as_the_kernel(
         monkeypatch.setattr(la, "dispatch_choices", {})
     name = telemetry.OPS_SOFTMAX_KERNEL_FOLDS
     before = telemetry.observe_snapshot()["counters"].get(name, 0)
-    ids, mask, result = generate(
-        make_engine("waves", 0, bent=form == "kernel"), params, lora)
-    assert worst_difference(params, lora, ids, mask, result) < 2e-5
+    engine = fs.make_engine(FAMILY, "waves", 0) if form == "kernel" else fs.engine(
+        FAMILY, "waves", 0)
+    ids, mask, result = fs.generate(FAMILY, engine)
+    assert fs.worst_difference(FAMILY, params, lora, ids, mask, result) < 2e-5
     assert la.dispatch_choices[la.dispatch_key(8, KEY_ROW, 0, 16, 12, jnp.float32)] == form
     assert telemetry.observe_snapshot()["counters"][name] - before == folds  # filed, even 0
 
@@ -638,7 +392,6 @@ def test_the_counter_is_the_paged_softmax_layers_times_the_folds(
     rope part, the value's width. A dense model files nothing (a latent
     model's counter is its own: tests/test_latent_moe.py)."""
     from distrl_llm_tpu import telemetry
-    from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.ops import latent_attention as la
 
     cfg = PRESETS[preset]

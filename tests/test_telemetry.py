@@ -76,7 +76,7 @@ class TestSpans:
         t = threading.Thread(target=work, name="rollout-0")
         with telemetry.span("main-side"):
             t.start()
-            t.join()
+            t.join(timeout=30)
         tids = {e["name"]: e["tid"] for e in events()}
         assert tids["worker-side"] != tids["main-side"]
         assert telemetry._STATE.thread_names[tids["worker-side"]] == "rollout-0"

@@ -174,6 +174,16 @@ stage "suite_misc" timeout 600 python -m pytest -q \
   tests/test_telemetry.py tests/test_obs.py tests/test_weight_bus.py \
   tests/test_lineage.py tests/test_control.py tests/test_serving_obs.py \
   tests/test_gateway.py
+# the hybrid families (PR 62): the cases every family repeats, once
+# (tests/test_family_conformance.py over tests/family_suite.py's records), then
+# each family's own mechanism
+stage "suite_families_shared" timeout 900 python -m pytest -q \
+  tests/test_family_conformance.py tests/test_hybrid_prefill_stages.py \
+  tests/test_decode_view.py
+stage "suite_families_own" timeout 900 python -m pytest -q \
+  tests/test_hybrid_model.py tests/test_latent_moe.py tests/test_delta_moe.py \
+  tests/test_power_model.py tests/test_jamba_model.py tests/test_window_moe_model.py \
+  tests/test_dsa_moe_model.py tests/test_cca_moe.py tests/test_swa_sink_moe_model.py
 stage "suite_io" timeout 600 python -m pytest -q \
   tests/test_from_pretrained.py tests/test_remote_engine.py \
   tests/test_native_tokenizer.py tests/test_native_spm.py \
