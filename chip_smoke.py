@@ -188,12 +188,15 @@ def _latent_attention_case(key, *, rows, heads, nope, rope, v_dim, rank, context
 
 
 def _shared_prefix_attention_case(key, *, rows, heads, nope, rope, v_dim, rank,
-                                  latent_row, prompt, page, per):
+                                  latent_row, prompt, page, per, choose=None):
     """Absorbed decode attention through the page pool (bf16), ``rows``
     candidates of ONE prompt laid out as the engine's fan-out lays them out
-    (its full pages shared, the partial page and a ragged answer private): the
-    walk that reads the shared blocks once a group, against the expanded form
-    over each row's own gathered context in float32."""
+    (its full pages shared, the partial page and a ragged answer private), as
+    ``absorbed_decode`` dispatches it (the one Mosaic launch over the pool on
+    a TPU, the XLA walk elsewhere; ``impl`` says which ran): the walk that
+    reads the shared columns once a group, against the expanded form over
+    each row's own gathered context in float32. With ``choose`` every row
+    attends a drawn share of what it sees (a learned index's mask)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -216,35 +219,44 @@ def _shared_prefix_attention_case(key, *, rows, heads, nope, rope, v_dim, rank,
     lengths = prompt + jax.random.randint(kn, (rows,), 0, 2 * page)
     w_k, w_v = la.split_kvb(w, heads, nope, v_dim)
     scale = (nope + rope) ** -0.5
-    wide = la.shared_pages_per_block(rows, heads, page, per, table.shape[1])
+    # as ``hybrid._latent_page_walk`` sizes it: the launch shares by the column
+    wide = (1 if la.absorbed_decode_impl(heads, pool, rows) == "kernel"
+            else la.shared_pages_per_block(rows, heads, page, per, table.shape[1]))
     walk = la.shared_page_walk(table, lengths, page_size=page, wide=wide, rows=rows)
+    positions = jnp.arange(walk.cols.shape[1] * page)
+    sees = positions[None, :] <= lengths[:, None]
+    chosen = None
+    if choose is not None:
+        sees &= jax.random.uniform(jax.random.fold_in(key, 1), sees.shape) < choose
+        chosen = sees.astype(la.FOLD_MASK_DTYPE)
 
     @jax.jit
-    def absorbed(q, pool, walk, lengths):
+    def absorbed(q, pool, walk, lengths, chosen):
         q_row = la.absorbed_query(q[..., :nope], q[..., nope:], w_k)
         q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, latent_row - rank - rope)))
-        carry = la.absorbed_paged_attention(
-            q_row, pool, walk, lengths, scale, per=per, wide=wide, rows=rows)
+        carry = la.absorbed_decode(
+            q_row, pool, walk, lengths, scale, chosen, rank=rank, per=per, wide=wide,
+            rows=rows)
         return la.absorbed_output(carry, w_v, jnp.float32)
 
     @jax.jit
-    def expanded(q, pool, table_row, length):  # one row: K and V of its context
+    def expanded(q, pool, table_row, seen):  # one row: K and V of its context
         f32 = pool[table_row].reshape(1, -1, latent_row).astype(jnp.float32)
         kv = (f32[..., :rank] @ w.astype(jnp.float32)).reshape(1, -1, heads, nope + v_dim)
         qf = q.astype(jnp.float32)[None, None]
-        seen = (jnp.arange(f32.shape[1]) <= length)[None, None, :]
         return la.expanded_finish(la.expanded_attention(
-            qf[..., :nope], qf[..., nope:], kv, f32[..., rank: rank + rope], seen),
-            jnp.float32)[0, 0]
+            qf[..., :nope], qf[..., nope:], kv, f32[..., rank: rank + rope],
+            seen[None, None, :f32.shape[1]]), jnp.float32)[0, 0]
 
-    got = absorbed(q, pool, walk, lengths)
+    got = absorbed(q, pool, walk, lengths, chosen)
+    ran = la.dispatch_choices[la.decode_dispatch_key(heads, latent_row, page, pool.dtype)]
     with jax.default_matmul_precision("highest"):
-        want = jnp.stack([expanded(q[r], pool, table[r], lengths[r]) for r in range(rows)])
+        want = jnp.stack([expanded(q[r], pool, table[r], sees[r]) for r in range(rows)])
     err = float(jnp.max(jnp.abs(got - want)))
     attended, read = (int(x) for x in walk.stats)
     assert int(walk.shared[0]) == prompt // page // wide, "the prompt's blocks are not read once"
-    assert np.isfinite(err) and err < 5e-2, f"shared-prefix latent attention max|err| {err}"
-    return {"max_abs_err": round(err, 5), "shared_blocks": int(walk.shared[0]),
+    assert np.isfinite(err) and err < 5e-2, f"shared-prefix latent attention {ran} max|err| {err}"
+    return {"impl": ran, "max_abs_err": round(err, 5), "shared_blocks": int(walk.shared[0]),
             "pages_attended": attended, "pages_read": read}
 
 
@@ -485,9 +497,17 @@ def phase_kernels(seed: int, compiles: CompileLog) -> None:
     out["latent_attention"] = _latent_attention_case(
         key, rows=8, heads=16, nope=128, rope=64, v_dim=128, rank=512, context=2048)
     # 16 candidates over one 10k-token prompt: its pages read once for all
+    # (one Mosaic launch over the pool where it lies: PR 63)
     out["latent_attention_shared_prefix"] = _shared_prefix_attention_case(
         key, rows=16, heads=16, nope=128, rope=64, v_dim=128, rank=512, latent_row=640,
         prompt=10300, page=128, per=8)
+    assert out["latent_attention_shared_prefix"]["impl"] == "kernel", out
+    # the eighth configuration's decode (GLM-5): 64 heads, the same launch with
+    # each row attending a tenth of what it sees (a learned index's mask)
+    out["latent_attention_chosen"] = _shared_prefix_attention_case(
+        key, rows=16, heads=64, nope=192, rope=64, v_dim=256, rank=512, latent_row=640,
+        prompt=10300, page=128, per=8, choose=0.1)
+    assert out["latent_attention_chosen"]["impl"] == "kernel", out
     # a prefill segment of the same configuration: three folds of 1,024 keys
     # into 4 rows' 16 heads of 1,024 queries, the Mosaic kernel against the
     # XLA form

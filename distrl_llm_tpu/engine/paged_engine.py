@@ -357,6 +357,24 @@ def _record_fold_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
     telemetry.counter_add(name, layers * (n_seg * (n_seg + 1) // 2) * (ran == "kernel"))
 
 
+def _record_latent_decode_telemetry(cfg: ModelConfig, steps: int, page_size: int,
+                                    cache_dtype) -> None:
+    """``ops/latent_decode_launches``: the round's latent layer-steps whose
+    decode attention ran as the one Mosaic launch over the pool, read from
+    what ``absorbed_decode`` recorded for this model's heads and pages when
+    the step was traced (0 where it took the XLA walk, and where a model with
+    an index gathered its chosen rows: nothing is recorded there). A model
+    without latent layers files nothing."""
+    if not cfg.latent or not steps:
+        return
+    from distrl_llm_tpu.ops.latent_attention import decode_dispatch_key, dispatch_choices
+
+    ran = dispatch_choices.get(decode_dispatch_key(
+        cfg.num_heads, cfg.latent_row, page_size, cache_dtype))
+    telemetry.counter_add(
+        telemetry.OPS_LATENT_DECODE_LAUNCHES, cfg.num_layers * steps * (ran == "kernel"))
+
+
 def _record_index_telemetry(cfg: ModelConfig, steps: int, prompt_pages: int,
                             private_pages: int, page_size: int,
                             segments: int | None = None) -> None:
@@ -4369,6 +4387,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_fold_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
+        _record_latent_decode_telemetry(
+            self.cfg, dispatched, self.page_size, self.cache_dtype)
         _record_index_telemetry(
             self.cfg, dispatched, self.prompt_pages, self.private_pages,
             self.page_size, prompt_segments)
@@ -4501,6 +4521,8 @@ class PagedGenerationEngine(LoraMailbox):
         _record_fold_telemetry(
             self.cfg, self.prompt_pages, self.page_size, params["embed"].dtype,
             prompt_segments)
+        _record_latent_decode_telemetry(
+            self.cfg, steps_seen[0], self.page_size, self.cache_dtype)
         _record_index_telemetry(
             self.cfg, steps_seen[0], self.prompt_pages, self.private_pages,
             self.page_size, prompt_segments)

@@ -78,10 +78,15 @@ D_I]`` a layer under the latent rows' own page table, so whatever aliases,
 copies or grows a prompt's pages does it to both. No cache: the ``[S, S]``
 scores and the choice as a mask over the expanded form. A prefill segment:
 the segment's scores over the row's cached keys, the choice as a mask over the
-folds (``expanded_attention``, never the kernel: it takes no mask). Decode:
-the scores walk the page table as absorbed attention did (a group's shared
-blocks of keys once), the chosen positions off the same mask, their latent rows
-GATHERED ``[B, index_topk, latent_row]`` and attended in the absorbed form.
+folds (``expanded_segment``: the fold kernel reads a tile of it on a TPU).
+Decode: the scores walk the page table as absorbed attention does (a group's
+shared blocks of keys once), and then either the SAME launch as a model
+without an index runs, ``latent_attention.absorbed_decode`` walking the rows'
+pages whole behind the choice's mask (where the launch runs and a group's
+rows gather at least as many tokens as the table holds:
+``_choice_walks_pages``), or the chosen positions are read off the mask and
+their latent rows GATHERED ``[B, index_topk, latent_row]`` and attended in the
+absorbed form.
 The choice is not differentiated and the index carries no adapter. The round's
 counter ``index_stats`` [2] int32 takes the place of ``latent_stats``: tokens
 attended and tokens visible, a live row, layer and decode step, in units of
@@ -233,7 +238,8 @@ from distrl_llm_tpu.ops.delta_attention import (
     delta_chunked, delta_step, l2norm, short_conv,
 )
 from distrl_llm_tpu.ops.latent_attention import (
-    FOLD_MASK_DTYPE, absorbed_attention, absorbed_output, absorbed_paged_attention,
+    FOLD_MASK_DTYPE, absorbed_attention, absorbed_decode, absorbed_decode_impl,
+    absorbed_output,
     absorbed_query, expanded_attention, expanded_finish, expanded_segment,
     rope_interleaved, shared_page_walk, shared_pages_per_block, split_kvb,
 )
@@ -1092,12 +1098,16 @@ def _mamba_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
     return x, (None if mode == "full" else (state, tail))
 
 
-def _latent_page_walk(env: dict, cfg: ModelConfig):
+def _latent_page_walk(env: dict, cfg: ModelConfig, pages: jax.Array):
     """How absorbed decode attention walks a step's page tables ``[B, W]``:
-    LATENT_DECODE_ROWS rows together, a row's own pages LATENT_DECODE_PAGES
-    columns at a time as far as the longest of the group reaches (a short
-    row's group does not walk a long row's width), the columns every row of
-    the group holds in common in blocks of ``wide``, once for all of them.
+    LATENT_DECODE_ROWS rows together, the columns every row of the group holds
+    in common once for all of them, in blocks of ``wide``, then a row's own
+    as far as the longest of the group reaches (a short row's group does not
+    walk a long row's width). Where the walk is the Mosaic launch
+    (``absorbed_decode_impl`` of a layer's ``pages``: it sizes its own blocks)
+    ``wide`` is ONE column, so that everything the rows share is read once; the
+    XLA form gathers a shared block of as many columns as its scores allow
+    and a row's own LATENT_DECODE_PAGES at a time.
     Returns (the block shapes, what ``shared_page_walk`` read off the tables):
     the same for every layer of the step. A model with an index walks its
     INDEX KEYS so (``index_paged_scores``: the index's heads size a block)."""
@@ -1106,7 +1116,10 @@ def _latent_page_walk(env: dict, cfg: ModelConfig):
     per = min(LATENT_DECODE_PAGES, width)
     rows = LATENT_DECODE_ROWS if b % LATENT_DECODE_ROWS == 0 else b
     heads = cfg.index_heads if cfg.index_topk else cfg.num_heads
-    wide = shared_pages_per_block(rows, heads, ps, per, width)
+    if not cfg.index_topk and absorbed_decode_impl(cfg.num_heads, pages, rows) == "kernel":
+        wide = 1
+    else:
+        wide = shared_pages_per_block(rows, heads, ps, per, width)
     walk = shared_page_walk(
         idx, env["lengths"], env.get("alive"), page_size=ps, wide=wide, rows=rows)
     return {"per": per, "wide": wide, "rows": rows}, walk
@@ -1115,12 +1128,19 @@ def _latent_page_walk(env: dict, cfg: ModelConfig):
 def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale,
                      index=None):
     """One decode token a row: write its latent row, then attend over the
-    row's pages with W_kvb absorbed (its adapter too). ``q_nope [B, H, nope]``,
-    ``q_pe [B, H, rope]``, ``row [B, latent_row]``. With ``index`` (``q_i [B,
-    H_I, D_I]``, ``w [B, H_I]``, ``k_i [B, D_I]``, the layer's index-key pages)
-    the new token's index key is written beside its row, and the row attends
-    the tokens its index chooses, their latent rows gathered, and no other.
-    Returns (o [B, 1, H, v], the page array, the index-key pages or None)."""
+    row's pages with W_kvb absorbed (its adapter too), in the form
+    ``latent_attention.absorbed_decode`` reads off the backend and the pages:
+    one Mosaic launch over the pool where it lies on a TPU, the XLA walk that
+    gathers its blocks elsewhere. ``q_nope [B, H, nope]``, ``q_pe [B, H,
+    rope]``, ``row [B, latent_row]``. With ``index`` (``q_i [B, H_I, D_I]``,
+    ``w [B, H_I]``, ``k_i [B, D_I]``, the layer's index-key pages) the new
+    token's index key is written beside its row, and the row attends the
+    tokens its index chooses and no other: the SAME launch under the choice
+    as a mask over the table's positions where the launch runs and walking a
+    group's pages whole reads no more than gathering each row's choice
+    (``_choice_walks_pages``), their latent rows gathered into one block
+    otherwise. Returns (o [B, 1, H, v], the page array, the index-key pages
+    or None)."""
     b, heads, nope = q_nope.shape
     idx, ps, lengths = env["page_indices"], env["page_size"], env["lengths"]
     shape, walk = env["page_walk"]
@@ -1132,11 +1152,17 @@ def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale,
         if index is not None:
             q_i, w_i, k_i, key_pages = index
             key_pages = key_pages.at[at].set(k_i.astype(key_pages.dtype), mode="drop")
+    walked = index is not None and _choice_walks_pages(cfg, pages, walk, shape["rows"])
+    mask = None
     if index is not None:
         with jax.named_scope(telemetry.MODEL_INDEX_SCORE):
             scores = index_paged_scores(q_i, w_i, key_pages, walk, **shape)
         with jax.named_scope(telemetry.MODEL_INDEX_SELECT):
-            chosen, seen = chosen_tokens(scores, lengths, cfg.index_topk)
+            if walked:  # the choice as it is made: a mask over the table's positions
+                visible = jnp.arange(scores.shape[-1], dtype=jnp.int32) <= lengths[:, None]
+                mask = chosen_mask(scores, visible, cfg.index_topk).astype(FOLD_MASK_DTYPE)
+            else:
+                chosen, seen = chosen_tokens(scores, lengths, cfg.index_topk)
     with jax.named_scope(
             telemetry.MODEL_LATENT_ATTN if index is None else telemetry.MODEL_INDEXED_ATTN):
         w = p["wkv_b"]
@@ -1148,8 +1174,9 @@ def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale,
         q_row = absorbed_query(q_nope, q_pe, w_k)
         q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, cfg.latent_row - cfg.latent_dim)))
         scale = cfg.head_dim ** -0.5
-        if index is None:
-            carry = absorbed_paged_attention(q_row, pages, walk, lengths, scale, **shape)
+        if index is None or walked:
+            carry = absorbed_decode(
+                q_row, pages, walk, lengths, scale, mask, rank=cfg.kv_lora_rank, **shape)
         else:  # the chosen tokens' rows, [B, index_topk, latent_row], in one block
             # a token's page by a compare over the row's few columns: a gather of
             # 131k scalars took 6.7 ms a step on the v5e where this takes none
@@ -1158,6 +1185,19 @@ def _absorbed_decode(q_nope, q_pe, row, pages, p, lora, *, cfg, env, lora_scale,
             held = pages[page, chosen % ps]
             carry = absorbed_attention(q_row, held, seen, scale)
         return absorbed_output(carry, w_v, q_nope.dtype)[:, None], pages, key_pages
+
+
+def _choice_walks_pages(cfg: ModelConfig, pages, walk, rows: int) -> bool:
+    """Whether a decode step under a learned index's choice walks the rows'
+    pages whole behind the choice's mask (``absorbed_decode``'s launch)
+    rather than gathering each row's chosen tokens: where the launch runs, and
+    a group's ``rows`` rows gather at least as many tokens (``index_topk``
+    each) as the table's width holds (a group reads its shared pages once): a
+    group of one row, or a table of 200k tokens, keeps the gather. From
+    shapes alone."""
+    width = walk.cols.shape[1] * pages.shape[1]
+    return (rows * cfg.index_topk >= width
+            and absorbed_decode_impl(cfg.num_heads, pages, rows) == "kernel")
 
 
 def _layer_norm(x, weight, bias, eps: float):
@@ -1392,7 +1432,7 @@ def forward_hybrid(
         if cfg.latent:  # read off the table once a step, for every layer
             with jax.named_scope(telemetry.MODEL_INDEX_SCORE if cfg.index_topk
                                  else telemetry.MODEL_LATENT_ATTN):
-                env["page_walk"] = _latent_page_walk(env, cfg)
+                env["page_walk"] = _latent_page_walk(env, cfg, kv_cache["k"][0])
     else:
         start = kv_cache["segment_start"]
         q_pos = start + jnp.broadcast_to(

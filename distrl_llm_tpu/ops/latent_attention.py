@@ -27,24 +27,40 @@ has two forms, and which is cheaper depends on queries a cached token:
   ``W_v`` into the output, so every head attends over the latent row itself:
   ``scores = (q_nope W_k[h]^T) . c + q_pe . k_pe``, ``o = (sum p c) W_v[h]``.
   The cache is read ``latent_dim`` values a token, whatever the heads. A
-  row's pages are gathered and folded in a block of pages at a time
-  (``absorbed_attention``'s running softmax): the gathered context of 64 rows
-  of 21k tokens, 1.5 GB a layer, never exists whole.
+  row's pages are folded in a block of pages at a time (``absorbed_attention``'s
+  running softmax): the gathered context of 64 rows of 21k tokens, 1.5 GB a
+  layer, never exists whole.
 
-**What is read once** (``absorbed_paged_attention``). Rows that walk their
-page tables together (a GRPO prompt's candidates) mostly name the SAME
+**What is read once, and by what** (``absorbed_decode``). Rows that walk
+their page tables together (a GRPO prompt's candidates) mostly name the SAME
 physical pages: the prompt's. ``shared_page_walk`` reads off the tables how
 many leading blocks of columns every row of a group holds in common, and the
-walk is split there: a shared block is gathered ONCE (row 0's pages) and all
-the group's (row, head) queries meet it in one product, ``[rows * H, row]`` by
-``[row, block]``; the columns after it are gathered a row, as before (in
-narrower blocks: most of a row's private columns are not reached yet),
-carrying the same running softmax on. Equal table entries are equal pages, so
-this is exact whatever made them equal, and a group that shares nothing walks
-as it always did. A column past a row's newest page repeats that page: no page
-that the row does not hold is ever fetched.
+walk is split there: a shared block is read ONCE (row 0's pages) and all the
+group's (row, head) queries meet it in one product, ``[rows * H, row]`` by
+``[row, block]``; the columns after it are read a row, carrying the same
+running softmax on. Equal table entries are equal pages, so this is exact
+whatever made them equal, and a group that shares nothing walks as it always
+did. A column past a row's newest page repeats that page: no page that the row
+does not hold is ever fetched. The walk is ONE algorithm in two forms, chosen
+by what ``absorbed_decode`` can observe (``absorbed_decode_impl``; no
+argument, no environment variable, recorded in ``dispatch_choices``): on a TPU
+backend, for bf16 or float32 pages of whole 128-lane tiles,
+``absorbed_decode_kernel``, ONE Mosaic launch a layer-step over the pool left
+in HBM, which copies a group's shared pages itself into a double buffer in
+VMEM (whole pages of ``page_size x latent_row``, the next block's copies
+behind this block's products), then each row's own pages, multiplies back
+over the rows' first ``kv_lora_rank`` lanes only, and writes nothing but the
+running softmax; anywhere else (the CPU tests' small rows, a CPU run)
+``absorbed_paged_attention``, the same walk in ``jnp``, which gathers each
+block to HBM and reads it back (0.59 ms a layer where the launch takes 0.31:
+PERF.md §6, PR 63) and is the launch's reference. The launch has two callers
+and one body: a model whose rows attend all they see, and a model with a
+learned index, which hands it the index's choice as the mask it is made as
+(``chosen``: a tile of it ANDed into each block's position compare) where
+walking a group's pages whole reads no more than gathering every row's
+chosen tokens (``models/hybrid.py::_choice_walks_pages``).
 
-The absorbed form is plain XLA (no Mosaic kernel yet: ROADMAP). The softmax
+The softmax
 scale is ``(nope + rope)^-0.5`` in both. RoPE pairs are DeepSeek's interleaved ones,
 ``(x[2i], x[2i+1])``; the output keeps the halves apart (evens first), which a
 score cannot tell as long as q and k are rotated alike.
@@ -64,10 +80,11 @@ from distrl_llm_tpu.ops.attention import NEG_INF
 from distrl_llm_tpu.ops.per_device import per_device
 
 _LANES = 128  # a VMEM tile's minor axis: the kernel takes whole tiles
-#: what each geometry's prefill segment resolved to, "kernel" or "xla", under
-#: ``dispatch_key``: the engine's counters ``ops/latent_kernel_folds`` and
-#: ``ops/softmax_kernel_folds`` and chip_smoke.py read it, so a run on the XLA
-#: form cannot pass for the kernel
+#: what each geometry's prefill segment (under ``dispatch_key``) and decode
+#: walk (under ``decode_dispatch_key``) resolved to, "kernel" or "xla": the
+#: engine's counters ``ops/latent_kernel_folds``, ``ops/softmax_kernel_folds``
+#: and ``ops/latent_decode_launches`` and chip_smoke.py read it, so a run on the
+#: XLA form cannot pass for the kernel
 dispatch_choices: dict[tuple, str] = {}
 
 
@@ -635,19 +652,27 @@ def absorbed_paged_attention(
     walk: PageWalk,
     lengths: jax.Array,  # [B]
     scale: float,
+    chosen=None,  # [B, C * page_size]: non-zero where a row attends, of what it sees
     *, per: int, wide: int, rows: int,
 ):
     """A decode step's attention over each row's pages; returns the running
     softmax for ``absorbed_output``. A group walks its shared blocks first
     (``wide`` columns each, gathered once for all its rows), then the rest of
     its tables ``per`` columns a row at a time (module docstring); a group
-    whose rows share nothing runs the second loop alone."""
+    whose rows share nothing runs the second loop alone. ``wide`` is a
+    multiple of ``per``. The plain form, and ``absorbed_decode_kernel``'s
+    reference argument for argument: under a choice too (a learned index's,
+    over the positions of ``walk.cols``' columns), though no model runs this
+    form under one."""
     page_size = pages.shape[1]
 
-    def group(q_g, cols_g, len_g, shared, newest):
+    def group(q_g, cols_g, len_g, chosen_g, shared, newest):
         def block(j, n, of):
             at = jax.lax.dynamic_slice_in_dim(of, j * n, n, axis=of.ndim - 1)
             seen = (j * n * page_size + jnp.arange(n * page_size))[None, :] <= len_g[:, None]
+            if chosen_g is not None:
+                seen &= jax.lax.dynamic_slice_in_dim(
+                    chosen_g, j * n * page_size, n * page_size, axis=1) != 0
             return pages[at].reshape(*of.shape[:-1], n * page_size, -1), seen
 
         def fold_shared(j, carry):
@@ -663,8 +688,265 @@ def absorbed_paged_attention(
     return jax.tree_util.tree_map(
         lambda *parts: jnp.concatenate(parts, axis=0),
         *(group(q_row[r: r + rows], walk.cols[r: r + rows], lengths[r: r + rows],
+                None if chosen is None else chosen[r: r + rows],
                 walk.shared[r // rows], walk.newest[r // rows])
           for r in range(0, q_row.shape[0], rows)))
+
+
+#: pages of one shared block of ``absorbed_decode_kernel`` (the copies started
+#: together and multiplied together; the walk's ``wide`` is the XLA form's block
+#: and sizes nothing here): no more than DECODE_BLOCK_PAGES, and no more than
+#: DECODE_SCORE_BYTES of float32 scores for the group's ``rows * H`` queries.
+#: Timed on a v5e at the two latent cells' shapes, 64 rows in groups of 16 over
+#: prompts of 10,240-20,480 tokens and 256 decoded, us a layer (PERF.md §6, PR
+#: 63): 16 heads, 388 / 323 / 305 / 313 at 2 / 4 / 8 / 16 pages where the XLA
+#: walk takes 591; 64 heads under a choice, 1,007 / 991 / 1,003 at 2 / 4 / 8 where
+#: the gather of the chosen rows and ``absorbed_attention`` take 2,663
+DECODE_BLOCK_PAGES = 8
+DECODE_SCORE_BYTES = 2 << 20
+#: VMEM a launch may ask for of a v5e's 128 MiB; a group whose buffers need more
+#: takes the XLA form (``_decode_vmem``)
+_DECODE_VMEM_MOST = 100 << 20
+
+
+def decode_block_pages(rows: int, heads: int, page_size: int) -> int:
+    """Pages of a shared block of the launch, from shapes alone (above): 8 for
+    16 rows' 16 heads, 4 for their 64."""
+    fit = DECODE_SCORE_BYTES // (rows * heads * page_size * 4)
+    return max(1, min(DECODE_BLOCK_PAGES, fit))
+
+
+def _decode_vmem(rows: int, heads: int, page_size: int, row: int, itemsize: int) -> int:
+    """``vmem_limit_bytes`` of a decode launch, twice what its buffers count
+    to: the two page buffers (a block of shared pages and one page a row of
+    the group) and the group's queries and running softmax, each twice, a
+    block's float32 scores with their weights, the weights' cast and the mask
+    beside them, and 4 MiB for a choice's rows (int8 in tiles of 32 sublanes,
+    twice: 64k positions). 31 MiB for 16 rows' 16 heads over pages of 128 x 640
+    bf16, 50 MiB for their 64 heads, over ``_DECODE_VMEM_MOST`` from about 48 rows
+    of 64 heads a group."""
+    queries = rows * heads
+    block = decode_block_pages(rows, heads, page_size) * page_size
+    pages = 2 * (block + rows * page_size) * row * itemsize
+    group = 2 * queries * (row * itemsize + (row + 2 * _LANES) * 4)
+    return 2 * (pages + group + queries * block * 14) + (4 << 20)
+
+
+def decode_dispatch_key(heads: int, row: int, page_size: int, dtype) -> tuple:
+    """The key ``absorbed_decode`` records its choice under in
+    ``dispatch_choices``: the queries' heads and the pool's pages, and not the
+    rows, the table's width or whether a choice arrived."""
+    return ("decode", heads, row, page_size, jnp.dtype(dtype).name)
+
+
+def absorbed_decode_impl(heads: int, pages: jax.Array, rows: int) -> str:
+    """The form a decode step's attention of ``heads`` heads over a layer's
+    latent ``pages [P, page_size, latent_row]`` takes, ``rows`` rows a group:
+    "kernel" on a TPU backend for bf16 or float32 pages of whole 128-lane
+    tiles (a row's lanes, a page's tokens), heads that fill the sublanes of a
+    tile of that type (16 / 8: a row's heads are then whole tiles of the
+    group's ``[rows * H, .]`` operand) and a group whose buffers VMEM holds
+    (``_decode_vmem``: the engine's groups of 16 rows always), "xla" otherwise
+    (the CPU, the tests' small rows). On the TPU nothing falls back: a launch
+    that fails to lower fails the step that called it."""
+    sublanes = {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.float32): 8}.get(pages.dtype)
+    page_size, row = pages.shape[1:]
+    whole = row % _LANES == 0 and page_size % _LANES == 0
+    if (jax.default_backend() == "tpu" and sublanes and whole and heads % sublanes == 0
+            and _decode_vmem(rows, heads, page_size, row, pages.dtype.itemsize)
+            <= _DECODE_VMEM_MOST):
+        return "kernel"
+    return "xla"
+
+
+def absorbed_decode(q_row, pages, walk: PageWalk, lengths, scale: float, chosen=None,
+                    *, rank: int, per: int, wide: int, rows: int):
+    """A decode step's attention over each row's latent pages in the form
+    ``absorbed_decode_impl`` names (recorded in ``dispatch_choices`` under
+    ``decode_dispatch_key``): ONE ``absorbed_decode_kernel`` launch over the
+    pool where it lies, or ``absorbed_paged_attention``. Returns the running
+    softmax for ``absorbed_output``, whose values are the row's first ``rank``.
+    ``chosen [B, C * page_size]`` of ``FOLD_MASK_DTYPE`` over the positions of
+    ``walk.cols``' columns, if given, is what each row attends (non-zero) of
+    what it sees: a learned index's choice."""
+    impl = absorbed_decode_impl(q_row.shape[1], pages, rows)
+    dispatch_choices[decode_dispatch_key(
+        q_row.shape[1], pages.shape[-1], pages.shape[1], pages.dtype)] = impl
+    if impl == "kernel":
+        return per_device(absorbed_decode_kernel)(
+            q_row, pages, walk, lengths, chosen, scale=scale, rank=rank, wide=wide,
+            rows=rows)
+    return absorbed_paged_attention(
+        q_row, pages, walk, lengths, scale, chosen, per=per, wide=wide, rows=rows)
+
+
+def _decode_body(cols_ref, ends_ref, *refs, names: tuple, scale: float, rows: int,
+                 block: int, values: int):
+    """One group of ``rows`` rows: its shared columns a block of ``block``
+    pages at a time against all its (row, head) queries, then every column
+    after them a page a row against that row's heads. ``refs`` are the
+    launch's operands, results and scratch under ``names``; ``ends_ref [G, 2]``
+    the group's shared columns and its longest row's newest column."""
+    ref = dict(zip(names, refs))
+    q_ref, pool, shared_buf, own_buf, sem = (
+        ref[n] for n in ("q", "pages", "shared_buf", "own_buf", "sem"))
+    m_ref, l_ref, acc_ref = ref["m"], ref["l"], ref["acc"]
+    g = pl.program_id(0)
+    ps = pool.shape[1]
+    heads = q_ref.shape[0] // rows
+    shared, newest = ends_ref[g, 0], ends_ref[g, 1]
+    q = q_ref[...]
+    length = ref["length"][...]  # [rows * H, 1]: a query's row's newest position
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fold(scores, first, weigh, end=None):
+        """A block's scores ``[rows * H, tokens]`` (positions ``first ..``,
+        those from ``end`` on repeats of a page) into the running softmax;
+        ``weigh(p)`` the weights' product with the block's values."""
+        tokens = scores.shape[1]
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+        seen = pos <= length
+        if end is not None:
+            seen &= pos < end
+        if "chosen" in ref:  # a row's choice, every head of the row alike
+            mine = ref["chosen"][:, pl.ds(pl.multiple_of(first, ps), tokens)]
+            mine = jnp.broadcast_to(
+                mine.astype(jnp.float32)[:, None, :], (rows, heads, tokens))
+            seen &= mine.reshape(scores.shape) != 0
+        scores = jnp.where(seen, scores * scale, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, scores.max(axis=1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(scores - m_new), 0.0)
+        fix = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * fix + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * fix + weigh(p.astype(pool.dtype))
+
+    def landed(buf, slot):
+        # a wait is for a size: one descriptor answers for a buffer's copies
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+    # ---- the columns every row of the group holds: row 0's pages, once
+    def fetch_shared(j, slot):
+        for i in range(block):  # past the last shared column, that one again (masked)
+            page = cols_ref[g * rows, jnp.minimum(j * block + i, shared - 1)]
+            pltpu.make_async_copy(
+                pool.at[page], shared_buf.at[slot, pl.ds(i * ps, ps)], sem.at[slot]).start()
+
+    def fold_shared(j, _):
+        slot = j % 2
+        pl.when((j + 1) * block < shared)(lambda: fetch_shared(j + 1, 1 - slot))
+        landed(shared_buf, slot)
+        held = shared_buf[slot]  # [block * ps, row]
+        scores = jax.lax.dot_general(
+            q, held, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        fold(scores, j * block * ps,
+             lambda p: jnp.dot(p, held[:, :values], preferred_element_type=jnp.float32),
+             end=shared * ps)
+
+    pl.when(shared > 0)(lambda: fetch_shared(0, 0))
+    jax.lax.fori_loop(0, (shared + block - 1) // block, fold_shared, None)
+
+    # ---- then each row's own: column ``c`` of every row of the group together
+    def fetch_own(c, slot):
+        for r in range(rows):  # past a row's newest page the walk repeats it (masked)
+            pltpu.make_async_copy(
+                pool.at[cols_ref[g * rows + r, c]], own_buf.at[slot, pl.ds(r * ps, ps)],
+                sem.at[slot]).start()
+
+    def fold_own(c, _):
+        slot = (c - shared) % 2
+        pl.when(c < newest)(lambda: fetch_own(c + 1, 1 - slot))
+        landed(own_buf, slot)
+        held = own_buf[slot].reshape(rows, ps, -1)
+        scores = jax.lax.dot_general(
+            q.reshape(rows, heads, -1), held, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32).reshape(rows * heads, ps)
+        fold(scores, c * ps,
+             lambda p: jax.lax.dot_general(
+                 p.reshape(rows, heads, ps), held[:, :, :values],
+                 (((2,), (1,)), ((0,), (0,))),
+                 preferred_element_type=jnp.float32).reshape(rows * heads, values))
+
+    pl.when(shared <= newest)(lambda: fetch_own(shared, 0))
+    jax.lax.fori_loop(shared, newest + 1, fold_own, None)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "rank", "wide", "rows", "block_pages", "interpret"))
+def absorbed_decode_kernel(q_row, pages, walk: PageWalk, lengths, chosen=None, *,
+                           scale: float, rank: int, wide: int, rows: int,
+                           block_pages: int = 0, interpret: bool = False):
+    """``absorbed_paged_attention``'s walk as ONE Mosaic launch over a pool
+    left in HBM (a TPU; ``interpret`` for the CPU's tests): argument for
+    argument the XLA form's, which is its reference, and the same running
+    softmax out, ``(m [B, H], l [B, H], acc [B, H, .])`` in float32, ``acc``
+    over the row's values alone (``rank`` rounded up to whole lanes).
+
+    Grid (groups,), a step a group of ``rows`` rows whose ``rows * H`` queries
+    sit in VMEM as one ``[rows * H, latent_row]`` operand. **The columns the
+    group's rows all hold** (``walk.shared * wide`` of them) are copied by the
+    kernel itself from row 0's pages, ``block_pages`` whole pages a block (a
+    page is ``page_size x latent_row`` contiguous) into a double buffer, the
+    next block's copies behind this block's arithmetic: one product ``[rows *
+    H, row] x [row, block]``, one running-softmax update, one product back
+    over the block's first ``rank`` lanes (a slice of a VMEM tile is free).
+    **Then each row's own columns**, to the group's ``walk.newest``: column
+    ``c`` of every row copied together, a row's ``H`` queries against its own
+    page, the same ``m / l / acc`` carried on. A position past a row's
+    ``lengths`` is masked by one compare; a column past a row's newest page
+    repeats that page (``shared_page_walk``), so no page that a row does not
+    hold is fetched. Nothing is written but the running softmax: no gathered
+    block, no float32 scores in HBM.
+
+    ``chosen [B, C * page_size]`` (``FOLD_MASK_DTYPE``; non-zero = attend), if
+    given, is a learned index's choice over the positions of ``walk.cols``'
+    columns: a group's rows of it sit in VMEM and a tile is ANDed into each
+    block's position compare, every head of a row alike. The pages' type for
+    the products' operands, float32 for everything else, as the XLA form.
+    ``block_pages`` 0 is the launch's own choice (``decode_block_pages``); the
+    tests name one to reach ragged last blocks at small sizes."""
+    b, heads, row = q_row.shape
+    ps, groups = pages.shape[1], b // rows
+    block_pages = block_pages or decode_block_pages(rows, heads, ps)
+    values = min(_whole_lanes(rank), row)
+    ends = jnp.stack([walk.shared * wide, walk.newest], axis=1).astype(jnp.int32)
+    of_group = lambda w: pl.BlockSpec((rows * heads, w), lambda g, *_: (g, 0))
+    operands = {
+        "q": (q_row.astype(pages.dtype).reshape(b * heads, row), of_group(row)),
+        "length": (jnp.repeat(lengths.astype(jnp.int32), heads)[:, None], of_group(1)),
+        "pages": (pages, pl.BlockSpec(memory_space=pl.ANY)),
+    }
+    if chosen is not None:
+        operands["chosen"] = (
+            chosen.reshape(groups, rows, -1),
+            pl.BlockSpec((None, rows, chosen.shape[-1]), lambda g, *_: (g, 0, 0)))
+    names = (*operands, "m", "l", "acc", "shared_buf", "own_buf", "sem")
+    m, l, acc = pl.pallas_call(
+        functools.partial(_decode_body, names=names, scale=scale, rows=rows,
+                          block=block_pages, values=values),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # the walk's columns and ends ride SMEM
+            grid=(groups,),
+            in_specs=[spec for _, spec in operands.values()],
+            out_specs=[of_group(1), of_group(1), of_group(values)],
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * ps, row), pages.dtype),
+                pltpu.VMEM((2, rows * ps, row), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b * heads, w), jnp.float32)
+                   for w in (1, 1, values)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_decode_vmem(rows, heads, ps, row, pages.dtype.itemsize)),
+        interpret=interpret,
+    )(walk.cols.astype(jnp.int32), ends, *(x for x, _ in operands.values()))
+    return m.reshape(b, heads), l.reshape(b, heads), acc.reshape(b, heads, values)
 
 
 def absorbed_output(carry, w_v: jax.Array, dtype) -> jax.Array:

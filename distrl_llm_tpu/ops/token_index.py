@@ -25,8 +25,11 @@ The scores are float32 products of the operands as they are cached (bf16 on
 the chip). Plain XLA throughout (on a v5e a segment's ``[4, 1024, 20480]``
 k-th score takes 8 ms where the sort took 58, a decode step's positions for
 64 rows 0.58 ms where ``top_k`` took 1.24: PERF.md section 6, PR 55); the
-engine counts the choices made so in ``ops/index_counted_choices``. A kernel
-for the gather of the chosen rows is ROADMAP's.
+engine counts the choices made so in ``ops/index_counted_choices``. A decode
+step whose group gathers at least as many tokens as its table holds does not
+gather at all: the mask goes to ``latent_attention.absorbed_decode``'s launch
+as it is made (``models/hybrid.py::_choice_walks_pages``; PR 63), and
+``mask_positions`` is left to the tables too wide for that.
 """
 
 from __future__ import annotations

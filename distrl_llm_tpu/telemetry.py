@@ -271,6 +271,14 @@ OPS_LATENT_KERNEL_FOLDS = "ops/latent_kernel_folds"
 # where ``expanded_segment`` chose the kernel, 0 where it took the XLA form.
 # Filed beside the counter above; no metric reads it
 OPS_SOFTMAX_KERNEL_FOLDS = "ops/softmax_kernel_folds"
+# counter: a round's decode layer-steps whose attention over latent pages ran
+# as the one Mosaic launch over the pool where it lies
+# (ops/latent_attention.py::absorbed_decode_kernel): latent layers x decode
+# steps where ``absorbed_decode`` chose it (under a learned index's choice too,
+# where the choice walks the pages: models/hybrid.py::_choice_walks_pages), 0
+# where the XLA walk or the gather of the chosen rows ran (a CPU, small rows).
+# Filed beside the two counters above; no metric reads it
+OPS_LATENT_DECODE_LAUNCHES = "ops/latent_decode_launches"
 # counter: a round's choices of a learned index that ran by counting
 # (ops/token_index.py::kth_largest, no sort): layers x the decode steps whose
 # row sees more than ``index_topk`` columns, plus layers x the prefill's

@@ -56,6 +56,11 @@ def test_the_latent_and_expert_checks_hold_at_tiny_size(monkeypatch):
     # 53 tokens are 6 full pages, two blocks read once; what is left a row
     assert shared["max_abs_err"] < 5e-2 and shared["shared_blocks"] == 2
     assert shared["pages_read"] == 6 + shared["pages_attended"] - 4 * 6
+    assert shared["impl"] == "xla"  # a CPU: the chip's run asserts the launch
+    chosen = chip_smoke._shared_prefix_attention_case(
+        key, rows=4, heads=4, nope=16, rope=8, v_dim=16, rank=32, latent_row=48,
+        prompt=53, page=8, per=3, choose=0.4)  # under a drawn choice
+    assert chosen["max_abs_err"] < 5e-2 and chosen["impl"] == "xla"
     for tokens, form in ((24, "dense"), (160, "grouped")):
         layer = chip_smoke._expert_layer_case(
             key, tokens=tokens, hidden=64, width=32, experts=8, per_token=2)

@@ -380,6 +380,44 @@ def test_eight_shares_sum_to_the_whole_layer_and_the_program_holds_its_own():
 
 # -------------------------------------------------------------- the engine
 
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_round_under_the_choice_through_the_launch_is_the_gathers(
+        weights, small_pieces, monkeypatch, scheduler, slots):
+    """The engine's decode with every layer-step's attention ONE
+    ``absorbed_decode_kernel`` launch that walks the rows' pages whole behind
+    the index's choice as a mask (interpreted; the dispatch and the shapes'
+    inequality answered for it: 4 rows x 8 chosen tokens are fewer than the
+    tiny table's positions). The same tokens are attended as the gather of
+    the chosen rows attends: the sampled tokens are the gather's, the
+    log-probabilities its and the reference's, the counter of attended tokens
+    its, and ``ops/latent_decode_launches`` reads 3 layers x the steps where
+    the gather's round reads 0."""
+    import functools
+
+    from distrl_llm_tpu import telemetry
+
+    params, lora = weights
+    counters = lambda: dict(telemetry.observe_snapshot()["counters"])
+    moved = lambda before, name: counters().get(name, 0) - before.get(name, 0)
+    # what an earlier case's launch recorded is not this case's plain engine's
+    monkeypatch.setattr(latent_attention, "dispatch_choices", {})
+    before = counters()
+    _, _, plain = fs.generate(FAMILY, fs.engine(FAMILY, scheduler, slots))
+    assert moved(before, telemetry.OPS_LATENT_DECODE_LAUNCHES) == 0  # the gather ran
+    attended = moved(before, "engine/index_tokens_attended")
+    monkeypatch.setattr(latent_attention, "absorbed_decode_impl", lambda heads, pages, rows: "kernel")
+    monkeypatch.setattr(hybrid, "_choice_walks_pages", lambda cfg, pages, walk, rows: True)
+    monkeypatch.setattr(latent_attention, "absorbed_decode_kernel", functools.partial(
+        latent_attention.absorbed_decode_kernel, interpret=True))
+    before = counters()
+    ids, mask, result = fs.generate(FAMILY, fs.make_engine(FAMILY, scheduler, slots))
+    assert moved(before, telemetry.OPS_LATENT_DECODE_LAUNCHES) == 3 * result.steps_dispatched
+    assert moved(before, "engine/index_tokens_attended") == attended
+    np.testing.assert_array_equal(result.tokens, plain.tokens)
+    np.testing.assert_allclose(result.logprobs, plain.logprobs, atol=2e-5)
+    assert fs.worst_difference(FAMILY, params, lora, ids, mask, result) < 2e-5
+
+
 
 def test_a_prefill_through_the_fold_kernel_is_the_xla_forms(weights, small_pieces, monkeypatch):
     """The engine's prefill with every fold run by ``expanded_fold_kernel``

@@ -581,6 +581,167 @@ def test_a_column_past_a_rows_newest_page_repeats_that_page():
     assert la.shared_pages_per_block(64, 128, 128, 8, 165) == 8  # nor narrower than a row's
 
 
+def _walk_inputs(groups, dtype, heads, cols=None):
+    """``paged_walk_case`` with ``heads`` queries a row: (pool, tables, lengths,
+    context, q, q_row, w_kvb, w_v, scale)."""
+    w = SimpleNamespace(**WALK)
+    pool, tables, lengths, context = paged_walk_case(groups, dtype)
+    tables = tables if cols is None else tables[:, :cols]
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    w_kvb = 0.3 * jax.random.normal(keys[0], (w.rank, heads * (w.nope + w.v_dim)))
+    q = jax.random.normal(keys[1], (len(context), heads, w.nope + w.rope))
+    w_k, w_v = latent_attention.split_kvb(w_kvb, heads, w.nope, w.v_dim)
+    q_row = jnp.pad(latent_attention.absorbed_query(q[..., :w.nope], q[..., w.nope:], w_k),
+                    ((0, 0), (0, 0), (0, w.row - w.rank - w.rope)))
+    return pool, tables, lengths, context, q, q_row, w_kvb, w_v, (w.nope + w.rope) ** -0.5
+
+
+LAUNCH_CASES = [  # case, groups, rows, wide, block, heads, k
+    # every column of the prompt shared: 12 columns in blocks of 4, and of 5 (a
+    # ragged last block: its columns past the twelfth repeat it, masked)
+    ("all_shared", [(96, [0, 1, 2, 3])], 4, 1, 4, 4, None),
+    ("all_shared_ragged_last_block", [(96, [0, 1, 2, 3])], 4, 1, 5, 4, None),
+    # the XLA walk's blocks of 3 columns: 9 shared, walked 2 pages a block
+    ("some_shared_in_the_walks_blocks", [(76, [0, 3, 9, 20])], 4, 3, 2, 4, None),
+    ("nothing_shared", [(40, [5]), (41, [5]), (57, [0]), (30, [9])], 4, 1, 4, 4, None),
+    ("rows_end_on_different_pages", [(105, [0, 1, 13, 27])], 4, 1, 4, 4, None),
+    ("a_row_not_alive", [(57, [4, -1, 4, 6])], 4, 1, 2, 4, None),
+    ("two_groups", [(50, [1, 2, 3, 4]), (110, [7, 7, 0, 1])], 4, 1, 4, 4, None),
+    ("one_group_of_every_row", [(50, [0, 1, 2]), (50, [3, 4, 5])], 6, 1, 4, 4, None),
+    ("a_table_narrower_than_per", [(5, [0, 3, 6, 9])], 4, 2, 4, 4, None),
+    ("kimis_heads", [(76, [0, 3, 9, 20])], 4, 1, 4, 16, None),
+    ("glm5s_heads", [(76, [0, 3, 9, 20])], 4, 1, 4, 64, None),
+    # under a choice: scores in halves tie at the k-th, a row of one token has
+    # fewer than k, and a k of the table's width or more chooses all it sees
+    ("choice_ties_at_the_kth", [(76, [0, 3, 9, 20])], 4, 3, 2, 4, 8),
+    ("choice_a_row_of_fewer_than_k", [(57, [4, -1, 4, 6])], 4, 1, 4, 4, 16),
+    ("choice_k_of_the_width_or_more", [(50, [1, 2, 3, 4])], 4, 1, 4, 4, 1000),
+    ("choice_glm5s_heads", [(76, [0, 3, 9, 20])], 4, 1, 3, 64, 8),
+]
+# every case in float32, to 2e-5; the pages' own type where a block's weights
+# are cast to it before their product: the chip's bf16
+BF16_CASES = ("kimis_heads", "choice_glm5s_heads")
+
+
+@pytest.mark.parametrize("case,groups,rows,wide,block,heads,k,dtype", [
+    pytest.param(*c, dtype, id=f"{c[0]}-{name}")
+    for name, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16))
+    for c in LAUNCH_CASES if name == "f32" or c[0] in BF16_CASES])
+def test_the_launch_equals_the_plain_walk_and_expanded_attention(
+        case, groups, rows, wide, block, heads, k, dtype):
+    """``absorbed_decode_kernel`` (interpreted) against the plain form it
+    stands for (``absorbed_paged_attention``'s walk; under a choice, that
+    and the gather of the chosen rows with ``absorbed_attention``) and against K and V
+    rebuilt per head over each row's own context (under a choice, its chosen
+    tokens); whole pages past a row's length hold NaN. The launch takes any
+    ``wide`` (the XLA walk a multiple of its ``per``) and sizes its own blocks."""
+    from distrl_llm_tpu.ops import latent_attention as la
+    from distrl_llm_tpu.ops import token_index as ti
+
+    w = SimpleNamespace(**WALK)
+    narrow = 2 if case == "a_table_narrower_than_per" else None
+    pool, tables, lengths, context, q, q_row, w_kvb, w_v, scale = _walk_inputs(
+        groups, dtype, heads, narrow)
+    b, per = len(context), min(w.per, tables.shape[1])
+    alive = jnp.asarray(np.asarray([g for _, gen in groups for g in gen]) >= 0)
+    walk = la.shared_page_walk(tables, lengths, alive, page_size=w.ps, wide=wide, rows=rows)
+    plain_walk = la.shared_page_walk(tables, lengths, alive, page_size=w.ps, wide=per, rows=rows)
+    chosen = mask = None
+    if k is not None:  # the choice over the positions of the walk's columns
+        width = walk.cols.shape[1] * w.ps
+        scores = jnp.round(2 * jax.random.normal(jax.random.PRNGKey(11), (b, width))) / 2
+        visible = jnp.arange(width, dtype=jnp.int32) <= lengths[:, None]
+        mask = ti.chosen_mask(scores, visible, k)
+        chosen = mask.astype(la.FOLD_MASK_DTYPE)
+    out = lambda carry: np.asarray(la.absorbed_output(carry, w_v, jnp.float32))
+    got = out(la.absorbed_decode_kernel(
+        q_row, pool, walk, lengths, chosen, scale=scale, rank=w.rank, wide=wide, rows=rows,
+        block_pages=block, interpret=True))
+    plain = out(la.absorbed_paged_attention(
+        q_row, pool, plain_walk, lengths, scale, chosen, per=per, wide=per, rows=rows))
+    if k is not None:  # and as ``hybrid._absorbed_decode`` gathers the chosen rows
+        at, seen = ti.chosen_tokens(scores, lengths, k)
+        column = jnp.arange(walk.cols.shape[1], dtype=jnp.int32)
+        page = jnp.where((at // w.ps)[..., None] == column, walk.cols[:, None], 0).sum(-1)
+        gathered = out(la.absorbed_attention(q_row, pool[page, at % w.ps], seen, scale))
+        np.testing.assert_allclose(got, gathered, atol=2e-5 if dtype == jnp.float32 else 2e-2)
+    exact = dtype == jnp.float32
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, plain, atol=2e-5 if exact else 2e-2)
+    # every row's own context, K and V rebuilt a head: one padded batch
+    longest = max(len(rows_r) for rows_r in context)
+    latent = jnp.stack([jnp.pad(jnp.asarray(rows_r), ((0, longest - len(rows_r)), (0, 0)))
+                        for rows_r in context])
+    sees = jnp.arange(longest) <= lengths[:, None]
+    if mask is not None:
+        sees &= mask[:, :longest]
+    kv = (latent[..., :w.rank] @ w_kvb).reshape(b, longest, heads, w.nope + w.v_dim)
+    want = la.expanded_finish(la.expanded_attention(
+        q[:, None, :, :w.nope], q[:, None, :, w.nope:], kv,
+        latent[..., w.rank: w.rank + w.rope], sees[:, None]), jnp.float32)[:, 0]
+    np.testing.assert_allclose(got, want, atol=2e-5 if exact else 3e-2)
+    # what the walk says it fetched is the walk's, whichever form follows it
+    live = np.where(alive, np.asarray(lengths) // w.ps + 1, 0).reshape(-1, rows)
+    once = np.asarray(walk.shared)[:, None] * wide
+    read = np.minimum(once[:, 0], live.max(axis=1)) + np.maximum(live - once, 0).sum(axis=1)
+    np.testing.assert_array_equal(walk.stats, [live.sum(), read.sum()])
+    assert walk.stats[0] == plain_walk.stats[0] and walk.stats[1] <= plain_walk.stats[0]
+
+
+@pytest.mark.parametrize("backend,dtype,heads,row,page,b,want", [
+    ("tpu", jnp.bfloat16, 16, 640, 128, 32, "kernel"),  # the Kimi cell's
+    ("tpu", jnp.bfloat16, 64, 640, 128, 32, "kernel"),  # the GLM-5 cell's
+    ("tpu", jnp.float32, 8, 640, 128, 32, "kernel"),
+    ("cpu", jnp.bfloat16, 16, 640, 128, 32, "xla"),
+    ("tpu", jnp.bfloat16, 16, 576, 128, 32, "xla"),  # a row of no whole tiles
+    ("tpu", jnp.bfloat16, 16, 640, 64, 32, "xla"),  # nor a page's tokens
+    ("tpu", jnp.bfloat16, 8, 640, 128, 32, "xla"),  # heads that fill no bf16 tile's sublanes
+    ("tpu", jnp.float16, 16, 640, 128, 32, "xla"),
+    ("tpu", jnp.bfloat16, 64, 640, 128, 24, "kernel"),  # no groups of 16: ONE of 24 rows
+    ("tpu", jnp.bfloat16, 64, 640, 128, 72, "xla"),  # ONE of 72: more than VMEM holds
+])
+def test_the_decode_walks_form_is_read_off_the_backend_and_the_pages(
+        monkeypatch, backend, dtype, heads, row, page, b, want):
+    """The rule, what a step traced under it records, and the walk's ``wide``
+    that follows from it (ONE column for the launch, the XLA form's block of
+    gathered pages otherwise)."""
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(la, "dispatch_choices", {})
+    shape = lambda *s, t=dtype: jax.ShapeDtypeStruct(s, t)
+    pages = shape(40, page, row)
+    rows = 16 if b % 16 == 0 else b
+    assert la.absorbed_decode_impl(heads, pages, rows) == want
+    cfg = SimpleNamespace(index_topk=0, index_heads=0, num_heads=heads)
+    tables = jnp.zeros((b, 20), jnp.int32)
+    env = {"page_indices": tables, "page_size": page, "lengths": jnp.zeros((b,), jnp.int32)}
+    sizes, walk = hybrid._latent_page_walk(env, cfg, pages)
+    assert sizes["rows"] == rows and (sizes["wide"] == 1) == (want == "kernel")
+    m, l, acc = jax.eval_shape(  # traced, never lowered: the launch is an equation
+        lambda q, pages: la.absorbed_decode(
+            q, pages, walk, env["lengths"], 0.1, rank=512, **sizes),
+        shape(b, heads, row), pages)
+    assert m.shape == l.shape == (b, heads) and acc.shape[:2] == (b, heads)
+    assert la.dispatch_choices == {la.decode_dispatch_key(heads, row, page, dtype): want}
+
+
+@pytest.mark.parametrize("backend,rows,topk,width,want", [
+    ("tpu", 16, 2048, 164, True),  # the cell's: 32,768 gathered rows >= 20,992 positions
+    ("tpu", 1, 2048, 164, False),  # a group of one row reads less by gathering
+    ("tpu", 16, 2048, 1640, False),  # and so does a table of 200k tokens
+    ("tpu", 16, 2048, 256, True),  # equal: the walk
+    ("cpu", 16, 2048, 164, False),  # no launch, no walk
+])
+def test_a_choice_walks_the_pages_exactly_where_that_reads_no_more(
+        monkeypatch, backend, rows, topk, width, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = SimpleNamespace(index_topk=topk, num_heads=64)
+    walk = SimpleNamespace(cols=jnp.zeros((rows, width), jnp.int32))
+    pages = jax.ShapeDtypeStruct((40, 128, 640), jnp.bfloat16)
+    assert hybrid._choice_walks_pages(cfg, pages, walk, rows) is want
+
+
 # -------------------------------------------------------------- the experts
 
 
@@ -744,6 +905,78 @@ def test_a_prefill_through_the_kernel_equals_the_reference(weights, small_pieces
     assert fs.worst_difference(FAMILY, params, lora, ids, mask, result) < 2e-5
     after = telemetry.observe_snapshot()["counters"][telemetry.OPS_LATENT_KERNEL_FOLDS]
     assert after - before == 3 * 10
+
+
+@pytest.mark.parametrize("scheduler,slots", [("refill", 8), ("waves", 0)])
+def test_a_cpu_round_counts_no_decode_launches(weights, small_pieces, scheduler, slots):
+    """``ops/latent_decode_launches`` is filed by both schedulers and reads 0
+    here: a CPU takes the XLA walk, and ``absorbed_decode`` says so under the
+    heads' and the pages' geometry."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    before = telemetry.observe_snapshot()["counters"].get(
+        telemetry.OPS_LATENT_DECODE_LAUNCHES, 0)
+    fs.engine(FAMILY, scheduler, slots).generate(
+        params, lora, *fs.prompts((40, 57)),
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=4),
+        jax.random.PRNGKey(3))
+    assert la.dispatch_choices[
+        la.decode_dispatch_key(4, CFG.latent_row, 8, jnp.float32)] == "xla"
+    after = telemetry.observe_snapshot()["counters"]
+    assert after[telemetry.OPS_LATENT_DECODE_LAUNCHES] == before  # filed, and 0
+
+
+@pytest.mark.parametrize("ran,want", [("kernel", 3 * 24), ("xla", 0), (None, 0)])
+def test_the_counter_is_layers_times_steps_where_the_launch_ran(monkeypatch, ran, want):
+    """24 decode steps through 3 latent layers (the Kimi cell: 7 x 512 = 3,584)."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    monkeypatch.setattr(la, "dispatch_choices", {} if ran is None else {
+        la.decode_dispatch_key(4, CFG.latent_row, 8, jnp.float32): ran,
+        la.decode_dispatch_key(4, CFG.latent_row, 16, jnp.float32): "kernel"})  # other pages
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_latent_decode_telemetry(CFG, 24, 8, jnp.float32)
+    assert filed == [("ops/latent_decode_launches", want)]
+    filed.clear()  # no step, or a model without latent layers: nothing filed
+    paged_engine._record_latent_decode_telemetry(CFG, 0, 8, jnp.float32)
+    paged_engine._record_latent_decode_telemetry(PRESETS["tiny"], 24, 8, jnp.float32)
+    assert filed == []
+
+
+def test_a_round_through_the_launch_equals_the_reference(weights, small_pieces, monkeypatch):
+    """The engine's decode with every layer-step's attention run by
+    ``absorbed_decode_kernel`` (interpreted; the dispatch answered for it):
+    two prompts' candidates in groups of four, 24 steps that cross three page
+    boundaries. The captured log-probabilities are the reference's, the
+    counter reads 3 layers x 24 steps, the walk shares by ONE column (the
+    prompts' 5 and 7 full pages are fetched once a group where the XLA walk's
+    blocks of 6 share 0 and 6) and attends what it always did."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    params, lora = weights
+    ids, mask = fs.prompts((40, 57))
+    monkeypatch.setattr(la, "absorbed_decode_impl", lambda heads, pages, rows: "kernel")
+    monkeypatch.setattr(hybrid, "absorbed_decode_impl", lambda heads, pages, rows: "kernel")
+    monkeypatch.setattr(la, "absorbed_decode_kernel", functools.partial(
+        la.absorbed_decode_kernel, interpret=True))
+    counters = lambda: dict(telemetry.observe_snapshot()["counters"])
+    before = counters()
+    result = fs.make_engine(FAMILY, "waves", 0).generate(
+        params, lora, ids, mask,
+        SamplingConfig(temperature=1.0, top_p=1.0, n=4, max_tokens=24),
+        jax.random.PRNGKey(3))
+    assert fs.worst_difference(FAMILY, params, lora, ids, mask, result) < 2e-5
+    moved = lambda name: counters()[name] - before.get(name, 0)
+    assert moved(telemetry.OPS_LATENT_DECODE_LAUNCHES) == 3 * 24
+    held = np.asarray([[(p + t) // 8 + 1 for t in range(24)] for p in (40, 57)])
+    once = np.asarray([[5], [7]])
+    assert moved("engine/latent_pages_attended") == 3 * 4 * held.sum()
+    assert moved("engine/latent_pages_read") == 3 * (once + 4 * (held - once)).sum()
 
 
 def test_slots_of_mixed_prompts_fetch_every_page_a_row(weights, small_pieces):

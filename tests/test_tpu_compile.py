@@ -272,25 +272,35 @@ def _kimi_cell():
         moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446)
 
 
-def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
+@pytest.mark.parametrize("backend", ["cpu", "tpu"], ids=["xla_walk", "launch"])
+def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(
+        chip, monkeypatch, backend):
     """One layer's fragment of Kimi-VL-A3B's decode step at the cell's sizes
     (64 rows, a table of 165 pages of 128 rows of 576 values in 640 lanes, 64 experts of
     2,048 x 1,408 in a stack of 6 layers): the latent row's point scatter
     keeps the pool's layout (at 576 lanes a row the compiler keeps the pool
-    token-minor and copies it round the write), the page gather copies no pool,
+    token-minor and copies it round the write), the walk copies no pool,
     and the experts' products read their layer out of the stack in place: a
     layer copied out of it first (as ``lax.ragged_dot``'s custom call had it)
-    is 369 MB a matrix, three a layer, 6.6 GB of temporaries a step."""
+    is 369 MB a matrix, three a layer, 6.6 GB of temporaries a step. In both
+    forms of the walk (what ``jax.default_backend()`` answers decides, and
+    the test answers for it): the XLA walk gathers a group's shared blocks
+    once; the launch (PR 63) is ONE Mosaic call under ``model/latent_attn``
+    that reads the pool where it lies, and no block of pages ``[.., 128, 640]``
+    is gathered at all."""
     from distrl_llm_tpu.models import moe
     from distrl_llm_tpu.models.hybrid import _latent_mix, _latent_page_walk
     from distrl_llm_tpu.models.transformer import _proj
+    from distrl_llm_tpu.ops import latent_attention as la
 
     cfg = _kimi_cell()
     pool, layers = (960, 128, 640), 6
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(la, "dispatch_choices", {})
 
     def fragment(pages, q, c, k_pe, lengths, table, w_kvb, h, p):
         env = {"page_indices": table, "page_size": 128, "lengths": lengths}
-        env["page_walk"] = _latent_page_walk(env, cfg)
+        env["page_walk"] = _latent_page_walk(env, cfg, pages)
         o, pages, _ = _latent_mix(  # no index: the third piece is None
             q[..., :128], q[..., 128:], c, k_pe, pages, {"wkv_b": w_kvb}, None,
             cfg=cfg, mode="decode", env=env, proj=_proj, lora_scale=1.0)
@@ -318,9 +328,17 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(chip):
     ]
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
-    # a group's shared blocks are gathered once, 16 pages for its 16 rows, and a
-    # row's own columns 8 pages at a time: never 16 pages for each of 16 rows
-    assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    ran = la.dispatch_choices[la.decode_dispatch_key(16, 640, 128, bf)]
+    if backend == "tpu":
+        assert ran == "kernel" and len(calls) == 1, calls
+        assert "%absorbed_decode_kernel" in calls[0] and "model/latent_attn" in calls[0]
+        assert ",128,640]" not in text.replace("bf16[960,128,640]", "")  # nothing gathered
+    else:
+        # a group's shared blocks are gathered once, 16 pages for its 16 rows, and a
+        # row's own columns 8 pages at a time: never 16 pages for each of 16 rows
+        assert ran == "xla" and not calls
+        assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
 
 
 def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch):
@@ -885,14 +903,17 @@ def _glm_cell():
 
 def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
     """``glm-5-ep16-L5.rollout-longctx-indexed``'s decode step (64 rows, a table
-    of 164 pages, a rank-32 adapter) fed the decode view: plain XLA throughout
-    (no Mosaic launch: the choice and the gather have no kernel yet). Both paged
+    of 164 pages, a rank-32 adapter) fed the decode view. Both paged
     arrays of every layer (latent rows ``[pages, 128, 640]``, index keys
     ``[pages, 128, 128]``) are donated and written in place by a point scatter:
-    no copy of a pool. The exact choice is a sort a layer (``top_k`` of 2,048
-    over 21k scores), the chosen rows one gather ``[64, 2048, 640]`` a layer,
-    and the temporaries stay under half a gigabyte (0.29 GB when this was
-    written, beside 8.85 GB of arguments)."""
+    no copy of a pool. The exact choice sorts nothing (PR 55), and since PR 63
+    it is handed on as the mask it is made as: 16 rows x 2,048 chosen tokens
+    are more than the table's 20,992 positions, so every layer's attention is
+    ONE Mosaic launch under ``model/indexed_attn`` that walks a group's pages
+    whole behind the choice (64 heads: the same ``absorbed_decode_kernel`` as
+    Kimi-VL's 16), the chosen rows ``[64, 2048, 640]`` are never gathered, and
+    the temporaries stay under half a gigabyte (0.29 GB before the launch,
+    beside 8.85 GB of arguments)."""
     from distrl_llm_tpu.models import forward, init_lora_params, init_params
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
@@ -922,13 +943,15 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
         place(jax.eval_shape(decode_view, params)), lora, cache,
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in text
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 5 and all(
+        "%absorbed_decode_kernel" in c and "model/indexed_attn" in c for c in calls), calls[:2]
     entry = text[text.index("ENTRY "):]
     for pool in (f"bf16[{pages},128,640]", f"bf16[{pages},128,128]"):
         copies = [line.strip()[:160] for line in entry.splitlines()
                   if " copy(" in line and pool in line.split("(")[0]]
         assert not copies, copies
-    assert "bf16[64,2048,640]" in text  # the chosen rows, gathered
+    assert "bf16[64,2048,640]" not in text  # the chosen rows are not gathered
     assert _sorts_under(text, "model/index_select") == []  # chosen by counting (PR 55)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 5 * pages * page * (640 + 128) * 2
