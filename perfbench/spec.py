@@ -36,6 +36,11 @@ class SpecError(ValueError):
 DEPTH_KEY = "num_hidden_layers"
 DENSE_KEY = "first_k_dense_replace"
 DEPTH_KEYS = (DEPTH_KEY, DENSE_KEY)
+#: the names a published config gives its depth (84 and 4 of the catalog's 88
+#: rows), and no pattern: a file's depth key is the ONE of them it holds, and
+#: any other spelling (``n_layer``, ``depth``) is refused as a width until a
+#: published config bears it out
+DEPTH_NAMES = (DEPTH_KEY, "num_layers")
 #: the fewest layers that follow the one leading dense layer
 MIN_LAYERS_AFTER_DENSE = 4
 #: keys of ``reduced`` that COUNT the routed experts this chip holds of a layer
@@ -63,7 +68,13 @@ def check_reduced(config_file: dict[str, Any], where: str) -> None:
     ONE of the model's leading dense layers.
 
     ``reduced`` may hold the depth key and, only beside a ``share`` object,
-    keys that count what THIS chip holds of a layer (``SHARE_KEYS``).
+    keys that count what THIS chip holds of a layer (``SHARE_KEYS``). The
+    depth key is the name the published config gives its depth: the one of
+    ``DEPTH_NAMES`` the file holds, never both, and ``reduced`` names that one:
+    a depth that is cut stands in the file, so a file that holds neither name
+    cuts no depth. Its number counts
+    the layers the published config counts, whatever a layer holds (two
+    attention sublayers, say: what a layer is made of is the program's).
     ``share`` is ``{"chips_per_layer": n, "published": {<key>: <published
     value>}}`` with one ``published`` entry for every such key and none else.
     What is held follows from ``n`` and the published count (one chip's share,
@@ -74,25 +85,33 @@ def check_reduced(config_file: dict[str, Any], where: str) -> None:
 
     ``reduced`` may also hold ``first_k_dense_replace``, a second DEPTH key
     (leading dense layers count once; it needs no ``share``): only beside
-    ``num_hidden_layers``, only with 1 held, only where the file's ``depth``
-    object ``{"published": {"num_hidden_layers": N, "first_k_dense_replace":
-    K}}`` states the published counts (``K > 1``), and only where at least
-    ``MIN_LAYERS_AFTER_DENSE`` layers follow the one dense layer. ``depth``
-    stands in a file exactly when that key is reduced.
+    the depth key, only with 1 held, only where the file's ``depth`` object
+    ``{"published": {<depth key>: N, "first_k_dense_replace": K}}`` states the
+    published counts (``K > 1``) under the file's own names, and only where at
+    least ``MIN_LAYERS_AFTER_DENSE`` layers follow the one dense layer.
+    ``depth`` stands in a file exactly when that key is reduced.
 
     Any other key is a width, and a width is never cut. The harness does
-    nothing else with ``share`` or ``depth``: the whole file goes to
-    ``ModelConfig.from_hf_config``."""
+    nothing else with the depth key, ``share`` or ``depth``: the whole file
+    goes to ``ModelConfig.from_hf_config``."""
     reduced = list(config_file.get("reduced", []))
     share = config_file.get("share")
-    share_keys = [k for k in reduced if k not in DEPTH_KEYS]
+    depth_key = _depth_key(config_file, reduced, where)
+    # what the refusals call it: the file's own name, or both where it holds none
+    depth_name = repr(depth_key) if depth_key else " or ".join(map(repr, DEPTH_NAMES))
+    share_keys = [k for k in reduced if k not in (depth_key, DENSE_KEY)]
     for key in share_keys:
+        if key in DEPTH_NAMES:
+            raise SpecError(
+                f"{where}: 'reduced' names {key!r}, which the file does not hold: its "
+                f"depth key is {depth_key!r}, the name its published config counts its "
+                "layers under")
         if key not in SHARE_KEYS:
             raise SpecError(
                 f"{where}: 'reduced' names {key!r}: a width is never cut (only "
-                f"{DEPTH_KEY!r}, beside it {DENSE_KEY!r} held once, and, beside a "
+                f"{depth_name}, beside it {DENSE_KEY!r} held once, and, beside a "
                 f"'share', the counts {list(SHARE_KEYS)})")
-    _check_dense_once(config_file, reduced, where)
+    _check_dense_once(config_file, reduced, where, depth_key, depth_name)
     if share is None:
         if share_keys:
             raise SpecError(
@@ -144,9 +163,30 @@ def _is_count(value: Any) -> bool:
     return isinstance(value, int) and not isinstance(value, bool) and value >= 0
 
 
-def _check_dense_once(config_file: dict[str, Any], reduced: list[str], where: str) -> None:
+def _depth_key(config_file: dict[str, Any], reduced: list[str], where: str) -> str | None:
+    """The one of ``DEPTH_NAMES`` the file holds, or None where it holds neither
+    and ``reduced`` names neither (``check_reduced`` says the rule)."""
+    held = [k for k in DEPTH_NAMES if k in config_file]
+    if len(held) > 1:
+        raise SpecError(
+            f"{where}: the file holds {held[0]!r} and {held[1]!r}: a published config "
+            "counts its layers under one name, and the file keeps that one")
+    if held:
+        return held[0]
+    for key in reduced:
+        if key in DEPTH_NAMES:
+            raise SpecError(
+                f"{where}: 'reduced' names {key!r} and the file holds no {key!r}: a "
+                "depth that is cut stands in the file, the number of layers held "
+                "under the name its published config counts them under")
+    return None
+
+
+def _check_dense_once(config_file: dict[str, Any], reduced: list[str], where: str,
+                      depth_key: str | None, depth_name: str) -> None:
     """``first_k_dense_replace`` in ``reduced``: leading dense layers count
-    once (``check_reduced`` says the rule)."""
+    once (``check_reduced`` says the rule). ``depth_key`` is the file's own,
+    ``depth_name`` what a refusal calls it."""
     depth = config_file.get("depth")
     if DENSE_KEY not in reduced:
         if depth is not None:
@@ -154,24 +194,32 @@ def _check_dense_once(config_file: dict[str, Any], reduced: list[str], where: st
                 f"{where}: a 'depth' and no {DENSE_KEY!r} in 'reduced': it states the "
                 "published counts for that cut alone")
         return
-    if DEPTH_KEY not in reduced:
+    if depth_key not in reduced:
         raise SpecError(
-            f"{where}: 'reduced' names {DENSE_KEY!r} and not {DEPTH_KEY!r}: leading "
+            f"{where}: 'reduced' names {DENSE_KEY!r} and not {depth_name}: leading "
             "dense layers count once only where depth is cut (a model at its whole "
             "depth keeps them all)")
-    wanted = {"published": {DEPTH_KEY: "N", DENSE_KEY: "K"}}
+    wanted = {"published": {depth_key: "N", DENSE_KEY: "K"}}
+    stated = depth.get("published") if isinstance(depth, dict) else None
+    if isinstance(stated, dict):
+        for key in DEPTH_NAMES:
+            if key != depth_key and key in stated:
+                raise SpecError(
+                    f"{where}: 'depth' publishes {key!r} and the file's depth key is "
+                    f"{depth_key!r}: the published counts stand under the file's own "
+                    f"names, {wanted}")
     if (not isinstance(depth, dict) or set(depth) != {"published"}
             or not isinstance(depth["published"], dict)
-            or set(depth["published"]) != set(DEPTH_KEYS)):
+            or set(depth["published"]) != {depth_key, DENSE_KEY}):
         raise SpecError(
             f"{where}: 'reduced' names {DENSE_KEY!r} and the file's 'depth' is "
             f"{depth!r}, not {wanted}: the published counts it was cut from")
     published = depth["published"]
-    layers, dense = config_file.get(DEPTH_KEY), config_file.get(DENSE_KEY)
+    layers, dense = config_file.get(depth_key), config_file.get(DENSE_KEY)
     if (not all(map(_is_count, (layers, dense, *published.values())))
-            or published[DENSE_KEY] < 2 or published[DEPTH_KEY] <= layers):
+            or published[DENSE_KEY] < 2 or published[depth_key] <= layers):
         raise SpecError(
-            f"{where}: {DEPTH_KEY} {layers!r} and {DENSE_KEY} {dense!r} are held and "
+            f"{where}: {depth_key} {layers!r} and {DENSE_KEY} {dense!r} are held and "
             f"'depth' publishes {published}: {DENSE_KEY!r} is reduced where the model "
             "has 2 leading dense layers or more, and more layers than are held")
     if dense == 0:
@@ -184,7 +232,7 @@ def _check_dense_once(config_file: dict[str, Any], reduced: list[str], where: st
             f"layers count once, so 1 is held (or all, with the key left out of 'reduced')")
     if layers < 1 + MIN_LAYERS_AFTER_DENSE:
         raise SpecError(
-            f"{where}: {DEPTH_KEY} holds {layers}: the one dense layer and {layers - 1} "
+            f"{where}: {depth_key} holds {layers}: the one dense layer and {layers - 1} "
             f"after it; at least {MIN_LAYERS_AFTER_DENSE} layers follow the leading "
             "dense one")
 

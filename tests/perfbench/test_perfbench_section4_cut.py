@@ -6,6 +6,9 @@ tests; and what PR 53 found is held as it was: the seven configuration files
 and the traffic files by their hashes (one file's ``check`` was set again), ``spec.load_cell`` of the nine cells by
 a literal taken at PR 53's parent (``d1de52c``).
 
+A second made-up file (PR 64) names its depth ``num_layers``, as four of the
+catalog's published configs do, and goes the same way by its own name.
+
 The literals hold what was THERE by name: a later PR appends cells, metrics
 and cell names to a metric's list, and none of that fails here."""
 
@@ -22,6 +25,8 @@ from tiny_spec import REPO, TINY_DIR, real_benchmark, tiny_benchmark
 
 CONFIG = "tiny-cut-whole"
 FILE = f"{TINY_DIR}/configs/{CONFIG}.json"
+#: PR 64's: depth under ``num_layers``, cut by depth and by one of 32 chips' share
+NUM_LAYERS = "tiny-num-layers"
 #: the accepted traffic the made-up cell rides on in the copy of the real benchmark
 TRAFFIC = "rollout-longctx"
 
@@ -31,8 +36,12 @@ def sha256(path: str) -> str:
         return hashlib.sha256(f.read()).hexdigest()
 
 
-def held_file() -> dict:
-    with open(os.path.join(REPO, FILE), encoding="utf-8") as f:
+def file_of(config: str) -> str:
+    return f"{TINY_DIR}/configs/{config}.json"
+
+
+def held_file(config: str = CONFIG) -> dict:
+    with open(os.path.join(REPO, file_of(config)), encoding="utf-8") as f:
         return json.load(f)
 
 
@@ -53,33 +62,55 @@ def test_the_file_is_cut_the_new_way_and_check_reduced_takes_it():
     assert spec.check_reduced(held, FILE) is None
 
 
-def test_a_run_loads_it_whole():
+def test_the_file_that_says_num_layers_is_cut_under_that_name_and_check_reduced_takes_it():
+    held = held_file(NUM_LAYERS)
+    assert held["reduced"] == ["num_layers", "n_routed_experts", "vocab_size"]
+    assert "num_hidden_layers" not in held and "depth" not in held
+    chips, published = held["share"]["chips_per_layer"], held["share"]["published"]
+    assert chips == 32 and held["n_routed_experts"] * chips == published["n_routed_experts"]
+    assert held["n_routed_experts"] >= spec.MIN_EXPERTS_HELD
+    assert held["vocab_size"] * spec.MIN_VOCAB_SHARE == published["vocab_size"]
+    assert held["num_layers"] >= spec.MIN_LAYERS_AFTER_DENSE
+    assert spec.check_reduced(held, file_of(NUM_LAYERS)) is None
+
+
+def appended_entry(config: str) -> dict:
+    held = held_file(config)
+    return {"name": config, "source": held["source"], "file": file_of(config),
+            "reduced": held["reduced"], "why": "the section-4 cut, whole"}
+
+
+@pytest.mark.parametrize("config", [CONFIG, NUM_LAYERS])
+def test_a_run_loads_it_whole(config):
     """Through ``spec.load_cell``: the tiny benchmark with the configuration and
     a cell appended, as a later PR appends its own."""
     bench = tiny_benchmark()
-    bench["configs"].append({
-        "name": CONFIG, "source": held_file()["source"], "file": FILE,
-        "reduced": held_file()["reduced"], "why": "the section-4 cut, whole"})
-    cell = f"{CONFIG}.rollout"
-    bench["workloads"].append({"name": cell, "config": CONFIG, "traffic": "tiny-rollout",
+    bench["configs"].append(appended_entry(config))
+    cell = f"{config}.rollout"
+    bench["workloads"].append({"name": cell, "config": config, "traffic": "tiny-rollout",
                                "chips": 1, "why": "rehearsal"})
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if "tiny.rollout" in metric.get("workloads", []):
             metric["workloads"].append(cell)
     loaded = spec.load_cell(bench, cell)
-    assert loaded.config == held_file() and loaded.traffic["kind"] == "rollout"
+    assert loaded.config == held_file(config) and loaded.traffic["kind"] == "rollout"
     assert [m["name"] for m in loaded.per_layer] == [
         m["name"] for m in spec.load_cell(bench, "tiny.rollout").per_layer]
 
 
-@pytest.mark.parametrize("edit, refusal", [
-    ({"vocab_size": 128}, "an eighth of the vocabulary"),
-    ({"first_k_dense_replace": 3}, "leading dense layers count once"),
+@pytest.mark.parametrize("config, edit, refusal", [
+    (CONFIG, {"vocab_size": 128}, "an eighth of the vocabulary"),
+    (CONFIG, {"first_k_dense_replace": 3}, "leading dense layers count once"),
+    (NUM_LAYERS, {"reduced": ["num_layers", "n_routed_experts", "vocab_size", "q_lora_rank"]},
+     "'q_lora_rank': a width is never cut"),
+    (NUM_LAYERS, {"num_hidden_layers": 4}, "counts its layers under one name"),
+    (NUM_LAYERS, {"reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"]},
+     "'num_hidden_layers', which the file does not hold"),
 ])
-def test_a_run_refuses_the_same_file_cut_further(tmp_path, edit, refusal):
+def test_a_run_refuses_the_same_file_cut_further(tmp_path, config, edit, refusal):
     bench = tiny_benchmark()
     path = tmp_path / "cut.json"
-    path.write_text(json.dumps({**held_file(), **edit}), encoding="utf-8")
+    path.write_text(json.dumps({**held_file(config), **edit}), encoding="utf-8")
     entry = next(c for c in bench["configs"] if c["name"] == "tiny")
     entry["file"] = str(path)
     with pytest.raises(spec.SpecError, match=refusal):
@@ -88,24 +119,28 @@ def test_a_run_refuses_the_same_file_cut_further(tmp_path, edit, refusal):
 
 def test_appended_to_the_real_benchmark_it_passes_the_structural_tests(tmp_path):
     """``test_perfbench_appended``'s run over a copy of the real benchmark, with
-    THIS file as the appended configuration: its own ``test_config_file`` case
-    and its cell's case are collected and pass, because the file is there."""
+    THESE files as the appended configurations (one run for both: a run costs
+    seconds): each one's own ``test_config_file`` case and its cell's case are
+    collected and pass, because the file is there."""
     bench = real_benchmark()
-    cell = f"{CONFIG}.{TRAFFIC}"
-    bench["configs"].append({
-        "name": CONFIG, "source": held_file()["source"], "file": FILE,
-        "reduced": held_file()["reduced"], "why": "the section-4 cut, whole"})
-    bench["workloads"].append({"name": cell, "config": CONFIG, "traffic": TRAFFIC,
-                               "chips": 1, "why": "made up"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if metric["name"] in (*JOINED, *SHARED_WITH_A_FAMILY):
-            metric["workloads"].append(cell)
-    out = run_structural(bench, tmp_path / "BENCHMARK.cut.json", ("test_perfbench_spec.py",),
-                         "-k", "tiny-cut-whole or test_names_are_plain or test_top_level")
-    said = out.stdout[-3000:] + out.stderr[-2000:]
+    for config in (CONFIG, NUM_LAYERS):
+        cell = f"{config}.{TRAFFIC}"
+        bench["configs"].append(appended_entry(config))
+        bench["workloads"].append({"name": cell, "config": config, "traffic": TRAFFIC,
+                                   "chips": 1, "why": "made up"})
+        for metric in bench["end_to_end"] + bench["per_layer"]:
+            if metric["name"] in (*JOINED, *SHARED_WITH_A_FAMILY):
+                metric["workloads"].append(cell)
+    out = run_structural(
+        bench, tmp_path / "BENCHMARK.cut.json", ("test_perfbench_spec.py",), "-rp", "-k",
+        f"{CONFIG} or {NUM_LAYERS} or test_names_are_plain or test_top_level")
+    said = out.stdout[-4000:] + out.stderr[-2000:]
     assert out.returncode == 0, said
-    # test_config_file, the counts module's case, the cell's case; and the two named
-    assert "5 passed" in out.stdout, said
+    # a file: test_config_file, the counts module's case, the cell's case; and the two named
+    assert "8 passed" in out.stdout, said
+    for config in (CONFIG, NUM_LAYERS):
+        assert f"test_config_file[{config}]" in out.stdout, said
+        assert f"[{config}.{TRAFFIC}]" in out.stdout, said
 
 
 # --------------------------------------- what was there at PR 53's parent stays

@@ -134,9 +134,28 @@ def over(chips, experts, vocab=None, held_vocab=None):
                   n_routed_experts=experts // chips, vocab_size=held_vocab or 154880)
 
 
+#: the cut ISSUE 64 works out for its draw, letter for letter: a published config
+#: that names its depth ``num_layers``; one of 32 chips that share each layer
+#: holds 16 of 512 routed experts and an eighth of a vocabulary of 131,072 (8
+#: slices, each on 4 chips), 4 of the 28 layers, no leading dense one
+NUM_LAYERS_CUT = {
+    "num_layers": 4, "n_routed_experts": 16, "vocab_size": 16384,
+    "reduced": ["num_layers", "n_routed_experts", "vocab_size"],
+    "share": {"chips_per_layer": 32,
+              "published": {"n_routed_experts": 512, "vocab_size": 131072}},
+}
+#: ``DENSE_ONCE`` as a config that says ``num_layers`` would state it
+DENSE_ONCE_NUM_LAYERS = {
+    "num_layers": 5, "first_k_dense_replace": 1,
+    "reduced": ["num_layers", "first_k_dense_replace"],
+    "depth": {"published": {"num_layers": 78, "first_k_dense_replace": 3}},
+}
+
+
 REDUCED_CASES = {
     # accepted
-    "depth-alone": ({"reduced": ["num_hidden_layers"]}, None),
+    # (PR 64 gave this one the key it names: a depth that is cut stands in the file)
+    "depth-alone": ({"num_hidden_layers": 14, "reduced": ["num_hidden_layers"]}, None),
     "nothing-cut": ({"reduced": []}, None),
     "experts-and-vocabulary-over-8-chips": (SHARED, None),
     "heads-as-a-share": (shared(
@@ -210,6 +229,44 @@ REDUCED_CASES = {
         {**DENSE_ONCE, "reduced": ["num_hidden_layers"]},
         "a 'depth' and no 'first_k_dense_replace' in 'reduced'"),
 }
+#: since PR 64: the depth key is the ONE of two names the file holds. Cases of
+#: the same test, in a dict of their own so that they are held by name
+DEPTH_NAME_CASES = {
+    "cut-under-num_layers-32-chips-an-eighth": (NUM_LAYERS_CUT, None),
+    "depth-alone-under-num_layers": ({"num_layers": 4, "reduced": ["num_layers"]}, None),
+    "num_layers-and-one-dense-layer-held": (DENSE_ONCE_NUM_LAYERS, None),
+    "both-depth-names-in-one-file": (
+        {**NUM_LAYERS_CUT, "num_hidden_layers": 4},
+        "the file holds 'num_hidden_layers' and 'num_layers'"),
+    "num_layers-reduced-in-a-num_hidden_layers-file": (
+        shared(["num_layers", "n_routed_experts", "vocab_size"]),
+        "'reduced' names 'num_layers', which the file does not hold"),
+    "num_hidden_layers-reduced-in-a-num_layers-file": (
+        {**NUM_LAYERS_CUT, "reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"]},
+        "'reduced' names 'num_hidden_layers', which the file does not hold"),
+    "depth-published-under-the-other-name": (
+        {**DENSE_ONCE_NUM_LAYERS, "depth": DENSE_ONCE["depth"]},
+        "'depth' publishes 'num_hidden_layers' and the file's depth key is 'num_layers'"),
+    "depth-reduced-in-a-file-that-holds-none": (
+        {"reduced": ["num_hidden_layers"]},
+        "'reduced' names 'num_hidden_layers' and the file holds no 'num_hidden_layers'"),
+    "num_layers-reduced-in-a-file-that-holds-none": (
+        {k: v for k, v in NUM_LAYERS_CUT.items() if k != "num_layers"},
+        "'reduced' names 'num_layers' and the file holds no 'num_layers'"),
+    "n_layer-is-still-a-width": (
+        {"n_layer": 4, "reduced": ["n_layer"]},
+        "'n_layer': a width is never cut (only 'num_hidden_layers' or 'num_layers', beside"),
+    "a-width-beside-num_layers": (
+        {**NUM_LAYERS_CUT, "reduced": [*NUM_LAYERS_CUT["reduced"], "expert_ffn_hidden_size"]},
+        "'expert_ffn_hidden_size': a width is never cut (only 'num_layers'"),
+    "num_layers-with-three-after-the-dense-one": (
+        {**DENSE_ONCE_NUM_LAYERS, "num_layers": 4},
+        "num_layers holds 4: the one dense layer and 3 after it"),
+    "dense-layers-cut-with-num_layers-uncut": (
+        {**DENSE_ONCE_NUM_LAYERS, "reduced": ["first_k_dense_replace"]},
+        "'first_k_dense_replace' and not 'num_layers'"),
+}
+REDUCED_CASES.update(DEPTH_NAME_CASES)
 
 
 @pytest.mark.parametrize("held, refusal", REDUCED_CASES.values(), ids=REDUCED_CASES)
@@ -226,10 +283,30 @@ def test_reduced_names_depth_or_one_chips_share_and_never_a_width(held, refusal)
         assert str(said.value).startswith("a.json: ")
 
 
+def test_each_refusal_about_the_depths_name_is_met_by_its_own_case_alone():
+    """The parent refuses ``NUM_LAYERS_CUT`` ("'num_layers': a width is never
+    cut"); what PR 64 refuses instead, it refuses in words no other case meets."""
+    from perfbench import spec
+
+    def said(case):
+        try:
+            spec.check_reduced(REDUCED_CASES[case][0], "a.json")
+        except spec.SpecError as e:
+            return str(e)
+        return ""
+
+    everything = {case: said(case) for case in REDUCED_CASES}
+    for case, (_, refusal) in DEPTH_NAME_CASES.items():
+        if refusal is not None:
+            assert [c for c, text in everything.items() if refusal in text] == [case]
+    assert not any("'num_layers': a width" in text for text in everything.values())
+
+
 @pytest.mark.parametrize("case", ["experts-and-vocabulary-over-8-chips", "hidden_size",
                                   "held-times-chips-is-not-published",
                                   "cut-whole-16-chips-an-eighth-and-one-dense-layer",
-                                  "two-of-three-dense-layers-held"])
+                                  "two-of-three-dense-layers-held",
+                                  "both-depth-names-in-one-file"])
 def test_a_run_refuses_what_the_test_refuses(tmp_path, case):
     """``spec.load_cell`` goes through the same function: a configuration the
     tests would refuse never reaches a driver."""
