@@ -108,6 +108,10 @@ ENGINE_MOE_MAX_EXPERT_LOAD = "engine/moe_max_expert_load"  # counter
 # rows x experts_per_token), where a program holds one chip's share of them:
 # moe_assignments (pairs of experts HELD) over this is the share that landed here
 ENGINE_MOE_PAIRS_ROUTED = "engine/moe_pairs_routed"        # counter
+# and those of them that chose an expert that computes nothing (a router with
+# zero-compute outputs, models/moe.py::zero_part): over moe_pairs_routed, the
+# share of a token's choices that cost no product
+ENGINE_MOE_PAIRS_ZERO = "engine/moe_pairs_zero"            # counter
 # power retention (ops/power_retention.py): bytes of S and z that live rows'
 # decode steps read and wrote, summed over layers and steps (carried as a count
 # of (live row, layer) states, ``mixer["power_stats"]``, and fetched with the
@@ -211,7 +215,8 @@ def _no_full_collection():
 def _count_mixer_stats(mixer) -> dict:
     """File a round's counters (``mixer["sel_stats"]``: the block-sparse
     layers' blocks; ``mixer["moe_stats"]`` / ``["moe_routed"]``: the expert
-    layers' pairs, held here / chosen over all experts; ``mixer["moe_blocks"]``:
+    layers' pairs, held here / chosen over all experts; ``mixer["moe_zero"]``: those
+    of them that chose an expert that computes nothing; ``mixer["moe_blocks"]``:
     the blocks their grouped form ran and laid, the prefill's too;
     ``mixer["latent_stats"]``: absorbed attention's pages; ``mixer["power_stats"]``: the (live row, layer)
     power-retention states the decode steps read and wrote, filed as the bytes
@@ -250,6 +255,7 @@ def _count_mixer_stats(mixer) -> dict:
         ("sel_stats", (ENGINE_SPARSE_BLOCKS_ATTENDED, ENGINE_SPARSE_BLOCKS_VISIBLE)),
         ("moe_stats", (ENGINE_MOE_ASSIGNMENTS, ENGINE_MOE_MAX_EXPERT_LOAD)),
         ("moe_routed", (ENGINE_MOE_PAIRS_ROUTED,)),
+        ("moe_zero", (ENGINE_MOE_PAIRS_ZERO,)),
         ("moe_blocks", (telemetry.ENGINE_MOE_BLOCKS_RUN, telemetry.ENGINE_MOE_BLOCKS_LAID)),
         ("latent_stats", (ENGINE_LATENT_PAGES_ATTENDED, ENGINE_LATENT_PAGES_READ)),
     ):
@@ -341,7 +347,7 @@ def _record_fold_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
     embedding's; ``segments`` the longest row's, where the stages end (every
     segment of the prompt's width where it is not given)."""
     if cfg.latent:
-        name, layers = telemetry.OPS_LATENT_KERNEL_FOLDS, cfg.num_layers
+        name, layers = telemetry.OPS_LATENT_KERNEL_FOLDS, cfg.paged_layers
         layout = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)
     else:
         name = telemetry.OPS_SOFTMAX_KERNEL_FOLDS
@@ -372,7 +378,7 @@ def _record_latent_decode_telemetry(cfg: ModelConfig, steps: int, page_size: int
     ran = dispatch_choices.get(decode_dispatch_key(
         cfg.num_heads, cfg.latent_row, page_size, cache_dtype))
     telemetry.counter_add(
-        telemetry.OPS_LATENT_DECODE_LAUNCHES, cfg.num_layers * steps * (ran == "kernel"))
+        telemetry.OPS_LATENT_DECODE_LAUNCHES, cfg.paged_layers * steps * (ran == "kernel"))
 
 
 def _record_index_telemetry(cfg: ModelConfig, steps: int, prompt_pages: int,

@@ -98,6 +98,19 @@ The second half of every layer is routed experts chosen ONE a token by an MLP
 router of ``router_hidden_size`` whose input carries the previous layer's
 (``models/moe.py::route_mlp``), and each sublayer joins the stream through
 learned scales and shifts (``models/hybrid.py``).
+
+A shortcut-connected expert model (``longcat_flash``, LongCat-Flash-Chat;
+``shortcut_moe``) holds in ONE published layer two latent-attention sublayers,
+each with a dense gated MLP, and one expert block that reads the first MLP's
+normed input and joins the stream after the second MLP. ``num_layers`` stays
+the published count; ``layer_kinds`` counts SUBLAYERS ("latent_fork" then
+"latent_join", ``SHORTCUT_KINDS``), and with it the stacks, the page pools
+(``paged_layers``: two a published layer) and the adapters. Its router is a
+softmax (``router_softmax``) over the routed experts AND ``zero_experts`` further
+outputs that compute nothing (a chosen one adds ``w * u``): ``router_width``
+counts both, the chosen scores are the weights unnormalised, and the two
+latents are scaled by constants before their up-projections (``latent_q_scale``,
+``latent_kv_scale``).
 """
 
 from __future__ import annotations
@@ -135,14 +148,18 @@ def mixer_of(kind: str) -> str:
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
     "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa", "zaya",
-    "mimo_v2_flash",
+    "mimo_v2_flash", "longcat_flash",
 )
+#: the two SUBLAYERS of one published layer of a shortcut-connected expert model
+#: (``longcat_flash``): the first forks the expert block off its MLP's input, the
+#: second adds what the experts gave after its own MLP
+SHORTCUT_KINDS = ("latent_fork", "latent_join")
 #: what a slot holds for a layer of each kind, for a refusal
 _STATE_NAMES = {
     "sparse": "a selector cache of pooled keys",
     "lightning": "a recurrent float32 state",
-    "latent": "one latent row a token in place of K and V per head",
-    "latent_moe": "one latent row a token in place of K and V per head",
+    **dict.fromkeys(("latent", "latent_moe", *SHORTCUT_KINDS),
+                    "one latent row a token in place of K and V per head"),
     "softmax": "K/V pages for its softmax layers only",
     "delta": "a float32 delta-rule state and a convolution tail",
     "power": "a float32 power-retention state and its normaliser a KV head, "
@@ -155,7 +172,9 @@ _STATE_NAMES = {
 #: what a latent layer's token keeps beside its row where the model has an index
 _INDEX_STATE = " beside one index key a token in a second paged array"
 #: layer kind -> the published name a refusal gives it
-_LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert"}
+_LATENT_NAMES = {"latent": "latent-attention (MLA)", "latent_moe": "routed-expert",
+                 "latent_fork": "shortcut-connected routed-expert",
+                 "latent_join": "latent-attention (MLA)"}
 
 
 @dataclass(frozen=True)
@@ -254,12 +273,24 @@ class ModelConfig:
     window_rope_theta: float = 0.0  # RoPE's base in a "window" layer; 0 = rope_theta
     window_sink: bool = False  # one learned sink logit a query head, window layers
     value_scale: float = 1.0  # v = value_scale * (h W_v), before it is cached
+    # ---- a shortcut-connected expert layer (longcat_flash; module docstring):
+    # ONE published layer is two latent-attention sublayers, each with a dense
+    # MLP, and an expert block from the first MLP's input to the layer's end
+    shortcut_moe: bool = False
+    zero_experts: int = 0  # router outputs past the experts that compute nothing: w * u
+    router_softmax: bool = False  # s = softmax(u W_r) in place of the sigmoid
+    latent_q_scale: float = 1.0  # the normed query latent's constant (mla_scale_q_lora)
+    latent_kv_scale: float = 1.0  # the normed KV latent's (mla_scale_kv_lora)
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
                 f"hidden_act must be silu/gelu_tanh, got {self.hidden_act!r}"
             )
+        if self.shortcut_moe and not (self.latent and self.n_routed_experts):
+            raise ValueError(
+                "shortcut_moe is a latent-attention layer pair round routed experts: "
+                "it needs kv_lora_rank and n_routed_experts")
         if self.router_experts and (
                 self.router_experts % max(self.n_routed_experts, 1)
                 or self.expert_shard * self.n_routed_experts >= self.router_experts):
@@ -375,6 +406,8 @@ class ModelConfig:
         "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
         "softmax" | "delta" | "power" | "mamba" | "window" | "cca"; with ``mlp_types``
         a layer whose second half is the dense MLP carries ``DENSE_FFN``."""
+        if self.shortcut_moe:  # a published layer is two SUBLAYERS
+            return SHORTCUT_KINDS * self.num_layers
         if self.latent:
             dense = min(self.first_dense_layers, self.num_layers)
             if not self.n_routed_experts:
@@ -391,7 +424,8 @@ class ModelConfig:
         """"dense" | "experts": the second half of a "softmax", "delta",
         "mamba" or "window" layer of ``kind``. A kind that says so is dense;
         otherwise the model's routed experts, where it has any."""
-        if kind.endswith(DENSE_FFN) or kind == "latent" or not self.n_routed_experts:
+        if (kind.endswith(DENSE_FFN) or kind in ("latent", "latent_join")
+                or not self.n_routed_experts):
             return "dense"
         return "experts"
 
@@ -401,8 +435,9 @@ class ModelConfig:
 
     @property
     def router_width(self) -> int:
-        """Experts the router scores and chooses among: the published count."""
-        return self.router_experts or self.n_routed_experts
+        """Outputs the router scores and chooses among: the published count of
+        routed experts, and after them the experts that compute nothing."""
+        return (self.router_experts or self.n_routed_experts) + self.zero_experts
 
     @property
     def held_experts(self) -> tuple[int, ...] | None:
@@ -435,7 +470,9 @@ class ModelConfig:
     @property
     def paged_layers(self) -> int:
         """Layers that keep pages in the paged engine's pool."""
-        if self.latent or not self.hybrid:
+        if self.latent:  # every SUBLAYER where a published layer holds two
+            return len(self.layer_kinds)
+        if not self.hybrid:
             return self.num_layers
         return sum(self.mixer_count(m) for m in ("sparse", "softmax", "cca"))
 
@@ -614,7 +651,11 @@ class ModelConfig:
             + 2 * self.hidden_size * self.kv_dim    # k, v proj
         )
         if self.latent:
-            return self._latent_param_count(self.experts_per_token)
+            # of a token's choices, those that run a routed expert under an even
+            # router: a zero-compute choice multiplies nothing
+            return self._latent_param_count(
+                self.experts_per_token * (self.router_width - self.zero_experts)
+                // max(self.router_width, 1))
         if self.delta_moe:
             return self._delta_moe_param_count(self.experts_per_token)
         if self.window_moe:
@@ -662,6 +703,12 @@ class ModelConfig:
         moe = 3 * d * (
             experts * self.moe_intermediate_size + self.shared_expert_size
         ) + d * self.router_width
+        if self.shortcut_moe:
+            # two attention sublayers and two dense MLPs a published layer, and
+            # between them the experts (no shared one)
+            return self.num_layers * (
+                2 * (attn + 3 * d * self.intermediate_size) + moe
+            ) + d * self.vocab_size
         return (
             self.kind_count("latent") * (attn + 3 * d * self.intermediate_size)
             + self.kind_count("latent_moe") * (attn + moe)
@@ -737,7 +784,9 @@ class ModelConfig:
         """Model FLOPs per decoded token: 2·(matmul params) for the dense
         path plus the attention score/value dot-products (2 FLOPs × q_dim
         keys-side + values-side) at the mean resident KV length."""
-        attn = 4.0 * self.num_layers * self.q_dim * mean_kv_len
+        # a shortcut-connected layer attends twice: its sublayers are counted
+        layers = len(self.layer_kinds) if self.shortcut_moe else self.num_layers
+        attn = 4.0 * layers * self.q_dim * mean_kv_len
         if self.index_topk:
             # a token attends at most ``index_topk`` tokens, and scores every
             # visible token's index key with every index head
@@ -779,6 +828,8 @@ class ModelConfig:
     def model_type(self) -> str:
         """The HF model_type this config round-trips through
         ``from_hf_config`` as (used by HF-format snapshot export)."""
+        if self.shortcut_moe:
+            return "longcat_flash"
         if self.latent:
             return "glm_moe_dsa" if self.index_topk else "deepseek_v3"
         if self.delta_moe:
@@ -881,6 +932,9 @@ class ModelConfig:
             hybrid = _cca_fields(get)
         if mt == "mimo_v2_flash":
             hybrid = _swa_sink_moe_fields(get)
+        if mt == "longcat_flash":
+            hybrid = _shortcut_moe_fields(get)
+            head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -889,7 +943,8 @@ class ModelConfig:
             hidden_size=hf.hidden_size,
             # a zaya file has no dense MLP and no such key: its experts' width
             intermediate_size=hybrid.pop("intermediate_size", None) or hf.intermediate_size,
-            num_layers=hf.num_hidden_layers,
+            # a longcat_flash file counts its layers under ``num_layers``
+            num_layers=hybrid.pop("num_layers", None) or hf.num_hidden_layers,
             num_heads=num_heads,
             num_kv_heads=get("num_key_value_heads", num_heads),
             head_dim=head_dim,
@@ -1187,15 +1242,16 @@ def _power_fields(get) -> dict:
         mixer_types=("power-retention",) * int(get("num_hidden_layers")), qk_norm=True)
 
 
-def _refuse_router_variants(get, refuse) -> None:
+def _refuse_router_variants(get, refuse, scoring: str = "sigmoid") -> None:
     """The routers ``models/moe.py`` does not run, for every family with
-    DeepSeek-V3's key names."""
+    DeepSeek-V3's key names. ``scoring`` is the family's own: sigmoid for every
+    family but the one whose field function says softmax."""
     if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
         refuse("n_group" if get("n_group", 1) != 1 else "topk_group",
                "grouped routing (choose groups, then experts inside them) is "
                "not implemented; n_group and topk_group must be 1")
-    if str(get("scoring_func", "sigmoid")) != "sigmoid":
-        refuse("scoring_func", "the router scores by sigmoid only")
+    if str(get("scoring_func", scoring)) != scoring:
+        refuse("scoring_func", f"the router scores by {scoring} only")
 
 
 def _latent_fields(get, family: str = "deepseek_v3") -> dict:
@@ -1261,6 +1317,64 @@ def _latent_fields(get, family: str = "deepseek_v3") -> dict:
     if "rope_theta" in rope:
         fields["rope_theta"] = float(rope["rope_theta"])
     return fields
+
+
+def _shortcut_moe_fields(get) -> dict:
+    """The ``longcat_flash`` keys (LongCat-Flash-Chat) as ``ModelConfig``
+    fields: the depth under ``num_layers``, the dense MLPs' width under
+    ``ffn_hidden_size``, the experts' under ``expert_ffn_hidden_size``, the
+    choices a token under ``moe_topk``, the router's further outputs under
+    ``zero_expert_num``, the two latents' constants under ``mla_scale_*``, and
+    a ``share`` as ``_latent_fields`` reads one. The router is a softmax for
+    THIS family alone. A variant that is not implemented is REFUSED by name,
+    as ``_latent_fields`` does."""
+    def refuse(key: str, why: str):
+        raise ValueError(
+            f"longcat_flash with {key}={get(key)!r} is not supported: {why}")
+
+    if str(get("zero_expert_type", "identity")) != "identity":
+        refuse("zero_expert_type", "an expert that computes nothing returns its "
+               "input (identity); another kind is not implemented")
+    if str(get("attention_method", "MLA")) != "MLA":
+        refuse("attention_method", "both sublayers' attention is latent (MLA)")
+    if get("attention_bias", False):
+        refuse("attention_bias", "the latent projections carry no bias")
+    if get("rope_scaling") is not None:
+        refuse("rope_scaling", "scaled RoPE (YaRN and its softmax-scale "
+               "correction) is not implemented")
+    if get("router_bias", False):
+        refuse("router_bias", "the router's classifier carries no bias of its own; "
+               "e_score_correction_bias enters the choice only")
+    _refuse_router_variants(get, refuse, scoring="softmax")
+    if get("norm_topk_prob", False):
+        refuse("norm_topk_prob", "the chosen scores are weights as they are "
+               "(times routed_scaling_factor); renormalising them is not implemented")
+    if not get("q_lora_rank"):
+        refuse("q_lora_rank", "the query is projected from its own normed latent")
+    hidden, q_rank, kv_rank = (int(get(k)) for k in (
+        "hidden_size", "q_lora_rank", "kv_lora_rank"))
+    held = int(get("n_routed_experts") or 0)
+    published = dict((get("share") or {}).get("published") or {})
+    width = int(published.get("n_routed_experts", held))
+    return dict(
+        num_layers=int(get("num_layers")),
+        intermediate_size=int(get("ffn_hidden_size")),
+        shortcut_moe=True, router_softmax=True,
+        kv_lora_rank=kv_rank, q_lora_rank=q_rank,
+        qk_nope_head_dim=int(get("qk_nope_head_dim")),
+        qk_rope_head_dim=int(get("qk_rope_head_dim")),
+        v_head_dim=int(get("v_head_dim")),
+        latent_q_scale=(hidden / q_rank) ** 0.5 if get("mla_scale_q_lora", False) else 1.0,
+        latent_kv_scale=(hidden / kv_rank) ** 0.5 if get("mla_scale_kv_lora", False) else 1.0,
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
+        zero_experts=int(get("zero_expert_num") or 0),
+        experts_per_token=int(get("moe_topk")),
+        moe_intermediate_size=int(get("expert_ffn_hidden_size")),
+        norm_topk_prob=False,
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+    )
 
 
 def _delta_moe_fields(get) -> dict:
@@ -1429,6 +1543,19 @@ TINY_CCA = ModelConfig(
     n_routed_experts=4, experts_per_token=1, moe_intermediate_size=32,
 )
 
+# a shortcut-connected expert model at a size the CPU tests run (LongCat-Flash's
+# shape): two published layers of two latent sublayers each, 2 of 8 experts a
+# chip of 4 and 4 that compute nothing behind a softmax router of 12, 3 a token
+TINY_SCMOE = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
+    num_heads=4, num_kv_heads=4, head_dim=24, rope_theta=10000000.0,
+    rms_norm_eps=1e-5, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, shortcut_moe=True, router_softmax=True,
+    zero_experts=4, n_routed_experts=2, router_experts=8, experts_per_token=3,
+    moe_intermediate_size=32, norm_topk_prob=False, routed_scaling_factor=6.0,
+    latent_q_scale=(64 / 48) ** 0.5, latent_kv_scale=2 ** 0.5,
+)
+
 QWEN2_0_5B = ModelConfig(
     vocab_size=151936, hidden_size=896, intermediate_size=4864, num_layers=24,
     num_heads=14, num_kv_heads=2, head_dim=64, rope_theta=1000000.0,
@@ -1486,6 +1613,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-swa-sink-moe": TINY_SWA_SINK_MOE,
     "tiny-dsa": TINY_DSA,
     "tiny-cca": TINY_CCA,
+    "tiny-scmoe": TINY_SCMOE,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

@@ -65,6 +65,26 @@ group's rows share is fetched once, ``ops/latent_attention.py``). With
 layer is told which experts this program holds (``cfg.held_experts``) like the
 families below.
 
+**A shortcut-connected expert layer** (``cfg.shortcut_moe``: ``longcat_flash``,
+LongCat-Flash-Chat) is two more kinds, the two SUBLAYERS of one published
+layer, "latent_fork" then "latent_join" (``n_k``, ``m_k`` the norms before
+sublayer ``k``'s attention and MLP)::
+
+    fork:  a = x + MLA_0(n_0 x);  u = m_0 a;  e = Experts(u);  b = a + MLP_0(u)
+    join:  c = b + MLA_1(n_1 b);  y = c + MLP_1(m_1 c) + e
+
+Each sublayer has a stack, a page pool and adapters of its own, like any layer
+(``cfg.layer_kinds`` counts sublayers: a cache holds ``2 x num_layers`` latent
+arrays), and the layer loop carries ``(x, e)`` for this family alone, as it
+carries the MLP router's value for ``zaya``: the experts run ONCE, where the
+fork has their input, and what they gave rides to the join. ``Experts`` is a
+softmax router over the routed experts and ``cfg.zero_experts`` outputs that
+compute nothing (``models/moe.py``); no shared expert. The normed query latent
+is multiplied by ``cfg.latent_q_scale`` and the normed KV latent by
+``cfg.latent_kv_scale`` before their up-projections (the cached row holds the
+scaled latent; the rotary key is not scaled). ``moe_zero`` [1] int32 counts
+the pairs that chose an expert that computes nothing.
+
 **A learned index over tokens** (``cfg.index_topk``: ``glm_moe_dsa``, GLM-5)
 stands beside latent attention in every layer (``ops/token_index.py``)::
 
@@ -227,7 +247,7 @@ import jax
 import jax.numpy as jnp
 
 from distrl_llm_tpu import telemetry
-from distrl_llm_tpu.models.configs import ModelConfig, mixer_of
+from distrl_llm_tpu.models.configs import SHORTCUT_KINDS, ModelConfig, mixer_of
 from distrl_llm_tpu.models.transformer import (
     _head, _init_around_layers, _init_layer_stack, _mlp_half, _normal_init, _proj,
     _slice_layer, apply_rope, rms_norm, rope_cos_sin,
@@ -292,7 +312,9 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             p["o_norm"] = jnp.ones((n, q_dim), dtype)
         return p
 
-    def latent_stack(n: int, moe: bool) -> Params:
+    def latent_stack(n: int, moe: bool, f: int) -> Params:
+        """``f``: the width of the gated MLP under the MLP's names (the dense
+        MLP, or the shared expert beside routed experts; 0 = none)."""
         d, heads, experts = cfg.hidden_size, cfg.num_heads, cfg.n_routed_experts
         p = {
             "attn_norm": jnp.ones((n, d), dtype),
@@ -305,8 +327,6 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
                            heads * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
             "wo": init((n, heads * cfg.v_head_dim, d)),
         }
-        # the dense MLP, or the shared expert: one gated MLP under these names
-        f = cfg.shared_expert_size if moe else cfg.intermediate_size
         if f:
             p.update(w_gate=init((n, d, f)), w_up=init((n, d, f)),
                      w_down=init((n, f, d)))
@@ -448,9 +468,15 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
             "experts_up": init((n, experts, d, fm)),
             "experts_down": init((n, experts, fm, d)),
         }
-    for kind in ("latent", "latent_moe"):
+    for kind, moe, f in (
+            ("latent", False, cfg.intermediate_size),
+            ("latent_moe", True, cfg.shared_expert_size),
+            # a shortcut-connected layer's sublayers: a dense MLP in both, the
+            # experts in the first's stack
+            ("latent_fork", True, cfg.intermediate_size),
+            ("latent_join", False, cfg.intermediate_size)):
         if cfg.kind_count(kind):
-            layers[kind] = latent_stack(cfg.kind_count(kind), kind == "latent_moe")
+            layers[kind] = latent_stack(cfg.kind_count(kind), moe, f)
     if cfg.kind_count("sparse"):
         layers["sparse"] = stack(
             cfg.kind_count("sparse"), cfg.q_dim, cfg.kv_dim, cfg.head_dim,
@@ -486,6 +512,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
                  "moe_blocks": jnp.zeros((2,), jnp.int32)}
         if cfg.held_experts is not None:  # the pairs chosen over ALL experts
             state["moe_routed"] = jnp.zeros((1,), jnp.int32)
+        if cfg.zero_experts:  # and those of them that chose one that computes nothing
+            state["moe_zero"] = jnp.zeros((1,), jnp.int32)
         state["index_stats" if cfg.index_topk else "latent_stats"] = jnp.zeros(
             (2,), jnp.int32)
         return state
@@ -665,8 +693,8 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
            env: dict, lora_scale: float, lora_dropout: float, dropout_rng):
     """One layer of any kind: (x, new cache pieces, stats)."""
     proj = partial(_proj, lora_dropout=lora_dropout, dropout_rng=dropout_rng)
-    if kind in ("latent", "latent_moe"):
-        return _latent_block(x, p, lora, cache, moe=kind == "latent_moe", cfg=cfg,
+    if cfg.latent:  # "latent", "latent_moe", or a shortcut-connected layer's sublayer
+        return _latent_block(x, p, lora, cache, kind=kind, cfg=cfg,
                              mode=mode, env=env, proj=proj, lora_scale=lora_scale)
     mixer = mixer_of(kind)
     if mixer in _MIXER_CACHE:
@@ -1315,12 +1343,18 @@ def _latent_mix(q_nope, q_pe, c, k_pe, pages, p, lora, *, cfg, mode, env, proj,
             q_nope, q_pe, block, start, cfg.v_head_dim, c.dtype, chosen), pages, key_pages
 
 
-def _latent_block(x, p, lora, cache, *, moe: bool, cfg: ModelConfig, mode: str,
+def _latent_block(x, p, lora, cache, *, kind: str, cfg: ModelConfig, mode: str,
                   env: dict, proj, lora_scale: float):
-    """One latent-attention layer with a dense MLP or routed experts:
+    """One latent-attention layer with a dense MLP ("latent") or routed
+    experts ("latent_moe"), or one SUBLAYER of a shortcut-connected layer
+    ("latent_fork", "latent_join": module docstring; ``x`` and what is
+    returned in its place are then ``(the stream, the layer's experts' part)``):
     (x, the layer's page array, the expert layer's stats or None). Where the
     model has an index, ``cache`` and what is returned in its place are the
     pair (latent pages, index-key pages)."""
+    carried = None
+    if kind in SHORTCUT_KINDS:
+        x, carried = x
     b, s, _ = x.shape
     heads, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     pages, key_pages = cache if cfg.index_topk and cache is not None else (cache, None)
@@ -1330,9 +1364,13 @@ def _latent_block(x, p, lora, cache, *, moe: bool, cfg: ModelConfig, mode: str,
         if "wq_a" in p:  # the query's own normed latent
             c_q = rms_norm(proj(h, p, lora, "wq_a", "bq_a", lora_scale), p["q_a_norm"],
                            cfg.rms_norm_eps)
+            if cfg.latent_q_scale != 1.0:
+                c_q = c_q * jnp.asarray(cfg.latent_q_scale, c_q.dtype)
         q = proj(c_q, p, lora, "wq", "bq", lora_scale).reshape(b, s, heads, cfg.head_dim)
         kva = proj(h, p, lora, "wkv_a", "bkv_a", lora_scale)
         c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
+        if cfg.latent_kv_scale != 1.0:  # the row that is cached holds the scaled latent
+            c = c * jnp.asarray(cfg.latent_kv_scale, c.dtype)
     with jax.named_scope(telemetry.MODEL_ATTN_CORE):
         q_pe = rope_interleaved(q[..., nope:], env["cos"], env["sin"])
         k_pe = rope_interleaved(kva[..., rank:], env["cos"], env["sin"])
@@ -1348,10 +1386,20 @@ def _latent_block(x, p, lora, cache, *, moe: bool, cfg: ModelConfig, mode: str,
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         x = x + proj(o.reshape(b, s, heads * cfg.v_head_dim), p, lora, "wo", "bo",
                      lora_scale)
-    if not moe:
-        return _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale), pages, None
-    x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj, lora_scale=lora_scale)
-    return x, pages, stats
+    if kind == "latent_moe":
+        x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
+                                lora_scale=lora_scale)
+        return x, pages, stats
+    stats = None
+    if kind == "latent_fork":  # the experts read what this sublayer's MLP reads
+        with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
+            u = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        carried, stats = moe_half(u, p, cfg, held=cfg.held_experts,
+                                  alive=env.get("alive"))
+    x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
+    if kind == "latent_join":  # ... and join the stream after the second MLP
+        x = x + carried
+    return (x if carried is None else (x, carried)), pages, stats
 
 
 def _shared_layers(block):
@@ -1466,11 +1514,14 @@ def forward_hybrid(
             x = x * jnp.asarray(cfg.scale_emb, x.dtype)
     if cfg.cca:  # the layer loop's carry: the stream, and the router's r_{l-1}
         x = (x, jnp.zeros((b, s, cfg.router_hidden_size), jnp.float32))
+    if cfg.shortcut_moe:  # the stream, and what the layer's experts gave
+        x = (x, jnp.zeros_like(x))
 
     rates = (jnp.asarray(cfg.lightning_decay_rates())
              if cfg.kind_count("lightning") else None)
     use_dropout = dropout_rng is not None and lora_dropout > 0.0
-    layer_keys = jax.random.split(dropout_rng, cfg.num_layers) if use_dropout else None
+    layer_keys = (jax.random.split(dropout_rng, len(cfg.layer_kinds))
+                  if use_dropout else None)
     block = partial(
         _block, cfg=cfg, mode=mode, env=env, lora_scale=lora_scale,
         lora_dropout=lora_dropout if use_dropout else 0.0,
@@ -1481,20 +1532,28 @@ def forward_hybrid(
         block = _shared_layers(block)
 
     if mode == "full":
-        for kind, first, at, count in cfg.layer_runs:
+        runs = cfg.layer_runs
+        if cfg.shortcut_moe:  # ONE scan over the published layers, a step both sublayers
+            runs = ((SHORTCUT_KINDS, 0, 0, cfg.num_layers),)
+        for kinds, first, at, count in runs:
+            kinds = kinds if isinstance(kinds, tuple) else (kinds,)
             take = lambda tree: jax.tree_util.tree_map(
                 lambda w: w[at: at + count], tree)
-            xs = (
+            keys = None
+            if use_dropout:  # a key a sublayer: [count, len(kinds), ...]
+                keys = layer_keys[first: first + count * len(kinds)].reshape(
+                    count, len(kinds), *layer_keys.shape[1:])
+            xs = tuple((
                 take(stacks[kind]),
                 take(lora_stacks[kind]) if kind in lora_stacks else None,
                 rates[at: at + count] if kind == "lightning" else None,
-                layer_keys[first: first + count] if use_dropout else None,
-            )
+                keys[:, j] if use_dropout else None,
+            ) for j, kind in enumerate(kinds))
 
-            def body(x, xs, kind=kind):
-                p, lora_p, rate, key = xs
-                return block(x, p, lora_p, rate, None, kind=kind,
-                             dropout_rng=key)[0], None
+            def body(x, xs, kinds=kinds):
+                for kind, (p, lora_p, rate, key) in zip(kinds, xs):
+                    x = block(x, p, lora_p, rate, None, kind=kind, dropout_rng=key)[0]
+                return x, None
 
             if remat:
                 # keeps a layer's input and nothing else, whatever policy the
@@ -1507,7 +1566,7 @@ def forward_hybrid(
                 body = jax.checkpoint(
                     body, policy=jax.checkpoint_policies.nothing_saveable)
             x, _ = jax.lax.scan(body, x, xs)
-        if cfg.cca:
+        if cfg.cca or cfg.shortcut_moe:
             x = x[0]
         with jax.named_scope(telemetry.MODEL_HEAD):
             # back to the caller's columns before it slices the positions it wants
@@ -1523,7 +1582,7 @@ def forward_hybrid(
     stats = kv_cache.get("sel_stats")
     # an expert layer's four (``moe_half``): the pairs' two, the blocks' two; a
     # prefill's cache carries the blocks alone
-    moe_stats = (jnp.zeros((4,), jnp.int32)
+    moe_stats = (jnp.zeros((4 + bool(cfg.zero_experts),), jnp.int32)
                  if "moe_stats" in kv_cache or "moe_blocks" in kv_cache else None)
     at = dict.fromkeys(cfg.layer_kinds, 0)
     held_at = dict.fromkeys(_MIXER_CACHE, 0)  # a mixer's layers, whatever follows them
@@ -1574,14 +1633,15 @@ def forward_hybrid(
             x, new["lin"][j], _ = block(
                 x, p, lora_p, rates[j], new["lin"][j], kind=kind,
                 dropout_rng=layer_keys[i] if use_dropout else None)
-    if cfg.cca:
+    if cfg.cca or cfg.shortcut_moe:
         x = x[0]
     with jax.named_scope(telemetry.MODEL_HEAD):
         logits = _head(x, params, cfg, logits_slice, logits_positions, skip_lm_head)
     out = {**kv_cache, **{name: tuple(vals) for name, vals in new.items()}}
     if stats is not None:
         out["sel_stats"] = stats
-    for name, part in (("moe_stats", slice(0, 2)), ("moe_blocks", slice(2, 4))):
+    for name, part in (("moe_stats", slice(0, 2)), ("moe_blocks", slice(2, 4)),
+                       ("moe_zero", slice(4, 5))):
         if name in kv_cache:
             out[name] = kv_cache[name] + moe_stats[part]
     if "moe_routed" in kv_cache:  # the router's choices over ALL experts: live tokens x k a layer
@@ -1610,5 +1670,5 @@ def forward_hybrid(
                 jnp.stack([units(jnp.minimum(keys, most)), units(keys)]))
     if "latent_stats" in kv_cache and mode == "decode":  # every layer walks alike
         out["latent_stats"] = (
-            kv_cache["latent_stats"] + cfg.num_layers * env["page_walk"][1].stats)
+            kv_cache["latent_stats"] + cfg.paged_layers * env["page_walk"][1].stats)
     return logits, out

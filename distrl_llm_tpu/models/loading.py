@@ -16,7 +16,7 @@ from typing import Any, Callable, Mapping
 
 import numpy as np
 
-from distrl_llm_tpu.models.configs import ModelConfig
+from distrl_llm_tpu.models.configs import SHORTCUT_KINDS, ModelConfig
 
 Params = dict[str, Any]
 
@@ -75,6 +75,8 @@ _SKIPPED_PREFIXES = ("vision_tower.", "multi_modal_projector.")
 def _latent_layer_names(cfg: ModelConfig, kind: str) -> dict[str, Any]:
     """Leaf -> the layer's checkpoint name (a list over experts for an expert
     stack), for one layer of ``kind``."""
+    if kind in SHORTCUT_KINDS:
+        return _shortcut_layer_names(cfg, SHORTCUT_KINDS.index(kind))
     names: dict[str, Any] = dict(_HF_LATENT_MAP)
     if cfg.q_lora_rank:  # deepseek_v3's low-rank query path: ``wq`` is q_b_proj
         names.update(wq="self_attn.q_b_proj.weight", wq_a="self_attn.q_a_proj.weight",
@@ -90,6 +92,38 @@ def _latent_layer_names(cfg: ModelConfig, kind: str) -> dict[str, Any]:
         names["experts" + k[1:]] = [
             f"mlp.experts.{e}.{v}.weight" for e in range(cfg.n_routed_experts)]
     return names
+
+
+def _shortcut_layer_names(cfg: ModelConfig, k: int) -> dict[str, Any]:
+    """``_latent_layer_names`` for SUBLAYER ``k`` of a ``longcat_flash`` layer:
+    the published layer holds its two attentions, four norms and two dense MLPs
+    in lists (``self_attn.<k>``, ``input_layernorm.<k>``, ``mlps.<k>``), and one
+    expert block (``mlp.router``, ``mlp.experts.<e>``) that sublayer 0's stack
+    keeps. Its zero-compute experts hold no tensor."""
+    names: dict[str, Any] = {
+        "attn_norm": f"input_layernorm.{k}.weight",
+        "mlp_norm": f"post_attention_layernorm.{k}.weight",
+        "wq_a": f"self_attn.{k}.q_a_proj.weight",
+        "q_a_norm": f"self_attn.{k}.q_a_layernorm.weight",
+        "wq": f"self_attn.{k}.q_b_proj.weight",
+        "wkv_a": f"self_attn.{k}.kv_a_proj_with_mqa.weight",
+        "kv_a_norm": f"self_attn.{k}.kv_a_layernorm.weight",
+        "wkv_b": f"self_attn.{k}.kv_b_proj.weight",
+        "wo": f"self_attn.{k}.o_proj.weight",
+        **{key: f"mlps.{k}.{v}.weight" for key, v in _HF_LATENT_MLP.items()},
+    }
+    if k == 0:
+        names.update(router="mlp.router.classifier.weight",
+                     e_score_bias="mlp.router.e_score_correction_bias")
+        for key, v in _HF_LATENT_MLP.items():
+            names["experts" + key[1:]] = [
+                f"mlp.experts.{e}.{v}.weight" for e in range(cfg.n_routed_experts)]
+    return names
+
+
+def _published_layer(cfg: ModelConfig, i: int) -> int:
+    """The published layer that entry ``i`` of ``cfg.layer_kinds`` lies in."""
+    return i // len(SHORTCUT_KINDS) if cfg.shortcut_moe else i
 
 
 def _is_matrix(key: str) -> bool:
@@ -123,7 +157,7 @@ def _latent_params_from_state_dict(sd, cfg: ModelConfig, dtype) -> Params:
             per_layer = [
                 np.stack([take(f"model.layers.{i}.{n}") for n in name])
                 if isinstance(name, list) else take(f"model.layers.{i}.{name}")
-                for i in at
+                for i in (_published_layer(cfg, i) for i in at)
             ]
             out = np.stack(per_layer).astype(dtype)
             layers[kind][key] = out.swapaxes(-1, -2) if _is_matrix(key) else out
@@ -314,7 +348,7 @@ def state_dict_from_params(params: Params, cfg: ModelConfig) -> dict[str, np.nda
                 stacked = np.asarray(stacked)
                 if _is_matrix(key):
                     stacked = stacked.swapaxes(-1, -2)
-                for j, i in enumerate(at):
+                for j, i in enumerate(_published_layer(cfg, i) for i in at):
                     each = names[key] if isinstance(names[key], list) else None
                     for e, name in enumerate(each or [names[key]]):
                         sd[f"model.layers.{i}.{name}"] = np.ascontiguousarray(
@@ -377,6 +411,7 @@ def save_hf_checkpoint(
         "mistral": "MistralForCausalLM",
         "gemma": "GemmaForCausalLM",
         "deepseek_v3": "DeepseekV3ForCausalLM",
+        "longcat_flash": "LongcatFlashForCausalLM",
     }.get(model_type, "LlamaForCausalLM")
     hf_cfg = {
         "model_type": model_type,
@@ -394,7 +429,28 @@ def save_hf_checkpoint(
         "max_position_embeddings": cfg.max_position_embeddings,
         "torch_dtype": torch_dtype,
     }
-    if cfg.latent:
+    if cfg.shortcut_moe:  # the family's own names for depth, widths and router
+        for key in ("head_dim", "num_hidden_layers", "intermediate_size",
+                    "num_key_value_heads"):
+            del hf_cfg[key]
+        hf_cfg.update(
+            num_layers=cfg.num_layers, ffn_hidden_size=cfg.intermediate_size,
+            expert_ffn_hidden_size=cfg.moe_intermediate_size,
+            kv_lora_rank=cfg.kv_lora_rank, q_lora_rank=cfg.q_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+            mla_scale_q_lora=cfg.latent_q_scale != 1.0,
+            mla_scale_kv_lora=cfg.latent_kv_scale != 1.0,
+            n_routed_experts=cfg.n_routed_experts, moe_topk=cfg.experts_per_token,
+            zero_expert_num=cfg.zero_experts, zero_expert_type="identity",
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            attention_method="MLA",
+        )
+        if cfg.router_experts:  # one chip's share of the routed experts
+            hf_cfg.update(expert_shard=cfg.expert_shard, share={
+                "chips_per_layer": cfg.router_experts // cfg.n_routed_experts,
+                "published": {"n_routed_experts": cfg.router_experts}})
+    elif cfg.latent:
         del hf_cfg["head_dim"]  # the query head is nope + rope
         hf_cfg.update(
             kv_lora_rank=cfg.kv_lora_rank, q_lora_rank=cfg.q_lora_rank or None,

@@ -126,6 +126,9 @@ def init_lora_params(
         per_kind = {
             "latent": dims,
             "latent_moe": {**dims, "intermediate_size": cfg.shared_expert_size},
+            # a shortcut-connected layer's two sublayers: each its own attention
+            # and its own dense MLP; the router and the routed experts are frozen
+            "latent_fork": dims, "latent_join": dims,
         }
     elif cfg.delta_moe:
         # q, k, v, o of both mixers and the shared expert's three; the router,

@@ -64,6 +64,21 @@ second routing function: ONE expert a token, weighted by its probability::
     e   = argmax(p + beta), the lower index among equals;   y = p_e E_e(h)
 
 The experts' side is ``routed_experts`` as it stands, ``k = 1``.
+
+**A softmax router over experts of which some compute nothing**
+(``longcat_flash``: ``cfg.router_softmax``, ``cfg.zero_experts``) is ``route``
+with the softmax in the sigmoid's place over ``cfg.router_width`` outputs, the
+routed experts' ids first and ``zero_experts`` further ids after them, and the
+chosen scores as weights unnormalised (``norm_topk_prob`` false)::
+
+    s = softmax(h W_r);  idx = top-k of (s + b);  w = routed_scaling_factor * s[idx]
+    y = sum_{k: idx_k routed, held here} w_k E_idx_k(h) + (sum_{k: idx_k zero} w_k) * h
+
+A zero-compute choice is an identity: it costs no product, belongs to no
+block of either form (``_local_ids`` sends it where the pairs held elsewhere
+go) and is added WHOLE here whatever share of the routed experts is held, as
+a shared expert is (``zero_part``; counted apart, the fifth of ``moe_half``'s
+stats).
 """
 
 from __future__ import annotations
@@ -81,6 +96,13 @@ NORM_EPS = 1e-20
 DENSE_MAX_TOKENS = 128
 #: and where a token has ONE choice (timed at 192 x 1 over 16: module docstring)
 DENSE_MAX_TOKENS_TOP1 = 192
+#: pairs one call of the grouped form lays out at most: a call of more tokens
+#: runs in equal runs of tokens, one after another (``moe_half``). The form's
+#: buffers are sized by ALL the pairs, whoever holds their experts (a padded row
+#: of the hidden width a pair, and the same again gathered back): 2.5 GB each at
+#: 16 x 1,024 tokens x 12 choices of 6,144, where 1 pair in 48 is held here.
+#: No call of a cell that was there before this line has more (65,536 at most)
+GROUPED_MAX_PAIRS = 65536
 
 
 def route(h: jax.Array, router: jax.Array, bias: jax.Array, cfg: ModelConfig):
@@ -88,10 +110,12 @@ def route(h: jax.Array, router: jax.Array, bias: jax.Array, cfg: ModelConfig):
     choice and the weights are float32 at full precision: with bf16 scores two
     experts tie often, and the choice is not continuous."""
     with jax.named_scope(telemetry.MODEL_MOE_ROUTER):
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             h.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
-        ))
+        )
+        scores = (jax.nn.softmax(logits, axis=-1) if cfg.router_softmax
+                  else jax.nn.sigmoid(logits))
         _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
                                cfg.experts_per_token)
         w = jnp.take_along_axis(scores, idx, axis=-1)
@@ -222,24 +246,54 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
     return y, load, jnp.stack([live.sum(dtype=jnp.int32), jnp.int32(blocks)])
 
 
+def zero_part(h: jax.Array, idx: jax.Array, w: jax.Array, first: int, alive=None):
+    """The experts that compute nothing (ids ``first`` and up; module
+    docstring): ``(sum of a token's weights on them) * h`` and the pairs that
+    chose one (of ``alive`` tokens, if given). ``h [T, D]``, ``idx`` / ``w [T, k]``."""
+    with jax.named_scope(telemetry.MODEL_MOE_ZERO):
+        chose = idx >= first
+        weight = jnp.where(chose, w, 0.0).sum(axis=-1, keepdims=True)
+        pairs = chose.sum(axis=-1, dtype=jnp.int32)
+        if alive is not None:
+            pairs = pairs * alive.astype(jnp.int32)
+        return (weight * h.astype(jnp.float32)).astype(h.dtype), pairs.sum()
+
+
 def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
              choice=None):
     """The routed part of an expert layer on ``h [..., D]`` (normed). Returns
     ``(y like h, stats [4] int32)``: pairs computed, the fullest expert's, and
-    the grouped form's blocks that ran and that were laid (``routed_experts``).
+    the grouped form's blocks that ran and that were laid (``routed_experts``);
+    where the router has zero-compute outputs their part is in ``y`` and the
+    pairs that chose one are a fifth entry (``zero_part``).
     ``p["experts_layer"]``, if there, says that ``p["experts_*"]`` are every
     layer's and which is this one (``routed_experts``). ``choice`` is ``(idx,
     w)`` where the caller's own router chose (``route_mlp``)."""
     lead = h.shape[:-1]
     flat = h.reshape(-1, h.shape[-1])
     idx, w = choice or route(flat, p["router"], p["e_score_bias"], cfg)
-    y, load, blocks = routed_experts(
-        flat, idx, w,
-        {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]},
-        n_experts=cfg.router_width, held=held, layer=p.get("experts_layer"),
-        # ``alive`` is a flag a ROW: each of the row's tokens takes it
-        alive=None if alive is None else jnp.repeat(
-            alive, flat.shape[0] // alive.shape[0]),
-    )
+    # ``alive`` is a flag a ROW: each of the row's tokens takes it
+    alive = None if alive is None else jnp.repeat(alive, flat.shape[0] // alive.shape[0])
+    experts = {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]}
+
+    def some(tokens):
+        h_r, idx_r, w_r, alive_r = tokens
+        return routed_experts(
+            h_r, idx_r, w_r, experts, n_experts=cfg.router_width, held=held,
+            layer=p.get("experts_layer"), alive=alive_r)
+
+    t, k = idx.shape
+    runs = next(n for n in range(-(-t * k // GROUPED_MAX_PAIRS), t + 1) if t % n == 0)
+    if runs == 1:
+        y, load, blocks = some((flat, idx, w, alive))
+    else:  # equal runs of tokens, one after another: each lays out its own pairs
+        cut = lambda x: None if x is None else x.reshape(runs, t // runs, *x.shape[1:])
+        y, load, blocks = jax.lax.map(some, tuple(map(cut, (flat, idx, w, alive))))
+        y, load, blocks = y.reshape(t, -1), load.sum(0), blocks.sum(0)
+    zero_pairs = []
+    zero_experts = getattr(cfg, "zero_experts", 0)  # a bag of sizes may not state any
+    if zero_experts:
+        zero, pairs = zero_part(flat, idx, w, cfg.router_width - zero_experts, alive)
+        y, zero_pairs = y + zero, [pairs[None]]
     return y.reshape(*lead, -1), jnp.concatenate(
-        [jnp.stack([load.sum(), load.max()]), blocks])
+        [jnp.stack([load.sum(), load.max()]), blocks, *zero_pairs])

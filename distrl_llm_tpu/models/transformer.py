@@ -423,7 +423,8 @@ DECODE_VIEW_KEYS = frozenset(("wq", "wk", "wv", "wo"))
 # The kinds whose census differs: of a latent layer's projections ``wq`` alone
 # shows the two operations; its ``wo``, ``wkv_a`` and ``wkv_b`` show neither
 # and stay stacked.
-_KIND_VIEW_KEYS = {"latent": frozenset(("wq",)), "latent_moe": frozenset(("wq",)),
+_KIND_VIEW_KEYS = {**dict.fromkeys(("latent", "latent_moe", "latent_fork", "latent_join"),
+                                   frozenset(("wq",))),
                    # compressed convolutional attention: v is two halves
                    "cca": frozenset(("wq", "wk", "wv1", "wv2", "wo"))}
 

@@ -182,6 +182,9 @@ MODEL_MOE_ROUTER = "model/moe_router"
 MODEL_MOE_DISPATCH = "model/moe_dispatch"
 MODEL_MOE_EXPERTS = "model/moe_experts"
 MODEL_LATENT_ATTN = "model/latent_attn"
+# the experts that compute nothing (longcat_flash, models/moe.py::zero_part):
+# the sum of a token's weights on them times the layer's normed input
+MODEL_MOE_ZERO = "model/moe_zero"
 # a gated delta-rule model (solar_open2, ops/delta_attention.py): the
 # recurrence in both forms with its l2norm, decay and beta; the short
 # convolutions and their tail's update; both mixers' output gates and the
@@ -308,6 +311,7 @@ SCOPE_NAMES = (
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
     MODEL_SSM, MODEL_WINDOW_ATTN,
     MODEL_INDEX_SCORE, MODEL_INDEX_SELECT, MODEL_INDEXED_ATTN, MODEL_CCA_MIX,
+    MODEL_MOE_ZERO,
 )
 
 

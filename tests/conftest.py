@@ -66,6 +66,15 @@ jax.config.update("jax_platforms", "cpu")
 # it or clears every cache of the process, and what passes then depends on which
 # cases a worker was dealt before: more than a handful of files, and no way to
 # say which. Issue 62's rule for that outcome is to leave the flag out.
+# THE STEP BELOW IT WAS MEASURED AND REFUSED TOO (PR 65):
+# `--xla_backend_optimization_level=1` in `XLA_FLAGS`, for the whole run and every
+# process of it alike, so no case compiles with another's. A family's conformance
+# cases take 284 CPU-seconds where they take 333 (level 2: 314), the cases the
+# flag above failed pass, and the chip's compiler answers with the same programs
+# (thirteen of `tests/test_tpu_compile.py`'s: the same optimised HLO to the byte);
+# but `tests/test_learn_obs.py::TestDeviceBundle::test_armed_is_byte_identical_to_off`
+# fails in its first byte, every time: two programs that round alike at level 3 do
+# not at level 1. The suite holds bits, so it stays at the default.
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -111,8 +120,8 @@ def pytest_collection_modifyitems(items):
     compiles take 25-96 s each and no cache serves them; the file sorted 77th
     of 82, ``--dist load`` deals cases in collection order, and the driver's
     run ended with three workers inside one each while three idled. Then the
-    families' conformance module A FAMILY AT A TIME, so that a family's cases
-    are dealt to a worker together and share the engines it built
+    families' conformance module A FAMILY AT A TIME: a family is one unit of
+    ``unit_of`` below, its cases go to one worker and share the engines it built
     (``tests/family_suite.py::engine``). One stable sort on the file's name and
     the ``family`` parameter, the same in every worker, as xdist requires, and
     after pytest's own reordering (``trylast``)."""
@@ -125,3 +134,47 @@ def pytest_collection_modifyitems(items):
         return 2, ""
 
     items.sort(key=order)
+
+
+def unit_of(nodeid):
+    """WHAT ONE WORKER RUNS WHOLE: the cases that share what a process builds
+    once. The conformance module's unit is a family (its ``small_pieces``, its
+    weights, the engines of ``family_suite.engine``), named by the first word
+    of the case's id, which is the family's (``cca``, ``swa``: the controller
+    imports no family); every other file is one
+    unit (its module fixtures, ``shared_cell``'s runs of a tiny cell,
+    ``jax.jit``'s own cache of a file's programs). ``tests/test_tpu_compile.py``
+    too, though its real-size compiles share nothing: the TPU's compiler runs
+    on every core it finds, and six of them at once took 1,489 worker-seconds
+    where one after another, beside five workers of other files, take 737
+    (and the longest 289 s of ``CASE_LIMIT_S`` where it takes 147)."""
+    file, _, case = nodeid.partition("::")
+    if file.endswith("test_family_conformance.py") and "[" in case:
+        return f"{file}[{case.partition('[')[2].partition('-')[0]}]"
+    return file
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """WHO RUNS WHAT (PR 65). ``--dist load`` deals single cases to whichever
+    worker is free, so what a file builds once a PROCESS (an engine, a tiny
+    cell's run, a module's weights, every program ``jax.jit`` holds) was built
+    again in each worker a case of the file fell to (9,733 worker-seconds a
+    whole run, 7,722 with units, the same tree an hour apart; PERF.md has the
+    walls). Where ``--dist load`` is asked for (the driver's command, the
+    README's; any other mode is xdist's as it stands), xdist's own scope scheduler
+    with ``unit_of`` as the scope: a unit goes to one worker whole, units are
+    dealt in the collection's order (the long ones first, as ordered above; the
+    scheduler's own order, the units of most cases first, would deal the
+    real-size compiles last, six at once and the longest alone at the end)."""
+    from xdist.scheduler import LoadScopeScheduling
+
+    if config.getvalue("dist") != "load":
+        return None
+    config.option.loadscopereorder = False
+
+    class Units(LoadScopeScheduling):
+        def _split_scope(self, nodeid):
+            return unit_of(nodeid)
+
+    return Units(config, log)

@@ -6,8 +6,8 @@ its own mechanism. A case is generated for the families whose record has what
 it reads, and for no other: there is no ``skip`` here.
 
 ``tests/conftest.py`` orders this module a family at a time (a stable sort on
-the ``family`` parameter), so that a family's cases are dealt to a worker
-together: ``family`` and ``small_pieces`` are set up once a family, and the
+the ``family`` parameter) and hands a family's cases to ONE worker
+(``unit_of``): ``family`` and ``small_pieces`` are set up once a family, and the
 engines of ``family_suite.engine`` once a (family, scheduler, slots) a process.
 The cases that do not touch an engine come first in this file, so that they run
 before the family's ``small_pieces`` are in force, as they did in the family's

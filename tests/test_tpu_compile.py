@@ -958,6 +958,61 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
     assert memory.temp_size_in_bytes < 0.5e9
 
 
+def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
+    """``longcat-flash-ep32-L4.rollout-reasoning-zero-256``'s decode step (256
+    rows, a table of 20 pages, a rank-32 adapter) fed the decode view: four
+    published layers are EIGHT sublayers, each with a latent pool of its own
+    ``[pages, 128, 640]`` that is donated and written in place by a point
+    scatter (no copy of a pool), and each sublayer's attention is ONE Mosaic
+    launch under ``model/latent_attn`` (64 heads: the same
+    ``absorbed_decode_kernel`` as Kimi-VL's 16 and GLM-5's 64). 256 tokens are
+    past ``moe.DENSE_MAX_TOKENS``: the 16 held experts run in the grouped form,
+    a ``conditional`` a block, and the 3,072 pairs a layer of which a third
+    chose an expert that computes nothing are counted under ``model/moe_zero``.
+    0.13 GB of temporaries when this was written, beside 12.5 GB of arguments
+    (10.65 GB of weights with the view, 2.0 GB of pools)."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    cfg = _cell_config("longcat-flash-ep32-L4")
+    assert cfg.num_layers == 4 and cfg.paged_layers == 8
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 256, 128, jnp.bfloat16
+    width = (2048 + 512) // page
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pages = 16 * 16 + rows * 5 + 8
+    cache = {
+        "k": tuple(chip((pages, page, 640), bf) for _ in range(8)), "v": (),
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 8 and all(
+        "%absorbed_decode_kernel" in c and "model/latent_attn" in c for c in calls), calls[:2]
+    entry = text[text.index("ENTRY "):]
+    copies = [line.strip()[:160] for line in entry.splitlines()
+              if " copy(" in line and f"bf16[{pages},128,640]" in line.split("(")[0]]
+    assert not copies, copies
+    assert "conditional(" in text and "model/moe_zero" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 8 * pages * page * 640 * 2
+    assert memory.temp_size_in_bytes < 0.3e9
+
+
 def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip, monkeypatch):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through five
     layers of latent attention behind the index, a dense MLP and four expert
