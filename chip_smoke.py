@@ -261,9 +261,9 @@ def _shared_prefix_attention_case(key, *, rows, heads, nope, rope, v_dim, rank,
 
 
 def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
-    """An expert layer's routed part (bf16; ``tokens`` decides the form: every
-    expert on every token up to ``moe.DENSE_MAX_TOKENS``, single-expert blocks of
-    sorted pairs above) against every pair computed one expert at a time in
+    """An expert layer's routed part (bf16; ``moe.expert_form`` says the form
+    from the call's shapes: every expert on every token, or single-expert
+    blocks of sorted pairs) against every pair computed one expert at a time in
     float32; no pair may be dropped."""
     import jax
     import jax.numpy as jnp
@@ -295,7 +295,7 @@ def _expert_layer_case(key, *, tokens, hidden, width, experts, per_token):
     assert np.isfinite(err) and err < 5e-2, f"expert layer max|err| {err}"
     return {"max_abs_err": round(err, 5), "pairs": int(load.sum()),
             "fullest_expert": int(load.max()), "blocks_run_laid": blocks.tolist(),
-            "form": "dense" if tokens <= moe.DENSE_MAX_TOKENS else "grouped"}
+            "form": "grouped" if moe.expert_form(tokens, per_token, experts) else "dense"}
 
 
 def _delta_step_case(key, *, rows, heads, head_dim, steps=4):

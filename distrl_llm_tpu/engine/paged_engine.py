@@ -462,8 +462,8 @@ HYBRID_PREFILL_STAGES = 2
 
 #: the round's counters (``models/hybrid.py::init_mixer_state``) that a
 #: prefill's segments add to and hand on to the decode state: the blocks of the
-#: experts' grouped form, which a decode step of up to ``moe.DENSE_MAX_TOKENS``
-#: rows never lays
+#: experts' grouped form, which a decode step lays none of where
+#: ``moe.expert_form`` gives its rows the dense form (every cell's)
 PREFILL_COUNTERS = ("moe_blocks",)
 
 

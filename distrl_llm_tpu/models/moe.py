@@ -12,41 +12,38 @@ The shared expert is a plain gated MLP and goes through the decoder's own
 ``_mlp_half`` (``models/hybrid.py``); this module is the routed part.
 
 **Dropless.** There is no capacity: every (token, expert) pair is computed at
-any imbalance. Plain XLA throughout, in two forms chosen by the number of
-tokens (``DENSE_MAX_TOKENS``; ``DENSE_MAX_TOKENS_TOP1`` where a token has one
-choice), each timed on the v5e at the published widths before it was kept
-(PERF.md, PR 33 and PR 58):
+any imbalance. Plain XLA throughout, in two forms. ``expert_form`` chooses
+between them, and the grouped form's block, from the rows a HELD expert is
+given by the call, ``t * k / n_experts``: all of it static shapes (PERF.md,
+PR 33, PR 58 and PR 66 have the chip's times at seven cells' widths):
 
 * **grouped** (a prefill segment of 4,096 tokens, the learner with its
   backward): the pairs are sorted by expert and laid out so that every block
-  of ``block_rows`` rows belongs to ONE expert (a group is padded to whole
-  blocks: at most one block of padding an expert, whatever the imbalance); a
-  ``lax.scan`` over the blocks multiplies each by its expert's three matrices,
-  picked from the stack by a dynamic index that fuses into the products; the
-  results go back by one gather and a weighted sum over k. The scan steps
-  over ``pairs // block_rows + groups`` blocks, enough for any imbalance, and a
-  block does work only if it holds a pair of an expert held HERE (a
-  ``lax.cond`` a block, in forward and in reverse mode alike: the live branch
-  gathers the block's own rows and multiplies them, the other returns zeros
-  that no pair reads). The pairs held elsewhere sort last, so the live blocks
-  are a prefix: an eighth or a sixteenth of the blocks where a program holds
-  16 of 128 or of 256 experts (``blocks`` says how many). No scatter-add: the
-  combine is deterministic and its transpose cheap. ``lax.ragged_dot`` (XLA's
-  native grouped kernel) read the same at 4,096 tokens and 1.6x slower at 64,
+  of rows belongs to ONE expert (a group is padded to whole blocks: at most
+  one block of padding an expert, whatever the imbalance); a ``lax.scan`` over
+  the blocks multiplies each by its expert's three matrices, picked from the
+  stack by a dynamic index that fuses into the products; the results go back
+  by one gather and a weighted sum over k. The scan steps over ``pairs //
+  block + groups`` blocks, enough for any imbalance, and a block does work
+  only if it holds a pair of an expert held HERE (a ``lax.cond`` a block, in
+  forward and in reverse mode alike: the live branch gathers the block's own
+  rows and multiplies them, the other returns zeros that no pair reads). The
+  pairs held elsewhere sort last, so the live blocks are a prefix: an eighth
+  or a sixteenth of the blocks where a program holds 16 of 128 or of 256
+  experts (``blocks`` says how many). No scatter-add: the combine is
+  deterministic and its transpose cheap. ``lax.ragged_dot`` (XLA's native
+  grouped kernel) read the same at 4,096 tokens and 1.6x slower at 64,
   carries no scope name into the trace, and copies a layer sliced from the
   stack for its custom call; it was not kept.
-* **dense** (a decode step: 64 tokens x 6 choices touch all 64 experts): every
-  expert held runs on every token, one batched product an expert matrix, and a
-  combine matrix ``[T, E]``, zero outside the chosen k, weights the results.
-  The step must read every expert once in any case; this form reads them once
-  and nothing else (90% of the experts' bandwidth roofline where grouped
-  blocks of 64 rows read 62%), and its extra arithmetic is free under the
-  transfer up to about a hundred tokens. With ONE choice a token it holds
-  further (``DENSE_MAX_TOKENS_TOP1``): 192 tokens x 1 choice over 16 experts
-  of 2,048 x 2,048 are 19 grouped blocks of 64 rows, 19 reads of an expert's
-  matrices where 16 suffice, 15.99 ms a step of 20 layers where this form
-  takes 12.27 (80% of the experts' bandwidth roofline; blocks of 32, 128 and
-  256 rows took 17.57, 16.83 and 17.34: PERF.md, PR 58).
+* **dense** (a decode step: 64 tokens x 6 choices touch all 64 experts; 256
+  tokens x 12 choices over 768 outputs give each of 16 held experts 4 pairs):
+  every expert held runs on every token, one batched product an expert matrix,
+  and a combine matrix ``[T, E]``, zero outside the chosen k, weights the
+  results. The step must read every expert once in any case; this form reads
+  them once and nothing else (90% of the experts' bandwidth roofline where
+  grouped blocks of 64 rows read 62%): no sort, no scatter, no gather over
+  ALL the call's pairs, which is what the grouped form pays whoever holds
+  their experts.
 
 **Experts held.** ``held`` names the experts whose weights this program holds
 (``experts`` is stacked over them, in that order), the layer a chip of an
@@ -92,10 +89,6 @@ from distrl_llm_tpu.models.configs import ModelConfig
 
 #: added to the sum of the chosen scores before dividing (the published code's)
 NORM_EPS = 1e-20
-#: tokens up to which every expert runs on every token (module docstring)
-DENSE_MAX_TOKENS = 128
-#: and where a token has ONE choice (timed at 192 x 1 over 16: module docstring)
-DENSE_MAX_TOKENS_TOP1 = 192
 #: pairs one call of the grouped form lays out at most: a call of more tokens
 #: runs in equal runs of tokens, one after another (``moe_half``). The form's
 #: buffers are sized by ALL the pairs, whoever holds their experts (a padded row
@@ -146,12 +139,37 @@ def route_mlp(h: jax.Array, carried: jax.Array, p: dict, cfg: ModelConfig):
         return idx.astype(jnp.int32), jnp.take_along_axis(prob, idx, axis=-1), r
 
 
-def block_rows(pairs: int, groups: int) -> int:
-    """Rows of one block of the grouped form: the power of two at or above the
-    mean rows a group, within [64, 256] (timed at 384 and 24,576 pairs over 64
-    groups: 64 and 256 were the fastest of 16-128 and 128-512)."""
-    mean = max(pairs // max(groups, 1), 1)
-    return min(256, max(64, 1 << (mean - 1).bit_length()))
+def expert_form(t: int, k: int, n_experts: int) -> int:
+    """The form of ``routed_experts`` for a call of ``t`` tokens x ``k`` choices
+    over ``n_experts`` router outputs: the rows of one block of the grouped
+    form, or 0, the dense form. Both follow from the rows a HELD expert is
+    given, ``t * k / n_experts`` whoever holds the others:
+
+    * a block is the power of two above them (room for an uneven router: a
+      group that fills its block takes a second, and reads its expert again),
+      within [64, 256]: under 64 rows nothing is left to save, a block's time
+      is its expert's bytes, and past 256 the matrix unit's time is, so a
+      larger block only pads more (the fastest of 32-256 in 22 of 23 calls
+      timed, 4-384 rows an expert, experts all held or a share held alike,
+      the other within 1.3%: PERF.md, PR 66);
+    * the dense form is ONE block of ``t`` rows an expert with every token in
+      it: where that pads an expert by no more than the grouped form may (a
+      block of 256), it multiplies no more rows than the grouped form's worst
+      and lays nothing out. 64 x 6 over 64, 192 x 1 over 16 and 256 x 12 over
+      768 are dense by it, a segment of 1,024 tokens a row is grouped; timed,
+      the two forms cross at 320-430 tokens at five cells' widths.
+
+    A test that needs one form whatever the shapes puts its own rule here."""
+    given = -(-t * k // n_experts)
+    if t - given <= 256:
+        return 0
+    return min(256, max(64, 1 << given.bit_length()))
+
+
+def grouped_runs(t: int, k: int) -> int:
+    """The equal runs of tokens a call of ``t`` tokens x ``k`` choices is made
+    in: the fewest that divide ``t`` and lay ``GROUPED_MAX_PAIRS`` a run at most."""
+    return next(n for n in range(-(-t * k // GROUPED_MAX_PAIRS), t + 1) if t % n == 0)
 
 
 def _gated(x, gate, up, down):
@@ -174,7 +192,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
     Returns ``(y [T, D], load [n] int32, blocks [2] int32)``: ``load`` counts
     the pairs each held expert computed (of ``alive`` tokens, if given),
     ``blocks`` the grouped form's blocks that ran and that were laid (zeros
-    from the dense form).
+    from the dense form; ``expert_form`` says which).
 
     With ``layer`` the stacks are ALL layers' ``[L, n, ...]``: the grouped
     form indexes (layer, expert) in one step, so no layer's experts are
@@ -186,7 +204,8 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
         counted = jnp.ones((t,), jnp.int32) if alive is None else alive.astype(jnp.int32)
         load = jnp.zeros((n + 1,), jnp.int32).at[local.reshape(-1)].add(
             jnp.repeat(counted, k))[:n]
-    if t <= (DENSE_MAX_TOKENS_TOP1 if k == 1 else DENSE_MAX_TOKENS):
+    bm = expert_form(t, k, n_experts)
+    if not bm:
         if layer is not None:
             experts = {name: x[layer] for name, x in experts.items()}
         with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
@@ -200,7 +219,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
             y = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32)).astype(h.dtype)
         return y, load, jnp.zeros((2,), jnp.int32)
     groups = n + (held is not None)  # the pairs of experts held elsewhere: one more
-    rows_a, bm = t * k, block_rows(t * k, groups)
+    rows_a = t * k
     blocks = rows_a // bm + groups
     with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
         flat = local.reshape(rows_a)
@@ -283,7 +302,7 @@ def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
             layer=p.get("experts_layer"), alive=alive_r)
 
     t, k = idx.shape
-    runs = next(n for n in range(-(-t * k // GROUPED_MAX_PAIRS), t + 1) if t % n == 0)
+    runs = grouped_runs(t, k)
     if runs == 1:
         y, load, blocks = some((flat, idx, w, alive))
     else:  # equal runs of tokens, one after another: each lays out its own pairs

@@ -134,7 +134,7 @@ FAMILY = fs.Family(
     # shorter row rides two segments past its end, and a prompt's partial last
     # page is copied beside its tail. Decode rows dense, segments grouped.
     engine_pieces=((paged_engine, "HYBRID_PREFILL_SEGMENT", 16),
-                   (moe, "DENSE_MAX_TOKENS_TOP1", 8)),
+                   (moe, "expert_form", fs.expert_forms(8))),
     engine_kw={"max_new_tokens": 16}, lengths=(37, 57),
     refusals=(
         ({"sliding_window": 4096}, "sliding_window"),
@@ -273,10 +273,10 @@ def test_the_router_chooses_one_expert_the_lower_index_among_equals(weights, mon
             "e_score_bias": jnp.zeros_like(layer["e_score_bias"])}
     assert (np.asarray(moe.route_mlp(h, carried, flat, CFG)[0]) == 0).all()
     # both forms of the experts give the reference's part: 24 tokens of one
-    # choice are dense (up to ``DENSE_MAX_TOKENS_TOP1``), over it grouped
-    assert moe.DENSE_MAX_TOKENS_TOP1 == 192 > moe.DENSE_MAX_TOKENS == 128
+    # choice are dense by the rule (as the cell's 192 are), grouped when told
+    assert moe.expert_form(24, 1, 16) == moe.expert_form(192, 1, 16) == 0
     for most in (192, 8):
-        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS_TOP1", most)
+        monkeypatch.setattr(moe, "expert_form", fs.expert_forms(most))
         y, stats = moe.moe_half(h, layer, CFG, choice=(idx, w))
         np.testing.assert_allclose(y, ref.routed_part(h, prob, layer), atol=2e-5)
         assert int(stats[0]) == 24

@@ -61,7 +61,7 @@ def test_the_latent_and_expert_checks_hold_at_tiny_size(monkeypatch):
         key, rows=4, heads=4, nope=16, rope=8, v_dim=16, rank=32, latent_row=48,
         prompt=53, page=8, per=3, choose=0.4)  # under a drawn choice
     assert chosen["max_abs_err"] < 5e-2 and chosen["impl"] == "xla"
-    for tokens, form in ((24, "dense"), (160, "grouped")):
+    for tokens, form in ((24, "dense"), (1024, "grouped")):
         layer = chip_smoke._expert_layer_case(
             key, tokens=tokens, hidden=64, width=32, experts=8, per_token=2)
         assert layer["pairs"] == 2 * tokens and layer["form"] == form

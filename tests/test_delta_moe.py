@@ -116,7 +116,7 @@ FAMILY = fs.Family(
     weight_scale=3.0,
     # Eight tokens or fewer take the dense form (a decode step of 8 rows), more
     # the grouped one (a prefill segment, the learner's rows).
-    pieces=((moe, "DENSE_MAX_TOKENS", 8),),
+    pieces=((moe, "expert_form", fs.expert_forms(8)),),
     # Prefill in segments of 16 tokens (two pages of 8, scored a page at a time),
     # so that 40-57-token prompts cross every boundary the cell's 2,048-token
     # prompts cross: the state, the tail and the pages carried from segment to

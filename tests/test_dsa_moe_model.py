@@ -145,7 +145,7 @@ FAMILY = fs.Family(
         (paged_engine, "HYBRID_PREFILL_SEGMENT", 16),
         (hybrid, "LATENT_DECODE_PAGES", 3), (hybrid, "LATENT_DECODE_ROWS", 4),
         (latent_attention, "SHARED_SCORE_BYTES", 4 * 4 * 8 * 4 * 6),
-        (moe, "DENSE_MAX_TOKENS", 8)),
+        (moe, "expert_form", fs.expert_forms(8))),
     refusals=(
         ({"rope_scaling": {"rope_type": "yarn", "factor": 8}}, "rope_scaling"),
         ({"rope_parameters": {"rope_theta": 1e6, "rope_type": "yarn"}}, "rope_parameters"),

@@ -67,7 +67,8 @@ def small_pieces(monkeypatch):
     """Segments of 16 tokens in pages of 8 and every inner piece as small, so
     that 64-token prompts cross every boundary the cells' 20k-token ones do."""
     monkeypatch.setattr(paged_engine, "HYBRID_PREFILL_SEGMENT", SEGMENT)
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)  # a segment's tokens grouped
+    # a segment's tokens grouped
+    monkeypatch.setattr(moe, "expert_form", family_suite.expert_forms(8))
     monkeypatch.setattr(power_retention, "DEFAULT_CHUNK", SEGMENT)
     monkeypatch.setattr(selective_scan, "DEFAULT_CHUNK", SEGMENT)
 

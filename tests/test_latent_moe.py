@@ -58,7 +58,7 @@ FAMILY = fs.Family(
     # Eight tokens or fewer take the dense form (a decode step of 8 rows), more
     # the grouped one (a prefill segment, the learner's rows), as 128 divides
     # the 64-row decode step from the 4,096-token segment at the real size.
-    pieces=((moe, "DENSE_MAX_TOKENS", 8),),
+    pieces=((moe, "expert_form", fs.expert_forms(8)),),
     # Prefill in segments of 16 tokens and decode attention over 3 pages (6 where
     # a group shares them: the scores of 4 rows' heads over 6 pages of 8) and 4
     # rows at a time, so that 40-57-token prompts in pages of 8 cross every
@@ -776,7 +776,7 @@ def test_no_token_is_dropped_at_any_imbalance(weights, case, form, monkeypatch):
     """Dropless, in either form: 48 tokens that ALL choose the same two experts
     (six of the eight get none), a batch in which one expert gets none, and a
     drawn one lose nothing against the pairs computed one at a time."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 48)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(0 if form == "grouped" else 48))
     params, _ = weights
     layer = moe_layer(params)
     experts = {k: layer[f"experts_{k}"] for k in ("gate", "up", "down")}
@@ -802,7 +802,7 @@ def test_the_shares_of_an_expert_parallel_layer_sum_to_the_whole(weights, form, 
     the router scores all 8 and chooses among all; each share computes its own
     experts' part; with the shared expert counted ONCE the parts sum to the
     uncut reference's layer."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 40)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(0 if form == "grouped" else 40))
     params, _ = weights
     layer = moe_layer(params, 1)
     h = jax.random.normal(jax.random.PRNGKey(7), (40, CFG.hidden_size))
@@ -825,7 +825,7 @@ def test_the_shares_of_an_expert_parallel_layer_sum_to_the_whole(weights, form, 
 def test_a_layer_read_from_the_whole_stack_equals_its_slice(weights, form, monkeypatch):
     """The cache modes hand the experts' products every layer's stack and the
     layer's index: (layer, expert) is one index, no layer is sliced out first."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0 if form == "grouped" else 24)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(0 if form == "grouped" else 24))
     params, _ = weights
     stack = params["layers"]["latent_moe"]
     h = jax.random.normal(jax.random.PRNGKey(8), (24, CFG.hidden_size))

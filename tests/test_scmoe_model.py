@@ -125,7 +125,7 @@ FAMILY = fs.Family(
         (paged_engine, "HYBRID_PREFILL_SEGMENT", 16),
         (hybrid, "LATENT_DECODE_PAGES", 3), (hybrid, "LATENT_DECODE_ROWS", 4),
         (latent_attention, "SHARED_SCORE_BYTES", 4 * CFG.num_heads * 6 * 8 * 4),
-        (moe, "DENSE_MAX_TOKENS", 8)),
+        (moe, "expert_form", fs.expert_forms(8))),
     # every key the field function cannot honour, by name
     refusals=(
         ({"zero_expert_type": "copy"}, "zero_expert_type"),
@@ -291,7 +291,7 @@ def test_a_choice_that_computes_nothing_costs_no_block_and_is_counted_apart(
     """Every token sent to the experts of nothing (a bias no score outweighs):
     the block's output is (the sum of the weights) x u, no pair is computed, no
     block of the grouped form runs, and the fifth of the stats counts 3 a token."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 64 if form == "dense" else 8)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(64 if form == "dense" else 8))
     params, _ = weights
     layer = sublayer(params, "latent_fork", 0)
     layer["e_score_bias"] = jnp.where(jnp.arange(12) >= 8, 10.0, -10.0)
@@ -310,7 +310,7 @@ def test_a_call_of_more_pairs_than_one_layout_holds_runs_in_equal_runs_of_tokens
     prefill segment: 16 x 1,024 tokens x 12 choices) is cut into equal runs of
     tokens, one after another. Same values, same pairs, the fullest expert's
     load over the whole call; only the blocks laid differ (a run pads its own)."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(8))
     params, _ = weights
     layer = sublayer(params, "latent_fork", 1)
     u = jax.random.normal(jax.random.PRNGKey(11), (5, 8, 64))  # 40 tokens, 120 pairs
@@ -335,7 +335,7 @@ def test_the_shares_and_the_part_that_computes_nothing_add_up_to_the_whole_layer
     router's 8 routed experts: each chip's routed part (the program's is the
     reference's), summed, with the zero-compute part, both attentions and both
     MLPs counted ONCE, is the uncut reference's whole published layer."""
-    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 64 if form == "dense" else 8)
+    monkeypatch.setattr(moe, "expert_form", fs.expert_forms(64 if form == "dense" else 8))
     whole, _ = fs.seeded(FAMILY, UNCUT)
     fork, join = sublayer(whole, "latent_fork", 1), sublayer(whole, "latent_join", 1)
     x = jax.random.normal(jax.random.PRNGKey(7), (24, 64))

@@ -52,7 +52,7 @@ TOKEN_BYTES = 2 * (KEY_ROW + 16) * 4
 #: 24 is kept in 32 lanes, zeros after it, in the pages and in the rings, as the
 #: chip keeps 192 in 256. Decode rows dense, segments grouped.
 SMALL_PIECES = ((configs, "KEY_ROW_LANES", 16), (paged_engine, "HYBRID_PREFILL_SEGMENT", 12),
-                (moe, "DENSE_MAX_TOKENS", 8))
+                (moe, "expert_form", fs.expert_forms(8)))
 
 
 def _with_attention(monkeypatch, bend):

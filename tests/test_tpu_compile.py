@@ -451,13 +451,14 @@ def test_an_expert_layer_told_what_it_holds_compiles_inside_a_scan(chip):
         return jax.lax.scan(body, jnp.zeros((4,), jnp.int32), h)
 
     bf = jnp.bfloat16
-    compiled = jax.jit(scanned).lower(chip((3, 256, 64), bf), {
+    assert moe.expert_form(1024, cfg.experts_per_token, cfg.router_width)
+    compiled = jax.jit(scanned).lower(chip((3, 1024, 64), bf), {
         "router": chip((64, 16), bf), "e_score_bias": chip((16,), bf),
         "experts_gate": chip((2, 64, 32), bf), "experts_up": chip((2, 64, 32), bf),
         "experts_down": chip((2, 32, 64), bf)}).compile()
     assert "while" in compiled.as_text()
-    # 256 tokens a step are over ``DENSE_MAX_TOKENS``: the grouped form, whose
-    # blocks run behind a ``lax.cond`` each. It must reach the chip as a
+    # 1,024 tokens a step are the grouped form's by the rule (asserted above),
+    # whose blocks run behind a ``lax.cond`` each. It must reach the chip as a
     # conditional: a select would run both branches, every block's products
     assert " conditional(" in compiled.as_text()
 
@@ -965,13 +966,17 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
     ``[pages, 128, 640]`` that is donated and written in place by a point
     scatter (no copy of a pool), and each sublayer's attention is ONE Mosaic
     launch under ``model/latent_attn`` (64 heads: the same
-    ``absorbed_decode_kernel`` as Kimi-VL's 16 and GLM-5's 64). 256 tokens are
-    past ``moe.DENSE_MAX_TOKENS``: the 16 held experts run in the grouped form,
-    a ``conditional`` a block, and the 3,072 pairs a layer of which a third
-    chose an expert that computes nothing are counted under ``model/moe_zero``.
-    0.13 GB of temporaries when this was written, beside 12.5 GB of arguments
-    (10.65 GB of weights with the view, 2.0 GB of pools)."""
-    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    ``absorbed_decode_kernel`` as Kimi-VL's 16 and GLM-5's 64). 256 tokens x
+    12 choices over 768 outputs give a held expert 4 pairs: ``moe.expert_form``
+    says the DENSE form (each of the 16 held experts read once and run on every
+    row; the grouped form ran 16 blocks of 256 rows of padding and a sort
+    besides: PERF.md section 6, PR 66), so no ``conditional`` is left in the
+    step, and the 3,072 pairs a layer of which a third chose an expert that
+    computes nothing are counted under ``model/moe_zero``. 0.10 GB of
+    temporaries when this was written, well under the 3.5 GB the chip has left
+    beside 12.5 GB of arguments (10.65 GB of weights with the view, 2.0 GB of
+    pools)."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params, moe
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
 
@@ -1007,7 +1012,9 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
     copies = [line.strip()[:160] for line in entry.splitlines()
               if " copy(" in line and f"bf16[{pages},128,640]" in line.split("(")[0]]
     assert not copies, copies
-    assert "conditional(" in text and "model/moe_zero" in text
+    assert moe.expert_form(rows, cfg.experts_per_token, cfg.router_width) == 0
+    assert "conditional(" not in text and "model/moe_experts" in text
+    assert "model/moe_zero" in text
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 8 * pages * page * 640 * 2
     assert memory.temp_size_in_bytes < 0.3e9

@@ -109,7 +109,8 @@ FAMILY = fs.Family(
     # carried from segment to segment, a window that starts in the segment
     # before, the full layer over earlier segments' pages, a last segment that
     # is part padding. Decode rows dense, segments grouped.
-    engine_pieces=((paged_engine, "HYBRID_PREFILL_SEGMENT", 16), (moe, "DENSE_MAX_TOKENS", 8)),
+    engine_pieces=((paged_engine, "HYBRID_PREFILL_SEGMENT", 16),
+                   (moe, "expert_form", fs.expert_forms(8))),
     refusals=(
         ({"layer_types": ["sliding_attention", "chunked_attention"] * 24}, "layer_types"),
         ({"sliding_windows": [128] * 48}, "sliding_windows"),
