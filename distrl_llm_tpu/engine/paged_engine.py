@@ -342,16 +342,17 @@ def _record_fold_telemetry(cfg: ModelConfig, prompt_pages: int, page_size: int,
     the prefill was traced (0 where it took the XLA form). The first counter
     is a latent model's (every layer; K ``nope + rope`` wide), the second that
     of a model whose "softmax" and "cca" layers fold the rows' K/V pages
-    (``hybrid._segment_softmax``: a key ``key_row`` lanes wide, no rope part);
-    a model with neither files nothing. ``dtype`` is the activations', the
-    embedding's; ``segments`` the longest row's, where the stages end (every
-    segment of the prompt's width where it is not given)."""
+    (``hybrid._segment_softmax``: a key ``key_row`` lanes wide, no rope part)
+    or whose "sparse" layers do, under their choice (the same path, the same
+    record); a model with none of them files nothing. ``dtype`` is the
+    activations', the embedding's; ``segments`` the longest row's, where the
+    stages end (every segment of the prompt's width where it is not given)."""
     if cfg.latent:
         name, layers = telemetry.OPS_LATENT_KERNEL_FOLDS, cfg.paged_layers
         layout = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)
     else:
         name = telemetry.OPS_SOFTMAX_KERNEL_FOLDS
-        layers = cfg.mixer_count("softmax") + cfg.mixer_count("cca")
+        layers = sum(cfg.mixer_count(m) for m in ("softmax", "cca", "sparse"))
         layout = (cfg.key_row, 0, cfg.value_head_dim)
     if not layers:
         return

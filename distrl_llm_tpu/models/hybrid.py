@@ -36,8 +36,10 @@ published order: runs of like layers are scanned (no cache) or unrolled
   the chosen pages; lightning layers step their state.
 * a paged cache with ``"segment_start"``: one page-aligned SEGMENT of a prompt
   prefill, every row at the same offset. Sparse layers write the segment's
-  pages whole and attend over the row's pages gathered dense; lightning layers
-  run the segment chunked from the carried state. A prompt is prefilled
+  pages whole, choose once for the segment and attend over the row's pages a
+  segment's width of keys at a time, the choice the mask of the fold the
+  full-attention layers run (``_segment_softmax``); lightning layers run the
+  segment chunked from the carried state. A prompt is prefilled
   segment after segment (``engine/paged_engine.py``): a 20k-token prompt at
   once would need the MLP's activations and the attention scores for all of it.
 
@@ -271,7 +273,7 @@ from distrl_llm_tpu.ops.token_index import (
     chosen_mask, chosen_tokens, index_paged_scores, index_scores,
 )
 from distrl_llm_tpu.ops.sparse_attention import (
-    pool_keys, pooled_count, sparse_attend, sparse_decode, update_pooled,
+    pool_keys, pooled_count, segment_choice, sparse_attend, sparse_decode, update_pooled,
 )
 
 Params = dict[str, Any]
@@ -640,18 +642,23 @@ def _sparse_mix(q, k, v, cache, *, cfg, mode, env):
             q[:, 0], pages_k, pages_v, pooled, lengths, idx, cfg, alive=env["alive"]
         )
         return o[:, None], (pages_k, pages_v, pooled), stats
-    # one page-aligned segment of a prefill, every row at offset ``start``
-    b, s = q.shape[:2]
+    # one page-aligned segment of a prefill, every row at offset ``start``: the
+    # choice once for the segment, as the mask of the fold the full-attention
+    # layers run over the rows' pages (K is gathered dense for the selector's
+    # pooled keys alone; V never is)
+    s = q.shape[1]
     start = env["segment_start"]
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         dest = jax.lax.dynamic_slice_in_dim(idx, start // ps, s // ps, axis=1)
         pages_k = _write_segment_pages(pages_k, k, dest, ps)
         pages_v = _write_segment_pages(pages_v, v, dest, ps)
         ctx_k = gather_pages_dense(pages_k, idx, dtype=q.dtype)
-        ctx_v = gather_pages_dense(pages_v, idx, dtype=q.dtype)
     with jax.named_scope(telemetry.MODEL_SPARSE_SELECT):
         pooled = pool_keys(ctx_k, cfg, count=pooled.shape[1]).astype(pooled.dtype)
-    o = sparse_attend(q, ctx_k, ctx_v, pooled, env["q_pos"], cfg)
+        chosen = segment_choice(
+            q, pooled, env["q_pos"], cfg, idx.shape[1] * ps).astype(FOLD_MASK_DTYPE)
+    with jax.named_scope(telemetry.MODEL_SPARSE_ATTN):
+        o = _segment_softmax(q, pages_k, pages_v, idx, start, ps, chosen)
     return o, (pages_k, pages_v, pooled), None
 
 
@@ -779,7 +786,7 @@ def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale, carried=None):
     return (x if carried is None else (x, carried)), stats
 
 
-def _segment_softmax(q, pages_k, pages_v, idx, start, page_size: int):
+def _segment_softmax(q, pages_k, pages_v, idx, start, page_size: int, chosen=None):
     """A prefill segment's causal attention over the rows' PAGES (the
     segment's own are written already), a segment's width of keys at a time
     under a running softmax: the scores of all of a 20k-token context at once
@@ -789,7 +796,12 @@ def _segment_softmax(q, pages_k, pages_v, idx, start, page_size: int):
     folded by ``expanded_segment``: the fold the latent layers run, handed a
     GQA layer's head layout (K and V two arrays a KV head, no rope part: the
     keys were rotated before they were written) and, where a key's row is
-    wider than its head, the head's own scale."""
+    wider than its head, the head's own scale. One path over GQA pages for
+    the "softmax" and "cca" layers, which attend all they see, and the
+    "sparse" ones, which hand it ``chosen [B, K, S, W * page_size]`` of
+    ``FOLD_MASK_DTYPE``: a KV head's choice over the positions of the row's
+    page table, causality in it, read by the folds in place of the
+    positions' order."""
     b, s, heads, hd = q.shape
     kv, per = pages_k.shape[0], s // page_size
     head = jnp.arange(kv)[None, :, None]
@@ -804,7 +816,7 @@ def _segment_softmax(q, pages_k, pages_v, idx, start, page_size: int):
 
     return expanded_segment(
         _to_row(q, pages_k.shape[-1]),  # the lanes a key's row takes in a page
-        q[..., :0], block, start, pages_v.shape[-1], q.dtype, scale=hd ** -0.5)
+        q[..., :0], block, start, pages_v.shape[-1], q.dtype, chosen, scale=hd ** -0.5)
 
 
 def _qkv_heads(x, p, lora, *, cfg, proj, lora_scale, mixer: str = "softmax"):

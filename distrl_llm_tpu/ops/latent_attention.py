@@ -16,9 +16,10 @@ has two forms, and which is cheaper depends on queries a cached token:
   ``expanded_attention`` elsewhere. The fold is ONE algorithm whose
   parameters are read off what it is handed: the head's layout off the
   shapes (K 128 + 64 wide beside V of 128 for Kimi-VL, 192 + 64 beside 256 for
-  GLM-5), which keys a query attends off whether a choice arrived (a
-  learned index's mask, a tile of it read beside the tile of keys) or not
-  (the two positions' order), and where the keys come from off what a block
+  GLM-5), which keys a query attends off whether a choice arrived (a mask, a
+  tile of it read beside the tile of keys: one for every head, a learned
+  index's, or one a KV head, a block-sparse layer's; its rank says which) or
+  not (the two positions' order), and where the keys come from off what a block
   is: one array a head, the latent's product through W_kvb, or a pair ``(k, v)``
   a KV head, a block of the rows' K/V pages. The second is how a GQA layer's
   prefill segment runs the same fold (``models/hybrid.py::_segment_softmax``:
@@ -121,7 +122,7 @@ def expanded_attention(
     q_pe: jax.Array,  # [B, Sq, H, rope], rotated
     kv,  # [B, Sk, H, nope + v]: the latent through W_kvb; or (k, v) a KV head
     k_pe: jax.Array,  # [B, Sk, rope], rotated
-    mask: jax.Array,  # [B, Sq, Sk] bool; True = attend
+    mask: jax.Array,  # [B, Sq, Sk] bool, or [B, K, Sq, Sk] a KV head; True = attend
     carry=None,
     scale: float | None = None,
 ):
@@ -152,9 +153,11 @@ def expanded_attention(
         preferred_element_type=jnp.float32,
     ).reshape(b, h, sq, -1) + jnp.einsum(
         "bqhd,bkd->bhqk", q_pe, k_pe, preferred_element_type=jnp.float32)
-    scores = jnp.where(mask[:, None], scores * scale, NEG_INF)
+    # one mask for every head, or a KV head's for the query heads that share it
+    seen = mask[:, None] if mask.ndim == 3 else jnp.repeat(mask, h // mask.shape[1], axis=1)
+    scores = jnp.where(seen, scores * scale, NEG_INF)
     m_new = jnp.maximum(m, scores.max(axis=-1))
-    p = jnp.where(mask[:, None], jnp.exp(scores - m_new[..., None]), 0.0)
+    p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
     fix = jnp.exp(m - m_new)
     l = l * fix + p.sum(axis=-1)
     # the product head-major, then transposed: the CPU's dot refuses bf16
@@ -205,11 +208,13 @@ def expanded_segment(q_nope, q_pe, block, start, v_dim: int, dtype,
     ``expanded_segment_impl`` names (recorded in ``dispatch_choices``): one
     ``expanded_fold_kernel`` launch, whose scores never leave VMEM and whose
     carry keeps the kernel's layout from the first fold to the last, or
-    ``expanded_fold``, which is ``expanded_attention``. ``chosen [B, S, keys]``
-    of ``FOLD_MASK_DTYPE``, if given, is what each query attends (non-zero) over
-    the row's key positions ``0 .. keys`` (a learned index's choice, causality
-    and the row's length in it): either form then reads its block's columns of
-    it in place of the two positions' order."""
+    ``expanded_fold``, which is ``expanded_attention``. ``chosen`` of
+    ``FOLD_MASK_DTYPE``, if given, is what each query attends (non-zero) over
+    the row's key positions ``0 .. keys``, causality in it: ``[B, S, keys]`` one
+    choice for every head (a learned index's) or ``[B, K, S, keys]`` a KV head's,
+    shared by the query heads that read that head (a block-sparse layer's:
+    ``ops/sparse_attention.py::segment_choice``). Either form then reads its
+    block's columns of it in place of the two positions' order."""
     b, s, h, nope = q_nope.shape
     impl = expanded_segment_impl(q_nope, v_dim)
     dispatch_choices[dispatch_key(h, nope, q_pe.shape[-1], v_dim, s, q_nope.dtype)] = impl
@@ -236,17 +241,18 @@ def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=N
                   *, scale: float | None = None):
     """``expanded_attention`` with the mask given as two positions: the queries
     stand at ``q_start ..``, the keys at ``k_start ..``, and a query sees the
-    keys at or before it (every row alike); or, where ``chosen [B, S, keys]`` is
-    given, as its columns ``k_start ..`` (non-zero = attend). ``expanded_fold_
-    kernel``'s reference, argument for argument after the queries (the kernel
-    takes ``fold_queries``' one array for these two)."""
+    keys at or before it (every row alike); or, where ``chosen [B, S, keys]`` (or
+    ``[B, K, S, keys]``, a KV head's) is given, as its columns ``k_start ..``
+    (non-zero = attend). ``expanded_fold_kernel``'s reference, argument for
+    argument after the queries (the kernel takes ``fold_queries``' one array
+    for these two)."""
     b, sq = q_nope.shape[:2]
     sk = k_pe.shape[1]
     if chosen is None:
         mask = (k_start + jnp.arange(sk))[None, :] <= (q_start + jnp.arange(sq))[:, None]
         mask = jnp.broadcast_to(mask, (b, sq, sk))
     else:
-        mask = jax.lax.dynamic_slice_in_dim(chosen, k_start, sk, axis=2) != 0
+        mask = jax.lax.dynamic_slice_in_dim(chosen, k_start, sk, axis=chosen.ndim - 1) != 0
     return expanded_attention(q_nope, q_pe, kv, k_pe, mask, carry, scale)
 
 
@@ -268,7 +274,11 @@ def expanded_fold(q_nope, q_pe, kv, k_pe, q_start, k_start, carry=None, chosen=N
 #: THE OTHER SOURCE OF KEYS, a page a tile read from the pool where it lies through
 #: a scalar-prefetched page table (tiles of 128 keys), 12.455 at 1,024 queries and
 #: 15.795 at 512; the XLA form 14.451. At [4, 8 x 8, 1024] with 128 / 128: 1.386,
-#: 1.479 at 512 x 1,024, the table's 4.919, the XLA form 6.177
+#: 1.479 at 512 x 1,024, the table's 4.919, the XLA form 6.177.
+#: Under a KV head's choice (PERF.md §6, PR 67; a block-sparse layer's, one byte a
+#: pair, 16 query heads reading one tile of it; a fold of 1,024 keys gathered from
+#: pages of 64): at [4, 2 x 16, 1024] with 128 / 128, 0.825 ms at 1,024 x 1,024,
+#: 0.878 at 512 x 1,024, 1.178 at 1,024 x 512; the same fold with no choice 0.689
 FOLD_TILE_Q = 1024
 FOLD_TILE_K = 1024
 
@@ -433,7 +443,11 @@ def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
     ..``, and a query sees the keys at or before it. With ``chosen [B, S,
     keys]`` (``FOLD_MASK_DTYPE``; non-zero = attend, causality in it) a tile of
     it is read beside the tile of keys, the key axis at ``k_start ..``, and the
-    positions only say which tiles lie wholly above the diagonal. ``q [B, H, S,
+    positions only say which tiles lie wholly above the diagonal. ``chosen [B,
+    K, S, keys]`` is a KV head's choice (its RANK says so): query head ``h``
+    reads the tile of head ``h // (H / K)``, an index map like the keys', so the
+    heads of a group name the same tile one after another and the pipeline
+    copies it once a group. ``q [B, H, S,
     lanes]`` from ``fold_queries``, ``kv`` and ``k_pe [B, Sk, rope]`` as
     ``expanded_attention`` takes them, ``carry`` from ``fold_start`` or an
     earlier fold, updated in place; ``scale`` the scores', ``(nope + rope)^-0.5``
@@ -505,9 +519,18 @@ def expanded_fold_kernel(q, kv, k_pe, q_start, k_start, carry, chosen=None,
         # the block, the block of ``tk``). Its key axis is the page table's width
         # (20,992 positions in GLM-5's cell: no multiple of the tile), but a fold's
         # keys end at or before the segment's own, which whole blocks hold: the
-        # ragged last tile is never named
-        operands["chosen"] = (chosen, pl.BlockSpec(
-            (None, tq, tk), lambda b, h, i, j, pos: (b, i, pos[1] // tk + keys(i, j, pos))))
+        # ragged last tile is never named. One choice for every head, or (a
+        # fourth axis) one a KV head, named by the heads that share it
+        column = lambda i, j, pos: pos[1] // tk + keys(i, j, pos)
+        if chosen.ndim == 3:
+            tile = pl.BlockSpec(
+                (None, tq, tk), lambda b, h, i, j, pos: (b, i, column(i, j, pos)))
+        else:
+            share = h // chosen.shape[1]
+            tile = pl.BlockSpec(
+                (None, None, tq, tk),
+                lambda b, h, i, j, pos: (b, h // share, i, column(i, j, pos)))
+        operands["chosen"] = (chosen, tile)
     names = (*operands, "m_out", "l_out", "acc_out", "m_s", "l_s", "acc_s")
     return tuple(pl.pallas_call(
         functools.partial(_fold_body, names=names, scale=scale, nope=nope),

@@ -10,12 +10,18 @@ the rest. A query whose context is at most ``dense_len`` long attends to
 every block. The choice is shared by a KV head's group and carries no
 gradient (it is a boolean mask).
 
-Three entry points over one ``choose_blocks``:
+Four entry points over one ``choose_blocks``:
 
-* ``sparse_attend``: many queries over dense K/V (training, and prefill over
-  the pages gathered dense). Scores are full and masked, one block of queries
-  at a time per row, so the operations are a dense attention's and the memory
-  is one query block's.
+* ``sparse_attend``: many queries over dense K/V (``full`` mode: training,
+  which differentiates it, and the tests' reference for the others). Scores
+  are full and masked, one block of queries at a time per row, so the
+  operations are a dense attention's and the memory is one query block's.
+* ``segment_choice``: a prefill segment's choice as the mask ``allowed_keys``
+  makes of it (``sparse_attend``'s own), a KV head's queries together. The
+  segment does not attend here: ``models/hybrid.py::_sparse_mix`` hands the
+  mask to the fold the full-attention layers run over the rows' pages
+  (``ops/latent_attention.py::expanded_segment``), whose scores never leave
+  VMEM on a TPU and which stops at the segment's own block of keys.
 * ``sparse_decode``: one query per slot over the paged cache with pages of
   one block: the chosen blocks ARE a page list per (slot, KV head)
   (``chosen_pages``: the pages in position order, the last of them the
@@ -166,12 +172,82 @@ def choose_blocks(q, pooled, q_pos, cfg, n_blocks: int):
     ]), axis=0)  # [B, S, K, NB]
     rest = causal & ~forced
     rest_score = jnp.where(rest, score, -jnp.inf)
-    top_val, top_idx = jax.lax.top_k(rest_score, min(cfg.sparse_topk, n_blocks))
-    kth, kth_idx = top_val[..., -1:], top_idx[..., -1:]
+    kth, kth_idx = _kth_best(rest_score, min(cfg.sparse_topk, n_blocks))
     # the top-k as a mask: above the k-th, or level with it and not after it
-    # (lax.top_k puts the lower index first among equals)
     picked = rest & ((rest_score > kth) | ((rest_score == kth) & (blk <= kth_idx)))
     return jnp.where(dense, causal, forced | picked)
+
+
+def _kth_best(score, k: int):
+    """The ``k``-th best of ``score [..., n]`` along its last axis and its
+    index, both ``[..., 1]``, in ``jax.lax.top_k``'s order: descending, the
+    lower index first among equals. One sort along the LEADING axis of ``[n,
+    problems]``: the independent problems lie along the lanes and every
+    compare-exchange is between rows. ``top_k`` sorts the minor axis, and there
+    the compiler's choice of layout decides what it costs: one block of 128
+    queries' 256 problems of 320 took 21 us on a v5e with the queries minor and
+    486 us with the blocks minor, the same operation in two programs (PERF.md
+    §6, PR 67)."""
+    n = score.shape[-1]
+    flat = score.reshape(-1, n).T
+    order = jax.lax.broadcasted_iota(jnp.int32, flat.shape, 0)
+    # ascending by (-score, index); a score is never -0.0 (a sum of weights, -1
+    # or -inf), so negating keeps equals equal
+    best, at = jax.lax.sort((-flat, order), dimension=0, num_keys=2)
+    shape = (*score.shape[:-1], 1)
+    return -best[k - 1].reshape(shape), at[k - 1].reshape(shape)
+
+
+def allowed_keys(blocks, q_pos, keys: int, cfg):
+    """The keys each query attends, from the blocks it chose: bool ``[..., K,
+    S, keys]`` (a KV head's queries together, as the scores hold them) from
+    ``choose_blocks``' ``[..., S, K, n_blocks]`` and ``q_pos [..., S]``: every
+    key of a chosen block that lies at or before the query."""
+    # a block's choice spread over its keys by a product with a 0/1 matrix
+    # (exact: one non-zero term a sum), which the matrix unit writes in the
+    # layout the mask is read in. ``jnp.repeat``'s reshape of the minor axis
+    # (320 x 64 -> 20,480) is a relayout copy of the whole mask on a TPU, and
+    # the folds' loop then re-lays it a fold: 2.2 ms a fold of 4 rows where
+    # this form reads 0.82 (PERF.md §6, PR 67)
+    spread = jnp.arange(keys) // cfg.sparse_block_size == jnp.arange(blocks.shape[-1])[:, None]
+    chosen = jnp.einsum(
+        "...sn,nt->...st", jnp.swapaxes(blocks, -3, -2).astype(jnp.bfloat16),
+        spread.astype(jnp.bfloat16), preferred_element_type=_F32) > 0
+    return chosen & (jnp.arange(keys) <= q_pos[..., None, :, None])
+
+
+def _query_blocks(q, q_pos, q_block: int):
+    """``q [B, S, H, hd]`` and ``q_pos [B, S]`` cut into blocks of at most
+    ``q_block`` queries, the last one padded: ``([B, nq, Q, H, hd], [B, nq, Q])``."""
+    b, s, h, hd = q.shape
+    q_block = min(q_block, s)
+    nq = -(-s // q_block)
+    pad = nq * q_block - s
+    return (jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(b, nq, q_block, h, hd),
+            jnp.pad(q_pos, ((0, 0), (0, pad))).reshape(b, nq, q_block))
+
+
+def segment_choice(q, pooled, q_pos, cfg, keys: int, q_block: int = DEFAULT_Q_BLOCK):
+    """A prefill segment's choice over the row's first ``keys`` positions:
+    bool ``[B, K, S, keys]``, ``allowed_keys`` of ``choose_blocks`` for q ``[B,
+    S, H, hd]`` at ``q_pos [B, S]`` over pooled ``[B, NP, K, hd]``. The choice
+    is made one block of queries of one row at a time, as ``sparse_attend``
+    makes it (a block's logits, ``[128, 2, 16, 1315]`` float32 at the
+    long-context cell's sizes, are 22 MB where a segment's whole are 689 MB),
+    and only the blocks chosen are kept; the mask is made of them once."""
+    b, s = q.shape[:2]
+    n_blocks = block_count(keys, cfg)
+    qb, pb = _query_blocks(q, q_pos, q_block)
+    nq = qb.shape[1]
+    # a (row, block of queries) an iteration, ``[B * nq, 1, Q, ...]``: no transpose
+    blocks = jax.lax.map(
+        lambda c: choose_blocks(
+            c[0], jax.lax.dynamic_index_in_dim(pooled, c[2], keepdims=True), c[1], cfg,
+            n_blocks),
+        (qb.reshape(b * nq, 1, *qb.shape[2:]), pb.reshape(b * nq, 1, -1),
+         jnp.arange(b * nq) // nq))  # [B * nq, 1, Q, K, NB]
+    blocks = blocks.reshape(b, -1, *blocks.shape[3:])[:, :s]
+    return allowed_keys(blocks, q_pos, keys, cfg)
 
 
 def sparse_attend(q, k, v, pooled, q_pos, cfg, q_block: int = DEFAULT_Q_BLOCK):
@@ -182,14 +258,9 @@ def sparse_attend(q, k, v, pooled, q_pos, cfg, q_block: int = DEFAULT_Q_BLOCK):
     b, s, h, hd = q.shape
     t, kh = k.shape[1], k.shape[2]
     g = h // kh
-    bs = cfg.sparse_block_size
     n_blocks = block_count(t, cfg)
-    q_block = min(q_block, s)
-    nq = -(-s // q_block)
-    pad = nq * q_block - s
-    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(b, nq, q_block, h, hd)
-    pb = jnp.pad(q_pos, ((0, 0), (0, pad))).reshape(b, nq, q_block)
-    tpos = jnp.arange(t)
+    qb, pb = _query_blocks(q, q_pos, q_block)
+    nq, q_block = qb.shape[1:3]
 
     @jax.checkpoint
     def one_block(q_c, pos_c, k_r, v_r, pooled_r):
@@ -197,15 +268,13 @@ def sparse_attend(q, k, v, pooled, q_pos, cfg, q_block: int = DEFAULT_Q_BLOCK):
             blocks = choose_blocks(
                 q_c[None], pooled_r[None], pos_c[None], cfg, n_blocks
             )[0]  # [Q, K, NB]
-            allowed = jnp.repeat(blocks, bs, axis=-1)[..., :t] & (
-                tpos <= pos_c[:, None, None]
-            )  # [Q, K, T]
+            allowed = allowed_keys(blocks, pos_c, t, cfg)  # [K, Q, T]
         with jax.named_scope(telemetry.MODEL_SPARSE_ATTN):
             qg = q_c.reshape(q_block, kh, g, hd)
             logits = jnp.einsum(
                 "qkgd,tkd->kgqt", qg, k_r, preferred_element_type=_F32
             ) * hd**-0.5
-            logits = jnp.where(allowed.transpose(1, 0, 2)[:, None], logits, NEG_INF)
+            logits = jnp.where(allowed[:, None], logits, NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1).astype(v_r.dtype)
             out = jnp.einsum("kgqt,tkd->qkgd", probs, v_r, preferred_element_type=_F32)
         return out.reshape(q_block, h, hd).astype(q_c.dtype)

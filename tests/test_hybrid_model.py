@@ -190,6 +190,64 @@ def test_the_chosen_blocks_are_the_references_and_the_choice_is_live():
     assert (got[late][:, 0] != got[late][:, 1]).any()  # the KV heads choose apart
 
 
+def test_a_segments_choice_is_the_mask_sparse_attend_makes():
+    """``segment_choice`` (a block of queries at a time, every row's together,
+    the last block ragged) is ``choose_blocks``' choice bit for bit, as the
+    mask ``sparse_attend`` makes of it: each key of a chosen block at or
+    before the query, a KV head's queries together."""
+    from distrl_llm_tpu.ops.sparse_attention import (
+        block_count, choose_blocks, pool_keys, segment_choice,
+    )
+
+    t, s, heads, kh, d = 96, 32, 4, 2, 16
+    q = jax.random.normal(jax.random.PRNGKey(0), (2, s, heads, d))
+    k = jax.random.normal(jax.random.PRNGKey(1), (2, t, kh, d))
+    pos = 48 + jnp.broadcast_to(jnp.arange(s), (2, s))  # the fourth segment of six
+    pooled = pool_keys(k, CFG)
+    got = np.asarray(segment_choice(q, pooled, pos, CFG, t, q_block=12))
+    blocks = np.asarray(choose_blocks(q, pooled, pos, CFG, block_count(t, CFG)))
+    allowed = np.repeat(blocks, CFG.sparse_block_size, axis=-1)[..., :t] & (
+        np.arange(t) <= np.asarray(pos)[:, :, None, None])  # [B, S, K, T], as PR 66 had it
+    assert got.dtype == bool and got.shape == (2, kh, s, t)
+    np.testing.assert_array_equal(got, allowed.transpose(0, 2, 1, 3))
+    assert (got[:, 0] != got[:, 1]).any() and not got[..., 80:].any()
+
+
+def test_a_sparse_layers_segments_are_its_full_mode(monkeypatch):
+    """A sparse layer's attention over a row of 64 tokens, ``full`` mode
+    (``sparse_attend``: the learner's, masked full scores) against four
+    prefill segments of 16 over pages (the choice as the folds' mask, each
+    block of keys folded by the kernel, interpreted; the form a CPU takes is
+    held by the engine's rounds against the reference and by
+    ``tests/test_softmax_fold.py``), float32, to this file's 2e-5: the first
+    segment within ``dense_len``, the others past it."""
+    from distrl_llm_tpu.ops import latent_attention as la
+    from distrl_llm_tpu.ops.sparse_attention import pooled_count
+
+    monkeypatch.setattr(la, "expanded_segment_impl", lambda q_nope, v_dim: "kernel")
+    monkeypatch.setattr(la, "expanded_fold_kernel", functools.partial(
+        la.expanded_fold_kernel, interpret=True))
+    b, t, seg, ps, kh, d = 2, 64, 16, CFG.sparse_block_size, CFG.num_kv_heads, CFG.head_dim
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (b, t, h, d))
+               for i, h in enumerate((CFG.num_heads, kh, kh)))
+    arange = lambda n: jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (b, n))
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = hybrid._sparse_mix(q, k, v, None, cfg=CFG, mode="full", env={
+            "q_pos": arange(t), "valid": jnp.ones((b, t), jnp.int32)})
+        pool = jnp.zeros((kh, b * t // ps, ps, d))
+        cache = (pool, pool, jnp.zeros((b, pooled_count(t, CFG), kh, d)))
+        table = jax.random.permutation(jax.random.PRNGKey(3), b * t // ps).reshape(b, -1)
+        for start in range(0, t, seg):
+            at = slice(start, start + seg)
+            got, cache, _ = hybrid._sparse_mix(
+                q[:, at], k[:, at], v[:, at], cache, cfg=CFG, mode="segment", env={
+                    "segment_start": jnp.int32(start), "q_pos": start + arange(seg),
+                    "page_indices": table.astype(jnp.int32), "page_size": ps})
+            np.testing.assert_allclose(got, want[:, at], atol=2e-5)
+    assert la.dispatch_choices[la.dispatch_key(
+        CFG.num_heads, d, 0, d, seg, jnp.float32)] == "kernel"
+
+
 @pytest.mark.parametrize("kh", [2, 4])
 def test_the_decode_steps_pooled_key_is_a_plain_loops(kh):
     """``update_pooled`` reads the last ``kernel`` keys of each (row, KV head)
@@ -342,6 +400,28 @@ def test_the_counter_is_sparse_layers_times_steps_where_the_launch_ran(
     from distrl_llm_tpu.models.configs import PRESETS
     paged_engine._record_sparse_telemetry(PRESETS["tiny"], 512, jnp.bfloat16)
     assert filed == [("ops/sparse_kernel_steps", 0)]
+
+
+@pytest.mark.parametrize("ran,longest,want", [
+    ("kernel", None, 2 * 210), ("kernel", 3, 2 * 6), ("xla", None, 0), (None, None, 0)])
+def test_the_fold_counter_is_sparse_layers_times_the_folds(monkeypatch, ran, longest, want):
+    """``ops/softmax_kernel_folds`` counts a sparse layer's folds with the
+    full-attention layers' (one path over the rows' pages): 2 layers x segment
+    j's j + 1 of the long-context cell's 20 segments of 1,024 in pages of 64,
+    420 a round, where ``expanded_segment`` recorded the kernel under the
+    layers' geometry; to the longest row's segments where the stages end
+    sooner; 0 where it took the XLA form or traced nothing."""
+    from distrl_llm_tpu import telemetry
+    from distrl_llm_tpu.engine import paged_engine
+    from distrl_llm_tpu.ops import latent_attention as la
+
+    cfg = dataclasses.replace(CFG, head_dim=128, sparse_block_size=64)
+    key = la.dispatch_key(cfg.num_heads, 128, 0, 128, 1024, jnp.bfloat16)
+    monkeypatch.setattr(la, "dispatch_choices", {} if ran is None else {key: ran})
+    filed = []
+    monkeypatch.setattr(telemetry, "counter_add", lambda name, value: filed.append((name, value)))
+    paged_engine._record_fold_telemetry(cfg, 320, 64, jnp.bfloat16, longest)
+    assert filed == [("ops/softmax_kernel_folds", want)]
 
 
 # ------------------------------------------------------------ the refusals

@@ -1221,16 +1221,22 @@ def test_sala_mixers_at_published_widths(chip, monkeypatch, mode):
     pool is left in the step. The launch alone at the cell's 64 slots: one
     custom call, named after ``attend_pages_kernel`` as the trace reduction
     spells it (no metric selects by the name yet; one that comes to finds it
-    held here). A prefill segment: masked scores and the float32 HIGHEST
-    chunked scan, plain XLA both."""
+    held here). A prefill segment as ``rollout-longctx``'s first stage runs it
+    (``hybrid._sparse_mix`` over the pages of 4 rows, 20,480 tokens of table):
+    the choice a byte mask a KV head, every fold of a block of keys the ONE
+    ``expanded_fold_kernel`` launch under it, so that neither a block of
+    queries' scores over the whole table (``f32[2,16,128,20480]``, 335 MB, what
+    ``sparse_attend`` writes) nor a fold's (``f32[.., 1024, 1024]``) is a buffer
+    of the program; beside it the float32 HIGHEST chunked scan, plain XLA."""
+    from distrl_llm_tpu.models import hybrid
     from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.ops import latent_attention as la
     from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
-    from distrl_llm_tpu.ops.sparse_attention import (
-        pool_keys, sparse_attend, sparse_decode, update_pooled,
-    )
+    from distrl_llm_tpu.ops.sparse_attention import sparse_decode, update_pooled
 
     cfg = sala_config()
-    rows, width = 8, 329  # page-table columns of 20,480 + 512 tokens in pages of 64
+    # page-table columns of 20,480 + 512 tokens in pages of 64
+    rows, width = 4 if mode == "segment" else 8, 329
     state = jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * 64))
     pages = chip((2, rows * width, 64, 128), jnp.bfloat16)
     pooled = chip(state["pooled"][0].shape, jnp.bfloat16)
@@ -1265,16 +1271,31 @@ def test_sala_mixers_at_published_widths(chip, monkeypatch, mode):
         assert "tpu_custom_call" in text
         assert not re.search(r"bf16\[[\d,]*128,64,128\]", text)
     else:
-        def segment(q, k, v, pos, ql, kl, vl, valid, lin, rates):
-            out = sparse_attend(q, k, v, pool_keys(k, cfg), pos, cfg)
-            return out, lightning_chunked(ql, kl, vl, rates, valid, state=lin)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(la, "dispatch_choices", {})
+
+        def segment(q, k, v, k_pages, v_pages, pooled, table, start, ql, kl, vl, valid,
+                    lin, rates):
+            env = {"segment_start": start, "page_indices": table, "page_size": 64,
+                   "q_pos": start + jnp.broadcast_to(jnp.arange(1024), (rows, 1024))}
+            out, cache, _ = hybrid._sparse_mix(
+                q, k, v, (k_pages, v_pages, pooled), cfg=cfg, mode="segment", env=env)
+            return out, cache, lightning_chunked(ql, kl, vl, rates, valid, state=lin)
 
         seg = chip((rows, 1024, 32, 128), jnp.bfloat16)
-        ctx = chip((rows, 20480, 2, 128), jnp.bfloat16)
-        compiled = jax.jit(segment).lower(
-            seg, ctx, ctx, chip((rows, 1024), jnp.int32), seg, seg, seg,
-            chip((rows, 1024), jnp.int32), lin, rates,
+        new = chip((rows, 1024, 2, 128), jnp.bfloat16)
+        compiled = jax.jit(segment, donate_argnums=(3, 4)).lower(
+            seg, new, new, pages, pages, pooled, chip((rows, 320), jnp.int32),
+            chip((), jnp.int32), seg, seg, seg, chip((rows, 1024), jnp.int32), lin, rates,
         ).compile()
+        assert la.dispatch_choices == {
+            la.dispatch_key(32, 128, 0, 128, 1024, jnp.bfloat16): "kernel"}
+        text = compiled.as_text()
+        (call,) = [line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        assert f"f32[{rows},32,1024,128]" in call  # the carry, in the kernel's layout
+        assert f"s8[{rows},2,1024,20480]" in call  # a KV head's choice, a byte a pair
+        assert not re.search(r"f32\[[\d,]*(128,20480|1024,1024)\]", text)
     # one query block's scores and one slot's gathered pages, not a whole prompt's
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
