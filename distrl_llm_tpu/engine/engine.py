@@ -99,6 +99,8 @@ def _prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
             if cache_dtype == "int8"
             else init_kv_cache(cfg, b, max_total, dtype=cache_dtype)
         )
+        if cfg.looped:
+            cache.update(looped_account())
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         key_mask = jnp.pad(prompt_mask, ((0, 0), (0, max_total - p)))
     last_logits, cache = forward(
@@ -115,7 +117,12 @@ def _decode_init(cache, key_mask, first_logits, row_alive,
     """Expand prefill state to candidate rows: row b*n + j is candidate j of
     prompt b."""
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
-        cache = jax.tree_util.tree_map(lambda c: jnp.repeat(c, n, axis=0), cache)
+        # a looped model's exit account is the round's, not a row's
+        account = {k: cache[k] for k in ("exit_stats",) if k in cache}
+        cache = jax.tree_util.tree_map(
+            lambda c: jnp.repeat(c, n, axis=0),
+            {k: v for k, v in cache.items() if k not in account})
+        cache.update(account)
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         key_mask = jnp.repeat(key_mask, n, axis=0)
         logits = jnp.repeat(first_logits, n, axis=0)
@@ -187,10 +194,14 @@ def _decode_step(params, lora, state: _DecodeState, rng,
     next_logits, cache = forward(
         params, cfg, tok[:, None],
         attention_mask=key_mask, lora=lora, lora_scale=lora_scale,
-        kv_cache=s.cache, cache_offset=prompt_len + s.step,
+        # a looped model's exit account counts the rows that emit a token
+        kv_cache=({**s.cache, "alive": ~s.done} if "exit_stats" in s.cache
+                  else s.cache),
+        cache_offset=prompt_len + s.step,
         attn_impl=attn_impl,
         cache_read_formulation=cache_read_formulation,
     )
+    cache.pop("alive", None)
     with jax.named_scope(telemetry.ENGINE_BOOKKEEPING):
         return _DecodeState(
             step=s.step + 1, out=out, logps=logps, lengths=lengths, done=done,
@@ -696,6 +707,39 @@ def file_round(marks: RoundMarks, stats: dict | None) -> dict | None:
     return record
 
 
+def looped_account() -> dict:
+    """What a looped model's decode state holds beside its K/V: the round's
+    exit account, which every decode step adds to
+    (``transformer._exit_step_sums``); no row state."""
+    return {"exit_stats": jnp.zeros((2,), jnp.float32)}
+
+
+def file_exit_stats(cfg: ModelConfig, stats) -> dict:
+    """File a round's ``engine/exit_step_mean``: for a looped model its exit
+    account (``stats`` ``[2]``: the sum over decoded tokens of the pass the
+    exit distribution would stop at, and the tokens), returned as the round's
+    span says it; for a model that runs its layers once the one pass every
+    token takes, so that the gauge never holds another engine's reading."""
+    if not cfg.looped:
+        telemetry.gauge_set(telemetry.ENGINE_EXIT_STEP_MEAN, 1.0)
+        return {}
+    total, tokens = (float(x) for x in np.asarray(stats))
+    if tokens <= 0:
+        return {}
+    telemetry.gauge_set(telemetry.ENGINE_EXIT_STEP_MEAN, total / tokens)
+    return {"exit_step_mean": total / tokens}
+
+
+def file_loop_layer_steps(cfg: ModelConfig, steps: int) -> None:
+    """``engine/loop_layer_steps``: the layer applications of a looped
+    model's round, ``layer_steps`` a decode step and as many for the prefill
+    forward (host arithmetic, like ``ops/paged_grid_steps``). A model that
+    runs its layers once files nothing."""
+    if cfg.looped:
+        telemetry.counter_add(
+            telemetry.ENGINE_LOOP_LAYER_STEPS, cfg.layer_steps * (steps + 1))
+
+
 def pool_nbytes(*trees) -> int:
     """Total bytes of the KV buffers a chunked program must alias in place
     (the denominator of compile_chunk_guarded's double-buffer check)."""
@@ -1180,6 +1224,8 @@ class GenerationEngine(LoraMailbox):
         )
         if kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be none/int8, got {kv_quant!r}")
+        if kv_quant == "int8":
+            cfg.refuse_looped("kv_quant='int8' (an int8 dense cache)")
         # "int8" rides the cache_dtype static arg as a sentinel: _prefill
         # builds the scale-carrying cache and the forward's dense-cache
         # branch switches to attention_cached_quant
@@ -1467,9 +1513,11 @@ class GenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
+            exit_said = file_exit_stats(self.cfg, state.cache.get("exit_stats"))
         host.blocked(t_read)
-        dec_span.set(tokens=gen_tokens, steps=steps_seen[0])
+        dec_span.set(tokens=gen_tokens, steps=steps_seen[0], **exit_said)
         dec_span.__exit__(None, None, None)
+        file_loop_layer_steps(self.cfg, steps_seen[0])
         self.last_round_stats = accumulate_round_stats(
             self.last_round_stats, prefill_s=t_prefill,
             prefill_tokens=prefill_tokens, prompt_rows=b,

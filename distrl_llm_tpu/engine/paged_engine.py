@@ -51,6 +51,9 @@ from distrl_llm_tpu.engine.engine import (
     RoundHostAccount,
     RoundMarks,
     accumulate_round_stats,
+    file_exit_stats,
+    file_loop_layer_steps,
+    looped_account,
     file_round,
     cached_chunk_program,
     generate_in_waves,
@@ -277,7 +280,9 @@ def _record_grid_telemetry(num_layers: int, steps: int,
     stale batch);
     ``calls_per_step`` is the op calls per layer per dispatched step (1
     for plain decode, draft_len+1 for the speculative verify fan-out).
-    Total grid steps this round = per-call × calls/step × layers × steps."""
+    Total grid steps this round = per-call × calls/step × layers × steps, where
+    ``num_layers`` counts CACHE layers (``cfg.paged_layers``: a looped model
+    launches once a (pass, layer))."""
     if per_call and steps:
         telemetry.counter_add(
             OPS_PAGED_GRID_STEPS, per_call * calls_per_step * num_layers * steps)
@@ -426,8 +431,9 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
 
     with jax.named_scope(telemetry.ENGINE_KV_WRITE):
         cache = {
-            "k": tuple(make_pages() for _ in range(cfg.num_layers)),
-            "v": tuple(make_pages() for _ in range(cfg.num_layers)),
+            # one pool a CACHE layer: a looped model's (pass, layer)
+            "k": tuple(make_pages() for _ in range(cfg.paged_layers)),
+            "v": tuple(make_pages() for _ in range(cfg.paged_layers)),
             "lengths": real_len,
             "page_indices": jnp.asarray(
                 make_page_table(b, pad_to, page_size)
@@ -444,6 +450,8 @@ def _paged_prefill(params, lora, prompt_ids, prompt_mask, *, cfg: ModelConfig,
         # a per-row gather that also skips the [B, Ppad, V] lm_head
         logits_positions=jnp.maximum(real_len - 1, 0),
     )
+    if cfg.looped:  # and the round's exit account, which the decode steps add to
+        return cache["k"], cache["v"], logits[:, 0], real_len, looped_account()
     return cache["k"], cache["v"], logits[:, 0], real_len
 
 
@@ -969,6 +977,8 @@ def _refill_init(prompt_k, prompt_v, counted=None,
 
         mixer = {**init_mixer_state(cfg, r_slots, width * page_size, cache_dtype),
                  **(counted or {})}
+    elif cfg is not None and cfg.looped:
+        mixer = looped_account()
 
     return _RefillState(
         step=jnp.zeros((), jnp.int32),
@@ -2036,6 +2046,21 @@ class PagedGenerationEngine(LoraMailbox):
         if kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be none/int8, got {kv_quant!r}")
         self.kv_quant = kv_quant
+        if cfg.looped:
+            # a looped model runs through the wave and refill schedulers, held
+            # to the reference there; what re-derives, parks or re-reads K/V by
+            # another path has not been, and refuses by name
+            for asked, what in (
+                (kv_quant != "none", f"kv_quant={kv_quant!r} (an int8 KV pool)"),
+                (spec_draft, "spec_draft (speculative decoding)"),
+                (prefix_sharing, "prefix_sharing / continuous_admission "
+                                 "(pool-allocated prompt chains)"),
+                (max_kv_pages, "max_kv_pages (a budgeted pool preempts by re-prefill)"),
+                (prefix_cache, "prefix_cache (the radix cache over K/V pages)"),
+                (kv_spill, "kv_spill (K/V pages parked in host memory)"),
+            ):
+                if asked:
+                    cfg.refuse_looped(what)
         if cfg.hybrid:
             # a model whose layers differ in kind (sparse + lightning): each
             # slot also holds a recurrent state and a selector cache, handed
@@ -2616,6 +2641,7 @@ class PagedGenerationEngine(LoraMailbox):
         self.last_round_stats = None  # waves/refill of THIS round accumulate
         if self.turn_hook is not None:
             self.cfg.refuse_hybrid("turn_hook (in-place multi-turn resume)")
+            self.cfg.refuse_looped("turn_hook (in-place multi-turn resume)")
         if self.turn_hook is not None and (
             self.scheduler != "refill" or not self.max_concurrent_rows
             or self.spec_draft
@@ -2722,8 +2748,8 @@ class PagedGenerationEngine(LoraMailbox):
             else:
                 def _empty():
                     return jnp.zeros(shape0, self.cache_dtype)
-            prompt_k = tuple(_empty() for _ in range(self.cfg.num_layers))
-            prompt_v = tuple(_empty() for _ in range(self.cfg.num_layers))
+            prompt_k = tuple(_empty() for _ in range(self.cfg.paged_layers))
+            prompt_v = tuple(_empty() for _ in range(self.cfg.paged_layers))
             # per-group sampling logits, scatter-published by each adopt
             # (the admit paths index it by prompt id exactly as they index
             # the monolithic prefill's batched logits)
@@ -4142,7 +4168,9 @@ class PagedGenerationEngine(LoraMailbox):
         slot_state = _file_slot_state(
             getattr(state, "mixer", None), getattr(state, "k_pages", ()),
             getattr(state, "v_pages", ()))
-        state_said = {**_count_mixer_stats(getattr(state, "mixer", None)), **slot_state}
+        mixer = getattr(state, "mixer", None)
+        state_said = {**_count_mixer_stats(mixer), **slot_state,
+                      **file_exit_stats(self.cfg, (mixer or {}).get("exit_stats"))}
         if cache_on:
             # park every resident cached page host-side: device page ids
             # are round-scoped, so the tree survives between rounds as a
@@ -4331,10 +4359,10 @@ class PagedGenerationEngine(LoraMailbox):
             # layer count here. vchoice is the SUMMARY spelling for the
             # stats record (the configured d's decision).
             vchoice = self._verify_dispatch_choice()
-            verify_grid = verify_grid_units * self.cfg.num_layers
+            verify_grid = verify_grid_units * self.cfg.paged_layers
             draft_grid = (
                 self._grid_steps_per_call(r_slots)
-                * self.cfg.num_layers * draft_call_steps
+                * self.cfg.paged_layers * draft_call_steps
             )
             if verify_grid:
                 telemetry.counter_add(
@@ -4385,9 +4413,10 @@ class PagedGenerationEngine(LoraMailbox):
             _record_grid_telemetry(1, 1, per_call=verify_grid + draft_grid)
         else:
             _record_grid_telemetry(
-                self.cfg.num_layers, dispatched,
+                self.cfg.paged_layers, dispatched,
                 per_call=self._grid_steps_per_call(r_slots),
             )
+        file_loop_layer_steps(self.cfg, dispatched)
         _record_delta_telemetry(self.cfg, dispatched)
         _record_sparse_telemetry(self.cfg, dispatched, self.cache_dtype)
         _record_power_telemetry(self.cfg, dispatched)
@@ -4513,15 +4542,18 @@ class PagedGenerationEngine(LoraMailbox):
                 if self.capture_logprobs else None
             )
             gen_tokens = int(lengths.sum())
-            state_said = _count_mixer_stats(state.mixer)
+            state_said = {
+                **_count_mixer_stats(state.mixer),
+                **file_exit_stats(self.cfg, (state.mixer or {}).get("exit_stats"))}
         host.blocked(t_read)
         dec_span.set(tokens=gen_tokens, steps=steps_seen[0], **state_said, **slot_state)
         dec_span.__exit__(None, None, None)
         decode_s = host.stop()
         _record_grid_telemetry(
-            self.cfg.num_layers, steps_seen[0],
+            self.cfg.paged_layers, steps_seen[0],
             per_call=self._grid_steps_per_call(b * n),
         )
+        file_loop_layer_steps(self.cfg, steps_seen[0])
         _record_delta_telemetry(self.cfg, steps_seen[0])
         _record_sparse_telemetry(self.cfg, steps_seen[0], self.cache_dtype)
         _record_power_telemetry(self.cfg, steps_seen[0])

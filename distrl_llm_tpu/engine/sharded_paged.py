@@ -118,6 +118,7 @@ class ShardedPagedEngine(LoraMailbox):
         spec_draft: int | None = None,
     ):
         cfg.refuse_hybrid("the dp-sharded paged engine (engine_impl='paged_sharded')")
+        cfg.refuse_looped("the dp-sharded paged engine (engine_impl='paged_sharded')")
         if spec_draft:
             raise NotImplementedError(
                 "speculative decoding is a per-replica refill-scheduler "
@@ -215,7 +216,7 @@ class ShardedPagedEngine(LoraMailbox):
     def _state_specs(self) -> _PagedDecodeState:
         page = P(None, "dp", None, None)
         pages = lambda: tuple(  # noqa: E731 — spec tuple per layer
-            page for _ in range(self.cfg.num_layers)
+            page for _ in range(self.cfg.paged_layers)
         )
 
         def quant_aware(spec_tuple):

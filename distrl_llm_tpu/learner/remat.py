@@ -27,7 +27,9 @@ def kept_products(cfg: ModelConfig, *, tokens: int, itemsize: int,
     """Which named products a rematerialised scan over ``cfg``'s layers keeps
     at ``tokens`` tokens a micro-batch when it may spend ``room`` bytes, and
     the bytes that takes: whole groups of ``KEPT_PRODUCT_GROUPS`` in their
-    order, every layer the same, until the next group does not fit. A token's
+    order, every layer application the same (``cfg.layer_steps``: a looped
+    model's scan keeps a pass's products for every pass), until the next
+    group does not fit. A token's
     value a byte is the same for all five (the product's own operations over
     its output's bytes), so the order sets only the grain. Arithmetic alone:
     nothing is compiled or run to decide."""
@@ -37,7 +39,7 @@ def kept_products(cfg: ModelConfig, *, tokens: int, itemsize: int,
     names: tuple[str, ...] = ()
     spent = 0
     for group in KEPT_PRODUCT_GROUPS:
-        cost = cfg.num_layers * tokens * itemsize * sum(width[n] for n in group)
+        cost = cfg.layer_steps * tokens * itemsize * sum(width[n] for n in group)
         if spent + cost > room:
             break
         names, spent = names + group, spent + cost
@@ -52,8 +54,8 @@ def step_working_set(cfg: ModelConfig, *, rows: int, seq: int, head_positions: i
     accumulator, one micro-batch's gradients, and what the optimizer holds
     while it updates: half a GB for a rank-32 adapter, 12 bytes a parameter
     in ``full`` mode, where a step compiled with the 8-bit optimizer read 2.9
-    to 3.7 trees' worth with its activations); every layer's input (the
-    scan's own residuals); and the larger of one layer's backward (the
+    to 3.7 trees' worth with its activations); every layer application's input (the
+    scan's own residuals: a looped model's for every pass); and the larger of one layer's backward (the
     attention core's float32 scores and their cotangents, the MLP's
     intermediate-wide values, the hidden-wide ones) and the head's float32
     logits and their cotangent over ``head_positions`` positions a row (one
@@ -63,7 +65,7 @@ def step_working_set(cfg: ModelConfig, *, rows: int, seq: int, head_positions: i
     above in both modes); a kernel that keeps no scores (flash, splash, ring)
     is counted as if it did, which keeps less."""
     tokens = rows * seq
-    inputs = cfg.num_layers * tokens * cfg.hidden_size * itemsize
+    inputs = cfg.layer_steps * tokens * cfg.hidden_size * itemsize
     scores = 2 * rows * cfg.num_heads * seq * seq * 4
     mlp = 6 * tokens * cfg.intermediate_size * itemsize
     stream = 8 * tokens * cfg.hidden_size * 4

@@ -111,6 +111,22 @@ outputs that compute nothing (a chosen one adds ``w * u``): ``router_width``
 counts both, the chosen scores are the weights unnormalised, and the two
 latents are scaled by constants before their up-projections (``latent_q_scale``,
 ``latent_kv_scale``).
+
+A looped model (``ouro``, Ouro-2.6B; ``loop_steps`` > 1) is the dense GQA
+decoder with ONE stack of weights run ``loop_steps`` times a token: pass ``u``
+walks layers ``0..num_layers-1``, the final norm closes EVERY pass and its
+output feeds the next, and one ``Linear(hidden, 1)`` exit gate reads each
+pass's output (``models/transformer.py``). Three counts that were one number:
+``num_layers`` stays the published count of WEIGHT layers (the stacks, the
+adapters, the loader); ``loop_steps`` is the passes; ``layer_steps`` =
+``num_layers x loop_steps`` is the layer applications a token, and each is a
+CACHE layer of its own (pass ``u``'s layer ``l`` attends the keys pass ``u``
+wrote: cache layer ``u * num_layers + l``), which is what ``paged_layers``
+returns and both engines, the budget and the learner's kept-products
+arithmetic size by. ``sublayer_out_norm`` norms each sublayer's OUTPUT too
+(``x + N2(attn(N1 x))``, ``x + N4(mlp(N3 x))``). The published
+``early_exit_threshold`` of 1 runs every pass for every token; the gates are
+computed and reported, and decide nothing.
 """
 
 from __future__ import annotations
@@ -148,7 +164,7 @@ def mixer_of(kind: str) -> str:
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
     "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa", "zaya",
-    "mimo_v2_flash", "longcat_flash",
+    "mimo_v2_flash", "longcat_flash", "ouro",
 )
 #: the two SUBLAYERS of one published layer of a shortcut-connected expert model
 #: (``longcat_flash``): the first forks the expert block off its MLP's input, the
@@ -281,12 +297,22 @@ class ModelConfig:
     router_softmax: bool = False  # s = softmax(u W_r) in place of the sigmoid
     latent_q_scale: float = 1.0  # the normed query latent's constant (mla_scale_q_lora)
     latent_kv_scale: float = 1.0  # the normed KV latent's (mla_scale_kv_lora)
+    # ---- a looped model (ouro; module docstring): the one stack of weights is
+    # run ``loop_steps`` times a token, a cache layer a (pass, layer)
+    loop_steps: int = 1  # total_ut_steps
+    sublayer_out_norm: bool = False  # RMSNorm on each sublayer's OUTPUT as well
 
     def __post_init__(self):
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
                 f"hidden_act must be silu/gelu_tanh, got {self.hidden_act!r}"
             )
+        if self.loop_steps < 1 or (self.loop_steps > 1 and (
+                self.mixer_types is not None or self.kv_lora_rank)):
+            raise ValueError(
+                f"loop_steps {self.loop_steps}: a looped model is the dense GQA "
+                "decoder run one or more times a token; a model whose layers "
+                "differ in kind runs once")
         if self.shortcut_moe and not (self.latent and self.n_routed_experts):
             raise ValueError(
                 "shortcut_moe is a latent-attention layer pair round routed experts: "
@@ -468,12 +494,23 @@ class ModelConfig:
         return -(-self.latent_dim // 128) * 128
 
     @property
+    def layer_steps(self) -> int:
+        """Layer applications a token: the weight layers times the passes a
+        looped model makes over them (``num_layers`` where there is no loop)."""
+        return self.num_layers * self.loop_steps
+
+    @property
+    def looped(self) -> bool:
+        return self.loop_steps > 1
+
+    @property
     def paged_layers(self) -> int:
-        """Layers that keep pages in the paged engine's pool."""
+        """Layers that keep K/V in an engine's cache: CACHE layers. A looped
+        model keeps one a (pass, layer), ``layer_steps`` of them."""
         if self.latent:  # every SUBLAYER where a published layer holds two
             return len(self.layer_kinds)
         if not self.hybrid:
-            return self.num_layers
+            return self.layer_steps
         return sum(self.mixer_count(m) for m in ("sparse", "softmax", "cca"))
 
     def page_pool_shape(self, pages: int, page_size: int) -> tuple[int, ...]:
@@ -542,6 +579,19 @@ class ModelConfig:
                 "keeps one kind of K/V for every layer, and these layers keep "
                 f"{self.slot_state_names}. Use engine_impl='paged' without it."
             )
+
+    def refuse_looped(self, what: str) -> None:
+        """Raise where ``what`` has not been held to the reference for a model
+        whose layers run several times a token. The single owner of that
+        sentence for every engine and feature."""
+        if self.looped:
+            raise ValueError(
+                f"{what} is not supported for a looped model (model_type "
+                f"'ouro'): its {self.num_layers} weight layers run "
+                f"{self.loop_steps} times a token and keep {self.paged_layers} "
+                "cache layers, one a (pass, layer), and this path has not been "
+                "held to the reference over them. Use engine_impl='dense' or "
+                "'paged' (waves or the refill scheduler) without it.")
 
     @property
     def residual_scale(self) -> float:
@@ -816,6 +866,11 @@ class ModelConfig:
             attn = 2.0 * self.num_heads * (self.head_dim + self.value_head_dim) * (
                 self.mixer_count("softmax") * mean_kv_len
                 + self.mixer_count("window") * min(mean_kv_len, self.sliding_window))
+        if self.looped:
+            # the layers' weights and their attention once a PASS; the head once
+            head = self.hidden_size * self.vocab_size
+            return (2.0 * head + self.loop_steps * (
+                2.0 * (self.matmul_param_count - head) + attn))
         return 2.0 * self.matmul_param_count + attn
 
     def train_flops_per_token(self, seq_len: int) -> float:
@@ -844,6 +899,8 @@ class ModelConfig:
             return "zaya"
         if self.hybrid:
             return "minicpm_sala"
+        if self.looped or self.sublayer_out_norm:
+            return "ouro"
         if self.rmsnorm_offset:
             return "gemma"
         if self.sliding_window is not None:
@@ -935,6 +992,8 @@ class ModelConfig:
         if mt == "longcat_flash":
             hybrid = _shortcut_moe_fields(get)
             head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
+        if mt == "ouro":
+            hybrid = _looped_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -959,6 +1018,38 @@ class ModelConfig:
             sliding_window=int(window) if window else None,
             **hybrid,
         )
+
+
+def _looped_fields(get) -> dict:
+    """The ``ouro`` keys as ``ModelConfig`` fields: ``total_ut_steps`` passes
+    over the one stack of layers, every ``layer_types`` entry that is run
+    ``full_attention``. What the published file does not state (the two output
+    norms and their order, the final norm after every pass, the gate's form:
+    the readings under ``assumed`` in the benchmark's configuration file) is
+    the LoopLM family's published description. What this program cannot run is
+    refused by key."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"model_type 'ouro': {key} {get(key)!r} is not supported: {why}")
+
+    steps = get("total_ut_steps")
+    if not isinstance(steps, int) or steps < 1:
+        refuse("total_ut_steps", "the passes over the layers, a whole number of at least 1")
+    if float(get("early_exit_threshold", 1)) != 1.0:
+        refuse("early_exit_threshold",
+               "under 1 rows stop at different passes, and a step here runs every "
+               "pass for every row (the gates are computed and reported)")
+    if get("use_sliding_window", False):
+        refuse("use_sliding_window", "every layer attends its whole context")
+    if get("sliding_window") is not None:
+        refuse("sliding_window", "every layer attends its whole context")
+    if get("rope_scaling") is not None:
+        refuse("rope_scaling", "positions are rotated at rope_theta alone")
+    kinds = get("layer_types")
+    run = list(kinds or ())[: int(get("num_hidden_layers"))]
+    if kinds is not None and (len(run) < int(get("num_hidden_layers"))
+                              or set(run) != {"full_attention"}):
+        refuse("layer_types", "one 'full_attention' entry for every layer that is run")
+    return dict(loop_steps=steps, sublayer_out_norm=True)
 
 
 def _cca_fields(get) -> dict:
@@ -1603,8 +1694,17 @@ GEMMA_7B = ModelConfig(
     max_position_embeddings=8192,
 )
 
+# a looped model at a size the CPU tests run: 2 weight layers, 3 passes (so
+# that cache layer u * L + l and l * T + u differ), no grouping of the heads
+TINY_OURO = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
+    num_heads=4, num_kv_heads=4, head_dim=16, rope_theta=10000.0,
+    rms_norm_eps=1e-6, loop_steps=3, sublayer_out_norm=True,
+)
+
 PRESETS: dict[str, ModelConfig] = {
     "tiny": TINY,
+    "tiny-ouro": TINY_OURO,
     "tiny-latent-moe": TINY_LATENT_MOE,
     "tiny-delta-moe": TINY_DELTA_MOE,
     "tiny-power": TINY_POWER,

@@ -195,10 +195,17 @@ def _get(sd: Mapping[str, np.ndarray], name: str) -> np.ndarray:
 
 
 def _refuse_unnamed(cfg: ModelConfig) -> None:
-    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe``, ``mimo_v2_flash``, ``glm_moe_dsa`` or ``zaya`` checkpoint is refused by name, in both directions:
+    """A ``solar_open2``, ``brumby``, ``jamba``, ``exaone_moe``, ``mimo_v2_flash``, ``glm_moe_dsa``, ``zaya`` or ``ouro`` checkpoint is refused by name, in both directions:
     its published tensor names cannot be read here, and names guessed for the
     delta-rule layers' convolutions, low-rank pairs and gates would load or
     save something else under the model's name. Seeded weights only."""
+    if cfg.model_type == "ouro":
+        raise NotImplementedError(
+            "model_type 'ouro' checkpoints are not supported: the published tensor "
+            "names of its layers (the two norms on each sublayer's output, the exit "
+            "gate) cannot be checked here until the checkpoint's files are in the "
+            "repository, and a guessed name would load or save something else under "
+            "the model's name; the model runs from seeded weights only (init_params)")
     if cfg.index_topk:
         raise NotImplementedError(
             "model_type 'glm_moe_dsa' checkpoints are not supported: the published "

@@ -186,7 +186,11 @@ def _layer(
         )
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         att = att.reshape(b, s, cfg.q_dim)
-        x = x + proj(att, p, lora, "wo", "bo", lora_scale)
+        out = proj(att, p, lora, "wo", "bo", lora_scale)
+        if "attn_out_norm" in p:  # a sublayer normed on both sides (ouro)
+            out = rms_norm(out, p["attn_out_norm"], cfg.rms_norm_eps,
+                           offset=cfg.rmsnorm_offset)
+        x = x + out
 
     x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
     return x, cache_k, cache_v, cache_k_scale, cache_v_scale
@@ -205,6 +209,9 @@ def _mlp_half(x, p: Params, lora, *, cfg: ModelConfig, proj, lora_scale,
         gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
         up = proj(h, p, lora, "w_up", "b_up", lora_scale)
         y = proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
+        if "mlp_out_norm" in p:  # a sublayer normed on both sides (ouro)
+            y = rms_norm(y, p["mlp_out_norm"], cfg.rms_norm_eps,
+                         offset=cfg.rmsnorm_offset)
         return x + (y if residual_scale is None else residual_scale * y)
 
 
@@ -389,7 +396,7 @@ def _attend(
 
 
 _MLP_KEYS = frozenset(
-    ("mlp_norm", "w_gate", "w_up", "w_down", "b_gate", "b_up", "b_down")
+    ("mlp_norm", "mlp_out_norm", "w_gate", "w_up", "w_down", "b_gate", "b_up", "b_down")
 )
 # an expert layer's own leaves (models/moe.py); any other key is attention's
 _SLICE_SCOPES = {
@@ -519,8 +526,17 @@ def forward(
     dropout_rng: jax.Array | None = None,
     skip_lm_head: bool = False,  # return final-norm hidden states, not logits
     cache_read_formulation: str = "dot",  # see ops.attention.attention_cached
+    exit_gates: list | None = None,  # a looped model's gates [T, B, S] are appended
 ) -> tuple[jax.Array, Params | None]:
     """Decoder forward. Returns (logits f32 [B, S, V], updated kv_cache).
+
+    A looped model (``cfg.loop_steps`` > 1) walks ``params["layers"]`` that
+    many times: the final norm closes every pass and its output feeds the
+    next, the exit gate reads each pass's output (``_close_pass``), and with a
+    cache pass ``u``'s layer ``l`` reads and writes cache layer
+    ``u * num_layers + l`` of the ``cfg.paged_layers`` the cache holds. A
+    decode step adds its rows' expected exit pass to ``kv_cache["exit_stats"]``
+    where the cache carries it.
 
     Without a cache this is the training/prefill path (causal over the input);
     with a dense cache (per-layer tuples from init_kv_cache — NOT a stacked
@@ -621,9 +637,10 @@ def forward(
     )
 
     layer_keys = (
-        jax.random.split(dropout_rng, cfg.num_layers)
+        jax.random.split(dropout_rng, cfg.layer_steps)  # one a (pass, layer)
         if (dropout_rng is not None and lora_dropout > 0.0) else None
     )
+    gates = None  # a looped model's exit gates, one [B, S] a pass
     xs = (
         params["layers"],
         lora["layers"] if lora is not None else None,
@@ -643,7 +660,22 @@ def forward(
             # device's memory; ``True`` keeps nothing, as models/hybrid.py does
             scan_body = jax.checkpoint(scan_body, policy=(
                 jax.checkpoint_policies.nothing_saveable if remat is True else remat))
-        x, _ = jax.lax.scan(scan_body, x, xs)
+        if cfg.looped:
+            # one scan over the layers inside one scan over the passes: the
+            # layer body is compiled once, the adapters' gradient is the sum
+            # over the passes, and the backward pass finds what each (pass,
+            # layer) kept, ``loop_steps`` times a plain model's (learner/remat.py)
+            def one_pass(x, pass_keys):
+                x, _ = jax.lax.scan(scan_body, x, (*xs[:2], pass_keys))
+                return _close_pass(x, params, cfg)
+
+            x, gates = jax.lax.scan(
+                one_pass, x,
+                None if layer_keys is None else layer_keys.reshape(
+                    cfg.loop_steps, cfg.num_layers, *layer_keys.shape[1:]),
+                length=cfg.loop_steps)
+        else:
+            x, _ = jax.lax.scan(scan_body, x, xs)
         new_k = new_v = None
     else:
         # UNROLLED layer loop over PER-LAYER cache buffers. Carrying a stacked
@@ -657,21 +689,29 @@ def forward(
         # ``rollout-lockstep`` round; ledger, PR 44), so the engines hand these
         # programs a ``decode_view`` that holds them one array a layer.
         kv_quant = "k_scale" in kv_cache  # int8 dense cache carries scales
-        new_k, new_v, new_ks, new_vs = [], [], [], []
-        for i in range(cfg.num_layers):
-            p_i = _slice_layer(params["layers"], i)
-            lora_i = _slice_layer(lora["layers"], i) if lora is not None else None
-            key_i = layer_keys[i] if layer_keys is not None else None
-            x, ck, cv, cks, cvs = layer_fn(
-                x, p_i, lora_i, kv_cache["k"][i], kv_cache["v"][i],
-                cache_k_scale=kv_cache["k_scale"][i] if kv_quant else None,
-                cache_v_scale=kv_cache["v_scale"][i] if kv_quant else None,
-                dropout_rng=key_i,
-            )
-            new_k.append(ck)
-            new_v.append(cv)
-            new_ks.append(cks)
-            new_vs.append(cvs)
+        new_k, new_v = list(kv_cache["k"]), list(kv_cache["v"])
+        new_ks = list(kv_cache["k_scale"]) if kv_quant else [None] * len(new_k)
+        new_vs = list(kv_cache["v_scale"]) if kv_quant else [None] * len(new_k)
+        gates = [] if cfg.looped else None
+        sliced: dict = {}  # a weight layer's slices, shared by a looped model's passes
+        for u in range(cfg.loop_steps):
+            for l in range(cfg.num_layers):
+                if l not in sliced:
+                    sliced[l] = (
+                        _slice_layer(params["layers"], l),
+                        _slice_layer(lora["layers"], l) if lora is not None else None)
+                # a looped model's pass reads the keys and values THAT pass wrote
+                i = _cache_layer(cfg, u, l)
+                x, new_k[i], new_v[i], new_ks[i], new_vs[i] = layer_fn(
+                    x, *sliced[l], new_k[i], new_v[i],
+                    cache_k_scale=new_ks[i], cache_v_scale=new_vs[i],
+                    dropout_rng=(layer_keys[u * cfg.num_layers + l]
+                                 if layer_keys is not None else None),
+                )
+            if cfg.looped:
+                x, gate = _close_pass(x, params, cfg)
+                gates.append(gate)
+        gates = jnp.stack(gates) if cfg.looped else None  # [T, B, S], as the scan's
         new_k, new_v = tuple(new_k), tuple(new_v)
         new_scales = (
             {"k_scale": tuple(new_ks), "v_scale": tuple(new_vs)}
@@ -681,18 +721,74 @@ def forward(
     with jax.named_scope(telemetry.MODEL_HEAD):
         logits = _head(x, params, cfg, logits_slice, logits_positions, skip_lm_head)
 
+    if gates is not None and exit_gates is not None:
+        exit_gates.append(gates)
     if kv_cache is None:
         new_cache = None
     else:
         new_cache = {**kv_cache, "k": new_k, "v": new_v, **new_scales}
+        if gates is not None and s == 1 and "exit_stats" in kv_cache:
+            with jax.named_scope(telemetry.MODEL_EXIT_GATE):
+                new_cache["exit_stats"] = kv_cache["exit_stats"] + _exit_step_sums(
+                    gates[:, :, 0], kv_cache.get("alive"))
     return logits, new_cache
+
+
+def _cache_layer(cfg: ModelConfig, u: int, l: int) -> int:
+    """The cache layer of pass ``u``'s layer ``l``: pass-major, so that a
+    model with no loop keeps layer ``l`` at ``l``."""
+    return u * cfg.num_layers + l
+
+
+def _close_pass(x, params: Params, cfg: ModelConfig):
+    """What stands between a looped model's passes: the final norm, whose
+    output is the pass's ``h_u`` AND the next pass's input, and the exit gate
+    ``sigmoid(w . h_u + b)``, one ``Linear(hidden, 1)`` for every pass, in
+    float32. Returns ``(h_u, gate [B, S])``."""
+    with jax.named_scope(telemetry.MODEL_EXIT_GATE):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     offset=cfg.rmsnorm_offset)
+        gate = params["exit_gate"]
+        z = jnp.einsum("bsd,do->bso", x.astype(jnp.float32),
+                       gate["w"].astype(jnp.float32))[..., 0]
+        return x, jax.nn.sigmoid(z + gate["b"].astype(jnp.float32)[0])
+
+
+def exit_probabilities(gates: jax.Array) -> jax.Array:
+    """The exit distribution of gates ``[T, ...]``: ``p_u = g_u prod_{j<u}
+    (1 - g_j)`` for ``u < T - 1`` and the rest of the mass on the last pass."""
+    stay = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([(gates * before)[:-1], before[-1:]], axis=0)
+
+
+def _exit_step_sums(gates: jax.Array, alive) -> jax.Array:
+    """``[2]`` float32: over the rows of one decode step that are alive, the
+    sum of ``sum_u (u + 1) p_u`` (the pass the exit distribution of ``gates``
+    ``[T, rows]`` would stop at, in expectation) and the rows counted."""
+    steps = jnp.arange(1, gates.shape[0] + 1, dtype=jnp.float32)[:, None]
+    expected = (steps * exit_probabilities(gates)).sum(0)
+    counted = (jnp.ones_like(expected) if alive is None
+               else alive.astype(jnp.float32))
+    return jnp.stack([(expected * counted).sum(), counted.sum()])
+
+
+def exit_distribution(params: Params, cfg: ModelConfig, input_ids: jax.Array,
+                      **forward_kwargs) -> jax.Array:
+    """``[T, B, S]``: a looped model's exit distribution ``p_u`` a token, by
+    the forward as it is served or trained (``forward``'s keywords)."""
+    gates: list = []
+    forward(params, cfg, input_ids, skip_lm_head=True, exit_gates=gates,
+            **forward_kwargs)
+    return exit_probabilities(gates[0])
 
 
 def _head(x, params: Params, cfg: ModelConfig, logits_slice, logits_positions,
           skip_lm_head: bool) -> jax.Array:
     """Final norm, the positions the caller wants, and the output head."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
-                 offset=cfg.rmsnorm_offset)
+    if not cfg.looped:  # a looped model's last pass ended with it (_close_pass)
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     offset=cfg.rmsnorm_offset)
     if cfg.logit_scale != 1.0:  # muP: the head reads x / (hidden / dim_model_base)
         x = x * jnp.asarray(cfg.logit_scale, x.dtype)
     if logits_slice is not None:
@@ -732,7 +828,14 @@ def init_params(
         layers["bq"] = jnp.zeros((L, cfg.q_dim), dtype)
         layers["bk"] = jnp.zeros((L, cfg.kv_dim), dtype)
         layers["bv"] = jnp.zeros((L, cfg.kv_dim), dtype)
-    return _init_around_layers(init, cfg, layers, dtype)
+    if cfg.sublayer_out_norm:
+        layers["attn_out_norm"] = jnp.ones((L, cfg.hidden_size), dtype)
+        layers["mlp_out_norm"] = jnp.ones((L, cfg.hidden_size), dtype)
+    params = _init_around_layers(init, cfg, layers, dtype)
+    if cfg.looped:  # one Linear(hidden, 1) for every pass
+        params["exit_gate"] = {"w": init((cfg.hidden_size, 1)),
+                               "b": jnp.zeros((1,), dtype)}
+    return params
 
 
 def _normal_init(rng: jax.Array, draws: int, dtype):
@@ -775,7 +878,8 @@ def _init_around_layers(init, cfg: ModelConfig, layers: Params, dtype) -> Params
 def init_kv_cache(
     cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16
 ) -> Params:
-    """Per-layer tuples of [B, K, hd, Smax], S minormost.
+    """Per-CACHE-layer tuples of [B, K, hd, Smax], S minormost: one a layer,
+    and one a (pass, layer) of a looped model (``cfg.paged_layers``).
 
     Two deliberate choices, both required for the decode loop to update the
     cache in place (zero HBM temps, verified with compile memory_analysis):
@@ -785,8 +889,8 @@ def init_kv_cache(
     cache-sized layout-conversion copies)."""
     shape = (batch, cfg.num_kv_heads, cfg.head_dim, max_seq)
     return {
-        "k": tuple(jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)),
-        "v": tuple(jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)),
+        "k": tuple(jnp.zeros(shape, dtype) for _ in range(cfg.paged_layers)),
+        "v": tuple(jnp.zeros(shape, dtype) for _ in range(cfg.paged_layers)),
     }
 
 
@@ -799,8 +903,8 @@ def init_kv_cache_int8(cfg: ModelConfig, batch: int, max_seq: int) -> Params:
     shape = (batch, cfg.num_kv_heads, cfg.head_dim, max_seq)
     sshape = (batch, cfg.num_kv_heads, 1, max_seq)
     return {
-        "k": tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
-        "v": tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.num_layers)),
-        "k_scale": tuple(jnp.zeros(sshape, jnp.float32) for _ in range(cfg.num_layers)),
-        "v_scale": tuple(jnp.zeros(sshape, jnp.float32) for _ in range(cfg.num_layers)),
+        "k": tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.paged_layers)),
+        "v": tuple(jnp.zeros(shape, jnp.int8) for _ in range(cfg.paged_layers)),
+        "k_scale": tuple(jnp.zeros(sshape, jnp.float32) for _ in range(cfg.paged_layers)),
+        "v_scale": tuple(jnp.zeros(sshape, jnp.float32) for _ in range(cfg.paged_layers)),
     }

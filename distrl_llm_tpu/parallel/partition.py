@@ -73,6 +73,8 @@ _REPLICATED = {"wkv_a", "wkv_b", "router", "e_score_bias", "experts_gate",
 def _spec_for_path(path: tuple[str, ...], shape: tuple[int, ...]) -> P:
     ndim = len(shape)
     name = path[-1]
+    if len(path) >= 2 and path[-2] == "exit_gate":  # a looped model's gate: 2,049 values
+        return P(*([None] * ndim))
     if name in ("a", "b"):  # LoRA factor: path is (..., "layers", target, "a"|"b")
         target = path[-2]
         if name == "a":  # [L, in, r]

@@ -131,6 +131,17 @@ ENGINE_WINDOW_PAGES_VISIBLE = "engine/window_pages_visible"    # counter
 # decode state is built (engine/paged_engine.py::_file_slot_state), by every
 # paged family, tracing on or off, nothing fetched
 ENGINE_CACHE_TOKEN_BYTES = "engine/cache_token_bytes"          # gauge
+# a looped model (ouro): layer applications a round, weight layers x passes x
+# decode steps plus the same for the prefill forward, host arithmetic like
+# ``ops/paged_grid_steps``; a model that runs its layers once files nothing
+ENGINE_LOOP_LAYER_STEPS = "engine/loop_layer_steps"            # counter
+# a looped model's exit gates, over a round's decoded tokens: the mean of
+# ``sum_u (u + 1) p_u``, the pass at which the published exit distribution
+# would stop on average, between 1 and ``loop_steps`` (at the published
+# threshold of 1 every pass runs whatever it reads). Summed on the device in
+# the decode state (``mixer["exit_stats"]``: the sum and the tokens) and filed
+# at readback: no extra fetch. A model that runs its layers once files 1.0
+ENGINE_EXIT_STEP_MEAN = "engine/exit_step_mean"                # gauge
 # a learned index over tokens (glm_moe_dsa): per live row, layer and decode
 # step, the tokens attended (min(context, index_topk)) and the tokens latent
 # attention without the index would attend (the context), in the same units of
@@ -185,6 +196,10 @@ MODEL_LATENT_ATTN = "model/latent_attn"
 # the experts that compute nothing (longcat_flash, models/moe.py::zero_part):
 # the sum of a token's weights on them times the layer's normed input
 MODEL_MOE_ZERO = "model/moe_zero"
+# a looped model (ouro, models/transformer.py): what stands BETWEEN passes,
+# the final norm that closes each pass and the exit gate's product and
+# distribution; the layers of every pass stay under the names above
+MODEL_EXIT_GATE = "model/exit_gate"
 # a gated delta-rule model (solar_open2, ops/delta_attention.py): the
 # recurrence in both forms with its l2norm, decay and beta; the short
 # convolutions and their tail's update; both mixers' output gates and the
@@ -311,7 +326,7 @@ SCOPE_NAMES = (
     MODEL_DELTA_ATTN, MODEL_SHORT_CONV, MODEL_ATTN_GATE, MODEL_POWER_ATTN,
     MODEL_SSM, MODEL_WINDOW_ATTN,
     MODEL_INDEX_SCORE, MODEL_INDEX_SELECT, MODEL_INDEXED_ATTN, MODEL_CCA_MIX,
-    MODEL_MOE_ZERO,
+    MODEL_MOE_ZERO, MODEL_EXIT_GATE,
 )
 
 

@@ -1020,6 +1020,60 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
     assert memory.temp_size_in_bytes < 0.3e9
 
 
+def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
+    """``ouro-2.6b-L8.rollout-reasoning-loop4``'s decode step (64 rows, a table
+    of 19 pages, a rank-32 adapter) fed the decode view: eight weight layers
+    run four times are THIRTY-TWO unrolled layer bodies, each with a K and a V
+    pool of its own ``[16, pages, 128, 128]`` that is donated and written in
+    place, and each body's attention is ONE ``paged_attention_native`` launch
+    at 16 KV heads and a group of ONE query head (``[64, 16, 1, 128]`` queries:
+    the launch compiled as it stood, no padded group). The weights' slices fuse
+    into their matmuls in every pass (no copy of a weight's size, 0.07 GB of
+    temporaries when this was written beside 9.86 GB of arguments: 8.59 GB of
+    pools, 1.27 GB of weights with the view), and what stands between the
+    passes is under ``model/exit_gate``."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    cfg = _cell_config("ouro-2.6b-L8")
+    assert (cfg.num_layers, cfg.loop_steps, cfg.paged_layers) == (8, 4, 32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 64, 128, jnp.bfloat16
+    width = (2048 + 384) // page
+    pages = 4 * 16 + rows * 3
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pool = lambda: tuple(chip((16, pages, page, 128), bf) for _ in range(32))
+    cache = {
+        "k": pool(), "v": pool(), "exit_stats": chip((2,), jnp.float32),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       positions=cache["lengths"][:, None], page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 32 and all(
+        "%paged_attention_native" in c and "bf16[64,16,1,128]" in c for c in calls), calls[:2]
+    entry = text[text.index("ENTRY "):]
+    copies = [line.strip()[:160] for line in entry.splitlines() if " copy(" in line and (
+        f"bf16[16,{pages},128,128]" in line.split("(")[0]
+        or any(w in line.split("(")[0] for w in ("[2048,5632]", "[5632,2048]", "[2048,2048]")))]
+    assert not copies, copies
+    assert "model/exit_gate" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 64 * 16 * pages * page * 128 * 2
+    assert memory.temp_size_in_bytes < 0.3e9
+
+
 def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip, monkeypatch):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through five
     layers of latent attention behind the index, a dense MLP and four expert
