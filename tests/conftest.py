@@ -85,8 +85,8 @@ import threading  # noqa: E402
 
 import pytest  # noqa: E402
 
-#: seconds of wall a case may take, set-up and tear-down included (PR 62: the
-#: slowest case of the suite is a real-size TPU compile under 100 s). A case
+#: seconds of wall a case may take, set-up and tear-down included (the slowest
+#: case of the default run takes under a minute, of `slow` under three). A case
 #: that waits on a worker, a socket or a `jax.distributed` round fails BY NAME
 #: with the stack it was in, where before it ate the run's limit and showed as
 #: rc 124 with no name. `pytest-timeout` is not installed: this is its
@@ -116,11 +116,11 @@ def case_limit(request):
 
 @pytest.hookimpl(trylast=True)
 def pytest_collection_modifyitems(items):
-    """THE LONG CASES FIRST (PR 62): ``tests/test_tpu_compile.py``'s real-size
-    compiles take 25-96 s each and no cache serves them; the file sorted 77th
-    of 82, ``--dist load`` deals cases in collection order, and the driver's
-    run ended with three workers inside one each while three idled. Then the
-    families' conformance module A FAMILY AT A TIME: a family is one unit of
+    """THE LONGEST UNIT FIRST (PR 62, PR 69): ``tests/test_tpu_compile.py`` is
+    one unit of ``unit_of`` below, 65 compiles for the TPU that no cache serves,
+    two to five minutes of one worker; where it sorts by name, among the last
+    files, the run would end with that worker inside it while five idled. Then
+    the families' conformance module A FAMILY AT A TIME: a family is one unit of
     ``unit_of`` below, its cases go to one worker and share the engines it built
     (``tests/family_suite.py::engine``). One stable sort on the file's name and
     the ``family`` parameter, the same in every worker, as xdist requires, and
@@ -144,14 +144,40 @@ def unit_of(nodeid):
     imports no family); every other file is one
     unit (its module fixtures, ``shared_cell``'s runs of a tiny cell,
     ``jax.jit``'s own cache of a file's programs). ``tests/test_tpu_compile.py``
-    too, though its real-size compiles share nothing: the TPU's compiler runs
-    on every core it finds, and six of them at once took 1,489 worker-seconds
-    where one after another, beside five workers of other files, take 737
-    (and the longest 289 s of ``CASE_LIMIT_S`` where it takes 147)."""
+    too, though its compiles share nothing: one process may load the TPU's
+    library, and its compiler runs on every core it finds, so six of them at
+    once fight for the cores (PR 65: 1,489 worker-seconds where one after
+    another took 737)."""
     file, _, case = nodeid.partition("::")
     if file.endswith("test_family_conformance.py") and "[" in case:
         return f"{file}[{case.partition('[')[2].partition('-')[0]}]"
     return file
+
+
+def unit_seconds(reports, heaviest=10):
+    """WHERE A RUN'S TIME WENT, as lines for its log: the ``heaviest`` units of
+    ``unit_of`` by the seconds their cases held a worker (set-up, call and
+    tear-down, as the junit file sums them), heaviest first, under the total.
+    Every line starts with a word, so the driver's count of a log's dots
+    reads none of them."""
+    seconds = {}
+    for report in reports:
+        unit = unit_of(report.nodeid)
+        seconds[unit] = seconds.get(unit, 0.0) + report.duration
+    ranked = sorted(seconds.items(), key=lambda item: (-item[1], item[0]))[:heaviest]
+    return [f"worker-seconds {sum(seconds.values()):.0f} in {len(seconds)} units "
+            f"(tests/conftest.py::unit_of), the {len(ranked)} heaviest:",
+            *(f"unit {took:7.1f} s  {unit}" for unit, took in ranked)]
+
+
+def pytest_terminal_summary(terminalreporter):
+    """The table above at the end of every run, from the reports the process
+    that prints the summary was sent anyway (xdist's controller: its workers'):
+    a run that reaches its end leaves it in the driver's ``/tmp/_t1.log``."""
+    reports = [report for found in terminalreporter.stats.values() for report in found
+               if isinstance(report, pytest.TestReport)]
+    if reports:
+        terminalreporter.write_line("\n".join(unit_seconds(reports)))
 
 
 @pytest.hookimpl(optionalhook=True)
@@ -164,9 +190,8 @@ def pytest_xdist_make_scheduler(config, log):
     walls). Where ``--dist load`` is asked for (the driver's command, the
     README's; any other mode is xdist's as it stands), xdist's own scope scheduler
     with ``unit_of`` as the scope: a unit goes to one worker whole, units are
-    dealt in the collection's order (the long ones first, as ordered above; the
-    scheduler's own order, the units of most cases first, would deal the
-    real-size compiles last, six at once and the longest alone at the end)."""
+    dealt in the collection's order (the longest first, as ordered above, and
+    not in the scheduler's own, the units of most cases first)."""
     from xdist.scheduler import LoadScopeScheduling
 
     if config.getvalue("dist") != "load":

@@ -1,5 +1,26 @@
 """The main path's Pallas kernels compile for a TPU v5e, at model widths.
 
+WHICH RUN HOLDS WHAT (PR 69). The default run holds the kernels and the
+one-layer fragments, each a compile of seconds, and of every case that
+compiles a cell's WHOLE decode step or prefill its ``[one-period]`` form
+(``two_depths``): the cell's widths, rows, page size and adapter rank at the
+fewest layers that still hold what the case names (a period of the layer
+pattern; the one dense layer where the case is about the attention every layer
+shares), a prefill over the fewest segments. It asserts what is per layer or
+per launch: which kernel, with which operands, how many, no copy of a pool,
+ring, state or weight, the donated bytes written in place. ``slow`` holds the
+same body at the cell's own depth and context, ``[cell]``, with every byte
+limit as it stood, and five cases whole that a smaller shape cannot stand in
+for: a byte limit at the cell's size is all they hold, or (the learner's two)
+the stack is scanned and fewer layers compile no faster. The TPU's compiler
+runs on every core it finds, and a body is compiled once a KIND of layer and
+stage, not once a layer: with fifteen cases at the cells' size the file took
+2,333 of a run's 11,760 core-seconds (8 cores x 1,470 s); it takes 498. A
+case that compiles a cell's whole step at the cell's size enters ``slow`` and
+the default run gets its one-period twin. Who touched a cell's step, a
+kernel's launch or the pools runs ``python -m pytest -m slow
+tests/test_tpu_compile.py`` before the chip (eight minutes alone).
+
 Interpret mode proves a kernel's arithmetic; it never meets Mosaic's block
 rules, its memory spaces or the chip's VMEM. The TPU compiler is installed
 wherever JAX's TPU support is, and compiles for a chip that is DESCRIBED and
@@ -66,6 +87,16 @@ def assert_kernel(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     return text
+
+
+def two_depths(period, cell):
+    """ONE BODY, TWO DEPTHS, for a case that compiles a cell's whole decode
+    step, prefill or gradient: ``[one-period]`` in the default run and
+    ``[cell]``, the cell's own depth (and context), in ``slow``. The module's
+    docstring says what each holds."""
+    return pytest.mark.parametrize("depth", [
+        pytest.param(period, id="one-period"),
+        pytest.param(cell, id="cell", marks=pytest.mark.slow)])
 
 
 def kv_pages(chip, shape, quantized: bool):
@@ -259,13 +290,14 @@ def _sorts_under(text: str, scope: str) -> list[str]:
             if " sort(" in line and scope in line]
 
 
-def _kimi_cell():
-    """Kimi-VL-A3B's language model as ``kimi-vl-a3b-L7`` runs it: 7 layers,
-    every width as published."""
+def _kimi_cell(layers=7):
+    """Kimi-VL-A3B's language model as ``kimi-vl-a3b-L7`` runs it: 7 layers
+    (the first dense, then expert layers: two are a period), every width as
+    published."""
     from distrl_llm_tpu.models import ModelConfig
 
     return ModelConfig(
-        vocab_size=163840, hidden_size=2048, intermediate_size=11264, num_layers=7,
+        vocab_size=163840, hidden_size=2048, intermediate_size=11264, num_layers=layers,
         num_heads=16, num_kv_heads=16, head_dim=192, kv_lora_rank=512,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         n_routed_experts=64, n_shared_experts=2, experts_per_token=6,
@@ -341,9 +373,13 @@ def test_latent_decode_fragment_copies_neither_the_pool_nor_the_experts(
         assert "bf16[16,128,640]" in text and "bf16[256,128,640]" not in text
 
 
-def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch):
+@two_depths((1, 32), (7, 160))
+def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch, depth):
     """``rollout-longctx-latent``'s prefill (4 prompts of 20,480 in segments of
-    1,024 through Kimi-VL-A3B's 7 layers at the published widths): on a TPU
+    1,024 through Kimi-VL-A3B's 7 layers at the published widths; in the
+    default run the dense layer alone, whose latent attention is every
+    layer's, over 4,096 tokens, the fewest segments that still run in two
+    stages, and the chip's program alone): on a TPU
     every fold of a block of keys is ``expanded_fold_kernel`` under
     ``model/attn_core``, a block's float32 scores ``[4, 16, 1024, 1024]``
     (268 MB, written and re-read about six times a fold by the XLA form) are
@@ -354,17 +390,18 @@ def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch
     from distrl_llm_tpu.models import init_lora_params, init_params
     from distrl_llm_tpu.ops import latent_attention as la
 
-    cfg = _kimi_cell()
+    layers, prompt_pages = depth
+    cfg = _kimi_cell(layers)
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     params = place(jax.eval_shape(functools.partial(
         init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
     lora = place(jax.eval_shape(
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
     prefill = functools.partial(
-        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=160, page_size=128,
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=prompt_pages, page_size=128,
         lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
-        total_tokens=20480 + 640)
-    tokens = chip((4, 20480), jnp.int32)
+        total_tokens=prompt_pages * 128 + 640)
+    tokens = chip((4, prompt_pages * 128), jnp.int32)
 
     def compiled(backend):
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -381,11 +418,13 @@ def test_latent_prefill_segment_keeps_its_scores_in_the_kernel(chip, monkeypatch
     assert calls and all("model/attn_core" in line for line in calls), calls
     # the prefill's stages (PR 52): one segment body a size of the ladder, and
     # in each a layer's fold is the kernel, at the stage's batch of 4 and of 2
-    sizes = paged_engine._stage_sizes(4, 20)
+    sizes = paged_engine._stage_sizes(4, prompt_pages // 8)
     assert sizes == (4, 2) and _entry_whiles(text) == len(sizes)
     for rows in sizes:
         assert any(f"f32[{rows},16,1024,128]" in line for line in calls), (rows, calls)
         assert f"f32[{rows},16,1024,1024]" not in text
+    if layers < 7:
+        return  # the XLA form's program, a second compile, is the cell's to hold
     parents_text, parents = compiled("cpu")
     assert "f32[4,16,1024,1024]" in parents_text and "tpu_custom_call" not in parents_text
     # 457.6 against 464.0 MB at one stage, when this was written: the scores'
@@ -615,18 +654,20 @@ def _jamba_decode_step(chip, monkeypatch, cfg, rows=480, page=128):
     return compiled, cache
 
 
-def test_state_space_decode_step_at_published_widths(chip, monkeypatch):
+@two_depths((3, 3, 1), (14, 14, 7))
+def test_state_space_decode_step_at_published_widths(chip, monkeypatch, depth):
     """One period of AI21-Jamba2-3B (14 layers at the published widths:
-    attention at 7, thirteen Mamba layers, the tied head over 65,536) as a
+    attention at 7, thirteen Mamba layers, the tied head over 65,536; in the
+    default run a Mamba layer on either side of the attention layer) as a
     decode step of the cell's 480 rows. The attention layer's decode is
     ``paged_attention_native`` at ONE KV head and a query group of 20 (no other
     cell runs K < 2 or a group that is no multiple of 8). The head reads the
     ``[65536, 2560]`` table where it lies: no copy and no transpose of it. The
-    thirteen states (``[480, 16, 5120]`` float32, the channels along the lanes)
+    states (``[480, 16, 5120]`` float32, the channels along the lanes)
     are donated and updated in place: ONE fusion a layer reads a state, and it
     both writes the new state and reduces it against C, so a step moves a
     state once in and once out; no second state is kept live."""
-    cfg = jamba_config(14)
+    cfg = jamba_config(*depth)
     compiled, cache = _jamba_decode_step(chip, monkeypatch, cfg)
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -641,7 +682,7 @@ def test_state_space_decode_step_at_published_widths(chip, monkeypatch):
               if " copy(" in line and held in line.split("(")[0]]
     assert not copies, copies
     memory = compiled.memory_analysis()
-    states = 13 * 480 * 16 * 5120 * 4
+    states = (cfg.num_layers - 1) * 480 * 16 * 5120 * 4
     assert memory.alias_size_in_bytes >= states  # every state's output is its input's buffer
     assert memory.temp_size_in_bytes < 480 * 16 * 5120 * 4 + 250e6  # not a second set of states
     entry = text[text.index("ENTRY "):]
@@ -652,6 +693,7 @@ def test_state_space_decode_step_at_published_widths(chip, monkeypatch):
         assert "f32[480,5120]" in readers[0] and held in readers[0], readers  # y and the state
 
 
+@pytest.mark.slow
 def test_state_space_prefill_keeps_one_layers_segment(chip, monkeypatch):
     """The cell's prefill (30 prompts of 2,048 in segments of 1,024 through two
     attention and six Mamba layers at the published widths): the window is read
@@ -676,8 +718,9 @@ def test_state_space_prefill_keeps_one_layers_segment(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
-def _cell_config(name: str):
-    """A cell's configuration as ``perfbench/configs/<name>.json`` states it."""
+def _cell_config(name: str, **cut):
+    """A cell's configuration as ``perfbench/configs/<name>.json`` states it;
+    ``cut`` replaces the keys that give its depth, and no other."""
     import json
     import os
     from types import SimpleNamespace
@@ -687,7 +730,7 @@ def _cell_config(name: str):
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                         "perfbench", "configs", f"{name}.json")
     with open(path) as f:
-        return ModelConfig.from_hf_config(SimpleNamespace(**json.load(f)))
+        return ModelConfig.from_hf_config(SimpleNamespace(**{**json.load(f), **cut}))
 
 
 def _exaone_cell():
@@ -748,9 +791,11 @@ def test_window_decode_step_at_published_widths(chip, monkeypatch):
     assert not _weight_sized_operations(text, sizes)
 
 
-def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
+@two_depths(2, 7)
+def test_sink_window_decode_step_at_published_widths(chip, monkeypatch, depth):
     """``mimo-v2-flash-ep16-L7.rollout-longctx-sink-128``'s decode step (128
-    rows, a table of 164 pages, a rank-32 adapter) fed the decode view. The two
+    rows, a table of 164 pages, a rank-32 adapter; in the default run the first
+    full layer and the first window layer) fed the decode view. The two
     full layers' decode is the ``paged_attention_native`` launch, where the
     ``kernel.*`` regexes look for it, at 4 KV heads and a GROUP OF 16, K in 256
     lanes (a key's 192 values and zeros: ``ModelConfig.key_row``) and V at its
@@ -765,7 +810,9 @@ def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
 
-    cfg = _cell_config("mimo-v2-flash-ep16-L7")
+    cfg = _cell_config("mimo-v2-flash-ep16-L7", num_hidden_layers=depth)
+    full = sum(kind.startswith("softmax") for kind in cfg.layer_kinds)
+    assert (full, cfg.num_layers - full) == {2: (1, 1), 7: (2, 5)}[depth]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     rows, page, bf = 128, 128, jnp.bfloat16
@@ -780,7 +827,7 @@ def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
     k_pool = chip(cfg.page_pool_shape(pages, page), bf)
     v_pool = chip(cfg.second_pool_shape(pages, page), bf)
     cache = {
-        "k": (k_pool, k_pool), "v": (v_pool, v_pool),
+        "k": (k_pool,) * full, "v": (v_pool,) * full,
         **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
         "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
         "alive": chip((rows,), jnp.bool_)}
@@ -794,7 +841,7 @@ def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 2 and all("%paged_attention_native" in c for c in calls), calls
+    assert len(calls) == full and all("%paged_attention_native" in c for c in calls), calls
     # rows, 4 KV heads, their groups of 16, V's width
     assert all("bf16[128,4,16,128]" in c for c in calls), calls
     entry = text[text.index("ENTRY "):]
@@ -804,13 +851,14 @@ def test_sink_window_decode_step_at_published_widths(chip, monkeypatch):
         copies = [line.strip()[:160] for line in entry.splitlines()
                   if " copy(" in line and held in line.split("(")[0]]
         assert not copies, copies
-    rings = 5 * rows * 8 * 128 * (256 + 128) * 2
-    pools = 2 * 4 * pages * 128 * (256 + 128) * 2
+    rings = (cfg.num_layers - full) * rows * 8 * 128 * (256 + 128) * 2
+    pools = full * 4 * pages * 128 * (256 + 128) * 2
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= rings + pools
     assert memory.temp_size_in_bytes < 64e6
 
 
+@pytest.mark.slow
 def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip, monkeypatch):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through
     four window layers, one full layer and four expert layers in the grouped
@@ -842,13 +890,15 @@ def test_window_prefill_segment_stays_under_two_gigabytes_of_temporaries(chip, m
     assert compiled.memory_analysis().temp_size_in_bytes < 1.08 * 1.5064e9
 
 
-def test_cca_decode_step_at_published_widths(chip, monkeypatch):
+@two_depths(2, 20)
+def test_cca_decode_step_at_published_widths(chip, monkeypatch, depth):
     """``zaya1-8b-L20.rollout-reasoning-cca``'s decode step (192 rows, a table
-    of 21 pages, a rank-32 adapter) fed the decode view. Every layer's page walk
+    of 21 pages, a rank-32 adapter; every layer is alike, and two make a stack
+    to slice) fed the decode view. Every layer's page walk
     is the ONE ``paged_attention_native`` launch the ``kernel.*`` regexes look
     for, at 2 KV heads and a group of 4; what compressed convolutional attention
-    adds round it is plain XLA over ``[192, 1280]`` rows. The forty pools and
-    the twenty tails (``[192, 2688]`` bf16) are donated and written in place: no
+    adds round it is plain XLA over ``[192, 1280]`` rows. The two pools and
+    the tail a layer (``[192, 2688]`` bf16) are donated and written in place: no
     copy of a pool or a tail. No projection is copied or sliced (the view holds
     q, k, the value's two halves and o), and no layer's sixteen experts are
     sliced out of the stack: the grouped form indexes (layer, expert)."""
@@ -856,7 +906,7 @@ def test_cca_decode_step_at_published_widths(chip, monkeypatch):
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
 
-    cfg = _cell_config("zaya1-8b-L20")
+    cfg = _cell_config("zaya1-8b-L20", num_hidden_layers=depth)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     rows, page, bf = 192, 128, jnp.bfloat16
@@ -867,11 +917,11 @@ def test_cca_decode_step_at_published_widths(chip, monkeypatch):
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
     pool = chip((2, 12 * 16 + rows * 5 + 8, page, 128), bf)
     cache = {
-        "k": (pool,) * 20, "v": (pool,) * 20,
+        "k": (pool,) * depth, "v": (pool,) * depth,
         **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
         "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
         "alive": chip((rows,), jnp.bool_)}
-    assert [x.shape for x in cache["cca_tail"]] == [(rows, 2688)] * 20
+    assert [x.shape for x in cache["cca_tail"]] == [(rows, 2688)] * depth
 
     def step(params, lora, cache, ids):
         return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
@@ -882,14 +932,14 @@ def test_cca_decode_step_at_published_widths(chip, monkeypatch):
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 20 and all("%paged_attention_native" in c for c in calls), calls[:2]
+    assert len(calls) == depth and all("%paged_attention_native" in c for c in calls), calls[:2]
     assert "bf16[192,2,4,128]" in calls[0], calls[0]  # rows, 2 KV heads, their groups of 4
     entry = text[text.index("ENTRY "):]
     held = ("bf16[2,1160,128,128]", "bf16[192,2688]")
     copies = [line.strip()[:160] for line in entry.splitlines()
               if " copy(" in line and any(shape in line.split("(")[0] for shape in held)]
     assert not copies, copies
-    pools, tails = 40 * pool.size * 2, 20 * rows * 2688 * 2
+    pools, tails = 2 * depth * pool.size * 2, depth * rows * 2688 * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= pools + tails
     sizes = {2048 * 1024, 2048 * 256, 2048 * 128}  # q and o, k, a half of the value
     assert not _weight_sized_operations(text, sizes)
@@ -897,14 +947,18 @@ def test_cca_decode_step_at_published_widths(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
-def _glm_cell():
-    """Five layers at the published widths, 16 of 256 experts held, the index whole."""
-    return _cell_config("glm-5-ep16-L5")
+def _glm_cell(layers=5):
+    """Five layers at the published widths (the first's MLP dense, then expert
+    layers: two are a period), 16 of 256 experts held, the index whole."""
+    return _cell_config("glm-5-ep16-L5", num_hidden_layers=layers)
 
 
-def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
+@two_depths((2, 32), (5, 164))
+def test_indexed_decode_step_at_published_widths(chip, monkeypatch, depth):
     """``glm-5-ep16-L5.rollout-longctx-indexed``'s decode step (64 rows, a table
-    of 164 pages, a rank-32 adapter) fed the decode view. Both paged
+    of 164 pages, a rank-32 adapter; in the default run the dense layer and one
+    expert layer under a table of 32 pages, 4,096 positions, still fewer than
+    16 rows choose) fed the decode view. Both paged
     arrays of every layer (latent rows ``[pages, 128, 640]``, index keys
     ``[pages, 128, 128]``) are donated and written in place by a point scatter:
     no copy of a pool. The exact choice sorts nothing (PR 55), and since PR 63
@@ -919,19 +973,19 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
 
-    cfg = _glm_cell()
+    layers, width = depth
+    cfg = _glm_cell(layers)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     rows, page, bf = 64, 128, jnp.bfloat16
-    width = (20480 + 512) // page
     params = jax.eval_shape(
         functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
     lora = place(jax.eval_shape(
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
     pages = 4 * 160 + rows * 5 + 8
     cache = {
-        "k": tuple(chip((pages, page, 640), bf) for _ in range(5)),
-        "v": tuple(chip((pages, page, 128), bf) for _ in range(5)),
+        "k": tuple(chip((pages, page, 640), bf) for _ in range(layers)),
+        "v": tuple(chip((pages, page, 128), bf) for _ in range(layers)),
         **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
         "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
         "alive": chip((rows,), jnp.bool_)}
@@ -945,7 +999,7 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 5 and all(
+    assert len(calls) == layers and all(
         "%absorbed_decode_kernel" in c and "model/indexed_attn" in c for c in calls), calls[:2]
     entry = text[text.index("ENTRY "):]
     for pool in (f"bf16[{pages},128,640]", f"bf16[{pages},128,128]"):
@@ -955,14 +1009,16 @@ def test_indexed_decode_step_at_published_widths(chip, monkeypatch):
     assert "bf16[64,2048,640]" not in text  # the chosen rows are not gathered
     assert _sorts_under(text, "model/index_select") == []  # chosen by counting (PR 55)
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= 5 * pages * page * (640 + 128) * 2
+    assert memory.alias_size_in_bytes >= layers * pages * page * (640 + 128) * 2
     assert memory.temp_size_in_bytes < 0.5e9
 
 
-def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
+@two_depths(1, 4)
+def test_shortcut_decode_step_at_published_widths(chip, monkeypatch, depth):
     """``longcat-flash-ep32-L4.rollout-reasoning-zero-256``'s decode step (256
     rows, a table of 20 pages, a rank-32 adapter) fed the decode view: four
-    published layers are EIGHT sublayers, each with a latent pool of its own
+    published layers are EIGHT sublayers (a period is one layer: the sublayer
+    the experts fork from and the one they join), each with a latent pool of its own
     ``[pages, 128, 640]`` that is donated and written in place by a point
     scatter (no copy of a pool), and each sublayer's attention is ONE Mosaic
     launch under ``model/latent_attn`` (64 heads: the same
@@ -980,8 +1036,8 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
     from distrl_llm_tpu.models.hybrid import init_mixer_state
     from distrl_llm_tpu.models.transformer import decode_view
 
-    cfg = _cell_config("longcat-flash-ep32-L4")
-    assert cfg.num_layers == 4 and cfg.paged_layers == 8
+    cfg = _cell_config("longcat-flash-ep32-L4", num_layers=depth)
+    assert cfg.num_layers == depth and cfg.paged_layers == 2 * depth
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     rows, page, bf = 256, 128, jnp.bfloat16
@@ -992,7 +1048,7 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
     pages = 16 * 16 + rows * 5 + 8
     cache = {
-        "k": tuple(chip((pages, page, 640), bf) for _ in range(8)), "v": (),
+        "k": tuple(chip((pages, page, 640), bf) for _ in range(2 * depth)), "v": (),
         **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
         "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
         "alive": chip((rows,), jnp.bool_)}
@@ -1006,7 +1062,7 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 8 and all(
+    assert len(calls) == 2 * depth and all(
         "%absorbed_decode_kernel" in c and "model/latent_attn" in c for c in calls), calls[:2]
     entry = text[text.index("ENTRY "):]
     copies = [line.strip()[:160] for line in entry.splitlines()
@@ -1016,14 +1072,16 @@ def test_shortcut_decode_step_at_published_widths(chip, monkeypatch):
     assert "conditional(" not in text and "model/moe_experts" in text
     assert "model/moe_zero" in text
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= 8 * pages * page * 640 * 2
+    assert memory.alias_size_in_bytes >= 2 * depth * pages * page * 640 * 2
     assert memory.temp_size_in_bytes < 0.3e9
 
 
-def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
+@two_depths((2, 2), (8, 4))
+def test_looped_decode_step_at_the_cells_size(chip, monkeypatch, depth):
     """``ouro-2.6b-L8.rollout-reasoning-loop4``'s decode step (64 rows, a table
     of 19 pages, a rank-32 adapter) fed the decode view: eight weight layers
-    run four times are THIRTY-TWO unrolled layer bodies, each with a K and a V
+    run four times are THIRTY-TWO unrolled layer bodies (the period: a stack
+    of two weight layers run twice, one boundary between passes), each with a K and a V
     pool of its own ``[16, pages, 128, 128]`` that is donated and written in
     place, and each body's attention is ONE ``paged_attention_native`` launch
     at 16 KV heads and a group of ONE query head (``[64, 16, 1, 128]`` queries:
@@ -1035,8 +1093,9 @@ def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
     from distrl_llm_tpu.models import forward, init_lora_params, init_params
     from distrl_llm_tpu.models.transformer import decode_view
 
-    cfg = _cell_config("ouro-2.6b-L8")
-    assert (cfg.num_layers, cfg.loop_steps, cfg.paged_layers) == (8, 4, 32)
+    layers, passes = depth
+    cfg = _cell_config("ouro-2.6b-L8", num_hidden_layers=layers, total_ut_steps=passes)
+    assert (cfg.num_layers, cfg.loop_steps, cfg.paged_layers) == (*depth, layers * passes)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     rows, page, bf = 64, 128, jnp.bfloat16
@@ -1046,7 +1105,7 @@ def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
         functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
     lora = place(jax.eval_shape(
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
-    pool = lambda: tuple(chip((16, pages, page, 128), bf) for _ in range(32))
+    pool = lambda: tuple(chip((16, pages, page, 128), bf) for _ in range(cfg.paged_layers))
     cache = {
         "k": pool(), "v": pool(), "exit_stats": chip((2,), jnp.float32),
         "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
@@ -1061,7 +1120,7 @@ def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
         chip((rows, 1), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 32 and all(
+    assert len(calls) == cfg.paged_layers and all(
         "%paged_attention_native" in c and "bf16[64,16,1,128]" in c for c in calls), calls[:2]
     entry = text[text.index("ENTRY "):]
     copies = [line.strip()[:160] for line in entry.splitlines() if " copy(" in line and (
@@ -1070,14 +1129,19 @@ def test_looped_decode_step_at_the_cells_size(chip, monkeypatch):
     assert not copies, copies
     assert "model/exit_gate" in text
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= 64 * 16 * pages * page * 128 * 2
+    assert memory.alias_size_in_bytes >= 2 * cfg.paged_layers * 16 * pages * page * 128 * 2
     assert memory.temp_size_in_bytes < 0.3e9
 
 
-def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip, monkeypatch):
+@two_depths((1, 16, (4,)), (5, 160, (4, 2)))
+def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(
+        chip, monkeypatch, depth):
     """The cell's prefill (4 prompts of 20,480 in segments of 1,024 through five
     layers of latent attention behind the index, a dense MLP and four expert
-    layers in the grouped form, at the published widths): a segment's index
+    layers in the grouped form, at the published widths; in the default run
+    the dense layer alone, whose index and latent attention are every layer's,
+    over 2,048 tokens: ONE stage, whose body is half the compile, and the mask
+    ``[4, 1024, 2048]``): a segment's index
     scores are made a block of 1,024 keys at a time into one ``[4, 1024,
     20480]`` float32 array (336 MB) and its choice is a mask of the same shape,
     one byte a pair, not a ``[.., 32 heads, 20480]`` product (10.7 GB). On a
@@ -1093,7 +1157,8 @@ def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip
     from distrl_llm_tpu.models import init_lora_params, init_params
     from distrl_llm_tpu.ops import latent_attention as la
 
-    cfg = _glm_cell()
+    layers, prompt_pages, stages = depth
+    cfg, prompt = _glm_cell(layers), prompt_pages * 128
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(la, "dispatch_choices", {})
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
@@ -1102,21 +1167,21 @@ def test_indexed_prefill_segment_stays_under_three_gigabytes_of_temporaries(chip
     lora = place(jax.eval_shape(
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
     prefill = functools.partial(
-        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=160, page_size=128,
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=prompt_pages, page_size=128,
         lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
-        total_tokens=20480 + 512)
+        total_tokens=prompt + 512)
     compiled = jax.jit(lambda *a: prefill(*a)).lower(
-        params, lora, chip((4, 20480), jnp.int32), chip((4, 20480), jnp.int32)).compile()
+        params, lora, chip((4, prompt), jnp.int32), chip((4, prompt), jnp.int32)).compile()
     assert la.dispatch_choices == {
         la.dispatch_key(64, 192, 64, 256, 1024, jnp.bfloat16): "kernel"}
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all("model/attn_core" in line for line in calls), calls
-    sizes = paged_engine._stage_sizes(4, 20)
-    assert sizes == (4, 2) and _entry_whiles(text) == len(sizes)
+    sizes = paged_engine._stage_sizes(4, prompt_pages // 8)
+    assert sizes == stages and _entry_whiles(text) == len(sizes)
     for rows in sizes:
-        assert any(f"f32[{rows},64,1024,256]" in line and f"s8[{rows},1024,20480]" in line
+        assert any(f"f32[{rows},64,1024,256]" in line and f"s8[{rows},1024,{prompt}]" in line
                    for line in calls), (rows, calls)
         assert f"f32[{rows},64,1024,1024]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.45e9
@@ -1175,6 +1240,7 @@ def test_the_indexed_models_choice_compiles_to_no_sort(chip, monkeypatch, progra
     assert _sorts_under(text, telemetry.MODEL_INDEX_SELECT) == []
 
 
+@pytest.mark.slow
 def test_the_indexed_cells_float32_check_fits_beside_the_engine(chip):
     """The check of ``glm-5-ep16-L5.rollout-longctx-indexed`` runs
     ``perfbench/reference_dsa_moe.py`` over 4 rows of 20,992 tokens beside 7.9
@@ -1205,9 +1271,12 @@ def test_the_indexed_cells_float32_check_fits_beside_the_engine(chip):
     assert compiled.memory_analysis().argument_size_in_bytes > 9.4e9
 
 
-def test_retention_prefill_is_one_stage_and_no_larger_than_it_was(chip):
+@two_depths((1, 32), (4, 128))
+def test_retention_prefill_is_one_stage_and_no_larger_than_it_was(chip, depth):
     """``rollout-retention-16k``'s prefill (2 prompts of 16,384 in segments of
-    1,024 through Brumby-14B's first four layers at the published widths): a
+    1,024 through Brumby-14B's first four layers at the published widths; the
+    period: one layer, every layer being alike, over 4,096 tokens, where a
+    prefill of more rows would take a second stage): a
     prefill of two rows is ONE stage (``_stage_sizes``: no stage holds a single
     row; a one-row body of this model is 2.6 times the two-row body's text and
     8 MB more executable, PERF.md §6, PR 52), and one stage sorts no row, so
@@ -1216,19 +1285,20 @@ def test_retention_prefill_is_one_stage_and_no_larger_than_it_was(chip):
     from distrl_llm_tpu.engine import paged_engine
     from distrl_llm_tpu.models import init_lora_params, init_params
 
-    cfg = _cell_config("brumby-14b-L4")
+    layers, prompt_pages = depth
+    cfg = _cell_config("brumby-14b-L4", num_hidden_layers=layers)
     place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
     params = place(jax.eval_shape(functools.partial(
         init_params, cfg=cfg, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
     lora = place(jax.eval_shape(
         lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    tokens = chip((2, prompt_pages * 128), jnp.int32)
     prefill = functools.partial(
-        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=128, page_size=128,
+        paged_engine._paged_prefill_hybrid, cfg=cfg, prompt_pages=prompt_pages, page_size=128,
         lora_scale=0.5, cache_dtype=jnp.bfloat16, attn_impl="reference",
-        total_tokens=16384 + 256)
-    compiled = jax.jit(prefill).lower(
-        params, lora, chip((2, 16384), jnp.int32), chip((2, 16384), jnp.int32)).compile()
-    assert paged_engine._stage_sizes(2, 16) == (2,)
+        total_tokens=prompt_pages * 128 + 256)
+    compiled = jax.jit(prefill).lower(params, lora, tokens, tokens).compile()
+    assert paged_engine._stage_sizes(2, prompt_pages // 8) == (2,)
     assert _entry_whiles(compiled.as_text()) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * 792.2e6
 
@@ -1431,6 +1501,7 @@ def test_decode_step_reads_the_view_without_a_copy_of_a_projection(chip, family)
     assert not found["view"], found["view"]
 
 
+@pytest.mark.slow
 def test_kept_products_leave_the_backward_and_take_their_own_bytes(chip):
     """The learner's micro-batch gradient at ``learner-1k``'s shape (Qwen2.5-7B's
     widths, 14 layers, ``[4, 1024]``, rank 32, chunked cross-entropy) with
@@ -1531,6 +1602,7 @@ def test_kept_products_leave_the_backward_and_take_their_own_bytes(chip):
         assert not kept["weight_sized"] - none["weight_sized"], (none, kept)
 
 
+@pytest.mark.slow
 def test_a_full_mode_step_stays_under_the_working_set_the_rule_subtracts(chip, monkeypatch):
     """The WHOLE train step in ``full`` mode (the trainable tree a float32
     copy of every weight, so float32 products; the accumulator, a

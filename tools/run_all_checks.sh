@@ -195,6 +195,12 @@ stage "suite_io" timeout 600 python -m pytest -q \
 stage "suite_slow_engines" timeout 1200 python -m pytest -q -m slow \
   tests/test_engine.py tests/test_paged.py tests/test_sharded_paged.py \
   tests/test_inflight_updates.py
+# the cells' whole steps and prefills compiled for the described v5e at the
+# cells' OWN depth and context, each byte limit with them (14 cases, eight
+# minutes on four cores; the default run holds their one-period forms): run it
+# before the chip after touching a cell's step, a kernel's launch or the pools
+stage "suite_slow_tpu_compile" timeout 1200 python -m pytest -q -m slow \
+  tests/test_tpu_compile.py
 stage "suite_slow_sched" timeout 1200 python -m pytest -q -m slow \
   tests/test_speculative.py tests/test_paged_budget.py \
   tests/test_prefix_sharing.py
