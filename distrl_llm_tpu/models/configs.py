@@ -127,6 +127,26 @@ arithmetic size by. ``sublayer_out_norm`` norms each sublayer's OUTPUT too
 (``x + N2(attn(N1 x))``, ``x + N4(mlp(N3 x))``). The published
 ``early_exit_threshold`` of 1 runs every pass for every token; the gates are
 computed and reported, and decide nothing.
+
+A state-space expert model whose layers are ONE sublayer each (``nemotron_h``,
+NVIDIA-Nemotron-3-Nano-30B-A3B) places three kinds by the characters of
+``hybrid_override_pattern`` (kept WHOLE when the depth is cut, like every
+per-layer list here; ``mixer_types`` holds them by name, "mamba-2",
+"attention-only" and "moe"): ``M`` -> "mamba2"
+(Mamba-2, ``ops/ssd.py``: ``ssd_heads`` heads of ``ssd_head_dim`` whose
+``B`` and ``C`` are shared by the heads of one of ``ssd_groups`` groups, ONE
+scalar decay a head; a slot keeps a float32 state ``[heads, head_dim,
+mamba_d_state]`` a layer, the state's columns along the lanes, and the last
+``mamba_d_conv - 1`` tokens of the convolution's input, x, B and C alike, kept
+flat ``[rows, 3 x channels]``),
+``*`` -> "softmax_alone" (GQA attention without RoPE, K/V pages, and NO second
+half) and ``E`` -> "experts" (NO mixer: the routed experts HELD, under a share
+as above, beside one shared expert of ``shared_expert_width``). A layer is
+``x + Mixer(RMSNorm(x))``: ``layer_ffn`` says "none" for the first two kinds
+and ``mixer_of`` a kind's mixer. An expert of this family is UNGATED,
+``W_down relu(W_up h)^2``: its stacks hold no gate (``models/moe.py`` reads
+the form off the stack), and so is the shared expert. The family's dense
+relu^2 MLP layer (``-``) is refused by name.
 """
 
 from __future__ import annotations
@@ -146,10 +166,16 @@ MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning",
                # exaone_moe publishes ``layer_types``
                "sliding_attention": "window", "full_attention": "softmax",
                # zaya publishes ``layer_types``, every entry "hybrid"
-               "hybrid": "cca"}
+               "hybrid": "cca",
+               # nemotron_h publishes a pattern of characters; from_hf_config
+               # names every layer (M, *, E): each kind is ONE sublayer
+               "mamba-2": "mamba2", "attention-only": "softmax_alone", "moe": "experts"}
 #: what a kind's name carries where its layer's second half is the dense gated
 #: MLP in a model whose other layers have routed experts (``mlp_types``)
 DENSE_FFN = "_dense"
+#: what a kind's name carries where its layer is the mixer ALONE, with no second
+#: half at all (nemotron_h's attention layers; its "mamba2" kind is always so)
+ALONE = "_alone"
 
 
 #: lanes of a tile's minor dimension on the chip: what ``ModelConfig.key_row``
@@ -159,12 +185,12 @@ KEY_ROW_LANES = 128
 
 def mixer_of(kind: str) -> str:
     """A layer kind's MIXER, whatever its second half is."""
-    return kind.removesuffix(DENSE_FFN)
+    return kind.removesuffix(DENSE_FFN).removesuffix(ALONE)
 #: ``model_type`` values ``from_hf_config`` can represent; "" is a bare config
 KNOWN_MODEL_TYPES = (
     "", "qwen2", "llama", "mistral", "gemma", "minicpm_sala", "deepseek_v3",
     "solar_open2", "brumby", "jamba", "exaone_moe", "glm_moe_dsa", "zaya",
-    "mimo_v2_flash", "longcat_flash", "ouro",
+    "mimo_v2_flash", "longcat_flash", "ouro", "nemotron_h",
 )
 #: the two SUBLAYERS of one published layer of a shortcut-connected expert model
 #: (``longcat_flash``): the first forks the expert block off its MLP's input, the
@@ -181,6 +207,8 @@ _STATE_NAMES = {
     "power": "a float32 power-retention state and its normaliser a KV head, "
              "and no K/V at all",
     "mamba": "a float32 state-space state and a convolution window",
+    "mamba2": "a float32 state a head of its state-space (Mamba-2) layers and a "
+              "convolution tail",
     "window": "a ring of the last sliding_window tokens' K and V and no page",
     "cca": "K/V pages and, beside them in the same layer, a row state: the "
            "convolutions' tail and the value taken a token late",
@@ -273,6 +301,13 @@ class ModelConfig:
     mamba_d_conv: int = 4  # taps of the causal depth-wise convolution
     mamba_expand: int = 2  # d_inner = expand x hidden
     mamba_dt_rank: int = 0  # inner width of the step size's low-rank pair
+    # ---- Mamba-2 layers of one sublayer (nemotron_h; module docstring); the
+    # state's columns are ``mamba_d_state``, the taps ``mamba_d_conv``
+    ssd_heads: int = 0  # mamba_num_heads
+    ssd_head_dim: int = 0  # mamba_head_dim
+    ssd_groups: int = 1  # n_groups: B and C are a group's, read by its heads
+    ssd_chunk: int = 128  # chunk_size: tokens a chunk of the matrix form
+    shared_expert_width: int = 0  # the shared expert's own; 0 = n_shared x moe width
     # ---- a layer's second half, a LAYER (exaone_moe's ``mlp_layer_types``, the
     # published list whole: "dense" | "sparse"). None = the family's own rule
     mlp_types: tuple[str, ...] | None = None
@@ -350,6 +385,10 @@ class ModelConfig:
                     f"mlp_types holds {sorted(set(self.mlp_types))} over "
                     f"{len(self.mlp_types)} layers: one of 'dense' / 'sparse' for "
                     f"each of the {len(self.mixer_types)} published layers")
+            if self.ssd_moe and (not self.ssd_heads or self.ssd_heads % self.ssd_groups):
+                raise ValueError(
+                    f"mamba2 layers need ssd_heads ({self.ssd_heads}) a multiple of "
+                    f"ssd_groups ({self.ssd_groups}): a head reads its group's B and C")
             if self.window_moe and not self.sliding_window:
                 raise ValueError(
                     "sliding_attention layers need sliding_window: the tokens a "
@@ -387,6 +426,28 @@ class ModelConfig:
         "softmax" layers, each with a dense MLP."""
         return self.mixer_types is not None and (
             "mamba" in self.mixer_types[: self.num_layers])
+
+    @property
+    def ssd_moe(self) -> bool:
+        """True for a state-space expert model of one sublayer a layer
+        (``nemotron_h``): "mamba2", "softmax_alone" and "experts" layers."""
+        return self.mixer_types is not None and bool(
+            {"mamba-2", "attention-only", "moe"} & set(self.mixer_types[: self.num_layers]))
+
+    @property
+    def ssd_inner(self) -> int:
+        """Channels of a Mamba-2 layer: heads x head_dim (NOT expand x hidden)."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_conv_dim(self) -> int:
+        """Channels the convolution mixes and a slot's tail keeps: ``[x | B | C]``."""
+        return self.ssd_inner + 2 * self.ssd_groups * self.mamba_d_state
+
+    @property
+    def ssd_in_dim(self) -> int:
+        """Columns of ``W_in``: ``[z | xBC | dt]``."""
+        return self.ssd_inner + self.ssd_conv_dim + self.ssd_heads
 
     @property
     def window_moe(self) -> bool:
@@ -430,8 +491,9 @@ class ModelConfig:
     def layer_kinds(self) -> tuple[str, ...]:
         """Kind of each layer that is RUN: "dense" | "sparse" | "lightning" |
         "latent" (latent attention, dense MLP) | "latent_moe" (experts) |
-        "softmax" | "delta" | "power" | "mamba" | "window" | "cca"; with ``mlp_types``
-        a layer whose second half is the dense MLP carries ``DENSE_FFN``."""
+        "softmax" | "delta" | "power" | "mamba" | "window" | "cca" | "mamba2" |
+        "softmax_alone" | "experts"; with ``mlp_types`` a layer whose second
+        half is the dense MLP carries ``DENSE_FFN``."""
         if self.shortcut_moe:  # a published layer is two SUBLAYERS
             return SHORTCUT_KINDS * self.num_layers
         if self.latent:
@@ -447,9 +509,13 @@ class ModelConfig:
         return tuple(k + DENSE_FFN * (f == "dense") for k, f in zip(kinds, self.mlp_types))
 
     def layer_ffn(self, kind: str) -> str:
-        """"dense" | "experts": the second half of a "softmax", "delta",
-        "mamba" or "window" layer of ``kind``. A kind that says so is dense;
-        otherwise the model's routed experts, where it has any."""
+        """"dense" | "experts" | "none": the second half of a "softmax",
+        "delta", "mamba" or "window" layer of ``kind``. A kind that says so is
+        dense, or the mixer alone ("none"; a "mamba2" layer always is);
+        otherwise the model's routed experts, where it has any (an "experts"
+        layer is they and no mixer)."""
+        if kind.endswith(ALONE) or kind == "mamba2":
+            return "none"
         if (kind.endswith(DENSE_FFN) or kind in ("latent", "latent_join")
                 or not self.n_routed_experts):
             return "dense"
@@ -533,7 +599,7 @@ class ModelConfig:
 
     @property
     def shared_expert_size(self) -> int:
-        return self.n_shared_experts * self.moe_intermediate_size
+        return self.shared_expert_width or self.n_shared_experts * self.moe_intermediate_size
 
     def kind_count(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds if k == kind)
@@ -712,6 +778,8 @@ class ModelConfig:
             return self._window_moe_param_count(self.experts_per_token)
         if self.cca:
             return self._cca_param_count(self.experts_per_token)
+        if self.ssd_moe:
+            return self._ssd_moe_param_count(self.experts_per_token)
         if not self.hybrid:
             return self.num_layers * (attn + mlp) + self.hidden_size * self.vocab_size
         sparse = attn + self.hidden_size * self.q_dim * self.attn_output_gate
@@ -816,6 +884,20 @@ class ModelConfig:
             attn + conv + router + 3 * d * self.moe_intermediate_size * experts
         ) + d * self.vocab_size
 
+    def _ssd_moe_param_count(self, experts: int) -> int:
+        """Matmul parameters of a ``nemotron_h`` model with ``experts`` routed
+        experts counted an expert layer (``_delta_moe_param_count`` says
+        which): a layer is ONE of W_in and W_out; q, k, v, o; or the router,
+        the experts' two matrices each and the shared expert's two."""
+        d = self.hidden_size
+        mamba = d * self.ssd_in_dim + self.ssd_inner * d
+        attn = 2 * d * self.q_dim + 2 * d * self.kv_dim
+        moe = 2 * d * (experts * self.moe_intermediate_size + self.shared_expert_size) + (
+            d * self.router_width)
+        return (
+            self.kind_count("mamba2") * mamba + self.mixer_count("softmax") * attn
+            + self.kind_count("experts") * moe + d * self.vocab_size)
+
     @property
     def total_matmul_param_count(self) -> int:
         """``matmul_param_count`` over every expert HELD, not only those a
@@ -828,6 +910,8 @@ class ModelConfig:
             return self._window_moe_param_count(self.n_routed_experts)
         if self.cca:
             return self._cca_param_count(self.n_routed_experts)
+        if self.ssd_moe:
+            return self._ssd_moe_param_count(self.n_routed_experts)
         return self.matmul_param_count
 
     def decode_flops_per_token(self, mean_kv_len: float = 0.0) -> float:
@@ -860,6 +944,11 @@ class ModelConfig:
             # multiply-add, the reduction against C: 6 a state entry, and an exp)
             attn = 4.0 * self.kind_count("softmax") * self.q_dim * mean_kv_len + (
                 7.0 * self.kind_count("mamba") * self.mamba_inner * self.mamba_d_state)
+        if self.ssd_moe:
+            # as above with heads of state: the decay a head, dt x B^T, the
+            # multiply-add and the reduction against C, 6 a state entry
+            attn = 4.0 * self.mixer_count("softmax") * self.q_dim * mean_kv_len + (
+                6.0 * self.kind_count("mamba2") * self.ssd_inner * self.mamba_d_state)
         if self.window_moe:
             # a window layer's token attends at most ``sliding_window`` keys;
             # a key costs a query head its q.k and its p v, each at its width
@@ -893,6 +982,8 @@ class ModelConfig:
             return "brumby"
         if self.mamba:
             return "jamba"
+        if self.ssd_moe:
+            return "nemotron_h"
         if self.window_moe:
             return "mimo_v2_flash" if self.window_sink else "exaone_moe"
         if self.cca:
@@ -994,6 +1085,8 @@ class ModelConfig:
             head_dim = hybrid["qk_nope_head_dim"] + hybrid["qk_rope_head_dim"]
         if mt == "ouro":
             hybrid = _looped_fields(get)
+        if mt == "nemotron_h":
+            hybrid = _ssd_moe_fields(get)
         act = str(get("hidden_activation", None) or get("hidden_act", "silu"))
         # Qwen2 configs carry sliding_window but gate it off by default
         window = get("sliding_window") if get("use_sliding_window", True) else None
@@ -1309,6 +1402,83 @@ def _jamba_fields(get, keys) -> dict:
         mamba_d_conv=int(get("mamba_d_conv", 4)),
         mamba_expand=int(get("mamba_expand", 2)),
         mamba_dt_rank=-(-hidden // 16) if rank == "auto" else int(rank),
+    )
+
+
+#: ``hybrid_override_pattern``'s characters -> the names ``mixer_types`` holds
+_PATTERN_NAMES = {"M": "mamba-2", "*": "attention-only", "E": "moe"}
+
+
+def _ssd_moe_fields(get) -> dict:
+    """The ``nemotron_h`` keys as ``ModelConfig`` fields: the layers from the
+    characters of ``hybrid_override_pattern`` (whole, whatever depth is run),
+    Mamba-2's sizes (``mamba_num_heads`` x ``mamba_head_dim`` channels: ``expand``
+    is read by nothing), the experts under DeepSeek-V3's router keys with a
+    ``share`` as ``_delta_moe_fields`` reads one, the shared expert's own width,
+    the norm's eps from ``layer_norm_epsilon``. The attention layers rotate
+    nothing: the family's published attention applies no rotary, so
+    ``rope_theta`` and ``partial_rotary_factor`` are read by nothing (an
+    ``assumed`` entry of the benchmark's file). A variant that is not
+    implemented is REFUSED by key; the family's dense relu^2 MLP layer (``-``)
+    is refused by name."""
+    def refuse(key: str, why: str):
+        raise ValueError(f"nemotron_h with {key}={get(key)!r} is not supported: {why}")
+
+    pattern = get("hybrid_override_pattern")
+    layers = int(get("num_hidden_layers"))
+    if not pattern:
+        raise ValueError("model_type 'nemotron_h' needs its hybrid_override_pattern")
+    if len(pattern) < layers:
+        refuse("hybrid_override_pattern", f"it places {len(pattern)} layers and "
+               f"num_hidden_layers is {layers}")
+    if "-" in pattern:
+        refuse("hybrid_override_pattern", "'-' is the family's dense relu^2 MLP "
+               "layer, which is not implemented: a layer is M (Mamba-2), * "
+               "(attention) or E (experts)")
+    unknown = sorted(set(pattern) - set(_PATTERN_NAMES))
+    if unknown:
+        refuse("hybrid_override_pattern", f"a layer is M, * or E, not {unknown}")
+    for key in ("mamba_proj_bias", "use_bias", "attention_bias", "mlp_bias"):
+        if get(key, False):
+            refuse(key, "no projection of any layer carries a bias")
+    if not get("use_conv_bias", True):
+        refuse("use_conv_bias", "the convolution's bias is always read")
+    if str(get("mlp_hidden_act", "relu2")) != "relu2":
+        refuse("mlp_hidden_act", "an expert is W_down relu(W_up h)^2, ungated")
+    if str(get("mamba_hidden_act", "silu")) != "silu":
+        refuse("mamba_hidden_act", "the convolution's and the gate's activation is SiLU")
+    _refuse_router_variants(get, refuse)
+    if not get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "the chosen scores are normalised to sum to 1 "
+               "before routed_scaling_factor")
+    if get("sliding_window") is not None:
+        refuse("sliding_window", "the attention layers attend over the whole "
+               "context; a window is not implemented")
+    if get("tie_word_embeddings", False):
+        refuse("tie_word_embeddings", "the head is a matrix of its own")
+    heads, groups = int(get("mamba_num_heads")), int(get("n_groups", 1))
+    if heads % groups:
+        refuse("mamba_num_heads", f"head h reads group h // (heads / n_groups) of "
+               f"n_groups {groups}: the heads must be a multiple of the groups")
+    held = int(get("n_routed_experts") or 0)
+    published = dict((get("share") or {}).get("published") or {})
+    width = int(published.get("n_routed_experts", held))
+    return dict(
+        mixer_types=tuple(_PATTERN_NAMES[c] for c in pattern),
+        attn_use_rope=False,
+        rms_norm_eps=float(get("layer_norm_epsilon", get("norm_eps", 1e-5))),
+        ssd_heads=heads, ssd_head_dim=int(get("mamba_head_dim")), ssd_groups=groups,
+        ssd_chunk=int(get("chunk_size", 128)),
+        mamba_d_state=int(get("ssm_state_size")), mamba_d_conv=int(get("conv_kernel", 4)),
+        n_routed_experts=held,
+        router_experts=width if width != held else 0,
+        expert_shard=int(get("expert_shard", 0) or 0),
+        n_shared_experts=int(get("n_shared_experts") or 0),
+        shared_expert_width=int(get("moe_shared_expert_intermediate_size") or 0),
+        experts_per_token=int(get("num_experts_per_tok") or 0),
+        moe_intermediate_size=int(get("moe_intermediate_size") or 0),
+        norm_topk_prob=True,
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
     )
 
 
@@ -1702,6 +1872,20 @@ TINY_OURO = ModelConfig(
     rms_norm_eps=1e-6, loop_steps=3, sublayer_out_norm=True,
 )
 
+# a state-space expert model of one sublayer a layer at a size the CPU tests run
+# (Nemotron-3-Nano's shape): the three kinds (M E M * E M), 4 heads of state
+# 8 x 16 in two groups (chunks of 12), 4 query heads over 2 KV heads, 4 of 8 experts a chip of
+# 2, 3 a token, ungated relu^2, a shared expert twice an expert's width
+TINY_NEMOTRON_H = ModelConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=48, num_layers=6,
+    num_heads=4, num_kv_heads=2, head_dim=16, rms_norm_eps=1e-5,
+    mixer_types=("mamba-2", "moe", "mamba-2", "attention-only", "moe", "mamba-2", "moe"),
+    attn_use_rope=False, ssd_heads=4, ssd_head_dim=8, ssd_groups=2, ssd_chunk=12,
+    mamba_d_state=16, n_routed_experts=4, router_experts=8, n_shared_experts=1,
+    shared_expert_width=96, experts_per_token=3, moe_intermediate_size=48,
+    routed_scaling_factor=2.5,
+)
+
 PRESETS: dict[str, ModelConfig] = {
     "tiny": TINY,
     "tiny-ouro": TINY_OURO,
@@ -1714,6 +1898,7 @@ PRESETS: dict[str, ModelConfig] = {
     "tiny-dsa": TINY_DSA,
     "tiny-cca": TINY_CCA,
     "tiny-scmoe": TINY_SCMOE,
+    "tiny-nemotron-h": TINY_NEMOTRON_H,
     "qwen2.5-0.5b": QWEN2_0_5B,
     "qwen2.5-7b": QWEN2_7B,
     "qwen2.5-72b": QWEN2_72B,

@@ -217,6 +217,31 @@ real token, a candidate from its prompt. The router's ``r`` is a second value
 that flows from layer to layer: for this family alone the layer loop carries
 ``(x, r)``.
 
+**Layers of ONE sublayer** (``nemotron_h``, NVIDIA-Nemotron-3-Nano-30B-A3B) are
+three kinds, each ``x <- x + Mixer(RMSNorm(x))`` and nothing after it: a kind
+states which halves it has (``cfg.layer_ffn``: "none" for the first two;
+``mixer_of("experts")`` names no mixer)::
+
+    mamba2:        [z | xBC | dt] = W_in h                       no bias
+                   xBC = silu(conv4(xBC) + b_conv)               x, B and C alike
+                   x [T, H, P];  B, C [T, G, N];  head h reads group h // (H / G)
+                   dt = softplus(dt + dt_bias) a head, float32;  A = -exp(A_log) a head
+                   S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) B_t^T;  y_t = S_t C_t + D x_t
+                   out = W_out (RMSNorm_group(y * silu(z)) * w)  the gate BEFORE the norm
+    softmax_alone: "softmax" above without RoPE, gate or norm, and no second half
+    experts:       x + Shared(h) + sum_{k held here} w_k E_k(h),  E(h) = W_down relu(W_up h)^2
+
+A "mamba2" layer (``ops/ssd.py``) keeps the state-space entries of a slot's
+state under the names the "mamba" kind uses, at its own shapes: ``ssm``, a
+float32 ``[B, H, P, N]`` state a layer (64 x 64 x 128 = 2 MiB a slot at the
+published sizes, the 128 state columns along the lanes, no padding), and
+``conv``, the last three tokens' ``xBC`` kept FLAT, ``[B, 3 (E + 2 G N)]``
+(three rows on the sublanes would be padded to a tile or moved by a copy a
+step: ``ops/ssd.py::conv_step``). A
+"softmax_alone" layer keeps K/V pages as a "softmax" layer does; an "experts"
+layer keeps nothing. ``ssm_stats`` counts the (live row, "mamba2" layer)
+states the decode steps read and wrote, the expert counters as above.
+
 The second window family (``mimo_v2_flash``, MiMo-V2-Flash) is the SAME two
 kinds with what its configuration states (``models/configs.py``): ``K_l`` KV
 heads a kind (the pages' ``num_kv_heads``, the rings' ``window_kv_heads``),
@@ -269,6 +294,7 @@ from distrl_llm_tpu.ops.linear import linear
 from distrl_llm_tpu.ops.linear_attention import lightning_chunked, lightning_step
 from distrl_llm_tpu.ops.power_retention import init_state, power_chunked, power_step
 from distrl_llm_tpu.ops.selective_scan import ssm_chunked, ssm_step
+from distrl_llm_tpu.ops.ssd import conv_step, gated_group_norm, ssd_chunked, ssd_step
 from distrl_llm_tpu.ops.token_index import (
     chosen_mask, chosen_tokens, index_paged_scores, index_scores,
 )
@@ -292,10 +318,12 @@ INDEX_COUNT_UNIT = 128
 #: eps of the index key's LayerNorm (torch's default; the config has no key for it)
 INDEX_NORM_EPS = 1e-6
 #: the mixers whose layers ``_block`` runs as a mixer and then the layer's own
-#: second half, and the cache entries each keeps a layer
+#: second half (``cfg.layer_ffn``: either may be absent), and the cache entries
+#: each keeps a layer; "experts" is a layer with no mixer, which keeps nothing
 _MIXER_CACHE = {"softmax": ("k", "v"), "delta": ("delta", "conv"),
                 "mamba": ("ssm", "conv"), "window": ("win_k", "win_v"),
-                "cca": ("k", "v", "cca_tail")}
+                "cca": ("k", "v", "cca_tail"), "mamba2": ("ssm", "conv"),
+                "experts": ()}
 
 
 def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -359,18 +387,19 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
         """An expert layer's second half: the shared expert under the MLP's
         names, the router at its published width, the experts HELD."""
         d, fm, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts
+        f, gated = cfg.shared_expert_size, not cfg.ssd_moe  # ungated: two matrices each
         p = {
             "mlp_norm": jnp.ones((n, d), dtype),
             "router": init((n, d, cfg.router_width)),
             "e_score_bias": jnp.zeros((n, cfg.router_width), dtype),
-            "experts_gate": init((n, held, d, fm)),
-            "experts_up": init((n, held, d, fm)),
-            "experts_down": init((n, held, fm, d)),
         }
-        f = cfg.shared_expert_size
+        if gated:
+            p["experts_gate"] = init((n, held, d, fm))
+        p.update(experts_up=init((n, held, d, fm)), experts_down=init((n, held, fm, d)))
+        if f and gated:
+            p["w_gate"] = init((n, d, f))
         if f:
-            p.update(w_gate=init((n, d, f)), w_up=init((n, d, f)),
-                     w_down=init((n, f, d)))
+            p.update(w_up=init((n, d, f)), w_down=init((n, f, d)))
         return p
 
     def mixer_stack(n: int, q_dim: int, kv_dim: int, v_dim: int = 0,
@@ -388,7 +417,28 @@ def init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> P
                 "w_up": init((n, d, f)), "w_down": init((n, f, d))}
 
     layers: Params = {}
-    if cfg.window_moe:  # the mixer as its kind states it; the second half is the layer's
+    if cfg.ssd_moe:  # ONE sublayer a layer: a mixer's stack, or the experts'
+        if cfg.kind_count("softmax_alone"):
+            layers["softmax_alone"] = mixer_stack(
+                cfg.kind_count("softmax_alone"), cfg.q_dim, cfg.kv_dim)
+        if cfg.kind_count("mamba2"):
+            n, d, heads = cfg.kind_count("mamba2"), cfg.hidden_size, cfg.ssd_heads
+            layers["mamba2"] = {
+                "attn_norm": jnp.ones((n, d), dtype),
+                "w_in": init((n, d, cfg.ssd_in_dim)),  # z, then x B C, then dt
+                "conv": init((n, cfg.mamba_d_conv, cfg.ssd_conv_dim)),
+                "b_conv": jnp.zeros((n, cfg.ssd_conv_dim), dtype),
+                # A = -(1..16) over the heads; the inverse softplus of a step of 0.01
+                "A_log": jnp.broadcast_to(
+                    jnp.log(jnp.linspace(1.0, 16.0, heads)), (n, heads)).astype(dtype),
+                "dt_bias": jnp.full((n, heads), -4.6, dtype),
+                "ssd_d": jnp.ones((n, heads), dtype),
+                "gate_norm": jnp.ones((n, cfg.ssd_inner), dtype),
+                "w_out": init((n, cfg.ssd_inner, d)),
+            }
+        if cfg.kind_count("experts"):
+            layers["experts"] = expert_half(cfg.kind_count("experts"))
+    elif cfg.window_moe:  # the mixer as its kind states it; the second half is the layer's
         for kind in dict.fromkeys(cfg.layer_kinds):
             n, kv = cfg.kind_count(kind), cfg.kv_heads_of(mixer_of(kind))
             layers[kind] = {
@@ -507,7 +557,8 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
     layer, the selector's pooled keys per sparse layer, a float32 state and a
     convolution tail per delta-rule layer, a float32 state and its normaliser
     per power-retention layer, a float32 state and a convolution window per
-    Mamba layer, a tail per compressed-convolutional layer, the round's
+    Mamba layer (of either generation: "mamba" ``[B, N, E]``, "mamba2" ``[B, H,
+    P, N]``), a tail per compressed-convolutional layer, the round's
     counters. The entries named in ``ROW_STATES`` are tuples of one array a row."""
     if cfg.latent:  # all of a slot's cache is in pages; the round's counters
         state = {"lin": (), "pooled": (), "moe_stats": jnp.zeros((2,), jnp.int32),
@@ -551,6 +602,23 @@ def init_mixer_state(cfg: ModelConfig, rows: int, max_tokens: int,
             "moe_routed": jnp.zeros((1,), jnp.int32),
             "window_stats": jnp.zeros((2,), jnp.int32),
         }
+    if cfg.ssd_moe:
+        n = cfg.kind_count("mamba2")
+        state = {
+            "lin": (), "pooled": (),
+            "ssm": tuple(
+                jnp.zeros((rows, cfg.ssd_heads, cfg.ssd_head_dim, cfg.mamba_d_state),
+                          jnp.float32) for _ in range(n)),
+            "conv": tuple(
+                jnp.zeros((rows, (cfg.mamba_d_conv - 1) * cfg.ssd_conv_dim), cache_dtype)
+                for _ in range(n)),
+            "ssm_stats": jnp.zeros((1,), jnp.int32),
+            "moe_stats": jnp.zeros((2,), jnp.int32),
+            "moe_blocks": jnp.zeros((2,), jnp.int32),
+        }
+        if cfg.held_experts is not None:  # the pairs chosen over ALL experts
+            state["moe_routed"] = jnp.zeros((1,), jnp.int32)
+        return state
     if cfg.mamba:
         n, e = cfg.kind_count("mamba"), cfg.mamba_inner
         return {
@@ -706,13 +774,17 @@ def _block(x, p, lora, rate, cache, *, kind: str, cfg: ModelConfig, mode: str,
     mixer = mixer_of(kind)
     if mixer in _MIXER_CACHE:
         mix = {"softmax": _softmax_mix, "delta": _delta_mix, "mamba": _mamba_mix,
-               "window": _window_mix, "cca": _cca_mix}[mixer]
+               "window": _window_mix, "cca": _cca_mix, "mamba2": _ssd_mix}.get(mixer)
         carried = None
         if mixer == "cca":  # the stream, and the router's value from the layer before
             x, carried = x
-        x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
-                       lora_scale=lora_scale)
-        if cfg.layer_ffn(kind) == "dense":  # the layer's own second half
+        if mix is not None:  # an "experts" layer has no mixer
+            x, cache = mix(x, p, lora, cache, cfg=cfg, mode=mode, env=env, proj=proj,
+                           lora_scale=lora_scale)
+        ffn = cfg.layer_ffn(kind)  # the layer's own second half, if it has one
+        if ffn == "none":
+            return x, cache, None
+        if ffn == "dense":
             return _mlp_half(x, p, lora, cfg=cfg, proj=proj,
                              lora_scale=lora_scale), cache, None
         x, stats = _expert_half(x, p, lora, cfg=cfg, env=env, proj=proj,
@@ -780,7 +852,7 @@ def _expert_half(x, p, lora, *, cfg, env, proj, lora_scale, carried=None):
         *choice, carried = route_mlp(h, carried, p, cfg)
         routed, stats = moe_half(h, p, cfg, held=cfg.held_experts, alive=env.get("alive"),
                                  choice=tuple(choice))
-    if "w_gate" in p:  # the shared expert: x + S(h)
+    if "w_up" in p:  # the shared expert: x + S(h)
         x = _mlp_half(x, p, lora, cfg=cfg, proj=proj, lora_scale=lora_scale)
     x = _merge(x, routed, p, "mlp")
     return (x if carried is None else (x, carried)), stats
@@ -1133,6 +1205,50 @@ def _mamba_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
         else:
             y, state = ssm_chunked(
                 c, dt, b, cc, a, p["ssm_d"], env["valid"], state=state, z=z)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        x = x + proj(y, p, lora, "w_out", None, lora_scale)
+    return x, (None if mode == "full" else (state, tail))
+
+
+def _ssd_mix(x, p, lora, cache, *, cfg, mode, env, proj, lora_scale):
+    """A Mamba-2 layer (module docstring; ``ops/ssd.py``): (x + y, (state,
+    tail) or None)."""
+    b, s, _ = x.shape
+    heads, groups, cols = cfg.ssd_heads, cfg.ssd_groups, cfg.mamba_d_state
+    inner, mixed_dim = cfg.ssd_inner, cfg.ssd_conv_dim
+    state, tail = cache if cache is not None else (None, None)
+    with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        zxd = proj(h, p, lora, "w_in", None, lora_scale)
+        z, xbc, dt = (zxd[..., :inner], zxd[..., inner: inner + mixed_dim],
+                      zxd[..., inner + mixed_dim:])
+    with jax.named_scope(telemetry.MODEL_SHORT_CONV):
+        if mode == "decode":  # a slot's tail is FLAT, [B, 3 (E + 2 G N)]: ops/ssd.py
+            mixed, kept = conv_step(xbc[:, 0], p["conv"], tail)
+            mixed = mixed[:, None]
+        else:
+            mixed, kept = short_conv(
+                xbc, p["conv"], env["valid"],
+                None if tail is None else tail.reshape(b, -1, mixed_dim))
+            kept = kept.reshape(b, -1)
+        if mode == "segment":  # the tail is read out before the chunks run (_mamba_mix)
+            mixed, kept = jax.lax.optimization_barrier((mixed, kept))
+        tail = None if tail is None else kept.astype(tail.dtype)  # the cache's type
+        xbc = jax.nn.silu(mixed + p["b_conv"].astype(mixed.dtype))
+    with jax.named_scope(telemetry.MODEL_SSM):
+        u = xbc[..., :inner].reshape(b, s, heads, cfg.ssd_head_dim)
+        bb = xbc[..., inner: inner + groups * cols].reshape(b, s, groups, cols)
+        cc = xbc[..., inner + groups * cols:].reshape(b, s, groups, cols)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["A_log"].astype(jnp.float32))
+        if mode == "decode":
+            y, state = ssd_step(u[:, 0], dt[:, 0], bb[:, 0], cc[:, 0], a, p["ssd_d"], state)
+            y = y[:, None]
+        else:
+            y, state = ssd_chunked(u, dt, bb, cc, a, p["ssd_d"], env["valid"],
+                                   state=state, chunk=cfg.ssd_chunk)
+        y = gated_group_norm(y.reshape(b, s, inner), z, p["gate_norm"], groups,
+                             cfg.rms_norm_eps).astype(x.dtype)
     with jax.named_scope(telemetry.MODEL_ATTN_PROJ):
         x = x + proj(y, p, lora, "w_out", None, lora_scale)
     return x, (None if mode == "full" else (state, tail))
@@ -1668,7 +1784,7 @@ def forward_hybrid(
     if "ssm_stats" in kv_cache and mode == "decode":  # live rows' states, Mamba layers
         live = b if env.get("alive") is None else env["alive"].sum()
         out["ssm_stats"] = kv_cache["ssm_stats"] + jnp.asarray(
-            cfg.kind_count("mamba") * live, jnp.int32)
+            (cfg.kind_count("mamba") + cfg.kind_count("mamba2")) * live, jnp.int32)
     # keys a live row's decoded token attends of those it sees, in whole units:
     # every window layer alike, and every layer of a model with an index
     for name, layers, most, unit in (

@@ -235,6 +235,14 @@ def _refuse_unnamed(cfg: ModelConfig) -> None:
             "the three inner norms) cannot be checked here, the state's A_log is held "
             "transposed, and a guessed name would load or save something else under "
             "the model's name; the model runs from seeded weights only (init_params)")
+    if cfg.ssd_moe:
+        raise NotImplementedError(
+            "model_type 'nemotron_h' checkpoints are not supported: the published "
+            "tensor names of its layers (a Mamba-2 mixer's in_proj, conv1d, A_log, D, "
+            "dt_bias and gated norm, the router with its correction bias, the experts' "
+            "and the shared expert's two matrices) cannot be checked here, and a "
+            "guessed name would load or save something else under the model's name; "
+            "the model runs from seeded weights only (init_params)")
     if cfg.model_type == "mimo_v2_flash":
         raise NotImplementedError(
             "model_type 'mimo_v2_flash' checkpoints are not supported: the published "

@@ -160,6 +160,23 @@ def init_lora_params(
     elif cfg.power:
         # Qwen3's seven targets; the log-decay's projection and bias are frozen
         per_kind = {"power": dims}
+    elif cfg.ssd_moe:
+        # layers of ONE sublayer: q, k, v, o of an attention layer; W_in and W_out
+        # of a Mamba-2 layer; the shared expert's TWO matrices (it has no gate) of
+        # an expert layer. The convolution, A_log, dt_bias, D, the gate's norm, the
+        # router and the routed experts are frozen
+        attention = {k: v for k, v in dims.items() if k != "intermediate_size"}
+        per_kind = {
+            "softmax_alone": attention,
+            "mamba2": {"hidden_size": cfg.hidden_size, "mamba_inner": cfg.ssd_inner,
+                       "mamba_in_dim": cfg.ssd_in_dim},
+            "experts": {"hidden_size": cfg.hidden_size,
+                        **({"intermediate_size": cfg.shared_expert_size}
+                           if cfg.shared_expert_size else {})}}
+        # a gate is a gated MLP's: this family's shared expert has none
+        kind_targets = {"experts": tuple(t for t in targets if t != "w_gate")}
+        if not named:
+            kind_targets["mamba2"] = MAMBA_TARGETS
     elif cfg.mamba:
         per_kind = {"softmax": dims, "mamba": {
             "hidden_size": cfg.hidden_size, "intermediate_size": cfg.intermediate_size,

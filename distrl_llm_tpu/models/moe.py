@@ -11,6 +11,14 @@ top-k without groups, a shared expert beside the routed ones).
 The shared expert is a plain gated MLP and goes through the decoder's own
 ``_mlp_half`` (``models/hybrid.py``); this module is the routed part.
 
+**An ungated expert** (``nemotron_h``, NVIDIA-Nemotron-3-Nano: experts in a
+layer of their own, no mixer before them) is TWO matrices and a squared ReLU,
+``E_e(h) = W_down_e relu(W_up_e h)^2``. The form is read off the stack: where
+it holds no ``gate`` (the layer no ``experts_gate`` leaf) both forms below
+multiply two matrices an expert; routing, dispatch, the share held and the
+counters are the same code. The shared expert beside them is ungated too
+(``_mlp_half`` reads it off the layer the same way).
+
 **Dropless.** There is no capacity: every (token, expert) pair is computed at
 any imbalance. Plain XLA throughout, in two forms. ``expert_form`` chooses
 between them, and the grouped form's block, from the rows a HELD expert is
@@ -172,8 +180,26 @@ def grouped_runs(t: int, k: int) -> int:
     return next(n for n in range(-(-t * k // GROUPED_MAX_PAIRS), t + 1) if t % n == 0)
 
 
+def relu2(x):
+    """``relu(x)^2``: an ungated expert's activation (module docstring)."""
+    return jnp.square(jax.nn.relu(x))
+
+
+#: an expert's matrices in the order its product takes them; an ungated
+#: expert's stack holds the last two (module docstring)
+_MATRICES = ("gate", "up", "down")
+
+
 def _gated(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def _expert(x, *matrices):
+    """One expert on its rows: gated (gate, up, down) or ungated (up, down)."""
+    if len(matrices) == 3:
+        return _gated(x, *matrices)
+    up, down = matrices
+    return relu2(x @ up) @ down
 
 
 def _local_ids(idx, n: int, n_experts: int, held):
@@ -188,7 +214,8 @@ def _local_ids(idx, n: int, n_experts: int, held):
 def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
                    *, n_experts: int, held=None, alive=None, layer=None):
     """``sum_k w_k E_idx_k(h)`` over the experts held. ``h [T, D]``, ``idx`` /
-    ``w [T, k]``; ``experts = {"gate", "up" [n, D, F], "down" [n, F, D]}``.
+    ``w [T, k]``; ``experts = {"gate", "up" [n, D, F], "down" [n, F, D]}``, or
+    ``up`` and ``down`` alone: ungated experts (module docstring).
     Returns ``(y [T, D], load [n] int32, blocks [2] int32)``: ``load`` counts
     the pairs each held expert computed (of ``alive`` tokens, if given),
     ``blocks`` the grouped form's blocks that ran and that were laid (zeros
@@ -198,7 +225,7 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
     form indexes (layer, expert) in one step, so no layer's experts are
     sliced out of the stack first."""
     t, k = idx.shape
-    n = experts["gate"].shape[-3]
+    n = experts["down"].shape[-3]
     with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
         local = _local_ids(idx, n, n_experts, held)  # [T, k]
         counted = jnp.ones((t,), jnp.int32) if alive is None else alive.astype(jnp.int32)
@@ -212,8 +239,11 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
             comb = jnp.zeros((t, n + 1), jnp.float32).at[
                 jnp.arange(t)[:, None], local].set(w)[:, :n]
         with jax.named_scope(telemetry.MODEL_MOE_EXPERTS):
-            act = jax.nn.silu(jnp.einsum("td,edf->etf", h, experts["gate"])) * (
-                jnp.einsum("td,edf->etf", h, experts["up"]))
+            if "gate" in experts:
+                act = jax.nn.silu(jnp.einsum("td,edf->etf", h, experts["gate"])) * (
+                    jnp.einsum("td,edf->etf", h, experts["up"]))
+            else:
+                act = relu2(jnp.einsum("td,edf->etf", h, experts["up"]))
             y = jnp.einsum("etf,efd->etd", act, experts["down"])
         with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
             y = jnp.einsum("te,etd->td", comb, y.astype(jnp.float32)).astype(h.dtype)
@@ -244,13 +274,13 @@ def routed_experts(h: jax.Array, idx: jax.Array, w: jax.Array, experts: dict,
         live = first < ends[n - 1]
     with jax.named_scope(telemetry.MODEL_MOE_EXPERTS):
         stacks = [experts[name].reshape(-1, *experts[name].shape[-2:])
-                  for name in ("gate", "up", "down")]
-        nothing = jnp.zeros((bm, stacks[2].shape[-1]), jnp.result_type(h, *stacks))
+                  for name in _MATRICES if name in experts]
+        nothing = jnp.zeros((bm, stacks[-1].shape[-1]), jnp.result_type(h, *stacks))
 
         def product(tokens, e):
             with jax.named_scope(telemetry.MODEL_MOE_DISPATCH):
                 x = h[tokens]  # this block's rows, and no other block's
-            return _gated(x, *(stack[e] for stack in stacks))
+            return _expert(x, *(stack[e] for stack in stacks))
 
         @jax.checkpoint  # reverse mode keeps a block's tokens, not its rows or matrices
         def one(_, block):
@@ -293,7 +323,7 @@ def moe_half(h: jax.Array, p: dict, cfg: ModelConfig, *, held=None, alive=None,
     idx, w = choice or route(flat, p["router"], p["e_score_bias"], cfg)
     # ``alive`` is a flag a ROW: each of the row's tokens takes it
     alive = None if alive is None else jnp.repeat(alive, flat.shape[0] // alive.shape[0])
-    experts = {"gate": p["experts_gate"], "up": p["experts_up"], "down": p["experts_down"]}
+    experts = {name: p["experts_" + name] for name in _MATRICES if "experts_" + name in p}
 
     def some(tokens):
         h_r, idx_r, w_r, alive_r = tokens

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from distrl_llm_tpu import telemetry
+from distrl_llm_tpu.models import moe
 from distrl_llm_tpu.models.configs import ModelConfig
 from distrl_llm_tpu.ops.attention import attention, attention_cached, causal_padding_mask
 from distrl_llm_tpu.ops.linear import OutIn, linear, lora_delta
@@ -199,16 +200,21 @@ def _layer(
 def _mlp_half(x, p: Params, lora, *, cfg: ModelConfig, proj, lora_scale,
               residual_scale=None):
     """The second half of a layer of any kind: norm, gated MLP, residual
-    (``residual_scale``: muP's factor on what joins the stream, or None)."""
+    (``residual_scale``: muP's factor on what joins the stream, or None). A
+    layer that holds no ``w_gate`` has the UNGATED MLP ``W_down relu(W_up h)^2``
+    (``nemotron_h``'s shared expert: models/moe.py)."""
     with jax.named_scope(telemetry.MODEL_MLP):
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps, offset=cfg.rmsnorm_offset)
         act = (
             jax.nn.silu if cfg.hidden_act == "silu"
             else partial(jax.nn.gelu, approximate=True)  # Gemma gelu_pytorch_tanh
         )
-        gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
-        up = proj(h, p, lora, "w_up", "b_up", lora_scale)
-        y = proj(gate * up, p, lora, "w_down", "b_down", lora_scale)
+        if "w_gate" in p:
+            gate = act(proj(h, p, lora, "w_gate", "b_gate", lora_scale))
+            up = gate * proj(h, p, lora, "w_up", "b_up", lora_scale)
+        else:
+            up = moe.relu2(proj(h, p, lora, "w_up", "b_up", lora_scale))
+        y = proj(up, p, lora, "w_down", "b_down", lora_scale)
         if "mlp_out_norm" in p:  # a sublayer normed on both sides (ouro)
             y = rms_norm(y, p["mlp_out_norm"], cfg.rms_norm_eps,
                          offset=cfg.rmsnorm_offset)

@@ -35,7 +35,11 @@ Layout conventions (models/transformer.py):
                parallel and its W_out [L, d_inner, hidden] row parallel, like
                the MLP's pair (39 of its 41 M parameters); W_x, W_dt, the
                convolution, A_log, D and the inner norms are small and whole
-               on every chip.
+               on every chip. A Mamba-2 layer's pair (``nemotron_h``) goes the
+               same way under the same names; its convolution, A_log, dt_bias,
+               D and the gate's norm are whole on every chip, and an expert
+               layer's two matrices an expert are ``_REPLICATED`` like a gated
+               expert's three.
 """
 
 from __future__ import annotations

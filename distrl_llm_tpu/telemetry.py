@@ -215,7 +215,11 @@ MODEL_POWER_ATTN = "model/power_attn"
 # state-space layers (jamba, ops/selective_scan.py): the three inner norms,
 # softplus, the discretisation, the one-token step or the chunked scan, the D
 # skip and the silu(z) gate. W_in, W_x, W_dt and W_out stay ``model/attn_proj``;
-# the convolution with its bias and SiLU is ``model/short_conv``
+# the convolution with its bias and SiLU is ``model/short_conv``. A Mamba-2
+# layer (nemotron_h, ops/ssd.py) stands under the same names: softplus, the
+# one-token step or the chunked matrix form, the D skip and the gated group
+# norm here; W_in and W_out ``model/attn_proj``; the convolution over x, B and
+# C ``model/short_conv``
 MODEL_SSM = "model/ssm"
 # sliding-window layers (exaone_moe, models/hybrid.py::_window_mix): a window
 # layer's attention in all three modes (the band over a whole row, over a

@@ -42,7 +42,7 @@ LORA_SCALE = 2.0
 FAMILY_FILES = (
     "test_hybrid_model", "test_latent_moe", "test_delta_moe", "test_power_model",
     "test_jamba_model", "test_window_moe_model", "test_dsa_moe_model", "test_cca_moe",
-    "test_swa_sink_moe_model", "test_scmoe_model")
+    "test_swa_sink_moe_model", "test_scmoe_model", "test_ssd_moe")
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

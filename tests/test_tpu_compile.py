@@ -791,6 +791,69 @@ def test_window_decode_step_at_published_widths(chip, monkeypatch):
     assert not _weight_sized_operations(text, sizes)
 
 
+@two_depths(("ME*", 3), (None, 13))
+def test_ssd_expert_decode_step_at_published_widths(chip, monkeypatch, depth):
+    """``nemotron-3-nano-ep2-L13.rollout-reasoning-ssd``'s decode step (256 rows,
+    a table of 20 pages, a rank-32 adapter; in the default run one Mamba-2 layer,
+    one expert layer and one attention layer, in ``slow`` the cell's 13) fed the
+    decode view. The attention layers' decode is the ``paged_attention_native``
+    launch at 2 KV heads and a group of 16. The Mamba-2 states (``[256, 64, 64,
+    128]`` float32, 2 MiB a row a layer, the 128 state columns along the lanes),
+    the tails (kept flat, ``[256, 18432]`` bf16) and the two pools are donated and
+    written in place: no synchronous copy of a state pool or of a tail, every
+    state's output its input's buffer, and no second set of states among the
+    temporaries. The 64 held experts of a layer run in the dense form (12 pairs
+    an expert: one block of 256 rows) and are read where they lie: nothing the
+    size of a layer's ``[64, 2688, 1856]`` stack is copied or sliced out."""
+    from distrl_llm_tpu.models import forward, init_lora_params, init_params
+    from distrl_llm_tpu.models.hybrid import init_mixer_state
+    from distrl_llm_tpu.models.transformer import decode_view
+
+    pattern, layers = depth
+    cut = {"num_hidden_layers": layers}
+    if pattern:
+        cut["hybrid_override_pattern"] = pattern
+    cfg = _cell_config("nemotron-3-nano-ep2-L13", **cut)
+    mamba, attention = cfg.kind_count("mamba2"), cfg.paged_layers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda tree: jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), tree)
+    rows, page, bf = 256, 128, jnp.bfloat16
+    width = (2048 + 512) // page
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg=cfg, dtype=bf), jax.random.PRNGKey(0))
+    lora = place(jax.eval_shape(
+        lambda key: init_lora_params(key, cfg, 32, dtype=jnp.float32), jax.random.PRNGKey(1)))
+    pool = chip((2, 16 * 16 + rows * 4 + 8, page, 128), bf)
+    cache = {
+        "k": (pool,) * attention, "v": (pool,) * attention,
+        **place(jax.eval_shape(lambda: init_mixer_state(cfg, rows, width * page, bf))),
+        "lengths": chip((rows,), jnp.int32), "page_indices": chip((rows, width), jnp.int32),
+        "alive": chip((rows,), jnp.bool_)}
+
+    def step(params, lora, cache, ids):
+        return forward(params, cfg, ids, lora=lora, lora_scale=0.5, kv_cache=cache,
+                       page_size=page, paged_impl="auto")
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        place(jax.eval_shape(decode_view, params)), lora, cache,
+        chip((rows, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == attention and all("%paged_attention_native" in c for c in calls), calls
+    assert "bf16[256,2,16,128]" in calls[0], calls  # rows, 2 KV heads, their groups of 16
+    entry = text[text.index("ENTRY "):]
+    state, tail = "f32[256,64,64,128]", "bf16[256,18432]"
+    copies = [line.strip()[:160] for line in entry.splitlines()
+              if " copy(" in line and any(held in line.split("(")[0] for held in (state, tail))]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    states, tails = mamba * rows * 2 * 2**20, mamba * rows * 3 * 6144 * 2
+    assert memory.alias_size_in_bytes >= states + tails + 2 * attention * pool.size * 2
+    assert memory.temp_size_in_bytes < rows * 2 * 2**20 + 400e6  # not a second set of states
+    # no layer's experts copied or sliced out of the stack they lie in
+    assert not _weight_sized_operations(text, {64 * 2688 * 1856})
+
+
 @two_depths(2, 7)
 def test_sink_window_decode_step_at_published_widths(chip, monkeypatch, depth):
     """``mimo-v2-flash-ep16-L7.rollout-longctx-sink-128``'s decode step (128
